@@ -8,6 +8,15 @@ import numpy as np
 from .abstract_accelerator import DeepSpeedAccelerator
 
 
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``. A kind that
+# is not here is an error: a utilization figured against another chip's peak
+# is wrong by a factor nobody sees. (v5e's oft-quoted 394 is the int8 rate.)
+CHIP_PEAKS = {
+    # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s HBM
+    "TPU v5 lite": {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9},
+}
+
+
 class TPU_Accelerator(DeepSpeedAccelerator):
 
     def __init__(self):
@@ -44,10 +53,7 @@ class TPU_Accelerator(DeepSpeedAccelerator):
         return jax.random.key(self._seed)
 
     def memory_stats(self, device_index=None):
-        try:
-            return self.device(device_index).memory_stats() or {}
-        except Exception:
-            return {}
+        return self.device(device_index).memory_stats() or {}
 
     def is_bf16_supported(self):
         return True
@@ -82,34 +88,19 @@ class TPU_Accelerator(DeepSpeedAccelerator):
         devs = self._devices()
         return devs[0].device_kind if devs else "unknown"
 
+    def _peaks(self):
+        kind = self.device_kind()
+        if kind not in CHIP_PEAKS:
+            raise KeyError(
+                f"no published peaks on record for device_kind {kind!r}; add it "
+                f"to accelerator.tpu_accelerator.CHIP_PEAKS with its source "
+                f"(known: {sorted(CHIP_PEAKS)})")
+        return CHIP_PEAKS[kind]
+
     def peak_flops(self, dtype=jnp.bfloat16):
-        """Peak per-chip matmul FLOP/s for MFU math (best-effort by kind)."""
-        kind = self.device_kind().lower()
-        table = {
-            # bf16 peaks (v5e's oft-quoted 394 is the int8 rate — bf16 is 197)
-            "v5 lite": 197e12,
-            "v5litepod": 197e12,
-            "v4": 275e12,
-            "v5p": 459e12,
-            "v6": 918e12,  # trillium
-        }
-        for k, v in table.items():
-            if k in kind:
-                return v
-        return 275e12
+        """Published per-chip bf16 matmul FLOP/s, for MFU math."""
+        return self._peaks()["bf16_flops"]
 
     def peak_hbm_bandwidth(self):
-        """Peak per-chip HBM bandwidth (bytes/s) for roofline math
-        (best-effort by kind, same convention as :meth:`peak_flops`)."""
-        kind = self.device_kind().lower()
-        table = {
-            "v5 lite": 819e9,
-            "v5litepod": 819e9,
-            "v4": 1228e9,
-            "v5p": 2765e9,
-            "v6": 1640e9,  # trillium
-        }
-        for k, v in table.items():
-            if k in kind:
-                return v
-        return 1228e9
+        """Published per-chip HBM bandwidth (bytes/s), for roofline math."""
+        return self._peaks()["hbm_bytes_per_s"]

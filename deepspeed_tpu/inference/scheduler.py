@@ -2052,7 +2052,7 @@ class DecodeScheduler:
             delivered += self._decode_backoff(live)
         return delivered, 1
 
-    def warm_programs(self):
+    def warm_programs(self, ladder=True):
         """Dispatch every step-program variant the cold-expert replay and
         backoff ladder can reach — the (K, chunk) primary, its (1, chunk) /
         (K, 1) / (1, 1) fallbacks, greedy AND sampled, plus the speculative
@@ -2061,7 +2061,9 @@ class DecodeScheduler:
         invisible to traffic. Runs at build (before any gateway recompile
         watch arms), which is what makes residency churn recompile-free
         mid-stream. Requests overriding ``collect_logits`` per-call still
-        compile their variant on first use."""
+        compile their variant on first use. ``ladder=False`` leaves out the
+        (1, 1) program only the backoff ladder and an extent boundary reach
+        (the serving CLI's start-up warm: what plain traffic dispatches)."""
         N = self.cache.num_slots
         C = max(1, self.prefill_chunk)
         K = self.steps_per_sync
@@ -2087,7 +2089,7 @@ class DecodeScheduler:
             out = self._call_step(fn, args, lora)
             self.cache.pool = out[0]
 
-        shapes = sorted({(K, C), (1, C), (K, 1), (1, 1)})
+        shapes = sorted({(K, C), (1, C), (K, 1)} | ({(1, 1)} if ladder else set()))
         # seq-parallel prefill reaches the PLAIN program at the wide chunk
         # width when the seq axis has one device (same math, unsharded)
         wide = ({(K, self._seq_chunk), (1, self._seq_chunk)}

@@ -21,6 +21,7 @@ import shlex
 import subprocess
 import sys
 
+from ..utils import compile_cache
 from ..utils.logging import logger
 
 DEFAULT_COORD_PORT = 8476
@@ -221,6 +222,7 @@ def main(argv=None):
         env = dict(os.environ)
         env.update({"COORDINATOR_ADDRESS": f"{coordinator}:{args.master_port}",
                     "JAX_NUM_PROCESSES": "1", "JAX_PROCESS_ID": "0"})
+        compile_cache.export(env)
         argv = [sys.executable, "-u", args.user_script] + args.user_args
         logger.info(f"single-host launch: {' '.join(argv)}")
         os.execvpe(argv[0], argv, env)  # replaces this process

@@ -380,6 +380,18 @@ def _tp_mesh_size():
     return dist.get_mesh().shape[dist.TENSOR_AXIS]
 
 
+def _paged_kernel_kw(kv_scale, ext_ops, tp_shard):
+    """Optional operands of the paged decode/span kernels: the int8 pool's
+    per-token-row scales, the long-context extent table with its lossy
+    knobs, and the tensor mesh axis to shard the head walk over."""
+    kw = {"k_scale": kv_scale, "v_scale": kv_scale}
+    if ext_ops is not None:
+        kw["ext"], _, _, kw["sink"], kw["window"] = ext_ops
+    if tp_shard:
+        kw.update(mesh=dist.get_mesh(), axis=dist.TENSOR_AXIS)
+    return kw
+
+
 def _tp_replicate(x):
     """Re-replicate a tensor-sharded activation (bitwise-TP serving layout):
     the constraint lowers to an all-gather over ``tensor`` — pure
@@ -835,9 +847,7 @@ class Attention(nn.Module):
                     and not seq_shard
                     and (write_index is not None or not quant_kv)):
                 from ..ops.pallas.decode_attention import decode_attention, \
-                    extent_paged_decode_attention, paged_decode_attention, \
-                    sharded_extent_paged_decode_attention, \
-                    sharded_paged_decode_attention
+                    paged_decode_attention
                 if attn_mask is not None:
                     starts = jnp.argmax(attn_mask.astype(jnp.int32), axis=1)
                 else:
@@ -845,36 +855,12 @@ class Attention(nn.Module):
                 if window:
                     # a sliding window is just a raised start for one query
                     starts = jnp.maximum(starts, cache_index + 1 - window)
-                if ext_ops is not None and tp_kernel_shard:
-                    ext_table, _, _, ext_sink, ext_win = ext_ops
-                    out = sharded_extent_paged_decode_attention(
-                        q[:, :, 0], ck, cv, starts, write_index + 1, ext_table,
-                        mesh=dist.get_mesh(), axis=dist.TENSOR_AXIS,
-                        block_kv=cfg.decode_block_kv,
-                        k_scale=csc if quant_kv else None,
-                        v_scale=csc if quant_kv else None,
-                        sink=ext_sink, window=ext_win)[:, :, None]
-                elif ext_ops is not None:
-                    ext_table, _, _, ext_sink, ext_win = ext_ops
-                    out = extent_paged_decode_attention(
-                        q[:, :, 0], ck, cv, starts, write_index + 1, ext_table,
-                        block_kv=cfg.decode_block_kv,
-                        k_scale=csc if quant_kv else None,
-                        v_scale=csc if quant_kv else None,
-                        sink=ext_sink, window=ext_win)[:, :, None]
-                elif write_index is not None and tp_kernel_shard:
-                    out = sharded_paged_decode_attention(
-                        q[:, :, 0], ck, cv, starts, write_index + 1,
-                        mesh=dist.get_mesh(), axis=dist.TENSOR_AXIS,
-                        block_kv=cfg.decode_block_kv,
-                        k_scale=csc if quant_kv else None,
-                        v_scale=csc if quant_kv else None)[:, :, None]
-                elif write_index is not None:
+                if write_index is not None:
                     out = paged_decode_attention(
                         q[:, :, 0], ck, cv, starts, write_index + 1,
                         block_kv=cfg.decode_block_kv,
-                        k_scale=csc if quant_kv else None,
-                        v_scale=csc if quant_kv else None)[:, :, None]
+                        **_paged_kernel_kw(csc if quant_kv else None, ext_ops,
+                                           tp_kernel_shard))[:, :, None]
                 else:
                     out = decode_attention(q[:, :, 0], ck, cv, starts, cache_index + 1,
                                            block_kv=cfg.decode_block_kv)[:, :, None]
@@ -885,57 +871,23 @@ class Attention(nn.Module):
                 # decode kernel (each row's causal window advances with its
                 # query column)
                 from ..ops.pallas.decode_attention import \
-                    extent_paged_span_attention, paged_span_attention, \
-                    seq_sharded_span_attention, \
-                    sharded_extent_paged_span_attention, \
-                    sharded_paged_span_attention
+                    paged_span_attention, seq_sharded_span_attention
                 if attn_mask is not None:
                     starts = jnp.argmax(attn_mask.astype(jnp.int32), axis=1)
                 else:
                     starts = jnp.zeros((B, ), jnp.int32)
+                kw = _paged_kernel_kw(csc if quant_kv else None, ext_ops,
+                                      tp_kernel_shard)
                 if seq_shard:
                     # sequence-parallel chunked prefill: shards split the
                     # chunk's query columns over the seq axis; KV (already
                     # written, replicated) streams whole on every shard
-                    ext_table = ext_sink = ext_win = None
-                    if ext_ops is not None:
-                        ext_table, _, _, ext_sink, ext_win = ext_ops
                     out = seq_sharded_span_attention(
-                        q, ck, cv, starts, write_index,
-                        mesh=dist.get_mesh(), axis=dist.SEQ_AXIS,
-                        block_kv=cfg.decode_block_kv,
-                        k_scale=csc if quant_kv else None,
-                        v_scale=csc if quant_kv else None,
-                        ext=ext_table, sink=ext_sink, window=ext_win)
-                elif ext_ops is not None and tp_kernel_shard:
-                    ext_table, _, _, ext_sink, ext_win = ext_ops
-                    out = sharded_extent_paged_span_attention(
-                        q, ck, cv, starts, write_index, ext_table,
-                        mesh=dist.get_mesh(), axis=dist.TENSOR_AXIS,
-                        block_kv=cfg.decode_block_kv,
-                        k_scale=csc if quant_kv else None,
-                        v_scale=csc if quant_kv else None,
-                        sink=ext_sink, window=ext_win)
-                elif ext_ops is not None:
-                    ext_table, _, _, ext_sink, ext_win = ext_ops
-                    out = extent_paged_span_attention(
-                        q, ck, cv, starts, write_index, ext_table,
-                        block_kv=cfg.decode_block_kv,
-                        k_scale=csc if quant_kv else None,
-                        v_scale=csc if quant_kv else None,
-                        sink=ext_sink, window=ext_win)
-                elif tp_kernel_shard:
-                    out = sharded_paged_span_attention(
-                        q, ck, cv, starts, write_index,
-                        mesh=dist.get_mesh(), axis=dist.TENSOR_AXIS,
-                        block_kv=cfg.decode_block_kv,
-                        k_scale=csc if quant_kv else None,
-                        v_scale=csc if quant_kv else None)
+                        q, ck, cv, starts, write_index, mesh=dist.get_mesh(),
+                        axis=dist.SEQ_AXIS, block_kv=cfg.decode_block_kv, **kw)
                 else:
                     out = paged_span_attention(q, ck, cv, starts, write_index,
-                                               block_kv=cfg.decode_block_kv,
-                                               k_scale=csc if quant_kv else None,
-                                               v_scale=csc if quant_kv else None)
+                                               block_kv=cfg.decode_block_kv, **kw)
             elif (cfg.attention_impl == "flash" and attn_mask is None and T >= 128
                   and isinstance(cache_index, int) and cache_index == 0 and alibi is None
                   and not window):
@@ -1841,7 +1793,7 @@ class CausalLMModel:
             if dist.in_manual_region():
                 # the aux carry becomes stage-varying inside the scan; mark
                 # its initial value so the carry types agree (shard_map vma)
-                aux0 = jax.lax.pvary(aux0, tuple(dist.get_manual_axes()))
+                aux0 = jax.lax.pcast(aux0, tuple(dist.get_manual_axes()), to="varying")
             (h, aux), _ = jax.lax.scan(body, (h, aux0), (local_layers, global_idx))
             out = (h, mask) if mask is not None else h
             return (out, aux) if moe else out

@@ -1,44 +1,28 @@
 """Pallas TPU kernels.
 
-Shared compat: jax renamed ``pltpu.TPUCompilerParams`` to
-``CompilerParams`` around 0.5 — kernels import the alias from here so the
-version shim can't drift between files. ``shard_map_compat`` papers over
-the ``jax.experimental.shard_map`` (0.4.x: ``check_rep``/``auto``) →
-``jax.shard_map`` (``check_vma``/``axis_names``) API move the same way.
+What every kernel file shares: the interpret-mode rule and the VMEM budget
+its block sizes are planned against.
 """
 
 import jax as _jax
-from jax.experimental.pallas import tpu as _pltpu
 
-CompilerParams = getattr(_pltpu, "CompilerParams", None) or _pltpu.TPUCompilerParams
+# One v5e TensorCore has 128 MiB of VMEM; a kernel gets what its
+# ``vmem_limit_bytes`` asks for (the compiler's default is 16 MiB). Kernels
+# whose blocks grow with the model (decode attention, the fused decode
+# blocks, the int8 matmul) ask for VMEM_LIMIT_BYTES and size their blocks so
+# their own estimate stays under VMEM_BLOCK_BUDGET; the quarter between the
+# two absorbs what the estimate cannot see (the compiler's internal scratch,
+# relayout copies).
+VMEM_LIMIT_BYTES = 32 * 2**20
+VMEM_BLOCK_BUDGET = 24 * 2**20
 
 
-def shard_map_compat(fn, mesh, in_specs, out_specs, manual_axes=None):
-    """Version-tolerant shard_map: replication checking off (pallas_call
-    outputs carry no vma/rep annotations), manual only over
-    ``manual_axes`` (None = every mesh axis)."""
-    if hasattr(_jax, "shard_map"):
-        kw = {"check_vma": False}
-        if manual_axes is not None:
-            kw["axis_names"] = set(manual_axes)
-        return _jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as _sm
+def fits_vmem(nbytes):
+    """Whether a kernel's own estimate of one grid step stays in the budget."""
+    return nbytes <= VMEM_BLOCK_BUDGET
 
-    def call(*args):
-        kw = {"check_rep": False}
-        if manual_axes is not None:
-            auto = frozenset(mesh.axis_names) - frozenset(manual_axes)
-            if auto:
-                kw["auto"] = auto
-        try:
-            return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                       **kw)(*args)
-        except NotImplementedError:
-            # 0.4.x partial-auto shard_map is unimplemented for most mixes;
-            # full-manual is equivalent for these kernel bodies (no inner
-            # collectives over the would-be-auto axes — unmentioned spec
-            # axes just replicate)
-            return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                       check_rep=False)(*args)
-    return call
+
+def interpret():
+    """Pallas interpret mode: on for the CPU test mesh, off on any real
+    backend — a kernel never reaches a chip through the interpreter."""
+    return _jax.default_backend() == "cpu"

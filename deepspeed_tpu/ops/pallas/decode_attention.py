@@ -1,54 +1,53 @@
-"""Pallas single-token decode attention (TPU).
+"""Pallas decode attention over a preallocated KV cache (TPU).
 
 TPU-native equivalent of the reference's fused KV-cache decode attention
 (``softmax_context_*`` ops, csrc/transformer/inference/csrc/pt_binding.cpp:1745
--1805, and the softmax/attention kernels behind them): one query token per
-sequence attends over a preallocated contiguous KV cache.
+-1805, and the softmax/attention kernels behind them).
 
 GQA-native: the cache keeps ``kv_heads`` heads and each program computes
 whole groups of query heads sharing one KV head — no ``jnp.repeat``
 expansion of the cache.
 
-Kernel shape (v2): ALL (batch, kv_head) pairs fold into ONE batched dot per
-grid step, and the grid walks KV blocks. The v1 design ran a (B, kv_heads)
-program grid — 160 programs of (S, D)=32KB slabs at gpt2-large decode —
-whose per-program fixed costs dominated: measured 77us/call vs ~20us for
-this layout (the decode step is issued once per LAYER, so kernel fixed
-costs multiply by depth). Blocks past the write head are skipped: the
-index map clamps to the last live block (no re-DMA) and ``pl.when`` skips
-the compute, so work scales with the live context length.
+Kernel shape: one kernel, grid ``(rows, kv-head blocks, KV blocks)``. A
+program owns one batch row (cache slot) and a block of its kv heads and
+walks that row's KV blocks with an online softmax. Everything per-row is a
+scalar-prefetch operand, so the row's window ``[start_i, end_i)`` masks
+with scalars, blocks past the row's OWN write head are skipped (the index
+map clamps to the last live block — no re-DMA — and ``pl.when`` skips the
+compute, so a short row beside a long one pays only for its own context),
+and the physical pool row of each block comes from the index map. The
+kv-head block and the KV block are sized from the operand shapes and
+dtypes against the chip's VMEM budget (:func:`_pick_blocks`): the
+all-heads-in-one-step layout this replaces needed 40 MB of VMEM at
+gpt2-large widths and was refused by the v5e compiler.
 
-Per-row window [start_i, end_i): ``start`` masks left-padding slots of batched
-generation; ``end`` is the write head. Two entry points share one kernel:
+``start`` masks left-padding slots of batched generation; ``end`` is the
+write head. Entry points:
 
 - :func:`decode_attention` — shared scalar ``end`` (the static-batch engine
   path: prompts are left-aligned to a common write head).
 - :func:`paged_decode_attention` — per-row ``ends`` (the continuous-batching
-  slot pool: every slot sits at its own sequence position, so each row
-  attends its own live window). Blocks past the LONGEST live row are
-  skipped, so a mostly-short batch still pays only for its max context.
+  slot pool: every slot sits at its own sequence position).
 - :func:`paged_span_attention` — per-row QUERY SPANS of ``T`` columns
   (chunked prefill fused into the decode step: decode rows carry one live
   query, the in-flight prefill row carries up to a chunk of them). Query
   column ``j`` of row ``i`` sits at absolute position ``base_i + j`` and
-  attends ``[start_i, base_i + j]``; the span fold reuses the same kernel
-  with the query columns folded into the head-group axis and a per-column
-  offset added to the causal end.
+  attends ``[start_i, base_i + j]``; the query columns fold into the
+  head-group axis and the kernel adds a per-column offset to the causal end.
 
-Long-context extensions (multi-extent paged KV + seq-parallel prefill):
+Both paged entry points take the long-context and sharding operands:
 
-- :func:`extent_paged_decode_attention` / :func:`extent_paged_span_attention`
-  — one request's KV spans SEVERAL pool slots ("extents") through a per-row
-  extent table: logical position ``p`` of row ``i`` lives at physical pool
-  row ``ext[i, p // S]``, offset ``p % S``. The kernel walks LOGICAL blocks
-  (grid ``E * S/block_kv``) and gathers each row's physical slot for the
-  current extent in-register, so the extent count stays an OPERAND (table
-  values), never a shape — the O(1)-compiled-programs guard holds across
-  any extent mix. With an identity table (``ext[i, 0] == i``) the math is
-  bit-identical to the plain paged kernels row for row. Optional per-row
-  ``sink``/``window`` operands add attention-sink + sliding-window masking
-  (the LOSSY long-context mode — rows with ``window == 0`` keep the exact
-  mask, so lossy and exact rows co-reside in one dispatch).
+- ``ext`` — one request's KV spans SEVERAL pool slots ("extents") through
+  a per-row extent table: logical position ``p`` of row ``i`` lives at
+  physical pool row ``ext[i, p // S]``, offset ``p % S``. The grid walks
+  LOGICAL blocks (``E * S/block_kv``) and the KV index map reads the table,
+  so the extent count stays an OPERAND (table values), never a shape — the
+  O(1)-compiled-programs guard holds across any extent mix, and an identity
+  table (``ext[i, 0] == i``) is bit-identical to no table. ``sink``/
+  ``window`` add attention-sink + sliding-window masking (the LOSSY
+  long-context mode — rows with ``window == 0`` keep the exact mask, so
+  lossy and exact rows co-reside in one dispatch).
+- ``mesh``/``axis`` — shard_map over the tensor axis (head-sharded pool).
 - :func:`seq_sharded_span_attention` — the span kernel shard_mapped over
   the SEQUENCE mesh axis: a wide seq-parallel prefill chunk splits its
   query columns across seq shards (shard ``s`` computes columns
@@ -64,182 +63,40 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import CompilerParams as _CompilerParams
+from .. import pallas as _pallas
 
 DEFAULT_MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
+_NO_WINDOW = 1 << 30  # window == 0 means "exact": end - _NO_WINDOW masks nothing
 
 
-def _interpret():
-    return jax.default_backend() == "cpu"
+def _attn_kernel(ext_ref, start_ref, end_ref, sink_ref, win_ref, q_ref, k_ref,
+                 v_ref, *rest, scale, block_kv, span, quantized, lossy):
+    """One (row, kv-head block) program walking that row's KV blocks.
 
-
-def _decode_kernel(start_ref, end_ref, max_end_ref, q_ref, k_ref, v_ref, *rest,
-                   scale, block_kv, B, nkv, g, D, span=1, quantized=False):
-    """``g`` is the FOLDED query axis: head-groups x span columns. With
-    ``span > 1`` the per-row ``end`` is the causal end of column 0 and each
-    later column's window extends by its offset (column j of a row attends
-    one more key than column j-1 — per-row mixed decode/prefill query
-    spans share this one kernel).
+    ``q_ref``: (1, bh, g, D) where ``g`` is the FOLDED query axis:
+    head-groups x span columns, span fastest. With ``span > 1`` the row's
+    ``end`` is the causal end of column 0 and column j attends j more keys
+    (per-row mixed decode/prefill query spans share this one kernel).
 
     ``quantized``: the KV blocks are int8 with per-token-row scales (two
-    extra (B, block_kv) scale operands); dequantization is the in-register
-    multiply below — the bf16/f32 KV never exists in HBM, so the block
-    walk's DMA bytes stay int8-sized."""
+    extra (1, 1, block_kv) operands). The K scale multiplies the scores and
+    the V scale the probabilities — both have keys on the lane axis, as the
+    scale blocks do, so dequantization needs no relayout and the bf16/f32
+    KV never exists in HBM.
+
+    ``lossy``: ``sink_ref``/``win_ref`` carry per-row attention-sink +
+    sliding-window knobs — a row with ``win > 0`` additionally masks
+    logical positions in ``[sink, end - win)`` (StreamingLLM shape);
+    ``win == 0`` leaves the exact mask untouched, so lossy and exact rows
+    share one compiled program."""
     if quantized:
         ks_ref, vs_ref, o_ref, m_s, l_s, acc_s = rest
     else:
         o_ref, m_s, l_s, acc_s = rest
-    j = pl.program_id(0)
-    nj = pl.num_programs(0)
-    max_end = max_end_ref[0]
-    BH = B * nkv
-
-    @pl.when(j == 0)
-    def _init():
-        m_s[...] = jnp.full_like(m_s, -jnp.inf)
-        l_s[...] = jnp.zeros_like(l_s)
-        acc_s[...] = jnp.zeros_like(acc_s)
-
-    kv_start = j * block_kv
-
-    @pl.when(kv_start < max_end)
-    def _block():
-        q = q_ref[...].astype(jnp.float32).reshape(BH, g, D) * scale
-        k = k_ref[...].astype(jnp.float32)  # (B, nkv, bkv, D)
-        v = v_ref[...].astype(jnp.float32)
-        if quantized:
-            k = k * ks_ref[...].astype(jnp.float32)[:, None, :, None]
-            v = v * vs_ref[...].astype(jnp.float32)[:, None, :, None]
-        k = k.reshape(BH, block_kv, D)
-        v = v.reshape(BH, block_kv, D)
-        s = jax.lax.dot_general(q, k, (((2, ), (2, )), ((0, ), (0, ))),
-                                preferred_element_type=jnp.float32)  # (BH, g, bkv)
-        # masking in 2-D folded form: Mosaic rejects lane-dim-1 vector
-        # reshapes, so per-row starts/ends become full (rows, bkv) fills
-        s2 = s.reshape(BH * g, block_kv)
-        kv_pos = kv_start + jax.lax.broadcasted_iota(jnp.int32, (BH * g, block_kv), 1)
-        start2d = jnp.concatenate(
-            [jnp.full((nkv * g, block_kv), start_ref[i], jnp.int32) for i in range(B)])
-        end2d = jnp.concatenate(
-            [jnp.full((nkv * g, block_kv), end_ref[i], jnp.int32) for i in range(B)])
-        if span > 1:
-            # folded rows cycle through span columns fastest: column j of a
-            # row sits j positions later, so its causal end advances by j
-            col = jax.lax.broadcasted_iota(jnp.int32, (BH * g, block_kv), 0) % span
-            end2d = end2d + col
-        mask = (kv_pos >= start2d) & (kv_pos < end2d)
-        s2 = jnp.where(mask, s2, DEFAULT_MASK_VALUE)
-
-        m_prev = m_s[...].reshape(BH * g, 1)
-        m_new = jnp.maximum(m_prev, jnp.max(s2, axis=1, keepdims=True))
-        p = jnp.exp(s2 - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_s[...] = (l_s[...].reshape(BH * g, 1) * alpha
-                    + jnp.sum(p, axis=1, keepdims=True)).reshape(BH, g)
-        pv = jax.lax.dot_general(p.reshape(BH, g, block_kv), v,
-                                 (((2, ), (1, )), ((0, ), (0, ))),
-                                 preferred_element_type=jnp.float32)  # (BH, g, D)
-        acc3 = acc_s[...].reshape(BH, g, D)
-        acc_s[...] = (acc3 * alpha.reshape(BH, g)[:, :, None] + pv).reshape(BH, g * D)
-        m_s[...] = m_new.reshape(BH, g)
-
-    @pl.when(j == nj - 1)
-    def _flush():
-        l = l_s[...].reshape(BH, g)
-        l = jnp.where(l == 0, 1.0, l)
-        out = acc_s[...].reshape(BH, g, D) / l[:, :, None]
-        o_ref[...] = out.reshape(B, nkv, g, D).astype(o_ref.dtype)
-
-
-def _decode_call(qg, k_cache, v_cache, start, ends, max_end, *, block_kv, scale,
-                 span=1, k_scale=None, v_scale=None):
-    """Shared pallas_call builder: per-row windows [start_i, ends_i), with
-    ``max_end`` (scalar) bounding the walked KV blocks. ``qg``: queries
-    pre-folded to (B, nkv, g, D) where ``g`` = head-groups x ``span``
-    columns (span fastest). ``k_scale``/``v_scale``: optional (B, S)
-    per-token-row dequant scales for int8 caches (walked in lockstep with
-    the KV blocks; the lane axis is S, so scale blocks stay lane-aligned)."""
-    B, nkv, g, D = qg.shape
-    S = k_cache.shape[2]
-    scale = scale if scale is not None else 1.0 / (D**0.5)
-    block_kv = min(block_kv, S)
-    if S % block_kv:
-        raise ValueError(f"cache length {S} must be a multiple of block_kv={block_kv}")
-    quantized = k_scale is not None
-
-    start = start.astype(jnp.int32)
-    ends = ends.astype(jnp.int32)
-    max_end_arr = jnp.full((1, ), max_end, jnp.int32)
-    nj = S // block_kv
-
-    def kv_index(j, start_r, end_r, max_end_r):
-        # clamp to the last block holding live keys (of the LONGEST row):
-        # skipped steps keep the previous index so no extra DMA is issued
-        last = jnp.maximum(max_end_r[0] - 1, 0) // block_kv
-        return (0, 0, jnp.minimum(j, last), 0)
-
-    def sc_index(j, start_r, end_r, max_end_r):
-        last = jnp.maximum(max_end_r[0] - 1, 0) // block_kv
-        return (0, jnp.minimum(j, last))
-
-    in_specs = [
-        pl.BlockSpec((B, nkv, g, D), lambda j, *_: (0, 0, 0, 0)),
-        pl.BlockSpec((B, nkv, block_kv, D), kv_index),
-        pl.BlockSpec((B, nkv, block_kv, D), kv_index),
-    ]
-    operands = [qg, k_cache, v_cache]
-    if quantized:
-        in_specs += [pl.BlockSpec((B, block_kv), sc_index)] * 2
-        operands += [k_scale, v_scale]
-
-    kernel = functools.partial(_decode_kernel, scale=scale, block_kv=block_kv,
-                               B=B, nkv=nkv, g=g, D=D, span=span,
-                               quantized=quantized)
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=(nj, ),
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec((B, nkv, g, D), lambda j, *_: (0, 0, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((B * nkv, g), jnp.float32),      # running max
-                pltpu.VMEM((B * nkv, g), jnp.float32),      # running denom
-                pltpu.VMEM((B * nkv, g * D), jnp.float32),  # running numerator
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((B, nkv, g, D), qg.dtype),
-        compiler_params=_CompilerParams(dimension_semantics=("arbitrary", )),
-        interpret=_interpret(),
-    )(start, ends, max_end_arr, *operands)
-    return out
-
-
-def _extent_kernel(ext_ref, start_ref, end_ref, max_end_ref, sink_ref, win_ref,
-                   q_ref, k_ref, v_ref, *rest, scale, block_kv, B, E, nkv, g, D,
-                   bpe, span=1, quantized=False):
-    """Multi-extent variant of :func:`_decode_kernel`: the KV walk is over
-    LOGICAL blocks — grid step ``j`` covers logical positions
-    ``[j*block_kv, (j+1)*block_kv)``, which live in extent ``j // bpe`` at
-    within-slot offset ``j % bpe``. The KV block spec streams the FULL pool
-    column at that offset and each row gathers its own extent's slot
-    (``ext_ref[i*E + e]``) in-register; windows, masks, and the span offset
-    all stay in logical coordinates, so with an identity extent table every
-    arithmetic op matches :func:`_decode_kernel` value for value.
-
-    ``sink_ref``/``win_ref``: per-row lossy knobs — a row with ``win > 0``
-    additionally masks logical positions in ``[sink, end - win)`` (keeps
-    the attention-sink head and the sliding recent window; StreamingLLM
-    shape). ``win == 0`` leaves the exact mask bit-untouched, so lossy and
-    exact rows share one compiled program."""
-    if quantized:
-        ks_ref, vs_ref, o_ref, m_s, l_s, acc_s = rest
-    else:
-        o_ref, m_s, l_s, acc_s = rest
-    j = pl.program_id(0)
-    nj = pl.num_programs(0)
-    max_end = max_end_ref[0]
-    BH = B * nkv
+    i = pl.program_id(0)
+    j = pl.program_id(2)
+    start = start_ref[i]
+    end = end_ref[i]
 
     @pl.when(j == 0)
     def _init():
@@ -249,144 +106,250 @@ def _extent_kernel(ext_ref, start_ref, end_ref, max_end_ref, sink_ref, win_ref,
 
     kv_start = j * block_kv  # LOGICAL position of this block's first key
 
-    @pl.when(kv_start < max_end)
+    @pl.when(kv_start < end + (span - 1))
     def _block():
-        e = j // bpe  # extent index of this logical block
-        q = q_ref[...].astype(jnp.float32).reshape(BH, g, D) * scale
-        # per-row physical slot for extent e; demoted/unreserved extents
-        # carry -1 — clamp for a safe (masked-out) gather
-        slots = jnp.stack([jnp.maximum(ext_ref[i * E + e], 0) for i in range(B)])
-        k = jnp.take(k_ref[...], slots, axis=0).astype(jnp.float32)  # (B, nkv, bkv, D)
-        v = jnp.take(v_ref[...], slots, axis=0).astype(jnp.float32)
-        if quantized:
-            ks = jnp.take(ks_ref[...], slots, axis=0).astype(jnp.float32)
-            vs = jnp.take(vs_ref[...], slots, axis=0).astype(jnp.float32)
-            k = k * ks[:, None, :, None]
-            v = v * vs[:, None, :, None]
-        k = k.reshape(BH, block_kv, D)
-        v = v.reshape(BH, block_kv, D)
+        q = q_ref[0].astype(jnp.float32) * scale  # (bh, g, D)
+        k = k_ref[0].astype(jnp.float32)          # (bh, bkv, D)
+        v = v_ref[0].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((2, ), (2, )), ((0, ), (0, ))),
-                                preferred_element_type=jnp.float32)  # (BH, g, bkv)
-        s2 = s.reshape(BH * g, block_kv)
-        kv_pos = kv_start + jax.lax.broadcasted_iota(jnp.int32, (BH * g, block_kv), 1)
-        start2d = jnp.concatenate(
-            [jnp.full((nkv * g, block_kv), start_ref[i], jnp.int32) for i in range(B)])
-        end2d = jnp.concatenate(
-            [jnp.full((nkv * g, block_kv), end_ref[i], jnp.int32) for i in range(B)])
+                                preferred_element_type=jnp.float32)  # (bh, g, bkv)
+        if quantized:
+            s = s * ks_ref[...]
+        g = s.shape[1]
+        kv_pos = kv_start + jax.lax.broadcasted_iota(jnp.int32, (1, g, block_kv), 2)
+        end_col = end
         if span > 1:
-            col = jax.lax.broadcasted_iota(jnp.int32, (BH * g, block_kv), 0) % span
-            end2d = end2d + col
-        mask = (kv_pos >= start2d) & (kv_pos < end2d)
-        sink2d = jnp.concatenate(
-            [jnp.full((nkv * g, block_kv), sink_ref[i], jnp.int32) for i in range(B)])
-        win2d = jnp.concatenate(
-            [jnp.full((nkv * g, block_kv), win_ref[i], jnp.int32) for i in range(B)])
-        keep = (win2d == 0) | (kv_pos < sink2d) | (kv_pos >= end2d - win2d)
-        mask = mask & keep
-        s2 = jnp.where(mask, s2, DEFAULT_MASK_VALUE)
+            # folded columns cycle through the span fastest: column j of a
+            # row sits j positions later, so its causal end advances by j
+            end_col = end + jax.lax.broadcasted_iota(
+                jnp.int32, (1, g, block_kv), 1) % span
+        mask = (kv_pos >= start) & (kv_pos < end_col)
+        if lossy:
+            win = win_ref[i]
+            win = jnp.where(win == 0, _NO_WINDOW, win)
+            mask = mask & ((kv_pos < sink_ref[i]) | (kv_pos >= end_col - win))
+        s = jnp.where(mask, s, DEFAULT_MASK_VALUE)
 
-        m_prev = m_s[...].reshape(BH * g, 1)
-        m_new = jnp.maximum(m_prev, jnp.max(s2, axis=1, keepdims=True))
-        p = jnp.exp(s2 - m_new)
+        m_prev = m_s[...]  # (bh, g, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
+        p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
-        l_s[...] = (l_s[...].reshape(BH * g, 1) * alpha
-                    + jnp.sum(p, axis=1, keepdims=True)).reshape(BH, g)
-        pv = jax.lax.dot_general(p.reshape(BH, g, block_kv), v,
-                                 (((2, ), (1, )), ((0, ), (0, ))),
-                                 preferred_element_type=jnp.float32)  # (BH, g, D)
-        acc3 = acc_s[...].reshape(BH, g, D)
-        acc_s[...] = (acc3 * alpha.reshape(BH, g)[:, :, None] + pv).reshape(BH, g * D)
-        m_s[...] = m_new.reshape(BH, g)
+        l_s[...] = l_s[...] * alpha + jnp.sum(p, axis=2, keepdims=True)
+        if quantized:
+            p = p * vs_ref[...]
+        pv = jax.lax.dot_general(p, v, (((2, ), (1, )), ((0, ), (0, ))),
+                                 preferred_element_type=jnp.float32)  # (bh, g, D)
+        acc_s[...] = acc_s[...] * alpha + pv
+        m_s[...] = m_new
 
-    @pl.when(j == nj - 1)
+    @pl.when(j == pl.num_programs(2) - 1)
     def _flush():
-        l = l_s[...].reshape(BH, g)
+        l = l_s[...]
         l = jnp.where(l == 0, 1.0, l)
-        out = acc_s[...].reshape(BH, g, D) / l[:, :, None]
-        o_ref[...] = out.reshape(B, nkv, g, D).astype(o_ref.dtype)
+        o_ref[0] = (acc_s[...] / l).astype(o_ref.dtype)
 
 
-def _extent_call(qg, k_cache, v_cache, start, ends, max_end, ext, sink, win, *,
-                 block_kv, scale, span=1, k_scale=None, v_scale=None):
-    """pallas_call builder for the multi-extent kernel. ``ext``: (B, E)
-    int32 per-row extent chains — physical pool slot of each S-row extent,
-    -1 for unreserved/demoted entries. ``start``/``ends``/``max_end`` are
-    LOGICAL positions (max ``E * S``). ``sink``/``win``: optional (B,)
-    int32 lossy-mode knobs (None → zeros → exact masking). ``k_scale``/
-    ``v_scale``: optional (Npool, S) per-token-row dequant scales covering
-    the FULL pool (the kernel gathers scale rows with the KV rows).
+def _pad(n, m):
+    return -(-n // m) * m
 
-    The walked-bytes tradeoff vs :func:`_decode_call`: each logical block
-    streams the whole pool column (Npool rows) so rows can gather any slot
-    — in serving the dispatch batch IS the pool (B == Npool), so per-block
-    DMA matches the plain kernel and the extra cost is the E-fold longer
-    logical walk, priced by ``CapacityModel.dispatch_cost``."""
-    B, nkv, g, D = qg.shape
-    Np, nkv_c, S, Dc = k_cache.shape
-    E = ext.shape[1]
-    scale = scale if scale is not None else 1.0 / (D**0.5)
+
+def _vmem_estimate(bh, bkv, g, D, q_bytes, kv_bytes, quantized):
+    """VMEM bytes one grid step of :func:`_attn_kernel` needs, counted the
+    way Mosaic lays blocks out: the last dim pads to 128 lanes (so
+    ``D == 64`` costs as much as 128), the second-to-last to 8 sublanes x
+    the dtype's packing, and every pipelined operand is double-buffered.
+    Of the in-kernel values the compiler keeps about one f32 K/V copy and
+    the score and probability planes in VMEM. Checked against the least
+    ``vmem_limit_bytes`` the v5e compiler accepts at twelve (heads, g, D,
+    block) points: the estimate ran 1.4x-2.2x above it, never below."""
+    Dp = _pad(D, 128)
+    g8 = _pad(g, 8)
+    gq = _pad(g, 8 * (4 // q_bytes))
+    kv_rows = _pad(bkv, 8 * (4 // kv_bytes))
+    io = 2 * 2 * bh * gq * Dp * q_bytes            # q + out, double-buffered
+    io += 2 * 2 * bh * kv_rows * Dp * kv_bytes     # k + v, double-buffered
+    if quantized:
+        io += 2 * 2 * 8 * _pad(bkv, 128) * 4
+    scratch = bh * g8 * (2 * 128 + Dp) * 4         # m, l (lane-padded), acc
+    temps = bh * bkv * Dp * 4 + 2 * bh * g8 * _pad(bkv, 128) * 4
+    return io + scratch + temps
+
+
+def _pick_blocks(nkv, g, D, S, block_kv, q_dtype, kv_dtype, quantized):
+    """(kv-head block, KV block) for one grid step, from the operand shapes
+    and dtypes and the chip's VMEM budget (``ops.pallas.VMEM_BLOCK_BUDGET``).
+    ``block_kv`` is the caller's setting and the upper bound: it is kept
+    while any kv-head block fits beside it (heads are independent, so a
+    smaller head block only adds grid steps), and halves through the
+    lane-aligned divisors of ``S`` when not even one head does."""
     block_kv = min(block_kv, S)
     if S % block_kv:
         raise ValueError(f"cache length {S} must be a multiple of block_kv={block_kv}")
+    kv_cands = [block_kv] + [b for b in range(block_kv - block_kv % 128, 0, -128)
+                             if b < block_kv and S % b == 0]
+    q_bytes = jnp.dtype(q_dtype).itemsize
+    kv_bytes = jnp.dtype(kv_dtype).itemsize
+    for bkv in kv_cands:
+        for bh in range(nkv, 0, -1):
+            if nkv % bh == 0 and _pallas.fits_vmem(_vmem_estimate(
+                    bh, bkv, g, D, q_bytes, kv_bytes, quantized)):
+                return bh, bkv
+    raise ValueError(
+        f"decode attention: one kv head x {kv_cands[-1]} keys with {g} folded "
+        f"query columns of width {D} needs "
+        f"{_vmem_estimate(1, kv_cands[-1], g, D, q_bytes, kv_bytes, quantized)} "
+        f"bytes of VMEM, over the {_pallas.VMEM_BLOCK_BUDGET}-byte budget; "
+        f"narrow the query span (prefill_chunk)")
+
+
+def _decode_call(qg, k_cache, v_cache, start, ends, *, block_kv, scale, span=1,
+                 k_scale=None, v_scale=None, ext=None, sink=None, win=None):
+    """Shared pallas_call builder: row ``i`` attends its own window
+    ``[start_i, ends_i)`` and walks KV blocks only up to its own write
+    head. ``qg``: queries pre-folded to (B, nkv, g, D) where ``g`` =
+    head-groups x ``span`` columns (span fastest). ``k_scale``/``v_scale``:
+    optional (Npool, S) per-token-row dequant scales for int8 caches,
+    walked in lockstep with the KV blocks.
+
+    ``ext``: optional (B, E) int32 per-row extent chains over a pool of
+    ``Npool`` slots — logical position ``p`` of row ``i`` lives at pool row
+    ``ext[i, p // S]``, offset ``p % S``; ``start``/``ends`` are then
+    LOGICAL (up to ``E * S``). The chain is a scalar-prefetch operand read
+    by the KV index map, so each grid step DMAs exactly its row's physical
+    block and the extent count never becomes a shape. -1 marks
+    unreserved/demoted extents, which must lie outside every attended
+    window; they clamp to slot 0 and are masked. Without ``ext`` row ``i``
+    reads pool row ``i``. ``sink``/``win``: optional (B,) int32 lossy-mode
+    knobs (see :func:`_attn_kernel`)."""
+    B, nkv, g, D = qg.shape
+    S = k_cache.shape[2]
+    scale = scale if scale is not None else 1.0 / (D**0.5)
     quantized = k_scale is not None
-    bpe = S // block_kv
-    nj = E * bpe
+    lossy = sink is not None or win is not None
+    bh, block_kv = _pick_blocks(nkv, g, D, S, block_kv, qg.dtype, k_cache.dtype,
+                                quantized)
+    if ext is None:
+        ext = jnp.arange(B, dtype=jnp.int32)[:, None]
+    E = ext.shape[1]
+    bpe = S // block_kv  # blocks per extent
 
-    ext_flat = ext.reshape(B * E).astype(jnp.int32)
-    start = start.astype(jnp.int32)
-    ends = ends.astype(jnp.int32)
-    max_end_arr = jnp.full((1, ), max_end, jnp.int32)
-    sink = (jnp.zeros((B, ), jnp.int32) if sink is None
-            else sink.astype(jnp.int32))
-    win = (jnp.zeros((B, ), jnp.int32) if win is None
-           else win.astype(jnp.int32))
+    zeros = jnp.zeros((B, ), jnp.int32)
+    scalars = (ext.reshape(B * E).astype(jnp.int32), start.astype(jnp.int32),
+               ends.astype(jnp.int32),
+               zeros if sink is None else sink.astype(jnp.int32),
+               zeros if win is None else win.astype(jnp.int32))
 
-    def kv_index(j, ext_r, start_r, end_r, max_end_r, sink_r, win_r):
-        # clamp to the last LIVE logical block; skipped steps keep the
-        # previous index so no extra DMA is issued
-        last = jnp.maximum(max_end_r[0] - 1, 0) // block_kv
-        return (0, 0, jnp.minimum(j, last) % bpe, 0)
+    def walk(i, j, ext_r, end_r):
+        # clamp to the row's last LIVE logical block: skipped steps keep
+        # the previous index so no extra DMA is issued
+        last = jnp.maximum(end_r[i] + (span - 2), 0) // block_kv
+        jj = jnp.minimum(j, last)
+        return jnp.maximum(ext_r[i * E + jj // bpe], 0), jj % bpe
 
-    def sc_index(j, ext_r, start_r, end_r, max_end_r, sink_r, win_r):
-        last = jnp.maximum(max_end_r[0] - 1, 0) // block_kv
-        return (0, jnp.minimum(j, last) % bpe)
+    def kv_index(i, h, j, ext_r, start_r, end_r, sink_r, win_r):
+        slot, blk = walk(i, j, ext_r, end_r)
+        return (slot, h, blk, 0)
 
-    in_specs = [
-        pl.BlockSpec((B, nkv, g, D), lambda j, *_: (0, 0, 0, 0)),
-        pl.BlockSpec((Np, nkv, block_kv, D), kv_index),
-        pl.BlockSpec((Np, nkv, block_kv, D), kv_index),
-    ]
+    def sc_index(i, h, j, ext_r, start_r, end_r, sink_r, win_r):
+        slot, blk = walk(i, j, ext_r, end_r)
+        return (slot, 0, blk)
+
+    q_spec = pl.BlockSpec((1, bh, g, D), lambda i, h, j, *_: (i, h, 0, 0))
+    in_specs = [q_spec,
+                pl.BlockSpec((1, bh, block_kv, D), kv_index),
+                pl.BlockSpec((1, bh, block_kv, D), kv_index)]
     operands = [qg, k_cache, v_cache]
     if quantized:
-        in_specs += [pl.BlockSpec((Np, block_kv), sc_index)] * 2
-        operands += [k_scale, v_scale]
+        Np = k_cache.shape[0]
+        in_specs += [pl.BlockSpec((1, 1, block_kv), sc_index)] * 2
+        operands += [k_scale.reshape(Np, 1, S), v_scale.reshape(Np, 1, S)]
 
-    kernel = functools.partial(_extent_kernel, scale=scale, block_kv=block_kv,
-                               B=B, E=E, nkv=nkv, g=g, D=D, bpe=bpe, span=span,
-                               quantized=quantized)
-    out = pl.pallas_call(
+    kernel = functools.partial(_attn_kernel, scale=scale, block_kv=block_kv,
+                               span=span, quantized=quantized, lossy=lossy)
+    return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=6,
-            grid=(nj, ),
+            num_scalar_prefetch=len(scalars),
+            grid=(B, nkv // bh, E * bpe),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((B, nkv, g, D), lambda j, *_: (0, 0, 0, 0)),
+            out_specs=q_spec,
             scratch_shapes=[
-                pltpu.VMEM((B * nkv, g), jnp.float32),      # running max
-                pltpu.VMEM((B * nkv, g), jnp.float32),      # running denom
-                pltpu.VMEM((B * nkv, g * D), jnp.float32),  # running numerator
+                pltpu.VMEM((bh, g, 1), jnp.float32),  # running max
+                pltpu.VMEM((bh, g, 1), jnp.float32),  # running denom
+                pltpu.VMEM((bh, g, D), jnp.float32),  # running numerator
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, nkv, g, D), qg.dtype),
-        compiler_params=_CompilerParams(dimension_semantics=("arbitrary", )),
-        interpret=_interpret(),
-    )(ext_flat, start, ends, max_end_arr, sink, win, *operands)
-    return out
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_pallas.VMEM_LIMIT_BYTES),
+        interpret=_pallas.interpret(),
+    )(*scalars, *operands)
 
 
 def _group(q, nkv):
     B, H, D = q.shape
     return q.reshape(B, nkv, H // nkv, D)
+
+
+def _row_scales(k_scale, v_scale, B, S):
+    """(B, 1, S, 1) stored per-token-row scale leaves (fp16 in the pool) ->
+    the f32 (B, S) layout the kernel walks (lane axis = S, so scale blocks
+    stay lane-aligned; widened here because the pool is KB-sized and the
+    kernel then needs no fp16 support from the chip)."""
+    if k_scale is None:
+        return None, None
+    return (k_scale.reshape(B, S).astype(jnp.float32),
+            v_scale.reshape(B, S).astype(jnp.float32))
+
+
+def _optional_operands(k_cache, k_scale, v_scale, ext, sink, window):
+    """(keyword names, arrays) of the optional :func:`_decode_call` operands
+    that are present — they ride through shard_map positionally."""
+    Np, _, S, _ = k_cache.shape
+    ks, vs = _row_scales(k_scale, v_scale, Np, S)
+    opt = {"ext": ext, "sink": sink, "win": window, "k_scale": ks, "v_scale": vs}
+    names = [n for n, v in opt.items() if v is not None]
+    return names, [opt[n] for n in names]
+
+
+def _tp_shard_map(fn, mesh, axis, n_rep):
+    """shard_map wrapper for the paged kernels over the ``axis`` (tensor)
+    mesh dim: q and the KV cache split on their HEAD axes, the ``n_rep``
+    operands after (q, k, v) — window scalars, extent table, lossy knobs,
+    per-token-row scale leaves — stay replicated. Each shard's kernel then
+    walks ONLY its local KV-head blocks (shard-local block walk — DMA and
+    compute scale down tp-fold), and because every (batch, kv-head) pair is
+    computed independently by the same kernel, the gathered output is
+    BIT-identical to the unsharded call."""
+    from jax.sharding import PartitionSpec as SP
+    head = SP(None, axis, None, None)
+    return jax.shard_map(fn, mesh=mesh, in_specs=(head, ) * 3 + (SP(), ) * n_rep,
+                         out_specs=head, check_vma=False)
+
+
+def _paged(qg, k_cache, v_cache, start, ends, *, span, block_kv, scale,
+           k_scale, v_scale, ext=None, sink=None, window=None, mesh=None,
+           axis=None):
+    """Common tail of the public entry points: scale layout and the
+    optional tensor-axis shard_map around :func:`_decode_call`.
+    ``qg``: (B, nkv, g, D) folded queries, or (B, H, T, D) span queries
+    when sharded (the (head-group, column) fold then happens INSIDE each
+    shard, so per-column causal offsets see only local heads)."""
+    names, optional = _optional_operands(k_cache, k_scale, v_scale, ext, sink,
+                                         window)
+
+    def call(q, kc, vc, st, en, *rest):
+        nkv_l = kc.shape[1]
+        out = _decode_call(q.reshape(q.shape[0], nkv_l, -1, q.shape[-1]), kc, vc,
+                           st, en, block_kv=block_kv, scale=scale, span=span,
+                           **dict(zip(names, rest)))
+        return out.reshape(q.shape)
+
+    args = (qg, k_cache, v_cache, start, ends, *optional)
+    if mesh is None:
+        return call(*args)
+    return _tp_shard_map(call, mesh, axis, len(args) - 3)(*args)
 
 
 def decode_attention(q, k_cache, v_cache, start, end, *, block_kv=256, scale=None):
@@ -395,118 +358,46 @@ def decode_attention(q, k_cache, v_cache, start, end, *, block_kv=256, scale=Non
     row; end: scalar int32, one past the last written slot (shared).
     Returns (B, H, D)."""
     B, H, D = q.shape
-    ends = jnp.full((B, ), end, jnp.int32)
-    out = _decode_call(_group(q, k_cache.shape[1]), k_cache, v_cache, start, ends,
-                       end, block_kv=block_kv, scale=scale)
-    return out.reshape(B, H, D)
-
-
-def _row_scales(k_scale, v_scale, B, S):
-    """(B, 1, S, 1) stored per-token-row scale leaves -> the (B, S) layout
-    the kernel walks (lane axis = S, so scale blocks stay lane-aligned)."""
-    if k_scale is None:
-        return None, None
-    return k_scale.reshape(B, S), v_scale.reshape(B, S)
-
-
-def _tp_shard_map(fn, mesh, axis, q_ndim, quantized, n_rep=3):
-    """shard_map wrapper for the paged kernels over the ``axis`` (tensor)
-    mesh dim: q and the KV cache split on their HEAD axes, window scalars
-    and the per-token-row scale leaves stay replicated. Each shard's kernel
-    then walks ONLY its local KV-head blocks (shard-local block walk — DMA
-    and compute scale down tp-fold), and because every (batch, kv-head)
-    pair is computed independently by the same kernel, the gathered output
-    is BIT-identical to the unsharded call. ``n_rep``: replicated operands
-    following (q, k, v) — 3 for the plain window scalars, 6 for the extent
-    variants (ext table + sink/window knobs ride along replicated)."""
-    from jax.sharding import PartitionSpec as SP
-    from . import shard_map_compat
-    head_q = SP(*(None, axis) + (None, ) * (q_ndim - 2))
-    head_c = SP(None, axis, None, None)
-    rep = SP()
-    in_specs = [head_q, head_c, head_c] + [rep] * n_rep
-    if quantized:
-        in_specs += [rep, rep]
-    return shard_map_compat(fn, mesh, tuple(in_specs), head_q)
-
-
-def sharded_paged_decode_attention(q, k_cache, v_cache, start, ends, *, mesh,
-                                   axis, block_kv=256, scale=None,
-                                   k_scale=None, v_scale=None):
-    """:func:`paged_decode_attention` shard_mapped over the ``axis`` mesh
-    dim (tensor-parallel serving): the KV pool stays head-sharded in HBM
-    and each shard walks only its local heads' blocks. Bit-identical to the
-    unsharded call (per-head independence). ``k_cache.shape[1]`` (and the
-    query head count) must divide by the axis size."""
-    B, H, D = q.shape
-    ends = ends.astype(jnp.int32)
-    ks, vs = _row_scales(k_scale, v_scale, B, k_cache.shape[2])
-    max_end = jnp.max(ends)
-
-    def body(qg, kc, vc, st, en, me, *scales):
-        kss, vss = scales if scales else (None, None)
-        return _decode_call(qg, kc, vc, st, en, me[0], block_kv=block_kv,
-                            scale=scale, k_scale=kss, v_scale=vss)
-
-    out = _tp_shard_map(body, mesh, axis, 4, ks is not None)(
-        *((_group(q, k_cache.shape[1]), k_cache, v_cache,
-           start.astype(jnp.int32), ends, max_end[None])
-          + ((ks, vs) if ks is not None else ())))
-    return out.reshape(B, H, D)
-
-
-def sharded_paged_span_attention(q, k_cache, v_cache, start, base, *, mesh,
-                                 axis, block_kv=256, scale=None,
-                                 k_scale=None, v_scale=None):
-    """:func:`paged_span_attention` shard_mapped over the ``axis`` mesh dim
-    — the fused chunked-prefill/decode (and speculative verify) step's
-    kernel with a shard-local block walk. q: (B, H, T, D); the head axis
-    (and the cache's kv-head axis) must divide by the axis size. The
-    (head-group, column) fold happens INSIDE each shard, so per-column
-    causal offsets see only local heads and results stay bit-identical."""
-    B, H, T, D = q.shape
-    nkv = k_cache.shape[1]
-    base = base.astype(jnp.int32)
-    ks, vs = _row_scales(k_scale, v_scale, B, k_cache.shape[2])
-    max_end = jnp.max(base) + T
-    g = H // nkv
-
-    def body(qs, kc, vc, st, bs, me, *scales):
-        nkv_l = kc.shape[1]
-        qf = qs.reshape(B, nkv_l, g * T, D)
-        kss, vss = scales if scales else (None, None)
-        out = _decode_call(qf, kc, vc, st, bs + 1, me[0], block_kv=block_kv,
-                           scale=scale, span=T, k_scale=kss, v_scale=vss)
-        return out.reshape(B, nkv_l * g, T, D)
-
-    out = _tp_shard_map(body, mesh, axis, 4, ks is not None)(
-        *((q, k_cache, v_cache, start.astype(jnp.int32), base, max_end[None])
-          + ((ks, vs) if ks is not None else ())))
-    return out.reshape(B, H, T, D)
+    return paged_decode_attention(q, k_cache, v_cache, start,
+                                  jnp.full((B, ), end, jnp.int32),
+                                  block_kv=block_kv, scale=scale)
 
 
 def paged_decode_attention(q, k_cache, v_cache, start, ends, *, block_kv=256,
-                           scale=None, k_scale=None, v_scale=None):
+                           scale=None, k_scale=None, v_scale=None, ext=None,
+                           sink=None, window=None, mesh=None, axis=None):
     """Slot-pool variant: per-row ends. q: (B, H, D); k_cache/v_cache:
     (B, kv_heads, S, D) where B indexes cache SLOTS; ``ends``: (B,) int32 one
     past each slot's last written position (rows with ``ends == 0`` attend
-    nothing — their output is unspecified; callers mask dead slots).
-    The KV-block walk stops at ``max(ends)``, so compute and DMA
-    scale with the longest LIVE context, not the pool capacity S.
-    ``k_scale``/``v_scale``: optional (B, 1, S, 1) per-token-row dequant
-    scales for int8 caches — dequantization fuses into the kernel.
-    Returns (B, H, D)."""
+    nothing and return zeros). Each row's KV-block walk stops at its own
+    write head, so compute and DMA scale with the LIVE context, not the
+    pool capacity S. ``k_scale``/``v_scale``: optional (B, 1, S, 1)
+    per-token-row dequant scales for int8 caches — dequantization fuses
+    into the kernel. ``mesh``/``axis``: shard_map the call over that
+    (tensor) mesh axis — the KV pool stays head-sharded in HBM and each
+    shard walks only its local heads' blocks; both head counts must divide
+    the axis size.
+
+    ``ext``: optional (B, E) int32 extent table for multi-extent KV — row
+    ``i``'s logical position ``p`` lives at pool row ``ext[i, p // S]``
+    offset ``p % S``, ``start``/``ends`` are then LOGICAL (up to ``E * S``)
+    and -1 marks unreserved/demoted extents (which must lie entirely outside
+    every attended window — the scheduler's detect-miss-and-restore
+    guarantees it in exact mode, the sink/window mask in lossy mode). With
+    an identity table the result is bit-identical to the call without one.
+    ``sink``/``window``: optional (B,) int32 attention-sink +
+    sliding-window knobs (the LOSSY long-context mode). Returns (B, H, D)."""
     B, H, D = q.shape
-    ends = ends.astype(jnp.int32)
-    ks, vs = _row_scales(k_scale, v_scale, B, k_cache.shape[2])
-    out = _decode_call(_group(q, k_cache.shape[1]), k_cache, v_cache, start, ends,
-                       jnp.max(ends), block_kv=block_kv, scale=scale,
-                       k_scale=ks, v_scale=vs)
+    out = _paged(_group(q, k_cache.shape[1]), k_cache, v_cache, start, ends,
+                 span=1, block_kv=block_kv, scale=scale, k_scale=k_scale,
+                 v_scale=v_scale, ext=ext, sink=sink, window=window, mesh=mesh,
+                 axis=axis)
     return out.reshape(B, H, D)
 
 
 def paged_span_attention(q, k_cache, v_cache, start, base, *, block_kv=256,
-                         scale=None, k_scale=None, v_scale=None):
+                         scale=None, k_scale=None, v_scale=None, ext=None,
+                         sink=None, window=None, mesh=None, axis=None):
     """Fused chunked-prefill/decode variant: per-row query SPANS. q:
     (B, H, T, D) — row ``i``'s query column ``j`` sits at absolute cache
     position ``base_i + j`` and attends keys in ``[start_i, base_i + j]``
@@ -514,121 +405,15 @@ def paged_span_attention(q, k_cache, v_cache, start, base, *, block_kv=256,
     token in column 0; the in-flight prefill row fills up to a chunk; columns
     past a row's live span compute garbage that the caller never reads.
     ``base``: (B,) int32 per-row write heads (== column 0's position).
-    ``k_scale``/``v_scale``: optional (B, 1, S, 1) per-token-row dequant
-    scales for int8 caches — dequantization fuses into the kernel. The
-    KV-block walk stops at ``max(base) + T``. Returns (B, H, T, D)."""
+    The (head-group, column) pair folds into one query axis, column
+    fastest — the kernel recovers the per-column causal offset from
+    ``idx % span``. Other arguments as :func:`paged_decode_attention`.
+    Returns (B, H, T, D)."""
     B, H, T, D = q.shape
-    nkv = k_cache.shape[1]
-    # fold (head-group, column) into one query axis, column fastest — the
-    # kernel recovers the per-column causal offset from ``idx % span``
-    qf = q.reshape(B, nkv, (H // nkv) * T, D)
-    base = base.astype(jnp.int32)
-    ks, vs = _row_scales(k_scale, v_scale, B, k_cache.shape[2])
-    out = _decode_call(qf, k_cache, v_cache, start, base + 1, jnp.max(base) + T,
-                       block_kv=block_kv, scale=scale, span=T, k_scale=ks,
-                       v_scale=vs)
-    return out.reshape(B, H, T, D)
-
-
-# --------------------------------------------------------------------- extents
-def extent_paged_decode_attention(q, k_cache, v_cache, start, ends, ext, *,
-                                  block_kv=256, scale=None, k_scale=None,
-                                  v_scale=None, sink=None, window=None):
-    """:func:`paged_decode_attention` over multi-extent KV: row ``i``'s
-    logical position ``p`` lives at pool row ``ext[i, p // S]`` offset
-    ``p % S``. ``start``/``ends`` are LOGICAL (up to ``E * S``); ``ext`` is
-    (B, E) int32 with -1 marking unreserved/demoted extents (which must lie
-    entirely outside every attended window — the scheduler's detect-miss-
-    and-restore guarantees it in exact mode, the sink/window mask in lossy
-    mode). With an identity table this is bit-identical to the plain paged
-    kernel row for row. Returns (B, H, D)."""
-    B, H, D = q.shape
-    Np, nkv, S, _ = k_cache.shape
-    ends = ends.astype(jnp.int32)
-    ks, vs = _row_scales(k_scale, v_scale, Np, S)
-    out = _extent_call(_group(q, nkv), k_cache, v_cache, start.astype(jnp.int32),
-                       ends, jnp.max(ends), ext, sink, window,
-                       block_kv=block_kv, scale=scale, k_scale=ks, v_scale=vs)
-    return out.reshape(B, H, D)
-
-
-def extent_paged_span_attention(q, k_cache, v_cache, start, base, ext, *,
-                                block_kv=256, scale=None, k_scale=None,
-                                v_scale=None, sink=None, window=None):
-    """:func:`paged_span_attention` over multi-extent KV (the fused chunked-
-    prefill/decode step when any live row's context spans pool extents).
-    ``base``: (B,) int32 LOGICAL write heads. Returns (B, H, T, D)."""
-    B, H, T, D = q.shape
-    Np, nkv, S, _ = k_cache.shape
-    qf = q.reshape(B, nkv, (H // nkv) * T, D)
-    base = base.astype(jnp.int32)
-    ks, vs = _row_scales(k_scale, v_scale, Np, S)
-    out = _extent_call(qf, k_cache, v_cache, start.astype(jnp.int32), base + 1,
-                       jnp.max(base) + T, ext, sink, window, block_kv=block_kv,
-                       scale=scale, span=T, k_scale=ks, v_scale=vs)
-    return out.reshape(B, H, T, D)
-
-
-def _lossy_args(B, sink, window):
-    return (jnp.zeros((B, ), jnp.int32) if sink is None else sink.astype(jnp.int32),
-            jnp.zeros((B, ), jnp.int32) if window is None else window.astype(jnp.int32))
-
-
-def sharded_extent_paged_decode_attention(q, k_cache, v_cache, start, ends, ext,
-                                          *, mesh, axis, block_kv=256,
-                                          scale=None, k_scale=None,
-                                          v_scale=None, sink=None, window=None):
-    """:func:`extent_paged_decode_attention` shard_mapped over the tensor
-    mesh axis — head-sharded pool, shard-local LOGICAL block walk, extent
-    table replicated. Bit-identical to the unsharded extent call."""
-    B, H, D = q.shape
-    Np, nkv, S, _ = k_cache.shape
-    ends = ends.astype(jnp.int32)
-    ks, vs = _row_scales(k_scale, v_scale, Np, S)
-    max_end = jnp.max(ends)
-    sk, wn = _lossy_args(B, sink, window)
-
-    def body(qg, kc, vc, st, en, me, ex, skr, wnr, *scales):
-        kss, vss = scales if scales else (None, None)
-        return _extent_call(qg, kc, vc, st, en, me[0], ex, skr, wnr,
-                            block_kv=block_kv, scale=scale, k_scale=kss,
-                            v_scale=vss)
-
-    out = _tp_shard_map(body, mesh, axis, 4, ks is not None, n_rep=6)(
-        *((_group(q, nkv), k_cache, v_cache, start.astype(jnp.int32), ends,
-           max_end[None], ext.astype(jnp.int32), sk, wn)
-          + ((ks, vs) if ks is not None else ())))
-    return out.reshape(B, H, D)
-
-
-def sharded_extent_paged_span_attention(q, k_cache, v_cache, start, base, ext,
-                                        *, mesh, axis, block_kv=256, scale=None,
-                                        k_scale=None, v_scale=None, sink=None,
-                                        window=None):
-    """:func:`extent_paged_span_attention` shard_mapped over the tensor mesh
-    axis (fused chunk step with multi-extent rows under bitwise-tp)."""
-    B, H, T, D = q.shape
-    Np, nkv, S, _ = k_cache.shape
-    base = base.astype(jnp.int32)
-    ks, vs = _row_scales(k_scale, v_scale, Np, S)
-    max_end = jnp.max(base) + T
-    g = H // nkv
-    sk, wn = _lossy_args(B, sink, window)
-
-    def body(qs, kc, vc, st, bs, me, ex, skr, wnr, *scales):
-        nkv_l = kc.shape[1]
-        qf = qs.reshape(B, nkv_l, g * T, D)
-        kss, vss = scales if scales else (None, None)
-        out = _extent_call(qf, kc, vc, st, bs + 1, me[0], ex, skr, wnr,
-                           block_kv=block_kv, scale=scale, span=T,
-                           k_scale=kss, v_scale=vss)
-        return out.reshape(B, nkv_l * g, T, D)
-
-    out = _tp_shard_map(body, mesh, axis, 4, ks is not None, n_rep=6)(
-        *((q, k_cache, v_cache, start.astype(jnp.int32), base, max_end[None],
-           ext.astype(jnp.int32), sk, wn)
-          + ((ks, vs) if ks is not None else ())))
-    return out.reshape(B, H, T, D)
+    return _paged(q, k_cache, v_cache, start, base + 1,
+                  span=T, block_kv=block_kv, scale=scale, k_scale=k_scale,
+                  v_scale=v_scale, ext=ext, sink=sink, window=window, mesh=mesh,
+                  axis=axis)
 
 
 # ----------------------------------------------------------- seq-parallel span
@@ -647,43 +432,24 @@ def seq_sharded_span_attention(q, k_cache, v_cache, start, base, *, mesh, axis,
     chunks landed in other extents); tensor sharding does NOT compose here
     — the scheduler gates seq-parallel prefill to tp == 1."""
     from jax.sharding import PartitionSpec as SP
-    from . import shard_map_compat
     B, H, T, D = q.shape
     Np, nkv, S, _ = k_cache.shape
     n = mesh.shape[axis]
     if T % n:
         raise ValueError(f"span width {T} must divide by the seq axis size {n}")
     Tl = T // n
-    g = H // nkv
-    base = base.astype(jnp.int32)
-    ks, vs = _row_scales(k_scale, v_scale, Np, S)
-    max_end = jnp.max(base) + T
-    has_ext = ext is not None
-    ext_arr = (ext.astype(jnp.int32) if has_ext
-               else jnp.zeros((B, 1), jnp.int32))
-    sk, wn = _lossy_args(B, sink, window)
+    names, optional = _optional_operands(k_cache, k_scale, v_scale, ext, sink,
+                                         window)
 
-    def body(qs, kc, vc, st, bs, me, ex, skr, wnr, *scales):
-        sh = jax.lax.axis_index(axis)
-        bl = bs + sh * Tl  # this shard's columns start Tl*sh later
-        qf = qs.reshape(B, nkv, g * Tl, D)
-        kss, vss = scales if scales else (None, None)
-        if has_ext:
-            out = _extent_call(qf, kc, vc, st, bl + 1, me[0], ex, skr, wnr,
-                               block_kv=block_kv, scale=scale, span=Tl,
-                               k_scale=kss, v_scale=vss)
-        else:
-            out = _decode_call(qf, kc, vc, st, bl + 1, me[0],
-                               block_kv=block_kv, scale=scale, span=Tl,
-                               k_scale=kss, v_scale=vss)
+    def body(qs, kc, vc, st, bs, *rest):
+        bl = bs + jax.lax.axis_index(axis) * Tl  # this shard's columns start Tl*sh later
+        out = _decode_call(qs.reshape(B, nkv, (H // nkv) * Tl, D), kc, vc, st,
+                           bl + 1, block_kv=block_kv, scale=scale, span=Tl,
+                           **dict(zip(names, rest)))
         return out.reshape(B, H, Tl, D)
 
     seq_q = SP(None, None, axis, None)
-    rep = SP()
-    in_specs = [seq_q, rep, rep, rep, rep, rep, rep, rep, rep]
-    if ks is not None:
-        in_specs += [rep, rep]
-    out = shard_map_compat(body, mesh, tuple(in_specs), seq_q)(
-        *((q, k_cache, v_cache, start.astype(jnp.int32), base, max_end[None],
-           ext_arr, sk, wn) + ((ks, vs) if ks is not None else ())))
-    return out.reshape(B, H, T, D)
+    args = (q, k_cache, v_cache, start, base, *optional)
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(seq_q, ) + (SP(), ) * (len(args) - 1),
+                         out_specs=seq_q, check_vma=False)(*args)

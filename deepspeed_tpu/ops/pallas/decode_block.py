@@ -44,17 +44,14 @@ microbenchmarks.
 """
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import CompilerParams as _CompilerParams
-
-
-def _interpret():
-    return jax.default_backend() == "cpu"
+from .. import pallas as _pallas
 
 
 def _norm(x32, norms_ref, row, kind, eps):
@@ -82,21 +79,22 @@ def _act(h, kind):
     return jnp.maximum(h, 0.0)
 
 
-def _rope_rotate(y, sin, cos, rot_heads, hd):
-    """Rotate the first ``rot_heads`` head segments of the fused [q;k;v]
-    row ``y`` (f32 (B, Nqkv)); columns past ``rot_heads * hd`` (the v
-    segment) pass through. ``sin``/``cos``: (B, hd // 2) f32 gathered at
+def _rope_rotate(y, sin, cos, col0, rot_cols, hd):
+    """Rotate the head segments of ``y`` (f32 (B, bn), a column block of the
+    fused [q;k;v] row starting at global column ``col0``) that lie below
+    ``rot_cols`` — the q and k heads; the v tail passes through. ``bn`` is a
+    whole number of heads. ``sin``/``cos``: (B, hd // 2) f32 gathered at
     each row's position. Same half-split convention as ``apply_rope``."""
     half = hd // 2
     parts = []
-    for i in range(rot_heads):
+    for i in range(y.shape[1] // hd):
         off = i * hd
         a = y[:, off:off + half]
         b = y[:, off + half:off + hd]
         parts.append(a * cos - b * sin)
         parts.append(b * cos + a * sin)
-    parts.append(y[:, rot_heads * hd:])
-    return jnp.concatenate(parts, axis=-1)
+    col = col0 + jax.lax.broadcasted_iota(jnp.int32, y.shape, 1)
+    return jnp.where(col < rot_cols, jnp.concatenate(parts, axis=-1), y)
 
 
 def _qdot(x_bf16, w_ref, s_ref, k_idx, bk, gsize, col_off=None):
@@ -124,7 +122,7 @@ def _qdot(x_bf16, w_ref, s_ref, k_idx, bk, gsize, col_off=None):
     return acc
 
 
-from .quant_matmul import pick_block_k as _pick_bk
+from .quant_matmul import pick_block, pick_block_k as _pick_bk
 
 
 def _prep_scales(sc):
@@ -134,14 +132,24 @@ def _prep_scales(sc):
     return (jnp.pad(sc, ((0, Gp - G), (0, 0))) if Gp != G else sc), G
 
 
+def _row_blocks(B):
+    """Row-block candidates, largest first: all rows when there are few (the
+    decode step), else the multiples of 8 dividing ``B`` up to 256 (the
+    chunked-prefill step runs slots x chunk rows through these kernels)."""
+    if B <= 256:
+        return [B]
+    return [d for d in range(256, 7, -8) if B % d == 0] or [B]
+
+
 # --------------------------------------------------------------- kernel A
 def _qkv_ln_kernel(x_ref, norms_ref, w_ref, s_ref, b_ref, *rest,
-                   nk1, bk1, g1, eps, norm_kind, rot_heads, hd):
-    if rot_heads:
+                   nk1, bk1, g1, eps, norm_kind, rot_cols, hd):
+    if rot_cols:
         sin_ref, cos_ref, o_ref, xln_s, acc_s = rest
     else:
         o_ref, xln_s, acc_s = rest
-    s = pl.program_id(0)
+    col0 = pl.program_id(1) * o_ref.shape[1]
+    s = pl.program_id(2)
 
     @pl.when(s == 0)
     def _ln1():
@@ -161,9 +169,47 @@ def _qkv_ln_kernel(x_ref, norms_ref, w_ref, s_ref, b_ref, *rest,
     @pl.when(s == nk1 - 1)
     def _done():
         y = acc_s[...] + b_ref[0, :][None, :]
-        if rot_heads:
-            y = _rope_rotate(y, sin_ref[...], cos_ref[...], rot_heads, hd)
+        if rot_cols:
+            y = _rope_rotate(y, sin_ref[...], cos_ref[...], col0, rot_cols, hd)
         o_ref[...] = y.astype(o_ref.dtype)
+
+
+def _qkv_vmem(bm, bn, bk, H, Gp, g1, xb, rope):
+    """VMEM bytes of one :func:`_qkv_ln_kernel` step: double-buffered
+    operand blocks, the scratch, one group's widened weight slice and the
+    f32 values of the norm and flush steps. Ran 1.3x-1.6x above the least
+    ``vmem_limit_bytes`` the v5e compiler accepts at six shapes (gpt2-large,
+    llama2-7b, llama3-8b x 8 and 512 rows), never below."""
+    io = 2 * (bm * H * xb + 8 * H * 4 + bk * bn + Gp * bn * 4 + 8 * bn * 4
+              + bm * bn * xb)
+    if rope:
+        io += 2 * 2 * bm * 128 * 4
+    scratch = bm * H * xb + bm * bn * 4
+    temps = min(g1, bk) * bn * xb + 2 * bm * H * 4 + 3 * bm * bn * 4
+    return io + scratch + temps
+
+
+def _qkv_blocks(B, H, Nq, Gp, g1, xb, hd):
+    """(row, column, contraction) blocks of kernel A under the VMEM budget.
+    The decode step keeps every row and every column resident and only
+    walks k; wider models halve the k block, and the chunked-prefill step's
+    hundreds of rows tile rows and columns (a column block is a whole
+    number of heads so the rotary rotation stays block-local)."""
+    col_unit = math.lcm(128, hd) if hd else 128
+    cols = [Nq] + [d for d in range(Nq - Nq % col_unit, 0, -col_unit)
+                   if d < Nq and Nq % d == 0]
+    for bm in _row_blocks(B):
+        for bn in cols:
+            cap = 1024
+            while cap >= min(g1, 128):
+                bk = _pick_bk(H, g1, cap)
+                if _pallas.fits_vmem(_qkv_vmem(bm, bn, bk, H, Gp, g1, xb, bool(hd))):
+                    return bm, bn, bk
+                cap //= 2
+    raise ValueError(
+        f"fused_qkv_ln: no (rows, columns, k) blocking of ({B}, {H}) x "
+        f"({H}, {Nq}) with int8 group {g1} fits the "
+        f"{_pallas.VMEM_BLOCK_BUDGET}-byte VMEM budget")
 
 
 def fused_qkv_ln(x, norms, qkv, *, eps=1e-5, norm="layernorm", rope=None):
@@ -179,38 +225,39 @@ def fused_qkv_ln(x, norms, qkv, *, eps=1e-5, norm="layernorm", rope=None):
     Nq = w.shape[1]
     sc, G = _prep_scales(sc)
     g1 = H // G
-    bk1 = _pick_bk(H, g1)
-    nk1 = H // bk1
     if rope is not None:
         sin2d, cos2d, rot_heads, hd = rope
     else:
         sin2d = cos2d = None
         rot_heads, hd = 0, 0
+    bm, bn, bk1 = _qkv_blocks(B, H, Nq, sc.shape[0], g1, x.dtype.itemsize, hd)
+    nk1 = H // bk1
     kernel = functools.partial(_qkv_ln_kernel, nk1=nk1, bk1=bk1, g1=g1, eps=eps,
-                               norm_kind=norm, rot_heads=rot_heads, hd=hd)
+                               norm_kind=norm, rot_cols=rot_heads * hd, hd=hd)
     in_specs = [
-        pl.BlockSpec((B, H), lambda s: (0, 0)),
-        pl.BlockSpec(norms.shape, lambda s: (0, 0)),
-        pl.BlockSpec((bk1, Nq), lambda s: (s, 0)),
-        pl.BlockSpec(sc.shape, lambda s: (0, 0)),
-        pl.BlockSpec((1, Nq), lambda s: (0, 0)),
+        pl.BlockSpec((bm, H), lambda i, j, s: (i, 0)),
+        pl.BlockSpec(norms.shape, lambda i, j, s: (0, 0)),
+        pl.BlockSpec((bk1, bn), lambda i, j, s: (s, j)),
+        pl.BlockSpec((sc.shape[0], bn), lambda i, j, s: (0, j)),
+        pl.BlockSpec((1, bn), lambda i, j, s: (0, j)),
     ]
     operands = [x, norms, w, sc, b.reshape(1, -1)]
     if rot_heads:
         half = hd // 2
-        in_specs += [pl.BlockSpec((B, half), lambda s: (0, 0)),
-                     pl.BlockSpec((B, half), lambda s: (0, 0))]
+        in_specs += [pl.BlockSpec((bm, half), lambda i, j, s: (i, 0))] * 2
         operands += [jnp.asarray(sin2d, jnp.float32),
                      jnp.asarray(cos2d, jnp.float32)]
     return pl.pallas_call(
         kernel,
-        grid=(nk1, ),
+        grid=(B // bm, Nq // bn, nk1),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((B, Nq), lambda s: (0, 0)),
+        out_specs=pl.BlockSpec((bm, bn), lambda i, j, s: (i, j)),
         out_shape=jax.ShapeDtypeStruct((B, Nq), x.dtype),
-        scratch_shapes=[pltpu.VMEM((B, H), x.dtype), pltpu.VMEM((B, Nq), jnp.float32)],
-        compiler_params=_CompilerParams(dimension_semantics=("arbitrary", )),
-        interpret=_interpret(),
+        scratch_shapes=[pltpu.VMEM((bm, H), x.dtype), pltpu.VMEM((bm, bn), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_pallas.VMEM_LIMIT_BYTES),
+        interpret=_pallas.interpret(),
     )(*operands)
 
 
@@ -225,7 +272,7 @@ def _out_mlp_kernel(attn_ref, x_ref, norms_ref,
     else:
         dn_w, dn_s, dn_b, xo_ref, res2, ln2_s, up_h, acc_s = rest
         gt_w = gt_s = gt_b = g_h = None
-    s = pl.program_id(0)
+    s = pl.program_id(1)
     A1 = nko
     A2 = A1 + nju * nku
 
@@ -299,9 +346,29 @@ def _out_mlp_kernel(attn_ref, x_ref, norms_ref,
         def _():
             acc_s[...] += part
 
-    @pl.when(s == pl.num_programs(0) - 1)
+    @pl.when(s == pl.num_programs(1) - 1)
     def _finish():
         xo_ref[...] = (res2[...] + acc_s[...] + dn_b[0, :][None, :]).astype(xo_ref.dtype)
+
+
+def _out_mlp_vmem(bm, Ko, H, F, bko, bk1, bnu, bkd, groups, gsizes, xb, gated):
+    """VMEM bytes of one :func:`_out_mlp_kernel` step. Every operand block
+    is resident for the whole o -> up -> down walk, so the weight blocks of
+    all three phases add up; the walked ones are double-buffered, the
+    whole-array scale and bias blocks (constant block index) are counted
+    once, which is how the v5e compiler's own accounting came out at the
+    six shapes of :func:`_qkv_vmem` (this estimate 1.1x-1.5x above it)."""
+    Gop, Gup, Gdp = groups
+    ng = 2 if gated else 1
+    rows = 2 * bm * (Ko + 2 * H) * xb                       # attn, x, out
+    weights = 2 * (bko * H + ng * bk1 * bnu + bkd * H)
+    scales = 4 * (Gop * H + ng * Gup * F + Gdp * H)
+    small = 8 * 4 * (H + 2 * H + ng * F + H)                # norms + biases
+    scratch = bm * (4 * H + xb * H + ng * xb * F + 4 * H)
+    wide = max(min(gsizes[0], bko) * H, min(gsizes[1], bk1) * bnu,
+               min(gsizes[2], bkd) * H)
+    temps = wide * xb + 4 * bm * max(H, bnu) * 4
+    return rows + weights + scales + small + scratch + temps
 
 
 def fused_out_mlp(attn2d, x, norms, o, up, down, *, activation="gelu",
@@ -313,7 +380,9 @@ def fused_out_mlp(attn2d, x, norms, o, up, down, *, activation="gelu",
     ``activation`` in ("swiglu", "geglu") pass ``gate``; the gate
     contraction shares norm2(x)'s k-tiles with up and the activation
     applies to the gate (silu for swiglu, tanh-gelu for geglu), matching
-    ``MLP``. Returns x_out (B, H) bf16."""
+    ``MLP``. Rows are independent: when they do not all fit the VMEM
+    budget (the chunked-prefill step) an outer grid axis walks row blocks
+    and each re-streams the weights. Returns x_out (B, H) bf16."""
     B, H = x.shape
     o_w, o_s, o_b = o
     up_w, up_s, up_b = up
@@ -324,17 +393,28 @@ def fused_out_mlp(attn2d, x, norms, o, up, down, *, activation="gelu",
     up_s, Gu = _prep_scales(up_s)
     dn_s, Gd = _prep_scales(dn_s)
     go, gu, gd = Ko // Go, H // Gu, F // Gd
-    bko = _pick_bk(Ko, go)
-    bk1 = _pick_bk(H, gu)
-    bkd = _pick_bk(F, gd)
-    from .quant_matmul import pick_block
-    bnu = pick_block(F, 2560, 128)
+    gated = gate is not None
+    xb = x.dtype.itemsize
+    groups = (o_s.shape[0], up_s.shape[0], dn_s.shape[0])
+    for cap in (1024, 512, 256, 128):
+        bko = _pick_bk(Ko, go, cap)
+        bk1 = _pick_bk(H, gu, cap)
+        bkd = _pick_bk(F, gd, cap)
+        bnu = pick_block(F, 2560 * cap // 1024, 128)
+        bm = next((m for m in _row_blocks(B) if _pallas.fits_vmem(_out_mlp_vmem(
+            m, Ko, H, F, bko, bk1, bnu, bkd, groups, (go, gu, gd), xb, gated))),
+            None)
+        if bm is not None:
+            break
+    else:
+        raise ValueError(
+            f"fused_out_mlp: no blocking of {B} rows with hidden {H}, ffn {F} "
+            f"fits the {_pallas.VMEM_BLOCK_BUDGET}-byte VMEM budget")
     nko, nkd = Ko // bko, F // bkd
     nju, nku = F // bnu, H // bk1
     nsteps = nko + nju * nku + nkd
     A1 = nko
 
-    gated = gate is not None
     act = activation
     if gated:
         act = "silu" if activation == "swiglu" else "gelu"
@@ -348,50 +428,48 @@ def fused_out_mlp(attn2d, x, norms, o, up, down, *, activation="gelu",
         bko=bko, bk1=bk1, bnu=bnu, bkd=bkd, go=go, gu=gu, gd=gd,
         eps=eps, act=act, norm_kind=norm, gated=gated)
     f32 = jnp.float32
-    up_spec = pl.BlockSpec((bk1, bnu), lambda s: (
+    rows = lambda n: pl.BlockSpec((bm, n), lambda i, s: (i, 0))
+    whole = lambda a: pl.BlockSpec(a.shape, lambda i, s: (0, 0))
+    bias = lambda n: pl.BlockSpec((1, n), lambda i, s: (0, 0))
+    up_spec = pl.BlockSpec((bk1, bnu), lambda i, s: (
         jnp.clip(s - A1, 0, nju * nku - 1) % nku,
         jnp.clip(s - A1, 0, nju * nku - 1) // nku))
     in_specs = [
-        pl.BlockSpec((B, Ko), lambda s: (0, 0)),
-        pl.BlockSpec((B, H), lambda s: (0, 0)),
-        pl.BlockSpec(norms.shape, lambda s: (0, 0)),
-        pl.BlockSpec((bko, H), lambda s: (jnp.clip(s, 0, nko - 1), 0)),
-        pl.BlockSpec(o_s.shape, lambda s: (0, 0)),
-        pl.BlockSpec((1, H), lambda s: (0, 0)),
-        up_spec,
-        pl.BlockSpec(up_s.shape, lambda s: (0, 0)),
-        pl.BlockSpec((1, F), lambda s: (0, 0)),
+        rows(Ko), rows(H), whole(norms),
+        pl.BlockSpec((bko, H), lambda i, s: (jnp.clip(s, 0, nko - 1), 0)),
+        whole(o_s), bias(H),
+        up_spec, whole(up_s), bias(F),
     ]
     operands = [attn2d, x, norms, o_w, o_s, o_b.reshape(1, -1),
                 up_w, up_s, up_b.reshape(1, -1)]
     if gated:
-        in_specs += [up_spec,  # gate walks the same tiles as up
-                     pl.BlockSpec(gt_s.shape, lambda s: (0, 0)),
-                     pl.BlockSpec((1, F), lambda s: (0, 0))]
+        in_specs += [up_spec, whole(gt_s), bias(F)]  # gate walks up's tiles
         operands += [gt_w, gt_s, gt_b.reshape(1, -1)]
     in_specs += [
-        pl.BlockSpec((bkd, H), lambda s: (jnp.clip(s - A1 - nju * nku, 0, nkd - 1), 0)),
-        pl.BlockSpec(dn_s.shape, lambda s: (0, 0)),
-        pl.BlockSpec((1, H), lambda s: (0, 0)),
+        pl.BlockSpec((bkd, H),
+                     lambda i, s: (jnp.clip(s - A1 - nju * nku, 0, nkd - 1), 0)),
+        whole(dn_s), bias(H),
     ]
     operands += [dn_w, dn_s, dn_b.reshape(1, -1)]
     scratch = [
-        pltpu.VMEM((B, H), f32),       # res2
-        pltpu.VMEM((B, H), x.dtype),   # ln2 out
-        pltpu.VMEM((B, F), x.dtype),   # up_h
+        pltpu.VMEM((bm, H), f32),       # res2
+        pltpu.VMEM((bm, H), x.dtype),   # ln2 out
+        pltpu.VMEM((bm, F), x.dtype),   # up_h
     ]
     if gated:
-        scratch.append(pltpu.VMEM((B, F), x.dtype))  # gate partials
-    scratch.append(pltpu.VMEM((B, H), f32))          # shared o/down accumulator
+        scratch.append(pltpu.VMEM((bm, F), x.dtype))  # gate partials
+    scratch.append(pltpu.VMEM((bm, H), f32))          # shared o/down accumulator
     return pl.pallas_call(
         kernel,
-        grid=(nsteps, ),
+        grid=(B // bm, nsteps),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((B, H), lambda s: (0, 0)),
+        out_specs=rows(H),
         out_shape=jax.ShapeDtypeStruct((B, H), x.dtype),
         scratch_shapes=scratch,
-        compiler_params=_CompilerParams(dimension_semantics=("arbitrary", )),
-        interpret=_interpret(),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_pallas.VMEM_LIMIT_BYTES),
+        interpret=_pallas.interpret(),
     )(*operands)
 
 

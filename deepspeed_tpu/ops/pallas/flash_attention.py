@@ -39,11 +39,9 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.sharding import PartitionSpec as P
 
+from .. import pallas as _pallas
+
 DEFAULT_MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
-
-
-def _interpret():
-    return jax.default_backend() == "cpu"
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, block_kv, causal, q_len,
@@ -249,7 +247,7 @@ def _flash_call(q, k, v, causal, block_q, block_kv, scale):
             jax.ShapeDtypeStruct((B, H, Tq, D), q.dtype),
             jax.ShapeDtypeStruct((B, H, Tq, 1), jnp.float32),
         ],
-        interpret=_interpret(),
+        interpret=_pallas.interpret(),
     )(qp, kp, vp)
     return out, lse, (qp, kp, vp, Tq, Tkv)
 
@@ -309,7 +307,7 @@ def _flash_bwd_impl(causal, block_q, block_kv, scale, res, g_out, delta_shift=No
         ],
         out_specs=pl.BlockSpec((1, 1, bq, D), lambda b, h, i: (b, h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, Tq, D), qp.dtype),
-        interpret=_interpret(),
+        interpret=_pallas.interpret(),
     )(qp, kp, vp, dop, lse, delta)
 
     dk, dv = pl.pallas_call(
@@ -332,7 +330,7 @@ def _flash_bwd_impl(causal, block_q, block_kv, scale, res, g_out, delta_shift=No
             jax.ShapeDtypeStruct((B, H, Tkv, D), kp.dtype),
             jax.ShapeDtypeStruct((B, H, Tkv, D), vp.dtype),
         ],
-        interpret=_interpret(),
+        interpret=_pallas.interpret(),
     )(qp, kp, vp, dop, lse, delta)
 
     if grp > 1:  # group-sum per-query-head dk/dv back onto the shared KV head
@@ -414,9 +412,16 @@ def sharded_flash_attention(q, k, v, causal=True, block_q=512, block_kv=512, sca
     def fn(q, k, v):  # positional: custom_vjp rejects kwargs
         return flash_attention(q, k, v, causal, block_q, block_kv, scale)
 
-    with dist.manual_axes(set(dp_axes) | set(head_axes)):
-        # replication checking off: pallas_call out_shapes carry no
-        # vma/rep annotations (shard_map_compat spans the jax API move)
-        from . import shard_map_compat
-        return shard_map_compat(fn, mesh, (qspec, kvspec, kvspec), qspec,
-                                manual_axes=set(dp_axes) | set(head_axes))(q, k, v)
+    # Mosaic refuses a kernel under any automatic axis, so the size-1 axes go
+    # manual too (trivially: nothing is split over them). An unused axis of
+    # size > 1 stays automatic — a manual axis the specs don't mention would
+    # have its cotangents psum'd (check_vma=False) — and the chip's compiler
+    # then refuses the layout by name.
+    manual = (set(dp_axes) | set(head_axes)
+              | {a for a in mesh.axis_names if mesh.shape[a] == 1})
+    with dist.manual_axes(manual):
+        # replication checking off: pallas_call out_shapes carry no vma
+        # annotations
+        return jax.shard_map(fn, mesh=mesh, in_specs=(qspec, kvspec, kvspec),
+                             out_specs=qspec, check_vma=False,
+                             axis_names=manual)(q, k, v)

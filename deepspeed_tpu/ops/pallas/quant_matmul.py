@@ -31,9 +31,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-
-def _interpret():
-    return jax.default_backend() == "cpu"
+from .. import pallas as _pallas
 
 
 def _qmm_kernel(x_ref, w_ref, s_ref, o_ref, acc_ref, *, nk, bk, gsize, ng):
@@ -87,8 +85,28 @@ def pick_block_k(K, gsize, cap=1024):
     return gsize
 
 
-def _pick_bn(n, cap=4096):
-    return pick_block(n, cap, 128)
+def _qmm_vmem(bm, bn, bk, Gp, gsize, xb, ob):
+    """VMEM bytes of one :func:`_qmm_kernel` step: double-buffered blocks,
+    the f32 accumulator scratch, the step's local f32 partials and one
+    group's widened weight slice."""
+    io = 2 * (bm * bk * xb + bk * bn + Gp * bn * 4 + bm * bn * ob)
+    return io + 3 * bm * bn * 4 + 2 * min(gsize, bk) * bn * xb
+
+
+def _pick_bn(N, bm, bk, Gp, gsize, xb, ob):
+    """Widest n-block (fewer grid steps keep the DMA pipeline fed) whose
+    step fits the VMEM budget: 4096 columns at decode row counts, narrower
+    as the row block grows."""
+    cap = 4096
+    while cap >= 128:
+        bn = pick_block(N, cap, 128)
+        if _pallas.fits_vmem(_qmm_vmem(bm, bn, bk, Gp, gsize, xb, ob)):
+            return bn
+        cap //= 2
+    raise ValueError(
+        f"quant_matmul: a ({bm}, {bk}) x ({bk}, {pick_block(N, 128, 128)}) step "
+        f"does not fit the {_pallas.VMEM_BLOCK_BUDGET}-byte VMEM budget; "
+        f"pass a smaller block_m")
 
 
 def quant_matmul(x, qw, scales, block_m=256, block_n=None, block_k=None, out_dtype=None):
@@ -131,12 +149,13 @@ def quant_matmul(x, qw, scales, block_m=256, block_n=None, block_k=None, out_dty
     if K % bk:
         raise ValueError(f"block_k {bk} must divide K={K}")
     ng = max(1, bk // gsize)
-    bn = block_n or _pick_bn(N)
+    out_dtype = out_dtype or x.dtype
+    Gpad = -(-G // 8) * 8
+    bn = block_n or _pick_bn(N, bm, bk, Gpad, gsize, x.dtype.itemsize,
+                             jnp.dtype(out_dtype).itemsize)
     if M % bm or N % bn:
         raise ValueError(f"shape ({M},{K})x({K},{N}) not divisible by blocks ({bm},{bk},{bn})")
-    out_dtype = out_dtype or x.dtype
     nk = K // bk
-    Gpad = -(-G // 8) * 8
     if Gpad != G:  # Mosaic block sublanes must be a multiple of 8
         scales = jnp.pad(scales, ((0, Gpad - G), (0, 0)))
 
@@ -151,5 +170,8 @@ def quant_matmul(x, qw, scales, block_m=256, block_n=None, block_k=None, out_dty
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=_interpret(),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_pallas.VMEM_LIMIT_BYTES),
+        interpret=_pallas.interpret(),
     )(x, qw, scales)

@@ -257,8 +257,8 @@ def ring_attention(q, k, v, causal=True, block_q=512, block_kv=512, scale=None,
     n_ring = mesh.shape[dist.SEQ_AXIS]
     with dist.manual_axes(axes):
         fn = local_fn(n_ring, T // n_ring)
-        from . import shard_map_compat
-        return shard_map_compat(fn, mesh, (spec, spec, spec), spec)(q, k, v)
+        return jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                             out_specs=spec, check_vma=False)(q, k, v)
 
 
 def _dense_fallback(q, k, v, causal, block_q, block_kv, scale):

@@ -27,11 +27,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import pallas as _pallas
+
 DEFAULT_MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
-
-
-def _interpret():
-    return jax.default_backend() == "cpu"
 
 
 def _index_tables(layout):
@@ -231,7 +229,7 @@ def make_block_sparse_attention(layout, block, causal=True, scale=None):
                 jax.ShapeDtypeStruct((B, H, nq * block, D), q.dtype),
                 jax.ShapeDtypeStruct((B, H, nq * block, 1), jnp.float32),
             ],
-            interpret=_interpret(),
+            interpret=_pallas.interpret(),
         )(q_idx, q_cnt, qp, kp, vp)
         return out, lse, (qp, kp, vp)
 
@@ -264,7 +262,7 @@ def make_block_sparse_attention(layout, block, causal=True, scale=None):
             ],
             out_specs=pl.BlockSpec((1, 1, block, D), lambda b, h, i: (b, h, i, 0)),
             out_shape=jax.ShapeDtypeStruct(qp.shape, qp.dtype),
-            interpret=_interpret(),
+            interpret=_pallas.interpret(),
         )(q_idx, q_cnt, qp, kp, vp, dop, lse_f, delta)
 
         dk, dv = pl.pallas_call(
@@ -286,7 +284,7 @@ def make_block_sparse_attention(layout, block, causal=True, scale=None):
             ],
             out_shape=[jax.ShapeDtypeStruct(kp.shape, kp.dtype),
                        jax.ShapeDtypeStruct(vp.shape, vp.dtype)],
-            interpret=_interpret(),
+            interpret=_pallas.interpret(),
         )(kv_idx, kv_cnt, qp, kp, vp, dop, lse_f, delta)
         return dq[:, :, :T], dk[:, :, :T], dv[:, :, :T]
 

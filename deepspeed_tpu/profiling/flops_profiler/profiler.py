@@ -27,10 +27,7 @@ from ...utils.logging import logger, log_dist
 
 
 def _cost_analysis(compiled):
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):  # older jax returns [dict]
-        ca = ca[0] if ca else {}
-    return dict(ca or {})
+    return dict(compiled.cost_analysis() or {})
 
 
 def profile_compiled(fn, *args, full_compile=False, **kwargs):

@@ -103,8 +103,11 @@ class DeepSpeedEngine:
         self.skipped_steps = 0
         self.loaded_checkpoint_tag = None
 
+        # the batch triple counts the devices this engine runs on: an installed
+        # mesh may cover fewer than jax.device_count() (one chip of four)
         self._config = config_class if config_class is not None else DeepSpeedConfig(
-            config, mpu, world_size=dist.get_world_size())
+            config, mpu, world_size=(dist.get_mesh().size if dist.has_mesh()
+                                     else dist.get_world_size()))
 
         # ---- mesh --------------------------------------------------------
         m = self._config.mesh
@@ -868,11 +871,10 @@ class DeepSpeedEngine:
             batch_specs = jax.tree_util.tree_map(
                 lambda x: P(*(([None, axis] + [None] * max(x.ndim - 2, 0))[:x.ndim])), batch)
             opt_specs = jax.tree_util.tree_map(lambda _: P(axis), state.opt_state)
-            from ..ops.pallas import shard_map_compat
-            new_params, new_opt, loss_mean, gnorm, overflow = shard_map_compat(
-                shard_fn, self.mesh,
-                (P(), opt_specs, P(), P(), batch_specs),
-                (P(), opt_specs, P(), P(), P()))(
+            new_params, new_opt, loss_mean, gnorm, overflow = jax.shard_map(
+                shard_fn, mesh=self.mesh,
+                in_specs=(P(), opt_specs, P(), P(), batch_specs),
+                out_specs=(P(), opt_specs, P(), P(), P()), check_vma=False)(
                     state.params, state.opt_state, state.loss_scale.cur_scale,
                     state.step, batch)
             new_scale = self.loss_scaler.update(state.loss_scale, overflow)

@@ -30,68 +30,16 @@ def num_pipeline_steps(num_microbatches, num_stages):
     return num_microbatches + num_stages - 1
 
 
-def _pvary(v, axes):
-    """``jax.lax.pvary`` (the >=0.6 varying-manual-axes annotation) or
-    identity on jax 0.4.x, whose shard_map tracks no vma types — the
-    annotation exists only for the new API's replication checker."""
-    pv = getattr(jax.lax, "pvary", None)
-    return pv(v, axes) if pv is not None else v
-
-
 def _vma(v):
-    """The value's varying-manual-axes set (empty on jax 0.4.x, which has
-    neither ``jax.typeof`` nor vma tracking — every pvary is then identity,
-    so 'not yet varying' is always the right answer)."""
-    tf = getattr(jax, "typeof", None)
-    return getattr(tf(v), "vma", frozenset()) if tf is not None else frozenset()
+    """The value's varying-manual-axes set."""
+    return jax.typeof(v).vma
 
 
-def _pipe_shard_map(fn, mesh, in_specs, out_specs, grad_through):
-    """Manual-over-``pipe`` shard_map spanning the jax API move.
-
-    jax >= 0.6: ``jax.shard_map(axis_names={pipe})`` — manual over the pipe
-    axis, every other mesh axis stays under the automatic partitioner
-    (UNCHANGED from the call these schedules always made; the chip rounds
-    validated it).
-
-    jax 0.4.x has no ``jax.shard_map``, and its
-    ``jax.experimental.shard_map`` partial-auto mode is unimplemented for
-    scan/ppermute bodies (the PR 10 note). FULL-manual is an exact
-    substitute in two cases:
-
-    - every non-pipe mesh axis has size 1 (unmentioned spec axes replicate;
-      psum/transpose over a size-1 axis is identity), or
-    - the caller never differentiates THROUGH the shard_map
-      (``grad_through=False`` — the 1F1B schedule computes its grads
-      INSIDE and returns them as plain outputs, so the replicated-input
-      transpose rule that would scale cotangents by the unmentioned axis
-      sizes is never exercised; forward values are genuinely replicated
-      over non-pipe axes, so ``P()`` outputs are exact).
-
-    Differentiating through a full-manual region with a >1 auto axis WOULD
-    silently scale ``P()``-input cotangents by that axis size (the
-    check_rep=False transpose psums over every manual axis), so that mix
-    raises a structured NotImplementedError instead — callers (the
-    multichip dryrun) skip the leg with the reason rather than training on
-    wrong gradients."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs,
-                             axis_names={dist.PIPE_AXIS})
-    from jax.experimental.shard_map import shard_map as _sm
-    other = [ax for ax in mesh.axis_names
-             if ax != dist.PIPE_AXIS and mesh.shape[ax] > 1]
-    if grad_through and other:
-        raise NotImplementedError(
-            f"fill-drain pipeline backward needs partial-manual shard_map "
-            f"(manual over '{dist.PIPE_AXIS}', auto over {other}); jax "
-            f"{jax.__version__} has neither jax.shard_map nor a working "
-            f"partial-auto jax.experimental.shard_map for scan/ppermute "
-            f"bodies, and the full-manual fallback would mis-scale "
-            f"replicated-input gradients by the {other} axis sizes — use a "
-            f"pipe-only (x size-1) mesh on this jax, or jax >= 0.6")
-    return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
+def _pipe_shard_map(fn, mesh, in_specs, out_specs):
+    """shard_map manual over the ``pipe`` axis; every other mesh axis stays
+    under the automatic partitioner."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                         axis_names={dist.PIPE_AXIS})
 
 
 def spmd_pipeline(stage_fn, stage_params, x_stream, mesh=None, remat=False, with_aux=False):
@@ -127,7 +75,7 @@ def spmd_pipeline(stage_fn, stage_params, x_stream, mesh=None, remat=False, with
     def run(local_params, xs):
         stage = jax.lax.axis_index(dist.PIPE_AXIS)
         # carries become stage-varying inside the loop; mark them so upfront
-        pvary = lambda v: _pvary(v, (dist.PIPE_AXIS, ))
+        pvary = lambda v: jax.lax.pcast(v, (dist.PIPE_AXIS, ), to="varying")
         state = tmap(lambda x: pvary(jnp.zeros_like(x[0])), xs)
         out_stream = tmap(lambda x: pvary(jnp.zeros_like(x)), xs)
         aux_total = pvary(jnp.zeros((), jnp.float32))
@@ -172,8 +120,7 @@ def spmd_pipeline(stage_fn, stage_params, x_stream, mesh=None, remat=False, with
     with dist.manual_axes({dist.PIPE_AXIS}):
         # grad_through: the engine differentiates jax.grad-style THROUGH
         # this call (backward is the transposed scan/ppermute)
-        return _pipe_shard_map(run, mesh, in_specs, out_specs,
-                               grad_through=True)(stage_params, x_stream)
+        return _pipe_shard_map(run, mesh, in_specs, out_specs)(stage_params, x_stream)
 
 
 def spmd_pipeline_1f1b(stage_fn, loss_head, stage_params, head_params, x_stream,
@@ -223,7 +170,7 @@ def spmd_pipeline_1f1b(stage_fn, loss_head, stage_params, head_params, x_stream,
             # idempotent invariant->varying promotion (stage params arrive
             # already pipe-varying; the replicated streams do not)
             return (v if dist.PIPE_AXIS in _vma(v)
-                    else _pvary(v, (dist.PIPE_AXIS, )))
+                    else jax.lax.pcast(v, (dist.PIPE_AXIS, ), to="varying"))
 
         # head params MUST be promoted to pipe-varying before value_and_grad:
         # differentiating a varying loss w.r.t. an INVARIANT input makes
@@ -314,11 +261,9 @@ def spmd_pipeline_1f1b(stage_fn, loss_head, stage_params, head_params, x_stream,
                  jax.tree_util.tree_map(lambda _: P(), x_stream))
     with dist.manual_axes({dist.PIPE_AXIS}):
         # 1F1B computes loss AND grads inside the region and returns them
-        # as plain outputs — nothing transposes through the shard_map, so
-        # the full-manual jax 0.4.x fallback is exact on any mesh
-        return _pipe_shard_map(run, mesh, in_specs, out_specs,
-                               grad_through=False)(stage_params, head_params,
-                                                   x_stream)
+        # as plain outputs — nothing transposes through the shard_map
+        return _pipe_shard_map(run, mesh, in_specs, out_specs)(
+            stage_params, head_params, x_stream)
 
 
 def _single_stage_1f1b(stage_fn, loss_head, stage_params, head_params, x_stream):

@@ -15,7 +15,10 @@ Multi-host modes (``serving/router.py``):
   to decode workers through that store.
 - ``--router``: no model at all — run the router tier (placement + proxy +
   store directory). Prints one ``ROUTER_READY`` JSON line; optionally
-  spawns a local worker fleet (``--spawn-workers N``) for smoke tests.
+  spawns a local worker fleet (``--spawn-workers N``) for smoke tests. A
+  worker is a process and an accelerator belongs to one process, so N local
+  workers need N accelerators each visible to one of them; a worker that
+  cannot claim its own exits, and the router then exits non-zero with it.
 """
 
 import argparse
@@ -24,6 +27,8 @@ import os
 import signal
 import subprocess
 import sys
+import threading
+import time
 
 
 def build_parser():
@@ -118,6 +123,7 @@ def run_router(args):
                     port=args.port if args.port is not None else 0,
                     heartbeat_timeout_s=args.heartbeat_timeout_s or 10.0)
     procs = []
+    failed = []
 
     def on_ready():
         print(json.dumps({"event": "ROUTER_READY", "host": router.host,
@@ -143,6 +149,26 @@ def run_router(args):
                 if val is not None:
                     cmd += [name, str(val)]
             procs.append(subprocess.Popen(cmd))
+        if procs:
+            threading.Thread(target=watch_workers, daemon=True).start()
+
+    def watch_workers():
+        # an accelerator belongs to ONE process: a second local worker that
+        # cannot claim its own dies at start-up. Take the fleet down with it
+        # rather than route around a worker that never came up.
+        while not failed:
+            for i, proc in enumerate(procs):
+                rc = proc.poll()
+                if rc not in (None, 0, -signal.SIGTERM):
+                    failed.append(rc)
+                    print(f"deepspeed_tpu.serving: spawned worker w{i} exited "
+                          f"with code {rc}. If it could not initialise its "
+                          f"accelerator, another local process (another worker) "
+                          f"holds the chip: one process per chip.",
+                          file=sys.stderr, flush=True)
+                    router.close()
+                    return
+            time.sleep(0.5)
 
     def shutdown(*_):
         for proc in procs:
@@ -161,7 +187,7 @@ def run_router(args):
                 proc.wait(timeout=10)
             except subprocess.TimeoutExpired:
                 proc.kill()
-    return 0
+    return 1 if failed else 0
 
 
 def main(argv=None):
@@ -220,9 +246,17 @@ def main(argv=None):
 
     import deepspeed_tpu
     from deepspeed_tpu.serving import Gateway
+    from deepspeed_tpu.utils import compile_cache
 
+    compile_cache.configure()
     engine = deepspeed_tpu.init_inference(args.model, config=cfg)
     gateway = Gateway(engine)
+    # At real depth a step program compiles for longer than the default
+    # request deadline, so a cold server would expire its first requests
+    # mid-compile. Compile what plain traffic dispatches before reporting
+    # ready (one program set serves every replica; seconds once the compile
+    # cache is warm).
+    gateway.scheduler.warm_programs(ladder=False)
     for sig in (signal.SIGTERM, signal.SIGINT):
         signal.signal(sig, lambda *_: gateway.begin_drain())
     if hasattr(signal, "SIGUSR1"):

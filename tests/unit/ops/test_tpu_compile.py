@@ -1,0 +1,205 @@
+"""The main-path Pallas kernels, compiled for a v5e chip that is described
+and not attached (``jax.experimental.topologies``), at gpt2-large and
+llama2-7b widths with the serving defaults (8 slots, ``decode_block_kv``
+256, ``prefill_chunk`` 64) and the training micro-batch (4 x 1024).
+
+Interpret mode accepts anything; the chip's compiler refuses a block that
+overflows VMEM and a relayout Mosaic cannot do. Nothing runs here — a
+compile that passes is not a chip run (``chip_smoke.py`` is).
+
+The topology is described inside a module-scoped fixture: only the xdist
+worker that is handed this file loads the TPU library, and only once a
+test has started.
+"""
+
+import dataclasses
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+import deepspeed_tpu.ops.pallas as pallas_pkg
+from deepspeed_tpu.models import get_model
+
+SLOTS, CHUNK, POOL_LEN = 8, 64, 1024
+MODELS = ("gpt2-large", "llama2-7b")
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def for_chip(monkeypatch, one_chip):
+    """Compile, don't interpret (the backend here is the CPU), and keep the
+    persistent cache out of it: an entry written for a described chip cannot
+    be read back without one."""
+    from jax.experimental.compilation_cache import compilation_cache
+    monkeypatch.setattr(pallas_pkg, "interpret", lambda: False)
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def compile_(fn, *args):
+        args = jax.tree_util.tree_map(
+            lambda a: sds(a.shape, a.dtype), args,
+            is_leaf=lambda a: hasattr(a, "shape"))
+        text = jax.jit(fn).lower(*args).compile().as_text()
+        assert "tpu_custom_call" in text
+        return text
+
+    yield sds, compile_
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _serving_model(name, **over):
+    """The model config ``init_inference(name, dtype=int8, kernel_inject)``
+    serves, one layer deep (every layer compiles the same kernels)."""
+    cfg = get_model(name).cfg
+    cfg = dataclasses.replace(
+        cfg, dtype=jnp.bfloat16, int8_weights=True, int8_fused_qkv=True,
+        attention_impl="flash", scan_layers=False, num_layers=1, **over)
+    return type(get_model(name))(cfg)
+
+
+# ------------------------------------------------------------------ training
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd_bwd"])
+@pytest.mark.parametrize("name", MODELS)
+def test_flash_attention(for_chip, name, grad):
+    from deepspeed_tpu.ops.pallas.flash_attention import sharded_flash_attention
+    sds, compile_ = for_chip
+    cfg = get_model(name).cfg
+    q = sds((4, cfg.num_heads, 1024, cfg.head_size), jnp.bfloat16)
+    kv = sds((4, cfg.kv_heads, 1024, cfg.head_size), jnp.bfloat16)
+
+    def fwd(q, k, v):  # as Attention calls it (models/transformer.py)
+        return sharded_flash_attention(q, k, v, causal=True,
+                                       block_q=cfg.attention_block_q,
+                                       block_kv=cfg.attention_block_kv)
+
+    if grad:
+        compile_(jax.grad(lambda *a: fwd(*a).astype(jnp.float32).sum(),
+                          argnums=(0, 1, 2)), q, kv, kv)
+    else:
+        compile_(fwd, q, kv, kv)
+
+
+# ------------------------------------------------------------ decode kernels
+def _pool(sds, cfg, int8):
+    shape = (SLOTS, cfg.kv_heads, POOL_LEN, cfg.head_size)
+    if int8:
+        return sds(shape, jnp.int8), sds((SLOTS, 1, POOL_LEN, 1), jnp.float16)
+    return sds(shape, jnp.bfloat16), None
+
+
+@pytest.mark.parametrize("variant", ["dense", "paged", "paged_int8kv", "span",
+                                     "span_int8kv", "span_extents"])
+@pytest.mark.parametrize("name", MODELS)
+def test_decode_attention(for_chip, name, variant):
+    from deepspeed_tpu.ops.pallas import decode_attention as da
+    sds, compile_ = for_chip
+    cfg = get_model(name).cfg
+    block = cfg.decode_block_kv
+    kv, scale = _pool(sds, cfg, "int8kv" in variant)
+    rows = sds((SLOTS, ), jnp.int32)
+    q1 = sds((SLOTS, cfg.num_heads, cfg.head_size), jnp.bfloat16)
+    qT = sds((SLOTS, cfg.num_heads, CHUNK, cfg.head_size), jnp.bfloat16)
+    if variant == "dense":
+        compile_(lambda q, k, v, st, end: da.decode_attention(
+            q, k, v, st, end, block_kv=block), q1, kv, kv, rows, sds((), jnp.int32))
+    elif variant == "paged":
+        compile_(lambda q, k, v, st, en: da.paged_decode_attention(
+            q, k, v, st, en, block_kv=block), q1, kv, kv, rows, rows)
+    elif variant == "paged_int8kv":
+        compile_(lambda q, k, v, st, en, sc: da.paged_decode_attention(
+            q, k, v, st, en, block_kv=block, k_scale=sc, v_scale=sc),
+            q1, kv, kv, rows, rows, scale)
+    elif variant == "span":
+        compile_(lambda q, k, v, st, ba: da.paged_span_attention(
+            q, k, v, st, ba, block_kv=block), qT, kv, kv, rows, rows)
+    elif variant == "span_int8kv":
+        compile_(lambda q, k, v, st, ba, sc: da.paged_span_attention(
+            q, k, v, st, ba, block_kv=block, k_scale=sc, v_scale=sc),
+            qT, kv, kv, rows, rows, scale)
+    else:
+        compile_(lambda q, k, v, st, ba, ext: da.paged_span_attention(
+            q, k, v, st, ba, block_kv=block, ext=ext, sink=st, window=st),
+            qT, kv, kv, rows, rows, sds((SLOTS, 2), jnp.int32))
+
+
+# ------------------------------------------------------- fused decode blocks
+def _layer_operands(name):
+    """Shapes of the operand tuples the engines hand the fused kernels:
+    ``fused_decode_operands`` over the int8 module's own param tree."""
+    model = _serving_model(name)
+    params = jax.eval_shape(model.init_params, jax.random.key(0))
+    (layer, ), head = jax.eval_shape(model.fused_decode_operands, params)
+    return model.cfg, layer, head
+
+
+@pytest.mark.parametrize("rows", [SLOTS, SLOTS * CHUNK], ids=["decode", "span"])
+@pytest.mark.parametrize("kernel", ["fused_qkv_ln", "fused_out_mlp", "logits"])
+@pytest.mark.parametrize("name", MODELS)
+def test_fused_block(for_chip, name, kernel, rows):
+    from deepspeed_tpu.models.transformer import _qmm2d
+    from deepspeed_tpu.ops.pallas import decode_block as db
+    sds, compile_ = for_chip
+    cfg, (norms, qkv, o, up, down, gate), head = _layer_operands(name)
+    nh, nkv, hd = cfg.num_heads, cfg.kv_heads, cfg.head_size
+    x = sds((rows, cfg.hidden_size), jnp.bfloat16)
+    if kernel == "fused_qkv_ln":
+        rope = sds((rows, hd // 2), jnp.float32) if cfg.pos_embedding == "rope" else None
+        compile_(lambda x, n, w, r: db.fused_qkv_ln(
+            x, n, w, eps=cfg.layernorm_epsilon, norm=cfg.norm,
+            rope=None if r is None else (r, r, nh + nkv, hd)), x, norms, qkv, rope)
+    elif kernel == "fused_out_mlp":
+        compile_(lambda a, x, n, o, up, down, gate: db.fused_out_mlp(
+            a, x, n, o, up, down, activation=cfg.activation,
+            eps=cfg.layernorm_epsilon, norm=cfg.norm, gate=gate),
+            sds((rows, nh * hd), jnp.bfloat16), x, norms, o, up, down, gate)
+    else:
+        compile_(_qmm2d, x, head["logits_q"], head["logits_scale"])
+
+
+@pytest.mark.parametrize("step", ["decode", "span", "span_int8kv"])
+@pytest.mark.parametrize("name", MODELS)
+def test_scheduler_step_program(for_chip, name, step):
+    """The continuous-batching step as the scheduler builds it —
+    ``fused_paged_step`` over the ``(num_slots, prefill_chunk)`` span (or
+    the one-column decode), bf16 and int8 pools — one layer deep."""
+    sds, compile_ = for_chip
+    model = _serving_model(name)
+    params = jax.eval_shape(model.init_params, jax.random.key(0))
+    pool = jax.eval_shape(lambda: model.init_cache(
+        SLOTS, POOL_LEN, quantized=step.endswith("int8kv")))
+    cols = 1 if step == "decode" else CHUNK
+    ids = sds((SLOTS, cols), jnp.int32)
+    rows = sds((SLOTS, ), jnp.int32)
+    compile_(model.fused_paged_step, params, ids, pool, ids, rows, rows)
+
+
+def test_generate_step_program(for_chip):
+    """``InferenceEngine._fused_step``'s layer: ``fused_decode_block`` with
+    the static-batch cache, gpt2-large, B=8."""
+    from deepspeed_tpu.ops.pallas.decode_block import fused_decode_block
+    sds, compile_ = for_chip
+    cfg, (norms, qkv, o, up, down, gate), _ = _layer_operands("gpt2-large")
+    kv, _ = _pool(sds, cfg, False)
+    compile_(lambda x, n, k, v, qkv, o, up, down, st, pos: fused_decode_block(
+        x, n, k, v, qkv, o, up, down, st, pos, activation=cfg.activation,
+        eps=cfg.layernorm_epsilon, block_kv=cfg.decode_block_kv, norm=cfg.norm),
+        sds((SLOTS, cfg.hidden_size), jnp.bfloat16), norms, kv, kv, qkv, o, up,
+        down, sds((SLOTS, ), jnp.int32), sds((), jnp.int32))

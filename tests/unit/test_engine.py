@@ -345,11 +345,7 @@ def test_multiprocess_smoke(tmp_path):
 import os, sys
 import jax
 jax.config.update("jax_platforms", "cpu")
-try:
-    jax.config.update("jax_num_cpu_devices", 2)
-except AttributeError:  # older jax: the XLA flag is read at backend init
-    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
-                               + " --xla_force_host_platform_device_count=2")
+jax.config.update("jax_num_cpu_devices", 2)
 import numpy as np
 import deepspeed_tpu
 sys.path.insert(0, os.environ["DSTPU_TESTS"])

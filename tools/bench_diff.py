@@ -1,18 +1,19 @@
 #!/usr/bin/env python
 """Diff a fresh BENCH_*.json against the prior round's and flag regressions.
 
-The growth loop records one ``BENCH_r0N.json`` per round (driver-wrapped:
+Two saved ``python bench.py`` outputs (raw, or driver-wrapped:
 ``{"n", "rc", "tail", "parsed": {metric, value, unit, vs_baseline, extra}}``)
-— but nothing compared rounds, so a perf regression only surfaced when a
-human eyeballed two JSON files. This tool walks every numeric leaf shared by
+are otherwise compared by eyeballing two JSON files. No round's JSON is
+kept in the repo — the driver's record of a PR is ``PERF_LEDGER.jsonl`` —
+so save the outputs you want compared. This tool walks every numeric leaf shared by
 two rounds and prints the delta, flagging moves past a threshold in the
 metric's BAD direction (lower-is-better names — ms/latency/stall/error —
 regress upward; everything else regresses downward).
 
     python tools/bench_diff.py NEW.json [OLD.json] [--threshold 0.05] [--strict]
 
-``OLD`` defaults to the highest-numbered ``BENCH_r*.json`` in the repo root
-other than ``NEW`` itself. Accepts driver-wrapped files, raw bench JSON
+``OLD`` defaults to the highest-numbered ``BENCH_r*.json`` lying in the repo
+root other than ``NEW`` itself, if there is one. Accepts driver-wrapped files, raw bench JSON
 lines (the ``python bench.py`` stdout), and files whose last line is the
 JSON (mixed logs). Exit code is 0 unless ``--strict`` is given and a
 regression was flagged — the default mode is ADVISORY (ci_check.sh runs it

@@ -259,18 +259,15 @@ multihost_lane() {
   # lease expiry reclaiming orphaned entries, directory version/coverage
   # semantics, capacity_math fleet merging (no draining double-count), and
   # the per-worker labeled Prometheus families under the 256-label cap.
-  # The matching perf leg is `python bench.py serving` ("multihost" entry:
-  # 1 vs 2 process aggregate tok/s + TTFT p95, BENCH_SERVING_MULTIHOST
-  # knob, scaling_efficiency reported).
   timeout -k 10 900 env JAX_PLATFORMS=cpu python -m pytest \
     tests/unit/serving/test_multihost.py -q -p no:cacheprovider
 }
 
 bench_diff() {
   echo "== bench diff (advisory) =="
-  # diff the given fresh bench JSON (or the latest committed round) against
-  # the prior BENCH_r0*.json and print per-metric deltas with regression
-  # flags. ADVISORY: regressions print loudly but never fail CI — a slow
+  # diff the given fresh bench JSON against the highest-numbered
+  # BENCH_r*.json lying in the repo root (none is committed: save the ones
+  # you want compared) and print per-metric deltas with regression flags. ADVISORY: regressions print loudly but never fail CI — a slow
   # bench leg should be seen, not block unrelated work (pass --strict to
   # tools/bench_diff.py directly to gate on it).
   local new="${1:-}"

@@ -1,6 +1,6 @@
 #!/usr/bin/env python
 """CI gateway smoke: the full lifecycle of ``python -m deepspeed_tpu.serving``
-as a black box, on an ephemeral port with the tiny model (CPU-safe).
+as a black box, on an ephemeral port with the tiny model.
 
 Asserts, in one server process:
   1. the GATEWAY_READY line appears with a bound port;
@@ -19,7 +19,6 @@ otherwise. No third-party deps (stdlib http.client only).
 
 import http.client
 import json
-import os
 import signal
 import subprocess
 import sys
@@ -46,13 +45,14 @@ def request(port, body, out, timeout=180):
 
 
 def main():
-    env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    # the server is a child and takes whatever backend JAX finds: CI pins
+    # JAX_PLATFORMS=cpu itself (tools/ci_check.sh); this parent never touches
+    # JAX, so on a machine with a chip the child gets the chip
     proc = subprocess.Popen(
         [sys.executable, "-m", "deepspeed_tpu.serving", "--model", "tiny",
          "--dtype", "float32", "--port", "0", "--num-slots", "1",
          "--max-queue-depth", "1"],
-        stdout=subprocess.PIPE, stderr=subprocess.DEVNULL, env=env, text=True)
+        stdout=subprocess.PIPE, stderr=subprocess.DEVNULL, text=True)
     try:
         port = None
         deadline = time.time() + 180
