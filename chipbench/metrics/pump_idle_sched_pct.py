@@ -1,0 +1,11 @@
+"""``pump_idle_sched_pct``: the share of the traced window in which the first
+chip ran nothing while the serving pump was under ``dstpu/sched/admit``,
+``/assemble``, ``/deliver`` or the rest of ``/step``: the scheduler's host
+work. With the two other ``pump_idle_*`` it adds up to the cell's idle share
+(``chipbench/xplane.py: idle_by_host``)."""
+
+from chipbench import xplane
+
+
+def reduce(obs):
+    return xplane.pump_idle_pct(obs, "sched")

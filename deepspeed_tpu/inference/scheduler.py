@@ -887,10 +887,8 @@ class DecodeScheduler:
         # request is STILL fully owned by this scheduler (active slot
         # intact) — the normal sick-replica shedding can fail it, instead
         # of stranding a request that is owned by nobody and parked nowhere
-        t0 = time.perf_counter() if self._gap is not None else 0.0
-        self.kv_tier.demote_request(slot, kv_len, key, on_ready)
-        if self._gap is not None:
-            self._gap.add("tier_transfer", time.perf_counter() - t0)
+        with self._span("sched/tier_transfer"):
+            self.kv_tier.demote_request(slot, kv_len, key, on_ready)
         if self.capacity is not None:
             # goodput: the demoted KV bytes are pure handoff traffic —
             # no request token comes out of moving them
@@ -970,12 +968,9 @@ class DecodeScheduler:
         if slot is None:
             return None  # every slot live: stays parked, retried next pull
         try:
-            t0 = time.perf_counter() if self._gap is not None else 0.0
-            with self.engine.mesh:
+            with self._span("sched/tier_transfer"), self.engine.mesh:
                 ok = self.kv_tier.restore_request(record.entry, slot,
                                                   record.kv_len)
-            if self._gap is not None:
-                self._gap.add("tier_transfer", time.perf_counter() - t0)
             if ok and self.capacity is not None:
                 # the restore half of the handoff: traffic, not tokens
                 self.capacity.account(
@@ -1033,20 +1028,42 @@ class DecodeScheduler:
         """One scheduler iteration: settle cancellations, admit (chunked: at
         most one in-flight prefill; legacy: while slots are free), then
         advance — one fused chunk+decode step while a prefill is in flight,
-        else ``steps_per_sync`` decode steps."""
+        else ``steps_per_sync`` decode steps.
+
+        The iteration is the ``sched/step`` span; inside it ``sched/admit``,
+        ``sched/assemble``, ``sched/dispatch``, ``sched/fetch`` and
+        ``sched/deliver`` mark what the pump was doing (profiler
+        annotations always; sink events while request tracing is on, where
+        request phases that landed this sync flow-link to the step)."""
         tel = self.telemetry
-        t0 = tel.now()
         tracing = tel.enabled and getattr(tel, "trace_requests", False)
         self._iter_links = [] if tracing else None
-        # sampled fenced-timing window (telemetry/capacity.py): every Nth
-        # sync the next dispatch is fenced and timed for the live MFU /
-        # bandwidth / roofline gauges; between samples the async dispatch
-        # pipeline is untouched
-        cap = self.capacity
-        if cap is not None:
-            self._sync_seq += 1
-            self._cap_sample = cap.should_sample(self._sync_seq)
-        gap = self._gap
+        try:
+            with self._span("sched/step") as span:
+                delivered, kind = self._iterate()
+                if kind is None:
+                    span.record = False  # nothing ran: no iteration to show
+                elif tracing:
+                    span.attrs = {"iter": self._iter, "kind": kind,
+                                  "live": len(self.active), "delivered": delivered}
+                    span.flow_out = self._iter_links or None
+        finally:
+            self._iter_links = None
+        return delivered
+
+    def _span(self, name):
+        """A block-level host span (``TelemetrySink.span``) of this pump:
+        the host-gap tracker hears its boundaries; the sink records it
+        while request tracing is on."""
+        tel = self.telemetry
+        return tel.span(name, record=getattr(tel, "trace_requests", False),
+                        observer=self._gap)
+
+    def _admit_queued(self):
+        """The admission part of an iteration, under ``sched/admit``: reap
+        cancellations, service long-context paging, pick from the queue,
+        acquire a slot (with its trie probe) and begin the prefill. Returns
+        the number of requests admitted."""
         # adapter invalidations (page evicted / adapter reloaded elsewhere
         # in the fleet) drain HERE, on the pump thread — trie surgery never
         # races a dispatch
@@ -1096,10 +1113,24 @@ class DecodeScheduler:
                     continue
                 self._admit(req)
                 admitted += 1
-        if gap is not None:
-            # everything since t0 was host-side admission work (the trie
-            # probe inside _acquire_slot re-files its share)
-            gap.add("admission", tel.now() - t0)
+        return admitted
+
+    def _iterate(self):
+        """The body of :meth:`step`. Returns (tokens delivered, the kind of
+        sync that ran: "fused", "spec", "decode", or None when nothing
+        could run)."""
+        tel = self.telemetry
+        t0 = tel.now()
+        # sampled fenced-timing window (telemetry/capacity.py): every Nth
+        # sync the next dispatch is fenced and timed for the live MFU /
+        # bandwidth / roofline gauges; between samples the async dispatch
+        # pipeline is untouched
+        cap = self.capacity
+        if cap is not None:
+            self._sync_seq += 1
+            self._cap_sample = cap.should_sample(self._sync_seq)
+        with self._span("sched/admit"):
+            admitted = self._admit_queued()
         if admitted and tel.enabled:
             tel.counter("serving/admitted", admitted)
         fused = self._prefill is not None
@@ -1111,7 +1142,6 @@ class DecodeScheduler:
                 # nothing can dispatch and nothing can ever free a row:
                 # every live request waits on a restore, and restores wait
                 # on a free row only a live request could release
-                self._iter_links = None
                 raise RuntimeError(
                     "long-context paging deadlock: every live request is "
                     "parked on demoted extents and no free pool row exists "
@@ -1124,8 +1154,7 @@ class DecodeScheduler:
                 kind = "decode"
                 delivered, ksteps = self._decode_step()
         else:
-            self._iter_links = None
-            return 0
+            return 0, None
         self._iter += 1
         if tel.enabled:
             dur_ms = (tel.now() - t0) * 1e3
@@ -1150,16 +1179,7 @@ class DecodeScheduler:
                 live_lens = [self.cache.lengths[s] for s in self.active]
                 ctx = (sum(live_lens) / len(live_lens)) if live_lens else 0.0
                 cap.account(delivered, wasted_tokens=max(0, rejected), ctx=ctx)
-        if tracing:
-            # the shared per-iteration span (pump-thread track): request
-            # phases that landed this sync flow-link to it via _iter_links
-            tel.record_span("sched/step", t0, tel.now() - t0,
-                            attrs={"iter": self._iter, "kind": kind,
-                                   "live": len(self.active),
-                                   "delivered": delivered},
-                            flow_out=self._iter_links or None)
-        self._iter_links = None
-        return delivered
+        return delivered, kind
 
     def _trace_link(self, trace):
         """Mint a flow id binding a request phase to the sync currently in
@@ -1397,11 +1417,8 @@ class DecodeScheduler:
                     raise RuntimeError(
                         "long-context paging invariant violated: a demoted "
                         "extent has no parked host entry to restore from")
-                t0 = time.perf_counter() if self._gap is not None else 0.0
-                with self.engine.mesh:
+                with self._span("sched/tier_transfer"), self.engine.mesh:
                     ok = self.kv_tier.restore_extent(entry, row)
-                if self._gap is not None:
-                    self._gap.add("tier_transfer", time.perf_counter() - t0)
                 if not ok:
                     raise RuntimeError(
                         "long-context paging invariant violated: a parked "
@@ -1461,13 +1478,10 @@ class DecodeScheduler:
             req.adapter_ref = aref
             return slot, (0, None)
         if self.radix is not None:
-            t0 = time.perf_counter() if self._gap is not None else 0.0
-            match = self.radix.match(req.prompt, adapter=akey)
-            if self._gap is not None:
-                # the probe ran inside the admission region already stamped
-                # by step(): re-file its share so buckets stay disjoint
-                self._gap.add("trie_probe", time.perf_counter() - t0,
-                              steal_from="admission")
+            # inside sched/admit: the host-gap tracker files the probe's
+            # share under its own bucket, so buckets stay disjoint
+            with self._span("sched/trie_probe"):
+                match = self.radix.match(req.prompt, adapter=akey)
         else:
             match = (0, None)
         slot = self.cache.alloc(owner=req.rid)
@@ -1499,6 +1513,10 @@ class DecodeScheduler:
         tel = self.telemetry
         req.slot = slot
         pos = 0
+        if tel.enabled:
+            # how long the request waited for the one prefill lane (its own
+            # chunks' time is the rest of serving/ttft_ms)
+            tel.histogram("serving/prefill_wait_ms", (tel.now() - req.submit_ts) * 1e3)
         tr = req.trace
         if tr is not None and tr.enabled:
             tr.mark("prefill")  # phase closes at _finish_prefill
@@ -1524,25 +1542,22 @@ class DecodeScheduler:
             # Adapter requests probe under their uid namespace — a base (or
             # other-adapter) host entry can never restore for them
             hm, entry = 0, None
-            tier_t0 = time.perf_counter() if self._gap is not None else 0.0
-            if self.kv_tier is not None:
-                ns = (self.adapters.namespace(req.adapter_ref.uid)
-                      if req.adapter_ref is not None else ())
-                hm, entry = self.kv_tier.probe(req.prompt, namespace=ns)
-                hm = min(hm, req.prompt.size - 1)
-                hm = (hm // self.prefill_chunk) * self.prefill_chunk
-                if hm < max(self.prefill_chunk, self.kv_tier.min_restore_tokens):
-                    hm, entry = 0, None
             restored = False
-            if entry is not None and hm > m:
-                with self.engine.mesh:
-                    restored = self.kv_tier.restore(entry, slot, hm,
-                                                    req.prompt.size)
-            if self._gap is not None and self.kv_tier is not None:
-                # host-tier probe + restore run inside the admission region
-                # already stamped by step(): re-file their share
-                self._gap.add("tier_transfer", time.perf_counter() - tier_t0,
-                              steal_from="admission")
+            if self.kv_tier is not None:
+                # host-tier probe + restore, inside sched/admit: the tracker
+                # re-files their share under tier_transfer
+                with self._span("sched/tier_transfer"):
+                    ns = (self.adapters.namespace(req.adapter_ref.uid)
+                          if req.adapter_ref is not None else ())
+                    hm, entry = self.kv_tier.probe(req.prompt, namespace=ns)
+                    hm = min(hm, req.prompt.size - 1)
+                    hm = (hm // self.prefill_chunk) * self.prefill_chunk
+                    if hm < max(self.prefill_chunk, self.kv_tier.min_restore_tokens):
+                        hm, entry = 0, None
+                    if entry is not None and hm > m:
+                        with self.engine.mesh:
+                            restored = self.kv_tier.restore(entry, slot, hm,
+                                                            req.prompt.size)
             if restored:
                 pos = hm
                 if tel.enabled:
@@ -1658,6 +1673,7 @@ class DecodeScheduler:
             # monolithic prefill stalls every live decode row for the WHOLE
             # prompt — the interference chunked prefill bounds at one chunk
             tel.histogram("serving/prefill_stall_ms", (req.first_token_ts - t_pf) * 1e3)
+            tel.histogram("serving/prefill_wait_ms", (t_pf - req.submit_ts) * 1e3)
             tel.histogram("serving/ttft_ms", (req.first_token_ts - req.submit_ts) * 1e3)
             tel.gauge("serving/queue_depth", len(self.queue))
         tr = req.trace
@@ -1741,7 +1757,6 @@ class DecodeScheduler:
         collect); ``steps`` is each row's ABSOLUTE step index, so results
         are K/fused-invariant."""
         N = self.cache.num_slots
-        t0 = time.perf_counter() if self._gap is not None else 0.0
         seeds = np.zeros(N, np.uint32)
         steps = np.zeros(N, np.int32)
         flags = np.zeros(N, bool)
@@ -1759,25 +1774,22 @@ class DecodeScheduler:
             topps[slot] = req.top_p
             sampling = sampling or req.do_sample
             collect = collect or req.collect_logits
-        if self._gap is not None:
-            self._gap.add("sampling_host", time.perf_counter() - t0)
         return seeds, steps, flags, temps, topks, topps, sampling, collect
 
     def _fetch_block(self, out, collect, K):
         """Unpack a compiled step program's result: replace the pool, fetch
         the (K, num_slots) token block (+ logits when collected)."""
-        if collect:
-            self.cache.pool, toks_k, logits_k = out
-            logits_k = np.asarray(jax.device_get(logits_k), np.float32)  # (K, N, V)
-        else:
-            self.cache.pool, toks_k = out
-            logits_k = None
-        toks_k = np.asarray(jax.device_get(toks_k)).reshape(K, self.cache.num_slots)
+        # the device_get is the sync fence: when sched/fetch closes the
+        # device is idle, until the next sched/dispatch opens
+        with self._span("sched/fetch"):
+            if collect:
+                self.cache.pool, toks_k, logits_k = out
+                logits_k = np.asarray(jax.device_get(logits_k), np.float32)  # (K, N, V)
+            else:
+                self.cache.pool, toks_k = out
+                logits_k = None
+            toks_k = np.asarray(jax.device_get(toks_k)).reshape(K, self.cache.num_slots)
         self._steps += K
-        if self._gap is not None:
-            # the device_get above was the sync fence: the device is idle
-            # from here until the next _dispatch closes the gap
-            self._gap.sync_end(time.perf_counter())
         return toks_k, logits_k
 
     def _deliver_block(self, live, toks_k, logits_k, K):
@@ -1786,24 +1798,23 @@ class DecodeScheduler:
         [len, len+K)); tokens past EOS/budget were computed but are
         discarded. Returns tokens delivered."""
         n_delivered = 0
-        t0 = time.perf_counter() if self._gap is not None else 0.0
-        for slot, req in live:
-            self.cache.lengths[slot] += K
-            for k in range(K):
-                if req.done:
-                    break
-                if req.collect_logits and logits_k is not None:
-                    req.logits.append(logits_k[k, slot])
-                self._deliver(req, int(toks_k[k, slot]))
-                n_delivered += 1
-        if self._gap is not None:
-            self._gap.add("on_token", time.perf_counter() - t0)
+        with self._span("sched/deliver"):
+            for slot, req in live:
+                self.cache.lengths[slot] += K
+                for k in range(K):
+                    if req.done:
+                        break
+                    if req.collect_logits and logits_k is not None:
+                        req.logits.append(logits_k[k, slot])
+                    self._deliver(req, int(toks_k[k, slot]))
+                    n_delivered += 1
         return n_delivered
 
     def _dispatch(self, fn, call_args, step_args):
-        """Hand ONE compiled program to the device. Owns the capacity hooks:
-        closes the open host gap (the device stops being idle the moment the
-        dispatch is enqueued) and, on a sampled sync, fences the dispatch —
+        """Hand ONE compiled program to the device, under ``sched/dispatch``
+        (whose start closes the open host gap: the device stops being idle
+        the moment the dispatch is enqueued). On a sampled sync, fences the
+        dispatch —
         ``block_until_ready`` on the input pool (drain outstanding work) and
         on the result — so the measured wall time is this program's device
         time alone. The fence touches only arrays the pipeline already owns:
@@ -1811,10 +1822,8 @@ class DecodeScheduler:
         tuple (pool at [1], lens at [3], spans at [4]) used for batch-shape
         recovery; ``call_args`` is what the program actually takes."""
         cap = self.capacity
-        if self._gap is not None:
-            self._gap.dispatch(time.perf_counter())
         if cap is None or not self._cap_sample:
-            with self.engine.mesh:
+            with self._span("sched/dispatch"), self.engine.mesh:
                 return fn(*call_args)
         # one fenced dispatch per sampled sync, even across MoE replays
         self._cap_sample = False
@@ -1822,7 +1831,7 @@ class DecodeScheduler:
         key = cap.key_for(fn)
         jax.block_until_ready(step_args[1])
         t0 = time.perf_counter()
-        with self.engine.mesh:
+        with self._span("sched/dispatch"), self.engine.mesh:
             out = fn(*call_args)
         jax.block_until_ready(out)
         dur = time.perf_counter() - t0
@@ -1934,25 +1943,26 @@ class DecodeScheduler:
         while pending:
             group = list(pending)
             while True:
-                ids = np.zeros((N, 1), np.int32)
-                spans = np.zeros(N, np.int32)
-                lens = np.zeros(N, np.int32)
-                for slot, req in group:
-                    ids[slot, 0] = req.out[-1]
-                    spans[slot] = 1
-                    lens[slot] = self.cache.lengths[slot]
-                (seeds, steps, flags, temps, topks, topps, sampling,
-                 collect) = self._gather_sampling(group)
-                lora = self._adapter_arg(group)
-                eo = self._ext_operands(group)
-                fn = self._fused_fn(sampling, collect, 1, 1, lora=lora is not None,
-                                    ext=eo is not None)
-                args = (eng.params, self.cache.pool, jnp.asarray(ids),
-                        jnp.asarray(lens), jnp.asarray(spans),
-                        jnp.asarray(seeds), jnp.asarray(steps), jnp.asarray(flags),
-                        jnp.asarray(temps), jnp.asarray(topks), jnp.asarray(topps))
-                if eo is not None:
-                    args = args + tuple(jnp.asarray(x) for x in eo)
+                with self._span("sched/assemble"):
+                    ids = np.zeros((N, 1), np.int32)
+                    spans = np.zeros(N, np.int32)
+                    lens = np.zeros(N, np.int32)
+                    for slot, req in group:
+                        ids[slot, 0] = req.out[-1]
+                        spans[slot] = 1
+                        lens[slot] = self.cache.lengths[slot]
+                    (seeds, steps, flags, temps, topks, topps, sampling,
+                     collect) = self._gather_sampling(group)
+                    lora = self._adapter_arg(group)
+                    eo = self._ext_operands(group)
+                    fn = self._fused_fn(sampling, collect, 1, 1, lora=lora is not None,
+                                        ext=eo is not None)
+                    args = (eng.params, self.cache.pool, jnp.asarray(ids),
+                            jnp.asarray(lens), jnp.asarray(spans),
+                            jnp.asarray(seeds), jnp.asarray(steps), jnp.asarray(flags),
+                            jnp.asarray(temps), jnp.asarray(topks), jnp.asarray(topps))
+                    if eo is not None:
+                        args = args + tuple(jnp.asarray(x) for x in eo)
                 try:
                     out = self._call_step(fn, args, lora)
                     break
@@ -2149,35 +2159,36 @@ class DecodeScheduler:
         row, not the longest retained prefix."""
         eng = self.engine
         N = self.cache.num_slots
-        live = [(s, r) for s, r in sorted(self.active.items())
-                if s not in self._parked]
-        ids = np.zeros((N, 1), np.int32)
-        spans = np.zeros(N, np.int32)
-        lens = np.zeros(N, np.int32)
-        for slot, req in live:
-            ids[slot, 0] = req.out[-1]
-            spans[slot] = 1
-            lens[slot] = self.cache.lengths[slot]
-        (seeds, steps, flags, temps, topks, topps, sampling,
-         collect) = self._gather_sampling(live)
-        K = self.steps_per_sync
-        eo = self._ext_operands(live)
-        if eo is not None and K > 1:
-            # a K-step sync writes rows [len, len+K) contiguously in the
-            # write extent — a row about to cross an extent boundary steps
-            # through it one token at a time (the (1, 1) program is warm)
-            S = self.max_len
-            if any(S - int(self.cache.lengths[s]) % S < K for s, _ in live):
-                K = 1
-        lora = self._adapter_arg(live)
-        fn = self._fused_fn(sampling, collect, K, 1, lora=lora is not None,
-                            ext=eo is not None)
-        args = (eng.params, self.cache.pool, jnp.asarray(ids),
-                jnp.asarray(lens), jnp.asarray(spans),
-                jnp.asarray(seeds), jnp.asarray(steps), jnp.asarray(flags),
-                jnp.asarray(temps), jnp.asarray(topks), jnp.asarray(topps))
-        if eo is not None:
-            args = args + tuple(jnp.asarray(x) for x in eo)
+        with self._span("sched/assemble"):
+            live = [(s, r) for s, r in sorted(self.active.items())
+                    if s not in self._parked]
+            ids = np.zeros((N, 1), np.int32)
+            spans = np.zeros(N, np.int32)
+            lens = np.zeros(N, np.int32)
+            for slot, req in live:
+                ids[slot, 0] = req.out[-1]
+                spans[slot] = 1
+                lens[slot] = self.cache.lengths[slot]
+            (seeds, steps, flags, temps, topks, topps, sampling,
+             collect) = self._gather_sampling(live)
+            K = self.steps_per_sync
+            eo = self._ext_operands(live)
+            if eo is not None and K > 1:
+                # a K-step sync writes rows [len, len+K) contiguously in the
+                # write extent — a row about to cross an extent boundary steps
+                # through it one token at a time (the (1, 1) program is warm)
+                S = self.max_len
+                if any(S - int(self.cache.lengths[s]) % S < K for s, _ in live):
+                    K = 1
+            lora = self._adapter_arg(live)
+            fn = self._fused_fn(sampling, collect, K, 1, lora=lora is not None,
+                                ext=eo is not None)
+            args = (eng.params, self.cache.pool, jnp.asarray(ids),
+                    jnp.asarray(lens), jnp.asarray(spans),
+                    jnp.asarray(seeds), jnp.asarray(steps), jnp.asarray(flags),
+                    jnp.asarray(temps), jnp.asarray(topks), jnp.asarray(topps))
+            if eo is not None:
+                args = args + tuple(jnp.asarray(x) for x in eo)
         try:
             out = self._call_step(fn, args, lora)
         except _ExpertOverflow as e:
@@ -2229,24 +2240,25 @@ class DecodeScheduler:
             total_draft += d.size
         if total_draft == 0:
             return self._decode_step()
-        ids = np.zeros((N, W), np.int32)
-        spans = np.zeros(N, np.int32)
-        lens = np.zeros(N, np.int32)
-        for slot, req in live:
-            d = drafts[slot]
-            ids[slot, 0] = req.out[-1]
-            if d.size:
-                ids[slot, 1:1 + d.size] = d
-            spans[slot] = 1 + d.size
-            lens[slot] = self.cache.lengths[slot]
-        (seeds, steps, flags, temps, topks, topps, sampling,
-         collect) = self._gather_sampling(live)
-        lora = self._adapter_arg(live)
-        fn = self._spec_fn(sampling, collect, W, lora=lora is not None)
-        args = (eng.params, self.cache.pool, jnp.asarray(ids),
-                jnp.asarray(lens), jnp.asarray(spans),
-                jnp.asarray(seeds), jnp.asarray(steps), jnp.asarray(flags),
-                jnp.asarray(temps), jnp.asarray(topks), jnp.asarray(topps))
+        with self._span("sched/assemble"):
+            ids = np.zeros((N, W), np.int32)
+            spans = np.zeros(N, np.int32)
+            lens = np.zeros(N, np.int32)
+            for slot, req in live:
+                d = drafts[slot]
+                ids[slot, 0] = req.out[-1]
+                if d.size:
+                    ids[slot, 1:1 + d.size] = d
+                spans[slot] = 1 + d.size
+                lens[slot] = self.cache.lengths[slot]
+            (seeds, steps, flags, temps, topks, topps, sampling,
+             collect) = self._gather_sampling(live)
+            lora = self._adapter_arg(live)
+            fn = self._spec_fn(sampling, collect, W, lora=lora is not None)
+            args = (eng.params, self.cache.pool, jnp.asarray(ids),
+                    jnp.asarray(lens), jnp.asarray(spans),
+                    jnp.asarray(seeds), jnp.asarray(steps), jnp.asarray(flags),
+                    jnp.asarray(temps), jnp.asarray(topks), jnp.asarray(topps))
         try:
             out = self._call_step(fn, args, lora)
         except _ExpertOverflow as e:
@@ -2254,13 +2266,14 @@ class DecodeScheduler:
             # advance one exact token per row (bit-identical either way)
             self.cache.pool = e.pool
             return self._decode_backoff(live), 1
-        if collect:
-            self.cache.pool, toks_k, logits_k = out
-            logits_k = np.asarray(jax.device_get(logits_k), np.float32)  # (W, N, V)
-        else:
-            self.cache.pool, toks_k = out
-            logits_k = None
-        toks_k = np.asarray(jax.device_get(toks_k)).reshape(W, N)
+        with self._span("sched/fetch"):
+            if collect:
+                self.cache.pool, toks_k, logits_k = out
+                logits_k = np.asarray(jax.device_get(logits_k), np.float32)  # (W, N, V)
+            else:
+                self.cache.pool, toks_k = out
+                logits_k = None
+            toks_k = np.asarray(jax.device_get(toks_k)).reshape(W, N)
         self._steps += 1
         tel = self.telemetry
         delivered = 0
@@ -2335,56 +2348,57 @@ class DecodeScheduler:
         # write lands in exactly one extent's pool row
         take = min(C, L - pf.pos, S - pf.pos % S)
         final = pf.pos + take >= L
-        ids = np.zeros((N, C), np.int32)
-        spans = np.zeros(N, np.int32)
-        # dead/cached rows keep length 0 in the program input: their writes
-        # are dropped (span 0), and the paged kernel's KV-block walk stays
-        # bounded by the longest live row, not the longest retained prefix
-        lens = np.zeros(N, np.int32)
-        live = [(s, r) for s, r in sorted(self.active.items())
-                if s not in self._parked]
-        (seeds, steps, flags, temps, topks, topps, sampling,
-         collect) = self._gather_sampling(live)
-        sampling = sampling or preq.do_sample
-        collect = collect or preq.collect_logits
-        for slot, req in live:
-            ids[slot, 0] = req.out[-1]
-            spans[slot] = 1
-            lens[slot] = self.cache.lengths[slot]
-        ps = preq.slot
-        ids[ps, :take] = preq.prompt[pf.pos:pf.pos + take]
-        spans[ps] = take
-        seeds[ps] = preq.seed  # steps[ps] stays 0: prefill samples token 0
-        flags[ps] = preq.do_sample
-        temps[ps] = preq.temperature
-        topks[ps] = preq.top_k
-        topps[ps] = preq.top_p
-        # substeps only pay off when something real decodes in them: live
-        # rows, or the prefill row itself once its final chunk lands — a
-        # non-final chunk on an otherwise idle pool runs the 1-step variant
-        K = self.steps_per_sync if (live or final) else 1
-        eo = self._ext_operands(live + [(ps, preq)], force=seqp)
-        if eo is not None and K > 1:
-            # substep writes stay inside each row's write extent: decode
-            # rows need K rows of extent headroom; a FINAL chunk's row
-            # needs its chunk plus the K-1 substep rows to fit its extent
-            room = [S - int(self.cache.lengths[s]) % S for s, _ in live]
-            if final:
-                room.append(S - pf.pos % S - take + 1)
-            if any(r < K for r in room):
-                K = 1
-        lora = self._adapter_arg(live + [(ps, preq)])
-        fn = self._fused_fn(sampling, collect, K, C, lora=lora is not None,
-                            ext=eo is not None, seqp=seqp)
-        tel = self.telemetry
-        t0 = tel.now()
-        lens[ps] = self.cache.lengths[ps]  # prefix copy and/or earlier chunks
-        args = (eng.params, self.cache.pool, jnp.asarray(ids),
-                jnp.asarray(lens), jnp.asarray(spans),
-                jnp.asarray(seeds), jnp.asarray(steps), jnp.asarray(flags),
-                jnp.asarray(temps), jnp.asarray(topks), jnp.asarray(topps))
-        if eo is not None:
-            args = args + tuple(jnp.asarray(x) for x in eo)
+        with self._span("sched/assemble"):
+            ids = np.zeros((N, C), np.int32)
+            spans = np.zeros(N, np.int32)
+            # dead/cached rows keep length 0 in the program input: their writes
+            # are dropped (span 0), and the paged kernel's KV-block walk stays
+            # bounded by the longest live row, not the longest retained prefix
+            lens = np.zeros(N, np.int32)
+            live = [(s, r) for s, r in sorted(self.active.items())
+                    if s not in self._parked]
+            (seeds, steps, flags, temps, topks, topps, sampling,
+             collect) = self._gather_sampling(live)
+            sampling = sampling or preq.do_sample
+            collect = collect or preq.collect_logits
+            for slot, req in live:
+                ids[slot, 0] = req.out[-1]
+                spans[slot] = 1
+                lens[slot] = self.cache.lengths[slot]
+            ps = preq.slot
+            ids[ps, :take] = preq.prompt[pf.pos:pf.pos + take]
+            spans[ps] = take
+            seeds[ps] = preq.seed  # steps[ps] stays 0: prefill samples token 0
+            flags[ps] = preq.do_sample
+            temps[ps] = preq.temperature
+            topks[ps] = preq.top_k
+            topps[ps] = preq.top_p
+            # substeps only pay off when something real decodes in them: live
+            # rows, or the prefill row itself once its final chunk lands — a
+            # non-final chunk on an otherwise idle pool runs the 1-step variant
+            K = self.steps_per_sync if (live or final) else 1
+            eo = self._ext_operands(live + [(ps, preq)], force=seqp)
+            if eo is not None and K > 1:
+                # substep writes stay inside each row's write extent: decode
+                # rows need K rows of extent headroom; a FINAL chunk's row
+                # needs its chunk plus the K-1 substep rows to fit its extent
+                room = [S - int(self.cache.lengths[s]) % S for s, _ in live]
+                if final:
+                    room.append(S - pf.pos % S - take + 1)
+                if any(r < K for r in room):
+                    K = 1
+            lora = self._adapter_arg(live + [(ps, preq)])
+            fn = self._fused_fn(sampling, collect, K, C, lora=lora is not None,
+                                ext=eo is not None, seqp=seqp)
+            tel = self.telemetry
+            t0 = tel.now()
+            lens[ps] = self.cache.lengths[ps]  # prefix copy and/or earlier chunks
+            args = (eng.params, self.cache.pool, jnp.asarray(ids),
+                    jnp.asarray(lens), jnp.asarray(spans),
+                    jnp.asarray(seeds), jnp.asarray(steps), jnp.asarray(flags),
+                    jnp.asarray(temps), jnp.asarray(topks), jnp.asarray(topps))
+            if eo is not None:
+                args = args + tuple(jnp.asarray(x) for x in eo)
         try:
             out = self._call_step(fn, args, lora)
         except _ExpertOverflow as e:
@@ -2549,10 +2563,11 @@ class DecodeScheduler:
             offload = self.experts is not None
 
             def sample(l2, seeds, steps, flags, temps, topks, topps):
-                if sampling:
-                    return jax.vmap(_sample_slot)(seeds, steps, l2, flags,
-                                                  temps, topks, topps)
-                return jnp.argmax(l2, axis=-1).astype(jnp.int32)
+                with jax.named_scope("sample"):
+                    if sampling:
+                        return jax.vmap(_sample_slot)(seeds, steps, l2, flags,
+                                                      temps, topks, topps)
+                    return jnp.argmax(l2, axis=-1).astype(jnp.int32)
 
             def fused(params, pool, ids, lengths, spans, seeds, steps, flags,
                       temps, topks, topps, *extra):
@@ -2683,10 +2698,11 @@ class DecodeScheduler:
             offload = self.experts is not None
 
             def sample(l2, seeds, steps, flags, temps, topks, topps):
-                if sampling:
-                    return jax.vmap(_sample_slot)(seeds, steps, l2, flags,
-                                                  temps, topks, topps)
-                return jnp.argmax(l2, axis=-1).astype(jnp.int32)
+                with jax.named_scope("sample"):
+                    if sampling:
+                        return jax.vmap(_sample_slot)(seeds, steps, l2, flags,
+                                                      temps, topks, topps)
+                    return jnp.argmax(l2, axis=-1).astype(jnp.int32)
 
             def spec(params, pool, ids, lengths, spans, seeds, steps, flags,
                      temps, topks, topps, *extra):

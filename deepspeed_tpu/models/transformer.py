@@ -790,10 +790,11 @@ class Attention(nn.Module):
                 tgt = (write_index - ext_base)[:, None] + jnp.arange(T)[None, :]
                 tgt = jnp.where(jnp.arange(T)[None, :] < q_spans[:, None], tgt,
                                 ck.shape[2])
-                written = [
-                    c.at[wslot[:, None], :, tgt].set(
-                        kk.transpose(0, 2, 1, 3).astype(c.dtype), mode="drop")
-                    for c, kk in writes]
+                with jax.named_scope("kv_commit"):
+                    written = [
+                        c.at[wslot[:, None], :, tgt].set(
+                            kk.transpose(0, 2, 1, 3).astype(c.dtype), mode="drop")
+                        for c, kk in writes]
                 cache_index = write_index
             elif write_index is not None and q_spans is not None:
                 # fused chunk/decode span write: column j of row i lands at
@@ -806,7 +807,8 @@ class Attention(nn.Module):
                 tgt = jnp.where(jnp.arange(T)[None, :] < q_spans[:, None], tgt,
                                 ck.shape[2])
                 upd = lambda c, kk, i: c.at[:, i, :].set(kk.astype(c.dtype), mode="drop")
-                written = [jax.vmap(upd)(c, kk, tgt) for c, kk in writes]
+                with jax.named_scope("kv_commit"):
+                    written = [jax.vmap(upd)(c, kk, tgt) for c, kk in writes]
                 cache_index = write_index  # per-row causal window below
             elif write_index is not None:
                 # slot-pool decode: each row appends at its own position
@@ -1615,7 +1617,8 @@ class CausalLMModel:
             tgt = jnp.where(col < q_spans[:, None], tgt, ck.shape[2])
             upd = lambda c, kk, t_: c.at[:, t_, :].set(kk.astype(c.dtype),
                                                        mode="drop")
-            written = [jax.vmap(upd)(c, kk, tgt) for c, kk in writes]
+            with jax.named_scope("kv_commit"):
+                written = [jax.vmap(upd)(c, kk, tgt) for c, kk in writes]
             if quant_kv:
                 ck, cv, csc = written
             else:
@@ -1717,16 +1720,19 @@ class CausalLMModel:
             shift = slice(None, -1)
         valid = (labels >= 0)
         labels_c = jnp.maximum(labels, 0)
-        if chunked:
-            w, transpose = self._ce_weight(params)
-            total = chunked_cross_entropy(hidden_or_logits[:, shift], w, labels_c, valid,
-                                          chunk=self._ce_chunk(), transpose=transpose)
-            loss = total / jnp.maximum(jnp.sum(valid), 1)
-        else:
-            import optax
-            ce = optax.softmax_cross_entropy_with_integer_labels(
-                hidden_or_logits[:, shift].astype(jnp.float32), labels_c)
-            loss = jnp.sum(ce * valid) / jnp.maximum(jnp.sum(valid), 1)
+        # the vocabulary projection (chunked path) and the cross-entropy,
+        # named for the device trace
+        with jax.named_scope("loss_ce"):
+            if chunked:
+                w, transpose = self._ce_weight(params)
+                total = chunked_cross_entropy(hidden_or_logits[:, shift], w, labels_c, valid,
+                                              chunk=self._ce_chunk(), transpose=transpose)
+                loss = total / jnp.maximum(jnp.sum(valid), 1)
+            else:
+                import optax
+                ce = optax.softmax_cross_entropy_with_integer_labels(
+                    hidden_or_logits[:, shift].astype(jnp.float32), labels_c)
+                loss = jnp.sum(ce * valid) / jnp.maximum(jnp.sum(valid), 1)
         if self.cfg.num_experts > 0:
             aux = mutated.get("intermediates", {})
             aux_losses = jax.tree_util.tree_leaves(aux)

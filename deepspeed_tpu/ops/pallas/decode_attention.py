@@ -268,6 +268,7 @@ def _decode_call(qg, k_cache, v_cache, start, ends, *, block_kv, scale, span=1,
                                span=span, quantized=quantized, lossy=lossy)
     return pl.pallas_call(
         kernel,
+        name="dstpu_decode_attn",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(scalars),
             grid=(B, nkv // bh, E * bpe),

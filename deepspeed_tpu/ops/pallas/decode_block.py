@@ -249,6 +249,7 @@ def fused_qkv_ln(x, norms, qkv, *, eps=1e-5, norm="layernorm", rope=None):
                      jnp.asarray(cos2d, jnp.float32)]
     return pl.pallas_call(
         kernel,
+        name="dstpu_fused_qkv_ln",
         grid=(B // bm, Nq // bn, nk1),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, s: (i, j)),
@@ -461,6 +462,7 @@ def fused_out_mlp(attn2d, x, norms, o, up, down, *, activation="gelu",
     scratch.append(pltpu.VMEM((bm, H), f32))          # shared o/down accumulator
     return pl.pallas_call(
         kernel,
+        name="dstpu_fused_out_mlp",
         grid=(B // bm, nsteps),
         in_specs=in_specs,
         out_specs=rows(H),

@@ -161,6 +161,7 @@ def quant_matmul(x, qw, scales, block_m=256, block_n=None, block_k=None, out_dty
 
     return pl.pallas_call(
         functools.partial(_qmm_kernel, nk=nk, bk=bk, gsize=gsize, ng=ng),
+        name="dstpu_quant_matmul",
         grid=(M // bm, N // bn, nk),
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),

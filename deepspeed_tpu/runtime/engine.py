@@ -668,38 +668,42 @@ class DeepSpeedEngine:
     def _apply_grads(self, state, grads, loss_mean):
         """Unscale, clip, update, handle overflow — shared by both paths."""
         scale = state.loss_scale.cur_scale
-        denom = self._grad_denom(scale)
-        grads = jax.tree_util.tree_map(lambda g: (g / denom).astype(jnp.float32), grads)
-        # stage>=2: pin gradients to their scattered sharding
-        grads = jax.lax.with_sharding_constraint(
-            grads, self.planner.shardings(self.planner.grad_specs(state.params)))
+        # named regions of the compiled step (no flax module names them): the
+        # device trace books an operation under the scope it was traced in
+        with jax.named_scope("grad_norm"):
+            denom = self._grad_denom(scale)
+            grads = jax.tree_util.tree_map(lambda g: (g / denom).astype(jnp.float32), grads)
+            # stage>=2: pin gradients to their scattered sharding
+            grads = jax.lax.with_sharding_constraint(
+                grads, self.planner.shardings(self.planner.grad_specs(state.params)))
 
-        gnorm = optax.global_norm(grads)
-        overflow = ~jnp.isfinite(gnorm)
-        coef = self._clip_coef(gnorm)
-        if coef is not None:
-            grads = jax.tree_util.tree_map(lambda g: g * coef, grads)
+            gnorm = optax.global_norm(grads)
+            overflow = ~jnp.isfinite(gnorm)
+            coef = self._clip_coef(gnorm)
+            if coef is not None:
+                grads = jax.tree_util.tree_map(lambda g: g * coef, grads)
 
-        updates, new_opt = self.tx.update(grads, state.opt_state, state.params)
-        new_params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("optimizer"):
+            updates, new_opt = self.tx.update(grads, state.opt_state, state.params)
+            new_params = optax.apply_updates(state.params, updates)
 
-        # overflow: skip the update entirely (reference loss-scaler semantics)
-        def sel(new, old):
-            return jax.tree_util.tree_map(lambda n, o: jnp.where(overflow, o, n), new, old)
+            # overflow: skip the update entirely (reference loss-scaler semantics)
+            def sel(new, old):
+                return jax.tree_util.tree_map(lambda n, o: jnp.where(overflow, o, n), new, old)
 
-        new_params = sel(new_params, state.params)
-        new_opt = sel(new_opt, state.opt_state)
-        new_scale = self.loss_scaler.update(state.loss_scale, overflow)
+            new_params = sel(new_params, state.params)
+            new_opt = sel(new_opt, state.opt_state)
+            new_scale = self.loss_scaler.update(state.loss_scale, overflow)
 
-        new_state = state._replace(
-            step=state.step + jnp.where(overflow, 0, 1),
-            params=new_params,
-            opt_state=new_opt,
-            grad_acc=jax.tree_util.tree_map(jnp.zeros_like, state.grad_acc),
-            micro_step=jnp.zeros((), jnp.int32),
-            loss_scale=new_scale,
-            skipped_steps=state.skipped_steps + overflow.astype(jnp.int32),
-        )
+            new_state = state._replace(
+                step=state.step + jnp.where(overflow, 0, 1),
+                params=new_params,
+                opt_state=new_opt,
+                grad_acc=jax.tree_util.tree_map(jnp.zeros_like, state.grad_acc),
+                micro_step=jnp.zeros((), jnp.int32),
+                loss_scale=new_scale,
+                skipped_steps=state.skipped_steps + overflow.astype(jnp.int32),
+            )
         lr = self.lr_schedule_fn(state.step.astype(jnp.float32))
         metrics = {
             "loss": loss_mean,

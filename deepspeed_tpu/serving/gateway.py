@@ -104,6 +104,7 @@ from ..inference.config import GatewayConfig
 from ..telemetry import (DEFAULT_SERVING_OBJECTIVES, RequestTrace, SLOEngine,
                          extract_trace_context)
 from ..telemetry import prometheus as prom
+from ..utils import compile_cache
 from ..utils.logging import logger
 from . import capacity_math
 from .controller import FleetController, FleetSignals
@@ -450,8 +451,11 @@ class Gateway:
         exactly once per turn regardless of fleet size."""
         sched = rep.scheduler
         primary = rep.idx == 0
+        # the pump's own two spans: profiler annotations only (an idle pump
+        # turns 50 times a second; the sink records none of them)
+        span = self.telemetry.span
         while not self._force_stop:
-            with self._dispatch_lock:
+            with span("gateway/admit", record=False), self._dispatch_lock:
                 self._enforce_cancellations()
                 self._admit()
             try:
@@ -523,7 +527,8 @@ class Gateway:
             if rep.idle() or rep.sick:
                 if self.draining and not len(self._fair) and not self._active:
                     break
-                self._wake.wait(0.02)
+                with span("gateway/idle", record=False):
+                    self._wake.wait(0.02)
                 self._wake.clear()
         # force-stop: anything still in flight is failed, not silently
         # dropped (any one pump suffices — _fail_in_flight spans the fleet)
@@ -1430,6 +1435,9 @@ class Gateway:
             # present only when a WorkerAgent attached a NetPrefixStore
             "net_store": (self.net_store.stats()
                           if self.net_store is not None else None),
+            # seconds and counts of this process's program set-up, by phase
+            # (trace, lower, backend, cache read): moves on compiles only
+            "compile": compile_cache.stats(),
             "telemetry": self.telemetry.snapshot(),
         }
 

@@ -33,9 +33,10 @@ no-ops when the telemetry sink is disabled:
 
 - :class:`HostGapTracker` — device-idle attribution for the pump thread.
   The gap between one sync's fence and the next dispatch is pure host
-  time; the scheduler stamps its admission / trie-probe / sampling-host /
-  on_token-delivery / tier-transfer sections into the open gap and the
-  tracker emits a ``serving/host_gap_ms`` histogram plus per-bucket
+  time; the scheduler's spans (``sched/admit``, ``sched/trie_probe``,
+  ``sched/assemble``, ``sched/deliver``, ``sched/tier_transfer``: the same
+  boundaries a profiler capture shows) stamp their sections into the open
+  gap and the tracker emits a ``serving/host_gap_ms`` histogram plus per-bucket
   ``serving/host_gap/<bucket>_ms`` counters whose sum equals the measured
   gap exactly (residue lands in ``other``; over-attribution from timer
   overlap is scaled back proportionally).
@@ -51,6 +52,13 @@ import numpy as np
 # overhead, GIL waits, and anything not explicitly instrumented.
 GAP_BUCKETS = ("admission", "trie_probe", "sampling_host", "on_token",
                "tier_transfer", "other")
+
+# the scheduler's spans (``TelemetrySink.span``) that feed the tracker, and
+# the bucket each one's time belongs to. ``sched/dispatch`` (its start
+# closes the gap) and ``sched/fetch`` (its end opens one) carry no bucket.
+SPAN_BUCKETS = {"sched/admit": "admission", "sched/trie_probe": "trie_probe",
+                "sched/assemble": "sampling_host", "sched/deliver": "on_token",
+                "sched/tier_transfer": "tier_transfer"}
 
 _GATED_ACTS = ("swiglu", "geglu")
 
@@ -293,23 +301,44 @@ class CapacityMeter:
 class HostGapTracker:
     """Device-idle (host-gap) attribution for one pump thread.
 
-    Lifecycle per sync: the scheduler calls :meth:`sync_end` when a
-    dispatch's results are fenced on the host (the device goes idle),
-    stamps host sections into the open gap via :meth:`add`, and calls
-    :meth:`dispatch` the moment the next program is handed to the device —
-    closing the gap, normalizing attribution so the per-bucket counters
-    sum EXACTLY to the measured gap, and emitting the histogram. All
-    methods are single-float arithmetic; the tracker is only constructed
-    when the sink is enabled."""
+    Lifecycle per sync: :meth:`sync_end` when a dispatch's results are
+    fenced on the host (the device goes idle), host sections stamped into
+    the open gap via :meth:`add`, and :meth:`dispatch` the moment the next
+    program is handed to the device — closing the gap, normalizing
+    attribution so the per-bucket counters sum EXACTLY to the measured
+    gap, and emitting the histogram. The scheduler calls none of these
+    itself: it passes the tracker as the ``observer`` of its spans, and
+    :meth:`span_enter` / :meth:`span_exit` make the calls from the span
+    boundaries. All methods are single-float arithmetic; the tracker is
+    only constructed when the sink is enabled."""
 
-    __slots__ = ("sink", "_open_ts", "_acc", "gaps", "total_gap_s")
+    __slots__ = ("sink", "_open_ts", "_acc", "_open_buckets", "gaps", "total_gap_s")
 
     def __init__(self, sink):
         self.sink = sink
         self._open_ts = None
         self._acc = {b: 0.0 for b in GAP_BUCKETS if b != "other"}
+        self._open_buckets = []  # the bucket spans open now, outermost first
         self.gaps = 0
         self.total_gap_s = 0.0
+
+    def span_enter(self, name, ts):
+        """A scheduler span opened at ``ts``."""
+        if name == "sched/dispatch":
+            self.dispatch(ts)
+        elif name in SPAN_BUCKETS:
+            self._open_buckets.append(name)
+
+    def span_exit(self, name, t0, t1):
+        """The span that opened at ``t0`` closed at ``t1``. A bucket span
+        inside another (the trie probe inside admission) takes its time
+        out of the enclosing one."""
+        if name == "sched/fetch":
+            self.sync_end(t1)
+        elif name in SPAN_BUCKETS:
+            self._open_buckets.pop()
+            outer = self._open_buckets[-1] if self._open_buckets else None
+            self.add(SPAN_BUCKETS[name], t1 - t0, steal_from=outer and SPAN_BUCKETS[outer])
 
     def sync_end(self, ts):
         """Device results just landed on the host: the idle gap opens."""

@@ -47,8 +47,11 @@ Exports:
 
 The sink is rank-0-gated (``jax.process_index() != 0`` disables file output)
 and default-off: with ``telemetry.enabled`` false no files are written and
-producers take the early-return path (the disabled ``span()`` returns one
-shared null object — zero allocation on the hot path). Timestamps come from
+producers take the early-return path. ``span()`` is the exception: it marks
+a ``jax.profiler.TraceAnnotation`` (``dstpu/<name>``) whether the sink is
+enabled or not, so any profiler capture (the benchmark's traced run, the
+operator's ``POST /v1/debug/profile``) holds the program's host intervals on
+the device trace's clock. The sink's own timestamps come from
 ``time.perf_counter`` (monotonic) against a base captured at construction.
 """
 
@@ -204,37 +207,55 @@ def summarize_histogram(name, samples, ts, *, count, total, window_seen,
 
 
 class _Span:
-    """Context manager recording one span into the sink on exit."""
+    """One host interval, marked twice: a ``jax.profiler.TraceAnnotation``
+    (``dstpu/<name>``: a TraceMe, well under a microsecond while no profiler
+    session is open, and on the device trace's clock in any capture) and,
+    when the sink is enabled and ``record`` is set, the sink's span event.
+    ``attrs``, ``flow_out`` and ``record`` may be set inside the block.
+    ``observer`` (a :class:`~.capacity.HostGapTracker`) hears the same two
+    boundaries the records carry."""
 
-    __slots__ = ("_sink", "name", "attrs", "_t0")
+    __slots__ = ("_sink", "name", "attrs", "flow_out", "record", "_observer", "_ann", "_t0")
 
-    def __init__(self, sink, name, attrs):
+    def __init__(self, sink, name, attrs, record, observer):
         self._sink = sink
         self.name = name
         self.attrs = attrs
+        self.flow_out = None
+        self.record = record
+        self._observer = observer
 
     def __enter__(self):
-        self._t0 = self._sink.now()
+        self._ann = _annotation(SPAN_PREFIX + self.name)
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        if self._observer is not None:
+            self._observer.span_enter(self.name, self._t0)
         return self
 
     def __exit__(self, *exc):
-        self._sink.record_span(self.name, self._t0, self._sink.now() - self._t0, self.attrs)
+        t1 = time.perf_counter()
+        if self._observer is not None:
+            self._observer.span_exit(self.name, self._t0, t1)
+        sink = self._sink
+        if self.record and sink.enabled:
+            sink.record_span(self.name, self._t0 - sink._t0, t1 - self._t0, self.attrs,
+                             flow_out=self.flow_out)
+        self._ann.__exit__(*exc)
         return False
 
 
-class _NullSpan:
-    """Reusable no-op span for the disabled path (zero allocation per call)."""
-
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
+# every program span is in a profiler capture under this prefix
+SPAN_PREFIX = "dstpu/"
+_annotation = None
 
 
-_NULL_SPAN = _NullSpan()
+def _load_annotation():
+    """``jax.profiler.TraceAnnotation``, looked up at the first span so that
+    importing the sink (offline tooling) does not import jax."""
+    global _annotation
+    from jax.profiler import TraceAnnotation
+    _annotation = TraceAnnotation
 
 
 class TelemetrySink:
@@ -332,11 +353,16 @@ class TelemetrySink:
         return ent[0]
 
     # ------------------------------------------------------------------ producers
-    def span(self, name, **attrs):
-        """Context manager timing a named span; no-op when disabled."""
-        if not self.enabled:
-            return _NULL_SPAN
-        return _Span(self, name, attrs or None)
+    def span(self, name, record=True, observer=None, **attrs):
+        """The one way the program marks a host interval. Always enters a
+        profiler annotation ``dstpu/<name>``; with the sink enabled (and
+        ``record`` left on) also records the sink's span event. Block
+        level only: a few per scheduler sync, never per token, row or
+        request. ``observer`` hears ``span_enter(name, t0)`` and
+        ``span_exit(name, t0, t1)`` on the ``time.perf_counter`` clock."""
+        if _annotation is None:
+            _load_annotation()
+        return _Span(self, name, attrs or None, record, observer)
 
     def record_span(self, name, start, dur, attrs=None, flow_out=None):
         """Record an already-measured interval (``start``/``dur`` seconds on

@@ -38,7 +38,6 @@ from deepspeed_tpu.comm.overlap import CommOverlapTracker
 from deepspeed_tpu.telemetry import (RequestTrace, SLOEngine, TelemetrySink,
                                      set_sink)
 from deepspeed_tpu.telemetry.prometheus import render as prom_render
-from deepspeed_tpu.telemetry.sink import _NULL_SPAN
 from deepspeed_tpu.telemetry.tracing import extract_trace_context
 
 from .simple_model import SimpleModel, random_batch
@@ -178,8 +177,10 @@ def test_traceparent_parsing():
 # ---------------------------------------------------------------------------
 def test_disabled_sink_hot_path_is_inert(tmp_path):
     sink = TelemetrySink({"enabled": False, "output_path": str(tmp_path / "t")})
-    # span() returns the ONE shared null object: zero allocation per call
-    assert sink.span("a") is _NULL_SPAN and sink.span("b") is _NULL_SPAN
+    # a disabled sink's span only annotates the profiler: the sink records
+    # nothing of it (its own events are checked empty below)
+    with sink.span("a"), sink.span("b", attr=1):
+        pass
     sink.histogram("h", 1.0)
     sink.counter("c", 1)
     sink.event("e")
