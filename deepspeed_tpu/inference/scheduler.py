@@ -567,6 +567,8 @@ class DecodeScheduler:
             self._fused_block = False
             self._fused_block_reasons = [
                 "model family without fused decode-block support"]
+        # step programs built so far, by the K/V commit their trace took
+        self.kv_commit_programs = {"inplace": 0, "scatter": 0}
         self._prefill = None  # at most one in-flight _PrefillState
         # long-context paging: slots whose chained extents are (partly)
         # host-demoted sit in ``_parked`` — excluded from every dispatch
@@ -1824,7 +1826,7 @@ class DecodeScheduler:
         cap = self.capacity
         if cap is None or not self._cap_sample:
             with self._span("sched/dispatch"), self.engine.mesh:
-                return fn(*call_args)
+                return self._run_program(fn, call_args)
         # one fenced dispatch per sampled sync, even across MoE replays
         self._cap_sample = False
         from ..telemetry.capacity import program_shape
@@ -1832,7 +1834,7 @@ class DecodeScheduler:
         jax.block_until_ready(step_args[1])
         t0 = time.perf_counter()
         with self._span("sched/dispatch"), self.engine.mesh:
-            out = fn(*call_args)
+            out = self._run_program(fn, call_args)
         jax.block_until_ready(out)
         dur = time.perf_counter() - t0
         if key is not None:
@@ -1846,6 +1848,22 @@ class DecodeScheduler:
                        if key[0] in ("fused_ext", "fused_seqp") else 1)
             cap.observe_dispatch(key, dur, live_ctx, width, ksteps,
                                  kv_mult=kv_mult)
+        return out
+
+    def _run_program(self, fn, call_args):
+        """Call a step program. A call that traced it (the first at these
+        shapes) built it: count which K/V commit it was built with —
+        ``scatter`` if any layer's span write fell back to the XLA scatter
+        (``models/transformer.py: _commit_span_rows``), so a server whose
+        steps relay the pool says so."""
+        from ..ops.pallas import kv_commit
+        before = kv_commit.traced()
+        out = fn(*call_args)
+        after = kv_commit.traced()
+        if after != before:
+            path = "scatter" if after[1] > before[1] else "inplace"
+            self.kv_commit_programs[path] += 1
+            self.telemetry.counter(f"serving/kv_commit_{path}_programs")
         return out
 
     def _call_step(self, fn, args, lora):

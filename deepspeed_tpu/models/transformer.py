@@ -392,6 +392,39 @@ def _paged_kernel_kw(kv_scale, ext_ops, tp_shard):
     return kw
 
 
+def _commit_span_rows(writes, write_index, q_spans, paged_kernels):
+    """The span write of the slot pool, for :class:`Attention` and
+    ``fused_paged_step`` alike: column ``j`` of row ``i`` lands at position
+    ``write_index_i + j``; columns past the row's live span, and positions
+    past the pool's ``S``, are DROPPED — padding never writes, so retained
+    prefix slots and co-resident decode rows stay byte-stable. ``writes``:
+    ``(pool leaf, fresh rows)`` pairs, K and V first, then an int8 pool's
+    scale leaf (its S axis matches theirs).
+
+    ``paged_kernels``: the caller attends through the paged Pallas kernels
+    on one device. Then K and V commit through the in-place kernel
+    (``ops/pallas/kv_commit.py``), which takes the pool row-major as those
+    kernels do, so the compiler has no layout to convert between; elsewhere
+    (the XLA attention fallback, a tensor-parallel pool, leaves the kernel
+    does not tile, the kilobyte scale leaf) the scatter stays. Both leave
+    the same bytes. The choice is tallied per trace for the scheduler's
+    ``serving/kv_commit_*_programs`` counters."""
+    from ..ops.pallas import kv_commit
+    (ck, k), (cv, v) = writes[:2]
+    in_place = (paged_kernels and _tp_mesh_size() == 1
+                and kv_commit.commits_in_place(ck) and cv.shape == ck.shape)
+    kv_commit.tally(in_place)
+    T = k.shape[2]
+    tgt = write_index[:, None] + jnp.arange(T)[None, :]
+    tgt = jnp.where(jnp.arange(T)[None, :] < q_spans[:, None], tgt, ck.shape[2])
+    upd = lambda c, kk, i: c.at[:, i, :].set(kk.astype(c.dtype), mode="drop")
+    with jax.named_scope("kv_commit"):
+        written = list(kv_commit.commit_kv_rows(
+            (ck, cv), (k, v), write_index, q_spans)) if in_place else []
+        written += [jax.vmap(upd)(c, kk, tgt) for c, kk in writes[len(written):]]
+    return written
+
+
 def _tp_replicate(x):
     """Re-replicate a tensor-sharded activation (bitwise-TP serving layout):
     the constraint lowers to an all-gather over ``tensor`` — pure
@@ -797,18 +830,13 @@ class Attention(nn.Module):
                         for c, kk in writes]
                 cache_index = write_index
             elif write_index is not None and q_spans is not None:
-                # fused chunk/decode span write: column j of row i lands at
-                # row position write_index_i + j; columns past the row's live
-                # span target row S (out of range) and are DROPPED — padding
-                # never writes, so retained prefix slots and co-resident
-                # decode rows in the pool stay byte-stable. The scale leaves
-                # share the tgt row indices (their S axis matches the KV S).
-                tgt = write_index[:, None] + jnp.arange(T)[None, :]
-                tgt = jnp.where(jnp.arange(T)[None, :] < q_spans[:, None], tgt,
-                                ck.shape[2])
-                upd = lambda c, kk, i: c.at[:, i, :].set(kk.astype(c.dtype), mode="drop")
-                with jax.named_scope("kv_commit"):
-                    written = [jax.vmap(upd)(c, kk, tgt) for c, kk in writes]
+                # fused chunk/decode span write, in place where the paged
+                # kernels below attend (the same conditions as their
+                # branches, on one device)
+                written = _commit_span_rows(
+                    writes, write_index, q_spans,
+                    paged_kernels=(cfg.attention_impl == "flash" and alibi is None
+                                   and not seq_shard and (T == 1 or not window)))
                 cache_index = write_index  # per-row causal window below
             elif write_index is not None:
                 # slot-pool decode: each row appends at its own position
@@ -1559,10 +1587,10 @@ class CausalLMModel:
         logits. Three resident kernels per layer instead of the
         per-projection path's ~9+ XLA-glued dispatches.
 
-        The KV commit and paged-attention dispatch mirror
-        :class:`Attention`'s span-write path LINE FOR LINE (same ``tgt``
-        row drop, same ``paged_decode_attention`` for C == 1 /
-        ``paged_span_attention`` for C > 1, same int8-KV quantize) so the
+        The KV commit is :class:`Attention`'s own (``_commit_span_rows``)
+        and the paged-attention dispatch mirrors its span path (same
+        ``paged_decode_attention`` for C == 1 / ``paged_span_attention``
+        for C > 1, same int8-KV quantize) so the
         pool stays byte-compatible with the unfused programs — prefill,
         copy_slot, and tier restore interoperate with fused decode on the
         same pool. Only eligible configs reach here (engine
@@ -1591,7 +1619,6 @@ class CausalLMModel:
         if quant_kv:
             from ..ops.quantizer import quantize_kv_rows
         starts = jnp.zeros((N, ), jnp.int32)
-        col = jnp.arange(C)[None, :]
         new_layers = []
         for i, (norms, qkv, o, up, down, gate) in enumerate(layers):
             layer_cache = tuple(comp[i] for comp in kv_cache)
@@ -1610,15 +1637,10 @@ class CausalLMModel:
                 writes = [(ck, kq), (cv, vq), (csc, sc_new)]
             else:
                 writes = [(ck, k), (cv, v)]
-            # span commit, identical to Attention's: column j of row i lands
-            # at write_index_i + j; columns past the live span target row S
-            # (out of range) and are DROPPED
-            tgt = write_index[:, None] + col
-            tgt = jnp.where(col < q_spans[:, None], tgt, ck.shape[2])
-            upd = lambda c, kk, t_: c.at[:, t_, :].set(kk.astype(c.dtype),
-                                                       mode="drop")
-            with jax.named_scope("kv_commit"):
-                written = [jax.vmap(upd)(c, kk, tgt) for c, kk in writes]
+            # Attention's span commit; this path attends through the paged
+            # kernels unconditionally
+            written = _commit_span_rows(writes, write_index, q_spans,
+                                        paged_kernels=True)
             if quant_kv:
                 ck, cv, csc = written
             else:

@@ -1,0 +1,189 @@
+"""In-place commit of fresh K/V rows into the slot-paged serving pool (TPU).
+
+The serving step writes each slot's new keys and values at that slot's own
+write head: column ``j`` of row ``i`` lands at position ``write_index[i] +
+j`` when ``j < q_spans[i]`` and the position lies inside the pool; every
+other byte of the pool stays as it was. As an XLA scatter
+(``c.at[:, t, :].set(..., mode="drop")`` under ``vmap``) that write wants
+the pool in a layout of its own, and the paged attention kernels want it
+row-major, so the compiler relays the whole pool around every commit. This
+kernel aliases the pool (``input_output_aliases``) and takes it row-major,
+as the attention kernels do, so a step's loop carries one layout and moves
+only the row blocks under the write heads.
+
+Kernel shape: one call for a layer's K and V, grid ``(slots, kv-head
+blocks, row blocks)``. ``write_index`` and ``q_spans`` are scalar-prefetch
+operands; the index map turns them into the row block (one sublane tile: 8
+rows of f32, 16 of bf16, 32 of int8) that a step reads, patches under a
+mask on the absolute position, and writes back. A span of ``C`` columns
+can straddle ``(C - 2) // rows + 2`` blocks; steps past a slot's last live
+block hold that block's index, so nothing more is copied and the block gets
+the same bytes again. A slot with span 0 rewrites one block unchanged.
+
+For ``C > 1`` the fresh rows are shifted to the block's rows through an f32
+staging buffer (an unaligned dynamic slice of sublanes is a 32-bit
+operation on the chip); bf16 and int8 values pass through f32 unchanged.
+"""
+
+import functools
+import threading
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .. import pallas as _pallas
+
+
+# Span commits traced by this thread, (in place, scatter): a scheduler reads
+# it around a dispatch to learn which commit a program was built with.
+_traced = threading.local()
+
+
+def tally(in_place):
+    """Note one traced span commit and the path it took."""
+    n = traced()
+    _traced.counts = (n[0] + 1, n[1]) if in_place else (n[0], n[1] + 1)
+
+
+def traced():
+    return getattr(_traced, "counts", (0, 0))
+
+
+def _pad(n, m):
+    return -(-n // m) * m
+
+
+def block_rows(dtype):
+    """Rows of one sublane tile of ``dtype``: the row block a commit moves."""
+    return 8 * (4 // jnp.dtype(dtype).itemsize)
+
+
+def commits_in_place(leaf):
+    """Whether a pool leaf is one this kernel writes: ``(N, nkv, S, hd)``
+    with ``S`` a whole number of row blocks."""
+    if leaf.ndim != 4 or jnp.dtype(leaf.dtype).itemsize not in (1, 2, 4):
+        return False
+    return leaf.shape[2] % block_rows(leaf.dtype) == 0
+
+
+def _vmem_estimate(bh, rows, C, hd, itemsize):
+    """VMEM bytes of one grid step, K and V together, counted as Mosaic lays
+    blocks out (the last dimension pads to 128 lanes, pipelined operands are
+    double-buffered): pool blocks in and out, the fresh rows, the f32
+    staging buffers of a span, and about two f32 copies of a block."""
+    hdp = _pad(hd, 128)
+    io = 2 * 2 * 2 * bh * rows * hdp * itemsize
+    io += 2 * 2 * bh * _pad(C, rows) * hdp * itemsize
+    stage = 2 * bh * (C + 2 * rows) * hdp * 4 if C > 1 else 0
+    return io + stage + 2 * 2 * bh * rows * hdp * 4
+
+
+def _row_block(wi, span, j, rows, n_blocks):
+    """The pool row block that step ``j`` of a slot works on: the block
+    under the write head and the ones after it, held at the last block with
+    a live target, and inside the pool (a head at or past ``S`` writes
+    nothing, whichever block it is given)."""
+    last = (wi + jnp.maximum(span, 1) - 1) // rows
+    return jnp.clip(jnp.minimum(wi // rows + j, last), 0, n_blocks - 1)
+
+
+def _commit_kernel(wi_ref, span_ref, k_new_ref, v_new_ref, k_pool_ref,
+                   v_pool_ref, k_out_ref, v_out_ref, *stages, rows, cols,
+                   n_blocks):
+    i = pl.program_id(0)
+    j = pl.program_id(2)
+    wi = wi_ref[i]
+    span = span_ref[i]
+    base = _row_block(wi, span, j, rows, n_blocks) * rows
+    pos = base + jax.lax.broadcasted_iota(jnp.int32, (1, rows, 1), 1)
+    live = (pos >= wi) & (pos < wi + span)
+    # stage row t holds fresh column t - rows, so block row r (position
+    # base + r, column base + r - wi) is stage row off + r; the rows beside
+    # the columns are never selected
+    off = jnp.clip(base - wi + rows, 0, cols + rows)
+    for new_ref, pool_ref, out_ref, stage in zip(
+            (k_new_ref, v_new_ref), (k_pool_ref, v_pool_ref),
+            (k_out_ref, v_out_ref), stages or (None, None)):
+        if cols == 1:
+            fresh = new_ref[0]  # (bh, 1, hd): one row for every position
+        else:
+            @pl.when(j == 0)
+            def _stage(new_ref=new_ref, stage=stage):
+                stage[:, rows:rows + cols, :] = new_ref[0].astype(jnp.float32)
+
+            fresh = stage[:, pl.ds(off, rows), :]
+            if jnp.issubdtype(out_ref.dtype, jnp.integer):
+                fresh = fresh.astype(jnp.int32)
+            fresh = fresh.astype(out_ref.dtype)
+        out_ref[0] = jnp.where(live, fresh, pool_ref[0])
+
+
+def commit_kv_rows(leaves, new_rows, write_index, q_spans):
+    """Write a layer's fresh K and V rows into its pool leaves, in place.
+
+    ``leaves``: the layer's ``(k_pool, v_pool)``, each ``(N, nkv, S, hd)``;
+    ``new_rows``: ``(k, v)``, each ``(N, nkv, C, hd)`` (cast to the pool's
+    dtype here); ``write_index``, ``q_spans``: ``(N,)`` int32, heads
+    non-negative. Row ``i``'s column ``j`` lands at ``write_index[i] + j``
+    when ``j < q_spans[i]`` and that position is ``< S`` — the pool the
+    ``mode="drop"`` scatter leaves, byte for byte. Returns the new
+    ``(k_pool, v_pool)``; the operands are aliased to them, so inside a
+    program that donates or carries the pool nothing else of it moves.
+
+    Jitted, so that the layers of a step program (and its first forward
+    and loop body) share one trace and one lowering of the kernel: lowering
+    a Mosaic call is a tenth of a second, 36 to 72 times a program."""
+    return _commit(leaves, new_rows, write_index, q_spans,
+                   interpret=_pallas.interpret())
+
+
+@functools.partial(jax.jit, static_argnames="interpret")
+def _commit(leaves, new_rows, write_index, q_spans, *, interpret):
+    k_pool, v_pool = leaves
+    k_new, v_new = (x.astype(k_pool.dtype) for x in new_rows)
+    N, nkv, S, hd = k_pool.shape
+    C = k_new.shape[2]
+    rows = block_rows(k_pool.dtype)
+    itemsize = jnp.dtype(k_pool.dtype).itemsize
+    if not commits_in_place(k_pool) or v_pool.shape != k_pool.shape:
+        raise ValueError(f"kv commit: pool leaves {k_pool.shape}/{v_pool.shape} "
+                         f"are not whole {rows}-row blocks of one shape")
+    bh = next((b for b in range(nkv, 0, -1) if nkv % b == 0
+               and _pallas.fits_vmem(_vmem_estimate(b, rows, C, hd, itemsize))), None)
+    if bh is None:
+        raise ValueError(
+            f"kv commit: one kv head x {C} columns of width {hd} needs "
+            f"{_vmem_estimate(1, rows, C, hd, itemsize)} bytes of VMEM, over the "
+            f"{_pallas.VMEM_BLOCK_BUDGET}-byte budget; narrow the query span "
+            f"(prefill_chunk)")
+    n_blocks = S // rows
+    steps = (C - 2) // rows + 2  # row blocks a span of C can straddle
+
+    def pool_index(i, h, j, wi_r, span_r):
+        return (i, h, _row_block(wi_r[i], span_r[i], j, rows, n_blocks), 0)
+
+    new_spec = pl.BlockSpec((1, bh, C, hd), lambda i, h, j, *_: (i, h, 0, 0))
+    pool_spec = pl.BlockSpec((1, bh, rows, hd), pool_index)
+    stage = pltpu.VMEM((bh, C + 2 * rows, hd), jnp.float32)
+    pool_shape = jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype)
+    return tuple(pl.pallas_call(
+        functools.partial(_commit_kernel, rows=rows, cols=C, n_blocks=n_blocks),
+        name="dstpu_kv_commit",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(N, nkv // bh, steps),
+            in_specs=[new_spec, new_spec, pool_spec, pool_spec],
+            out_specs=[pool_spec, pool_spec],
+            scratch_shapes=[stage, stage] if C > 1 else [],
+        ),
+        out_shape=[pool_shape, pool_shape],
+        # operand numbers count the two scalar-prefetch operands
+        input_output_aliases={4: 0, 5: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_pallas.VMEM_LIMIT_BYTES),
+        interpret=interpret,
+    )(write_index.astype(jnp.int32), q_spans.astype(jnp.int32),
+      k_new, v_new, k_pool, v_pool))

@@ -1399,7 +1399,11 @@ class Gateway:
                           "fused_decode_block": getattr(
                               sched, "_fused_block", False),
                           "fused_decode_reasons": list(getattr(
-                              sched, "_fused_block_reasons", ()))},
+                              sched, "_fused_block_reasons", ())),
+                          # step programs by the K/V commit they were built
+                          # with: "scatter" ones relay the pool every step
+                          "kv_commit_programs": dict(getattr(
+                              sched, "kv_commit_programs", {}))},
             "adapters": (sched.adapters.stats()
                          if sched.adapters is not None else None),
             "expert_store": (sched.experts.stats()

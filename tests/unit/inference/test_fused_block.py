@@ -121,6 +121,56 @@ def test_fused_paged_step_parity_matrix(shape):
             assert cache_err < 1e-4, tag
 
 
+@pytest.mark.parametrize("quant_kv", [False, True], ids=["f32kv", "int8kv"])
+def test_mixed_sync_leaves_one_pool(quant_kv, monkeypatch):
+    """One chunk row, decode rows and a dead row in a sync, on a pool that
+    already holds other requests' rows: the per-projection path and the
+    fused path share the span commit and leave byte-identical pools — the
+    bytes the XLA scatter would leave — so prefill, ``copy_slot``, the radix
+    cache and the tier restore read the same pool after either."""
+    from deepspeed_tpu.models import transformer
+    qmodel, qparams = _quantized_model(**_SHAPES["llama"])
+    N, S, C = 4, 64, 4
+    rng = np.random.RandomState(1)
+    pool = jax.tree_util.tree_map(
+        lambda a: jnp.asarray(rng.randint(-100, 100, a.shape), a.dtype),
+        qmodel.init_cache(N, S, quantized=quant_kv))
+    ids = jnp.asarray(rng.randint(0, qmodel.cfg.vocab_size, (N, C)), jnp.int32)
+    lengths = jnp.asarray([6, 15, 31, 9], jnp.int32)
+    spans = jnp.asarray([C, 1, 1, 0], jnp.int32)
+    pos = lengths[:, None] + jnp.arange(C)[None, :]
+
+    def both():
+        _, per_proj = qmodel.apply_with_cache(
+            qparams, ids, pool, 0, position_ids=pos, write_index=lengths,
+            q_spans=spans)
+        _, fused = qmodel.fused_paged_step(qparams, ids, pool, pos, lengths, spans)
+        return jax.tree_util.tree_leaves((per_proj, fused))
+
+    in_place = both()
+    paged = transformer._commit_span_rows  # then every leaf through the scatter
+    monkeypatch.setattr(transformer, "_commit_span_rows",
+                        lambda w, wi, sp, paged_kernels: paged(w, wi, sp, False))
+    scattered = both()
+    half = len(in_place) // 2
+    as_bytes = lambda a: np.asarray(a).view(np.uint8)
+    for got, ref in zip(in_place, scattered):
+        np.testing.assert_array_equal(as_bytes(got), as_bytes(ref))
+    for per_proj, fused, before in zip(in_place[:half], in_place[half:],
+                                       jax.tree_util.tree_leaves(pool)):
+        np.testing.assert_array_equal(as_bytes(fused)[3], as_bytes(before)[3])  # dead row
+        assert (as_bytes(fused) != as_bytes(before)).any()
+        if not quant_kv:  # int8 rows may round a last-bit difference apart
+            np.testing.assert_allclose(np.asarray(fused), np.asarray(per_proj),
+                                       atol=1e-4)
+        untouched = np.ones((N, S), bool)
+        for i, (at, n) in enumerate(zip(np.asarray(lengths), np.asarray(spans))):
+            untouched[i, at:at + n] = False
+        np.testing.assert_array_equal(
+            as_bytes(np.moveaxis(np.asarray(fused), 2, 1)[untouched]),
+            as_bytes(np.moveaxis(np.asarray(before), 2, 1)[untouched]))
+
+
 # ----------------------------------------------------- scheduler-level parity
 def test_scheduler_fused_block_matches_per_projection(baseline):
     """Greedy AND seeded-sampled streams through the retagged
@@ -161,6 +211,26 @@ def test_scheduler_fused_block_matches_per_projection(baseline):
     kinds_off = {k[0] for k in sched_off._compiled if isinstance(k, tuple)}
     assert "fused_block" in kinds_on and "fused" not in kinds_on
     assert "fused" in kinds_off and "fused_block" not in kinds_off
+
+
+def test_commit_path_counter(baseline, tmp_path):
+    """``serving/kv_commit_*_programs``: a fused program on one device is
+    built with the in-place commit, a tensor-parallel one with the scatter."""
+    params, _ = baseline
+    eng = make_fused_engine(params, telemetry={"enabled": True,
+                                               "output_path": str(tmp_path)})
+    sched = eng.scheduler()
+    sched.submit(PROMPTS[0], max_new_tokens=3).result()
+    built = eng.telemetry.counter_total("serving/kv_commit_inplace_programs")
+    assert built == sched.kv_commit_programs["inplace"] > 0
+    assert sched.kv_commit_programs["scatter"] == 0
+    assert eng.telemetry.counter_total("serving/kv_commit_scatter_programs") == 0
+    eng_tp = make_engine(params=params, tensor_parallel={"tp_size": 2},
+                         continuous_batching={"enabled": True, "num_slots": 4})
+    sched_tp = eng_tp.scheduler()
+    sched_tp.submit(PROMPTS[0], max_new_tokens=3).result()
+    assert sched_tp.kv_commit_programs == {
+        "inplace": 0, "scatter": len(sched_tp._compiled) - ("copy" in sched_tp._compiled)}
 
 
 def test_scheduler_fused_block_spec_lossless(baseline):

@@ -1,0 +1,96 @@
+"""The in-place K/V commit kernel (``ops/pallas/kv_commit.py``, interpret
+mode) against the ``vmap`` scatter it stands in for: the whole pool, bit
+for bit."""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from deepspeed_tpu.ops.pallas import kv_commit
+
+N, NKV, S, HD = 6, 3, 128, 64
+
+
+def _scatter(pools, fresh, write_index, q_spans):
+    """The span write where the kernel is not taken: the model's own scatter."""
+    from deepspeed_tpu.models.transformer import _commit_span_rows
+    return _commit_span_rows(list(zip(pools, fresh)), write_index, q_spans,
+                             paged_kernels=False)
+
+
+def _cases(C, rows):
+    """(write heads, spans) per named case; six slots each, the untouched
+    ones (span 0) among them."""
+    full = [C] * N
+    return {
+        "span0": ([0, rows, 5, S - 1, 40, 7], [0] * N),
+        "span1": ([0, rows - 1, rows, S - 1, 2 * rows + 3, 7], [1, 1, 1, 1, 0, 1]),
+        "partial": ([3, rows - 1, rows, 40, 2 * rows + 1, 0],
+                    [max(C // 2, 1), min(C, 5), 0, min(C, rows + 1), 1, C - 1]),
+        "full": ([0, rows - 1, rows, 33, 2 * rows - 2, 1], full),
+        "block_first_row": ([0, rows, 2 * rows, 3 * rows, 0, rows], full),
+        "block_last_row": ([rows - 1, 2 * rows - 1, 3 * rows - 1, rows - 1, S - 1, 0],
+                           [C, C, C, 1, 1, 0]),
+        "straddle": ([rows - 2, 2 * rows - 1, 3 * rows - 3, rows - 1, 0, 5],
+                     [min(C, 4), min(C, 2), min(C, 6), C, 0, C]),
+        "past_end": ([S - 1, S - 2, S - rows, S - 1, S, S + 7],
+                     [C, C, C, 0, C, min(C, 3)]),
+    }
+
+
+@pytest.mark.parametrize("case", ["span0", "span1", "partial", "full",
+                                  "block_first_row", "block_last_row",
+                                  "straddle", "past_end"])
+@pytest.mark.parametrize("C", [1, 64])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.int8], ids=["bf16", "int8"])
+def test_commit_matches_scatter(dtype, C, case):
+    rows = kv_commit.block_rows(dtype)
+    rng = np.random.default_rng(C + rows)
+    if dtype == jnp.int8:
+        make = lambda *shape: jnp.asarray(rng.integers(-128, 128, shape), jnp.int8)
+    else:
+        make = lambda *shape: jnp.asarray(rng.standard_normal(shape), dtype)
+    pools = make(N, NKV, S, HD), make(N, NKV, S, HD)
+    fresh = make(N, NKV, C, HD), make(N, NKV, C, HD)
+    heads, spans = (jnp.asarray(x, jnp.int32) for x in _cases(C, rows)[case])
+    got = jax.jit(kv_commit.commit_kv_rows)(pools, fresh, heads, spans)
+    for pool, out, want in zip(pools, got, _scatter(pools, fresh, heads, spans)):
+        np.testing.assert_array_equal(np.asarray(out.view(jnp.uint8)),
+                                      np.asarray(want.view(jnp.uint8)))
+        idle = np.asarray(spans) == 0
+        np.testing.assert_array_equal(np.asarray(out.view(jnp.uint8))[idle],
+                                      np.asarray(pool.view(jnp.uint8))[idle])
+
+
+def test_commit_splits_heads_to_fit_vmem(monkeypatch):
+    """A head block is chosen against the VMEM budget; a smaller budget gives
+    more grid steps and the same pool."""
+    import deepspeed_tpu.ops.pallas as pallas_pkg
+    rng = np.random.default_rng(0)
+    make = lambda *shape: jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+    pools = make(2, 4, 64, 64), make(2, 4, 64, 64)
+    fresh = make(2, 4, 8, 64), make(2, 4, 8, 64)
+    heads, spans = jnp.asarray([15, 60], jnp.int32), jnp.asarray([8, 8], jnp.int32)
+    commit = functools.partial(kv_commit._commit.__wrapped__, interpret=True)
+    whole = commit(pools, fresh, heads, spans)
+    one_head = kv_commit._vmem_estimate(1, 16, 8, 64, 2)
+    monkeypatch.setattr(pallas_pkg, "VMEM_BLOCK_BUDGET", one_head)
+    split = commit(pools, fresh, heads, spans)
+    for a, b in zip(whole, split):
+        np.testing.assert_array_equal(np.asarray(a.view(jnp.uint8)),
+                                      np.asarray(b.view(jnp.uint8)))
+    monkeypatch.setattr(pallas_pkg, "VMEM_BLOCK_BUDGET", one_head - 1)
+    with pytest.raises(ValueError, match="VMEM"):
+        commit(pools, fresh, heads, spans)
+
+
+def test_commits_in_place_reads_the_leaf():
+    sds = jax.ShapeDtypeStruct
+    assert kv_commit.commits_in_place(sds((4, 2, 64, 64), jnp.bfloat16))
+    assert kv_commit.commits_in_place(sds((4, 2, 64, 64), jnp.int8))
+    assert not kv_commit.commits_in_place(sds((4, 2, 48, 64), jnp.int8))   # 32-row blocks
+    assert not kv_commit.commits_in_place(sds((4, 2, 20, 64), jnp.float32))
+    assert not kv_commit.commits_in_place(sds((4, 64, 1), jnp.float16))

@@ -14,6 +14,7 @@ test has started.
 
 import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -140,6 +141,20 @@ def test_decode_attention(for_chip, name, variant):
             qT, kv, kv, rows, rows, sds((SLOTS, 2), jnp.int32))
 
 
+@pytest.mark.parametrize("cols", [1, CHUNK], ids=["decode", "span"])
+@pytest.mark.parametrize("int8", [False, True], ids=["bf16kv", "int8kv"])
+@pytest.mark.parametrize("name", MODELS)
+def test_kv_commit(for_chip, name, int8, cols):
+    from deepspeed_tpu.ops.pallas.kv_commit import commit_kv_rows
+    sds, compile_ = for_chip
+    cfg = get_model(name).cfg
+    kv, _ = _pool(sds, cfg, int8)
+    fresh = sds((SLOTS, cfg.kv_heads, cols, cfg.head_size), kv.dtype)
+    rows = sds((SLOTS, ), jnp.int32)
+    text = compile_(commit_kv_rows, (kv, kv), (fresh, fresh), rows, rows)
+    assert "dstpu_kv_commit" in text
+
+
 # ------------------------------------------------------- fused decode blocks
 def _layer_operands(name):
     """Shapes of the operand tuples the engines hand the fused kernels:
@@ -189,6 +204,73 @@ def test_scheduler_step_program(for_chip, name, step):
     ids = sds((SLOTS, cols), jnp.int32)
     rows = sds((SLOTS, ), jnp.int32)
     compile_(model.fused_paged_step, params, ids, pool, ids, rows, rows)
+
+
+def _pool_relayouts(text, shape):
+    """Instructions of a compiled program that move a whole pool leaf
+    (``copy``, ``scatter`` or ``transpose`` with a ``shape`` result, fused
+    or not): (inside the ``while`` bodies and what they call, elsewhere)."""
+    comps, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?(%[\w.\-]+) \(.*\{$", line)
+        if head:
+            name = head.group(1)
+            comps[name] = []
+        elif name is not None:
+            comps[name].append(line)
+    called = {n: set(re.findall(r"(?:calls|to_apply|body|condition)=(%[\w.\-]+)",
+                                "\n".join(lines))) for n, lines in comps.items()}
+    inside = set(re.findall(r"body=(%[\w.\-]+)", text))
+    todo = list(inside)
+    while todo:
+        new = called[todo.pop()] - inside
+        inside |= new
+        todo += new
+    moves = re.compile(r"= \w+" + re.escape(shape) + r"\S* (copy|scatter|transpose)\(")
+    count = lambda names: sum(bool(moves.search(l)) for n in names for l in comps[n])
+    return count(inside), count(set(comps) - inside)
+
+
+@pytest.mark.parametrize("name", MODELS)
+def test_step_loop_carries_the_pool_in_one_layout(for_chip, name):
+    """A sync as ``DecodeScheduler._fused_fn`` builds it, one layer deep: a
+    first forward, then a ``fori_loop`` of one-column steps on the donated
+    pool. The commit kernel and the attention kernels both take the pool
+    row-major, so the loop body holds no operation that moves a whole leaf,
+    and the entry at most one relayout in and one out per leaf (head size
+    64; none at head size 128, whose row-major form is also the resting
+    one). The scatter this replaced put four in the body and eight around
+    it."""
+    sds, _ = for_chip
+    model = _serving_model(name)
+    cfg = model.cfg
+
+    def sync(params, pool, ids, lengths, spans):
+        pos = lengths[:, None] + jnp.arange(ids.shape[1])[None, :]
+        logits, pool = model.fused_paged_step(params, ids, pool, pos, lengths, spans)
+        base = lengths + jnp.maximum(spans, 1) - 1
+        live = jnp.minimum(spans, 1)
+
+        def body(k, carry):
+            pool, tok = carry
+            lg, pool = model.fused_paged_step(
+                params, tok[:, None], pool, (base + k)[:, None], base + k, live)
+            return pool, jnp.argmax(lg[:, 0], -1).astype(jnp.int32)
+
+        return jax.lax.fori_loop(
+            1, 3, body, (pool, jnp.argmax(logits[:, 0], -1).astype(jnp.int32)))
+
+    shaped = lambda tree: jax.tree_util.tree_map(lambda a: sds(a.shape, a.dtype), tree)
+    params = shaped(jax.eval_shape(model.init_params, jax.random.key(0)))
+    pool = shaped(jax.eval_shape(lambda: model.init_cache(SLOTS, POOL_LEN)))
+    rows = sds((SLOTS, ), jnp.int32)
+    text = jax.jit(sync, donate_argnums=(1, )).lower(
+        params, pool, sds((SLOTS, CHUNK), jnp.int32), rows, rows).compile().as_text()
+    assert "dstpu_kv_commit" in text and " while(" in text
+    leaf = f"[{SLOTS},{cfg.kv_heads},{POOL_LEN},{cfg.head_size}]"
+    in_loop, around = _pool_relayouts(text, leaf)
+    assert in_loop == 0
+    assert around <= 2 * len(jax.tree_util.tree_leaves(pool))
 
 
 def test_generate_step_program(for_chip):
