@@ -198,6 +198,10 @@ class InferenceEngine:
         elif self._int8_weights:
             raise ValueError(f"dtype=int8 requires a model with int8 weight support "
                              f"(CausalLMModel family); got {type(model)}")
+        if getattr(model.cfg, "latent_width", 0) and (self._int8_weights or tp_eff > 1):
+            raise ValueError(
+                "latent attention is served in its float dtype on an unsharded pool: "
+                "int8 weights and a tensor mesh axis > 1 are not supported yet")
         if cfg.kernel_inject and hasattr(model.cfg, "scan_layers"):
             overrides["attention_impl"] = "flash"
             # unrolled layers: the KV cache becomes per-layer tensors that
@@ -415,6 +419,14 @@ class InferenceEngine:
             params if params is not None else jax.eval_shape(self.module.init_params, jax.random.key(0))))
         dtype = self.model_config.dtype
         if params is not None:
+            leaves, placed = jax.tree_util.tree_leaves(params), jax.tree_util.tree_leaves(shardings)
+            if all(isinstance(x, jax.Array) and x.dtype == dtype
+                   and x.sharding.is_equivalent_to(sh, x.ndim)
+                   for x, sh in zip(leaves, placed)):
+                # already what the cast would return (a model made on the
+                # device in its serving dtype): a jitted identity would hold
+                # a second copy of the weights while it runs
+                return params
             cast = jax.jit(lambda p: jax.tree_util.tree_map(lambda x: jnp.asarray(x, dtype), p),
                            out_shardings=shardings)
             with self.mesh:

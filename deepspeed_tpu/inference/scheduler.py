@@ -233,7 +233,7 @@ class _Request:
                  "temperature", "top_k", "top_p", "seed", "slot", "out", "logits",
                  "done", "cancelled", "submit_ts", "first_token_ts", "collect_logits",
                  "on_token", "trace", "adapter_id", "adapter_ref", "handle",
-                 "migrating", "error", "kv_window", "row_budget")
+                 "migrating", "error", "kv_window", "row_budget", "choice")
 
     def __init__(self, rid, prompt, max_new_tokens, eos_token_id, do_sample,
                  temperature, top_k, top_p, seed, collect_logits, submit_ts,
@@ -253,6 +253,7 @@ class _Request:
         self.slot = None
         self.out = []      # generated token ids (host ints)
         self.logits = []   # per-step (V,) logits when collect_logits
+        self.choice = []   # (L, columns, k) expert ids per forward (MoE, collect_logits)
         self.done = False
         self.cancelled = False
         self.submit_ts = submit_ts
@@ -325,6 +326,17 @@ class SchedulerHandle:
             return np.stack(self._req.logits)
         V = self._sched.engine.model_config.vocab_size
         return np.zeros((0, V), np.float32)
+
+    def result_choice(self):
+        """(L, T, k) expert ids every layer's router chose at the positions
+        the step programs ran for this request, in order: its prompt, then
+        each token it was fed (``collect_logits`` on an MoE model; positions
+        a prefix hit copied were not run). What a reference follows where
+        routing is a near tie."""
+        self.result()
+        if not self._req.choice:
+            raise ValueError("no routing choice was collected for this request")
+        return np.concatenate(self._req.choice, axis=1)
 
 
 class _PrefillState:
@@ -478,6 +490,20 @@ class DecodeScheduler:
                                  f"dtype name, got {kv_cache_dtype!r}")
             kv_arg = _DTYPE_MAP[kvd]
         self.kv_quantized = kv_arg == "int8"
+        if getattr(model.cfg, "latent_width", 0):
+            # the latent pool (one leaf a layer, see CausalLMModel.init_cache)
+            # is served as it is or not at all
+            unsupported = [name for name, on in (
+                ("an int8 KV pool (kv_cache_dtype)", self.kv_quantized),
+                ("extent chains (max_extents > 1)", int(max_extents) > 1),
+                ("sequence-parallel prefill", bool(self._seq_chunk)),
+                ("lossy KV windows", self.allow_lossy_kv),
+                ("a tensor-parallel pool", tp_ax > 1),
+                ("tier demotion (prefix_store)", prefix_store is not None),
+                ("adapters (adapter_store)", adapter_store is not None)) if on]
+            if unsupported:
+                raise ValueError("the latent KV pool does not support "
+                                 + ", ".join(unsupported) + " yet")
         self.cache = SlotKVCache(engine._init_cache(int(num_slots), S, kv_dtype=kv_arg),
                                  int(num_slots), S, page_size=min(block, S),
                                  max_extents=me)
@@ -569,6 +595,8 @@ class DecodeScheduler:
                 "model family without fused decode-block support"]
         # step programs built so far, by the K/V commit their trace took
         self.kv_commit_programs = {"inplace": 0, "scatter": 0}
+        # ... and, for MoE models, by the expert dispatch it took
+        self.moe_dispatch_programs = {"sparse": 0, "dense": 0}
         self._prefill = None  # at most one in-flight _PrefillState
         # long-context paging: slots whose chained extents are (partly)
         # host-demoted sit in ``_parked`` — excluded from every dispatch
@@ -618,6 +646,7 @@ class DecodeScheduler:
         self._shard_deg = max(self.tp_size, self.ep_size)
         self._rid = 0
         self._steps = 0
+        self._choice = None  # the last fetched block's routing choice (MoE, collecting)
         # weight-swap protocol (RLHF hybrid engine): pause gates ADMISSION
         # only — in-flight rows keep decoding under the weights that
         # prefilled them until flush() drains the pool
@@ -1784,15 +1813,23 @@ class DecodeScheduler:
         # the device_get is the sync fence: when sched/fetch closes the
         # device is idle, until the next sched/dispatch opens
         with self._span("sched/fetch"):
-            if collect:
-                self.cache.pool, toks_k, logits_k = out
-                logits_k = np.asarray(jax.device_get(logits_k), np.float32)  # (K, N, V)
-            else:
-                self.cache.pool, toks_k = out
-                logits_k = None
+            self.cache.pool, toks_k, *rest = out
+            # (K, N, V); MoE programs add their routing choice behind it
+            logits_k = np.asarray(jax.device_get(rest[0]), np.float32) if collect else None
+            self._choice = (tuple(np.asarray(x) for x in jax.device_get(rest[1:]))
+                            if len(rest) == 3 else None)
             toks_k = np.asarray(jax.device_get(toks_k)).reshape(K, self.cache.num_slots)
         self._steps += K
         return toks_k, logits_k
+
+    def _keep_choice(self, req, slot, width, K):
+        """Keep, for a request that collects logits, the expert ids the block
+        just fetched chose in its row: ``width`` columns of the first
+        forward, then one column per substep."""
+        if self._choice is not None and req.collect_logits:
+            first, substeps = self._choice  # (L, N, C, k), (K, L, N, k)
+            req.choice.append(first[:, slot, :width])
+            req.choice.extend(substeps[j][:, slot][:, None] for j in range(1, K))
 
     def _deliver_block(self, live, toks_k, logits_k, K):
         """Deliver a fetched K-step token block to the live rows. Each row's
@@ -1803,6 +1840,7 @@ class DecodeScheduler:
         with self._span("sched/deliver"):
             for slot, req in live:
                 self.cache.lengths[slot] += K
+                self._keep_choice(req, slot, 1, K)
                 for k in range(K):
                     if req.done:
                         break
@@ -1856,14 +1894,20 @@ class DecodeScheduler:
         ``scatter`` if any layer's span write fell back to the XLA scatter
         (``models/transformer.py: _commit_span_rows``), so a server whose
         steps relay the pool says so."""
+        from ..moe.layer import traced_dispatches
         from ..ops.pallas import kv_commit
-        before = kv_commit.traced()
+        before, moe_before = kv_commit.traced(), traced_dispatches()
         out = fn(*call_args)
-        after = kv_commit.traced()
+        after, moe_after = kv_commit.traced(), traced_dispatches()
         if after != before:
             path = "scatter" if after[1] > before[1] else "inplace"
             self.kv_commit_programs[path] += 1
             self.telemetry.counter(f"serving/kv_commit_{path}_programs")
+        if moe_after != moe_before:
+            # ``dense`` if any layer broadcast its rows to every expert
+            path = "dense" if moe_after[1] > moe_before[1] else "sparse"
+            self.moe_dispatch_programs[path] += 1
+            self.telemetry.counter(f"serving/moe_{path}_programs")
         return out
 
     def _call_step(self, fn, args, lora):
@@ -1900,11 +1944,11 @@ class DecodeScheduler:
         while True:
             emap, pools, resident = self.experts.dispatch_operands()
             out = self._dispatch(fn, args + extra + ((emap, pools), ), args)
-            counts = np.asarray(jax.device_get(out[-1]))
+            counts = np.asarray(jax.device_get(out[-1]))[:, :-2]
             used = counts > 0
             if not self.experts.missing(used, resident).any():
                 self.experts.touch(used)
-                self._record_expert_stats(counts)
+                self._record_expert_stats(np.asarray(jax.device_get(out[-1])))
                 return out[:-1]
             # the donated pool moved forward; replay reads the new buffers
             args = args[:1] + (out[0], ) + args[2:]
@@ -1928,15 +1972,39 @@ class DecodeScheduler:
                     f"re-dispatches (cross-replica eviction thrash?); raise "
                     f"expert_offload.resident_experts")
 
-    def _record_expert_stats(self, counts):
-        """Routing telemetry from one successful dispatch's (L, E) counts:
-        total token->expert assignments and the per-step load-balance gauge
-        (1.0 = tokens spread evenly; 1/E = everything on one expert)."""
+    def _held_experts(self, num_experts):
+        """The slice of the router's experts that this engine's layers hold."""
+        mc = self.engine.model_config
+        lo = getattr(mc, "moe_first_expert", 0)
+        return slice(lo, lo + getattr(mc, "experts_held", num_experts))
+
+    def _moe_forward_stats(self, counts):
+        """In-program: ONE forward's (L, E) routed-pair counts (live rows,
+        over all the router's experts), widened by two columns a step
+        program can sum over its forwards: how many of the experts held
+        here some live row routed to, and 1 (a layer call)."""
+        here = counts[:, self._held_experts(counts.shape[1])]
+        return jnp.concatenate(
+            [counts, jnp.sum(here > 0, axis=1, keepdims=True, dtype=counts.dtype),
+             jnp.ones((counts.shape[0], 1), counts.dtype)], axis=1)
+
+    def _record_expert_stats(self, stats):
+        """Routing telemetry from one successful dispatch's (L, E + 2)
+        stats (:meth:`_moe_forward_stats`, summed over the sync's forwards):
+        total token->expert assignments, the per-step load-balance gauge
+        (1.0 = tokens spread evenly; 1/E = everything on one expert), and
+        the row-expert pairs by where the expert lives."""
+        counts, touched, calls = stats[:, :-2], stats[:, -2], stats[:, -1]
         total = int(counts.sum())
         self.expert_dispatch_tokens += total
         tel = self.telemetry
         if not tel.enabled or total == 0:
             return
+        here = int(counts[:, self._held_experts(counts.shape[1])].sum())
+        tel.counter("serving/moe_pairs_here", here)
+        tel.counter("serving/moe_pairs_elsewhere", total - here)
+        tel.counter("serving/moe_experts_touched", int(touched.sum()))
+        tel.counter("serving/moe_layer_calls", int(calls.sum()))
         tel.counter("serving/expert_dispatch_tokens", total)
         mx = counts.max(axis=1)
         tot = counts.sum(axis=1)
@@ -2440,6 +2508,7 @@ class DecodeScheduler:
                      pos=int(pf.pos), take=int(take), final=bool(final))
         # live rows: column 0 + each substep appended one KV row
         delivered = self._deliver_block(live, toks_k, logits_k, K)
+        self._keep_choice(preq, ps, take, K if final else 1)
         pf.pos += take
         if final:
             # the chunk's rows plus K-1 substep rows: token 0's KV landed
@@ -2579,6 +2648,7 @@ class DecodeScheduler:
             tp = self._shard_deg
             stats = self._moe_stats
             offload = self.experts is not None
+            choice = collect and self._moe and not fused_block
 
             def sample(l2, seeds, steps, flags, temps, topks, topps):
                 with jax.named_scope("sample"):
@@ -2609,32 +2679,29 @@ class DecodeScheduler:
                 pos = lengths[:, None] + jnp.arange(C)[None, :]
 
                 def forward(pool, tok_block, pos_block, widx, sp, seq_sh=False):
-                    """One in-sync forward; returns (logits, pool, counts)
-                    with counts None when stats are off (the non-stats
-                    trace is unchanged from the pre-MoE program)."""
+                    """One in-sync forward; returns (logits, pool, counts,
+                    choice), counts None when stats are off and choice None
+                    unless an MoE program collects (the plain trace is
+                    unchanged from the pre-MoE program)."""
                     if fused_block:
                         # 3 resident kernels per layer; stats/lora/offload
                         # are structurally absent on this path (the gate
                         # excludes MoE, and lora variants stay unfused)
                         lg, pl = model.fused_paged_step(
                             params, tok_block, pool, pos_block, widx, sp)
-                        return lg, pl, None
-                    if stats:
-                        return model.apply_with_cache(
-                            params, tok_block, pool, 0, position_ids=pos_block,
-                            write_index=widx, q_spans=sp, lora_ops=lops,
-                            expert_ops=eops, expert_stats=True,
-                            ext_ops=ext_ops, seq_shard=seq_sh)
-                    lg, pl = model.apply_with_cache(
+                        return lg, pl, None, None
+                    lg, pl, *rest = model.apply_with_cache(
                         params, tok_block, pool, 0, position_ids=pos_block,
                         write_index=widx, q_spans=sp, lora_ops=lops,
+                        expert_ops=eops, expert_stats=stats, expert_choice=choice,
                         ext_ops=ext_ops, seq_shard=seq_sh)
-                    return lg, pl, None
+                    cnt = self._moe_forward_stats(rest.pop(0)) if stats else None
+                    return lg, pl, cnt, (rest.pop(0) if choice else None)
 
                 # only the first (wide) forward seq-shards: the substeps'
                 # single-column blocks can't split over the seq axis
-                logits, pool, total_cnt = forward(pool, ids, pos, lengths,
-                                                  spans, seq_sh=seqp)
+                logits, pool, total_cnt, choice0 = forward(pool, ids, pos, lengths,
+                                                           spans, seq_sh=seqp)
                 # each row's LAST live column: decode rows column 0, the
                 # prefill row its chunk fill - 1 (dead rows clamp to 0 —
                 # their token is garbage the host never reads)
@@ -2647,38 +2714,45 @@ class DecodeScheduler:
                 out_logits = jnp.zeros((K, N, V) if collect else (), jnp.float32)
                 if collect:
                     out_logits = out_logits.at[0].set(l0)
+                # an MoE program that collects also returns what every row's
+                # routers chose: the whole first block, then each substep
+                out_choice = (jnp.zeros((K, ) + choice0.shape[:2] + choice0.shape[3:],
+                                        jnp.int32) if choice else ())
+
+                def result(pool, out_toks, out_logits, out_choice, total_cnt):
+                    return ((pool, out_toks) + ((out_logits, ) if collect else ())
+                            + ((choice0, out_choice) if choice else ())
+                            + ((total_cnt, ) if stats else ()))
+
                 if K == 1:
-                    out = (pool, out_toks) + ((out_logits, ) if collect else ())
-                    return out + ((total_cnt, ) if stats else ())
+                    return result(pool, out_toks, out_logits, out_choice, total_cnt)
                 base = lengths + jnp.maximum(spans, 1) - 1  # per-row write head - 1
                 live01 = jnp.minimum(spans, 1)  # substep spans: drop dead rows' writes
 
                 def body(k, carry):
-                    if stats:
-                        pool, tok, out_toks, out_logits, total_cnt = carry
-                    else:
-                        pool, tok, out_toks, out_logits = carry
-                    logits, pool, cnt = forward(pool, tok[:, None],
-                                                (base + k)[:, None], base + k,
-                                                live01)
+                    pool, tok, out_toks, out_logits, out_choice, total_cnt = carry
+                    logits, pool, cnt, ch = forward(pool, tok[:, None],
+                                                    (base + k)[:, None], base + k,
+                                                    live01)
                     l2 = _replicate_logits(logits[:, 0].astype(jnp.float32), tp)
                     nxt = sample(l2, seeds, steps + k, flags, temps, topks, topps)
                     out_toks = jax.lax.dynamic_update_index_in_dim(out_toks, nxt, k, 0)
                     if collect:
                         out_logits = jax.lax.dynamic_update_index_in_dim(
                             out_logits, l2, k, 0)
+                    if choice:
+                        out_choice = jax.lax.dynamic_update_index_in_dim(
+                            out_choice, ch[:, :, 0], k, 0)
                     if stats:
-                        return pool, nxt, out_toks, out_logits, total_cnt + cnt
-                    return pool, nxt, out_toks, out_logits
+                        total_cnt = total_cnt + cnt
+                    return pool, nxt, out_toks, out_logits, out_choice, total_cnt
 
-                carry = (pool, tok0, out_toks, out_logits)
-                carry += (total_cnt, ) if stats else ()
-                carry = jax.lax.fori_loop(1, K, body, carry)
-                pool, _, out_toks, out_logits = carry[:4]
-                out = (pool, out_toks) + ((out_logits, ) if collect else ())
-                return out + ((carry[4], ) if stats else ())
+                carry = jax.lax.fori_loop(
+                    1, K, body, (pool, tok0, out_toks, out_logits, out_choice,
+                                 total_cnt if stats else ()))
+                return result(carry[0], *carry[2:])
 
-            return self._jit_step(fused, (1 if collect else 0)
+            return self._jit_step(fused, (1 if collect else 0) + (2 if choice else 0)
                                   + (1 if self._moe_stats else 0) + 1, (1, ))
 
         return self._program(key, build)
@@ -2741,6 +2815,7 @@ class DecodeScheduler:
                         params, ids, pool, 0, position_ids=pos,
                         write_index=lengths, q_spans=spans, lora_ops=lops,
                         expert_ops=eops, expert_stats=True)
+                    cnt = self._moe_forward_stats(cnt)
                 else:
                     logits, pool = model.apply_with_cache(
                         params, ids, pool, 0, position_ids=pos,

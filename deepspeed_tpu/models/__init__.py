@@ -129,3 +129,37 @@ def tiny_moe():
     return TransformerConfig(vocab_size=256, hidden_size=64, num_layers=2, num_heads=4,
                              num_kv_heads=2, max_seq_len=128, intermediate_size=128,
                              num_experts=4, moe_top_k=2)
+
+
+def _mla_moe(hidden, layers, heads, q_rank, kv_rank, nope, rope, v_dim, experts, top_k,
+             expert_ffn, shared, vocab, seq, original_max_len, factor):
+    """Latent attention + routed experts with shared ones (``mistral4`` /
+    DeepSeek-V2 style): YaRN frequencies, interleaved rotary pairs, the
+    position-dependent query scale, untied head, dropless routing."""
+    return TransformerConfig(
+        vocab_size=vocab, hidden_size=hidden, num_layers=layers, num_heads=heads,
+        head_dim=nope + rope, max_seq_len=seq, pos_embedding="rope", norm="rmsnorm",
+        activation="swiglu", tie_embeddings=False, layernorm_epsilon=1e-6, rope_theta=10000.0,
+        kv_lora_rank=kv_rank, q_lora_rank=q_rank, qk_nope_head_dim=nope,
+        qk_rope_head_dim=rope, v_head_dim=v_dim, rope_interleave=True,
+        rope_factor=factor, rope_beta_fast=32.0, rope_beta_slow=1.0,
+        rope_original_max_len=original_max_len, rope_mscale=1.0, rope_mscale_all_dim=1.0,
+        attn_temp_beta=0.1, num_experts=experts, moe_top_k=top_k, moe_ffn_size=expert_ffn,
+        moe_shared_experts=shared, moe_routed_scale=1.0, moe_dropless=True)
+
+
+@register("mistral-small-4-119b")
+def mistral_small_4_119b():
+    """Mistral-Small-4-119B-2603's language model at its published sizes
+    (huggingface.co/mistralai/Mistral-Small-4-119B-2603 config.json,
+    ``model_type: mistral4``): 36 layers, all 128 experts held. The vision
+    encoder is not built. Served only (no capacity-buffered training path)."""
+    return _mla_moe(4096, 36, 32, 1024, 256, 64, 64, 128, 128, 4, 2048, 1, 131072,
+                    1048576, 8192, 128.0)
+
+
+@register("tiny-mla-moe")
+def tiny_mla_moe():
+    """Test-scale latent-attention MoE; ``rope_original_max_len`` 16 so that
+    the YaRN blend and g(t) are exercised within 128 positions."""
+    return _mla_moe(64, 2, 4, 32, 16, 8, 8, 16, 8, 2, 32, 1, 256, 128, 16, 8.0)

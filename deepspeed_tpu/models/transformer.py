@@ -75,6 +75,37 @@ class TransformerConfig:
     # expert biases — set True when loading them (MegatronPolicy.convert
     # enforces it); HF Mixtral-family experts are bias-less (default).
     moe_expert_bias: bool = False
+    # experts this layer HOLDS: the contiguous ids [moe_first_expert,
+    # moe_first_expert + moe_experts_held) of the num_experts the router
+    # scores (None = all). One chip's share of an expert-parallel deployment:
+    # the router keeps its width and top-k, the layer computes its own
+    # experts' part of the result and leaves out what absent experts would add
+    moe_experts_held: Optional[int] = None
+    moe_first_expert: int = 0
+    moe_ffn_size: Optional[int] = None  # expert width; None = ffn_size
+    moe_shared_experts: int = 0  # always-on experts of width moe_ffn_size, computed once
+    moe_routed_scale: float = 1.0  # routed_scaling_factor on the renormalised top-k weights
+    # no capacity buffers anywhere: the full (no-cache) forward routes per
+    # token like serving does and reports no aux loss (serving-only presets)
+    moe_dropless: bool = False
+    # latent attention (MLA, kv_lora_rank > 0): low-rank q and kv projections
+    # with their RMSNorms, per-head [nope ; rope] query/key parts, ONE rotated
+    # key part for all heads, and a cache of kv_lora_rank + qk_rope_head_dim
+    # values a position (see LatentAttention)
+    kv_lora_rank: int = 0
+    q_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    rope_interleave: bool = False  # rotate dims (2j, 2j+1) together, not (j, j + d/2)
+    # YaRN frequencies (rope_factor > 1) and the position-dependent query scale
+    rope_factor: float = 1.0
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_original_max_len: int = 0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 0.0
+    attn_temp_beta: float = 0.0  # g(t) = 1 + beta * ln(1 + floor(t / rope_original_max_len))
     # systems
     dtype: Any = jnp.bfloat16
     scan_layers: bool = True
@@ -129,6 +160,23 @@ class TransformerConfig:
         if self.local_attention_layers and self.scan_layers:
             raise ValueError("local_attention_layers (per-layer windows) requires "
                              "scan_layers=False — scanned layers share one program")
+        if self.kv_lora_rank and not (self.q_lora_rank and self.qk_nope_head_dim
+                                      and self.qk_rope_head_dim and self.v_head_dim
+                                      and self.pos_embedding == "rope"):
+            raise ValueError("latent attention (kv_lora_rank > 0) needs q_lora_rank, "
+                             "qk_nope_head_dim, qk_rope_head_dim, v_head_dim and rope positions")
+        if (self.rope_factor > 1 or self.attn_temp_beta) and self.rope_original_max_len <= 0:
+            raise ValueError("YaRN frequencies and the position-dependent query scale "
+                             "need rope_original_max_len")
+        if self.num_experts and not (
+                0 <= self.moe_first_expert
+                and self.moe_first_expert + self.experts_held <= self.num_experts):
+            raise ValueError(f"held experts [{self.moe_first_expert}, "
+                             f"{self.moe_first_expert + self.experts_held}) lie outside the "
+                             f"router's {self.num_experts}")
+        if self.experts_held != self.num_experts and not self.moe_dropless:
+            raise ValueError("a layer that holds a share of the experts has no "
+                             "capacity-buffered path: set moe_dropless")
         if self.attention_impl == "flash":
             import importlib.util
             if importlib.util.find_spec("deepspeed_tpu.ops.pallas.flash_attention") is None:
@@ -154,16 +202,35 @@ class TransformerConfig:
             return (d + 255) // 256 * 256
         return 4 * self.hidden_size
 
+    @property
+    def experts_held(self):
+        return self.num_experts if self.moe_experts_held is None else self.moe_experts_held
+
+    @property
+    def expert_ffn_size(self):
+        return self.moe_ffn_size or self.ffn_size
+
+    @property
+    def latent_width(self):
+        """Values a position holds in a latent cache (0 = per-head K and V)."""
+        return self.kv_lora_rank + self.qk_rope_head_dim if self.kv_lora_rank else 0
+
     def num_params(self):
-        """Approximate parameter count (for MFU math)."""
+        """Approximate parameter count (for MFU math); experts held here."""
         h, v, L = self.hidden_size, self.vocab_size, self.num_layers
-        attn = h * self.head_size * (self.num_heads + 2 * self.kv_heads) + self.num_heads * self.head_size * h
-        if self.activation in ("swiglu", "geglu"):
-            mlp = 3 * h * self.ffn_size
+        if self.kv_lora_rank:
+            nh, qk = self.num_heads, self.qk_nope_head_dim + self.qk_rope_head_dim
+            attn = (h * self.q_lora_rank + self.q_lora_rank * nh * qk + h * self.latent_width
+                    + self.kv_lora_rank * nh * (self.qk_nope_head_dim + self.v_head_dim)
+                    + nh * self.v_head_dim * h)
         else:
-            mlp = 2 * h * self.ffn_size
+            attn = (h * self.head_size * (self.num_heads + 2 * self.kv_heads)
+                    + self.num_heads * self.head_size * h)
+        per_h = 3 * h if self.activation in ("swiglu", "geglu") else 2 * h
+        mlp = per_h * self.ffn_size
         if self.num_experts > 0:
-            mlp *= self.num_experts
+            mlp = (per_h * self.expert_ffn_size * (self.experts_held + self.moe_shared_experts)
+                   + h * self.num_experts)
         emb = v * h * (1 if self.tie_embeddings else 2)
         pos = self.max_seq_len * h if self.pos_embedding == "learned" else 0
         return L * (attn + mlp + 2 * h) + emb + pos + h
@@ -301,11 +368,56 @@ def make_norm(cfg, name=None):
     return nn.LayerNorm(epsilon=cfg.layernorm_epsilon, dtype=cfg.dtype, param_dtype=jnp.float32, name=name)
 
 
-def rope_table(head_size, max_len, theta):
+def yarn_mscale(factor, mscale):
+    """YaRN's attention magnitude correction: 0.1 m ln(factor) + 1."""
+    import math
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def rope_frequencies(head_size, theta, factor=1.0, beta_fast=32.0, beta_slow=1.0,
+                     original_max_len=0):
+    """Inverse frequencies of the rotary pairs. ``factor`` > 1 blends them the
+    YaRN way (arXiv:2309.00071, as HF ``_compute_yarn_parameters`` does):
+    pairs that turn more than ``beta_fast`` times over the original context
+    keep their frequency, pairs that turn fewer than ``beta_slow`` times are
+    interpolated (divided by ``factor``), a linear ramp in between."""
+    import math
     freq = 1.0 / (theta**(jnp.arange(0, head_size, 2, dtype=jnp.float32) / head_size))
+    if factor <= 1:
+        return freq
+
+    def correction_dim(turns):
+        return head_size * math.log(original_max_len / (turns * 2 * math.pi)) / (2 * math.log(theta))
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), head_size - 1)
+    ramp = jnp.clip((jnp.arange(head_size // 2, dtype=jnp.float32) - low)
+                    / max(high - low, 1e-3), 0.0, 1.0)
+    return freq / factor * ramp + freq * (1.0 - ramp)
+
+
+def rope_table(head_size, max_len, theta, freq=None, magnitude=1.0):
+    if freq is None:
+        freq = rope_frequencies(head_size, theta)
     pos = jnp.arange(max_len, dtype=jnp.float32)
     angles = jnp.outer(pos, freq)  # (T, hd/2)
-    return jnp.sin(angles), jnp.cos(angles)
+    return jnp.sin(angles) * magnitude, jnp.cos(angles) * magnitude
+
+
+def model_rope_table(cfg):
+    """The (sin, cos) table a configuration's attention reads, or (None,
+    None) without rotary positions: the rotary width (partial rotary, or a
+    latent head's rope part), YaRN frequencies and magnitude."""
+    if cfg.pos_embedding != "rope":
+        return None, None
+    dim = cfg.qk_rope_head_dim if cfg.kv_lora_rank else (cfg.rotary_dim or cfg.head_size)
+    if cfg.rope_factor <= 1:
+        return rope_table(dim, cfg.max_seq_len, cfg.rope_theta)
+    freq = rope_frequencies(dim, cfg.rope_theta, cfg.rope_factor, cfg.rope_beta_fast,
+                            cfg.rope_beta_slow, cfg.rope_original_max_len)
+    magnitude = (yarn_mscale(cfg.rope_factor, cfg.rope_mscale)
+                 / yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim))
+    return rope_table(dim, cfg.max_seq_len, cfg.rope_theta, freq, magnitude)
 
 
 def alibi_slopes(num_heads):
@@ -334,6 +446,16 @@ def apply_rope(x, sin, cos):
         sin = sin[:, None, :, :]
         cos = cos[:, None, :, :]
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1).astype(x.dtype)
+
+
+def apply_rope_interleaved(x, sin, cos):
+    """As :func:`apply_rope`, with dimensions ``2j`` and ``2j + 1`` rotating
+    together (``rope_interleave``). The result comes out de-interleaved
+    ([evens ; odds]): queries and keys get the same permutation, so their
+    dot products are those of the interleaved layout."""
+    x32 = x.astype(jnp.float32)
+    return apply_rope(jnp.concatenate([x32[..., 0::2], x32[..., 1::2]], axis=-1),
+                      sin, cos).astype(x.dtype)
 
 
 def _ulysses_specs(B, nh, nkv=None):
@@ -410,9 +532,10 @@ def _commit_span_rows(writes, write_index, q_spans, paged_kernels):
     the same bytes. The choice is tallied per trace for the scheduler's
     ``serving/kv_commit_*_programs`` counters."""
     from ..ops.pallas import kv_commit
-    (ck, k), (cv, v) = writes[:2]
-    in_place = (paged_kernels and _tp_mesh_size() == 1
-                and kv_commit.commits_in_place(ck) and cv.shape == ck.shape)
+    ck, k = writes[0]
+    # a latent pool is ONE leaf a layer: the kernel commits K/V pairs
+    in_place = (paged_kernels and _tp_mesh_size() == 1 and len(writes) >= 2
+                and kv_commit.commits_in_place(ck) and writes[1][0].shape == ck.shape)
     kv_commit.tally(in_place)
     T = k.shape[2]
     tgt = write_index[:, None] + jnp.arange(T)[None, :]
@@ -420,7 +543,7 @@ def _commit_span_rows(writes, write_index, q_spans, paged_kernels):
     upd = lambda c, kk, i: c.at[:, i, :].set(kk.astype(c.dtype), mode="drop")
     with jax.named_scope("kv_commit"):
         written = list(kv_commit.commit_kv_rows(
-            (ck, cv), (k, v), write_index, q_spans)) if in_place else []
+            (ck, writes[1][0]), (k, writes[1][1]), write_index, q_spans)) if in_place else []
         written += [jax.vmap(upd)(c, kk, tgt) for c, kk in writes[len(written):]]
     return written
 
@@ -1015,6 +1138,161 @@ class Attention(nn.Module):
         return out, new_cache
 
 
+def _latent_attention_xla(qf, lat, qpos, live_end, key_mask, score_scale, *, rank, block_kv,
+                          dtype):
+    """Absorbed latent attention against a latent cache, in XLA.
+
+    ``qf``: (B, nh, T, rank + rope) absorbed queries ``[q_nope W_kvb^K ;
+    RoPE(q_rope)]``; ``lat``: (B, S, rank + rope) cache rows ``[c_kv ; k_r]``,
+    one for all heads; ``qpos``: (B or 1, T) absolute position of each query
+    (its causal end); ``live_end``: one past the last position any live query
+    attends; ``key_mask``: optional (B, S) attendable rows;
+    ``score_scale``: (B or 1, T) fp32 ``scale * g(t)``. Returns
+    ``sum_s p_s c_kv,s``: (B, nh, T, rank), for the value up-projection.
+
+    An online softmax over key blocks, walked to the batch's longest live
+    row only (a masked block leaves every accumulator bit-unchanged, so a
+    row's result does not depend on how far its neighbours reach). The block
+    narrows for wide spans so that one score plane stays near 256 MB."""
+    B, nh, T, _ = qf.shape
+    S = lat.shape[1]
+    blk = min(block_kv, S)
+    while blk > 64 and B * nh * T * blk > (1 << 26) and S % (blk // 2) == 0:
+        blk //= 2
+    if S % blk:
+        blk = S
+    n_live = jnp.clip((live_end + blk - 1) // blk, 1, S // blk)
+
+    def body(j, carry):
+        m, l, acc = carry
+        kb = jax.lax.dynamic_slice_in_dim(lat, j * blk, blk, axis=1)  # (B, blk, D)
+        s = jnp.einsum("bntd,bsd->bnts", qf, kb, preferred_element_type=jnp.float32)
+        s = s * score_scale[:, None, :, None]
+        kpos = j * blk + jnp.arange(blk)
+        keep = kpos[None, None, :] <= qpos[:, :, None]  # (B or 1, T, blk)
+        if key_mask is not None:
+            keep = keep & jax.lax.dynamic_slice_in_dim(key_mask, j * blk, blk, axis=1)[:, None, :]
+        s = jnp.where(keep[:, None], s, -1e30)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+        p = jnp.exp(s - m_new[..., None])
+        alpha = jnp.exp(m - m_new)
+        pv = jnp.einsum("bnts,bsr->bntr", p.astype(dtype), kb[..., :rank],
+                        preferred_element_type=jnp.float32)
+        return m_new, l * alpha + jnp.sum(p, axis=-1), acc * alpha[..., None] + pv
+
+    init = (jnp.full((B, nh, T), -jnp.inf, jnp.float32), jnp.zeros((B, nh, T), jnp.float32),
+            jnp.zeros((B, nh, T, rank), jnp.float32))
+    _, l, acc = jax.lax.fori_loop(0, n_live, body, init)
+    return (acc / jnp.where(l == 0, 1.0, l)[..., None]).astype(dtype)
+
+
+class LatentAttention(nn.Module):
+    """Multi-head latent attention (MLA; DeepSeek-V2, arXiv:2405.04434, as
+    ``mistral4`` configures it). Per position the cache holds ONE row of
+    ``kv_lora_rank + qk_rope_head_dim`` values for all heads: the normalised
+    kv latent ``c_kv`` and the rotated shared key part ``k_r``; per-head K
+    and V are never stored.
+
+        c_q = RMSNorm(a W_qa) ; q_i = c_q W_qb,i = [q_nope_i ; q_rope_i]
+        [c_kv ; k_r] = a W_kva ; c_kv = RMSNorm(c_kv) ; k_r = RoPE(k_r)
+        [k_nope_i ; v_i] = c_kv W_kvb,i
+        s_ts,i = (q_nope_i . k_nope_i + RoPE(q_rope_i) . k_r) scale g(t)
+
+    Without a cache (full forward) the EXPANDED form computes per-head K
+    and V from ``c_kv``. With a cache (static generate, slot-pool decode and
+    chunked-prefill spans alike) the ABSORBED form attends the latent rows
+    directly: ``q~_i = q_nope_i W_kvb,i^K^T`` against ``c_kv``, and ``o_i =
+    (sum p c_kv) W_kvb,i^V``. The call signature is :class:`Attention`'s;
+    adapters, extent chains and sequence-parallel spans are refused."""
+    cfg: TransformerConfig
+    layer_idx: int = -1
+
+    @nn.compact
+    def __call__(self, x, sin, cos, attn_mask=None, kv_cache=None, cache_index=None,
+                 position_ids=None, write_index=None, q_spans=None, lora_ops=None,
+                 ext_ops=None, seq_shard=False):
+        import math
+        cfg = self.cfg
+        B, T, H = x.shape
+        nh, rank = cfg.num_heads, cfg.kv_lora_rank
+        nope, rope, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+        if lora_ops or ext_ops is not None or seq_shard:
+            raise NotImplementedError("latent attention serves without adapters, extent "
+                                      "chains or sequence-parallel spans")
+        dense = partial(nn.Dense, use_bias=False, dtype=cfg.dtype, param_dtype=jnp.float32,
+                        kernel_init=nn.initializers.normal(0.02))
+        norm = partial(RMSNorm, epsilon=cfg.layernorm_epsilon, dtype=cfg.dtype)
+        with jax.named_scope("mla_proj"):
+            c_q = norm(name="q_a_norm")(dense(cfg.q_lora_rank, name="q_a_proj")(x))
+            q = HeadProjection(nh, nope + rope, False, cfg.dtype, name="q_b_proj")(c_q)
+            kv_a = dense(rank + rope, name="kv_a_proj")(x)
+            c_kv = norm(name="kv_a_norm")(kv_a[..., :rank])  # (B, T, rank)
+            # (rank, nh, nope + v): k_nope and v of every head from the latent
+            w_kvb = self.param("kv_b_proj", nn.initializers.normal(0.02),
+                               (rank, nh, nope + vd), jnp.float32).astype(cfg.dtype)
+
+            if position_ids is not None:
+                pos = position_ids  # (B, T)
+            elif write_index is not None:
+                pos = write_index[:, None] + jnp.arange(T)[None, :]
+            else:
+                pos = ((0 if cache_index is None else cache_index) + jnp.arange(T))[None, :]
+            rotate = apply_rope_interleaved if cfg.rope_interleave else apply_rope
+            pos_sin, pos_cos = sin[pos], cos[pos]  # (B or 1, T, rope/2)
+            q_rope = rotate(q[..., nope:], pos_sin, pos_cos)  # (B, nh, T, rope)
+            k_r = rotate(kv_a[:, None, :, rank:], pos_sin, pos_cos)  # (B, 1, T, rope)
+
+            # softmax scale: head width and YaRN's magnitude (squared: it is
+            # meant for q and k alike), then the position-dependent g(t)
+            scale = (nope + rope) ** -0.5 * yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim) ** 2
+            score_scale = jnp.full(pos.shape, scale, jnp.float32)
+            if cfg.attn_temp_beta:
+                score_scale = score_scale * (1.0 + cfg.attn_temp_beta * jnp.log1p(
+                    jnp.floor(pos.astype(jnp.float32) / cfg.rope_original_max_len)))
+
+        if kv_cache is None:
+            # expanded form: per-head K and V of this call's own tokens
+            with jax.named_scope("mla_attn"):
+                kv = jnp.einsum("btr,rnd->bntd", c_kv, w_kvb)
+                k = jnp.concatenate([kv[..., :nope],
+                                     jnp.broadcast_to(k_r, (B, nh, T, rope))], axis=-1)
+                qf = jnp.concatenate([q[..., :nope], q_rope], axis=-1)
+                s = jnp.einsum("bnqd,bnkd->bnqk", qf, k, preferred_element_type=jnp.float32)
+                s = s * score_scale[:, None, :, None]
+                keep = jnp.tril(jnp.ones((T, T), bool))[None, None]
+                if attn_mask is not None:
+                    keep = keep & attn_mask[:, None, None, :]
+                probs = jax.nn.softmax(jnp.where(keep, s, -1e30), axis=-1).astype(cfg.dtype)
+                out = jnp.einsum("bnqk,bnkd->bnqd", probs, kv[..., nope:])
+            new_cache = None
+        else:
+            (pool, ) = kv_cache  # (B, 1, S, rank + rope)
+            fresh = jnp.concatenate([c_kv[:, None], k_r], axis=-1).astype(pool.dtype)
+            if write_index is not None and q_spans is not None:
+                (pool, ) = _commit_span_rows([(pool, fresh)], write_index, q_spans,
+                                             paged_kernels=False)
+            elif write_index is not None:
+                pool = jax.vmap(lambda c, r, i: jax.lax.dynamic_update_slice_in_dim(
+                    c, r, i, axis=1))(pool, fresh, write_index)
+            else:
+                pool = jax.lax.dynamic_update_slice_in_dim(pool, fresh, cache_index, axis=2)
+            with jax.named_scope("mla_attn"):
+                q_lat = jnp.einsum("bntd,rnd->bntr", q[..., :nope], w_kvb[..., :nope])
+                qf = jnp.concatenate([q_lat, q_rope], axis=-1)  # (B, nh, T, rank + rope)
+                # padding columns past a row's span attend garbage nobody
+                # reads: the block walk stops at the last LIVE query
+                live_end = (jnp.max(pos) + 1 if q_spans is None
+                            else jnp.max(write_index + jnp.maximum(q_spans, 1)))
+                o_lat = _latent_attention_xla(
+                    qf, pool[:, 0].astype(cfg.dtype), pos, live_end, attn_mask, score_scale,
+                    rank=rank, block_kv=cfg.decode_block_kv, dtype=cfg.dtype)
+                out = jnp.einsum("bntr,rnd->bntd", o_lat, w_kvb[..., nope:])
+            new_cache = (pool, )
+        with jax.named_scope("mla_proj"):
+            out = OutProjection(H, False, cfg.dtype, name="o_proj")(out.astype(cfg.dtype))
+        return out, new_cache
+
+
 class QuantDense(nn.Module):
     """nn.Dense over (int8 weight, fp32 group scales) via the Pallas quant
     matmul (serving path; params come from ``quantize_params``)."""
@@ -1092,7 +1370,8 @@ class Block(nn.Module):
             x = fake_quantize(x, bits=cfg.act_quant_bits, groups=1,
                               symmetric=cfg.act_quant_symmetric)
         h = make_norm(cfg, name="attn_norm")(x)
-        h, new_cache = Attention(cfg, layer_idx=self.layer_idx, name="attn")(
+        attention = LatentAttention if cfg.kv_lora_rank else Attention
+        h, new_cache = attention(cfg, layer_idx=self.layer_idx, name="attn")(
             h, sin, cos, attn_mask, kv_cache, cache_index, position_ids, write_index,
             q_spans, lora_ops, ext_ops, seq_shard)
         if drop is not None:
@@ -1107,7 +1386,7 @@ class Block(nn.Module):
             ff_in = make_norm(cfg, name="mlp_norm")(x)
         if cfg.num_experts > 0:
             from ..moe.layer import MoE
-            if kv_cache is not None:
+            if kv_cache is not None or cfg.moe_dropless:
                 # KV-cache (serving/decode) forward: deterministic per-token
                 # capacity-free dispatch, NO aux-loss sow — the gating
                 # intermediates are training-only, and collecting them here
@@ -1161,8 +1440,7 @@ class CausalLM(nn.Module):
                 x = x + jax.lax.dynamic_slice_in_dim(pos_emb, cache_index, T, axis=0).astype(cfg.dtype)
             else:
                 x = x + jax.lax.dynamic_slice_in_dim(pos_emb, 0, T, axis=0).astype(cfg.dtype)
-        sin, cos = (rope_table(cfg.rotary_dim or cfg.head_size, cfg.max_seq_len, cfg.rope_theta)
-                    if cfg.pos_embedding == "rope" else (None, None))
+        sin, cos = model_rope_table(cfg)
 
         block = Block
         if cfg.remat_policy:
@@ -1216,7 +1494,8 @@ class CausalLM(nn.Module):
 
             x, new_cache = nn.scan(
                 scan_body,
-                variable_axes={"params": 0, "intermediates": 0, "expert_stats": 0},
+                variable_axes={"params": 0, "intermediates": 0, "expert_stats": 0,
+                               "expert_choice": 0},
                 split_rngs={"params": True, "dropout": True},
                 length=cfg.num_layers,
                 metadata_params={"partition_name": "layers"},
@@ -1448,6 +1727,18 @@ class CausalLMModel:
         treat both layouts uniformly."""
         cfg = self.cfg
         dt = dtype or cfg.dtype
+        if cfg.latent_width:
+            # latent geometry: ONE leaf a layer, a single "head" of
+            # kv_lora_rank + qk_rope_head_dim values a position (normalised
+            # c_kv and rotated k_r), never expanded to per-head K and V at
+            # rest. The slot axis stays at ndim - 4, so slot_slice /
+            # slot_update / copy_slot and the radix copy take it as it is.
+            if quantized:
+                raise NotImplementedError("the latent KV pool has no int8 tier")
+            shape = (batch_size, 1, max_len, cfg.latent_width)
+            if cfg.scan_layers:
+                return (jnp.zeros((cfg.num_layers, ) + shape, dt), )
+            return (tuple(jnp.zeros(shape, dt) for _ in range(cfg.num_layers)), )
         shape = (batch_size, cfg.kv_heads, max_len, cfg.head_size)
         sshape = (batch_size, 1, max_len, 1)
         if quantized:
@@ -1467,7 +1758,7 @@ class CausalLMModel:
     def apply_with_cache(self, params, input_ids, kv_cache, cache_index, cache_mask=None,
                          position_ids=None, write_index=None, q_spans=None,
                          lora_ops=None, expert_ops=None, expert_stats=False,
-                         ext_ops=None, seq_shard=False):
+                         ext_ops=None, seq_shard=False, expert_choice=False):
         """Forward writing into (and attending over) the KV cache. Returns
         (logits, new_cache). ``cache_mask``: (B, S) attendable cache slots.
         ``write_index``: optional (B,) per-row cache positions (slot-pool
@@ -1488,38 +1779,45 @@ class CausalLMModel:
         layer axis ``(expert->page map (L, E), pools {leaf: (L, R, ...)})``.
         ``expert_stats=True`` additionally returns per-layer routed-token
         counts ``(L, E) int32`` (the scheduler's residency/telemetry
-        signal) as a third output.
+        signal) as a third output; ``expert_choice=True`` the expert ids
+        every row chose, ``(L, B, T, k) int32``, as the last output (what a
+        reference follows where routing is a near tie).
 
         ``ext_ops``/``seq_shard``: long-context extent operands and the
         sequence-parallel prefill flag, layer-invariant pass-throughs to
         :class:`Attention` (see there for semantics)."""
-        mutable = ["expert_stats"] if expert_stats else False
+        mutable = ((["expert_stats"] if expert_stats else [])
+                   + (["expert_choice"] if expert_choice else [])) or False
         out = self.module.apply({"params": params}, input_ids, cache_mask, True, kv_cache,
                                 cache_index, position_ids, write_index=write_index,
                                 q_spans=q_spans, lora_ops=lora_ops,
                                 expert_ops=expert_ops, ext_ops=ext_ops,
                                 seq_shard=seq_shard, mutable=mutable)
-        if not expert_stats:
-            logits, new_cache = out
-            return logits, new_cache
+        if not mutable:
+            return out
         (logits, new_cache), mut = out
-        E = self.cfg.num_experts
-        stats = mut.get("expert_stats", {})
-        if self.cfg.scan_layers:
-            # one stacked (L, E) leaf under the scanned "layers" scope
-            leaves = jax.tree_util.tree_leaves(stats)
-            counts = jnp.concatenate([leaf.reshape(-1, E) for leaf in leaves],
-                                     axis=0)
-        else:
-            # unrolled: one (E,) leaf per "layer_<i>" scope — walk NUMERIC
-            # layer order explicitly (pytree flattening sorts keys
-            # lexicographically, which misorders layer_10 vs layer_2)
-            rows = []
-            for i in range(self.cfg.num_layers):
-                rows.extend(jax.tree_util.tree_leaves(stats.get(f"layer_{i}", {})))
-            counts = jnp.concatenate([leaf.reshape(-1, E) for leaf in rows],
-                                     axis=0)
-        return logits, new_cache, counts
+
+        def per_layer(collection, tail):
+            """One (L, *tail) array from a collection's per-layer leaves."""
+            sown = mut.get(collection, {})
+            if self.cfg.scan_layers:
+                # one stacked leaf under the scanned "layers" scope
+                leaves = jax.tree_util.tree_leaves(sown)
+            else:
+                # unrolled: one leaf per "layer_<i>" scope, walked in NUMERIC
+                # layer order (pytree flattening sorts keys lexicographically,
+                # which misorders layer_10 vs layer_2)
+                leaves = []
+                for i in range(self.cfg.num_layers):
+                    leaves.extend(jax.tree_util.tree_leaves(sown.get(f"layer_{i}", {})))
+            return jnp.concatenate([leaf.reshape((-1, ) + tail) for leaf in leaves], axis=0)
+
+        extra = ()
+        if expert_stats:
+            extra += (per_layer("expert_stats", (self.cfg.num_experts, )), )
+        if expert_choice:
+            extra += (per_layer("expert_choice", input_ids.shape + (self.cfg.moe_top_k, )), )
+        return (logits, new_cache) + extra
 
     # ---- fused decode blocks (serving fast path) -------------------------
     def fused_decode_operands(self, params):
@@ -1783,8 +2081,7 @@ class CausalLMModel:
             x = make_norm(cfg).apply({"params": params["embed_norm"]}, x)
         if cfg.pos_embedding == "learned":
             x = x + params["pos_embed"][:T].astype(cfg.dtype)
-        sin, cos = (rope_table(cfg.rotary_dim or cfg.head_size, cfg.max_seq_len, cfg.rope_theta)
-                    if cfg.pos_embedding == "rope" else (None, None))
+        sin, cos = self._rope()
 
         block_mod = Block(cfg)
         dropout_on = rng is not None and cfg.dropout > 0
@@ -2028,9 +2325,7 @@ class CausalLMModel:
         return x
 
     def _rope(self):
-        cfg = self.cfg
-        return (rope_table(cfg.rotary_dim or cfg.head_size, cfg.max_seq_len, cfg.rope_theta)
-                if cfg.pos_embedding == "rope" else (None, None))
+        return model_rope_table(self.cfg)
 
     def stream_layer(self, layer_tree, h, attn_mask=None, return_aux=False):
         """One transformer block (deterministic): ``layer_tree`` is a single

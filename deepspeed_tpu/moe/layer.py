@@ -8,13 +8,29 @@ all-to-alls that the reference issues by hand (``_AllToAll``,
 sharded_moe.py:90).
 """
 
+import threading
+
 import jax
 import jax.numpy as jnp
 import flax.linen as nn
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..comm import comm as dist
-from .sharded_moe import top_k_gating, top_k_serving_weights
+from .sharded_moe import top_k_gating, top_k_serving_choice
+
+# Serving MoE dispatches traced by this thread, (sparse, dense): a scheduler
+# reads it around a dispatch to learn which one a step program was built
+# with, as it does for the K/V commit (``ops/pallas/kv_commit.traced``).
+_traced = threading.local()
+
+
+def tally_dispatch(sparse):
+    n = traced_dispatches()
+    _traced.counts = (n[0] + 1, n[1]) if sparse else (n[0], n[1] + 1)
+
+
+def traced_dispatches():
+    return getattr(_traced, "counts", (0, 0))
 
 
 def _expert_constraint(x, spec):
@@ -48,6 +64,17 @@ def _deq(q, s, dtype):
             * s[:, :, None, :].astype(dtype)).reshape(E, k, n)
 
 
+def _kernel_leaves(kernels, activation, dtype):
+    """(gate or None, up, down) expert kernels in ``dtype`` from the
+    param-tree leaf dict (fp leaves or int8 ``*_q``/``*_scale`` pairs)."""
+    glu = activation in ("swiglu", "geglu")
+    if "up_proj_q" in kernels:
+        leaf = lambda n: _deq(kernels[n + "_q"], kernels[n + "_scale"], dtype)
+    else:
+        leaf = lambda n: kernels[n].astype(dtype)
+    return (leaf("gate_proj") if glu else None), leaf("up_proj"), leaf("down_proj")
+
+
 def expert_ffn(x, kernels, activation, dtype, bitwise_tp=False, keep_expert_axis=False):
     """Batched expert FFN math on EXPLICIT kernel leaves.
 
@@ -66,16 +93,7 @@ def expert_ffn(x, kernels, activation, dtype, bitwise_tp=False, keep_expert_axis
     tp>1 == tp=1 bit-identity contract). ``keep_expert_axis`` preserves the
     leading axis's ``expert`` sharding through that constraint."""
     use_bias = "down_bias" in kernels
-    glu = activation in ("swiglu", "geglu")
-    if "up_proj_q" in kernels:
-        uk = _deq(kernels["up_proj_q"], kernels["up_proj_scale"], dtype)
-        dk = _deq(kernels["down_proj_q"], kernels["down_proj_scale"], dtype)
-        gk = (_deq(kernels["gate_proj_q"], kernels["gate_proj_scale"], dtype)
-              if glu else None)
-    else:
-        uk = kernels["up_proj"].astype(dtype)
-        dk = kernels["down_proj"].astype(dtype)
-        gk = kernels["gate_proj"].astype(dtype) if glu else None
+    gk, uk, dk = _kernel_leaves(kernels, activation, dtype)
     x = x.astype(dtype)
     if activation in ("swiglu", "geglu"):
         g = jnp.einsum("ech,ehf->ecf", x, gk)
@@ -97,6 +115,120 @@ def expert_ffn(x, kernels, activation, dtype, bitwise_tp=False, keep_expert_axis
     if use_bias:
         out = out + kernels["down_bias"][:, None, :].astype(out.dtype)
     return out
+
+
+def expert_rank(ids):
+    """``ids`` (N, k), a row's k distinct experts -> (N, k) int32: each
+    choice's place among them in increasing expert order."""
+    return jnp.sum(ids[:, None, :] < ids[:, :, None], axis=-1, dtype=jnp.int32)
+
+
+def in_expert_order(x, rank):
+    """``x`` (N, k) by choice -> (N, k) by place (:func:`expert_rank`)."""
+    return jnp.stack([jnp.sum(jnp.where(rank == r, x, 0), axis=1)
+                      for r in range(x.shape[1])], axis=1)
+
+
+def combine_chosen(y, weights):
+    """``y`` (N, k, H): each row's k expert results IN INCREASING EXPERT
+    ORDER, ``weights`` (N, k) their fp32 combine weights -> (N, H) fp32, one
+    ``acc + w * y`` a step. The one accumulation of the serving dispatches
+    (sparse, dense broadcast, paged experts): whichever computed ``y``, the
+    same products are added in the same order by the same expression, so
+    they agree bit for bit."""
+    acc = jnp.zeros((y.shape[0], y.shape[2]), jnp.float32)
+    for j in range(y.shape[1]):
+        acc = acc + weights[:, j:j + 1] * y[:, j].astype(jnp.float32)
+    return acc
+
+
+# pairs one grouped product takes: a wider step (chunked prefill, speculative
+# verify) walks its pairs in tiles of this many, as far as the pairs reach
+SPARSE_TILE = 1024
+
+
+def sparse_expert_ffn(tokens, ids, weights, valid, kernels, first, activation, dtype):
+    """The routed experts' part of the result for the experts HELD here, by
+    a sparse dispatch: row-expert pairs are sorted by expert and each held
+    expert multiplies only the rows routed to it (``jax.lax.ragged_dot``,
+    one grouped product per projection).
+
+    ``tokens``: (N, H); ``ids``/``weights``: (N, k) the router's choice over
+    ALL its experts; ``valid``: (N,) live rows (padding past a slot's span is
+    not dispatched); ``kernels``: the held experts' leaves, leading axis =
+    experts ``first .. first + held``. Pairs routed elsewhere, and what those
+    experts would add, are left out. Returns (N, H) fp32.
+
+    A row's result is a pure function of the row, and the same sum as the
+    dense broadcast's: each pair's product accumulates over the contraction
+    alone and lands in the pair's OWN place of an ``(N, k, H)`` buffer, the
+    row's k places in increasing expert order (a scatter of distinct targets,
+    nothing is added there), then each row adds its k weighted results by
+    :func:`combine_chosen`, as the dense broadcast does (a pair routed
+    elsewhere adds ``0 * 0``). Where a pair sits among the sorted pairs, and
+    what else shares the step, changes nothing. Pairs are walked in tiles of
+    :data:`SPARSE_TILE`, only as many as hold a pair."""
+    N, H = tokens.shape
+    k = ids.shape[1]
+    gk, uk, dk = _kernel_leaves(kernels, activation, dtype)
+    held = uk.shape[0]
+    P = N * k
+    tile = min(P, SPARSE_TILE)
+    n_tiles = -(-P // tile)
+    pad = n_tiles * tile - P
+    with jax.named_scope("moe_router"):
+        local = ids - first
+        here = (local >= 0) & (local < held) & valid[:, None]
+        key = jnp.where(here, local, held).reshape(P)  # pairs routed elsewhere sort last
+        order = jnp.argsort(key, stable=True)
+        sizes = jnp.zeros((held + 1, ), jnp.int32).at[key].add(1)[:held]
+        ends = jnp.cumsum(sizes)
+        n_here = ends[-1]
+        row_of = jnp.pad(order // k, (0, pad))
+        # sorted position -> where its pair's result goes: the row's k places
+        # in increasing expert order (P: no pair held here, the write is dropped)
+        rank = expert_rank(ids)
+        place = (jnp.arange(N)[:, None] * k + rank).reshape(P)
+        dest_of = jnp.pad(jnp.where(jnp.arange(P) < n_here, place[order], P), (0, pad),
+                          constant_values=P)
+        expert_of = jnp.pad(jnp.minimum(key[order], held - 1), (0, pad))
+        tokens = tokens.astype(dtype)
+
+    def product(x, w, gs):
+        return jax.lax.ragged_dot(x, w, gs, preferred_element_type=jnp.float32).astype(dtype)
+
+    def one_tile(t, buf):
+        lo = t * tile
+        with jax.named_scope("moe_router"):
+            rows = jax.lax.dynamic_slice_in_dim(row_of, lo, tile)
+            e = jax.lax.dynamic_slice_in_dim(expert_of, lo, tile)
+            # this tile's share of every group: [start_e, end_e) cut to [lo, lo + tile)
+            gs = jnp.clip(jnp.minimum(ends, lo + tile) - jnp.maximum(ends - sizes, lo), 0, tile)
+            x = jnp.take(tokens, rows, axis=0)
+        with jax.named_scope("moe_experts"):
+            up = product(x, uk, gs)
+            if gk is not None:
+                g = product(x, gk, gs)
+                h = (nn.silu(g) if activation == "swiglu" else nn.gelu(g)) * up
+            else:
+                if "up_bias" in kernels:
+                    up = up + jnp.take(kernels["up_bias"], e, axis=0).astype(dtype)
+                h = nn.gelu(up) if activation == "gelu" else nn.relu(up)
+            y = product(h, dk, gs)
+            if "down_bias" in kernels:
+                y = y + jnp.take(kernels["down_bias"], e, axis=0).astype(dtype)
+        with jax.named_scope("moe_router"):
+            dest = jax.lax.dynamic_slice_in_dim(dest_of, lo, tile)
+            return buf.at[dest].set(y, mode="drop", unique_indices=True)
+
+    buf = jnp.zeros((P, H), dtype)
+    if n_tiles == 1:
+        buf = one_tile(0, buf)
+    else:
+        buf = jax.lax.fori_loop(0, (n_here + tile - 1) // tile, one_tile, buf)
+    with jax.named_scope("moe_router"):
+        w = in_expert_order(jnp.where(here, weights, 0.0), rank)
+        return combine_chosen(buf.reshape(N, k, H), w)
 
 
 class Experts(nn.Module):
@@ -124,8 +256,8 @@ class Experts(nn.Module):
 
     def _kernels(self):
         """Declare this module's kernel/bias params and return them in the
-        leaf-name dict :func:`expert_ffn` consumes (one math path for
-        in-tree and paged-pool experts)."""
+        leaf-name dict :func:`expert_ffn` and :func:`sparse_expert_ffn`
+        consume (one set of leaves for the dense, sparse and paged paths)."""
         init = nn.initializers.normal(0.02)
         E, H, F = self.num_experts, self.hidden, self.ffn
         glu = self.activation in ("swiglu", "geglu")
@@ -151,7 +283,14 @@ class Experts(nn.Module):
         return kernels
 
     @nn.compact
-    def __call__(self, x, keep_expert_axis=False):  # x: (E, C, H)
+    def __call__(self, x, keep_expert_axis=False, route=None):
+        """``x``: (E, C, H) per-expert row buffers, every expert on every
+        row of its buffer (the dense broadcast); or, with ``route = (ids,
+        weights, valid, first)``, the (N, H) rows themselves, dispatched
+        sparsely to the experts held (:func:`sparse_expert_ffn`)."""
+        if route is not None:
+            return sparse_expert_ffn(x, *route[:3], self._kernels(), route[3],
+                                     self.activation, self.dtype)
         return expert_ffn(x, self._kernels(), self.activation, self.dtype,
                           bitwise_tp=self.bitwise_tp,
                           keep_expert_axis=keep_expert_axis)
@@ -214,7 +353,7 @@ class MoE(nn.Module):
 
         expert_in = jnp.einsum("nec,nh->ech", dispatch.astype(cfg.dtype), tokens)
         expert_in = _expert_constraint(expert_in, P(dist.EXPERT_AXIS, None, None))
-        expert_out = Experts(E, H, cfg.ffn_size, cfg.activation, cfg.dtype,
+        expert_out = Experts(E, H, cfg.expert_ffn_size, cfg.activation, cfg.dtype,
                              int8=getattr(cfg, "int8_weights", False),
                              int8_groups=getattr(cfg, "int8_group_size", 0),
                              # explicit flag, NOT inferred from cfg.norm: bias
@@ -226,7 +365,22 @@ class MoE(nn.Module):
         out = jnp.einsum("nec,ech->nh", combine.astype(cfg.dtype), expert_out)
         if dist.has_mesh():
             out = dist.constrain(out, self._token_spec(B, T))
-        return out.reshape(B, T, H), aux_loss
+        out = out.reshape(B, T, H)
+        if cfg.moe_shared_experts:
+            out = out + self._shared(x)
+        return out, aux_loss
+
+    def _shared(self, x):
+        """The shared experts: one always-on FFN of their joint width,
+        computed once for every row (what every chip of an expert-parallel
+        deployment computes alike)."""
+        import dataclasses
+        from ..models.transformer import MLP
+        cfg = self.cfg
+        wide = dataclasses.replace(
+            cfg, intermediate_size=cfg.moe_shared_experts * cfg.expert_ffn_size)
+        with jax.named_scope("moe_shared"):
+            return MLP(wide, name="shared_expert")(x)
 
     def _serving(self, x, q_spans, expert_ops):
         """Serving forward: per-token capacity-free top-k dispatch.
@@ -236,9 +390,12 @@ class MoE(nn.Module):
         sharded over ``expert`` when it divides ``num_experts``, each shard
         computing its experts' FULL (H, F) contractions — then the (E, N, H)
         expert outputs ALL-GATHER to replicated (pure concatenation) and the
-        combine accumulates in fp32 over a FIXED increasing-expert-index
-        loop. No cross-shard reduction ever happens, so ep>1 logits are
-        bit-identical to the ep=1 replicated program's; a non-dividing
+        combine adds each row's k chosen results in fp32 in increasing
+        expert order (:func:`combine_chosen`, shared with the sparse
+        dispatch that one device takes). No cross-shard reduction ever
+        happens, so ep>1 logits are bit-identical to the ep=1 program's
+        (on the CPU, where both dispatches' products round alike; on the
+        chip the mesh paths agree among themselves); a non-dividing
         expert count skips the constraints entirely (loud replicated
         fallback, the engine's ready line says so).
 
@@ -255,36 +412,57 @@ class MoE(nn.Module):
         ``expert_stats`` collection (live columns only, per ``q_spans``) —
         the residency/replay signal and the load-balance telemetry. The
         collection is opt-in ``mutable``; when the caller doesn't open it,
-        the sow is dropped and XLA dead-code-eliminates the counts."""
+        the sow is dropped and XLA dead-code-eliminates the counts. Likewise
+        ``expert_choice``: the (B, T, k) expert ids each row chose, for a
+        reference that follows the program's routing."""
         cfg = self.cfg
         B, T, H = x.shape
         N, E = B * T, cfg.num_experts
         k = cfg.moe_top_k
+        first, held = cfg.moe_first_expert, cfg.experts_held
         tokens = x.reshape(N, H)
 
-        gate_w = self.param("gate", nn.initializers.normal(0.02), (H, E), jnp.float32)
-        logits = tokens.astype(jnp.float32) @ gate_w
-        weights = top_k_serving_weights(logits, k)  # (N, E) fp32, per-token
+        with jax.named_scope("moe_router"):
+            gate_w = self.param("gate", nn.initializers.normal(0.02), (H, E), jnp.float32)
+            logits = tokens.astype(jnp.float32) @ gate_w
+            if q_spans is not None:
+                valid = (jnp.arange(T)[None, :] < q_spans[:, None]).reshape(N)
+            else:
+                valid = jnp.ones((N, ), bool)
 
-        if q_spans is not None:
-            valid = (jnp.arange(T)[None, :] < q_spans[:, None]).reshape(N)
-        else:
-            valid = jnp.ones((N, ), bool)
-        counts = jnp.sum((weights > 0) & valid[:, None], axis=0,
-                         dtype=jnp.int32)  # (E,)
+        experts = Experts(held, H, cfg.expert_ffn_size, cfg.activation, cfg.dtype,
+                          int8=getattr(cfg, "int8_weights", False),
+                          int8_groups=getattr(cfg, "int8_group_size", 0),
+                          use_bias=getattr(cfg, "moe_expert_bias", False),
+                          bitwise_tp=getattr(cfg, "bitwise_tp", False),
+                          name="experts")
+        # the dense broadcast stays where the expert or tensor mesh axis is
+        # live (its all-gather combine is what keeps ep>1 bit-identical to
+        # ep=1) and for paged experts; everything on one device is sparse
+        sparse = expert_ops is None and _ep_size() == 1 and not _tp_live()
+        tally_dispatch(sparse)
+        with jax.named_scope("moe_router"):
+            ids, w = top_k_serving_choice(logits, k)  # (N, k) over ALL E, per token
+            w = w * cfg.moe_routed_scale
+            counts = jnp.zeros((E, ), jnp.int32).at[ids].add(
+                jnp.broadcast_to(valid[:, None], ids.shape).astype(jnp.int32))
         self.sow("expert_stats", "counts", counts)
+        self.sow("expert_choice", "ids", ids.reshape(B, T, k))
+        if sparse:
+            acc = experts(tokens, route=(ids, w, valid, first))
+            out = acc.astype(cfg.dtype).reshape(B, T, H)
+            return out + self._shared(x) if cfg.moe_shared_experts else out
 
+        if held != E:
+            raise NotImplementedError(
+                "a layer holding a share of the experts serves through the sparse "
+                "dispatch only: no live expert/tensor mesh axis, no paged experts")
         ep_ok = _ep_size() > 1 and E % _ep_size() == 0
         if expert_ops is None:
             xin = jnp.broadcast_to(tokens[None].astype(cfg.dtype), (E, N, H))
             if ep_ok:
                 xin = dist.constrain(xin, P(dist.EXPERT_AXIS, None, None))
-            eo = Experts(E, H, cfg.ffn_size, cfg.activation, cfg.dtype,
-                         int8=getattr(cfg, "int8_weights", False),
-                         int8_groups=getattr(cfg, "int8_group_size", 0),
-                         use_bias=getattr(cfg, "moe_expert_bias", False),
-                         bitwise_tp=getattr(cfg, "bitwise_tp", False),
-                         name="experts")(xin, keep_expert_axis=ep_ok)
+            eo = experts(xin, keep_expert_axis=ep_ok)
             if ep_ok:
                 eo = dist.constrain(eo, P(dist.EXPERT_AXIS, None, None))
                 # all-gather (exact concat) so the combine below reduces
@@ -298,11 +476,12 @@ class MoE(nn.Module):
                               bitwise_tp=getattr(cfg, "bitwise_tp", False))
             eo = jnp.take(phys, emap, axis=0)  # (E, N, H) logical expert outputs
 
-        # fixed-order fp32 combine: a strictly sequential expert-index walk
-        # gives every program variant (ep1/ep2, in-tree/paged) the same
-        # float addition order — einsum would leave the reduction order to
-        # each program's XLA schedule
-        acc = jnp.zeros((N, H), jnp.float32)
-        for e in range(E):
-            acc = acc + weights[:, e:e + 1] * eo[e].astype(jnp.float32)
-        return acc.astype(cfg.dtype).reshape(B, T, H)
+        # fixed-order fp32 combine of each row's k chosen results, the same
+        # expression for every program variant (sparse/dense, ep1/ep2,
+        # in-tree/paged): einsum would leave the reduction order to each
+        # program's XLA schedule
+        rank = expert_rank(ids)
+        ids, w = in_expert_order(ids, rank), in_expert_order(w, rank)
+        acc = combine_chosen(eo[ids, jnp.arange(N)[:, None]], w)  # (N, k, H) of (E, N, H)
+        out = acc.astype(cfg.dtype).reshape(B, T, H)
+        return out + self._shared(x) if cfg.moe_shared_experts else out

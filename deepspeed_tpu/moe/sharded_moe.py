@@ -103,16 +103,32 @@ def top_k_serving_weights(logits, k):
     what makes scheduler results slot/batch-independent and lets dead
     (span-0) pool rows carry garbage without perturbing live rows.
     """
+    return _serving_top_k(logits, k)[0]
+
+
+def _serving_top_k(logits, k):
+    """(weights (N, E), chosen expert ids (N, k) in the order chosen)."""
     N, E = logits.shape
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
     masked = logits.astype(jnp.float32)
     weights = jnp.zeros((N, E), jnp.float32)
+    ids = []
     for _ in range(k):
         idx = jnp.argmax(masked, axis=-1)
+        ids.append(idx.astype(jnp.int32))
         m = jax.nn.one_hot(idx, E, dtype=jnp.float32)
         weights = weights + m * probs
         masked = jnp.where(m > 0, -jnp.inf, masked)
     if k > 1:
         denom = jnp.sum(weights, axis=-1, keepdims=True)
         weights = weights / jnp.maximum(denom, 1e-9)
-    return weights
+    return weights, jnp.stack(ids, axis=-1)
+
+
+def top_k_serving_choice(logits, k):
+    """:func:`top_k_serving_weights` as ``(expert ids (N, k) int32, weights
+    (N, k) fp32)``: the same numbers, bit for bit, as the k non-zero entries
+    of each row. What a sparse dispatch sorts by; each row is a pure
+    function of its logits."""
+    weights, ids = _serving_top_k(logits, k)
+    return ids, jnp.take_along_axis(weights, ids, axis=-1)

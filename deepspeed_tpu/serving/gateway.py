@@ -1403,7 +1403,11 @@ class Gateway:
                           # step programs by the K/V commit they were built
                           # with: "scatter" ones relay the pool every step
                           "kv_commit_programs": dict(getattr(
-                              sched, "kv_commit_programs", {}))},
+                              sched, "kv_commit_programs", {})),
+                          # ... and, for MoE models, by the expert dispatch:
+                          # "dense" ones run every expert on every row
+                          "moe_dispatch_programs": dict(getattr(
+                              sched, "moe_dispatch_programs", {}))},
             "adapters": (sched.adapters.stats()
                          if sched.adapters is not None else None),
             "expert_store": (sched.experts.stats()

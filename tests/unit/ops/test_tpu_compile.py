@@ -273,6 +273,61 @@ def test_step_loop_carries_the_pool_in_one_layout(for_chip, name):
     assert around <= 2 * len(jax.tree_util.tree_leaves(pool))
 
 
+@pytest.mark.parametrize("step", ["decode", "span"])
+def test_latent_moe_step_program(for_chip, step):
+    """Mistral-Small-4-119B's sync at the published widths as the chip
+    benchmark serves it (64 slots, ``prefill_chunk`` 256, a 2,048-position
+    latent pool, 32 of the 128 experts held, a quarter of the vocabulary),
+    one layer deep, as ``DecodeScheduler._fused_fn`` builds it: a first
+    forward over the one column or over the whole (slots, chunk) block, then
+    a ``fori_loop`` of one-column steps on the donated pool, through
+    ``apply_with_cache`` (latent attention over the pool, the span commit of
+    latent rows, the sparse expert dispatch)."""
+    sds, _ = for_chip
+    slots, chunk, pool_len = 64, 256, 2048
+    base = get_model("mistral-small-4-119b")
+    model = type(base)(dataclasses.replace(
+        base.cfg, dtype=jnp.bfloat16, num_layers=1, moe_experts_held=32, vocab_size=32768,
+        max_seq_len=8192, attention_impl="flash", scan_layers=False))
+
+    def forward(pool, ids, pos, lengths, spans):
+        return model.apply_with_cache(params_box[0], ids, pool, 0, position_ids=pos,
+                                      write_index=lengths, q_spans=spans)
+
+    def sync(params, pool, ids, lengths, spans):
+        params_box[0] = params
+        pos = lengths[:, None] + jnp.arange(ids.shape[1])[None, :]
+        logits, pool = forward(pool, ids, pos, lengths, spans)
+        last = jnp.take_along_axis(logits, jnp.maximum(spans - 1, 0)[:, None, None], axis=1)[:, 0]
+        base_ = lengths + jnp.maximum(spans, 1) - 1
+        live = jnp.minimum(spans, 1)
+
+        def body(k, carry):
+            pool, tok = carry
+            lg, pool = forward(pool, tok[:, None], (base_ + k)[:, None], base_ + k, live)
+            return pool, jnp.argmax(lg[:, 0], -1).astype(jnp.int32)
+
+        return jax.lax.fori_loop(1, 4, body, (pool, jnp.argmax(last, -1).astype(jnp.int32)))
+
+    params_box = [None]
+    shaped = lambda tree, dt=None: jax.tree_util.tree_map(
+        lambda a: sds(a.shape, dt or a.dtype), tree)
+    params = shaped(jax.eval_shape(model.init_params, jax.random.key(0)), jnp.bfloat16)
+    pool = shaped(jax.eval_shape(lambda: model.init_cache(slots, pool_len)))
+    assert jax.tree_util.tree_leaves(pool)[0].shape == (slots, 1, pool_len, 320)
+    rows = sds((slots, ), jnp.int32)
+    compiled = jax.jit(sync, donate_argnums=(1, )).lower(
+        params, pool, sds((slots, 1 if step == "decode" else chunk), jnp.int32), rows,
+        rows).compile()
+    text = compiled.as_text()
+    assert "ragged-dot" in text and "tpu_custom_call" in text  # the grouped products, on the chip
+    mem = compiled.memory_analysis()
+    # one layer's weights (1.72 GB) and the head beside its temporaries: the
+    # six layers of the cell leave 16 GB - 10.85 GB - 0.5 GB for these
+    assert mem.temp_size_in_bytes < 4.0e9, mem
+    print(step, "temporaries", mem.temp_size_in_bytes)
+
+
 def test_generate_step_program(for_chip):
     """``InferenceEngine._fused_step``'s layer: ``fused_decode_block`` with
     the static-batch cache, gpt2-large, B=8."""
