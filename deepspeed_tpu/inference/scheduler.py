@@ -8,13 +8,20 @@ without recompiling anything.
 
 **Chunked prefill (Sarathi-Serve, default)**: admission never runs a
 monolithic whole-prompt prefill. Each scheduler iteration with a prefill in
-flight dispatches ONE fixed-shape fused program over ``(num_slots,
-prefill_chunk)`` query columns: live decode rows carry their single next
-token in column 0, the (at most one) in-flight prefill row carries up to
-``prefill_chunk`` prompt tokens, and per-row query spans mask the rest —
+flight dispatches ONE fixed-shape fused program whose ids are a
+``(num_slots, prefill_chunk)`` block: live decode rows carry their single
+next token in column 0, the (at most one) in-flight prefill row carries up
+to ``prefill_chunk`` prompt tokens, and per-row query spans mask the rest —
 then finishes the sync with the remaining ``steps_per_sync - 1`` decode
 steps in one on-device loop, so decode keeps its K-step dispatch
-amortization even while prefills chain back-to-back. Decode slots
+amortization even while prefills chain back-to-back. The plain
+per-projection program on one device runs that first forward over the live
+rows only, where the block is large enough for it to pay
+(:func:`_split_pays`): every slot's column 0, then the chunk as a
+``(1, prefill_chunk)`` forward over its own slot
+(:func:`_first_forward_live_rows`); the fused decode blocks, the extent,
+seq-parallel and adapter variants, a sharded pool and the speculative verify
+run the whole block. Decode slots
 therefore stall at most one chunk's compute per K tokens instead of a full
 prompt, TTFT/decode-p95 trade off via ``prefill_chunk``, and the compiled
 program count is O(1) in the prompt-length mix (no per-bucket prefills).
@@ -36,7 +43,8 @@ a few variants — width ``prefill_chunk`` for chunk syncs and width 1 for
 pure decode syncs, two step counts (K, and 1 for chunks with nothing to
 decode), each x greedy/sampling x logits collection — plus the slot-copy
 program. O(1) total regardless of the request mix, and fused-vs-decode
-results can never diverge because they share one step body.
+results can never diverge because they share one step body (a split chunk
+program's column IS the decode program's first forward, shape and all).
 
 Per-slot sampling parameters (do_sample / temperature / top_k / top_p) are
 runtime TENSORS, so requests with different sampling configs share one
@@ -132,7 +140,9 @@ hierarchical tier, ``serving/spec_steps``,
 ``serving/spec_draft_tokens``, ``serving/spec_accepted_tokens``;
 histograms ``serving/ttft_ms``, ``serving/step_ms``,
 ``serving/tokens_per_step``, ``serving/prefill_stall_ms``,
-``serving/spec_tokens_per_step``. Multi-LoRA adds
+``serving/spec_tokens_per_step``; counters ``serving/step_rows_run`` (rows
+a step program's forwards compute) and ``serving/step_rows_live`` (those
+among them inside a row's span), per dispatch. Multi-LoRA adds
 ``serving/adapter_{loads,evicts}`` + per-adapter
 ``serving/adapter/<id>/{loads,evicts,requests,tokens}`` (256-label cap),
 ``serving/adapter_swap_ms``, ``serving/adapter_kv_invalidated_tokens``, and
@@ -149,6 +159,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..comm import comm as dist
+from ..telemetry.capacity import program_shape
 from .engine import _round_up
 from .kv_cache import RadixPrefixCache, SlotKVCache, copy_slot, slot_slice, slot_update
 from .speculative import PromptLookupDrafter
@@ -166,6 +177,53 @@ _PROGRAM_LOCK = threading.RLock()
 # adapter store's negative-uid namespaces). Entries are pinned and held by
 # the owning scheduler; probes can never surface them.
 _EXT_NS = -0x10C7E57
+
+# Rows of one forward below which its time is the weight stream's: a v5e's
+# ridge is 197e12 / 819e9 = 240 operations a byte, and a bf16 weight gives 2
+# operations a row for its 2 bytes. A forward over r rows then costs about
+# max(1, r / 240) weight streams.
+_RIDGE_ROWS = 240
+
+
+def _split_pays(n, c):
+    """Whether a chunk sync's first forward is cheaper as two forwards over
+    its live rows (``n`` decode rows as one column, the chunk's ``c`` columns
+    over its own slot: two weight streams) than as one over the ``(n, c)``
+    block (one stream, n * c rows of compute). Splits at (64, 256), (24, 64)
+    and (8, 64); keeps (4, 16), (4, 64) and any block of one slot."""
+    streams = lambda rows: max(1.0, rows / _RIDGE_ROWS)
+    return streams(n * c) > streams(n) + streams(c)
+
+
+def _first_forward_live_rows(forward, pool, ids, lengths, spans):
+    """A chunk sync's first forward as two over its live rows, in place of one
+    over the ``(N, C)`` block: every slot's column 0 (the decode program's own
+    first forward; the chunk's row rides with span 0 and writes nothing), then
+    the chunk's columns as a (1, C) forward over its own slot's rows of the
+    pool. The chunk's row is the one whose span is over 1; with none (a
+    warm-up, a final chunk of one token, which rides the column) the second
+    forward runs slot 0 with span 0. ``forward(pool, ids, positions,
+    write_index, spans)`` returns (logits, pool, stats or None, choice or
+    None). Returns each row's last live logits, the pool, the two forwards'
+    summed stats and the choice in the block's (L, N, C, k) shape."""
+    C = ids.shape[1]
+    wide = spans > 1
+    ps = jnp.argmax(wide)
+    lg, pool, cnt, ch = forward(pool, ids[:, :1], lengths[:, None], lengths,
+                                jnp.where(wide, 0, spans))
+    take = jnp.where(wide[ps], spans[ps], 0)
+    start = lengths[ps]
+    lgc, rows, cntc, chc = forward(slot_slice(pool, ps), ids[ps][None],
+                                   (start + jnp.arange(C))[None], start[None], take[None])
+    pool = slot_update(pool, ps, rows)
+    last = jnp.where(wide[:, None], lgc[0, jnp.maximum(take - 1, 0)][None], lg[:, 0])
+    if cnt is not None:
+        cnt = cnt + cntc
+    if ch is not None:
+        ch = jnp.pad(ch, ((0, 0), (0, 0), (0, C - 1), (0, 0)))
+        own = jax.lax.dynamic_slice_in_dim(ch, ps, 1, axis=1)
+        ch = jax.lax.dynamic_update_slice_in_dim(ch, jnp.where(wide[ps], chc, own), ps, axis=1)
+    return last, pool, cnt, ch
 
 
 def _bucket_len(n, base, cap):
@@ -1850,11 +1908,13 @@ class DecodeScheduler:
                     n_delivered += 1
         return n_delivered
 
-    def _dispatch(self, fn, call_args, step_args):
+    def _dispatch(self, fn, call_args, step_args, spans):
         """Hand ONE compiled program to the device, under ``sched/dispatch``
         (whose start closes the open host gap: the device stops being idle
-        the moment the dispatch is enqueued). On a sampled sync, fences the
-        dispatch —
+        the moment the dispatch is enqueued), and count the rows its
+        forwards compute beside the live ones among them (``spans``: the
+        host's copy of the spans at ``step_args[4]``). On a sampled sync,
+        fences the dispatch —
         ``block_until_ready`` on the input pool (drain outstanding work) and
         on the result — so the measured wall time is this program's device
         time alone. The fence touches only arrays the pipeline already owns:
@@ -1862,13 +1922,20 @@ class DecodeScheduler:
         tuple (pool at [1], lens at [3], spans at [4]) used for batch-shape
         recovery; ``call_args`` is what the program actually takes."""
         cap = self.capacity
+        if cap is not None:  # the sink is on
+            key = cap.key_for(fn)
+            width, ksteps = program_shape(key)
+            split = self._splits_chunk(key)
+            N = self.cache.num_slots
+            self.telemetry.counter("serving/step_rows_run",
+                                   (N + width if split else N * width) + N * (ksteps - 1))
+            self.telemetry.counter("serving/step_rows_live", int(spans.sum())
+                                   + int(np.count_nonzero(spans)) * (ksteps - 1))
         if cap is None or not self._cap_sample:
             with self._span("sched/dispatch"), self.engine.mesh:
                 return self._run_program(fn, call_args)
         # one fenced dispatch per sampled sync, even across MoE replays
         self._cap_sample = False
-        from ..telemetry.capacity import program_shape
-        key = cap.key_for(fn)
         jax.block_until_ready(step_args[1])
         t0 = time.perf_counter()
         with self._span("sched/dispatch"), self.engine.mesh:
@@ -1876,16 +1943,14 @@ class DecodeScheduler:
         jax.block_until_ready(out)
         dur = time.perf_counter() - t0
         if key is not None:
-            spans = np.asarray(step_args[4])
             lens = np.asarray(step_args[3])
             live_ctx = lens[spans > 0] if spans.shape == lens.shape else lens
-            width, ksteps = program_shape(key)
             # the extent-walk kernels DMA every extent's pool column per KV
             # block, so their KV traffic prices at max_extents x contiguous
             kv_mult = (self.cache.max_extents
                        if key[0] in ("fused_ext", "fused_seqp") else 1)
             cap.observe_dispatch(key, dur, live_ctx, width, ksteps,
-                                 kv_mult=kv_mult)
+                                 kv_mult=kv_mult, split=split)
         return out
 
     def _run_program(self, fn, call_args):
@@ -1910,8 +1975,10 @@ class DecodeScheduler:
             self.telemetry.counter(f"serving/moe_{path}_programs")
         return out
 
-    def _call_step(self, fn, args, lora):
-        """Dispatch ONE step program, owning the MoE serving plumbing:
+    def _call_step(self, fn, args, lora, spans):
+        """Dispatch ONE step program (``spans``: the host's copy of
+        ``args[4]``, for :meth:`_dispatch`'s row counters), owning the MoE
+        serving plumbing:
 
         - dense models (or MoE with telemetry off and no offload): a plain
           dispatch, byte-identical to the pre-MoE scheduler;
@@ -1931,9 +1998,9 @@ class DecodeScheduler:
         """
         extra = (lora, ) if lora is not None else ()
         if not self._moe_stats:
-            return self._dispatch(fn, args + extra, args)
+            return self._dispatch(fn, args + extra, args, spans)
         if self.experts is None:
-            out = self._dispatch(fn, args + extra, args)
+            out = self._dispatch(fn, args + extra, args, spans)
             self._record_expert_stats(np.asarray(jax.device_get(out[-1])))
             return out[:-1]
         replays = 0
@@ -1943,7 +2010,7 @@ class DecodeScheduler:
         max_replays = 2 * self.experts.num_layers * self.experts.num_experts + 8
         while True:
             emap, pools, resident = self.experts.dispatch_operands()
-            out = self._dispatch(fn, args + extra + ((emap, pools), ), args)
+            out = self._dispatch(fn, args + extra + ((emap, pools), ), args, spans)
             counts = np.asarray(jax.device_get(out[-1]))[:, :-2]
             used = counts > 0
             if not self.experts.missing(used, resident).any():
@@ -2050,7 +2117,7 @@ class DecodeScheduler:
                     if eo is not None:
                         args = args + tuple(jnp.asarray(x) for x in eo)
                 try:
-                    out = self._call_step(fn, args, lora)
+                    out = self._call_step(fn, args, lora, spans)
                     break
                 except _ExpertOverflow as e:
                     self.cache.pool = e.pool
@@ -2117,7 +2184,7 @@ class DecodeScheduler:
                 if eo is not None:
                     args = args + tuple(jnp.asarray(x) for x in eo)
                 try:
-                    out = self._call_step(fn, args, lora)
+                    out = self._call_step(fn, args, lora, spans)
                     break
                 except _ExpertOverflow as e:
                     self.cache.pool = e.pool
@@ -2182,7 +2249,7 @@ class DecodeScheduler:
                     jnp.asarray(np.zeros(N, bool)),
                     jnp.asarray(np.ones(N, np.float32)), jnp.asarray(zeros),
                     jnp.asarray(np.ones(N, np.float32))) + tuple(ext_args)
-            out = self._call_step(fn, args, lora)
+            out = self._call_step(fn, args, lora, zeros)
             self.cache.pool = out[0]
 
         shapes = sorted({(K, C), (1, C), (K, 1)} | ({(1, 1)} if ladder else set()))
@@ -2276,7 +2343,7 @@ class DecodeScheduler:
             if eo is not None:
                 args = args + tuple(jnp.asarray(x) for x in eo)
         try:
-            out = self._call_step(fn, args, lora)
+            out = self._call_step(fn, args, lora, spans)
         except _ExpertOverflow as e:
             # a K-step sync's routing union outgrew the expert pool: advance
             # one token per row in overflow-safe groups instead
@@ -2346,7 +2413,7 @@ class DecodeScheduler:
                     jnp.asarray(seeds), jnp.asarray(steps), jnp.asarray(flags),
                     jnp.asarray(temps), jnp.asarray(topks), jnp.asarray(topps))
         try:
-            out = self._call_step(fn, args, lora)
+            out = self._call_step(fn, args, lora, spans)
         except _ExpertOverflow as e:
             # speculation is opportunistic — skip it for this sync and
             # advance one exact token per row (bit-identical either way)
@@ -2410,9 +2477,10 @@ class DecodeScheduler:
 
     # ------------------------------------------------------------------ fused chunk step
     def _fused_chunk_step(self):
-        """One fixed-shape fused SYNC over ``(num_slots, prefill_chunk)``
-        query columns plus the remaining ``steps_per_sync - 1`` decode
-        steps, all in one dispatch: live decode rows advance K tokens
+        """One fixed-shape fused SYNC whose ids are a ``(num_slots,
+        prefill_chunk)`` block (run whole, or as its live rows only:
+        :meth:`_splits_chunk`) plus the remaining ``steps_per_sync - 1``
+        decode steps, all in one dispatch: live decode rows advance K tokens
         (column 0 + the substeps), the in-flight prefill row consumes up to
         a chunk of prompt tokens (and, on its final chunk, starts decoding
         in the same dispatch), dead rows carry span 0 (their KV writes are
@@ -2486,7 +2554,7 @@ class DecodeScheduler:
             if eo is not None:
                 args = args + tuple(jnp.asarray(x) for x in eo)
         try:
-            out = self._call_step(fn, args, lora)
+            out = self._call_step(fn, args, lora, spans)
         except _ExpertOverflow as e:
             # the chunk's routing demand outgrew the expert pool: feed the
             # prefill alone in shrinking pieces, then advance decode rows
@@ -2580,10 +2648,22 @@ class DecodeScheduler:
                 else (self._pool_sharding, ) + (self._host_sharding, ) * aux_outs)
         return jax.jit(fn, donate_argnums=donate, out_shardings=outs)
 
+    def _splits_chunk(self, key):
+        """Whether the step program under ``key`` runs its first forward as
+        two over the live rows (:meth:`_fused_fn`): the plain per-projection
+        program, on one device, at a shape where that is cheaper
+        (:func:`_split_pays`). The fused decode blocks, the extent and
+        seq-parallel variants, adapters, a sharded pool and the verify
+        programs keep the whole block."""
+        return (isinstance(key, tuple) and key[0] == "fused" and key[-1] != "lora"
+                and self._shard_deg == 1
+                and _split_pays(self.cache.num_slots, key[3]))
+
     def _fused_fn(self, sampling, collect, ksteps, chunk, lora=False,
                   ext=False, seqp=False):
         """THE step program: per-row query spans over a fixed ``(num_slots,
-        chunk)`` ids block, then the sync's remaining ``ksteps - 1`` decode
+        chunk)`` ids block (one forward over the block, or two over its live
+        rows), then the sync's remaining ``ksteps - 1`` decode
         steps in the same on-device loop — one dispatch per scheduler
         iteration, so decode keeps its K-step amortization while prefills
         chain. A pure decode sync is the same program at ``chunk == 1``
@@ -2593,6 +2673,17 @@ class DecodeScheduler:
         compiled at most (greedy/sampling) x logits-collection x two step
         counts (K, and 1 for chunks with nothing to decode) x two widths
         (chunk, 1) regardless of the prompt-length mix.
+
+        Live rows only: where :meth:`_splits_chunk` says so (decided here,
+        when the program is built, from its key, the device count and the
+        shape), the first forward of a ``chunk > 1`` program is two
+        (:func:`_first_forward_live_rows`): the ``(num_slots, 1)`` column,
+        exactly the decode program's first forward, and the chunk's columns
+        as a ``(1, chunk)`` forward over its own slot's rows of the pool,
+        found from the spans at run time. Same key, same arguments, same
+        outputs (the routing choice keeps its (L, N, C, k) shape; the MoE
+        stats count two layer calls a layer for that phase); the substeps
+        are untouched.
 
         Substep write positions: each row continues at its own write head
         (``lengths + max(span, 1) - 1 + k``) — decode rows one past their
@@ -2640,6 +2731,7 @@ class DecodeScheduler:
         tag = ("fused_seqp" if seqp else "fused_ext" if ext
                else "fused_block" if fused_block else "fused")
         key = (tag, sampling, collect, chunk, ksteps) + (("lora", ) if lora else ())
+        split = self._splits_chunk(key)
 
         def build():
             model = self.engine.module
@@ -2676,7 +2768,6 @@ class DecodeScheduler:
                 eops = extra[i] if offload else None
                 C = ids.shape[1]
                 N = ids.shape[0]
-                pos = lengths[:, None] + jnp.arange(C)[None, :]
 
                 def forward(pool, tok_block, pos_block, widx, sp, seq_sh=False):
                     """One in-sync forward; returns (logits, pool, counts,
@@ -2698,17 +2789,21 @@ class DecodeScheduler:
                     cnt = self._moe_forward_stats(rest.pop(0)) if stats else None
                     return lg, pl, cnt, (rest.pop(0) if choice else None)
 
-                # only the first (wide) forward seq-shards: the substeps'
-                # single-column blocks can't split over the seq axis
-                logits, pool, total_cnt, choice0 = forward(pool, ids, pos, lengths,
-                                                           spans, seq_sh=seqp)
-                # each row's LAST live column: decode rows column 0, the
-                # prefill row its chunk fill - 1 (dead rows clamp to 0 —
-                # their token is garbage the host never reads)
-                last_col = jnp.maximum(spans - 1, 0)
-                l0 = jnp.take_along_axis(
-                    logits, last_col[:, None, None], axis=1)[:, 0].astype(jnp.float32)
-                l0 = _replicate_logits(l0, tp)
+                if split:
+                    l0, pool, total_cnt, choice0 = _first_forward_live_rows(
+                        forward, pool, ids, lengths, spans)
+                else:
+                    # only the first (wide) forward seq-shards: the substeps'
+                    # single-column blocks can't split over the seq axis
+                    logits, pool, total_cnt, choice0 = forward(
+                        pool, ids, lengths[:, None] + jnp.arange(C)[None, :], lengths,
+                        spans, seq_sh=seqp)
+                    # each row's LAST live column: decode rows column 0, the
+                    # prefill row its chunk fill - 1 (dead rows clamp to 0 —
+                    # their token is garbage the host never reads)
+                    last_col = jnp.maximum(spans - 1, 0)
+                    l0 = jnp.take_along_axis(logits, last_col[:, None, None], axis=1)[:, 0]
+                l0 = _replicate_logits(l0.astype(jnp.float32), tp)
                 tok0 = sample(l0, seeds, steps, flags, temps, topks, topps)
                 out_toks = jnp.zeros((K, N), jnp.int32).at[0].set(tok0)
                 out_logits = jnp.zeros((K, N, V) if collect else (), jnp.float32)

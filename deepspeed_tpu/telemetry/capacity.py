@@ -11,7 +11,8 @@ no-ops when the telemetry sink is disabled:
 - :class:`CapacityModel` — analytic FLOPs and HBM bytes per dispatched
   step program, derived from the model config and the dispatch's batch
   shape (live rows, per-row context, query columns, K substeps). The
-  numbers count what the DEVICE executes (the full padded slot block),
+  numbers count what the DEVICE executes (the full padded slot block, or
+  its column and its chunk where the scheduler splits a chunk sync),
   which is what makes the live MFU/bandwidth gauges roofline-honest and
   lets a test cross-check them against ``jit(...).lower().cost_analysis()``.
 
@@ -129,23 +130,28 @@ class CapacityModel:
         self.kv_bytes_per_token = float(kv_bytes_per_token)
         self.num_slots = int(num_slots)
 
-    def dispatch_cost(self, live_ctx, width, ksteps, kv_mult=1.0):
+    def dispatch_cost(self, live_ctx, width, ksteps, kv_mult=1.0, split=False):
         """(flops, hbm_bytes) for ONE step dispatch: ``width`` query columns
         over the full slot block plus ``ksteps - 1`` single-column substeps,
         with ``live_ctx`` the live rows' context lengths (attention + KV
         traffic scale with these). ``kv_mult`` scales the KV-read term for
         the multi-extent block walk — the extent kernel DMAs every extent's
         pool column per KV block, so its KV traffic is ``max_extents``× the
-        contiguous walk even when most extents sit behind the mask."""
+        contiguous walk even when most extents sit behind the mask.
+        ``split``: the program runs its first forward as one column over
+        every slot and ``width`` columns over one (the scheduler's
+        ``_splits_chunk``): ``num_slots + width`` rows, two weight streams."""
         ksteps = max(1, int(ksteps))
-        cols_full = self.num_slots * (max(1, int(width)) + (ksteps - 1))
+        width = max(1, int(width))
+        first_rows = self.num_slots + width if split else self.num_slots * width
+        cols_full = first_rows + self.num_slots * (ksteps - 1)
         ctx_sum = float(np.sum(live_ctx)) if len(live_ctx) else 0.0
-        cols_per_row = max(1, int(width)) + (ksteps - 1)
+        cols_per_row = width + (ksteps - 1)
         flops = (cols_full * self.matmul_flops_per_col
                  + cols_per_row * ctx_sum * self.attn_flops_per_ctx_tok)
-        bytes_ = ksteps * (self.weight_read_bytes
-                           + ctx_sum * self.kv_bytes_per_token
-                           * max(1.0, float(kv_mult)))
+        bytes_ = ((ksteps + bool(split)) * self.weight_read_bytes
+                  + ksteps * ctx_sum * self.kv_bytes_per_token
+                  * max(1.0, float(kv_mult)))
         return flops, bytes_
 
     def flops_per_token(self, ctx):
@@ -224,13 +230,13 @@ class CapacityMeter:
 
     # ---------------------------------------------------------------- sampling
     def observe_dispatch(self, key, dur_s, live_ctx, width, ksteps,
-                         kv_mult=1.0):
+                         kv_mult=1.0, split=False):
         """Fold one fenced dispatch sample into the live gauges. ``dur_s``
         is the fence-to-fence wall time of the dispatch alone."""
         if dur_s <= 0.0:
             return
         flops, bytes_ = self.model.dispatch_cost(live_ctx, width, ksteps,
-                                                 kv_mult)
+                                                 kv_mult, split)
         mfu = flops / dur_s / self.peak_flops
         bw = bytes_ / dur_s / self.peak_hbm_bw
         intensity = flops / max(1.0, bytes_)

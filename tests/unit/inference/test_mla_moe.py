@@ -347,3 +347,54 @@ def test_two_chunk_prompts_beside_decoding_rows_match_reference(tiny):
     for p, h in zip(prompts, handles):
         _assert_matches_reference(tree, hp, p, h)
     sched.cache.check_invariants()
+
+
+@pytest.mark.parametrize("ksteps", [1, 4])
+def test_split_chunk_program_keeps_choice_shape_and_counts_its_forwards(tiny, tmp_path, ksteps):
+    """An (8, 64) collecting chunk program that runs its first forward as two
+    over the live rows: the routing choice comes back in the block's
+    (L, N, C, k) shape with the column's choices in column 0 and the chunk's in
+    its row, equal to the whole-block program's, and the stats count two layer
+    calls a layer for the first phase and one a substep."""
+    model, params, _, _ = tiny
+    comm._state["mesh"] = None
+    from deepspeed_tpu.telemetry import set_sink
+    set_sink(None)
+
+    def run(whole_block):
+        eng = deepspeed_tpu.init_inference("tiny-mla-moe", params=params, config={
+            "dtype": "float32", "max_out_tokens": 128,
+            "continuous_batching": {"enabled": True, "num_slots": 8, "prefill_chunk": 64},
+            "telemetry": {"enabled": True, "output_path": str(tmp_path)}})
+        sched = eng.scheduler()
+        if whole_block:
+            sched._splits_chunk = lambda key: False
+        key = ("fused", False, True, 64, ksteps)
+        assert sched._splits_chunk(key) is not whole_block
+        rng = np.random.default_rng(4)
+        ids = rng.integers(0, 256, (8, 64)).astype(np.int32)
+        spans = np.array([1, 0, 1, 40, 0, 1, 0, 0], np.int32)  # three decode rows, a chunk of 40
+        lens = np.array([5, 0, 9, 0, 0, 3, 0, 0], np.int32)
+        zeros = np.zeros(8, np.int32)
+        fn = sched._fused_fn(False, True, ksteps, 64)
+        out = fn(eng.params, sched.cache.pool, ids, lens, spans, zeros.astype(np.uint32), zeros,
+                 zeros.astype(bool), np.ones(8, np.float32), zeros, np.ones(8, np.float32))
+        eng.telemetry.close()
+        set_sink(None)
+        return [np.asarray(x) for x in out[1:]]
+
+    toks, logits, first, substeps, stats = run(False)
+    btoks, blogits, bfirst, bsubsteps, bstats = run(True)
+    L, k = model.cfg.num_layers, model.cfg.moe_top_k
+    assert first.shape == bfirst.shape == (L, 8, 64, k)
+    assert substeps.shape == bsubsteps.shape == (ksteps, L, 8, k)
+    live = [0, 2, 5]
+    assert np.array_equal(first[:, live, 0], bfirst[:, live, 0])
+    assert np.array_equal(first[:, 3, :40], bfirst[:, 3, :40])
+    assert np.array_equal(substeps[:, :, live + [3]], bsubsteps[:, :, live + [3]])
+    assert np.array_equal(toks[:, live + [3]], btoks[:, live + [3]])
+    np.testing.assert_allclose(logits[:, live + [3]], blogits[:, live + [3]], rtol=0, atol=1e-6)
+    assert (stats[:, -1] == 2 + (ksteps - 1)).all() and (bstats[:, -1] == ksteps).all()
+    # the routed pairs of the live rows are the same whichever way they ran
+    assert np.array_equal(stats[:, :-2], bstats[:, :-2])
+    assert stats[:, :-2].sum() == L * k * ((3 + 40) + 4 * (ksteps - 1))

@@ -765,3 +765,183 @@ def test_spec_telemetry_counters(tmp_path, baseline):
                  "serving/kv_bytes_per_token", "serving/kv_cache_capacity_bytes",
                  "serving/kv_bytes_live"):
         assert name in text, f"{name} missing from telemetry stream"
+
+
+# ---------------------------------------------------------------------------
+# A chunk sync's first forward over its live rows only (`_fused_fn`'s split)
+
+SPLIT_SHAPE = dict(num_slots=8, prefill_chunk=64)  # over the threshold; (4, 16) is under it
+
+
+def _split_engine(model, params=None, whole_block=False, **cfg):
+    """An (8, 64) scheduler of ``model`` that collects logits, with the
+    scheduler module's shape rule as it is or (``whole_block``) answering no,
+    so the same traffic runs through the whole-block programs."""
+    from deepspeed_tpu.inference.scheduler import _split_pays
+    cfg["continuous_batching"] = dict(enabled=True, collect_logits=True, **SPLIT_SHAPE)
+    eng = make_engine(model, params=params, max_out_tokens=128, **cfg)
+    sched = eng.scheduler()
+    if whole_block:
+        sched._splits_chunk = lambda key: False
+    assert _split_pays(sched.cache.num_slots, sched.prefill_chunk)
+    return eng, sched
+
+
+def _mixed_traffic(sched, vocab):
+    """A non-final chunk on an idle pool (the K = 1 program), its final chunk,
+    then beside the rows that decode: a 65-token prompt (a non-final chunk and
+    a final chunk of one token), a 112-token prompt and a short one (the tiny
+    presets hold 128 positions). Returns each request's (tokens, logits)."""
+    rng = np.random.default_rng(5)
+    prompt = lambda n: [int(t) for t in rng.integers(3, vocab, n)]
+    handles = [sched.submit(prompt(100), max_new_tokens=14)]
+    sched.step()
+    assert sched._prefill is not None and not sched.active  # chunk on an idle pool
+    sched.step()
+    handles += [sched.submit(prompt(n), max_new_tokens=8) for n in (65, 112, 7)]
+    return [(h.result(), h.result_logits()) for h in handles]
+
+
+@pytest.fixture(scope="module", params=["tiny", "tiny-mla-moe"])
+def split_pair(request):
+    """(model name, params, results of the mixed traffic through the split
+    programs, the same through the whole-block programs, the split scheduler)."""
+    model = request.param
+    eng, sched = _split_engine(model)
+    params = jax.device_get(eng.params)
+    vocab = eng.model_config.vocab_size
+    split = _mixed_traffic(sched, vocab)
+    _, block_sched = _split_engine(model, params, whole_block=True)
+    block = _mixed_traffic(block_sched, vocab)
+    return model, params, split, block, sched, block_sched
+
+
+def test_split_chunk_sync_matches_whole_block(split_pair):
+    """(a) The split programs' tokens equal the whole-block programs' and
+    their logits agree within what the (slots, 1) and (slots, C) programs
+    already differ by; both ran the same program keys, and the split
+    scheduler's chunk programs say they split."""
+    _, _, split, block, sched, block_sched = split_pair
+    for (ta, la), (tb, lb) in zip(split, block):
+        assert list(ta) == list(tb)
+        np.testing.assert_allclose(la, lb, rtol=0, atol=1e-6)
+    assert set(sched._compiled) == set(block_sched._compiled)
+    chunk_keys = [k for k in sched._compiled if k != "copy" and k[3] == 64]
+    assert {k[4] for k in chunk_keys} == {1, 4}
+    assert all(sched._splits_chunk(k) for k in chunk_keys)
+    assert not any(sched._splits_chunk(k) for k in sched._compiled if k not in chunk_keys)
+    sched.cache.check_invariants()
+
+
+def test_split_decode_rows_bit_identical_to_decode_program(split_pair):
+    """(b) A row that decodes while chunks ride its syncs gets, bit for bit,
+    the logits the decode program gives it on a pool with no prefill: its
+    column is the decode program's own first forward."""
+    model, params, _, _, _, _ = split_pair
+    vocab = 256
+    rng = np.random.default_rng(9)
+    short = [int(t) for t in rng.integers(3, vocab, 9)]
+    long_prompt = [int(t) for t in rng.integers(3, vocab, 120)]
+    _, alone = _split_engine(model, params)
+    want = alone.submit(short, max_new_tokens=14)
+    want_tokens, want_logits = want.result(), want.result_logits()
+    _, sched = _split_engine(model, params)
+    got = sched.submit(short, max_new_tokens=14)
+    sched.step()  # one chunk prefills it and its first K tokens
+    rider = sched.submit(long_prompt, max_new_tokens=2)
+    chunk_syncs = 0
+    while not got.done:
+        chunk_syncs += not rider._req.out  # still prefilling: this sync carries its chunk
+        sched.step()
+    assert chunk_syncs == 2
+    assert list(got.result()) == list(want_tokens)
+    np.testing.assert_array_equal(got.result_logits(), want_logits)
+
+
+def test_split_leaves_other_slots_byte_stable(split_pair):
+    """(c) Span-0 rows and a retained prefix's slot are byte-stable across
+    split syncs, and a radix hit after them returns the same tokens."""
+    from deepspeed_tpu.inference.kv_cache import slot_slice
+    model, params, _, _, _, _ = split_pair
+    rng = np.random.default_rng(11)
+    kept = [int(t) for t in rng.integers(3, 256, 70)]
+    _, sched = _split_engine(model, params)
+    first = sched.submit(kept, max_new_tokens=6)
+    tokens = first.result()
+    slot = next(iter(sched.radix.match(kept)[1:]))
+    rows = lambda s: [np.asarray(x) for x in
+                      jax.tree_util.tree_leaves(slot_slice(sched.cache.pool, s))]
+    before, idle_before = rows(slot), rows(7)
+    other = sched.submit([int(t) for t in rng.integers(3, 256, 110)], max_new_tokens=6)
+    other.result()
+    assert other._req.slot != slot
+    for a, b in zip(before + idle_before, rows(slot) + rows(7)):
+        np.testing.assert_array_equal(a, b)
+    hits = sched.radix.hits
+    again = sched.submit(kept, max_new_tokens=6)
+    assert list(again.result()) == list(tokens) and sched.radix.hits == hits + 1
+    sched.cache.check_invariants()
+
+
+@pytest.mark.parametrize("case", ["fused_block", "ext", "seqp", "lora", "verify",
+                                  "tp2", "under_threshold", "warmed_count"])
+def test_programs_that_keep_the_whole_block(baseline, case):
+    """(e) Only the plain per-projection program on one device, over the
+    shape rule, splits; every other says it keeps the block, and a warmed
+    scheduler holds the programs it held."""
+    params, _ = baseline
+    cb = dict(enabled=True, **SPLIT_SHAPE)
+    plain = ("fused", False, False, 64, 4)
+    if case == "tp2":
+        sched = make_engine(params=params, continuous_batching=cb,
+                            tensor_parallel={"tp_size": 2}).scheduler()
+        assert sched._shard_deg == 2 and not sched._splits_chunk(plain)
+        return
+    if case == "under_threshold":
+        sched = make_engine(params=params, continuous_batching=dict(
+            enabled=True, num_slots=4, prefill_chunk=16)).scheduler()
+        assert not sched._splits_chunk(("fused", False, False, 16, 4))
+        return
+    sched = make_engine(params=params, continuous_batching=cb).scheduler()
+    assert sched._splits_chunk(plain) and sched._splits_chunk(("fused", True, True, 64, 1))
+    if case == "warmed_count":
+        sched.warm_programs(ladder=False)
+        block = make_engine(params=params, continuous_batching=cb).scheduler()
+        block._splits_chunk = lambda key: False
+        block.warm_programs(ladder=False)
+        assert set(sched._compiled) == set(block._compiled)
+        assert sched.compiled_program_count() == block.compiled_program_count() == 7
+        return
+    key = {"fused_block": ("fused_block", False, False, 64, 4),
+           "ext": ("fused_ext", False, False, 64, 4),
+           "seqp": ("fused_seqp", False, False, 64, 4),
+           "lora": plain + ("lora", ),
+           "verify": ("spec", False, False, 64)}[case]
+    assert not sched._splits_chunk(key)
+    assert not sched._splits_chunk(plain[:3] + (1, 4))  # the decode program is one column already
+
+
+@pytest.mark.parametrize("split", [True, False])
+def test_step_row_counters(tmp_path, baseline, split):
+    """(f) ``serving/step_rows_run`` and ``_live`` over three syncs of an
+    (8, 64) pool, K = 4: a 70-token prompt's non-final chunk on an idle pool
+    (the K = 1 program), its final chunk of 6, one decode sync."""
+    params, _ = baseline
+    eng = make_engine(params=params, continuous_batching=dict(enabled=True, **SPLIT_SHAPE),
+                      telemetry={"enabled": True, "output_path": str(tmp_path)})
+    sched = eng.scheduler()
+    if not split:
+        sched._splits_chunk = lambda key: False
+    h = sched.submit(list(range(3, 73)), max_new_tokens=6)
+    syncs = 0
+    while not h.done:
+        sched.step()
+        syncs += 1
+    assert syncs == 3
+    first = (8 + 64) if split else 8 * 64
+    tel = eng.telemetry
+    assert tel.counter_total("serving/step_rows_run") == first + (first + 3 * 8) + 4 * 8
+    assert tel.counter_total("serving/step_rows_live") == 64 + (6 + 3) + 4
+    tel.close()
+    from deepspeed_tpu.telemetry import set_sink
+    set_sink(None)

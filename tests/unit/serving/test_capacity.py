@@ -215,6 +215,20 @@ def test_observe_dispatch_roofline_classification():
     assert ent["bound"] in ("compute", "bandwidth")
 
 
+@pytest.mark.parametrize("split", [False, True])
+def test_dispatch_cost_prices_a_split_chunk_program(split):
+    """A chunk program that runs its first forward over the live rows is
+    priced at ``num_slots + width`` first-forward rows and two weight streams
+    for them, the whole block at ``num_slots * width`` rows and one."""
+    model = CapacityModel(type("C", (), {"hidden_size": 64, "num_layers": 2,
+                                         "num_heads": 4, "vocab_size": 128})(),
+                          kv_bytes_per_token=0, num_slots=8)
+    flops, bytes_ = model.dispatch_cost(np.zeros(0), width=64, ksteps=4, split=split)
+    first = 8 + 64 if split else 8 * 64
+    assert flops == (first + 3 * 8) * model.matmul_flops_per_col
+    assert bytes_ == (4 + split) * model.weight_read_bytes
+
+
 # ------------------------------------------------- analytic-model cross-check
 def test_capacity_model_flops_cross_check(params):
     """Analytic matmul+attention FLOPs vs XLA's own cost analysis of the
