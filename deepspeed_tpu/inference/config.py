@@ -312,6 +312,18 @@ class AutoscalerConfig(DeepSpeedConfigModel):
                                   "possibly a one-off tier-program warmup)")
 
 
+def check_prefill_chunk(value):
+    """``prefill_chunk`` as an int of at least 1 (the config field's validator
+    and :class:`DecodeScheduler`'s own check)."""
+    chunk = int(value)
+    if chunk < 1:
+        raise ValueError(
+            f"prefill_chunk must be at least 1, got {value!r}: the monolithic "
+            f"prefill path (prefill_chunk=0) was removed in PR 28; a chunk as "
+            f"wide as the prompt is its equivalent")
+    return chunk
+
+
 class ContinuousBatchingConfig(DeepSpeedConfigModel):
     """Continuous-batching serving path (``inference/scheduler.py``):
     iteration-level admission into a fixed slot-pool KV cache. When enabled,
@@ -323,9 +335,6 @@ class ContinuousBatchingConfig(DeepSpeedConfigModel):
                             "shape XLA compiles the decode step against")
     max_len = ConfigField(default=None, help="per-slot KV rows; default "
                           "min(model max_seq_len, max_out_tokens)")
-    prefill_bucket = ConfigField(default=64, help="prompt lengths round up to "
-                                 "powers of two from this floor (bounds prefill "
-                                 "compile count at ~log2(max_len/bucket))")
     collect_logits = ConfigField(default=False, help="also return per-step logits "
                                  "(debug/parity testing; fetches (slots, V) per token)")
     steps_per_sync = ConfigField(default=4, help="decode steps per host round trip "
@@ -333,17 +342,17 @@ class ContinuousBatchingConfig(DeepSpeedConfigModel):
                                  "amortizes dispatch/fetch K-fold; admission/eviction "
                                  "granularity becomes K tokens; results identical for "
                                  "any K (sampling keys use absolute step indices)")
-    prefill_chunk = ConfigField(default=64, help="chunked prefill (Sarathi-Serve): "
+    prefill_chunk = ConfigField(default=64, validator=check_prefill_chunk,
+                                help="chunked prefill (Sarathi-Serve): "
                                 "admission feeds at most this many prompt tokens per "
                                 "fused chunk+decode step, so live decode rows stall one "
                                 "chunk instead of a whole prompt (smaller = better "
-                                "decode p95, worse TTFT); 0 restores the monolithic "
-                                "pow2-bucketed prefill path")
+                                "decode p95, worse TTFT); at least 1")
     prefix_cache = ConfigField(default=True, help="radix prefix cache (SGLang "
                                "RadixAttention): retain finished slots' prompt KV in a "
                                "token trie and seed new requests from the longest "
                                "matched prefix (LRU eviction when admission needs a "
-                               "slot); chunked-prefill mode only")
+                               "slot)")
     spec_tokens = ConfigField(default=0, help="self-speculative decoding (Leviathan "
                               "et al. / prompt-lookup drafting): up to this many "
                               "host-drafted tokens verified per decode step through "

@@ -741,13 +741,12 @@ class InferenceEngine:
         """The engine's continuous-batching :class:`DecodeScheduler`
         (``inference/scheduler.py``), built lazily from the
         ``continuous_batching`` config section. ``overrides`` replace config
-        fields (num_slots/max_len/prefill_bucket/collect_logits) on first
+        fields (num_slots/max_len/collect_logits/...) on first
         construction."""
         if self._scheduler is None:
             from .scheduler import DecodeScheduler
             cb = self._config.continuous_batching
             kw = {"num_slots": cb.num_slots, "max_len": cb.max_len,
-                  "prefill_bucket": cb.prefill_bucket,
                   "collect_logits": cb.collect_logits,
                   "steps_per_sync": cb.steps_per_sync,
                   "prefill_chunk": cb.prefill_chunk,

@@ -6,8 +6,8 @@ free KV-cache slots at TOKEN-ITERATION granularity — a finished sequence
 evicts mid-loop and the next queued request joins the very next decode step,
 without recompiling anything.
 
-**Chunked prefill (Sarathi-Serve, default)**: admission never runs a
-monolithic whole-prompt prefill. Each scheduler iteration with a prefill in
+**Chunked prefill (Sarathi-Serve)**: admission never runs a whole prompt
+as one forward of its own. Each scheduler iteration with a prefill in
 flight dispatches ONE fixed-shape fused program whose ids are a
 ``(num_slots, prefill_chunk)`` block: live decode rows carry their single
 next token in column 0, the (at most one) in-flight prefill row carries up
@@ -25,8 +25,6 @@ run the whole block. Decode slots
 therefore stall at most one chunk's compute per K tokens instead of a full
 prompt, TTFT/decode-p95 trade off via ``prefill_chunk``, and the compiled
 program count is O(1) in the prompt-length mix (no per-bucket prefills).
-``prefill_chunk=0`` restores the legacy monolithic pow2-bucketed prefill
-path.
 
 **Radix prefix cache (SGLang RadixAttention)**: finished slots are retained
 (not scrubbed) and their prompts registered in a token trie
@@ -102,8 +100,7 @@ Base-only dispatches run the byte-identical pre-adapter program variant.
 Radix/host-tier prefix registrations carry the adapter uid (per-adapter
 trie roots + negative-sentinel store namespaces): cross-adapter KV reuse is
 structurally impossible, and a page eviction or adapter reload queues an
-invalidation this scheduler drains on its own pump thread. Chunked-prefill
-mode only.
+invalidation this scheduler drains on its own pump thread.
 
 **Weight-swap protocol** (RLHF hybrid engine, ``deepspeed_tpu/rlhf/``):
 ``pause()`` gates admission, ``flush()`` drains in-flight rows under the
@@ -139,10 +136,10 @@ Telemetry (PR-1 sink): gauges ``serving/slot_occupancy``,
 hierarchical tier, ``serving/spec_steps``,
 ``serving/spec_draft_tokens``, ``serving/spec_accepted_tokens``;
 histograms ``serving/ttft_ms``, ``serving/step_ms``,
-``serving/tokens_per_step``, ``serving/prefill_stall_ms``,
-``serving/spec_tokens_per_step``; counters ``serving/step_rows_run`` (rows
-a step program's forwards compute) and ``serving/step_rows_live`` (those
-among them inside a row's span), per dispatch. Multi-LoRA adds
+``serving/tokens_per_step``, ``serving/spec_tokens_per_step``; counters
+``serving/step_rows_run`` (rows a step program's forwards compute) and
+``serving/step_rows_live`` (those among them inside a row's span), per
+dispatch. Multi-LoRA adds
 ``serving/adapter_{loads,evicts}`` + per-adapter
 ``serving/adapter/<id>/{loads,evicts,requests,tokens}`` (256-label cap),
 ``serving/adapter_swap_ms``, ``serving/adapter_kv_invalidated_tokens``, and
@@ -160,6 +157,7 @@ import numpy as np
 
 from ..comm import comm as dist
 from ..telemetry.capacity import program_shape
+from .config import check_prefill_chunk
 from .engine import _round_up
 from .kv_cache import RadixPrefixCache, SlotKVCache, copy_slot, slot_slice, slot_update
 from .speculative import PromptLookupDrafter
@@ -224,16 +222,6 @@ def _first_forward_live_rows(forward, pool, ids, lengths, spans):
         own = jax.lax.dynamic_slice_in_dim(ch, ps, 1, axis=1)
         ch = jax.lax.dynamic_update_slice_in_dim(ch, jnp.where(wide[ps], chc, own), ps, axis=1)
     return last, pool, cnt, ch
-
-
-def _bucket_len(n, base, cap):
-    """Prefill bucket: next power of two >= n (floor ``base``), capped at
-    ``cap``. Geometric buckets bound the compiled-prefill count at
-    ~log2(cap/base) while wasting at most 2x prefill compute."""
-    b = base
-    while b < n:
-        b *= 2
-    return min(b, cap)
 
 
 def _replicate_logits(l, tp_size):
@@ -420,14 +408,14 @@ class DecodeScheduler:
     against); ``max_len`` is the per-slot KV capacity. Requests whose
     ``prompt + max_new_tokens`` exceed ``max_len`` are rejected at submit.
 
-    ``prefill_chunk`` > 0 (default) fuses admission into the decode step in
-    chunks of that many prompt tokens (see module docstring); 0 restores
-    the legacy monolithic pow2-bucketed prefill. ``prefix_cache`` retains
-    finished prefixes for cross-request KV reuse (chunked mode only: reuse
-    rounds matches to chunk boundaries to keep hit/cold paths bit-identical).
+    ``prefill_chunk`` (at least 1) is how many prompt tokens of the one
+    in-flight prefill ride each sync (see module docstring).
+    ``prefix_cache`` retains finished prefixes for cross-request KV reuse
+    (matches round down to chunk boundaries, which keeps hit and cold paths
+    bit-identical).
     """
 
-    def __init__(self, engine, num_slots=8, max_len=None, prefill_bucket=64,
+    def __init__(self, engine, num_slots=8, max_len=None,
                  collect_logits=False, steps_per_sync=4, prefill_chunk=64,
                  prefix_cache=True, spec_tokens=0, spec_ngram_max=3,
                  spec_ngram_min=1, kv_cache_dtype="auto", compiled_cache=None,
@@ -444,7 +432,7 @@ class DecodeScheduler:
         # prefix (or an adapter/expert page) computed/loaded on replica A
         # servable on replica B
         self._init_kwargs = dict(
-            num_slots=num_slots, max_len=max_len, prefill_bucket=prefill_bucket,
+            num_slots=num_slots, max_len=max_len,
             collect_logits=collect_logits, steps_per_sync=steps_per_sync,
             prefill_chunk=prefill_chunk, prefix_cache=prefix_cache,
             spec_tokens=spec_tokens, spec_ngram_max=spec_ngram_max,
@@ -475,7 +463,6 @@ class DecodeScheduler:
             raise ValueError(f"model max_seq_len {model.cfg.max_seq_len} leaves no "
                              f"room for a KV slot")
         self.max_len = S
-        self.prefill_bucket = int(prefill_bucket)
         self.collect_logits = bool(collect_logits)
         # multi-step scheduling (vLLM --num-scheduler-steps): K decode steps
         # per host round trip. The K-step program is ONE compiled XLA loop,
@@ -486,26 +473,16 @@ class DecodeScheduler:
         self.steps_per_sync = max(1, int(steps_per_sync))
         # chunked prefill: clamp the chunk to the slot capacity (a chunk
         # wider than a slot could never land a full write)
-        self.prefill_chunk = min(max(0, int(prefill_chunk)), S)
+        self.prefill_chunk = min(check_prefill_chunk(prefill_chunk), S)
         # ---- long-context serving: multi-extent paged KV, seq-parallel
         # chunked prefill, mid-decode cold-range demotion ------------------
         me = max(1, int(max_extents))
-        if me > 1 and self.prefill_chunk <= 0:
-            raise ValueError(
-                "long_context.max_extents > 1 requires chunked prefill "
-                "(prefill_chunk > 0): the monolithic prefill path writes one "
-                "contiguous slot and has no extent plumbing")
         # a chain's logical positions are bounded by the model's rope/mask
         # horizon — extents past max_seq_len could never hold a valid row
         me = max(1, min(me, model.cfg.max_seq_len // S))
         self.allow_lossy_kv = bool(allow_lossy_kv)
         self.seq_parallel_min_tokens = max(0, int(seq_parallel_min_tokens))
         seq_on = self.seq_parallel_min_tokens > 0
-        if seq_on and self.prefill_chunk <= 0:
-            raise ValueError(
-                "seq_parallel_min_tokens > 0 requires chunked prefill "
-                "(prefill_chunk > 0): sequence parallelism shards the "
-                "chunked path's wide prefill forwards")
         seq_ax = int(engine.mesh.shape[dist.SEQ_AXIS])
         tp_ax = int(engine.mesh.shape[dist.TENSOR_AXIS])
         self._seq_shards = seq_ax if (seq_on and seq_ax > 1) else 1
@@ -578,15 +555,14 @@ class DecodeScheduler:
         self.spec_drafted = 0     # draft tokens submitted to verification
         self.spec_accepted = 0    # draft tokens that committed
         self.spec_delivered = 0   # tokens delivered by spec steps
-        # radix prefix cache: chunked-mode only — reuse rounds matches to
-        # chunk boundaries so a hit replays the cold path's exact programs
-        self.radix = (RadixPrefixCache(self.cache)
-                      if prefix_cache and self.prefill_chunk > 0 else None)
+        # radix prefix cache: reuse rounds matches to chunk boundaries so a
+        # hit replays the cold path's exact programs
+        self.radix = RadixPrefixCache(self.cache) if prefix_cache else None
         # hierarchical KV tier: a shared GlobalPrefixStore turns radix
         # eviction into demotion (device -> host/NVMe) and admission into
         # restoration — LRU pressure stops destroying reuse, and the store
         # being fleet-global means ANY replica restores what any other
-        # computed. Chunked-radix mode only (restores replay the hit path).
+        # computed. Needs the radix cache (restores replay the hit path).
         self.kv_tier = None
         if prefix_store is not None and self.radix is not None:
             from ..memory.kv_tier import KVTier
@@ -595,19 +571,13 @@ class DecodeScheduler:
             self.radix.tier = self.kv_tier
         # multi-LoRA serving (deepspeed_tpu/adapters/): per-request model
         # variants gathered from the shared paged adapter store inside the
-        # fused step programs. Chunked-radix mode only — the monolithic
-        # prefill path has no adapter plumbing (submit validates). The
-        # store's invalidation listeners queue adapter uids here; step()
-        # drains them on THIS pump thread, so trie surgery never races a
-        # dispatch (the same single-threaded discipline as cancellation).
+        # step programs. The store's invalidation listeners queue adapter
+        # uids here; step() drains them on THIS pump thread, so trie surgery
+        # never races a dispatch (the same single-threaded discipline as
+        # cancellation).
         self.adapters = adapter_store
         self._adapter_invalidations = collections.deque()
         if adapter_store is not None:
-            if self.prefill_chunk <= 0:
-                raise ValueError(
-                    "multi-LoRA serving requires chunked prefill "
-                    "(prefill_chunk > 0): the monolithic prefill path has no "
-                    "per-row adapter plumbing")
             if self.radix is not None:
                 self.radix.adapter_ns = adapter_store.namespace
             adapter_store.add_listener(self._adapter_invalidations.append)
@@ -619,11 +589,6 @@ class DecodeScheduler:
         if expert_store is not None:
             if not self._moe:
                 raise ValueError("expert_store on a dense model (num_experts == 0)")
-            if self.prefill_chunk <= 0:
-                raise ValueError(
-                    "cold-expert offload requires chunked prefill "
-                    "(prefill_chunk > 0): the monolithic prefill path has no "
-                    "expert paging plumbing")
             topk = int(getattr(engine.model_config, "moe_top_k", 1))
             if expert_store.resident < topk:
                 raise ValueError(
@@ -833,8 +798,7 @@ class DecodeScheduler:
         # validate the PROMPT alone up front (before any early return): a
         # prompt that can never fit a slot must fail here with a clear
         # message, not deep inside a compiled prefill
-        cap = (self.cache.spannable_len if self.prefill_chunk > 0
-               else self.max_len)
+        cap = self.cache.spannable_len
         if req.prompt.size >= cap:
             raise ValueError(
                 f"prompt of {req.prompt.size} tokens exceeds the per-slot KV capacity "
@@ -1114,10 +1078,9 @@ class DecodeScheduler:
 
     # ------------------------------------------------------------------ loop
     def step(self):
-        """One scheduler iteration: settle cancellations, admit (chunked: at
-        most one in-flight prefill; legacy: while slots are free), then
-        advance — one fused chunk+decode step while a prefill is in flight,
-        else ``steps_per_sync`` decode steps.
+        """One scheduler iteration: settle cancellations, admit (at most one
+        in-flight prefill), then advance — one fused chunk+decode step while
+        a prefill is in flight, else ``steps_per_sync`` decode steps.
 
         The iteration is the ``sched/step`` span; inside it ``sched/admit``,
         ``sched/assemble``, ``sched/dispatch``, ``sched/fetch`` and
@@ -1165,44 +1128,30 @@ class DecodeScheduler:
             # admitting new work in front of it; lossy rows drop extents
             # that slid outside their attention window
             self._service_long_context()
-        admitted = 0
         if self._paused:
-            pass  # swap protocol: no admission; in-flight work still advances
-        elif self.prefill_chunk > 0:
-            while self.queue and self.queue[0].cancelled:
-                self.queue.popleft().done = True
-            if self._prefill is None and self.queue:
-                # FIFO, except a request whose adapter bucket is pinned
-                # SOLID (every page held by live requests) must not
-                # head-of-line-block traffic that needs no page — scan past
-                # such heads to the first admissible request. KV-slot
-                # exhaustion still gates everyone equally: only the first
-                # non-skipped candidate is tried per iteration.
-                pick = None
-                for i, req in enumerate(self.queue):
-                    if req.cancelled:
-                        continue  # reaped when it reaches the head
-                    if (req.adapter_id is not None and self.adapters is not None
-                            and not self.adapters.acquirable(req.adapter_id)):
-                        continue  # its page pool is pinned solid: skip
-                    pick = i
-                    break
-                if pick is not None:
-                    req = self.queue[pick]
-                    slot, match = self._acquire_slot(req)
-                    if slot is not None:
-                        del self.queue[pick]
-                        self._begin_prefill(req, slot, match)
-                        admitted = 1
-        else:
-            while self.queue and self.cache.active_slots < self.cache.num_slots:
-                req = self.queue.popleft()
-                if req.cancelled:
-                    req.done = True
-                    continue
-                self._admit(req)
-                admitted += 1
-        return admitted
+            return 0  # swap protocol: no admission; in-flight work still advances
+        while self.queue and self.queue[0].cancelled:
+            self.queue.popleft().done = True
+        if self._prefill is not None:
+            return 0
+        # FIFO, except a request whose adapter bucket is pinned SOLID (every
+        # page held by live requests) must not head-of-line-block traffic
+        # that needs no page — scan past such heads to the first admissible
+        # request. KV-slot exhaustion still gates everyone equally: only the
+        # first non-skipped candidate is tried per iteration.
+        for i, req in enumerate(self.queue):
+            if req.cancelled:
+                continue  # reaped when it reaches the head
+            if (req.adapter_id is not None and self.adapters is not None
+                    and not self.adapters.acquirable(req.adapter_id)):
+                continue  # its page pool is pinned solid: skip
+            slot, match = self._acquire_slot(req)
+            if slot is None:
+                return 0
+            del self.queue[i]
+            self._begin_prefill(req, slot, match)
+            return 1
+        return 0
 
     def _iterate(self):
         """The body of :meth:`step`. Returns (tokens delivered, the kind of
@@ -1222,8 +1171,7 @@ class DecodeScheduler:
             admitted = self._admit_queued()
         if admitted and tel.enabled:
             tel.counter("serving/admitted", admitted)
-        fused = self._prefill is not None
-        if fused:
+        if self._prefill is not None:
             kind = "fused"
             delivered, ksteps = self._fused_chunk_step()
         elif self.active:
@@ -1588,7 +1536,7 @@ class DecodeScheduler:
         req.adapter_ref = aref
         return slot, match
 
-    def _begin_prefill(self, req, slot, match=(0, None)):
+    def _begin_prefill(self, req, slot, match):
         """Start the chunked prefill for ``req`` on ``slot``: seed the slot
         with the longest matched prefix (``match`` from :meth:`_acquire_slot`,
         one compiled copy program) and leave the suffix to the fused chunk
@@ -1721,56 +1669,6 @@ class DecodeScheduler:
             tr.mark("decode")  # phase closes when the request finishes
         if req.collect_logits and last_logits is not None:
             req.logits.append(last_logits)
-        self._deliver(req, tok)
-
-    def _admit(self, req):
-        eng = self.engine
-        slot = self.cache.alloc(owner=req.rid)
-        assert slot is not None
-        req.slot = slot
-        L = req.prompt.size
-        Pb = _bucket_len(L, self.prefill_bucket, self.max_len)
-        ids = np.zeros((1, Pb), np.int32)
-        ids[0, :L] = req.prompt
-        fn = self._prefill_fn(Pb, req.collect_logits)
-        t_pf = self.telemetry.now()
-        try:
-            with eng.mesh:
-                out = fn(eng.params, self.cache.pool, jnp.asarray(ids),
-                         jnp.asarray(L, jnp.int32), jnp.asarray(slot, jnp.int32),
-                         jnp.asarray(req.seed, jnp.uint32),
-                         jnp.asarray(req.do_sample),
-                         jnp.asarray(req.temperature, jnp.float32),
-                         jnp.asarray(req.top_k, jnp.int32),
-                         jnp.asarray(req.top_p, jnp.float32))
-        except Exception:
-            # a failed prefill must not strand the slot (the pool would
-            # permanently lose capacity)
-            self.cache.free(slot)
-            raise
-        if req.collect_logits:
-            self.cache.pool, tok, logits = out
-            req.logits.append(np.asarray(jax.device_get(logits), np.float32))
-        else:
-            self.cache.pool, tok = out
-        tok = int(jax.device_get(tok))
-        self.cache.lengths[slot] = L
-        self.active[slot] = req
-        tel = self.telemetry
-        req.first_token_ts = tel.now()
-        if tel.enabled:
-            # monolithic prefill stalls every live decode row for the WHOLE
-            # prompt — the interference chunked prefill bounds at one chunk
-            tel.histogram("serving/prefill_stall_ms", (req.first_token_ts - t_pf) * 1e3)
-            tel.histogram("serving/prefill_wait_ms", (t_pf - req.submit_ts) * 1e3)
-            tel.histogram("serving/ttft_ms", (req.first_token_ts - req.submit_ts) * 1e3)
-            tel.gauge("serving/queue_depth", len(self.queue))
-        tr = req.trace
-        if tr is not None and tr.enabled:
-            tr.phase("prefill", start=t_pf, prompt=int(req.prompt.size),
-                     monolithic=True,
-                     ttft_ms=round((req.first_token_ts - req.submit_ts) * 1e3, 3))
-            tr.mark("decode")
         self._deliver(req, tok)
 
     def _deliver(self, req, tok):
@@ -2228,7 +2126,7 @@ class DecodeScheduler:
         (1, 1) program only the backoff ladder and an extent boundary reach
         (the serving CLI's start-up warm: what plain traffic dispatches)."""
         N = self.cache.num_slots
-        C = max(1, self.prefill_chunk)
+        C = self.prefill_chunk
         K = self.steps_per_sync
         zeros = np.zeros(N, np.int32)
         # multi-LoRA composes with offload: warm the lora program variants
@@ -2471,8 +2369,7 @@ class DecodeScheduler:
 
     def mean_spec_tokens_per_step(self):
         """Mean tokens delivered per (live row, speculative sync) — > 1.0
-        means speculation is netting multi-token steps (the bench's
-        acceptance criterion)."""
+        means speculation is netting multi-token steps."""
         return self.spec_delivered / self.spec_row_steps if self.spec_row_steps else 0.0
 
     # ------------------------------------------------------------------ fused chunk step
@@ -2561,13 +2458,6 @@ class DecodeScheduler:
             self.cache.pool = e.pool
             return self._fused_backoff(pf, live)
         toks_k, logits_k = self._fetch_block(out, collect, K)
-        if tel.enabled:
-            # the stall co-resident decode rows eat while a prefill chunk
-            # rides their sync (one chunk + K-1 substeps of compute; the
-            # monolithic path records the WHOLE prefill here). Measured
-            # through the block fetch — jit dispatch alone returns before
-            # the compute finishes on async backends
-            tel.histogram("serving/prefill_stall_ms", (tel.now() - t0) * 1e3)
         tr = preq.trace
         if tr is not None and tr.enabled:
             fid = self._trace_link(tr)
@@ -2931,35 +2821,6 @@ class DecodeScheduler:
         dst are runtime scalars, so every donor/recipient pair shares it."""
         return self._program("copy", lambda: self._jit_step(
             lambda pool, src, dst: copy_slot(pool, src, dst), 0, (0, )))
-
-    def _prefill_fn(self, Pb, collect):
-        """Single-request prefill into one pool slot, compiled per prompt
-        bucket ``Pb``: right-pad the prompt to ``Pb`` (padding rows are
-        causally invisible to the real tokens and get overwritten by later
-        decode writes), take the last real token's logits, sample token 0."""
-        key = ("prefill", Pb, collect)
-
-        def build():
-            model = self.engine.module
-            tp = self._shard_deg
-
-            def prefill(params, pool, ids, length, slot, seed, do_sample,
-                        temperature, top_k, top_p):
-                cache = slot_slice(pool, slot)
-                logits, cache = model.apply_with_cache(params, ids, cache, 0)
-                pool = slot_update(pool, slot, cache)
-                last = jnp.take_along_axis(
-                    logits, (length - 1)[None, None, None], axis=1)[0, 0].astype(jnp.float32)
-                last = _replicate_logits(last, tp)
-                tok = _sample_slot(seed, jnp.zeros((), jnp.int32), last, do_sample,
-                                   temperature, top_k, top_p)
-                if collect:
-                    return pool, tok, last
-                return pool, tok
-
-            return self._jit_step(prefill, 2 if collect else 1, (1, ))
-
-        return self._program(key, build)
 
     # ------------------------------------------------------------------ introspection
     def compiled_program_count(self):

@@ -84,7 +84,7 @@ class KVTier:
         token, so adapter-scoped and base entries share one store but can
         never cross-match; the entry's host ROWS cover ``tokens`` only."""
         m = len(tokens)
-        if m < max(self.sched.prefill_chunk, self.min_restore_tokens, 1):
+        if m < max(self.sched.prefill_chunk, self.min_restore_tokens):
             # below the restore threshold it could never be restored (the
             # match rounds to chunk multiples and honors min_restore_tokens)
             # — demoting it would only waste host RAM

@@ -695,11 +695,8 @@ def _qmm2d(x2d, qw, scales, out_dtype=None):
     """int8 matmul: ``x @ (dequant(qw))`` without a persistent bf16 weight.
 
     Default path is the Pallas w8a16 kernel (one-pass s8->bf16 widen, group
-    scales applied to the (M, N) partials after the dot): measured 469 GB/s
-    of int8 bytes at the decode shapes vs 387 for the best XLA lowering
-    (whose dequant only half-fuses into the dot) and 169 for a naive
-    dequantize-then-dot tile loop — see ``benchmarks/qmm_microbench.py``.
-    Set DSTPU_QMM_IMPL=xla to compare.
+    scales applied to the (M, N) partials after the dot; XLA's lowering only
+    half-fuses the dequant into the dot). Set DSTPU_QMM_IMPL=xla to compare.
 
     Under tensor parallelism the XLA path is used instead: pallas_call is
     opaque to the GSPMD partitioner, so tensor-sharded kernel_q operands

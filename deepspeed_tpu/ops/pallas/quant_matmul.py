@@ -7,18 +7,16 @@ weights stream from HBM as int8 and hit the MXU straight after an
 int8->bf16 widen — the bf16 weight matrix never exists in HBM, halving
 weight bandwidth (the decode-time bottleneck).
 
-Kernel design (microbenched on v5e, ``benchmarks/qmm_microbench.py``):
+Kernel design (its roofline share is not measured yet: ROADMAP S2):
 - The int8 block is converted bf16 in ONE VPU pass (no fp32 round-trip)
   and fed to the MXU; the per-group quantization scale is applied to the
   tiny ``(block_m, block_n)`` fp32 partial sum AFTER the dot — K*N scale
-  multiplies become M*N (M is the batch, ~8 at decode). This measured
-  ~2.8x the naive dequantize-then-dot tile loop (469 vs 169 GB/s of int8
-  bytes at decode shapes; bf16 streaming roof ~690 GB/s).
+  multiplies become M*N (M is the batch, ~8 at decode).
 - Scales load once per n-tile as a ``(G, block_n)`` block reused across
   the k grid, not replicated per k-step.
 - ``block_k`` = one quantization group so each k-block sees exactly one
   scale row; ``block_n`` as large as divides N (fewer grid steps keep the
-  DMA pipeline fed — block_n 2560 beat 512 by 1.7x).
+  DMA pipeline fed).
 
 Layout: x (M, K) bf16; qw (K, N) int8; scales (G, N) fp32 with group size
 K/G along the contraction dim.

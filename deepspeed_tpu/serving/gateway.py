@@ -1534,10 +1534,8 @@ class Gateway:
         # impossible requests 400 immediately instead of queueing first
         budget = _round_up(max(1, max_tokens), sched.steps_per_sync)
         # spannable capacity: one request may chain up to
-        # long_context.max_extents slot extents (chunked mode; the
-        # monolithic path stays bounded by one slot)
-        cap = (sched.cache.spannable_len if sched.prefill_chunk > 0
-               else sched.max_len)
+        # long_context.max_extents slot extents
+        cap = sched.cache.spannable_len
         if len(prompt) >= cap or len(prompt) + budget > cap:
             raise ValueError(
                 f"prompt ({len(prompt)} tokens) + max_tokens ({max_tokens}) exceeds "

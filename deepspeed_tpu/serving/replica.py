@@ -318,8 +318,7 @@ class ReplicaSet:
         # sticky prefix index: leading-chunk key -> replica idx (bounded LRU)
         self._sticky = collections.OrderedDict()
         self._sticky_capacity = int(sticky_capacity)
-        chunk = self.primary.prefill_chunk
-        self._sticky_chunk = chunk if chunk > 0 else 64
+        self._sticky_chunk = self.primary.prefill_chunk
         # disaggregated prefill/decode: the fleet-wide handoff queue (pull
         # model — decode pumps claim READY records as they find capacity)
         # plus the migrate-time knobs. Hooks install lazily the first time
@@ -668,10 +667,6 @@ class ReplicaSet:
         pump's concurrent pool updates. The pump executes it at its next
         ``admit_migrations`` turn, which both pump loops run BEFORE any
         step that could migrate."""
-        if self.primary.prefill_chunk <= 0:
-            raise ValueError("disaggregated serving requires chunked prefill "
-                             "(prefill_chunk > 0): migration hands off at "
-                             "chunk-prefill completion")
         for rep in self.replicas:
             rep.scheduler.migrate_hook = self._maybe_migrate
         self._warmup_pending = True
@@ -979,7 +974,7 @@ class ReplicaSet:
         if tel.enabled:
             tel.counter(f"serving/replica/{rep.idx}/dispatched")
 
-    # ---------------------------------------------------------------- drive (testing/bench)
+    # ---------------------------------------------------------------- drive (tests, dryrun)
     def pump_once(self):
         """One single-threaded fleet turn: let every replica claim parked
         handoffs, then step the non-idle ones. Returns whether anything
@@ -1000,8 +995,8 @@ class ReplicaSet:
 
     def drain_all_work(self):
         """Single-threaded convenience pump: step every replica (and place
-        parked migrations) until the whole fleet is idle (benches and
-        tests; the gateway runs one pump thread per replica instead)."""
+        parked migrations) until the whole fleet is idle (tests and the
+        multichip dryrun; the gateway runs one pump thread per replica)."""
         while True:
             if self.pump_once():
                 continue

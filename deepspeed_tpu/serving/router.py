@@ -32,7 +32,7 @@ the gateway:
 
 Worker protocol (all JSON over HTTP/1.1, ``Connection: close``):
 
-    POST /v1/workers/register   {wid, url, role, weights_version, ...}
+    POST /v1/workers/register   {wid, url, role, prefill_chunk, weights_version, ...}
     POST /v1/workers/heartbeat  {wid, signals, store, weights_version}
          -> 404 when unknown (restarted router): worker re-registers
     POST /v1/workers/deregister {wid}
@@ -126,9 +126,6 @@ class WorkerAgent:
                     "a prefill-role worker needs the hierarchical-KV prefix "
                     "store as the migration transport: enable "
                     "continuous_batching.disaggregation (or hierarchical_kv)")
-            if primary.prefill_chunk <= 0:
-                raise ValueError("cross-process handoff requires chunked "
-                                 "prefill (prefill_chunk > 0)")
             if gw.replicas._hooks_installed:
                 # in-process disaggregation owns the hook: a fleet that is
                 # ALSO phase-split internally migrates within the process
@@ -296,7 +293,7 @@ class _Worker:
         self.host = parsed.hostname
         self.port = parsed.port or 80
         self.role = role
-        self.prefill_chunk = int(prefill_chunk or 64)
+        self.prefill_chunk = int(prefill_chunk)
         self.weights_version = int(weights_version or 0)
         self.signals = dict(signals or {})
         self.store = None
@@ -460,10 +457,8 @@ class Router:
     # ------------------------------------------------------------------ placement
     def _sticky_key(self, prompt, adapter):
         # caller holds self._lock (non-reentrant)
-        chunk = 64
-        for w in self.workers.values():
-            chunk = w.prefill_chunk or chunk
-            break
+        # (with a candidate chosen, so a worker is registered)
+        chunk = next(iter(self.workers.values())).prefill_chunk
         return (adapter, tuple(prompt[:chunk]))
 
     def _record_sticky(self, key, wid):
@@ -657,13 +652,14 @@ class Router:
 
     async def _worker_register(self, body, writer):
         req = self._parse_json(body)
-        if not req or not req.get("wid") or not req.get("url"):
-            await self._json(writer, 400,
-                             {"error": {"message": "register needs wid+url"}})
+        chunk = req.get("prefill_chunk") if req else None
+        if (not req or not req.get("wid") or not req.get("url")
+                or not isinstance(chunk, int) or chunk < 1):
+            await self._json(writer, 400, {"error": {
+                "message": "register needs wid+url and an integer prefill_chunk >= 1"}})
             return
         wid = req["wid"]
-        w = _Worker(wid, req["url"], req.get("role", "mixed"),
-                    req.get("prefill_chunk", 64),
+        w = _Worker(wid, req["url"], req.get("role", "mixed"), chunk,
                     req.get("weights_version", 0), req.get("signals"))
         with self._lock:
             known = wid in self.workers

@@ -17,7 +17,7 @@ no-ops when the telemetry sink is disabled:
   lets a test cross-check them against ``jit(...).lower().cost_analysis()``.
 
 - :class:`CapacityMeter` — the per-compiled-program registry. Every
-  program the scheduler builds (fused/spec/prefill/copy/tier_slice/
+  program the scheduler builds (fused/spec/copy/tier_slice/
   tier_restore, LoRA variants included) registers here at warm/build
   time; a *sampled* fenced-timing window (every ``sample_every``-th sync,
   default 1/32 — the async dispatch pipeline is never fenced on the hot
@@ -164,7 +164,7 @@ def program_shape(key):
     """(width, ksteps) batch shape encoded in a compiled-program cache key:
     fused/fused_block keys carry (chunk, ksteps), spec/spec_block keys
     carry the draft width (the verify program scores ``width`` columns in
-    one pass); everything else (prefill/copy/tier ops) is shape-accounted
+    one pass); everything else (copy/tier ops) is shape-accounted
     as a single column. The ``*_block`` kinds are the fused decode-block
     retags — same tuple positions, priced separately in the roofline."""
     if (isinstance(key, tuple) and len(key) >= 5
@@ -179,7 +179,7 @@ def program_shape(key):
 
 def _program_kind(key):
     """Registry kind for a compiled-program cache key: the key's leading
-    tag (``fused``/``spec``/``prefill``/``copy``/``tier_slice``/...),
+    tag (``fused``/``spec``/``copy``/``tier_slice``/...),
     ``+lora`` suffixed for adapter variants."""
     if isinstance(key, tuple):
         kind = str(key[0])

@@ -1,6 +1,6 @@
 """Where compiled programs are kept between runs.
 
-One rule for every process entry point (``chip_smoke.py``, ``bench.py``,
+One rule for every process entry point (``chip_smoke.py``,
 ``python -m deepspeed_tpu.serving``, the launcher's user script): if
 ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it and nothing is set in
 code; otherwise the cache is one fixed directory inside the checkout. The

@@ -293,15 +293,8 @@ def test_longctx_telemetry_reaches_sink(long_baseline, tmp_path):
 
 
 def test_config_validation():
-    """Compose rules fail loudly at construction: extents need chunked
-    prefill; seq-parallel needs chunked prefill and tp=1; the long-context
-    machinery needs the flash paged path."""
-    eng = make_engine()
-    with pytest.raises(ValueError, match="prefill_chunk"):
-        eng.scheduler(prefill_chunk=0, max_extents=4)
-    eng2 = make_engine()
-    with pytest.raises(ValueError, match="prefill_chunk"):
-        eng2.scheduler(prefill_chunk=0, seq_parallel_min_tokens=32)
+    """Compose rules fail loudly at construction: seq-parallel needs tp=1;
+    the long-context machinery needs the flash paged path."""
     eng3 = make_engine(mesh_kw={"seq": 2, "tensor": 2})
     with pytest.raises(ValueError, match="tp=1"):
         eng3.scheduler(prefill_chunk=16, seq_parallel_min_tokens=32)

@@ -4,7 +4,7 @@ Covers the serving invariants the static-batch engine tests can't: admission
 and eviction at token-iteration granularity, queue saturation, slot reuse
 purity (a request's tokens AND logits must not depend on which slot it lands
 in or what else is in flight), and the compile-count bound that makes
-bucketed continuous batching viable on XLA.
+continuous batching viable on XLA.
 """
 
 import numpy as np
@@ -234,44 +234,17 @@ def _count_xla_compiles():
     return _XLA_COMPILES
 
 
-def test_compile_count_bounded_on_mixed_stream(baseline):
-    """Compile-count regression guard, legacy monolithic-prefill mode: a
-    mixed-length request stream must stay within the bucketed bound — one
-    decode program plus one prefill program per power-of-two bucket —
-    measured by actual XLA backend compiles (jax.monitoring), not just the
-    scheduler's own cache."""
-    params, _ = baseline
-    eng = make_sched_engine(params, num_slots=3)
-    sched = eng.scheduler(prefill_chunk=0)
-    # warm one 64-bucket request first: the first _admit also compiles a few
-    # one-off scalar-convert helpers that would otherwise pollute the count
-    sched.submit([1, 2], max_new_tokens=4).result()
-    compiles = _count_xla_compiles()
-    n_before = len(compiles)
-    lens = [2, 3, 5, 9, 17, 33, 40, 50, 63, 64, 65, 70, 90, 100]
-    handles = [sched.submit(list(range(1, n + 1)), max_new_tokens=4) for n in lens]
-    for h in handles:
-        h.result()
-    n_compiles = len(compiles) - n_before
-    # buckets hit: 64 (warmed) and 128 (lens>64) -> the stream may compile
-    # ONE new prefill program (the 128 bucket) and nothing else
-    assert sched.compiled_program_count() <= 3
-    assert n_compiles <= 2, f"XLA compiled {n_compiles} programs for a mixed stream"
-    # and the stream produced sane output
-    assert all(len(h.result()) == 4 for h in handles)
-
-
 def test_fused_compile_count_o1_in_length_mix(baseline):
-    """Compile-count guard for the CHUNKED path: the same mixed-length
-    stream through the fused chunk+decode sync compiles O(1) programs —
-    the fused sync (its K-step and, for idle-pool non-final chunks, 1-step
-    variants), its width-1 pure-decode variant, and the slot-copy program —
-    with NO per-bucket prefill growth (a bucketed run of this mix compiles
-    one prefill per power-of-two bucket on top)."""
+    """Compile-count guard: a mixed-length stream through the fused
+    chunk+decode sync compiles O(1) programs — the fused sync (its K-step
+    and, for idle-pool non-final chunks, 1-step variants), its width-1
+    pure-decode variant, and the slot-copy program — with no growth in the
+    prompt-length mix, measured by actual XLA backend compiles
+    (jax.monitoring), not just the scheduler's own cache."""
     params, _ = baseline
     eng = make_sched_engine(params, num_slots=3)
-    sched = eng.scheduler()  # chunked prefill + radix cache on by default
-    assert sched.prefill_chunk > 0 and sched.radix is not None
+    sched = eng.scheduler()  # radix cache on by default
+    assert sched.radix is not None
     compiles = _count_xla_compiles()
     n_before = len(compiles)
     lens = [2, 3, 5, 9, 17, 33, 40, 50, 63, 64, 65, 70, 90, 100]
@@ -328,20 +301,34 @@ def test_prompt_exceeding_capacity_rejected_at_submit(baseline):
     assert sched.cache.total_allocs == 0 and not sched.queue
 
 
-def test_chunked_prefill_matches_legacy(baseline):
-    """Multi-chunk prefill (prompt >> chunk) produces the same tokens as the
-    monolithic-prefill scheduler, for any chunk size. (generate() parity for
-    scheduler-servable prompt lengths is test_scheduler_matches_generate —
-    the static path can't fit this prompt's padded cache on the tiny model.)"""
+def test_chunk_size_does_not_change_tokens(baseline):
+    """Multi-chunk prefill (prompt >> chunk) produces the same tokens as one
+    chunk wider than the prompt (the whole prompt in a single forward), for
+    any chunk size. (generate() parity for scheduler-servable prompt lengths
+    is test_scheduler_matches_generate — the static path can't fit this
+    prompt's padded cache on the tiny model.)"""
     params, _ = baseline
     prompt = [int(t) for t in np.resize(np.arange(3, 40), 100)]
-    eng_leg = make_sched_engine(params)
-    out_leg = eng_leg.scheduler(prefill_chunk=0).submit(prompt, max_new_tokens=8).result()
-    assert len(out_leg) == 8
+    out_one = make_sched_engine(params).scheduler(prefill_chunk=128).submit(
+        prompt, max_new_tokens=8).result()
+    assert len(out_one) == 8
     for chunk in (16, 64):  # 7 chunks and 2 chunks through the state machine
         eng = make_sched_engine(params)
         got = eng.scheduler(prefill_chunk=chunk).submit(prompt, max_new_tokens=8).result()
-        assert (got == out_leg).all(), f"chunk={chunk} diverged from monolithic prefill"
+        assert (got == out_one).all(), f"chunk={chunk} diverged from the one-chunk prefill"
+
+
+@pytest.mark.parametrize("chunk", [0, -1])
+def test_prefill_chunk_below_one_is_refused(baseline, chunk):
+    """``prefill_chunk=0`` selected the monolithic bucketed prefill until
+    PR 28 removed it: the constructor and the config both say so."""
+    params, _ = baseline
+    msg = "monolithic prefill path .* was removed in PR 28.*wide as the prompt"
+    with pytest.raises(ValueError, match=msg):
+        make_sched_engine(params).scheduler(prefill_chunk=chunk)
+    with pytest.raises(ValueError, match=msg):
+        make_engine(params=params, continuous_batching={"enabled": True,
+                                                        "prefill_chunk": chunk})
 
 
 def test_decode_advances_during_chunked_prefill(baseline):
@@ -456,9 +443,9 @@ def test_prefix_cache_eviction_storm_through_scheduler(baseline):
     assert sched.cache.total_allocs == sched.cache.total_frees == 8
 
 
-def test_prefix_cache_and_stall_telemetry(tmp_path, baseline):
-    """Satellite: serving/prefix_cache_{hit,miss,evict} counters, the
-    hit-rate gauge, and the prefill_stall_ms histogram all reach the sink."""
+def test_prefix_cache_telemetry(tmp_path, baseline):
+    """Satellite: serving/prefix_cache_{hit,miss,evict} counters and the
+    hit-rate gauge reach the sink."""
     params, _ = baseline
     eng = make_sched_engine(params, num_slots=2,
                             telemetry={"enabled": True, "output_path": str(tmp_path)})
@@ -475,8 +462,7 @@ def test_prefix_cache_and_stall_telemetry(tmp_path, baseline):
     assert tel.counter_total("serving/prefix_cache_hit_tokens") == 64
     tel.flush()
     text = (tmp_path / "telemetry.jsonl").read_text()
-    for name in ("serving/prefix_cache_hit_rate", "serving/prefill_stall_ms"):
-        assert name in text, f"{name} missing from telemetry stream"
+    assert "serving/prefix_cache_hit_rate" in text
 
 
 def test_on_token_streams_in_delivery_order(baseline):

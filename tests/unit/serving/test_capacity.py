@@ -169,7 +169,7 @@ def test_program_shape_and_kind():
     assert program_shape(("fused", True, False, 8, 4, "lora")) == (8, 4)
     assert program_shape(("spec", False, False, 5)) == (5, 1)
     assert program_shape(("spec", False, False, 5, "lora")) == (5, 1)
-    assert program_shape(("prefill", 64, False)) == (1, 1)
+    assert program_shape(("fused_block", False, False, 64, 4)) == (64, 4)
     assert program_shape("copy") == (1, 1)
     assert _program_kind(("fused", True, False, 8, 4, "lora")) == "fused+lora"
     assert _program_kind(("spec", False, False, 5)) == "spec"

@@ -33,8 +33,10 @@ def _batch(bs=8, T=32, seed=0):
     return {"input_ids": np.random.default_rng(seed).integers(0, 256, (bs, T)).astype(np.int32)}
 
 
-def _engine(cfg, model=None):
+def _engine(cfg, model=None, devices=None):
     comm._state["mesh"] = None
+    if devices is not None:
+        comm.initialize_mesh(devices=devices)
     model = model or get_model("tiny")
     e, _, _, _ = deepspeed_tpu.initialize(model=model, config=cfg, rng_seed=0)
     return e, model
@@ -215,12 +217,22 @@ def test_zero_inference_generate_matches_dense():
 
 
 def test_nvme_tier_parity(tmp_path):
-    """nvme param store steps identically to the cpu store."""
-    cpu_e, _ = _engine(_cfg())
+    """nvme param store steps identically to the cpu store.
+
+    Both engines run on a one-device mesh. On the 8-device CPU mesh the
+    gradients themselves are not reproducible under load: against a saved
+    unloaded result, 2 of 238 cpu-store steps and 3 of 233 nvme-store steps
+    differed (eight processes and six busy loops on eight cores, PR 28), in the
+    same near-zero-gradient elements, where the first AdamW step is
+    lr * g / (|g| + eps) and the last place of g decides a share of lr. On one
+    device 472 of 472 steps under the same load were bit-equal. The stores'
+    write and read-back run on the host and do not depend on the mesh."""
+    one = jax.devices()[:1]
+    cpu_e, _ = _engine(_cfg(), devices=one)
     host_params = cpu_e.param_stream.get_params_tree()
 
     nvme_e, _ = _engine(_cfg(extra_zero={
-        "offload_param": {"device": "nvme", "nvme_path": str(tmp_path)}}))
+        "offload_param": {"device": "nvme", "nvme_path": str(tmp_path)}}), devices=one)
     nvme_e.param_stream.set_params_from_tree(host_params)
 
     b = _batch()

@@ -127,9 +127,12 @@ def main():
         metrics = json.loads(conn.getresponse().read())
         conn.close()
         compiled = metrics["scheduler"]["compiled_programs"]
-        if not (1 <= compiled <= 5):
+        # the bound is the server's start-up warm (warm_programs(ladder=False)):
+        # (K, C), (1, C) and (K, 1), greedy and sampled, and the slot copy.
+        # The traffic above must add none
+        if not (1 <= compiled <= 7):
             fail(f"compiled-program bound violated: {compiled}")
-        print(f"ok: compiled programs bounded ({compiled} <= 5)", flush=True)
+        print(f"ok: compiled programs bounded ({compiled} <= 7)", flush=True)
 
         # -- SIGTERM drain -------------------------------------------------
         proc.send_signal(signal.SIGTERM)
