@@ -133,6 +133,7 @@ def phase_kernels(model=MODEL, slots=8, chunk=64, pool_len=1024, train_batch=4,
     import jax.numpy as jnp
 
     from deepspeed_tpu.models import get_model
+    from deepspeed_tpu.models.transformer import kv_packs
     from deepspeed_tpu.ops.pallas import decode_attention as da
     from deepspeed_tpu.ops.pallas import decode_block as db
     from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
@@ -206,16 +207,21 @@ def phase_kernels(model=MODEL, slots=8, chunk=64, pool_len=1024, train_batch=4,
     def ref_decode(q, k, v, start, base):
         return _ref_span_attention(q[:, :, None], k, v, start, base)[:, :, 0]
 
+    def served(k, v):
+        """K and V as the pool of this head size holds them: one packed
+        leaf (keys, then values, on the last axis) at head size 64."""
+        return (jnp.concatenate([k, v], axis=-1), None) if kv_packs(hd) else (k, v)
+
     check("decode_attention", TOL,
-          lambda q, k, v, s, e: da.decode_attention(q, k, v, s, e, block_kv=block),
+          lambda q, k, v, s, e: da.decode_attention(q, *served(k, v), s, e, block_kv=block),
           lambda q, k, v, s, e: ref_decode(q, k, v, s, jnp.full((slots, ), e - 1)),
           q1, kc, vc, start, end)
     check("paged_decode_attention", TOL,
-          lambda q, k, v, s, b: da.paged_decode_attention(q, k, v, s, b + 1,
+          lambda q, k, v, s, b: da.paged_decode_attention(q, *served(k, v), s, b + 1,
                                                           block_kv=block),
           ref_decode, q1, kc, vc, start, base)
     check("paged_span_attention", TOL,
-          lambda q, k, v, s, b: da.paged_span_attention(q, k, v, s, b,
+          lambda q, k, v, s, b: da.paged_span_attention(q, *served(k, v), s, b,
                                                         block_kv=block),
           _ref_span_attention, qT, kc, vc, start, base)
     k8, v8, sc = jax.jit(quantize_kv_rows)(kc, vc)
@@ -227,11 +233,11 @@ def phase_kernels(model=MODEL, slots=8, chunk=64, pool_len=1024, train_batch=4,
 
     check("paged_decode_attention.int8kv", TOL,
           lambda q, k, v, s, b, sc: da.paged_decode_attention(
-              q, k, v, s, b + 1, block_kv=block, k_scale=sc, v_scale=sc),
+              q, *served(k, v), s, b + 1, block_kv=block, k_scale=sc, v_scale=sc),
           dequant(ref_decode), q1, k8, v8, start, base, sc)
     check("paged_span_attention.int8kv", TOL,
           lambda q, k, v, s, b, sc: da.paged_span_attention(
-              q, k, v, s, b, block_kv=block, k_scale=sc, v_scale=sc),
+              q, *served(k, v), s, b, block_kv=block, k_scale=sc, v_scale=sc),
           dequant(_ref_span_attention), qT, k8, v8, start, base, sc)
 
     # ---- fused decode blocks and the logits head, decode and span rows

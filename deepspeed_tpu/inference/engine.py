@@ -652,16 +652,16 @@ class InferenceEngine:
             sin, cos = rope_table(mc.rotary_dim or mc.head_size,
                                   mc.max_seq_len, mc.rope_theta)
             rope = (sin[pos_rows], cos[pos_rows])
-        cks, cvs = caches
-        new_ck, new_cv = [], []
+        # the float cache of init_cache: (k leaves, v leaves) or, packed,
+        # (kv leaves, ); the block takes a layer's leaves as they are
+        new_layers = []
         for i, (norms, qkv, o, up, down, gate) in enumerate(layers):
-            x, ck, cv = fused_decode_block(
-                x, norms, cks[i], cvs[i], qkv, o, up, down, pads, pos,
-                activation=mc.activation, eps=mc.layernorm_epsilon,
+            x, kv = fused_decode_block(
+                x, norms, tuple(comp[i] for comp in caches), qkv, o, up, down,
+                pads, pos, activation=mc.activation, eps=mc.layernorm_epsilon,
                 block_kv=mc.decode_block_kv, norm=mc.norm, rope=rope,
                 gate=gate)
-            new_ck.append(ck)
-            new_cv.append(cv)
+            new_layers.append(kv)
         x32 = x.astype(jnp.float32)
         if "final_bias" in head:  # layernorm head
             mu = jnp.mean(x32, axis=-1, keepdims=True)
@@ -676,7 +676,8 @@ class InferenceEngine:
                               block_m=8)[:, :mc.vocab_size].astype(jnp.float32)
         if "logits_bias" in head:
             logits = logits + head["logits_bias"]
-        return logits, (tuple(new_ck), tuple(new_cv))
+        return logits, tuple(tuple(lay[j] for lay in new_layers)
+                             for j in range(len(caches)))
 
     def _build_generate(self, B, P, S, W, max_gen, do_sample, temperature, top_k, top_p, eos, pad,
                         padded):
@@ -1045,9 +1046,9 @@ class InferenceEngine:
 
     def _init_cache(self, B, S, kv_dtype=None):
         """``kv_dtype``: None = the model compute dtype; "int8" = the
-        group-quantized paged KV tier (3-leaf cache with joint per-token-row
-        scales; serving ``kv_cache_dtype: int8``); any jnp float dtype =
-        an explicit-precision plain cache."""
+        group-quantized paged KV tier (int8 K/V leaves and a leaf of joint
+        per-token-row scales; serving ``kv_cache_dtype: int8``); any jnp
+        float dtype = an explicit-precision plain cache."""
         quantized = kv_dtype == "int8"
         key = ("init_cache", B, S, str(kv_dtype))
         if key not in self._compiled:

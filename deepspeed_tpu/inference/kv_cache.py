@@ -376,7 +376,8 @@ class SlotKVCache:
         """HBM bytes backing ONE cache row (all layers, K+V, and — on the
         int8 tier — the per-token scale leaves): every pool leaf keeps its
         slot and row axes, so per-row bytes fall out of leaf sizes
-        generically for both the plain and quantized layouts. 0 when the
+        generically for the plain and quantized layouts, split or packed
+        (the packed leaf holds the split pair's bytes). 0 when the
         pool is host-bookkeeping-only (tests)."""
         if self.pool is None:
             return 0
@@ -450,8 +451,10 @@ class SlotKVCache:
 def slot_slice(pool, slot):
     """Pure function: one slot's cache as a (B=1)-batch cache tree, for the
     single-request prefill program. Works on both layouts — stacked leaves
-    are (L, N, kv, S, hd) (slot axis 1), per-layer leaves (N, kv, S, hd)
-    (slot axis 0)."""
+    are (L, N, kv, S, lanes) (slot axis 1), per-layer leaves (N, kv, S,
+    lanes) (slot axis 0) — and on every geometry of ``init_cache`` (split
+    K and V leaves, the packed K/V leaf, the latent leaf, the int8 tier's
+    scale leaf): the slot axis is at ``ndim - 4`` in all of them."""
     return jax.tree_util.tree_map(
         lambda c: jax.lax.dynamic_slice_in_dim(c, slot, 1, axis=c.ndim - 4), pool)
 

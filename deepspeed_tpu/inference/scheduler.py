@@ -511,7 +511,7 @@ class DecodeScheduler:
                 "attention_impl='flash': the extent block walk and the "
                 "seq-sharded span kernel live in the paged Pallas path")
         # KV storage tier: "auto" rides the model compute dtype; "int8" is
-        # the group-quantized paged tier (3-leaf pool with joint per-token-
+        # the group-quantized paged tier (int8 K/V leaves, joint per-token-
         # row scales); explicit float names force that precision
         kvd = str(kv_cache_dtype or "auto").lower()
         if kvd in ("auto", "model", "none"):
@@ -618,6 +618,11 @@ class DecodeScheduler:
                 "model family without fused decode-block support"]
         # step programs built so far, by the K/V commit their trace took
         self.kv_commit_programs = {"inplace": 0, "scatter": 0}
+        # ... and the geometry init_cache gave the pool they carry: "packed"
+        # (K beside V in one 128-lane leaf a layer: head size 64), "split"
+        # or "latent"
+        from ..models.transformer import kv_pool_geometry
+        self.kv_pool_geometry = kv_pool_geometry(model.cfg, self.cache.pool)
         # ... and, for MoE models, by the expert dispatch it took
         self.moe_dispatch_programs = {"sparse": 0, "dense": 0}
         self._prefill = None  # at most one in-flight _PrefillState
@@ -683,6 +688,8 @@ class DecodeScheduler:
         self._iter = 0
         self._iter_links = None  # list while a traced sync is in flight
         self.telemetry = engine.telemetry
+        self.telemetry.gauge("serving/kv_pool_packed",
+                             int(self.kv_pool_geometry == "packed"))
         # set by serving/replica.py when this scheduler serves in a fleet;
         # request traces stamp it so the migration-aware trace_summary view
         # can pair prefill and decode replicas per request

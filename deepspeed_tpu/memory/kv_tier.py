@@ -26,7 +26,8 @@ mechanics and rides the shared streaming layer
 
 All dtype tiers ride through generically — the pool's flat leaf list is
 sliced/padded on the row axis (``ndim - 2``), which holds for plain bf16/
-fp32 pools and the 3-leaf int8 pool (k, v, per-token-row scales) alike.
+fp32 pools and the int8 pool (K/V leaves, split or packed, and per-token-row
+scales) alike.
 
 Compiled-program budget: exactly two programs (``tier_slice``,
 ``tier_restore``), warmed on the first demote/restore; every cycle after
@@ -171,6 +172,17 @@ class KVTier:
             # FINITE bit patterns (uninitialized bf16 bytes can be NaN)
             self._stage = [np.zeros(s.shape[:s.ndim - 4] + (1,) + s.shape[s.ndim - 3:],
                                     np.dtype(s.dtype)) for s in pool_leaves]
+        # rows written by a pool of another geometry (split K and V leaves
+        # against this pool's packed ones, another head count or dtype) are
+        # refused, never reinterpreted: everything but the row axis matches
+        off_rows = lambda x: (x.shape[:x.ndim - 2], x.shape[x.ndim - 1:], np.dtype(x.dtype))
+        if (len(leaves) != len(self._stage)
+                or any(off_rows(src) != off_rows(buf) for buf, src in zip(self._stage, leaves))):
+            raise ValueError(
+                f"KV rows of {len(leaves)} leaves {[tuple(x.shape) for x in leaves[:2]]}... do "
+                f"not have this pool's geometry ({len(self._stage)} leaves "
+                f"{[tuple(b.shape) for b in self._stage[:2]]}...): written by a pool of "
+                f"another layout")
         for buf, src in zip(self._stage, leaves):
             n = min(rows, src.shape[src.ndim - 2])
             buf[(Ellipsis, slice(0, n), slice(None))] = \

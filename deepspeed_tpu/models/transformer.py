@@ -514,28 +514,93 @@ def _paged_kernel_kw(kv_scale, ext_ops, tp_shard):
     return kw
 
 
+def kv_packs(head_size):
+    """THE rule of the K/V pool's geometry: a layer's K and V rest side by
+    side in one leaf ``(slots, kv_heads, S, 2 * head_size)`` where that
+    fills whole 128-lane tiles and a leaf of ``head_size`` lanes alone
+    would not (head size 64). A 64-lane leaf rests position-major on the
+    chip, no kernel reads it so, and the compiler relays every leaf on
+    entry to and exit from every program; the pair's rows rest as the
+    kernels read them. Head sizes 128 and 256 are dense split, 80 and 96
+    dense in neither form: they stay split."""
+    return (2 * head_size) % 128 == 0 and head_size % 128 != 0
+
+
+def kv_layer_leaves(cfg, layer_cache):
+    """One layer's cache as ``(K/V leaves, scale leaf or None, split)``,
+    from what a trace can see: the first leaf's last dimension against
+    ``cfg.head_size``. Packed (``split == cfg.head_size``: keys in lanes
+    ``[0, split)``, values after them): ``(kv, )`` or ``(kv, scale)``.
+    Split (``split == 0``): ``(k, v)`` or ``(k, v, scale)``. The number of
+    leaves alone does not tell: a packed int8 layer has two, as a split
+    float one has."""
+    hd, lanes = cfg.head_size, layer_cache[0].shape[-1]
+    n = 1 if lanes == 2 * hd else 2
+    if lanes not in (hd, 2 * hd) or not n <= len(layer_cache) <= n + 1:
+        raise ValueError(f"KV cache leaves {[c.shape for c in layer_cache]} are neither the "
+                         f"split nor the packed geometry of head size {hd}")
+    scale = layer_cache[n] if len(layer_cache) > n else None
+    return tuple(layer_cache[:n]), scale, (hd if n == 1 else 0)
+
+
+def kv_pool_geometry(cfg, kv_cache):
+    """``"latent"``, ``"packed"`` or ``"split"``: which of ``init_cache``'s
+    three geometries a cache tree has."""
+    if cfg.latent_width:
+        return "latent"
+    leaf = jax.tree_util.tree_leaves(kv_cache[0])[0]
+    return "packed" if leaf.shape[-1] == 2 * cfg.head_size else "split"
+
+
+def _kv_writes(cfg, layer_cache, k, v):
+    """A layer's cache and its fresh ``(B, nkv, T, hd)`` K and V rows as the
+    ``(pool leaf, fresh rows)`` pairs of a cache write, K/V leaves first:
+    the rows quantized where the layer has a scale leaf (int8 tier), and
+    joined on the last axis where its K and V rest packed. Returns
+    ``(writes, split, quantized)``, ``split`` as :func:`kv_layer_leaves`."""
+    kv_leaves, scale, split = kv_layer_leaves(cfg, layer_cache)
+    fresh, tail = (k, v), []
+    if scale is not None:
+        from ..ops.quantizer import quantize_kv_rows
+        *fresh, sc_new = quantize_kv_rows(k, v)
+        tail = [(scale, sc_new)]
+    if split:
+        fresh = (jnp.concatenate(fresh, axis=-1), )
+    return list(zip(kv_leaves, fresh)) + tail, split, scale is not None
+
+
+def _written_kv(written, split, quantized):
+    """``(k_cache, v_cache, scale)`` of a layer's written leaves as the
+    paged kernels' entry points take them: the packed leaf whole, as
+    ``k_cache`` with ``v_cache=None``."""
+    ck, cv = (written[0], None) if split else written[:2]
+    return ck, cv, (written[-1] if quantized else None)
+
+
 def _commit_span_rows(writes, write_index, q_spans, paged_kernels):
     """The span write of the slot pool, for :class:`Attention` and
     ``fused_paged_step`` alike: column ``j`` of row ``i`` lands at position
     ``write_index_i + j``; columns past the row's live span, and positions
     past the pool's ``S``, are DROPPED — padding never writes, so retained
     prefix slots and co-resident decode rows stay byte-stable. ``writes``:
-    ``(pool leaf, fresh rows)`` pairs, K and V first, then an int8 pool's
-    scale leaf (its S axis matches theirs).
+    ``(pool leaf, fresh rows)`` pairs, the K/V leaves first (the packed
+    leaf with its joined rows, or K and V), then an int8 pool's scale leaf
+    (its S axis matches theirs).
 
     ``paged_kernels``: the caller attends through the paged Pallas kernels
-    on one device. Then K and V commit through the in-place kernel
-    (``ops/pallas/kv_commit.py``), which takes the pool row-major as those
-    kernels do, so the compiler has no layout to convert between; elsewhere
-    (the XLA attention fallback, a tensor-parallel pool, leaves the kernel
-    does not tile, the kilobyte scale leaf) the scatter stays. Both leave
-    the same bytes. The choice is tallied per trace for the scheduler's
+    on one device. Then the K/V leaves (the leading writes of one shape)
+    commit through the in-place kernel (``ops/pallas/kv_commit.py``), which
+    takes the pool row-major as those kernels do, so the compiler has no
+    layout to convert between; elsewhere (the XLA attention fallback, the
+    latent pool, a tensor-parallel pool, leaves the kernel does not tile,
+    the kilobyte scale leaf) the scatter stays. Both leave the same bytes.
+    The choice is tallied per trace for the scheduler's
     ``serving/kv_commit_*_programs`` counters."""
     from ..ops.pallas import kv_commit
     ck, k = writes[0]
-    # a latent pool is ONE leaf a layer: the kernel commits K/V pairs
-    in_place = (paged_kernels and _tp_mesh_size() == 1 and len(writes) >= 2
-                and kv_commit.commits_in_place(ck) and writes[1][0].shape == ck.shape)
+    n_kv = next((j for j, (c, _) in enumerate(writes) if c.shape != ck.shape), len(writes))
+    in_place = (paged_kernels and _tp_mesh_size() == 1
+                and kv_commit.commits_in_place(ck))
     kv_commit.tally(in_place)
     T = k.shape[2]
     tgt = write_index[:, None] + jnp.arange(T)[None, :]
@@ -543,7 +608,8 @@ def _commit_span_rows(writes, write_index, q_spans, paged_kernels):
     upd = lambda c, kk, i: c.at[:, i, :].set(kk.astype(c.dtype), mode="drop")
     with jax.named_scope("kv_commit"):
         written = list(kv_commit.commit_kv_rows(
-            (ck, writes[1][0]), (k, writes[1][1]), write_index, q_spans)) if in_place else []
+            [c for c, _ in writes[:n_kv]], [kk for _, kk in writes[:n_kv]],
+            write_index, q_spans)) if in_place else []
         written += [jax.vmap(upd)(c, kk, tgt) for c, kk in writes[len(written):]]
     return written
 
@@ -917,22 +983,20 @@ class Attention(nn.Module):
             # arena: csrc/transformer/inference/includes/inference_context.h).
             # k/v are already bhtd, so the cache write needs no transpose.
             #
-            # int8 paged KV tier: a 3-leaf cache (k, v, scale) stores
+            # At head size 64 the layer's K and V rest PACKED in one leaf
+            # (B, nkv, S, 2 * hd), keys in lanes [0, hd) and values after
+            # them (kv_packs): fresh rows are joined on the last axis
+            # before the write, the paged kernels read the leaf as it is,
+            # and the XLA paths take its two lane slices.
+            #
+            # int8 paged KV tier: a scale leaf beside the K/V leaves stores
             # group-quantized rows — ONE symmetric scale per written token
             # row, shared by K and V across every head (group = the row),
             # scale leaf (B, 1, S, 1) fp16. Fresh K/V quantize at write
             # time; the paged Pallas kernels dequantize in-register (bf16
             # KV never lands in HBM), the XLA fallback dequantizes before
             # attending.
-            quant_kv = len(kv_cache) == 3
-            if quant_kv:
-                from ..ops.quantizer import dequantize_kv_rows, quantize_kv_rows
-                ck, cv, csc = kv_cache
-                kq, vq, sc_new = quantize_kv_rows(k, v)
-                writes = [(ck, kq), (cv, vq), (csc, sc_new)]
-            else:
-                ck, cv = kv_cache
-                writes = [(ck, k), (cv, v)]
+            writes, kv_split, quant_kv = _kv_writes(cfg, kv_cache, k, v)
             if ext_ops is not None and write_index is not None and q_spans is not None:
                 # long-context extent write: the chunk lands in the pool row
                 # holding the write head's extent (wslot), at in-slot offset
@@ -942,7 +1006,7 @@ class Attention(nn.Module):
                 ext_table, wslot, ext_base, _snk, _wnd = ext_ops
                 tgt = (write_index - ext_base)[:, None] + jnp.arange(T)[None, :]
                 tgt = jnp.where(jnp.arange(T)[None, :] < q_spans[:, None], tgt,
-                                ck.shape[2])
+                                writes[0][0].shape[2])
                 with jax.named_scope("kv_commit"):
                     written = [
                         c.at[wslot[:, None], :, tgt].set(
@@ -967,10 +1031,7 @@ class Attention(nn.Module):
             else:
                 written = [jax.lax.dynamic_update_slice_in_dim(
                     c, kk.astype(c.dtype), cache_index, axis=2) for c, kk in writes]
-            if quant_kv:
-                ck, cv, csc = written
-            else:
-                ck, cv = written
+            ck, cv, csc = _written_kv(written, kv_split, quant_kv)
             # bitwise-TP serving: the paged kernels shard over the tensor
             # axis (kv-head split, shard-local KV block walk) via shard_map
             # when the head counts divide; otherwise the plain call runs and
@@ -1009,8 +1070,7 @@ class Attention(nn.Module):
                     out = paged_decode_attention(
                         q[:, :, 0], ck, cv, starts, write_index + 1,
                         block_kv=cfg.decode_block_kv,
-                        **_paged_kernel_kw(csc if quant_kv else None, ext_ops,
-                                           tp_kernel_shard))[:, :, None]
+                        **_paged_kernel_kw(csc, ext_ops, tp_kernel_shard))[:, :, None]
                 else:
                     out = decode_attention(q[:, :, 0], ck, cv, starts, cache_index + 1,
                                            block_kv=cfg.decode_block_kv)[:, :, None]
@@ -1026,8 +1086,7 @@ class Attention(nn.Module):
                     starts = jnp.argmax(attn_mask.astype(jnp.int32), axis=1)
                 else:
                     starts = jnp.zeros((B, ), jnp.int32)
-                kw = _paged_kernel_kw(csc if quant_kv else None, ext_ops,
-                                      tp_kernel_shard)
+                kw = _paged_kernel_kw(csc, ext_ops, tp_kernel_shard)
                 if seq_shard:
                     # sequence-parallel chunked prefill: shards split the
                     # chunk's query columns over the seq axis; KV (already
@@ -1049,14 +1108,14 @@ class Attention(nn.Module):
                                               block_q=cfg.attention_block_q,
                                               block_kv=cfg.attention_block_kv)
             else:
+                if kv_split:
+                    ck, cv = ck[..., :kv_split], ck[..., kv_split:]
                 if quant_kv:
-                    out = _cached_attention_xla(
-                        q, dequantize_kv_rows(ck, csc, dtype=cfg.dtype),
-                        dequantize_kv_rows(cv, csc, dtype=cfg.dtype),
-                        cache_index, attn_mask, cfg.dtype, alibi=alibi, window=window)
-                else:
-                    out = _cached_attention_xla(q, ck, cv, cache_index, attn_mask,
-                                                cfg.dtype, alibi=alibi, window=window)
+                    from ..ops.quantizer import dequantize_kv_rows
+                    ck = dequantize_kv_rows(ck, csc, dtype=cfg.dtype)
+                    cv = dequantize_kv_rows(cv, csc, dtype=cfg.dtype)
+                out = _cached_attention_xla(q, ck, cv, cache_index, attn_mask,
+                                            cfg.dtype, alibi=alibi, window=window)
             out = out.astype(cfg.dtype)
             new_cache = tuple(written)
         else:
@@ -1411,9 +1470,10 @@ class CausalLM(nn.Module):
                  pld_theta=None, pld_rng=None, ltd_keep=None, ltd_layers=(), ltd_rng=None,
                  write_index=None, q_spans=None, lora_ops=None, expert_ops=None,
                  ext_ops=None, seq_shard=False):
-        """``kv_cache``: optional per-layer (k, v) with leading layer dim —
-        shapes (L, B, kv_heads, S, head_dim) — scanned alongside the layer
-        stack. Returns logits, or (logits, new_kv_cache) when caching, or the
+        """``kv_cache``: optional cache tree of ``init_cache`` (split K and V
+        leaves, the packed K/V leaf or the latent leaf, each component with
+        a leading layer dim (L, B, kv_heads, S, lanes) or as a per-layer
+        tuple) — scanned alongside the layer stack. Returns logits, or (logits, new_kv_cache) when caching, or the
         final-norm hidden states when ``return_hidden`` (the loss path fuses
         the vocab projection into a chunked cross-entropy instead).
 
@@ -1503,7 +1563,8 @@ class CausalLM(nn.Module):
             for i in range(cfg.num_layers):
                 # per-layer tuple cache (init_cache, unrolled form); stacked
                 # arrays also index correctly for backward compatibility.
-                # 2 components (k, v) or 3 (+ the int8 tier's scale leaf)
+                # 1 to 3 components: the K/V leaves (packed, split or latent)
+                # and the int8 tier's scale leaf
                 layer_cache = (None if kv_cache is None
                                else tuple(comp[i] for comp in kv_cache))
                 layer_lora = (None if lora_ops is None else
@@ -1709,19 +1770,33 @@ class CausalLMModel:
     def init_cache(self, batch_size, max_len, dtype=None, quantized=False):
         """Preallocated KV cache — the analogue of the reference's inference
         workspace KV arena (``csrc/transformer/inference/includes/
-        inference_context.h``). Scanned models carry one stacked
-        (L, B, kv_heads, S, head_dim) pair; unrolled models carry per-layer
-        tuples of (B, kv_heads, S, head_dim) — separate tensors alias
-        IN-PLACE through the decode while-loop carry, where a scan's stacked
-        ys output is rebuilt (full cache copy) every token.
+        inference_context.h``). One of three geometries, chosen here from the
+        config's widths alone and recognised by every reader from the leaves
+        (:func:`kv_layer_leaves`, :func:`kv_pool_geometry`):
+
+        - **split**: a K and a V leaf a layer, ``(B, kv_heads, S,
+          head_size)`` each: the tree ``(k leaves, v leaves)``;
+        - **packed** (:func:`kv_packs`: head size 64): ONE leaf a layer,
+          ``(B, kv_heads, S, 2 * head_size)``, a position's key in lanes
+          ``[0, head_size)`` and its value after it: the tree ``(kv
+          leaves, )``. The same bytes at rest, in the row-major form the
+          kernels read;
+        - **latent** (``kv_lora_rank > 0``): ONE leaf a layer, ``(B, 1, S,
+          latent_width)``: the tree ``(latent leaves, )``.
+
+        Scanned models carry each component stacked ``(L, ...)``; unrolled
+        models carry per-layer tuples — separate tensors alias IN-PLACE
+        through the decode while-loop carry, where a scan's stacked ys
+        output is rebuilt (full cache copy) every token.
 
         ``quantized``: the int8 paged KV tier (serving ``kv_cache_dtype:
-        int8``) — each layer carries THREE leaves ``(k int8, v int8,
-        scale)``: one fp16 per-token-row scale shaped (B, 1, S, 1), shared
-        by K and V across every head. Scales init to 1 (rows past each
-        slot's end are never attended), and every leaf keeps its batch/slot
-        axis at ``ndim - 4`` so the slot pool's slice/update/copy programs
-        treat both layouts uniformly."""
+        int8``) — the K/V leaves are int8 and each layer carries one more
+        leaf, LAST in the tree: one fp16 per-token-row scale shaped (B, 1,
+        S, 1), shared by K and V across every head (``(k, v, scale)`` split,
+        ``(kv, scale)`` packed). Scales init to 1 (rows past each slot's end
+        are never attended), and every leaf keeps its batch/slot axis at
+        ``ndim - 4`` and its row axis at ``ndim - 2``, so the slot pool's
+        slice/update/copy programs treat every geometry uniformly."""
         cfg = self.cfg
         dt = dtype or cfg.dtype
         if cfg.latent_width:
@@ -1732,25 +1807,18 @@ class CausalLMModel:
             # slot_update / copy_slot and the radix copy take it as it is.
             if quantized:
                 raise NotImplementedError("the latent KV pool has no int8 tier")
-            shape = (batch_size, 1, max_len, cfg.latent_width)
-            if cfg.scan_layers:
-                return (jnp.zeros((cfg.num_layers, ) + shape, dt), )
-            return (tuple(jnp.zeros(shape, dt) for _ in range(cfg.num_layers)), )
-        shape = (batch_size, cfg.kv_heads, max_len, cfg.head_size)
-        sshape = (batch_size, 1, max_len, 1)
-        if quantized:
-            if cfg.scan_layers:
-                L = (cfg.num_layers, )
-                return (jnp.zeros(L + shape, jnp.int8), jnp.zeros(L + shape, jnp.int8),
-                        jnp.ones(L + sshape, jnp.float16))
-            return (tuple(jnp.zeros(shape, jnp.int8) for _ in range(cfg.num_layers)),
-                    tuple(jnp.zeros(shape, jnp.int8) for _ in range(cfg.num_layers)),
-                    tuple(jnp.ones(sshape, jnp.float16) for _ in range(cfg.num_layers)))
+            comps = [((batch_size, 1, max_len, cfg.latent_width), dt, jnp.zeros)]
+        else:
+            packed = kv_packs(cfg.head_size)
+            shape = (batch_size, cfg.kv_heads, max_len,
+                     (2 if packed else 1) * cfg.head_size)
+            comps = [(shape, jnp.int8 if quantized else dt, jnp.zeros)] * (1 if packed else 2)
+            if quantized:
+                comps.append(((batch_size, 1, max_len, 1), jnp.float16, jnp.ones))
         if cfg.scan_layers:
-            stacked = (cfg.num_layers, ) + shape
-            return (jnp.zeros(stacked, dt), jnp.zeros(stacked, dt))
-        return (tuple(jnp.zeros(shape, dt) for _ in range(cfg.num_layers)),
-                tuple(jnp.zeros(shape, dt) for _ in range(cfg.num_layers)))
+            return tuple(fill((cfg.num_layers, ) + shape, t) for shape, t, fill in comps)
+        return tuple(tuple(fill(shape, t) for _ in range(cfg.num_layers))
+                     for shape, t, fill in comps)
 
     def apply_with_cache(self, params, input_ids, kv_cache, cache_index, cache_mask=None,
                          position_ids=None, write_index=None, q_spans=None,
@@ -1910,36 +1978,21 @@ class CausalLMModel:
             sin, cos = rope_table(cfg.rotary_dim or hd, cfg.max_seq_len,
                                   cfg.rope_theta)
             rope = (sin[pos_flat], cos[pos_flat], nh + nkv, hd)
-        quant_kv = len(kv_cache) == 3
-        if quant_kv:
-            from ..ops.quantizer import quantize_kv_rows
         starts = jnp.zeros((N, ), jnp.int32)
         new_layers = []
         for i, (norms, qkv, o, up, down, gate) in enumerate(layers):
-            layer_cache = tuple(comp[i] for comp in kv_cache)
-            csc = None
-            if quant_kv:
-                ck, cv, csc = layer_cache
-            else:
-                ck, cv = layer_cache
             y = fused_qkv_ln(x2d, norms, qkv, eps=cfg.layernorm_epsilon,
                              norm=cfg.norm, rope=rope)
             qf, kf, vf = jnp.split(y, [nh * hd, (nh + nkv) * hd], axis=-1)
             k = kf.reshape(N, C, nkv, hd).transpose(0, 2, 1, 3)
             v = vf.reshape(N, C, nkv, hd).transpose(0, 2, 1, 3)
-            if quant_kv:
-                kq, vq, sc_new = quantize_kv_rows(k, v)
-                writes = [(ck, kq), (cv, vq), (csc, sc_new)]
-            else:
-                writes = [(ck, k), (cv, v)]
+            writes, kv_split, quant_kv = _kv_writes(
+                cfg, tuple(comp[i] for comp in kv_cache), k, v)
             # Attention's span commit; this path attends through the paged
             # kernels unconditionally
             written = _commit_span_rows(writes, write_index, q_spans,
                                         paged_kernels=True)
-            if quant_kv:
-                ck, cv, csc = written
-            else:
-                ck, cv = written
+            ck, cv, csc = _written_kv(written, kv_split, quant_kv)
             if C == 1:
                 out = paged_decode_attention(
                     qf.reshape(N, nh, hd), ck, cv, starts, write_index + 1,

@@ -8,6 +8,15 @@ GQA-native: the cache keeps ``kv_heads`` heads and each program computes
 whole groups of query heads sharing one KV head — no ``jnp.repeat``
 expansion of the cache.
 
+Two cache geometries, as ``CausalLMModel.init_cache`` makes them: a K and a
+V leaf ``(slots, kv_heads, S, D)``, or at head size 64 ONE packed leaf
+``(slots, kv_heads, S, 2 * D)`` with a row's key in lanes ``[0, D)`` and
+its value after it (every entry point takes it as ``k_cache`` with
+``v_cache=None``). The packed leaf rests row-major in full 128-lane tiles,
+the form these kernels read, so a program that carries the pool relays
+nothing; a KV block is one operand and one DMA a grid step, half the VMEM
+and half the padded bytes of the split pair.
+
 Kernel shape: one kernel, grid ``(rows, kv-head blocks, KV blocks)``. A
 program owns one batch row (cache slot) and a block of its kv heads and
 walks that row's KV blocks with an online softmax. Everything per-row is a
@@ -69,14 +78,24 @@ DEFAULT_MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
 _NO_WINDOW = 1 << 30  # window == 0 means "exact": end - _NO_WINDOW masks nothing
 
 
-def _attn_kernel(ext_ref, start_ref, end_ref, sink_ref, win_ref, q_ref, k_ref,
-                 v_ref, *rest, scale, block_kv, span, quantized, lossy):
+def _attn_kernel(ext_ref, start_ref, end_ref, sink_ref, win_ref, q_ref, *rest,
+                 scale, block_kv, span, quantized, lossy, packed):
     """One (row, kv-head block) program walking that row's KV blocks.
 
     ``q_ref``: (1, bh, g, D) where ``g`` is the FOLDED query axis:
     head-groups x span columns, span fastest. With ``span > 1`` the row's
     ``end`` is the causal end of column 0 and column j attends j more keys
     (per-row mixed decode/prefill query spans share this one kernel).
+
+    ``packed``: ONE KV block (1, bh, block_kv, 2 * D) follows ``q_ref``
+    where the split form has a K and a V block of (1, bh, block_kv, D):
+    lanes ``[0, D)`` of a row are its key, ``[D, 2 * D)`` its value (the
+    packed pool of ``CausalLMModel.init_cache``). The block is never
+    sliced: ``q_ref`` arrives zero-extended to 2 * D lanes, so ``q . block``
+    is ``q . K`` exactly (the value lanes meet zeros), and ``p @ block``
+    holds ``p @ V`` in lanes ``[D, 2 * D)``, which the flush keeps. The
+    split form's arithmetic, bit for bit, with one f32 copy of a
+    lane-dense block where it made two of half-empty ones.
 
     ``quantized``: the KV blocks are int8 with per-token-row scales (two
     extra (1, 1, block_kv) operands). The K scale multiplies the scores and
@@ -89,6 +108,8 @@ def _attn_kernel(ext_ref, start_ref, end_ref, sink_ref, win_ref, q_ref, k_ref,
     logical positions in ``[sink, end - win)`` (StreamingLLM shape);
     ``win == 0`` leaves the exact mask untouched, so lossy and exact rows
     share one compiled program."""
+    n_kv = 1 if packed else 2
+    kv_refs, rest = rest[:n_kv], rest[n_kv:]
     if quantized:
         ks_ref, vs_ref, o_ref, m_s, l_s, acc_s = rest
     else:
@@ -108,9 +129,9 @@ def _attn_kernel(ext_ref, start_ref, end_ref, sink_ref, win_ref, q_ref, k_ref,
 
     @pl.when(kv_start < end + (span - 1))
     def _block():
-        q = q_ref[0].astype(jnp.float32) * scale  # (bh, g, D)
-        k = k_ref[0].astype(jnp.float32)          # (bh, bkv, D)
-        v = v_ref[0].astype(jnp.float32)
+        q = q_ref[0].astype(jnp.float32) * scale  # (bh, g, D), or 2 * D packed
+        k = kv_refs[0][0].astype(jnp.float32)     # (bh, bkv, D), or 2 * D packed
+        v = k if packed else kv_refs[1][0].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((2, ), (2, )), ((0, ), (0, ))),
                                 preferred_element_type=jnp.float32)  # (bh, g, bkv)
         if quantized:
@@ -146,36 +167,42 @@ def _attn_kernel(ext_ref, start_ref, end_ref, sink_ref, win_ref, q_ref, k_ref,
     def _flush():
         l = l_s[...]
         l = jnp.where(l == 0, 1.0, l)
-        o_ref[0] = (acc_s[...] / l).astype(o_ref.dtype)
+        out = acc_s[...] / l
+        if packed:
+            out = out[:, :, o_ref.shape[-1]:]
+        o_ref[0] = out.astype(o_ref.dtype)
 
 
 def _pad(n, m):
     return -(-n // m) * m
 
 
-def _vmem_estimate(bh, bkv, g, D, q_bytes, kv_bytes, quantized):
+def _vmem_estimate(bh, bkv, g, D, q_bytes, kv_bytes, quantized, packed=False):
     """VMEM bytes one grid step of :func:`_attn_kernel` needs, counted the
-    way Mosaic lays blocks out: the last dim pads to 128 lanes (so
-    ``D == 64`` costs as much as 128), the second-to-last to 8 sublanes x
-    the dtype's packing, and every pipelined operand is double-buffered.
-    Of the in-kernel values the compiler keeps about one f32 K/V copy and
-    the score and probability planes in VMEM. Checked against the least
-    ``vmem_limit_bytes`` the v5e compiler accepts at twelve (heads, g, D,
-    block) points: the estimate ran 1.4x-2.2x above it, never below."""
+    way Mosaic lays blocks out: the last dim pads to 128 lanes (so a split
+    K or V block of ``D == 64`` costs as much as one of 128, and the packed
+    block of ``2 * D`` lanes costs what ONE of them does), the
+    second-to-last to 8 sublanes x the dtype's packing, and every pipelined
+    operand is double-buffered. Of the in-kernel values the compiler keeps
+    about one f32 K/V copy and the score and probability planes in VMEM.
+    Checked against the least ``vmem_limit_bytes`` the v5e compiler accepts
+    at twelve (heads, g, D, block) points of the split form: the estimate
+    ran 1.4x-2.2x above it, never below."""
     Dp = _pad(D, 128)
+    Dq = _pad(2 * D, 128) if packed else Dp        # q, the numerator and the f32 block
     g8 = _pad(g, 8)
     gq = _pad(g, 8 * (4 // q_bytes))
     kv_rows = _pad(bkv, 8 * (4 // kv_bytes))
-    io = 2 * 2 * bh * gq * Dp * q_bytes            # q + out, double-buffered
-    io += 2 * 2 * bh * kv_rows * Dp * kv_bytes     # k + v, double-buffered
+    io = 2 * bh * gq * (Dq + Dp) * q_bytes         # q + out, double-buffered
+    io += 2 * bh * kv_rows * (Dq if packed else 2 * Dp) * kv_bytes  # k and v, double-buffered
     if quantized:
         io += 2 * 2 * 8 * _pad(bkv, 128) * 4
-    scratch = bh * g8 * (2 * 128 + Dp) * 4         # m, l (lane-padded), acc
-    temps = bh * bkv * Dp * 4 + 2 * bh * g8 * _pad(bkv, 128) * 4
+    scratch = bh * g8 * (2 * 128 + Dq) * 4         # m, l (lane-padded), acc
+    temps = bh * bkv * Dq * 4 + 2 * bh * g8 * _pad(bkv, 128) * 4
     return io + scratch + temps
 
 
-def _pick_blocks(nkv, g, D, S, block_kv, q_dtype, kv_dtype, quantized):
+def _pick_blocks(nkv, g, D, S, block_kv, q_dtype, kv_dtype, quantized, packed=False):
     """(kv-head block, KV block) for one grid step, from the operand shapes
     and dtypes and the chip's VMEM budget (``ops.pallas.VMEM_BLOCK_BUDGET``).
     ``block_kv`` is the caller's setting and the upper bound: it is kept
@@ -192,22 +219,26 @@ def _pick_blocks(nkv, g, D, S, block_kv, q_dtype, kv_dtype, quantized):
     for bkv in kv_cands:
         for bh in range(nkv, 0, -1):
             if nkv % bh == 0 and _pallas.fits_vmem(_vmem_estimate(
-                    bh, bkv, g, D, q_bytes, kv_bytes, quantized)):
+                    bh, bkv, g, D, q_bytes, kv_bytes, quantized, packed)):
                 return bh, bkv
     raise ValueError(
         f"decode attention: one kv head x {kv_cands[-1]} keys with {g} folded "
         f"query columns of width {D} needs "
-        f"{_vmem_estimate(1, kv_cands[-1], g, D, q_bytes, kv_bytes, quantized)} "
+        f"{_vmem_estimate(1, kv_cands[-1], g, D, q_bytes, kv_bytes, quantized, packed)} "
         f"bytes of VMEM, over the {_pallas.VMEM_BLOCK_BUDGET}-byte budget; "
         f"narrow the query span (prefill_chunk)")
 
 
-def _decode_call(qg, k_cache, v_cache, start, ends, *, block_kv, scale, span=1,
+def _decode_call(qg, kv, start, ends, *, block_kv, scale, span=1,
                  k_scale=None, v_scale=None, ext=None, sink=None, win=None):
     """Shared pallas_call builder: row ``i`` attends its own window
     ``[start_i, ends_i)`` and walks KV blocks only up to its own write
     head. ``qg``: queries pre-folded to (B, nkv, g, D) where ``g`` =
-    head-groups x ``span`` columns (span fastest). ``k_scale``/``v_scale``:
+    head-groups x ``span`` columns (span fastest). ``kv``: the cache
+    leaves, ``(k_cache, v_cache)`` of (Npool, nkv, S, D) each or ONE packed
+    ``(kv_cache, )`` of (Npool, nkv, S, 2 * D) with a row's key in lanes
+    ``[0, D)`` and its value in ``[D, 2 * D)``: one KV operand and one DMA
+    a grid step where the split form has two. ``k_scale``/``v_scale``:
     optional (Npool, S) per-token-row dequant scales for int8 caches,
     walked in lockstep with the KV blocks.
 
@@ -222,12 +253,16 @@ def _decode_call(qg, k_cache, v_cache, start, ends, *, block_kv, scale, span=1,
     reads pool row ``i``. ``sink``/``win``: optional (B,) int32 lossy-mode
     knobs (see :func:`_attn_kernel`)."""
     B, nkv, g, D = qg.shape
-    S = k_cache.shape[2]
+    packed = len(kv) == 1
+    Np, _, S, lanes = kv[0].shape
+    if lanes != (2 * D if packed else D) or any(c.shape != kv[0].shape for c in kv):
+        raise ValueError(f"decode attention: cache leaves {[c.shape for c in kv]} "
+                         f"do not hold heads of width {D}")
     scale = scale if scale is not None else 1.0 / (D**0.5)
     quantized = k_scale is not None
     lossy = sink is not None or win is not None
-    bh, block_kv = _pick_blocks(nkv, g, D, S, block_kv, qg.dtype, k_cache.dtype,
-                                quantized)
+    bh, block_kv = _pick_blocks(nkv, g, D, S, block_kv, qg.dtype, kv[0].dtype,
+                                quantized, packed)
     if ext is None:
         ext = jnp.arange(B, dtype=jnp.int32)[:, None]
     E = ext.shape[1]
@@ -254,18 +289,21 @@ def _decode_call(qg, k_cache, v_cache, start, ends, *, block_kv, scale, span=1,
         slot, blk = walk(i, j, ext_r, end_r)
         return (slot, 0, blk)
 
-    q_spec = pl.BlockSpec((1, bh, g, D), lambda i, h, j, *_: (i, h, 0, 0))
-    in_specs = [q_spec,
-                pl.BlockSpec((1, bh, block_kv, D), kv_index),
-                pl.BlockSpec((1, bh, block_kv, D), kv_index)]
-    operands = [qg, k_cache, v_cache]
+    if packed:
+        # zero lanes against the block's value lanes (see _attn_kernel)
+        qg = jnp.pad(qg, ((0, 0), ) * 3 + ((0, D), ))
+    Dq = qg.shape[-1]
+    q_spec = pl.BlockSpec((1, bh, g, Dq), lambda i, h, j, *_: (i, h, 0, 0))
+    o_spec = pl.BlockSpec((1, bh, g, D), lambda i, h, j, *_: (i, h, 0, 0))
+    in_specs = [q_spec] + [pl.BlockSpec((1, bh, block_kv, lanes), kv_index)] * len(kv)
+    operands = [qg, *kv]
     if quantized:
-        Np = k_cache.shape[0]
         in_specs += [pl.BlockSpec((1, 1, block_kv), sc_index)] * 2
         operands += [k_scale.reshape(Np, 1, S), v_scale.reshape(Np, 1, S)]
 
     kernel = functools.partial(_attn_kernel, scale=scale, block_kv=block_kv,
-                               span=span, quantized=quantized, lossy=lossy)
+                               span=span, quantized=quantized, lossy=lossy,
+                               packed=packed)
     return pl.pallas_call(
         kernel,
         name="dstpu_decode_attn",
@@ -273,11 +311,11 @@ def _decode_call(qg, k_cache, v_cache, start, ends, *, block_kv, scale, span=1,
             num_scalar_prefetch=len(scalars),
             grid=(B, nkv // bh, E * bpe),
             in_specs=in_specs,
-            out_specs=q_spec,
+            out_specs=o_spec,
             scratch_shapes=[
                 pltpu.VMEM((bh, g, 1), jnp.float32),  # running max
                 pltpu.VMEM((bh, g, 1), jnp.float32),  # running denom
-                pltpu.VMEM((bh, g, D), jnp.float32),  # running numerator
+                pltpu.VMEM((bh, g, Dq), jnp.float32),  # running numerator
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, nkv, g, D), qg.dtype),
@@ -314,50 +352,61 @@ def _optional_operands(k_cache, k_scale, v_scale, ext, sink, window):
     return names, [opt[n] for n in names]
 
 
-def _tp_shard_map(fn, mesh, axis, n_rep):
+def _kv_leaves(k_cache, v_cache):
+    """The cache leaves of a public entry point as :func:`_decode_call`
+    takes them: ``v_cache=None`` says ``k_cache`` is the packed leaf."""
+    return (k_cache, ) if v_cache is None else (k_cache, v_cache)
+
+
+def _tp_shard_map(fn, mesh, axis, n_head, n_rep):
     """shard_map wrapper for the paged kernels over the ``axis`` (tensor)
-    mesh dim: q and the KV cache split on their HEAD axes, the ``n_rep``
-    operands after (q, k, v) — window scalars, extent table, lossy knobs,
-    per-token-row scale leaves — stay replicated. Each shard's kernel then
+    mesh dim: q and the KV cache leaves (the first ``n_head`` operands)
+    split on their HEAD axes, the ``n_rep`` operands after them — window
+    scalars, extent table, lossy knobs, per-token-row scale leaves — stay
+    replicated. Each shard's kernel then
     walks ONLY its local KV-head blocks (shard-local block walk — DMA and
     compute scale down tp-fold), and because every (batch, kv-head) pair is
     computed independently by the same kernel, the gathered output is
     BIT-identical to the unsharded call."""
     from jax.sharding import PartitionSpec as SP
     head = SP(None, axis, None, None)
-    return jax.shard_map(fn, mesh=mesh, in_specs=(head, ) * 3 + (SP(), ) * n_rep,
+    return jax.shard_map(fn, mesh=mesh, in_specs=(head, ) * n_head + (SP(), ) * n_rep,
                          out_specs=head, check_vma=False)
 
 
-def _paged(qg, k_cache, v_cache, start, ends, *, span, block_kv, scale,
+def _paged(qg, kv, start, ends, *, span, block_kv, scale,
            k_scale, v_scale, ext=None, sink=None, window=None, mesh=None,
            axis=None):
     """Common tail of the public entry points: scale layout and the
     optional tensor-axis shard_map around :func:`_decode_call`.
     ``qg``: (B, nkv, g, D) folded queries, or (B, H, T, D) span queries
     when sharded (the (head-group, column) fold then happens INSIDE each
-    shard, so per-column causal offsets see only local heads)."""
-    names, optional = _optional_operands(k_cache, k_scale, v_scale, ext, sink,
+    shard, so per-column causal offsets see only local heads). ``kv``: the
+    cache leaves (:func:`_kv_leaves`)."""
+    names, optional = _optional_operands(kv[0], k_scale, v_scale, ext, sink,
                                          window)
+    n = len(kv)
 
-    def call(q, kc, vc, st, en, *rest):
-        nkv_l = kc.shape[1]
-        out = _decode_call(q.reshape(q.shape[0], nkv_l, -1, q.shape[-1]), kc, vc,
+    def call(q, *rest):
+        kvl, (st, en), rest = rest[:n], rest[n:n + 2], rest[n + 2:]
+        nkv_l = kvl[0].shape[1]
+        out = _decode_call(q.reshape(q.shape[0], nkv_l, -1, q.shape[-1]), kvl,
                            st, en, block_kv=block_kv, scale=scale, span=span,
                            **dict(zip(names, rest)))
         return out.reshape(q.shape)
 
-    args = (qg, k_cache, v_cache, start, ends, *optional)
+    args = (qg, *kv, start, ends, *optional)
     if mesh is None:
         return call(*args)
-    return _tp_shard_map(call, mesh, axis, len(args) - 3)(*args)
+    return _tp_shard_map(call, mesh, axis, 1 + n, len(args) - 1 - n)(*args)
 
 
 def decode_attention(q, k_cache, v_cache, start, end, *, block_kv=256, scale=None):
     """q: (B, H, D) one query token per sequence; k_cache/v_cache:
-    (B, kv_heads, S, D); start: (B,) int32 first attendable cache slot per
-    row; end: scalar int32, one past the last written slot (shared).
-    Returns (B, H, D)."""
+    (B, kv_heads, S, D), or the packed (B, kv_heads, S, 2 * D) leaf as
+    ``k_cache`` with ``v_cache=None``; start: (B,) int32 first attendable
+    cache slot per row; end: scalar int32, one past the last written slot
+    (shared). Returns (B, H, D)."""
     B, H, D = q.shape
     return paged_decode_attention(q, k_cache, v_cache, start,
                                   jnp.full((B, ), end, jnp.int32),
@@ -368,7 +417,10 @@ def paged_decode_attention(q, k_cache, v_cache, start, ends, *, block_kv=256,
                            scale=None, k_scale=None, v_scale=None, ext=None,
                            sink=None, window=None, mesh=None, axis=None):
     """Slot-pool variant: per-row ends. q: (B, H, D); k_cache/v_cache:
-    (B, kv_heads, S, D) where B indexes cache SLOTS; ``ends``: (B,) int32 one
+    (B, kv_heads, S, D) where B indexes cache SLOTS, or the pool's packed
+    (B, kv_heads, S, 2 * D) leaf (key lanes, then value lanes: the head-
+    size-64 geometry of ``CausalLMModel.init_cache``) as ``k_cache`` with
+    ``v_cache=None``; ``ends``: (B,) int32 one
     past each slot's last written position (rows with ``ends == 0`` attend
     nothing and return zeros). Each row's KV-block walk stops at its own
     write head, so compute and DMA scale with the LIVE context, not the
@@ -389,7 +441,7 @@ def paged_decode_attention(q, k_cache, v_cache, start, ends, *, block_kv=256,
     ``sink``/``window``: optional (B,) int32 attention-sink +
     sliding-window knobs (the LOSSY long-context mode). Returns (B, H, D)."""
     B, H, D = q.shape
-    out = _paged(_group(q, k_cache.shape[1]), k_cache, v_cache, start, ends,
+    out = _paged(_group(q, k_cache.shape[1]), _kv_leaves(k_cache, v_cache), start, ends,
                  span=1, block_kv=block_kv, scale=scale, k_scale=k_scale,
                  v_scale=v_scale, ext=ext, sink=sink, window=window, mesh=mesh,
                  axis=axis)
@@ -411,7 +463,7 @@ def paged_span_attention(q, k_cache, v_cache, start, base, *, block_kv=256,
     ``idx % span``. Other arguments as :func:`paged_decode_attention`.
     Returns (B, H, T, D)."""
     B, H, T, D = q.shape
-    return _paged(q, k_cache, v_cache, start, base + 1,
+    return _paged(q, _kv_leaves(k_cache, v_cache), start, base + 1,
                   span=T, block_kv=block_kv, scale=scale, k_scale=k_scale,
                   v_scale=v_scale, ext=ext, sink=sink, window=window, mesh=mesh,
                   axis=axis)
@@ -434,7 +486,8 @@ def seq_sharded_span_attention(q, k_cache, v_cache, start, base, *, mesh, axis,
     — the scheduler gates seq-parallel prefill to tp == 1."""
     from jax.sharding import PartitionSpec as SP
     B, H, T, D = q.shape
-    Np, nkv, S, _ = k_cache.shape
+    kv = _kv_leaves(k_cache, v_cache)
+    nkv = k_cache.shape[1]
     n = mesh.shape[axis]
     if T % n:
         raise ValueError(f"span width {T} must divide by the seq axis size {n}")
@@ -442,15 +495,16 @@ def seq_sharded_span_attention(q, k_cache, v_cache, start, base, *, mesh, axis,
     names, optional = _optional_operands(k_cache, k_scale, v_scale, ext, sink,
                                          window)
 
-    def body(qs, kc, vc, st, bs, *rest):
+    def body(qs, *rest):
+        kvl, (st, bs), rest = rest[:len(kv)], rest[len(kv):len(kv) + 2], rest[len(kv) + 2:]
         bl = bs + jax.lax.axis_index(axis) * Tl  # this shard's columns start Tl*sh later
-        out = _decode_call(qs.reshape(B, nkv, (H // nkv) * Tl, D), kc, vc, st,
+        out = _decode_call(qs.reshape(B, nkv, (H // nkv) * Tl, D), kvl, st,
                            bl + 1, block_kv=block_kv, scale=scale, span=Tl,
                            **dict(zip(names, rest)))
         return out.reshape(B, H, Tl, D)
 
     seq_q = SP(None, None, axis, None)
-    args = (q, k_cache, v_cache, start, base, *optional)
+    args = (q, *kv, start, base, *optional)
     return jax.shard_map(body, mesh=mesh,
                          in_specs=(seq_q, ) + (SP(), ) * (len(args) - 1),
                          out_specs=seq_q, check_vma=False)(*args)

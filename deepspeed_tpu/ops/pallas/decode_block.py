@@ -475,29 +475,32 @@ def fused_out_mlp(attn2d, x, norms, o, up, down, *, activation="gelu",
     )(*operands)
 
 
-def fused_decode_block(x, norms, k_cache, v_cache, qkv, o, up, down,
+def fused_decode_block(x, norms, kv, qkv, o, up, down,
                        start, pos, *, activation="gelu", eps=1e-5, block_kv=256,
                        norm="layernorm", rope=None, gate=None):
     """One fused transformer decode layer for a single token per row.
 
     x: (B, H) bf16 residual stream. norms: (4, H) f32 rows
     [norm1_scale, norm1_bias, norm2_scale, norm2_bias] (zero bias rows for
-    rmsnorm). k_cache/v_cache: (B, kv_heads, S, hd) — ``kv_heads`` may be
-    smaller than ``num_heads`` (GQA; attention groups q heads over the KV
-    heads). qkv/o/up/down (and ``gate`` for swiglu/geglu): (weight_q int8,
-    scales f32 (G, N), bias f32 (N,)) tuples in matmul layout (qkv fused
-    [q;k;v]). start: (B,) int32 first attendable slot; pos: scalar int32
-    cache write position. ``rope``: optional (sin2d, cos2d) — (B, hd // 2)
-    f32 rotary tables gathered at each row's position, rotated in-kernel
-    over the q and k head segments.
+    rmsnorm). kv: the layer's cache leaves as ``init_cache`` makes them,
+    ``(k_cache, v_cache)`` of (B, kv_heads, S, hd) each or the packed
+    ``(kv_cache, )`` of (B, kv_heads, S, 2 * hd), keys in lanes ``[0, hd)``
+    — ``kv_heads`` may be smaller than ``num_heads`` (GQA; attention groups
+    q heads over the KV heads). qkv/o/up/down (and ``gate`` for
+    swiglu/geglu): (weight_q int8, scales f32 (G, N), bias f32 (N,)) tuples
+    in matmul layout (qkv fused [q;k;v]). start: (B,) int32 first
+    attendable slot; pos: scalar int32 cache write position. ``rope``:
+    optional (sin2d, cos2d) — (B, hd // 2) f32 rotary tables gathered at
+    each row's position, rotated in-kernel over the q and k head segments.
 
-    Returns (x_out (B, H) bf16, new_k_cache, new_v_cache) — the caches are
-    committed (dynamic_update_slice at ``pos``) before attention, exactly
-    like the unfused model path.
+    Returns (x_out (B, H) bf16, new cache leaves) — the rows are committed
+    (dynamic_update_slice at ``pos``; a packed row is its key and value
+    joined) before attention, exactly like the unfused model path.
     """
     from .decode_attention import decode_attention
     B, H = x.shape
-    _, nkv, S, hd = k_cache.shape
+    _, nkv, S, lanes = kv[0].shape
+    hd = lanes // 2 if len(kv) == 1 else lanes
     Nq = qkv[0].shape[1]
     nh = Nq // hd - 2 * nkv
     rope_op = None
@@ -506,14 +509,13 @@ def fused_decode_block(x, norms, k_cache, v_cache, qkv, o, up, down,
         rope_op = (sin2d, cos2d, nh + nkv, hd)
     qkv2d = fused_qkv_ln(x, norms, qkv, eps=eps, norm=norm, rope=rope_op)
     qf, kf, vf = jnp.split(qkv2d, [nh * hd, (nh + nkv) * hd], axis=-1)
-    k3 = kf.reshape(B, nkv, 1, hd)
-    v3 = vf.reshape(B, nkv, 1, hd)
-    k_cache = jax.lax.dynamic_update_slice_in_dim(k_cache, k3.astype(k_cache.dtype),
-                                                  pos, axis=2)
-    v_cache = jax.lax.dynamic_update_slice_in_dim(v_cache, v3.astype(v_cache.dtype),
-                                                  pos, axis=2)
-    attn = decode_attention(qf.reshape(B, nh, hd), k_cache, v_cache,
+    fresh = (kf.reshape(B, nkv, 1, hd), vf.reshape(B, nkv, 1, hd))
+    if len(kv) == 1:
+        fresh = (jnp.concatenate(fresh, axis=-1), )
+    kv = tuple(jax.lax.dynamic_update_slice_in_dim(c, r.astype(c.dtype), pos, axis=2)
+               for c, r in zip(kv, fresh))
+    attn = decode_attention(qf.reshape(B, nh, hd), kv[0], kv[1] if len(kv) == 2 else None,
                             start, pos + 1, block_kv=min(block_kv, S))
     x_out = fused_out_mlp(attn.reshape(B, nh * hd), x, norms, o, up, down,
                           activation=activation, eps=eps, norm=norm, gate=gate)
-    return x_out, k_cache, v_cache
+    return x_out, kv

@@ -11,8 +11,12 @@ kernel aliases the pool (``input_output_aliases``) and takes it row-major,
 as the attention kernels do, so a step's loop carries one layout and moves
 only the row blocks under the write heads.
 
-Kernel shape: one call for a layer's K and V, grid ``(slots, kv-head
-blocks, row blocks)``. ``write_index`` and ``q_spans`` are scalar-prefetch
+Kernel shape: one call for a layer's K/V leaves, grid ``(slots, kv-head
+blocks, row blocks)``: the split pool's K and V, two operands and two
+aliased outputs, or the packed pool's ONE leaf ``(N, nkv, S, 2 * hd)`` (a
+row's key in lanes ``[0, hd)``, its value after it: ``CausalLMModel.
+init_cache`` at head size 64), one operand, one aliased output and blocks
+that fill their 128 lanes. ``write_index`` and ``q_spans`` are scalar-prefetch
 operands; the index map turns them into the row block (one sublane tile: 8
 rows of f32, 16 of bf16, 32 of int8) that a step reads, patches under a
 mask on the absolute position, and writes back. A span of ``C`` columns
@@ -61,23 +65,25 @@ def block_rows(dtype):
 
 
 def commits_in_place(leaf):
-    """Whether a pool leaf is one this kernel writes: ``(N, nkv, S, hd)``
-    with ``S`` a whole number of row blocks."""
+    """Whether a pool leaf is one this kernel writes: ``(N, nkv, S, lanes)``
+    (split K or V, or the packed pair) with ``S`` a whole number of row
+    blocks."""
     if leaf.ndim != 4 or jnp.dtype(leaf.dtype).itemsize not in (1, 2, 4):
         return False
     return leaf.shape[2] % block_rows(leaf.dtype) == 0
 
 
-def _vmem_estimate(bh, rows, C, hd, itemsize):
-    """VMEM bytes of one grid step, K and V together, counted as Mosaic lays
+def _vmem_estimate(n, bh, rows, C, hd, itemsize):
+    """VMEM bytes of one grid step over ``n`` leaves of ``hd`` lanes (2 x
+    head size, or 1 x twice the head size packed), counted as Mosaic lays
     blocks out (the last dimension pads to 128 lanes, pipelined operands are
     double-buffered): pool blocks in and out, the fresh rows, the f32
     staging buffers of a span, and about two f32 copies of a block."""
     hdp = _pad(hd, 128)
-    io = 2 * 2 * 2 * bh * rows * hdp * itemsize
-    io += 2 * 2 * bh * _pad(C, rows) * hdp * itemsize
-    stage = 2 * bh * (C + 2 * rows) * hdp * 4 if C > 1 else 0
-    return io + stage + 2 * 2 * bh * rows * hdp * 4
+    io = n * 2 * 2 * bh * rows * hdp * itemsize
+    io += n * 2 * bh * _pad(C, rows) * hdp * itemsize
+    stage = n * bh * (C + 2 * rows) * hdp * 4 if C > 1 else 0
+    return io + stage + n * 2 * bh * rows * hdp * 4
 
 
 def _row_block(wi, span, j, rows, n_blocks):
@@ -89,9 +95,10 @@ def _row_block(wi, span, j, rows, n_blocks):
     return jnp.clip(jnp.minimum(wi // rows + j, last), 0, n_blocks - 1)
 
 
-def _commit_kernel(wi_ref, span_ref, k_new_ref, v_new_ref, k_pool_ref,
-                   v_pool_ref, k_out_ref, v_out_ref, *stages, rows, cols,
-                   n_blocks):
+def _commit_kernel(wi_ref, span_ref, *refs, n, rows, cols, n_blocks):
+    """``refs``: the fresh rows of the ``n`` leaves, their pool blocks, the
+    aliased output blocks and (for a span) their staging buffers."""
+    new_refs, pool_refs, out_refs, stages = (refs[k * n:(k + 1) * n] for k in range(4))
     i = pl.program_id(0)
     j = pl.program_id(2)
     wi = wi_ref[i]
@@ -104,8 +111,7 @@ def _commit_kernel(wi_ref, span_ref, k_new_ref, v_new_ref, k_pool_ref,
     # the columns are never selected
     off = jnp.clip(base - wi + rows, 0, cols + rows)
     for new_ref, pool_ref, out_ref, stage in zip(
-            (k_new_ref, v_new_ref), (k_pool_ref, v_pool_ref),
-            (k_out_ref, v_out_ref), stages or (None, None)):
+            new_refs, pool_refs, out_refs, stages or (None, ) * n):
         if cols == 1:
             fresh = new_ref[0]  # (bh, 1, hd): one row for every position
         else:
@@ -123,39 +129,46 @@ def _commit_kernel(wi_ref, span_ref, k_new_ref, v_new_ref, k_pool_ref,
 def commit_kv_rows(leaves, new_rows, write_index, q_spans):
     """Write a layer's fresh K and V rows into its pool leaves, in place.
 
-    ``leaves``: the layer's ``(k_pool, v_pool)``, each ``(N, nkv, S, hd)``;
-    ``new_rows``: ``(k, v)``, each ``(N, nkv, C, hd)`` (cast to the pool's
-    dtype here); ``write_index``, ``q_spans``: ``(N,)`` int32, heads
-    non-negative. Row ``i``'s column ``j`` lands at ``write_index[i] + j``
-    when ``j < q_spans[i]`` and that position is ``< S`` — the pool the
-    ``mode="drop"`` scatter leaves, byte for byte. Returns the new
-    ``(k_pool, v_pool)``; the operands are aliased to them, so inside a
-    program that donates or carries the pool nothing else of it moves.
+    ``leaves``: the layer's K/V leaves, ``(k_pool, v_pool)`` of ``(N, nkv,
+    S, hd)`` each or the packed ``(kv_pool, )`` of ``(N, nkv, S, 2 * hd)``;
+    ``new_rows``: the fresh rows in the same form, ``(N, nkv, C, lanes)``
+    each (cast to the pool's dtype here; a packed row is its key and its
+    value joined on the last axis); ``write_index``, ``q_spans``: ``(N,)``
+    int32, heads non-negative. Row ``i``'s column ``j`` lands at
+    ``write_index[i] + j`` when ``j < q_spans[i]`` and that position is
+    ``< S`` — the pool the ``mode="drop"`` scatter leaves, byte for byte.
+    Returns the new leaves as a tuple; the operands are aliased to them, so
+    inside a program that donates or carries the pool nothing else of it
+    moves.
 
     Jitted, so that the layers of a step program (and its first forward
     and loop body) share one trace and one lowering of the kernel: lowering
     a Mosaic call is a tenth of a second, 36 to 72 times a program."""
-    return _commit(leaves, new_rows, write_index, q_spans,
+    return _commit(tuple(leaves), tuple(new_rows), write_index, q_spans,
                    interpret=_pallas.interpret())
 
 
 @functools.partial(jax.jit, static_argnames="interpret")
 def _commit(leaves, new_rows, write_index, q_spans, *, interpret):
-    k_pool, v_pool = leaves
-    k_new, v_new = (x.astype(k_pool.dtype) for x in new_rows)
-    N, nkv, S, hd = k_pool.shape
-    C = k_new.shape[2]
-    rows = block_rows(k_pool.dtype)
-    itemsize = jnp.dtype(k_pool.dtype).itemsize
-    if not commits_in_place(k_pool) or v_pool.shape != k_pool.shape:
-        raise ValueError(f"kv commit: pool leaves {k_pool.shape}/{v_pool.shape} "
-                         f"are not whole {rows}-row blocks of one shape")
+    pool = leaves[0]
+    new_rows = tuple(x.astype(pool.dtype) for x in new_rows)
+    n = len(leaves)
+    N, nkv, S, hd = pool.shape
+    C = new_rows[0].shape[2]
+    rows = block_rows(pool.dtype)
+    itemsize = jnp.dtype(pool.dtype).itemsize
+    if (not commits_in_place(pool) or len(new_rows) != n
+            or any(c.shape != pool.shape or c.dtype != pool.dtype for c in leaves)
+            or any(x.shape != (N, nkv, C, hd) for x in new_rows)):
+        raise ValueError(f"kv commit: pool leaves {[c.shape for c in leaves]} "
+                         f"are not whole {rows}-row blocks of one shape, or the "
+                         f"fresh rows {[x.shape for x in new_rows]} are not theirs")
     bh = next((b for b in range(nkv, 0, -1) if nkv % b == 0
-               and _pallas.fits_vmem(_vmem_estimate(b, rows, C, hd, itemsize))), None)
+               and _pallas.fits_vmem(_vmem_estimate(n, b, rows, C, hd, itemsize))), None)
     if bh is None:
         raise ValueError(
             f"kv commit: one kv head x {C} columns of width {hd} needs "
-            f"{_vmem_estimate(1, rows, C, hd, itemsize)} bytes of VMEM, over the "
+            f"{_vmem_estimate(n, 1, rows, C, hd, itemsize)} bytes of VMEM, over the "
             f"{_pallas.VMEM_BLOCK_BUDGET}-byte budget; narrow the query span "
             f"(prefill_chunk)")
     n_blocks = S // rows
@@ -167,23 +180,23 @@ def _commit(leaves, new_rows, write_index, q_spans, *, interpret):
     new_spec = pl.BlockSpec((1, bh, C, hd), lambda i, h, j, *_: (i, h, 0, 0))
     pool_spec = pl.BlockSpec((1, bh, rows, hd), pool_index)
     stage = pltpu.VMEM((bh, C + 2 * rows, hd), jnp.float32)
-    pool_shape = jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype)
+    pool_shape = jax.ShapeDtypeStruct(pool.shape, pool.dtype)
     return tuple(pl.pallas_call(
-        functools.partial(_commit_kernel, rows=rows, cols=C, n_blocks=n_blocks),
+        functools.partial(_commit_kernel, n=n, rows=rows, cols=C, n_blocks=n_blocks),
         name="dstpu_kv_commit",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(N, nkv // bh, steps),
-            in_specs=[new_spec, new_spec, pool_spec, pool_spec],
-            out_specs=[pool_spec, pool_spec],
-            scratch_shapes=[stage, stage] if C > 1 else [],
+            in_specs=[new_spec] * n + [pool_spec] * n,
+            out_specs=[pool_spec] * n,
+            scratch_shapes=[stage] * n if C > 1 else [],
         ),
-        out_shape=[pool_shape, pool_shape],
+        out_shape=[pool_shape] * n,
         # operand numbers count the two scalar-prefetch operands
-        input_output_aliases={4: 0, 5: 1},
+        input_output_aliases={2 + n + k: k for k in range(n)},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=_pallas.VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(write_index.astype(jnp.int32), q_spans.astype(jnp.int32),
-      k_new, v_new, k_pool, v_pool))
+      *new_rows, *leaves))

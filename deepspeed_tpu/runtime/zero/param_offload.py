@@ -1027,10 +1027,10 @@ class ParamStreamRunner:
         ids = np.asarray(input_ids)
         B, T0 = ids.shape
         S = T0 + max_new_tokens
-        cfg = model.cfg
-        cache = [(jnp.zeros((B, cfg.kv_heads, S, cfg.head_size), cd),
-                  jnp.zeros((B, cfg.kv_heads, S, cfg.head_size), cd))
-                 for _ in range(self.L)]
+        # per-layer leaves in the geometry the model's own cache has
+        pool = model.init_cache(B, S, dtype=cd)
+        cache = [tuple(comp[l] for comp in pool) for l in range(self.L)]
+        del pool
 
         def build():
             ef = jax.jit(lambda ep, i, ci: model.stream_embed(ep, i, ci).astype(cd))

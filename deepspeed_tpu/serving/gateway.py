@@ -1404,6 +1404,10 @@ class Gateway:
                           # with: "scatter" ones relay the pool every step
                           "kv_commit_programs": dict(getattr(
                               sched, "kv_commit_programs", {})),
+                          # the pool's geometry at rest: "packed" (K beside
+                          # V in one leaf a layer), "split" or "latent"
+                          "kv_pool_geometry": getattr(
+                              sched, "kv_pool_geometry", None),
                           # ... and, for MoE models, by the expert dispatch:
                           # "dense" ones run every expert on every row
                           "moe_dispatch_programs": dict(getattr(

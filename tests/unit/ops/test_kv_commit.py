@@ -1,6 +1,7 @@
 """The in-place K/V commit kernel (``ops/pallas/kv_commit.py``, interpret
 mode) against the ``vmap`` scatter it stands in for: the whole pool, bit
-for bit."""
+for bit, in both geometries a per-head pool rests in: split (a K and a V
+leaf) and packed (one leaf, a row's key and value side by side)."""
 
 import functools
 
@@ -19,6 +20,11 @@ def _scatter(pools, fresh, write_index, q_spans):
     from deepspeed_tpu.models.transformer import _commit_span_rows
     return _commit_span_rows(list(zip(pools, fresh)), write_index, q_spans,
                              paged_kernels=False)
+
+
+def _pack(leaves):
+    """The packed form of a split (K, V) pair: one leaf, keys then values."""
+    return (jnp.concatenate(leaves, axis=-1), )
 
 
 def _cases(C, rows):
@@ -46,7 +52,8 @@ def _cases(C, rows):
                                   "straddle", "past_end"])
 @pytest.mark.parametrize("C", [1, 64])
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.int8], ids=["bf16", "int8"])
-def test_commit_matches_scatter(dtype, C, case):
+@pytest.mark.parametrize("form", ["split", "packed"])
+def test_commit_matches_scatter(form, dtype, C, case):
     rows = kv_commit.block_rows(dtype)
     rng = np.random.default_rng(C + rows)
     if dtype == jnp.int8:
@@ -56,7 +63,15 @@ def test_commit_matches_scatter(dtype, C, case):
     pools = make(N, NKV, S, HD), make(N, NKV, S, HD)
     fresh = make(N, NKV, C, HD), make(N, NKV, C, HD)
     heads, spans = (jnp.asarray(x, jnp.int32) for x in _cases(C, rows)[case])
+    if form == "packed":
+        # ... and the packed commit leaves what the split one does, joined
+        split = jax.jit(kv_commit.commit_kv_rows)(pools, fresh, heads, spans)
+        pools, fresh = _pack(pools), _pack(fresh)
     got = jax.jit(kv_commit.commit_kv_rows)(pools, fresh, heads, spans)
+    assert len(got) == len(pools)
+    if form == "packed":
+        np.testing.assert_array_equal(np.asarray(got[0].view(jnp.uint8)),
+                                      np.asarray(_pack(split)[0].view(jnp.uint8)))
     for pool, out, want in zip(pools, got, _scatter(pools, fresh, heads, spans)):
         np.testing.assert_array_equal(np.asarray(out.view(jnp.uint8)),
                                       np.asarray(want.view(jnp.uint8)))
@@ -65,18 +80,24 @@ def test_commit_matches_scatter(dtype, C, case):
                                       np.asarray(pool.view(jnp.uint8))[idle])
 
 
-def test_commit_splits_heads_to_fit_vmem(monkeypatch):
+@pytest.mark.parametrize("form", ["split", "packed"])
+def test_commit_splits_heads_to_fit_vmem(monkeypatch, form):
     """A head block is chosen against the VMEM budget; a smaller budget gives
-    more grid steps and the same pool."""
+    more grid steps and the same pool. One packed leaf of 128 lanes needs
+    half of what a K and a V leaf of 64 (padded to 128) need."""
     import deepspeed_tpu.ops.pallas as pallas_pkg
     rng = np.random.default_rng(0)
     make = lambda *shape: jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
     pools = make(2, 4, 64, 64), make(2, 4, 64, 64)
     fresh = make(2, 4, 8, 64), make(2, 4, 8, 64)
     heads, spans = jnp.asarray([15, 60], jnp.int32), jnp.asarray([8, 8], jnp.int32)
+    assert kv_commit._vmem_estimate(2, 1, 16, 8, 64, 2) == 2 * kv_commit._vmem_estimate(
+        1, 1, 16, 8, 128, 2)
+    if form == "packed":
+        pools, fresh = _pack(pools), _pack(fresh)
     commit = functools.partial(kv_commit._commit.__wrapped__, interpret=True)
     whole = commit(pools, fresh, heads, spans)
-    one_head = kv_commit._vmem_estimate(1, 16, 8, 64, 2)
+    one_head = kv_commit._vmem_estimate(len(pools), 1, 16, 8, pools[0].shape[-1], 2)
     monkeypatch.setattr(pallas_pkg, "VMEM_BLOCK_BUDGET", one_head)
     split = commit(pools, fresh, heads, spans)
     for a, b in zip(whole, split):
@@ -91,6 +112,7 @@ def test_commits_in_place_reads_the_leaf():
     sds = jax.ShapeDtypeStruct
     assert kv_commit.commits_in_place(sds((4, 2, 64, 64), jnp.bfloat16))
     assert kv_commit.commits_in_place(sds((4, 2, 64, 64), jnp.int8))
+    assert kv_commit.commits_in_place(sds((4, 2, 64, 128), jnp.bfloat16))  # packed
     assert not kv_commit.commits_in_place(sds((4, 2, 48, 64), jnp.int8))   # 32-row blocks
     assert not kv_commit.commits_in_place(sds((4, 2, 20, 64), jnp.float32))
     assert not kv_commit.commits_in_place(sds((4, 64, 1), jnp.float16))
