@@ -202,6 +202,9 @@ class InferenceEngine:
             raise ValueError(
                 "latent attention is served in its float dtype on an unsharded pool: "
                 "int8 weights and a tensor mesh axis > 1 are not supported yet")
+        if getattr(model.cfg, "layer_types", ()) and self._int8_weights:
+            raise ValueError("a model with layer_types (per-layer mixers) is served in its "
+                             "float dtype: int8 weights are not supported yet")
         if cfg.kernel_inject and hasattr(model.cfg, "scan_layers"):
             overrides["attention_impl"] = "flash"
             # unrolled layers: the KV cache becomes per-layer tensors that
@@ -591,6 +594,9 @@ class InferenceEngine:
         if getattr(mc, "local_attention_layers", ()):
             reasons.append("local-attention layers (the fused path has no "
                            "per-layer sliding-window starts)")
+        if getattr(mc, "layer_types", ()):
+            reasons.append("layer_types (per-layer mixers: the fused path has one "
+                           "attention block and no recurrent-state update)")
         if getattr(mc, "act_quant_bits", 0):
             reasons.append(f"act_quant_bits={mc.act_quant_bits} (no fused "
                            f"fake-quant of block inputs)")
@@ -978,6 +984,11 @@ class InferenceEngine:
         per-row new-token arrays). The KV cache returns to the pool
         immediately (device-side refs; execution order serializes reuse)."""
         self._check_offload_path("the static-batch generate() path")
+        if "linear_attention" in getattr(self.model_config, "layer_types", ()):
+            raise ValueError("a model with linear_attention layers is served through the "
+                             "continuous-batching scheduler (submit() / the gateway): the "
+                             "static-batch generate() cache has no per-row spans to "
+                             "advance a recurrent state by")
         rows = [np.asarray(r, np.int32).reshape(-1) for r in input_ids]
         B = len(rows)
         lens = np.array([len(r) for r in rows], np.int32)

@@ -66,10 +66,18 @@ class SlotKVCache:
     ``pool`` is the device-side cache tree (``model.init_cache(num_slots,
     max_len)``); it is REPLACED by the scheduler after every compiled step
     (functional update with donation, so the buffers alias in place).
+
+    ``kinds``: the model's declaration of what each leaf holds
+    (``model.cache_kinds()``, a tree of ``pool``'s structure): ``"rows"``
+    (a row axis at ``ndim - 2``, one row a position) or ``"state"`` (per
+    slot, no row axis: a linear-attention layer's recurrent state and
+    convolution window). None: every leaf holds rows. A leaf's shape does
+    not say which it is; the declaration does.
     """
 
-    def __init__(self, pool, num_slots, max_len, page_size=256, max_extents=1):
+    def __init__(self, pool, num_slots, max_len, page_size=256, max_extents=1, kinds=None):
         self.pool = pool
+        self.leaf_kinds = (None if kinds is None else tuple(jax.tree_util.tree_leaves(kinds)))
         self.num_slots = int(num_slots)
         self.max_len = int(max_len)
         self.page_size = int(page_size)
@@ -374,20 +382,36 @@ class SlotKVCache:
 
     def bytes_per_token(self):
         """HBM bytes backing ONE cache row (all layers, K+V, and — on the
-        int8 tier — the per-token scale leaves): every pool leaf keeps its
+        int8 tier — the per-token scale leaves): every ROW leaf keeps its
         slot and row axes, so per-row bytes fall out of leaf sizes
         generically for the plain and quantized layouts, split or packed
-        (the packed leaf holds the split pair's bytes). 0 when the
+        (the packed leaf holds the split pair's bytes). State leaves
+        (:meth:`state_bytes_per_slot`) do not count. 0 when the
         pool is host-bookkeeping-only (tests)."""
-        if self.pool is None:
-            return 0
         denom = self.num_slots * self.max_len
         return int(sum((leaf.size // denom) * leaf.dtype.itemsize
-                       for leaf in jax.tree_util.tree_leaves(self.pool)))
+                       for leaf in self._leaves("rows")))
+
+    def _leaves(self, kind):
+        """The pool's leaves the model declared as ``kind``."""
+        if self.pool is None:
+            return []
+        leaves = jax.tree_util.tree_leaves(self.pool)
+        if self.leaf_kinds is None:
+            return leaves if kind == "rows" else []
+        return [leaf for leaf, k in zip(leaves, self.leaf_kinds) if k == kind]
+
+    def state_bytes_per_slot(self):
+        """HBM bytes of per-slot STATE one slot holds (all layers' recurrent
+        state and convolution window): what a slot costs whatever its
+        length. 0 for a pool of rows only."""
+        return int(sum((leaf.size // self.num_slots) * leaf.dtype.itemsize
+                       for leaf in self._leaves("state")))
 
     def capacity_bytes(self):
-        """Total HBM held by the fixed-shape pool."""
-        return self.bytes_per_token() * self.num_slots * self.max_len
+        """Total HBM held by the fixed-shape pool, rows and state."""
+        return (self.bytes_per_token() * self.max_len
+                + self.state_bytes_per_slot()) * self.num_slots
 
     def live_bytes(self):
         """Bytes backing live + retained rows (the working set; the rest of
@@ -454,7 +478,8 @@ def slot_slice(pool, slot):
     are (L, N, kv, S, lanes) (slot axis 1), per-layer leaves (N, kv, S,
     lanes) (slot axis 0) — and on every geometry of ``init_cache`` (split
     K and V leaves, the packed K/V leaf, the latent leaf, the int8 tier's
-    scale leaf): the slot axis is at ``ndim - 4`` in all of them."""
+    scale leaf, a linear-attention layer's state and window): the slot axis
+    is at ``ndim - 4`` in all of them."""
     return jax.tree_util.tree_map(
         lambda c: jax.lax.dynamic_slice_in_dim(c, slot, 1, axis=c.ndim - 4), pool)
 

@@ -124,6 +124,23 @@ count O(1) in expert count, routing mix, and churn (every reachable
 variant warms at build via :meth:`warm_programs`). See
 ``benchmarks/SERVING.md`` ("MoE serving").
 
+**Recurrent state beside rows**: a model with linear-attention layers
+(``layer_types``) holds, for those layers, a per-slot recurrent state and
+convolution window in the pool and no rows (``CausalLMModel.cache_spec``;
+:class:`~deepspeed_tpu.inference.kv_cache.SlotKVCache` counts rows and state
+apart). The same step programs serve it: a row's state advances over exactly
+its live columns, a span-0 slot's leaves come out bit for bit as they went
+in, a span that starts at position 0 starts from zero. Such a pool's
+programs take one more operand, the rows' substep spans
+(:meth:`DecodeScheduler._substep_spans`). Prefix reuse is off for it (counted:
+``serving/prefix_cache_state_bypass``) and speculative verify, extent chains,
+sequence-parallel prefill, tiering, migration, lossy windows, an int8 pool,
+adapters and a tensor-parallel pool are refused at build: a state has no
+rows to mask, copy by length or roll back. Gauges
+``serving/state_bytes_per_slot``, ``serving/state_slots_live``; counter
+``serving/state_slots_reset``. See ``benchmarks/SERVING.md`` ("Recurrent
+state beside K/V rows").
+
 Telemetry (PR-1 sink): gauges ``serving/slot_occupancy``,
 ``serving/batch_efficiency``, ``serving/kv_token_utilization``,
 ``serving/prefix_cache_hit_rate``, ``serving/spec_acceptance_rate``,
@@ -539,9 +556,30 @@ class DecodeScheduler:
             if unsupported:
                 raise ValueError("the latent KV pool does not support "
                                  + ", ".join(unsupported) + " yet")
+        # per-slot STATE beside the rows (a model with linear-attention
+        # layers): a state has no rows for per-slot ends to mask and no past
+        # to roll back to, so everything that copies, truncates, moves or
+        # re-reads a slot's rows is refused here, by name
+        self._state_pool = "linear_attention" in getattr(model.cfg, "layer_types", ())
+        if self._state_pool:
+            unsupported = [name for name, on in (
+                ("speculative verify (spec_tokens): a state cannot roll back the "
+                 "rejected columns", int(spec_tokens) > 0),
+                ("extent chains (max_extents > 1)", int(max_extents) > 1),
+                ("sequence-parallel prefill", bool(self._seq_chunk)),
+                ("tier demotion (prefix_store)", prefix_store is not None),
+                ("lossy KV windows", self.allow_lossy_kv),
+                ("an int8 KV pool (kv_cache_dtype)", self.kv_quantized),
+                ("adapters (adapter_store)", adapter_store is not None),
+                ("a tensor-parallel pool", tp_ax > 1)) if on]
+            if unsupported:
+                raise ValueError("a slot pool that holds recurrent state (layer_types with "
+                                 "linear_attention) does not support "
+                                 + "; ".join(unsupported) + " yet")
+        kinds = model.cache_kinds() if hasattr(model, "cache_kinds") else None
         self.cache = SlotKVCache(engine._init_cache(int(num_slots), S, kv_dtype=kv_arg),
                                  int(num_slots), S, page_size=min(block, S),
-                                 max_extents=me)
+                                 max_extents=me, kinds=kinds)
         # self-speculative decoding: spec_tokens drafted columns verified
         # per pure-decode sync (clamped so a full verify block always fits
         # one slot alongside at least one row of decode headroom)
@@ -557,7 +595,15 @@ class DecodeScheduler:
         self.spec_delivered = 0   # tokens delivered by spec steps
         # radix prefix cache: reuse rounds matches to chunk boundaries so a
         # hit replays the cold path's exact programs
-        self.radix = RadixPrefixCache(self.cache) if prefix_cache else None
+        # A pool with state leaves serves every prompt cold: the radix copy
+        # trusts per-slot ends to mask the donor's rows past the match, and a
+        # state has no rows to mask (a hit at any prefix but the donor's own
+        # end would be silently wrong). The lookups not made are counted.
+        self.radix = (RadixPrefixCache(self.cache)
+                      if prefix_cache and not self._state_pool else None)
+        self._state_bypass = bool(prefix_cache) and self._state_pool
+        self.prefix_cache_state_bypass = 0  # lookups not made
+        self.state_slots_reset = 0  # requests begun from a zero state
         # hierarchical KV tier: a shared GlobalPrefixStore turns radix
         # eviction into demotion (device -> host/NVMe) and admission into
         # restoration — LRU pressure stops destroying reuse, and the store
@@ -690,6 +736,9 @@ class DecodeScheduler:
         self.telemetry = engine.telemetry
         self.telemetry.gauge("serving/kv_pool_packed",
                              int(self.kv_pool_geometry == "packed"))
+        if self._state_pool:
+            self.telemetry.gauge("serving/state_bytes_per_slot",
+                                 self.cache.state_bytes_per_slot())
         # set by serving/replica.py when this scheduler serves in a fleet;
         # request traces stamp it so the migration-aware trace_summary view
         # can pair prefill and decode replicas per request
@@ -858,6 +907,13 @@ class DecodeScheduler:
         return self.cache.num_slots
 
     @property
+    def steps_run(self):
+        """Forwards over the whole slot block fetched so far: a sync's
+        column (or block) forward and each of its substeps (a split chunk's
+        one-slot forward is not among them)."""
+        return self._steps
+
+    @property
     def weights_version(self):
         """Monotonic weights generation of the slot pool: every KV row and
         trie registration is stamped with the version that computed it."""
@@ -939,6 +995,7 @@ class DecodeScheduler:
         request — the store is fleet-shared, so the decode replica's rows
         gather the same resident pages. ``on_ready(entry_or_None)`` fires
         once the handoff entry is claimable."""
+        self._refuse_state_migration()
         slot = req.slot
         kv_len = int(self.cache.lengths[slot])
         # demote FIRST, release AFTER: the compiled slice's output owns
@@ -968,6 +1025,12 @@ class DecodeScheduler:
             req.trace.instant("migrate_out", replica=self.replica_idx,
                               kv_len=kv_len)
         return kv_len
+
+    def _refuse_state_migration(self):
+        if self._state_pool:
+            raise ValueError("a request whose slot holds recurrent state (layer_types with "
+                             "linear_attention) cannot migrate between replicas yet: the "
+                             "handoff moves rows only")
 
     def _settle_migration(self, record, error=None, discard=True):
         """Terminal bookkeeping shared by every failed/cancelled handoff
@@ -1000,6 +1063,7 @@ class DecodeScheduler:
         parked. A restore raising on device settles the request as failed
         FIRST and then re-raises, so the pump's sick-replica handling
         runs without stranding a request that no scheduler owns."""
+        self._refuse_state_migration()
         req = record.req
         tel = self.telemetry
         if req.cancelled or record.entry is None:
@@ -1206,6 +1270,8 @@ class DecodeScheduler:
             tel.counter("serving/decode_tokens", delivered)
             tel.histogram("serving/step_ms", dur_ms / ksteps)
             tel.histogram("serving/tokens_per_step", delivered / ksteps)
+            if self._state_pool:
+                tel.gauge("serving/state_slots_live", self.cache.active_slots)
             tel.gauges([("serving/slot_occupancy", self.cache.occupancy(), None),
                         ("serving/batch_efficiency",
                          delivered / (ksteps * self.cache.num_slots), None),
@@ -1633,6 +1699,15 @@ class DecodeScheduler:
                          cached_tokens=pos, prompt=int(req.prompt.size),
                          **({"restored": True} if restored else {}))
         self.cache.lengths[slot] = pos
+        if self._state_pool:
+            # the first chunk starts at position 0: the step program starts
+            # this slot's state and window from zero whatever it held
+            self.state_slots_reset += 1
+            self.prefix_cache_state_bypass += int(self._state_bypass)
+            if tel.enabled:
+                tel.counter("serving/state_slots_reset")
+                if self._state_bypass:
+                    tel.counter("serving/prefix_cache_state_bypass")
         if req.adapter_id is not None and tel.enabled:
             tel.counter(f"serving/adapter/{self.adapters.label(req.adapter_id)}"
                         f"/requests")
@@ -1879,6 +1954,21 @@ class DecodeScheduler:
             self.moe_dispatch_programs[path] += 1
             self.telemetry.counter(f"serving/moe_{path}_programs")
         return out
+
+    def _substep_spans(self, spans, held=None):
+        """The step program's trailing operand for a pool with state leaves:
+        each row's span in the sync's SUBSTEPS, 1 for the rows that really
+        decode there and 0 for slot ``held``, a prefill row whose chunk is
+        not its last. Rows of K/V forgive a substep fed a garbage token (the
+        next chunk overwrites what it wrote); a recurrent state does not, so
+        such a row is told to stand still. () for a pool of rows only, whose
+        programs take no such operand."""
+        if not self._state_pool:
+            return ()
+        sub = np.minimum(spans, 1).astype(np.int32)
+        if held is not None:
+            sub[held] = 0
+        return (jnp.asarray(sub), )
 
     def _call_step(self, fn, args, lora, spans):
         """Dispatch ONE step program (``spans``: the host's copy of
@@ -2153,7 +2243,8 @@ class DecodeScheduler:
                     jnp.asarray(np.zeros(N, np.uint32)), jnp.asarray(zeros),
                     jnp.asarray(np.zeros(N, bool)),
                     jnp.asarray(np.ones(N, np.float32)), jnp.asarray(zeros),
-                    jnp.asarray(np.ones(N, np.float32))) + tuple(ext_args)
+                    jnp.asarray(np.ones(N, np.float32))) + tuple(ext_args) \
+                + self._substep_spans(zeros)
             out = self._call_step(fn, args, lora, zeros)
             self.cache.pool = out[0]
 
@@ -2247,6 +2338,7 @@ class DecodeScheduler:
                     jnp.asarray(temps), jnp.asarray(topks), jnp.asarray(topps))
             if eo is not None:
                 args = args + tuple(jnp.asarray(x) for x in eo)
+            args = args + self._substep_spans(spans)
         try:
             out = self._call_step(fn, args, lora, spans)
         except _ExpertOverflow as e:
@@ -2457,6 +2549,7 @@ class DecodeScheduler:
                     jnp.asarray(temps), jnp.asarray(topks), jnp.asarray(topps))
             if eo is not None:
                 args = args + tuple(jnp.asarray(x) for x in eo)
+            args = args + self._substep_spans(spans, held=None if final else ps)
         try:
             out = self._call_step(fn, args, lora, spans)
         except _ExpertOverflow as e:
@@ -2590,6 +2683,11 @@ class DecodeScheduler:
         rows in the first forward AND the substeps — so the scheduler can
         pass them length 0 and keep the paged kernel's KV-block walk
         bounded by the longest LIVE row, not the longest retained prefix.
+        A prefill row whose chunk is NOT its last rides the substeps on a
+        garbage token: its K/V rows are overwritten by the next chunk. Where
+        slots hold recurrent state that would corrupt it, so such a pool's
+        program takes the substep spans as an operand
+        (:meth:`_substep_spans`) and the row stands still.
 
         Fused decode blocks: when the engine's structured gate passes
         (``self._fused_block``) the forward routes through
@@ -2637,6 +2735,7 @@ class DecodeScheduler:
             tp = self._shard_deg
             stats = self._moe_stats
             offload = self.experts is not None
+            state_pool = self._state_pool
             choice = collect and self._moe and not fused_block
 
             def sample(l2, seeds, steps, flags, temps, topks, topps):
@@ -2657,6 +2756,12 @@ class DecodeScheduler:
                 if ext or seqp:
                     ext_ops = tuple(extra[:5])
                     i = 5
+                # a pool with state leaves: the rows' substep spans
+                # (_substep_spans), the last of the canonical operands
+                sub_spans = None
+                if state_pool:
+                    sub_spans = extra[i]
+                    i += 1
                 lops = None
                 if lora:
                     from ..adapters.batched_lora import gather_rows
@@ -2719,7 +2824,9 @@ class DecodeScheduler:
                 if K == 1:
                     return result(pool, out_toks, out_logits, out_choice, total_cnt)
                 base = lengths + jnp.maximum(spans, 1) - 1  # per-row write head - 1
-                live01 = jnp.minimum(spans, 1)  # substep spans: drop dead rows' writes
+                # substep spans: drop dead rows' writes (and, where slots hold
+                # state, hold a prefill row still until its last chunk)
+                live01 = jnp.minimum(spans, 1) if sub_spans is None else sub_spans
 
                 def body(k, carry):
                     pool, tok, out_toks, out_logits, out_choice, total_cnt = carry

@@ -163,3 +163,35 @@ def tiny_mla_moe():
     """Test-scale latent-attention MoE; ``rope_original_max_len`` 16 so that
     the YaRN blend and g(t) are exercised within 128 positions."""
     return _mla_moe(64, 2, 4, 32, 16, 8, 8, 16, 8, 2, 32, 1, 256, 128, 16, 8.0)
+
+
+def _hybrid(hidden, layers, heads, head_dim, ffn, lin_heads, lin_dk, lin_dv, vocab, seq,
+            period=4):
+    """Gated-delta-rule linear attention with one full-attention layer a
+    ``period`` (``olmo_hybrid`` style): post-norm residuals, QK norm, no
+    rotary rotation, SwiGLU, untied head. Unrolled: the layers differ."""
+    types = tuple("full_attention" if (i + 1) % period == 0 else "linear_attention"
+                  for i in range(layers))
+    return TransformerConfig(
+        vocab_size=vocab, hidden_size=hidden, num_layers=layers, num_heads=heads,
+        head_dim=head_dim, intermediate_size=ffn, max_seq_len=seq, pos_embedding="none",
+        norm="rmsnorm", activation="swiglu", tie_embeddings=False, layernorm_epsilon=1e-6,
+        attn_bias=False, layer_types=types, linear_num_heads=lin_heads,
+        linear_key_head_dim=lin_dk, linear_value_head_dim=lin_dv, linear_neg_eigval=True, post_norm=True, qk_norm=True, scan_layers=False)
+
+
+@register("olmo-hybrid-7b")
+def olmo_hybrid_7b():
+    """Olmo-Hybrid-7B at its published sizes (huggingface.co/allenai/
+    Olmo-Hybrid-7B config.json, ``model_type: olmo_hybrid``): 32 layers,
+    three gated-delta-rule layers (30 heads, keys of 96, values of 192,
+    convolution of 4) to each full-attention layer (30 heads of 128).
+    Served only. ``num_layers`` is overridden together with ``layer_types``."""
+    return _hybrid(3840, 32, 30, 128, 11008, 30, 96, 192, 100352, 65536)
+
+
+@register("tiny-hybrid")
+def tiny_hybrid():
+    """Test-scale hybrid: one period of three linear-attention layers and a
+    full-attention one."""
+    return _hybrid(64, 4, 4, 16, 128, 4, 8, 16, 256, 256)

@@ -29,6 +29,9 @@ from jax.sharding import PartitionSpec as P
 from ..comm import comm as dist
 
 
+LAYER_TYPES = ("full_attention", "linear_attention")
+
+
 @dataclasses.dataclass(frozen=True)
 class TransformerConfig:
     vocab_size: int = 50257
@@ -106,6 +109,21 @@ class TransformerConfig:
     rope_mscale: float = 1.0
     rope_mscale_all_dim: float = 0.0
     attn_temp_beta: float = 0.0  # g(t) = 1 + beta * ln(1 + floor(t / rope_original_max_len))
+    # per-layer mixers: one of LAYER_TYPES for each layer ("full_attention":
+    # Attention; "linear_attention": the gated delta rule, GatedDeltaNet,
+    # which holds a per-slot recurrent state and convolution window and no
+    # cache rows). () = every layer full attention. Needs unrolled layers
+    layer_types: Tuple[str, ...] = ()
+    linear_num_heads: int = 0  # key heads = value heads of a linear layer
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
+    linear_conv_kernel: int = 4  # causal depthwise convolution over q, k, v
+    linear_neg_eigval: bool = False  # beta in (0, 2): negative eigenvalues of the transition
+    # h = x + norm(mixer(x)), y = h + norm(mlp(h)): mixer and MLP read the
+    # residual stream itself and their OUTPUTS are normalised (Olmo 2/3)
+    post_norm: bool = False
+    # RMSNorm over the whole q and k projections, before the split into heads
+    qk_norm: bool = False
     # systems
     dtype: Any = jnp.bfloat16
     scan_layers: bool = True
@@ -160,6 +178,30 @@ class TransformerConfig:
         if self.local_attention_layers and self.scan_layers:
             raise ValueError("local_attention_layers (per-layer windows) requires "
                              "scan_layers=False — scanned layers share one program")
+        if self.layer_types:
+            object.__setattr__(self, "layer_types", tuple(self.layer_types))  # a JSON list
+            unknown = sorted(set(self.layer_types) - set(LAYER_TYPES))
+            if unknown or len(self.layer_types) != self.num_layers:
+                raise ValueError(f"layer_types names one of {LAYER_TYPES} for each of the "
+                                 f"{self.num_layers} layers, got {len(self.layer_types)} "
+                                 f"entries" + (f" with unknown {unknown}" if unknown else ""))
+            if self.scan_layers:
+                raise ValueError("layer_types (per-layer mixers) requires scan_layers=False "
+                                 "— scanned layers share one program")
+            if "linear_attention" in self.layer_types and not (
+                    self.linear_num_heads and self.linear_key_head_dim
+                    and self.linear_value_head_dim and self.linear_conv_kernel > 1):
+                raise ValueError("linear_attention layers need linear_num_heads, "
+                                 "linear_key_head_dim, linear_value_head_dim and a "
+                                 "convolution of width > 1")
+            if self.kv_lora_rank or self.num_experts or self.parallel_residual \
+                    or self.int8_weights:
+                raise ValueError("layer_types composes with plain attention and a dense MLP "
+                                 "in a float dtype only (no latent attention, experts, "
+                                 "parallel residual or int8 weights)")
+        if self.post_norm and (self.num_experts or self.parallel_residual or self.dropout > 0):
+            raise ValueError("post_norm composes with a dense MLP, sequential residuals "
+                             "and no dropout only")
         if self.kv_lora_rank and not (self.q_lora_rank and self.qk_nope_head_dim
                                       and self.qk_rope_head_dim and self.v_head_dim
                                       and self.pos_embedding == "rope"):
@@ -210,6 +252,16 @@ class TransformerConfig:
     def expert_ffn_size(self):
         return self.moe_ffn_size or self.ffn_size
 
+    def layer_type(self, layer_idx):
+        """The mixer of layer ``layer_idx`` (one of :data:`LAYER_TYPES`)."""
+        return self.layer_types[layer_idx] if self.layer_types else "full_attention"
+
+    @property
+    def linear_conv_channels(self):
+        """Channels of a linear layer's convolution: its q, k and v side by side."""
+        return self.linear_num_heads * (2 * self.linear_key_head_dim
+                                        + self.linear_value_head_dim)
+
     @property
     def latent_width(self):
         """Values a position holds in a latent cache (0 = per-head K and V)."""
@@ -233,6 +285,16 @@ class TransformerConfig:
                    + h * self.num_experts)
         emb = v * h * (1 if self.tie_embeddings else 2)
         pos = self.max_seq_len * h if self.pos_embedding == "learned" else 0
+        if self.qk_norm:
+            attn += self.head_size * (self.num_heads + self.kv_heads)
+        n_lin = sum(t == "linear_attention" for t in self.layer_types)
+        if n_lin:
+            # q, k; v, gate, out; the two per-head gates with A_log and
+            # dt_bias; the convolution; the gated norm's scale
+            nl, dk, dv = self.linear_num_heads, self.linear_key_head_dim, self.linear_value_head_dim
+            lin = (2 * h * nl * dk + 3 * h * nl * dv + 2 * h * nl + 2 * nl
+                   + self.linear_conv_channels * self.linear_conv_kernel + dv)
+            return (L * (mlp + 2 * h) + (L - n_lin) * attn + n_lin * lin) + emb + pos + h
         return L * (attn + mlp + 2 * h) + emb + pos + h
 
 
@@ -545,10 +607,12 @@ def kv_layer_leaves(cfg, layer_cache):
 
 def kv_pool_geometry(cfg, kv_cache):
     """``"latent"``, ``"packed"`` or ``"split"``: which of ``init_cache``'s
-    three geometries a cache tree has."""
+    three geometries a cache tree's ROWS have (read from the first layer that
+    holds rows: a linear-attention layer holds state instead)."""
     if cfg.latent_width:
         return "latent"
-    leaf = jax.tree_util.tree_leaves(kv_cache[0])[0]
+    first = cfg.layer_types.index("full_attention") if cfg.layer_types else 0
+    leaf = jax.tree_util.tree_leaves(kv_cache[0])[first]
     return "packed" if leaf.shape[-1] == 2 * cfg.head_size else "split"
 
 
@@ -857,6 +921,23 @@ class OutProjection(nn.Module):
         return y
 
 
+class ProjectionRMSNorm(nn.Module):
+    """RMSNorm over a WHOLE head-major projection ``(B, heads, T, hd)``: the
+    mean square over all of a position's ``heads * hd`` values, one learned
+    scale each (``qk_norm``: the norm comes before the split into heads)."""
+    epsilon: float
+    dtype: Any
+
+    @nn.compact
+    def __call__(self, x):
+        n, d = x.shape[1], x.shape[3]
+        scale = self.param("scale", nn.initializers.ones, (n * d, ), jnp.float32)
+        x32 = x.astype(jnp.float32)
+        ms = jnp.mean(x32 * x32, axis=(1, 3), keepdims=True)
+        y = x32 * jax.lax.rsqrt(ms + self.epsilon) * scale.reshape(n, 1, d)
+        return y.astype(self.dtype)
+
+
 class Attention(nn.Module):
     cfg: TransformerConfig
     layer_idx: int = -1  # set on unrolled layers; drives local-window lookup
@@ -950,6 +1031,10 @@ class Attention(nn.Module):
                 k = k + dk.astype(k.dtype)
             if dv is not None:
                 v = v + dv.astype(v.dtype)
+
+        if cfg.qk_norm:
+            q = ProjectionRMSNorm(cfg.layernorm_epsilon, cfg.dtype, name="q_norm")(q)
+            k = ProjectionRMSNorm(cfg.layernorm_epsilon, cfg.dtype, name="k_norm")(k)
 
         if cfg.pos_embedding == "rope":
             if position_ids is not None:
@@ -1349,6 +1434,201 @@ class LatentAttention(nn.Module):
         return out, new_cache
 
 
+GDN_CHUNK = 64  # positions the gated-delta scan solves together
+GDN_L2_EPS = 1e-6  # under the root of q's and k's L2 norm: a zero vector stays zero
+
+
+def gdn_conv_init(key, shape, dtype=jnp.float32):
+    """U(-W^-1/2, W^-1/2) over ``(channels, W)``: a depthwise convolution's
+    usual start."""
+    bound = shape[-1] ** -0.5
+    return jax.random.uniform(key, shape, dtype, -bound, bound)
+
+
+def gdn_a_log_init(key, shape, dtype=jnp.float32):
+    """``A_log`` of a gated-delta layer as the layer is published to start:
+    the log of A ~ U(0, 16] a head, so heads forget at different rates."""
+    return jnp.log(16.0 * (1.0 - jax.random.uniform(key, shape, jnp.float32))).astype(dtype)
+
+
+def gdn_dt_bias_init(key, shape, dtype=jnp.float32):
+    """``dt_bias`` of a gated-delta layer as published: the inverse softplus
+    of dt, log-uniform in [0.001, 0.1]."""
+    lo, hi = jnp.log(0.001), jnp.log(0.1)
+    dt = jnp.maximum(jnp.exp(jax.random.uniform(key, shape, jnp.float32) * (hi - lo) + lo), 1e-4)
+    return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
+
+
+def gated_delta_step(S, q, k, v, g, beta):
+    """The gated delta rule for ONE token, float32: ``S`` (B, n, dk, dv),
+    ``q``/``k`` (B, n, dk), ``v`` (B, n, dv), ``g`` (log decay) and ``beta``
+    (B, n). ``S' = a S + beta k (v - a S^T k)^T`` with ``a = exp(g)``;
+    returns ``(S'^T q, S')``. Elementwise products and sums over ``dk``: a
+    slot's state is read and written once, nothing runs on tiny matrices."""
+    S = S * jnp.exp(g)[..., None, None]
+    u = beta[..., None] * (v - jnp.sum(S * k[..., None], axis=-2))
+    S = S + k[..., None] * u[..., None, :]
+    return jnp.sum(S * q[..., None], axis=-2), S
+
+
+def gated_delta_chunked(S, q, k, v, g, beta, chunk=GDN_CHUNK):
+    """The same recurrence over ``T`` tokens, chunk by chunk, float32:
+    ``q``/``k`` (B, n, T, dk), ``v`` (B, n, T, dv), ``g``/``beta`` (B, n,
+    T), ``S`` the incoming state. With ``G_t`` the running sum of ``g``
+    inside a chunk and ``u_t = beta_t (v_t - a_t S_{t-1}^T k_t)``:
+
+        (I + A) U = diag(beta) (V - diag(e^G) K S_0),
+        A[t, s] = beta_t e^(G_t - G_s) k_t . k_s   (s < t: unit lower triangular)
+        O = diag(e^G) Q S_0 + tril(Q K^T * e^(G_t - G_s)) U
+        S_C = e^(G_C) S_0 + (K * e^(G_C - G_s))^T U
+
+    A token with ``beta`` 0 and ``g`` 0 (padding up to a whole chunk, a
+    column past a row's span) leaves the state as it is. Returns ``(O (B, n,
+    T, dv), S_T)``. The small products run at ``highest`` precision: their
+    operands are float32 that bfloat16 passes would round."""
+    B, n, T, dk = q.shape
+    pad = -T % chunk
+    if pad:
+        q, k, v = (jnp.pad(x, ((0, 0), (0, 0), (0, pad), (0, 0))) for x in (q, k, v))
+        g, beta = (jnp.pad(x, ((0, 0), (0, 0), (0, pad))) for x in (g, beta))
+    nc = (T + pad) // chunk
+    # (nc, B, n, chunk, ...): the scan walks the chunks
+    split = lambda x: jnp.moveaxis(x.reshape(x.shape[:2] + (nc, chunk) + x.shape[3:]), 2, 0)
+    mm = partial(jnp.einsum, precision=jax.lax.Precision.HIGHEST)
+    lower = jnp.tril(jnp.ones((chunk, chunk), bool))
+    strict = jnp.tril(jnp.ones((chunk, chunk), bool), -1)
+    eye = jnp.eye(chunk, dtype=jnp.float32)
+
+    def body(S, xs):
+        qc, kc, vc, gc, bc = xs
+        G = jnp.cumsum(gc, axis=-1)
+        decay = jnp.exp(jnp.where(lower, G[..., :, None] - G[..., None, :], -jnp.inf))
+        gam = jnp.exp(G)[..., None]
+        A = jnp.where(strict, bc[..., None] * decay * mm("bntd,bnsd->bnts", kc, kc), 0.0)
+        rhs = bc[..., None] * (vc - gam * mm("bntd,bndv->bntv", kc, S))
+        U = jax.scipy.linalg.solve_triangular(eye + A, rhs, lower=True, unit_diagonal=True)
+        o = (gam * mm("bntd,bndv->bntv", qc, S)
+             + mm("bnts,bnsv->bntv", decay * mm("bntd,bnsd->bnts", qc, kc), U))
+        S = (gam[..., -1:, :] * S
+             + mm("bnsd,bnsv->bndv", kc * jnp.exp(G[..., -1:] - G)[..., None], U))
+        return S, o
+
+    S, o = jax.lax.scan(body, S, tuple(split(x) for x in (q, k, v, g, beta)))
+    o = jnp.moveaxis(o, 0, 2).reshape(B, n, T + pad, -1)
+    return o[:, :, :T], S
+
+
+class GatedDeltaNet(nn.Module):
+    """Linear attention by the gated delta rule (Gated DeltaNet,
+    arXiv:2412.06464, as ``olmo_hybrid``'s ``linear_*`` keys configure it):
+    the mixer of a ``linear_attention`` layer. Per head of ``dk`` key and
+    ``dv`` value dimensions it carries a state ``S`` (dk, dv) and no rows:
+
+        [q~ ; k~ ; v~] = x [W_q ; W_k ; W_v] ; u_t = SiLU(sum_j w[:, j] u~_(t-W+1+j))
+        q = q / |q| dk^-1/2 ; k = k / |k|
+        beta = sigmoid(x W_b) (x 2 with linear_neg_eigval) ; g = -exp(A_log) softplus(x W_a + dt_bias)
+        S_t = e^g S_(t-1) + beta k (v - e^g S_(t-1)^T k)^T ; o_t = S_t^T q_t
+        y = [RMSNorm_dv(o) * SiLU(x W_g)] W_o
+
+    What a slot holds for such a layer (``init_cache``): the state ``(B, n,
+    dk, dv)`` and the convolution's last ``W - 1`` inputs ``(B, 1, W - 1,
+    n (2 dk + dv))``, both at rest in the serving dtype, loaded to float32
+    and rounded once on the store. Without a cache (full forward) the scan
+    starts from zero. With one it is served through the slot pool's span
+    programs only (``write_index`` + ``q_spans``): a row advances over
+    exactly its ``q_spans`` live columns (later columns get beta 0 and g 0,
+    the window is taken from the last live inputs), a span-0 row's leaves
+    come out bit for bit as they went in, and a row whose span starts at
+    position 0 starts from a zero state and window whatever the slot held.
+    The call signature is :class:`Attention`'s; adapters, extent chains,
+    sequence-parallel spans and padding masks are refused."""
+    cfg: TransformerConfig
+    layer_idx: int = -1
+
+    @nn.compact
+    def __call__(self, x, sin, cos, attn_mask=None, kv_cache=None, cache_index=None,
+                 position_ids=None, write_index=None, q_spans=None, lora_ops=None,
+                 ext_ops=None, seq_shard=False):
+        cfg = self.cfg
+        B, T, H = x.shape
+        n, dk, dv = cfg.linear_num_heads, cfg.linear_key_head_dim, cfg.linear_value_head_dim
+        W = cfg.linear_conv_kernel
+        if lora_ops or ext_ops is not None or seq_shard or attn_mask is not None:
+            raise NotImplementedError("a linear-attention layer serves without adapters, "
+                                      "extent chains, sequence-parallel spans or padding masks")
+        if kv_cache is not None and (write_index is None or q_spans is None):
+            raise NotImplementedError(
+                "a linear-attention layer's state is served through the slot pool's span "
+                "programs (the continuous-batching scheduler): the static-batch cache "
+                "paths have no per-row spans to advance it by")
+        f32 = jnp.float32
+        dense = partial(nn.Dense, use_bias=False, dtype=cfg.dtype, param_dtype=f32,
+                        kernel_init=nn.initializers.normal(0.02))
+        with jax.named_scope("gdn_proj"):
+            mixed = jnp.concatenate([dense(n * dk, name="q_proj")(x), dense(n * dk, name="k_proj")(x),
+                                     dense(n * dv, name="v_proj")(x)], axis=-1)
+            gate = dense(n * dv, name="g_proj")(x)
+            beta = jax.nn.sigmoid(dense(n, name="b_proj")(x).astype(f32))
+            if cfg.linear_neg_eigval:
+                beta = 2.0 * beta
+            a_log = self.param("A_log", gdn_a_log_init, (n, ), f32)
+            dt_bias = self.param("dt_bias", gdn_dt_bias_init, (n, ), f32)
+            g = -jnp.exp(a_log) * jax.nn.softplus(dense(n, name="a_proj")(x).astype(f32) + dt_bias)
+            conv_w = self.param("conv", gdn_conv_init, (cfg.linear_conv_channels, W), f32)
+            if kv_cache is None:
+                state = jnp.zeros((B, n, dk, dv), f32)
+                window = jnp.zeros((B, W - 1, mixed.shape[-1]), cfg.dtype)
+            else:
+                state_rest, window_rest = kv_cache
+                live_row = q_spans > 0
+                fresh = live_row & (write_index == 0)
+                state = jnp.where(fresh[:, None, None, None], 0.0, state_rest.astype(f32))
+                window = jnp.where(fresh[:, None, None], 0, window_rest[:, 0]).astype(cfg.dtype)
+                live = (jnp.arange(T)[None, :] < q_spans[:, None])[..., None]
+                beta, g = jnp.where(live, beta, 0.0), jnp.where(live, g, 0.0)
+            # causal depthwise convolution over [window ; this call's inputs]
+            seq = jnp.concatenate([window, mixed.astype(cfg.dtype)], axis=1)
+            conv = sum(seq[:, j:j + T].astype(f32) * conv_w[:, j] for j in range(W))
+            u = jax.nn.silu(conv).astype(cfg.dtype)
+            heads = lambda y, d: y.reshape(B, T, n, d).transpose(0, 2, 1, 3).astype(f32)
+            q, k = heads(u[..., :n * dk], dk), heads(u[..., n * dk:2 * n * dk], dk)
+            v = heads(u[..., 2 * n * dk:], dv)
+            l2 = lambda y: y * jax.lax.rsqrt(jnp.sum(y * y, axis=-1, keepdims=True) + GDN_L2_EPS)
+            q, k = l2(q) * dk ** -0.5, l2(k)
+            g, beta = g.transpose(0, 2, 1), beta.transpose(0, 2, 1)  # (B, n, T)
+        with jax.named_scope("gdn_state"):
+            if T == 1:
+                o, state = gated_delta_step(state, q[:, :, 0], k[:, :, 0], v[:, :, 0],
+                                            g[:, :, 0], beta[:, :, 0])
+                o = o[:, :, None]
+            else:
+                o, state = gated_delta_chunked(state, q, k, v, g, beta)
+            if kv_cache is None:
+                new_cache = None
+            else:
+                # the last W - 1 LIVE inputs: rows [span, span + W - 1) of seq.
+                # One column: the old window or the one a step on. Wider: a
+                # one-hot pick (a per-row gather would rest with the W - 1
+                # rows in the lanes, padded forty-fold)
+                if T == 1:
+                    tail = jnp.where(live_row[:, None, None], seq[:, 1:], seq[:, :-1])
+                else:
+                    rows = q_spans[:, None] + jnp.arange(W - 1)[None, :]
+                    pick = (rows[:, :, None] == jnp.arange(T + W - 1)[None, None, :])
+                    tail = jnp.einsum("bjt,btc->bjc", pick.astype(seq.dtype), seq,
+                                      precision=jax.lax.Precision.HIGHEST)
+                new_cache = (
+                    jnp.where(live_row[:, None, None, None], state.astype(state_rest.dtype),
+                              state_rest),
+                    jnp.where(live_row[:, None, None, None],
+                              tail[:, None].astype(window_rest.dtype), window_rest))
+        with jax.named_scope("gdn_out"):
+            o = RMSNorm(epsilon=cfg.layernorm_epsilon, dtype=cfg.dtype, name="o_norm")(o)
+            o = o * jax.nn.silu(gate.reshape(B, T, n, dv).transpose(0, 2, 1, 3))
+            out = OutProjection(H, False, cfg.dtype, name="o_proj")(o)
+        return out, new_cache
+
+
 class QuantDense(nn.Module):
     """nn.Dense over (int8 weight, fp32 group scales) via the Pallas quant
     matmul (serving path; params come from ``quantize_params``)."""
@@ -1425,9 +1705,20 @@ class Block(nn.Module):
             from ..compression.helper import fake_quantize
             x = fake_quantize(x, bits=cfg.act_quant_bits, groups=1,
                               symmetric=cfg.act_quant_symmetric)
+        if cfg.layer_type(self.layer_idx) == "linear_attention":
+            mixer = GatedDeltaNet(cfg, layer_idx=self.layer_idx, name="gdn")
+        else:
+            attention = LatentAttention if cfg.kv_lora_rank else Attention
+            mixer = attention(cfg, layer_idx=self.layer_idx, name="attn")
+        if cfg.post_norm:
+            # the mixer and the MLP read the residual stream itself; what
+            # they return is normalised on its way into it
+            h, new_cache = mixer(x, sin, cos, attn_mask, kv_cache, cache_index, position_ids,
+                                 write_index, q_spans, lora_ops, ext_ops, seq_shard)
+            x = x + make_norm(cfg, name="attn_norm")(h)
+            return x + make_norm(cfg, name="mlp_norm")(MLP(cfg, name="mlp")(x, lora_ops)), new_cache
         h = make_norm(cfg, name="attn_norm")(x)
-        attention = LatentAttention if cfg.kv_lora_rank else Attention
-        h, new_cache = attention(cfg, layer_idx=self.layer_idx, name="attn")(
+        h, new_cache = mixer(
             h, sin, cos, attn_mask, kv_cache, cache_index, position_ids, write_index,
             q_spans, lora_ops, ext_ops, seq_shard)
         if drop is not None:
@@ -1796,7 +2087,30 @@ class CausalLMModel:
         ``(kv, scale)`` packed). Scales init to 1 (rows past each slot's end
         are never attended), and every leaf keeps its batch/slot axis at
         ``ndim - 4`` and its row axis at ``ndim - 2``, so the slot pool's
-        slice/update/copy programs treat every geometry uniformly."""
+        slice/update/copy programs treat every geometry uniformly.
+
+        A ``linear_attention`` layer (``layer_types``) holds no rows: in the
+        same two places of the tree it carries its recurrent state and its
+        convolution window, per slot (:meth:`cache_spec`)."""
+        spec = self.cache_spec(batch_size, max_len, dtype, quantized)
+        if self.cfg.scan_layers:
+            return tuple(fill((self.cfg.num_layers, ) + shape, t)
+                         for _, shape, t, fill in spec[0])
+        return tuple(tuple(fill(shape, t) for _, shape, t, fill in comp)
+                     for comp in zip(*spec))
+
+    def cache_spec(self, batch_size, max_len, dtype=None, quantized=False):
+        """What a slot holds, as each layer declares it: for every layer a
+        tuple of ``(kind, shape, dtype, fill)`` components, ``kind`` being
+        ``"rows"`` (a row axis at ``ndim - 2``, one row a position: K, V,
+        the packed pair, the latent row, the int8 tier's scales) or
+        ``"state"`` (per-slot, no row axis: a linear-attention layer's
+        recurrent state ``(B, n, dk, dv)`` and the ``W - 1`` last inputs of
+        its convolution ``(B, 1, W - 1, channels)``, at rest in the cache
+        dtype). Every component keeps its slot axis at ``ndim - 4``.
+        :meth:`init_cache` builds the tree from it; :meth:`cache_kinds`
+        tells the slot pool which leaves are which. Every layer declares the
+        same NUMBER of components (the tree is component-major)."""
         cfg = self.cfg
         dt = dtype or cfg.dtype
         if cfg.latent_width:
@@ -1807,18 +2121,33 @@ class CausalLMModel:
             # slot_update / copy_slot and the radix copy take it as it is.
             if quantized:
                 raise NotImplementedError("the latent KV pool has no int8 tier")
-            comps = [((batch_size, 1, max_len, cfg.latent_width), dt, jnp.zeros)]
+            rows = [("rows", (batch_size, 1, max_len, cfg.latent_width), dt, jnp.zeros)]
         else:
             packed = kv_packs(cfg.head_size)
             shape = (batch_size, cfg.kv_heads, max_len,
                      (2 if packed else 1) * cfg.head_size)
-            comps = [(shape, jnp.int8 if quantized else dt, jnp.zeros)] * (1 if packed else 2)
+            rows = [("rows", shape, jnp.int8 if quantized else dt, jnp.zeros)] * (
+                1 if packed else 2)
             if quantized:
-                comps.append(((batch_size, 1, max_len, 1), jnp.float16, jnp.ones))
-        if cfg.scan_layers:
-            return tuple(fill((cfg.num_layers, ) + shape, t) for shape, t, fill in comps)
-        return tuple(tuple(fill(shape, t) for _ in range(cfg.num_layers))
-                     for shape, t, fill in comps)
+                rows.append(("rows", (batch_size, 1, max_len, 1), jnp.float16, jnp.ones))
+        if "linear_attention" not in cfg.layer_types:
+            return [tuple(rows)] * cfg.num_layers
+        if quantized or len(rows) != 2:
+            raise NotImplementedError("a pool with state leaves has no int8 tier and no "
+                                      "packed geometry")
+        state = (("state", (batch_size, cfg.linear_num_heads, cfg.linear_key_head_dim,
+                            cfg.linear_value_head_dim), dt, jnp.zeros),
+                 ("state", (batch_size, 1, cfg.linear_conv_kernel - 1,
+                            cfg.linear_conv_channels), dt, jnp.zeros))
+        return [state if t == "linear_attention" else tuple(rows) for t in cfg.layer_types]
+
+    def cache_kinds(self):
+        """``"rows"`` or ``"state"`` for every leaf of :meth:`init_cache`'s
+        tree, in a tree of its structure (sizes do not enter)."""
+        spec = self.cache_spec(1, 1)
+        if self.cfg.scan_layers:
+            return tuple(kind for kind, *_ in spec[0])
+        return tuple(tuple(kind for kind, *_ in comp) for comp in zip(*spec))
 
     def apply_with_cache(self, params, input_ids, kv_cache, cache_index, cache_mask=None,
                          position_ids=None, write_index=None, q_spans=None,

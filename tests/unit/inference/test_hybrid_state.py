@@ -1,0 +1,302 @@
+"""A model whose slots hold recurrent state beside K/V rows (``layer_types``
+with ``linear_attention``: the gated delta rule), at a small size on the CPU
+in float32 unless said: the program against the plain reference
+(``chipbench/references/olmo_hybrid.py``, token by token, no cache), the
+scan against the recurrence, the slot pool's span programs, the refusals,
+and the sizes of the published preset.
+
+Weights: the benchmark's own draw (``serve_hybrid.hybrid_params``: unit
+embedding, output norms at 0.3, the gates' published start), a forward that
+does not amplify a rounding. ``TOL``: the reference's float32 limit, 1e-5;
+the served path reads 1e-6 at worst; a wrong state, window or span gives
+0.01 and up. (With flax's own start, norm scales 1 over a 0.02 embedding, the
+same program reads 1.1e-5 and the reference itself 9e-6 against a float64
+forward: such a network amplifies float32's noise.)"""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import deepspeed_tpu
+from chipbench.references import olmo_hybrid as ref
+from deepspeed_tpu.models import get_model
+from deepspeed_tpu.models import transformer as tfm
+
+TOL = ref.TOL["float32"]
+HP = {"eps": 1e-6, "neg_eigval": True}
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    from chipbench.jobs.serve_hybrid import hybrid_params
+    model = get_model("tiny-hybrid", dtype=jnp.float32)
+    return model, hybrid_params(model, 7, jnp.dtype("float32"))
+
+
+def _engine(tiny, slots=4, chunk=16, steps=4, dtype="float32", **cb):
+    model, params = tiny
+    return deepspeed_tpu.init_inference(model, config={
+        "dtype": dtype, "kernel_inject": True, "max_out_tokens": 256,
+        "continuous_batching": dict({"enabled": True, "num_slots": slots,
+                                     "steps_per_sync": steps, "prefill_chunk": chunk}, **cb)},
+        params=params)
+
+
+def _prompts(lengths, seed=0):
+    rng = np.random.RandomState(seed)
+    return [[int(t) for t in rng.randint(0, 256, n)] for n in lengths]
+
+
+def _reference_logits(eng, prompt, tokens):
+    """The reference's logits of the positions that chose ``tokens``."""
+    cfg = eng.model_config
+    ids = jnp.asarray([prompt + [int(t) for t in tokens[:-1]]], jnp.int32)
+    return ref.forward(ref.from_tree(eng.params, cfg.layer_types), ids, HP,
+                       first=len(prompt) - 1)[0]
+
+
+def test_full_forward_matches_the_reference(tiny):
+    model, params = tiny
+    ids = jax.random.randint(jax.random.key(1), (2, 150), 0, 256)
+    with jax.default_matmul_precision("highest"):
+        got = model.apply(params, ids)
+    want = ref.forward(ref.from_tree(params, model.cfg.layer_types), ids, HP)
+    assert ref.compare(got.reshape(-1, 256), want.reshape(-1, 256), tol=TOL)["ok"]
+
+
+@pytest.mark.parametrize("length", [1, 63, 64, 150, 200])
+def test_chunked_scan_matches_the_recurrence(length):
+    """Lengths that are no multiple of 64, from a carried state; a token with
+    beta 0 and g 0 in the middle leaves the state as it is."""
+    B, n, dk, dv = 2, 3, 8, 16
+    ks = jax.random.split(jax.random.key(length), 6)
+    q = jax.random.normal(ks[0], (B, n, length, dk))
+    k = jax.random.normal(ks[1], (B, n, length, dk))
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    v = jax.random.normal(ks[2], (B, n, length, dv))
+    g = -1.6 * jax.random.uniform(ks[3], (B, n, length))
+    beta = 2.0 * jax.random.uniform(ks[4], (B, n, length))
+    g, beta = g.at[:, :, length // 2].set(0.0), beta.at[:, :, length // 2].set(0.0)
+    S0 = jax.random.normal(ks[5], (B, n, dk, dv))
+    o_scan, S_scan = tfm.gated_delta_chunked(S0, q, k, v, g, beta)
+    S, outs = S0, []
+    for t in range(length):
+        before = S
+        o, S = tfm.gated_delta_step(S, q[:, :, t], k[:, :, t], v[:, :, t], g[:, :, t],
+                                    beta[:, :, t])
+        if t == length // 2:
+            np.testing.assert_array_equal(np.asarray(S), np.asarray(before))
+        outs.append(o)
+    rel = lambda a, b: float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
+    assert rel(o_scan, jnp.stack(outs, axis=2)) < 5e-6 and rel(S_scan, S) < 5e-6
+
+
+@pytest.mark.parametrize("slots, chunk, steps, split", [
+    (4, 16, 1, False), (4, 16, 4, False), (4, 64, 4, False), (8, 64, 1, True), (8, 64, 4, True),
+    (8, 128, 4, True)])
+def test_served_path_matches_the_reference(tiny, slots, chunk, steps, split):
+    """Prefill in chunks (a partial last one: 150 = 2 x 64 + 22 = 128 + 22 =
+    9 x 16 + 6), then decode through the pool, neighbours live in other
+    slots, in the whole-block program and in the live-rows split."""
+    eng = _engine(tiny, slots, chunk, steps)
+    sched = eng.scheduler()
+    assert sched._splits_chunk(("fused", False, True, chunk, steps)) is split
+    prompts = _prompts((37, 150, 70))
+    handles = [sched.submit(p, max_new_tokens=12, collect_logits=True) for p in prompts]
+    sched.drain()
+    for p, h in zip(prompts, handles):
+        res = ref.compare(h.result_logits(), _reference_logits(eng, p, h.result()), tol=TOL)
+        assert res["ok"], res["error"]
+    assert sched.state_slots_reset == 3 and sched.radix is None
+
+
+def test_a_span_0_slot_is_bit_for_bit_unchanged(tiny):
+    """A sync that advances other slots leaves an idle slot's state, window
+    and rows exactly as they were: slot 1's, once its request has ended,
+    through a neighbour's chunked prefill (eight chunks) and both
+    neighbours' decode."""
+    sched = _engine(tiny, slots=4, chunk=16, steps=4).scheduler()
+    a, b, c = _prompts((20, 50, 120))
+    long_one = sched.submit(a, max_new_tokens=60)
+    short = sched.submit(b, max_new_tokens=6)  # still live when the third is admitted
+    late = sched.submit(c, max_new_tokens=8)
+    while not short.done:
+        sched.step()
+    assert sched.cache.state[1] == "free" and late._req.slot == 2 and not late.done
+    slot1 = lambda: [np.asarray(leaf[1]) for leaf in jax.tree_util.tree_leaves(sched.cache.pool)]
+    before = slot1()
+    assert all(np.any(x != 0) for x in before)
+    steps = 0
+    while not (long_one.done and late.done):
+        sched.step()
+        steps += 1
+    assert steps >= 8 and sched.cache.state[1] == "free"
+    for x, y in zip(before, slot1()):
+        np.testing.assert_array_equal(x, y)
+
+
+def test_a_reused_slot_gives_a_fresh_pools_logits(tiny):
+    """A new request in a slot that held another starts from a zero state
+    and window: its logits are a fresh pool's, bit for bit."""
+    prompt = _prompts((40, ), seed=5)[0]
+    fresh = _engine(tiny, slots=2, chunk=16).scheduler()
+    want = fresh.submit(prompt, max_new_tokens=8, collect_logits=True)
+    fresh.drain()
+    used = _engine(tiny, slots=2, chunk=16).scheduler()
+    for p in _prompts((33, 61), seed=6):
+        used.submit(p, max_new_tokens=10)
+    used.drain()
+    got = used.submit(prompt, max_new_tokens=8, collect_logits=True)
+    used.drain()
+    assert used.state_slots_reset == 3
+    np.testing.assert_array_equal(got.result_logits(), want.result_logits())
+
+
+def test_one_prompt_twice_is_served_cold_twice(tiny):
+    """The radix cache is off for a pool with state: the second request finds
+    no prefix, gives the same logits, and the lookups not made are counted."""
+    sched = _engine(tiny, slots=4, chunk=16).scheduler()
+    prompt = _prompts((50, ), seed=7)[0]
+    one = sched.submit(prompt, max_new_tokens=8, collect_logits=True)
+    sched.drain()
+    two = sched.submit(prompt, max_new_tokens=8, collect_logits=True)
+    sched.drain()
+    np.testing.assert_array_equal(one.result_logits(), two.result_logits())
+    assert sched.radix is None and sched.prefix_cache_state_bypass == 2
+    assert _engine(tiny, prefix_cache=False).scheduler().prefix_cache_state_bypass == 0
+
+
+@pytest.mark.parametrize("overrides, message", [
+    ({"spec_tokens": 2}, "speculative verify"),
+    ({"max_extents": 2}, "extent chains"),
+    ({"seq_parallel_min_tokens": 64}, "sequence-parallel prefill"),
+    ({"prefix_store": object()}, "tier demotion"),
+    ({"allow_lossy_kv": True}, "lossy KV windows"),
+    ({"kv_cache_dtype": "int8"}, "an int8 KV pool"),
+    ({"adapter_store": object()}, "adapters"),
+])
+def test_what_a_state_pool_refuses(tiny, overrides, message):
+    eng = _engine(tiny)
+    with pytest.raises(ValueError, match="holds recurrent state.*" + message):
+        eng.scheduler(**overrides)
+
+
+def test_the_other_refusals(tiny):
+    """Migration between replicas, the static-batch cache, int8 weights, a
+    tensor-parallel pool, scanned layers; the fused decode gate declines
+    with ``layer_types`` as its reason."""
+    model, params = tiny
+    eng = _engine(tiny)
+    sched = eng.scheduler()
+    with pytest.raises(ValueError, match="cannot migrate between replicas"):
+        sched.migrate_out(None, "key", None)
+    with pytest.raises(ValueError, match="cannot migrate between replicas"):
+        sched.admit_migration(None)
+    with pytest.raises(ValueError, match="continuous-batching scheduler"):
+        eng.generate([[1, 2, 3]], max_new_tokens=2)
+    assert any("layer_types" in r for r in eng._fused_decode_eligible().reasons)
+    assert any("layer_types" in r for r in sched._fused_block_reasons)
+    with pytest.raises(ValueError, match="served in its float dtype"):
+        deepspeed_tpu.init_inference(model, config={"dtype": "int8"}, params=params)
+    with pytest.raises(ValueError, match="requires scan_layers=False"):
+        dataclasses.replace(model.cfg, scan_layers=True)
+    with pytest.raises(NotImplementedError, match="span programs"):
+        model.apply_with_cache(params, jnp.zeros((2, 4), jnp.int32), model.init_cache(2, 64), 0)
+    from deepspeed_tpu.comm import comm
+    comm._state["mesh"] = None
+    comm.initialize_mesh(tensor=2)
+    tp = deepspeed_tpu.init_inference(model, config={
+        "dtype": "float32", "continuous_batching": {"enabled": True, "num_slots": 2}},
+        params=params)
+    with pytest.raises(ValueError, match="a tensor-parallel pool"):
+        tp.scheduler()
+
+
+def test_bf16_state_at_rest_over_512_decode_steps(tiny):
+    """A float32 program over a pool at rest in bf16 (``kv_cache_dtype``:
+    state, window and rows), so that the rounding at rest is all that
+    differs from the reference: the state is rounded once a token, 512
+    times. The band: a position reads 0.003 in the median and under 0.01
+    (bf16 keeps 8 bits; the decay forgets old roundings, a head's slowest
+    here keeps a few hundred positions), and it does not widen: the last 64
+    positions' median stays under 1.5 times the first 64's, every position
+    under 0.02. A float32 pool reads 1e-6 on the same request."""
+    model = get_model("tiny-hybrid", dtype=jnp.float32, max_seq_len=768)
+    prompt = _prompts((24, ), seed=9)[0]
+    medians = {}
+    for at_rest in ("bfloat16", "auto"):
+        eng = deepspeed_tpu.init_inference(model, config={
+            "dtype": "float32", "kernel_inject": True, "max_out_tokens": 768,
+            "continuous_batching": {"enabled": True, "num_slots": 2, "steps_per_sync": 4,
+                                    "prefill_chunk": 16, "kv_cache_dtype": at_rest}},
+            params=tiny[1])
+        sched = eng.scheduler()
+        want_dtype = jnp.dtype(jnp.bfloat16 if at_rest == "bfloat16" else jnp.float32)
+        assert {leaf.dtype for leaf in jax.tree_util.tree_leaves(sched.cache.pool)} == {want_dtype}
+        h = sched.submit(prompt, max_new_tokens=513, collect_logits=True)
+        sched.drain()
+        err = np.asarray(ref.position_errors(h.result_logits(),
+                                             _reference_logits(eng, prompt, h.result())))
+        assert err.shape == (513, )
+        medians[at_rest] = (np.median(err[:64]), np.median(err[-64:]), err.max())
+    first, last, worst = medians["bfloat16"]
+    assert 5e-4 < first and last < 1.5 * first and worst < 0.02, medians
+    assert medians["auto"][2] < TOL, medians
+
+
+def test_preset_builds_the_published_sizes():
+    """7.43 B parameters whole; the cut (16 layers, four whole periods, the
+    whole vocabulary) 4,100,788,944; a position costs 61,440 B of rows in
+    its 4 full-attention layers, a slot 14,100,480 B of state in its 12
+    linear-attention layers."""
+    from deepspeed_tpu.inference.kv_cache import SlotKVCache
+    whole = get_model("olmo-hybrid-7b")
+    cfg = whole.cfg
+    assert cfg.num_layers == 32 and cfg.layer_types == (
+        ("linear_attention", ) * 3 + ("full_attention", )) * 8
+    assert (cfg.hidden_size, cfg.ffn_size, cfg.vocab_size, cfg.num_heads, cfg.kv_heads,
+            cfg.head_size) == (3840, 11008, 100352, 30, 30, 128)
+    assert (cfg.linear_num_heads, cfg.linear_key_head_dim, cfg.linear_value_head_dim,
+            cfg.linear_conv_kernel, cfg.linear_neg_eigval) == (30, 96, 192, 4, True)
+    assert cfg.num_params() == 7_430_870_688 and round(cfg.num_params() / 1e9, 2) == 7.43
+    abstract = jax.eval_shape(whole.init_params, jax.random.key(0))
+    assert sum(x.size for x in jax.tree_util.tree_leaves(abstract)) == cfg.num_params()
+    from chipbench import cells
+    cut = cells.build_model(cells.load_config("olmo-hybrid-7b"), dtype=jnp.bfloat16)
+    assert cut.cfg.num_params() == 4_100_788_944 and cut.cfg.num_layers == 16
+    pool = jax.eval_shape(lambda: cut.init_cache(64, 1024))
+    kv = SlotKVCache(pool, 64, 1024, kinds=cut.cache_kinds())
+    assert kv.bytes_per_token() == 61_440 and kv.state_bytes_per_slot() == 14_100_480
+    assert kv.capacity_bytes() == 64 * (1024 * 61_440 + 14_100_480)
+    shapes = [leaf.shape for leaf in jax.tree_util.tree_leaves(pool)]
+    assert shapes.count((64, 30, 96, 192)) == 12 and shapes.count((64, 1, 3, 11520)) == 12
+    assert shapes.count((64, 30, 1024, 128)) == 8
+
+
+@pytest.mark.parametrize("name, unrolled, leaves, geometry", [
+    ("gpt2-large", False, [(36, 2, 20, 64, 128)], "packed"),
+    ("gpt2-large", True, [(2, 20, 64, 128)] * 36, "packed"),
+    ("llama2-7b", False, [(32, 2, 32, 64, 128)] * 2, "split"),
+    ("llama2-7b", True, [(2, 32, 64, 128)] * 64, "split"),
+    ("mistral-small-4-119b", False, [(36, 2, 1, 64, 320)], "latent"),
+    ("mistral-small-4-119b", True, [(2, 1, 64, 320)] * 36, "latent"),
+])
+def test_models_without_layer_types_build_the_cache_they_built(name, unrolled, leaves, geometry):
+    """``init_cache``'s leaves and ``kv_pool_geometry`` as the parent commit
+    (f7774de) built them, every leaf declared as rows; the int8 tier keeps
+    its scale leaf last."""
+    model = get_model(name, scan_layers=not unrolled)
+    pool = jax.eval_shape(lambda: model.init_cache(2, 64))
+    assert [leaf.shape for leaf in jax.tree_util.tree_leaves(pool)] == leaves
+    assert tfm.kv_pool_geometry(model.cfg, pool) == geometry
+    assert set(jax.tree_util.tree_leaves(model.cache_kinds())) == {"rows"}
+    assert (jax.tree_util.tree_structure(model.cache_kinds())
+            == jax.tree_util.tree_structure(pool))
+    if geometry != "latent":
+        q = jax.tree_util.tree_leaves(jax.eval_shape(lambda: model.init_cache(2, 64,
+                                                                              quantized=True)))
+        assert q[0].dtype == jnp.int8 and q[-1].dtype == jnp.float16
+        assert q[-1].shape[-3:] == (1, 64, 1)

@@ -413,3 +413,38 @@ def test_generate_step_program(for_chip):
         eps=cfg.layernorm_epsilon, block_kv=cfg.decode_block_kv, norm=cfg.norm),
         sds((SLOTS, cfg.hidden_size), jnp.bfloat16), norms, leaves, qkv, o, up,
         down, sds((SLOTS, ), jnp.int32), sds((), jnp.int32))
+
+
+@pytest.mark.parametrize("step", ["decode", "span"])
+def test_hybrid_state_step_program(for_chip, step):
+    """Olmo-Hybrid-7B's sync at the published widths as the chip benchmark
+    serves it (64 slots x 1024, ``prefill_chunk`` 128, ``steps_per_sync`` 4,
+    the whole vocabulary), one period deep (three gated-delta-rule layers and
+    a full-attention one): the one-token update of 64 states and, in the
+    chunk sync, the 128-token scan over the chunk's own slot. The donated
+    pool is updated in place: no whole-leaf copy, scatter or transpose of a
+    state leaf, a window leaf or a K/V leaf stands in the loop or around it
+    (a per-row gather of the window's three rows once left them in the
+    lanes, padded forty-fold: 180 MB a copy for a 4 MB leaf). It fits with
+    its temporaries: all sixteen layers' weights and pool are 13.4 GB of the
+    chip's 15.75 GiB, and a period's sync holds under 1.3 GB beside them
+    (all sixteen layers': 1.15 GB, compiled once by hand, PR 30)."""
+    sds, _ = for_chip
+    slots, chunk, pool_len = 64, 128, 1024
+    base = get_model("olmo-hybrid-7b")
+    model = type(base)(dataclasses.replace(
+        base.cfg, dtype=jnp.bfloat16, num_layers=4, layer_types=base.cfg.layer_types[:4],
+        max_seq_len=pool_len, attention_impl="flash"))
+    compiled, pool, around = _compile_sync(
+        sds, model, slots, 1 if step == "decode" else chunk, pool_len)
+    shapes = [leaf.shape for leaf in jax.tree_util.tree_leaves(pool)]
+    assert shapes[0] == (slots, 30, 96, 192) and around == 0
+    text = compiled.as_text()
+    for shape in set(shapes):
+        assert _pool_relayouts(text, "[" + ",".join(map(str, shape)) + "]") == (0, 0), shape
+    assert "dstpu_decode_attn" in text and "dstpu_kv_commit" in text
+    mem = compiled.memory_analysis()
+    print(step, "temporaries", mem.temp_size_in_bytes)
+    assert mem.temp_size_in_bytes < 1.3e9, mem
+    whole = 4 * (mem.argument_size_in_bytes - 2 * 3840 * 100352 * 2) + 2 * 3840 * 100352 * 2
+    assert whole + mem.temp_size_in_bytes < 15.75 * 2**30, whole
