@@ -14,14 +14,17 @@ next token in column 0, the (at most one) in-flight prefill row carries up
 to ``prefill_chunk`` prompt tokens, and per-row query spans mask the rest —
 then finishes the sync with the remaining ``steps_per_sync - 1`` decode
 steps in one on-device loop, so decode keeps its K-step dispatch
-amortization even while prefills chain back-to-back. The plain
-per-projection program on one device runs that first forward over the live
-rows only, where the block is large enough for it to pay
-(:func:`_split_pays`): every slot's column 0, then the chunk as a
+amortization even while prefills chain back-to-back. On one device the
+plain per-projection program and the fused int8 decode blocks run that first
+forward over the live rows only, where the block is large enough for it to
+pay for the bytes of the program's weights (:func:`_split_pays`: over 480
+rows of block in bf16, over 240 in int8, so (24, 64) and (8, 64) split in
+both and (4, 64) in int8 alone): every slot's column 0, then the chunk as a
 ``(1, prefill_chunk)`` forward over its own slot
-(:func:`_first_forward_live_rows`); the fused decode blocks, the extent,
-seq-parallel and adapter variants, a sharded pool and the speculative verify
-run the whole block. Decode slots
+(:func:`_first_forward_live_rows`); the extent, seq-parallel and adapter
+variants, a sharded pool and the speculative verify run the whole block. The
+fused path's three layer kernels are jitted, so the layers and forwards of a
+program share one lowering of each a shape. Decode slots
 therefore stall at most one chunk's compute per K tokens instead of a full
 prompt, TTFT/decode-p95 trade off via ``prefill_chunk``, and the compiled
 program count is O(1) in the prompt-length mix (no per-bucket prefills).
@@ -193,20 +196,25 @@ _PROGRAM_LOCK = threading.RLock()
 # the owning scheduler; probes can never surface them.
 _EXT_NS = -0x10C7E57
 
-# Rows of one forward below which its time is the weight stream's: a v5e's
-# ridge is 197e12 / 819e9 = 240 operations a byte, and a bf16 weight gives 2
-# operations a row for its 2 bytes. A forward over r rows then costs about
-# max(1, r / 240) weight streams.
-_RIDGE_ROWS = 240
+# Rows of one forward below which its time is the weight stream's, for each
+# byte a weight's element takes: a v5e's ridge is 197e12 / 819e9 = 240
+# operations a byte and a row does 2 operations on an element, so a bf16 weight
+# (2 bytes) has its ridge at 240 rows and an int8 weight (1 byte) at 120. A
+# forward over r rows then costs about max(1, r / ridge) weight streams.
+_RIDGE_ROWS_PER_BYTE = 120
 
 
-def _split_pays(n, c):
+def _split_pays(n, c, weight_bytes=2):
     """Whether a chunk sync's first forward is cheaper as two forwards over
     its live rows (``n`` decode rows as one column, the chunk's ``c`` columns
     over its own slot: two weight streams) than as one over the ``(n, c)``
-    block (one stream, n * c rows of compute). Splits at (64, 256), (24, 64)
-    and (8, 64); keeps (4, 16), (4, 64) and any block of one slot."""
-    streams = lambda rows: max(1.0, rows / _RIDGE_ROWS)
+    block (one stream, n * c rows of compute). ``weight_bytes``: the bytes of
+    a weight's element in the program (2: bf16, the ridge at 240 rows; 1: the
+    int8 weights of the fused decode blocks, 120 rows). Both split (64, 256),
+    (24, 64) and (8, 64) and keep (4, 16) and any block of one slot; they
+    differ between 240 and 480 rows of block: int8 splits (4, 64), (8, 32) and
+    (2, 128), which bf16 keeps."""
+    streams = lambda rows: max(1.0, rows / (_RIDGE_ROWS_PER_BYTE * weight_bytes))
     return streams(n * c) > streams(n) + streams(c)
 
 
@@ -2641,13 +2649,17 @@ class DecodeScheduler:
     def _splits_chunk(self, key):
         """Whether the step program under ``key`` runs its first forward as
         two over the live rows (:meth:`_fused_fn`): the plain per-projection
-        program, on one device, at a shape where that is cheaper
-        (:func:`_split_pays`). The fused decode blocks, the extent and
-        seq-parallel variants, adapters, a sharded pool and the verify
-        programs keep the whole block."""
-        return (isinstance(key, tuple) and key[0] == "fused" and key[-1] != "lora"
-                and self._shard_deg == 1
-                and _split_pays(self.cache.num_slots, key[3]))
+        program (``fused``) and the fused int8 decode blocks
+        (``fused_block``), on one device, at a shape where that is cheaper
+        for the bytes of their weights (:func:`_split_pays`: ``fused_block``
+        streams int8, whose ridge is half bf16's, so it also splits blocks
+        of 240 to 480 rows such as (4, 64)). The extent and seq-parallel
+        variants, adapters, a sharded pool and the verify programs
+        (``spec``, ``spec_block``) keep the whole block."""
+        return (isinstance(key, tuple) and key[0] in ("fused", "fused_block")
+                and key[-1] != "lora" and self._shard_deg == 1
+                and _split_pays(self.cache.num_slots, key[3],
+                                1 if key[0] == "fused_block" else 2))
 
     def _fused_fn(self, sampling, collect, ksteps, chunk, lora=False,
                   ext=False, seqp=False):
@@ -2665,8 +2677,9 @@ class DecodeScheduler:
         (chunk, 1) regardless of the prompt-length mix.
 
         Live rows only: where :meth:`_splits_chunk` says so (decided here,
-        when the program is built, from its key, the device count and the
-        shape), the first forward of a ``chunk > 1`` program is two
+        when the program is built, from its key, the device count, the
+        shape and the bytes of the weights: ``fused`` and ``fused_block``
+        alike), the first forward of a ``chunk > 1`` program is two
         (:func:`_first_forward_live_rows`): the ``(num_slots, 1)`` column,
         exactly the decode program's first forward, and the chunk's columns
         as a ``(1, chunk)`` forward over its own slot's rows of the pool,
@@ -2697,7 +2710,12 @@ class DecodeScheduler:
         write-index/q_spans threading and pool layout. The program key is
         retagged ``fused_block`` so capacity telemetry prices the fused
         kind separately; the variant count is unchanged, so the O(1)
-        compiled-programs contract holds.
+        compiled-programs contract holds. A split chunk program calls the
+        kernels at two row counts (``num_slots`` and ``chunk``: the chunk's
+        forward reads and writes one slot's rows of each pool leaf, never a
+        whole leaf); each kernel is jitted (``ops/pallas/decode_block.py``,
+        ``decode_attention.py``, ``kv_commit.py``), so the program's layers,
+        its column and its loop body share one lowering a row count.
 
         ``lora=True`` builds the multi-adapter variant: the program takes a
         trailing ``lora`` argument (per-bucket pool tensors + per-row slot

@@ -2288,6 +2288,13 @@ class CausalLMModel:
         same pool. Only eligible configs reach here (engine
         ``_fused_decode_eligible``): tp=1, so no sharded kernel variants.
 
+        Written for any ``(N, C)`` and any number of pool slots ``N``: the
+        scheduler's split chunk sync calls it over all slots' one column and
+        over ONE slot's rows of the pool at ``(1, prefill_chunk)``
+        (``inference/scheduler.py: _first_forward_live_rows``). The three
+        layer kernels and the commit are jitted, so the 36 layers of a
+        program share one lowering of each a shape.
+
         Returns ``(logits (N, C, V) compute-dtype, new_pool)`` with the
         pool structure ``apply_with_cache`` returns."""
         from ..ops.pallas.decode_block import fused_qkv_ln, fused_out_mlp

@@ -251,7 +251,20 @@ def _decode_call(qg, kv, start, ends, *, block_kv, scale, span=1,
     unreserved/demoted extents, which must lie outside every attended
     window; they clamp to slot 0 and are masked. Without ``ext`` row ``i``
     reads pool row ``i``. ``sink``/``win``: optional (B,) int32 lossy-mode
-    knobs (see :func:`_attn_kernel`)."""
+    knobs (see :func:`_attn_kernel`).
+
+    Jitted with its non-array parameters static, as
+    ``kv_commit.commit_kv_rows`` is: the layers of a step program, its first
+    forwards and its loop body share one trace and one lowering of the
+    kernel a shape."""
+    return _decode_jit(qg, tuple(kv), start, ends, k_scale, v_scale, ext, sink, win,
+                       block_kv=block_kv, scale=scale, span=span,
+                       interpret=_pallas.interpret())
+
+
+@functools.partial(jax.jit, static_argnames=("block_kv", "scale", "span", "interpret"))
+def _decode_jit(qg, kv, start, ends, k_scale, v_scale, ext, sink, win, *,
+                block_kv, scale, span, interpret):
     B, nkv, g, D = qg.shape
     packed = len(kv) == 1
     Np, _, S, lanes = kv[0].shape
@@ -322,7 +335,7 @@ def _decode_call(qg, kv, start, ends, *, block_kv, scale, span=1,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=_pallas.VMEM_LIMIT_BYTES),
-        interpret=_pallas.interpret(),
+        interpret=interpret,
     )(*scalars, *operands)
 
 

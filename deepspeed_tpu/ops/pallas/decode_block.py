@@ -219,17 +219,24 @@ def fused_qkv_ln(x, norms, qkv, *, eps=1e-5, norm="layernorm", rope=None):
     ``(sin2d, cos2d, rot_heads, head_dim)`` — (B, head_dim // 2) f32
     tables gathered at each row's position; the first ``rot_heads`` head
     segments (the q and k heads of the fused layout) are rotated on the
-    flush step, the v tail passes through. Returns (B, Nqkv) bf16."""
+    flush step, the v tail passes through. Returns (B, Nqkv) bf16.
+
+    Jitted (the arrays of ``rope`` apart from its two integers), as
+    ``kv_commit.commit_kv_rows`` is: the layers of a step program, its first
+    forwards and its loop body share one trace and one lowering of the
+    kernel a row count."""
+    sin2d, cos2d, rot_heads, hd = rope if rope is not None else (None, None, 0, 0)
+    return _qkv_ln(x, norms, tuple(qkv), sin2d, cos2d, eps=eps, norm=norm,
+                   rot_heads=rot_heads, hd=hd, interpret=_pallas.interpret())
+
+
+@functools.partial(jax.jit, static_argnames=("eps", "norm", "rot_heads", "hd", "interpret"))
+def _qkv_ln(x, norms, qkv, sin2d, cos2d, *, eps, norm, rot_heads, hd, interpret):
     B, H = x.shape
     w, sc, b = qkv
     Nq = w.shape[1]
     sc, G = _prep_scales(sc)
     g1 = H // G
-    if rope is not None:
-        sin2d, cos2d, rot_heads, hd = rope
-    else:
-        sin2d = cos2d = None
-        rot_heads, hd = 0, 0
     bm, bn, bk1 = _qkv_blocks(B, H, Nq, sc.shape[0], g1, x.dtype.itemsize, hd)
     nk1 = H // bk1
     kernel = functools.partial(_qkv_ln_kernel, nk1=nk1, bk1=bk1, g1=g1, eps=eps,
@@ -258,7 +265,7 @@ def fused_qkv_ln(x, norms, qkv, *, eps=1e-5, norm="layernorm", rope=None):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=_pallas.VMEM_LIMIT_BYTES),
-        interpret=_pallas.interpret(),
+        interpret=interpret,
     )(*operands)
 
 
@@ -383,7 +390,15 @@ def fused_out_mlp(attn2d, x, norms, o, up, down, *, activation="gelu",
     applies to the gate (silu for swiglu, tanh-gelu for geglu), matching
     ``MLP``. Rows are independent: when they do not all fit the VMEM
     budget (the chunked-prefill step) an outer grid axis walks row blocks
-    and each re-streams the weights. Returns x_out (B, H) bf16."""
+    and each re-streams the weights. Returns x_out (B, H) bf16. Jitted, as
+    :func:`fused_qkv_ln` is and for the same reason."""
+    return _out_mlp(attn2d, x, norms, tuple(o), tuple(up), tuple(down),
+                    None if gate is None else tuple(gate), activation=activation,
+                    eps=eps, norm=norm, interpret=_pallas.interpret())
+
+
+@functools.partial(jax.jit, static_argnames=("activation", "eps", "norm", "interpret"))
+def _out_mlp(attn2d, x, norms, o, up, down, gate, *, activation, eps, norm, interpret):
     B, H = x.shape
     o_w, o_s, o_b = o
     up_w, up_s, up_b = up
@@ -471,7 +486,7 @@ def fused_out_mlp(attn2d, x, norms, o, up, down, *, activation="gelu",
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=_pallas.VMEM_LIMIT_BYTES),
-        interpret=_pallas.interpret(),
+        interpret=interpret,
     )(*operands)
 
 

@@ -140,7 +140,9 @@ class CapacityModel:
         contiguous walk even when most extents sit behind the mask.
         ``split``: the program runs its first forward as one column over
         every slot and ``width`` columns over one (the scheduler's
-        ``_splits_chunk``): ``num_slots + width`` rows, two weight streams."""
+        ``_splits_chunk``: the ``fused`` and ``fused_block`` chunk programs):
+        ``num_slots + width`` rows, two weight streams (int8 ones with their
+        group scales where the model's weights are int8)."""
         ksteps = max(1, int(ksteps))
         width = max(1, int(width))
         first_rows = self.num_slots + width if split else self.num_slots * width

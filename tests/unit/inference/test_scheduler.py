@@ -759,17 +759,33 @@ def test_spec_telemetry_counters(tmp_path, baseline):
 SPLIT_SHAPE = dict(num_slots=8, prefill_chunk=64)  # over the threshold; (4, 16) is under it
 
 
+# rides the fused int8 decode blocks (tag ``fused_block``, Pallas in interpret
+# mode): cell 2's path, tiny-gpt2 at head size 64 so that its pool leaf is packed
+FUSED_INT8 = "tiny-int8-fused"
+
+
+def _program_tags(sched):
+    return {k[0] for k in sched._compiled if k != "copy"}
+
+
 def _split_engine(model, params=None, whole_block=False, **cfg):
-    """An (8, 64) scheduler of ``model`` that collects logits, with the
-    scheduler module's shape rule as it is or (``whole_block``) answering no,
-    so the same traffic runs through the whole-block programs."""
+    """An (8, 64) scheduler of ``model`` (a preset, or ``FUSED_INT8``) that
+    collects logits, with the scheduler module's shape rule as it is or
+    (``whole_block``) answering no, so the same traffic runs through the
+    whole-block programs."""
     from deepspeed_tpu.inference.scheduler import _split_pays
+    from deepspeed_tpu.models import get_model
+    fused = model == FUSED_INT8
+    if fused:
+        model = get_model("tiny-gpt2", head_dim=64)
+        cfg.update(dtype="int8", kernel_inject=True)
     cfg["continuous_batching"] = dict(enabled=True, collect_logits=True, **SPLIT_SHAPE)
     eng = make_engine(model, params=params, max_out_tokens=128, **cfg)
     sched = eng.scheduler()
+    assert sched._fused_block == fused, sched._fused_block_reasons
     if whole_block:
         sched._splits_chunk = lambda key: False
-    assert _split_pays(sched.cache.num_slots, sched.prefill_chunk)
+    assert _split_pays(sched.cache.num_slots, sched.prefill_chunk, 1 if fused else 2)
     return eng, sched
 
 
@@ -788,13 +804,19 @@ def _mixed_traffic(sched, vocab):
     return [(h.result(), h.result_logits()) for h in handles]
 
 
-@pytest.fixture(scope="module", params=["tiny", "tiny-mla-moe"])
+@pytest.fixture(scope="module", params=["tiny", "tiny-mla-moe", FUSED_INT8])
 def split_pair(request):
     """(model name, params, results of the mixed traffic through the split
     programs, the same through the whole-block programs, the split scheduler)."""
     model = request.param
-    eng, sched = _split_engine(model)
-    params = jax.device_get(eng.params)
+    params = None
+    if model == FUSED_INT8:  # the engine quantizes: hand every one the same floats
+        from deepspeed_tpu.models import get_model
+        params = jax.device_get(get_model("tiny-gpt2", head_dim=64).init_params(
+            jax.random.key(3)))
+    eng, sched = _split_engine(model, params)
+    if params is None:
+        params = jax.device_get(eng.params)
     vocab = eng.model_config.vocab_size
     split = _mixed_traffic(sched, vocab)
     _, block_sched = _split_engine(model, params, whole_block=True)
@@ -807,11 +829,13 @@ def test_split_chunk_sync_matches_whole_block(split_pair):
     their logits agree within what the (slots, 1) and (slots, C) programs
     already differ by; both ran the same program keys, and the split
     scheduler's chunk programs say they split."""
-    _, _, split, block, sched, block_sched = split_pair
+    model, _, split, block, sched, block_sched = split_pair
     for (ta, la), (tb, lb) in zip(split, block):
         assert list(ta) == list(tb)
+        # (the fused kernels' rows are independent: their logits come out equal)
         np.testing.assert_allclose(la, lb, rtol=0, atol=1e-6)
     assert set(sched._compiled) == set(block_sched._compiled)
+    assert _program_tags(sched) == {"fused_block" if model == FUSED_INT8 else "fused"}
     chunk_keys = [k for k in sched._compiled if k != "copy" and k[3] == 64]
     assert {k[4] for k in chunk_keys} == {1, 4}
     assert all(sched._splits_chunk(k) for k in chunk_keys)
@@ -869,15 +893,27 @@ def test_split_leaves_other_slots_byte_stable(split_pair):
     sched.cache.check_invariants()
 
 
-@pytest.mark.parametrize("case", ["fused_block", "ext", "seqp", "lora", "verify",
-                                  "tp2", "under_threshold", "warmed_count"])
+def _fused_int8_sched(shape=SPLIT_SHAPE, **cfg):
+    """A scheduler on the fused int8 decode blocks (tag ``fused_block``)."""
+    sched = make_engine(dtype="int8", kernel_inject=True,
+                        continuous_batching=dict(enabled=True, **shape), **cfg).scheduler()
+    assert sched._fused_block, sched._fused_block_reasons
+    return sched
+
+
+@pytest.mark.parametrize("case", ["fused_block", "spec_block", "ext", "seqp", "lora", "verify",
+                                  "tp2", "under_threshold", "fused_block_under_threshold",
+                                  "fused_block_int8_ridge", "warmed_count",
+                                  "fused_block_warmed_count"])
 def test_programs_that_keep_the_whole_block(baseline, case):
-    """(e) Only the plain per-projection program on one device, over the
-    shape rule, splits; every other says it keeps the block, and a warmed
+    """(e) Only the plain per-projection program and the fused int8 decode
+    blocks, on one device and over the shape rule for the bytes of their
+    weights, split; every other says it keeps the block, and a warmed
     scheduler holds the programs it held."""
     params, _ = baseline
     cb = dict(enabled=True, **SPLIT_SHAPE)
     plain = ("fused", False, False, 64, 4)
+    block = ("fused_block", False, False, 64, 4)
     if case == "tp2":
         sched = make_engine(params=params, continuous_batching=cb,
                             tensor_parallel={"tp_size": 2}).scheduler()
@@ -887,6 +923,28 @@ def test_programs_that_keep_the_whole_block(baseline, case):
         sched = make_engine(params=params, continuous_batching=dict(
             enabled=True, num_slots=4, prefill_chunk=16)).scheduler()
         assert not sched._splits_chunk(("fused", False, False, 16, 4))
+        return
+    if case == "fused_block_under_threshold":  # (4, 16) and a pool of one slot keep the block
+        sched = _fused_int8_sched(dict(num_slots=4, prefill_chunk=16))
+        assert not sched._splits_chunk(("fused_block", False, False, 16, 4))
+        assert not _fused_int8_sched(dict(num_slots=1))._splits_chunk(block)
+        return
+    if case == "fused_block_int8_ridge":  # 256 rows of block: over int8's ridge, under bf16's
+        sched = _fused_int8_sched(dict(num_slots=4))
+        assert sched._splits_chunk(block) and not sched._splits_chunk(plain)
+        return
+    if case.startswith("fused_block"):
+        sched = _fused_int8_sched()
+        assert sched._splits_chunk(block) and sched._splits_chunk(("fused_block", True, True, 64, 1))
+        assert not sched._splits_chunk(block[:3] + (1, 4))
+        if case == "fused_block_warmed_count":
+            sched.warm_programs(ladder=False)
+            whole = _fused_int8_sched()
+            whole._splits_chunk = lambda key: False
+            whole.warm_programs(ladder=False)
+            assert set(sched._compiled) == set(whole._compiled)
+            assert sched.compiled_program_count() == whole.compiled_program_count() == 7
+            assert _program_tags(sched) == {"fused_block"}
         return
     sched = make_engine(params=params, continuous_batching=cb).scheduler()
     assert sched._splits_chunk(plain) and sched._splits_chunk(("fused", True, True, 64, 1))
@@ -898,7 +956,7 @@ def test_programs_that_keep_the_whole_block(baseline, case):
         assert set(sched._compiled) == set(block._compiled)
         assert sched.compiled_program_count() == block.compiled_program_count() == 7
         return
-    key = {"fused_block": ("fused_block", False, False, 64, 4),
+    key = {"spec_block": ("spec_block", False, False, 64),
            "ext": ("fused_ext", False, False, 64, 4),
            "seqp": ("fused_seqp", False, False, 64, 4),
            "lora": plain + ("lora", ),
@@ -907,15 +965,31 @@ def test_programs_that_keep_the_whole_block(baseline, case):
     assert not sched._splits_chunk(plain[:3] + (1, 4))  # the decode program is one column already
 
 
-@pytest.mark.parametrize("split", [True, False])
+@pytest.mark.parametrize("n, c, bf16, int8", [
+    (64, 256, True, True), (24, 64, True, True), (8, 64, True, True),   # both ridges split
+    (4, 64, False, True), (8, 32, False, True), (2, 128, False, True),  # 256 rows: int8 alone
+    (4, 16, False, False), (3, 64, False, False), (1, 512, False, False)])
+def test_split_pays_at_both_ridges(n, c, bf16, int8):
+    """The shape rule at a bf16 weight's ridge (240 rows) and an int8 weight's
+    (120): they differ for blocks of 240 to 480 rows."""
+    from deepspeed_tpu.inference.scheduler import _split_pays
+    assert _split_pays(n, c) == _split_pays(n, c, 2) == bf16
+    assert _split_pays(n, c, 1) == int8
+
+
+@pytest.mark.parametrize("split", [True, False, "fused_block"])
 def test_step_row_counters(tmp_path, baseline, split):
     """(f) ``serving/step_rows_run`` and ``_live`` over three syncs of an
     (8, 64) pool, K = 4: a 70-token prompt's non-final chunk on an idle pool
-    (the K = 1 program), its final chunk of 6, one decode sync."""
+    (the K = 1 program), its final chunk of 6, one decode sync. The fused int8
+    decode blocks split as the per-projection program does."""
     params, _ = baseline
-    eng = make_engine(params=params, continuous_batching=dict(enabled=True, **SPLIT_SHAPE),
-                      telemetry={"enabled": True, "output_path": str(tmp_path)})
-    sched = eng.scheduler()
+    tel_cfg = {"enabled": True, "output_path": str(tmp_path)}
+    if split == "fused_block":
+        sched = _fused_int8_sched(telemetry=tel_cfg)
+    else:
+        sched = make_engine(params=params, continuous_batching=dict(enabled=True, **SPLIT_SHAPE),
+                            telemetry=tel_cfg).scheduler()
     if not split:
         sched._splits_chunk = lambda key: False
     h = sched.submit(list(range(3, 73)), max_new_tokens=6)
@@ -924,8 +998,9 @@ def test_step_row_counters(tmp_path, baseline, split):
         sched.step()
         syncs += 1
     assert syncs == 3
+    assert _program_tags(sched) == {"fused_block" if split == "fused_block" else "fused"}
     first = (8 + 64) if split else 8 * 64
-    tel = eng.telemetry
+    tel = sched.telemetry
     assert tel.counter_total("serving/step_rows_run") == first + (first + 3 * 8) + 4 * 8
     assert tel.counter_total("serving/step_rows_live") == 64 + (6 + 3) + 4
     tel.close()

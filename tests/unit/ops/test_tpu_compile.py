@@ -12,6 +12,7 @@ worker that is handed this file loads the TPU library, and only once a
 test has started.
 """
 
+import collections
 import dataclasses
 import os
 import re
@@ -71,9 +72,9 @@ def _serving_model(name, **over):
     """The model config ``init_inference(name, dtype=int8, kernel_inject)``
     serves, one layer deep (every layer compiles the same kernels)."""
     cfg = get_model(name).cfg
-    cfg = dataclasses.replace(
-        cfg, dtype=jnp.bfloat16, int8_weights=True, int8_fused_qkv=True,
-        attention_impl="flash", scan_layers=False, num_layers=1, **over)
+    cfg = dataclasses.replace(cfg, **{
+        "dtype": jnp.bfloat16, "int8_weights": True, "int8_fused_qkv": True,
+        "attention_impl": "flash", "scan_layers": False, "num_layers": 1, **over})
     return type(get_model(name))(cfg)
 
 
@@ -308,16 +309,19 @@ def test_fused_sync_temporaries_at_cell_2(for_chip):
     assert mem.temp_size_in_bytes < 410e6, mem
 
 
-def _sync(model, chunk):
-    """A sync of ``DecodeScheduler._fused_fn``'s plain per-projection program
-    at ``steps_per_sync`` 4: the first forward over one column (``chunk`` 1,
-    the decode program) or over the live rows of a (slots, chunk) block (the
-    scheduler's own split), then a ``fori_loop`` of one-column steps on the
-    donated pool, all through ``apply_with_cache``."""
+def _sync(model, chunk, fused=False):
+    """A sync of ``DecodeScheduler._fused_fn``'s program at ``steps_per_sync``
+    4: the first forward over one column (``chunk`` 1, the decode program) or
+    over the live rows of a (slots, chunk) block (the scheduler's own split),
+    then a ``fori_loop`` of one-column steps on the donated pool, all through
+    ``apply_with_cache`` (the plain per-projection program) or, ``fused``,
+    through ``fused_paged_step`` (the fused int8 decode blocks)."""
     from deepspeed_tpu.inference.scheduler import _first_forward_live_rows, _split_pays
 
     def sync(params, pool, ids, lengths, spans):
         def forward(pool, ids, pos, widx, sp):
+            if fused:
+                return model.fused_paged_step(params, ids, pool, pos, widx, sp) + (None, None)
             return model.apply_with_cache(params, ids, pool, 0, position_ids=pos,
                                           write_index=widx, q_spans=sp) + (None, None)
 
@@ -325,7 +329,7 @@ def _sync(model, chunk):
             logits, pool, _, _ = forward(pool, ids, lengths[:, None], lengths, spans)
             last = logits[:, 0]
         else:
-            assert _split_pays(ids.shape[0], chunk)
+            assert _split_pays(ids.shape[0], chunk, 1 if fused else 2)
             last, pool, _, _ = _first_forward_live_rows(forward, pool, ids, lengths, spans)
         base_ = lengths + jnp.maximum(spans, 1) - 1
         live = jnp.minimum(spans, 1)
@@ -340,14 +344,22 @@ def _sync(model, chunk):
     return sync
 
 
-def _compile_sync(sds, model, slots, chunk, pool_len):
+def _lower_sync(sds, model, slots, chunk, pool_len, fused=False):
+    """(the lowered sync, its pool's shapes); an int8 model's parameters keep
+    their own dtypes, a float model's are bf16."""
     shaped = lambda tree, dt=None: jax.tree_util.tree_map(
         lambda a: sds(a.shape, dt or a.dtype), tree)
-    params = shaped(jax.eval_shape(model.init_params, jax.random.key(0)), jnp.bfloat16)
+    params = shaped(jax.eval_shape(model.init_params, jax.random.key(0)),
+                    None if fused else jnp.bfloat16)
     pool = shaped(jax.eval_shape(lambda: model.init_cache(slots, pool_len)))
     rows = sds((slots, ), jnp.int32)
-    compiled = jax.jit(_sync(model, chunk), donate_argnums=(1, )).lower(
-        params, pool, sds((slots, chunk), jnp.int32), rows, rows).compile()
+    return jax.jit(_sync(model, chunk, fused), donate_argnums=(1, )).lower(
+        params, pool, sds((slots, chunk), jnp.int32), rows, rows), pool
+
+
+def _compile_sync(sds, model, slots, chunk, pool_len, fused=False):
+    lowered, pool = _lower_sync(sds, model, slots, chunk, pool_len, fused)
+    compiled = lowered.compile()
     # the donated pool is updated in place: every leaf is aliased input to
     # output and nothing in the loop moves a whole leaf; ``around`` counts
     # the whole-leaf moves outside it (a relayout in and out of a leaf that
@@ -399,6 +411,44 @@ def test_dense_per_projection_chunk_sync(for_chip):
     assert jax.tree_util.tree_leaves(pool)[0].shape == (24, 20, 1024, 128)  # packed
     assert around == 0
     print("temporaries", compiled.memory_analysis().temp_size_in_bytes)
+
+
+def test_fused_int8_chunk_sync_at_cell_2(for_chip):
+    """Cell 2's own chunk sync (gpt2-large, int8 fused decode blocks, packed
+    pool, 24 slots x 1024, ``prefill_chunk`` 64, K = 4) as the scheduler
+    splits it: the 24 decode rows as one column and the chunk as a (1, 64)
+    forward over its own slot. One layer deep, compiled: the pool aliased, no
+    copy, scatter or transpose of a whole pool leaf (one slot's rows go out
+    and back by a dynamic slice and an in-place update), nothing of the
+    block's 1,536 rows, and temporaries far under the block program's. At the
+    model's 36 layers, lowered: each of the three layer kernels and the
+    commit is ONE function a row count (24 and 64), called from every layer
+    (and, at 24 rows, from the column and the loop body alike), not 36
+    bodies."""
+    sds, _ = for_chip
+    compiled, pool, around = _compile_sync(sds, _serving_model("gpt2-large"), 24, 64, 1024,
+                                           fused=True)
+    assert jax.tree_util.tree_leaves(pool)[0].shape == (24, 20, 1024, 128)  # packed
+    assert around == 0
+    text = compiled.as_text()
+    assert "dstpu_kv_commit" in text and "[1536," not in text
+    for kernel in ("dstpu_fused_qkv_ln", "dstpu_fused_out_mlp", "dstpu_decode_attn"):
+        assert kernel in text, kernel  # none fell back to an XLA path
+    block, _ = _compile_fused_sync(sds, _serving_model("gpt2-large"), 24, 1024, 4)
+    temps, block_temps = (c.memory_analysis().temp_size_in_bytes for c in (compiled, block))
+    print("temporaries", temps, "block", block_temps)
+    assert temps < block_temps / 4
+
+    lowered, _ = _lower_sync(sds, _serving_model("gpt2-large", num_layers=36), 24, 64, 1024,
+                             fused=True)
+    module = lowered.as_text()
+    calls = collections.Counter(re.findall(r"call @(\w+?)(?:_\d+)?\(", module))
+    bodies = collections.Counter(re.findall(r"func\.func private @(\w+?)(?:_\d+)?\(", module))
+    for fn, kernel in (("_qkv_ln", "dstpu_fused_qkv_ln"), ("_out_mlp", "dstpu_fused_out_mlp"),
+                       ("_decode_jit", "dstpu_decode_attn"), ("_commit", "dstpu_kv_commit")):
+        # two row counts; the column and the loop body share the 24-row one
+        assert bodies[fn] == 2 and calls[fn] == 3 * 36, (fn, bodies[fn], calls[fn])
+        assert module.count(kernel) == 2, (kernel, module.count(kernel))
 
 
 def test_generate_step_program(for_chip):

@@ -215,18 +215,23 @@ def test_observe_dispatch_roofline_classification():
     assert ent["bound"] in ("compute", "bandwidth")
 
 
+@pytest.mark.parametrize("int8", [False, True], ids=["fused", "fused_block"])
 @pytest.mark.parametrize("split", [False, True])
-def test_dispatch_cost_prices_a_split_chunk_program(split):
+def test_dispatch_cost_prices_a_split_chunk_program(split, int8):
     """A chunk program that runs its first forward over the live rows is
     priced at ``num_slots + width`` first-forward rows and two weight streams
-    for them, the whole block at ``num_slots * width`` rows and one."""
-    model = CapacityModel(type("C", (), {"hidden_size": 64, "num_layers": 2,
-                                         "num_heads": 4, "vocab_size": 128})(),
-                          kv_bytes_per_token=0, num_slots=8)
+    for them, the whole block at ``num_slots * width`` rows and one; a split
+    ``fused_block`` program's extra stream is one of int8 weights with their
+    group scales (1 + 4/128 bytes a parameter, where bf16 moves 2)."""
+    cfg = {"hidden_size": 64, "num_layers": 2, "num_heads": 4, "vocab_size": 128,
+           "dtype": "bfloat16", "int8_weights": int8}
+    model = CapacityModel(type("C", (), cfg)(), kv_bytes_per_token=0, num_slots=8)
     flops, bytes_ = model.dispatch_cost(np.zeros(0), width=64, ksteps=4, split=split)
     first = 8 + 64 if split else 8 * 64
     assert flops == (first + 3 * 8) * model.matmul_flops_per_col
     assert bytes_ == (4 + split) * model.weight_read_bytes
+    weights = 2 * (64 * 16 * 12 + 4 * 16 * 64 + 2 * 64 * 256) + 64 * 128
+    assert model.weight_read_bytes == weights * ((1 + 4 / 128) if int8 else 2)
 
 
 # ------------------------------------------------- analytic-model cross-check
