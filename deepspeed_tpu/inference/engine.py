@@ -595,8 +595,10 @@ class InferenceEngine:
             reasons.append("local-attention layers (the fused path has no "
                            "per-layer sliding-window starts)")
         if getattr(mc, "layer_types", ()):
-            reasons.append("layer_types (per-layer mixers: the fused path has one "
-                           "attention block and no recurrent-state update)")
+            kinds = sorted(set(mc.layer_types) - {"full_attention"})
+            reasons.append(f"layer_types ({', '.join(kinds)}: the fused path has one "
+                           f"attention block and no recurrent-state update, ring, "
+                           f"differential map or value carried between layers)")
         if getattr(mc, "act_quant_bits", 0):
             reasons.append(f"act_quant_bits={mc.act_quant_bits} (no fused "
                            f"fake-quant of block inputs)")
@@ -984,11 +986,12 @@ class InferenceEngine:
         per-row new-token arrays). The KV cache returns to the pool
         immediately (device-side refs; execution order serializes reuse)."""
         self._check_offload_path("the static-batch generate() path")
-        if "linear_attention" in getattr(self.model_config, "layer_types", ()):
-            raise ValueError("a model with linear_attention layers is served through the "
+        if set(getattr(self.model_config, "layer_types", ())) - {"full_attention"}:
+            raise ValueError("a model whose slots hold recurrent state or ring rows "
+                             "(layer_types) is served through the "
                              "continuous-batching scheduler (submit() / the gateway): the "
                              "static-batch generate() cache has no per-row spans to "
-                             "advance a recurrent state by")
+                             "advance a recurrent state or a ring by")
         rows = [np.asarray(r, np.int32).reshape(-1) for r in input_ids]
         B = len(rows)
         lens = np.array([len(r) for r in rows], np.int32)

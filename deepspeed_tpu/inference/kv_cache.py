@@ -70,9 +70,13 @@ class SlotKVCache:
     ``kinds``: the model's declaration of what each leaf holds
     (``model.cache_kinds()``, a tree of ``pool``'s structure): ``"rows"``
     (a row axis at ``ndim - 2``, one row a position) or ``"state"`` (per
-    slot, no row axis: a linear-attention layer's recurrent state and
-    convolution window). None: every leaf holds rows. A leaf's shape does
-    not say which it is; the declaration does.
+    slot, no row axis: a linear-attention or Mamba layer's recurrent state
+    and convolution window) or ``"ring"`` (a windowed layer's K and V: a row
+    axis at ``ndim - 2`` of a fixed number of rows whatever ``max_len`` is,
+    position ``p`` in row ``p mod R``; per-slot bytes, as a state's). A
+    layer may hold nothing (None in ``kinds`` and in ``pool``: no leaf).
+    None: every leaf holds rows. A leaf's shape does not say which it is;
+    the declaration does.
     """
 
     def __init__(self, pool, num_slots, max_len, page_size=256, max_extents=1, kinds=None):
@@ -385,8 +389,9 @@ class SlotKVCache:
         int8 tier — the per-token scale leaves): every ROW leaf keeps its
         slot and row axes, so per-row bytes fall out of leaf sizes
         generically for the plain and quantized layouts, split or packed
-        (the packed leaf holds the split pair's bytes). State leaves
-        (:meth:`state_bytes_per_slot`) do not count. 0 when the
+        (the packed leaf holds the split pair's bytes). State and ring leaves
+        (:meth:`state_bytes_per_slot`, :meth:`window_bytes_per_slot`) do not
+        count: they do not grow with a slot's length. 0 when the
         pool is host-bookkeeping-only (tests)."""
         denom = self.num_slots * self.max_len
         return int(sum((leaf.size // denom) * leaf.dtype.itemsize
@@ -408,10 +413,17 @@ class SlotKVCache:
         return int(sum((leaf.size // self.num_slots) * leaf.dtype.itemsize
                        for leaf in self._leaves("state")))
 
+    def window_bytes_per_slot(self):
+        """HBM bytes of RING rows one slot holds (all windowed layers' K and
+        V): what a slot costs whatever its length, as a state does. 0 for a
+        pool without windowed layers."""
+        return int(sum((leaf.size // self.num_slots) * leaf.dtype.itemsize
+                       for leaf in self._leaves("ring")))
+
     def capacity_bytes(self):
-        """Total HBM held by the fixed-shape pool, rows and state."""
-        return (self.bytes_per_token() * self.max_len
-                + self.state_bytes_per_slot()) * self.num_slots
+        """Total HBM held by the fixed-shape pool: rows, rings and state."""
+        return (self.bytes_per_token() * self.max_len + self.state_bytes_per_slot()
+                + self.window_bytes_per_slot()) * self.num_slots
 
     def live_bytes(self):
         """Bytes backing live + retained rows (the working set; the rest of

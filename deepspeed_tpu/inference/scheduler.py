@@ -144,6 +144,20 @@ rows to mask, copy by length or roll back. Gauges
 ``serving/state_slots_reset``. See ``benchmarks/SERVING.md`` ("Recurrent
 state beside K/V rows").
 
+**Ring rows and shared rows**: the scheduler follows the model's declaration
+(``cache_kinds``), not a layer kind's name: a pool with any leaf that is not
+``"rows"`` (``"state"``; ``"ring"``: a windowed layer's K and V in a ring of
+about its window's rows, position ``p`` in row ``p mod R``) or with a layer
+that declares nothing (a gated memory unit; a cross-attention layer that
+reads the rows of the full layer below it) is such a pool: same substep-spans
+operand (a ring does not forgive a garbage substep either), same refusals,
+same bypass counter. Gauge ``serving/window_bytes_per_slot``; counters
+``serving/attn_rows_window`` / ``serving/attn_rows_shared`` (the K/V positions
+a sync's attention has to read, from the host's lengths and spans:
+:meth:`DecodeScheduler._count_attention_rows`) and
+``serving/cross_decoder_rows_unread``. See ``benchmarks/SERVING.md`` ("Ring
+rows, shared rows and SSM state").
+
 Telemetry (PR-1 sink): gauges ``serving/slot_occupancy``,
 ``serving/batch_efficiency``, ``serving/kv_token_utilization``,
 ``serving/prefix_cache_hit_rate``, ``serving/spec_acceptance_rate``,
@@ -564,15 +578,31 @@ class DecodeScheduler:
             if unsupported:
                 raise ValueError("the latent KV pool does not support "
                                  + ", ".join(unsupported) + " yet")
-        # per-slot STATE beside the rows (a model with linear-attention
-        # layers): a state has no rows for per-slot ends to mask and no past
-        # to roll back to, so everything that copies, truncates, moves or
-        # re-reads a slot's rows is refused here, by name
-        self._state_pool = "linear_attention" in getattr(model.cfg, "layer_types", ())
+        # per-slot STATE or a RING of rows beside the rows that grow, or rows
+        # that layers share, as the model declares them (cache_kinds): a
+        # state has no rows for per-slot ends to mask and no past to roll
+        # back to, a ring forgets what a copy by prefix or a rollback would
+        # need, so everything that copies, truncates, moves or re-reads a
+        # slot's rows is refused here, by name
+        kinds = model.cache_kinds() if hasattr(model, "cache_kinds") else None
+        declared = set(jax.tree_util.tree_leaves(kinds))
+        shared = kinds is not None and any(not layer for layer in model.cache_spec(1, 1))
+        held = [name for name, on in (("recurrent state", "state" in declared),
+                                      ("ring rows", "ring" in declared),
+                                      ("rows that layers share", shared)) if on]
+        self._state_pool = bool(held)
+        # (windowed layers, their window, layers that read the shared rows):
+        # what the host's counters of attended rows multiply by
+        cfg = model.cfg
+        layers = range(cfg.num_layers) if getattr(cfg, "carries_across_layers", False) else ()
+        windows = [cfg.layer_window(i) for i in layers]
+        self._attn_layers = (sum(w > 0 for w in windows), max(windows, default=0),
+                             sum(not w and cfg.layer_type(i) in ("diff_attention", "cross_attention")
+                                 for i, w in zip(layers, windows)))
         if self._state_pool:
             unsupported = [name for name, on in (
-                ("speculative verify (spec_tokens): a state cannot roll back the "
-                 "rejected columns", int(spec_tokens) > 0),
+                ("speculative verify (spec_tokens): a state or a ring cannot roll back "
+                 "the rejected columns", int(spec_tokens) > 0),
                 ("extent chains (max_extents > 1)", int(max_extents) > 1),
                 ("sequence-parallel prefill", bool(self._seq_chunk)),
                 ("tier demotion (prefix_store)", prefix_store is not None),
@@ -581,10 +611,9 @@ class DecodeScheduler:
                 ("adapters (adapter_store)", adapter_store is not None),
                 ("a tensor-parallel pool", tp_ax > 1)) if on]
             if unsupported:
-                raise ValueError("a slot pool that holds recurrent state (layer_types with "
-                                 "linear_attention) does not support "
+                raise ValueError("a slot pool that holds " + ", ".join(held)
+                                 + " (layer_types) does not support "
                                  + "; ".join(unsupported) + " yet")
-        kinds = model.cache_kinds() if hasattr(model, "cache_kinds") else None
         self.cache = SlotKVCache(engine._init_cache(int(num_slots), S, kv_dtype=kv_arg),
                                  int(num_slots), S, page_size=min(block, S),
                                  max_extents=me, kinds=kinds)
@@ -747,6 +776,8 @@ class DecodeScheduler:
         if self._state_pool:
             self.telemetry.gauge("serving/state_bytes_per_slot",
                                  self.cache.state_bytes_per_slot())
+            self.telemetry.gauge("serving/window_bytes_per_slot",
+                                 self.cache.window_bytes_per_slot())
         # set by serving/replica.py when this scheduler serves in a fleet;
         # request traces stamp it so the migration-aware trace_summary view
         # can pair prefill and decode replicas per request
@@ -1036,9 +1067,9 @@ class DecodeScheduler:
 
     def _refuse_state_migration(self):
         if self._state_pool:
-            raise ValueError("a request whose slot holds recurrent state (layer_types with "
-                             "linear_attention) cannot migrate between replicas yet: the "
-                             "handoff moves rows only")
+            raise ValueError("a request whose slot holds recurrent state, ring rows or rows "
+                             "that layers share (layer_types) cannot migrate between "
+                             "replicas yet: the handoff moves rows by length only")
 
     def _settle_migration(self, record, error=None, discard=True):
         """Terminal bookkeeping shared by every failed/cancelled handoff
@@ -1978,6 +2009,37 @@ class DecodeScheduler:
             sub[held] = 0
         return (jnp.asarray(sub), )
 
+    def _count_attention_rows(self, lens, spans, ksteps, chunk=None):
+        """With the sink on, for a model with windowed or shared-row layers:
+        the K/V positions a sync's attention has to read, from the host's
+        copies of the lengths and spans, summed over slots, forwards and
+        layers. A forward that leaves a row at ``n`` positions after ``s``
+        live columns reads ``n`` shared rows a reading layer (the full layer
+        and every cross layer) and ``min(n, window + s - 1)`` ring rows a
+        windowed layer, each once whatever the number of queries.
+        ``chunk``: ``(slot, final)`` of a sync's prefill row: unless its
+        chunk is final it stands still in the substeps. Also counts the
+        chunk's positions that run through the cross-decoder layers and
+        whose output nothing reads (all but a prompt's last)."""
+        tel = self.telemetry
+        n_win, window, n_shared = self._attn_layers
+        if not (tel.enabled and (n_win or n_shared)):
+            return
+        live = spans > 0
+        after, sp = (lens + spans)[live].astype(np.int64), spans[live]
+        shared, ring = int(after.sum()), int(np.minimum(after, window + sp - 1).sum())
+        if chunk is not None and not chunk[1]:
+            live[chunk[0]] = False
+        base = (lens + spans)[live].astype(np.int64)
+        for k in range(1, ksteps):
+            shared += int((base + k).sum())
+            ring += int(np.minimum(base + k, window).sum())
+        tel.counter("serving/attn_rows_shared", n_shared * shared)
+        tel.counter("serving/attn_rows_window", n_win * ring)
+        if chunk is not None and n_shared:
+            tel.counter("serving/cross_decoder_rows_unread",
+                        int(spans[chunk[0]]) - int(chunk[1]))
+
     def _call_step(self, fn, args, lora, spans):
         """Dispatch ONE step program (``spans``: the host's copy of
         ``args[4]``, for :meth:`_dispatch`'s row counters), owning the MoE
@@ -2347,6 +2409,7 @@ class DecodeScheduler:
             if eo is not None:
                 args = args + tuple(jnp.asarray(x) for x in eo)
             args = args + self._substep_spans(spans)
+            self._count_attention_rows(lens, spans, K)
         try:
             out = self._call_step(fn, args, lora, spans)
         except _ExpertOverflow as e:
@@ -2558,6 +2621,7 @@ class DecodeScheduler:
             if eo is not None:
                 args = args + tuple(jnp.asarray(x) for x in eo)
             args = args + self._substep_spans(spans, held=None if final else ps)
+            self._count_attention_rows(lens, spans, K, chunk=(ps, final))
         try:
             out = self._call_step(fn, args, lora, spans)
         except _ExpertOverflow as e:

@@ -8,7 +8,7 @@ llama-style) plus the BASELINE.json tracked configs (GPT-2 125M, Llama-3
 
 import jax.numpy as jnp
 
-from .transformer import TransformerConfig, CausalLM, CausalLMModel
+from .transformer import TransformerConfig, CausalLM, CausalLMModel, sambay_layers
 
 _PRESETS = {}
 
@@ -195,3 +195,36 @@ def tiny_hybrid():
     """Test-scale hybrid: one period of three linear-attention layers and a
     full-attention one."""
     return _hybrid(64, 4, 4, 16, 128, 4, 8, 16, 256, 256)
+
+
+def _sambay(hidden, layers, heads, kv_heads, ffn, window, vocab, seq, d_state=16, mb_per_layer=2):
+    """A decoder-hybrid-decoder stack (SambaY, arXiv:2507.06607, ``phi4flash``):
+    Mamba layers alternate with differential attention, windowed in the first
+    half; one full-attention layer, whose K/V the second half's
+    cross-attention layers share, and gated memory units over the last Mamba
+    layer's output. Pre-LN with LayerNorm, SwiGLU without bias, no positional
+    encoding, tied head. Unrolled: the layers differ."""
+    types, windows = sambay_layers(layers, mb_per_layer, window)
+    return TransformerConfig(
+        vocab_size=vocab, hidden_size=hidden, num_layers=layers, num_heads=heads,
+        num_kv_heads=kv_heads, intermediate_size=ffn, max_seq_len=seq, pos_embedding="none",
+        norm="layernorm", activation="swiglu", tie_embeddings=True, layernorm_epsilon=1e-5,
+        attn_bias=True, mlp_bias=False, layer_types=types, layer_windows=windows,
+        ssm_state_size=d_state, ssm_conv_kernel=4, ssm_expand=2, ssm_dt_rank=-(-hidden // 16),
+        scan_layers=False)
+
+
+@register("phi-4-mini-flash-reasoning")
+def phi_4_mini_flash_reasoning():
+    """Phi-4-mini-flash-reasoning at its published sizes (huggingface.co/
+    microsoft/Phi-4-mini-flash-reasoning config.json, ``model_type:
+    phi4flash``): 32 layers, 40 query and 20 key/value heads of 64, a window
+    of 512, Mamba with 16 states a channel, 3.85 B parameters. Served only."""
+    return _sambay(2560, 32, 40, 20, 10240, 512, 200064, 262144)
+
+
+@register("tiny-sambay")
+def tiny_sambay():
+    """Test-scale SambaY: 8 layers (mamba, window, mamba, window, mamba, full,
+    gmu, cross), head size 64, a window of 16 keys."""
+    return _sambay(256, 8, 4, 2, 128, 16, 256, 256, d_state=4)

@@ -29,7 +29,34 @@ from jax.sharding import PartitionSpec as P
 from ..comm import comm as dist
 
 
-LAYER_TYPES = ("full_attention", "linear_attention")
+# the SambaY kinds (arXiv:2507.06607): a selective state-space layer, a
+# differential-attention layer (arXiv:2410.05258; windowed or full by
+# ``layer_windows``), a gated memory unit over the nearest SSM output below
+# it, and a differential cross-attention over the nearest full differential
+# layer's K/V rows below it
+SAMBAY_TYPES = ("mamba", "diff_attention", "gmu", "cross_attention")
+LAYER_TYPES = ("full_attention", "linear_attention") + SAMBAY_TYPES
+
+
+def sambay_layers(num_layers, mb_per_layer, sliding_window):
+    """``(layer_types, layer_windows)`` of a decoder-hybrid-decoder stack
+    (``phi4flash``), from the three numbers its config gives: every
+    ``mb_per_layer``-th layer from 0 is a Mamba layer and the ones between
+    attend. In the first half the attention layers see a sliding window; the
+    second half opens with one more Mamba layer and ONE full-attention
+    layer, and after them Mamba's places hold gated memory units over that
+    Mamba layer's output and attention's places cross-attend the full
+    layer's keys and values."""
+    half = num_layers // 2
+    types, windows = [], []
+    for i in range(num_layers):
+        ssm = i % mb_per_layer == 0
+        if i >= half + 2:
+            types.append("gmu" if ssm else "cross_attention")
+        else:
+            types.append("mamba" if ssm else "diff_attention")
+        windows.append(sliding_window if types[-1] == "diff_attention" and i < half else 0)
+    return tuple(types), tuple(windows)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -112,8 +139,19 @@ class TransformerConfig:
     # per-layer mixers: one of LAYER_TYPES for each layer ("full_attention":
     # Attention; "linear_attention": the gated delta rule, GatedDeltaNet,
     # which holds a per-slot recurrent state and convolution window and no
-    # cache rows). () = every layer full attention. Needs unrolled layers
+    # cache rows; SAMBAY_TYPES: Mamba, DiffAttention, GatedMemoryUnit and
+    # DiffAttention(cross), which hold state, rows or a ring of rows, nothing,
+    # and nothing). () = every layer full attention. Needs unrolled layers
     layer_types: Tuple[str, ...] = ()
+    # keys a diff_attention layer's query sees, its own included (0 = all):
+    # such a layer's slot holds a ring of about that many rows. () = none
+    layer_windows: Tuple[int, ...] = ()
+    # a mamba layer (Mamba-1): d_inner = ssm_expand * hidden_size
+    ssm_state_size: int = 0
+    ssm_conv_kernel: int = 4
+    ssm_expand: int = 2
+    ssm_dt_rank: int = 0
+    mlp_bias: Optional[bool] = None  # None = follow norm (layernorm -> biased)
     linear_num_heads: int = 0  # key heads = value heads of a linear layer
     linear_key_head_dim: int = 0
     linear_value_head_dim: int = 0
@@ -194,6 +232,36 @@ class TransformerConfig:
                 raise ValueError("linear_attention layers need linear_num_heads, "
                                  "linear_key_head_dim, linear_value_head_dim and a "
                                  "convolution of width > 1")
+            sambay = set(self.layer_types) & set(SAMBAY_TYPES)
+            object.__setattr__(self, "layer_windows", tuple(self.layer_windows))
+            if self.layer_windows and (len(self.layer_windows) != self.num_layers or any(
+                    w and t != "diff_attention"
+                    for w, t in zip(self.layer_windows, self.layer_types))):
+                raise ValueError("layer_windows gives a window (0 = none) for each layer, and "
+                                 "only a diff_attention layer takes one")
+            if sambay:
+                if set(self.layer_types) - set(SAMBAY_TYPES):
+                    raise ValueError(f"the kinds {SAMBAY_TYPES} carry values from layer to "
+                                     f"layer and do not mix with the others")
+                if {"mamba", "gmu"} & sambay and not (
+                        self.ssm_state_size and self.ssm_dt_rank and self.ssm_conv_kernel > 1):
+                    raise ValueError("mamba and gmu layers need ssm_state_size, ssm_dt_rank "
+                                     "and a convolution of width > 1")
+                if self.pos_embedding != "none" or self.post_norm or self.qk_norm \
+                        or self.num_heads % 2 or self.kv_heads % 2 \
+                        or (self.num_heads // 2) % (self.kv_heads // 2):
+                    raise ValueError("differential attention pairs heads (even counts of "
+                                     "query and key/value heads, whole groups of pairs) and "
+                                     "composes with pos_embedding='none', pre-norm blocks "
+                                     "and no qk_norm only")
+                seen = set()
+                for i, t in enumerate(self.layer_types):
+                    needs = {"gmu": "mamba", "cross_attention": "full"}.get(t)
+                    if needs and needs not in seen:
+                        raise ValueError(f"layer {i} ({t}) has no {needs} layer below it "
+                                         f"to read")
+                    seen.add("full" if t == "diff_attention" and not self.layer_window(i)
+                             else t)
             if self.kv_lora_rank or self.num_experts or self.parallel_residual \
                     or self.int8_weights:
                 raise ValueError("layer_types composes with plain attention and a dense MLP "
@@ -256,6 +324,32 @@ class TransformerConfig:
         """The mixer of layer ``layer_idx`` (one of :data:`LAYER_TYPES`)."""
         return self.layer_types[layer_idx] if self.layer_types else "full_attention"
 
+    def layer_window(self, layer_idx):
+        """Keys layer ``layer_idx``'s queries see, their own included (0 = all)."""
+        return self.layer_windows[layer_idx] if self.layer_windows else 0
+
+    def ring_rows(self, layer_idx):
+        """Rows of the ring a windowed layer's slot holds: its window, rounded
+        up to the decode kernel's KV block (to 8 rows under one block). The
+        paged decode kernel masks a ring by a row count alone, so it serves
+        the ring whose rows ARE the window (512 = 2 blocks)."""
+        w = self.layer_window(layer_idx)
+        blk = self.decode_block_kv if w >= self.decode_block_kv else 8
+        return -(-w // blk) * blk
+
+    @property
+    def ssm_inner(self):
+        """Channels of a Mamba layer's state-space model (``d_inner``)."""
+        return self.ssm_expand * self.hidden_size
+
+    @property
+    def carries_across_layers(self):
+        """Whether the layer loop hands values from layer to layer beside the
+        residual stream (a Mamba layer's SSM output to the gated memory
+        units above it, the full differential layer's K/V rows to the
+        cross-attention layers above it)."""
+        return bool(set(self.layer_types) & set(SAMBAY_TYPES))
+
     @property
     def linear_conv_channels(self):
         """Channels of a linear layer's convolution: its q, k and v side by side."""
@@ -287,6 +381,17 @@ class TransformerConfig:
         pos = self.max_seq_len * h if self.pos_embedding == "learned" else 0
         if self.qk_norm:
             attn += self.head_size * (self.num_heads + self.kv_heads)
+        if self.carries_across_layers:
+            hd2, di = 2 * self.head_size, self.ssm_inner
+            q_o = 2 * (h * h + h) + 2 * hd2 + hd2  # W_q, W_o with bias; lambdas; sub-norm
+            per = {"mamba": (2 * h * di + di * (self.ssm_conv_kernel + 1)
+                             + di * (self.ssm_dt_rank + 2 * self.ssm_state_size)
+                             + self.ssm_dt_rank * di + di + di * self.ssm_state_size + di
+                             + di * h),
+                   "diff_attention": q_o + 2 * self.kv_heads * self.head_size * (h + 1),
+                   "gmu": 2 * h * di, "cross_attention": q_o}
+            return (sum(per[t] for t in self.layer_types) + L * (mlp + 4 * h)
+                    + emb + pos + 2 * h)
         n_lin = sum(t == "linear_attention" for t in self.layer_types)
         if n_lin:
             # q, k; v, gate, out; the two per-head gates with A_log and
@@ -608,11 +713,16 @@ def kv_layer_leaves(cfg, layer_cache):
 def kv_pool_geometry(cfg, kv_cache):
     """``"latent"``, ``"packed"`` or ``"split"``: which of ``init_cache``'s
     three geometries a cache tree's ROWS have (read from the first layer that
-    holds rows: a linear-attention layer holds state instead)."""
+    holds rows: a linear-attention layer holds state instead; differential
+    layers' rows, and their rings, are always split)."""
     if cfg.latent_width:
         return "latent"
-    first = cfg.layer_types.index("full_attention") if cfg.layer_types else 0
-    leaf = jax.tree_util.tree_leaves(kv_cache[0])[first]
+    if not cfg.layer_types:
+        leaf = jax.tree_util.tree_leaves(kv_cache[0])[0]
+    elif cfg.carries_across_layers:
+        return "split"  # a pair's keys, and its values, as one head of 2 x head size
+    else:
+        leaf = kv_cache[0][cfg.layer_types.index("full_attention")]
     return "packed" if leaf.shape[-1] == 2 * cfg.head_size else "split"
 
 
@@ -1629,6 +1739,305 @@ class GatedDeltaNet(nn.Module):
         return out, new_cache
 
 
+def mamba_a_log_init(key, shape, dtype=jnp.float32):
+    """``A_log`` of a Mamba layer as published (the S4D-real start): the log
+    of 1..d_state for every channel, so a channel's 16 states forget at 16
+    rates."""
+    return jnp.broadcast_to(jnp.log(jnp.arange(1, shape[-1] + 1, dtype=jnp.float32)),
+                            shape).astype(dtype)
+
+
+def selective_scan_step(h, delta, x, Bm, Cm, A, D):
+    """Mamba-1's recurrence for ONE token, float32: ``h`` (B, ds, di) the
+    state, ``delta``/``x`` (B, di), ``Bm``/``Cm`` (B, ds), ``A`` (ds, di),
+    ``D`` (di,). ``h' = exp(delta A) h + (delta x) (x) B``; returns ``(h' C +
+    D x, h')``. A token with ``delta`` 0 leaves the state as it is."""
+    h = jnp.exp(delta[:, None, :] * A) * h + (delta * x)[:, None, :] * Bm[:, :, None]
+    return jnp.sum(h * Cm[:, :, None], axis=1) + D * x, h
+
+
+def _grouped_attention_xla(q, k, v, keep, scale, dtype):
+    """Masked grouped-query attention in XLA: ``q`` (B, nh, T, D), ``k``/``v``
+    (B, nkv, S, D), ``keep`` (B or 1, T, S) bool. Softmax in float32; a
+    query that keeps nothing (padding) reads a mean, never a NaN."""
+    B, nh, T, D = q.shape
+    nkv = k.shape[1]
+    qg = q.reshape(B, nkv, nh // nkv, T, D)
+    scores = jnp.einsum("bkgtd,bksd->bkgts", qg, k).astype(jnp.float32) * scale
+    scores = jnp.where(keep[:, None, None], scores, -1e30)
+    probs = jax.nn.softmax(scores, axis=-1).astype(dtype)
+    return jnp.einsum("bkgts,bksd->bkgtd", probs, v).reshape(B, nh, T, D)
+
+
+def _serves_by_spans(kind, kv_cache, write_index, q_spans):
+    """The SambaY mixers take a cache through the slot pool's span programs only."""
+    if kv_cache is not None and (write_index is None or q_spans is None):
+        raise NotImplementedError(
+            f"a {kind} layer's slot is served through the slot pool's span programs (the "
+            f"continuous-batching scheduler): the static-batch cache paths have no "
+            f"per-row spans to advance it by")
+
+
+class Mamba(nn.Module):
+    """A selective state-space layer (Mamba-1, arXiv:2312.00752), the mixer
+    of a ``mamba`` layer:
+
+        [x~ ; z] = u W_in ; x_t = SiLU(sum_j w[:, j] x~_(t-W+1+j) + b_c)
+        [d ; B ; C] = x W_x ; Delta = softplus(d W_dt + b_dt) ; A = -exp(A_log)
+        h_t = exp(Delta_t A) h_(t-1) + (Delta_t x_t) (x) B_t ; y_t = h_t C_t + D x_t
+        out = (y * SiLU(z)) W_out
+
+    It hands ``y`` (before the gate) on to the gated memory units above it
+    (``carry["m"]``). What a slot holds for it: the state, at rest ``(B, 1,
+    d_state, d_inner)`` (the channels in the lanes), and the convolution's
+    last ``W - 1`` inputs ``(B, 1, W - 1, d_inner)``, both in the serving
+    dtype, loaded to float32 and rounded once on the store. The rules of a
+    span program are :class:`GatedDeltaNet`'s: a row advances over exactly
+    its ``q_spans`` live columns (later columns get Delta 0), a span-0 row's
+    leaves come out bit for bit, a span at position 0 starts from zero."""
+    cfg: TransformerConfig
+    layer_idx: int = -1
+
+    @nn.compact
+    def __call__(self, x, kv_cache=None, write_index=None, q_spans=None, carry=None):
+        cfg = self.cfg
+        B, T, H = x.shape
+        di, ds, W, r = cfg.ssm_inner, cfg.ssm_state_size, cfg.ssm_conv_kernel, cfg.ssm_dt_rank
+        _serves_by_spans("mamba", kv_cache, write_index, q_spans)
+        f32 = jnp.float32
+        dense = partial(nn.Dense, use_bias=False, dtype=cfg.dtype, param_dtype=f32,
+                        kernel_init=nn.initializers.normal(0.02))
+        with jax.named_scope("ssm_proj"):
+            xz = dense(2 * di, name="in_proj")(x)
+            conv_w = self.param("conv", gdn_conv_init, (di, W), f32)
+            conv_b = self.param("conv_bias", nn.initializers.zeros, (di, ), f32)
+            if kv_cache is None:
+                state = jnp.zeros((B, ds, di), f32)
+                window = jnp.zeros((B, W - 1, di), cfg.dtype)
+            else:
+                state_rest, window_rest = kv_cache
+                live_row = q_spans > 0
+                fresh = live_row & (write_index == 0)
+                state = jnp.where(fresh[:, None, None], 0.0, state_rest[:, 0].astype(f32))
+                window = jnp.where(fresh[:, None, None], 0, window_rest[:, 0]).astype(cfg.dtype)
+            seq = jnp.concatenate([window, xz[..., :di].astype(cfg.dtype)], axis=1)
+            conv = sum(seq[:, j:j + T].astype(f32) * conv_w[:, j] for j in range(W)) + conv_b
+            u = jax.nn.silu(conv).astype(cfg.dtype)
+            dbc = dense(r + 2 * ds, name="x_proj")(u)
+            dt_bias = self.param("dt_bias", gdn_dt_bias_init, (di, ), f32)
+            delta = jax.nn.softplus(dense(di, name="dt_proj")(dbc[..., :r]).astype(f32) + dt_bias)
+            if kv_cache is not None:
+                delta = jnp.where((jnp.arange(T)[None, :] < q_spans[:, None])[..., None],
+                                  delta, 0.0)
+            Bm, Cm = dbc[..., r:r + ds].astype(f32), dbc[..., r + ds:].astype(f32)
+            A = -jnp.exp(self.param("A_log", mamba_a_log_init, (di, ds), f32)).T
+            D = self.param("D", nn.initializers.ones, (di, ), f32)
+        with jax.named_scope("ssm_state"):
+            uf = u.astype(f32)
+            if T == 1:
+                y, state = selective_scan_step(state, delta[:, 0], uf[:, 0], Bm[:, 0], Cm[:, 0],
+                                               A, D)
+                y = y[:, None]
+            else:
+                def body(h, xs):
+                    y_t, h = selective_scan_step(h, *xs, A, D)
+                    return h, y_t
+                state, y = jax.lax.scan(
+                    body, state, tuple(jnp.moveaxis(a, 1, 0) for a in (delta, uf, Bm, Cm)),
+                    unroll=min(T, 8))
+                y = jnp.moveaxis(y, 0, 1)
+            if kv_cache is None:
+                new_cache = None
+            else:
+                # the last W - 1 LIVE inputs: rows [span, span + W - 1) of seq
+                # (GatedDeltaNet's pick)
+                if T == 1:
+                    tail = jnp.where(live_row[:, None, None], seq[:, 1:], seq[:, :-1])
+                else:
+                    rows = q_spans[:, None] + jnp.arange(W - 1)[None, :]
+                    pick = (rows[:, :, None] == jnp.arange(T + W - 1)[None, None, :])
+                    tail = jnp.einsum("bjt,btc->bjc", pick.astype(seq.dtype), seq,
+                                      precision=jax.lax.Precision.HIGHEST)
+                keep = live_row[:, None, None, None]
+                new_cache = (
+                    jnp.where(keep, state[:, None].astype(state_rest.dtype), state_rest),
+                    jnp.where(keep, tail[:, None].astype(window_rest.dtype), window_rest))
+            m = y.astype(cfg.dtype)
+        with jax.named_scope("ssm_out"):
+            out = dense(H, name="out_proj")(m * jax.nn.silu(xz[..., di:]))
+        return out, new_cache, dict(carry, m=m)
+
+
+class GatedMemoryUnit(nn.Module):
+    """The mixer of a ``gmu`` layer: ``out = (SiLU(u W_1) * m) W_2``, ``m``
+    the SSM output of the same token from the nearest Mamba layer below
+    (``carry["m"]``). A slot holds nothing for it."""
+    cfg: TransformerConfig
+    layer_idx: int = -1
+
+    @nn.compact
+    def __call__(self, x, kv_cache=None, write_index=None, q_spans=None, carry=None):
+        cfg = self.cfg
+        dense = partial(nn.Dense, use_bias=False, dtype=cfg.dtype, param_dtype=jnp.float32,
+                        kernel_init=nn.initializers.normal(0.02))
+        with jax.named_scope("gmu"):
+            gate = jax.nn.silu(dense(cfg.ssm_inner, name="in_proj")(x))
+            out = dense(x.shape[-1], name="out_proj")(gate * carry["m"])
+        return out, (None if kv_cache is None else (None, None)), carry
+
+
+def diff_lambda_init(layer_idx):
+    """Differential attention's ``lambda_init`` by 0-based layer index."""
+    import math
+    return 0.8 - 0.6 * math.exp(-0.3 * layer_idx)
+
+
+class DiffAttention(nn.Module):
+    """Differential attention (arXiv:2410.05258), the mixer of a
+    ``diff_attention`` layer and, with ``cross``, of a ``cross_attention``
+    layer. Heads of size ``d`` pair up: query pair ``p`` is ``(q_2p,
+    q_2p+1)``, its key/value pair ``g = p // (pairs a group)`` gives ``K1 =
+    K_2g``, ``K2 = K_2g+1``, ``V = [V_2g ; V_2g+1]``:
+
+        A1 = softmax(q_2p K1^T / sqrt(d)) ; A2 = softmax(q_2p+1 K2^T / sqrt(d))
+        o_p = RMSNorm_2d((A1 - lambda A2) V) (1 - lambda_init)
+        lambda = exp(l_q1 . l_k1) - exp(l_q2 . l_k2) + lambda_init
+
+    under the layer's mask: causal, and with a window (``layer_windows``)
+    only a query's last ``window`` keys, its own included. There is no
+    positional encoding, so which row of a cache a key rests in does not
+    matter, only whether it is attended.
+
+    How it is computed: a pair's keys rest side by side, ``[K_2g | K_2g+1]``,
+    as ONE key of ``2d`` lanes, and so do its values; a query is
+    zero-extended to ``2d`` lanes on its own side (``[q | 0]`` even, ``[0 |
+    q]`` odd), so ``q' . K'`` is exactly ``q . K1`` or ``q . K2`` and ``A
+    V'`` is the 2d-wide read-out of either map. That is plain grouped-query
+    attention over ``kv_heads / 2`` heads of size ``2d`` at scale
+    ``1/sqrt(d)``, which the paged kernels (``dstpu_decode_attn``, head size
+    128) and the XLA fallback both serve; the two maps are combined after.
+
+    What a slot holds: a full layer, rows ``(B, kv_heads / 2, max_len, 2d)``
+    for K and for V; a windowed layer, a RING of ``cfg.ring_rows`` rows each
+    (position ``p`` rests in row ``p mod R``); a cross layer, nothing: it
+    projects queries only and reads the rows the nearest full layer below
+    wrote in this very forward (``carry["kv"]``), the chunk's own included.
+    A ring is attended BEFORE the chunk's rows are committed, over [ring ;
+    fresh rows], so a chunk's queries see the keys that its own writes
+    displace; one decode column on the kernel path commits first (its one
+    row displaces the key that just left the window). Span-0 rows write
+    nothing."""
+    cfg: TransformerConfig
+    layer_idx: int = -1
+    cross: bool = False
+
+    @nn.compact
+    def __call__(self, x, kv_cache=None, write_index=None, q_spans=None, carry=None):
+        cfg = self.cfg
+        B, T, H = x.shape
+        nh, nkv, d = cfg.num_heads, cfg.kv_heads // 2, cfg.head_size
+        window = cfg.layer_window(self.layer_idx)
+        kind = "cross_attention" if self.cross else "diff_attention"
+        _serves_by_spans(kind, kv_cache, write_index, q_spans)
+        use_bias = cfg.attn_bias if cfg.attn_bias is not None else cfg.norm == "layernorm"
+        f32 = jnp.float32
+        with jax.named_scope("attn_proj"):
+            q = HeadProjection(nh, d, use_bias, cfg.dtype, name="q_proj")(x)
+            even = (jnp.arange(nh) % 2 == 0)[None, :, None, None]
+            zero = jnp.zeros_like(q)
+            q = jnp.concatenate([jnp.where(even, q, zero), jnp.where(even, zero, q)], axis=-1)
+            if not self.cross:
+                k = HeadProjection(nkv, 2 * d, use_bias, cfg.dtype, name="k_proj")(x)
+                v = HeadProjection(nkv, 2 * d, use_bias, cfg.dtype, name="v_proj")(x)
+        scale = d ** -0.5
+        cached = kv_cache is not None
+        kernels = cached and cfg.attention_impl == "flash" and _tp_mesh_size() == 1
+        kernel_kw = dict(block_kv=cfg.decode_block_kv, scale=scale)
+        new_cache = (None, None) if cached else None
+        with jax.named_scope("swa_attn" if window else "shared_attn"):
+            if not cached:
+                if self.cross:
+                    k, v = carry["kv"]
+                rel = jnp.arange(T)[:, None] - jnp.arange(T)[None, :]
+                keep = (rel >= 0) & (rel < window) if window else rel >= 0
+                out = _grouped_attention_xla(q, k, v, keep[None], scale, cfg.dtype)
+                kv = (k, v)
+            elif window:
+                out, new_cache = self._ring(q, k, v, kv_cache, write_index, q_spans, window,
+                                            kernels, kernel_kw)
+                kv = None
+            else:
+                if self.cross:
+                    kv = carry["kv"]
+                else:
+                    kv = new_cache = tuple(_commit_span_rows(
+                        [(kv_cache[0], k), (kv_cache[1], v)], write_index, q_spans, kernels))
+                ck, cv = kv
+                if kernels:
+                    from ..ops.pallas.decode_attention import paged_decode_attention, \
+                        paged_span_attention
+                    starts = jnp.zeros((B, ), jnp.int32)
+                    if T == 1:
+                        out = paged_decode_attention(q[:, :, 0], ck, cv, starts, write_index + 1,
+                                                     **kernel_kw)[:, :, None]
+                    else:
+                        out = paged_span_attention(q, ck, cv, starts, write_index, **kernel_kw)
+                else:
+                    qpos = write_index[:, None] + jnp.arange(T)[None, :]
+                    keep = jnp.arange(ck.shape[2])[None, None, :] <= qpos[:, :, None]
+                    out = _grouped_attention_xla(q, ck.astype(cfg.dtype), cv.astype(cfg.dtype),
+                                                 keep, scale, cfg.dtype)
+            lam_init = diff_lambda_init(self.layer_idx)
+            lq1, lk1, lq2, lk2 = (self.param(name, nn.initializers.normal(0.1), (d, ), f32)
+                                  for name in ("lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2"))
+            lam = jnp.exp(jnp.sum(lq1 * lk1)) - jnp.exp(jnp.sum(lq2 * lk2)) + lam_init
+            out = out.astype(f32)
+            out = out[:, 0::2] - lam * out[:, 1::2]  # (B, nh / 2, T, 2d)
+            out = RMSNorm(epsilon=cfg.layernorm_epsilon, dtype=f32, name="sub_norm")(out)
+            out = (out * (1.0 - lam_init)).astype(cfg.dtype)
+        with jax.named_scope("attn_proj"):
+            out = OutProjection(H, use_bias, cfg.dtype, name="o_proj")(out)
+        if not (self.cross or window):
+            carry = dict(carry, kv=kv)
+        return out, new_cache, carry
+
+    def _ring(self, q, k, v, kv_cache, write_index, q_spans, window, kernels, kernel_kw):
+        """Windowed attention over a slot's ring, and the ring with this
+        call's live rows committed. Ring row ``r`` of a slot whose write head
+        is at ``p0`` holds position ``p0 - 1 - ((p0 - 1 - r) mod R)``, if that
+        is not negative (the slot's request has not written the row yet)."""
+        cfg = self.cfg
+        B, nh, T, _ = q.shape
+        rk, rv = kv_cache
+        R = rk.shape[2]
+        if kernels and T == 1 and R == window:
+            # one column: its row displaces the key that just left the
+            # window, so commit first and the ring IS the window
+            from ..ops.pallas.decode_attention import paged_decode_attention
+            ck, cv = _commit_span_rows([(rk, k), (rv, v)], write_index % R, q_spans, True)
+            out = paged_decode_attention(q[:, :, 0], ck, cv, jnp.zeros((B, ), jnp.int32),
+                                         jnp.minimum(write_index + 1, R), **kernel_kw)
+            return out[:, :, None], (ck, cv)
+        p0, j = write_index[:, None], jnp.arange(T)[None, :]
+        held = p0 - 1 - ((p0 - 1 - jnp.arange(R)[None, :]) % R)  # (B, R)
+        keep_ring = (held[:, None, :] >= 0) & (held[:, None, :] > (p0 + j)[:, :, None] - window)
+        rel = jnp.arange(T)[:, None] - jnp.arange(T)[None, :]
+        keep_fresh = jnp.broadcast_to((rel >= 0) & (rel < window), (B, T, T))
+        out = _grouped_attention_xla(
+            q, jnp.concatenate([rk.astype(cfg.dtype), k], axis=2),
+            jnp.concatenate([rv.astype(cfg.dtype), v], axis=2),
+            jnp.concatenate([keep_ring, keep_fresh], axis=-1), kernel_kw["scale"], cfg.dtype)
+        # the last R live columns land at their positions mod R; the others
+        # (padding, and what a chunk wider than the ring would overwrite at
+        # once) are dropped
+        live = (j < q_spans[:, None]) & (j >= q_spans[:, None] - R)
+        tgt = jnp.where(live, (p0 + j) % R, R)
+        upd = lambda c, kk, i: c.at[:, i, :].set(kk.astype(c.dtype), mode="drop")
+        with jax.named_scope("kv_commit"):
+            ring = (jax.vmap(upd)(rk, k, tgt), jax.vmap(upd)(rv, v, tgt))
+        return out, ring
+
+
 class QuantDense(nn.Module):
     """nn.Dense over (int8 weight, fp32 group scales) via the Pallas quant
     matmul (serving path; params come from ``quantize_params``)."""
@@ -1663,11 +2072,12 @@ class MLP(nn.Module):
             d = _lora_site_delta(x_in, lora_ops, site)
             return y if d is None else y + d.reshape(y.shape).astype(y.dtype)
 
+        use_bias = cfg.norm == "layernorm" if cfg.mlp_bias is None else cfg.mlp_bias
         if cfg.int8_weights:
-            dense = partial(QuantDense, use_bias=cfg.norm == "layernorm", dtype=cfg.dtype,
+            dense = partial(QuantDense, use_bias=use_bias, dtype=cfg.dtype,
                             groups=cfg.int8_group_size)
         else:
-            dense = partial(nn.Dense, use_bias=cfg.norm == "layernorm", dtype=cfg.dtype,
+            dense = partial(nn.Dense, use_bias=use_bias, dtype=cfg.dtype,
                             param_dtype=jnp.float32, kernel_init=nn.initializers.normal(0.02))
         if cfg.activation in ("swiglu", "geglu"):
             gate = lora_add(dense(cfg.ffn_size, name="gate_proj")(x), "gate", x)
@@ -1698,14 +2108,30 @@ class Block(nn.Module):
     @nn.compact
     def __call__(self, x, sin, cos, attn_mask=None, deterministic=True, kv_cache=None,
                  cache_index=None, position_ids=None, write_index=None, q_spans=None,
-                 lora_ops=None, expert_ops=None, ext_ops=None, seq_shard=False):
+                 lora_ops=None, expert_ops=None, ext_ops=None, seq_shard=False, carry=None):
+        """``carry``: what the layers below hand on beside the residual
+        stream (``cfg.carries_across_layers``: a dict); with one, the block
+        returns ``(x, new_cache, carry)``."""
         cfg = self.cfg
         drop = nn.Dropout(rate=cfg.dropout) if cfg.dropout > 0 else None
         if cfg.act_quant_bits:  # QAT activation fake-quant (compression)
             from ..compression.helper import fake_quantize
             x = fake_quantize(x, bits=cfg.act_quant_bits, groups=1,
                               symmetric=cfg.act_quant_symmetric)
-        if cfg.layer_type(self.layer_idx) == "linear_attention":
+        kind = cfg.layer_type(self.layer_idx)
+        if kind in SAMBAY_TYPES:
+            if lora_ops or ext_ops is not None or seq_shard or attn_mask is not None:
+                raise NotImplementedError(f"a {kind} layer serves without adapters, extent "
+                                          f"chains, sequence-parallel spans or padding masks")
+            sambay = {"mamba": partial(Mamba, name="mamba"),
+                      "gmu": partial(GatedMemoryUnit, name="gmu"),
+                      "diff_attention": partial(DiffAttention, name="attn"),
+                      "cross_attention": partial(DiffAttention, cross=True, name="attn")}
+            h, new_cache, carry = sambay[kind](cfg, layer_idx=self.layer_idx)(
+                make_norm(cfg, name="attn_norm")(x), kv_cache, write_index, q_spans, carry)
+            x = x + h
+            return x + MLP(cfg, name="mlp")(make_norm(cfg, name="mlp_norm")(x)), new_cache, carry
+        if kind == "linear_attention":
             mixer = GatedDeltaNet(cfg, layer_idx=self.layer_idx, name="gdn")
         else:
             attention = LatentAttention if cfg.kv_lora_rank else Attention
@@ -1851,11 +2277,13 @@ class CausalLM(nn.Module):
               (kv_cache, jnp.arange(cfg.num_layers), lora_ops, expert_ops))
         else:
             caches = []
+            carry = {} if cfg.carries_across_layers else None
             for i in range(cfg.num_layers):
                 # per-layer tuple cache (init_cache, unrolled form); stacked
                 # arrays also index correctly for backward compatibility.
                 # 1 to 3 components: the K/V leaves (packed, split or latent)
-                # and the int8 tier's scale leaf
+                # and the int8 tier's scale leaf; None where a layer declares
+                # fewer than the tree has (cache_spec)
                 layer_cache = (None if kv_cache is None
                                else tuple(comp[i] for comp in kv_cache))
                 layer_lora = (None if lora_ops is None else
@@ -1868,6 +2296,11 @@ class CausalLM(nn.Module):
                         lambda xs_, ms_, ps_, blk=blk, lc=layer_cache: blk(
                             xs_, sin, cos, ms_, deterministic, lc, cache_index, ps_),
                         x, i)
+                elif carry is not None:
+                    y, c, carry = blk(x, sin, cos, attn_mask, deterministic,
+                                      layer_cache, cache_index, position_ids, write_index,
+                                      q_spans, layer_lora, layer_experts, ext_ops,
+                                      seq_shard, carry)
                 else:
                     y, c = blk(x, sin, cos, attn_mask, deterministic,
                                layer_cache, cache_index, position_ids, write_index,
@@ -2096,8 +2529,17 @@ class CausalLMModel:
         if self.cfg.scan_layers:
             return tuple(fill((self.cfg.num_layers, ) + shape, t)
                          for _, shape, t, fill in spec[0])
-        return tuple(tuple(fill(shape, t) for _, shape, t, fill in comp)
-                     for comp in zip(*spec))
+        return self._component_major(spec, lambda kind, shape, t, fill: fill(shape, t))
+
+    @staticmethod
+    def _component_major(spec, make):
+        """The unrolled cache tree's structure from the per-layer
+        declarations: component ``j`` of layer ``i`` at ``tree[j][i]``, None
+        where the layer declares fewer than ``j + 1`` components (None is an
+        empty subtree: no leaf)."""
+        width = max(len(layer) for layer in spec)
+        return tuple(tuple(make(*layer[j]) if j < len(layer) else None for layer in spec)
+                     for j in range(width))
 
     def cache_spec(self, batch_size, max_len, dtype=None, quantized=False):
         """What a slot holds, as each layer declares it: for every layer a
@@ -2107,10 +2549,17 @@ class CausalLMModel:
         ``"state"`` (per-slot, no row axis: a linear-attention layer's
         recurrent state ``(B, n, dk, dv)`` and the ``W - 1`` last inputs of
         its convolution ``(B, 1, W - 1, channels)``, at rest in the cache
-        dtype). Every component keeps its slot axis at ``ndim - 4``.
-        :meth:`init_cache` builds the tree from it; :meth:`cache_kinds`
-        tells the slot pool which leaves are which. Every layer declares the
-        same NUMBER of components (the tree is component-major)."""
+        dtype; a Mamba layer's ``(B, 1, d_state, d_inner)`` and ``(B, 1, W -
+        1, d_inner)``) or ``"ring"`` (a row axis at ``ndim - 2`` of
+        ``cfg.ring_rows`` rows whatever ``max_len`` is, position ``p`` in row
+        ``p mod R``: a windowed differential layer's K and V, per-slot bytes
+        as a state's are). Every component keeps its slot axis at ``ndim -
+        4``. A layer may declare nothing (a gated memory unit reads the
+        forward's carry, a cross-attention layer the rows of the full layer
+        below it). :meth:`init_cache` builds the tree from it;
+        :meth:`cache_kinds` tells the slot pool which leaves are which. The
+        tree is component-major and as wide as the widest declaration; a
+        layer that declares fewer has None there."""
         cfg = self.cfg
         dt = dtype or cfg.dtype
         if cfg.latent_width:
@@ -2130,6 +2579,25 @@ class CausalLMModel:
                 1 if packed else 2)
             if quantized:
                 rows.append(("rows", (batch_size, 1, max_len, 1), jnp.float16, jnp.ones))
+        if cfg.carries_across_layers:
+            if quantized:
+                raise NotImplementedError("a pool with ring rows or rows that layers share "
+                                          "has no int8 tier")
+            pair = lambda kind, n: ((kind, (batch_size, cfg.kv_heads // 2, n,
+                                            2 * cfg.head_size), dt, jnp.zeros), ) * 2
+            ssm = (("state", (batch_size, 1, cfg.ssm_state_size, cfg.ssm_inner), dt, jnp.zeros),
+                   ("state", (batch_size, 1, cfg.ssm_conv_kernel - 1, cfg.ssm_inner), dt,
+                    jnp.zeros))
+
+            def declares(i, kind):
+                if kind == "mamba":
+                    return ssm
+                if kind == "diff_attention":
+                    return (pair("ring", cfg.ring_rows(i)) if cfg.layer_window(i)
+                            else pair("rows", max_len))
+                return ()  # gmu, cross_attention: they read the forward's carry
+
+            return [declares(i, t) for i, t in enumerate(cfg.layer_types)]
         if "linear_attention" not in cfg.layer_types:
             return [tuple(rows)] * cfg.num_layers
         if quantized or len(rows) != 2:
@@ -2142,12 +2610,13 @@ class CausalLMModel:
         return [state if t == "linear_attention" else tuple(rows) for t in cfg.layer_types]
 
     def cache_kinds(self):
-        """``"rows"`` or ``"state"`` for every leaf of :meth:`init_cache`'s
-        tree, in a tree of its structure (sizes do not enter)."""
+        """``"rows"``, ``"ring"`` or ``"state"`` for every leaf of
+        :meth:`init_cache`'s tree, in a tree of its structure (sizes do not
+        enter)."""
         spec = self.cache_spec(1, 1)
         if self.cfg.scan_layers:
             return tuple(kind for kind, *_ in spec[0])
-        return tuple(tuple(kind for kind, *_ in comp) for comp in zip(*spec))
+        return self._component_major(spec, lambda kind, *_: kind)
 
     def apply_with_cache(self, params, input_ids, kv_cache, cache_index, cache_mask=None,
                          position_ids=None, write_index=None, q_spans=None,

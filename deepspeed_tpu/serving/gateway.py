@@ -1409,10 +1409,12 @@ class Gateway:
                           "kv_pool_geometry": getattr(
                               sched, "kv_pool_geometry", None),
                           # what a position costs in rows, and what a slot
-                          # costs in per-slot state whatever its length
-                          # (0 unless the model has linear-attention layers)
+                          # costs in per-slot state and ring rows whatever
+                          # its length (0 unless the model has recurrent or
+                          # windowed layers)
                           "kv_bytes_per_token": sched.cache.bytes_per_token(),
                           "state_bytes_per_slot": sched.cache.state_bytes_per_slot(),
+                          "window_bytes_per_slot": sched.cache.window_bytes_per_slot(),
                           # ... and, for MoE models, by the expert dispatch:
                           # "dense" ones run every expert on every row
                           "moe_dispatch_programs": dict(getattr(

@@ -498,3 +498,42 @@ def test_hybrid_state_step_program(for_chip, step):
     assert mem.temp_size_in_bytes < 1.3e9, mem
     whole = 4 * (mem.argument_size_in_bytes - 2 * 3840 * 100352 * 2) + 2 * 3840 * 100352 * 2
     assert whole + mem.temp_size_in_bytes < 15.75 * 2**30, whole
+
+
+@pytest.mark.parametrize("step", ["decode", "span"])
+def test_sambay_step_program(for_chip, step):
+    """Phi-4-mini-flash-reasoning's sync at the published widths as the chip
+    benchmark serves it (64 slots x 4096, ``prefill_chunk`` 512,
+    ``steps_per_sync`` 4, the whole vocabulary), with one layer of each kind
+    (Mamba, windowed differential attention, Mamba, the full differential
+    layer, a gated memory unit, a cross-attention layer): the one-token update
+    of 64 SSM states and, in the chunk sync, the 512-token scan, the ring
+    attended before its commit and the cross layer reading the full layer's
+    rows, all over the chunk's own slot. The donated pool is updated in
+    place: no whole-leaf copy, scatter or transpose of a state, window, ring
+    or rows leaf stands in the loop or around it; a ring leaf is 512 rows
+    long whatever the pool's length. It fits with its temporaries: the 32
+    layers' weights (7.71 GB) and pool (3.48 GB) are 11.2 GB of the chip's
+    15.75 GiB (all 32 layers compiled once by hand, PR 32: temporaries 0.15
+    GB decode, 0.26 GB chunk; 10.0 GiB in all)."""
+    sds, _ = for_chip
+    slots, chunk, pool_len = 64, 512, 4096
+    base = get_model("phi-4-mini-flash-reasoning")
+    kinds = ("mamba", "diff_attention", "mamba", "diff_attention", "gmu", "cross_attention")
+    model = type(base)(dataclasses.replace(
+        base.cfg, dtype=jnp.bfloat16, num_layers=6, layer_types=kinds,
+        layer_windows=(0, 512, 0, 0, 0, 0), max_seq_len=pool_len, attention_impl="flash"))
+    compiled, pool, around = _compile_sync(
+        sds, model, slots, 1 if step == "decode" else chunk, pool_len)
+    shapes = [leaf.shape for leaf in jax.tree_util.tree_leaves(pool)]
+    assert sorted(set(shapes)) == [(slots, 1, 3, 5120), (slots, 1, 16, 5120),
+                                   (slots, 10, 512, 128), (slots, 10, pool_len, 128)]
+    assert around == 0
+    text = compiled.as_text()
+    for shape in set(shapes):
+        assert _pool_relayouts(text, "[" + ",".join(map(str, shape)) + "]") == (0, 0), shape
+    assert "dstpu_decode_attn" in text and "dstpu_kv_commit" in text
+    mem = compiled.memory_analysis()
+    print(step, "temporaries", mem.temp_size_in_bytes)
+    assert mem.temp_size_in_bytes < 2.0e9, mem
+    assert 7.71e9 + 3.48e9 + mem.temp_size_in_bytes < 15.75 * 2**30
