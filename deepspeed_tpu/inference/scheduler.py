@@ -61,6 +61,36 @@ granularity (K=1 recovers pure iteration-level scheduling; results are
 identical for any K). EOS detection, admission, and eviction are host-side
 bookkeeping on the fetched block.
 
+**The pump is one sync deep**: an iteration LAUNCHES sync N+1 (admit,
+assemble, dispatch) and only then LANDS sync N (fetch its token block,
+deliver it), so the device runs N+1 the moment N ends while the host
+delivers N's tokens, calls the ``on_token`` hooks, admits and assembles N+2.
+JAX dispatch is asynchronous already: the pool that N returns is handed to
+N+1 as a future. The host's view at a launch is the state after everything
+LAUNCHED: ``cache.lengths`` as a whole counts the rows written by every
+launched sync, a request's ``inflight`` its tokens no landing has delivered
+yet (its sampling step is ``len(out) + inflight``; a row whose budget ends
+in flight is left out of the next launch; the prefill lane advances, and a
+final chunk's row is booked as a decode row and its prompt registered in the
+trie, when that chunk is launched). The one input the host lacks, each
+decoding row's last token, stays on the device: a tiny jitted merge
+(:func:`_merge_carried`) writes the last row of N's token block into column
+0 of N+1's ids for the rows flagged as carrying, outside the step programs.
+What a launch cannot know is an EOS or a cancellation inside N: N+1 then
+computes that row once more and its landing drops it (counted:
+``serving/ahead_rows_discarded``); the slot is released when N lands, one
+sync later than behind a serial pump, and what N+1 wrote there lies past the
+row's final length (a state leaf is reset at the next admission) and is
+ordered before any later program by the device's queue. Where the next
+sync's inputs need the last one's results ON THE HOST the pump lands before
+it launches, by what it can observe and with no setting
+(:meth:`DecodeScheduler._lands_first`): a drafter, cold-expert offload,
+parked or chained rows, a capacity-sampled fence, a migrate hook on a final
+chunk, a row flagged for cancellation. ``pause`` / ``flush`` / ``drain`` /
+``swap_weights`` / ``migrate_out`` / ``admit_migration`` land what is in
+flight first. Counters ``serving/syncs_ahead`` (launched with the previous
+sync unlanded) and ``serving/syncs_serial``.
+
 **Self-speculative k-token decoding** (Leviathan et al. / prompt-lookup
 drafting, ``spec_tokens > 0``): each pure-decode sync first asks a host-side
 :class:`~deepspeed_tpu.inference.speculative.PromptLookupDrafter` for up to
@@ -318,7 +348,7 @@ class _Request:
                  "temperature", "top_k", "top_p", "seed", "slot", "out", "logits",
                  "done", "cancelled", "submit_ts", "first_token_ts", "collect_logits",
                  "on_token", "trace", "adapter_id", "adapter_ref", "handle",
-                 "migrating", "error", "kv_window", "row_budget", "choice")
+                 "migrating", "error", "kv_window", "row_budget", "choice", "inflight")
 
     def __init__(self, rid, prompt, max_new_tokens, eos_token_id, do_sample,
                  temperature, top_k, top_p, seed, collect_logits, submit_ts,
@@ -337,6 +367,11 @@ class _Request:
         self.collect_logits = bool(collect_logits)
         self.slot = None
         self.out = []      # generated token ids (host ints)
+        # tokens of this request that a launched sync computes and no landing
+        # has delivered yet: THE place that says how far the device is ahead of
+        # ``out`` (the row's sampling step, whether its budget ends in flight
+        # and whether its next id is still on the device all derive from it)
+        self.inflight = 0
         self.logits = []   # per-step (V,) logits when collect_logits
         self.choice = []   # (L, columns, k) expert ids per forward (MoE, collect_logits)
         self.done = False
@@ -438,6 +473,45 @@ class _PrefillState:
         # run at the seq-parallel chunk width (sharded over the seq mesh
         # axis when it has more than one device)
         self.seq_parallel = False
+
+
+class _Flight:
+    """A sync that was launched and has not landed: what the landing needs
+    to fetch its block and deliver it. ``out`` is the step program's result
+    behind the pool (tokens, then logits, routing choice and MoE stats where
+    the program returns them), still on the device; ``rows`` the decode rows
+    it advances ``K`` tokens each; ``chunk`` the prefill row's ``(request,
+    pos, take, final)`` on a chunk sync; ``t0`` when its iteration began."""
+
+    __slots__ = ("out", "K", "collect", "rows", "chunk", "t0")
+
+    def __init__(self, out, K, collect, rows, chunk=None):
+        self.out = out
+        self.K = K
+        self.collect = collect
+        self.rows = rows
+        self.chunk = chunk
+        self.t0 = 0.0
+
+    @property
+    def final(self):
+        """Whether this sync carries a prompt's last chunk."""
+        return self.chunk is not None and self.chunk[3]
+
+
+# the id of a row whose last token is still on the device: column 0 of the
+# host's ids block says so with this, and :func:`_merge_carried` fills it in
+_CARRIED = -1
+
+
+def _merge_carried(ids, toks):
+    """The ids block of a sync launched ahead: a row flagged ``_CARRIED`` in
+    column 0 takes its id from the last row of ``toks``, the (K, num_slots)
+    token block of the sync in flight; prompt tokens and the ids the host
+    knew stay as they are. Outside the step programs, which take the same
+    operands as when the host fed every id."""
+    col = ids[:, 0]
+    return ids.at[:, 0].set(jnp.where(col == _CARRIED, toks[-1], col))
 
 
 class DecodeScheduler:
@@ -758,6 +832,24 @@ class DecodeScheduler:
         self._rid = 0
         self._steps = 0
         self._choice = None  # the last fetched block's routing choice (MoE, collecting)
+        # the pump is one sync deep: the sync launched and not yet landed
+        # (:class:`_Flight`), or None. Its depth at each sync follows from
+        # state the scheduler can see (:meth:`_lands_first`); there is no
+        # setting.
+        self._flight = None
+        # where every ids block is placed: a token block that is still on the
+        # device is a committed array and so is whatever is merged from it, and
+        # JAX lowers a committed operand with its sharding and an uncommitted
+        # one without. So that a step program is built once, whether its ids
+        # came from the host alone or through the merge, all of them are
+        # committed, replicated over the engine's mesh
+        from jax.sharding import NamedSharding, PartitionSpec
+        self._ids_sharding = NamedSharding(engine.mesh, PartitionSpec())
+        self._merge = jax.jit(_merge_carried, out_shardings=self._ids_sharding)
+        self._landed_ts = 0.0  # sink clock at the last landing
+        self.syncs_ahead = 0   # launched while the previous sync was unlanded
+        self.syncs_serial = 0  # launched (or run whole) with nothing in flight
+        self.ahead_rows_discarded = 0  # rows landed for a request that had ended
         # weight-swap protocol (RLHF hybrid engine): pause gates ADMISSION
         # only — in-flight rows keep decoding under the weights that
         # prefilled them until flush() drains the pool
@@ -806,7 +898,8 @@ class DecodeScheduler:
                 peak_hbm_bw=accel.peak_hbm_bandwidth(),
                 n_devices=n_dev,
                 sample_every=getattr(self.telemetry, "capacity_sample_every", 32))
-            self._gap = HostGapTracker(self.telemetry)
+            self._gap = HostGapTracker(self.telemetry,
+                                       unlanded=lambda: self._flight is not None)
             # the KV tier's HBM price tag: int8 should show ~half the bytes
             # per resident token of an "auto" bf16 pool
             self.telemetry.gauges([
@@ -937,9 +1030,26 @@ class DecodeScheduler:
         return handle
 
     def drain(self):
-        """Run until every queued/active request finishes."""
-        while self.queue or self.active or self._prefill is not None:
+        """Run until every queued/active request finishes and the last sync
+        has landed."""
+        while (self.queue or self.active or self._prefill is not None
+               or self._flight is not None):
             self.step()
+
+    @property
+    def in_flight(self):
+        """Whether a sync was launched and has not landed: work, to whoever
+        asks if this scheduler is idle."""
+        return self._flight is not None
+
+    def land_in_flight(self):
+        """Land the sync in flight, if any, outside :meth:`step`: what
+        everything that reads or moves a request's landed state does first
+        (pause, swap, migration). Returns the tokens delivered. Pump thread
+        only, like :meth:`step`."""
+        if self._flight is None:
+            return 0
+        return self._land(self._take_flight())
 
     @property
     def num_slots(self):
@@ -966,8 +1076,9 @@ class DecodeScheduler:
     # an argument, and the new tree has the same treedef/shapes/dtypes).
     def pause(self):
         """Stop admitting new work (queued requests stay queued; in-flight
-        rows keep decoding). Idempotent."""
+        rows keep decoding) and land the sync in flight. Idempotent."""
         self._paused = True
+        self.land_in_flight()
 
     def resume(self):
         """Re-open admission after a swap. Idempotent."""
@@ -977,8 +1088,10 @@ class DecodeScheduler:
         """Drive the loop until nothing is in flight (active rows and any
         mid-prefill row run to completion under the CURRENT weights). With
         admission paused this terminates even when requests are queued —
-        they stay parked for the post-swap weights."""
-        while self.active or self._prefill is not None:
+        they stay parked for the post-swap weights. A launched sync counts:
+        when this returns the last one has landed."""
+        while (self.active or self._prefill is not None
+               or self._flight is not None):
             self.step()
 
     def swap_weights(self, params, version=None):
@@ -1001,6 +1114,7 @@ class DecodeScheduler:
                 "unsupported: the expert kernels live in the paged store, "
                 "not the param tree, so a tree swap would serve mixed "
                 "weights — rebuild the engine to change MoE weights")
+        self.land_in_flight()  # a sync of discarded rows may still be out
         if self.active or self._prefill is not None:
             raise ValueError(
                 f"swap_weights with {len(self.active)} active slots"
@@ -1035,6 +1149,10 @@ class DecodeScheduler:
         gather the same resident pages. ``on_ready(entry_or_None)`` fires
         once the handoff entry is claimable."""
         self._refuse_state_migration()
+        # the handoff moves the row's LANDED state. From the migrate hook
+        # nothing is in flight (:meth:`_lands_first`); a brownout park lands
+        # first itself, before it looks at the request
+        self.land_in_flight()
         slot = req.slot
         kv_len = int(self.cache.lengths[slot])
         # demote FIRST, release AFTER: the compiled slice's output owns
@@ -1103,6 +1221,7 @@ class DecodeScheduler:
         FIRST and then re-raises, so the pump's sick-replica handling
         runs without stranding a request that no scheduler owns."""
         self._refuse_state_migration()
+        self.land_in_flight()
         req = record.req
         tel = self.telemetry
         if req.cancelled or record.entry is None:
@@ -1188,9 +1307,17 @@ class DecodeScheduler:
 
     # ------------------------------------------------------------------ loop
     def step(self):
-        """One scheduler iteration: settle cancellations, admit (at most one
-        in-flight prefill), then advance — one fused chunk+decode step while
-        a prefill is in flight, else ``steps_per_sync`` decode steps.
+        """One scheduler iteration of a pump that is one sync deep: settle
+        cancellations, admit (at most one in-flight prefill), LAUNCH the next
+        sync (one fused chunk+decode step while a prefill is in flight, else
+        ``steps_per_sync`` decode steps) from the state after everything
+        launched so far, and only then LAND the sync launched before it:
+        fetch its token block and deliver it. The device runs sync N+1 the
+        moment N ends, while the host delivers N's tokens and assembles N+2.
+        Where the next sync's inputs need the previous one's results on the
+        host (:meth:`_lands_first`) the iteration lands before it launches
+        and lands what it launched before it returns, which is the order of
+        a serial pump. Returns the tokens delivered.
 
         The iteration is the ``sched/step`` span; inside it ``sched/admit``,
         ``sched/assemble``, ``sched/dispatch``, ``sched/fetch`` and
@@ -1263,10 +1390,32 @@ class DecodeScheduler:
             return 1
         return 0
 
+    def _lands_first(self):
+        """Whether the next sync's inputs need the previous one's tokens or
+        counts on the host, so that the pump lands before it launches: a
+        drafter reads the accepted tokens, cold-expert offload replays on
+        the routing counts (and backs off on an overflow), parked or chained
+        rows are paged by their landed lengths, a capacity-sampled dispatch
+        is fenced, a final chunk's row may be handed to the migrate hook
+        the moment its tokens are out, and a row flagged for cancellation
+        gets the tokens already computed for it before it is reaped (as
+        behind a serial pump, where a sync lands in the step that launched
+        it). All of it state the scheduler can see; everything else
+        launches ahead."""
+        fl = self._flight
+        return (self.drafter is not None or self.experts is not None
+                or bool(self._parked) or bool(self.cache.chain) or self._cap_sample
+                or (self.migrate_hook is not None and fl is not None and fl.final)
+                or any(r.cancelled and r.inflight for r in self.active.values()))
+
+    def _take_flight(self):
+        fl, self._flight = self._flight, None
+        return fl
+
     def _iterate(self):
         """The body of :meth:`step`. Returns (tokens delivered, the kind of
-        sync that ran: "fused", "spec", "decode", or None when nothing
-        could run)."""
+        sync that was launched or, with nothing to launch, landed: "fused",
+        "spec", "decode", or None when nothing could run)."""
         tel = self.telemetry
         t0 = tel.now()
         # sampled fenced-timing window (telemetry/capacity.py): every Nth
@@ -1277,13 +1426,24 @@ class DecodeScheduler:
         if cap is not None:
             self._sync_seq += 1
             self._cap_sample = cap.should_sample(self._sync_seq)
+        delivered = 0
+        kind = None
+        if self._flight is not None:
+            kind = "fused" if self._flight.chunk is not None else "decode"
+            if self._lands_first():
+                delivered += self._land(self._take_flight())
         with self._span("sched/admit"):
             admitted = self._admit_queued()
         if admitted and tel.enabled:
             tel.counter("serving/admitted", admitted)
+        # a step method returns the _Flight it launched (which reads what it
+        # needs of the sync still in ``self._flight``), the (delivered,
+        # ksteps) of a path that landed its own dispatches (verify, backoff),
+        # or None when no row has anything left to run
+        ran = None
         if self._prefill is not None:
             kind = "fused"
-            delivered, ksteps = self._fused_chunk_step()
+            ran = self._fused_chunk_step()
         elif self.active:
             if self._parked and all(s in self._parked for s in self.active):
                 # nothing can dispatch and nothing can ever free a row:
@@ -1295,40 +1455,88 @@ class DecodeScheduler:
                     "to restore into — demote fewer extents or leave slot "
                     "headroom")
             if self.drafter is not None:
-                kind = "spec"
-                delivered, ksteps = self._spec_decode_step()
+                ran = self._spec_decode_step()
+                kind = "spec" if ran is not None else kind
             else:
-                kind = "decode"
-                delivered, ksteps = self._decode_step()
-        else:
-            return 0, None
-        self._iter += 1
-        if tel.enabled:
-            dur_ms = (tel.now() - t0) * 1e3
-            tel.counter("serving/decode_steps", ksteps)
-            tel.counter("serving/decode_tokens", delivered)
-            tel.histogram("serving/step_ms", dur_ms / ksteps)
-            tel.histogram("serving/tokens_per_step", delivered / ksteps)
-            if self._state_pool:
-                tel.gauge("serving/state_slots_live", self.cache.active_slots)
-            tel.gauges([("serving/slot_occupancy", self.cache.occupancy(), None),
-                        ("serving/batch_efficiency",
-                         delivered / (ksteps * self.cache.num_slots), None),
-                        ("serving/kv_token_utilization", self.cache.token_utilization(),
-                         None),
-                        ("serving/kv_bytes_live", self.cache.live_bytes(), None)])
-            if cap is not None:
-                # goodput: tokens delivered vs computed-then-discarded.
-                # Speculative rejected columns fold in here (as the delta
-                # of drafted - accepted this sync); MoE miss replays and
-                # migration/restore traffic account at their own sites.
-                rejected = ((self.spec_drafted - self.spec_accepted)
-                            - self._goodput_spec_seen)
-                self._goodput_spec_seen += rejected
-                live_lens = [self.cache.lengths[s] for s in self.active]
-                ctx = (sum(live_lens) / len(live_lens)) if live_lens else 0.0
-                cap.account(delivered, wasted_tokens=max(0, rejected), ctx=ctx)
+                ran = self._decode_step()
+                kind = "decode" if ran is not None else kind
+        prev = self._take_flight()
+        if ran is not None:
+            ahead = prev is not None
+            self.syncs_ahead += ahead
+            self.syncs_serial += not ahead
+            if tel.enabled:
+                tel.counter("serving/syncs_ahead" if ahead else "serving/syncs_serial")
+            if isinstance(ran, _Flight):
+                ran.t0 = t0
+                self._flight = ran
+            else:
+                delivered += ran[0]
+                self._observe(ran[0], ran[1], t0)
+        if prev is not None:
+            delivered += self._land(prev)
+        elif self._flight is not None and self._lands_first():
+            delivered += self._land(self._take_flight())
+        if kind is not None:
+            self._iter += 1
         return delivered, kind
+
+    def _land(self, fl):
+        """Land a launched sync: fetch its block (``sched/fetch``), deliver
+        the decode rows' tokens and the chunk's (``sched/deliver``). A row
+        whose request ended while the sync was in flight (an EOS or a
+        cancellation the launch could not know of) computed once more for
+        nothing: its tokens are dropped and counted."""
+        toks_k, logits_k = self._fetch_block(fl.out, fl.collect, fl.K)
+        if fl.chunk is not None:
+            preq, pos, take, final = fl.chunk
+            tr = preq.trace
+            if tr is not None and tr.enabled:
+                fid = self._trace_link(tr)
+                tr.phase("prefill_chunk", start=fl.t0,
+                         flow_in=[fid] if fid else None,
+                         pos=int(pos), take=int(take), final=bool(final))
+        delivered = self._deliver_block(fl.rows, toks_k, logits_k, fl.K)
+        if fl.chunk is not None:
+            delivered += self._deliver_chunk(fl, toks_k, logits_k)
+        self._observe(delivered, fl.K, fl.t0)
+        return delivered
+
+    def _observe(self, delivered, ksteps, since):
+        """Per-sync telemetry, at its landing. ``serving/step_ms`` is the
+        wall time a sync took of the pump over its steps: from the landing
+        before it (or from ``since``, its own iteration's start, where the
+        pump was idle or serial) to this one."""
+        tel = self.telemetry
+        if not tel.enabled:
+            return
+        now = tel.now()
+        dur_ms = (now - max(since, self._landed_ts)) * 1e3
+        self._landed_ts = now
+        tel.counter("serving/decode_steps", ksteps)
+        tel.counter("serving/decode_tokens", delivered)
+        tel.histogram("serving/step_ms", dur_ms / ksteps)
+        tel.histogram("serving/tokens_per_step", delivered / ksteps)
+        if self._state_pool:
+            tel.gauge("serving/state_slots_live", self.cache.active_slots)
+        tel.gauges([("serving/slot_occupancy", self.cache.occupancy(), None),
+                    ("serving/batch_efficiency",
+                     delivered / (ksteps * self.cache.num_slots), None),
+                    ("serving/kv_token_utilization", self.cache.token_utilization(),
+                     None),
+                    ("serving/kv_bytes_live", self.cache.live_bytes(), None)])
+        cap = self.capacity
+        if cap is not None:
+            # goodput: tokens delivered vs computed-then-discarded.
+            # Speculative rejected columns fold in here (as the delta
+            # of drafted - accepted this sync); MoE miss replays and
+            # migration/restore traffic account at their own sites.
+            rejected = ((self.spec_drafted - self.spec_accepted)
+                        - self._goodput_spec_seen)
+            self._goodput_spec_seen += rejected
+            live_lens = [self.cache.lengths[s] for s in self.active]
+            ctx = (sum(live_lens) / len(live_lens)) if live_lens else 0.0
+            cap.account(delivered, wasted_tokens=max(0, rejected), ctx=ctx)
 
     def _trace_link(self, trace):
         """Mint a flow id binding a request phase to the sync currently in
@@ -1472,6 +1680,7 @@ class DecodeScheduler:
         bit-identical. A lossy request (``kv_window``) drops the rows
         outright — its sliding-window mask already hides every position
         they held. Returns the number of extents demoted."""
+        self.land_in_flight()  # the row is paged by its landed length
         req = self.active.get(slot)
         if req is None:
             raise ValueError(f"slot {slot} is not a live decode row")
@@ -1760,11 +1969,15 @@ class DecodeScheduler:
                 tel.counter("serving/seq_parallel_prefills")
         self._prefill = pf
 
-    def _finish_prefill(self, req, tok, last_logits):
-        """The final chunk landed: deliver token 0, register the prompt in
-        the radix trie (live prefixes serve as donors too — prefill rows are
-        never rewritten during decode), and move the row to decode."""
-        tel = self.telemetry
+    def _book_decode_row(self, req):
+        """A prompt's final chunk was LAUNCHED: the prefill lane is free for
+        the next queued prompt and the row decodes from the next launch on
+        (its first id is the token this sync samples, carried on the device).
+        The prompt registers in the radix trie now (live prefixes serve as
+        donors too — prefill rows are never rewritten during decode): its
+        rows are written by a program already queued, and whatever copies
+        them is queued behind it, so a twin prompt admitted the very next
+        iteration finds its donor as it would behind a serial pump."""
         self._prefill = None
         self.active[req.slot] = req
         if self.radix is not None and req.slot not in self.cache.chain:
@@ -1779,6 +1992,13 @@ class DecodeScheduler:
                 ns = self.adapters.namespace(akey) if akey is not None else ()
                 self.kv_tier.discard_exact(req.prompt, namespace=ns)
             self.radix.insert(req.slot, req.prompt, adapter=akey)
+
+    def _first_token(self, req, tok, last_logits):
+        """The final chunk LANDED: stamp the first token's time and deliver
+        token 0."""
+        if req.done:  # cancelled while its final chunk was in flight
+            return
+        tel = self.telemetry
         req.first_token_ts = tel.now()
         if tel.enabled:
             tel.histogram("serving/ttft_ms", (req.first_token_ts - req.submit_ts) * 1e3)
@@ -1875,7 +2095,7 @@ class DecodeScheduler:
         collect = False
         for slot, req in live:
             seeds[slot] = req.seed
-            steps[slot] = len(req.out)  # prefill consumed step 0
+            steps[slot] = len(req.out) + req.inflight  # prefill consumed step 0
             flags[slot] = req.do_sample
             temps[slot] = req.temperature
             topks[slot] = req.top_k
@@ -1884,13 +2104,45 @@ class DecodeScheduler:
             collect = collect or req.collect_logits
         return seeds, steps, flags, temps, topks, topps, sampling, collect
 
+    def _live_rows(self):
+        """The decode rows of the next launch, with the ids block's column
+        0 for them: active, not parked, and with budget left once the
+        tokens in flight have landed (a row that reaches ``max_new_tokens``
+        inside the sync in flight is known to end there; it stays in
+        ``active`` until that sync lands and releases its slot). A row with
+        tokens in flight has its last token on the device
+        (``_CARRIED``: :func:`_merge_carried`); the others' the host knows."""
+        live = [(s, r) for s, r in sorted(self.active.items())
+                if s not in self._parked and len(r.out) + r.inflight < r.max_new_tokens]
+        return live, [(_CARRIED if r.inflight else r.out[-1]) for _, r in live]
+
+    def _device_ids(self, ids):
+        """The host's ids block on the device, its carried rows filled in
+        from the token block of the sync in flight."""
+        dev = jax.device_put(ids, self._ids_sharding)
+        if self._flight is not None and (ids[:, 0] == _CARRIED).any():
+            dev = self._merge(dev, self._flight.out[0])
+        return dev
+
+    def _advance(self, rows, K):
+        """A sync that runs ``rows`` for ``K`` steps was launched: the host's
+        view moves to the state after it. ``cache.lengths`` as a whole means
+        the rows written by everything launched, not by everything landed."""
+        for slot, req in rows:
+            self.cache.lengths[slot] += K
+            req.inflight += K
+
     def _fetch_block(self, out, collect, K):
-        """Unpack a compiled step program's result: replace the pool, fetch
-        the (K, num_slots) token block (+ logits when collected)."""
-        # the device_get is the sync fence: when sched/fetch closes the
-        # device is idle, until the next sched/dispatch opens
+        """Fetch a compiled step program's result behind the pool (which
+        the launch already handed on): the (K, num_slots) token block (+
+        logits when collected, the routing choice, the MoE stats)."""
+        # the device_get waits for THIS sync. With the next one launched
+        # already the device has work queued when sched/fetch closes; where
+        # the pump is serial the device is idle from then until the next
+        # sched/dispatch opens
         with self._span("sched/fetch"):
-            self.cache.pool, toks_k, *rest = out
+            toks_k, *rest = out
+            self._pop_expert_stats(rest)
             # (K, N, V); MoE programs add their routing choice behind it
             logits_k = np.asarray(jax.device_get(rest[0]), np.float32) if collect else None
             self._choice = (tuple(np.asarray(x) for x in jax.device_get(rest[1:]))
@@ -1898,6 +2150,14 @@ class DecodeScheduler:
             toks_k = np.asarray(jax.device_get(toks_k)).reshape(K, self.cache.num_slots)
         self._steps += K
         return toks_k, logits_k
+
+    def _pop_expert_stats(self, rest):
+        """Where a step program's MoE stats ride its result to the landing
+        (stats on, no offload: nothing at launch reads the routing counts, and
+        :meth:`_call_step` strips them itself under offload): fetch, record
+        and strip them from ``rest``, the outputs behind the token block."""
+        if self._moe_stats and self.experts is None:
+            self._record_expert_stats(np.asarray(jax.device_get(rest.pop())))
 
     def _keep_choice(self, req, slot, width, K):
         """Keep, for a request that collects logits, the expert ids the block
@@ -1909,14 +2169,19 @@ class DecodeScheduler:
             req.choice.extend(substeps[j][:, slot][:, None] for j in range(1, K))
 
     def _deliver_block(self, live, toks_k, logits_k, K):
-        """Deliver a fetched K-step token block to the live rows. Each row's
-        KV advanced K positions on device (the program wrote rows
-        [len, len+K)); tokens past EOS/budget were computed but are
-        discarded. Returns tokens delivered."""
+        """Deliver a fetched K-step token block to the rows it was launched
+        for. Each row's KV advanced K positions on device (the program wrote
+        rows [len, len+K)); tokens past EOS/budget were computed but are
+        discarded, and so is the whole row of a request that ended while the
+        sync was in flight. Returns tokens delivered."""
         n_delivered = 0
+        discarded = 0
         with self._span("sched/deliver"):
             for slot, req in live:
-                self.cache.lengths[slot] += K
+                req.inflight -= K
+                if req.done:
+                    discarded += 1
+                    continue
                 self._keep_choice(req, slot, 1, K)
                 for k in range(K):
                     if req.done:
@@ -1925,7 +2190,56 @@ class DecodeScheduler:
                         req.logits.append(logits_k[k, slot])
                     self._deliver(req, int(toks_k[k, slot]))
                     n_delivered += 1
+        self._count_discarded(discarded)
         return n_delivered
+
+    def _count_discarded(self, rows):
+        """Rows a landing dropped because their request had ended while the
+        sync was in flight."""
+        if rows:
+            self.ahead_rows_discarded += rows
+            if self.telemetry.enabled:
+                self.telemetry.counter("serving/ahead_rows_discarded", rows)
+
+    def _deliver_chunk(self, fl, toks_k, logits_k):
+        """The chunk's part of a landing. A final chunk's row: token 0, then
+        the K-1 tokens its substeps decoded; then, with a migrate hook and
+        budget left, the handoff. Returns tokens delivered."""
+        preq, _, take, final = fl.chunk
+        K = fl.K
+        if final:
+            preq.inflight -= K
+        if preq.done:  # cancelled while the chunk was in flight
+            self._count_discarded(1)
+            return 0
+        ps = preq.slot
+        self._keep_choice(preq, ps, take, K if final else 1)
+        if not final:
+            return 0
+        collects = preq.collect_logits and logits_k is not None
+        with self._span("sched/deliver"):
+            self._first_token(preq, int(toks_k[0, ps]), logits_k[0, ps] if collects else None)
+            delivered = 1
+            for k in range(1, K):
+                if preq.done:
+                    break
+                if collects:
+                    preq.logits.append(logits_k[k, ps])
+                self._deliver(preq, int(toks_k[k, ps]))
+                delivered += 1
+        # disaggregated serving: a prefill-role replica hands the
+        # freshly-prefilled request to a decode replica here — after
+        # this sync's tokens streamed (they were computed anyway), with
+        # budget left, via the hook the ReplicaSet installed. The hook
+        # runs migrate_out; decode then resumes elsewhere from the
+        # exact per-row state this sync left behind, so the stream is
+        # bit-identical to staying put. Nothing was launched past this
+        # sync (:meth:`_lands_first`). Multi-extent chains and lossy-window
+        # rows stay put: the handoff demotes/restores one contiguous slot.
+        if (not preq.done and self.migrate_hook is not None
+                and ps not in self.cache.chain and preq.kv_window is None):
+            self.migrate_hook(self, preq)  # True: migrated out, owned elsewhere
+        return delivered
 
     def _dispatch(self, fn, call_args, step_args, spans):
         """Hand ONE compiled program to the device, under ``sched/dispatch``
@@ -2047,9 +2361,10 @@ class DecodeScheduler:
 
         - dense models (or MoE with telemetry off and no offload): a plain
           dispatch, byte-identical to the pre-MoE scheduler;
-        - MoE with stats: the program's trailing per-layer expert-counts
-          output is fetched, recorded, and STRIPPED, so callers unpack the
-          same (pool, tokens[, logits]) shape either way;
+        - MoE with stats and no offload: the program's trailing per-layer
+          expert-counts output rides the result to the landing, where
+          :meth:`_fetch_block` fetches, records and strips it (nothing at
+          launch reads it, and fetching it here would fence the launch);
         - cold-expert offload: dispatch against a consistent residency
           snapshot, diff the routed experts against it, and on a miss
           hot-load the wanted pages and RE-DISPATCH the same program with
@@ -2062,12 +2377,8 @@ class DecodeScheduler:
         pool — the caller backs off to a smaller step.
         """
         extra = (lora, ) if lora is not None else ()
-        if not self._moe_stats:
-            return self._dispatch(fn, args + extra, args, spans)
         if self.experts is None:
-            out = self._dispatch(fn, args + extra, args, spans)
-            self._record_expert_stats(np.asarray(jax.device_get(out[-1])))
-            return out[:-1]
+            return self._dispatch(fn, args + extra, args, spans)
         replays = 0
         # hard bound on the replay loop: each round loads at least one page
         # on this replica, so L*E rounds can only be exceeded by pathological
@@ -2175,7 +2486,7 @@ class DecodeScheduler:
                     eo = self._ext_operands(group)
                     fn = self._fused_fn(sampling, collect, 1, 1, lora=lora is not None,
                                         ext=eo is not None)
-                    args = (eng.params, self.cache.pool, jnp.asarray(ids),
+                    args = (eng.params, self.cache.pool, self._device_ids(ids),
                             jnp.asarray(lens), jnp.asarray(spans),
                             jnp.asarray(seeds), jnp.asarray(steps), jnp.asarray(flags),
                             jnp.asarray(temps), jnp.asarray(topks), jnp.asarray(topps))
@@ -2193,7 +2504,9 @@ class DecodeScheduler:
                             "resident_experts >= moe_top_k (validated at "
                             "build); this is a bug")
                     group = group[:(len(group) + 1) // 2]
-            toks_k, logits_k = self._fetch_block(out, collect, 1)
+            self.cache.pool = out[0]
+            self._advance(group, 1)
+            toks_k, logits_k = self._fetch_block(out[1:], collect, 1)
             delivered += self._deliver_block(group, toks_k, logits_k, 1)
             done = {slot for slot, _ in group}
             pending = [(s, r) for (s, r) in pending if s not in done]
@@ -2242,7 +2555,7 @@ class DecodeScheduler:
                 eo = self._ext_operands([(ps, preq)])
                 fn = self._fused_fn(preq.do_sample, preq.collect_logits, 1, C,
                                     lora=lora is not None, ext=eo is not None)
-                args = (eng.params, self.cache.pool, jnp.asarray(ids),
+                args = (eng.params, self.cache.pool, self._device_ids(ids),
                         jnp.asarray(lens), jnp.asarray(spans),
                         jnp.asarray(seeds), jnp.asarray(steps), jnp.asarray(flags),
                         jnp.asarray(temps), jnp.asarray(topks), jnp.asarray(topps))
@@ -2260,22 +2573,17 @@ class DecodeScheduler:
                             "resident_experts >= moe_top_k (validated at "
                             "build); this is a bug")
                     take = (take + 1) // 2
-            toks_k, logits_k = self._fetch_block(out, preq.collect_logits, 1)
+            self.cache.pool = out[0]
+            piece = _Flight(out[1:], 1, preq.collect_logits, [],
+                            (preq, pf.pos, take, pf.pos + take >= L))
             pf.pos += take
-            if pf.pos >= L:
-                self.cache.lengths[ps] = L  # single-step: no substep rows
-                self._finish_prefill(
-                    preq, int(toks_k[0, ps]),
-                    logits_k[0, ps] if (preq.collect_logits and logits_k is not None)
-                    else None)
-                delivered += 1
-                if (not preq.done and self.migrate_hook is not None
-                        and ps not in self.cache.chain
-                        and preq.kv_window is None
-                        and self.migrate_hook(self, preq)):
-                    pass  # migrated out (see _fused_chunk_step)
-            else:
-                self.cache.lengths[ps] = pf.pos
+            # single-step: no substep rows past the chunk's
+            self.cache.lengths[ps] = pf.pos
+            if piece.final:
+                preq.inflight += 1
+                self._book_decode_row(preq)
+            toks_k, logits_k = self._fetch_block(piece.out, piece.collect, 1)
+            delivered += self._deliver_chunk(piece, toks_k, logits_k)
         if live:
             delivered += self._decode_backoff(live)
         return delivered, 1
@@ -2308,7 +2616,7 @@ class DecodeScheduler:
 
         def dispatch(fn, width, lora, ext_args=()):
             args = (self.engine.params, self.cache.pool,
-                    jnp.asarray(np.zeros((N, width), np.int32)),
+                    self._device_ids(np.zeros((N, width), np.int32)),
                     jnp.asarray(zeros), jnp.asarray(zeros),
                     jnp.asarray(np.zeros(N, np.uint32)), jnp.asarray(zeros),
                     jnp.asarray(np.zeros(N, bool)),
@@ -2368,24 +2676,32 @@ class DecodeScheduler:
                 self.cache.pool = self._copy_fn()(
                     self.cache.pool, jnp.asarray(0, jnp.int32),
                     jnp.asarray(0, jnp.int32))
+        # the carried-token merge of a sync launched ahead, at every ids
+        # width such a sync can have, over a K-step token block
+        toks = jax.device_put(np.zeros((K, N), np.int32), self._ids_sharding)
+        for width in sorted({1, C} | ({self._seq_chunk} if self._seq_chunk else set())):
+            self._merge(self._device_ids(np.zeros((N, width), np.int32)), toks)
 
     def _decode_step(self):
-        """A pure decode sync: the fused program at chunk width 1 (every
-        live row span 1, no prefill row) — ONE on-device step body serves
-        both paths, so fused-vs-decode results can never diverge. Dead and
-        cached rows carry span 0 and length 0: their writes are dropped and
-        the paged kernel's KV-block walk stays bounded by the longest LIVE
-        row, not the longest retained prefix."""
+        """Launch a pure decode sync: the fused program at chunk width 1
+        (every live row span 1, no prefill row) — ONE on-device step body
+        serves both paths, so fused-vs-decode results can never diverge. Dead
+        and cached rows carry span 0 and length 0: their writes are dropped
+        and the paged kernel's KV-block walk stays bounded by the longest LIVE
+        row, not the longest retained prefix. Returns the :class:`_Flight`,
+        None when every active row ends inside the sync in flight, or
+        (delivered, 1) where cold-expert pressure made it back off."""
         eng = self.engine
         N = self.cache.num_slots
         with self._span("sched/assemble"):
-            live = [(s, r) for s, r in sorted(self.active.items())
-                    if s not in self._parked]
+            live, col0 = self._live_rows()
+            if not live:
+                return None
             ids = np.zeros((N, 1), np.int32)
             spans = np.zeros(N, np.int32)
             lens = np.zeros(N, np.int32)
-            for slot, req in live:
-                ids[slot, 0] = req.out[-1]
+            for (slot, req), tok in zip(live, col0):
+                ids[slot, 0] = tok
                 spans[slot] = 1
                 lens[slot] = self.cache.lengths[slot]
             (seeds, steps, flags, temps, topks, topps, sampling,
@@ -2402,7 +2718,7 @@ class DecodeScheduler:
             lora = self._adapter_arg(live)
             fn = self._fused_fn(sampling, collect, K, 1, lora=lora is not None,
                                 ext=eo is not None)
-            args = (eng.params, self.cache.pool, jnp.asarray(ids),
+            args = (eng.params, self.cache.pool, self._device_ids(ids),
                     jnp.asarray(lens), jnp.asarray(spans),
                     jnp.asarray(seeds), jnp.asarray(steps), jnp.asarray(flags),
                     jnp.asarray(temps), jnp.asarray(topks), jnp.asarray(topps))
@@ -2417,8 +2733,9 @@ class DecodeScheduler:
             # one token per row in overflow-safe groups instead
             self.cache.pool = e.pool
             return self._decode_backoff(live), 1
-        toks_k, logits_k = self._fetch_block(out, collect, K)
-        return self._deliver_block(live, toks_k, logits_k, K), K
+        self.cache.pool = out[0]
+        self._advance(live, K)
+        return _Flight(out[1:], K, collect, live)
 
     # ------------------------------------------------------------------ speculative decode
     def _spec_decode_step(self):
@@ -2434,7 +2751,10 @@ class DecodeScheduler:
         writes reclaim them). Rows advance by their own accepted count —
         between 1 and ``1 + spec_tokens`` tokens per dispatch. A sync where
         NO row drafts falls back to the K-step decode program, keeping its
-        dispatch amortization when the drafter is dry."""
+        dispatch amortization when the drafter is dry. A drafter reads the
+        accepted tokens, so this pump is serial (:meth:`_lands_first`): the
+        verify lands here, and nothing is in flight when it is assembled.
+        Returns (delivered, 1), or what :meth:`_decode_step` returns."""
         eng = self.engine
         N, W = self.cache.num_slots, self._spec_width
         live = [(s, r) for s, r in sorted(self.active.items())
@@ -2476,7 +2796,7 @@ class DecodeScheduler:
              collect) = self._gather_sampling(live)
             lora = self._adapter_arg(live)
             fn = self._spec_fn(sampling, collect, W, lora=lora is not None)
-            args = (eng.params, self.cache.pool, jnp.asarray(ids),
+            args = (eng.params, self.cache.pool, self._device_ids(ids),
                     jnp.asarray(lens), jnp.asarray(spans),
                     jnp.asarray(seeds), jnp.asarray(steps), jnp.asarray(flags),
                     jnp.asarray(temps), jnp.asarray(topks), jnp.asarray(topps))
@@ -2488,12 +2808,10 @@ class DecodeScheduler:
             self.cache.pool = e.pool
             return self._decode_backoff(live), 1
         with self._span("sched/fetch"):
-            if collect:
-                self.cache.pool, toks_k, logits_k = out
-                logits_k = np.asarray(jax.device_get(logits_k), np.float32)  # (W, N, V)
-            else:
-                self.cache.pool, toks_k = out
-                logits_k = None
+            self.cache.pool, toks_k, *rest = out
+            self._pop_expert_stats(rest)
+            # (W, N, V)
+            logits_k = np.asarray(jax.device_get(rest[0]), np.float32) if collect else None
             toks_k = np.asarray(jax.device_get(toks_k)).reshape(W, N)
         self._steps += 1
         tel = self.telemetry
@@ -2544,15 +2862,19 @@ class DecodeScheduler:
 
     # ------------------------------------------------------------------ fused chunk step
     def _fused_chunk_step(self):
-        """One fixed-shape fused SYNC whose ids are a ``(num_slots,
+        """Launch one fixed-shape fused SYNC whose ids are a ``(num_slots,
         prefill_chunk)`` block (run whole, or as its live rows only:
         :meth:`_splits_chunk`) plus the remaining ``steps_per_sync - 1``
         decode steps, all in one dispatch: live decode rows advance K tokens
         (column 0 + the substeps), the in-flight prefill row consumes up to
         a chunk of prompt tokens (and, on its final chunk, starts decoding
         in the same dispatch), dead rows carry span 0 (their KV writes are
-        dropped, so retained prefix slots stay byte-stable). Returns
-        (tokens delivered, K)."""
+        dropped, so retained prefix slots stay byte-stable). The prefill
+        lane advances HERE, at the launch: a final chunk frees it and books
+        its row as a decode row (:meth:`_book_decode_row`), so the next
+        queued prompt's first chunk rides the very next sync. Returns the
+        :class:`_Flight`, or (delivered, 1) where cold-expert pressure made
+        it back off."""
         eng = self.engine
         N = self.cache.num_slots
         pf = self._prefill
@@ -2576,14 +2898,13 @@ class DecodeScheduler:
             # are dropped (span 0), and the paged kernel's KV-block walk stays
             # bounded by the longest live row, not the longest retained prefix
             lens = np.zeros(N, np.int32)
-            live = [(s, r) for s, r in sorted(self.active.items())
-                    if s not in self._parked]
+            live, col0 = self._live_rows()
             (seeds, steps, flags, temps, topks, topps, sampling,
              collect) = self._gather_sampling(live)
             sampling = sampling or preq.do_sample
             collect = collect or preq.collect_logits
-            for slot, req in live:
-                ids[slot, 0] = req.out[-1]
+            for (slot, req), tok in zip(live, col0):
+                ids[slot, 0] = tok
                 spans[slot] = 1
                 lens[slot] = self.cache.lengths[slot]
             ps = preq.slot
@@ -2596,8 +2917,12 @@ class DecodeScheduler:
             topps[ps] = preq.top_p
             # substeps only pay off when something real decodes in them: live
             # rows, or the prefill row itself once its final chunk lands — a
-            # non-final chunk on an otherwise idle pool runs the 1-step variant
-            K = self.steps_per_sync if (live or final) else 1
+            # non-final chunk on an otherwise idle pool runs the 1-step
+            # variant. Rows of the sync in flight keep a pool from being idle
+            # even where every one of them ends there: the launch stays on
+            # the program a busy pool runs and the landing settles who is left
+            K = self.steps_per_sync if (live or final or any(
+                r.inflight for r in self.active.values())) else 1
             eo = self._ext_operands(live + [(ps, preq)], force=seqp)
             if eo is not None and K > 1:
                 # substep writes stay inside each row's write extent: decode
@@ -2611,10 +2936,8 @@ class DecodeScheduler:
             lora = self._adapter_arg(live + [(ps, preq)])
             fn = self._fused_fn(sampling, collect, K, C, lora=lora is not None,
                                 ext=eo is not None, seqp=seqp)
-            tel = self.telemetry
-            t0 = tel.now()
             lens[ps] = self.cache.lengths[ps]  # prefix copy and/or earlier chunks
-            args = (eng.params, self.cache.pool, jnp.asarray(ids),
+            args = (eng.params, self.cache.pool, self._device_ids(ids),
                     jnp.asarray(lens), jnp.asarray(spans),
                     jnp.asarray(seeds), jnp.asarray(steps), jnp.asarray(flags),
                     jnp.asarray(temps), jnp.asarray(topks), jnp.asarray(topps))
@@ -2629,53 +2952,21 @@ class DecodeScheduler:
             # prefill alone in shrinking pieces, then advance decode rows
             self.cache.pool = e.pool
             return self._fused_backoff(pf, live)
-        toks_k, logits_k = self._fetch_block(out, collect, K)
-        tr = preq.trace
-        if tr is not None and tr.enabled:
-            fid = self._trace_link(tr)
-            tr.phase("prefill_chunk", start=t0,
-                     flow_in=[fid] if fid else None,
-                     pos=int(pf.pos), take=int(take), final=bool(final))
-        # live rows: column 0 + each substep appended one KV row
-        delivered = self._deliver_block(live, toks_k, logits_k, K)
-        self._keep_choice(preq, ps, take, K if final else 1)
+        self.cache.pool = out[0]
+        self._advance(live, K)
+        fl = _Flight(out[1:], K, collect, live, (preq, pf.pos, take, final))
         pf.pos += take
         if final:
-            # the chunk's rows plus K-1 substep rows: token 0's KV landed
-            # when substep 1 consumed it; the newest token's KV is written
+            # the chunk's rows plus K-1 substep rows: token 0's KV lands
+            # when substep 1 consumes it; the newest token's KV is written
             # when the NEXT sync feeds it (same contract as the decode
-            # program). Set the length BEFORE delivery — a request finishing
-            # mid-sync releases the slot, which must see the final length.
+            # program)
             self.cache.lengths[ps] = L + K - 1
-            self._finish_prefill(
-                preq, int(toks_k[0, ps]),
-                logits_k[0, ps] if (preq.collect_logits and logits_k is not None)
-                else None)
-            delivered += 1
-            for k in range(1, K):
-                if preq.done:
-                    break
-                if preq.collect_logits and logits_k is not None:
-                    preq.logits.append(logits_k[k, ps])
-                self._deliver(preq, int(toks_k[k, ps]))
-                delivered += 1
-            # disaggregated serving: a prefill-role replica hands the
-            # freshly-prefilled request to a decode replica here — after
-            # this sync's tokens streamed (they were computed anyway), with
-            # budget left, via the hook the ReplicaSet installed. The hook
-            # runs migrate_out; decode then resumes elsewhere from the
-            # exact per-row state this sync left behind, so the stream is
-            # bit-identical to staying put.
-            if (not preq.done and self.migrate_hook is not None
-                    and ps not in self.cache.chain
-                    and preq.kv_window is None
-                    and self.migrate_hook(self, preq)):
-                pass  # migrated out: slot released, request owned elsewhere
-                # (multi-extent chains and lossy-window rows stay put: the
-                # handoff protocol demotes/restores one contiguous slot)
+            preq.inflight += K
+            self._book_decode_row(preq)
         else:
             self.cache.lengths[ps] = pf.pos
-        return delivered, K
+        return fl
 
     # ------------------------------------------------------------------ compiled programs
     def _program(self, key, builder):

@@ -201,8 +201,10 @@ class Replica:
                     or self.pending_drain or self.retired)
 
     def idle(self):
+        """Nothing queued, prefilling, decoding or launched and not yet
+        landed (the scheduler's pump is one sync deep)."""
         s = self.scheduler
-        return not (s.active or s.queue or s._prefill is not None)
+        return not (s.active or s.queue or s._prefill is not None or s.in_flight)
 
     def expected_drain_s(self, fallback_ema):
         """Placement score: expected time for this replica's backlog (+ the
@@ -570,6 +572,9 @@ class ReplicaSet:
         Returns the record, or None when the request isn't parkable (no
         transport, not decoding here, mid-prefill, already terminal)."""
         sched = rep.scheduler
+        # the handoff moves the request's landed state: land what is in flight
+        # BEFORE looking at it (it may end in that very sync)
+        sched.land_in_flight()
         if sched.kv_tier is None or req.done or req.cancelled or req.migrating:
             return None
         if req.slot is None or sched.active.get(req.slot) is not req:

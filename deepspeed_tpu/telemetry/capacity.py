@@ -33,7 +33,12 @@ no-ops when the telemetry sink is disabled:
   ``serving/goodput_fraction`` gauge.
 
 - :class:`HostGapTracker` — device-idle attribution for the pump thread.
-  The gap between one sync's fence and the next dispatch is pure host
+  The scheduler's pump is one sync deep: it launches sync N+1 before it
+  lands sync N, so where it runs ahead the device has work queued when a
+  fetch returns and the gap is 0 (one 0.0 observation a sync, no bucket
+  counters). Where the pump is serial (a drafter, expert offload, paged
+  extents, a capacity-sampled fence: ``DecodeScheduler._lands_first``) the
+  gap between one sync's fence and the next dispatch is pure host
   time; the scheduler's spans (``sched/admit``, ``sched/trie_probe``,
   ``sched/assemble``, ``sched/deliver``, ``sched/tier_transfer``: the same
   boundaries a profiler capture shows) stamp their sections into the open
@@ -309,21 +314,30 @@ class CapacityMeter:
 class HostGapTracker:
     """Device-idle (host-gap) attribution for one pump thread.
 
-    Lifecycle per sync: :meth:`sync_end` when a dispatch's results are
-    fenced on the host (the device goes idle), host sections stamped into
-    the open gap via :meth:`add`, and :meth:`dispatch` the moment the next
-    program is handed to the device — closing the gap, normalizing
-    attribution so the per-bucket counters sum EXACTLY to the measured
-    gap, and emitting the histogram. The scheduler calls none of these
-    itself: it passes the tracker as the ``observer`` of its spans, and
-    :meth:`span_enter` / :meth:`span_exit` make the calls from the span
-    boundaries. All methods are single-float arithmetic; the tracker is
-    only constructed when the sink is enabled."""
+    Lifecycle per sync of a serial pump: :meth:`sync_end` when a dispatch's
+    results are fenced on the host (the device goes idle), host sections
+    stamped into the open gap via :meth:`add`, and :meth:`dispatch` the
+    moment the next program is handed to the device — closing the gap,
+    normalizing attribution so the per-bucket counters sum EXACTLY to the
+    measured gap, and emitting the histogram. Of a pump that runs ahead:
+    ``unlanded()`` says whether a sync was launched and has not landed. A
+    ``sched/dispatch`` that opens while one is (before the previous
+    ``sched/fetch`` closes) left the device no gap: ONE observation of 0.0,
+    no bucket counters; and a ``sched/fetch`` that closes while the next
+    sync is out opens none. Span order alone cannot tell the two pumps
+    apart (both alternate dispatch and fetch), hence the callable. The
+    scheduler calls none of the methods itself: it passes the tracker as
+    the ``observer`` of its spans, and :meth:`span_enter` /
+    :meth:`span_exit` make the calls from the span boundaries. All methods
+    are single-float arithmetic; the tracker is only constructed when the
+    sink is enabled."""
 
-    __slots__ = ("sink", "_open_ts", "_acc", "_open_buckets", "gaps", "total_gap_s")
+    __slots__ = ("sink", "_unlanded", "_open_ts", "_acc", "_open_buckets", "gaps",
+                 "total_gap_s")
 
-    def __init__(self, sink):
+    def __init__(self, sink, unlanded=None):
         self.sink = sink
+        self._unlanded = unlanded if unlanded is not None else (lambda: False)
         self._open_ts = None
         self._acc = {b: 0.0 for b in GAP_BUCKETS if b != "other"}
         self._open_buckets = []  # the bucket spans open now, outermost first
@@ -349,8 +363,9 @@ class HostGapTracker:
             self.add(SPAN_BUCKETS[name], t1 - t0, steal_from=outer and SPAN_BUCKETS[outer])
 
     def sync_end(self, ts):
-        """Device results just landed on the host: the idle gap opens."""
-        self._open_ts = ts
+        """Device results just landed on the host: the idle gap opens,
+        unless the next sync was launched already."""
+        self._open_ts = None if self._unlanded() else ts
 
     def add(self, bucket, dur, steal_from=None):
         """Stamp ``dur`` seconds of host work into ``bucket``.
@@ -367,13 +382,18 @@ class HostGapTracker:
 
     def dispatch(self, ts):
         """The next program is being handed to the device: close the gap,
-        emit, and reset. A dispatch before any sync (warmup) just clears
-        the accumulators."""
+        emit, and reset. A dispatch behind a sync that has not landed found
+        the device busy: a gap of 0.0 and nothing to attribute. A dispatch
+        before any sync (warmup) just clears the accumulators."""
         open_ts, self._open_ts = self._open_ts, None
         acc = self._acc
         if open_ts is None:
             for b in acc:
                 acc[b] = 0.0
+            if self._unlanded():
+                self.gaps += 1
+                if self.sink is not None and self.sink.enabled:
+                    self.sink.histogram("serving/host_gap_ms", 0.0)
             return
         gap = max(0.0, ts - open_ts)
         for b in acc:  # floor deferred-steal debits (see :meth:`add`)

@@ -861,8 +861,9 @@ def test_split_decode_rows_bit_identical_to_decode_program(split_pair):
     rider = sched.submit(long_prompt, max_new_tokens=2)
     chunk_syncs = 0
     while not got.done:
-        chunk_syncs += not rider._req.out  # still prefilling: this sync carries its chunk
         sched.step()
+        fl = sched._flight  # the sync this step launched, if any: does it carry a chunk?
+        chunk_syncs += fl is not None and fl.chunk is not None
     assert chunk_syncs == 2
     assert list(got.result()) == list(want_tokens)
     np.testing.assert_array_equal(got.result_logits(), want_logits)
@@ -993,11 +994,9 @@ def test_step_row_counters(tmp_path, baseline, split):
     if not split:
         sched._splits_chunk = lambda key: False
     h = sched.submit(list(range(3, 73)), max_new_tokens=6)
-    syncs = 0
     while not h.done:
         sched.step()
-        syncs += 1
-    assert syncs == 3
+    assert sched.syncs_ahead + sched.syncs_serial == 3
     assert _program_tags(sched) == {"fused_block" if split == "fused_block" else "fused"}
     first = (8 + 64) if split else 8 * 64
     tel = sched.telemetry

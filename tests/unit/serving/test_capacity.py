@@ -163,6 +163,52 @@ def test_host_gap_dispatch_before_sync_clears():
     assert not sink.counters and not sink.hists and gap.gaps == 0
 
 
+@pytest.mark.parametrize("ahead", [True, False])
+def test_host_gap_of_a_pump_one_sync_deep(ahead):
+    """A dispatch that opens before the previous fetch closes (the pump
+    launched sync N+1 with N unlanded) found the device busy: ONE 0.0
+    observation, no bucket counters, whatever host work was stamped since;
+    and the fetch that then lands N opens no gap, because N+1 is out. The
+    serial order records what it always did, its buckets summing to the gap."""
+    sink = FakeSink()
+    unlanded = [False]
+    gap = HostGapTracker(sink, unlanded=lambda: unlanded[0])
+    gap.span_enter("sched/dispatch", 0.0)             # sync N: nothing before it
+    assert not sink.hists and gap.gaps == 0
+    unlanded[0] = ahead                               # N is out when N+1 is assembled
+    if not ahead:
+        gap.span_exit("sched/fetch", 0.001, 0.010)    # serial: N lands first
+    gap.span_enter("sched/admit", 0.010)
+    gap.span_exit("sched/admit", 0.010, 0.014)
+    gap.span_enter("sched/assemble", 0.014)
+    gap.span_exit("sched/assemble", 0.014, 0.016)
+    gap.span_enter("sched/dispatch", 0.020)           # sync N+1
+    if ahead:
+        assert sink.hists["serving/host_gap_ms"] == [0.0] and not sink.counters
+        gap.span_exit("sched/fetch", 0.021, 0.030)    # lands N while N+1 is out: no gap opens
+        gap.span_enter("sched/deliver", 0.030)
+        gap.span_exit("sched/deliver", 0.030, 0.035)
+        gap.span_enter("sched/dispatch", 0.040)       # sync N+2, again behind an unlanded one
+        assert sink.hists["serving/host_gap_ms"] == [0.0, 0.0] and not sink.counters
+        assert gap.gaps == 2 and gap.total_gap_s == 0.0
+        # the pump turns serial (say a capacity-sampled fence): N+1 and N+2
+        # land with nothing out, and the next dispatch measures a real gap
+        unlanded[0] = False
+        gap.span_exit("sched/fetch", 0.041, 0.050)
+        gap.span_enter("sched/deliver", 0.050)
+        gap.span_exit("sched/deliver", 0.050, 0.053)
+        gap.span_enter("sched/dispatch", 0.060)
+        assert sink.hists["serving/host_gap_ms"][2] == pytest.approx(10.0)
+        assert sink.counters["serving/host_gap/on_token_ms"][1] == pytest.approx(3.0)
+        assert sum(t for _, t in sink.counters.values()) == pytest.approx(10.0)
+    else:
+        assert sink.hists["serving/host_gap_ms"] == [pytest.approx(10.0)]
+        assert sink.counters["serving/host_gap/admission_ms"][1] == pytest.approx(4.0)
+        assert sink.counters["serving/host_gap/sampling_host_ms"][1] == pytest.approx(2.0)
+        assert sum(t for _, t in sink.counters.values()) == pytest.approx(10.0)
+        assert gap.gaps == 1
+
+
 # ---------------------------------------------------------- program-key units
 def test_program_shape_and_kind():
     assert program_shape(("fused", True, False, 8, 4)) == (8, 4)

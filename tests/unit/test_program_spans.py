@@ -131,6 +131,31 @@ def test_span_feeds_the_gap_tracker_from_its_boundaries():
     assert set(SPAN_BUCKETS.values()) <= set(gap._acc)
 
 
+def test_pump_ahead_observes_a_zero_gap_every_sync(params, tmp_path):
+    """The scheduler's own spans through its own tracker: every sync launched
+    with the last one unlanded adds one 0.0 to ``serving/host_gap_ms`` (an
+    empty histogram has no quantile for ``sched_host_gap_ms`` to read) and
+    nothing to the bucket counters; those still sum to the gaps measured."""
+    eng = make_engine(params, telemetry={"enabled": True, "output_path": str(tmp_path)})
+    sched = eng.scheduler()
+    handles = [sched.submit(PROMPTS[i], max_new_tokens=24, seed=i) for i in range(2)]
+    sched.drain()
+    assert all(len(h.result()) == 24 for h in handles)
+    tel = eng.telemetry
+    assert sched.syncs_ahead > 0
+    assert tel.counter_total("serving/syncs_ahead") == sched.syncs_ahead
+    assert tel.counter_total("serving/syncs_serial") == sched.syncs_serial
+    snap = tel.snapshot()
+    hg = snap["histograms"]["serving/host_gap_ms"]
+    assert hg["count"] == sched._gap.gaps >= sched.syncs_ahead
+    assert hg["min"] == 0.0
+    buckets = sum(c["total"] for name, c in snap["counters"].items()
+                  if name.startswith("serving/host_gap/"))
+    assert buckets == pytest.approx(sched._gap.total_gap_s * 1e3, rel=1e-6)
+    tel.close()
+    set_sink(None)
+
+
 def test_scheduler_times_no_gap_section_by_hand():
     import inspect
     from deepspeed_tpu.inference import scheduler
