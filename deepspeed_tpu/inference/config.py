@@ -269,8 +269,8 @@ class AutoscalerConfig(DeepSpeedConfigModel):
                                   "ANY scale action before shrinking "
                                   "(hysteresis against grow/shrink flapping)")
     host_gap_veto = ConfigField(default=0.5, help="host-gap fraction (device-"
-                                "idle seconds per wall second, from serving/"
-                                "host_gap/*) at/above which scale-up is "
+                                "idle seconds per wall second, from the gaps "
+                                "behind serving/host_gap_ms) at/above which scale-up is "
                                 "VETOED: the host, not the device, is the "
                                 "bottleneck, and another replica would only "
                                 "add host work")

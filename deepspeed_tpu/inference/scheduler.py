@@ -89,7 +89,12 @@ parked or chained rows, a capacity-sampled fence, a migrate hook on a final
 chunk, a row flagged for cancellation. ``pause`` / ``flush`` / ``drain`` /
 ``swap_weights`` / ``migrate_out`` / ``admit_migration`` land what is in
 flight first. Counters ``serving/syncs_ahead`` (launched with the previous
-sync unlanded) and ``serving/syncs_serial``.
+sync unlanded) and ``serving/syncs_serial``. A pump that runs ahead leaves
+the device no gap to measure, so with the sink on it accounts for every
+landed sync from its span boundaries (``telemetry/capacity.py:
+HostGapTracker``): the host's work, by span, and the wait for the device
+(``serving/pump_busy_ms``, ``serving/pump_wait_ms``, ``serving/pump/*``).
+Where the wait nears 0 the host sets the pace.
 
 **Self-speculative k-token decoding** (Leviathan et al. / prompt-lookup
 drafting, ``spec_tokens > 0``): each pure-decode sync first asks a host-side
@@ -875,7 +880,7 @@ class DecodeScheduler:
         # can pair prefill and decode replicas per request
         self.replica_idx = None
         # serving capacity accounting (telemetry/capacity.py): per-program
-        # roofline registry + sampled fenced timing + host-gap attribution.
+        # roofline registry + sampled fenced timing + the pump's account.
         # Only built on an enabled sink — the disabled path allocates
         # nothing and every hook below gates on `self.capacity is None`.
         self.capacity = None
@@ -887,6 +892,7 @@ class DecodeScheduler:
             from ..accelerator import get_accelerator
             from ..telemetry.capacity import (CapacityMeter, CapacityModel,
                                               HostGapTracker)
+            from ..utils import compile_cache
             accel = get_accelerator()
             n_dev = max(1, int(np.prod(list(engine.mesh.shape.values()))))
             self.capacity = CapacityMeter(
@@ -898,8 +904,11 @@ class DecodeScheduler:
                 peak_hbm_bw=accel.peak_hbm_bandwidth(),
                 n_devices=n_dev,
                 sample_every=getattr(self.telemetry, "capacity_sample_every", 32))
+            # the account needs to know a program was built under a span
+            compile_cache.listen()
             self._gap = HostGapTracker(self.telemetry,
-                                       unlanded=lambda: self._flight is not None)
+                                       unlanded=lambda: self._flight is not None,
+                                       compiles=compile_cache.programs)
             # the KV tier's HBM price tag: int8 should show ~half the bytes
             # per resident token of an "auto" bf16 pool
             self.telemetry.gauges([
@@ -1342,7 +1351,8 @@ class DecodeScheduler:
 
     def _span(self, name):
         """A block-level host span (``TelemetrySink.span``) of this pump:
-        the host-gap tracker hears its boundaries; the sink records it
+        the pump's account (``HostGapTracker``, which books each name of
+        ``capacity.PUMP_SPANS``) hears its boundaries; the sink records it
         while request tracing is on."""
         tel = self.telemetry
         return tel.span(name, record=getattr(tel, "trace_requests", False),
@@ -1836,8 +1846,8 @@ class DecodeScheduler:
             req.adapter_ref = aref
             return slot, (0, None)
         if self.radix is not None:
-            # inside sched/admit: the host-gap tracker files the probe's
-            # share under its own bucket, so buckets stay disjoint
+            # inside sched/admit: the pump's account books the probe's time
+            # under its own bucket and not admission's
             with self._span("sched/trie_probe"):
                 match = self.radix.match(req.prompt, adapter=akey)
         else:
@@ -2249,11 +2259,13 @@ class DecodeScheduler:
         host's copy of the spans at ``step_args[4]``). On a sampled sync,
         fences the dispatch —
         ``block_until_ready`` on the input pool (drain outstanding work) and
-        on the result — so the measured wall time is this program's device
-        time alone. The fence touches only arrays the pipeline already owns:
-        zero new XLA programs. ``step_args`` is the canonical step-argument
-        tuple (pool at [1], lens at [3], spans at [4]) used for batch-shape
-        recovery; ``call_args`` is what the program actually takes."""
+        on the result, each under ``sched/fence`` (the pump blocked on the
+        device: ``wait`` in its account) — so the measured wall time is this
+        program's device time alone. The fence touches only arrays the
+        pipeline already owns: zero new XLA programs. ``step_args`` is the
+        canonical step-argument tuple (pool at [1], lens at [3], spans at
+        [4]) used for batch-shape recovery; ``call_args`` is what the program
+        actually takes."""
         cap = self.capacity
         if cap is not None:  # the sink is on
             key = cap.key_for(fn)
@@ -2269,11 +2281,13 @@ class DecodeScheduler:
                 return self._run_program(fn, call_args)
         # one fenced dispatch per sampled sync, even across MoE replays
         self._cap_sample = False
-        jax.block_until_ready(step_args[1])
+        with self._span("sched/fence"):
+            jax.block_until_ready(step_args[1])
         t0 = time.perf_counter()
         with self._span("sched/dispatch"), self.engine.mesh:
             out = self._run_program(fn, call_args)
-        jax.block_until_ready(out)
+        with self._span("sched/fence"):
+            jax.block_until_ready(out)
         dur = time.perf_counter() - t0
         if key is not None:
             lens = np.asarray(step_args[3])

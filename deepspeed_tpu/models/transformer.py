@@ -17,6 +17,7 @@ activations default bf16 with fp32 LayerNorm/softmax accumulations; remat via
 path and the Pallas flash kernel (``ops.pallas.flash_attention``).
 """
 
+import contextlib
 import dataclasses
 from functools import partial
 from typing import Any, Optional, Tuple
@@ -2312,33 +2313,37 @@ class CausalLM(nn.Module):
                 new_cache = tuple(tuple(c[j] for c in caches)
                                   for j in range(len(caches[0])))
 
-        x = make_norm(cfg, name="final_norm")(x)
-        if return_hidden:
-            return x
-        # logits matmul runs in compute dtype (MXU rate); CE upcasts to fp32
-        if cfg.int8_weights:
-            # one int8 vocab projection covers both tied and untied heads
-            # (vocab padded to a 2048 multiple so the quant-matmul kernel
-            # gets wide n-blocks — 50304's largest divisor under the block
-            # cap is a DMA-starving 384; quantize_params builds the padding)
-            Vpad = -(-cfg.vocab_size // 2048) * 2048
-            qw = self.param("logits_q", nn.initializers.zeros,
-                            (cfg.hidden_size, Vpad), jnp.int8)
-            sc = self.param("logits_scale", nn.initializers.ones,
-                            (_q_groups(cfg.hidden_size, cfg.int8_group_size), Vpad),
-                            jnp.float32)
-            Bx, Tx, Hx = x.shape
-            logits = _qmm2d(x.reshape(Bx * Tx, Hx), qw, sc)
-            logits = logits.reshape(Bx, Tx, Vpad)[..., :cfg.vocab_size]
-            if cfg.lm_head_bias:
-                lb = self.param("logits_bias", nn.initializers.zeros,
-                                (cfg.vocab_size, ), jnp.float32)
-                logits = logits + lb.astype(logits.dtype)
-        elif cfg.tie_embeddings:
-            logits = emb.attend(x)
-        else:
-            logits = nn.Dense(cfg.vocab_size, use_bias=cfg.lm_head_bias, dtype=cfg.dtype,
-                              param_dtype=jnp.float32, name="lm_head")(x)
+        # the head's mark in a served step's device trace: the final norm and
+        # the vocabulary product (the step samples right behind it, under
+        # `sample`). Training's head is under `loss_ce`, and keeps its text.
+        with jax.named_scope("lm_head") if kv_cache is not None else contextlib.nullcontext():
+            x = make_norm(cfg, name="final_norm")(x)
+            if return_hidden:
+                return x
+            # logits matmul runs in compute dtype (MXU rate); CE upcasts to fp32
+            if cfg.int8_weights:
+                # one int8 vocab projection covers both tied and untied heads
+                # (vocab padded to a 2048 multiple so the quant-matmul kernel
+                # gets wide n-blocks — 50304's largest divisor under the block
+                # cap is a DMA-starving 384; quantize_params builds the padding)
+                Vpad = -(-cfg.vocab_size // 2048) * 2048
+                qw = self.param("logits_q", nn.initializers.zeros,
+                                (cfg.hidden_size, Vpad), jnp.int8)
+                sc = self.param("logits_scale", nn.initializers.ones,
+                                (_q_groups(cfg.hidden_size, cfg.int8_group_size), Vpad),
+                                jnp.float32)
+                Bx, Tx, Hx = x.shape
+                logits = _qmm2d(x.reshape(Bx * Tx, Hx), qw, sc)
+                logits = logits.reshape(Bx, Tx, Vpad)[..., :cfg.vocab_size]
+                if cfg.lm_head_bias:
+                    lb = self.param("logits_bias", nn.initializers.zeros,
+                                    (cfg.vocab_size, ), jnp.float32)
+                    logits = logits + lb.astype(logits.dtype)
+            elif cfg.tie_embeddings:
+                logits = emb.attend(x)
+            else:
+                logits = nn.Dense(cfg.vocab_size, use_bias=cfg.lm_head_bias, dtype=cfg.dtype,
+                                  param_dtype=jnp.float32, name="lm_head")(x)
         if kv_cache is not None:
             return logits, new_cache
         return logits
@@ -2819,21 +2824,22 @@ class CausalLMModel:
             new_layers.append(written)
         new_cache = tuple(tuple(lay[j] for lay in new_layers)
                           for j in range(len(new_layers[0])))
-        x32 = x2d.astype(jnp.float32)
-        if "final_bias" in head:  # layernorm head
-            mu = jnp.mean(x32, axis=-1, keepdims=True)
-            var = jnp.mean(jnp.square(x32 - mu), axis=-1, keepdims=True)
-            xn = ((x32 - mu) * jax.lax.rsqrt(var + cfg.layernorm_epsilon)
-                  * head["final_scale"] + head["final_bias"])
-        else:  # rmsnorm
-            ms = jnp.mean(x32 * x32, axis=-1, keepdims=True)
-            xn = (x32 * jax.lax.rsqrt(ms + cfg.layernorm_epsilon)
-                  * head["final_scale"])
-        logits = _qmm2d(xn.astype(x2d.dtype), head["logits_q"],
-                        head["logits_scale"])
-        logits = logits.reshape(N, C, -1)[..., :cfg.vocab_size]
-        if "logits_bias" in head:
-            logits = logits + head["logits_bias"].astype(logits.dtype)
+        with jax.named_scope("lm_head"):
+            x32 = x2d.astype(jnp.float32)
+            if "final_bias" in head:  # layernorm head
+                mu = jnp.mean(x32, axis=-1, keepdims=True)
+                var = jnp.mean(jnp.square(x32 - mu), axis=-1, keepdims=True)
+                xn = ((x32 - mu) * jax.lax.rsqrt(var + cfg.layernorm_epsilon)
+                      * head["final_scale"] + head["final_bias"])
+            else:  # rmsnorm
+                ms = jnp.mean(x32 * x32, axis=-1, keepdims=True)
+                xn = (x32 * jax.lax.rsqrt(ms + cfg.layernorm_epsilon)
+                      * head["final_scale"])
+            logits = _qmm2d(xn.astype(x2d.dtype), head["logits_q"],
+                            head["logits_scale"])
+            logits = logits.reshape(N, C, -1)[..., :cfg.vocab_size]
+            if "logits_bias" in head:
+                logits = logits + head["logits_bias"].astype(logits.dtype)
         return logits, new_cache
 
     def _apply_kwargs(self, rng):

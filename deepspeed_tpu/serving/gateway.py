@@ -452,10 +452,11 @@ class Gateway:
         sched = rep.scheduler
         primary = rep.idx == 0
         # the pump's own two spans: profiler annotations only (an idle pump
-        # turns 50 times a second; the sink records none of them)
-        span = self.telemetry.span
+        # turns 50 times a second; the sink records none of them), heard by
+        # the scheduler's account of the pump's time (None with the sink off)
+        span, gap = self.telemetry.span, sched._gap
         while not self._force_stop:
-            with span("gateway/admit", record=False), self._dispatch_lock:
+            with span("gateway/admit", record=False, observer=gap), self._dispatch_lock:
                 self._enforce_cancellations()
                 self._admit()
             try:
@@ -527,7 +528,7 @@ class Gateway:
             if rep.idle() or rep.sick:
                 if self.draining and not len(self._fair) and not self._active:
                     break
-                with span("gateway/idle", record=False):
+                with span("gateway/idle", record=False, observer=gap):
                     self._wake.wait(0.02)
                 self._wake.clear()
         # force-stop: anything still in flight is failed, not silently
@@ -1436,6 +1437,8 @@ class Gateway:
                 "samples": sched.capacity.samples,
                 "host_gaps": sched._gap.gaps,
                 "host_gap_total_s": round(sched._gap.total_gap_s, 6),
+                "pump_busy_total_s": round(sched._gap.busy_s, 6),
+                "pump_wait_total_s": round(sched._gap.wait_s, 6),
                 "profiling": (self.profiler.active
                               if self.profiler is not None else None),
             } if sched.capacity is not None else None),

@@ -284,6 +284,10 @@ class Replica:
                                  if s.capacity is not None else None),
             "host_gap_total_s": (round(s._gap.total_gap_s, 4)
                                  if s._gap is not None else None),
+            "pump_busy_total_s": (round(s._gap.busy_s, 4)
+                                  if s._gap is not None else None),
+            "pump_wait_total_s": (round(s._gap.wait_s, 4)
+                                  if s._gap is not None else None),
             "ema_service_s": self.ema_service_s,
             "tp_size": s.tp_size,
             "ep_size": s.ep_size,

@@ -32,20 +32,26 @@ no-ops when the telemetry sink is disabled:
   converted at the machine balance) rolled into the
   ``serving/goodput_fraction`` gauge.
 
-- :class:`HostGapTracker` — device-idle attribution for the pump thread.
-  The scheduler's pump is one sync deep: it launches sync N+1 before it
-  lands sync N, so where it runs ahead the device has work queued when a
-  fetch returns and the gap is 0 (one 0.0 observation a sync, no bucket
-  counters). Where the pump is serial (a drafter, expert offload, paged
-  extents, a capacity-sampled fence: ``DecodeScheduler._lands_first``) the
-  gap between one sync's fence and the next dispatch is pure host
-  time; the scheduler's spans (``sched/admit``, ``sched/trie_probe``,
-  ``sched/assemble``, ``sched/deliver``, ``sched/tier_transfer``: the same
-  boundaries a profiler capture shows) stamp their sections into the open
-  gap and the tracker emits a ``serving/host_gap_ms`` histogram plus per-bucket
-  ``serving/host_gap/<bucket>_ms`` counters whose sum equals the measured
-  gap exactly (residue lands in ``other``; over-attribution from timer
-  overlap is scaled back proportionally).
+- :class:`HostGapTracker` — the pump thread's account of its own time. It
+  is the ``observer`` of the pump's spans (the scheduler's ``sched/*`` and
+  the gateway's ``gateway/admit`` and ``gateway/idle``: the same boundaries
+  a profiler capture shows) and keeps two things from them. One account a
+  LANDED sync: every ``sched/fetch`` exit closes the period that opened at
+  the landing before it and splits it into ``wait`` (inside ``sched/fetch``:
+  the pump blocked on the device), ``idle`` (inside ``gateway/idle``, or
+  under no span with nobody pumping), ``compile`` (a span under which a program was built: set-up, not a sync's
+  work) and ``busy``, the host's work for that sync, by the span it was
+  under (``BUSY_BUCKETS``). The buckets add up to ``busy`` and the parts to
+  the period, exactly, whether the sync ran ahead or serial:
+  ``serving/pump_busy_ms`` / ``serving/pump_wait_ms`` histograms and
+  ``serving/pump/<part>_ms`` counters. With ``wait`` near 0 the host sets
+  the pace, whatever the gap below reads. And the device-idle gap, as
+  before: the pump is one sync deep, so where it runs ahead the device has
+  work queued when a fetch returns and the gap is 0 (one 0.0 observation a
+  sync); where it is serial (a drafter, expert offload, paged extents, a
+  capacity-sampled fence: ``DecodeScheduler._lands_first``) the time from
+  one sync's fetch to the next dispatch is pure host time:
+  ``serving/host_gap_ms``.
 
 Everything here is stdlib + numpy on the host side; the only device
 interaction is the sampled fence.
@@ -53,18 +59,20 @@ interaction is the sampled fence.
 
 import numpy as np
 
-# host-gap attribution buckets, in emission order. "other" is the residue
-# between the measured gap and the stamped sections — it absorbs pump-loop
-# overhead, GIL waits, and anything not explicitly instrumented.
-GAP_BUCKETS = ("admission", "trie_probe", "sampling_host", "on_token",
-               "tier_transfer", "other")
+# the parts of a period's ``busy`` time, each named after the span the pump
+# was under: ``gateway`` is ``gateway/admit`` and whatever else lies between
+# two ``sched/step``s, ``other`` what ``sched/step`` spends under none of its
+# inner spans (the loop, ``_observe``, waits for the GIL)
+BUSY_BUCKETS = ("admit", "trie_probe", "assemble", "dispatch", "deliver",
+                "tier_transfer", "gateway", "other")
 
-# the scheduler's spans (``TelemetrySink.span``) that feed the tracker, and
-# the bucket each one's time belongs to. ``sched/dispatch`` (its start
-# closes the gap) and ``sched/fetch`` (its end opens one) carry no bucket.
-SPAN_BUCKETS = {"sched/admit": "admission", "sched/trie_probe": "trie_probe",
-                "sched/assemble": "sampling_host", "sched/deliver": "on_token",
-                "sched/tier_transfer": "tier_transfer"}
+# where the self time of each span the tracker hears goes, unless a program
+# was built under it: that is ``compile``, a part like ``wait`` and ``idle``
+PUMP_SPANS = {"sched/step": "other", "sched/fetch": "wait", "sched/fence": "wait",
+              "gateway/admit": "gateway", "gateway/idle": "idle",
+              **{"sched/" + b: b for b in BUSY_BUCKETS[:6]}}
+PUMP_PARTS = ("wait", "idle", "compile") + BUSY_BUCKETS
+GATEWAY_SPANS = ("gateway/admit", "gateway/idle")
 
 _GATED_ACTS = ("swiglu", "geglu")
 
@@ -312,111 +320,136 @@ class CapacityMeter:
 
 
 class HostGapTracker:
-    """Device-idle (host-gap) attribution for one pump thread.
+    """The pump thread's account of its time, from its span boundaries.
 
-    Lifecycle per sync of a serial pump: :meth:`sync_end` when a dispatch's
-    results are fenced on the host (the device goes idle), host sections
-    stamped into the open gap via :meth:`add`, and :meth:`dispatch` the
-    moment the next program is handed to the device — closing the gap,
-    normalizing attribution so the per-bucket counters sum EXACTLY to the
-    measured gap, and emitting the histogram. Of a pump that runs ahead:
-    ``unlanded()`` says whether a sync was launched and has not landed. A
-    ``sched/dispatch`` that opens while one is (before the previous
-    ``sched/fetch`` closes) left the device no gap: ONE observation of 0.0,
-    no bucket counters; and a ``sched/fetch`` that closes while the next
-    sync is out opens none. Span order alone cannot tell the two pumps
-    apart (both alternate dispatch and fetch), hence the callable. The
-    scheduler calls none of the methods itself: it passes the tracker as
-    the ``observer`` of its spans, and :meth:`span_enter` /
-    :meth:`span_exit` make the calls from the span boundaries. All methods
-    are single-float arithmetic; the tracker is only constructed when the
-    sink is enabled."""
+    The scheduler and the gateway call none of the methods themselves: they
+    pass the tracker as the ``observer`` of the pump's spans, and
+    :meth:`span_enter` / :meth:`span_exit` hear the ``time.perf_counter``
+    boundaries. All methods are float arithmetic on a short stack; the
+    tracker is only constructed when the sink is enabled.
 
-    __slots__ = ("sink", "_unlanded", "_open_ts", "_acc", "_open_buckets", "gaps",
-                 "total_gap_s")
+    *Periods.* A landing (a ``sched/fetch`` exit) closes the period that
+    opened at the landing before it, or at the first ``sched/step`` entry.
+    Every span's SELF time (its own less the spans inside it: the trie probe
+    comes out of admission) goes to its part (``PUMP_SPANS``); a span open
+    across a landing is cut there, so each period gets its share. A span
+    under which ``compiles()`` moved (a program was traced, lowered and
+    built: seconds to minutes, once a program) gives its self time to
+    ``compile`` whatever its name, so the totals of a process describe its
+    syncs and not its set-up. Time under no span is the gateway pump's own
+    loop around ``sched/step`` where a sync is in flight and one of the
+    gateway's spans is on either side, and is left to ``gateway``; otherwise
+    nobody pumped (a caller that steps the scheduler itself, a pump not yet
+    started) and it is ``idle``. ``busy`` is the period less ``wait``,
+    ``idle`` and ``compile``, and ``gateway`` takes what of it no span
+    covered, so the parts add up exactly and nothing is ever scaled. A pump
+    that went idle (a ``gateway/idle`` turn with nothing in flight) closes
+    the stretch since the last landing at its next ``sched/step`` entry into
+    the counters alone: the histograms hold one observation a landed sync,
+    of that sync's own host work.
 
-    def __init__(self, sink, unlanded=None):
+    *The device-idle gap.* ``unlanded()`` says whether a sync was launched
+    and has not landed. A ``sched/dispatch`` that opens while one is left
+    the device no gap: ONE observation of 0.0; a ``sched/fetch`` that closes
+    while the next sync is out opens none; a serial pump's gap is the time
+    from the fetch's end to the next dispatch's start. Span order alone
+    cannot tell the two pumps apart (both alternate dispatch and fetch),
+    hence the callable. Where the host's work a sync passes the device's
+    the gap still reads 0 (the host cannot see the device finish): ``wait``
+    near 0 is the sign."""
+
+    __slots__ = ("sink", "_unlanded", "_compiles", "_compiled", "_open_ts", "gaps",
+                 "total_gap_s", "_stack", "_acc", "_period_ts", "_was_idle", "_left_ts",
+                 "_left_gateway", "busy_s", "wait_s")
+
+    def __init__(self, sink, unlanded=None, compiles=None):
         self.sink = sink
         self._unlanded = unlanded if unlanded is not None else (lambda: False)
-        self._open_ts = None
-        self._acc = {b: 0.0 for b in GAP_BUCKETS if b != "other"}
-        self._open_buckets = []  # the bucket spans open now, outermost first
+        self._compiles = compiles if compiles is not None else (lambda: 0)
+        self._compiled = self._compiles()
+        self._open_ts = None     # where the device-idle gap opened
         self.gaps = 0
         self.total_gap_s = 0.0
+        self._stack = []         # open spans, outermost first: [part, since, inside]
+        self._acc = dict.fromkeys(PUMP_PARTS, 0.0)
+        self._period_ts = None   # where the open period began
+        self._was_idle = False
+        self._left_ts = None     # where the pump was last under a span, while under none
+        self._left_gateway = False   # ... and whether that span was the gateway's
+        self.busy_s = 0.0
+        self.wait_s = 0.0
 
     def span_enter(self, name, ts):
-        """A scheduler span opened at ``ts``."""
-        if name == "sched/dispatch":
-            self.dispatch(ts)
-        elif name in SPAN_BUCKETS:
-            self._open_buckets.append(name)
+        """A span of the pump opened at ``ts``."""
+        if self._left_ts is not None:
+            # under no span: the gateway pump's loop (left to ``gateway``)
+            # where one of its spans is on either side and a sync is in
+            # flight; else nobody pumped
+            if not (self._unlanded() and (self._left_gateway or name in GATEWAY_SPANS)):
+                self._acc["idle"] += ts - self._left_ts
+            self._left_ts = None
+        if name == "sched/step":
+            if self._period_ts is None or (self._was_idle and not self._unlanded()):
+                self._close(ts, landed=False)
+            self._was_idle = False
+        elif name == "sched/dispatch":
+            self._dispatch(ts)
+        self._stack.append([PUMP_SPANS[name], ts, 0.0])
 
     def span_exit(self, name, t0, t1):
-        """The span that opened at ``t0`` closed at ``t1``. A bucket span
-        inside another (the trie probe inside admission) takes its time
-        out of the enclosing one."""
+        """The innermost open span, which opened at ``t0``, closed at ``t1``."""
+        part, since, inside = self._stack.pop()
+        compiled = self._compiles()
+        if compiled != self._compiled:
+            self._compiled, part = compiled, "compile"
+        self._acc[part] += t1 - since - inside
+        if self._stack:
+            self._stack[-1][2] += t1 - since
+        else:
+            self._left_ts, self._left_gateway = t1, name in GATEWAY_SPANS
         if name == "sched/fetch":
-            self.sync_end(t1)
-        elif name in SPAN_BUCKETS:
-            self._open_buckets.pop()
-            outer = self._open_buckets[-1] if self._open_buckets else None
-            self.add(SPAN_BUCKETS[name], t1 - t0, steal_from=outer and SPAN_BUCKETS[outer])
+            # results on the host: the device idles from here unless the
+            # next sync was launched already
+            self._open_ts = None if self._unlanded() else t1
+            self._close(t1, landed=True)
+        elif name == "gateway/idle" and not self._unlanded():
+            self._was_idle = True
 
-    def sync_end(self, ts):
-        """Device results just landed on the host: the idle gap opens,
-        unless the next sync was launched already."""
-        self._open_ts = None if self._unlanded() else ts
-
-    def add(self, bucket, dur, steal_from=None):
-        """Stamp ``dur`` seconds of host work into ``bucket``.
-        ``steal_from`` moves the time out of an ENCLOSING section (e.g. the
-        trie probe runs inside the admission region) so nested timers never
-        double-count. The debit may land before the enclosing section is
-        stamped — the accumulator is allowed to go negative and is floored
-        at :meth:`dispatch`, so stamp order doesn't matter."""
-        if dur <= 0.0:
-            return
-        self._acc[bucket] += dur
-        if steal_from is not None:
-            self._acc[steal_from] -= dur
-
-    def dispatch(self, ts):
-        """The next program is being handed to the device: close the gap,
-        emit, and reset. A dispatch behind a sync that has not landed found
-        the device busy: a gap of 0.0 and nothing to attribute. A dispatch
-        before any sync (warmup) just clears the accumulators."""
+    def _dispatch(self, ts):
+        """The next program is being handed to the device: the device-idle
+        gap closes. Behind a sync that has not landed the device was busy: a
+        gap of 0.0. A dispatch before any sync (warm-up) records nothing."""
         open_ts, self._open_ts = self._open_ts, None
-        acc = self._acc
-        if open_ts is None:
-            for b in acc:
-                acc[b] = 0.0
-            if self._unlanded():
-                self.gaps += 1
-                if self.sink is not None and self.sink.enabled:
-                    self.sink.histogram("serving/host_gap_ms", 0.0)
+        if open_ts is None and not self._unlanded():
             return
-        gap = max(0.0, ts - open_ts)
-        for b in acc:  # floor deferred-steal debits (see :meth:`add`)
-            if acc[b] < 0.0:
-                acc[b] = 0.0
-        attributed = sum(acc.values())
-        if attributed > gap > 0.0:
-            # timer overlap / clock skew: scale back so the invariant
-            # "buckets sum to the measured gap" holds exactly
-            scale = gap / attributed
-            for b in acc:
-                acc[b] *= scale
-            attributed = gap
-        other = max(0.0, gap - attributed)
+        gap = 0.0 if open_ts is None else max(0.0, ts - open_ts)
         self.gaps += 1
         self.total_gap_s += gap
-        sink = self.sink
-        if sink is not None and sink.enabled:
-            sink.histogram("serving/host_gap_ms", gap * 1e3)
-            for b, v in acc.items():
-                if v > 0.0:
-                    sink.counter(f"serving/host_gap/{b}_ms", v * 1e3)
-            if other > 0.0:
-                sink.counter("serving/host_gap/other_ms", other * 1e3)
-        for b in acc:
-            acc[b] = 0.0
+        self.sink.histogram("serving/host_gap_ms", gap * 1e3)
+
+    def _close(self, ts, landed):
+        """Close the period at ``ts``: book the open spans' self time so far
+        (innermost first, each less the open span inside it), emit, and
+        open the next period at ``ts``. With no period open (before the
+        first ``sched/step``) what the spans booked so far is dropped."""
+        acc, covered = self._acc, 0.0
+        for span in reversed(self._stack):
+            acc[span[0]] += ts - span[1] - span[2] - covered
+            covered = ts - span[1]
+            span[1], span[2] = ts, 0.0
+        start, self._period_ts = self._period_ts, ts
+        if start is not None:
+            wait = acc["wait"]
+            busy = ts - start - wait - acc["idle"] - acc["compile"]
+            acc["gateway"] = busy - sum(acc[b] for b in BUSY_BUCKETS if b != "gateway")
+            self.busy_s += busy
+            self.wait_s += wait
+            sink = self.sink
+            if landed:
+                sink.histogram("serving/pump_busy_ms", busy * 1e3)
+                sink.histogram("serving/pump_wait_ms", wait * 1e3)
+            for part, v in (("busy", busy), *acc.items()):
+                if v:
+                    sink.counter(f"serving/pump/{part}_ms", v * 1e3)
+        for part in acc:
+            acc[part] = 0.0

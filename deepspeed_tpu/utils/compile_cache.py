@@ -97,9 +97,18 @@ def stats():
     included), the backend's compile-or-load, and the persistent cache's
     retrieval inside the latter. What runs inside another interval is that
     interval's (a lowering's own tracing is lowering). All zero until
-    ``configure()`` ran."""
+    ``listen()`` ran (``configure()`` calls it, and so does a scheduler
+    built on an enabled sink)."""
     with _lock:
         return dict(_stats)
+
+
+def programs():
+    """How many programs the backend was asked for so far (built, or read
+    from the persistent cache): it moves when something compiled. The pump's
+    account (``telemetry/capacity.py``) reads it at every span exit, so no
+    lock and no copy."""
+    return _stats["backend_count"]
 
 
 def export(env):

@@ -23,7 +23,7 @@ import pytest
 import deepspeed_tpu
 from deepspeed_tpu.comm import comm
 from deepspeed_tpu.telemetry import TelemetrySink, set_sink
-from deepspeed_tpu.telemetry.capacity import SPAN_BUCKETS, HostGapTracker
+from deepspeed_tpu.telemetry.capacity import PUMP_PARTS, PUMP_SPANS, HostGapTracker
 from deepspeed_tpu.utils import compile_cache
 
 _RNG = np.random.default_rng(31)
@@ -115,7 +115,9 @@ def test_span_feeds_the_gap_tracker_from_its_boundaries():
 
     fake = Sink()
     gap = HostGapTracker(fake)
-    gap.span_exit("sched/fetch", 0.0, 1.0)            # results on the host: gap opens at 1.0
+    gap.span_enter("sched/fetch", 0.0)
+    gap.span_exit("sched/fetch", 0.0, 1.0)            # results on the host: gap and period open
+    gap.span_enter("sched/step", 1.0)
     gap.span_enter("sched/admit", 1.0)
     gap.span_enter("sched/trie_probe", 1.002)
     gap.span_exit("sched/trie_probe", 1.002, 1.005)   # 3 ms out of admission's 10
@@ -124,18 +126,28 @@ def test_span_feeds_the_gap_tracker_from_its_boundaries():
     gap.span_exit("sched/assemble", 1.010, 1.014)
     gap.span_enter("sched/dispatch", 1.020)           # closes the gap: 20 ms
     assert fake.hist == [("serving/host_gap_ms", pytest.approx(20.0))]
-    assert fake.counters["serving/host_gap/admission_ms"] == pytest.approx(7.0)
-    assert fake.counters["serving/host_gap/trie_probe_ms"] == pytest.approx(3.0)
-    assert fake.counters["serving/host_gap/sampling_host_ms"] == pytest.approx(4.0)
-    assert fake.counters["serving/host_gap/other_ms"] == pytest.approx(6.0)
-    assert set(SPAN_BUCKETS.values()) <= set(gap._acc)
+    assert not fake.counters                          # the period is open until the landing
+    gap.span_exit("sched/dispatch", 1.020, 1.020)
+    gap.span_enter("sched/fetch", 1.020)
+    gap.span_exit("sched/fetch", 1.020, 1.030)
+    assert fake.counters["serving/pump/admit_ms"] == pytest.approx(7.0)
+    assert fake.counters["serving/pump/trie_probe_ms"] == pytest.approx(3.0)
+    assert fake.counters["serving/pump/assemble_ms"] == pytest.approx(4.0)
+    assert fake.counters["serving/pump/other_ms"] == pytest.approx(6.0)
+    assert fake.counters["serving/pump/busy_ms"] == pytest.approx(20.0)
+    assert fake.counters["serving/pump/wait_ms"] == pytest.approx(10.0)
+    assert fake.hist[1:] == [("serving/pump_busy_ms", pytest.approx(20.0)),
+                             ("serving/pump_wait_ms", pytest.approx(10.0))]
+    # every span the pump opens has a part to go to, and every part a counter's name
+    assert set(PUMP_SPANS.values()) <= set(PUMP_PARTS) == set(gap._acc)
 
 
 def test_pump_ahead_observes_a_zero_gap_every_sync(params, tmp_path):
     """The scheduler's own spans through its own tracker: every sync launched
     with the last one unlanded adds one 0.0 to ``serving/host_gap_ms`` (an
-    empty histogram has no quantile for ``sched_host_gap_ms`` to read) and
-    nothing to the bucket counters; those still sum to the gaps measured."""
+    empty histogram has no quantile for ``sched_host_gap_ms`` to read), the
+    gaps measured sum to ``total_gap_s`` as before, and every landed sync,
+    ahead or serial, adds one observation of its host work and of its wait."""
     eng = make_engine(params, telemetry={"enabled": True, "output_path": str(tmp_path)})
     sched = eng.scheduler()
     handles = [sched.submit(PROMPTS[i], max_new_tokens=24, seed=i) for i in range(2)]
@@ -149,9 +161,14 @@ def test_pump_ahead_observes_a_zero_gap_every_sync(params, tmp_path):
     hg = snap["histograms"]["serving/host_gap_ms"]
     assert hg["count"] == sched._gap.gaps >= sched.syncs_ahead
     assert hg["min"] == 0.0
-    buckets = sum(c["total"] for name, c in snap["counters"].items()
-                  if name.startswith("serving/host_gap/"))
-    assert buckets == pytest.approx(sched._gap.total_gap_s * 1e3, rel=1e-6)
+    assert hg["sum"] == pytest.approx(sched._gap.total_gap_s * 1e3, rel=1e-6)
+    landed = sched.syncs_ahead + sched.syncs_serial
+    busy, wait = (snap["histograms"][f"serving/pump_{part}_ms"] for part in ("busy", "wait"))
+    assert busy["count"] == wait["count"] == landed
+    counters = snap["counters"]
+    # the histograms hold the landed periods; the counters the last landing's delivery too
+    assert busy["sum"] <= counters["serving/pump/busy_ms"]["total"] + 1e-5
+    assert wait["sum"] == pytest.approx(counters["serving/pump/wait_ms"]["total"], rel=1e-6)
     tel.close()
     set_sink(None)
 
@@ -161,6 +178,10 @@ def test_scheduler_times_no_gap_section_by_hand():
     from deepspeed_tpu.inference import scheduler
     src = inspect.getsource(scheduler)
     assert "_gap.add(" not in src and "_gap.sync_end(" not in src and "_gap.dispatch(" not in src
+    # and every span it opens is one the account has a part for
+    import re
+    opened = set(re.findall(r'_span\("([^"]+)"\)', src))
+    assert {"sched/step", "sched/fetch", "sched/dispatch"} <= opened <= set(PUMP_SPANS)
 
 
 def test_disabled_sink_span_records_nothing_but_tells_its_observer(tmp_path):
@@ -201,8 +222,60 @@ def test_serving_kernels_carry_their_names_into_the_program():
     text = jax.jit(model.fused_paged_step).lower(params, ids, pool, ids, rows, rows).as_text(
         debug_info=True)
     for name in ("dstpu_decode_attn", "dstpu_fused_qkv_ln", "dstpu_fused_out_mlp",
-                 "dstpu_quant_matmul", "kv_commit"):
+                 "dstpu_quant_matmul", "kv_commit", "/lm_head/"):
         assert name in text, name
+
+
+class _Lowered(Exception):
+    """Carries a step program's lowering out of the pump that was about to run it."""
+
+
+def _step_program_text(model_name, mark_head):
+    """The first step program ``warm_programs`` would dispatch, lowered and
+    not run: (text without locations, text with them). With ``mark_head``
+    off, ``lm_head`` scopes are not entered (the parent's program)."""
+    import contextlib
+    from unittest import mock
+    from deepspeed_tpu.models import get_model
+    comm._state["mesh"] = None
+    set_sink(None)
+    eng = deepspeed_tpu.init_inference(
+        get_model(model_name, dtype=jnp.float32),
+        config={"dtype": "float32", "max_out_tokens": 128,
+                "continuous_batching": {"enabled": True, "num_slots": 3, "steps_per_sync": 2,
+                                        "prefill_chunk": 16}})
+    sched = eng.scheduler()
+
+    def lower(fn, call_args):
+        lowered = fn.lower(*call_args)
+        raise _Lowered(lowered.as_text(), lowered.as_text(debug_info=True))
+
+    sched._run_program = lower
+    scope = jax.named_scope
+    unmarked = mock.patch.object(
+        jax, "named_scope",
+        lambda name: contextlib.nullcontext() if name == "lm_head" else scope(name))
+    with pytest.raises(_Lowered) as got, (contextlib.nullcontext() if mark_head else unmarked):
+        sched.warm_programs(ladder=False)
+    return got.value.args
+
+
+@pytest.mark.parametrize("model_name", ["tiny", "tiny-hybrid", "tiny-mla-moe"])
+def test_lm_head_scope_marks_the_head_and_moves_nothing_else(model_name):
+    """The served step program's final norm and vocabulary product are
+    traced under ``lm_head`` (what ``lm_head_device_pct`` reads in the
+    device trace), and the scope is metadata: without locations the program
+    is text for text the one lowered with the scope never entered."""
+    import re
+    plain, located = _step_program_text(model_name, mark_head=True)
+    parent_plain, parent_located = _step_program_text(model_name, mark_head=False)
+    assert plain == parent_plain
+    # the product over the vocabulary, and the norm before it
+    assert re.search(r'"[^"]*/lm_head/[^"]*dot_general', located), model_name
+    assert re.search(r'"[^"]*/lm_head/[^"]*final_norm', located), model_name
+    assert not re.search(r'"[^"]*/lm_head/[^"]*final_norm', parent_located)
+    # the choice right behind it keeps its own mark
+    assert re.search(r'"[^"]*/sample["/]', located)
 
 
 def test_flash_attention_calls_stay_unnamed():
