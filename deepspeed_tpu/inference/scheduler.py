@@ -208,7 +208,11 @@ histograms ``serving/ttft_ms``, ``serving/step_ms``,
 ``serving/tokens_per_step``, ``serving/spec_tokens_per_step``; counters
 ``serving/step_rows_run`` (rows a step program's forwards compute) and
 ``serving/step_rows_live`` (those among them inside a row's span), per
-dispatch. Multi-LoRA adds
+dispatch, and for every model whose attention runs the paged decode kernel
+``serving/attn_keys_live`` (the keys inside the rows' attended windows, over
+slots, forwards and layers) and ``serving/attn_keys_walked`` (the same
+rounded out to the blocks the kernel's walk fetches:
+:meth:`DecodeScheduler._count_attention_keys`). Multi-LoRA adds
 ``serving/adapter_{loads,evicts}`` + per-adapter
 ``serving/adapter/<id>/{loads,evicts,requests,tokens}`` (256-label cap),
 ``serving/adapter_swap_ms``, ``serving/adapter_kv_invalidated_tokens``, and
@@ -696,6 +700,8 @@ class DecodeScheduler:
         self.cache = SlotKVCache(engine._init_cache(int(num_slots), S, kv_dtype=kv_arg),
                                  int(num_slots), S, page_size=min(block, S),
                                  max_extents=me, kinds=kinds)
+        self._attn_walks = self._attention_walks(model, tp_ax)
+        self._walk_blocks = {}
         # self-speculative decoding: spec_tokens drafted columns verified
         # per pure-decode sync (clamped so a full verify block always fits
         # one slot alongside at least one row of decode headroom)
@@ -2251,12 +2257,14 @@ class DecodeScheduler:
             self.migrate_hook(self, preq)  # True: migrated out, owned elsewhere
         return delivered
 
-    def _dispatch(self, fn, call_args, step_args, spans):
+    def _dispatch(self, fn, call_args, step_args, spans, lens, chunk=None):
         """Hand ONE compiled program to the device, under ``sched/dispatch``
         (whose start closes the open host gap: the device stops being idle
         the moment the dispatch is enqueued), and count the rows its
-        forwards compute beside the live ones among them (``spans``: the
-        host's copy of the spans at ``step_args[4]``). On a sampled sync,
+        forwards compute beside the live ones among them, and the keys its
+        attention reads (``spans``, ``lens``: the host's copies of the spans
+        at ``step_args[4]`` and the lengths at ``step_args[3]``; ``chunk``:
+        ``(slot, final)`` of a chunk sync's prefill row). On a sampled sync,
         fences the dispatch —
         ``block_until_ready`` on the input pool (drain outstanding work) and
         on the result, each under ``sched/fence`` (the pump blocked on the
@@ -2276,6 +2284,10 @@ class DecodeScheduler:
                                    (N + width if split else N * width) + N * (ksteps - 1))
             self.telemetry.counter("serving/step_rows_live", int(spans.sum())
                                    + int(np.count_nonzero(spans)) * (ksteps - 1))
+            self._count_attention_rows(lens, spans, ksteps, chunk)
+            # the programs whose attention walks a row's extent chain
+            ext_walk = key is not None and key[0] in ("fused_ext", "fused_seqp")
+            self._count_attention_keys(lens, spans, width, ksteps, split, chunk, ext_walk)
         if cap is None or not self._cap_sample:
             with self._span("sched/dispatch"), self.engine.mesh:
                 return self._run_program(fn, call_args)
@@ -2290,12 +2302,10 @@ class DecodeScheduler:
             jax.block_until_ready(out)
         dur = time.perf_counter() - t0
         if key is not None:
-            lens = np.asarray(step_args[3])
             live_ctx = lens[spans > 0] if spans.shape == lens.shape else lens
             # the extent-walk kernels DMA every extent's pool column per KV
             # block, so their KV traffic prices at max_extents x contiguous
-            kv_mult = (self.cache.max_extents
-                       if key[0] in ("fused_ext", "fused_seqp") else 1)
+            kv_mult = self.cache.max_extents if ext_walk else 1
             cap.observe_dispatch(key, dur, live_ctx, width, ksteps,
                                  kv_mult=kv_mult, split=split)
         return out
@@ -2368,10 +2378,117 @@ class DecodeScheduler:
             tel.counter("serving/cross_decoder_rows_unread",
                         int(spans[chunk[0]]) - int(chunk[1]))
 
-    def _call_step(self, fn, args, lora, spans):
-        """Dispatch ONE step program (``spans``: the host's copy of
-        ``args[4]``, for :meth:`_dispatch`'s row counters), owning the MoE
-        serving plumbing:
+    @staticmethod
+    def _attention_walks(model, tp):
+        """The layers whose attention over the slot pool runs the paged
+        decode kernel (``ops/pallas/decode_attention.py``), grouped by what
+        its walk depends on: ``(layers, ring rows, window, (kv heads, query
+        heads a kv head, head size, rows a slot, q dtype, K/V dtype,
+        quantized, packed))`` for the layers that read rows that grow (ring
+        rows and window 0; a cross-attention layer reads the full layer's),
+        a windowed layer's ring (the ring's rows), and a plain layer with a
+        sliding window (which raises a column's first key). The geometry is
+        the pool leaf's, as the model's code hands it to the kernel. Empty
+        where no layer does: XLA's attention, ALiBi, a latent pool."""
+        from ..models.transformer import kv_packs
+        cfg = model.cfg
+        if (getattr(cfg, "attention_impl", "xla") != "flash" or getattr(cfg, "latent_width", 0)
+                or getattr(cfg, "pos_embedding", None) == "alibi"
+                or not hasattr(model, "cache_spec")):
+            return []
+        carries = getattr(cfg, "carries_across_layers", False)
+        if carries and tp > 1:
+            return []
+        shard = tp if (tp > 1 and getattr(cfg, "bitwise_tp", False)
+                       and cfg.kv_heads % tp == 0 and cfg.num_heads % tp == 0) else 1
+        groups = collections.Counter()
+        for i in range(cfg.num_layers):
+            kind = cfg.layer_type(i) if hasattr(cfg, "layer_type") else "full_attention"
+            window = cfg.layer_window(i) if hasattr(cfg, "layer_window") else 0
+            if carries:
+                if kind == "diff_attention" and window:
+                    if cfg.ring_rows(i) != window:
+                        continue  # such a ring is read through XLA's attention
+                    groups[(window, 0, cfg.kv_heads // 2, 2 * cfg.head_size, False)] += 1
+                elif kind in ("diff_attention", "cross_attention"):
+                    groups[(0, 0, cfg.kv_heads // 2, 2 * cfg.head_size, False)] += 1
+            elif kind == "full_attention":
+                groups[(0, window, cfg.kv_heads // shard, cfg.head_size,
+                        kv_packs(cfg.head_size))] += 1
+        return [(n, ring, window, (nkv, cfg.num_heads // shard // nkv, D, packed))
+                for (ring, window, nkv, D, packed), n in groups.items()]
+
+    def _walk_block(self, group, span, ext):
+        """(keys a block, blocks a row's extents hold) of the kernel's walk
+        for a layer group at a query span: the kernel module's own choice."""
+        key = (group, span, ext)
+        if key not in self._walk_blocks:
+            from ..ops.pallas.decode_attention import walk_block_kv
+            _, ring, _, (nkv, rep, D, packed) = self._attn_walks[group]
+            rows = ring or self.max_len
+            cfg = self.engine.module.cfg
+            kv_dtype = jnp.int8 if self.kv_quantized else jax.tree_util.tree_leaves(
+                self.cache.pool)[0].dtype
+            bkv = walk_block_kv(nkv, rep * span, D, rows, cfg.decode_block_kv, cfg.dtype,
+                                kv_dtype, self.kv_quantized, packed)
+            self._walk_blocks[key] = bkv, (self.cache.max_extents if ext else 1) * rows // bkv
+        return self._walk_blocks[key]
+
+    def _count_attention_keys(self, lens, spans, width, ksteps, split, chunk, ext):
+        """With the sink on, for every model whose attention runs the paged
+        decode kernel: ``serving/attn_keys_live``, the keys inside the rows'
+        attended windows, and ``serving/attn_keys_walked``, the same rounded
+        out to the blocks the kernel's walk fetches (its own arithmetic:
+        ``decode_attention.walked_keys`` at ``walk_block_kv``), from the
+        host's copies of the lengths and spans, summed over slots, a step
+        program's forwards (:meth:`_fused_fn`: the first forward whole or as
+        a column and the chunk's (1, C), then the substeps) and layers. A
+        forward counts a row's keys once whatever its number of columns; one
+        that runs a layer through XLA's attention (a windowed layer's chunk)
+        counts nothing for it. The seq-sharded call counts as the whole."""
+        if not self._attn_walks:
+            return
+        from ..ops.pallas.decode_attention import walked_keys
+        lens, spans = lens.astype(np.int64), spans.astype(np.int64)
+        on = spans > 0
+        # (one past each row's write head, keys of its window, the call's span)
+        if width == 1 or not split:
+            forwards = [(np.where(on, lens + 1, 0), np.where(on, lens + spans, 0), width)]
+        else:
+            column = spans == 1
+            forwards = [(np.where(column, lens + 1, 0), ) * 2 + (1, )]
+            if (spans > 1).any():
+                ps = int(np.argmax(spans > 1))
+                forwards.append((lens[ps:ps + 1] + 1, lens[ps:ps + 1] + spans[ps], width))
+        stepping = on.copy()
+        if self._state_pool and chunk is not None and not chunk[1]:
+            stepping[chunk[0]] = False  # _substep_spans: the row stands still
+        if ksteps > 1:  # the substeps at once: (ksteps - 1, N), a key further each
+            ends = np.where(stepping, lens + np.maximum(spans, 1)
+                            + np.arange(1, ksteps)[:, None], 0)
+            forwards.append((ends, ends, 1))
+        live = walked = 0
+        for g, (n, ring, window, _) in enumerate(self._attn_walks):
+            for ends, keys, span in forwards:
+                if span > 1 and (ring or window):
+                    continue
+                start = np.zeros_like(ends)
+                if ring:
+                    ends = keys = np.minimum(ends, ring)
+                elif window:
+                    start = np.maximum(ends - window, 0)
+                    keys = ends - start
+                bkv, blocks = self._walk_block(g, span, ext)
+                live += n * int(keys.sum())
+                walked += n * walked_keys(start, ends, span, bkv, blocks)
+        self.telemetry.counter("serving/attn_keys_live", live)
+        self.telemetry.counter("serving/attn_keys_walked", walked)
+
+    def _call_step(self, fn, args, lora, spans, lens, chunk=None):
+        """Dispatch ONE step program (``spans``, ``lens``: the host's copies
+        of ``args[4]`` and ``args[3]``, and ``chunk``: ``(slot, final)`` of a
+        chunk sync's prefill row, for :meth:`_dispatch`'s counters), owning
+        the MoE serving plumbing:
 
         - dense models (or MoE with telemetry off and no offload): a plain
           dispatch, byte-identical to the pre-MoE scheduler;
@@ -2392,7 +2509,7 @@ class DecodeScheduler:
         """
         extra = (lora, ) if lora is not None else ()
         if self.experts is None:
-            return self._dispatch(fn, args + extra, args, spans)
+            return self._dispatch(fn, args + extra, args, spans, lens, chunk)
         replays = 0
         # hard bound on the replay loop: each round loads at least one page
         # on this replica, so L*E rounds can only be exceeded by pathological
@@ -2400,7 +2517,7 @@ class DecodeScheduler:
         max_replays = 2 * self.experts.num_layers * self.experts.num_experts + 8
         while True:
             emap, pools, resident = self.experts.dispatch_operands()
-            out = self._dispatch(fn, args + extra + ((emap, pools), ), args, spans)
+            out = self._dispatch(fn, args + extra + ((emap, pools), ), args, spans, lens, chunk)
             counts = np.asarray(jax.device_get(out[-1]))[:, :-2]
             used = counts > 0
             if not self.experts.missing(used, resident).any():
@@ -2507,7 +2624,7 @@ class DecodeScheduler:
                     if eo is not None:
                         args = args + tuple(jnp.asarray(x) for x in eo)
                 try:
-                    out = self._call_step(fn, args, lora, spans)
+                    out = self._call_step(fn, args, lora, spans, lens)
                     break
                 except _ExpertOverflow as e:
                     self.cache.pool = e.pool
@@ -2576,7 +2693,7 @@ class DecodeScheduler:
                 if eo is not None:
                     args = args + tuple(jnp.asarray(x) for x in eo)
                 try:
-                    out = self._call_step(fn, args, lora, spans)
+                    out = self._call_step(fn, args, lora, spans, lens)
                     break
                 except _ExpertOverflow as e:
                     self.cache.pool = e.pool
@@ -2637,7 +2754,7 @@ class DecodeScheduler:
                     jnp.asarray(np.ones(N, np.float32)), jnp.asarray(zeros),
                     jnp.asarray(np.ones(N, np.float32))) + tuple(ext_args) \
                 + self._substep_spans(zeros)
-            out = self._call_step(fn, args, lora, zeros)
+            out = self._call_step(fn, args, lora, zeros, zeros)
             self.cache.pool = out[0]
 
         shapes = sorted({(K, C), (1, C), (K, 1)} | ({(1, 1)} if ladder else set()))
@@ -2739,9 +2856,8 @@ class DecodeScheduler:
             if eo is not None:
                 args = args + tuple(jnp.asarray(x) for x in eo)
             args = args + self._substep_spans(spans)
-            self._count_attention_rows(lens, spans, K)
         try:
-            out = self._call_step(fn, args, lora, spans)
+            out = self._call_step(fn, args, lora, spans, lens)
         except _ExpertOverflow as e:
             # a K-step sync's routing union outgrew the expert pool: advance
             # one token per row in overflow-safe groups instead
@@ -2815,7 +2931,7 @@ class DecodeScheduler:
                     jnp.asarray(seeds), jnp.asarray(steps), jnp.asarray(flags),
                     jnp.asarray(temps), jnp.asarray(topks), jnp.asarray(topps))
         try:
-            out = self._call_step(fn, args, lora, spans)
+            out = self._call_step(fn, args, lora, spans, lens)
         except _ExpertOverflow as e:
             # speculation is opportunistic — skip it for this sync and
             # advance one exact token per row (bit-identical either way)
@@ -2958,9 +3074,8 @@ class DecodeScheduler:
             if eo is not None:
                 args = args + tuple(jnp.asarray(x) for x in eo)
             args = args + self._substep_spans(spans, held=None if final else ps)
-            self._count_attention_rows(lens, spans, K, chunk=(ps, final))
         try:
-            out = self._call_step(fn, args, lora, spans)
+            out = self._call_step(fn, args, lora, spans, lens, chunk=(ps, final))
         except _ExpertOverflow as e:
             # the chunk's routing demand outgrew the expert pool: feed the
             # prefill alone in shrinking pieces, then advance decode rows

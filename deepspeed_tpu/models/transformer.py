@@ -682,6 +682,15 @@ def _paged_kernel_kw(kv_scale, ext_ops, tp_shard):
     return kw
 
 
+def _attended_ends(write_index, q_spans):
+    """One past each row's write head as the paged kernels take it: 0 for a
+    slot whose span is 0 (idle, cached, a prefill row riding another
+    forward), so the kernel's walk reads nothing for a row nobody reads.
+    Without spans every row is live."""
+    ends = write_index + 1
+    return ends if q_spans is None else jnp.where(q_spans > 0, ends, 0)
+
+
 def kv_packs(head_size):
     """THE rule of the K/V pool's geometry: a layer's K and V rest side by
     side in one leaf ``(slots, kv_heads, S, 2 * head_size)`` where that
@@ -1264,7 +1273,7 @@ class Attention(nn.Module):
                     starts = jnp.maximum(starts, cache_index + 1 - window)
                 if write_index is not None:
                     out = paged_decode_attention(
-                        q[:, :, 0], ck, cv, starts, write_index + 1,
+                        q[:, :, 0], ck, cv, starts, _attended_ends(write_index, q_spans),
                         block_kv=cfg.decode_block_kv,
                         **_paged_kernel_kw(csc, ext_ops, tp_kernel_shard))[:, :, None]
                 else:
@@ -1283,15 +1292,16 @@ class Attention(nn.Module):
                 else:
                     starts = jnp.zeros((B, ), jnp.int32)
                 kw = _paged_kernel_kw(csc, ext_ops, tp_kernel_shard)
+                base = _attended_ends(write_index, q_spans) - 1
                 if seq_shard:
                     # sequence-parallel chunked prefill: shards split the
                     # chunk's query columns over the seq axis; KV (already
                     # written, replicated) streams whole on every shard
                     out = seq_sharded_span_attention(
-                        q, ck, cv, starts, write_index, mesh=dist.get_mesh(),
+                        q, ck, cv, starts, base, mesh=dist.get_mesh(),
                         axis=dist.SEQ_AXIS, block_kv=cfg.decode_block_kv, **kw)
                 else:
-                    out = paged_span_attention(q, ck, cv, starts, write_index,
+                    out = paged_span_attention(q, ck, cv, starts, base,
                                                block_kv=cfg.decode_block_kv, **kw)
             elif (cfg.attention_impl == "flash" and attn_mask is None and T >= 128
                   and isinstance(cache_index, int) and cache_index == 0 and alibi is None
@@ -1978,11 +1988,12 @@ class DiffAttention(nn.Module):
                     from ..ops.pallas.decode_attention import paged_decode_attention, \
                         paged_span_attention
                     starts = jnp.zeros((B, ), jnp.int32)
+                    ends = _attended_ends(write_index, q_spans)
                     if T == 1:
-                        out = paged_decode_attention(q[:, :, 0], ck, cv, starts, write_index + 1,
+                        out = paged_decode_attention(q[:, :, 0], ck, cv, starts, ends,
                                                      **kernel_kw)[:, :, None]
                     else:
-                        out = paged_span_attention(q, ck, cv, starts, write_index, **kernel_kw)
+                        out = paged_span_attention(q, ck, cv, starts, ends - 1, **kernel_kw)
                 else:
                     qpos = write_index[:, None] + jnp.arange(T)[None, :]
                     keep = jnp.arange(ck.shape[2])[None, None, :] <= qpos[:, :, None]
@@ -2017,7 +2028,8 @@ class DiffAttention(nn.Module):
             from ..ops.pallas.decode_attention import paged_decode_attention
             ck, cv = _commit_span_rows([(rk, k), (rv, v)], write_index % R, q_spans, True)
             out = paged_decode_attention(q[:, :, 0], ck, cv, jnp.zeros((B, ), jnp.int32),
-                                         jnp.minimum(write_index + 1, R), **kernel_kw)
+                                         jnp.minimum(_attended_ends(write_index, q_spans), R),
+                                         **kernel_kw)
             return out[:, :, None], (ck, cv)
         p0, j = write_index[:, None], jnp.arange(T)[None, :]
         held = p0 - 1 - ((p0 - 1 - jnp.arange(R)[None, :]) % R)  # (B, R)
@@ -2789,6 +2801,7 @@ class CausalLMModel:
                                   cfg.rope_theta)
             rope = (sin[pos_flat], cos[pos_flat], nh + nkv, hd)
         starts = jnp.zeros((N, ), jnp.int32)
+        ends = _attended_ends(write_index, q_spans)
         new_layers = []
         for i, (norms, qkv, o, up, down, gate) in enumerate(layers):
             y = fused_qkv_ln(x2d, norms, qkv, eps=cfg.layernorm_epsilon,
@@ -2805,14 +2818,14 @@ class CausalLMModel:
             ck, cv, csc = _written_kv(written, kv_split, quant_kv)
             if C == 1:
                 out = paged_decode_attention(
-                    qf.reshape(N, nh, hd), ck, cv, starts, write_index + 1,
+                    qf.reshape(N, nh, hd), ck, cv, starts, ends,
                     block_kv=cfg.decode_block_kv,
                     k_scale=csc, v_scale=csc)
                 attn2d = out.astype(cfg.dtype).reshape(N, nh * hd)
             else:
                 q4 = qf.reshape(N, C, nh, hd).transpose(0, 2, 1, 3)
                 out = paged_span_attention(
-                    q4, ck, cv, starts, write_index,
+                    q4, ck, cv, starts, ends - 1,
                     block_kv=cfg.decode_block_kv,
                     k_scale=csc, v_scale=csc)
                 attn2d = out.astype(cfg.dtype).transpose(0, 2, 1, 3) \

@@ -17,18 +17,26 @@ the form these kernels read, so a program that carries the pool relays
 nothing; a KV block is one operand and one DMA a grid step, half the VMEM
 and half the padded bytes of the split pair.
 
-Kernel shape: one kernel, grid ``(rows, kv-head blocks, KV blocks)``. A
-program owns one batch row (cache slot) and a block of its kv heads and
-walks that row's KV blocks with an online softmax. Everything per-row is a
-scalar-prefetch operand, so the row's window ``[start_i, end_i)`` masks
-with scalars, blocks past the row's OWN write head are skipped (the index
-map clamps to the last live block — no re-DMA — and ``pl.when`` skips the
-compute, so a short row beside a long one pays only for its own context),
-and the physical pool row of each block comes from the index map. The
-kv-head block and the KV block are sized from the operand shapes and
-dtypes against the chip's VMEM budget (:func:`_pick_blocks`): the
-all-heads-in-one-step layout this replaces needed 40 MB of VMEM at
-gpt2-large widths and was refused by the v5e compiler.
+Kernel shape: one kernel, grid ``(rows, kv-head blocks)``. A program owns
+one batch row (cache slot) and a block of its kv heads; the pool stays in
+HBM and the program walks that row's LIVE KV blocks itself, in a loop whose
+trip count it reads from its scalar-prefetch operands: the blocks from the
+row's first attendable key (``start_i``) to its causal end (``end_i + span -
+1``), each copied into one of two VMEM buffers while the block before it is
+computed (online softmax), its physical pool row read from the extent
+table. A block past the row's OWN write head costs nothing, not a grid step
+and not a descriptor, so a short row beside a long one pays for its own
+context and a row with ``end_i <= start_i`` (an idle slot: the callers hand
+it ``end = 0``) for one grid step and no copy. Before a program computes its
+last block it starts the first block of the next program that has one (the
+buffer index carries over the grid step in SMEM), so a call exposes one
+copy's latency, its first, and not one a row. The kv-head block and the KV
+block are sized from the operand shapes and dtypes against the chip's VMEM
+budget and against the fixed cost of a loop iteration
+(:func:`_pick_blocks`); the all-heads-in-one-step layout of the first
+version needed 40 MB of VMEM at gpt2-large widths and was refused by the
+v5e compiler, and the grid that walked the POOL's blocks (to PR 34) spent a
+quarter of a chat-traffic call on steps past the rows' ends.
 
 ``start`` masks left-padding slots of batched generation; ``end`` is the
 write head. Entry points:
@@ -48,8 +56,8 @@ Both paged entry points take the long-context and sharding operands:
 
 - ``ext`` — one request's KV spans SEVERAL pool slots ("extents") through
   a per-row extent table: logical position ``p`` of row ``i`` lives at
-  physical pool row ``ext[i, p // S]``, offset ``p % S``. The grid walks
-  LOGICAL blocks (``E * S/block_kv``) and the KV index map reads the table,
+  physical pool row ``ext[i, p // S]``, offset ``p % S``. The kernel walks
+  LOGICAL blocks (up to ``E * S/block_kv``) and reads the table for each copy,
   so the extent count stays an OPERAND (table values), never a shape — the
   O(1)-compiled-programs guard holds across any extent mix, and an identity
   table (``ext[i, 0] == i``) is bit-identical to no table. ``sink``/
@@ -69,6 +77,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -80,28 +89,46 @@ _NO_WINDOW = 1 << 30  # window == 0 means "exact": end - _NO_WINDOW masks nothin
 
 def _attn_kernel(ext_ref, start_ref, end_ref, sink_ref, win_ref, q_ref, *rest,
                  scale, block_kv, span, quantized, lossy, packed):
-    """One (row, kv-head block) program walking that row's KV blocks.
+    """One (row, kv-head block) program: walks that row's LIVE KV blocks in a
+    loop of its own, each block copied from the pool in HBM into one of two
+    VMEM buffers while the block before it is computed.
 
     ``q_ref``: (1, bh, g, D) where ``g`` is the FOLDED query axis:
     head-groups x span columns, span fastest. With ``span > 1`` the row's
     ``end`` is the causal end of column 0 and column j attends j more keys
     (per-row mixed decode/prefill query spans share this one kernel).
 
-    ``packed``: ONE KV block (1, bh, block_kv, 2 * D) follows ``q_ref``
-    where the split form has a K and a V block of (1, bh, block_kv, D):
-    lanes ``[0, D)`` of a row are its key, ``[D, 2 * D)`` its value (the
-    packed pool of ``CausalLMModel.init_cache``). The block is never
-    sliced: ``q_ref`` arrives zero-extended to 2 * D lanes, so ``q . block``
-    is ``q . K`` exactly (the value lanes meet zeros), and ``p @ block``
-    holds ``p @ V`` in lanes ``[D, 2 * D)``, which the flush keeps. The
-    split form's arithmetic, bit for bit, with one f32 copy of a
-    lane-dense block where it made two of half-empty ones.
+    The walk. After ``q_ref`` come the cache leaves as they rest in HBM
+    (whole, ``memory_space=pl.ANY``), for an int8 pool its two (Npool, 1, S)
+    scale planes, the output block, and the scratch: a (2, ...) VMEM buffer
+    and a pair of DMA semaphores for each of those operands, two scalars of
+    carried state in SMEM and the online softmax's state. A row attends the
+    logical blocks ``[start // block_kv, ceil((end + span - 1) / block_kv))``
+    (:func:`_walk`): the trip count is the row's own, read from the scalar
+    operands, so a block past a row's write head costs nothing, not a grid
+    step and not a descriptor. Block ``t + 1`` is in flight while block ``t``
+    is computed, and before a (row, head block)'s LAST block is computed the
+    FIRST block of the next program that has one is started into the other
+    buffer (the buffer index and the fact that a copy is in flight carry
+    over the grid step in SMEM), so a row exposes no copy's latency but the
+    call's first. A row with ``end <= start`` attends nothing: no copy, and
+    zeros out.
 
-    ``quantized``: the KV blocks are int8 with per-token-row scales (two
-    extra (1, 1, block_kv) operands). The K scale multiplies the scores and
-    the V scale the probabilities — both have keys on the lane axis, as the
-    scale blocks do, so dequantization needs no relayout and the bf16/f32
-    KV never exists in HBM.
+    ``packed``: ONE leaf (Npool, nkv, S, 2 * D) where the split form has a K
+    and a V leaf of (Npool, nkv, S, D): lanes ``[0, D)`` of a row are its
+    key, ``[D, 2 * D)`` its value (the packed pool of
+    ``CausalLMModel.init_cache``). The block is never sliced: ``q_ref``
+    arrives zero-extended to 2 * D lanes, so ``q . block`` is ``q . K``
+    exactly (the value lanes meet zeros), and ``p @ block`` holds ``p @ V``
+    in lanes ``[D, 2 * D)``, which the flush keeps. The split form's
+    arithmetic, bit for bit, with one f32 copy of a lane-dense block where it
+    made two of half-empty ones.
+
+    ``quantized``: the KV blocks are int8 with per-token-row scales (a
+    (1, block_kv) block of each plane rides with its KV block). The K scale
+    multiplies the scores and the V scale the probabilities — both have keys
+    on the lane axis, as the scale blocks do, so dequantization needs no
+    relayout and the bf16/f32 KV never exists in HBM.
 
     ``lossy``: ``sink_ref``/``win_ref`` carry per-row attention-sink +
     sliding-window knobs — a row with ``win > 0`` additionally masks
@@ -109,33 +136,49 @@ def _attn_kernel(ext_ref, start_ref, end_ref, sink_ref, win_ref, q_ref, *rest,
     ``win == 0`` leaves the exact mask untouched, so lossy and exact rows
     share one compiled program."""
     n_kv = 1 if packed else 2
-    kv_refs, rest = rest[:n_kv], rest[n_kv:]
-    if quantized:
-        ks_ref, vs_ref, o_ref, m_s, l_s, acc_s = rest
-    else:
-        o_ref, m_s, l_s, acc_s = rest
-    i = pl.program_id(0)
-    j = pl.program_id(2)
-    start = start_ref[i]
-    end = end_ref[i]
+    n_src = n_kv + (2 if quantized else 0)
+    srcs, o_ref, bufs = rest[:n_src], rest[n_src], rest[n_src + 1:2 * n_src + 1]
+    sems, carry, m_s, l_s, acc_s = rest[2 * n_src + 1:]
+    i, h = pl.program_id(0), pl.program_id(1)
+    B, n_hb = pl.num_programs(0), pl.num_programs(1)
+    bh = q_ref.shape[1]
+    S = srcs[0].shape[2]
+    bpe = S // block_kv                    # blocks an extent
+    E = ext_ref.shape[0] // start_ref.shape[0]
+    start, end = start_ref[i], end_ref[i]
 
-    @pl.when(j == 0)
-    def _init():
-        m_s[...] = jnp.full_like(m_s, -jnp.inf)
-        l_s[...] = jnp.zeros_like(l_s)
-        acc_s[...] = jnp.zeros_like(acc_s)
+    def walk(r):
+        r = jnp.minimum(r, B - 1)
+        return _walk(start_ref[r], end_ref[r], span, block_kv, E * bpe)
 
-    kv_start = j * block_kv  # LOGICAL position of this block's first key
+    def attends(r):
+        lo_r, hi_r = walk(r)
+        return hi_r > lo_r
 
-    @pl.when(kv_start < end + (span - 1))
-    def _block():
+    def copies(r, hb, t, slot):
+        """The copies of row ``r``'s logical block ``t`` (head block ``hb``)
+        into buffer ``slot``: the extent table names the pool row."""
+        pool_row = jnp.maximum(ext_ref[r * E + t // bpe], 0)
+        keys = pl.ds(pl.multiple_of((t % bpe) * block_kv, block_kv), block_kv)
+        views = [src.at[pool_row, pl.ds(hb * bh, bh), keys] for src in srcs[:n_kv]]
+        views += [src.at[pool_row, :, keys] for src in srcs[n_kv:]]
+        return [pltpu.make_async_copy(view, buf.at[slot], sems.at[n, slot])
+                for n, (view, buf) in enumerate(zip(views, bufs))]
+
+    @pl.when((i == 0) & (h == 0))
+    def _reset():
+        carry[0] = 0  # the buffer the next block to compute lands in
+        carry[1] = 0  # whether the program before this one started that copy
+
+    def block(t, slot):
+        kv_start = t * block_kv  # LOGICAL position of this block's first key
         q = q_ref[0].astype(jnp.float32) * scale  # (bh, g, D), or 2 * D packed
-        k = kv_refs[0][0].astype(jnp.float32)     # (bh, bkv, D), or 2 * D packed
-        v = k if packed else kv_refs[1][0].astype(jnp.float32)
+        k = bufs[0][slot].astype(jnp.float32)     # (bh, bkv, D), or 2 * D packed
+        v = k if packed else bufs[1][slot].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((2, ), (2, )), ((0, ), (0, ))),
                                 preferred_element_type=jnp.float32)  # (bh, g, bkv)
         if quantized:
-            s = s * ks_ref[...]
+            s = s * bufs[n_kv][slot]
         g = s.shape[1]
         kv_pos = kv_start + jax.lax.broadcasted_iota(jnp.int32, (1, g, block_kv), 2)
         end_col = end
@@ -157,20 +200,84 @@ def _attn_kernel(ext_ref, start_ref, end_ref, sink_ref, win_ref, q_ref, *rest,
         alpha = jnp.exp(m_prev - m_new)
         l_s[...] = l_s[...] * alpha + jnp.sum(p, axis=2, keepdims=True)
         if quantized:
-            p = p * vs_ref[...]
+            p = p * bufs[n_kv + 1][slot]
         pv = jax.lax.dot_general(p, v, (((2, ), (1, )), ((0, ), (0, ))),
                                  preferred_element_type=jnp.float32)  # (bh, g, D)
         acc_s[...] = acc_s[...] * alpha + pv
         m_s[...] = m_new
 
-    @pl.when(j == pl.num_programs(2) - 1)
-    def _flush():
+    lo, hi = walk(i)
+
+    @pl.when(hi <= lo)
+    def _nothing():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(hi > lo)
+    def _row():
+        slot0 = carry[0]
+
+        @pl.when(carry[1] == 0)
+        def _first():
+            for c in copies(i, h, lo, slot0):
+                c.start()
+
+        m_s[...] = jnp.full_like(m_s, -jnp.inf)
+        l_s[...] = jnp.zeros_like(l_s)
+        acc_s[...] = jnp.zeros_like(acc_s)
+
+        # the program after this one that has a block to walk: this row's
+        # next head block, or head block 0 of the next row that attends
+        wraps = h + 1 == n_hb
+        nxt = jax.lax.while_loop(lambda r: (r < B) & jnp.logical_not(attends(r)),
+                                 lambda r: r + 1, i + 1)
+        n_row, n_hd = jnp.where(wraps, nxt, i), jnp.where(wraps, 0, h + 1)
+        n_lo = walk(n_row)[0]
+
+        def step(t, slot):
+            last = t + 1 == hi
+
+            @pl.when(jnp.logical_not(last) | (n_row < B))
+            def _ahead():
+                for c in copies(jnp.where(last, jnp.minimum(n_row, B - 1), i),
+                                jnp.where(last, n_hd, h),
+                                jnp.where(last, n_lo, t + 1), 1 - slot):
+                    c.start()
+
+            for c in copies(i, h, t, slot):
+                c.wait()
+            block(t, slot)
+            return 1 - slot
+
+        carry[0] = jax.lax.fori_loop(lo, hi, step, slot0)
+        carry[1] = (n_row < B).astype(jnp.int32)
+
         l = l_s[...]
         l = jnp.where(l == 0, 1.0, l)
         out = acc_s[...] / l
         if packed:
             out = out[:, :, o_ref.shape[-1]:]
         o_ref[0] = out.astype(o_ref.dtype)
+
+
+def _walk(start, end, span, block_kv, n_blocks, xp=jnp):
+    """``[lo, hi)``: the logical KV blocks a row attends, from its first
+    attendable key's block to its causal end's (``end + span - 1`` keys: the
+    last column's), inside the ``n_blocks`` its extents hold; ``hi <= lo``
+    for a row with ``end <= start``, which attends nothing. The kernel's
+    trip count, and with ``xp=numpy`` on the host's copies of the same
+    scalars the scheduler's count of the keys a walk fetches
+    (:func:`walked_keys`): one function, so the two cannot drift."""
+    lo = start // block_kv
+    hi = xp.minimum((end + (span - 1) + (block_kv - 1)) // block_kv, n_blocks)
+    return lo, xp.where(end > start, hi, lo)
+
+
+def walked_keys(start, end, span, block_kv, n_blocks):
+    """Keys the kernel's walk fetches for rows attending ``[start, end +
+    span - 1)`` (numpy arrays, on the host), summed: whole blocks of
+    ``block_kv`` (:func:`walk_block_kv`), whatever part of each is attended."""
+    lo, hi = _walk(start, end, span, block_kv, n_blocks, xp=np)
+    return int(((hi - lo) * block_kv).sum())
 
 
 def _pad(n, m):
@@ -182,19 +289,20 @@ def _vmem_estimate(bh, bkv, g, D, q_bytes, kv_bytes, quantized, packed=False):
     way Mosaic lays blocks out: the last dim pads to 128 lanes (so a split
     K or V block of ``D == 64`` costs as much as one of 128, and the packed
     block of ``2 * D`` lanes costs what ONE of them does), the
-    second-to-last to 8 sublanes x the dtype's packing, and every pipelined
-    operand is double-buffered. Of the in-kernel values the compiler keeps
-    about one f32 K/V copy and the score and probability planes in VMEM.
-    Checked against the least ``vmem_limit_bytes`` the v5e compiler accepts
-    at twelve (heads, g, D, block) points of the split form: the estimate
-    ran 1.4x-2.2x above it, never below."""
+    second-to-last to 8 sublanes x the dtype's packing; the pipelined q and
+    output blocks are double-buffered, and so are the K/V (and scale)
+    blocks, in the two buffers the kernel's walk owns. Of the in-kernel
+    values the compiler keeps about one f32 K/V copy and the score and
+    probability planes in VMEM. Checked against the least
+    ``vmem_limit_bytes`` the v5e compiler accepts at twelve (heads, g, D,
+    block) points of the split form: the estimate ran 1.4x-2.2x above it,
+    never below."""
     Dp = _pad(D, 128)
     Dq = _pad(2 * D, 128) if packed else Dp        # q, the numerator and the f32 block
     g8 = _pad(g, 8)
     gq = _pad(g, 8 * (4 // q_bytes))
-    kv_rows = _pad(bkv, 8 * (4 // kv_bytes))
     io = 2 * bh * gq * (Dq + Dp) * q_bytes         # q + out, double-buffered
-    io += 2 * bh * kv_rows * (Dq if packed else 2 * Dp) * kv_bytes  # k and v, double-buffered
+    io += 2 * _block_bytes(bh, bkv, D, kv_bytes, packed)  # the walk's two K/V buffers
     if quantized:
         io += 2 * 2 * 8 * _pad(bkv, 128) * 4
     scratch = bh * g8 * (2 * 128 + Dq) * 4         # m, l (lane-padded), acc
@@ -202,13 +310,33 @@ def _vmem_estimate(bh, bkv, g, D, q_bytes, kv_bytes, quantized, packed=False):
     return io + scratch + temps
 
 
+def _block_bytes(bh, bkv, D, kv_bytes, packed):
+    """Bytes of one walked block in VMEM: ``bh`` heads' ``bkv`` keys and
+    values, lanes padded to 128 and rows to the dtype's sublane packing."""
+    lanes = _pad(2 * D, 128) if packed else 2 * _pad(D, 128)
+    return bh * _pad(bkv, 8 * (4 // kv_bytes)) * lanes * kv_bytes
+
+
+# A loop iteration of the walk costs ~0.3 us whatever it copies (its
+# descriptors, two semaphore waits, the softmax state's round trip), so a
+# block pays for itself once its copy takes a few times that: ~2 MiB at the
+# v5e's 819 GB/s. Past that a larger block only reads more keys beyond a
+# row's end. On the chip (PR 35, the kernel alone over ragged rows, us a
+# call at 128 / 256 / 512 keys a block): 20 heads packed at 64, 1.3 MiB at
+# 256: 42.6 / 41.3 / 70.8; 10 heads split at 128, S 4096, 1.3 MiB at 256:
+# 915 / 858 / 898; 30 heads split at 128, 3.9 MiB at 256: 579 / 663 / 762.
+_WALK_BLOCK_BYTES = 2 * 2**20
+
+
 def _pick_blocks(nkv, g, D, S, block_kv, q_dtype, kv_dtype, quantized, packed=False):
     """(kv-head block, KV block) for one grid step, from the operand shapes
     and dtypes and the chip's VMEM budget (``ops.pallas.VMEM_BLOCK_BUDGET``).
-    ``block_kv`` is the caller's setting and the upper bound: it is kept
-    while any kv-head block fits beside it (heads are independent, so a
-    smaller head block only adds grid steps), and halves through the
-    lane-aligned divisors of ``S`` when not even one head does."""
+    ``block_kv`` is the caller's setting and the upper bound; below it the
+    candidates are the lane-aligned divisors of ``S``. Each is taken with
+    the largest kv-head block that fits beside it (heads are independent, so
+    a smaller head block only adds grid steps), and the largest candidate
+    whose block copies at most ``_WALK_BLOCK_BYTES`` wins: the last one that
+    fits at all where none is that small."""
     block_kv = min(block_kv, S)
     if S % block_kv:
         raise ValueError(f"cache length {S} must be a multiple of block_kv={block_kv}")
@@ -216,38 +344,52 @@ def _pick_blocks(nkv, g, D, S, block_kv, q_dtype, kv_dtype, quantized, packed=Fa
                              if b < block_kv and S % b == 0]
     q_bytes = jnp.dtype(q_dtype).itemsize
     kv_bytes = jnp.dtype(kv_dtype).itemsize
+    best = None
     for bkv in kv_cands:
-        for bh in range(nkv, 0, -1):
-            if nkv % bh == 0 and _pallas.fits_vmem(_vmem_estimate(
-                    bh, bkv, g, D, q_bytes, kv_bytes, quantized, packed)):
-                return bh, bkv
-    raise ValueError(
-        f"decode attention: one kv head x {kv_cands[-1]} keys with {g} folded "
-        f"query columns of width {D} needs "
-        f"{_vmem_estimate(1, kv_cands[-1], g, D, q_bytes, kv_bytes, quantized, packed)} "
-        f"bytes of VMEM, over the {_pallas.VMEM_BLOCK_BUDGET}-byte budget; "
-        f"narrow the query span (prefill_chunk)")
+        bh = next((b for b in range(nkv, 0, -1) if nkv % b == 0 and _pallas.fits_vmem(
+            _vmem_estimate(b, bkv, g, D, q_bytes, kv_bytes, quantized, packed))), None)
+        if bh is None:
+            continue
+        best = bh, bkv
+        if _block_bytes(bh, bkv, D, kv_bytes, packed) <= _WALK_BLOCK_BYTES:
+            break
+    if best is None:
+        raise ValueError(
+            f"decode attention: one kv head x {kv_cands[-1]} keys with {g} folded "
+            f"query columns of width {D} needs "
+            f"{_vmem_estimate(1, kv_cands[-1], g, D, q_bytes, kv_bytes, quantized, packed)} "
+            f"bytes of VMEM, over the {_pallas.VMEM_BLOCK_BUDGET}-byte budget; "
+            f"narrow the query span (prefill_chunk)")
+    return best
+
+
+def walk_block_kv(nkv, g, D, S, block_kv, q_dtype, kv_dtype, quantized=False, packed=False):
+    """Keys a block of the kernel's walk holds at these shapes (``g``: query
+    heads a kv head x span columns; ``block_kv``: the caller's bound): what
+    :func:`walked_keys` rounds a row's attended window out to."""
+    return _pick_blocks(nkv, g, D, S, block_kv, q_dtype, kv_dtype, quantized, packed)[1]
 
 
 def _decode_call(qg, kv, start, ends, *, block_kv, scale, span=1,
                  k_scale=None, v_scale=None, ext=None, sink=None, win=None):
     """Shared pallas_call builder: row ``i`` attends its own window
-    ``[start_i, ends_i)`` and walks KV blocks only up to its own write
-    head. ``qg``: queries pre-folded to (B, nkv, g, D) where ``g`` =
+    ``[start_i, ends_i)`` and walks the KV blocks from its first attendable
+    key to its own write head (nothing where ``ends_i <= start_i``).
+    ``qg``: queries pre-folded to (B, nkv, g, D) where ``g`` =
     head-groups x ``span`` columns (span fastest). ``kv``: the cache
     leaves, ``(k_cache, v_cache)`` of (Npool, nkv, S, D) each or ONE packed
     ``(kv_cache, )`` of (Npool, nkv, S, 2 * D) with a row's key in lanes
-    ``[0, D)`` and its value in ``[D, 2 * D)``: one KV operand and one DMA
-    a grid step where the split form has two. ``k_scale``/``v_scale``:
+    ``[0, D)`` and its value in ``[D, 2 * D)``: one KV operand and one copy
+    a block where the split form has two. ``k_scale``/``v_scale``:
     optional (Npool, S) per-token-row dequant scales for int8 caches,
     walked in lockstep with the KV blocks.
 
     ``ext``: optional (B, E) int32 per-row extent chains over a pool of
     ``Npool`` slots — logical position ``p`` of row ``i`` lives at pool row
     ``ext[i, p // S]``, offset ``p % S``; ``start``/``ends`` are then
-    LOGICAL (up to ``E * S``). The chain is a scalar-prefetch operand read
-    by the KV index map, so each grid step DMAs exactly its row's physical
-    block and the extent count never becomes a shape. -1 marks
+    LOGICAL (up to ``E * S``). The chain is a scalar-prefetch operand the
+    kernel reads for each copy, so a block's copy names exactly its row's
+    physical block and the extent count never becomes a shape. -1 marks
     unreserved/demoted extents, which must lie outside every attended
     window; they clamp to slot 0 and are masked. Without ``ext`` row ``i``
     reads pool row ``i``. ``sink``/``win``: optional (B,) int32 lossy-mode
@@ -279,7 +421,6 @@ def _decode_jit(qg, kv, start, ends, k_scale, v_scale, ext, sink, win, *,
     if ext is None:
         ext = jnp.arange(B, dtype=jnp.int32)[:, None]
     E = ext.shape[1]
-    bpe = S // block_kv  # blocks per extent
 
     zeros = jnp.zeros((B, ), jnp.int32)
     scalars = (ext.reshape(B * E).astype(jnp.int32), start.astype(jnp.int32),
@@ -287,32 +428,19 @@ def _decode_jit(qg, kv, start, ends, k_scale, v_scale, ext, sink, win, *,
                zeros if sink is None else sink.astype(jnp.int32),
                zeros if win is None else win.astype(jnp.int32))
 
-    def walk(i, j, ext_r, end_r):
-        # clamp to the row's last LIVE logical block: skipped steps keep
-        # the previous index so no extra DMA is issued
-        last = jnp.maximum(end_r[i] + (span - 2), 0) // block_kv
-        jj = jnp.minimum(j, last)
-        return jnp.maximum(ext_r[i * E + jj // bpe], 0), jj % bpe
-
-    def kv_index(i, h, j, ext_r, start_r, end_r, sink_r, win_r):
-        slot, blk = walk(i, j, ext_r, end_r)
-        return (slot, h, blk, 0)
-
-    def sc_index(i, h, j, ext_r, start_r, end_r, sink_r, win_r):
-        slot, blk = walk(i, j, ext_r, end_r)
-        return (slot, 0, blk)
-
     if packed:
         # zero lanes against the block's value lanes (see _attn_kernel)
         qg = jnp.pad(qg, ((0, 0), ) * 3 + ((0, D), ))
     Dq = qg.shape[-1]
-    q_spec = pl.BlockSpec((1, bh, g, Dq), lambda i, h, j, *_: (i, h, 0, 0))
-    o_spec = pl.BlockSpec((1, bh, g, D), lambda i, h, j, *_: (i, h, 0, 0))
-    in_specs = [q_spec] + [pl.BlockSpec((1, bh, block_kv, lanes), kv_index)] * len(kv)
+    q_spec = pl.BlockSpec((1, bh, g, Dq), lambda i, h, *_: (i, h, 0, 0))
+    o_spec = pl.BlockSpec((1, bh, g, D), lambda i, h, *_: (i, h, 0, 0))
+    # the pool stays where it rests: the kernel copies the blocks it walks
     operands = [qg, *kv]
+    buffers = [pltpu.VMEM((2, bh, block_kv, lanes), kv[0].dtype)] * len(kv)
     if quantized:
-        in_specs += [pl.BlockSpec((1, 1, block_kv), sc_index)] * 2
         operands += [k_scale.reshape(Np, 1, S), v_scale.reshape(Np, 1, S)]
+        buffers += [pltpu.VMEM((2, 1, block_kv), jnp.float32)] * 2
+    in_specs = [q_spec] + [pl.BlockSpec(memory_space=pl.ANY)] * (len(operands) - 1)
 
     kernel = functools.partial(_attn_kernel, scale=scale, block_kv=block_kv,
                                span=span, quantized=quantized, lossy=lossy,
@@ -322,18 +450,21 @@ def _decode_jit(qg, kv, start, ends, k_scale, v_scale, ext, sink, win, *,
         name="dstpu_decode_attn",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(scalars),
-            grid=(B, nkv // bh, E * bpe),
+            grid=(B, nkv // bh),
             in_specs=in_specs,
             out_specs=o_spec,
-            scratch_shapes=[
-                pltpu.VMEM((bh, g, 1), jnp.float32),  # running max
-                pltpu.VMEM((bh, g, 1), jnp.float32),  # running denom
+            scratch_shapes=buffers + [
+                pltpu.SemaphoreType.DMA((len(buffers), 2)),
+                pltpu.SMEM((2, ), jnp.int32),          # buffer index, copy in flight
+                pltpu.VMEM((bh, g, 1), jnp.float32),   # running max
+                pltpu.VMEM((bh, g, 1), jnp.float32),   # running denom
                 pltpu.VMEM((bh, g, Dq), jnp.float32),  # running numerator
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, nkv, g, D), qg.dtype),
+        # in order: a program starts the first copy of the one after it
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=_pallas.VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(*scalars, *operands)

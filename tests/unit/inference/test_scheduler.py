@@ -978,6 +978,49 @@ def test_split_pays_at_both_ridges(n, c, bf16, int8):
     assert _split_pays(n, c, 1) == int8
 
 
+@pytest.mark.parametrize("case", ["whole_block", "split", "sink_off"])
+def test_attention_key_counters(tmp_path, case):
+    """``serving/attn_keys_live`` and ``serving/attn_keys_walked`` against a
+    hand count: three slots of 128 rows in 32-key blocks, 2 attention layers,
+    K = 2, chunk 16, the serial order. Slot 0 takes a 16-token prompt (one
+    final chunk) and decodes 9 tokens, slot 1 a 20-token prompt (16 + 4) and
+    3 tokens, slot 2 stays idle. A (3, 16) forward's span reaches 15 keys
+    past a row's column: row 0 at 18 and 20 keys walks two blocks there and
+    one as a column of the split forward. Nothing is counted with the sink off."""
+    tel = {"enabled": True, "output_path": str(tmp_path)} if case != "sink_off" else {}
+    eng = make_engine(kernel_inject=True, decode_block_kv=32, max_out_tokens=128,
+                      continuous_batching=dict(enabled=True, num_slots=3, steps_per_sync=2,
+                                               prefill_chunk=16), telemetry=tel)
+    sched = eng.scheduler()
+    assert sched.max_len == 128 and eng.module.cfg.num_layers == 2
+    sched._splits_chunk = lambda key: case == "split"
+    sched._lands_first = lambda: True
+    counted = []
+    count = sched._count_attention_keys
+    sched._count_attention_keys = lambda *a, **kw: (counted.append(a), count(*a, **kw))
+    sched.submit(list(range(3, 19)), max_new_tokens=9)
+    sched.submit(list(range(40, 60)), max_new_tokens=3)  # no prefix of the first: nothing copied
+    sched.drain()
+    total = eng.telemetry.counter_total
+    if case == "sink_off":
+        assert not counted and sched.syncs_ahead + sched.syncs_serial == 5
+        assert not total("serving/attn_keys_live") and not total("serving/attn_keys_walked")
+        return
+    assert [(list(lens), list(spans)) for lens, spans, *_ in counted] == [
+        ([0, 0, 0], [16, 0, 0]), ([17, 0, 0], [1, 16, 0]), ([19, 16, 0], [1, 4, 0]),
+        ([21, 21, 0], [1, 1, 0]), ([23, 0, 0], [1, 0, 0])]
+    # a layer, by sync, first forward then substep: the final chunk alone;
+    # the column beside a non-final chunk (which rides the substep on a
+    # garbage token: a pool of rows); the column beside the final chunk; two
+    # columns; one
+    live = (16 + 17) + (18 + 16 + 19 + 17) + (20 + 20 + 21 + 21) + (22 + 22 + 23 + 23) + (24 + 25)
+    blocks = {"whole_block": (1 + 1) + (2 + 1 + 1 + 1) + (2 + 1 + 1 + 1) + 4 + 2,
+              "split": (1 + 1) + (1 + 1 + 1 + 1) + (1 + 1 + 1 + 1) + 4 + 2}[case]
+    assert total("serving/attn_keys_live") == 2 * live == 648
+    assert total("serving/attn_keys_walked") == 2 * 32 * blocks
+    eng.telemetry.close()
+
+
 @pytest.mark.parametrize("split", [True, False, "fused_block"])
 def test_step_row_counters(tmp_path, baseline, split):
     """(f) ``serving/step_rows_run`` and ``_live`` over three syncs of an

@@ -149,6 +149,40 @@ def test_decode_attention(for_chip, name, variant):
             qT, k, v, rows, rows, sds((SLOTS, 2), jnp.int32))
 
 
+# (slots, query heads, kv heads, head size, pool rows, span, packed) of the
+# attention calls the benchmark's serving cells make: cell 2's one column
+# over 24 packed slots and its chunk as a (1, 64) span over one slot, cell
+# 5's split leaves, cell 6's grouped queries over 4096 shared rows and over
+# its 512-key rings
+CELL_ATTENTION = {
+    "gpt2-large.column": (24, 20, 20, 64, 1024, 0, True),
+    "gpt2-large.chunk": (1, 20, 20, 64, 1024, 64, True),
+    "olmo-hybrid.column": (64, 30, 30, 128, 1024, 0, False),
+    "phi-4-flash.column": (64, 40, 10, 128, 4096, 0, False),
+    "phi-4-flash.ring": (64, 40, 10, 128, 512, 0, False),
+}
+
+
+@pytest.mark.parametrize("call", sorted(CELL_ATTENTION))
+def test_decode_attention_cell_shapes(for_chip, call):
+    """The in-kernel walk (two VMEM buffers, DMA semaphores, a loop of a
+    dynamic trip count) at the shapes the cells run: a VMEM overflow or a
+    copy Mosaic refuses fails here, not on the chip."""
+    from deepspeed_tpu.ops.pallas import decode_attention as da
+    sds, compile_ = for_chip
+    B, H, nkv, D, S, span, packed = CELL_ATTENTION[call]
+    leaf = sds((B, nkv, S, (2 if packed else 1) * D), jnp.bfloat16)
+    k, v = (leaf, None) if packed else (leaf, leaf)
+    rows = sds((B, ), jnp.int32)
+    if span:
+        text = compile_(lambda q, k, v, st, ba: da.paged_span_attention(q, k, v, st, ba),
+                        sds((B, H, span, D), jnp.bfloat16), k, v, rows, rows)
+    else:
+        text = compile_(lambda q, k, v, st, en: da.paged_decode_attention(q, k, v, st, en),
+                        sds((B, H, D), jnp.bfloat16), k, v, rows, rows)
+    assert "dstpu_decode_attn" in text
+
+
 @pytest.mark.parametrize("cols", [1, CHUNK], ids=["decode", "span"])
 @pytest.mark.parametrize("int8", [False, True], ids=["bf16kv", "int8kv"])
 @pytest.mark.parametrize("name", MODELS)
