@@ -25,10 +25,10 @@ def train_flops_per_token(cfg, seq_len):
     embedding lookup and learned positions are gathers and cost none), plus
     causal attention: scores and values are 2 x 2 x (T/2) x H operations per
     token per layer forward, three times that with the backward pass:
-    6 x L x H x T. bench.py's ``_mfu`` (PaLM's arithmetic) counts the full
-    T x T attention (12 x L x H x T) and leaves the head and the norms out;
-    this count is 2.1% above it at gpt2-large, seq 1024 (4,915,822,080
-    against 4,813,524,480 operations a token)."""
+    6 x L x H x T. PaLM's arithmetic counts the full T x T attention
+    (12 x L x H x T) and leaves the head and the norms out; this count is
+    2.1% above it at gpt2-large, seq 1024 (4,915,822,080 against
+    4,813,524,480 operations a token)."""
     weights = cfg.num_layers * layer_matmul_params(cfg) + cfg.hidden_size * cfg.vocab_size
     attention = 6 * cfg.num_layers * cfg.num_heads * cfg.head_size * seq_len
     return 6 * weights + attention

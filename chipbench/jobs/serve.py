@@ -1,7 +1,6 @@
-"""The ``serve`` job: the engine and the gateway in this process (as
-``bench.py:_gateway_bench`` builds them: ``init_inference`` +
-``Gateway(engine, port=0)`` + ``start_background()``), load from a child
-process over localhost HTTP with SSE.
+"""The ``serve`` job: the engine and the gateway in this process
+(``init_inference`` + ``Gateway(engine, port=0)`` + ``start_background()``),
+load from a child process over localhost HTTP with SSE.
 
 Set-up, all before the window and all in ``setup_s``:
 
@@ -22,6 +21,8 @@ Set-up, all before the window and all in ``setup_s``:
    token and the primer has ended; then the window opens in steady state.
 
 What is measured, from the child's raw records: see ``reduce_records``.
+The window itself (sampling, the traced part, the snapshot before the
+profiler stops) is ``harness.measured_window``'s, as in every serving job.
 """
 
 import http.client
@@ -38,7 +39,7 @@ import jax.numpy as jnp
 
 from chipbench import reference, traffic
 from chipbench.cells import HERE, CellError, build_model
-from chipbench.harness import TracedWindow, finish_trace
+from chipbench.harness import finish_trace, measured_window
 
 COLLECT_STEPS = 17  # the prefill's token and 16 decode steps
 
@@ -54,7 +55,24 @@ def quantile(xs, q):
     return xs[lo] + (xs[hi] - xs[lo]) * (pos - lo)
 
 
-def reduce_records(records, t0, t1, t_stop, min_tokens):
+def delivery_stalls(records, t0, t1, gap_ms):
+    """The stretches of [t0, t1) of ``gap_ms`` or more in which NO client
+    received a token: the arrival instants of all clients merged and sorted,
+    a stall a gap between two consecutive ones, as far as it lies inside the
+    window. In a closed loop with a client a slot some row receives tokens
+    every period of the pump, so a gap of several periods is the server
+    standing still, whatever the cause; one client's pause while the others
+    receive is none. Returns [(seconds after t0, length in ms)], by start."""
+    instants = sorted(t for r in records for t, _ in r["events"])
+    out = []
+    for a, b in zip(instants, instants[1:]):
+        a, b = max(a, t0), min(b, t1)
+        if (b - a) * 1e3 >= gap_ms:
+            out.append((a - t0, (b - a) * 1e3))
+    return out
+
+
+def reduce_records(records, t0, t1, t_stop, min_tokens, stall_gap_ms=None):
     """End-to-end numbers from the load generator's records.
 
     - ``serve_tokens_per_s``: output tokens whose SSE event reached a client
@@ -64,7 +82,11 @@ def reduce_records(records, t0, t1, t_stop, min_tokens):
     - ``tpot_p90_ms``: per request, the mean gap between its tokens that
       arrived in [t0, t1): (last arrival - first arrival) / (tokens after
       the first arrival's), for requests with ``min_tokens`` or more tokens
-      in the window; the 90th percentile over requests.
+      in the window; the 90th percentile over requests. ``tpot_p50_ms`` is
+      the median of the same per-request numbers.
+    - ``delivery_stall_ms_per_s``, ``delivery_stalls_per_min`` (where the
+      cell states a ``stall_gap_ms``): see ``delivery_stalls``; their summed
+      length a second of window, and their count a minute.
     - failed: a request sent in the window that was refused, errored, got
       no first token, or ended short of ``max_tokens``. A stream this run
       cut itself at the end (after ``t_stop``) did not fail."""
@@ -90,14 +112,28 @@ def reduce_records(records, t0, t1, t_stop, min_tokens):
         if r["t_first"] is not None:
             ttft.append((r["t_first"] - r["t_send"]) * 1e3)
     turnaround = [(r["t_send"] - r["t_ready"]) * 1e3 for r in records if "t_send" in r]
+    stalls = delivery_stalls(records, t0, t1, stall_gap_ms) if stall_gap_ms else None
+    stall_ms_per_s = stalls_per_min = None
+    if stalls is not None:
+        stall_ms_per_s = sum(ms for _, ms in stalls) / (t1 - t0)
+        stalls_per_min = len(stalls) * 60.0 / (t1 - t0)
+    tpot_p50 = quantile(tpot, 0.5) if tpot else None
+    tpot_p90 = quantile(tpot, 0.9) if tpot else None
     return {"serve_tokens_per_s": tokens / (t1 - t0),
             "ttft_p90_ms": quantile(ttft, 0.9) if ttft else None,
-            "tpot_p90_ms": quantile(tpot, 0.9) if tpot else None,
+            "tpot_p90_ms": tpot_p90, "tpot_p50_ms": tpot_p50,
+            "delivery_stall_ms_per_s": stall_ms_per_s, "delivery_stalls_per_min": stalls_per_min,
             "attempted": sent, "failed": failed,
             "info": {"tokens_in_window": tokens, "requests_sent_in_window": sent,
                      "ttft_samples": len(ttft), "tpot_samples": len(tpot),
                      "ttft_p50_ms": quantile(ttft, 0.5) if ttft else None,
-                     "tpot_p50_ms": quantile(tpot, 0.5) if tpot else None,
+                     "tpot_p50_ms": tpot_p50, "tpot_p90_ms": tpot_p90,
+                     "tpot_max_ms": max(tpot) if tpot else None,
+                     "delivery_stall_ms_per_s": stall_ms_per_s,
+                     "delivery_stalls_per_min": stalls_per_min,
+                     # the longest few, [seconds after t0, ms]: when they came
+                     "delivery_stalls_longest": stalls and sorted(
+                         sorted(stalls, key=lambda s: -s[1])[:8]),
                      "completed_in_window": sum(1 for r in records if r["done"]
                                                 and t0 <= r.get("t_end", -1) < t1),
                      "generator_connect_ms_max": max(turnaround) if turnaround else None,
@@ -197,7 +233,7 @@ def run(ctx):
     eng = deepspeed_tpu.init_inference(model, config=engine_cfg, params=params)
     del params
     # the default deadline (120 s) is shorter than the compile of a cold
-    # run's first two step programs; a deployment setting, as bench.py sets it
+    # run's first two step programs; a deployment setting
     gw = Gateway(eng, port=0, max_queue_depth=max(64, 2 * tr["clients"]),
                  request_timeout_s=600)
     sched = gw.scheduler
@@ -244,20 +280,16 @@ def run(ctx):
         # ---- the window
         programs_before = ctx.compiles["programs"]
         before = _metrics(port)
-        traced = TracedWindow(ctx, p["trace_window_s"])
         ctx.mark_window_start()
         t0 = time.monotonic() + 0.05
         t1 = t0 + ctx.seconds
         child.stdin.write(json.dumps({"window": [t0, t1]}) + "\n")
         child.stdin.flush()
         occupancy = []
-        while time.monotonic() < t1:
-            time.sleep(0.25)
-            occupancy.append(100.0 * sched.cache.occupancy())
-            if traced.due():
-                traced.stop()
-        traced.stop()
-        after = _metrics(port)
+        traced, after, _, after_s, host = measured_window(
+            ctx, t0, t1, p["trace_window_s"],
+            sample=lambda: occupancy.append(100.0 * sched.cache.occupancy()),
+            snapshot=lambda: _metrics(port))
         late_compiles = ctx.compiles["programs"] - programs_before
         out = json.loads(child.stdout.readline() or '{"event": "died"}')
         if out["event"] != "records":
@@ -271,7 +303,8 @@ def run(ctx):
         drained = gw.close(timeout=120)
         eng.telemetry.close()  # its files live in the run's scratch directory
 
-    res = reduce_records(out["records"], t0, t1, out["t_stop"], p["tpot_min_tokens"])
+    res = reduce_records(out["records"], t0, t1, out["t_stop"], p["tpot_min_tokens"],
+                         p.get("stall_gap_ms"))
     sched_m = after["scheduler"]
     checks = {
         "logits_match_reference": max(errs) <= reference.SERVE_LOGITS_TOL,
@@ -285,14 +318,19 @@ def run(ctx):
     obs = {
         "correct": all(checks.values()), "checks": checks,
         "attempted": res["attempted"], "failed": res["failed"],
-        "end_to_end": {k: res[k] for k in ("serve_tokens_per_s", "tpot_p90_ms")},
-        "values": {"client_ttft_p90_ms": res["ttft_p90_ms"]},
+        "end_to_end": {k: res[k] for k in ("serve_tokens_per_s", "tpot_p50_ms")},
+        "values": {"client_ttft_p90_ms": res["ttft_p90_ms"],
+                   "client_tpot_p50_ms": res["tpot_p50_ms"],
+                   "client_tpot_p90_ms": res["tpot_p90_ms"],
+                   "delivery_stall_ms_per_s": res["delivery_stall_ms_per_s"],
+                   "delivery_stalls_per_min": res["delivery_stalls_per_min"]},
         "series": {"slot_occupancy_pct": occupancy},
         "telemetry": after.get("telemetry"),
         "info": dict(res["info"], logits_errors=errs, tol=reference.SERVE_LOGITS_TOL,
                      late_compiles=late_compiles, drained=bool(drained),
-                     after_window_s={"first_tokens_and_records": t_records - t1,
-                                     "drain": time.monotonic() - t_records},
+                     host=host, generator=out.get("generator"),
+                     after_window_s=dict(after_s, first_tokens_and_records=t_records - t1,
+                                         drain=time.monotonic() - t_records),
                      compiled_programs=sched_m["compiled_programs"],
                      num_slots=sched_m["num_slots"], max_len=sched.max_len,
                      kv_bytes_per_token=sched.cache.bytes_per_token(),

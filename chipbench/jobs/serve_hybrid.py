@@ -48,7 +48,7 @@ import jax.numpy as jnp
 
 from chipbench import traffic
 from chipbench.cells import HERE, CellError, build_model
-from chipbench.harness import TracedWindow, finish_trace
+from chipbench.harness import finish_trace, measured_window
 from chipbench.jobs.serve import _metrics, _post, reduce_records
 from chipbench.jobs.serve_ref import _collect, seeded_params
 
@@ -228,8 +228,6 @@ def run(ctx):
 
         programs_before = ctx.compiles["programs"]
         before = _metrics(port)
-        traced = TracedWindow(ctx, p["trace_window_s"])
-        steps_at = {"start": sched.steps_run}  # column forwards at the trace's start and stop
         ctx.mark_window_start()
         t0 = time.monotonic() + 0.05
         t1 = t0 + ctx.seconds
@@ -237,18 +235,14 @@ def run(ctx):
         child.stdin.flush()
         occupancy, live_rows = [], []
 
-        def stop_trace():
-            steps_at.setdefault("stop", sched.steps_run)
-            traced.stop()
-
-        while time.monotonic() < t1:
-            time.sleep(0.25)
+        def sample():
             occupancy.append(100.0 * sched.cache.occupancy())
             live_rows.append(sched.cache.live_tokens())
-            if traced.due():
-                stop_trace()
-        stop_trace()
-        after = _metrics(port)
+
+        # steps_at: column forwards at the traced part's start and stop
+        traced, after, steps_at, after_s, host = measured_window(
+            ctx, t0, t1, p["trace_window_s"], sample, snapshot=lambda: _metrics(port),
+            counted=lambda: sched.steps_run)
         late_compiles = ctx.compiles["programs"] - programs_before
         out = json.loads(child.stdout.readline() or '{"event": "died"}')
         if out["event"] != "records":
@@ -262,7 +256,8 @@ def run(ctx):
         drained = gw.close(timeout=120)
         eng.telemetry.close()
 
-    res = reduce_records(out["records"], t0, t1, out["t_stop"], p["tpot_min_tokens"])
+    res = reduce_records(out["records"], t0, t1, out["t_stop"], p["tpot_min_tokens"],
+                         p.get("stall_gap_ms"))
     sched_m = after["scheduler"]
     want = ctx.config["reference"]
     brief = lambda r: {k: r[k] for k in ("ok", "error", "min_error", "median_error", "errors")}
@@ -281,11 +276,12 @@ def run(ctx):
     obs = {
         "correct": all(checks.values()), "checks": checks,
         "attempted": res["attempted"], "failed": res["failed"],
-        "end_to_end": {k: res[k] for k in ("serve_tokens_per_s", "tpot_p90_ms")},
+        "end_to_end": {k: res[k] for k in ("serve_tokens_per_s", "tpot_p50_ms")},
         "values": {"client_ttft_p90_ms": res["ttft_p90_ms"],
+                   "client_tpot_p50_ms": res["tpot_p50_ms"],
                    "client_tpot_p90_ms": res["tpot_p90_ms"],
                    "column_forwards_traced": (steps_at["stop"] - steps_at["start"]
-                                              if ctx.trace else None)},
+                                              if steps_at else None)},
         "series": {"slot_occupancy_pct": occupancy, "live_kv_rows": live_rows},
         "telemetry": after.get("telemetry"),
         "model_cfg": cfg, "itemsize": dtype.itemsize, "num_slots": sched_m["num_slots"],
@@ -296,8 +292,9 @@ def run(ctx):
                      int8_state_program=brief(compared["int8_state_program"]),
                      int8_rows_program=brief(compared["int8_rows_program"]),
                      tol=ref.TOL[p["dtype"]], late_compiles=late_compiles, drained=bool(drained),
-                     after_window_s={"first_tokens_and_records": t_records - t1,
-                                     "drain": time.monotonic() - t_records},
+                     host=host, generator=out.get("generator"),
+                     after_window_s=dict(after_s, first_tokens_and_records=t_records - t1,
+                                         drain=time.monotonic() - t_records),
                      compiled_programs=sched_m["compiled_programs"],
                      num_slots=sched_m["num_slots"], max_len=sched.max_len,
                      kv_bytes_per_token=sched_m["kv_bytes_per_token"],

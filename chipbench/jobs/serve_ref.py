@@ -46,7 +46,7 @@ import jax.numpy as jnp
 
 from chipbench import traffic
 from chipbench.cells import HERE, CellError, build_model
-from chipbench.harness import TracedWindow, finish_trace
+from chipbench.harness import finish_trace, measured_window
 from chipbench.jobs.serve import COLLECT_STEPS, _metrics, _post, reduce_records
 
 
@@ -224,21 +224,19 @@ def run(ctx):
 
         programs_before = ctx.compiles["programs"]
         before = _metrics(port)
-        traced = TracedWindow(ctx, p["trace_window_s"])
         ctx.mark_window_start()
         t0 = time.monotonic() + 0.05
         t1 = t0 + ctx.seconds
         child.stdin.write(json.dumps({"window": [t0, t1]}) + "\n")
         child.stdin.flush()
         occupancy, live_rows = [], []
-        while time.monotonic() < t1:
-            time.sleep(0.25)
+
+        def sample():
             occupancy.append(100.0 * sched.cache.occupancy())
             live_rows.append(sched.cache.live_tokens())
-            if traced.due():
-                traced.stop()
-        traced.stop()
-        after = _metrics(port)
+
+        traced, after, _, after_s, host = measured_window(
+            ctx, t0, t1, p["trace_window_s"], sample, snapshot=lambda: _metrics(port))
         late_compiles = ctx.compiles["programs"] - programs_before
         out = json.loads(child.stdout.readline() or '{"event": "died"}')
         if out["event"] != "records":
@@ -252,7 +250,8 @@ def run(ctx):
         drained = gw.close(timeout=120)
         eng.telemetry.close()
 
-    res = reduce_records(out["records"], t0, t1, out["t_stop"], p["tpot_min_tokens"])
+    res = reduce_records(out["records"], t0, t1, out["t_stop"], p["tpot_min_tokens"],
+                         p.get("stall_gap_ms"))
     sched_m = after["scheduler"]
     dispatch = sched_m.get("moe_dispatch_programs") or {}
     kv_bytes = sched.cache.bytes_per_token()
@@ -272,8 +271,9 @@ def run(ctx):
     obs = {
         "correct": all(checks.values()), "checks": checks,
         "attempted": res["attempted"], "failed": res["failed"],
-        "end_to_end": {k: res[k] for k in ("serve_tokens_per_s", "tpot_p90_ms")},
+        "end_to_end": {k: res[k] for k in ("serve_tokens_per_s", "tpot_p50_ms")},
         "values": {"client_ttft_p90_ms": res["ttft_p90_ms"],
+                   "client_tpot_p50_ms": res["tpot_p50_ms"],
                    "client_tpot_p90_ms": res["tpot_p90_ms"]},
         "series": {"slot_occupancy_pct": occupancy, "live_kv_rows": live_rows},
         "telemetry": after.get("telemetry"),
@@ -295,8 +295,9 @@ def run(ctx):
                      tol=ref.TOL[p["dtype"]], routing_margin=ref.ROUTING_MARGIN,
                      max_followed_share=ref.MAX_FOLLOWED_SHARE,
                      late_compiles=late_compiles, drained=bool(drained),
-                     after_window_s={"first_tokens_and_records": t_records - t1,
-                                     "drain": time.monotonic() - t_records},
+                     host=host, generator=out.get("generator"),
+                     after_window_s=dict(after_s, first_tokens_and_records=t_records - t1,
+                                         drain=time.monotonic() - t_records),
                      compiled_programs=sched_m["compiled_programs"],
                      num_slots=sched_m["num_slots"], max_len=sched.max_len,
                      kv_bytes_per_token=kv_bytes, radix_hits=radix_hits,

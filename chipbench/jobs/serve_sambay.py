@@ -43,9 +43,10 @@ Set-up, all before the window and all in ``setup_s``:
    scheduler's bypass counter moved). This also warms the window's programs;
 4. the load generator ramps; then the window opens.
 
-A traced run profiles the LAST ``trace_window_s`` of the window, not the
-first as the other serving jobs do, and snapshots the gateway's metrics
-before it stops the profiler. This cell's chunk scans put over a million
+A traced run profiles the LAST ``trace_window_s`` of the window and snapshots
+the gateway's metrics before it stops the profiler
+(``harness.measured_window``; every serving job since PR 38, this one since
+PR 32, where the need showed first). This cell's chunk scans put over a million
 device operations into five seconds of trace and ``stop_trace`` takes
 37-44 s to write them (my chip runs, PR 32). Stopped five seconds into the
 window it held this thread until long after the load generator had hung up
@@ -68,13 +69,10 @@ import jax.numpy as jnp
 
 from chipbench import traffic
 from chipbench.cells import HERE, CellError, build_model
-from chipbench.harness import TracedWindow, finish_trace
+from chipbench.harness import finish_trace, measured_window
 from chipbench.jobs.serve import _metrics, _post, reduce_records
 from chipbench.jobs.serve_ref import _collect, seeded_params
 
-
-# what ``jax.profiler.start_trace`` may take before the traced part begins
-TRACE_START_S = 2.0
 
 # the deviation of a Mamba layer's W_x (Delta's input, B and C), as the
 # configuration's ``assumed.weights`` states it
@@ -261,22 +259,14 @@ def run(ctx):
         child.stdin.write(json.dumps({"window": [t0, t1]}) + "\n")
         child.stdin.flush()
         occupancy, live_rows = [], []
-        # the profiler opens TRACE_START_S before the traced part has to, so
-        # that it closes inside the window, while the clients still send
-        trace_from = max(t0, t1 - p["trace_window_s"] - TRACE_START_S)
-        traced = steps_at = None
-        while traced is None or (time.monotonic() < t1 and not traced.due()):
-            if traced is None and time.monotonic() >= trace_from:
-                traced = TracedWindow(ctx, p["trace_window_s"])
-                steps_at = {"start": counted()}
-            time.sleep(0.25)
+
+        def sample():
             occupancy.append(100.0 * sched.cache.occupancy())
             live_rows.append(sched.cache.live_tokens())
-        after = _metrics(port)
-        steps_at["stop"] = counted()
-        t_after = time.monotonic()
-        traced.stop()
-        t_traced = time.monotonic()
+
+        traced, after, steps_at, after_s, host = measured_window(
+            ctx, t0, t1, p["trace_window_s"], sample, snapshot=lambda: _metrics(port),
+            counted=counted)
         late_compiles = ctx.compiles["programs"] - programs_before
         out = json.loads(child.stdout.readline() or '{"event": "died"}')
         if out["event"] != "records":
@@ -290,7 +280,8 @@ def run(ctx):
         drained = gw.close(timeout=120)
         eng.telemetry.close()
 
-    res = reduce_records(out["records"], t0, t1, out["t_stop"], p["tpot_min_tokens"])
+    res = reduce_records(out["records"], t0, t1, out["t_stop"], p["tpot_min_tokens"],
+                         p.get("stall_gap_ms"))
     sched_m = after["scheduler"]
     want = ctx.config["reference"]
     brief = lambda r: {k: r[k] for k in ("ok", "error", "min_error", "median_error", "errors")}
@@ -310,13 +301,14 @@ def run(ctx):
     obs = {
         "correct": all(checks.values()), "checks": checks,
         "attempted": res["attempted"], "failed": res["failed"],
-        "end_to_end": {k: res[k] for k in ("serve_tokens_per_s", "tpot_p90_ms")},
+        "end_to_end": {k: res[k] for k in ("serve_tokens_per_s", "tpot_p50_ms")},
         "values": {"client_ttft_p90_ms": res["ttft_p90_ms"],
+                   "client_tpot_p50_ms": res["tpot_p50_ms"],
                    "client_tpot_p90_ms": res["tpot_p90_ms"],
                    **({name: stop - start for name, start, stop in zip(
                        ("column_forwards_traced", "attn_rows_window_traced",
                         "attn_rows_shared_traced"), steps_at["start"], steps_at["stop"])}
-                      if ctx.trace else {})},
+                      if steps_at else {})},
         "series": {"slot_occupancy_pct": occupancy, "live_kv_rows": live_rows},
         "telemetry": after.get("telemetry"),
         "model_cfg": cfg, "itemsize": dtype.itemsize, "num_slots": sched_m["num_slots"],
@@ -328,9 +320,9 @@ def run(ctx):
                      int8_state_program=brief(compared["int8_state_program"]),
                      int8_rows_program=brief(compared["int8_rows_program"]),
                      tol=ref.TOL[p["dtype"]], late_compiles=late_compiles, drained=bool(drained),
-                     after_window_s={"snapshot": t_after - t1, "stop_trace": t_traced - t_after,
-                                     "first_tokens_and_records": t_records - t1,
-                                     "drain": time.monotonic() - t_records},
+                     host=host, generator=out.get("generator"),
+                     after_window_s=dict(after_s, first_tokens_and_records=t_records - t1,
+                                         drain=time.monotonic() - t_records),
                      compiled_programs=sched_m["compiled_programs"],
                      num_slots=sched_m["num_slots"], max_len=sched.max_len,
                      kv_bytes_per_token=sched_m["kv_bytes_per_token"],

@@ -1,6 +1,6 @@
 """The load generator: a child process, standard library only (it must not
-import JAX: the parent holds the chip). Copied in spirit from
-``bench.py:_gateway_bench``'s client (``http.client``, SSE timestamps).
+import JAX: the parent holds the chip): ``http.client`` connections that
+stamp every SSE event with the monotonic clock as it is read.
 
     python -m chipbench.loadgen          # spec as one JSON line on stdin
 
@@ -12,17 +12,22 @@ monotonic clock (``time.monotonic`` is CLOCK_MONOTONIC, shared by parent and
 child). No request is started after t1. The child then waits (up to
 ``first_token_wait_s``) for the first token of every request sent inside
 the window, closes what is still streaming, prints one JSON line of raw
-records and exits. It reduces nothing: the parent does.
+records and exits. It reduces nothing: the parent does. Beside the records
+it says what it cost itself over the window (``generator``: its CPU seconds
+and context switches from the window's line to t1, the machine's core
+count), so that a generator that competes with the server for a core shows.
 """
 
 import http.client
 import json
+import os
 import socket
 import sys
 import threading
 import time
 
 from chipbench import traffic
+from chipbench.harness import process_usage  # standard library only, like this file
 
 
 class _Client(threading.Thread):
@@ -109,8 +114,12 @@ def main():
     print(json.dumps({"event": "ramped", "t": time.monotonic()}), flush=True)
     t0, t1 = json.loads(sys.stdin.readline())["window"]
     state["t1"] = t1
+    usage_at_t0 = process_usage()
     while time.monotonic() < t1:
         time.sleep(min(0.05, max(0.0, t1 - time.monotonic())))
+    usage = process_usage()
+    generator = dict({k: usage[k] - usage_at_t0[k] for k in usage}, cpu_count=os.cpu_count(),
+                     threads=len(clients))
     # every request sent inside the window gets its chance at a first token
     wait_until = t1 + spec["first_token_wait_s"]
 
@@ -131,7 +140,8 @@ def main():
     for c in clients:
         c.join(timeout=30)
     records = [r for c in clients for r in c.records]
-    print(json.dumps({"event": "records", "t_stop": t_stop, "records": records}), flush=True)
+    print(json.dumps({"event": "records", "t_stop": t_stop, "generator": generator,
+                      "records": records}), flush=True)
     return 0
 
 

@@ -5,6 +5,7 @@ import json
 import os
 
 import numpy as np
+import pytest
 
 from chipbench import cells, traffic
 
@@ -58,3 +59,79 @@ def test_loadgen_imports_no_jax():
             "sys.exit(any(m == 'jax' or m.startswith('jax.') or m == 'numpy' for m in sys.modules))")
     root = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
     assert subprocess.run([sys.executable, "-c", code], cwd=root).returncode == 0
+
+
+# ---- reduce_records: plain arithmetic on the load generator's records
+
+
+def _request(client, first, n, gap_s, t_send=None):
+    """One finished request: ``n`` tokens, one an event, ``gap_s`` apart."""
+    events = [(first + i * gap_s, 1) for i in range(n)]
+    return {"client": client, "events": events, "t_first": first, "status": 200, "done": True,
+            "max_tokens": n, "t_ready": (t_send or first) - 0.002,
+            "t_send": t_send if t_send is not None else first - 0.001, "t_end": events[-1][0]}
+
+
+def _reduce(records, stall_gap_ms=50, t0=0.0, t1=10.0):
+    from chipbench.jobs.serve import reduce_records
+    return reduce_records(records, t0, t1, t1 + 1.0, 16, stall_gap_ms)
+
+
+def test_reduce_records_client_tpot_quantiles():
+    # ten clients, one request each, at 10, 11, ..., 19 ms a token
+    records = [_request(c, 0.5, 101, (10 + c) * 1e-3) for c in range(10)]
+    res = _reduce(records)
+    assert res["tpot_p50_ms"] == pytest.approx(14.5) and res["tpot_p90_ms"] == pytest.approx(18.1)
+    assert res["info"]["tpot_p50_ms"] == res["tpot_p50_ms"] and res["info"]["tpot_samples"] == 10
+    assert res["info"]["tpot_max_ms"] == pytest.approx(19.0)
+    assert res["serve_tokens_per_s"] == pytest.approx(101.0) and res["failed"] == 0
+    # a request with fewer than min_tokens tokens in the window has no gap of its own
+    res = _reduce(records + [_request(10, 9.9, 40, 0.01)])
+    assert res["info"]["tpot_samples"] == 10
+
+
+def _interleaved(clients=4, period=0.016, until=9.99):
+    """``clients`` streams, each a token every ``period``, phases spread."""
+    n = int(until / period)
+    return [_request(c, 0.001 + c * period / clients, n, period, t_send=-0.5)
+            for c in range(clients)]
+
+
+def test_reduce_records_no_stall():
+    res = _reduce(_interleaved())
+    assert res["delivery_stall_ms_per_s"] == 0.0 and res["delivery_stalls_per_min"] == 0.0
+    assert res["info"]["delivery_stalls_longest"] == []
+    # a cell that states no stall_gap_ms reports neither number
+    res = _reduce(_interleaved(), stall_gap_ms=None)
+    assert res["delivery_stall_ms_per_s"] is None and res["delivery_stalls_per_min"] is None
+
+
+def test_reduce_records_one_stall_seen_by_all_clients():
+    records = _interleaved()
+    for r in records:  # at 5 s every client hears nothing for 80 ms more
+        r["events"] = [(t + 0.080 if t >= 5.0 else t, n) for t, n in r["events"]]
+    res = _reduce(records)
+    assert res["delivery_stalls_per_min"] == pytest.approx(6.0)  # one in a 10 s window
+    # the stall is the pause and the stream's own 4 ms between instants
+    assert res["delivery_stall_ms_per_s"] == pytest.approx(8.4, abs=0.05)
+    (at, ms), = res["info"]["delivery_stalls_longest"]
+    assert 4.98 < at < 5.0 and ms == pytest.approx(84.0, abs=0.5)
+    # the per-request gap hardly sees it: 80 ms over ~620 tokens
+    assert res["tpot_p90_ms"] == pytest.approx(16.0 + 80 / 623, abs=0.01)
+
+
+def test_reduce_records_one_clients_gap_is_no_stall():
+    records = _interleaved()
+    records[2]["events"] = [(t + 0.5 if t >= 5.0 else t, n) for t, n in records[2]["events"]]
+    res = _reduce(records)
+    assert res["delivery_stall_ms_per_s"] == 0.0 and res["delivery_stalls_per_min"] == 0.0
+    assert res["tpot_p90_ms"] > res["tpot_p50_ms"]  # that client's own gap did grow
+
+
+def test_reduce_records_stall_across_the_windows_edge_counts_its_part_inside():
+    records = _interleaved(until=12.0)
+    for r in records:  # silence from 9.9 s to 10.2 s, the window closes at 10
+        r["events"] = [(t + 0.3 if t >= 9.9 else t, n) for t, n in r["events"]]
+    res = _reduce(records)
+    (at, ms), = res["info"]["delivery_stalls_longest"]
+    assert at == pytest.approx(9.9, abs=0.01) and ms == pytest.approx(100.0, abs=5.0)
