@@ -267,6 +267,9 @@ class InferenceEngine:
         # shared-params engines never materialize: the publisher installs
         # (and later swaps) the compute-layout tree
         self.params = self._materialize_params(params) if materialize else params
+        if n_experts and self.params is not None:
+            from ..moe.layer import rest_experts_row_major
+            self.params = rest_experts_row_major(self.params)
         self._compiled = {}
         self._cache_pool = {}  # (B, S) -> reusable KV cache buffers
         # telemetry: reuse an already-installed global sink (e.g. the

@@ -193,6 +193,16 @@ a sync's attention has to read, from the host's lengths and spans:
 ``serving/cross_decoder_rows_unread``. See ``benchmarks/SERVING.md`` ("Ring
 rows, shared rows and SSM state").
 
+**One-sublayer blocks** (``nemotron_h``: a Mamba-2 mixer, an expert FFN or
+attention alone in each layer): a Mamba-2 layer declares ``"state"``, an
+attention layer ``"rows"``, an FFN-only layer nothing, and holds nothing; the
+pool is a state pool with the same operand, refusals and bypass counter.
+Counters ``serving/ssd_state_updates`` and ``serving/ssd_chunk_tokens``: the
+one-token updates and the chunk positions its Mamba-2 layers are required to
+run (:meth:`DecodeScheduler._count_state_updates`), beside the ``serving/moe_*``
+counters of its expert layers. See ``benchmarks/SERVING.md`` ("One-sublayer
+blocks").
+
 Telemetry (PR-1 sink): gauges ``serving/slot_occupancy``,
 ``serving/batch_efficiency``, ``serving/kv_token_utilization``,
 ``serving/prefix_cache_hit_rate``, ``serving/spec_acceptance_rate``,
@@ -669,7 +679,12 @@ class DecodeScheduler:
         # slot's rows is refused here, by name
         kinds = model.cache_kinds() if hasattr(model, "cache_kinds") else None
         declared = set(jax.tree_util.tree_leaves(kinds))
-        shared = kinds is not None and any(not layer for layer in model.cache_spec(1, 1))
+        # a layer that declares nothing reads rows a layer below it wrote,
+        # unless its block has no mixer at all (an FFN alone: nemotron_h)
+        mixer_of = getattr(model.cfg, "layer_parts", lambda i: ("full_attention", "mlp"))
+        shared = kinds is not None and any(
+            not layer and mixer_of(i)[0] is not None
+            for i, layer in enumerate(model.cache_spec(1, 1)))
         held = [name for name, on in (("recurrent state", "state" in declared),
                                       ("ring rows", "ring" in declared),
                                       ("rows that layers share", shared)) if on]
@@ -682,6 +697,8 @@ class DecodeScheduler:
         self._attn_layers = (sum(w > 0 for w in windows), max(windows, default=0),
                              sum(not w and cfg.layer_type(i) in ("diff_attention", "cross_attention")
                                  for i, w in zip(layers, windows)))
+        # Mamba-2 layers: what the host's counters of state updates multiply by
+        self._ssd_layers = sum(mixer_of(i)[0] == "mamba2" for i in range(cfg.num_layers))
         if self._state_pool:
             unsupported = [name for name, on in (
                 ("speculative verify (spec_tokens): a state or a ring cannot roll back "
@@ -2285,6 +2302,7 @@ class DecodeScheduler:
             self.telemetry.counter("serving/step_rows_live", int(spans.sum())
                                    + int(np.count_nonzero(spans)) * (ksteps - 1))
             self._count_attention_rows(lens, spans, ksteps, chunk)
+            self._count_state_updates(spans, ksteps, chunk)
             # the programs whose attention walks a row's extent chain
             ext_walk = key is not None and key[0] in ("fused_ext", "fused_seqp")
             self._count_attention_keys(lens, spans, width, ksteps, split, chunk, ext_walk)
@@ -2347,6 +2365,26 @@ class DecodeScheduler:
             sub[held] = 0
         return (jnp.asarray(sub), )
 
+    def _count_state_updates(self, spans, ksteps, chunk=None):
+        """With the sink on, for a model with Mamba-2 layers: the work a
+        sync's state layers are REQUIRED to do, from the host's copy of the
+        spans, summed over forwards and Mamba-2 layers:
+        ``serving/ssd_state_updates``, the one-token updates (a live decode
+        row in the first forward and in every substep it steps in), and
+        ``serving/ssd_chunk_tokens``, the positions of a prefill chunk (a
+        row's span past 1). ``chunk``: ``(slot, final)`` of a sync's prefill
+        row: unless its chunk is final it stands still in the substeps."""
+        if not (self.telemetry.enabled and self._ssd_layers):
+            return
+        live = spans > 0
+        stepping = int(np.count_nonzero(live))
+        if chunk is not None and not chunk[1] and live[chunk[0]]:
+            stepping -= 1
+        updates = int(np.count_nonzero(spans == 1)) + stepping * (ksteps - 1)
+        self.telemetry.counter("serving/ssd_state_updates", self._ssd_layers * updates)
+        self.telemetry.counter("serving/ssd_chunk_tokens",
+                               self._ssd_layers * int(spans[spans > 1].sum()))
+
     def _count_attention_rows(self, lens, spans, ksteps, chunk=None):
         """With the sink on, for a model with windowed or shared-row layers:
         the K/V positions a sync's attention has to read, from the host's
@@ -2404,6 +2442,7 @@ class DecodeScheduler:
         groups = collections.Counter()
         for i in range(cfg.num_layers):
             kind = cfg.layer_type(i) if hasattr(cfg, "layer_type") else "full_attention"
+            mixer = cfg.layer_parts(i)[0] if hasattr(cfg, "layer_parts") else kind
             window = cfg.layer_window(i) if hasattr(cfg, "layer_window") else 0
             if carries:
                 if kind == "diff_attention" and window:
@@ -2412,26 +2451,30 @@ class DecodeScheduler:
                     groups[(window, 0, cfg.kv_heads // 2, 2 * cfg.head_size, False)] += 1
                 elif kind in ("diff_attention", "cross_attention"):
                     groups[(0, 0, cfg.kv_heads // 2, 2 * cfg.head_size, False)] += 1
-            elif kind == "full_attention":
+            elif mixer == "full_attention":
                 groups[(0, window, cfg.kv_heads // shard, cfg.head_size,
                         kv_packs(cfg.head_size))] += 1
         return [(n, ring, window, (nkv, cfg.num_heads // shard // nkv, D, packed))
                 for (ring, window, nkv, D, packed), n in groups.items()]
 
     def _walk_block(self, group, span, ext):
-        """(keys a block, blocks a row's extents hold) of the kernel's walk
-        for a layer group at a query span: the kernel module's own choice."""
+        """(columns a kernel call takes of the span, keys a block, blocks a
+        row's extents hold) of the kernel's walk for a layer group at a query
+        span: the kernel module's own choice."""
         key = (group, span, ext)
         if key not in self._walk_blocks:
-            from ..ops.pallas.decode_attention import walk_block_kv
+            from ..ops.pallas.decode_attention import span_tile, walk_block_kv
             _, ring, _, (nkv, rep, D, packed) = self._attn_walks[group]
             rows = ring or self.max_len
             cfg = self.engine.module.cfg
             kv_dtype = jnp.int8 if self.kv_quantized else jax.tree_util.tree_leaves(
                 self.cache.pool)[0].dtype
-            bkv = walk_block_kv(nkv, rep * span, D, rows, cfg.decode_block_kv, cfg.dtype,
-                                kv_dtype, self.kv_quantized, packed)
-            self._walk_blocks[key] = bkv, (self.cache.max_extents if ext else 1) * rows // bkv
+            shape = (D, rows, cfg.decode_block_kv, cfg.dtype, kv_dtype, self.kv_quantized,
+                     packed)
+            tile = span_tile(rep, span, *shape)
+            bkv = walk_block_kv(nkv, rep * tile, *shape)
+            self._walk_blocks[key] = (tile, bkv,
+                                      (self.cache.max_extents if ext else 1) * rows // bkv)
         return self._walk_blocks[key]
 
     def _count_attention_keys(self, lens, spans, width, ksteps, split, chunk, ext):
@@ -2478,9 +2521,12 @@ class DecodeScheduler:
                 elif window:
                     start = np.maximum(ends - window, 0)
                     keys = ends - start
-                bkv, blocks = self._walk_block(g, span, ext)
+                tile, bkv, blocks = self._walk_block(g, span, ext)
                 live += n * int(keys.sum())
-                walked += n * walked_keys(start, ends, span, bkv, blocks)
+                # a span wider than one kernel call takes walks the keys once a tile
+                walked += n * sum(
+                    walked_keys(start, np.where(ends > start, ends + t0, ends), tile, bkv, blocks)
+                    for t0 in range(0, span, tile))
         self.telemetry.counter("serving/attn_keys_live", live)
         self.telemetry.counter("serving/attn_keys_walked", walked)
 

@@ -228,3 +228,55 @@ def tiny_sambay():
     """Test-scale SambaY: 8 layers (mamba, window, mamba, window, mamba, full,
     gmu, cross), head size 64, a window of 16 keys."""
     return _sambay(256, 8, 4, 2, 128, 16, 256, 256, d_state=4)
+
+
+_NEMOTRON_H_KINDS = {"M": "mamba2", "E": "moe", "*": "attention", "-": "mlp"}
+
+
+def nemotron_h_layers(pattern):
+    """``layer_types`` from a ``hybrid_override_pattern``: every layer is ONE
+    sublayer, ``M`` a Mamba-2 mixer, ``E`` an expert FFN, ``*`` attention,
+    ``-`` a dense FFN."""
+    return tuple(_NEMOTRON_H_KINDS[c] for c in pattern)
+
+
+def _nemotron_h(hidden, pattern, heads, kv_heads, head_dim, ssm_heads, ssm_head_dim, ssm_state,
+                ssm_groups, experts, top_k, expert_ffn, shared_ffn, routed_scale, vocab, seq):
+    """A ``nemotron_h`` stack: every layer ``x + f(RMSNorm(x))`` with ``f`` a
+    Mamba-2 mixer, an expert layer (relu2 experts of two matrices under a
+    sigmoid router with a selection bias, one shared expert of its own
+    width) or attention without any positional term; untied head.
+    Unrolled: the layers differ. Served only."""
+    return TransformerConfig(
+        vocab_size=vocab, hidden_size=hidden, num_layers=len(pattern), num_heads=heads,
+        num_kv_heads=kv_heads, head_dim=head_dim, intermediate_size=expert_ffn, max_seq_len=seq,
+        pos_embedding="none", norm="rmsnorm", activation="relu2", tie_embeddings=False,
+        layernorm_epsilon=1e-5, attn_bias=False, mlp_bias=False,
+        layer_types=nemotron_h_layers(pattern), num_experts=experts, moe_top_k=top_k,
+        moe_ffn_size=expert_ffn, moe_shared_experts=1, moe_shared_ffn_size=shared_ffn,
+        moe_routed_scale=routed_scale, moe_scoring="sigmoid", moe_dropless=True,
+        ssm_state_size=ssm_state, ssm_conv_kernel=4, ssm_num_heads=ssm_heads,
+        ssm_head_dim=ssm_head_dim, ssm_groups=ssm_groups, ssm_chunk_size=128, scan_layers=False)
+
+
+@register("nemotron-3-nano-30b-a3b")
+def nemotron_3_nano_30b_a3b():
+    """NVIDIA-Nemotron-3-Nano-30B-A3B at its published sizes (huggingface.co/
+    nvidia/NVIDIA-Nemotron-3-Nano-30B-A3B-BF16 config.json, ``model_type:
+    nemotron_h``): 52 one-sublayer blocks (23 Mamba-2, 23 expert, 6
+    attention), 64 Mamba-2 heads of 64 with a state of 128 in 8 groups, 32
+    query and 2 key/value heads of 128, 128 experts of 1,856 top-6 with a
+    shared one of 3,712, 31.6 B parameters. ``num_layers`` is overridden
+    together with ``layer_types``."""
+    return _nemotron_h(2688, "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME", 32, 2,
+                       128, 64, 64, 128, 8, 128, 6, 1856, 3712, 2.5, 131072, 262144)
+
+
+@register("tiny-nemotron-h")
+def tiny_nemotron_h():
+    """Test-scale ``nemotron_h``: 8 one-sublayer blocks, every kind present
+    (the dense FFN too), 4 Mamba-2 heads of 8 with a state of 16 in 2 groups,
+    8 experts top-2 with a shared one twice as wide, chunks of 8."""
+    import dataclasses
+    cfg = _nemotron_h(64, "MEM*E-ME", 4, 2, 16, 4, 8, 16, 2, 8, 2, 32, 64, 2.5, 256, 256)
+    return dataclasses.replace(cfg, ssm_chunk_size=8)

@@ -36,7 +36,12 @@ from ..comm import comm as dist
 # it, and a differential cross-attention over the nearest full differential
 # layer's K/V rows below it
 SAMBAY_TYPES = ("mamba", "diff_attention", "gmu", "cross_attention")
-LAYER_TYPES = ("full_attention", "linear_attention") + SAMBAY_TYPES
+# the kinds whose block is ONE sublayer, ``x + f(norm(x))`` (``nemotron_h``):
+# a Mamba-2 mixer, attention alone, an expert FFN alone, a dense FFN alone.
+# Each maps to (mixer, FFN), one of them absent
+ONE_SUBLAYER_TYPES = {"mamba2": ("mamba2", None), "attention": ("full_attention", None),
+                      "moe": (None, "moe"), "mlp": (None, "mlp")}
+LAYER_TYPES = ("full_attention", "linear_attention") + SAMBAY_TYPES + tuple(ONE_SUBLAYER_TYPES)
 
 
 def sambay_layers(num_layers, mb_per_layer, sliding_window):
@@ -73,7 +78,8 @@ class TransformerConfig:
     # family switches
     pos_embedding: str = "rope"  # "rope" | "learned" | "none" | "alibi"
     norm: str = "rmsnorm"  # "rmsnorm" | "layernorm"
-    activation: str = "swiglu"  # "swiglu" | "gelu" (tanh) | "gelu_exact" (erf) | "relu" | "geglu"
+    # "swiglu" | "gelu" (tanh) | "gelu_exact" (erf) | "relu" | "geglu" | "relu2" (relu squared)
+    activation: str = "swiglu"
     tie_embeddings: bool = True
     rope_theta: float = 10000.0
     rotary_dim: Optional[int] = None  # partial rotary (GPT-J/NeoX); None = full head
@@ -115,7 +121,16 @@ class TransformerConfig:
     moe_first_expert: int = 0
     moe_ffn_size: Optional[int] = None  # expert width; None = ffn_size
     moe_shared_experts: int = 0  # always-on experts of width moe_ffn_size, computed once
+    # the shared experts' joint width where it is published apart from the
+    # routed experts' (moe_shared_expert_intermediate_size); None =
+    # moe_shared_experts x the routed width
+    moe_shared_ffn_size: Optional[int] = None
     moe_routed_scale: float = 1.0  # routed_scaling_factor on the renormalised top-k weights
+    # how the router scores: "softmax" (probabilities, top-k, renormalised),
+    # or "sigmoid" (DeepSeek-V3's rule: s = sigmoid(logits); the k experts
+    # are the top of s + a stored selection bias, their weights s itself,
+    # renormalised). Serving dispatch only
+    moe_scoring: str = "softmax"
     # no capacity buffers anywhere: the full (no-cache) forward routes per
     # token like serving does and reports no aux loss (serving-only presets)
     moe_dropless: bool = False
@@ -137,12 +152,15 @@ class TransformerConfig:
     rope_mscale: float = 1.0
     rope_mscale_all_dim: float = 0.0
     attn_temp_beta: float = 0.0  # g(t) = 1 + beta * ln(1 + floor(t / rope_original_max_len))
-    # per-layer mixers: one of LAYER_TYPES for each layer ("full_attention":
+    # per-layer blocks: one of LAYER_TYPES for each layer ("full_attention":
     # Attention; "linear_attention": the gated delta rule, GatedDeltaNet,
     # which holds a per-slot recurrent state and convolution window and no
     # cache rows; SAMBAY_TYPES: Mamba, DiffAttention, GatedMemoryUnit and
     # DiffAttention(cross), which hold state, rows or a ring of rows, nothing,
-    # and nothing). () = every layer full attention. Needs unrolled layers
+    # and nothing; ONE_SUBLAYER_TYPES: a block of ONE sublayer, a Mamba-2
+    # mixer, attention, an expert FFN or a dense FFN alone, which hold state,
+    # rows, nothing and nothing). () = every layer full attention. Needs
+    # unrolled layers
     layer_types: Tuple[str, ...] = ()
     # keys a diff_attention layer's query sees, its own included (0 = all):
     # such a layer's slot holds a ring of about that many rows. () = none
@@ -152,6 +170,14 @@ class TransformerConfig:
     ssm_conv_kernel: int = 4
     ssm_expand: int = 2
     ssm_dt_rank: int = 0
+    # a mamba2 layer (models/mamba2.py): ssm_num_heads heads of ssm_head_dim
+    # channels (d_inner is their product, NOT ssm_expand x hidden), a state of
+    # ssm_head_dim x ssm_state_size a head, B and C shared by ssm_groups
+    # groups of heads, the chunked matrix form in chunks of ssm_chunk_size
+    ssm_num_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_groups: int = 1
+    ssm_chunk_size: int = 128
     mlp_bias: Optional[bool] = None  # None = follow norm (layernorm -> biased)
     linear_num_heads: int = 0  # key heads = value heads of a linear layer
     linear_key_head_dim: int = 0
@@ -263,11 +289,29 @@ class TransformerConfig:
                                          f"to read")
                     seen.add("full" if t == "diff_attention" and not self.layer_window(i)
                              else t)
-            if self.kv_lora_rank or self.num_experts or self.parallel_residual \
+            one = set(self.layer_types) & set(ONE_SUBLAYER_TYPES)
+            if one:
+                if set(self.layer_types) - set(ONE_SUBLAYER_TYPES):
+                    raise ValueError(f"the one-sublayer kinds {tuple(ONE_SUBLAYER_TYPES)} do "
+                                     f"not mix with kinds whose block is a mixer and an FFN")
+                if "mamba2" in one and not (
+                        self.ssm_num_heads and self.ssm_head_dim and self.ssm_state_size
+                        and self.ssm_conv_kernel > 1 and self.ssm_chunk_size > 0
+                        and self.ssm_groups > 0 and self.ssm_num_heads % self.ssm_groups == 0):
+                    raise ValueError("mamba2 layers need ssm_num_heads (a multiple of "
+                                     "ssm_groups), ssm_head_dim, ssm_state_size and a "
+                                     "convolution of width > 1")
+                if ("moe" in one) != bool(self.num_experts):
+                    raise ValueError("moe layers need num_experts, and experts under "
+                                     "layer_types need moe layers to live in")
+                if self.post_norm or self.dropout > 0:
+                    raise ValueError("a one-sublayer block is x + f(norm(x)): no post_norm, "
+                                     "no dropout")
+            if self.kv_lora_rank or (self.num_experts and not one) or self.parallel_residual \
                     or self.int8_weights:
                 raise ValueError("layer_types composes with plain attention and a dense MLP "
-                                 "in a float dtype only (no latent attention, experts, "
-                                 "parallel residual or int8 weights)")
+                                 "in a float dtype only (no latent attention, experts "
+                                 "outside moe layers, parallel residual or int8 weights)")
         if self.post_norm and (self.num_experts or self.parallel_residual or self.dropout > 0):
             raise ValueError("post_norm composes with a dense MLP, sequential residuals "
                              "and no dropout only")
@@ -285,6 +329,12 @@ class TransformerConfig:
             raise ValueError(f"held experts [{self.moe_first_expert}, "
                              f"{self.moe_first_expert + self.experts_held}) lie outside the "
                              f"router's {self.num_experts}")
+        if self.moe_scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"moe_scoring must be 'softmax' or 'sigmoid', got "
+                             f"{self.moe_scoring!r}")
+        if self.num_experts and self.moe_scoring == "sigmoid" and not self.moe_dropless:
+            raise ValueError("the sigmoid router with a selection bias has no "
+                             "capacity-buffered path: set moe_dropless")
         if self.experts_held != self.num_experts and not self.moe_dropless:
             raise ValueError("a layer that holds a share of the experts has no "
                              "capacity-buffered path: set moe_dropless")
@@ -325,6 +375,14 @@ class TransformerConfig:
         """The mixer of layer ``layer_idx`` (one of :data:`LAYER_TYPES`)."""
         return self.layer_types[layer_idx] if self.layer_types else "full_attention"
 
+    def layer_parts(self, layer_idx):
+        """``(mixer, FFN)`` of layer ``layer_idx``'s block, either None where
+        the block has no such half (:data:`ONE_SUBLAYER_TYPES`). The mixer is
+        the kind's own name for the two-sublayer kinds; the FFN is ``"moe"``
+        or ``"mlp"``."""
+        kind = self.layer_type(layer_idx)
+        return ONE_SUBLAYER_TYPES.get(kind, (kind, "moe" if self.num_experts else "mlp"))
+
     def layer_window(self, layer_idx):
         """Keys layer ``layer_idx``'s queries see, their own included (0 = all)."""
         return self.layer_windows[layer_idx] if self.layer_windows else 0
@@ -342,6 +400,21 @@ class TransformerConfig:
     def ssm_inner(self):
         """Channels of a Mamba layer's state-space model (``d_inner``)."""
         return self.ssm_expand * self.hidden_size
+
+    @property
+    def mamba2_inner(self):
+        """Channels of a Mamba-2 layer (``d_inner``): heads x head size."""
+        return self.ssm_num_heads * self.ssm_head_dim
+
+    @property
+    def mamba2_conv_channels(self):
+        """Channels of a Mamba-2 layer's convolution: x, B and C side by side."""
+        return self.mamba2_inner + 2 * self.ssm_groups * self.ssm_state_size
+
+    @property
+    def shared_ffn_size(self):
+        """The shared experts' joint width."""
+        return self.moe_shared_ffn_size or self.moe_shared_experts * self.expert_ffn_size
 
     @property
     def carries_across_layers(self):
@@ -376,7 +449,8 @@ class TransformerConfig:
         per_h = 3 * h if self.activation in ("swiglu", "geglu") else 2 * h
         mlp = per_h * self.ffn_size
         if self.num_experts > 0:
-            mlp = (per_h * self.expert_ffn_size * (self.experts_held + self.moe_shared_experts)
+            shared = self.shared_ffn_size if self.moe_shared_experts else 0
+            mlp = (per_h * (self.expert_ffn_size * self.experts_held + shared)
                    + h * self.num_experts)
         emb = v * h * (1 if self.tie_embeddings else 2)
         pos = self.max_seq_len * h if self.pos_embedding == "learned" else 0
@@ -393,6 +467,14 @@ class TransformerConfig:
                    "gmu": 2 * h * di, "cross_attention": q_o}
             return (sum(per[t] for t in self.layer_types) + L * (mlp + 4 * h)
                     + emb + pos + 2 * h)
+        if set(self.layer_types) & set(ONE_SUBLAYER_TYPES):
+            di, cc = self.mamba2_inner, self.mamba2_conv_channels
+            nh = self.ssm_num_heads
+            per = {"mamba2": (h * (di + cc + nh) + cc * (self.ssm_conv_kernel + 1) + 3 * nh
+                              + di + di * h),
+                   "attention": attn, "mlp": per_h * self.ffn_size,
+                   "moe": mlp + (self.num_experts if self.moe_scoring == "sigmoid" else 0)}
+            return sum(per[t] + h for t in self.layer_types) + emb + pos + h
         n_lin = sum(t == "linear_attention" for t in self.layer_types)
         if n_lin:
             # q, k; v, gate, out; the two per-head gates with A_log and
@@ -732,7 +814,8 @@ def kv_pool_geometry(cfg, kv_cache):
     elif cfg.carries_across_layers:
         return "split"  # a pair's keys, and its values, as one head of 2 x head size
     else:
-        leaf = kv_cache[0][cfg.layer_types.index("full_attention")]
+        leaf = kv_cache[0][next(i for i in range(cfg.num_layers)
+                                if cfg.layer_parts(i)[0] == "full_attention")]
     return "packed" if leaf.shape[-1] == 2 * cfg.head_size else "split"
 
 
@@ -2105,6 +2188,8 @@ class MLP(nn.Module):
                 h = nn.gelu(h, approximate=False)  # erf (HF "gelu")
             elif cfg.activation == "quick_gelu":
                 h = h * nn.sigmoid(1.702 * h)  # CLIP's QuickGELU
+            elif cfg.activation == "relu2":
+                h = jnp.square(nn.relu(h))  # nemotron_h's relu2: two matrices, no gate
             else:
                 h = nn.relu(h)
         if cfg.bitwise_tp:
@@ -2144,7 +2229,26 @@ class Block(nn.Module):
                 make_norm(cfg, name="attn_norm")(x), kv_cache, write_index, q_spans, carry)
             x = x + h
             return x + MLP(cfg, name="mlp")(make_norm(cfg, name="mlp_norm")(x)), new_cache, carry
-        if kind == "linear_attention":
+        mixer_kind, ffn_kind = cfg.layer_parts(self.layer_idx)
+        # a one-sublayer block has ONE norm and no parameters of the absent half
+        mixer_norm, ffn_norm = (("attn_norm", "mlp_norm") if mixer_kind and ffn_kind
+                                else ("norm", "norm"))
+        if mixer_kind is None:
+            # an FFN alone: the layer's slot holds nothing
+            h, new_cache = None, (None if kv_cache is None else (None, None))
+            ff_in = make_norm(cfg, name=ffn_norm)(x)
+        elif mixer_kind == "mamba2":
+            from .mamba2 import Mamba2
+            narrow = Mamba2(cfg, layer_idx=self.layer_idx, name="mamba2")
+
+            def mixer(h, sin, cos, attn_mask, kv_cache, cache_index, position_ids, write_index,
+                      q_spans, lora_ops, ext_ops, seq_shard):
+                if lora_ops or ext_ops is not None or seq_shard or attn_mask is not None:
+                    raise NotImplementedError(
+                        "a mamba2 layer serves without adapters, extent chains, "
+                        "sequence-parallel spans or padding masks")
+                return narrow(h, kv_cache, write_index, q_spans, carry)[:2]
+        elif mixer_kind == "linear_attention":
             mixer = GatedDeltaNet(cfg, layer_idx=self.layer_idx, name="gdn")
         else:
             attention = LatentAttention if cfg.kv_lora_rank else Attention
@@ -2156,21 +2260,24 @@ class Block(nn.Module):
                                  write_index, q_spans, lora_ops, ext_ops, seq_shard)
             x = x + make_norm(cfg, name="attn_norm")(h)
             return x + make_norm(cfg, name="mlp_norm")(MLP(cfg, name="mlp")(x, lora_ops)), new_cache
-        h = make_norm(cfg, name="attn_norm")(x)
-        h, new_cache = mixer(
-            h, sin, cos, attn_mask, kv_cache, cache_index, position_ids, write_index,
-            q_spans, lora_ops, ext_ops, seq_shard)
-        if drop is not None:
-            h = drop(h, deterministic=deterministic)
-        if cfg.parallel_residual:
-            # GPT-J/NeoX: attn and mlp both read the pre-attn stream and add
-            # into ONE residual (GPT-J ties attn_norm == mlp_norm weights —
-            # the conversion duplicates them)
-            ff_in = make_norm(cfg, name="mlp_norm")(x)
-        else:
-            x = x + h
-            ff_in = make_norm(cfg, name="mlp_norm")(x)
-        if cfg.num_experts > 0:
+        if mixer_kind is not None:
+            h = make_norm(cfg, name=mixer_norm)(x)
+            h, new_cache = mixer(
+                h, sin, cos, attn_mask, kv_cache, cache_index, position_ids, write_index,
+                q_spans, lora_ops, ext_ops, seq_shard)
+            if drop is not None:
+                h = drop(h, deterministic=deterministic)
+            if ffn_kind is None:
+                return x + h, new_cache
+            if cfg.parallel_residual:
+                # GPT-J/NeoX: attn and mlp both read the pre-attn stream and add
+                # into ONE residual (GPT-J ties attn_norm == mlp_norm weights —
+                # the conversion duplicates them)
+                ff_in = make_norm(cfg, name=ffn_norm)(x)
+            else:
+                x = x + h
+                ff_in = make_norm(cfg, name=ffn_norm)(x)
+        if ffn_kind == "moe":
             from ..moe.layer import MoE
             if kv_cache is not None or cfg.moe_dropless:
                 # KV-cache (serving/decode) forward: deterministic per-token
@@ -2567,13 +2674,15 @@ class CausalLMModel:
         recurrent state ``(B, n, dk, dv)`` and the ``W - 1`` last inputs of
         its convolution ``(B, 1, W - 1, channels)``, at rest in the cache
         dtype; a Mamba layer's ``(B, 1, d_state, d_inner)`` and ``(B, 1, W -
-        1, d_inner)``) or ``"ring"`` (a row axis at ``ndim - 2`` of
+        1, d_inner)``; a Mamba-2 layer's ``(B, heads, head size, d_state)``
+        and ``(B, 1, W - 1, conv channels)``) or ``"ring"`` (a row axis at ``ndim - 2`` of
         ``cfg.ring_rows`` rows whatever ``max_len`` is, position ``p`` in row
         ``p mod R``: a windowed differential layer's K and V, per-slot bytes
         as a state's are). Every component keeps its slot axis at ``ndim -
         4``. A layer may declare nothing (a gated memory unit reads the
         forward's carry, a cross-attention layer the rows of the full layer
-        below it). :meth:`init_cache` builds the tree from it;
+        below it, a block that is an FFN alone has no mixer).
+        :meth:`init_cache` builds the tree from it;
         :meth:`cache_kinds` tells the slot pool which leaves are which. The
         tree is component-major and as wide as the widest declaration; a
         layer that declares fewer has None there."""
@@ -2615,16 +2724,24 @@ class CausalLMModel:
                 return ()  # gmu, cross_attention: they read the forward's carry
 
             return [declares(i, t) for i, t in enumerate(cfg.layer_types)]
-        if "linear_attention" not in cfg.layer_types:
+        mixers = [cfg.layer_parts(i)[0] for i in range(cfg.num_layers)]
+        if not {"linear_attention", "mamba2", None} & set(mixers):
             return [tuple(rows)] * cfg.num_layers
         if quantized or len(rows) != 2:
             raise NotImplementedError("a pool with state leaves has no int8 tier and no "
                                       "packed geometry")
-        state = (("state", (batch_size, cfg.linear_num_heads, cfg.linear_key_head_dim,
-                            cfg.linear_value_head_dim), dt, jnp.zeros),
-                 ("state", (batch_size, 1, cfg.linear_conv_kernel - 1,
-                            cfg.linear_conv_channels), dt, jnp.zeros))
-        return [state if t == "linear_attention" else tuple(rows) for t in cfg.layer_types]
+        state = lambda shape, W, channels: (
+            ("state", (batch_size, ) + shape, dt, jnp.zeros),
+            ("state", (batch_size, 1, W - 1, channels), dt, jnp.zeros))
+        declares = {
+            "full_attention": tuple(rows),
+            "linear_attention": state((cfg.linear_num_heads, cfg.linear_key_head_dim,
+                                       cfg.linear_value_head_dim), cfg.linear_conv_kernel,
+                                      cfg.linear_conv_channels),
+            "mamba2": state((cfg.ssm_num_heads, cfg.ssm_head_dim, cfg.ssm_state_size),
+                            cfg.ssm_conv_kernel, cfg.mamba2_conv_channels),
+            None: ()}  # an FFN alone
+        return [declares[m] for m in mixers]
 
     def cache_kinds(self):
         """``"rows"``, ``"ring"`` or ``"state"`` for every leaf of

@@ -16,7 +16,7 @@ import flax.linen as nn
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..comm import comm as dist
-from .sharded_moe import top_k_gating, top_k_serving_choice
+from .sharded_moe import sigmoid_serving_choice, top_k_gating, top_k_serving_choice
 
 # Serving MoE dispatches traced by this thread, (sparse, dense): a scheduler
 # reads it around a dispatch to learn which one a step program was built
@@ -75,6 +75,14 @@ def _kernel_leaves(kernels, activation, dtype):
     return (leaf("gate_proj") if glu else None), leaf("up_proj"), leaf("down_proj")
 
 
+def _pointwise(h, activation):
+    """The activation of an expert of two matrices: gelu, relu, or relu
+    squared (``relu2``)."""
+    if activation == "gelu":
+        return nn.gelu(h)
+    return jnp.square(nn.relu(h)) if activation == "relu2" else nn.relu(h)
+
+
 def expert_ffn(x, kernels, activation, dtype, bitwise_tp=False, keep_expert_axis=False):
     """Batched expert FFN math on EXPLICIT kernel leaves.
 
@@ -104,7 +112,7 @@ def expert_ffn(x, kernels, activation, dtype, bitwise_tp=False, keep_expert_axis
         h = jnp.einsum("ech,ehf->ecf", x, uk)
         if use_bias and "up_bias" in kernels:
             h = h + kernels["up_bias"][:, None, :].astype(h.dtype)
-        h = nn.gelu(h) if activation == "gelu" else nn.relu(h)
+        h = _pointwise(h, activation)
     if bitwise_tp and _tp_live():
         # serving bitwise-TP: gather the ffn-sharded activation (exact
         # concat over `tensor`) so the replicated down_proj contracts fully
@@ -115,6 +123,64 @@ def expert_ffn(x, kernels, activation, dtype, bitwise_tp=False, keep_expert_axis
     if use_bias:
         out = out + kernels["down_bias"][:, None, :].astype(out.dtype)
     return out
+
+
+def row_major_format(leaf):
+    """The :class:`~jax.experimental.layout.Format` in which an expert kernel
+    ``(E, K, N)`` rests as the grouped products read it: row-major, ``N`` in
+    the lanes. Where ``N`` fills no whole 128-lane tiles (1,856) the device's
+    default layout puts ``K`` in the lanes instead, and every program that
+    reads the leaf would first copy it whole into this form (0.63 GB a layer,
+    read and written, a sync; a step program of 7 such layers does not fit
+    the chip). A committed array keeps its layout into ``jax.jit``."""
+    from jax.experimental.layout import Format, Layout
+    return Format(Layout(major_to_minor=tuple(range(leaf.ndim))), leaf.sharding)
+
+
+def _expert_kernel_row_major(x):
+    return x
+
+
+def rest_experts_row_major(params):
+    """``params`` with every float expert kernel (a 3-d leaf under an
+    ``experts`` scope) at rest in :func:`row_major_format`. A leaf that
+    rests so already (every leaf on the CPU; ``N`` a multiple of 128 on the
+    chip) is handed back as it is.
+
+    The one program that relays a leaf is kept OUT of the persistent
+    compile cache (it compiles in a tenth of a second): an executable read
+    back from that cache hands out row-major buffers that REPORT the
+    default layout (jaxlib 0.9.0 on the v5e, my chip runs, PR 39), every
+    ``jax.jit`` then compiles for the default layout, and the step program
+    either copies the leaf after all or refuses the buffer by its size."""
+    relay = {}
+
+    def rest(path, leaf):
+        if (not isinstance(leaf, jax.Array) or leaf.ndim != 3
+                or "['experts']" not in jax.tree_util.keystr(path)
+                or not jnp.issubdtype(leaf.dtype, jnp.floating)):
+            return leaf
+        want = tuple(range(leaf.ndim))
+        layout = getattr(leaf.format, "layout", None)
+        if layout is None or tuple(layout.major_to_minor) == want:
+            return leaf
+        fmt = row_major_format(leaf)
+        if fmt not in relay:
+            relay[fmt] = jax.jit(_expert_kernel_row_major, out_shardings=fmt)
+        out = relay[fmt](leaf)
+        if tuple(out.format.layout.major_to_minor) != want:
+            raise RuntimeError(
+                f"expert kernel {jax.tree_util.keystr(path)} was relaid row-major and reports "
+                f"layout {out.format.layout}: the step programs would compile for the wrong one")
+        return out
+
+    key = "jax_persistent_cache_min_compile_time_secs"
+    was = getattr(jax.config, key)
+    jax.config.update(key, float("inf"))  # nothing compiled in here is written to the cache
+    try:
+        return jax.tree_util.tree_map_with_path(rest, params)
+    finally:
+        jax.config.update(key, was)
 
 
 def expert_rank(ids):
@@ -213,7 +279,7 @@ def sparse_expert_ffn(tokens, ids, weights, valid, kernels, first, activation, d
             else:
                 if "up_bias" in kernels:
                     up = up + jnp.take(kernels["up_bias"], e, axis=0).astype(dtype)
-                h = nn.gelu(up) if activation == "gelu" else nn.relu(up)
+                h = _pointwise(up, activation)
             y = product(h, dk, gs)
             if "down_bias" in kernels:
                 y = y + jnp.take(kernels["down_bias"], e, axis=0).astype(dtype)
@@ -269,7 +335,8 @@ class Experts(nn.Module):
                                ("down_proj", F, H)):
                 kernels[name + "_q"], kernels[name + "_scale"] = self._qparam(name, k, n)
         else:
-            kernels["gate_proj"] = self.param("gate_proj", init, (E, H, F), jnp.float32)
+            if self.activation != "relu2":  # an expert of two matrices has no gate leaf
+                kernels["gate_proj"] = self.param("gate_proj", init, (E, H, F), jnp.float32)
             kernels["up_proj"] = self.param("up_proj", init, (E, H, F), jnp.float32)
             kernels["down_proj"] = self.param("down_proj", init, (E, F, H), jnp.float32)
         if self.use_bias:  # Megatron-style biased expert FFNs
@@ -377,8 +444,7 @@ class MoE(nn.Module):
         import dataclasses
         from ..models.transformer import MLP
         cfg = self.cfg
-        wide = dataclasses.replace(
-            cfg, intermediate_size=cfg.moe_shared_experts * cfg.expert_ffn_size)
+        wide = dataclasses.replace(cfg, intermediate_size=cfg.shared_ffn_size)
         with jax.named_scope("moe_shared"):
             return MLP(wide, name="shared_expert")(x)
 
@@ -442,7 +508,13 @@ class MoE(nn.Module):
         sparse = expert_ops is None and _ep_size() == 1 and not _tp_live()
         tally_dispatch(sparse)
         with jax.named_scope("moe_router"):
-            ids, w = top_k_serving_choice(logits, k)  # (N, k) over ALL E, per token
+            # (N, k) over ALL E, per token
+            if cfg.moe_scoring == "sigmoid":
+                bias = self.param("e_score_correction_bias", nn.initializers.zeros, (E, ),
+                                  jnp.float32)
+                ids, w = sigmoid_serving_choice(logits, bias, k)
+            else:
+                ids, w = top_k_serving_choice(logits, k)
             w = w * cfg.moe_routed_scale
             counts = jnp.zeros((E, ), jnp.int32).at[ids].add(
                 jnp.broadcast_to(valid[:, None], ids.shape).astype(jnp.int32))

@@ -370,6 +370,25 @@ def walk_block_kv(nkv, g, D, S, block_kv, q_dtype, kv_dtype, quantized=False, pa
     return _pick_blocks(nkv, g, D, S, block_kv, q_dtype, kv_dtype, quantized, packed)[1]
 
 
+def span_tile(rep, T, D, S, block_kv, q_dtype, kv_dtype, quantized=False, packed=False):
+    """Query columns ONE kernel call takes of a span of ``T`` at ``rep`` query
+    heads a kv head: ``T`` itself where a grid step's blocks fit the chip's
+    VMEM (every shape served before PR 39), else ``T`` halved until they do
+    (16 query heads a kv head x 512 columns of width 128 do not: the folded
+    query, output and softmax state of 8,192 columns alone pass the budget).
+    :func:`paged_span_attention` then walks the row's keys once a tile, each
+    tile's causal end advanced by its first column."""
+    t = T
+    while True:
+        try:
+            _pick_blocks(1, rep * t, D, S, block_kv, q_dtype, kv_dtype, quantized, packed)
+            return t
+        except ValueError:
+            if t % 2:
+                raise
+            t //= 2
+
+
 def _decode_call(qg, kv, start, ends, *, block_kv, scale, span=1,
                  k_scale=None, v_scale=None, ext=None, sink=None, win=None):
     """Shared pallas_call builder: row ``i`` attends its own window
@@ -607,10 +626,19 @@ def paged_span_attention(q, k_cache, v_cache, start, base, *, block_kv=256,
     ``idx % span``. Other arguments as :func:`paged_decode_attention`.
     Returns (B, H, T, D)."""
     B, H, T, D = q.shape
-    return _paged(q, _kv_leaves(k_cache, v_cache), start, base + 1,
-                  span=T, block_kv=block_kv, scale=scale, k_scale=k_scale,
-                  v_scale=v_scale, ext=ext, sink=sink, window=window, mesh=mesh,
-                  axis=axis)
+    kv = _kv_leaves(k_cache, v_cache)
+    tile = span_tile(H // kv[0].shape[1], T, D, kv[0].shape[2], block_kv, q.dtype,
+                     kv[0].dtype, k_scale is not None, len(kv) == 1)
+    call = functools.partial(_paged, span=tile, block_kv=block_kv, scale=scale,
+                             k_scale=k_scale, v_scale=v_scale, ext=ext, sink=sink,
+                             window=window, mesh=mesh, axis=axis)
+    if tile == T:
+        return call(q, kv, start, base + 1)
+    # a row that attends nothing (end <= start) attends nothing in any tile
+    return jnp.concatenate([
+        call(q[:, :, t0:t0 + tile], kv, start,
+             jnp.where(base + 1 > start, base + 1 + t0, base + 1))
+        for t0 in range(0, T, tile)], axis=2)
 
 
 # ----------------------------------------------------------- seq-parallel span

@@ -1,0 +1,468 @@
+"""A ``nemotron_h`` stack (``layer_types`` with ``mamba2``, ``moe``,
+``attention``, ``mlp``: every block ONE sublayer; NVIDIA-Nemotron-3-Nano's
+kinds) at a small size on the CPU in float32: the program against the plain
+reference (``chipbench/references/nemotron_h.py``: one causal forward, no
+cache, the recurrence token by token, a loop over experts), the chunked
+matrix form against the recurrence, the router's rule, the share of the
+experts against the uncut layer, the slot pool's span programs over Mamba-2
+state beside K/V rows, the refusals, the parameter trees and the sizes of the
+published preset.
+
+Weights: the benchmark's own draw (``serve_nemotron_h.nemotron_params``: the
+layers' published starts) with biases and norm scales moved off 0 and 1, so
+that a dropped bias or scale shows. ``TOL``: the reference's float32 limit,
+1e-5; the served path reads 1e-6 at worst; a wrong state, span, weight or
+choice gives 1e-3 and up."""
+
+import dataclasses
+import hashlib
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import deepspeed_tpu
+from chipbench.references import nemotron_h as ref
+from deepspeed_tpu.models import available_models, get_model, mamba2, nemotron_h_layers
+from deepspeed_tpu.moe.sharded_moe import sigmoid_serving_choice
+
+TOL = ref.TOL["float32"]
+HP = {"eps": 1e-5, "top_k": 2, "routed_scale": 2.5, "ssm_heads": 4, "ssm_head_dim": 8,
+      "ssm_state": 16, "ssm_groups": 2, "first": 0}
+VOCAB = 256
+
+
+def _params(model, seed=7):
+    """The benchmark's draw, biases and norm scales perturbed."""
+    from chipbench.jobs.serve_nemotron_h import nemotron_params
+    root = jax.random.key(seed)
+
+    def perturb(path, leaf):
+        name = jax.tree_util.keystr(path)
+        key = jax.random.fold_in(root, int(hashlib.sha256(name.encode()).hexdigest()[:7], 16))
+        if name.endswith("['conv_bias']"):
+            return 0.1 * jax.random.normal(key, leaf.shape, leaf.dtype)
+        if name.endswith("['scale']") or name.endswith("['D']"):
+            return 1.0 + 0.1 * jax.random.normal(key, leaf.shape, leaf.dtype)
+        return leaf
+
+    return jax.tree_util.tree_map_with_path(perturb,
+                                            nemotron_params(model, seed, jnp.dtype("float32")))
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    model = get_model("tiny-nemotron-h", dtype=jnp.float32)
+    return model, _params(model)
+
+
+def _engine(tiny, slots=4, chunk=16, steps=4, kernels=False, **cb):
+    model, params = tiny
+    return deepspeed_tpu.init_inference(model, config={
+        "dtype": "float32", "kernel_inject": kernels, "max_out_tokens": 128,
+        "continuous_batching": dict({"enabled": True, "num_slots": slots,
+                                     "steps_per_sync": steps, "prefill_chunk": chunk}, **cb)},
+        params=params)
+
+
+def _prompts(lengths, seed=0):
+    rng = np.random.RandomState(seed)
+    return [[int(t) for t in rng.randint(0, VOCAB, n)] for n in lengths]
+
+
+def _tree(model, params):
+    return ref.from_tree(params, model.cfg.layer_types)
+
+
+def _agrees(model, params, ids, hp=HP):
+    with jax.default_matmul_precision("highest"):
+        got = model.apply(params, ids)
+    want, _ = ref.forward(_tree(model, params), ids, hp)
+    res = ref.compare(got.reshape(-1, VOCAB), want.reshape(-1, VOCAB), tol=TOL)
+    assert res["ok"], res["error"]
+
+
+def test_full_forward_matches_the_reference(tiny):
+    _agrees(*tiny, jax.random.randint(jax.random.key(1), (2, 70), 0, VOCAB))
+
+
+@pytest.mark.parametrize("kinds", [("mamba2", ), ("attention", ), ("moe", ), ("mlp", )])
+def test_each_sublayer_alone_matches_its_reference(tiny, kinds):
+    """A stack of the one kind, 50 positions: six chunks of 8 and a partial
+    one, so the state is carried and the padding past the end is inert."""
+    cfg = dataclasses.replace(tiny[0].cfg, num_layers=1, layer_types=kinds,
+                              num_experts=8 if kinds == ("moe", ) else 0)
+    model = type(tiny[0])(cfg)
+    _agrees(model, _params(model, seed=11),
+            jax.random.randint(jax.random.key(2), (2, 50), 0, VOCAB))
+
+
+@pytest.mark.parametrize("T, chunk", [(1, 8), (5, 8), (8, 8), (29, 8), (64, 16), (50, 128)])
+def test_chunked_matrix_form_is_the_recurrence(T, chunk):
+    """``ssd_chunked`` from a non-zero state against a scan of ``ssd_step``,
+    at lengths under, at and over a chunk and not a multiple of it; a column
+    with Delta 0 (padding, a dead column) leaves the state as it is."""
+    B, nh, hd, N, G = 2, 4, 8, 16, 2
+    ks = jax.random.split(jax.random.key(T), 8)
+    x = jax.random.normal(ks[0], (B, T, nh, hd))
+    dt = jax.nn.softplus(jax.random.normal(ks[1], (B, T, nh)) - 2.0)
+    dt = dt.at[1, T // 2:].set(0.0)  # row 1 lives over its first half only
+    a = -jnp.exp(mamba2.mamba2_a_log_init(ks[2], (nh, )))
+    Bm, Cm = (jax.random.normal(k, (B, T, G, N)) for k in ks[3:5])
+    D = 1.0 + 0.1 * jax.random.normal(ks[5], (nh, ))
+    S0 = jax.random.normal(ks[6], (B, nh, hd, N))
+    per_head = lambda m: jnp.repeat(m, nh // G, axis=1)
+    S, want = S0, []
+    for t in range(T):
+        y, S = mamba2.ssd_step(S, x[:, t], dt[:, t], a, per_head(Bm[:, t]), per_head(Cm[:, t]), D)
+        want.append(y)
+    got, S_got = mamba2.ssd_chunked(S0, x, dt, a, Bm, Cm, D, chunk)
+    np.testing.assert_allclose(got, jnp.stack(want, axis=1), rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(S_got, S, rtol=1e-5, atol=1e-5)
+    half, S_half = mamba2.ssd_chunked(S0[1:], x[1:, :T // 2], dt[1:, :T // 2], a, Bm[1:, :T // 2],
+                                      Cm[1:, :T // 2], D, chunk) if T > 1 else (None, S0[1:])
+    np.testing.assert_allclose(S_got[1:], S_half, rtol=1e-5, atol=1e-5)
+
+
+def test_router_chooses_by_s_plus_b_and_weighs_by_s():
+    """Choice by ``s + b``, weights by ``s`` without ``b``, renormalised over
+    the k (the program then multiplies by 2.5); ties go to the lowest id."""
+    logits = jnp.log(jnp.asarray([[0.6, 0.5, 0.4, 0.3], [0.2, 0.2, 0.2, 0.2],
+                                  [0.9, 0.1, 0.8, 0.7]]) / (1 - jnp.asarray(
+                                      [[0.6, 0.5, 0.4, 0.3], [0.2, 0.2, 0.2, 0.2],
+                                       [0.9, 0.1, 0.8, 0.7]])))  # sigmoid^-1
+    bias = jnp.asarray([0.0, 0.0, 0.0, 0.35])  # lifts expert 3 past 0 and 1 in row 0
+    ids, w = sigmoid_serving_choice(logits, bias, 2)
+    assert ids.tolist() == [[3, 0], [3, 0], [3, 0]]  # row 1: 3 by its bias, then the lowest id
+    np.testing.assert_allclose(w[0], [0.3 / 0.9, 0.6 / 0.9], rtol=1e-6)  # by s, not s + b
+    np.testing.assert_allclose(w[2], [0.7 / 1.6, 0.9 / 1.6], rtol=1e-6)
+    ids0, w0 = sigmoid_serving_choice(logits, jnp.zeros(4), 2)
+    assert ids0.tolist() == [[0, 1], [0, 1], [0, 2]]
+    np.testing.assert_allclose(jnp.sum(w0, axis=-1), 1.0, rtol=1e-6)
+    # the reference's rule is the same rule, scale included
+    lp = {"gate": jnp.eye(4), "bias": bias}
+    hp = dict(HP, top_k=2)
+    u = logits[None]
+    rw, _ = ref.route(u, lp, hp)
+    np.testing.assert_allclose(rw[0, 0], [2.5 * 0.6 / 0.9, 0, 0, 2.5 * 0.3 / 0.9], rtol=1e-6)
+
+
+def test_expert_layer_scales_by_the_routed_factor(tiny):
+    """The program's expert layer against the reference's: the 2.5 is applied
+    to the routed part and not to the shared expert."""
+    model, params = tiny
+    cfg = dataclasses.replace(model.cfg, num_layers=1, layer_types=("moe", ))
+    one = type(model)(cfg)
+    p = _params(one, seed=5)
+    ids = jax.random.randint(jax.random.key(3), (1, 20), 0, VOCAB)
+    _agrees(one, p, ids)
+    with pytest.raises(AssertionError):
+        _agrees(one, p, ids, dict(HP, routed_scale=1.0))
+
+
+def test_shares_of_the_experts_add_up_to_the_uncut_layer(tiny):
+    """Four shares of 2 of the 8 experts, each computed by the PROGRAM's
+    expert layer as one chip of four would (router over all 8, top-2,
+    selection bias, its own two experts' part), with the shared expert, which
+    every chip computes alike, counted once: their sum is what the uncut
+    reference gives for the whole layer."""
+    from deepspeed_tpu.moe.layer import MoE
+    model, _ = tiny
+    whole_cfg = dataclasses.replace(model.cfg, num_layers=1, layer_types=("moe", ))
+    p = _params(type(model)(whole_cfg), seed=9)["layer_0"]["moe"]
+    x = jax.random.normal(jax.random.key(4), (2, 13, 64))
+    lp = {k: jnp.asarray(v, jnp.float32) for k, v in dict(
+        gate=p["gate"], bias=p["e_score_correction_bias"], w_up=p["experts"]["up_proj"],
+        w_down=p["experts"]["down_proj"], s_up=p["shared_expert"]["up_proj"]["kernel"],
+        s_down=p["shared_expert"]["down_proj"]["kernel"]).items()}
+    with jax.default_matmul_precision("highest"):
+        routed, _ = ref.routed(x, lp, HP, first=0, held=8)
+        shared = ref.shared(x, lp)
+        total = jnp.zeros_like(x)
+        for first in (0, 2, 4, 6):
+            cfg = dataclasses.replace(whole_cfg, moe_experts_held=2, moe_first_expert=first)
+            share = dict(p, experts={k: v[first:first + 2] for k, v in p["experts"].items()})
+            part = MoE(cfg).apply({"params": share}, x, serving=True)
+            # one share against the reference given the same share
+            mine, _ = ref.routed(x, dict(lp, w_up=lp["w_up"][first:first + 2],
+                                         w_down=lp["w_down"][first:first + 2]), HP, first=first)
+            np.testing.assert_allclose(part - shared, mine, atol=1e-5)
+            total = total + (part - shared)
+    np.testing.assert_allclose(total + shared, routed + shared, atol=1e-5)
+    assert float(jnp.max(jnp.abs(routed))) > 10 * 1e-5
+
+
+@pytest.mark.parametrize("slots, chunk, steps, split, kernels", [
+    (4, 16, 1, False, False), (4, 16, 4, False, False), (4, 12, 4, False, False),
+    (4, 2, 4, False, False), (8, 64, 4, True, False), (4, 16, 4, False, True),
+    (8, 64, 4, True, True)])
+def test_served_path_matches_the_reference(tiny, slots, chunk, steps, split, kernels):
+    """Prefill in chunks (sizes that do not divide the prompt; chunks of 12
+    end inside a Mamba-2 chunk of 8; a chunk of 2 is shorter than the
+    convolution), then 16 decode steps through the pool at every position,
+    neighbours live in other slots, in the whole-block program and in the
+    live-rows split, in XLA and through the paged kernels (interpreted); the
+    reference is given the program's routing and follows none of it."""
+    eng = _engine(tiny, slots, chunk, steps, kernels)
+    sched = eng.scheduler()
+    assert eng.model_config.attention_impl == ("flash" if kernels else "xla")
+    assert sched._splits_chunk(("fused", False, True, chunk, steps)) is split
+    prompts = _prompts((37, 70, 9))
+    handles = [sched.submit(p, max_new_tokens=16, collect_logits=True) for p in prompts]
+    sched.drain()
+    tree = _tree(eng.module, eng.params)
+    for p, h in zip(prompts, handles):
+        ids = jnp.asarray([p + [int(t) for t in h.result()[:-1]]], jnp.int32)
+        choice = h.result_choice()[:, None, :ids.shape[1]]
+        assert choice.shape[0] == 3  # the three expert layers
+        want, routing = ref.forward(tree, ids, HP, first=len(p) - 1, choice=choice)
+        res = ref.compare(h.result_logits(), want[0], routing["followed"], routing["refused"],
+                          tol=TOL)
+        assert res["ok"] and res["rows"] == 16, res["error"]
+        assert res["routing_margin_rows"] == res["routing_refused_rows"] == 0
+    assert sched.state_slots_reset == 3 and sched.radix is None
+    assert sched.moe_dispatch_programs["dense"] == 0 < sched.moe_dispatch_programs["sparse"]
+
+
+def test_a_span_0_slot_is_bit_for_bit_unchanged(tiny):
+    """A sync that advances other slots leaves an idle slot's state, window
+    and rows exactly as they were: slot 1's, once its request has ended,
+    through a neighbour's chunked prefill and both neighbours' decode."""
+    sched = _engine(tiny, slots=4, chunk=16, steps=4).scheduler()
+    a, b, c = _prompts((20, 50, 100))
+    long_one = sched.submit(a, max_new_tokens=60)
+    short = sched.submit(b, max_new_tokens=6)  # still live when the third is admitted
+    late = sched.submit(c, max_new_tokens=8)
+    while not short.done:
+        sched.step()
+    assert sched.cache.state[1] == "free" and late._req.slot == 2 and not late.done
+    slot1 = lambda: [np.asarray(leaf[1]) for leaf in jax.tree_util.tree_leaves(sched.cache.pool)]
+    before = slot1()
+    assert all(np.any(x != 0) for x in before)
+    steps = 0
+    while not (long_one.done and late.done):
+        sched.step()
+        steps += 1
+    assert steps >= 6 and sched.cache.state[1] == "free"
+    for x, y in zip(before, slot1()):
+        np.testing.assert_array_equal(x, y)
+
+
+def test_a_reused_slot_gives_a_fresh_pools_logits(tiny):
+    """A new request in a slot that held another starts from a zero state and
+    window: its logits are a fresh pool's, bit for bit, whatever the
+    neighbours; one prompt twice is served cold twice and counted."""
+    prompt = _prompts((40, ), seed=5)[0]
+    fresh = _engine(tiny, slots=2, chunk=16).scheduler()
+    want = fresh.submit(prompt, max_new_tokens=8, collect_logits=True)
+    fresh.drain()
+    used = _engine(tiny, slots=2, chunk=16).scheduler()
+    for p in _prompts((33, 61), seed=6):
+        used.submit(p, max_new_tokens=10)
+    used.drain()
+    for _ in range(2):
+        got = used.submit(prompt, max_new_tokens=8, collect_logits=True)
+        used.drain()
+        np.testing.assert_array_equal(got.result_logits(), want.result_logits())
+    assert used.state_slots_reset == 4 and used.prefix_cache_state_bypass == 4
+
+
+def test_neighbours_in_other_slots_change_nothing(tiny):
+    """One request alone in the pool and the same request among three others
+    (another routing mix in every expert layer, other states beside its own,
+    another slot, its chunks riding other programs): the same logits to the
+    reference's float32 limit (the CPU's products round with the block's
+    shape, by 3e-7)."""
+    prompt = _prompts((45, ), seed=8)[0]
+    alone = _engine(tiny, slots=4, chunk=16).scheduler()
+    want = alone.submit(prompt, max_new_tokens=12, collect_logits=True)
+    alone.drain()
+    crowd = _engine(tiny, slots=4, chunk=16).scheduler()
+    others = [crowd.submit(p, max_new_tokens=30) for p in _prompts((21, 60), seed=9)]
+    got = crowd.submit(prompt, max_new_tokens=12, collect_logits=True)
+    late = crowd.submit(_prompts((35, ), seed=10)[0], max_new_tokens=20)
+    crowd.drain()
+    assert all(h.done for h in others + [late]) and got._req.slot != want._req.slot
+    res = ref.compare(got.result_logits(), want.result_logits(), tol=TOL)
+    assert res["ok"] and res["rows"] == 12, res["error"]
+
+
+@pytest.mark.parametrize("overrides, message", [
+    ({"spec_tokens": 2}, "speculative verify"),
+    ({"max_extents": 2}, "extent chains"),
+    ({"seq_parallel_min_tokens": 64}, "sequence-parallel prefill"),
+    ({"prefix_store": object()}, "tier demotion"),
+    ({"allow_lossy_kv": True}, "lossy KV windows"),
+    ({"kv_cache_dtype": "int8"}, "an int8 KV pool"),
+    ({"adapter_store": object()}, "adapters"),
+])
+def test_what_a_pool_with_mamba2_state_refuses(tiny, overrides, message):
+    eng = _engine(tiny, kernels=True)
+    with pytest.raises(ValueError, match=r"holds recurrent state \(layer_types\).*" + message):
+        eng.scheduler(**overrides)
+
+
+def test_the_other_refusals(tiny):
+    """The static-batch cache, int8 weights, a tensor-parallel pool, experts
+    outside ``moe`` layers, a mix with two-sublayer kinds, the training
+    router; the fused decode gate declines by kind."""
+    model, params = tiny
+    eng = _engine(tiny)
+    sched = eng.scheduler()
+    with pytest.raises(ValueError, match="cannot migrate between replicas"):
+        sched.migrate_out(None, "key", None)
+    with pytest.raises(ValueError, match="continuous-batching scheduler"):
+        eng.generate([[1, 2, 3]], max_new_tokens=2)
+    assert any("attention, mamba2, mlp, moe" in r for r in sched._fused_block_reasons)
+    with pytest.raises(ValueError, match="served in its float dtype"):
+        deepspeed_tpu.init_inference(model, config={"dtype": "int8"}, params=params)
+    with pytest.raises(NotImplementedError, match="span programs"):
+        model.apply_with_cache(params, jnp.zeros((2, 4), jnp.int32), model.init_cache(2, 64), 0)
+    with pytest.raises(NotImplementedError, match="no int8 tier"):
+        model.init_cache(2, 64, quantized=True)
+    cfg = model.cfg
+    with pytest.raises(ValueError, match="do not mix"):
+        dataclasses.replace(cfg, num_layers=2, layer_types=("mamba2", "full_attention"))
+    with pytest.raises(ValueError, match="moe layers need num_experts"):
+        dataclasses.replace(cfg, num_layers=1, layer_types=("mamba2", ))
+    with pytest.raises(ValueError, match="experts outside moe layers"):
+        dataclasses.replace(get_model("tiny-hybrid").cfg, num_experts=4, moe_dropless=True)
+    with pytest.raises(ValueError, match="ssm_num_heads"):
+        dataclasses.replace(cfg, ssm_groups=3)
+    with pytest.raises(ValueError, match="no capacity-buffered path"):
+        dataclasses.replace(get_model("tiny-moe").cfg, moe_scoring="sigmoid")
+    from deepspeed_tpu.comm import comm
+    comm._state["mesh"] = None
+    comm.initialize_mesh(tensor=2)
+    tp = deepspeed_tpu.init_inference(model, config={
+        "dtype": "float32", "continuous_batching": {"enabled": True, "num_slots": 2}},
+        params=params)
+    with pytest.raises(ValueError, match="a tensor-parallel pool"):
+        tp.scheduler()
+
+
+def test_counters_of_required_state_work(tiny, tmp_path):
+    """Hand-counted: one request of 20 prompt tokens, chunk 16, K = 4, alone
+    in the pool; 3 Mamba-2 layers, 3 expert layers of 8 experts top-2."""
+    model, params = tiny
+    eng = deepspeed_tpu.init_inference(model, config={
+        "dtype": "float32", "max_out_tokens": 128,
+        "continuous_batching": {"enabled": True, "num_slots": 2, "steps_per_sync": 4,
+                                "prefill_chunk": 16},
+        "telemetry": {"enabled": True, "output_path": str(tmp_path)}}, params=params)
+    sched = eng.scheduler()
+    sched.submit(_prompts((20, ))[0], max_new_tokens=8)
+    sched.drain()
+    total = eng.telemetry.counter_total
+    # chunk 1 (16 columns, not final: it stands still in no substep, alone:
+    # K = 1); chunk 2 (4 columns, final, K = 4: 3 substeps); one decode sync
+    # (K = 4): its column and 3 substeps
+    assert total("serving/ssd_chunk_tokens") == 3 * (16 + 4)
+    assert total("serving/ssd_state_updates") == 3 * (3 + 1 + 3)
+    # every live position routes 2 pairs in each of 3 layers, all held here
+    positions = 16 + 4 + 3 + 4
+    assert total("serving/moe_pairs_here") == 3 * 2 * positions
+    assert not total("serving/moe_pairs_elsewhere")
+    assert total("serving/moe_layer_calls") == 3 * (1 + 4 + 4)
+    gauges = eng.telemetry.snapshot()["gauges"]
+    assert gauges["serving/state_bytes_per_slot"] == sched.cache.state_bytes_per_slot() == 9600
+    assert sched.cache.bytes_per_token() == 256
+    eng.telemetry.close()
+
+
+def test_a_one_sublayer_block_has_no_leaves_of_the_absent_half(tiny):
+    model, params = tiny
+    want = {"mamba2": {"norm", "mamba2"}, "moe": {"norm", "moe"}, "attention": {"norm", "attn"},
+            "mlp": {"norm", "mlp"}}
+    for i, kind in enumerate(model.cfg.layer_types):
+        assert set(params[f"layer_{i}"]) == want[kind], (i, kind)
+    assert set(params["layer_1"]["moe"]["experts"]) == {"up_proj", "down_proj"}  # no gate leaf
+    assert set(params["layer_1"]["moe"]["shared_expert"]) == {"up_proj", "down_proj"}
+    assert params["layer_1"]["moe"]["shared_expert"]["up_proj"]["kernel"].shape == (64, 64)
+    assert params["layer_1"]["moe"]["e_score_correction_bias"].shape == (8, )
+    assert set(params["layer_0"]["mamba2"]) == {"in_proj", "conv", "conv_bias", "dt_bias", "A_log",
+                                                "D", "norm", "out_proj"}
+    abstract = jax.eval_shape(model.init_params, jax.random.key(0))
+    assert sum(x.size for x in jax.tree_util.tree_leaves(abstract)) == model.cfg.num_params()
+    # the pool: state for a Mamba-2 layer, rows for attention, nothing for an FFN
+    kinds = model.cache_kinds()
+    assert [k for k in kinds[0]] == ["state", None, "state", "rows", None, None, "state", None]
+
+
+def _digest(tree):
+    items = [(jax.tree_util.keystr(p), tuple(getattr(leaf, "shape", ())),
+              str(getattr(leaf, "dtype", leaf)))
+             for p, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]]
+    return hashlib.sha256(repr(items).encode()).hexdigest()[:16], len(items)
+
+
+# every preset the parent commit 51fe9b3 registers: (digest, leaves) of its
+# parameter tree (paths, shapes, dtypes), taken there with ``_digest``
+PARENT_TREES = {
+    "gpt2-125m": ("6d07fb2736454732", 20), "gpt2-large": ("eecb65f1ac785bed", 20),
+    "gpt2-medium": ("849d0b37ca49721a", 20), "gpt2-xl": ("314611c3dbf7dfc6", 20),
+    "llama2-7b": ("700e0b920ed6e38b", 12), "llama3-70b": ("a69a8630b0d72197", 12),
+    "llama3-8b": ("e2bb5b2b306a8848", 12), "mistral-small-4-119b": ("9f1f4c1b70b5955c", 19),
+    "mixtral-8x7b": ("a0664719c25ea1b2", 13), "olmo-hybrid-7b": ("7bf46abc96dcf40d", 475),
+    "opt-125m": ("6146cd9c77b7a716", 20), "opt-66b": ("ba9a7bbc96259f34", 20),
+    "phi-4-mini-flash-reasoning": ("6ca2df6e33827984", 502), "tiny": ("2e710ce0485acc07", 11),
+    "tiny-gpt2": ("6ae0caca0a306fc5", 20), "tiny-hybrid": ("fe34f5c3f89eebab", 62),
+    "tiny-mla-moe": ("585ee1651c3e6625", 19), "tiny-moe": ("68682b378434514e", 12),
+    "tiny-sambay": ("a16812365d3d6eca", 136),
+}
+
+
+@pytest.mark.parametrize("name", sorted(PARENT_TREES))
+def test_existing_presets_build_the_trees_they_built(name):
+    """``Block`` picks mixer and FFN from the kind, relu2 experts have two
+    leaves, the shared expert a width of its own: none of it moves the
+    parameter tree of a preset the parent had."""
+    model = get_model(name)
+    assert _digest(jax.eval_shape(model.init_params, jax.random.key(0))) == PARENT_TREES[name]
+
+
+def test_no_preset_goes_unguarded():
+    assert set(available_models()) == set(PARENT_TREES) | {"nemotron-3-nano-30b-a3b",
+                                                           "tiny-nemotron-h"}
+
+
+def test_preset_builds_the_published_sizes():
+    """31.58 B parameters in 52 one-sublayer blocks (23 : 23 : 6); the chip's
+    share as the benchmark cuts it: 5.28 B, 1,085,440 B of state and window a
+    slot a Mamba-2 layer, 1,024 B a position an attention layer, nothing for
+    an expert layer."""
+    from chipbench import cells
+    from deepspeed_tpu.inference.kv_cache import SlotKVCache
+    whole = get_model("nemotron-3-nano-30b-a3b")
+    cfg = whole.cfg
+    pattern = "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"
+    assert cfg.layer_types == nemotron_h_layers(pattern) and len(pattern) == 52
+    assert [cfg.layer_types.count(k) for k in ("mamba2", "moe", "attention", "mlp")] == [
+        23, 23, 6, 0]
+    assert (cfg.hidden_size, cfg.vocab_size, cfg.num_heads, cfg.kv_heads, cfg.head_size) == (
+        2688, 131072, 32, 2, 128)
+    assert (cfg.ssm_num_heads, cfg.ssm_head_dim, cfg.ssm_state_size, cfg.ssm_groups,
+            cfg.ssm_conv_kernel, cfg.ssm_chunk_size, cfg.mamba2_inner,
+            cfg.mamba2_conv_channels) == (64, 64, 128, 8, 4, 128, 4096, 6144)
+    assert (cfg.num_experts, cfg.moe_top_k, cfg.expert_ffn_size, cfg.shared_ffn_size,
+            cfg.moe_routed_scale, cfg.moe_scoring, cfg.activation) == (
+        128, 6, 1856, 3712, 2.5, "sigmoid", "relu2")
+    assert cfg.num_params() == 31_577_940_288 and round(cfg.num_params() / 1e9, 2) == 31.58
+    abstract = jax.eval_shape(whole.init_params, jax.random.key(0))
+    assert sum(x.size for x in jax.tree_util.tree_leaves(abstract)) == cfg.num_params()
+    in_proj = abstract["layer_0"]["mamba2"]["in_proj"]["kernel"]
+    assert in_proj.shape == (2688, 10304)
+    config = cells.load_config("nemotron-3-nano-30b-a3b")
+    served = cells.build_model(config, dtype=jnp.bfloat16)
+    assert served.cfg.layer_types == nemotron_h_layers(pattern[:16])
+    assert served.cfg.num_params() == 5_282_534_208 == config["sizes"]["parameters_here"]
+    pool = jax.eval_shape(lambda: served.init_cache(192, 4096))
+    kv = SlotKVCache(pool, 192, 4096, kinds=served.cache_kinds())
+    assert kv.bytes_per_token() == 2 * 1024 == config["reference"]["kv_bytes_per_token"]
+    assert kv.state_bytes_per_slot() == 7 * 1_085_440 == config["reference"][
+        "state_bytes_per_slot"]
+    assert kv.capacity_bytes() == 192 * (4096 * 2048 + 7 * 1_085_440)
+    shapes = [leaf.shape for leaf in jax.tree_util.tree_leaves(pool)]
+    assert shapes.count((192, 64, 64, 128)) == 7 and shapes.count((192, 1, 3, 6144)) == 7
+    assert shapes.count((192, 2, 4096, 128)) == 4 and len(shapes) == 18

@@ -380,11 +380,17 @@ def _sync(model, chunk, fused=False):
 
 def _lower_sync(sds, model, slots, chunk, pool_len, fused=False):
     """(the lowered sync, its pool's shapes); an int8 model's parameters keep
-    their own dtypes, a float model's are bf16."""
+    their own dtypes, a float model's are bf16, its expert kernels at rest
+    as the engine leaves them (``moe.layer.rest_experts_row_major``)."""
+    from deepspeed_tpu.moe.layer import row_major_format
     shaped = lambda tree, dt=None: jax.tree_util.tree_map(
         lambda a: sds(a.shape, dt or a.dtype), tree)
     params = shaped(jax.eval_shape(model.init_params, jax.random.key(0)),
                     None if fused else jnp.bfloat16)
+    params = jax.tree_util.tree_map_with_path(
+        lambda path, a: (jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=row_major_format(a))
+                         if a.ndim == 3 and "['experts']" in jax.tree_util.keystr(path) else a),
+        params)
     pool = shaped(jax.eval_shape(lambda: model.init_cache(slots, pool_len)))
     rows = sds((slots, ), jnp.int32)
     return jax.jit(_sync(model, chunk, fused), donate_argnums=(1, )).lower(
@@ -571,3 +577,98 @@ def test_sambay_step_program(for_chip, step):
     print(step, "temporaries", mem.temp_size_in_bytes)
     assert mem.temp_size_in_bytes < 2.0e9, mem
     assert 7.71e9 + 3.48e9 + mem.temp_size_in_bytes < 15.75 * 2**30
+
+
+def _nemotron_share(kinds, pool_len=4096):
+    """NVIDIA-Nemotron-3-Nano-30B-A3B as the chip benchmark cuts it (experts
+    0-63 of 128, half the vocabulary), with the layers ``kinds``."""
+    base = get_model("nemotron-3-nano-30b-a3b")
+    return type(base)(dataclasses.replace(
+        base.cfg, dtype=jnp.bfloat16, num_layers=len(kinds), layer_types=kinds,
+        moe_experts_held=64, vocab_size=65536, max_seq_len=pool_len, attention_impl="flash"))
+
+
+@pytest.mark.parametrize("step", ["decode", "span"])
+def test_nemotron_h_step_program(for_chip, step):
+    """NVIDIA-Nemotron-3-Nano-30B-A3B's sync at the published widths as the
+    chip benchmark serves it (192 slots x 4096, ``prefill_chunk`` 512,
+    ``steps_per_sync`` 4, experts 0-63 of 128, half the vocabulary), with one
+    layer of each kind (a Mamba-2 mixer, an expert layer, attention, each a
+    block of ONE sublayer): the one-token update of 192 states of 64 x 64 x
+    128 and, in the chunk sync, the chunked matrix form over the chunk's own
+    slot, the sparse dispatch of 192 x 6 pairs through experts of two
+    matrices, attention without positions through the paged kernels. The
+    donated pool is updated in place: no whole-leaf copy, scatter or
+    transpose of a state, window or K/V leaf stands in the loop or around
+    it. It fits with its temporaries: the 16 layers' weights (10.57 GB) and
+    pool (3.07 GB) are 13.6 GB of the chip's 15.75 GiB."""
+    sds, _ = for_chip
+    slots, chunk, pool_len = 192, 512, 4096
+    model = _nemotron_share(("mamba2", "moe", "attention"), pool_len)
+    compiled, pool, around = _compile_sync(
+        sds, model, slots, 1 if step == "decode" else chunk, pool_len)
+    shapes = [leaf.shape for leaf in jax.tree_util.tree_leaves(pool)]
+    assert sorted(set(shapes)) == [(slots, 1, 3, 6144), (slots, 2, pool_len, 128),
+                                   (slots, 64, 64, 128)]
+    assert around == 0
+    text = compiled.as_text()
+    for shape in set(shapes):
+        assert _pool_relayouts(text, "[" + ",".join(map(str, shape)) + "]") == (0, 0), shape
+    assert "dstpu_decode_attn" in text and "dstpu_kv_commit" in text and "ragged-dot" in text
+    mem = compiled.memory_analysis()
+    print(step, "temporaries", mem.temp_size_in_bytes)
+    assert 10.57e9 + 3.07e9 + mem.temp_size_in_bytes < 15.75 * 2**30, mem
+
+
+def _accepted_cell_syncs():
+    """(cell, model one period deep, slots, chunk, pool length, fused) of the
+    serving cells the benchmark had before PR 39, as their tests above size
+    them."""
+    yield "gpt2-large.serve.chat-closed", _serving_model("gpt2-large"), 24, 64, 1024, True
+    base = get_model("mistral-small-4-119b")
+    yield "mistral-small-4-119b.serve.decode-closed", type(base)(dataclasses.replace(
+        base.cfg, dtype=jnp.bfloat16, num_layers=1, moe_experts_held=32, vocab_size=32768,
+        max_seq_len=8192, attention_impl="flash", scan_layers=False)), 64, 256, 2048, False
+    base = get_model("olmo-hybrid-7b")
+    yield "olmo-hybrid-7b.serve.decode-closed", type(base)(dataclasses.replace(
+        base.cfg, dtype=jnp.bfloat16, num_layers=4, layer_types=base.cfg.layer_types[:4],
+        max_seq_len=1024, attention_impl="flash")), 64, 128, 1024, False
+    base = get_model("phi-4-mini-flash-reasoning")
+    kinds = ("mamba", "diff_attention", "mamba", "diff_attention", "gmu", "cross_attention")
+    yield "phi-4-mini-flash.serve.reason-closed", type(base)(dataclasses.replace(
+        base.cfg, dtype=jnp.bfloat16, num_layers=6, layer_types=kinds,
+        layer_windows=(0, 512, 0, 0, 0, 0), max_seq_len=4096,
+        attention_impl="flash")), 64, 512, 4096, False
+
+
+# sha256[:16] of the lowered sync (StableHLO text), column and chunk, taken at
+# the parent commit 51fe9b3 with test_accepted_cells_lower_the_parents_programs'
+# own arithmetic. A PR that means to change one of these programs replaces its
+# digests; a PR that does not (a new model, a new layer kind) leaves them.
+PARENT_LOWERED = {
+    "gpt2-large.serve.chat-closed": ("15065a760c93d007", "9bccfab4d863721b"),
+    "mistral-small-4-119b.serve.decode-closed": ("7b6b3c73f810f160", "13426a2eaba288c8"),
+    "olmo-hybrid-7b.serve.decode-closed": ("ef0b951382842c66", "2b29bcdf4eabe0c7"),
+    "phi-4-mini-flash.serve.reason-closed": ("71e23c35b3433be3", "af018c91144275bb"),
+}
+
+
+def test_accepted_cells_lower_the_parents_programs(for_chip):
+    """Cells 2, 4, 5 and 6 lower, one period deep at their published widths
+    and the cells' shapes, the programs the parent lowers: a new layer kind,
+    a restructured ``Block`` or a new router leaves them as they were. What
+    is compared is the lowered text without the serialized bodies of the
+    Pallas kernels: a body carries the absolute path and line of every
+    frame that called it (``transformer.py``, this file), so it differs with
+    the checkout's directory and with any line added above a call, whatever
+    the kernel computes."""
+    import hashlib
+    sds, _ = for_chip
+    strip = lambda text: re.sub(r'(\\22body\\22: \\22)[^\\]+(\\22)', r"\1\2", text)
+    got = {}
+    for name, model, slots, chunk, pool_len, fused in _accepted_cell_syncs():
+        got[name] = tuple(
+            hashlib.sha256(strip(_lower_sync(sds, model, slots, width, pool_len, fused)[0]
+                                 .as_text()).encode()).hexdigest()[:16]
+            for width in (1, chunk))
+    assert got == PARENT_LOWERED

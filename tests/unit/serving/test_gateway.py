@@ -417,7 +417,13 @@ def test_drain_completes_in_flight_then_refuses(baseline):
         threads = [threading.Thread(target=run) for _ in range(3)]
         for t in threads:
             t.start()
-        time.sleep(0.1)
+        # all three are the gateway's (two in slots, one queued) before the
+        # drain starts: by its own books, not by a sleep that a loaded
+        # machine outlasts (a request arriving behind the drain is shed)
+        deadline = time.monotonic() + 60
+        while len(gw._active) + len(gw._fair) < 3:
+            assert time.monotonic() < deadline, (len(gw._active), len(gw._fair), gw.stats)
+            time.sleep(0.005)
         gw.begin_drain()
         status, headers, _ = post(gw.port, {"prompt": PROMPT, "max_tokens": 2})
         assert status == 503 and int(headers.get("Retry-After", 0)) >= 1
