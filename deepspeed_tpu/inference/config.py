@@ -368,6 +368,13 @@ class ContinuousBatchingConfig(DeepSpeedConfigModel):
                                  "occurrence (1 = always drafts when any token "
                                  "repeats; raise to cut wasted verify columns on "
                                  "low-repetition streams)")
+    spec_draft = ConfigField(default="ngram", help="who drafts: 'ngram' (the host-side "
+                             "prompt-lookup drafter, up to spec_tokens a step) or "
+                             "'module' (the model's multi-token-prediction module, "
+                             "mtp_layers: ONE draft a step, made and verified inside "
+                             "the step program, the advance decided on the device; "
+                             "needs spec_tokens 1). The emitted stream is that of "
+                             "spec_tokens 0 either way")
     kv_cache_dtype = ConfigField(default="auto", help="slot-pool KV storage: 'auto' "
                                  "= the model compute dtype; 'int8' = group-"
                                  "quantized paged KV (per-token-row fp16 scales, "

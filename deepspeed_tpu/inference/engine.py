@@ -763,6 +763,7 @@ class InferenceEngine:
                   "spec_tokens": cb.spec_tokens,
                   "spec_ngram_max": cb.spec_ngram_max,
                   "spec_ngram_min": cb.spec_ngram_min,
+                  "spec_draft": cb.spec_draft,
                   "kv_cache_dtype": cb.kv_cache_dtype}
             # long-context serving: extent chaining, seq-parallel prefill,
             # and the lossy-window gate ride the config section straight

@@ -97,7 +97,7 @@ HostGapTracker``): the host's work, by span, and the wait for the device
 Where the wait nears 0 the host sets the pace.
 
 **Self-speculative k-token decoding** (Leviathan et al. / prompt-lookup
-drafting, ``spec_tokens > 0``): each pure-decode sync first asks a host-side
+drafting, ``spec_tokens > 0``, ``spec_draft: "ngram"``): each pure-decode sync first asks a host-side
 :class:`~deepspeed_tpu.inference.speculative.PromptLookupDrafter` for up to
 ``spec_tokens`` continuation proposals per live slot, then verifies ALL of
 them in ONE fused span step — the same ``q_spans`` machinery chunked
@@ -109,7 +109,22 @@ first mismatch truncates and the garbage KV rows past the accepted prefix
 sit beyond the write head until later writes reclaim them. A sync where no
 slot drafts falls back to the plain ``steps_per_sync`` decode program, so
 the drafter being dry costs nothing. Compiled programs gain only the spec
-variant at width ``1 + spec_tokens`` — O(1) in k and acceptance mix.
+variant at width ``1 + spec_tokens`` — O(1) in k and acceptance mix. A host
+drafter reads the accepted tokens, so its pump is serial.
+
+**Drafting on the device** (``spec_draft: "module"``, ``spec_tokens: 1``;
+``inference/device_draft.py``): a model with a multi-token-prediction module
+(``mtp_layers``) drafts inside the step program. Each of a sync's steps
+verifies two columns a row (its last token and the module's draft), samples
+behind both, decides the advance (1 or 2) ON THE DEVICE, runs the module over
+the committed pairs (its own K/V rows in the pool, behind the stack's layers)
+and hands the next step its token, draft, write head and sampling step; the
+same four are carried to the next sync where it is launched ahead, so the
+pump stays one sync deep and the host learns advances at the landing (a row
+in flight is booked at 2 tokens a step until then). Void columns roll back by
+position, in rows that grow and in a windowed layer's ring alike. Bound at
+construction (``_launch_chunk`` / ``_launch_decode`` / ``_land``): a pool
+without such a drafter runs the programs and the pump it ran before.
 
 **int8 paged KV** (``kv_cache_dtype: "int8"``): the slot pool stores
 group-quantized K/V (per-token-row fp16 scales, ``ops/quantizer``
@@ -174,7 +189,8 @@ programs take one more operand, the rows' substep spans
 ``serving/prefix_cache_state_bypass``) and speculative verify, extent chains,
 sequence-parallel prefill, tiering, migration, lossy windows, an int8 pool,
 adapters and a tensor-parallel pool are refused at build: a state has no
-rows to mask, copy by length or roll back. Gauges
+rows to mask, copy by length or roll back (a RING does roll back the one void
+column of a device drafter's step, and only that: "Drafting on the device"). Gauges
 ``serving/state_bytes_per_slot``, ``serving/state_slots_live``; counter
 ``serving/state_slots_reset``. See ``benchmarks/SERVING.md`` ("Recurrent
 state beside K/V rows").
@@ -182,7 +198,8 @@ state beside K/V rows").
 **Ring rows and shared rows**: the scheduler follows the model's declaration
 (``cache_kinds``), not a layer kind's name: a pool with any leaf that is not
 ``"rows"`` (``"state"``; ``"ring"``: a windowed layer's K and V in a ring of
-about its window's rows, position ``p`` in row ``p mod R``) or with a layer
+about its window's rows, position ``p`` in row ``p mod R``: a differential
+layer's, or a plain attention layer's with its keys rotated at rest) or with a layer
 that declares nothing (a gated memory unit; a cross-attention layer that
 reads the rows of the full layer below it) is such a pool: same substep-spans
 operand (a ring does not forgive a garbage substep either), same refusals,
@@ -241,6 +258,7 @@ import numpy as np
 from ..comm import comm as dist
 from ..telemetry.capacity import program_shape
 from .config import check_prefill_chunk
+from .device_draft import DeviceDraft
 from .engine import _round_up
 from .kv_cache import RadixPrefixCache, SlotKVCache, copy_slot, slot_slice, slot_update
 from .speculative import PromptLookupDrafter
@@ -367,7 +385,8 @@ class _Request:
                  "temperature", "top_k", "top_p", "seed", "slot", "out", "logits",
                  "done", "cancelled", "submit_ts", "first_token_ts", "collect_logits",
                  "on_token", "trace", "adapter_id", "adapter_ref", "handle",
-                 "migrating", "error", "kv_window", "row_budget", "choice", "inflight")
+                 "migrating", "error", "kv_window", "row_budget", "choice", "inflight",
+                 "draft", "draft_logits")
 
     def __init__(self, rid, prompt, max_new_tokens, eos_token_id, do_sample,
                  temperature, top_k, top_p, seed, collect_logits, submit_ts,
@@ -393,6 +412,11 @@ class _Request:
         self.inflight = 0
         self.logits = []   # per-step (V,) logits when collect_logits
         self.choice = []   # (L, columns, k) expert ids per forward (MoE, collect_logits)
+        # a device drafter's (device_draft.py): its draft of the token after
+        # ``out[-1]``, as the last landing left it, and, collecting, the
+        # module's (V,) logits beside each entry of ``logits``
+        self.draft = 0
+        self.draft_logits = []
         self.done = False
         self.cancelled = False
         self.submit_ts = submit_ts
@@ -466,6 +490,16 @@ class SchedulerHandle:
         V = self._sched.engine.model_config.vocab_size
         return np.zeros((0, V), np.float32)
 
+    def result_draft_logits(self):
+        """(T, V) logits of the drafting module beside :meth:`result_logits`'s
+        rows (a device drafter, ``collect_logits``): row ``j`` is the module's
+        prediction of generated token ``j + 1`` from the stack's state that
+        chose token ``j``, and token ``j``."""
+        self.result()
+        if not self._req.draft_logits:
+            raise ValueError("no draft logits were collected for this request")
+        return np.stack(self._req.draft_logits)
+
     def result_choice(self):
         """(L, T, k) expert ids every layer's router chose at the positions
         the step programs ran for this request, in order: its prompt, then
@@ -533,7 +567,7 @@ def _merge_carried(ids, toks):
     return ids.at[:, 0].set(jnp.where(col == _CARRIED, toks[-1], col))
 
 
-class DecodeScheduler:
+class DecodeScheduler(DeviceDraft):
     """Continuous-batching serving loop over an :class:`InferenceEngine`.
 
     ``num_slots`` fixes the decode batch (the pool shape XLA compiles
@@ -550,7 +584,8 @@ class DecodeScheduler:
     def __init__(self, engine, num_slots=8, max_len=None,
                  collect_logits=False, steps_per_sync=4, prefill_chunk=64,
                  prefix_cache=True, spec_tokens=0, spec_ngram_max=3,
-                 spec_ngram_min=1, kv_cache_dtype="auto", compiled_cache=None,
+                 spec_ngram_min=1, spec_draft="ngram", kv_cache_dtype="auto",
+                 compiled_cache=None,
                  prefix_store=None, restore_min_tokens=0, adapter_store=None,
                  expert_store=None, max_extents=1, seq_parallel_min_tokens=0,
                  seq_parallel_degree=0, allow_lossy_kv=False):
@@ -568,8 +603,8 @@ class DecodeScheduler:
             collect_logits=collect_logits, steps_per_sync=steps_per_sync,
             prefill_chunk=prefill_chunk, prefix_cache=prefix_cache,
             spec_tokens=spec_tokens, spec_ngram_max=spec_ngram_max,
-            spec_ngram_min=spec_ngram_min, kv_cache_dtype=kv_cache_dtype,
-            prefix_store=prefix_store, restore_min_tokens=restore_min_tokens,
+            spec_ngram_min=spec_ngram_min, spec_draft=spec_draft,
+            kv_cache_dtype=kv_cache_dtype, prefix_store=prefix_store, restore_min_tokens=restore_min_tokens,
             adapter_store=adapter_store, expert_store=expert_store,
             max_extents=max_extents,
             seq_parallel_min_tokens=seq_parallel_min_tokens,
@@ -671,12 +706,39 @@ class DecodeScheduler:
             if unsupported:
                 raise ValueError("the latent KV pool does not support "
                                  + ", ".join(unsupported) + " yet")
+        # who drafts (spec_tokens > 0): the host-side n-gram drafter, or the
+        # model's own multi-token-prediction module inside the step program
+        # (device_draft.py), decided here once and for all
+        if spec_draft not in ("ngram", "module"):
+            raise ValueError(f"spec_draft must be 'ngram' or 'module', got {spec_draft!r}")
+        self._device_draft = spec_draft == "module" and int(spec_tokens) > 0
+        if self._device_draft:
+            unsupported = [name for name, on in (
+                ("a model without a multi-token-prediction module (mtp_layers)",
+                 not getattr(model.cfg, "mtp_layers", 0)),
+                ("spec_tokens other than 1 (the module drafts one token a step)",
+                 int(spec_tokens) != 1),
+                ("a prefill_chunk under 3", self.prefill_chunk < 3),
+                ("a latent pool", bool(getattr(model.cfg, "latent_width", 0))),
+                ("extent chains (max_extents > 1)", int(max_extents) > 1),
+                ("sequence-parallel prefill", bool(self._seq_chunk)),
+                ("lossy KV windows", self.allow_lossy_kv),
+                ("tier demotion (prefix_store)", prefix_store is not None),
+                ("adapters (adapter_store)", adapter_store is not None),
+                ("cold-expert offload (expert_store)", expert_store is not None),
+                ("a sharded pool", tp_ax > 1 or int(engine.mesh.shape[dist.EXPERT_AXIS]) > 1),
+            ) if on]
+            if unsupported:
+                raise ValueError("spec_draft='module' (drafting on the device) does not "
+                                 "support " + "; ".join(unsupported))
         # per-slot STATE or a RING of rows beside the rows that grow, or rows
         # that layers share, as the model declares them (cache_kinds): a
         # state has no rows for per-slot ends to mask and no past to roll
-        # back to, a ring forgets what a copy by prefix or a rollback would
-        # need, so everything that copies, truncates, moves or re-reads a
-        # slot's rows is refused here, by name
+        # back to, a ring forgets what a copy by prefix would need, so
+        # everything that copies, truncates, moves or re-reads a slot's rows
+        # is refused here, by name. A ring does roll back by position, the ONE
+        # void column of a device drafter's step (_ring_attention); a host
+        # drafter's wider verify would displace keys a later query still needs
         kinds = model.cache_kinds() if hasattr(model, "cache_kinds") else None
         declared = set(jax.tree_util.tree_leaves(kinds))
         # a layer that declares nothing reads rows a layer below it wrote,
@@ -692,7 +754,8 @@ class DecodeScheduler:
         # (windowed layers, their window, layers that read the shared rows):
         # what the host's counters of attended rows multiply by
         cfg = model.cfg
-        layers = range(cfg.num_layers) if getattr(cfg, "carries_across_layers", False) else ()
+        layers = range(cfg.num_layers) if (getattr(cfg, "carries_across_layers", False)
+                                           or any(getattr(cfg, "layer_windows", ()))) else ()
         windows = [cfg.layer_window(i) for i in layers]
         self._attn_layers = (sum(w > 0 for w in windows), max(windows, default=0),
                              sum(not w and cfg.layer_type(i) in ("diff_attention", "cross_attention")
@@ -701,8 +764,12 @@ class DecodeScheduler:
         self._ssd_layers = sum(mixer_of(i)[0] == "mamba2" for i in range(cfg.num_layers))
         if self._state_pool:
             unsupported = [name for name, on in (
-                ("speculative verify (spec_tokens): a state or a ring cannot roll back "
-                 "the rejected columns", int(spec_tokens) > 0),
+                ("speculative verify (spec_tokens): a recurrent state cannot roll back the "
+                 "rejected columns" if "state" in declared else
+                 "speculative verify by a host drafter (spec_tokens with spec_draft='ngram'): "
+                 "a ring rolls back ONE void column, the device drafter's (spec_draft="
+                 "'module')", int(spec_tokens) > 0
+                 and ("state" in declared or not self._device_draft)),
                 ("extent chains (max_extents > 1)", int(max_extents) > 1),
                 ("sequence-parallel prefill", bool(self._seq_chunk)),
                 ("tier demotion (prefix_store)", prefix_store is not None),
@@ -724,9 +791,10 @@ class DecodeScheduler:
         # one slot alongside at least one row of decode headroom)
         self.spec_tokens = max(0, min(int(spec_tokens), max(0, S - 2)))
         self._spec_width = 1 + self.spec_tokens
+        # the host-side drafter; a device drafter is no object of the host's
         self.drafter = (PromptLookupDrafter(self.spec_tokens, spec_ngram_max,
                                             spec_ngram_min)
-                        if self.spec_tokens > 0 else None)
+                        if self.spec_tokens > 0 and not self._device_draft else None)
         self.spec_steps = 0       # spec verify dispatches
         self.spec_row_steps = 0   # (live row, spec step) pairs
         self.spec_drafted = 0     # draft tokens submitted to verification
@@ -738,9 +806,11 @@ class DecodeScheduler:
         # trusts per-slot ends to mask the donor's rows past the match, and a
         # state has no rows to mask (a hit at any prefix but the donor's own
         # end would be silently wrong). The lookups not made are counted.
-        self.radix = (RadixPrefixCache(self.cache)
-                      if prefix_cache and not self._state_pool else None)
-        self._state_bypass = bool(prefix_cache) and self._state_pool
+        # A device drafter's pool serves cold too: its rows past a match hold
+        # the void columns of the donor's steps
+        cold = self._state_pool or self._device_draft
+        self.radix = RadixPrefixCache(self.cache) if prefix_cache and not cold else None
+        self._state_bypass = bool(prefix_cache) and cold
         self.prefix_cache_state_bypass = 0  # lookups not made
         self.state_slots_reset = 0  # requests begun from a zero state
         # hierarchical KV tier: a shared GlobalPrefixStore turns radix
@@ -874,6 +944,15 @@ class DecodeScheduler:
         from jax.sharding import NamedSharding, PartitionSpec
         self._ids_sharding = NamedSharding(engine.mesh, PartitionSpec())
         self._merge = jax.jit(_merge_carried, out_shardings=self._ids_sharding)
+        # what an iteration launches and how a sync lands: the plain step
+        # programs, or the device drafter's (device_draft.py). Bound here, so
+        # a pool without one runs what it ran before it existed
+        self._launch_chunk, self._launch_decode, self._land = (
+            self._fused_chunk_step, self._decode_step, self._land_block)
+        if self._device_draft:
+            self._init_device_draft()
+            self._launch_chunk, self._launch_decode, self._land = (
+                self._draft_chunk_step, self._draft_decode_step, self._land_draft)
         self._landed_ts = 0.0  # sink clock at the last landing
         self.syncs_ahead = 0   # launched while the previous sync was unlanded
         self.syncs_serial = 0  # launched (or run whole) with nothing in flight
@@ -1036,6 +1115,10 @@ class DecodeScheduler:
         budget = _round_up(req.max_new_tokens, self.steps_per_sync)
         if self.spec_tokens > 0:
             budget = max(budget, req.max_new_tokens + self._spec_width - 1)
+        if self._device_draft:
+            # a sync may advance a row by 2 a step, and its last step's second
+            # column is written whether it commits or not
+            budget = req.max_new_tokens + 2 * self.steps_per_sync
         if not self.cache.fits(req.prompt.size, budget):
             raise ValueError(
                 f"request needs {req.prompt.size + budget} cache rows > "
@@ -1476,7 +1559,7 @@ class DecodeScheduler:
         ran = None
         if self._prefill is not None:
             kind = "fused"
-            ran = self._fused_chunk_step()
+            ran = self._launch_chunk()
         elif self.active:
             if self._parked and all(s in self._parked for s in self.active):
                 # nothing can dispatch and nothing can ever free a row:
@@ -1491,7 +1574,7 @@ class DecodeScheduler:
                 ran = self._spec_decode_step()
                 kind = "spec" if ran is not None else kind
             else:
-                ran = self._decode_step()
+                ran = self._launch_decode()
                 kind = "decode" if ran is not None else kind
         prev = self._take_flight()
         if ran is not None:
@@ -1514,8 +1597,9 @@ class DecodeScheduler:
             self._iter += 1
         return delivered, kind
 
-    def _land(self, fl):
-        """Land a launched sync: fetch its block (``sched/fetch``), deliver
+    def _land_block(self, fl):
+        """Land a launched sync (``self._land`` of a pool without a device
+        drafter): fetch its block (``sched/fetch``), deliver
         the decode rows' tokens and the chunk's (``sched/deliver``). A row
         whose request ended while the sync was in flight (an EOS or a
         cancellation the launch could not know of) computed once more for
@@ -2292,7 +2376,12 @@ class DecodeScheduler:
         [4]) used for batch-shape recovery; ``call_args`` is what the program
         actually takes."""
         cap = self.capacity
-        if cap is not None:  # the sink is on
+        if cap is not None and self._device_draft:
+            key = cap.key_for(fn)
+            width, ksteps = program_shape(key)
+            split, ext_walk = False, False
+            self._count_draft_dispatch(key, spans, lens, chunk)
+        elif cap is not None:  # the sink is on
             key = cap.key_for(fn)
             width, ksteps = program_shape(key)
             split = self._splits_chunk(key)
@@ -2460,9 +2549,15 @@ class DecodeScheduler:
                     groups[(window, 0, cfg.kv_heads // 2, 2 * cfg.head_size, False)] += 1
                 elif kind in ("diff_attention", "cross_attention"):
                     groups[(0, 0, cfg.kv_heads // 2, 2 * cfg.head_size, False)] += 1
+            elif mixer == "full_attention" and window and getattr(cfg, "layer_windows", ()):
+                if cfg.ring_rows(i) == window and shard == 1:  # else XLA reads the ring
+                    groups[(window, 0, cfg.kv_heads, cfg.head_size, False)] += 1
             elif mixer == "full_attention":
                 groups[(0, window, cfg.kv_heads // shard, cfg.head_size,
                         kv_packs(cfg.head_size))] += 1
+        if getattr(cfg, "mtp_layers", 0):  # the module's own rows, read while it drafts
+            groups[(0, 0, cfg.kv_heads // shard, cfg.head_size,
+                    kv_packs(cfg.head_size))] += cfg.mtp_layers
         return [(n, ring, window, (nkv, cfg.num_heads // shard // nkv, D, packed))
                 for (ring, window, nkv, D, packed), n in groups.items()]
 

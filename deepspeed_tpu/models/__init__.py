@@ -280,3 +280,48 @@ def tiny_nemotron_h():
     import dataclasses
     cfg = _nemotron_h(64, "MEM*E-ME", 4, 2, 16, 4, 8, 16, 2, 8, 2, 32, 64, 2.5, 256, 256)
     return dataclasses.replace(cfg, ssm_chunk_size=8)
+
+
+def _exaone_moe(hidden, layers, heads, kv_heads, head_dim, dense_ffn, window, period, experts,
+                top_k, expert_ffn, routed_scale, vocab, seq, theta=1e6, first_dense=1, mtp=1):
+    """An ``exaone_moe`` stack (K-EXAONE): blocks ``h = x + RMSNorm(Attn(x))``,
+    ``y = h + RMSNorm(FFN(h))`` (Exaone 4's residual form); of every
+    ``period`` layers the last sees every key and takes NO positional term,
+    the others see a ``window`` of keys, rotated; RMSNorm over each head of
+    q and k; the first ``first_dense`` layers a dense SwiGLU, above them
+    gated experts under a sigmoid router with a selection bias and one shared
+    expert of the experts' width; untied head; a multi-token-prediction
+    module behind the stack. Unrolled: the layers differ. Served only."""
+    windows = tuple(0 if (i + 1) % period == 0 else window for i in range(layers))
+    return TransformerConfig(
+        vocab_size=vocab, hidden_size=hidden, num_layers=layers, num_heads=heads,
+        num_kv_heads=kv_heads, head_dim=head_dim, intermediate_size=dense_ffn, max_seq_len=seq,
+        pos_embedding="rope", rope_theta=theta, rope_windowed_only=True, norm="rmsnorm",
+        activation="swiglu", tie_embeddings=False, layernorm_epsilon=1e-5, attn_bias=False,
+        mlp_bias=False, layer_types=("full_attention", ) * layers, layer_windows=windows,
+        post_norm=True, qk_norm=True, qk_norm_per_head=True, num_experts=experts,
+        moe_top_k=top_k, moe_ffn_size=expert_ffn, moe_shared_experts=1,
+        moe_routed_scale=routed_scale, moe_scoring="sigmoid", moe_dropless=True,
+        moe_first_dense=first_dense, mtp_layers=mtp, scan_layers=False)
+
+
+@register("k-exaone-236b-a23b")
+def k_exaone_236b_a23b():
+    """K-EXAONE-236B-A23B at its published sizes (huggingface.co/LGAI-EXAONE/
+    K-EXAONE-236B-A23B config.json, ``model_type: exaone_moe``): 48 layers,
+    three with a window of 128 keys to each full one, 64 query and 8
+    key/value heads of 128, layer 0 a dense SwiGLU of 18,432, above it 128
+    experts of 2,048 top-8 with a shared one, 236.6 B parameters in the stack
+    and one multi-token-prediction module behind it. ``num_layers`` is
+    overridden together with ``layer_windows``."""
+    return _exaone_moe(6144, 48, 64, 8, 128, 18432, 128, 4, 128, 8, 2048, 2.5, 153600, 262144)
+
+
+@register("tiny-exaone-moe")
+def tiny_exaone_moe():
+    """Test-scale ``exaone_moe``: a dense layer and one period of the layers
+    above it (window, window, full, window as K-EXAONE's layers 1-4 fall), a
+    window of 8 keys, 8 experts top-2, the module behind."""
+    import dataclasses
+    cfg = _exaone_moe(64, 5, 4, 2, 16, 128, 8, 4, 8, 2, 32, 2.5, 256, 256)
+    return dataclasses.replace(cfg, layer_windows=(8, 8, 8, 0, 8))

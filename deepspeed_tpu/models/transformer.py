@@ -162,8 +162,11 @@ class TransformerConfig:
     # rows, nothing and nothing). () = every layer full attention. Needs
     # unrolled layers
     layer_types: Tuple[str, ...] = ()
-    # keys a diff_attention layer's query sees, its own included (0 = all):
-    # such a layer's slot holds a ring of about that many rows. () = none
+    # keys a layer's query sees, its own included (0 = all), for the kinds whose
+    # mixer attends over rows of its own: diff_attention and full_attention
+    # (Exaone's ``sliding_attention`` is a full_attention layer with a window).
+    # Such a layer's slot holds a ring of about that many rows, its keys
+    # rotated at rest where the layer rotates. () = none
     layer_windows: Tuple[int, ...] = ()
     # a mamba layer (Mamba-1): d_inner = ssm_expand * hidden_size
     ssm_state_size: int = 0
@@ -184,11 +187,25 @@ class TransformerConfig:
     linear_value_head_dim: int = 0
     linear_conv_kernel: int = 4  # causal depthwise convolution over q, k, v
     linear_neg_eigval: bool = False  # beta in (0, 2): negative eigenvalues of the transition
-    # h = x + norm(mixer(x)), y = h + norm(mlp(h)): mixer and MLP read the
-    # residual stream itself and their OUTPUTS are normalised (Olmo 2/3)
+    # h = x + norm(mixer(x)), y = h + norm(ffn(h)): mixer and FFN (dense or
+    # routed experts) read the residual stream itself and their OUTPUTS are
+    # normalised (Olmo 2/3, Exaone 4)
     post_norm: bool = False
-    # RMSNorm over the whole q and k projections, before the split into heads
+    # RMSNorm on q and k: over the whole projection before the split into
+    # heads (Olmo), or with qk_norm_per_head over the head_size values of
+    # ONE head, one weight vector shared by the heads (Exaone 4, Qwen 3)
     qk_norm: bool = False
+    qk_norm_per_head: bool = False
+    # rotary positions on the layers with a window only: a layer that sees
+    # every key takes no positional term (Exaone 4's hybrid stacks)
+    rope_windowed_only: bool = False
+    # the first layers' FFN is a dense MLP of intermediate_size and the
+    # experts start above them (first_k_dense_replace)
+    moe_first_dense: int = 0
+    # multi-token-prediction modules behind the stack (num_nextn_predict_layers;
+    # models/mtp.py): one block over [norm(embed(next token)) ; norm(hidden)],
+    # with the model's embedding and head. Serving drafts with it on the device
+    mtp_layers: int = 0
     # systems
     dtype: Any = jnp.bfloat16
     scan_layers: bool = True
@@ -262,10 +279,16 @@ class TransformerConfig:
             sambay = set(self.layer_types) & set(SAMBAY_TYPES)
             object.__setattr__(self, "layer_windows", tuple(self.layer_windows))
             if self.layer_windows and (len(self.layer_windows) != self.num_layers or any(
-                    w and t != "diff_attention"
+                    w and t not in ("diff_attention", "full_attention")
                     for w, t in zip(self.layer_windows, self.layer_types))):
                 raise ValueError("layer_windows gives a window (0 = none) for each layer, and "
-                                 "only a diff_attention layer takes one")
+                                 "only a diff_attention or full_attention layer takes one")
+            if any(self.layer_windows) and (self.local_attention_layers
+                                            or self.attn_scale is not None
+                                            or self.pos_embedding == "alibi"):
+                raise ValueError("a windowed layer's ring composes with rope or no positions "
+                                 "and the default score scale only (no local_attention_layers, "
+                                 "attn_scale or alibi)")
             if sambay:
                 if set(self.layer_types) - set(SAMBAY_TYPES):
                     raise ValueError(f"the kinds {SAMBAY_TYPES} carry values from layer to "
@@ -307,14 +330,38 @@ class TransformerConfig:
                 if self.post_norm or self.dropout > 0:
                     raise ValueError("a one-sublayer block is x + f(norm(x)): no post_norm, "
                                      "no dropout")
-            if self.kv_lora_rank or (self.num_experts and not one) or self.parallel_residual \
-                    or self.int8_weights:
-                raise ValueError("layer_types composes with plain attention and a dense MLP "
-                                 "in a float dtype only (no latent attention, experts "
-                                 "outside moe layers, parallel residual or int8 weights)")
-        if self.post_norm and (self.num_experts or self.parallel_residual or self.dropout > 0):
-            raise ValueError("post_norm composes with a dense MLP, sequential residuals "
-                             "and no dropout only")
+            experts_beside = self.num_experts and not one
+            if experts_beside and (sambay or "linear_attention" in self.layer_types
+                                   or not self.moe_dropless):
+                raise ValueError("experts in a mixer-and-FFN block under layer_types go with "
+                                 "full_attention layers and the dropless dispatch only "
+                                 "(elsewhere they live in one-sublayer moe layers)")
+            if self.kv_lora_rank or self.parallel_residual or self.int8_weights:
+                raise ValueError("layer_types composes with plain attention in a float dtype "
+                                 "only (no latent attention, parallel residual or int8 "
+                                 "weights)")
+        elif self.rope_windowed_only:
+            raise ValueError("rope_windowed_only goes by layer_windows, which need layer_types")
+        if self.post_norm and (self.parallel_residual or self.dropout > 0
+                               or (self.num_experts and not self.moe_dropless)):
+            raise ValueError("post_norm composes with sequential residuals, no dropout and, "
+                             "for experts, the dropless dispatch only")
+        if self.qk_norm_per_head and not self.qk_norm:
+            raise ValueError("qk_norm_per_head says which qk_norm: set qk_norm too")
+        if self.moe_first_dense and not (self.num_experts and not self.scan_layers
+                                         and 0 < self.moe_first_dense < self.num_layers
+                                         and not set(self.layer_types) & set(ONE_SUBLAYER_TYPES)):
+            raise ValueError("moe_first_dense puts a dense MLP in the first layers of a stack "
+                             "of mixer-and-expert blocks: it needs num_experts, unrolled "
+                             "layers and at least one expert layer above")
+        if self.mtp_layers not in (0, 1):
+            raise ValueError("one multi-token-prediction module at most (mtp_layers 0 or 1)")
+        if self.mtp_layers and (self.scan_layers or self.kv_lora_rank or self.int8_weights
+                                or self.carries_across_layers
+                                or set(self.layer_types) & set(ONE_SUBLAYER_TYPES)):
+            raise ValueError("the multi-token-prediction module is a block of attention and an "
+                             "FFN behind an unrolled stack of such blocks (no latent "
+                             "attention, int8 weights, SambaY or one-sublayer kinds)")
         if self.kv_lora_rank and not (self.q_lora_rank and self.qk_nope_head_dim
                                       and self.qk_rope_head_dim and self.v_head_dim
                                       and self.pos_embedding == "rope"):
@@ -381,7 +428,16 @@ class TransformerConfig:
         the kind's own name for the two-sublayer kinds; the FFN is ``"moe"``
         or ``"mlp"``."""
         kind = self.layer_type(layer_idx)
-        return ONE_SUBLAYER_TYPES.get(kind, (kind, "moe" if self.num_experts else "mlp"))
+        # (a scanned stack's layers carry no index, and no leading dense layer)
+        sparse = self.num_experts and not 0 <= layer_idx < self.moe_first_dense
+        return ONE_SUBLAYER_TYPES.get(kind, (kind, "moe" if sparse else "mlp"))
+
+    def layer_rotates(self, layer_idx):
+        """Whether layer ``layer_idx``'s attention rotates its queries and
+        keys by position (``pos_embedding == "rope"``; with
+        ``rope_windowed_only`` the layers with a window alone)."""
+        return self.pos_embedding == "rope" and (
+            not self.rope_windowed_only or bool(self.layer_window(layer_idx)))
 
     def layer_window(self, layer_idx):
         """Keys layer ``layer_idx``'s queries see, their own included (0 = all)."""
@@ -455,7 +511,11 @@ class TransformerConfig:
         emb = v * h * (1 if self.tie_embeddings else 2)
         pos = self.max_seq_len * h if self.pos_embedding == "learned" else 0
         if self.qk_norm:
-            attn += self.head_size * (self.num_heads + self.kv_heads)
+            attn += (2 * self.head_size if self.qk_norm_per_head
+                     else self.head_size * (self.num_heads + self.kv_heads))
+        if self.num_experts > 0 and self.moe_scoring == "sigmoid" \
+                and not set(self.layer_types) & set(ONE_SUBLAYER_TYPES):
+            mlp += self.num_experts  # the selection bias
         if self.carries_across_layers:
             hd2, di = 2 * self.head_size, self.ssm_inner
             q_o = 2 * (h * h + h) + 2 * hd2 + hd2  # W_q, W_o with bias; lambdas; sub-norm
@@ -483,7 +543,10 @@ class TransformerConfig:
             lin = (2 * h * nl * dk + 3 * h * nl * dv + 2 * h * nl + 2 * nl
                    + self.linear_conv_channels * self.linear_conv_kernel + dv)
             return (L * (mlp + 2 * h) + (L - n_lin) * attn + n_lin * lin) + emb + pos + h
-        return L * (attn + mlp + 2 * h) + emb + pos + h
+        dense = self.moe_first_dense * (per_h * self.ffn_size - mlp)
+        # the module: one expert block, W_eh over [embedding ; hidden], three norms
+        mtp = self.mtp_layers * (attn + mlp + 2 * h + 2 * h * h + 3 * h)
+        return L * (attn + mlp + 2 * h) + dense + mtp + emb + pos + h
 
 
 def resolve_remat_policy(name):
@@ -1236,10 +1299,13 @@ class Attention(nn.Module):
                 v = v + dv.astype(v.dtype)
 
         if cfg.qk_norm:
-            q = ProjectionRMSNorm(cfg.layernorm_epsilon, cfg.dtype, name="q_norm")(q)
-            k = ProjectionRMSNorm(cfg.layernorm_epsilon, cfg.dtype, name="k_norm")(k)
+            # over one head's values with the heads' shared weights, or over
+            # the whole projection
+            qk_norm = RMSNorm if cfg.qk_norm_per_head else ProjectionRMSNorm
+            q = qk_norm(cfg.layernorm_epsilon, cfg.dtype, name="q_norm")(q)
+            k = qk_norm(cfg.layernorm_epsilon, cfg.dtype, name="k_norm")(k)
 
-        if cfg.pos_embedding == "rope":
+        if cfg.layer_rotates(self.layer_idx):
             if position_ids is not None:
                 pos_sin, pos_cos = sin[position_ids], cos[position_ids]  # (B, T, hd/2)
             elif cache_index is not None:
@@ -1264,8 +1330,25 @@ class Attention(nn.Module):
         window = (cfg.local_attention_window
                   if (cfg.local_attention_window and self.layer_idx >= 0
                       and self.layer_idx in cfg.local_attention_layers) else 0)
+        # ... or by layer_windows, whose layer's slot holds a RING of rows
+        ring_window = cfg.layer_window(self.layer_idx) if self.layer_idx >= 0 else 0
 
-        if kv_cache is not None:
+        if kv_cache is not None and ring_window:
+            # a slot's ring of (rotated) keys and values, position p in row
+            # p mod R (cache_spec): attended and committed by _ring_attention,
+            # under the scope the windowed layers' share of a trace is read by
+            _serves_by_spans("windowed full_attention", kv_cache, write_index, q_spans)
+            if lora_ops or ext_ops is not None or seq_shard or attn_mask is not None:
+                raise NotImplementedError("a windowed layer's ring serves without adapters, "
+                                          "extent chains, sequence-parallel spans or padding "
+                                          "masks")
+            with jax.named_scope("swa_attn"):
+                out, new_cache = _ring_attention(
+                    cfg, q, k, v, kv_cache, write_index, q_spans, ring_window,
+                    cfg.attention_impl == "flash" and _tp_mesh_size() == 1,
+                    dict(block_kv=cfg.decode_block_kv, scale=hd ** -0.5))
+            out = out.astype(cfg.dtype)
+        elif kv_cache is not None:
             # cache layout (B, nkv, S, hd): contiguous (S, hd) slabs per head,
             # the shape the Pallas decode kernel streams (reference KV-cache
             # arena: csrc/transformer/inference/includes/inference_context.h).
@@ -1409,6 +1492,7 @@ class Attention(nn.Module):
             new_cache = tuple(written)
         else:
             new_cache = None
+            window = window or ring_window
             use_flash = (cfg.attention_impl == "flash" and T >= 128 and attn_mask is None
                          and alibi is None and not window)
             ring_possible = (cfg.sequence_parallel_impl == "ring" and dist.has_mesh()
@@ -2057,8 +2141,8 @@ class DiffAttention(nn.Module):
                 out = _grouped_attention_xla(q, k, v, keep[None], scale, cfg.dtype)
                 kv = (k, v)
             elif window:
-                out, new_cache = self._ring(q, k, v, kv_cache, write_index, q_spans, window,
-                                            kernels, kernel_kw)
+                out, new_cache = _ring_attention(cfg, q, k, v, kv_cache, write_index, q_spans,
+                                                 window, kernels, kernel_kw)
                 kv = None
             else:
                 if self.cross:
@@ -2096,42 +2180,55 @@ class DiffAttention(nn.Module):
             carry = dict(carry, kv=kv)
         return out, new_cache, carry
 
-    def _ring(self, q, k, v, kv_cache, write_index, q_spans, window, kernels, kernel_kw):
-        """Windowed attention over a slot's ring, and the ring with this
-        call's live rows committed. Ring row ``r`` of a slot whose write head
-        is at ``p0`` holds position ``p0 - 1 - ((p0 - 1 - r) mod R)``, if that
-        is not negative (the slot's request has not written the row yet)."""
-        cfg = self.cfg
-        B, nh, T, _ = q.shape
-        rk, rv = kv_cache
-        R = rk.shape[2]
-        if kernels and T == 1 and R == window:
-            # one column: its row displaces the key that just left the
-            # window, so commit first and the ring IS the window
-            from ..ops.pallas.decode_attention import paged_decode_attention
-            ck, cv = _commit_span_rows([(rk, k), (rv, v)], write_index % R, q_spans, True)
-            out = paged_decode_attention(q[:, :, 0], ck, cv, jnp.zeros((B, ), jnp.int32),
-                                         jnp.minimum(_attended_ends(write_index, q_spans), R),
-                                         **kernel_kw)
-            return out[:, :, None], (ck, cv)
-        p0, j = write_index[:, None], jnp.arange(T)[None, :]
-        held = p0 - 1 - ((p0 - 1 - jnp.arange(R)[None, :]) % R)  # (B, R)
-        keep_ring = (held[:, None, :] >= 0) & (held[:, None, :] > (p0 + j)[:, :, None] - window)
-        rel = jnp.arange(T)[:, None] - jnp.arange(T)[None, :]
-        keep_fresh = jnp.broadcast_to((rel >= 0) & (rel < window), (B, T, T))
-        out = _grouped_attention_xla(
-            q, jnp.concatenate([rk.astype(cfg.dtype), k], axis=2),
-            jnp.concatenate([rv.astype(cfg.dtype), v], axis=2),
-            jnp.concatenate([keep_ring, keep_fresh], axis=-1), kernel_kw["scale"], cfg.dtype)
-        # the last R live columns land at their positions mod R; the others
-        # (padding, and what a chunk wider than the ring would overwrite at
-        # once) are dropped
-        live = (j < q_spans[:, None]) & (j >= q_spans[:, None] - R)
-        tgt = jnp.where(live, (p0 + j) % R, R)
-        upd = lambda c, kk, i: c.at[:, i, :].set(kk.astype(c.dtype), mode="drop")
-        with jax.named_scope("kv_commit"):
-            ring = (jax.vmap(upd)(rk, k, tgt), jax.vmap(upd)(rv, v, tgt))
-        return out, ring
+
+def _ring_attention(cfg, q, k, v, kv_cache, write_index, q_spans, window, kernels, kernel_kw):
+    """Windowed attention over a slot's ring, and the ring with this call's
+    live rows committed. ``q`` (B, nh, T, D), fresh ``k``/``v`` (B, nkv, T,
+    D), ``kv_cache`` the ring's K and V leaves (B, nkv, R, D). Ring row ``r``
+    of a slot whose write head is at ``p0`` holds position ``p0 - 1 - ((p0 - 1
+    - r) mod R)``, if that is not negative (the slot's request has not written
+    the row yet). Keys are at rest as they are attended (rotated, where the
+    layer rotates): softmax over a set of keys does not care which row each
+    rests in, only whether it is attended.
+
+    One column on the kernel path, where the ring's rows ARE the window,
+    commits first: its row displaces the key that just left the window. A
+    wider call attends over [ring ; fresh rows] BEFORE the commit, so that a
+    span's queries see the keys its own writes displace, and masks every
+    ring row by the position it holds. That is also what rolls a ring back by
+    position: a row written for a column that was not committed (a rejected
+    draft of a verify step, past the span the NEXT call's write head stands
+    at) is taken to hold the position ``R`` before the one it was written for,
+    which no later query's window reaches, until the position's true row
+    overwrites it."""
+    B, nh, T, _ = q.shape
+    rk, rv = kv_cache
+    R = rk.shape[2]
+    if kernels and T == 1 and R == window:
+        from ..ops.pallas.decode_attention import paged_decode_attention
+        ck, cv = _commit_span_rows([(rk, k), (rv, v)], write_index % R, q_spans, True)
+        out = paged_decode_attention(q[:, :, 0], ck, cv, jnp.zeros((B, ), jnp.int32),
+                                     jnp.minimum(_attended_ends(write_index, q_spans), R),
+                                     **kernel_kw)
+        return out[:, :, None], (ck, cv)
+    p0, j = write_index[:, None], jnp.arange(T)[None, :]
+    held = p0 - 1 - ((p0 - 1 - jnp.arange(R)[None, :]) % R)  # (B, R)
+    keep_ring = (held[:, None, :] >= 0) & (held[:, None, :] > (p0 + j)[:, :, None] - window)
+    rel = jnp.arange(T)[:, None] - jnp.arange(T)[None, :]
+    keep_fresh = jnp.broadcast_to((rel >= 0) & (rel < window), (B, T, T))
+    out = _grouped_attention_xla(
+        q, jnp.concatenate([rk.astype(cfg.dtype), k], axis=2),
+        jnp.concatenate([rv.astype(cfg.dtype), v], axis=2),
+        jnp.concatenate([keep_ring, keep_fresh], axis=-1), kernel_kw["scale"], cfg.dtype)
+    # the last R live columns land at their positions mod R; the others
+    # (padding, and what a chunk wider than the ring would overwrite at
+    # once) are dropped
+    live = (j < q_spans[:, None]) & (j >= q_spans[:, None] - R)
+    tgt = jnp.where(live, (p0 + j) % R, R)
+    upd = lambda c, kk, i: c.at[:, i, :].set(kk.astype(c.dtype), mode="drop")
+    with jax.named_scope("kv_commit"):
+        ring = (jax.vmap(upd)(rk, k, tgt), jax.vmap(upd)(rv, v, tgt))
+    return out, ring
 
 
 class QuantDense(nn.Module):
@@ -2254,13 +2351,13 @@ class Block(nn.Module):
             attention = LatentAttention if cfg.kv_lora_rank else Attention
             mixer = attention(cfg, layer_idx=self.layer_idx, name="attn")
         if cfg.post_norm:
-            # the mixer and the MLP read the residual stream itself; what
+            # the mixer and the FFN read the residual stream itself; what
             # they return is normalised on its way into it
             h, new_cache = mixer(x, sin, cos, attn_mask, kv_cache, cache_index, position_ids,
                                  write_index, q_spans, lora_ops, ext_ops, seq_shard)
             x = x + make_norm(cfg, name="attn_norm")(h)
-            return x + make_norm(cfg, name="mlp_norm")(MLP(cfg, name="mlp")(x, lora_ops)), new_cache
-        if mixer_kind is not None:
+            ff_in = x
+        elif mixer_kind is not None:
             h = make_norm(cfg, name=mixer_norm)(x)
             h, new_cache = mixer(
                 h, sin, cos, attn_mask, kv_cache, cache_index, position_ids, write_index,
@@ -2291,6 +2388,8 @@ class Block(nn.Module):
                 self.sow("intermediates", "moe_aux_loss", aux)
         else:
             ff = MLP(cfg, name="mlp")(ff_in, lora_ops)
+        if cfg.post_norm:
+            return x + make_norm(cfg, name="mlp_norm")(ff), new_cache
         if drop is not None:
             ff = drop(ff, deterministic=deterministic)
         if cfg.parallel_residual:
@@ -2306,8 +2405,11 @@ class CausalLM(nn.Module):
                  cache_index=None, position_ids=None, return_hidden=False,
                  pld_theta=None, pld_rng=None, ltd_keep=None, ltd_layers=(), ltd_rng=None,
                  write_index=None, q_spans=None, lora_ops=None, expert_ops=None,
-                 ext_ops=None, seq_shard=False):
-        """``kv_cache``: optional cache tree of ``init_cache`` (split K and V
+                 ext_ops=None, seq_shard=False, with_hidden=False):
+        """``with_hidden``: return the final-norm hidden states BEHIND the
+        logits (and the cache), as a multi-token-prediction module takes them.
+
+        ``kv_cache``: optional cache tree of ``init_cache`` (split K and V
         leaves, the packed K/V leaf or the latent leaf, each component with
         a leading layer dim (L, B, kv_heads, S, lanes) or as a per-layer
         tuple) — scanned alongside the layer stack. Returns logits, or (logits, new_kv_cache) when caching, or the
@@ -2463,9 +2565,10 @@ class CausalLM(nn.Module):
             else:
                 logits = nn.Dense(cfg.vocab_size, use_bias=cfg.lm_head_bias, dtype=cfg.dtype,
                                   param_dtype=jnp.float32, name="lm_head")(x)
+        hidden = (x, ) if with_hidden else ()
         if kv_cache is not None:
-            return logits, new_cache
-        return logits
+            return (logits, new_cache) + hidden
+        return (logits, ) + hidden if hidden else logits
 
 
 class CausalLMModel:
@@ -2500,13 +2603,77 @@ class CausalLMModel:
                                        act_quant_symmetric=symmetric)
         self.module = CausalLM(self.cfg)
 
+    def _mtp_module(self):
+        from .mtp import MTPModule
+        return MTPModule(self.cfg)
+
     def init_params(self, rng):
+        """The stack's tree; with a multi-token-prediction module, its tree
+        beside it under ``"mtp"`` (the stack's own forward never reads it)."""
         B, T = 2, min(self.cfg.max_seq_len, 128)
         ids = jnp.zeros((B, T), jnp.int32)
-        return self.module.init({"params": rng}, ids)["params"]
+        params = self.module.init({"params": rng}, ids)["params"]
+        if self.cfg.mtp_layers:
+            h = jnp.zeros((B, T, self.cfg.hidden_size), self.cfg.dtype)
+            params = dict(params, mtp=self._mtp_module().init(
+                {"params": jax.random.fold_in(rng, 1)}, h, h)["params"])
+        return params
 
     def apply(self, params, input_ids, attn_mask=None):
         return self.module.apply({"params": params}, input_ids, attn_mask)
+
+    def apply_with_mtp(self, params, input_ids):
+        """One full causal forward, no cache: ``(logits, draft logits)``, both
+        (B, T, V). Position ``i``'s draft logits predict token ``i + 2`` from
+        the stack's output at ``i`` and token ``i + 1`` (the last position
+        has no next token: its draft row reads a wrapped id and means
+        nothing)."""
+        logits, hidden = self.module.apply({"params": params}, input_ids, with_hidden=True)
+        return logits, self.mtp_forward(params, hidden, jnp.roll(input_ids, -1, axis=1))
+
+    def mtp_forward(self, params, hidden, next_ids, kv_cache=None, position_ids=None,
+                    write_index=None, q_spans=None, expert_stats=False, expert_choice=False):
+        """The multi-token-prediction module (``models/mtp.py``) over the
+        stack's normed output ``hidden`` (B, T, H) and each position's next
+        token ``next_ids`` (B, T), with the model's embedding and head: draft
+        logits (B, T, V). With ``kv_cache`` (the WHOLE cache tree: the module's
+        leaves are the entry behind the stack's layers) it serves through the
+        slot pool's spans and returns ``(draft logits, the tree with the
+        module's leaves written)``, then its expert layer's ``(1, E)`` routed
+        counts and ``(1, B, T, k)`` chosen experts where asked for, as
+        :meth:`apply_with_cache` returns the stack's. On the device the whole
+        of it runs under the ``mtp_draft`` scope."""
+        cfg = self.cfg
+        L = cfg.num_layers
+        with jax.named_scope("mtp_draft"):
+            emb = jnp.take(params["embed"]["embedding"], next_ids, axis=0).astype(cfg.dtype)
+            mutable = ((["expert_stats"] if expert_stats else [])
+                       + (["expert_choice"] if expert_choice else [])) or False
+            own = None if kv_cache is None else tuple(comp[L] for comp in kv_cache)
+            out = self._mtp_module().apply({"params": params["mtp"]}, hidden, emb, own,
+                                           position_ids, write_index, q_spans, mutable=mutable)
+            out, mut = out if mutable else (out, {})
+            z, written = out if kv_cache is not None else (out, None)
+            with jax.named_scope("lm_head"):
+                if cfg.tie_embeddings:
+                    logits = jnp.einsum("bth,vh->btv", z, params["embed"]["embedding"].astype(
+                        cfg.dtype))
+                else:
+                    head = params["lm_head"]
+                    logits = jnp.dot(z, head["kernel"].astype(cfg.dtype))
+                    if cfg.lm_head_bias:
+                        logits = logits + head["bias"].astype(cfg.dtype)
+        if kv_cache is None:
+            return logits
+        tree = tuple(comp[:L] + (written[j], ) + comp[L + 1:]
+                     for j, comp in enumerate(kv_cache))
+        leaf = lambda name: jax.tree_util.tree_leaves(mut.get(name, {}))[0]
+        extra = ()
+        if expert_stats:
+            extra += (leaf("expert_stats").reshape(1, cfg.num_experts), )
+        if expert_choice:
+            extra += (leaf("expert_choice").reshape((1, ) + next_ids.shape + (cfg.moe_top_k, )), )
+        return (logits, tree) + extra
 
     # ---- generation (KV cache) -------------------------------------------
     def quantize_params(self, params, group_size=None, dtype=None):
@@ -2677,8 +2844,8 @@ class CausalLMModel:
         1, d_inner)``; a Mamba-2 layer's ``(B, heads, head size, d_state)``
         and ``(B, 1, W - 1, conv channels)``) or ``"ring"`` (a row axis at ``ndim - 2`` of
         ``cfg.ring_rows`` rows whatever ``max_len`` is, position ``p`` in row
-        ``p mod R``: a windowed differential layer's K and V, per-slot bytes
-        as a state's are). Every component keeps its slot axis at ``ndim -
+        ``p mod R``: a windowed differential or full-attention layer's K and
+        V, per-slot bytes as a state's are). Every component keeps its slot axis at ``ndim -
         4``. A layer may declare nothing (a gated memory unit reads the
         forward's carry, a cross-attention layer the rows of the full layer
         below it, a block that is an FFN alone has no mixer).
@@ -2725,14 +2892,20 @@ class CausalLMModel:
 
             return [declares(i, t) for i, t in enumerate(cfg.layer_types)]
         mixers = [cfg.layer_parts(i)[0] for i in range(cfg.num_layers)]
-        if not {"linear_attention", "mamba2", None} & set(mixers):
-            return [tuple(rows)] * cfg.num_layers
+        windows = [cfg.layer_window(i) for i in range(cfg.num_layers)]
+        # a multi-token-prediction module holds rows of its own, declared
+        # behind the stack's layers like one more full-attention layer
+        module = [tuple(rows)] * cfg.mtp_layers
+        if not {"linear_attention", "mamba2", None} & set(mixers) and not any(windows):
+            return [tuple(rows)] * cfg.num_layers + module
         if quantized or len(rows) != 2:
-            raise NotImplementedError("a pool with state leaves has no int8 tier and no "
-                                      "packed geometry")
+            raise NotImplementedError("a pool with state or ring leaves has no int8 tier and "
+                                      "no packed geometry")
         state = lambda shape, W, channels: (
             ("state", (batch_size, ) + shape, dt, jnp.zeros),
             ("state", (batch_size, 1, W - 1, channels), dt, jnp.zeros))
+        ring = lambda i: (("ring", (batch_size, cfg.kv_heads, cfg.ring_rows(i), cfg.head_size),
+                           dt, jnp.zeros), ) * 2
         declares = {
             "full_attention": tuple(rows),
             "linear_attention": state((cfg.linear_num_heads, cfg.linear_key_head_dim,
@@ -2741,7 +2914,8 @@ class CausalLMModel:
             "mamba2": state((cfg.ssm_num_heads, cfg.ssm_head_dim, cfg.ssm_state_size),
                             cfg.ssm_conv_kernel, cfg.mamba2_conv_channels),
             None: ()}  # an FFN alone
-        return [declares[m] for m in mixers]
+        return [ring(i) if w else declares[m]
+                for i, (m, w) in enumerate(zip(mixers, windows))] + module
 
     def cache_kinds(self):
         """``"rows"``, ``"ring"`` or ``"state"`` for every leaf of
@@ -2755,7 +2929,8 @@ class CausalLMModel:
     def apply_with_cache(self, params, input_ids, kv_cache, cache_index, cache_mask=None,
                          position_ids=None, write_index=None, q_spans=None,
                          lora_ops=None, expert_ops=None, expert_stats=False,
-                         ext_ops=None, seq_shard=False, expert_choice=False):
+                         ext_ops=None, seq_shard=False, expert_choice=False,
+                         with_hidden=False):
         """Forward writing into (and attending over) the KV cache. Returns
         (logits, new_cache). ``cache_mask``: (B, S) attendable cache slots.
         ``write_index``: optional (B,) per-row cache positions (slot-pool
@@ -2782,17 +2957,31 @@ class CausalLMModel:
 
         ``ext_ops``/``seq_shard``: long-context extent operands and the
         sequence-parallel prefill flag, layer-invariant pass-throughs to
-        :class:`Attention` (see there for semantics)."""
+        :class:`Attention` (see there for semantics).
+
+        ``with_hidden=True`` appends the final-norm hidden states (B, T, H)
+        as the LAST output. A multi-token-prediction module's leaves (the
+        cache tree's entry behind the stack's layers) come back as they went
+        in: :meth:`mtp_forward` writes them."""
         mutable = ((["expert_stats"] if expert_stats else [])
                    + (["expert_choice"] if expert_choice else [])) or False
+        L = self.cfg.num_layers
+        behind = None
+        if self.cfg.mtp_layers and not self.cfg.scan_layers:
+            behind = tuple(comp[L:] for comp in kv_cache)
+            kv_cache = tuple(comp[:L] for comp in kv_cache)
         out = self.module.apply({"params": params}, input_ids, cache_mask, True, kv_cache,
                                 cache_index, position_ids, write_index=write_index,
                                 q_spans=q_spans, lora_ops=lora_ops,
                                 expert_ops=expert_ops, ext_ops=ext_ops,
-                                seq_shard=seq_shard, mutable=mutable)
+                                seq_shard=seq_shard, with_hidden=with_hidden,
+                                mutable=mutable)
+        out, mut = out if mutable else (out, {})
+        logits, new_cache, *hidden = out
+        if behind is not None:
+            new_cache = tuple(comp + rest for comp, rest in zip(new_cache, behind))
         if not mutable:
-            return out
-        (logits, new_cache), mut = out
+            return (logits, new_cache) + tuple(hidden)
 
         def per_layer(collection, tail):
             """One (L, *tail) array from a collection's per-layer leaves."""
@@ -2814,7 +3003,7 @@ class CausalLMModel:
             extra += (per_layer("expert_stats", (self.cfg.num_experts, )), )
         if expert_choice:
             extra += (per_layer("expert_choice", input_ids.shape + (self.cfg.moe_top_k, )), )
-        return (logits, new_cache) + extra
+        return (logits, new_cache) + extra + tuple(hidden)
 
     # ---- fused decode blocks (serving fast path) -------------------------
     def fused_decode_operands(self, params):

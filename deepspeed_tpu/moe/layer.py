@@ -296,7 +296,13 @@ def dense_held_pays(N, k, E, H, F):
     layout, its copy of ``W_up`` (0.67 GB) in its time: 192 17.5 / 1.82, 2048
     22.7 / 16.0, 2560 24.2 / 20.7, 3072 29.5 / 24.8, 3584 27.5 / 28.8, 4096
     29.5 / 33.6, 8192 42.2 / 67.5; 32 held, top-4, gated: 64 (2) 9.09 / 1.32,
-    2048 15.4 / 11.7, 3072 16.8 / 17.6.
+    2048 15.4 / 11.7, 3072 16.8 / 17.6. *Both widths multiples of 256, few
+    held* (my chip run, PR 41; cell 8: 16 held of 128, top-8, 6144 x 2048 gated,
+    the weights' stream 1.47 ms): N 64 (4) 3.95 / 1.70, 128 4.05 / 1.77, 256
+    (16: its decode step's two columns) 4.50 / 2.04, 512 (32: its chunk) 5.28
+    / 4.30, 640 5.64 / 4.84, 1,024 7.28 / 7.84: they cross between 640 and
+    1,024 there, later than at 32 held, so the 608 below is a little early
+    at this shape and right at both row counts the cell runs.
 
     So: the dense product up to :data:`DENSE_ROWS_MAX` rows a call, by how
     many of ``H`` and ``F`` are no multiple of 256: 608 (they cross between

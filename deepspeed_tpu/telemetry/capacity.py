@@ -179,12 +179,13 @@ def program_shape(key):
     """(width, ksteps) batch shape encoded in a compiled-program cache key:
     fused/fused_block keys carry (chunk, ksteps), spec/spec_block keys
     carry the draft width (the verify program scores ``width`` columns in
-    one pass); everything else (copy/tier ops) is shape-accounted
+    one pass), draft keys (the device drafter's verify-and-draft program)
+    the first forward's width and the steps; everything else (copy/tier ops) is shape-accounted
     as a single column. The ``*_block`` kinds are the fused decode-block
     retags — same tuple positions, priced separately in the roofline."""
     if (isinstance(key, tuple) and len(key) >= 5
             and key[0] in ("fused", "fused_block", "fused_ext",
-                           "fused_seqp")):
+                           "fused_seqp", "draft")):
         return int(key[3]), int(key[4])
     if (isinstance(key, tuple) and len(key) >= 4
             and key[0] in ("spec", "spec_block")):

@@ -389,7 +389,7 @@ def test_the_other_refusals(tiny):
         dataclasses.replace(cfg, num_layers=2, layer_types=("mamba2", "full_attention"))
     with pytest.raises(ValueError, match="moe layers need num_experts"):
         dataclasses.replace(cfg, num_layers=1, layer_types=("mamba2", ))
-    with pytest.raises(ValueError, match="experts outside moe layers"):
+    with pytest.raises(ValueError, match="experts in a mixer-and-FFN block"):
         dataclasses.replace(get_model("tiny-hybrid").cfg, num_experts=4, moe_dropless=True)
     with pytest.raises(ValueError, match="ssm_num_heads"):
         dataclasses.replace(cfg, ssm_groups=3)
@@ -486,8 +486,9 @@ def test_existing_presets_build_the_trees_they_built(name):
 
 
 def test_no_preset_goes_unguarded():
-    assert set(available_models()) == set(PARENT_TREES) | {"nemotron-3-nano-30b-a3b",
-                                                           "tiny-nemotron-h"}
+    # PR 41's presets are guarded by tests/unit/inference/test_exaone_moe_pool.py
+    assert set(available_models()) == set(PARENT_TREES) | {
+        "nemotron-3-nano-30b-a3b", "tiny-nemotron-h", "k-exaone-236b-a23b", "tiny-exaone-moe"}
 
 
 def test_preset_builds_the_published_sizes():

@@ -123,7 +123,7 @@ def test_layer_table_and_its_rules():
     with pytest.raises(ValueError, match="do not mix"):
         dataclasses.replace(cfg, num_layers=2, layer_types=("mamba", "full_attention"),
                             layer_windows=())
-    with pytest.raises(ValueError, match="only a diff_attention layer takes one"):
+    with pytest.raises(ValueError, match="layer takes one"):
         dataclasses.replace(cfg, num_layers=1, layer_types=("mamba", ), layer_windows=(16, ))
 
 

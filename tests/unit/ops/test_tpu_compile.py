@@ -631,6 +631,71 @@ def test_nemotron_h_step_program(for_chip, step):
     assert 10.57e9 + 3.07e9 + mem.temp_size_in_bytes < 15.75 * 2**30, mem
 
 
+@pytest.mark.parametrize("width", [2, pytest.param(512, marks=pytest.mark.slow)],
+                         ids=["decode", "chunk"])
+def test_exaone_moe_verify_and_draft_program(for_chip, width):
+    """K-EXAONE-236B-A23B's sync at the published widths as the chip
+    benchmark serves it (128 slots x 4096, ``steps_per_sync`` 4,
+    ``prefill_chunk`` 512, experts 0-15 of 128, an eighth of the vocabulary,
+    the device drafter on), with the dense layer under a window, one full
+    expert layer and the multi-token-prediction module: every step verifies
+    two columns a row over a 128-row ring (XLA's masked attention over [ring ;
+    fresh rows]) and a row cache (the paged span kernel), decides the advance,
+    runs the module over its own rows and drafts; the chunk sync adds the
+    chunk as a (1, 512) forward over its own slot. The 256 rows x 8 pairs (16
+    rows an expert) and the chunk's go by the dense product over the 16 held:
+    no grouped product. The donated row caches are updated in place: no
+    whole-leaf copy, scatter or transpose of one. A RING leaf (33.5 MB) is
+    not so lucky: XLA's scatter of the two columns wants it position-major
+    and the compiler relays it on the way into and out of every step (two
+    copies a leaf in the loop's body: ``PERF.md`` section 7, for a
+    ``perf_opt`` issue); the count is held where it is so that it does not
+    grow. It fits with its temporaries beside the cell's 9.09 GB of weights
+    and 4.56 GB of pool."""
+    import types
+    from deepspeed_tpu.inference.scheduler import DecodeScheduler
+    sds, _ = for_chip
+    slots, pool_len, steps = 128, 4096, 4
+    base = get_model("k-exaone-236b-a23b")
+    model = type(base)(dataclasses.replace(
+        base.cfg, dtype=jnp.bfloat16, num_layers=2, layer_types=("full_attention", ) * 2,
+        layer_windows=(128, 0), moe_experts_held=16, vocab_size=19200, max_seq_len=pool_len,
+        attention_impl="flash"))
+    abstract = lambda tree, dtype=None: jax.tree_util.tree_map(
+        lambda a: sds(a.shape, dtype or a.dtype), tree)
+    params = abstract(jax.eval_shape(model.init_params, jax.random.key(0)), jnp.bfloat16)
+    pool = abstract(jax.eval_shape(lambda: model.init_cache(slots, pool_len)))
+    shapes = sorted({leaf.shape for leaf in jax.tree_util.tree_leaves(pool)})
+    assert shapes == [(slots, 8, 128, 128), (slots, 8, pool_len, 128)]
+    mock = types.SimpleNamespace(
+        engine=types.SimpleNamespace(module=model, model_config=model.cfg), _shard_deg=1,
+        _moe_stats=True, _moe=True, experts=None, _compiled={}, capacity=None,
+        _pool_sharding=None, _draft_keeps_void=False)
+    for name in ("_program", "_jit_step", "_moe_forward_stats", "_held_experts"):
+        setattr(mock, name, types.MethodType(getattr(DecodeScheduler, name), mock))
+    fn = DecodeScheduler._draft_fn(mock, False, False, steps, width)
+    i32 = lambda *shape: sds(shape, jnp.int32)
+    args = (params, pool, i32(slots, width), i32(slots), i32(slots), sds((slots, ), jnp.uint32),
+            i32(slots), sds((slots, ), jnp.bool_), sds((slots, ), jnp.float32), i32(slots),
+            sds((slots, ), jnp.float32)) + ((i32(4), ) if width > 2 else ())
+    compiled = fn.lower(*args).compile()
+    text = compiled.as_text()
+    assert "dstpu_decode_attn" in text and "dstpu_kv_commit" in text
+    assert "ragged-dot" not in text and "moe_experts" in text and "mtp_draft" in text
+    assert "swa_attn" in text
+    ring, rows = ("[" + ",".join(map(str, shape)) + "]" for shape in shapes)
+    assert _pool_relayouts(text, rows) == (0, 0)
+    in_loop, around = _pool_relayouts(text, ring)
+    print(width, "ring leaf moves", in_loop, around)
+    assert in_loop <= 4 and around <= 8, (in_loop, around)
+    for kernel in ("[16,6144,2048]", "[16,2048,6144]"):
+        assert _pool_relayouts(text, kernel) == (0, 0), kernel
+    mem = compiled.memory_analysis()
+    print(width, "temporaries", mem.temp_size_in_bytes)
+    assert mem.temp_size_in_bytes < 1.4e9, mem
+    assert 9.09e9 + 4.57e9 + mem.temp_size_in_bytes < 15.75 * 2**30, mem
+
+
 def _accepted_cell_syncs():
     """(cell, model one period deep, slots, chunk, pool length, fused) of the
     serving cells the benchmark had before PR 39, as their tests above size
