@@ -873,6 +873,8 @@ class DecodeScheduler(DeviceDraft):
                 "model family without fused decode-block support"]
         # step programs built so far, by the K/V commit their trace took
         self.kv_commit_programs = {"inplace": 0, "scatter": 0}
+        # ... and, for gated-delta layers, by their one-token state update
+        self.gdn_step_programs = {"kernel": 0, "xla": 0}
         # ... and the geometry init_cache gave the pool they carry: "packed"
         # (K beside V in one 128-lane leaf a layer: head size 64), "split"
         # or "latent"
@@ -2422,16 +2424,22 @@ class DecodeScheduler(DeviceDraft):
         shapes) built it: count which K/V commit it was built with —
         ``scatter`` if any layer's span write fell back to the XLA scatter
         (``models/transformer.py: _commit_span_rows``), so a server whose
-        steps relay the pool says so."""
+        steps relay the pool says so; and which one-token update its
+        gated-delta layers took, ``xla`` if any fell back to the definition
+        (``GatedDeltaNet``), so a server whose states go two passes says so."""
         from ..moe.layer import traced_dispatches
-        from ..ops.pallas import kv_commit
-        before, moe_before = kv_commit.traced(), traced_dispatches()
+        from ..ops.pallas import gdn_step, kv_commit
+        before, moe_before, gdn_before = kv_commit.traced(), traced_dispatches(), gdn_step.traced()
         out = fn(*call_args)
-        after, moe_after = kv_commit.traced(), traced_dispatches()
+        after, moe_after, gdn_after = kv_commit.traced(), traced_dispatches(), gdn_step.traced()
         if after != before:
             path = "scatter" if after[1] > before[1] else "inplace"
             self.kv_commit_programs[path] += 1
             self.telemetry.counter(f"serving/kv_commit_{path}_programs")
+        if gdn_after != gdn_before:
+            path = "xla" if gdn_after[1] > gdn_before[1] else "kernel"
+            self.gdn_step_programs[path] += 1
+            self.telemetry.counter(f"serving/gdn_step_{path}_programs")
         if moe_after != moe_before:
             _, dense, broadcast = (a > b for a, b in zip(moe_after, moe_before))
             # the counters say how a program's pairs were EVALUATED: ``dense``

@@ -1080,6 +1080,7 @@ def _cached_attention_xla(q, ck, cv, cache_index, cache_mask, dtype, alibi=None,
     return out.reshape(B, nh, T, hd)
 
 
+from ..ops.pallas import gdn_step
 from ..ops.pallas.quant_matmul import pick_block as _pick_block
 
 import os as _os
@@ -1818,16 +1819,28 @@ class GatedDeltaNet(nn.Module):
         S_t = e^g S_(t-1) + beta k (v - e^g S_(t-1)^T k)^T ; o_t = S_t^T q_t
         y = [RMSNorm_dv(o) * SiLU(x W_g)] W_o
 
-    What a slot holds for such a layer (``init_cache``): the state ``(B, n,
-    dk, dv)`` and the convolution's last ``W - 1`` inputs ``(B, 1, W - 1,
-    n (2 dk + dv))``, both at rest in the serving dtype, loaded to float32
-    and rounded once on the store. Without a cache (full forward) the scan
-    starts from zero. With one it is served through the slot pool's span
-    programs only (``write_index`` + ``q_spans``): a row advances over
-    exactly its ``q_spans`` live columns (later columns get beta 0 and g 0,
-    the window is taken from the last live inputs), a span-0 row's leaves
-    come out bit for bit as they went in, and a row whose span starts at
-    position 0 starts from a zero state and window whatever the slot held.
+    What a slot holds for such a layer (``init_cache``): the state and the
+    convolution's last ``W - 1`` inputs ``(B, 1, W - 1, n (2 dk + dv))``,
+    both at rest in the serving dtype, loaded to float32 and rounded once on
+    the store. The state rests as ``(B, n / p, dk, p dv)``, ``p`` heads side
+    by side in the lanes (``ops/pallas/gdn_step.py: state_packing``: the
+    smallest ``p`` that makes a row whole 128-lane tiles, 2 at the published
+    ``dv`` 192; 1, the plain ``(B, n, dk, dv)``, where there is none): a row
+    of 192 lanes rests and moves padded to 256. The ONE-TOKEN update (``T ==
+    1``: the decode column and its substeps) of such a leaf runs as the
+    Pallas kernel of that file, in place, where :class:`Attention` would
+    take its paged kernels (one device, ``attention_impl == "flash"``) and
+    the head shape tiles (``gdn_step.tiles``); everything else (a chunk's
+    scan, which converts its own slot's state on load and store; a sharded
+    pool; heads that do not tile) is :func:`gated_delta_step` /
+    :func:`gated_delta_chunked`, the definition. Without a cache (full
+    forward) the scan starts from zero. With one it is served through the
+    slot pool's span programs only (``write_index`` + ``q_spans``): a row
+    advances over exactly its ``q_spans`` live columns (later columns get
+    beta 0 and g 0, the window is taken from the last live inputs), a span-0
+    row's leaves come out bit for bit as they went in, and a row whose span
+    starts at position 0 starts from a zero state and window whatever the
+    slot held.
     The call signature is :class:`Attention`'s; adapters, extent chains,
     sequence-parallel spans and padding masks are refused."""
     cfg: TransformerConfig
@@ -1863,14 +1876,23 @@ class GatedDeltaNet(nn.Module):
             dt_bias = self.param("dt_bias", gdn_dt_bias_init, (n, ), f32)
             g = -jnp.exp(a_log) * jax.nn.softplus(dense(n, name="a_proj")(x).astype(f32) + dt_bias)
             conv_w = self.param("conv", gdn_conv_init, (cfg.linear_conv_channels, W), f32)
+            in_place = False
             if kv_cache is None:
                 state = jnp.zeros((B, n, dk, dv), f32)
                 window = jnp.zeros((B, W - 1, mixed.shape[-1]), cfg.dtype)
             else:
                 state_rest, window_rest = kv_cache
+                packing = gdn_step.state_packing(n, dv)
                 live_row = q_spans > 0
                 fresh = live_row & (write_index == 0)
-                state = jnp.where(fresh[:, None, None, None], 0.0, state_rest.astype(f32))
+                # the rule _commit_span_rows takes its in-place kernel by
+                in_place = (T == 1 and cfg.attention_impl == "flash" and _tp_mesh_size() == 1
+                            and gdn_step.tiles(state_rest, n, dk, dv))
+                if T == 1:
+                    gdn_step.tally(in_place)
+                if not in_place:
+                    state = jnp.where(fresh[:, None, None, None], 0.0,
+                                      gdn_step.unpack_state(state_rest, packing).astype(f32))
                 window = jnp.where(fresh[:, None, None], 0, window_rest[:, 0]).astype(cfg.dtype)
                 live = (jnp.arange(T)[None, :] < q_spans[:, None])[..., None]
                 beta, g = jnp.where(live, beta, 0.0), jnp.where(live, g, 0.0)
@@ -1886,8 +1908,12 @@ class GatedDeltaNet(nn.Module):
             g, beta = g.transpose(0, 2, 1), beta.transpose(0, 2, 1)  # (B, n, T)
         with jax.named_scope("gdn_state"):
             if T == 1:
-                o, state = gated_delta_step(state, q[:, :, 0], k[:, :, 0], v[:, :, 0],
-                                            g[:, :, 0], beta[:, :, 0])
+                column = (q[:, :, 0], k[:, :, 0], v[:, :, 0], g[:, :, 0], beta[:, :, 0])
+                if in_place:
+                    o, new_state = gdn_step.gated_delta_update(state_rest, *column,
+                                                               live_row, fresh)
+                else:
+                    o, state = gated_delta_step(state, *column)
                 o = o[:, :, None]
             else:
                 o, state = gated_delta_chunked(state, q, k, v, g, beta)
@@ -1905,9 +1931,12 @@ class GatedDeltaNet(nn.Module):
                     pick = (rows[:, :, None] == jnp.arange(T + W - 1)[None, None, :])
                     tail = jnp.einsum("bjt,btc->bjc", pick.astype(seq.dtype), seq,
                                       precision=jax.lax.Precision.HIGHEST)
+                if not in_place:
+                    new_state = jnp.where(
+                        live_row[:, None, None, None],
+                        gdn_step.pack_state(state.astype(state_rest.dtype), packing), state_rest)
                 new_cache = (
-                    jnp.where(live_row[:, None, None, None], state.astype(state_rest.dtype),
-                              state_rest),
+                    new_state,
                     jnp.where(live_row[:, None, None, None],
                               tail[:, None].astype(window_rest.dtype), window_rest))
         with jax.named_scope("gdn_out"):
@@ -2838,10 +2867,13 @@ class CausalLMModel:
         ``"rows"`` (a row axis at ``ndim - 2``, one row a position: K, V,
         the packed pair, the latent row, the int8 tier's scales) or
         ``"state"`` (per-slot, no row axis: a linear-attention layer's
-        recurrent state ``(B, n, dk, dv)`` and the ``W - 1`` last inputs of
-        its convolution ``(B, 1, W - 1, channels)``, at rest in the cache
-        dtype; a Mamba layer's ``(B, 1, d_state, d_inner)`` and ``(B, 1, W -
-        1, d_inner)``; a Mamba-2 layer's ``(B, heads, head size, d_state)``
+        recurrent state ``(B, n / p, dk, p dv)``, ``p`` heads side by side in
+        the lanes so that a row is whole 128-lane tiles (``gdn_step.
+        state_packing``: 2 at the published ``dv`` 192, which would rest
+        padded to 256; 1 where no small ``p`` does it), and the ``W - 1``
+        last inputs of its convolution ``(B, 1, W - 1, channels)``, at rest
+        in the cache dtype; a Mamba layer's ``(B, 1, d_state, d_inner)`` and
+        ``(B, 1, W - 1, d_inner)``; a Mamba-2 layer's ``(B, heads, head size, d_state)``
         and ``(B, 1, W - 1, conv channels)``) or ``"ring"`` (a row axis at ``ndim - 2`` of
         ``cfg.ring_rows`` rows whatever ``max_len`` is, position ``p`` in row
         ``p mod R``: a windowed differential or full-attention layer's K and
@@ -2901,6 +2933,7 @@ class CausalLMModel:
         if quantized or len(rows) != 2:
             raise NotImplementedError("a pool with state or ring leaves has no int8 tier and "
                                       "no packed geometry")
+        gdn_pack = gdn_step.state_packing(cfg.linear_num_heads, cfg.linear_value_head_dim)
         state = lambda shape, W, channels: (
             ("state", (batch_size, ) + shape, dt, jnp.zeros),
             ("state", (batch_size, 1, W - 1, channels), dt, jnp.zeros))
@@ -2908,9 +2941,9 @@ class CausalLMModel:
                            dt, jnp.zeros), ) * 2
         declares = {
             "full_attention": tuple(rows),
-            "linear_attention": state((cfg.linear_num_heads, cfg.linear_key_head_dim,
-                                       cfg.linear_value_head_dim), cfg.linear_conv_kernel,
-                                      cfg.linear_conv_channels),
+            "linear_attention": state((cfg.linear_num_heads // gdn_pack, cfg.linear_key_head_dim,
+                                       gdn_pack * cfg.linear_value_head_dim),
+                                      cfg.linear_conv_kernel, cfg.linear_conv_channels),
             "mamba2": state((cfg.ssm_num_heads, cfg.ssm_head_dim, cfg.ssm_state_size),
                             cfg.ssm_conv_kernel, cfg.mamba2_conv_channels),
             None: ()}  # an FFN alone

@@ -1405,6 +1405,10 @@ class Gateway:
                           # with: "scatter" ones relay the pool every step
                           "kv_commit_programs": dict(getattr(
                               sched, "kv_commit_programs", {})),
+                          # ... and by their gated-delta layers' one-token
+                          # state update: "xla" ones pass over the state twice
+                          "gdn_step_programs": dict(getattr(
+                              sched, "gdn_step_programs", {})),
                           # the pool's geometry at rest: "packed" (K beside
                           # V in one leaf a layer), "split" or "latent"
                           "kv_pool_geometry": getattr(

@@ -36,6 +36,20 @@ def tiny():
     return model, hybrid_params(model, 7, jnp.dtype("float32"))
 
 
+@pytest.fixture(scope="module")
+def published_heads():
+    """``tiny-hybrid``'s hidden size, depth and vocabulary around linear
+    layers whose heads keep the published shape (``dk`` 96, ``dv`` 192, two
+    of them: one packed unit of 384 lanes), so that the decode column's
+    state update is the Pallas kernel (``ops/pallas/gdn_step.py``, interpret
+    mode here) and the leaf at rest the packed one."""
+    from chipbench.jobs.serve_hybrid import hybrid_params
+    base = get_model("tiny-hybrid", dtype=jnp.float32)
+    model = type(base)(dataclasses.replace(
+        base.cfg, linear_num_heads=2, linear_key_head_dim=96, linear_value_head_dim=192))
+    return model, hybrid_params(model, 7, jnp.dtype("float32"))
+
+
 def _engine(tiny, slots=4, chunk=16, steps=4, dtype="float32", **cb):
     model, params = tiny
     return deepspeed_tpu.init_inference(model, config={
@@ -111,6 +125,51 @@ def test_served_path_matches_the_reference(tiny, slots, chunk, steps, split):
         res = ref.compare(h.result_logits(), _reference_logits(eng, p, h.result()), tol=TOL)
         assert res["ok"], res["error"]
     assert sched.state_slots_reset == 3 and sched.radix is None
+    # tiny-hybrid's heads (8 x 16) do not tile: the definition serves them
+    assert sched.gdn_step_programs["xla"] > 0 and sched.gdn_step_programs["kernel"] == 0
+
+
+@pytest.mark.parametrize("slots, chunk, steps", [(4, 16, 4), (8, 64, 1)])
+def test_published_heads_are_served_through_the_kernel(published_heads, slots, chunk, steps):
+    """The same streams at the published head shape: the decode column's
+    update is the in-place kernel on the packed leaf, the chunk's scan
+    converts its slot's state on load and store, and the logits are the
+    reference's; the counters say ``kernel`` here and ``xla`` for
+    ``tiny-hybrid``'s heads, which do not tile."""
+    eng = _engine(published_heads, slots, chunk, steps)
+    sched = eng.scheduler()
+    shapes = [leaf.shape for leaf in jax.tree_util.tree_leaves(sched.cache.pool)]
+    assert shapes.count((slots, 1, 96, 384)) == 3
+    prompts = _prompts((37, 150, 70))
+    handles = [sched.submit(p, max_new_tokens=12, collect_logits=True) for p in prompts]
+    sched.drain()
+    for p, h in zip(prompts, handles):
+        res = ref.compare(h.result_logits(), _reference_logits(eng, p, h.result()), tol=TOL)
+        assert res["ok"], res["error"]
+    assert sched.state_slots_reset == 3
+    assert sched.gdn_step_programs["kernel"] > 0 and sched.gdn_step_programs["xla"] == 0
+
+
+def test_the_kernel_serves_the_definitions_streams(published_heads):
+    """Kernel against definition on one model: ``attention_impl`` flash takes
+    the kernel, the XLA attention fallback keeps ``gated_delta_step``; same
+    tokens, logits within the reference's limit of each other."""
+    prompts = _prompts((23, 41), seed=3)
+    got = {}
+    for inject in (True, False):
+        model, params = published_heads
+        eng = deepspeed_tpu.init_inference(model, config={
+            "dtype": "float32", "kernel_inject": inject, "max_out_tokens": 256,
+            "continuous_batching": {"enabled": True, "num_slots": 4, "steps_per_sync": 4,
+                                    "prefill_chunk": 16}}, params=params)
+        sched = eng.scheduler()
+        handles = [sched.submit(p, max_new_tokens=24, collect_logits=True) for p in prompts]
+        sched.drain()
+        assert (sched.gdn_step_programs["kernel"] > 0) is inject
+        got[inject] = [(list(h.result()), h.result_logits()) for h in handles]
+    for (toks, logits), (want_toks, want_logits) in zip(got[True], got[False]):
+        assert toks == want_toks
+        assert ref.compare(logits, want_logits, tol=TOL)["ok"]
 
 
 def test_a_span_0_slot_is_bit_for_bit_unchanged(tiny):
@@ -215,7 +274,9 @@ def test_the_other_refusals(tiny):
         tp.scheduler()
 
 
-def test_bf16_state_at_rest_over_512_decode_steps(tiny):
+@pytest.mark.parametrize("heads, widens, at_worst", [("tiny", 1.5, 0.02),
+                                                     ("published_heads", 4.0, 0.05)])
+def test_bf16_state_at_rest_over_512_decode_steps(request, heads, widens, at_worst):
     """A float32 program over a pool at rest in bf16 (``kv_cache_dtype``:
     state, window and rows), so that the rounding at rest is all that
     differs from the reference: the state is rounded once a token, 512
@@ -223,8 +284,18 @@ def test_bf16_state_at_rest_over_512_decode_steps(tiny):
     (bf16 keeps 8 bits; the decay forgets old roundings, a head's slowest
     here keeps a few hundred positions), and it does not widen: the last 64
     positions' median stays under 1.5 times the first 64's, every position
-    under 0.02. A float32 pool reads 1e-6 on the same request."""
-    model = get_model("tiny-hybrid", dtype=jnp.float32, max_seq_len=768)
+    under 0.02. A float32 pool reads 1e-6 on the same request.
+    ``tiny-hybrid``'s heads go through the definition; the published head
+    shape goes through the kernel and reads the same 0.0030 first. Its two
+    heads of ``dk`` 96 keep more positions, so the medians of 64 positions
+    level off later (0.0030, 0.0034, 0.0040, 0.0044, then 0.0043 to 0.0046)
+    and a greedy stream that a rounding tie sends another way reads
+    otherwise: the kernel 0.0073 last and 0.023 at worst, the DEFINITION
+    through the XLA attention fallback 0.0098 and 0.038 on the same model
+    and prompt. Held under 4 times the first and 0.05: a wrong state reads
+    0.1 and up, and the float32 pool holds the kernel to 1e-5."""
+    model, params = request.getfixturevalue(heads)
+    model = type(model)(dataclasses.replace(model.cfg, max_seq_len=768))
     prompt = _prompts((24, ), seed=9)[0]
     medians = {}
     for at_rest in ("bfloat16", "auto"):
@@ -232,7 +303,7 @@ def test_bf16_state_at_rest_over_512_decode_steps(tiny):
             "dtype": "float32", "kernel_inject": True, "max_out_tokens": 768,
             "continuous_batching": {"enabled": True, "num_slots": 2, "steps_per_sync": 4,
                                     "prefill_chunk": 16, "kv_cache_dtype": at_rest}},
-            params=tiny[1])
+            params=params)
         sched = eng.scheduler()
         want_dtype = jnp.dtype(jnp.bfloat16 if at_rest == "bfloat16" else jnp.float32)
         assert {leaf.dtype for leaf in jax.tree_util.tree_leaves(sched.cache.pool)} == {want_dtype}
@@ -241,9 +312,10 @@ def test_bf16_state_at_rest_over_512_decode_steps(tiny):
         err = np.asarray(ref.position_errors(h.result_logits(),
                                              _reference_logits(eng, prompt, h.result())))
         assert err.shape == (513, )
+        assert (sched.gdn_step_programs["kernel"] > 0) is (heads == "published_heads")
         medians[at_rest] = (np.median(err[:64]), np.median(err[-64:]), err.max())
     first, last, worst = medians["bfloat16"]
-    assert 5e-4 < first and last < 1.5 * first and worst < 0.02, medians
+    assert 5e-4 < first and last < widens * first and worst < at_worst, medians
     assert medians["auto"][2] < TOL, medians
 
 
@@ -272,7 +344,8 @@ def test_preset_builds_the_published_sizes():
     assert kv.bytes_per_token() == 61_440 and kv.state_bytes_per_slot() == 14_100_480
     assert kv.capacity_bytes() == 64 * (1024 * 61_440 + 14_100_480)
     shapes = [leaf.shape for leaf in jax.tree_util.tree_leaves(pool)]
-    assert shapes.count((64, 30, 96, 192)) == 12 and shapes.count((64, 1, 3, 11520)) == 12
+    # two heads side by side in a state's lanes: 384, not 192 padded to 256
+    assert shapes.count((64, 15, 96, 384)) == 12 and shapes.count((64, 1, 3, 11520)) == 12
     assert shapes.count((64, 30, 1024, 128)) == 8
 
 

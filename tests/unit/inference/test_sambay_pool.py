@@ -356,7 +356,9 @@ def _digest(tree):
      ("7d581e97c6a287c5", 64)),
     ("mistral-small-4-119b", {"scan_layers": False}, ("d378c3c33fb6535c", 579),
      ("30bae00ac1380acc", 36), ("70973dd72aba3407", 36)),
-    ("olmo-hybrid-7b", {}, ("7bf46abc96dcf40d", 475), ("1c5f151820235ef8", 64),
+    # PR 42: its state leaves rest two heads a lane row, (2, 15, 96, 384)
+    # (1c5f151820235ef8 while they were (2, 30, 96, 192))
+    ("olmo-hybrid-7b", {}, ("7bf46abc96dcf40d", 475), ("df45013d6f63db31", 64),
      ("72fa486ac50a3d4b", 64)),
     ("tiny-hybrid", {}, ("fe34f5c3f89eebab", 62), ("19da4dfe885cea0f", 8), ("2cb26fe38a1746cd", 8)),
     ("tiny-mla-moe", {}, ("585ee1651c3e6625", 19), ("3b84258aa35bd04e", 1), ("7efe6c18a76c403f", 1)),

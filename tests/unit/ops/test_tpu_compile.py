@@ -197,6 +197,22 @@ def test_kv_commit(for_chip, name, int8, cols):
     assert "dstpu_kv_commit" in text
 
 
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
+def test_gdn_step(for_chip, dtype):
+    """The one-token gated-delta update alone at cell 5's shape: 64 slots of
+    30 heads (96, 192), two heads a lane row at rest."""
+    from deepspeed_tpu.ops.pallas import gdn_step
+    sds, compile_ = for_chip
+    slots, n, dk, dv = 64, 30, 96, 192
+    leaf = sds((slots, n // 2, dk, 2 * dv), dtype)
+    assert gdn_step.tiles(leaf, n, dk, dv)
+    f32 = jnp.float32
+    text = compile_(gdn_step.gated_delta_update, leaf, sds((slots, n, dk), f32),
+                    sds((slots, n, dk), f32), sds((slots, n, dv), f32), sds((slots, n), f32),
+                    sds((slots, n), f32), sds((slots, ), jnp.bool_), sds((slots, ), jnp.bool_))
+    assert "dstpu_gdn_step" in text
+
+
 # ------------------------------------------------------- fused decode blocks
 def _layer_operands(name):
     """Shapes of the operand tuples the engines hand the fused kernels:
@@ -518,9 +534,12 @@ def test_hybrid_state_step_program(for_chip, step):
     state leaf, a window leaf or a K/V leaf stands in the loop or around it
     (a per-row gather of the window's three rows once left them in the
     lanes, padded forty-fold: 180 MB a copy for a 4 MB leaf). It fits with
-    its temporaries: all sixteen layers' weights and pool are 13.4 GB of the
-    chip's 15.75 GiB, and a period's sync holds under 1.3 GB beside them
-    (all sixteen layers': 1.15 GB, compiled once by hand, PR 30)."""
+    its temporaries: all sixteen layers' weights and pool are 13.1 GB of the
+    chip's 15.75 GiB (the state leaves rest unpadded since PR 42: 0.28 GB
+    less), and a period's sync holds under 0.4 GB beside them (0.25 GB the
+    decode sync, 0.31 GB the chunk's; all sixteen layers': 1.15 GB, compiled
+    once by hand, PR 30). The one-token update is ``dstpu_gdn_step`` on the
+    packed leaf ``(64, 15, 96, 384)``."""
     sds, _ = for_chip
     slots, chunk, pool_len = 64, 128, 1024
     base = get_model("olmo-hybrid-7b")
@@ -530,14 +549,16 @@ def test_hybrid_state_step_program(for_chip, step):
     compiled, pool, around = _compile_sync(
         sds, model, slots, 1 if step == "decode" else chunk, pool_len)
     shapes = [leaf.shape for leaf in jax.tree_util.tree_leaves(pool)]
-    assert shapes[0] == (slots, 30, 96, 192) and around == 0
+    # two heads side by side in a state's lanes (PR 42): 384, not 192 at 256
+    assert shapes[0] == (slots, 15, 96, 384) and around == 0
     text = compiled.as_text()
     for shape in set(shapes):
         assert _pool_relayouts(text, "[" + ",".join(map(str, shape)) + "]") == (0, 0), shape
     assert "dstpu_decode_attn" in text and "dstpu_kv_commit" in text
+    assert "dstpu_gdn_step" in text  # the one-token update, in place
     mem = compiled.memory_analysis()
     print(step, "temporaries", mem.temp_size_in_bytes)
-    assert mem.temp_size_in_bytes < 1.3e9, mem
+    assert mem.temp_size_in_bytes < 0.4e9, mem
     whole = 4 * (mem.argument_size_in_bytes - 2 * 3840 * 100352 * 2) + 2 * 3840 * 100352 * 2
     assert whole + mem.temp_size_in_bytes < 15.75 * 2**30, whole
 
@@ -724,10 +745,15 @@ def _accepted_cell_syncs():
 # PR 40 moved ONE: cell 4's chunk program (13426a2eaba288c8 at the parent),
 # whose (1, 256) forward now takes the dense product over the 32 experts held
 # (8 rows an expert: ``moe.layer.dense_held_pays``); its column is the parent's.
+# PR 42 moved TWO, both of cell 5 (ef0b951382842c66 and 2b29bcdf4eabe0c7 at its
+# parent 551c9a3): the state leaves rest packed, (64, 15, 96, 384), and the
+# column's one-token update is ``dstpu_gdn_step`` (``ops/pallas/gdn_step.py``);
+# the chunk program converts its slot's state around the scan. The other
+# three cells hold no gated-delta layer and lower what they lowered.
 PARENT_LOWERED = {
     "gpt2-large.serve.chat-closed": ("15065a760c93d007", "9bccfab4d863721b"),
     "mistral-small-4-119b.serve.decode-closed": ("7b6b3c73f810f160", "b75b19168934fbc2"),
-    "olmo-hybrid-7b.serve.decode-closed": ("ef0b951382842c66", "2b29bcdf4eabe0c7"),
+    "olmo-hybrid-7b.serve.decode-closed": ("f887b9840c76ab25", "f1dfd33c486d64da"),
     "phi-4-mini-flash.serve.reason-closed": ("71e23c35b3433be3", "af018c91144275bb"),
 }
 
