@@ -21,181 +21,539 @@ the index maps point query head ``h`` at KV head ``h // group``; nothing is
 repeated in HBM. The backward dk/dv kernel accumulates per *query* head and
 the group-sum is folded outside (a cheap reduce over the group dim).
 
-K/V for one (batch, head) program live in VMEM — ~2·T·D·2 bytes, which fits
-tens-of-k tokens at D=64..128; beyond that, sequence parallelism (ring /
-Ulysses over the ``seq`` axis) splits T across chips before the kernel runs.
-
 Backward follows the standard two-kernel split (dq; dkv) with the saved
 softmax log-sum-exp and delta = rowsum(dO * O).
+
+The loops follow the causal structure (:func:`plan`). A grid step holds one
+whole (batch, head) — q, k, v and the outputs of a head are 128-512 KB each
+at training lengths — and walks it in sub-blocks of scores with STATIC trip
+counts: the blocks that lie wholly inside the mask with no padded row or
+column run a body with no mask at all, the blocks the diagonal crosses (and
+the padded edge) run the same body with its ``masked`` flag set. The mask's
+granularity is the sub-block, chosen from the shapes; the caller's
+``block_q`` / ``block_kv`` are upper limits on it. A head that does not fit
+the VMEM estimate, or is no longer than one sub-block, keeps the single
+masked body over ``block_q`` x ``block_kv`` grid blocks (``Plan.fallback``
+says why). The dk/dv kernel computes its scores transposed (``k q^T``).
+
+The layer alone (PR 44; TPU v5 lite, bf16, causal, ``block_q = block_kv =
+512``; device time of the Pallas call in a profiler trace, mean of 10 calls,
+ms; the parent is the kernel before PR 44: 512 x 512 grid blocks, every
+block masked, rolled loops)::
+
+                                 forward        dq             dk/dv        three calls
+    (B, H, T, D)                 parent change  parent change  parent change  parent change
+    (4, 20, 1024, 64)  cell 1    0.377  0.207   0.365  0.231   0.541  0.325   1.283  0.763
+    (2, 32, 2048, 64)  cell 3    0.880  0.489   0.910  0.625   1.469  0.839   3.259  1.953
+    (4, 32/8, 1024, 128) GQA     0.615  0.334   0.582  0.372   0.859  0.525   2.056  1.231
+
+``plan`` chose, at all three and for all three kernels: the whole head a grid
+step, sub-blocks of 256 x 256; scores computed / scores inside the mask 124.9%
+at T = 1024 and 112.4% at 2048 (the parent's 512 x 512: 149.9% and 124.9%),
+scores under the masked body / scores computed 40.0% and 22.2% (100%).
+
+Where the time went, by what was tried (same runs; cell 1 / cell 3, ms):
+
+- *Static loops are most of it.* The parent's ``fori_loop`` has a traced
+  trip count; with the whole head a grid step every bound is a Python int,
+  short loops are written out and long ones go four blocks an iteration, and
+  the compiler lays one block's vector work beside the next block's
+  products. Whole head, 512 x 512 blocks, EVERY block masked: forward 0.214 /
+  0.536, dq 0.262 / 0.672, dk/dv (transposed) 0.350 / 0.898. The same 256 x
+  256 walk ROLLED (one block an iteration): 0.396 / 1.207, 0.368 / 1.117,
+  0.527 / 1.610, no better than the parent; two blocks an iteration at T =
+  2048: 0.915, 0.834, 1.196. Mosaic unrolls a loop wholly or not at all
+  (``unroll=2`` is refused), so the partial unroll is by hand (``_loop``).
+- *The transposed dk/dv.* With ``p`` and ``ds`` as (q, kv) blocks, ``p^T do``
+  and ``ds^T q`` each put a block of scores through a transpose; as (kv, q)
+  blocks all four products are plain. Wall clock of the call with its XLA
+  neighbours, 256 x 256 two blocks an iteration: 0.805 -> 0.507 / 2.230 ->
+  1.504. lse and delta then lie along the lanes: the step turns its two
+  (T, 1) columns into rows first, one (128, 128) transpose a 128 q rows.
+- *The split and the granularity* give the rest: 256 x 256 with the mask-free
+  body against 512 x 512 masked everywhere, forward 0.214 -> 0.207 / 0.536 ->
+  0.489, dq 0.262 -> 0.231 / 0.672 -> 0.625, dk/dv 0.350 -> 0.325 / 0.898 ->
+  0.839.
+- *Set-up.* Written-out loops are traced and lowered block by block: a
+  layer's three calls took 0.36 s of tracing and lowering at T = 1024 and
+  0.76 s at 2048 where the parent's rolled loops took 0.12 (on this
+  sandbox's CPU), 36 and 24 times a training program. The calls are
+  therefore jitted (:func:`attn`): one trace and one lowering a distinct
+  shape, 0.01-0.03 s a layer after the first.
+- *Dropped:* a grid block of 512 rows walked in 256 x 256 sub-blocks (traced
+  bounds again: forward 0.73 / 1.76 by wall clock where the parent read 0.52 /
+  1.21); 128-wide kv sub-blocks in the forward and dq (0.240 / 0.903, 0.254 /
+  0.961); 128 x 128 everywhere (0.220 / 0.916, 0.276 / 0.811, 0.325 / 0.821
+  written out: 136 blocks a step at T = 2048 compile in 4-8 s a kernel); 128 x
+  256 (forward 0.202 / 0.478 but dq 0.263 / 0.769); 512-row sub-blocks
+  (0.241 / 0.540, 0.270 / 0.678, 0.372 / 0.913).
+
+What binds it now: at head size 64 every product half-fills the MXU (a
+64-deep contraction or a 64-wide result), so a causal-halved product of
+2 T^2 d a head takes 0.0545 ms in cell 1 (twice its 197 TFLOP/s time), and
+at the 62.5% of the square the 256 x 256 walk computes the forward's two
+products need 0.136 ms and the backward's seven 0.477: the forward runs at
+66% of that bound and the backward at 86%. What is left is the MXU's fill,
+the computed share, and a backward of five products (one fused kernel).
 
 Kernels run interpreted on CPU (tests) and compiled on TPU.
 """
 
 import functools
+import math
+import threading
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
 from .. import pallas as _pallas
 
 DEFAULT_MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
+LANES = 128
+# The sub-block of scores (q rows, kv columns) the three kernels walk inside
+# a grid step where the caller's ``block_q`` / ``block_kv`` allow it: the
+# granularity of the causal mask. One size won for all three at every shape
+# measured (the layer-alone table above).
+SUB_BLOCK = (256, 256)
+# A static loop of at most INLINE_BLOCKS blocks is written out; a longer one
+# runs UNROLL blocks an iteration (the layer-alone table: rolled loops lose
+# a third).
+INLINE_BLOCKS = 8
+UNROLL = 4
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, block_kv, causal, q_len,
-                kv_len):
-    """Grid: (B, H, num_q_blocks). Blocks: q/o (1, 1, bq, D);
-    k/v (1, 1, Tkv, D) — the full (padded) KV head in VMEM; lse (1, 1, bq)."""
-    block_q = q_ref.shape[2]
-    d = q_ref.shape[-1]
-    qi = pl.program_id(2)
-    q_start = qi * block_q
+class KernelPlan(NamedTuple):
+    """One kernel's sizes and the shares that follow from them."""
+    grid: int  # rows of a grid step's block: q rows (forward, dq), kv rows (dk/dv)
+    sub_q: int  # q rows of a block of scores
+    sub_kv: int  # kv columns of it
+    split: bool  # blocks wholly inside the mask run the mask-free body
+    computed_pct: float  # scores computed / scores inside the mask
+    masked_pct: float  # scores under the masked body / scores computed
 
-    q = q_ref[0, 0]  # (bq, D) operand dtype; accumulation is fp32
 
-    m = jnp.full((block_q, 1), -jnp.inf, jnp.float32)
-    l = jnp.zeros((block_q, 1), jnp.float32)
-    acc = jnp.zeros((block_q, d), jnp.float32)
+class Plan(NamedTuple):
+    fwd: KernelPlan
+    dq: KernelPlan
+    dkv: KernelPlan
+    fallback: str  # why the single masked body was kept; "" where it was not
 
-    num_kv = pl.cdiv(k_ref.shape[2], block_kv)
+    @property
+    def computed_pct(self):
+        """Over the three kernels: each computes every score it visits once."""
+        return sum(k.computed_pct for k in self[:3]) / 3
+
+    @property
+    def masked_pct(self):
+        return (sum(k.masked_pct * k.computed_pct for k in self[:3])
+                / sum(k.computed_pct for k in self[:3]))
+
+
+# Plans of the flash calls traced by this thread: the training engine reads
+# it around a step's first dispatch to say what its kernels compute.
+_traced = threading.local()
+
+
+def tally(plan_):
+    """Note one traced flash call's plan."""
+    _traced.plans = traced() + (plan_, )
+
+
+def traced():
+    return getattr(_traced, "plans", ())
+
+
+# ------------------------------------------------------------------ the loops
+def _static(*xs):
+    return all(isinstance(x, int) for x in xs)
+
+
+def _min(a, b):
+    return min(a, b) if _static(a, b) else jnp.minimum(a, b)
+
+
+def _max(a, b):
+    return max(a, b) if _static(a, b) else jnp.maximum(a, b)
+
+
+def _kv_blocks(q_start, sub_q, sub_kv, n_kv, kv_len, causal, split):
+    """The kv blocks a q block at ``q_start`` walks, ``(n_inside, n_visited)``:
+    blocks ``[0, n_inside)`` lie wholly inside the mask with no padded column,
+    ``[n_inside, n_visited)`` need a mask (the diagonal crosses them, or they
+    hold the padded edge). Python ints in, ints out; a traced ``q_start``
+    gives traced bounds."""
+    n_visited = _min(n_kv, (q_start + sub_q + sub_kv - 1) // sub_kv) if causal else n_kv
+    if not split:
+        return 0, n_visited
+    n_inside = kv_len // sub_kv
     if causal:
-        num_kv_eff = jax.lax.min(num_kv, pl.cdiv(q_start + block_q, block_kv))
-    else:
-        num_kv_eff = num_kv
-    # loop-invariant local iotas: mask = (ik - iq) <= q_start - kv_start —
-    # one scalar-broadcast compare per iteration instead of two iota adds
-    iq = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_kv), 0)
-    ik = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_kv), 1)
-    ikq = ik - iq
+        n_inside = _min(n_inside, (q_start + 1) // sub_kv)
+    return n_inside, n_visited
 
-    def body(j, carry):
-        m, l, acc = carry
-        kv_start = j * block_kv
-        k = k_ref[0, 0, pl.ds(kv_start, block_kv), :]
-        v = v_ref[0, 0, pl.ds(kv_start, block_kv), :]
-        s = jax.lax.dot_general(q, k, (((1, ), (1, )), ((), ())),
-                                preferred_element_type=jnp.float32) * scale  # (bq, bkv)
 
-        mask = ik < kv_len - kv_start
-        if causal:
-            mask = mask & (ikq <= q_start - kv_start)
-        s = jnp.where(mask, s, DEFAULT_MASK_VALUE)
-
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        # rows whose every visited entry is masked exist only when the
-        # sequence is padded (causal rows always see the diagonal): only then
-        # pay for the explicit zero that yields l=0 -> zero output, -inf lse
-        # (otherwise exp(MASK - m_new) underflows to 0 on its own)
-        if kv_len % block_kv or q_len % block_q:
-            p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+def _q_blocks(kv_start, sub_q, sub_kv, n_q, q_len, kv_len, causal, split):
+    """The q blocks a kv block at ``kv_start`` walks, ``(start, inside_from,
+    inside_to)``: ``[start, inside_from)`` and ``[inside_to, n_q)`` need a
+    mask (the diagonal, the padded rows), ``[inside_from, inside_to)`` do not.
+    A kv block with padded columns masks every q block."""
+    start = _min(kv_start // sub_q, n_q) if causal else 0
+    if not split:
+        return start, n_q, n_q
+    inside_to = _max(q_len // sub_q, start)
+    inside_from = (kv_start + sub_kv - 1 + sub_q - 1) // sub_q if causal else 0
+    inside_from = _max(start, _min(inside_from, inside_to))
+    if kv_len % sub_kv:  # the last kv block holds the padded columns
+        padded = kv_start + sub_kv > kv_len
+        if _static(kv_start):
+            inside_from = inside_to if padded else inside_from
         else:
+            inside_from = jnp.where(padded, inside_to, inside_from)
+    return start, inside_from, inside_to
+
+
+def _loop(lo, hi, body, carry):
+    """``fori_loop`` over blocks. A static range of up to ``INLINE_BLOCKS`` is
+    written out and a longer one goes ``UNROLL`` blocks an iteration, so that
+    one block's vector work has the next block's products beside it (Mosaic
+    unrolls a loop wholly or not at all)."""
+    if not _static(lo, hi):
+        return jax.lax.fori_loop(lo, hi, body, carry)
+    n = hi - lo
+    rolled = 0 if n <= INLINE_BLOCKS else n // UNROLL
+
+    def several(i, carry):
+        for r in range(UNROLL):
+            carry = body(lo + i * UNROLL + r, carry)
+        return carry
+
+    if rolled:
+        carry = jax.lax.fori_loop(0, rolled, several, carry)
+    for j in range(lo + rolled * UNROLL, hi):
+        carry = body(j, carry)
+    return carry
+
+
+def _dot(a, b, ca, cb):
+    return jax.lax.dot_general(a, b, (((ca, ), (cb, )), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _prescaled(scale):
+    """Whether ``scale`` is a power of two: ``q * scale`` is then exact in any
+    float dtype and takes the place of a pass over every block of scores."""
+    return math.frexp(scale)[0] == 0.5
+
+
+def _block_mask(masked, iq, ik, q_start, kv_start, causal, q_left, kv_left):
+    """The mask of one block of scores; None under the mask-free body and
+    where nothing in the block can be masked. ``q_left`` / ``kv_left``: the
+    rows / columns of the block inside the logical length, None where that
+    side has no padding."""
+    if not masked:
+        return None
+    terms = []
+    if kv_left is not None:
+        terms.append(ik < kv_left)
+    if q_left is not None:
+        terms.append(iq < q_left)
+    if causal:
+        terms.append(ik - iq <= q_start - kv_start)
+    return functools.reduce(jnp.logical_and, terms) if terms else None
+
+
+# ---------------------------------------------------------------- the kernels
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, sub_q, sub_kv, causal, split,
+                q_len, kv_len, one_step):
+    """Grid: (B, H, q grid blocks). Blocks: q/o (1, 1, gq, D); k/v
+    (1, 1, Tkv, D), the full (padded) KV head in VMEM; lse (1, 1, gq, 1). The
+    step walks its q rows in sub-blocks of ``sub_q`` and, for each, the kv
+    blocks of ``sub_kv`` columns the mask leaves."""
+    gq, d = q_ref.shape[2], q_ref.shape[3]
+    base = 0 if one_step else pl.program_id(2) * gq
+    n_kv = k_ref.shape[2] // sub_kv
+    prescale = _prescaled(scale)
+    kv_padded = bool(kv_len % sub_kv)
+    zero_masked = kv_padded or bool(q_len % sub_q)
+    iq = jax.lax.broadcasted_iota(jnp.int32, (sub_q, sub_kv), 0)
+    ik = jax.lax.broadcasted_iota(jnp.int32, (sub_q, sub_kv), 1)
+
+    def q_block(a):
+        q_start = base + a * sub_q
+        rows = pl.ds(a * sub_q, sub_q)
+        q = q_ref[0, 0, rows, :]  # operand dtype; accumulation is fp32
+        if prescale:
+            q = q * scale
+
+        def body(masked, j, carry):
+            m, l, acc = carry
+            kv_start = j * sub_kv
+            k = k_ref[0, 0, pl.ds(kv_start, sub_kv), :]
+            v = v_ref[0, 0, pl.ds(kv_start, sub_kv), :]
+            s = _dot(q, k, 1, 1)  # (sub_q, sub_kv)
+            if not prescale:
+                s = s * scale
+            mask = _block_mask(masked, iq, ik, q_start, kv_start, causal, None,
+                               kv_len - kv_start if kv_padded else None)
+            if mask is not None:
+                s = jnp.where(mask, s, DEFAULT_MASK_VALUE)
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
             p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m - m_new)
-        l = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc = acc * alpha + jax.lax.dot_general(p.astype(v.dtype), v, (((1, ), (0, )), ((), ())),
-                                                preferred_element_type=jnp.float32)
-        return m_new, l, acc
+            # rows whose every visited entry is masked exist only when the
+            # sequence is padded (causal rows always see the diagonal): only
+            # then pay for the explicit zero that yields l=0 -> zero output,
+            # -inf lse (otherwise exp(MASK - m_new) underflows to 0 itself)
+            if mask is not None and zero_masked:
+                p = jnp.where(mask, p, 0.0)
+            alpha = jnp.exp(m - m_new)
+            l = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            acc = acc * alpha + _dot(p.astype(v.dtype), v, 1, 0)
+            return m_new, l, acc
 
-    m, l, acc = jax.lax.fori_loop(0, num_kv_eff, body, (m, l, acc))
+        carry = (jnp.full((sub_q, 1), -jnp.inf, jnp.float32), jnp.zeros((sub_q, 1), jnp.float32),
+                 jnp.zeros((sub_q, d), jnp.float32))
+        n_inside, n_visited = _kv_blocks(q_start, sub_q, sub_kv, n_kv, kv_len, causal, split)
+        carry = _loop(0, n_inside, functools.partial(body, False), carry)
+        m, l, acc = _loop(n_inside, n_visited, functools.partial(body, True), carry)
 
-    l_safe = jnp.where(l == 0, 1.0, l)
-    o_ref[0, 0] = (acc / l_safe).astype(o_ref.dtype)
-    lse_ref[0, 0] = jnp.where(l == 0, -jnp.inf, m + jnp.log(l_safe))  # (bq, 1)
+        l_safe = jnp.where(l == 0, 1.0, l)
+        o_ref[0, 0, rows, :] = (acc / l_safe).astype(o_ref.dtype)
+        lse_ref[0, 0, rows, :] = jnp.where(l == 0, -jnp.inf, m + jnp.log(l_safe))
 
-
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *, scale, block_kv, causal,
-                   kv_len):
-    block_q = q_ref.shape[2]
-    d = q_ref.shape[-1]
-    qi = pl.program_id(2)
-    q_start = qi * block_q
-
-    q = q_ref[0, 0]
-    do = do_ref[0, 0]
-    # -inf marks attended-nothing (padding) rows; neutralize so exp(s - lse)
-    # stays finite — their dq is sliced away / masked out downstream
-    lse = jnp.where(jnp.isfinite(lse_ref[0, 0]), lse_ref[0, 0], 0.0)  # (bq, 1)
-    delta = delta_ref[0, 0]  # (bq, 1)
-
-    num_kv = pl.cdiv(k_ref.shape[2], block_kv)
-    num_kv_eff = jax.lax.min(num_kv, pl.cdiv(q_start + block_q, block_kv)) if causal else num_kv
-    iq = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_kv), 0)
-    ik = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_kv), 1)
-    ikq = ik - iq
-
-    def body(j, dq):
-        kv_start = j * block_kv
-        k = k_ref[0, 0, pl.ds(kv_start, block_kv), :]
-        v = v_ref[0, 0, pl.ds(kv_start, block_kv), :]
-        s = jax.lax.dot_general(q, k, (((1, ), (1, )), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        mask = ik < kv_len - kv_start
-        if causal:
-            mask = mask & (ikq <= q_start - kv_start)
-        p = jnp.where(mask, jnp.exp(s - lse), 0.0)
-        dp = jax.lax.dot_general(do, v, (((1, ), (1, )), ((), ())), preferred_element_type=jnp.float32)
-        # fold the softmax scale into ds before the bf16 cast (dq = scale·dsᵀk)
-        ds = (p * (dp - delta) * scale).astype(k.dtype)
-        return dq + jax.lax.dot_general(ds, k, (((1, ), (0, )), ((), ())),
-                                        preferred_element_type=jnp.float32)
-
-    dq = jax.lax.fori_loop(0, num_kv_eff, body, jnp.zeros((block_q, d), jnp.float32))
-    dq_ref[0, 0] = dq.astype(dq_ref.dtype)
+    for a in range(gq // sub_q):
+        q_block(a)
 
 
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref, *, scale, block_q,
-                    causal, q_len, kv_len):
-    """Grid: (B, H, num_kv_blocks). k/v blocks (1, 1, bkv, D) come from the
+def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *, scale, sub_q, sub_kv,
+                   causal, split, kv_len, one_step):
+    """The forward's grid and walk. lse / delta: (1, 1, gq, 1) columns."""
+    gq, d = q_ref.shape[2], q_ref.shape[3]
+    base = 0 if one_step else pl.program_id(2) * gq
+    n_kv = k_ref.shape[2] // sub_kv
+    prescale = _prescaled(scale)
+    kv_padded = bool(kv_len % sub_kv)
+    iq = jax.lax.broadcasted_iota(jnp.int32, (sub_q, sub_kv), 0)
+    ik = jax.lax.broadcasted_iota(jnp.int32, (sub_q, sub_kv), 1)
+
+    def q_block(a):
+        q_start = base + a * sub_q
+        rows = pl.ds(a * sub_q, sub_q)
+        q = q_ref[0, 0, rows, :]
+        if prescale:
+            q = q * scale
+        do = do_ref[0, 0, rows, :]
+        # -inf marks attended-nothing (padding) rows; neutralize so exp(s - lse)
+        # stays finite — their dq is sliced away / masked out downstream
+        lse = lse_ref[0, 0, rows, :]  # (sub_q, 1)
+        lse = jnp.where(jnp.isfinite(lse), lse, 0.0)
+        delta = delta_ref[0, 0, rows, :]
+
+        def body(masked, j, dq):
+            kv_start = j * sub_kv
+            k = k_ref[0, 0, pl.ds(kv_start, sub_kv), :]
+            v = v_ref[0, 0, pl.ds(kv_start, sub_kv), :]
+            s = _dot(q, k, 1, 1)
+            if not prescale:
+                s = s * scale
+            p = jnp.exp(s - lse)
+            mask = _block_mask(masked, iq, ik, q_start, kv_start, causal, None,
+                               kv_len - kv_start if kv_padded else None)
+            if mask is not None:
+                p = jnp.where(mask, p, 0.0)
+            ds = p * (_dot(do, v, 1, 1) - delta)
+            # the softmax scale folds into ds before the cast (dq =
+            # scale·ds·k), or into dq once where it is a power of two
+            if not prescale:
+                ds = ds * scale
+            return dq + _dot(ds.astype(k.dtype), k, 1, 0)
+
+        n_inside, n_visited = _kv_blocks(q_start, sub_q, sub_kv, n_kv, kv_len, causal, split)
+        dq = _loop(0, n_inside, functools.partial(body, False), jnp.zeros((sub_q, d), jnp.float32))
+        dq = _loop(n_inside, n_visited, functools.partial(body, True), dq)
+        if prescale:
+            dq = dq * scale
+        dq_ref[0, 0, rows, :] = dq.astype(dq_ref.dtype)
+
+    for a in range(gq // sub_q):
+        q_block(a)
+
+
+def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref, rows_ref, *, scale,
+                    sub_q, sub_kv, causal, split, q_len, kv_len, one_step):
+    """Grid: (B, H, kv grid blocks). k/v blocks (1, 1, gkv, D) come from the
     (possibly grouped) KV head for query head h; dk/dv are written per
-    *query* head (into (B, H, Tkv, D)) and group-summed by the caller."""
-    block_kv = k_ref.shape[2]
-    d = k_ref.shape[-1]
-    ki = pl.program_id(2)
-    kv_start = ki * block_kv
+    *query* head (into (B, H, Tkv, D)) and group-summed by the caller. q / do:
+    the full (padded) head; lse / delta: (1, 1, Tq, 1) columns.
 
-    k = k_ref[0, 0]
-    v = v_ref[0, 0]
+    The scores are computed TRANSPOSED (``k q^T``, a block is (sub_kv,
+    sub_q)), so that ``p^T do`` and ``ds^T q`` are plain products and no
+    block of scores goes through a transpose. lse and delta then ride along
+    the lanes: the step first turns its two columns into rows, one
+    (128, 128) transpose a 128 q rows, kept in ``rows_ref`` (n_q, 8, sub_q):
+    row 0 lse, row 1 delta."""
+    gkv, d = k_ref.shape[2], k_ref.shape[3]
+    base = 0 if one_step else pl.program_id(2) * gkv
+    n_q = q_ref.shape[2] // sub_q
+    prescale = _prescaled(scale)
+    q_padded, kv_padded = bool(q_len % sub_q), bool(kv_len % sub_kv)
+    ik = jax.lax.broadcasted_iota(jnp.int32, (sub_kv, sub_q), 0)
+    iq = jax.lax.broadcasted_iota(jnp.int32, (sub_kv, sub_q), 1)
 
-    num_q = pl.cdiv(q_ref.shape[2], block_q)
-    start_q = (kv_start // block_q) if causal else 0
+    lane = jax.lax.broadcasted_iota(jnp.int32, (LANES, LANES), 1)
+    for i in range(n_q):
+        for c in range(sub_q // LANES):
+            rows = pl.ds(i * sub_q + c * LANES, LANES)
+            lse = lse_ref[0, 0, rows, :]  # (128, 1)
+            # -inf marks attended-nothing (padding) rows; neutralize so
+            # exp(s - lse) stays finite: their p is masked out below
+            lse = jnp.where(jnp.isfinite(lse), lse, 0.0)
+            cols = jnp.where(lane == 0, lse, jnp.where(lane == 1, delta_ref[0, 0, rows, :], 0.0))
+            rows_ref[i, :, pl.ds(c * LANES, LANES)] = cols.T[:8]
 
-    iq = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_kv), 0)
-    ik = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_kv), 1)
-    ikq = ik - iq
+    def kv_block(b):
+        kv_start = base + b * sub_kv
+        cols = pl.ds(b * sub_kv, sub_kv)
+        k = k_ref[0, 0, cols, :]
+        v = v_ref[0, 0, cols, :]
 
-    def body(i, carry):
-        dk, dv = carry
-        q_start = i * block_q
-        q = q_ref[0, 0, pl.ds(q_start, block_q), :]
-        do = do_ref[0, 0, pl.ds(q_start, block_q), :]
-        lse_raw = lse_ref[0, 0, pl.ds(q_start, block_q), :]  # (bq, 1)
-        lse = jnp.where(jnp.isfinite(lse_raw), lse_raw, 0.0)
-        delta = delta_ref[0, 0, pl.ds(q_start, block_q), :]  # (bq, 1)
+        def body(masked, i, carry):
+            dk, dv = carry
+            q_start = i * sub_q
+            q = q_ref[0, 0, pl.ds(q_start, sub_q), :]
+            if prescale:
+                q = q * scale
+            do = do_ref[0, 0, pl.ds(q_start, sub_q), :]
+            lse, delta = rows_ref[i, 0:1, :], rows_ref[i, 1:2, :]  # (1, sub_q)
 
-        s = jax.lax.dot_general(q, k, (((1, ), (1, )), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        mask = (ik < kv_len - kv_start) & (iq < q_len - q_start)
-        if causal:
-            mask = mask & (ikq <= q_start - kv_start)
-        p = jnp.where(mask, jnp.exp(s - lse), 0.0)
-        pb = p.astype(do.dtype)
+            s = _dot(k, q, 1, 1)  # (sub_kv, sub_q)
+            if not prescale:
+                s = s * scale
+            p = jnp.exp(s - lse)
+            mask = _block_mask(masked, iq, ik, q_start, kv_start, causal,
+                               q_len - q_start if q_padded else None,
+                               kv_len - kv_start if kv_padded else None)
+            if mask is not None:
+                p = jnp.where(mask, p, 0.0)
+            dv = dv + _dot(p.astype(do.dtype), do, 1, 0)
+            ds = p * (_dot(v, do, 1, 1) - delta)
+            # scale folds into ds (dk = scale·dsᵀq), or rides on q where it
+            # is a power of two
+            if not prescale:
+                ds = ds * scale
+            dk = dk + _dot(ds.astype(q.dtype), q, 1, 0)
+            return dk, dv
 
-        dv = dv + jax.lax.dot_general(pb, do, (((0, ), (0, )), ((), ())),
-                                      preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, v, (((1, ), (1, )), ((), ())),
-                                  preferred_element_type=jnp.float32)
-        # scale folds into ds (dk = scale·dsᵀq), matching the fwd s-scaling
-        ds = (p * (dp - delta) * scale).astype(q.dtype)
-        dk = dk + jax.lax.dot_general(ds, q, (((0, ), (0, )), ((), ())),
-                                      preferred_element_type=jnp.float32)
-        return dk, dv
+        start, inside_from, inside_to = _q_blocks(kv_start, sub_q, sub_kv, n_q, q_len, kv_len,
+                                                  causal, split)
+        zero = jnp.zeros((sub_kv, d), jnp.float32)
+        carry = _loop(start, inside_from, functools.partial(body, True), (zero, zero))
+        carry = _loop(inside_from, inside_to, functools.partial(body, False), carry)
+        dk, dv = _loop(inside_to, n_q, functools.partial(body, True), carry)
+        dk_ref[0, 0, cols, :] = dk.astype(dk_ref.dtype)
+        dv_ref[0, 0, cols, :] = dv.astype(dv_ref.dtype)
 
-    zero = jnp.zeros((block_kv, d), jnp.float32)
-    dk, dv = jax.lax.fori_loop(start_q, num_q, body, (zero, zero))
-    dk_ref[0, 0] = dk.astype(dk_ref.dtype)
-    dv_ref[0, 0] = dv.astype(dv_ref.dtype)
+    for b in range(gkv // sub_kv):
+        kv_block(b)
+
+
+# ------------------------------------------------------------------- the plan
+def _pad_to(n, m):
+    return -(-n // m) * m
+
+
+def _vmem_estimate(kernel, T_q, T_kv, grid, sub_q, sub_kv, D, itemsize):
+    """VMEM bytes of one grid step as Mosaic lays its blocks out (lanes pad to
+    128, a trailing axis of 1 to a whole lane tile, pipelined operands are
+    double-buffered), and about eight float32 copies of one block of scores
+    with the accumulators."""
+    wide = _pad_to(D, LANES)
+    row = lambda t: t * wide * itemsize  # (t, D) in the operand dtype
+    col = lambda t: t * LANES * 4  # (t, 1) float32
+    if kernel == "fwd":
+        blocks = 2 * row(grid) + 2 * row(T_kv) + col(grid)
+    elif kernel == "dq":
+        blocks = 3 * row(grid) + 2 * row(T_kv) + 2 * col(grid)
+    else:
+        blocks = 2 * row(T_q) + 4 * row(grid) + 2 * col(T_q) + 8 * T_q * 4
+    scores = 8 * sub_q * _pad_to(sub_kv, LANES) * 4
+    acc = 3 * max(sub_q, sub_kv) * wide * 4
+    return 2 * blocks + scores + acc
+
+
+def _shares(kernel, T, T_kv, Tq, Tkv, sub_q, sub_kv, causal, split):
+    """``(computed, masked)`` scores of one (batch, head), walked as the
+    kernel walks them."""
+    computed = masked = 0
+    if kernel == "dkv":
+        for kv_start in range(0, Tkv, sub_kv):
+            start, lo, hi = _q_blocks(kv_start, sub_q, sub_kv, Tq // sub_q, T, T_kv, causal, split)
+            computed += Tq // sub_q - start
+            masked += Tq // sub_q - start - (hi - lo)
+    else:
+        for q_start in range(0, Tq, sub_q):
+            n_inside, n_visited = _kv_blocks(q_start, sub_q, sub_kv, Tkv // sub_kv, T_kv, causal,
+                                             split)
+            computed += n_visited
+            masked += n_visited - n_inside
+    return computed * sub_q * sub_kv, masked * sub_q * sub_kv
+
+
+@functools.lru_cache(maxsize=None)
+def plan(T, T_kv, D, dtype, causal, block_q=512, block_kv=512):
+    """The sizes the three kernels run ``(B, H, T, D)`` x ``(B, Hkv, T_kv, D)``
+    at and the shares that follow from them, from the shapes alone.
+
+    Each kernel's grid step takes the whole (padded) head and walks it in
+    sub-blocks of :data:`SUB_BLOCK` scores, no larger than ``block_q`` x
+    ``block_kv``; blocks wholly inside the mask run the mask-free body. A
+    head of one sub-block (nothing lies wholly inside), or one whose blocks
+    do not fit :func:`_vmem_estimate` in the budget, keeps the single masked
+    body over grid blocks of ``block_q`` / ``block_kv`` rows, and ``fallback``
+    says which."""
+    itemsize = jnp.dtype(dtype).itemsize
+    inside = sum(min(r + 1, T_kv) for r in range(T)) if causal else T * T_kv
+
+    def kernel_plan(kernel, grid_rows, sub_q, sub_kv, split):
+        Tq, Tkv = _pad_to(T, sub_q), _pad_to(T_kv, sub_kv)
+        grid = grid_rows or (Tkv if kernel == "dkv" else Tq)
+        computed, masked = _shares(kernel, T, T_kv, Tq, Tkv, sub_q, sub_kv, causal, split)
+        return KernelPlan(grid, sub_q, sub_kv, split, 100.0 * computed / inside,
+                          100.0 * masked / computed)
+
+    bq, bkv = min(block_q, T), min(block_kv, T_kv)
+
+    def sub(preferred, limit, length):
+        """Of the preferred size and its half the one that pads the length
+        less, no larger than the caller's limit."""
+        size = min(preferred, limit)
+        half = size // 2
+        return half if half % LANES == 0 and _pad_to(length, half) < _pad_to(length, size) else size
+
+    def lanes(name, sub_q):  # the dk/dv kernel's q rows lie along the lanes
+        return _pad_to(sub_q, LANES) if name == "dkv" else sub_q
+
+    sq, skv = sub(SUB_BLOCK[0], bq, T), sub(SUB_BLOCK[1], bkv, T_kv)
+    whole = {name: kernel_plan(name, 0, lanes(name, sq), skv, True)
+             for name in ("fwd", "dq", "dkv")}
+    fallback = ""
+    if all(k.masked_pct == 100.0 for k in whole.values()):
+        fallback = "no block lies wholly inside the mask"
+    else:
+        for name, k in whole.items():
+            need = _vmem_estimate(name, _pad_to(T, k.sub_q), _pad_to(T_kv, k.sub_kv), k.grid,
+                                  k.sub_q, k.sub_kv, D, itemsize)
+            if not _pallas.fits_vmem(need):
+                fallback = (f"a whole head in the {name} kernel takes {need} bytes of VMEM, "
+                            f"over the {_pallas.VMEM_BLOCK_BUDGET}-byte budget")
+                break
+    if not fallback:
+        return Plan(whole["fwd"], whole["dq"], whole["dkv"], "")
+    return Plan(kernel_plan("fwd", bq, bq, bkv, False), kernel_plan("dq", bq, bq, bkv, False),
+                kernel_plan("dkv", bkv, lanes("dkv", bq), bkv, False), fallback)
 
 
 def _pad_seq(x, block):
@@ -206,56 +564,157 @@ def _pad_seq(x, block):
     return x
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def flash_attention(q, k, v, causal=True, block_q=512, block_kv=512, scale=None):
-    """q: (B, H, T, D); k/v: (B, Hkv, T, D) with H divisible by Hkv (GQA
-    native — no pre-expansion). Returns (B, H, T, D)."""
-    out, _ = _flash_fwd(q, k, v, causal, block_q, block_kv, scale)
-    return out
+_COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=_pallas.VMEM_LIMIT_BYTES)
 
 
-def _flash_call(q, k, v, causal, block_q, block_kv, scale):
+def _fit_rows(x, rows):
+    """``x`` (B, H, t, n) cut or zero-padded to ``rows`` along its third axis
+    (the forward's and the backward's sub-blocks may pad a length
+    differently)."""
+    t = x.shape[2]
+    if t >= rows:
+        return x[:, :, :rows]
+    return jnp.pad(x, ((0, 0), (0, 0), (0, rows - t), (0, 0)))
+
+
+def _fwd_call(q, k, v, *, kp, causal, scale, interpret):
+    """The forward kernel's call on unpadded operands: ``(out, lse)`` at the
+    padded length."""
     B, H, T, D = q.shape
-    Hkv, T_kv = k.shape[1], k.shape[2]
-    assert H % Hkv == 0, f"query heads {H} not a multiple of kv heads {Hkv}"
-    g = H // Hkv
-    scale = scale if scale is not None else 1.0 / (D**0.5)
-    block_q = min(block_q, T)
-    block_kv = min(block_kv, T_kv)
-
-    qp = _pad_seq(q, block_q)
-    kp = _pad_seq(k, block_kv)
-    vp = _pad_seq(v, block_kv)
-    Tq, Tkv = qp.shape[2], kp.shape[2]
-    grid = (B, H, Tq // block_q)
-
-    kernel = functools.partial(_fwd_kernel, scale=scale, block_kv=block_kv, causal=causal,
-                               q_len=T, kv_len=T_kv)
-    out, lse = pl.pallas_call(
-        kernel,
-        grid=grid,
+    T_kv, g = k.shape[2], H // k.shape[1]
+    qp, kp_, vp = _pad_seq(q, kp.sub_q), _pad_seq(k, kp.sub_kv), _pad_seq(v, kp.sub_kv)
+    Tq, Tkv, gq = qp.shape[2], kp_.shape[2], kp.grid
+    return pl.pallas_call(
+        functools.partial(_fwd_kernel, scale=scale, sub_q=kp.sub_q, sub_kv=kp.sub_kv,
+                          causal=causal, split=kp.split, q_len=T, kv_len=T_kv,
+                          one_step=gq == Tq),
+        grid=(B, H, Tq // gq),
         in_specs=[
-            pl.BlockSpec((1, 1, block_q, D), lambda b, h, i: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, gq, D), lambda b, h, i: (b, h, i, 0)),
             pl.BlockSpec((1, 1, Tkv, D), lambda b, h, i: (b, h // g, 0, 0)),
             pl.BlockSpec((1, 1, Tkv, D), lambda b, h, i: (b, h // g, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, block_q, D), lambda b, h, i: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, block_q, 1), lambda b, h, i: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, gq, D), lambda b, h, i: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, gq, 1), lambda b, h, i: (b, h, i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, H, Tq, D), q.dtype),
             jax.ShapeDtypeStruct((B, H, Tq, 1), jnp.float32),
         ],
-        interpret=_pallas.interpret(),
-    )(qp, kp, vp)
-    return out, lse, (qp, kp, vp, Tq, Tkv)
+        compiler_params=_COMPILER_PARAMS,
+        interpret=interpret,
+    )(qp, kp_, vp)
+
+
+def _bwd_operands(q, k, v, do, lse, delta, kp):
+    Tq = _pad_to(q.shape[2], kp.sub_q)
+    return (_pad_seq(q, kp.sub_q), _pad_seq(k, kp.sub_kv), _pad_seq(v, kp.sub_kv),
+            _fit_rows(do, Tq), _fit_rows(lse, Tq), _fit_rows(delta, Tq))
+
+
+def _dq_call(q, k, v, do, lse, delta, *, kp, causal, scale, interpret):
+    """The dq kernel's call: dq at q's length. lse / delta: (B, H, t, 1)."""
+    B, H, T, D = q.shape
+    T_kv, grp = k.shape[2], H // k.shape[1]
+    args = _bwd_operands(q, k, v, do, lse, delta, kp)
+    Tq, Tkv, gq = args[0].shape[2], args[1].shape[2], kp.grid
+    dq = pl.pallas_call(
+        functools.partial(_bwd_dq_kernel, scale=scale, sub_q=kp.sub_q, sub_kv=kp.sub_kv,
+                          causal=causal, split=kp.split, kv_len=T_kv, one_step=gq == Tq),
+        grid=(B, H, Tq // gq),
+        in_specs=[
+            pl.BlockSpec((1, 1, gq, D), lambda b, h, i: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, Tkv, D), lambda b, h, i: (b, h // grp, 0, 0)),
+            pl.BlockSpec((1, 1, Tkv, D), lambda b, h, i: (b, h // grp, 0, 0)),
+            pl.BlockSpec((1, 1, gq, D), lambda b, h, i: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, gq, 1), lambda b, h, i: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, gq, 1), lambda b, h, i: (b, h, i, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, 1, gq, D), lambda b, h, i: (b, h, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, H, Tq, D), q.dtype),
+        compiler_params=_COMPILER_PARAMS,
+        interpret=interpret,
+    )(*args)
+    return dq[:, :, :T]
+
+
+def _dkv_call(q, k, v, do, lse, delta, *, kp, causal, scale, interpret):
+    """The dk/dv kernel's call: ``(dk, dv)`` per QUERY head at k's length."""
+    B, H, T, D = q.shape
+    T_kv, grp = k.shape[2], H // k.shape[1]
+    args = _bwd_operands(q, k, v, do, lse, delta, kp)
+    Tq, Tkv, gkv = args[0].shape[2], args[1].shape[2], kp.grid
+    dk, dv = pl.pallas_call(
+        functools.partial(_bwd_dkv_kernel, scale=scale, sub_q=kp.sub_q, sub_kv=kp.sub_kv,
+                          causal=causal, split=kp.split, q_len=T, kv_len=T_kv,
+                          one_step=gkv == Tkv),
+        grid=(B, H, Tkv // gkv),
+        in_specs=[
+            pl.BlockSpec((1, 1, Tq, D), lambda b, h, j: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, gkv, D), lambda b, h, j: (b, h // grp, j, 0)),
+            pl.BlockSpec((1, 1, gkv, D), lambda b, h, j: (b, h // grp, j, 0)),
+            pl.BlockSpec((1, 1, Tq, D), lambda b, h, j: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, Tq, 1), lambda b, h, j: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, Tq, 1), lambda b, h, j: (b, h, 0, 0)),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, 1, gkv, D), lambda b, h, j: (b, h, j, 0)),
+            pl.BlockSpec((1, 1, gkv, D), lambda b, h, j: (b, h, j, 0)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((B, H, Tkv, D), k.dtype),
+            jax.ShapeDtypeStruct((B, H, Tkv, D), v.dtype),
+        ],
+        scratch_shapes=[pltpu.VMEM((Tq // kp.sub_q, 8, kp.sub_q), jnp.float32)],
+        compiler_params=_COMPILER_PARAMS,
+        interpret=interpret,
+    )(*args)
+    return dk[:, :, :T_kv], dv[:, :, :T_kv]
+
+
+_CALLS = {"fwd": _fwd_call, "dq": _dq_call, "dkv": _dkv_call}
+
+
+@functools.partial(jax.jit, static_argnums=0,
+                   static_argnames=("kp", "causal", "scale", "interpret"))
+def attn(kernel, *operands, kp, causal, scale, interpret):
+    """One of the three kernels' calls, jitted: every layer of a model makes
+    the same three calls, and unjitted each is traced and lowered again for
+    each layer (the written-out loops make that 0.36 s a layer at T = 1024
+    and 0.76 s at 2048 where the rolled ones took 0.12; 36 and 24 layers of
+    it were 9 and 16 s of a training cell's set-up). The device trace names a
+    Pallas call after the innermost jit around it, and this one keeps the
+    name the layer's ``attn`` scope gave the calls before it."""
+    return _CALLS[kernel](*operands, kp=kp, causal=causal, scale=scale, interpret=interpret)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def flash_attention(q, k, v, causal=True, block_q=512, block_kv=512, scale=None):
+    """q: (B, H, T, D); k/v: (B, Hkv, T, D) with H divisible by Hkv (GQA
+    native — no pre-expansion). Returns (B, H, T, D). ``block_q`` /
+    ``block_kv``: upper limits on a block of scores (:func:`plan`)."""
+    out, _ = _flash_fwd(q, k, v, causal, block_q, block_kv, scale)
+    return out
+
+
+def _flash_call(q, k, v, causal, block_q, block_kv, scale, plan_=None):
+    """``(out, lse)`` at the forward's padded length."""
+    H, T, D = q.shape[1:]
+    Hkv, T_kv = k.shape[1], k.shape[2]
+    assert H % Hkv == 0, f"query heads {H} not a multiple of kv heads {Hkv}"
+    if plan_ is None:
+        plan_ = plan(T, T_kv, D, jnp.dtype(q.dtype), causal, block_q, block_kv)
+        tally(plan_)
+    return attn("fwd", q, k, v, kp=plan_.fwd, causal=causal,
+                scale=scale if scale is not None else 1.0 / (D**0.5),
+                interpret=_pallas.interpret())
 
 
 def _flash_fwd(q, k, v, causal, block_q, block_kv, scale):
     from jax.ad_checkpoint import checkpoint_name
     T = q.shape[2]
-    out_p, lse, (qp, kp, vp, Tq, Tkv) = _flash_call(q, k, v, causal, block_q, block_kv, scale)
+    out_p, lse = _flash_call(q, k, v, causal, block_q, block_kv, scale)
     # name the kernel outputs so a remat policy can pin them: re-running the
     # forward kernel inside backward costs ~6% of step time under plain
     # dots_saveable (the custom-call is not a "dot"). Pair with
@@ -272,71 +731,28 @@ def _flash_bwd(causal, block_q, block_kv, scale, res, g_out):
     return _flash_bwd_impl(causal, block_q, block_kv, scale, res, g_out)
 
 
-def _flash_bwd_impl(causal, block_q, block_kv, scale, res, g_out, delta_shift=None):
+def _flash_bwd_impl(causal, block_q, block_kv, scale, res, g_out, delta_shift=None, plan_=None):
     q, k, v, out_p, lse = res
     B, H, T, D = q.shape
-    Hkv = k.shape[1]
+    Hkv, T_kv = k.shape[1], k.shape[2]
     grp = H // Hkv
-    T_kv_logical = k.shape[2]
-    scale_v = scale if scale is not None else 1.0 / (D**0.5)
-    bq = min(block_q, T)
-    bkv = min(block_kv, T_kv_logical)
-    qp = _pad_seq(q, bq)
-    kp = _pad_seq(k, bkv)
-    vp = _pad_seq(v, bkv)
-    Tq, Tkv = qp.shape[2], kp.shape[2]
+    if plan_ is None:
+        plan_ = plan(T, T_kv, D, jnp.dtype(q.dtype), causal, block_q, block_kv)
 
-    dop = jnp.pad(g_out, ((0, 0), (0, 0), (0, Tq - T), (0, 0))) if Tq != T else g_out
-
-    delta = jnp.einsum("bhtd,bhtd->bht", dop.astype(jnp.float32),
-                       out_p.astype(jnp.float32))[..., None]  # (B, H, Tq, 1)
+    # delta over the logical rows: the XLA reduction it was
+    delta = jnp.einsum("bhtd,bhtd->bht", g_out.astype(jnp.float32),
+                       out_p[:, :, :T].astype(jnp.float32))[..., None]  # (B, H, T, 1)
     if delta_shift is not None:
         delta = delta - delta_shift.astype(jnp.float32)
 
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, scale=scale_v, block_kv=bkv, causal=causal,
-                          kv_len=T_kv_logical),
-        grid=(B, H, Tq // bq),
-        in_specs=[
-            pl.BlockSpec((1, 1, bq, D), lambda b, h, i: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, Tkv, D), lambda b, h, i: (b, h // grp, 0, 0)),
-            pl.BlockSpec((1, 1, Tkv, D), lambda b, h, i: (b, h // grp, 0, 0)),
-            pl.BlockSpec((1, 1, bq, D), lambda b, h, i: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, bq, 1), lambda b, h, i: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, bq, 1), lambda b, h, i: (b, h, i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, bq, D), lambda b, h, i: (b, h, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, H, Tq, D), qp.dtype),
-        interpret=_pallas.interpret(),
-    )(qp, kp, vp, dop, lse, delta)
-
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, scale=scale_v, block_q=bq, causal=causal,
-                          q_len=T, kv_len=T_kv_logical),
-        grid=(B, H, Tkv // bkv),
-        in_specs=[
-            pl.BlockSpec((1, 1, Tq, D), lambda b, h, j: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, bkv, D), lambda b, h, j: (b, h // grp, j, 0)),
-            pl.BlockSpec((1, 1, bkv, D), lambda b, h, j: (b, h // grp, j, 0)),
-            pl.BlockSpec((1, 1, Tq, D), lambda b, h, j: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, Tq, 1), lambda b, h, j: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, Tq, 1), lambda b, h, j: (b, h, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, bkv, D), lambda b, h, j: (b, h, j, 0)),
-            pl.BlockSpec((1, 1, bkv, D), lambda b, h, j: (b, h, j, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, H, Tkv, D), kp.dtype),
-            jax.ShapeDtypeStruct((B, H, Tkv, D), vp.dtype),
-        ],
-        interpret=_pallas.interpret(),
-    )(qp, kp, vp, dop, lse, delta)
-
+    static = dict(causal=causal, scale=scale if scale is not None else 1.0 / (D**0.5),
+                  interpret=_pallas.interpret())
+    dq = attn("dq", q, k, v, g_out, lse, delta, kp=plan_.dq, **static)
+    dk, dv = attn("dkv", q, k, v, g_out, lse, delta, kp=plan_.dkv, **static)
     if grp > 1:  # group-sum per-query-head dk/dv back onto the shared KV head
-        dk = dk.reshape(B, Hkv, grp, Tkv, D).sum(axis=2)
-        dv = dv.reshape(B, Hkv, grp, Tkv, D).sum(axis=2)
-    return dq[:, :, :T], dk[:, :, :T_kv_logical], dv[:, :, :T_kv_logical]
+        dk = dk.reshape(B, Hkv, grp, T_kv, D).sum(axis=2)
+        dv = dv.reshape(B, Hkv, grp, T_kv, D).sum(axis=2)
+    return dq, dk, dv
 
 
 flash_attention.defvjp(_flash_fwd, _flash_bwd)
@@ -355,7 +771,7 @@ def flash_attention_with_lse(q, k, v, causal=True, block_q=512, block_kv=512, sc
 
 def _flash_lse_fwd(q, k, v, causal, block_q, block_kv, scale):
     T = q.shape[2]
-    out_p, lse, (qp, kp, vp, Tq, Tkv) = _flash_call(q, k, v, causal, block_q, block_kv, scale)
+    out_p, lse = _flash_call(q, k, v, causal, block_q, block_kv, scale)
     return (out_p[:, :, :T], lse[:, :, :T, 0]), (q, k, v, out_p, lse)
 
 
@@ -365,12 +781,8 @@ def _flash_lse_bwd(causal, block_q, block_kv, scale, res, g):
     is ds = p∘(dp − (delta − g_lse)) — so shifting delta by −g_lse reuses
     both kernels unchanged."""
     g_out, g_lse = g
-    out_p = res[3]
-    T = g_out.shape[2]
-    Tq = out_p.shape[2]
-    g_lse_p = jnp.pad(g_lse, ((0, 0), (0, 0), (0, Tq - T))) if Tq != T else g_lse
     return _flash_bwd_impl(causal, block_q, block_kv, scale, res, g_out,
-                           delta_shift=g_lse_p[..., None])
+                           delta_shift=g_lse[..., None])
 
 
 flash_attention_with_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)

@@ -1252,6 +1252,9 @@ class DeepSpeedEngine:
             if after != before:
                 self._compiled.clear()
         t0 = time.perf_counter() if self.telemetry.enabled else None
+        if t0 is not None:
+            from ..ops.pallas import flash_attention
+            flash_before = flash_attention.traced()
         if self.offload_optimizer:
             metrics = self._offload_train_batch(stacked)
         else:
@@ -1270,6 +1273,15 @@ class DeepSpeedEngine:
                 attrs={"path": "offload" if self.offload_optimizer else "fused",
                        "micro_batches": gas})
             self._emit_step_counters()
+            # a step that traced its program says what its flash kernels
+            # compute: scores against the mask's, and the masked body's share
+            plans = flash_attention.traced()[len(flash_before):]
+            if plans:
+                self.telemetry.gauges([
+                    ("kernels/flash_scores_computed_pct",
+                     sum(p.computed_pct for p in plans) / len(plans), self.global_samples),
+                    ("kernels/flash_scores_masked_pct",
+                     sum(p.masked_pct for p in plans) / len(plans), self.global_samples)])
         self.global_steps += 1
         self.global_samples += self.train_batch_size()
         self.micro_steps += gas
