@@ -494,9 +494,6 @@ class DeepSpeedInferenceConfig(DeepSpeedConfigModel):
     # TPU additions
     decode_block_kv = ConfigField(default=256, help="KV block streamed per decode-kernel step")
     mp_size = ConfigField(default=None, help="deprecated alias for tensor_parallel.tp_size")
-    fused_decode_block = ConfigField(
-        default=True, help="use the fused per-layer decode kernel (one pallas call per "
-        "layer: qkv->attention->o->mlp) when the int8 serving config allows it")
     telemetry = ConfigField(
         default=dict, help="unified telemetry sink section (same keys as the training "
         "config's 'telemetry': enabled/output_path/flush_interval/trace_format/"

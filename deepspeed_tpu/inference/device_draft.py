@@ -55,11 +55,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .kv_cache import slot_slice, slot_update
-
-def _scheduler():
-    """``inference/scheduler.py``, which imports this module: looked up late."""
-    from . import scheduler
-    return scheduler
+from .sync import _CARRIED, _Flight, _replicate_logits, _sampler
 
 
 def _merge_carried_draft(ids, lens, steps, block):
@@ -69,7 +65,7 @@ def _merge_carried_draft(ids, lens, steps, block):
     flight (:meth:`DeviceDraft._draft_fn`); the others keep what the host
     gave them."""
     tok, draft, head, step = block[-4:]
-    carried = ids[:, 0] == _scheduler()._CARRIED
+    carried = ids[:, 0] == _CARRIED
     ids = ids.at[:, 0].set(jnp.where(carried, tok, ids[:, 0]))
     ids = ids.at[:, 1].set(jnp.where(carried, draft, ids[:, 1]))
     return ids, jnp.where(carried, head, lens), jnp.where(carried, step, steps)
@@ -98,46 +94,33 @@ class DeviceDraft:
         replicated, merged or not, so that a step program is built once."""
         put = lambda x: jax.device_put(x, self._ids_sharding)
         dev = put(ids), put(lens), put(steps)
-        if self._flight is not None and (ids[:, 0] == _scheduler()._CARRIED).any():
+        if self._flight is not None and (ids[:, 0] == _CARRIED).any():
             dev = self._draft_merge(*dev, self._flight.out[0])
         return dev
 
-    def _draft_rows(self, width):
-        """The decode rows of the next launch with their ids block's first two
-        columns, spans (2 a live row) and write heads."""
-        N = self.cache.num_slots
-        live, col0 = self._live_rows()
-        carried = _scheduler()._CARRIED
-        ids = np.zeros((N, width), np.int32)
-        spans = np.zeros(N, np.int32)
-        lens = np.zeros(N, np.int32)
-        for (slot, req), tok in zip(live, col0):
-            ids[slot, 0] = tok
-            ids[slot, 1] = 0 if tok == carried else req.draft
-            spans[slot] = 2
-            lens[slot] = self.cache.lengths[slot]
-        return live, ids, spans, lens
+    def _draft_rows(self):
+        """The decode rows of the next launch and each one's second column:
+        its draft, as the last landing left it, or nothing where the row is
+        carried (the merge fills it in beside the token)."""
+        live = self._live_rows()
+        return live, [(0 if r.inflight else r.draft, ) for _, r in live]
 
     def _draft_decode_step(self):
         """Launch a pure decode sync of ``steps_per_sync`` verify-and-draft
         steps. Returns the flight, or None when every active row may end
         inside the sync in flight."""
         with self._span("sched/assemble"):
-            live, ids, spans, lens = self._draft_rows(2)
+            live, drafts = self._draft_rows()
             if not live:
                 return None
-            (seeds, steps, flags, temps, topks, topps, sampling,
-             collect) = self._gather_sampling(live)
+            ops = self._assemble(live, 2, more=drafts)
             K = self.steps_per_sync
-            fn = self._draft_fn(sampling, collect, K, 2)
-            d_ids, d_lens, d_steps = self._draft_inputs(ids, lens, steps)
-            args = (self.engine.params, self.cache.pool, d_ids, d_lens, jnp.asarray(spans),
-                    jnp.asarray(seeds), d_steps, jnp.asarray(flags), jnp.asarray(temps),
-                    jnp.asarray(topks), jnp.asarray(topps))
-        out = self._dispatch(fn, args, args, spans, lens)
+            fn = self._draft_fn(ops.sampling, ops.collect, K, 2)
+            args = self._step_args(ops, drafts=True)
+        out = self._dispatch(fn, args, ops.spans, ops.lens)
         self.cache.pool = out[0]
         self._advance(live, 2 * K)
-        return _scheduler()._Flight(out[1:], K, collect, live)
+        return _Flight(out[1:], K, ops.collect, live)
 
     def _draft_chunk_step(self):
         """Launch a chunk sync: the decode rows' verify-and-draft step beside
@@ -152,35 +135,24 @@ class DeviceDraft:
         take = min(C, L - pf.pos)
         final = pf.pos + take >= L
         with self._span("sched/assemble"):
-            live, ids, spans, lens = self._draft_rows(C)
-            (seeds, steps, flags, temps, topks, topps, sampling,
-             collect) = self._gather_sampling(live)
-            sampling = sampling or preq.do_sample
-            collect = collect or preq.collect_logits
-            ids[ps, :take] = preq.prompt[pf.pos:pf.pos + take]
-            lens[ps] = self.cache.lengths[ps]
-            seeds[ps] = preq.seed  # steps[ps] stays 0: the chunk samples token 0
-            flags[ps] = preq.do_sample
-            temps[ps] = preq.temperature
-            topks[ps] = preq.top_k
-            topps[ps] = preq.top_p
+            live, drafts = self._draft_rows()
+            ops = self._assemble(live, C, more=drafts, prefill=(preq, pf.pos, take))
+            # the counters see the chunk's take; the program finds the chunk
+            # by ``chunk_ops``, and its row's span is 0
+            counted = ops.spans.copy()
+            ops.spans[ps] = 0
             K = self.steps_per_sync if (live or final or any(
                 r.inflight for r in self.active.values())) else 1
             # the token behind the chunk, which closes its last pair: the next
             # chunk's first (a final chunk's is sampled in the program)
             chunk_ops = np.asarray([ps, take, int(final),
                                     0 if final else preq.prompt[pf.pos + take]], np.int32)
-            fn = self._draft_fn(sampling, collect, K, C)
-            d_ids, d_lens, d_steps = self._draft_inputs(ids, lens, steps)
-            args = (self.engine.params, self.cache.pool, d_ids, d_lens, jnp.asarray(spans),
-                    jnp.asarray(seeds), d_steps, jnp.asarray(flags), jnp.asarray(temps),
-                    jnp.asarray(topks), jnp.asarray(topps), jnp.asarray(chunk_ops))
-            counted = spans.copy()
-            counted[ps] = take
-        out = self._dispatch(fn, args, args, counted, lens, chunk=(ps, final))
+            fn = self._draft_fn(ops.sampling, ops.collect, K, C)
+            args = self._step_args(ops, extra=(jnp.asarray(chunk_ops), ), drafts=True)
+        out = self._dispatch(fn, args, counted, ops.lens, chunk=(ps, final))
         self.cache.pool = out[0]
         self._advance(live, 2 * K)
-        fl = _scheduler()._Flight(out[1:], K, collect, live, (preq, pf.pos, take, final))
+        fl = _Flight(out[1:], K, ops.collect, live, (preq, pf.pos, take, final))
         pf.pos += take
         if final:
             # booked at the most the row can do: token 0, then 2 a substep
@@ -190,22 +162,6 @@ class DeviceDraft:
         else:
             self.cache.lengths[ps] = pf.pos
         return fl
-
-    def _count_draft_dispatch(self, key, spans, lens, chunk):
-        """:meth:`DecodeScheduler._dispatch`'s counters (the sink is on) for a
-        verify-and-draft program: the rows the STACK's forwards compute and
-        the live ones among them; the attended keys as the first forward's
-        span gives them, each later step's as one column's (it has two, and
-        advances by 1 or 2: the host cannot know which)."""
-        width, ksteps = int(key[3]), int(key[4])
-        N = self.cache.num_slots
-        tel = self.telemetry
-        first = 2 * N + (width if chunk is not None else 0)
-        tel.counter("serving/step_rows_run", first + 2 * N * (ksteps - 1))
-        stepping = int(np.count_nonzero(spans == 2)) + int(chunk is not None and chunk[1])
-        tel.counter("serving/step_rows_live", int(spans.sum()) + 2 * stepping * (ksteps - 1))
-        self._count_attention_rows(lens, spans, ksteps, chunk)
-        self._count_attention_keys(lens, spans, 2, ksteps, False, chunk, False)
 
     # ------------------------------------------------------------------ landing
     def _land_draft(self, fl):
@@ -317,20 +273,13 @@ class DeviceDraft:
         key = ("draft", sampling, collect, width, ksteps) + (("void", ) if keeps_void else ())
 
         def build():
-            _replicate_logits = _scheduler()._replicate_logits
-            _sample_slot = _scheduler()._sample_slot
             model = self.engine.module
             K = ksteps
             tp = self._shard_deg
             stats = self._moe_stats
             choice = collect and self._moe
 
-            def sample(l2, seeds, steps, flags, temps, topks, topps):
-                with jax.named_scope("sample"):
-                    if sampling:
-                        return jax.vmap(_sample_slot)(seeds, steps, l2, flags,
-                                                      temps, topks, topps)
-                    return jnp.argmax(l2, axis=-1).astype(jnp.int32)
+            sample = _sampler(sampling)
 
             def stack_and_module(params, pool, ids, pos, heads, spans, next_ids):
                 """The stack over ``ids``, then the module over its output and

@@ -223,8 +223,7 @@ class InferenceEngine:
         # silent boolean chain. Only meaningful for int8 configs that asked
         # for the fast path — an fp engine stays quiet.
         self._fused_decode_note = None
-        if (self._int8_weights and cfg.fused_decode_block
-                and hasattr(self.model_config, "int8_weights")):
+        if self._int8_weights and hasattr(self.model_config, "int8_weights"):
             elig = self._fused_decode_eligible()
             if not elig:
                 self._fused_decode_note = "; ".join(elig.reasons)
@@ -329,8 +328,7 @@ class InferenceEngine:
                 desc += f" expert_offload=on ({R}/{n_experts} resident)"
         if getattr(self, "_fused_decode_note", None):
             desc += f" fused_decode=off ({self._fused_decode_note})"
-        elif (self._int8_weights and self._config.fused_decode_block
-              and hasattr(self.model_config, "int8_weights")):
+        elif self._int8_weights and hasattr(self.model_config, "int8_weights"):
             desc += " fused_decode=on"
         return desc
 
@@ -617,8 +615,6 @@ class InferenceEngine:
         if tp_eff != 1:
             reasons.append(f"tensor={tp_eff}: the fused kernels are opaque "
                            f"to GSPMD; tp decodes per-projection")
-        if not self._config.fused_decode_block:
-            reasons.append("fused_decode_block=False in config")
         return FusedDecodeEligibility(reasons)
 
     def _fast_tree(self):

@@ -203,22 +203,24 @@ layer's, or a plain attention layer's with its keys rotated at rest) or with a l
 that declares nothing (a gated memory unit; a cross-attention layer that
 reads the rows of the full layer below it) is such a pool: same substep-spans
 operand (a ring does not forgive a garbage substep either), same refusals,
-same bypass counter. Gauge ``serving/window_bytes_per_slot``; counters
-``serving/attn_rows_window`` / ``serving/attn_rows_shared`` (the K/V positions
-a sync's attention has to read, from the host's lengths and spans:
-:meth:`DecodeScheduler._count_attention_rows`) and
-``serving/cross_decoder_rows_unread``. See ``benchmarks/SERVING.md`` ("Ring
-rows, shared rows and SSM state").
+same bypass counter. Gauge ``serving/window_bytes_per_slot``. See
+``benchmarks/SERVING.md`` ("Ring rows, shared rows and SSM state").
 
 **One-sublayer blocks** (``nemotron_h``: a Mamba-2 mixer, an expert FFN or
 attention alone in each layer): a Mamba-2 layer declares ``"state"``, an
 attention layer ``"rows"``, an FFN-only layer nothing, and holds nothing; the
-pool is a state pool with the same operand, refusals and bypass counter.
-Counters ``serving/ssd_state_updates`` and ``serving/ssd_chunk_tokens``: the
-one-token updates and the chunk positions its Mamba-2 layers are required to
-run (:meth:`DecodeScheduler._count_state_updates`), beside the ``serving/moe_*``
-counters of its expert layers. See ``benchmarks/SERVING.md`` ("One-sublayer
-blocks").
+pool is a state pool with the same operand, refusals and bypass counter. See
+``benchmarks/SERVING.md`` ("One-sublayer blocks").
+
+**Required work**: what a dispatched step program HAS to do (the rows its
+forwards compute and the live ones among them, the K/V positions and keys its
+attention reads, its Mamba-2 layers' state updates), which the serving
+benchmark's rooflines and live shares divide by, is counted from the program's
+key and the host's lengths and spans by ONE observer of a dispatch,
+:class:`~deepspeed_tpu.inference.required_work.RequiredWork`, built only where
+the sink is on. Its module lists the ``serving/step_rows_*``, ``attn_rows_*``,
+``attn_keys_*``, ``ssd_*`` and ``cross_decoder_rows_unread`` counters; a model
+configuration that needs another adds it there, not here.
 
 Telemetry (PR-1 sink): gauges ``serving/slot_occupancy``,
 ``serving/batch_efficiency``, ``serving/kv_token_utilization``,
@@ -232,14 +234,7 @@ Telemetry (PR-1 sink): gauges ``serving/slot_occupancy``,
 hierarchical tier, ``serving/spec_steps``,
 ``serving/spec_draft_tokens``, ``serving/spec_accepted_tokens``;
 histograms ``serving/ttft_ms``, ``serving/step_ms``,
-``serving/tokens_per_step``, ``serving/spec_tokens_per_step``; counters
-``serving/step_rows_run`` (rows a step program's forwards compute) and
-``serving/step_rows_live`` (those among them inside a row's span), per
-dispatch, and for every model whose attention runs the paged decode kernel
-``serving/attn_keys_live`` (the keys inside the rows' attended windows, over
-slots, forwards and layers) and ``serving/attn_keys_walked`` (the same
-rounded out to the blocks the kernel's walk fetches:
-:meth:`DecodeScheduler._count_attention_keys`). Multi-LoRA adds
+``serving/tokens_per_step``, ``serving/spec_tokens_per_step``. Multi-LoRA adds
 ``serving/adapter_{loads,evicts}`` + per-adapter
 ``serving/adapter/<id>/{loads,evicts,requests,tokens}`` (256-label cap),
 ``serving/adapter_swap_ms``, ``serving/adapter_kv_invalidated_tokens``, and
@@ -261,6 +256,8 @@ from .config import check_prefill_chunk
 from .device_draft import DeviceDraft
 from .engine import _round_up
 from .kv_cache import RadixPrefixCache, SlotKVCache, copy_slot, slot_slice, slot_update
+from .required_work import RequiredWork
+from .sync import _CARRIED, _Flight, _Operands, _merge_carried, _replicate_logits, _sampler
 from .speculative import PromptLookupDrafter
 
 # Guards COMPILED-PROGRAM CACHE INSERTION only (replica sets share one
@@ -328,45 +325,6 @@ def _first_forward_live_rows(forward, pool, ids, lengths, spans):
         own = jax.lax.dynamic_slice_in_dim(ch, ps, 1, axis=1)
         ch = jax.lax.dynamic_update_slice_in_dim(ch, jnp.where(wide[ps], chc, own), ps, axis=1)
     return last, pool, cnt, ch
-
-
-def _replicate_logits(l, tp_size):
-    """Gather vocab-sharded step logits to replicated BEFORE sampling
-    (tp>1 only): the gather is exact concatenation, and `jax.random`
-    bit-generation is NOT sharding-invariant on every jax version — a
-    categorical draw over a vocab-sharded operand can partition the
-    counter differently and change the sample. Replicated operands make
-    the sampling math byte-identical to the tp=1 program's. (N, V) per
-    sync is noise next to the model forward."""
-    if tp_size > 1:
-        from jax.sharding import PartitionSpec
-        l = jax.lax.with_sharding_constraint(
-            l, jax.sharding.NamedSharding(dist.get_mesh(),
-                                          PartitionSpec(*([None] * l.ndim))))
-    return l
-
-
-def _sample_slot(seed, step, logits, do_sample, temperature, top_k, top_p):
-    """Per-slot token choice with fully-dynamic sampling params (one compiled
-    program serves any mix of greedy/sampled requests). ``logits``: (V,)
-    f32. top-k uses a dynamic kth-largest threshold (sort is static-shape);
-    top-p then keeps the smallest prefix with cumulative prob >= top_p of
-    the top-k-FILTERED distribution (same sequential-filter semantics as
-    the static path's ``_sample_tokens``)."""
-    V = logits.shape[0]
-    greedy = jnp.argmax(logits).astype(jnp.int32)
-    x = logits / jnp.maximum(temperature, 1e-6)
-    kth = jnp.sort(x)[::-1][jnp.clip(top_k - 1, 0, V - 1)]
-    x = jnp.where((top_k > 0) & (x < kth), -jnp.inf, x)
-    desc = jnp.sort(x)[::-1]  # re-sort AFTER top-k: nucleus over the filtered dist
-    probs = jax.nn.softmax(desc)
-    cum = jnp.cumsum(probs)
-    keep = jnp.concatenate([jnp.ones((1, ), bool), cum[:-1] < top_p])
-    threshold = jnp.min(jnp.where(keep, desc, jnp.inf))
-    x = jnp.where((top_p < 1.0) & (x < threshold), -jnp.inf, x)
-    key = jax.random.fold_in(jax.random.key(seed), step)
-    sampled = jax.random.categorical(key, x).astype(jnp.int32)
-    return jnp.where(do_sample, sampled, greedy)
 
 
 class _ExpertOverflow(Exception):
@@ -526,45 +484,6 @@ class _PrefillState:
         # run at the seq-parallel chunk width (sharded over the seq mesh
         # axis when it has more than one device)
         self.seq_parallel = False
-
-
-class _Flight:
-    """A sync that was launched and has not landed: what the landing needs
-    to fetch its block and deliver it. ``out`` is the step program's result
-    behind the pool (tokens, then logits, routing choice and MoE stats where
-    the program returns them), still on the device; ``rows`` the decode rows
-    it advances ``K`` tokens each; ``chunk`` the prefill row's ``(request,
-    pos, take, final)`` on a chunk sync; ``t0`` when its iteration began."""
-
-    __slots__ = ("out", "K", "collect", "rows", "chunk", "t0")
-
-    def __init__(self, out, K, collect, rows, chunk=None):
-        self.out = out
-        self.K = K
-        self.collect = collect
-        self.rows = rows
-        self.chunk = chunk
-        self.t0 = 0.0
-
-    @property
-    def final(self):
-        """Whether this sync carries a prompt's last chunk."""
-        return self.chunk is not None and self.chunk[3]
-
-
-# the id of a row whose last token is still on the device: column 0 of the
-# host's ids block says so with this, and :func:`_merge_carried` fills it in
-_CARRIED = -1
-
-
-def _merge_carried(ids, toks):
-    """The ids block of a sync launched ahead: a row flagged ``_CARRIED`` in
-    column 0 takes its id from the last row of ``toks``, the (K, num_slots)
-    token block of the sync in flight; prompt tokens and the ids the host
-    knew stay as they are. Outside the step programs, which take the same
-    operands as when the host fed every id."""
-    col = ids[:, 0]
-    return ids.at[:, 0].set(jnp.where(col == _CARRIED, toks[-1], col))
 
 
 class DecodeScheduler(DeviceDraft):
@@ -751,17 +670,6 @@ class DecodeScheduler(DeviceDraft):
                                       ("ring rows", "ring" in declared),
                                       ("rows that layers share", shared)) if on]
         self._state_pool = bool(held)
-        # (windowed layers, their window, layers that read the shared rows):
-        # what the host's counters of attended rows multiply by
-        cfg = model.cfg
-        layers = range(cfg.num_layers) if (getattr(cfg, "carries_across_layers", False)
-                                           or any(getattr(cfg, "layer_windows", ()))) else ()
-        windows = [cfg.layer_window(i) for i in layers]
-        self._attn_layers = (sum(w > 0 for w in windows), max(windows, default=0),
-                             sum(not w and cfg.layer_type(i) in ("diff_attention", "cross_attention")
-                                 for i, w in zip(layers, windows)))
-        # Mamba-2 layers: what the host's counters of state updates multiply by
-        self._ssd_layers = sum(mixer_of(i)[0] == "mamba2" for i in range(cfg.num_layers))
         if self._state_pool:
             unsupported = [name for name, on in (
                 ("speculative verify (spec_tokens): a recurrent state cannot roll back the "
@@ -784,8 +692,6 @@ class DecodeScheduler(DeviceDraft):
         self.cache = SlotKVCache(engine._init_cache(int(num_slots), S, kv_dtype=kv_arg),
                                  int(num_slots), S, page_size=min(block, S),
                                  max_extents=me, kinds=kinds)
-        self._attn_walks = self._attention_walks(model, tp_ax)
-        self._walk_blocks = {}
         # self-speculative decoding: spec_tokens drafted columns verified
         # per pure-decode sync (clamped so a full verify block always fits
         # one slot alongside at least one row of decode headroom)
@@ -988,6 +894,7 @@ class DecodeScheduler(DeviceDraft):
         # Only built on an enabled sink — the disabled path allocates
         # nothing and every hook below gates on `self.capacity is None`.
         self.capacity = None
+        self._work = None  # the observer of required work (required_work.py)
         self._gap = None
         self._sync_seq = 0
         self._cap_sample = False
@@ -1008,6 +915,8 @@ class DecodeScheduler(DeviceDraft):
                 peak_hbm_bw=accel.peak_hbm_bandwidth(),
                 n_devices=n_dev,
                 sample_every=getattr(self.telemetry, "capacity_sample_every", 32))
+            self._work = RequiredWork(self.telemetry, model, self.cache, self.tp_size,
+                                      state_pool=self._state_pool, drafts=self._device_draft)
             # the account needs to know a program was built under a span
             compile_cache.listen()
             self._gap = HostGapTracker(self.telemetry,
@@ -2196,14 +2105,38 @@ class DecodeScheduler(DeviceDraft):
         pools = self.adapters.device_pools()
         return tuple((jnp.asarray(idx[b]), pools[b]) for b in buckets)
 
-    def _gather_sampling(self, live):
-        """Per-slot sampling-parameter rows for a compiled step program
-        (shared by the decode and fused-chunk paths — the bit-identity
-        contract between them rests on this assembly never diverging).
-        Returns (seeds, steps, flags, temps, topks, topps, sampling,
-        collect); ``steps`` is each row's ABSOLUTE step index, so results
-        are K/fused-invariant."""
+    def _live_rows(self):
+        """The decode rows of the next launch: active, not parked, and with
+        budget left once the tokens in flight have landed (a row that reaches
+        ``max_new_tokens`` inside the sync in flight is known to end there; it
+        stays in ``active`` until that sync lands and releases its slot)."""
+        return [(s, r) for s, r in sorted(self.active.items())
+                if s not in self._parked and len(r.out) + r.inflight < r.max_new_tokens]
+
+    def _assemble(self, rows, width, more=None, prefill=None):
+        """THE host block of one launch (:class:`_Operands`): the ``(num_slots,
+        width)`` ids, the lengths, the spans and the six per-slot
+        sampling-parameter rows of a compiled step program, with whether any
+        row samples or collects logits. Every launch is assembled here (the
+        bit-identity contract between the decode, chunk, verify and back-off
+        paths rests on this assembly being one), and
+        :meth:`_step_args` alone turns it into a program's arguments.
+
+        ``rows``: the decode rows, ``(slot, request)``. Column 0 is a row's
+        last token, or ``_CARRIED`` where tokens of it are in flight and the
+        last is still on the device (:func:`_merge_carried`); ``more[i]``: the
+        further columns of ``rows[i]`` (a drafter's proposals), which widen
+        its span; ``steps`` is each row's ABSOLUTE step index, so results are
+        K/fused-invariant. ``prefill``: ``(request, pos, take)`` of the prefill
+        lane's row, ``take`` prompt tokens from ``pos`` (its step stays 0: the
+        chunk samples token 0). Dead and cached rows keep span 0 and length 0:
+        their writes are dropped, and the paged kernel's KV-block walk stays
+        bounded by the longest LIVE row, not the longest retained prefix."""
         N = self.cache.num_slots
+        lengths = self.cache.lengths
+        ids = np.zeros((N, width), np.int32)
+        lens = np.zeros(N, np.int32)
+        spans = np.zeros(N, np.int32)
         seeds = np.zeros(N, np.uint32)
         steps = np.zeros(N, np.int32)
         flags = np.zeros(N, bool)
@@ -2212,7 +2145,14 @@ class DecodeScheduler(DeviceDraft):
         topps = np.ones(N, np.float32)
         sampling = False
         collect = False
-        for slot, req in live:
+        for i, (slot, req) in enumerate(rows):
+            ids[slot, 0] = _CARRIED if req.inflight else req.out[-1]
+            spans[slot] = 1
+            if more is not None:
+                cols = more[i]
+                ids[slot, 1:1 + len(cols)] = cols
+                spans[slot] += len(cols)
+            lens[slot] = lengths[slot]
             seeds[slot] = req.seed
             steps[slot] = len(req.out) + req.inflight  # prefill consumed step 0
             flags[slot] = req.do_sample
@@ -2221,19 +2161,47 @@ class DecodeScheduler(DeviceDraft):
             topps[slot] = req.top_p
             sampling = sampling or req.do_sample
             collect = collect or req.collect_logits
-        return seeds, steps, flags, temps, topks, topps, sampling, collect
+        if prefill is not None:
+            req, pos, take = prefill
+            ps = req.slot
+            ids[ps, :take] = req.prompt[pos:pos + take]
+            spans[ps] = take
+            lens[ps] = lengths[ps]  # prefix copy and/or earlier chunks
+            seeds[ps] = req.seed
+            flags[ps] = req.do_sample
+            temps[ps] = req.temperature
+            topks[ps] = req.top_k
+            topps[ps] = req.top_p
+            sampling = sampling or req.do_sample
+            collect = collect or req.collect_logits
+        return _Operands(ids, lens, spans, seeds, steps, flags, temps, topks, topps,
+                         sampling, collect)
 
-    def _live_rows(self):
-        """The decode rows of the next launch, with the ids block's column
-        0 for them: active, not parked, and with budget left once the
-        tokens in flight have landed (a row that reaches ``max_new_tokens``
-        inside the sync in flight is known to end there; it stays in
-        ``active`` until that sync lands and releases its slot). A row with
-        tokens in flight has its last token on the device
-        (``_CARRIED``: :func:`_merge_carried`); the others' the host knows."""
-        live = [(s, r) for s, r in sorted(self.active.items())
-                if s not in self._parked and len(r.out) + r.inflight < r.max_new_tokens]
-        return live, [(_CARRIED if r.inflight else r.out[-1]) for _, r in live]
+    def _step_args(self, ops, eo=None, held=None, extra=(), drafts=False):
+        """A step program's arguments from an assembled block, in THE canonical
+        order: ``(params, pool, ids, lens, spans, seeds, steps, flags, temps,
+        topks, topps)``, then the extent operands (``eo``:
+        :meth:`_ext_operands`, or None), then a state pool's substep spans
+        (:meth:`_substep_spans`, ``held``: the slot of a prefill row whose
+        chunk is not its last), then the caller's ``extra``. The only place a
+        launch's operands are put on the device, a warm-up's and a served
+        one's alike, so the two cannot differ in an operand's dtype or
+        sharding. ``drafts``: the device drafter's programs, whose lengths and
+        steps may be carried on the device like the ids
+        (``device_draft.py: _draft_inputs``) and which take no substep spans."""
+        ids, lens, spans, seeds, steps, flags, temps, topks, topps = ops[:9]
+        if drafts:
+            ids, lens, steps = self._draft_inputs(ids, lens, steps)
+            tail = ()
+        else:
+            ids, lens, steps = self._device_ids(ids), jnp.asarray(lens), jnp.asarray(steps)
+            tail = self._substep_spans(spans, held)
+        args = (self.engine.params, self.cache.pool, ids, lens, jnp.asarray(spans),
+                jnp.asarray(seeds), steps, jnp.asarray(flags), jnp.asarray(temps),
+                jnp.asarray(topks), jnp.asarray(topps))
+        if eo is not None:
+            args += tuple(jnp.asarray(x) for x in eo)
+        return args + tail + tuple(extra)
 
     def _device_ids(self, ids):
         """The host's ids block on the device, its carried rows filled in
@@ -2360,50 +2328,31 @@ class DecodeScheduler(DeviceDraft):
             self.migrate_hook(self, preq)  # True: migrated out, owned elsewhere
         return delivered
 
-    def _dispatch(self, fn, call_args, step_args, spans, lens, chunk=None):
+    def _dispatch(self, fn, call_args, spans, lens, chunk=None):
         """Hand ONE compiled program to the device, under ``sched/dispatch``
         (whose start closes the open host gap: the device stops being idle
-        the moment the dispatch is enqueued), and count the rows its
-        forwards compute beside the live ones among them, and the keys its
-        attention reads (``spans``, ``lens``: the host's copies of the spans
-        at ``step_args[4]`` and the lengths at ``step_args[3]``; ``chunk``:
-        ``(slot, final)`` of a chunk sync's prefill row). On a sampled sync,
-        fences the dispatch —
+        the moment the dispatch is enqueued). With the sink on, the observer
+        of required work hears of it first (``required_work.py``; ``spans``,
+        ``lens``: the host's copies of the spans at ``call_args[4]`` and the
+        lengths at ``call_args[3]``; ``chunk``: ``(slot, final)`` of a chunk
+        sync's prefill row). On a sampled sync, fences the dispatch —
         ``block_until_ready`` on the input pool (drain outstanding work) and
         on the result, each under ``sched/fence`` (the pump blocked on the
         device: ``wait`` in its account) — so the measured wall time is this
         program's device time alone. The fence touches only arrays the
-        pipeline already owns: zero new XLA programs. ``step_args`` is the
-        canonical step-argument tuple (pool at [1], lens at [3], spans at
-        [4]) used for batch-shape recovery; ``call_args`` is what the program
-        actually takes."""
+        pipeline already owns: zero new XLA programs."""
         cap = self.capacity
-        if cap is not None and self._device_draft:
+        if cap is not None:  # the sink is on
             key = cap.key_for(fn)
-            width, ksteps = program_shape(key)
-            split, ext_walk = False, False
-            self._count_draft_dispatch(key, spans, lens, chunk)
-        elif cap is not None:  # the sink is on
-            key = cap.key_for(fn)
-            width, ksteps = program_shape(key)
             split = self._splits_chunk(key)
-            N = self.cache.num_slots
-            self.telemetry.counter("serving/step_rows_run",
-                                   (N + width if split else N * width) + N * (ksteps - 1))
-            self.telemetry.counter("serving/step_rows_live", int(spans.sum())
-                                   + int(np.count_nonzero(spans)) * (ksteps - 1))
-            self._count_attention_rows(lens, spans, ksteps, chunk)
-            self._count_state_updates(spans, ksteps, chunk)
-            # the programs whose attention walks a row's extent chain
-            ext_walk = key is not None and key[0] in ("fused_ext", "fused_seqp")
-            self._count_attention_keys(lens, spans, width, ksteps, split, chunk, ext_walk)
+            self._work.dispatched(key, split, spans, lens, chunk)
         if cap is None or not self._cap_sample:
             with self._span("sched/dispatch"), self.engine.mesh:
                 return self._run_program(fn, call_args)
         # one fenced dispatch per sampled sync, even across MoE replays
         self._cap_sample = False
         with self._span("sched/fence"):
-            jax.block_until_ready(step_args[1])
+            jax.block_until_ready(call_args[1])
         t0 = time.perf_counter()
         with self._span("sched/dispatch"), self.engine.mesh:
             out = self._run_program(fn, call_args)
@@ -2411,10 +2360,11 @@ class DecodeScheduler(DeviceDraft):
             jax.block_until_ready(out)
         dur = time.perf_counter() - t0
         if key is not None:
+            width, ksteps = program_shape(key)
             live_ctx = lens[spans > 0] if spans.shape == lens.shape else lens
             # the extent-walk kernels DMA every extent's pool column per KV
             # block, so their KV traffic prices at max_extents x contiguous
-            kv_mult = self.cache.max_extents if ext_walk else 1
+            kv_mult = self.cache.max_extents if key[0] in ("fused_ext", "fused_seqp") else 1
             cap.observe_dispatch(key, dur, live_ctx, width, ksteps,
                                  kv_mult=kv_mult, split=split)
         return out
@@ -2471,177 +2421,6 @@ class DecodeScheduler(DeviceDraft):
             sub[held] = 0
         return (jnp.asarray(sub), )
 
-    def _count_state_updates(self, spans, ksteps, chunk=None):
-        """With the sink on, for a model with Mamba-2 layers: the work a
-        sync's state layers are REQUIRED to do, from the host's copy of the
-        spans, summed over forwards and Mamba-2 layers:
-        ``serving/ssd_state_updates``, the one-token updates (a live decode
-        row in the first forward and in every substep it steps in), and
-        ``serving/ssd_chunk_tokens``, the positions of a prefill chunk (a
-        row's span past 1). ``chunk``: ``(slot, final)`` of a sync's prefill
-        row: unless its chunk is final it stands still in the substeps."""
-        if not (self.telemetry.enabled and self._ssd_layers):
-            return
-        live = spans > 0
-        stepping = int(np.count_nonzero(live))
-        if chunk is not None and not chunk[1] and live[chunk[0]]:
-            stepping -= 1
-        updates = int(np.count_nonzero(spans == 1)) + stepping * (ksteps - 1)
-        self.telemetry.counter("serving/ssd_state_updates", self._ssd_layers * updates)
-        self.telemetry.counter("serving/ssd_chunk_tokens",
-                               self._ssd_layers * int(spans[spans > 1].sum()))
-
-    def _count_attention_rows(self, lens, spans, ksteps, chunk=None):
-        """With the sink on, for a model with windowed or shared-row layers:
-        the K/V positions a sync's attention has to read, from the host's
-        copies of the lengths and spans, summed over slots, forwards and
-        layers. A forward that leaves a row at ``n`` positions after ``s``
-        live columns reads ``n`` shared rows a reading layer (the full layer
-        and every cross layer) and ``min(n, window + s - 1)`` ring rows a
-        windowed layer, each once whatever the number of queries.
-        ``chunk``: ``(slot, final)`` of a sync's prefill row: unless its
-        chunk is final it stands still in the substeps. Also counts the
-        chunk's positions that run through the cross-decoder layers and
-        whose output nothing reads (all but a prompt's last)."""
-        tel = self.telemetry
-        n_win, window, n_shared = self._attn_layers
-        if not (tel.enabled and (n_win or n_shared)):
-            return
-        live = spans > 0
-        after, sp = (lens + spans)[live].astype(np.int64), spans[live]
-        shared, ring = int(after.sum()), int(np.minimum(after, window + sp - 1).sum())
-        if chunk is not None and not chunk[1]:
-            live[chunk[0]] = False
-        base = (lens + spans)[live].astype(np.int64)
-        for k in range(1, ksteps):
-            shared += int((base + k).sum())
-            ring += int(np.minimum(base + k, window).sum())
-        tel.counter("serving/attn_rows_shared", n_shared * shared)
-        tel.counter("serving/attn_rows_window", n_win * ring)
-        if chunk is not None and n_shared:
-            tel.counter("serving/cross_decoder_rows_unread",
-                        int(spans[chunk[0]]) - int(chunk[1]))
-
-    @staticmethod
-    def _attention_walks(model, tp):
-        """The layers whose attention over the slot pool runs the paged
-        decode kernel (``ops/pallas/decode_attention.py``), grouped by what
-        its walk depends on: ``(layers, ring rows, window, (kv heads, query
-        heads a kv head, head size, rows a slot, q dtype, K/V dtype,
-        quantized, packed))`` for the layers that read rows that grow (ring
-        rows and window 0; a cross-attention layer reads the full layer's),
-        a windowed layer's ring (the ring's rows), and a plain layer with a
-        sliding window (which raises a column's first key). The geometry is
-        the pool leaf's, as the model's code hands it to the kernel. Empty
-        where no layer does: XLA's attention, ALiBi, a latent pool."""
-        from ..models.transformer import kv_packs
-        cfg = model.cfg
-        if (getattr(cfg, "attention_impl", "xla") != "flash" or getattr(cfg, "latent_width", 0)
-                or getattr(cfg, "pos_embedding", None) == "alibi"
-                or not hasattr(model, "cache_spec")):
-            return []
-        carries = getattr(cfg, "carries_across_layers", False)
-        if carries and tp > 1:
-            return []
-        shard = tp if (tp > 1 and getattr(cfg, "bitwise_tp", False)
-                       and cfg.kv_heads % tp == 0 and cfg.num_heads % tp == 0) else 1
-        groups = collections.Counter()
-        for i in range(cfg.num_layers):
-            kind = cfg.layer_type(i) if hasattr(cfg, "layer_type") else "full_attention"
-            mixer = cfg.layer_parts(i)[0] if hasattr(cfg, "layer_parts") else kind
-            window = cfg.layer_window(i) if hasattr(cfg, "layer_window") else 0
-            if carries:
-                if kind == "diff_attention" and window:
-                    if cfg.ring_rows(i) != window:
-                        continue  # such a ring is read through XLA's attention
-                    groups[(window, 0, cfg.kv_heads // 2, 2 * cfg.head_size, False)] += 1
-                elif kind in ("diff_attention", "cross_attention"):
-                    groups[(0, 0, cfg.kv_heads // 2, 2 * cfg.head_size, False)] += 1
-            elif mixer == "full_attention" and window and getattr(cfg, "layer_windows", ()):
-                if cfg.ring_rows(i) == window and shard == 1:  # else XLA reads the ring
-                    groups[(window, 0, cfg.kv_heads, cfg.head_size, False)] += 1
-            elif mixer == "full_attention":
-                groups[(0, window, cfg.kv_heads // shard, cfg.head_size,
-                        kv_packs(cfg.head_size))] += 1
-        if getattr(cfg, "mtp_layers", 0):  # the module's own rows, read while it drafts
-            groups[(0, 0, cfg.kv_heads // shard, cfg.head_size,
-                    kv_packs(cfg.head_size))] += cfg.mtp_layers
-        return [(n, ring, window, (nkv, cfg.num_heads // shard // nkv, D, packed))
-                for (ring, window, nkv, D, packed), n in groups.items()]
-
-    def _walk_block(self, group, span, ext):
-        """(columns a kernel call takes of the span, keys a block, blocks a
-        row's extents hold) of the kernel's walk for a layer group at a query
-        span: the kernel module's own choice."""
-        key = (group, span, ext)
-        if key not in self._walk_blocks:
-            from ..ops.pallas.decode_attention import span_tile, walk_block_kv
-            _, ring, _, (nkv, rep, D, packed) = self._attn_walks[group]
-            rows = ring or self.max_len
-            cfg = self.engine.module.cfg
-            kv_dtype = jnp.int8 if self.kv_quantized else jax.tree_util.tree_leaves(
-                self.cache.pool)[0].dtype
-            shape = (D, rows, cfg.decode_block_kv, cfg.dtype, kv_dtype, self.kv_quantized,
-                     packed)
-            tile = span_tile(rep, span, *shape)
-            bkv = walk_block_kv(nkv, rep * tile, *shape)
-            self._walk_blocks[key] = (tile, bkv,
-                                      (self.cache.max_extents if ext else 1) * rows // bkv)
-        return self._walk_blocks[key]
-
-    def _count_attention_keys(self, lens, spans, width, ksteps, split, chunk, ext):
-        """With the sink on, for every model whose attention runs the paged
-        decode kernel: ``serving/attn_keys_live``, the keys inside the rows'
-        attended windows, and ``serving/attn_keys_walked``, the same rounded
-        out to the blocks the kernel's walk fetches (its own arithmetic:
-        ``decode_attention.walked_keys`` at ``walk_block_kv``), from the
-        host's copies of the lengths and spans, summed over slots, a step
-        program's forwards (:meth:`_fused_fn`: the first forward whole or as
-        a column and the chunk's (1, C), then the substeps) and layers. A
-        forward counts a row's keys once whatever its number of columns; one
-        that runs a layer through XLA's attention (a windowed layer's chunk)
-        counts nothing for it. The seq-sharded call counts as the whole."""
-        if not self._attn_walks:
-            return
-        from ..ops.pallas.decode_attention import walked_keys
-        lens, spans = lens.astype(np.int64), spans.astype(np.int64)
-        on = spans > 0
-        # (one past each row's write head, keys of its window, the call's span)
-        if width == 1 or not split:
-            forwards = [(np.where(on, lens + 1, 0), np.where(on, lens + spans, 0), width)]
-        else:
-            column = spans == 1
-            forwards = [(np.where(column, lens + 1, 0), ) * 2 + (1, )]
-            if (spans > 1).any():
-                ps = int(np.argmax(spans > 1))
-                forwards.append((lens[ps:ps + 1] + 1, lens[ps:ps + 1] + spans[ps], width))
-        stepping = on.copy()
-        if self._state_pool and chunk is not None and not chunk[1]:
-            stepping[chunk[0]] = False  # _substep_spans: the row stands still
-        if ksteps > 1:  # the substeps at once: (ksteps - 1, N), a key further each
-            ends = np.where(stepping, lens + np.maximum(spans, 1)
-                            + np.arange(1, ksteps)[:, None], 0)
-            forwards.append((ends, ends, 1))
-        live = walked = 0
-        for g, (n, ring, window, _) in enumerate(self._attn_walks):
-            for ends, keys, span in forwards:
-                if span > 1 and (ring or window):
-                    continue
-                start = np.zeros_like(ends)
-                if ring:
-                    ends = keys = np.minimum(ends, ring)
-                elif window:
-                    start = np.maximum(ends - window, 0)
-                    keys = ends - start
-                tile, bkv, blocks = self._walk_block(g, span, ext)
-                live += n * int(keys.sum())
-                # a span wider than one kernel call takes walks the keys once a tile
-                walked += n * sum(
-                    walked_keys(start, np.where(ends > start, ends + t0, ends), tile, bkv, blocks)
-                    for t0 in range(0, span, tile))
-        self.telemetry.counter("serving/attn_keys_live", live)
-        self.telemetry.counter("serving/attn_keys_walked", walked)
-
     def _call_step(self, fn, args, lora, spans, lens, chunk=None):
         """Dispatch ONE step program (``spans``, ``lens``: the host's copies
         of ``args[4]`` and ``args[3]``, and ``chunk``: ``(slot, final)`` of a
@@ -2667,7 +2446,7 @@ class DecodeScheduler(DeviceDraft):
         """
         extra = (lora, ) if lora is not None else ()
         if self.experts is None:
-            return self._dispatch(fn, args + extra, args, spans, lens, chunk)
+            return self._dispatch(fn, args + extra, spans, lens, chunk)
         replays = 0
         # hard bound on the replay loop: each round loads at least one page
         # on this replica, so L*E rounds can only be exceeded by pathological
@@ -2675,7 +2454,7 @@ class DecodeScheduler(DeviceDraft):
         max_replays = 2 * self.experts.num_layers * self.experts.num_experts + 8
         while True:
             emap, pools, resident = self.experts.dispatch_operands()
-            out = self._dispatch(fn, args + extra + ((emap, pools), ), args, spans, lens, chunk)
+            out = self._dispatch(fn, args + extra + ((emap, pools), ), spans, lens, chunk)
             counts = np.asarray(jax.device_get(out[-1]))[:, :-2]
             used = counts > 0
             if not self.experts.missing(used, resident).any():
@@ -2754,35 +2533,20 @@ class DecodeScheduler(DeviceDraft):
         most ``top_k`` experts per layer, which the store validated fits.
         Excluded rows keep span 0 (no KV write, nothing delivered) and
         simply advance in a later group/sync."""
-        eng = self.engine
-        N = self.cache.num_slots
         pending = list(live)
         delivered = 0
         while pending:
             group = list(pending)
             while True:
                 with self._span("sched/assemble"):
-                    ids = np.zeros((N, 1), np.int32)
-                    spans = np.zeros(N, np.int32)
-                    lens = np.zeros(N, np.int32)
-                    for slot, req in group:
-                        ids[slot, 0] = req.out[-1]
-                        spans[slot] = 1
-                        lens[slot] = self.cache.lengths[slot]
-                    (seeds, steps, flags, temps, topks, topps, sampling,
-                     collect) = self._gather_sampling(group)
+                    ops = self._assemble(group, 1)
                     lora = self._adapter_arg(group)
                     eo = self._ext_operands(group)
-                    fn = self._fused_fn(sampling, collect, 1, 1, lora=lora is not None,
+                    fn = self._fused_fn(ops.sampling, ops.collect, 1, 1, lora=lora is not None,
                                         ext=eo is not None)
-                    args = (eng.params, self.cache.pool, self._device_ids(ids),
-                            jnp.asarray(lens), jnp.asarray(spans),
-                            jnp.asarray(seeds), jnp.asarray(steps), jnp.asarray(flags),
-                            jnp.asarray(temps), jnp.asarray(topks), jnp.asarray(topps))
-                    if eo is not None:
-                        args = args + tuple(jnp.asarray(x) for x in eo)
+                    args = self._step_args(ops, eo)
                 try:
-                    out = self._call_step(fn, args, lora, spans, lens)
+                    out = self._call_step(fn, args, lora, ops.spans, ops.lens)
                     break
                 except _ExpertOverflow as e:
                     self.cache.pool = e.pool
@@ -2795,7 +2559,7 @@ class DecodeScheduler(DeviceDraft):
                     group = group[:(len(group) + 1) // 2]
             self.cache.pool = out[0]
             self._advance(group, 1)
-            toks_k, logits_k = self._fetch_block(out[1:], collect, 1)
+            toks_k, logits_k = self._fetch_block(out[1:], ops.collect, 1)
             delivered += self._deliver_block(group, toks_k, logits_k, 1)
             done = {slot for slot, _ in group}
             pending = [(s, r) for (s, r) in pending if s not in done]
@@ -2810,9 +2574,8 @@ class DecodeScheduler(DeviceDraft):
         preserved upward — pieces only subdivide the chunk the normal path
         would have fed — so the KV this path writes is byte-identical to
         the unconstrained sync's."""
-        eng = self.engine
         preq = pf.req
-        N, C = self.cache.num_slots, self.prefill_chunk
+        C = self.prefill_chunk
         S = self.max_len
         ps = preq.slot
         L = preq.prompt.size
@@ -2823,35 +2586,14 @@ class DecodeScheduler(DeviceDraft):
             # one extent per forward (same rule as the normal chunk step)
             take = min(chunk_end - pf.pos, S - pf.pos % S)
             while True:
-                ids = np.zeros((N, C), np.int32)
-                spans = np.zeros(N, np.int32)
-                lens = np.zeros(N, np.int32)
-                ids[ps, :take] = preq.prompt[pf.pos:pf.pos + take]
-                spans[ps] = take
-                lens[ps] = self.cache.lengths[ps]
-                seeds = np.zeros(N, np.uint32)
-                steps = np.zeros(N, np.int32)
-                flags = np.zeros(N, bool)
-                temps = np.ones(N, np.float32)
-                topks = np.zeros(N, np.int32)
-                topps = np.ones(N, np.float32)
-                seeds[ps] = preq.seed
-                flags[ps] = preq.do_sample
-                temps[ps] = preq.temperature
-                topks[ps] = preq.top_k
-                topps[ps] = preq.top_p
+                ops = self._assemble([], C, prefill=(preq, pf.pos, take))
                 lora = self._adapter_arg([(ps, preq)])
                 eo = self._ext_operands([(ps, preq)])
-                fn = self._fused_fn(preq.do_sample, preq.collect_logits, 1, C,
+                fn = self._fused_fn(ops.sampling, ops.collect, 1, C,
                                     lora=lora is not None, ext=eo is not None)
-                args = (eng.params, self.cache.pool, self._device_ids(ids),
-                        jnp.asarray(lens), jnp.asarray(spans),
-                        jnp.asarray(seeds), jnp.asarray(steps), jnp.asarray(flags),
-                        jnp.asarray(temps), jnp.asarray(topks), jnp.asarray(topps))
-                if eo is not None:
-                    args = args + tuple(jnp.asarray(x) for x in eo)
                 try:
-                    out = self._call_step(fn, args, lora, spans, lens)
+                    out = self._call_step(fn, self._step_args(ops, eo), lora,
+                                          ops.spans, ops.lens)
                     break
                 except _ExpertOverflow as e:
                     self.cache.pool = e.pool
@@ -2892,7 +2634,6 @@ class DecodeScheduler(DeviceDraft):
         N = self.cache.num_slots
         C = self.prefill_chunk
         K = self.steps_per_sync
-        zeros = np.zeros(N, np.int32)
         # multi-LoRA composes with offload: warm the lora program variants
         # too, with every row on the reserved all-zero slot-0 pages (the
         # backoff ladder otherwise compiles them on its first
@@ -2903,61 +2644,43 @@ class DecodeScheduler(DeviceDraft):
             lora_args += (tuple((jnp.asarray(np.zeros(N, np.int32)), pools[b])
                                 for b in self.adapters.bucket_keys()), )
 
-        def dispatch(fn, width, lora, ext_args=()):
-            args = (self.engine.params, self.cache.pool,
-                    self._device_ids(np.zeros((N, width), np.int32)),
-                    jnp.asarray(zeros), jnp.asarray(zeros),
-                    jnp.asarray(np.zeros(N, np.uint32)), jnp.asarray(zeros),
-                    jnp.asarray(np.zeros(N, bool)),
-                    jnp.asarray(np.ones(N, np.float32)), jnp.asarray(zeros),
-                    jnp.asarray(np.ones(N, np.float32))) + tuple(ext_args) \
-                + self._substep_spans(zeros)
-            out = self._call_step(fn, args, lora, zeros, zeros)
+        def dispatch(fn, width, lora, eo=None):
+            # no row: every span zero, through the seam that serves
+            ops = self._assemble([], width)
+            out = self._call_step(fn, self._step_args(ops, eo), lora, ops.spans, ops.lens)
             self.cache.pool = out[0]
 
-        shapes = sorted({(K, C), (1, C), (K, 1)} | ({(1, 1)} if ladder else set()))
-        # seq-parallel prefill reaches the PLAIN program at the wide chunk
+        shapes = {(K, C), (1, C), (K, 1)} | ({(1, 1)} if ladder else set())
+        # (steps, width, extent walk, seq-sharded) of every plain step program.
+        # Seq-parallel prefill reaches the PLAIN program at the wide chunk
         # width when the seq axis has one device (same math, unsharded)
         wide = ({(K, self._seq_chunk), (1, self._seq_chunk)}
                 if (self._seq_chunk and self._seq_shards == 1) else set())
-        for sampling in (False, True):
-            for lora in lora_args:
-                for ksteps, width in sorted(set(shapes) | wide):
-                    dispatch(self._fused_fn(sampling, self.collect_logits, ksteps,
-                                            width, lora=lora is not None),
-                             width, lora)
-                if self.drafter is not None:
-                    dispatch(self._spec_fn(sampling, self.collect_logits,
-                                           self._spec_width,
-                                           lora=lora is not None),
-                             self._spec_width, lora)
+        variants = [(k, w, False, False) for k, w in sorted(shapes | wide)]
+        eo = None
         if (self.cache.max_extents > 1 or self.allow_lossy_kv
                 or self._seq_chunk):
             # long-context variants: the extent program at every shape the
             # decode/backoff/chunk ladder reaches (plus the seq-parallel
             # chunk width), and the seq-sharded program at its one width —
             # warmed with the identity extent table and all spans zero
-            eo = tuple(jnp.asarray(x)
-                       for x in self._ext_operands([], force=True))
-            ext_shapes = set(shapes)
+            eo = self._ext_operands([], force=True)
             if self._seq_chunk:
-                ext_shapes |= {(K, self._seq_chunk), (1, self._seq_chunk)}
-            for sampling in (False, True):
-                for lora in lora_args:
-                    for ksteps, width in sorted(ext_shapes):
-                        dispatch(self._fused_fn(sampling, self.collect_logits,
-                                                ksteps, width,
-                                                lora=lora is not None,
-                                                ext=True),
-                                 width, lora, eo)
-                    if self._seq_shards > 1:
-                        for ksteps in (K, 1):
-                            dispatch(self._fused_fn(sampling,
-                                                    self.collect_logits,
-                                                    ksteps, self._seq_chunk,
-                                                    lora=lora is not None,
-                                                    ext=True, seqp=True),
-                                     self._seq_chunk, lora, eo)
+                shapes |= {(K, self._seq_chunk), (1, self._seq_chunk)}
+            variants += [(k, w, True, False) for k, w in sorted(shapes)]
+            if self._seq_shards > 1:
+                variants += [(k, self._seq_chunk, True, True) for k in (K, 1)]
+        for sampling in (False, True):
+            for lora in lora_args:
+                for ksteps, width, ext, seqp in variants:
+                    dispatch(self._fused_fn(sampling, self.collect_logits, ksteps, width,
+                                            lora=lora is not None, ext=ext, seqp=seqp),
+                             width, lora, eo if ext else None)
+                if self.drafter is not None:
+                    dispatch(self._spec_fn(sampling, self.collect_logits,
+                                           self._spec_width,
+                                           lora=lora is not None),
+                             self._spec_width, lora)
         if self.radix is not None:
             # the radix slot-copy program (src == dst is the identity copy,
             # safe against any pool state)
@@ -2980,21 +2703,11 @@ class DecodeScheduler(DeviceDraft):
         row, not the longest retained prefix. Returns the :class:`_Flight`,
         None when every active row ends inside the sync in flight, or
         (delivered, 1) where cold-expert pressure made it back off."""
-        eng = self.engine
-        N = self.cache.num_slots
         with self._span("sched/assemble"):
-            live, col0 = self._live_rows()
+            live = self._live_rows()
             if not live:
                 return None
-            ids = np.zeros((N, 1), np.int32)
-            spans = np.zeros(N, np.int32)
-            lens = np.zeros(N, np.int32)
-            for (slot, req), tok in zip(live, col0):
-                ids[slot, 0] = tok
-                spans[slot] = 1
-                lens[slot] = self.cache.lengths[slot]
-            (seeds, steps, flags, temps, topks, topps, sampling,
-             collect) = self._gather_sampling(live)
+            ops = self._assemble(live, 1)
             K = self.steps_per_sync
             eo = self._ext_operands(live)
             if eo is not None and K > 1:
@@ -3005,17 +2718,11 @@ class DecodeScheduler(DeviceDraft):
                 if any(S - int(self.cache.lengths[s]) % S < K for s, _ in live):
                     K = 1
             lora = self._adapter_arg(live)
-            fn = self._fused_fn(sampling, collect, K, 1, lora=lora is not None,
+            fn = self._fused_fn(ops.sampling, ops.collect, K, 1, lora=lora is not None,
                                 ext=eo is not None)
-            args = (eng.params, self.cache.pool, self._device_ids(ids),
-                    jnp.asarray(lens), jnp.asarray(spans),
-                    jnp.asarray(seeds), jnp.asarray(steps), jnp.asarray(flags),
-                    jnp.asarray(temps), jnp.asarray(topks), jnp.asarray(topps))
-            if eo is not None:
-                args = args + tuple(jnp.asarray(x) for x in eo)
-            args = args + self._substep_spans(spans)
+            args = self._step_args(ops, eo)
         try:
-            out = self._call_step(fn, args, lora, spans, lens)
+            out = self._call_step(fn, args, lora, ops.spans, ops.lens)
         except _ExpertOverflow as e:
             # a K-step sync's routing union outgrew the expert pool: advance
             # one token per row in overflow-safe groups instead
@@ -3023,7 +2730,7 @@ class DecodeScheduler(DeviceDraft):
             return self._decode_backoff(live), 1
         self.cache.pool = out[0]
         self._advance(live, K)
-        return _Flight(out[1:], K, collect, live)
+        return _Flight(out[1:], K, ops.collect, live)
 
     # ------------------------------------------------------------------ speculative decode
     def _spec_decode_step(self):
@@ -3043,7 +2750,6 @@ class DecodeScheduler(DeviceDraft):
         accepted tokens, so this pump is serial (:meth:`_lands_first`): the
         verify lands here, and nothing is in flight when it is assembled.
         Returns (delivered, 1), or what :meth:`_decode_step` returns."""
-        eng = self.engine
         N, W = self.cache.num_slots, self._spec_width
         live = [(s, r) for s, r in sorted(self.active.items())
                 if s not in self._parked]
@@ -3054,7 +2760,7 @@ class DecodeScheduler(DeviceDraft):
             # against truncated KV — advance exactly instead (bit-identical
             # either way; the extent mix is rare relative to decode syncs)
             return self._decode_step()
-        drafts = {}
+        drafts = []
         total_draft = 0
         for slot, req in live:
             # cap drafts at the remaining budget (a request one token from
@@ -3065,31 +2771,20 @@ class DecodeScheduler(DeviceDraft):
             d = (self.drafter.draft(
                 np.concatenate([req.prompt, np.asarray(req.out, np.int32)]), cap)
                 if cap > 0 else np.empty(0, np.int32))
-            drafts[slot] = d
+            drafts.append(d)
             total_draft += d.size
         if total_draft == 0:
             return self._decode_step()
         with self._span("sched/assemble"):
-            ids = np.zeros((N, W), np.int32)
-            spans = np.zeros(N, np.int32)
-            lens = np.zeros(N, np.int32)
-            for slot, req in live:
-                d = drafts[slot]
-                ids[slot, 0] = req.out[-1]
-                if d.size:
-                    ids[slot, 1:1 + d.size] = d
-                spans[slot] = 1 + d.size
-                lens[slot] = self.cache.lengths[slot]
-            (seeds, steps, flags, temps, topks, topps, sampling,
-             collect) = self._gather_sampling(live)
+            # the drafts as each row's further columns; the verify program
+            # carries no extent walk and no pool of state reaches it
+            ops = self._assemble(live, W, more=drafts)
+            ids, spans, collect = ops.ids, ops.spans, ops.collect
             lora = self._adapter_arg(live)
-            fn = self._spec_fn(sampling, collect, W, lora=lora is not None)
-            args = (eng.params, self.cache.pool, self._device_ids(ids),
-                    jnp.asarray(lens), jnp.asarray(spans),
-                    jnp.asarray(seeds), jnp.asarray(steps), jnp.asarray(flags),
-                    jnp.asarray(temps), jnp.asarray(topks), jnp.asarray(topps))
+            fn = self._spec_fn(ops.sampling, collect, W, lora=lora is not None)
+            args = self._step_args(ops)
         try:
-            out = self._call_step(fn, args, lora, spans, lens)
+            out = self._call_step(fn, args, lora, spans, ops.lens)
         except _ExpertOverflow as e:
             # speculation is opportunistic — skip it for this sync and
             # advance one exact token per row (bit-identical either way)
@@ -3163,8 +2858,6 @@ class DecodeScheduler(DeviceDraft):
         queued prompt's first chunk rides the very next sync. Returns the
         :class:`_Flight`, or (delivered, 1) where cold-expert pressure made
         it back off."""
-        eng = self.engine
-        N = self.cache.num_slots
         pf = self._prefill
         preq = pf.req
         # sequence-parallel prefill: wide chunks (the seq-parallel width),
@@ -3180,29 +2873,9 @@ class DecodeScheduler(DeviceDraft):
         take = min(C, L - pf.pos, S - pf.pos % S)
         final = pf.pos + take >= L
         with self._span("sched/assemble"):
-            ids = np.zeros((N, C), np.int32)
-            spans = np.zeros(N, np.int32)
-            # dead/cached rows keep length 0 in the program input: their writes
-            # are dropped (span 0), and the paged kernel's KV-block walk stays
-            # bounded by the longest live row, not the longest retained prefix
-            lens = np.zeros(N, np.int32)
-            live, col0 = self._live_rows()
-            (seeds, steps, flags, temps, topks, topps, sampling,
-             collect) = self._gather_sampling(live)
-            sampling = sampling or preq.do_sample
-            collect = collect or preq.collect_logits
-            for (slot, req), tok in zip(live, col0):
-                ids[slot, 0] = tok
-                spans[slot] = 1
-                lens[slot] = self.cache.lengths[slot]
+            live = self._live_rows()
+            ops = self._assemble(live, C, prefill=(preq, pf.pos, take))
             ps = preq.slot
-            ids[ps, :take] = preq.prompt[pf.pos:pf.pos + take]
-            spans[ps] = take
-            seeds[ps] = preq.seed  # steps[ps] stays 0: prefill samples token 0
-            flags[ps] = preq.do_sample
-            temps[ps] = preq.temperature
-            topks[ps] = preq.top_k
-            topps[ps] = preq.top_p
             # substeps only pay off when something real decodes in them: live
             # rows, or the prefill row itself once its final chunk lands — a
             # non-final chunk on an otherwise idle pool runs the 1-step
@@ -3222,18 +2895,11 @@ class DecodeScheduler(DeviceDraft):
                 if any(r < K for r in room):
                     K = 1
             lora = self._adapter_arg(live + [(ps, preq)])
-            fn = self._fused_fn(sampling, collect, K, C, lora=lora is not None,
+            fn = self._fused_fn(ops.sampling, ops.collect, K, C, lora=lora is not None,
                                 ext=eo is not None, seqp=seqp)
-            lens[ps] = self.cache.lengths[ps]  # prefix copy and/or earlier chunks
-            args = (eng.params, self.cache.pool, self._device_ids(ids),
-                    jnp.asarray(lens), jnp.asarray(spans),
-                    jnp.asarray(seeds), jnp.asarray(steps), jnp.asarray(flags),
-                    jnp.asarray(temps), jnp.asarray(topks), jnp.asarray(topps))
-            if eo is not None:
-                args = args + tuple(jnp.asarray(x) for x in eo)
-            args = args + self._substep_spans(spans, held=None if final else ps)
+            args = self._step_args(ops, eo, held=None if final else ps)
         try:
-            out = self._call_step(fn, args, lora, spans, lens, chunk=(ps, final))
+            out = self._call_step(fn, args, lora, ops.spans, ops.lens, chunk=(ps, final))
         except _ExpertOverflow as e:
             # the chunk's routing demand outgrew the expert pool: feed the
             # prefill alone in shrinking pieces, then advance decode rows
@@ -3241,7 +2907,7 @@ class DecodeScheduler(DeviceDraft):
             return self._fused_backoff(pf, live)
         self.cache.pool = out[0]
         self._advance(live, K)
-        fl = _Flight(out[1:], K, collect, live, (preq, pf.pos, take, final))
+        fl = _Flight(out[1:], K, ops.collect, live, (preq, pf.pos, take, final))
         pf.pos += take
         if final:
             # the chunk's rows plus K-1 substep rows: token 0's KV lands
@@ -3398,12 +3064,7 @@ class DecodeScheduler(DeviceDraft):
             state_pool = self._state_pool
             choice = collect and self._moe and not fused_block
 
-            def sample(l2, seeds, steps, flags, temps, topks, topps):
-                with jax.named_scope("sample"):
-                    if sampling:
-                        return jax.vmap(_sample_slot)(seeds, steps, l2, flags,
-                                                      temps, topks, topps)
-                    return jnp.argmax(l2, axis=-1).astype(jnp.int32)
+            sample = _sampler(sampling)
 
             def fused(params, pool, ids, lengths, spans, seeds, steps, flags,
                       temps, topks, topps, *extra):
@@ -3548,12 +3209,7 @@ class DecodeScheduler(DeviceDraft):
             stats = self._moe_stats
             offload = self.experts is not None
 
-            def sample(l2, seeds, steps, flags, temps, topks, topps):
-                with jax.named_scope("sample"):
-                    if sampling:
-                        return jax.vmap(_sample_slot)(seeds, steps, l2, flags,
-                                                      temps, topks, topps)
-                    return jnp.argmax(l2, axis=-1).astype(jnp.int32)
+            sample = _sampler(sampling)
 
             def spec(params, pool, ids, lengths, spans, seeds, steps, flags,
                      temps, topks, topps, *extra):

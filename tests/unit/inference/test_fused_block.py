@@ -29,6 +29,8 @@ import jax.numpy as jnp
 import deepspeed_tpu
 from deepspeed_tpu.comm import comm
 
+from ._reference_path import REASON, per_projection_engine
+
 PROMPTS = [[5, 6, 7, 8, 9], [10, 11, 12]]
 
 
@@ -172,7 +174,7 @@ def test_mixed_sync_leaves_one_pool(quant_kv, monkeypatch):
 
 
 # ----------------------------------------------------- scheduler-level parity
-def test_scheduler_fused_block_matches_per_projection(baseline):
+def test_scheduler_fused_block_matches_per_projection(baseline, monkeypatch):
     """Greedy AND seeded-sampled streams through the retagged
     ``fused_block`` step programs == the per-projection ``fused`` programs,
     and the radix cache lands prefix hits on the fused path."""
@@ -184,11 +186,10 @@ def test_scheduler_fused_block_matches_per_projection(baseline):
     sched_on = eng_on.scheduler()
     assert sched_on._fused_block and sched_on._fused_block_reasons == []
 
-    eng_off = make_fused_engine(params, fused_decode_block=False)
+    eng_off = per_projection_engine(monkeypatch, make_fused_engine, params)
     sched_off = eng_off.scheduler()
     assert not sched_off._fused_block
-    assert any("fused_decode_block=False" in r
-               for r in sched_off._fused_block_reasons)
+    assert sched_off._fused_block_reasons == [REASON]
 
     kw_s = dict(max_new_tokens=8, do_sample=True, temperature=0.7, top_k=20,
                 top_p=0.9, seed=11)

@@ -14,6 +14,8 @@ import jax.numpy as jnp
 import deepspeed_tpu
 from deepspeed_tpu.comm import comm
 
+from ._reference_path import per_projection_engine
+
 PROMPTS = [[5, 6, 7, 8, 9], [10, 11, 12]]
 
 
@@ -224,7 +226,7 @@ def test_int8_weight_serving_matches_fp32(baseline):
     assert bool(jnp.isfinite(logits).all())
 
 
-def test_fused_decode_block_matches_unfused():
+def test_fused_decode_block_matches_unfused(monkeypatch):
     """The fused per-layer decode kernel (ops/pallas/decode_block.py — the
     reference's one-pass qkv_gemm/softmax_context/mlp_gemm,
     pt_binding.cpp:1745) must generate the same tokens as the per-projection
@@ -236,8 +238,8 @@ def test_fused_decode_block_matches_unfused():
 
     eng_fused = make_engine(model="tiny-gpt2", params=params, dtype="int8", kernel_inject=True)
     assert eng_fused._fused_decode_eligible(), "tiny-gpt2 int8 should take the fused path"
-    eng_slow = make_engine(model="tiny-gpt2", params=params, dtype="int8", kernel_inject=True,
-                           fused_decode_block=False)
+    eng_slow = per_projection_engine(monkeypatch, make_engine, model="tiny-gpt2", params=params,
+                                     dtype="int8", kernel_inject=True)
     assert not eng_slow._fused_decode_eligible()
 
     for prompts in (PROMPTS, [[3, 4, 5, 6], [7, 8, 9, 10]]):  # ragged + uniform

@@ -3,11 +3,14 @@ reach the cache their own way: extent chains, a lossy window, speculative
 verify programs and a tensor-parallel pool, each against split leaves
 (``kv_packs`` patched off) or against tp=1."""
 
+from unittest import mock
+
 import jax
 import numpy as np
 import pytest
 
 import deepspeed_tpu
+from deepspeed_tpu.inference.scheduler import DecodeScheduler
 from deepspeed_tpu.models import get_model
 
 from ._packed_kv import HD, LONG, OTHER, ask, assert_same_runs, fresh_process_state, split_rule
@@ -28,11 +31,18 @@ def _feature_stream(feature):
     eng = deepspeed_tpu.init_inference(get_model("tiny", head_dim=HD), config={
         "dtype": "float32", "kernel_inject": True, "decode_block_kv": 32,
         "continuous_batching": cb})
-    sched = eng.scheduler(**kw)
+    # a long-context scheduler warms all sixteen of its step programs as it is
+    # built, with every span zero: nothing of a pool's geometry shows there, and
+    # tracing them through the interpreter was 100 s of this test's 120. Built
+    # cold, the traffic below compiles the four it reaches
+    with mock.patch.object(DecodeScheduler, "warm_programs", lambda self, ladder=True: None):
+        sched = eng.scheduler(**kw)
     prompt = [int(t) for t in np.resize(np.arange(3, 40), 100)]
     extra = {"kv_window": (4, 32)} if feature == "lossy" else {}
-    n = 20 if feature == "spec" else 8  # (interpret-mode extent walks are slow)
-    hs = [sched.submit(prompt, max_new_tokens=n, collect_logits=True, **extra),
+    # the chained row outlives the short one, so that every sync of either
+    # walks extents: the (16, 1) and (16, 4) chunk programs and the (1, 4) decode
+    n, n_long = (20, 20) if feature == "spec" else (8, 16)
+    hs = [sched.submit(prompt, max_new_tokens=n_long, collect_logits=True, **extra),
           sched.submit(prompt[:30], max_new_tokens=n, collect_logits=True, temperature=0.8,
                        top_k=20, seed=7)]
     runs = [(h.result().tolist(), h.result_logits()) for h in hs]

@@ -996,8 +996,12 @@ def test_attention_key_counters(tmp_path, case):
     sched._splits_chunk = lambda key: case == "split"
     sched._lands_first = lambda: True
     counted = []
-    count = sched._count_attention_keys
-    sched._count_attention_keys = lambda *a, **kw: (counted.append(a), count(*a, **kw))
+    if case == "sink_off":
+        assert sched._work is None  # no observer: required_work.py
+    else:
+        heard = sched._work.dispatched
+        sched._work.dispatched = lambda key, split, spans, lens, chunk=None: (
+            counted.append((lens, spans)), heard(key, split, spans, lens, chunk))
     sched.submit(list(range(3, 19)), max_new_tokens=9)
     sched.submit(list(range(40, 60)), max_new_tokens=3)  # no prefix of the first: nothing copied
     sched.drain()
