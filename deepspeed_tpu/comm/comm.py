@@ -20,6 +20,7 @@ translation (SURVEY §2.2/§5):
 """
 
 import os
+import threading
 from contextlib import contextmanager, nullcontext
 
 import jax
@@ -84,6 +85,28 @@ def in_manual_region():
     return bool(_state["manual_axes"])
 
 
+def dp_axes():
+    """The data-parallel axes of the mesh that are more than one: what a
+    batch axis is sharded over."""
+    mesh = get_mesh()
+    return tuple(a for a in DP_AXES if mesh.shape[a] > 1)
+
+
+# The chunked cross-entropy backwards this thread traced, and the cross-chip
+# sums of the vocabulary projection's weight gradient the last of them itself
+# asks for, ``(backwards, (sums, bytes))``: the training engine reads it
+# around a step's first dispatch.
+_traced = threading.local()
+
+
+def tally_head_grad(sums, nbytes):
+    _traced.head_grad = (traced_head_grad()[0] + 1, (sums, nbytes))
+
+
+def traced_head_grad():
+    return getattr(_traced, "head_grad", (0, (0, 0)))
+
+
 def get_manual_axes():
     """Axis names bound by enclosing ``manual_axes`` regions (frozenset)."""
     return _state["manual_axes"]
@@ -124,7 +147,7 @@ def attention_partition_axes(batch_size, num_heads):
     dropped (empty tuple) when the corresponding dim is not divisible, so the
     kernel wrapper and the model constraints always agree on placement."""
     mesh = get_mesh()
-    dp = tuple(a for a in (EXPERT_AXIS, DATA_AXIS) if mesh.shape[a] > 1)
+    dp = dp_axes()
     if dp and batch_size % int(np.prod([mesh.shape[a] for a in dp])) != 0:
         dp = ()
     # tensor-major head tiling: the projection side keeps heads sharded by

@@ -1255,6 +1255,7 @@ class DeepSpeedEngine:
         if t0 is not None:
             from ..ops.pallas import flash_attention
             flash_before = flash_attention.traced()
+            head_before, _ = dist.traced_head_grad()
         if self.offload_optimizer:
             metrics = self._offload_train_batch(stacked)
         else:
@@ -1282,6 +1283,14 @@ class DeepSpeedEngine:
                      sum(p.computed_pct for p in plans) / len(plans), self.global_samples),
                     ("kernels/flash_scores_masked_pct",
                      sum(p.masked_pct for p in plans) / len(plans), self.global_samples)])
+            # and what its cross-entropy asks of the links for the head's
+            # weight gradient: one loss a microbatch, so the last backward
+            # traced stands for each of them
+            traced, (sums, nbytes) = dist.traced_head_grad()
+            if traced > head_before:
+                self.telemetry.gauges([
+                    ("zero/head_grad_reductions_per_step", sums * gas, self.global_samples),
+                    ("zero/head_grad_reduced_bytes_per_step", nbytes * gas, self.global_samples)])
         self.global_steps += 1
         self.global_samples += self.train_batch_size()
         self.micro_steps += gas

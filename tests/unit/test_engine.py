@@ -58,6 +58,34 @@ def test_zero_stages_match_baseline(stage):
     np.testing.assert_allclose(baseline, stage_losses, rtol=2e-4)
 
 
+@pytest.mark.parametrize("stage", [2, 3])
+def test_zero_stages_match_baseline_on_a_tied_vocabulary(stage):
+    """The toy model above has no vocabulary projection. A tied model whose
+    vocabulary takes the chunked cross-entropy, over ``data=4``: the head's
+    weight gradient is summed over the chips once, behind the chunks, and
+    the stage's shard is cut from that sum, so the stage's losses are stage
+    0's only if the one sum is the whole gradient."""
+    import jax
+    import jax.numpy as jnp
+    from deepspeed_tpu.models import get_model
+
+    def losses(zero_stage):
+        comm.initialize_mesh(devices=jax.devices()[:4], data=4)
+        model = get_model("tiny", dtype=jnp.float32, vocab_size=4352, max_seq_len=65,
+                          ce_chunk_size=16)
+        assert model._use_chunked_ce() and model.cfg.tie_embeddings
+        engine, _, _, _ = deepspeed_tpu.initialize(model=model, rng_seed=0, config=base_config(
+            train_batch_size=8, gradient_accumulation_steps=1,
+            zero_optimization={"stage": zero_stage, "stage3_param_persistence_threshold": 0}))
+        rng = np.random.default_rng(7)
+        batches = [rng.integers(0, 4352, (8, 65)).astype(np.int32) for _ in range(2)]
+        return [float(engine.train_batch(batch={"input_ids": batches[i % 2]})) for i in range(5)]
+
+    baseline = losses(0)
+    np.testing.assert_allclose(baseline, losses(stage), rtol=2e-4)
+    assert baseline[-1] < baseline[0]
+
+
 def test_zero3_params_are_sharded():
     cfg = base_config(zero_optimization={"stage": 3, "stage3_param_persistence_threshold": 0})
     engine = make_engine(cfg)

@@ -51,6 +51,24 @@ def test_layout_equivalence_moe():
                                                               "tensor_parallel_size": 2}), rtol=1e-4)
 
 
+@pytest.mark.parametrize("model_name,mesh_cfg,zero_stage", [
+    ("tiny", {"tensor_parallel_size": 2}, 0),
+    ("tiny", {"tensor_parallel_size": 2}, 3),
+    ("tiny-moe", {"expert_parallel_size": 4}, 2),
+    ("tiny-moe", {"expert_parallel_size": 2, "tensor_parallel_size": 2}, 0),
+])
+def test_layout_equivalence_chunked_vocabulary(model_name, mesh_cfg, zero_stage):
+    """The layouts above run a 256-word vocabulary through dense logits. At
+    4,352 words the chunked cross-entropy sums the head's weight gradient by
+    chip and across the data-parallel axes (``expert`` and ``data``) once:
+    under ``tensor`` and ``expert`` axes the losses must still be plain DP's."""
+    kw = dict(vocab_size=4352, ce_chunk_size=32)
+    assert get_model(model_name, **kw)._use_chunked_ce()
+    assert np.allclose(run_losses(model_name, **kw),
+                       run_losses(model_name, mesh_cfg=mesh_cfg, zero_stage=zero_stage, **kw),
+                       rtol=1e-4)
+
+
 def test_moe_trains():
     losses = run_losses("tiny-moe", steps=5)
     assert losses[-1] < losses[0]

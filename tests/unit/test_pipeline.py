@@ -50,6 +50,16 @@ def test_pipe2_zero3_matches_dp():
     assert np.allclose(base, pp, rtol=2e-4), f"{base} vs {pp}"
 
 
+def test_pipe2_chunked_vocabulary_matches_dp():
+    """A vocabulary large enough for the chunked cross-entropy: under the
+    pipeline (a microbatch stream, not batch-major; a manual region) its
+    backward keeps the plain form and the losses are still plain DP's."""
+    kw = dict(vocab_size=4352, ce_chunk_size=32)
+    base = run_losses(**kw)
+    pp = run_losses({"pipeline_parallel_size": 2}, zero=2, **kw)
+    assert np.allclose(base, pp, rtol=2e-4), f"{base} vs {pp}"
+
+
 def test_pipe2_tp2_matches_dp():
     base = run_losses()
     pp = run_losses({"pipeline_parallel_size": 2, "tensor_parallel_size": 2})
