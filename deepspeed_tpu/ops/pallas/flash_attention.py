@@ -68,8 +68,7 @@ Where the time went, by what was tried (same runs; cell 1 / cell 3, ms):
   and ``ds^T q`` each put a block of scores through a transpose; as (kv, q)
   blocks all four products are plain. Wall clock of the call with its XLA
   neighbours, 256 x 256 two blocks an iteration: 0.805 -> 0.507 / 2.230 ->
-  1.504. lse and delta then lie along the lanes: the step turns its two
-  (T, 1) columns into rows first, one (128, 128) transpose a 128 q rows.
+  1.504. lse and delta then lie along the lanes, as they rest (below).
 - *The split and the granularity* give the rest: 256 x 256 with the mask-free
   body against 512 x 512 masked everywhere, forward 0.214 -> 0.207 / 0.536 ->
   0.489, dq 0.262 -> 0.231 / 0.672 -> 0.625, dk/dv 0.350 -> 0.325 / 0.898 ->
@@ -95,6 +94,56 @@ at the 62.5% of the square the 256 x 256 walk computes the forward's two
 products need 0.136 ms and the backward's seven 0.477: the forward runs at
 66% of that bound and the backward at 86%. What is left is the MXU's fill,
 the computed share, and a backward of five products (one fused kernel).
+
+The statistics rest along the lanes (PR 49). ``lse`` (forward out; dq and
+dk/dv in) and ``delta`` (dq and dk/dv in) cross HBM as ``(B, H, n_q, 1,
+sub_q)``, a q block's values a row (:func:`_stats`):
+``f32[4,20,4,1,256]{4,3,2,1,0:T(1,128)}`` in cell 1's compiled step, the
+bytes of their values. As ``(B, H, T, 1)`` columns they rested under an
+(8, 128) tile at 512 B a value, 41.9 MB each where the values are 0.33 MB,
+and the saved ``flash_lse`` of cell 1's 36 layers was 1.51 GB that put the
+4 x 1024 step over the chip's memory: the compiler bought the space back by
+running 30 of the 36 ``up_proj`` products (with their layer norms) a second
+time in the backward, 9.1 ms of a 195.4 ms step (:func:`stats_bytes`;
+``PERF.md`` section 6, PR 49). The forward and dq kernels use the statistics
+as ``(sub_q, 1)`` columns and turn a column into a row (:func:`_row`) or two
+rows into two columns (:func:`_columns`) once a q block, one (128, 128)
+transpose a 128 rows (every kernel's ``sub_q`` is a multiple of 128 for it);
+the dk/dv kernel loads a q block's row by the block's number. All three take
+ONE shape, so the forward's ``lse`` reaches both backward calls as it is and
+``delta``, still XLA's reduction ``bhtd,bhtd->bht`` with T in the lanes as it
+comes, through one relayout of 0.33 MB where two copies a layer wrote it as
+a column. (With rows ``(B, H, 1, T)`` for the forward and dq and the blocks
+for dk/dv alone, the same bytes, each backward call had its own relayout
+chain and cell 3's step read 340.64 ms against the parent's 339.55; with one
+shape 338.07.)
+
+The layer alone again (my chip runs, PR 49; same machine, same operands,
+device time of the Pallas calls, mean of 10, ms; every output of every kernel
+is the parent's bit for bit; "beside" is the XLA operations a backward runs
+beside its two calls: delta, the relayouts, the copies of the operands)::
+
+                                 forward        dq             dk/dv        three calls    beside
+    (B, H, T, D)                 PR 48  PR 49   PR 48  PR 49   PR 48  PR 49   PR 48  PR 49   PR 48  PR 49
+    (4, 20, 1024, 64)  cell 1    0.207  0.203   0.231  0.244   0.325  0.301   0.763  0.747   0.261  0.191
+    (2, 32, 2048, 64)  cell 3    0.489  0.493   0.625  0.635   0.839  0.818   1.954  1.945   0.499  0.339
+    (4, 32/8, 1024, 128) GQA     0.334  0.338   0.372  0.396   0.525  0.487   1.232  1.221   0.376  0.190
+
+dk/dv loses its transposes (-3 to -7%); dq gains two a q block (+2 to +6%;
+with a transpose a statistic, four a q block, it read 0.254 / 0.643 / 0.412);
+the forward trades 32 one-lane stores a q block for two transposes and two
+row stores. The three calls together are within 2% of the parent's: what
+this layout buys is memory, not kernel time.
+
+Three forms that do NOT move the layout, because layout assignment and not
+the ``jnp`` shape decides how a ``(..., T, 1)`` array rests (ISSUE 49,
+compiled for a described v5e): saving ``lse[..., 0]`` and restoring
+``[..., None]`` in the backward (the compiler cancels the pair: the parent's
+program instruction for instruction); the same through a transpose and
+``+ 1.0`` / ``- 1.0`` (folded, the same program); the same with a runtime
+scalar it cannot fold (the saved buffer is still
+``f32[4,20,1024,1]{...T(8,128)}``). The layout has to come from the kernels'
+own operand and result shapes.
 
 Kernels run interpreted on CPU (tests) and compiled on TPU.
 """
@@ -154,18 +203,40 @@ class Plan(NamedTuple):
                 / sum(k.computed_pct for k in self[:3]))
 
 
-# Plans of the flash calls traced by this thread: the training engine reads
-# it around a step's first dispatch to say what its kernels compute.
+def stats_bytes(shape):
+    """``(at rest, values)``: the bytes a float32 array of ``shape`` takes as
+    the TPU tiles its last two axes, and the bytes of its values. A tile is
+    128 lanes by 8 sublanes, or by the power of two that holds an axis
+    shorter than 8: ``(T, 1)`` rests at 128 times its values, ``(1, T)`` at
+    its values."""
+    *outer, rows, lanes = shape
+    n = math.prod(outer)
+    sublanes = min(8, 1 << (rows - 1).bit_length())
+    return n * _pad_to(rows, sublanes) * _pad_to(lanes, LANES) * 4, n * rows * lanes * 4
+
+
+class TracedCall(NamedTuple):
+    """One traced flash call: its plan, and what the softmax statistics its
+    kernels exchange through HBM (lse and delta) take there."""
+    plan: Plan
+    stats_bytes_at_rest: int
+    stats_bytes_values: int
+
+
+# The flash calls traced by this thread: the training engine reads it around
+# a step's first dispatch to say what its kernels compute.
 _traced = threading.local()
 
 
-def tally(plan_):
-    """Note one traced flash call's plan."""
-    _traced.plans = traced() + (plan_, )
+def tally(plan_, stats_shape):
+    """Note one traced flash call: its plan and the shape lse (and delta)
+    cross HBM in."""
+    at_rest, values = stats_bytes(stats_shape)
+    _traced.calls = traced() + (TracedCall(plan_, 2 * at_rest, 2 * values), )
 
 
 def traced():
-    return getattr(_traced, "plans", ())
+    return getattr(_traced, "calls", ())
 
 
 # ------------------------------------------------------------------ the loops
@@ -266,13 +337,34 @@ def _block_mask(masked, iq, ik, q_start, kv_start, causal, q_left, kv_left):
     return functools.reduce(jnp.logical_and, terms) if terms else None
 
 
+def _row(col):
+    """A (128, 1) column as a (1, 128) row: one (128, 128) transpose of its
+    broadcast. The statistics cross HBM with the sequence in the lanes; the
+    forward and dq kernels use them as columns."""
+    return jnp.broadcast_to(col, (LANES, LANES)).T[:1]
+
+
+def _columns(lse_ref, delta_ref, a):
+    """Q block ``a`` of the two statistics blocks ``(1, 1, n, 1, sub_q)`` as
+    two (sub_q, 1) columns. One (128, 128) transpose a 128 values turns
+    both: lse's row on sublane 0 and delta's on the others come out as column
+    0 and column 1."""
+    first = jax.lax.broadcasted_iota(jnp.int32, (LANES, LANES), 0) == 0
+    both = [jnp.where(first, lse_ref[0, 0, a, :, pl.ds(c, LANES)],
+                      delta_ref[0, 0, a, :, pl.ds(c, LANES)]).T
+            for c in range(0, lse_ref.shape[-1], LANES)]
+    return (jnp.concatenate([t[:, :1] for t in both], axis=0),
+            jnp.concatenate([t[:, 1:2] for t in both], axis=0))
+
+
 # ---------------------------------------------------------------- the kernels
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, sub_q, sub_kv, causal, split,
                 q_len, kv_len, one_step):
     """Grid: (B, H, q grid blocks). Blocks: q/o (1, 1, gq, D); k/v
-    (1, 1, Tkv, D), the full (padded) KV head in VMEM; lse (1, 1, gq, 1). The
-    step walks its q rows in sub-blocks of ``sub_q`` and, for each, the kv
-    blocks of ``sub_kv`` columns the mask leaves."""
+    (1, 1, Tkv, D), the full (padded) KV head in VMEM; lse (1, 1, gq / sub_q,
+    1, sub_q), a q block's rows along the lanes. The step walks its q rows in sub-blocks of
+    ``sub_q`` and, for each, the kv blocks of ``sub_kv`` columns the mask
+    leaves."""
     gq, d = q_ref.shape[2], q_ref.shape[3]
     base = 0 if one_step else pl.program_id(2) * gq
     n_kv = k_ref.shape[2] // sub_kv
@@ -322,7 +414,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, sub_q, sub_kv, ca
 
         l_safe = jnp.where(l == 0, 1.0, l)
         o_ref[0, 0, rows, :] = (acc / l_safe).astype(o_ref.dtype)
-        lse_ref[0, 0, rows, :] = jnp.where(l == 0, -jnp.inf, m + jnp.log(l_safe))
+        lse = jnp.where(l == 0, -jnp.inf, m + jnp.log(l_safe))  # (sub_q, 1)
+        for c in range(0, sub_q, LANES):
+            lse_ref[0, 0, a, :, pl.ds(c, LANES)] = _row(lse[c:c + LANES])
 
     for a in range(gq // sub_q):
         q_block(a)
@@ -330,7 +424,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, sub_q, sub_kv, ca
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *, scale, sub_q, sub_kv,
                    causal, split, kv_len, one_step):
-    """The forward's grid and walk. lse / delta: (1, 1, gq, 1) columns."""
+    """The forward's grid and walk. lse / delta: the forward's lse blocks,
+    turned into the columns the scores take once a q block."""
     gq, d = q_ref.shape[2], q_ref.shape[3]
     base = 0 if one_step else pl.program_id(2) * gq
     n_kv = k_ref.shape[2] // sub_kv
@@ -348,9 +443,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *, s
         do = do_ref[0, 0, rows, :]
         # -inf marks attended-nothing (padding) rows; neutralize so exp(s - lse)
         # stays finite — their dq is sliced away / masked out downstream
-        lse = lse_ref[0, 0, rows, :]  # (sub_q, 1)
+        lse, delta = _columns(lse_ref, delta_ref, a)  # (sub_q, 1)
         lse = jnp.where(jnp.isfinite(lse), lse, 0.0)
-        delta = delta_ref[0, 0, rows, :]
 
         def body(masked, j, dq):
             kv_start = j * sub_kv
@@ -382,19 +476,18 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *, s
         q_block(a)
 
 
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref, rows_ref, *, scale,
-                    sub_q, sub_kv, causal, split, q_len, kv_len, one_step):
+def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref, *, scale, sub_q,
+                    sub_kv, causal, split, q_len, kv_len, one_step):
     """Grid: (B, H, kv grid blocks). k/v blocks (1, 1, gkv, D) come from the
     (possibly grouped) KV head for query head h; dk/dv are written per
     *query* head (into (B, H, Tkv, D)) and group-summed by the caller. q / do:
-    the full (padded) head; lse / delta: (1, 1, Tq, 1) columns.
+    the full (padded) head; lse / delta: (1, 1, n_q, 1, sub_q), a q block's
+    values a row.
 
     The scores are computed TRANSPOSED (``k q^T``, a block is (sub_kv,
     sub_q)), so that ``p^T do`` and ``ds^T q`` are plain products and no
     block of scores goes through a transpose. lse and delta then ride along
-    the lanes: the step first turns its two columns into rows, one
-    (128, 128) transpose a 128 q rows, kept in ``rows_ref`` (n_q, 8, sub_q):
-    row 0 lse, row 1 delta."""
+    the lanes, as they rest."""
     gkv, d = k_ref.shape[2], k_ref.shape[3]
     base = 0 if one_step else pl.program_id(2) * gkv
     n_q = q_ref.shape[2] // sub_q
@@ -402,17 +495,6 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_
     q_padded, kv_padded = bool(q_len % sub_q), bool(kv_len % sub_kv)
     ik = jax.lax.broadcasted_iota(jnp.int32, (sub_kv, sub_q), 0)
     iq = jax.lax.broadcasted_iota(jnp.int32, (sub_kv, sub_q), 1)
-
-    lane = jax.lax.broadcasted_iota(jnp.int32, (LANES, LANES), 1)
-    for i in range(n_q):
-        for c in range(sub_q // LANES):
-            rows = pl.ds(i * sub_q + c * LANES, LANES)
-            lse = lse_ref[0, 0, rows, :]  # (128, 1)
-            # -inf marks attended-nothing (padding) rows; neutralize so
-            # exp(s - lse) stays finite: their p is masked out below
-            lse = jnp.where(jnp.isfinite(lse), lse, 0.0)
-            cols = jnp.where(lane == 0, lse, jnp.where(lane == 1, delta_ref[0, 0, rows, :], 0.0))
-            rows_ref[i, :, pl.ds(c * LANES, LANES)] = cols.T[:8]
 
     def kv_block(b):
         kv_start = base + b * sub_kv
@@ -427,7 +509,10 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_
             if prescale:
                 q = q * scale
             do = do_ref[0, 0, pl.ds(q_start, sub_q), :]
-            lse, delta = rows_ref[i, 0:1, :], rows_ref[i, 1:2, :]  # (1, sub_q)
+            lse, delta = lse_ref[0, 0, i], delta_ref[0, 0, i]  # (1, sub_q)
+            # -inf marks attended-nothing (padding) rows; neutralize so
+            # exp(s - lse) stays finite: their p is masked out below
+            lse = jnp.where(jnp.isfinite(lse), lse, 0.0)
 
             s = _dot(k, q, 1, 1)  # (sub_kv, sub_q)
             if not prescale:
@@ -467,18 +552,18 @@ def _pad_to(n, m):
 
 def _vmem_estimate(kernel, T_q, T_kv, grid, sub_q, sub_kv, D, itemsize):
     """VMEM bytes of one grid step as Mosaic lays its blocks out (lanes pad to
-    128, a trailing axis of 1 to a whole lane tile, pipelined operands are
-    double-buffered), and about eight float32 copies of one block of scores
-    with the accumulators."""
+    128, the one row of a statistics block to a sublane tile at most,
+    pipelined operands are double-buffered), and about eight float32 copies
+    of one block of scores with the accumulators."""
     wide = _pad_to(D, LANES)
     row = lambda t: t * wide * itemsize  # (t, D) in the operand dtype
-    col = lambda t: t * LANES * 4  # (t, 1) float32
+    stat = lambda t: stats_bytes((1, t))[0]  # (1, t) float32
     if kernel == "fwd":
-        blocks = 2 * row(grid) + 2 * row(T_kv) + col(grid)
+        blocks = 2 * row(grid) + 2 * row(T_kv) + stat(grid)
     elif kernel == "dq":
-        blocks = 3 * row(grid) + 2 * row(T_kv) + 2 * col(grid)
+        blocks = 3 * row(grid) + 2 * row(T_kv) + 2 * stat(grid)
     else:
-        blocks = 2 * row(T_q) + 4 * row(grid) + 2 * col(T_q) + 8 * T_q * 4
+        blocks = 2 * row(T_q) + 4 * row(grid) + 2 * stat(T_q)
     scores = 8 * sub_q * _pad_to(sub_kv, LANES) * 4
     acc = 3 * max(sub_q, sub_kv) * wide * 4
     return 2 * blocks + scores + acc
@@ -533,12 +618,11 @@ def plan(T, T_kv, D, dtype, causal, block_q=512, block_kv=512):
         half = size // 2
         return half if half % LANES == 0 and _pad_to(length, half) < _pad_to(length, size) else size
 
-    def lanes(name, sub_q):  # the dk/dv kernel's q rows lie along the lanes
-        return _pad_to(sub_q, LANES) if name == "dkv" else sub_q
-
-    sq, skv = sub(SUB_BLOCK[0], bq, T), sub(SUB_BLOCK[1], bkv, T_kv)
-    whole = {name: kernel_plan(name, 0, lanes(name, sq), skv, True)
-             for name in ("fwd", "dq", "dkv")}
+    # a block's q rows lie along the lanes of the statistics (and of the
+    # dk/dv kernel's scores): whole lane tiles of them
+    sq, skv = _pad_to(sub(SUB_BLOCK[0], bq, T), LANES), sub(SUB_BLOCK[1], bkv, T_kv)
+    bq = _pad_to(bq, LANES)
+    whole = {name: kernel_plan(name, 0, sq, skv, True) for name in ("fwd", "dq", "dkv")}
     fallback = ""
     if all(k.masked_pct == 100.0 for k in whole.values()):
         fallback = "no block lies wholly inside the mask"
@@ -553,7 +637,7 @@ def plan(T, T_kv, D, dtype, causal, block_q=512, block_kv=512):
     if not fallback:
         return Plan(whole["fwd"], whole["dq"], whole["dkv"], "")
     return Plan(kernel_plan("fwd", bq, bq, bkv, False), kernel_plan("dq", bq, bq, bkv, False),
-                kernel_plan("dkv", bkv, lanes("dkv", bq), bkv, False), fallback)
+                kernel_plan("dkv", bkv, bq, bkv, False), fallback)
 
 
 def _pad_seq(x, block):
@@ -567,19 +651,26 @@ def _pad_seq(x, block):
 _COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=_pallas.VMEM_LIMIT_BYTES)
 
 
-def _fit_rows(x, rows):
-    """``x`` (B, H, t, n) cut or zero-padded to ``rows`` along its third axis
-    (the forward's and the backward's sub-blocks may pad a length
+def _fit(x, rows):
+    """``x`` (B, H, t, ...) cut or zero-padded to ``rows`` along its third
+    axis (the forward's and the backward's sub-blocks may pad a length
     differently)."""
     t = x.shape[2]
     if t >= rows:
         return x[:, :, :rows]
-    return jnp.pad(x, ((0, 0), (0, 0), (0, rows - t), (0, 0)))
+    return jnp.pad(x, [(0, 0), (0, 0), (0, rows - t)] + [(0, 0)] * (x.ndim - 3))
+
+
+def _stats(x, B, H, Tq, sub_q):
+    """A statistic as the three kernels exchange it: ``(B, H, n_q, 1,
+    sub_q)``, a q block's values a row. ``x`` holds a value a q row in any
+    shape behind (B, H)."""
+    return _fit(x.reshape(B, H, -1), Tq).reshape(B, H, Tq // sub_q, 1, sub_q)
 
 
 def _fwd_call(q, k, v, *, kp, causal, scale, interpret):
     """The forward kernel's call on unpadded operands: ``(out, lse)`` at the
-    padded length."""
+    padded length, lse as :func:`_stats` gives it."""
     B, H, T, D = q.shape
     T_kv, g = k.shape[2], H // k.shape[1]
     qp, kp_, vp = _pad_seq(q, kp.sub_q), _pad_seq(k, kp.sub_kv), _pad_seq(v, kp.sub_kv)
@@ -596,11 +687,11 @@ def _fwd_call(q, k, v, *, kp, causal, scale, interpret):
         ],
         out_specs=[
             pl.BlockSpec((1, 1, gq, D), lambda b, h, i: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, gq, 1), lambda b, h, i: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, gq // kp.sub_q, 1, kp.sub_q), lambda b, h, i: (b, h, i, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, H, Tq, D), q.dtype),
-            jax.ShapeDtypeStruct((B, H, Tq, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, Tq // kp.sub_q, 1, kp.sub_q), jnp.float32),
         ],
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
@@ -608,13 +699,14 @@ def _fwd_call(q, k, v, *, kp, causal, scale, interpret):
 
 
 def _bwd_operands(q, k, v, do, lse, delta, kp):
-    Tq = _pad_to(q.shape[2], kp.sub_q)
+    B, H, T = q.shape[:3]
+    Tq = _pad_to(T, kp.sub_q)
     return (_pad_seq(q, kp.sub_q), _pad_seq(k, kp.sub_kv), _pad_seq(v, kp.sub_kv),
-            _fit_rows(do, Tq), _fit_rows(lse, Tq), _fit_rows(delta, Tq))
+            _fit(do, Tq), _stats(lse, B, H, Tq, kp.sub_q), _stats(delta, B, H, Tq, kp.sub_q))
 
 
 def _dq_call(q, k, v, do, lse, delta, *, kp, causal, scale, interpret):
-    """The dq kernel's call: dq at q's length. lse / delta: (B, H, t, 1)."""
+    """The dq kernel's call: dq at q's length. lse / delta: a value a q row."""
     B, H, T, D = q.shape
     T_kv, grp = k.shape[2], H // k.shape[1]
     args = _bwd_operands(q, k, v, do, lse, delta, kp)
@@ -628,8 +720,8 @@ def _dq_call(q, k, v, do, lse, delta, *, kp, causal, scale, interpret):
             pl.BlockSpec((1, 1, Tkv, D), lambda b, h, i: (b, h // grp, 0, 0)),
             pl.BlockSpec((1, 1, Tkv, D), lambda b, h, i: (b, h // grp, 0, 0)),
             pl.BlockSpec((1, 1, gq, D), lambda b, h, i: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, gq, 1), lambda b, h, i: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, gq, 1), lambda b, h, i: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, gq // kp.sub_q, 1, kp.sub_q), lambda b, h, i: (b, h, i, 0, 0)),
+            pl.BlockSpec((1, 1, gq // kp.sub_q, 1, kp.sub_q), lambda b, h, i: (b, h, i, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, gq, D), lambda b, h, i: (b, h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, Tq, D), q.dtype),
@@ -655,8 +747,8 @@ def _dkv_call(q, k, v, do, lse, delta, *, kp, causal, scale, interpret):
             pl.BlockSpec((1, 1, gkv, D), lambda b, h, j: (b, h // grp, j, 0)),
             pl.BlockSpec((1, 1, gkv, D), lambda b, h, j: (b, h // grp, j, 0)),
             pl.BlockSpec((1, 1, Tq, D), lambda b, h, j: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, Tq, 1), lambda b, h, j: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, Tq, 1), lambda b, h, j: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, Tq // kp.sub_q, 1, kp.sub_q), lambda b, h, j: (b, h, 0, 0, 0)),
+            pl.BlockSpec((1, 1, Tq // kp.sub_q, 1, kp.sub_q), lambda b, h, j: (b, h, 0, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, gkv, D), lambda b, h, j: (b, h, j, 0)),
@@ -666,7 +758,6 @@ def _dkv_call(q, k, v, do, lse, delta, *, kp, causal, scale, interpret):
             jax.ShapeDtypeStruct((B, H, Tkv, D), k.dtype),
             jax.ShapeDtypeStruct((B, H, Tkv, D), v.dtype),
         ],
-        scratch_shapes=[pltpu.VMEM((Tq // kp.sub_q, 8, kp.sub_q), jnp.float32)],
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(*args)
@@ -705,7 +796,7 @@ def _flash_call(q, k, v, causal, block_q, block_kv, scale, plan_=None):
     assert H % Hkv == 0, f"query heads {H} not a multiple of kv heads {Hkv}"
     if plan_ is None:
         plan_ = plan(T, T_kv, D, jnp.dtype(q.dtype), causal, block_q, block_kv)
-        tally(plan_)
+        tally(plan_, (q.shape[0], H, -(-T // plan_.fwd.sub_q), 1, plan_.fwd.sub_q))
     return attn("fwd", q, k, v, kp=plan_.fwd, causal=causal,
                 scale=scale if scale is not None else 1.0 / (D**0.5),
                 interpret=_pallas.interpret())
@@ -739,9 +830,9 @@ def _flash_bwd_impl(causal, block_q, block_kv, scale, res, g_out, delta_shift=No
     if plan_ is None:
         plan_ = plan(T, T_kv, D, jnp.dtype(q.dtype), causal, block_q, block_kv)
 
-    # delta over the logical rows: the XLA reduction it was
+    # delta over the logical rows: the XLA reduction it was, T in the lanes
     delta = jnp.einsum("bhtd,bhtd->bht", g_out.astype(jnp.float32),
-                       out_p[:, :, :T].astype(jnp.float32))[..., None]  # (B, H, T, 1)
+                       out_p[:, :, :T].astype(jnp.float32))  # (B, H, T)
     if delta_shift is not None:
         delta = delta - delta_shift.astype(jnp.float32)
 
@@ -772,7 +863,7 @@ def flash_attention_with_lse(q, k, v, causal=True, block_q=512, block_kv=512, sc
 def _flash_lse_fwd(q, k, v, causal, block_q, block_kv, scale):
     T = q.shape[2]
     out_p, lse = _flash_call(q, k, v, causal, block_q, block_kv, scale)
-    return (out_p[:, :, :T], lse[:, :, :T, 0]), (q, k, v, out_p, lse)
+    return (out_p[:, :, :T], lse.reshape(*lse.shape[:2], -1)[:, :, :T]), (q, k, v, out_p, lse)
 
 
 def _flash_lse_bwd(causal, block_q, block_kv, scale, res, g):
@@ -782,7 +873,7 @@ def _flash_lse_bwd(causal, block_q, block_kv, scale, res, g):
     both kernels unchanged."""
     g_out, g_lse = g
     return _flash_bwd_impl(causal, block_q, block_kv, scale, res, g_out,
-                           delta_shift=g_lse[..., None])
+                           delta_shift=g_lse)
 
 
 flash_attention_with_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)

@@ -1275,14 +1275,17 @@ class DeepSpeedEngine:
                        "micro_batches": gas})
             self._emit_step_counters()
             # a step that traced its program says what its flash kernels
-            # compute: scores against the mask's, and the masked body's share
-            plans = flash_attention.traced()[len(flash_before):]
-            if plans:
-                self.telemetry.gauges([
-                    ("kernels/flash_scores_computed_pct",
-                     sum(p.computed_pct for p in plans) / len(plans), self.global_samples),
-                    ("kernels/flash_scores_masked_pct",
-                     sum(p.masked_pct for p in plans) / len(plans), self.global_samples)])
+            # compute (scores against the mask's, the masked body's share)
+            # and what lse and delta take in HBM a layer call, beside their
+            # values: a layout that pads shows up as the ratio
+            calls = flash_attention.traced()[len(flash_before):]
+            if calls:
+                mean = lambda get: sum(map(get, calls)) / len(calls)
+                self.telemetry.gauges([(name, mean(get), self.global_samples) for name, get in (
+                    ("kernels/flash_scores_computed_pct", lambda c: c.plan.computed_pct),
+                    ("kernels/flash_scores_masked_pct", lambda c: c.plan.masked_pct),
+                    ("kernels/flash_stats_bytes_at_rest", lambda c: c.stats_bytes_at_rest),
+                    ("kernels/flash_stats_bytes_values", lambda c: c.stats_bytes_values))])
             # and what its cross-entropy asks of the links for the head's
             # weight gradient: one loss a microbatch, so the last backward
             # traced stands for each of them

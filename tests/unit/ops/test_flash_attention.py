@@ -159,6 +159,71 @@ def test_with_lse_and_its_cotangent(causal, T):
     assert_all_close(got, want, 2e-4)
 
 
+@pytest.mark.parametrize("hkv", [4, 2], ids=["mha", "gqa"])
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+@pytest.mark.parametrize("T,block", [(200, 512), (333, 128)])
+def test_statistics_rows_off_the_lane_tile(T, block, causal, hkv):
+    """lse and delta cross HBM as rows of whole lane tiles: a length that is
+    no multiple of 128 pads them (200 -> one block of 256, under the single
+    masked body where causal; 333 -> three sub-blocks of 128). Gradients of both entry
+    points against the float32 reference, the second with a non-zero lse
+    cotangent riding on delta."""
+    q, _, _ = make_qkv(T=T, B=1, H=4)
+    _, k, v = tuple(x[:, :hkv] for x in make_qkv(T=T, B=1, H=4, seed=1))
+    kx, vx = jnp.repeat(k, 4 // hkv, axis=1), jnp.repeat(v, 4 // hkv, axis=1)
+    p = fa.plan(T, T, 64, q.dtype, causal, block, block)
+    assert p.fwd.sub_q % fa.LANES == 0 and p.dq.sub_q == p.dkv.sub_q == p.fwd.sub_q
+    assert bool(p.fallback) == (block == 512 and causal)
+
+    def folded(grads_):  # the reference's per expanded head, summed onto the shared KV heads
+        fold = lambda g: g.reshape(1, hkv, 4 // hkv, T, 64).sum(2)
+        return grads_[0], fold(grads_[1]), fold(grads_[2])
+
+    assert_all_close(grads(flash_attention, q, k, v, causal, block, block),
+                     folded(grads(ref_attn, q, kx, vx, causal)), 2e-4)
+
+    def loss(attn, *operands):
+        def f(q, k, v):
+            o, l = attn(q, k, v)
+            return jnp.sum(o**2) + jnp.sum(jnp.sin(l))
+        return jax.grad(f, argnums=(0, 1, 2))(*operands)
+
+    out, lse = flash_attention_with_lse(q, k, v, causal, block, block)
+    assert lse.shape == (1, 4, T) and lse.dtype == jnp.float32
+    np.testing.assert_allclose(
+        np.asarray(lse), np.asarray(jax.nn.logsumexp(ref_scores(q, kx, causal), -1)), atol=2e-5)
+    got = loss(lambda q, k, v: flash_attention_with_lse(q, k, v, causal, block, block), q, k, v)
+    want = loss(lambda q, k, v: (ref_attn(q, k, v, causal),
+                                 jax.nn.logsumexp(ref_scores(q, k, causal), -1)), q, kx, vx)
+    assert_all_close(got, folded(want), 2e-4)
+
+
+@pytest.mark.parametrize("causal,T,S", [(True, 200, 200), (False, 333, 200), (False, 100, 384)])
+def test_rows_that_attend_nothing(causal, T, S):
+    """The lse the forward hands the backward is (B, H, q blocks, 1, sub_q),
+    a q block's values a row, float32. A row that attended nothing would carry -inf there; the only
+    rows that can are the padded ones (a logical row always sees key 0), and
+    both backward kernels neutralise it: with -inf on the padded rows every
+    gradient is finite, the same bits as with the forward's own values
+    there, and the reference's."""
+    q, _, _ = make_qkv(T=T, B=1, H=2)
+    _, k, v = make_qkv(T=S, B=1, H=2, seed=1)
+    do = make_qkv(T=T, B=1, H=2, seed=2)[0]
+    p = fa.plan(T, S, 64, q.dtype, causal, 128, 128)
+    blocks = -(-T // p.fwd.sub_q)
+    out, lse = fa._flash_call(q, k, v, causal, 128, 128, None)
+    assert lse.shape == (1, 2, blocks, 1, p.fwd.sub_q) and lse.dtype == jnp.float32
+    assert blocks * p.fwd.sub_q > T and np.isfinite(np.asarray(lse)).all()
+    nothing = lse.reshape(1, 2, -1).at[..., T:].set(-jnp.inf).reshape(lse.shape)
+    got = fa._flash_bwd_impl(causal, 128, 128, None, (q, k, v, out, nothing), do)
+    own = fa._flash_bwd_impl(causal, 128, 128, None, (q, k, v, out, lse), do)
+    for a, b in zip(got, own):
+        assert np.isfinite(np.asarray(a)).all()
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    _, vjp = jax.vjp(lambda q, k, v: ref_attn(q, k, v, causal), q, k, v)
+    assert_all_close(got, vjp(do), 2e-4)
+
+
 def test_bf16_operands():
     """bf16 operands into every product at a training cell's head: against
     the float32 reference on the same (rounded) operands."""
@@ -236,9 +301,13 @@ def test_tally_notes_a_traced_calls_plan():
     before = fa.traced()
     q, k, v = make_qkv(T=256, B=1, H=1)
     jax.jit(lambda q, k, v: flash_attention(q, k, v, True, 128, 128)).lower(q, k, v)
-    new = fa.traced()[len(before):]
-    assert new == (fa.plan(256, 256, 64, q.dtype, True, 128, 128), )
-    assert 100 < new[0].computed_pct < 150 and 0 < new[0].masked_pct < 100
+    (new, ) = fa.traced()[len(before):]
+    assert new.plan == fa.plan(256, 256, 64, q.dtype, True, 128, 128)
+    assert 100 < new.plan.computed_pct < 150 and 0 < new.plan.masked_pct < 100
+    # lse and delta, 256 float32 each: a row rests at its values, the column
+    # it was at 128 times them
+    assert (new.stats_bytes_at_rest, new.stats_bytes_values) == (2 * 256 * 4, 2 * 256 * 4)
+    assert fa.stats_bytes((1, 1, 256, 1)) == (128 * 256 * 4, 256 * 4)
 
 
 def test_engine_sets_the_flash_gauges(tmp_path):
@@ -268,9 +337,12 @@ def test_engine_sets_the_flash_gauges(tmp_path):
         set_sink(None)
     with open(engine.telemetry.jsonl_path) as f:
         gauges = [ev for ev in map(json.loads, f) if ev["type"] == "gauge"]
-    p = fa.traced()[-1]
+    call = fa.traced()[-1]
+    p = call.plan
     assert not p.fallback and p.fwd.sub_q == 128
     for name, want in (("kernels/flash_scores_computed_pct", p.computed_pct),
-                       ("kernels/flash_scores_masked_pct", p.masked_pct)):
+                       ("kernels/flash_scores_masked_pct", p.masked_pct),
+                       ("kernels/flash_stats_bytes_at_rest", call.stats_bytes_at_rest),
+                       ("kernels/flash_stats_bytes_values", call.stats_bytes_values)):
         got = [ev["value"] for ev in gauges if ev["name"] == name]
         assert got == [pytest.approx(want)]

@@ -14,6 +14,7 @@ test has started.
 
 import collections
 import dataclasses
+import math
 import os
 import re
 
@@ -31,13 +32,17 @@ MODELS = ("gpt2-large", "llama2-7b")
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     try:
-        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     except Exception as e:
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
@@ -89,11 +94,11 @@ def _flash_shapes(name):
     return 4, 1024, get_model(name).cfg
 
 
-@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd_bwd"])
-@pytest.mark.parametrize("name", MODELS + ("opt-1.3b", ))
-def test_flash_attention(for_chip, name, grad):
+def _flash_layer(for_chip, name):
+    """``(forward, its gradient, operands)`` of a layer's flash attention at
+    the preset's training shapes."""
     from deepspeed_tpu.ops.pallas.flash_attention import sharded_flash_attention
-    sds, compile_ = for_chip
+    sds, _ = for_chip
     batch, seq, cfg = _flash_shapes(name)
     q = sds((batch, cfg.num_heads, seq, cfg.head_size), jnp.bfloat16)
     kv = sds((batch, cfg.kv_heads, seq, cfg.head_size), jnp.bfloat16)
@@ -104,11 +109,16 @@ def test_flash_attention(for_chip, name, grad):
                                            block_q=cfg.attention_block_q,
                                            block_kv=cfg.attention_block_kv)
 
-    if grad:
-        text = compile_(jax.grad(lambda *a: fwd(*a).astype(jnp.float32).sum(),
-                                 argnums=(0, 1, 2)), q, kv, kv)
-    else:
-        text = compile_(fwd, q, kv, kv)
+    fwd_bwd = jax.grad(lambda *a: fwd(*a).astype(jnp.float32).sum(), argnums=(0, 1, 2))
+    return fwd, fwd_bwd, (q, kv, kv)
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd_bwd"])
+@pytest.mark.parametrize("name", MODELS + ("opt-1.3b", ))
+def test_flash_attention(for_chip, name, grad):
+    _, compile_ = for_chip
+    fwd, fwd_bwd, operands = _flash_layer(for_chip, name)
+    text = compile_(fwd_bwd if grad else fwd, *operands)
     # forward, dq and dk/dv stay three calls a layer under the name the
     # device trace had for them: the benchmark's flash_attention_roofline
     # reads ``^(attn|shard_map)[ .].* custom-call$`` and counts a unit of
@@ -116,6 +126,88 @@ def test_flash_attention(for_chip, name, grad):
     calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
     assert len(calls) == (3 if grad else 1)
     assert all(re.match(r"\s*(ROOT )?%(attn|shard_map)\.\d+ = ", line) for line in calls), calls
+
+
+def _at_rest(dims, minor_to_major, tile):
+    """Elements an array of ``dims`` takes as it rests, over its values: the
+    two minor axes of its layout pad to the tile."""
+    physical = [dims[i] for i in reversed(minor_to_major)]  # major to minor
+    padded = [-(-n // t) * t for n, t in zip(physical[-2:], tile)]
+    return padded[0] * padded[1] / (physical[-2] * physical[-1])
+
+
+@pytest.mark.parametrize("name", ["gpt2-large", "opt-1.3b"], ids=["cell1", "cell3"])
+def test_flash_statistics_rest_along_the_lanes(for_chip, name):
+    """lse and delta cross HBM between the forward call and the two backward
+    calls with the sequence in the lanes: no ``f32[B,H,T,1]`` column under an
+    (8, 128) tile (128 times its values: 1.5 GB over cell 1's 36 layers,
+    which put its step over the chip's memory), and every float32 array of a
+    value a row (lse, delta and each relayout between the reduction that
+    makes delta and the kernels) rests within 8x of its values."""
+    _, compile_ = for_chip
+    _, fwd_bwd, operands = _flash_layer(for_chip, name)
+    text = compile_(fwd_bwd, *operands)
+    batch, heads, seq, _ = operands[0].shape
+    rows = batch * heads * seq
+    found = collections.Counter()
+    for dims, order, sub, lanes in re.findall(r"f32\[([\d,]+)\]\{([\d,]+):T\((\d+),(\d+)\)", text):
+        dims = [int(n) for n in dims.split(",")]
+        if math.prod(dims) != rows:
+            continue
+        assert not (dims[-1] == 1 and (int(sub), int(lanes)) == (8, 128)), (dims, order)
+        ratio = _at_rest(dims, [int(i) for i in order.split(",")], (int(sub), int(lanes)))
+        assert ratio <= 8, (dims, order, sub, lanes, ratio)
+        found[tuple(dims)] += 1
+    # what the three calls exchange: a q block's values a row
+    assert found[(batch, heads, seq // 256, 1, 256)]
+
+
+def _rematerialised(text):
+    """The entry computation's instructions the compiler's rematerialisation
+    pass made (``.remat`` in their names), as the result each computes again.
+    The pass runs only on a program over the chip's memory, and buys the
+    space back with these."""
+    entry = text[text.index("\nENTRY "):]
+    return re.findall(r"^\s*(?:ROOT )?%\S*\.remat\S* = \(?(\w+\[[\d,]*\])", entry, re.M)
+
+
+# (cell, micro-batch a chip or None for the cell's own): rematerialised
+# entry instructions. Cell 1 at its own 4 x 1024 sits on the limit
+# (``temps_gib`` 6.04 of the 6.20 the state leaves): PR 48's program held 50
+# (30 ``up_proj`` products, 12 q/k/v products, 8 in the cross-entropy), with
+# the statistics along the lanes one chunk of the cross-entropy's logits and
+# the tied embedding's bf16 cast are left.
+REMATERIALISED = {
+    ("gpt2-large.train.s1024", None): 4,
+    ("gpt2-large.train.s1024", 2): 0,
+    ("opt-1.3b.train.zero3.s2048", None): 0,
+}
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("cell,micro_batch", sorted(REMATERIALISED, key=str))
+def test_train_step_rematerialises(for_chip, topo, monkeypatch, cell, micro_batch):
+    """A training cell's whole step at full size through the engine
+    (``chipbench.rehearse``'s path), compiled for the described chips: how
+    many of its instructions run twice because the step does not fit. ~1 min
+    a case."""
+    from chipbench import cells, rehearse
+    from deepspeed_tpu.runtime.engine import DeepSpeedEngine
+    for name in ("_init_params", "_init_state"):  # rehearse_train replaces them for good
+        monkeypatch.setattr(DeepSpeedEngine, name, getattr(DeepSpeedEngine, name))
+    texts = []
+    monkeypatch.setattr(rehearse, "_report",
+                        lambda name, compiled, *a, **kw: texts.append(compiled.as_text()))
+    _, workload, root = cells.load_workload(cell)
+    if micro_batch:
+        workload["train"]["micro_batch_per_chip"] = micro_batch
+    rehearse.rehearse_train(workload, cells.load_config(workload["config"], root), topo)
+    (text, ) = texts
+    found = collections.Counter(_rematerialised(text))
+    assert sum(found.values()) == REMATERIALISED[cell, micro_batch], found
+    # what is left is the cross-entropy's: no layer's product runs twice
+    assert not {"bf16[4,1024,5120]", "bf16[4,20,1024,64]"} & set(found), found
+    assert not re.search(r"f32\[\d+,\d+,\d+,1\]\{[^}]*T\(8,128\)", text)
 
 
 # ------------------------------------------------------------ decode kernels
