@@ -13,6 +13,10 @@ host's copies of the lengths and spans alone:
   model with windowed or shared-row layers has to read;
 - ``serving/ssd_state_updates`` / ``serving/ssd_chunk_tokens``: the one-token
   updates and chunk positions of a model's Mamba-2 layers;
+- ``serving/short_conv_updates`` / ``serving/short_conv_chunk_tokens`` /
+  ``serving/short_conv_layer_calls``: the same two of a model's gated
+  short-convolution layers, and the forwards that run them (each reads the
+  operator's weights once);
 - ``serving/attn_keys_live`` / ``serving/attn_keys_walked``: the keys inside
   the rows' attended windows and the same rounded out to the blocks the paged
   decode kernel's walk fetches.
@@ -104,6 +108,7 @@ class RequiredWork:
         # Mamba-2 layers: what the counters of state updates multiply by
         mixer_of = getattr(cfg, "layer_parts", lambda i: ("full_attention", "mlp"))
         self.ssd_layers = sum(mixer_of(i)[0] == "mamba2" for i in range(cfg.num_layers))
+        self.conv_layers = sum(mixer_of(i)[0] == "short_conv" for i in range(cfg.num_layers))
         self.attn_walks = attention_walks(model, tp)
         self._walk_blocks = {}
 
@@ -137,29 +142,41 @@ class RequiredWork:
         tel.counter("serving/step_rows_live",
                     int(spans.sum()) + int(np.count_nonzero(spans)) * (ksteps - 1))
         self._count_attention_rows(lens, spans, ksteps, chunk)
-        self._count_state_updates(spans, ksteps, chunk)
+        self._count_state_updates(spans, ksteps, chunk, 1 + bool(split and width > 1))
         # the programs whose attention walks a row's extent chain
         ext_walk = key is not None and key[0] in ("fused_ext", "fused_seqp")
         self._count_attention_keys(lens, spans, width, ksteps, split, chunk, ext_walk)
 
-    def _count_state_updates(self, spans, ksteps, chunk):
-        """For a model with Mamba-2 layers: the work a sync's state layers
-        are REQUIRED to do, summed over forwards and Mamba-2 layers:
-        ``serving/ssd_state_updates``, the one-token updates (a live decode
+    def _count_state_updates(self, spans, ksteps, chunk, first_forwards):
+        """For a model with Mamba-2 or gated short-convolution layers: the
+        work a sync's state layers are REQUIRED to do, summed over forwards
+        and such layers: ``serving/ssd_state_updates`` /
+        ``serving/short_conv_updates``, the one-token updates (a live decode
         row in the first forward and in every substep it steps in), and
-        ``serving/ssd_chunk_tokens``, the positions of a prefill chunk (a
-        row's span past 1). Unless its chunk is final the prefill row stands
-        still in the substeps."""
-        if not self.ssd_layers:
+        ``serving/ssd_chunk_tokens`` / ``serving/short_conv_chunk_tokens``,
+        the positions of a prefill chunk (a row's span past 1). Unless its
+        chunk is final the prefill row stands still in the substeps. Also
+        ``serving/short_conv_layer_calls``: the forwards that run a
+        short-convolution layer (``first_forwards`` for the first, 2 where it
+        runs as two over the live rows, and one a substep), each of which
+        reads the operator's weights once."""
+        if not (self.ssd_layers or self.conv_layers):
             return
         live = spans > 0
         stepping = int(np.count_nonzero(live))
         if chunk is not None and not chunk[1] and live[chunk[0]]:
             stepping -= 1
         updates = int(np.count_nonzero(spans == 1)) + stepping * (ksteps - 1)
-        self.telemetry.counter("serving/ssd_state_updates", self.ssd_layers * updates)
-        self.telemetry.counter("serving/ssd_chunk_tokens",
-                               self.ssd_layers * int(spans[spans > 1].sum()))
+        tokens = int(spans[spans > 1].sum())
+        tel = self.telemetry
+        if self.ssd_layers:
+            tel.counter("serving/ssd_state_updates", self.ssd_layers * updates)
+            tel.counter("serving/ssd_chunk_tokens", self.ssd_layers * tokens)
+        if self.conv_layers:
+            tel.counter("serving/short_conv_updates", self.conv_layers * updates)
+            tel.counter("serving/short_conv_chunk_tokens", self.conv_layers * tokens)
+            tel.counter("serving/short_conv_layer_calls",
+                        self.conv_layers * (first_forwards + ksteps - 1))
 
     def _count_attention_rows(self, lens, spans, ksteps, chunk):
         """For a model with windowed or shared-row layers: the K/V positions

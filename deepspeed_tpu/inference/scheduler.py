@@ -212,6 +212,13 @@ attention layer ``"rows"``, an FFN-only layer nothing, and holds nothing; the
 pool is a state pool with the same operand, refusals and bypass counter. See
 ``benchmarks/SERVING.md`` ("One-sublayer blocks").
 
+**Gated short convolutions** (``lfm2_moe``: ``short_conv`` layers beside
+attention): such a layer declares ONE ``"state"`` leaf, the gated inputs of a
+slot's last ``short_conv_kernel - 1`` positions, and an attention layer its
+``"rows"``, packed at head size 64; the pool is a state pool with the same
+operand, refusals and bypass counter. See ``benchmarks/SERVING.md`` ("Gated
+short convolutions beside attention").
+
 **Required work**: what a dispatched step program HAS to do (the rows its
 forwards compute and the live ones among them, the K/V positions and keys its
 attention reads, its Mamba-2 layers' state updates), which the serving

@@ -325,3 +325,56 @@ def tiny_exaone_moe():
     import dataclasses
     cfg = _exaone_moe(64, 5, 4, 2, 16, 128, 8, 4, 8, 2, 32, 2.5, 256, 256)
     return dataclasses.replace(cfg, layer_windows=(8, 8, 8, 0, 8))
+
+
+# the published ``layer_types`` of LFM2-8B-A1B: three gated short convolutions
+# to each attention layer, 18 : 6 (attention in layers 2, 6, 10, 14, 18, 21)
+_LFM2_8B_LAYERS = "ccac" * 5 + "cacc"
+
+
+def lfm2_layers(pattern):
+    """``layer_types`` from a string of ``c`` (a gated short convolution) and
+    ``a`` (attention), one letter a layer."""
+    return tuple({"c": "short_conv", "a": "full_attention"}[ch] for ch in pattern)
+
+
+def _lfm2_moe(hidden, pattern, heads, kv_heads, head_dim, dense_ffn, first_dense, experts, top_k,
+              expert_ffn, conv_taps, vocab, seq, theta=1e6):
+    """An ``lfm2_moe`` stack (LiquidAI LFM2-8B-A1B): pre-norm blocks ``h = x +
+    Op(RMSNorm(x))``, ``y = h + FFN(RMSNorm(h))``, ``Op`` a gated short
+    convolution of ``conv_taps`` taps or grouped-query attention with RMSNorm
+    over each head of q and k and rotary positions on every attention layer;
+    the first ``first_dense`` layers a dense SwiGLU, above them gated experts
+    under a sigmoid router with a selection bias, the chosen scores
+    renormalised with the published 1e-6, NO shared expert; tied head.
+    Unrolled: the layers differ. Served only."""
+    return TransformerConfig(
+        vocab_size=vocab, hidden_size=hidden, num_layers=len(pattern), num_heads=heads,
+        num_kv_heads=kv_heads, head_dim=head_dim, intermediate_size=dense_ffn, max_seq_len=seq,
+        pos_embedding="rope", rope_theta=theta, norm="rmsnorm", activation="swiglu",
+        tie_embeddings=True, layernorm_epsilon=1e-5, attn_bias=False, mlp_bias=False,
+        layer_types=lfm2_layers(pattern), short_conv_kernel=conv_taps, qk_norm=True,
+        qk_norm_per_head=True, num_experts=experts, moe_top_k=top_k, moe_ffn_size=expert_ffn,
+        moe_shared_experts=0, moe_routed_scale=1.0, moe_scoring="sigmoid",
+        moe_renorm_eps=1e-6, moe_dropless=True, moe_first_dense=first_dense, scan_layers=False)
+
+
+@register("lfm2-8b-a1b")
+def lfm2_8b_a1b():
+    """LFM2-8B-A1B at its published sizes (huggingface.co/LiquidAI/LFM2-8B-A1B
+    config.json, ``model_type: lfm2_moe``): 24 layers, 18 gated short
+    convolutions of 3 taps and 6 attention layers (32 query and 8 key/value
+    heads of 64), layers 0 and 1 a dense SwiGLU of 7,168, above them 32
+    experts of 1,792 top-4, vocabulary 65,536 tied, 8.34 B parameters, 1.56 B
+    a token. ``num_layers`` is overridden together with ``layer_types``."""
+    return _lfm2_moe(2048, _LFM2_8B_LAYERS, 32, 8, 64, 7168, 2, 32, 4, 1792, 3, 65536, 128000)
+
+
+@register("tiny-lfm2-moe")
+def tiny_lfm2_moe():
+    """Test-scale ``lfm2_moe``: the published list's first six layers (conv,
+    conv, attention, conv, conv, conv: two dense layers and one whole period
+    of expert layers), head size 64 (so K and V rest packed beside the
+    convolutions' windows, as at the published sizes), 8 experts top-2, 3
+    taps."""
+    return _lfm2_moe(256, _LFM2_8B_LAYERS[:6], 4, 2, 64, 256, 2, 8, 2, 64, 3, 256, 256)

@@ -34,7 +34,7 @@ import jax.numpy as jnp
 import flax.linen as nn
 
 from .transformer import (TransformerConfig, _serves_by_spans, gdn_conv_init,
-                          gdn_dt_bias_init)
+                          gdn_dt_bias_init, last_live_inputs)
 
 
 def mamba2_a_log_init(key, shape, dtype=jnp.float32):
@@ -171,14 +171,10 @@ class Mamba2(nn.Module):
                 new_cache = None
             else:
                 # the last W - 1 LIVE inputs: rows [span, span + W - 1) of seq
-                # (GatedDeltaNet's pick)
                 if T == 1:
                     tail = jnp.where(live_row[:, None, None], seq[:, 1:], seq[:, :-1])
                 else:
-                    rows = q_spans[:, None] + jnp.arange(W - 1)[None, :]
-                    pick = (rows[:, :, None] == jnp.arange(T + W - 1)[None, None, :])
-                    tail = jnp.einsum("bjt,btc->bjc", pick.astype(seq.dtype), seq,
-                                      precision=jax.lax.Precision.HIGHEST)
+                    tail = last_live_inputs(seq, q_spans, W - 1)
                 keep = live_row[:, None, None, None]
                 new_cache = (
                     jnp.where(keep, state.astype(state_rest.dtype), state_rest),

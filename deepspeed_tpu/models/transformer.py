@@ -42,7 +42,10 @@ SAMBAY_TYPES = ("mamba", "diff_attention", "gmu", "cross_attention")
 # Each maps to (mixer, FFN), one of them absent
 ONE_SUBLAYER_TYPES = {"mamba2": ("mamba2", None), "attention": ("full_attention", None),
                       "moe": (None, "moe"), "mlp": (None, "mlp")}
-LAYER_TYPES = ("full_attention", "linear_attention") + SAMBAY_TYPES + tuple(ONE_SUBLAYER_TYPES)
+# "short_conv" (``lfm2``): a gated short convolution in attention's place of
+# a mixer-and-FFN block (:class:`ShortConv`)
+LAYER_TYPES = (("full_attention", "linear_attention", "short_conv") + SAMBAY_TYPES
+               + tuple(ONE_SUBLAYER_TYPES))
 
 
 def sambay_layers(num_layers, mb_per_layer, sliding_window):
@@ -127,6 +130,9 @@ class TransformerConfig:
     # moe_shared_experts x the routed width
     moe_shared_ffn_size: Optional[int] = None
     moe_routed_scale: float = 1.0  # routed_scaling_factor on the renormalised top-k weights
+    # what a sigmoid router adds to the chosen scores' sum before it divides by
+    # it (DeepSeek-V3's code 1e-20; ``lfm2_moe`` publishes 1e-6)
+    moe_renorm_eps: float = 1e-20
     # how the router scores: "softmax" (probabilities, top-k, renormalised),
     # or "sigmoid" (DeepSeek-V3's rule: s = sigmoid(logits); the k experts
     # are the top of s + a stored selection bias, their weights s itself,
@@ -156,7 +162,9 @@ class TransformerConfig:
     # per-layer blocks: one of LAYER_TYPES for each layer ("full_attention":
     # Attention; "linear_attention": the gated delta rule, GatedDeltaNet,
     # which holds a per-slot recurrent state and convolution window and no
-    # cache rows; SAMBAY_TYPES: Mamba, DiffAttention, GatedMemoryUnit and
+    # cache rows; "short_conv": ShortConv, which holds the last
+    # short_conv_kernel - 1 inputs of its convolution per slot and no cache
+    # rows; SAMBAY_TYPES: Mamba, DiffAttention, GatedMemoryUnit and
     # DiffAttention(cross), which hold state, rows or a ring of rows, nothing,
     # and nothing; ONE_SUBLAYER_TYPES: a block of ONE sublayer, a Mamba-2
     # mixer, attention, an expert FFN or a dense FFN alone, which hold state,
@@ -188,6 +196,8 @@ class TransformerConfig:
     linear_value_head_dim: int = 0
     linear_conv_kernel: int = 4  # causal depthwise convolution over q, k, v
     linear_neg_eigval: bool = False  # beta in (0, 2): negative eigenvalues of the transition
+    # a short_conv layer: taps of its causal depthwise convolution (conv_L_cache)
+    short_conv_kernel: int = 0
     # h = x + norm(mixer(x)), y = h + norm(ffn(h)): mixer and FFN (dense or
     # routed experts) read the residual stream itself and their OUTPUTS are
     # normalised (Olmo 2/3, Exaone 4)
@@ -277,6 +287,9 @@ class TransformerConfig:
                 raise ValueError("linear_attention layers need linear_num_heads, "
                                  "linear_key_head_dim, linear_value_head_dim and a "
                                  "convolution of width > 1")
+            if "short_conv" in self.layer_types and self.short_conv_kernel < 2:
+                raise ValueError("short_conv layers need short_conv_kernel (the taps of their "
+                                 "convolution) of 2 or more")
             sambay = set(self.layer_types) & set(SAMBAY_TYPES)
             object.__setattr__(self, "layer_windows", tuple(self.layer_windows))
             if self.layer_windows and (len(self.layer_windows) != self.num_layers or any(
@@ -335,8 +348,9 @@ class TransformerConfig:
             if experts_beside and (sambay or "linear_attention" in self.layer_types
                                    or not self.moe_dropless):
                 raise ValueError("experts in a mixer-and-FFN block under layer_types go with "
-                                 "full_attention layers and the dropless dispatch only "
-                                 "(elsewhere they live in one-sublayer moe layers)")
+                                 "full_attention and short_conv layers and the dropless "
+                                 "dispatch only (elsewhere they live in one-sublayer moe "
+                                 "layers)")
             if self.kv_lora_rank or self.parallel_residual or self.int8_weights:
                 raise ValueError("layer_types composes with plain attention in a float dtype "
                                  "only (no latent attention, parallel residual or int8 "
@@ -359,10 +373,12 @@ class TransformerConfig:
             raise ValueError("one multi-token-prediction module at most (mtp_layers 0 or 1)")
         if self.mtp_layers and (self.scan_layers or self.kv_lora_rank or self.int8_weights
                                 or self.carries_across_layers
-                                or set(self.layer_types) & set(ONE_SUBLAYER_TYPES)):
+                                or set(self.layer_types) & ({"short_conv"}
+                                                            | set(ONE_SUBLAYER_TYPES))):
             raise ValueError("the multi-token-prediction module is a block of attention and an "
                              "FFN behind an unrolled stack of such blocks (no latent "
-                             "attention, int8 weights, SambaY or one-sublayer kinds)")
+                             "attention, int8 weights, SambaY, short_conv or one-sublayer "
+                             "kinds)")
         if self.kv_lora_rank and not (self.q_lora_rank and self.qk_nope_head_dim
                                       and self.qk_rope_head_dim and self.v_head_dim
                                       and self.pos_embedding == "rope"):
@@ -547,7 +563,10 @@ class TransformerConfig:
         dense = self.moe_first_dense * (per_h * self.ffn_size - mlp)
         # the module: one expert block, W_eh over [embedding ; hidden], three norms
         mtp = self.mtp_layers * (attn + mlp + 2 * h + 2 * h * h + 3 * h)
-        return L * (attn + mlp + 2 * h) + dense + mtp + emb + pos + h
+        # a short_conv layer's operator in attention's place: W_in (B, C, X), the taps, W_out
+        n_conv = sum(t == "short_conv" for t in self.layer_types)
+        conv = n_conv * (h * 3 * h + h * self.short_conv_kernel + h * h - attn)
+        return L * (attn + mlp + 2 * h) + conv + dense + mtp + emb + pos + h
 
 
 def resolve_remat_policy(name):
@@ -1865,6 +1884,18 @@ def gated_delta_chunked(S, q, k, v, g, beta, chunk=GDN_CHUNK):
     return o[:, :, :T], S
 
 
+def last_live_inputs(seq, q_spans, n):
+    """Rows ``[span, span + n)`` of ``seq`` (B, n + T, C), a convolution's ``n``
+    carried inputs in front of a call's T columns: the last ``n`` LIVE inputs
+    of a row whose first ``span`` columns are live. A one-hot product (a
+    per-row gather would rest with the ``n`` rows in the lanes, padded
+    forty-fold)."""
+    rows = q_spans[:, None] + jnp.arange(n)[None, :]
+    pick = (rows[:, :, None] == jnp.arange(seq.shape[1])[None, None, :])
+    return jnp.einsum("bjt,btc->bjc", pick.astype(seq.dtype), seq,
+                      precision=jax.lax.Precision.HIGHEST)
+
+
 class GatedDeltaNet(nn.Module):
     """Linear attention by the gated delta rule (Gated DeltaNet,
     arXiv:2412.06464, as ``olmo_hybrid``'s ``linear_*`` keys configure it):
@@ -1979,16 +2010,11 @@ class GatedDeltaNet(nn.Module):
                 new_cache = None
             else:
                 # the last W - 1 LIVE inputs: rows [span, span + W - 1) of seq.
-                # One column: the old window or the one a step on. Wider: a
-                # one-hot pick (a per-row gather would rest with the W - 1
-                # rows in the lanes, padded forty-fold)
+                # One column: the old window or the one a step on
                 if T == 1:
                     tail = jnp.where(live_row[:, None, None], seq[:, 1:], seq[:, :-1])
                 else:
-                    rows = q_spans[:, None] + jnp.arange(W - 1)[None, :]
-                    pick = (rows[:, :, None] == jnp.arange(T + W - 1)[None, None, :])
-                    tail = jnp.einsum("bjt,btc->bjc", pick.astype(seq.dtype), seq,
-                                      precision=jax.lax.Precision.HIGHEST)
+                    tail = last_live_inputs(seq, q_spans, W - 1)
                 if not in_place:
                     new_state = jnp.where(
                         live_row[:, None, None, None],
@@ -2115,14 +2141,10 @@ class Mamba(nn.Module):
                 new_cache = None
             else:
                 # the last W - 1 LIVE inputs: rows [span, span + W - 1) of seq
-                # (GatedDeltaNet's pick)
                 if T == 1:
                     tail = jnp.where(live_row[:, None, None], seq[:, 1:], seq[:, :-1])
                 else:
-                    rows = q_spans[:, None] + jnp.arange(W - 1)[None, :]
-                    pick = (rows[:, :, None] == jnp.arange(T + W - 1)[None, None, :])
-                    tail = jnp.einsum("bjt,btc->bjc", pick.astype(seq.dtype), seq,
-                                      precision=jax.lax.Precision.HIGHEST)
+                    tail = last_live_inputs(seq, q_spans, W - 1)
                 keep = live_row[:, None, None, None]
                 new_cache = (
                     jnp.where(keep, state[:, None].astype(state_rest.dtype), state_rest),
@@ -2131,6 +2153,76 @@ class Mamba(nn.Module):
         with jax.named_scope("ssm_out"):
             out = dense(H, name="out_proj")(m * jax.nn.silu(xz[..., di:]))
         return out, new_cache, dict(carry, m=m)
+
+
+class ShortConv(nn.Module):
+    """A gated short convolution (``lfm2``'s ``conv`` operator), the mixer of a
+    ``short_conv`` layer:
+
+        [B ; C ; X] = u W_in ; z_t = B_t * X_t
+        c_t = sum_j w[:, j] z_(t-W+1+j) ; out = (C_t * c_t) W_out
+
+    a causal depthwise convolution of ``W = short_conv_kernel`` taps over the
+    gated input, no bias, no activation, gated again on the way out. No
+    recurrence beyond the taps: what a slot holds for it is ``z`` of its last
+    ``W - 1`` positions, ONE leaf ``(B, 1, W - 1, hidden)`` at rest in the
+    serving dtype (``cache_spec``). Products in the compute dtype, the sum
+    over the taps in float32. Without a cache (full forward) the positions
+    before 0 read zeros. With one it is served through the slot pool's span
+    programs only, by :class:`GatedDeltaNet`'s rules: a row advances over
+    exactly its ``q_spans`` live columns (the rows left behind are the last
+    ``W - 1`` LIVE inputs, so a chunk of one live position keeps one old
+    row), a span-0 row's leaf comes out bit for bit as it went in, and a row
+    whose span starts at position 0 starts from zeros whatever the slot held.
+    The call signature is :class:`Attention`'s; adapters, extent chains,
+    sequence-parallel spans and padding masks are refused."""
+    cfg: TransformerConfig
+    layer_idx: int = -1
+
+    @nn.compact
+    def __call__(self, x, sin, cos, attn_mask=None, kv_cache=None, cache_index=None,
+                 position_ids=None, write_index=None, q_spans=None, lora_ops=None,
+                 ext_ops=None, seq_shard=False):
+        cfg = self.cfg
+        B, T, H = x.shape
+        W = cfg.short_conv_kernel
+        if lora_ops or ext_ops is not None or seq_shard or attn_mask is not None:
+            raise NotImplementedError("a short_conv layer serves without adapters, extent "
+                                      "chains, sequence-parallel spans or padding masks")
+        _serves_by_spans("short_conv", kv_cache, write_index, q_spans)
+        f32 = jnp.float32
+        dense = partial(nn.Dense, use_bias=False, dtype=cfg.dtype, param_dtype=f32,
+                        kernel_init=nn.initializers.normal(0.02))
+        with jax.named_scope("conv_proj"):
+            bcx = dense(3 * H, name="in_proj")(x)
+            z = bcx[..., :H] * bcx[..., 2 * H:]
+        with jax.named_scope("conv_state"):
+            conv_w = self.param("conv", gdn_conv_init, (H, W), f32)
+            if kv_cache is None:
+                window = jnp.zeros((B, W - 1, H), cfg.dtype)
+            else:
+                window_rest = kv_cache[0]
+                live_row = q_spans > 0
+                fresh = live_row & (write_index == 0)
+                window = jnp.where(fresh[:, None, None], 0, window_rest[:, 0]).astype(cfg.dtype)
+            seq = jnp.concatenate([window, z], axis=1)
+            c = sum(seq[:, j:j + T].astype(f32) * conv_w[:, j] for j in range(W))
+            if kv_cache is None:
+                new_cache = None
+            else:
+                # the last W - 1 LIVE inputs: rows [span, span + W - 1) of seq
+                # (one column shifts one in)
+                if T == 1:
+                    tail = seq[:, 1:]
+                else:
+                    tail = last_live_inputs(seq, q_spans, W - 1)
+                # (the layer's places in a wider cache tree hold nothing)
+                new_cache = (jnp.where(live_row[:, None, None, None],
+                                       tail[:, None].astype(window_rest.dtype), window_rest),
+                             ) + (None, ) * (len(kv_cache) - 1)
+        with jax.named_scope("conv_out"):
+            out = dense(H, name="out_proj")(bcx[..., H:2 * H] * c.astype(cfg.dtype))
+        return out, new_cache
 
 
 class GatedMemoryUnit(nn.Module):
@@ -2434,6 +2526,8 @@ class Block(nn.Module):
                 return narrow(h, kv_cache, write_index, q_spans, carry)[:2]
         elif mixer_kind == "linear_attention":
             mixer = GatedDeltaNet(cfg, layer_idx=self.layer_idx, name="gdn")
+        elif mixer_kind == "short_conv":
+            mixer = ShortConv(cfg, layer_idx=self.layer_idx, name="conv")
         else:
             attention = LatentAttention if cfg.kv_lora_rank else Attention
             mixer = attention(cfg, layer_idx=self.layer_idx, name="attn")
@@ -2932,7 +3026,8 @@ class CausalLMModel:
         last inputs of its convolution ``(B, 1, W - 1, channels)``, at rest
         in the cache dtype; a Mamba layer's ``(B, 1, d_state, d_inner)`` and
         ``(B, 1, W - 1, d_inner)``; a Mamba-2 layer's ``(B, heads, head size, d_state)``
-        and ``(B, 1, W - 1, conv channels)``) or ``"ring"`` (a row axis at ``ndim - 2`` of
+        and ``(B, 1, W - 1, conv channels)``; a short_conv layer's ONE leaf, the
+        ``W - 1`` last gated inputs of its convolution ``(B, 1, W - 1, hidden)``) or ``"ring"`` (a row axis at ``ndim - 2`` of
         ``cfg.ring_rows`` rows whatever ``max_len`` is, position ``p`` in row
         ``p mod R``: a windowed differential or full-attention layer's K and
         V, per-slot bytes as a state's are). Every component keeps its slot axis at ``ndim -
@@ -2986,11 +3081,13 @@ class CausalLMModel:
         # a multi-token-prediction module holds rows of its own, declared
         # behind the stack's layers like one more full-attention layer
         module = [tuple(rows)] * cfg.mtp_layers
-        if not {"linear_attention", "mamba2", None} & set(mixers) and not any(windows):
+        two_leaves = {"linear_attention", "mamba2", None} & set(mixers) or any(windows)
+        if not two_leaves and "short_conv" not in mixers:
             return [tuple(rows)] * cfg.num_layers + module
-        if quantized or len(rows) != 2:
-            raise NotImplementedError("a pool with state or ring leaves has no int8 tier and "
-                                      "no packed geometry")
+        # (packed rows beside state: a short_conv layer's one leaf alone)
+        if quantized or (len(rows) != 2 and two_leaves):
+            raise NotImplementedError("a pool with state or ring leaves has no int8 tier and, "
+                                      "but for short_conv layers' windows, no packed geometry")
         gdn_pack = gdn_step.state_packing(cfg.linear_num_heads, cfg.linear_value_head_dim)
         state = lambda shape, W, channels: (
             ("state", (batch_size, ) + shape, dt, jnp.zeros),
@@ -3004,6 +3101,8 @@ class CausalLMModel:
                                       cfg.linear_conv_kernel, cfg.linear_conv_channels),
             "mamba2": state((cfg.ssm_num_heads, cfg.ssm_head_dim, cfg.ssm_state_size),
                             cfg.ssm_conv_kernel, cfg.mamba2_conv_channels),
+            "short_conv": (("state", (batch_size, 1, cfg.short_conv_kernel - 1, cfg.hidden_size),
+                            dt, jnp.zeros), ),
             None: ()}  # an FFN alone
         return [ring(i) if w else declares[m]
                 for i, (m, w) in enumerate(zip(mixers, windows))] + module

@@ -134,13 +134,14 @@ def top_k_serving_choice(logits, k):
     return ids, jnp.take_along_axis(weights, ids, axis=-1)
 
 
-def sigmoid_serving_choice(logits, bias, k):
+def sigmoid_serving_choice(logits, bias, k, eps=1e-20):
     """The serving choice of a sigmoid router with a selection bias
     (DeepSeek-V3's rule, one group): ``s = sigmoid(logits)`` in fp32 over ALL
     experts; the k experts are the top k of ``s + bias`` (the same iterative
     argmax as :func:`_serving_top_k`: ties resolve to the lowest expert id);
     their weights are ``s`` itself, WITHOUT the bias, renormalised over the k
-    (``w / (sum w + 1e-20)``). Returns ``(expert ids (N, k) int32, weights (N,
+    (``w / (sum w + eps)``: DeepSeek-V3's code adds 1e-20, ``lfm2_moe``
+    publishes 1e-6; ``TransformerConfig.moe_renorm_eps``). Returns ``(expert ids (N, k) int32, weights (N,
     k) fp32)``; each row is a pure function of its logits."""
     s = jax.nn.sigmoid(logits.astype(jnp.float32))
     masked = s + bias.astype(jnp.float32)
@@ -152,4 +153,4 @@ def sigmoid_serving_choice(logits, bias, k):
                            masked)
     ids = jnp.stack(ids, axis=-1)
     w = jnp.take_along_axis(s, ids, axis=-1)
-    return ids, w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return ids, w / (jnp.sum(w, axis=-1, keepdims=True) + eps)

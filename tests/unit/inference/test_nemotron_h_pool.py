@@ -486,9 +486,11 @@ def test_existing_presets_build_the_trees_they_built(name):
 
 
 def test_no_preset_goes_unguarded():
-    # PR 41's presets are guarded by tests/unit/inference/test_exaone_moe_pool.py
+    # PR 41's presets are guarded by tests/unit/inference/test_exaone_moe_pool.py,
+    # PR 50's by tests/unit/inference/test_lfm2_moe_pool.py
     assert set(available_models()) == set(PARENT_TREES) | {
-        "nemotron-3-nano-30b-a3b", "tiny-nemotron-h", "k-exaone-236b-a23b", "tiny-exaone-moe"}
+        "nemotron-3-nano-30b-a3b", "tiny-nemotron-h", "k-exaone-236b-a23b", "tiny-exaone-moe",
+        "lfm2-8b-a1b", "tiny-lfm2-moe"}
 
 
 def test_preset_builds_the_published_sizes():

@@ -827,6 +827,74 @@ def test_exaone_moe_verify_and_draft_program(for_chip, width):
     assert 9.09e9 + 4.57e9 + mem.temp_size_in_bytes < 15.75 * 2**30, mem
 
 
+@pytest.mark.parametrize("width, collect", [(1, False), (512, False), (512, True)],
+                         ids=["decode", "chunk", "chunk-collecting"])
+def test_lfm2_moe_step_program(for_chip, width, collect):
+    """LFM2-8B-A1B's sync at the published widths as the chip benchmark serves
+    it (the slots of the cell's own file x 4096, ``steps_per_sync`` 4,
+    ``prefill_chunk`` 512, all 32 experts held, the whole vocabulary), the
+    scheduler's own program
+    (``DecodeScheduler._fused_fn``: greedy, and the collecting variant that
+    ``correct`` runs) with a dense layer under a gated short convolution, an
+    expert layer under attention and one under a convolution: the packed K/V
+    leaf (head size 64) beside the convolutions' two carried rows a slot,
+    per-head QK norm and rotation into the paged kernels at 4 query heads a
+    key head, the slots x 4 pairs (slots / 8 rows an expert; the chunk's 512
+    rows: 64) by the dense product over the 32 held (both widths multiples of
+    256): no grouped product, the expert kernels read as they rest. The
+    donated pool is updated in place: no whole-leaf copy of the K/V leaf in
+    the loop or around it. It fits with its temporaries beside the cell's
+    7.86 GB of weights and the 3.23 GB of pool that ISSUE 50's 128 slots
+    would take (the host's delivery, not memory, holds the cell under 128)."""
+    import json
+    import types
+    from deepspeed_tpu.inference.scheduler import DecodeScheduler
+    sds, _ = for_chip
+    with open(os.path.join(os.path.dirname(__file__), "..", "..", "..", "chipbench", "workloads",
+                           "lfm2-8b-a1b.serve.reason-closed.json")) as f:
+        serve = json.load(f)["serve"]
+    slots, pool_len, steps = serve["num_slots"], serve["max_len"], serve["steps_per_sync"]
+    assert (pool_len, steps, serve["prefill_chunk"]) == (4096, 4, 512) and 80 <= slots <= 128
+    base = get_model("lfm2-8b-a1b")
+    kinds = ("short_conv", "full_attention", "short_conv")
+    model = type(base)(dataclasses.replace(
+        base.cfg, dtype=jnp.bfloat16, num_layers=3, layer_types=kinds, moe_first_dense=1,
+        max_seq_len=pool_len, attention_impl="flash"))
+    abstract = lambda tree, dtype=None: jax.tree_util.tree_map(
+        lambda a: sds(a.shape, dtype or a.dtype), tree)
+    params = abstract(jax.eval_shape(model.init_params, jax.random.key(0)), jnp.bfloat16)
+    pool = abstract(jax.eval_shape(lambda: model.init_cache(slots, pool_len)))
+    shapes = sorted({leaf.shape for leaf in jax.tree_util.tree_leaves(pool)})
+    assert shapes == [(slots, 1, 2, 2048), (slots, 8, pool_len, 128)]
+    mock = types.SimpleNamespace(
+        engine=types.SimpleNamespace(module=model, model_config=model.cfg), _shard_deg=1,
+        _fused_block=False, _moe_stats=True, _moe=True, experts=None, _compiled={},
+        capacity=None, _pool_sharding=None, _state_pool=True,
+        cache=types.SimpleNamespace(num_slots=slots))
+    for name in ("_program", "_jit_step", "_moe_forward_stats", "_held_experts",
+                 "_splits_chunk"):
+        setattr(mock, name, types.MethodType(getattr(DecodeScheduler, name), mock))
+    assert mock._splits_chunk(("fused", False, collect, width, steps)) is (width > 1)
+    fn = DecodeScheduler._fused_fn(mock, False, collect, steps, width)
+    i32 = lambda *shape: sds(shape, jnp.int32)
+    args = (params, pool, i32(slots, width), i32(slots), i32(slots), sds((slots, ), jnp.uint32),
+            i32(slots), sds((slots, ), jnp.bool_), sds((slots, ), jnp.float32), i32(slots),
+            sds((slots, ), jnp.float32), i32(slots))
+    compiled = fn.lower(*args).compile()
+    text = compiled.as_text()
+    assert "dstpu_decode_attn" in text and "dstpu_kv_commit" in text
+    assert "ragged-dot" not in text and "moe_experts" in text
+    for scope in ("conv_proj", "conv_state", "conv_out"):
+        assert scope in text, scope
+    assert _pool_relayouts(text, "[" + ",".join(map(str, shapes[1])) + "]") == (0, 0)
+    for kernel in ("[32,2048,1792]", "[32,1792,2048]"):
+        assert _pool_relayouts(text, kernel) == (0, 0), kernel
+    mem = compiled.memory_analysis()
+    print(width, collect, "temporaries", mem.temp_size_in_bytes)
+    assert mem.temp_size_in_bytes < 1.5e9, mem
+    assert 7.86e9 + 3.23e9 + mem.temp_size_in_bytes < 15.75 * 2**30, mem
+
+
 def _accepted_cell_syncs():
     """(cell, model one period deep, slots, chunk, pool length, fused) of the
     serving cells the benchmark had before PR 39, as their tests above size
