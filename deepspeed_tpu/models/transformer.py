@@ -29,6 +29,7 @@ import flax.linen as nn
 from jax.sharding import PartitionSpec as P
 
 from ..comm import comm as dist
+from ..runtime.zero.gather_order import GatherOrder
 
 
 # the SambaY kinds (arXiv:2507.06607): a selective state-space layer, a
@@ -1209,6 +1210,33 @@ def _q_param(mod, name, k, n, group_size):
     return qw, sc
 
 
+def _product(spec, x, w, order, path="kernel"):
+    """``einsum(spec, x, w)``; under a ZeRO-3 gather order that holds the
+    weight's ``path``, the product whose gathers the program places."""
+    return jnp.einsum(spec, x, w) if order is None else order.product(spec, x, w, path)
+
+
+class OrderedDense(nn.Module):
+    """``nn.Dense`` (same parameter names, shapes and initialisers) for a
+    kernel that rests as a ZeRO-3 shard: the product states which product
+    its weight's gathers are due behind (``order``: a ``GatherOrder``)."""
+    features: int
+    use_bias: bool
+    dtype: Any
+    dx_behind_dw: bool = False  # the backward's regather is due behind this product's dW
+
+    @nn.compact
+    def __call__(self, x, order):
+        kernel = self.param("kernel", nn.initializers.normal(0.02),
+                            (x.shape[-1], self.features), jnp.float32)
+        y = order.product("...k,kn->...n", x.astype(self.dtype), kernel.astype(self.dtype),
+                          "kernel", self.dx_behind_dw)
+        if self.use_bias:
+            bias = self.param("bias", nn.initializers.zeros, (self.features, ), jnp.float32)
+            y = y + bias.astype(self.dtype)
+        return y
+
+
 class HeadProjection(nn.Module):
     """q/k/v projection emitting head-major ``(B, heads, T, head_dim)``
     directly — the matmul's output layout IS the attention layout, so no
@@ -1222,7 +1250,9 @@ class HeadProjection(nn.Module):
     int8_groups: int = 0  # scale-group SIZE (0 = default rule)
 
     @nn.compact
-    def __call__(self, x):  # (B, T, H) -> (B, heads, T, head_dim)
+    def __call__(self, x, order=None):  # (B, T, H) -> (B, heads, T, head_dim)
+        """``order``: the ZeRO-3 gather order of this module's leaves
+        (``runtime/zero/gather_order.py: GatherOrder``), or None."""
         B, T, H = x.shape
         if self.int8:
             qw, sc = _q_param(self, "kernel", H, self.heads * self.head_dim,
@@ -1232,7 +1262,7 @@ class HeadProjection(nn.Module):
         else:
             kernel = self.param("kernel", nn.initializers.normal(0.02),
                                 (x.shape[-1], self.heads, self.head_dim), jnp.float32)
-            y = jnp.einsum("bth,hnd->bntd", x, kernel.astype(self.dtype))
+            y = _product("bth,hnd->bntd", x, kernel.astype(self.dtype), order)
         if self.use_bias:
             bias = self.param("bias", nn.initializers.zeros, (self.heads, self.head_dim), jnp.float32)
             y = y + bias.astype(self.dtype)[None, :, None, :]
@@ -1249,7 +1279,7 @@ class OutProjection(nn.Module):
     int8_groups: int = 0  # scale-group SIZE (0 = default rule)
 
     @nn.compact
-    def __call__(self, x):  # (B, heads, T, hd) -> (B, T, features)
+    def __call__(self, x, order=None):  # (B, heads, T, hd) -> (B, T, features)
         B, n, T, d = x.shape
         if self.int8:
             qw, sc = _q_param(self, "kernel", n * d, self.features, self.int8_groups)
@@ -1258,7 +1288,7 @@ class OutProjection(nn.Module):
         else:
             kernel = self.param("kernel", nn.initializers.normal(0.02),
                                 (n, d, self.features), jnp.float32)
-            y = jnp.einsum("bntd,ndh->bth", x, kernel.astype(self.dtype))
+            y = _product("bntd,ndh->bth", x, kernel.astype(self.dtype), order)
         if self.use_bias:
             bias = self.param("bias", nn.initializers.zeros, (self.features, ), jnp.float32)
             y = y + bias.astype(self.dtype)
@@ -1289,7 +1319,7 @@ class Attention(nn.Module):
     @nn.compact
     def __call__(self, x, sin, cos, attn_mask=None, kv_cache=None, cache_index=None,
                  position_ids=None, write_index=None, q_spans=None, lora_ops=None,
-                 ext_ops=None, seq_shard=False):
+                 ext_ops=None, seq_shard=False, order=None):
         """``attn_mask`` semantics: without a cache it is (B, T) over the
         current tokens; with a cache it is (B, S) over cache slots (True =
         attendable, used for left-pad masking during generation).
@@ -1334,10 +1364,21 @@ class Attention(nn.Module):
         stays replicated so every shard's pool is byte-identical. Explicitly
         opt-in per program — ambient mesh detection would silently shard
         the reference chunked path.
+
+        ``order``: the ZeRO-3 gather order of this module's projections
+        (``GatherOrder``; training's unrolled forward only), or None.
         """
         cfg = self.cfg
         B, T, H = x.shape
         nh, nkv, hd = cfg.num_heads, cfg.kv_heads, cfg.head_size
+        sub = (lambda name: None) if order is None else order.sub
+        # under a ZeRO-3 gather order each projection's weight is gathered
+        # behind the projection before it (a product hides ONE gather): q's
+        # behind the layer below's attention (Block), then k's, v's and the
+        # output projection's in turn
+        behind = ((lambda y, name: y) if order is None else
+                  (lambda y, name: order.due_behind(y, {f"{name}/kernel": self.variables[
+                      "params"][name]["kernel"].astype(cfg.dtype)})))
         use_bias = cfg.attn_bias if cfg.attn_bias is not None else cfg.norm == "layernorm"
         # bhtd layout end-to-end: projections emit head-major
         i8, i8g = cfg.int8_weights, cfg.int8_group_size
@@ -1355,9 +1396,15 @@ class Attention(nn.Module):
             k = k.reshape(B, T, nkv, hd).transpose(0, 2, 1, 3)
             v = v.reshape(B, T, nkv, hd).transpose(0, 2, 1, 3)
         else:
-            q = HeadProjection(nh, hd, use_bias, cfg.dtype, i8, i8g, name="q_proj")(x)
-            k = HeadProjection(nkv, hd, use_bias, cfg.dtype, i8, i8g, name="k_proj")(x)
-            v = HeadProjection(nkv, hd, use_bias, cfg.dtype, i8, i8g, name="v_proj")(x)
+            q = HeadProjection(nh, hd, use_bias, cfg.dtype, i8, i8g, name="q_proj")(
+                x, sub("q_proj"))
+            q = behind(q, "k_proj")
+            k = HeadProjection(nkv, hd, use_bias, cfg.dtype, i8, i8g, name="k_proj")(
+                x, sub("k_proj"))
+            k = behind(k, "v_proj")
+            v = HeadProjection(nkv, hd, use_bias, cfg.dtype, i8, i8g, name="v_proj")(
+                x, sub("v_proj"))
+            v = behind(v, "o_proj")
 
         if lora_ops:
             # per-row adapter deltas land on the projection OUTPUTS (before
@@ -1639,7 +1686,7 @@ class Attention(nn.Module):
                                                      nh * hd)
             d_o = _lora_site_delta(o_in, lora_ops, "o")
         out = OutProjection(H, use_bias, cfg.dtype, cfg.int8_weights,
-                            cfg.int8_group_size, name="o_proj")(out)
+                            cfg.int8_group_size, name="o_proj")(out, sub("o_proj"))
         if d_o is not None:
             out = out + d_o.reshape(out.shape).astype(out.dtype)
         return out, new_cache
@@ -2435,7 +2482,9 @@ class MLP(nn.Module):
     cfg: TransformerConfig
 
     @nn.compact
-    def __call__(self, x, lora_ops=None):
+    def __call__(self, x, lora_ops=None, order=None):
+        """``order``: the ZeRO-3 gather order of this module's kernels
+        (``GatherOrder``; training's unrolled forward only), or None."""
         cfg = self.cfg
 
         def lora_add(y, site, x_in):
@@ -2449,8 +2498,21 @@ class MLP(nn.Module):
             dense = partial(QuantDense, use_bias=use_bias, dtype=cfg.dtype,
                             groups=cfg.int8_group_size)
         else:
-            dense = partial(nn.Dense, use_bias=use_bias, dtype=cfg.dtype,
-                            param_dtype=jnp.float32, kernel_init=nn.initializers.normal(0.02))
+            def dense(features, name):
+                ordered = None if order is None else order.sub(name)
+                if ordered is not None:
+                    # the layer's last product is its backward's first: its
+                    # weight's regather has only the layer above's attention
+                    # products in front of it, a fifth of its length, so its
+                    # dX is due behind its own dW. Every other regather
+                    # stands behind a product of its size already, and a
+                    # barrier on up_proj's dy would write out what the
+                    # compiler fuses into both its consumers
+                    return partial(OrderedDense(features, use_bias, cfg.dtype,
+                                                name == "down_proj", name=name), order=ordered)
+                return nn.Dense(features, use_bias=use_bias, dtype=cfg.dtype, name=name,
+                                param_dtype=jnp.float32,
+                                kernel_init=nn.initializers.normal(0.02))
         if cfg.activation in ("swiglu", "geglu"):
             gate = lora_add(dense(cfg.ffn_size, name="gate_proj")(x), "gate", x)
             up = lora_add(dense(cfg.ffn_size, name="up_proj")(x), "up", x)
@@ -2482,10 +2544,12 @@ class Block(nn.Module):
     @nn.compact
     def __call__(self, x, sin, cos, attn_mask=None, deterministic=True, kv_cache=None,
                  cache_index=None, position_ids=None, write_index=None, q_spans=None,
-                 lora_ops=None, expert_ops=None, ext_ops=None, seq_shard=False, carry=None):
+                 lora_ops=None, expert_ops=None, ext_ops=None, seq_shard=False, carry=None,
+                 order=None):
         """``carry``: what the layers below hand on beside the residual
         stream (``cfg.carries_across_layers``: a dict); with one, the block
-        returns ``(x, new_cache, carry)``."""
+        returns ``(x, new_cache, carry)``. ``order``: the ZeRO-3 gather order
+        of the layer's attention and MLP leaves (``GatherOrder``), or None."""
         cfg = self.cfg
         drop = nn.Dropout(rate=cfg.dropout) if cfg.dropout > 0 else None
         if cfg.act_quant_bits:  # QAT activation fake-quant (compression)
@@ -2506,6 +2570,7 @@ class Block(nn.Module):
             x = x + h
             return x + MLP(cfg, name="mlp")(make_norm(cfg, name="mlp_norm")(x)), new_cache, carry
         mixer_kind, ffn_kind = cfg.layer_parts(self.layer_idx)
+        handed = lambda h: h
         # a one-sublayer block has ONE norm and no parameters of the absent half
         mixer_norm, ffn_norm = (("attn_norm", "mlp_norm") if mixer_kind and ffn_kind
                                 else ("norm", "norm"))
@@ -2531,6 +2596,11 @@ class Block(nn.Module):
         else:
             attention = LatentAttention if cfg.kv_lora_rank else Attention
             mixer = attention(cfg, layer_idx=self.layer_idx, name="attn")
+            if order is not None and attention is Attention:
+                mixer = partial(mixer, order=order.sub("attn"))
+                # the layer above's first projection rides this layer's last
+                # attention product
+                handed = order.hand_down
         if cfg.post_norm:
             # the mixer and the FFN read the residual stream itself; what
             # they return is normalised on its way into it
@@ -2543,6 +2613,7 @@ class Block(nn.Module):
             h, new_cache = mixer(
                 h, sin, cos, attn_mask, kv_cache, cache_index, position_ids, write_index,
                 q_spans, lora_ops, ext_ops, seq_shard)
+            h = handed(h)
             if drop is not None:
                 h = drop(h, deterministic=deterministic)
             if ffn_kind is None:
@@ -2568,7 +2639,7 @@ class Block(nn.Module):
                 ff, aux = MoE(cfg, name="moe")(ff_in)
                 self.sow("intermediates", "moe_aux_loss", aux)
         else:
-            ff = MLP(cfg, name="mlp")(ff_in, lora_ops)
+            ff = MLP(cfg, name="mlp")(ff_in, lora_ops, None if order is None else order.sub("mlp"))
         if cfg.post_norm:
             return x + make_norm(cfg, name="mlp_norm")(ff), new_cache
         if drop is not None:
@@ -2581,14 +2652,28 @@ class Block(nn.Module):
 class CausalLM(nn.Module):
     cfg: TransformerConfig
 
+    def _shard(self, path):
+        """``{path: the compute-dtype leaf}`` of this model's parameter at
+        ``path``, empty if there is none: what a ``GatherOrder`` gathers ahead."""
+        w = self.variables["params"]
+        for key in path.split("/"):
+            w = w.get(key) if hasattr(w, "get") else None
+        return {} if w is None else {path: w.astype(self.cfg.dtype)}
+
     @nn.compact
     def __call__(self, input_ids, attn_mask=None, deterministic=True, kv_cache=None,
                  cache_index=None, position_ids=None, return_hidden=False,
                  pld_theta=None, pld_rng=None, ltd_keep=None, ltd_layers=(), ltd_rng=None,
                  write_index=None, q_spans=None, lora_ops=None, expert_ops=None,
-                 ext_ops=None, seq_shard=False, with_hidden=False):
+                 ext_ops=None, seq_shard=False, with_hidden=False, gather_order=None):
         """``with_hidden``: return the final-norm hidden states BEHIND the
         logits (and the cache), as a multi-token-prediction module takes them.
+
+        ``gather_order``: under ZeRO stage 3, ``{leaf path: gathered
+        placement}`` of the weights whose gathers the program places
+        (``ShardingPlanner.gathered_placements``, handed down by the engine).
+        Only the unrolled training forward (no cache, no rematerialised
+        blocks) reads it.
 
         ``kv_cache``: optional cache tree of ``init_cache`` (split K and V
         leaves, the packed K/V leaf or the latent leaf, each component with
@@ -2681,6 +2766,12 @@ class CausalLM(nn.Module):
         else:
             caches = []
             carry = {} if cfg.carries_across_layers else None
+            # one scanned body has no "next layer" to name, a served step
+            # shards no weight and a rematerialised block runs its forward
+            # twice: those keep the partitioner's placement
+            orders = (gather_order and kv_cache is None and not cfg.remat_policy
+                      and not self.is_initializing())
+            order = GatherOrder(gather_order) if orders else None
             for i in range(cfg.num_layers):
                 # per-layer tuple cache (init_cache, unrolled form); stacked
                 # arrays also index correctly for backward compatibility.
@@ -2704,6 +2795,18 @@ class CausalLM(nn.Module):
                                       layer_cache, cache_index, position_ids, write_index,
                                       q_spans, layer_lora, layer_experts, ext_ops,
                                       seq_shard, carry)
+                elif orders:
+                    y, c = blk(x, sin, cos, attn_mask, deterministic,
+                               layer_cache, cache_index, position_ids, write_index,
+                               q_spans, layer_lora, layer_experts, ext_ops,
+                               seq_shard, order=order.sub(f"layer_{i}", self._shard(
+                                   f"layer_{i + 1}/attn/q_proj/kernel")))
+                    # the layer above's first MLP weight, as long a gather as
+                    # this layer's last product, rides it: left to the
+                    # partitioner it rides the one product in front of its
+                    # consumer, the attention output's, a fifth of its length
+                    first = "gate_proj" if cfg.activation in ("swiglu", "geglu") else "up_proj"
+                    y = order.due_behind(y, self._shard(f"layer_{i + 1}/mlp/{first}/kernel"))
                 else:
                     y, c = blk(x, sin, cos, attn_mask, deterministic,
                                layer_cache, cache_index, position_ids, write_index,
@@ -3383,12 +3486,16 @@ class CausalLMModel:
         # bs16/seq1024/vocab50k; 512/1024 are within noise of 256)
         return self.cfg.ce_chunk_size or 256
 
-    def loss(self, params, batch, rng):
+    def loss(self, params, batch, rng, gather_order=None):
         """Next-token cross entropy. batch: input_ids (B,T), optional labels
-        (B,T; -100 = ignore), optional attention_mask (B,T)."""
+        (B,T; -100 = ignore), optional attention_mask (B,T). ``gather_order``:
+        the engine's ZeRO-3 plan of the weight gathers the program places
+        (``CausalLM.__call__``)."""
         input_ids = batch["input_ids"]
         attn_mask = batch.get("attention_mask")
         kw = self._apply_kwargs(rng)
+        if gather_order:
+            kw.update(gather_order=gather_order)
         det = kw.pop("deterministic")
         pld_theta = batch.get("__pld_theta__")  # progressive layer drop schedule value
         if pld_theta is not None and rng is not None:

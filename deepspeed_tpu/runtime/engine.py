@@ -16,6 +16,7 @@ is a pure loss function ``loss_fn(params, batch, rng) -> loss`` (or
 ``apply`` returns the loss.
 """
 
+import inspect
 import json
 import os
 import time
@@ -38,6 +39,7 @@ from .constants import (ADAM_OPTIMIZER, ADAMW_OPTIMIZER, FUSED_ADAM_OPTIMIZER, C
                         ONEBIT_LAMB_OPTIMIZER, ZERO_ONE_ADAM_OPTIMIZER)
 from .fp16.loss_scaler import create_loss_scaler
 from .lr_schedules import get_lr_schedule, _LRSchedule
+from .zero import gather_order
 from .zero.config import ZeroStageEnum
 from .zero.sharding import ShardingPlanner, TensorParallelRules
 
@@ -92,12 +94,15 @@ class DeepSpeedEngine:
                  rng_seed=None):
         self.module = model
         self.loss_fn = _resolve_loss_fn(model)
+        # a loss that takes the ZeRO-3 gather order is handed it (stage 3)
+        self._loss_takes_order = "gather_order" in inspect.signature(self.loss_fn).parameters
         self.client_optimizer = optimizer
         self.client_lr_scheduler = lr_scheduler
         self.training_data = training_data
         self.collate_fn = collate_fn
         self.mpu = mpu
         self.global_steps = 0
+        self._micro_traces = 0  # times a step program traced its loss (the gauges' cue)
         self.global_samples = 0
         self.micro_steps = 0
         self.skipped_steps = 0
@@ -639,12 +644,18 @@ class DeepSpeedEngine:
         """One microbatch: cast master->compute, forward, backward, unscale later."""
 
         def scaled_loss(p):
+            self._micro_traces += 1  # runs when the step's program is traced
             p_c = jax.tree_util.tree_map(lambda x: jnp.asarray(x, self.compute_dtype), p)
             # compute-param placement: stage-3 params stay scattered (XLA
             # all-gathers just-in-time per layer); params under
             # stage3_param_persistence_threshold are pinned replicated here
             p_c = jax.lax.with_sharding_constraint(p_c, self.planner.param_shardings(p_c))
-            out = self.loss_fn(p_c, batch, rng)
+            # ... and under stage 3 the model is told which leaves those are,
+            # so that its layer loop can state which product each large
+            # gather is due behind (runtime/zero/gather_order.py)
+            order = self.planner.gathered_placements(p_c) if self._loss_takes_order else None
+            out = (self.loss_fn(p_c, batch, rng, gather_order=order) if order
+                   else self.loss_fn(p_c, batch, rng))
             loss, aux = (out if isinstance(out, tuple) else (out, None))
             return loss.astype(jnp.float32) * scale, (loss, aux)
 
@@ -1256,6 +1267,7 @@ class DeepSpeedEngine:
             from ..ops.pallas import flash_attention
             flash_before = flash_attention.traced()
             head_before, _ = dist.traced_head_grad()
+            micro_before, gathers_before = self._micro_traces, len(gather_order.traced())
         if self.offload_optimizer:
             metrics = self._offload_train_batch(stacked)
         else:
@@ -1294,6 +1306,15 @@ class DeepSpeedEngine:
                 self.telemetry.gauges([
                     ("zero/head_grad_reductions_per_step", sums * gas, self.global_samples),
                     ("zero/head_grad_reduced_bytes_per_step", nbytes * gas, self.global_samples)])
+            # and how many of its large weight gathers the program placed
+            # (stage 3 across chips: forward and backward, a microbatch each)
+            # with their gathered bytes; a step with nothing sharded reads 0
+            if self._micro_traces > micro_before:
+                placed = dict(gather_order.traced()[gathers_before:])
+                self.telemetry.gauges([
+                    ("zero/param_gathers_pinned_per_step", len(placed) * gas, self.global_samples),
+                    ("zero/param_gather_bytes_per_step", sum(placed.values()) * gas,
+                     self.global_samples)])
         self.global_steps += 1
         self.global_samples += self.train_batch_size()
         self.micro_steps += gas

@@ -93,7 +93,12 @@ def _check_stage(value):
 
 
 class DeepSpeedZeroConfig(DeepSpeedConfigModel):
-    """``zero_optimization`` section (same keys as the reference)."""
+    """``zero_optimization`` section (same keys as the reference).
+
+    ``stage3_prefetch_bucket_size`` is accepted and unread: where the
+    reference prefetches by element count, the compiled step states which
+    PRODUCT each large gather is due behind (``gather_order.py``), for every
+    leaf over ``stage3_param_persistence_threshold`` that ZeRO alone shards."""
 
     stage = ConfigField(default=0, validator=_check_stage)
     contiguous_gradients = ConfigField(default=True)

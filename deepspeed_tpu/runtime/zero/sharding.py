@@ -11,9 +11,31 @@ scheduling that DeepSpeed drives by hand (SURVEY §7 design translation):
 - stage 0: params, grads, optimizer state replicated over DP.
 - stage 1: optimizer state (and fp32 master params) sharded over DP.
 - stage 2: + gradients reduce-scattered into the same sharding.
-- stage 3: + model params sharded over DP; XLA all-gathers just-in-time
-  per layer and frees after use (the fetch/release/prefetch coordinator
-  becomes the compiler's scheduling problem).
+- stage 3: + model params sharded over DP; each is all-gathered for its
+  forward product, freed, and gathered again for the backward's.
+
+Who places a stage-3 gather. The partitioner places one just in time, in
+front of its consumer, and the TPU compiler runs it asynchronously beside
+exactly ONE product, the one it schedules there: an accident of the
+program's text that put half of the large gathers beside products a fifth
+of their length. So for the leaves :meth:`ShardingPlanner.gathered_placements`
+names (sharded by ZeRO alone, over a group larger than one) the PROGRAM
+states which product a gather is due behind (``gather_order.py``; the
+reference's fetch/release/prefetch coordinator in the only form a compiled
+step has): the engine hands the plan to a loss that takes one (a
+``gather_order`` parameter: ``CausalLMModel.loss``), whose unrolled training
+forward gathers a weight explicitly behind the product it is to ride and
+orders a layer's last product's ``dX`` behind its ``dW``, so that the
+partitioner's regather stands behind ``dW``.
+Still the compiler's: that a gather is asynchronous at all, how many steps
+it takes and how far in front of its due point it starts; every gather of a
+scanned layer stack (one body has no next layer to name), of a
+rematerialised block, of a leaf a tensor-parallel or pipeline rule splits,
+of the embedding and the head, of a model that takes no plan; and every
+reduce-scatter. ``stage3_prefetch_bucket_size``, ``stage3_max_live_parameters``
+and ``stage3_max_reuse_distance`` are accepted and unread: the order is by
+product, not by element count, and a gathered weight is never kept from
+forward to backward.
 
 DeepSpeed concepts that survive as rules:
 - ``stage3_param_persistence_threshold`` → small params stay replicated.
@@ -179,12 +201,16 @@ class ShardingPlanner:
         entries[dim] = tuple(axes) if len(axes) > 1 else axes[0]
         return P(*entries)
 
-    def param_spec(self, path_str, shape):
-        """PartitionSpec for a *model* (compute) parameter."""
+    def _ruled_spec(self, path_str, shape):
+        """What the tensor-parallel and pipeline rules say of a leaf, before
+        any ZeRO sharding."""
         ndim = len(shape)
         spec = self.tp_rules.match(path_str, ndim) or P(*([None] * ndim))
-        spec = self._validate(spec, shape, path_str)
-        spec = self._apply_pipe(spec, shape, path_str)
+        return self._apply_pipe(self._validate(spec, shape, path_str), shape, path_str)
+
+    def param_spec(self, path_str, shape):
+        """PartitionSpec for a *model* (compute) parameter."""
+        spec = self._ruled_spec(path_str, shape)
         if self.stage >= ZeroStageEnum.weights:
             n_elem = int(np.prod(shape)) if shape else 1
             if n_elem > self.persistence_threshold:
@@ -193,20 +219,14 @@ class ShardingPlanner:
 
     def master_spec(self, path_str, shape):
         """PartitionSpec for fp32 master params + optimizer moments."""
-        ndim = len(shape)
-        spec = self.tp_rules.match(path_str, ndim) or P(*([None] * ndim))
-        spec = self._validate(spec, shape, path_str)
-        spec = self._apply_pipe(spec, shape, path_str)
+        spec = self._ruled_spec(path_str, shape)
         if self.stage >= ZeroStageEnum.optimizer_states:
             spec = self._apply_dp(spec, shape, path_str)
         return spec
 
     def grad_spec(self, path_str, shape):
         """PartitionSpec for gradients/accumulators: stage >= 2 scatters."""
-        ndim = len(shape)
-        spec = self.tp_rules.match(path_str, ndim) or P(*([None] * ndim))
-        spec = self._validate(spec, shape, path_str)
-        spec = self._apply_pipe(spec, shape, path_str)
+        spec = self._ruled_spec(path_str, shape)
         if self.stage >= ZeroStageEnum.gradients:
             spec = self._apply_dp(spec, shape, path_str)
         return spec
@@ -217,11 +237,7 @@ class ShardingPlanner:
         stage. ZeRO-Offload partitions optimizer state per DP rank so each
         host steps only its shard (reference ``stage_1_and_2.py:1031`` CPU
         accumulation of this rank's partition; ``stage3.py:463``)."""
-        ndim = len(shape)
-        spec = self.tp_rules.match(path_str, ndim) or P(*([None] * ndim))
-        spec = self._validate(spec, shape, path_str)
-        spec = self._apply_pipe(spec, shape, path_str)
-        return self._apply_dp(spec, shape, path_str)
+        return self._apply_dp(self._ruled_spec(path_str, shape), shape, path_str)
 
     # -- pytree planning -----------------------------------------------------
     def _tree_specs(self, params, leaf_fn):
@@ -250,6 +266,27 @@ class ShardingPlanner:
 
     def param_shardings(self, params):
         return self.shardings(self.param_specs(params))
+
+    def gathered_placements(self, params):
+        """``{leaf path: NamedSharding}`` of the compute parameters whose
+        gathers the PROGRAM places under stage 3 (``gather_order.py``): the
+        leaves :meth:`param_spec` shards over a ZeRO group larger than one
+        and no tensor-parallel or pipeline rule splits (over an axis larger
+        than one), each with the placement it has once gathered (replicated). Empty where nothing is
+        sharded: stages 0-2, one device, a leaf under the persistence
+        threshold. A leaf a rule splits is left to the partitioner."""
+        if self.stage < ZeroStageEnum.weights:
+            return {}
+        out = {}
+
+        def plan(path, leaf):
+            ps, shape = _path_str(path), tuple(leaf.shape)
+            split = lambda spec: [a for a in _spec_axes(spec) if self.mesh.shape[a] > 1]
+            if not split(self._ruled_spec(ps, shape)) and split(self.param_spec(ps, shape)):
+                out[ps] = NamedSharding(self.mesh, P())
+
+        jax.tree_util.tree_map_with_path(plan, params)
+        return out
 
     def master_shardings(self, params):
         return self.shardings(self.master_specs(params))

@@ -210,6 +210,121 @@ def test_train_step_rematerialises(for_chip, topo, monkeypatch, cell, micro_batc
     assert not re.search(r"f32\[\d+,\d+,\d+,1\]\{[^}]*T\(8,128\)", text)
 
 
+def _gather_riders():
+    """``tools/gather_riders.py``, the reader of which product each async
+    all-gather of a compiled step rides."""
+    import importlib.util
+    path = os.path.join(os.path.dirname(__file__), "..", "..", "..", "tools", "gather_riders.py")
+    spec = importlib.util.spec_from_file_location("gather_riders", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def _compiled_train_step(monkeypatch, topo, cell, **overrides):
+    """The text of a training cell's step through the engine
+    (``chipbench.rehearse``'s path), compiled for the described chips, with
+    the configuration's ``overrides`` (its depth) replaced."""
+    from chipbench import cells, rehearse
+    from deepspeed_tpu.runtime.engine import DeepSpeedEngine
+    for name in ("_init_params", "_init_state"):  # rehearse_train replaces them for good
+        monkeypatch.setattr(DeepSpeedEngine, name, getattr(DeepSpeedEngine, name))
+    texts = []
+    monkeypatch.setattr(rehearse, "_report",
+                        lambda name, compiled, *a, **kw: texts.append(compiled.as_text()))
+    _, workload, root = cells.load_workload(cell)
+    config = cells.load_config(workload["config"], root)
+    if overrides:
+        config = dict(config, overrides=dict(config["overrides"], **overrides),
+                      expect={k: v for k, v in config["expect"].items() if k not in overrides})
+    rehearse.rehearse_train(workload, config, topo)
+    (text, ) = texts
+    return text
+
+
+ZERO3_CELL = "opt-1.3b.train.zero3.s2048"
+
+
+def test_zero3_step_gathers_the_next_mlp_weight_behind_the_last_product(for_chip, topo,
+                                                                        monkeypatch):
+    """Two layers of cell 3's step at its widths on the described four
+    chips: the program states that layer 1's ``up_proj`` gather (33.5 MB) is
+    due behind layer 0's ``down_proj`` product, and the compiler fuses the
+    two; left to the partitioner it rode layer 1's attention output product,
+    a fifth of its length. ~30 s."""
+    riders = _gather_riders()
+    text = _compiled_train_step(monkeypatch, topo, ZERO3_CELL, num_layers=2)
+    found = riders.gathers(text)
+    by_leaf = {(riders.leaf_of(g["op_name"]), g["backward"]): g for g in found
+               if g["bytes"] > 2**20}
+    ahead = by_leaf["layer_1/mlp/up_proj", False]
+    assert not ahead["blocking"] and ahead["shape"] == "bf16[2048,8192]"
+    assert [riders.rider_of(name) for name, _ in ahead["riders"]] == ["fwd layer_0/mlp/down_proj"]
+    # the second MLP weight stays behind the first's product, and layer 1's
+    # first projection rides layer 0's attention, not its down_proj product
+    assert [riders.rider_of(name) for name, _ in by_leaf["layer_1/mlp/down_proj", False][
+        "riders"]] == ["fwd layer_1/mlp/up_proj"]
+    assert [riders.leaf_of(name).split("/")[:2] for name, _ in by_leaf[
+        "layer_1/attn/q_proj", False]["riders"]] == [["layer_0", "attn"]]
+    # backward: dX is due behind dW, so a regather rides a product of its
+    # own weight's size
+    for leaf in ("layer_0/mlp/down_proj", "layer_0/mlp/up_proj"):
+        behind = by_leaf[leaf, True]
+        assert not behind["blocking"]
+        assert any(shape.startswith(("bf16[8192,2048", "bf16[2048,8192", "bf16[2,2048,8192"))
+                   for _, shape in behind["riders"]), behind
+    # down_proj's regather behind its OWN dW (the weight's shape)
+    assert any(shape.startswith("bf16[8192,2048") and "down_proj" in name
+               for name, shape in by_leaf["layer_0/mlp/down_proj", True]["riders"])
+    # the same text by what each entry instruction is to the gathers: a
+    # traced run's time by operation name sums under those kinds
+    kinds = riders.kinds(text)
+    step = next(name for name, (kind, detail) in kinds.items() if kind == "gather step"
+                and detail.startswith("fwd mlp/up_proj <- fwd mlp/down_proj"))
+    assert sum(kind == "reduce-scatter" for kind, _ in kinds.values()) >= 12
+    times = riders.timed(text, {f"{step} bf16[2,2048,2048]": (0.002, 2), "%nowhere.1": (0.001, 1)},
+                         steps=2)
+    assert times["gather step", kinds[step][1]] == [1.0, 1.0]
+    assert times["not in the text", "%nowhere"] == [0.5, 0.5]
+
+
+@pytest.mark.slow
+def test_zero3_step_rides_every_large_gather_on_a_product_of_its_size(for_chip, topo,
+                                                                      monkeypatch):
+    """Cell 3's whole step (24 layers): which product each large gather
+    rides, forward and backward, by the ``op_name`` of the product inside the
+    fusion; no MLP gather left behind an attention projection's product
+    except layer 0's; no weight's gather blocking but layer 0's first two
+    (nothing stands in front of them but the lookup); the regathers all
+    there (ZeRO-3's memory, not ZeRO-2's); nothing rematerialised. ~2.5 min."""
+    riders = _gather_riders()
+    text = _compiled_train_step(monkeypatch, topo, ZERO3_CELL)
+    found = [g for g in riders.gathers(text) if g["bytes"] > 2**20]
+    layers = 24
+    weights = [g for g in found if re.match(r"layer_\d+/", riders.leaf_of(g["op_name"]))]
+    blocking = sorted(riders.leaf_of(g["op_name"]) for g in weights if g["blocking"])
+    assert all(leaf.startswith("layer_0/attn/") for leaf in blocking) and len(blocking) <= 2
+    # every weight gathered twice a step, but where a forward's gather is
+    # still there for the backward next to it (the last layer's)
+    assert 2 * 6 * layers - 4 <= len(weights) <= 2 * 6 * layers
+    table = collections.Counter()
+    for g in weights:
+        if g["blocking"]:
+            continue
+        leaf = riders.leaf_of(g["op_name"])
+        biggest = max(g["riders"], key=lambda r: riders._shape_bytes(r[1]))
+        big_rider = riders._shape_bytes(biggest[1]) >= 2 * 2048 * 8192 * 2 or "/mlp/" in biggest[0]
+        table[leaf.split("/", 1)[1], "backward" if g["backward"] else "forward", big_rider] += 1
+        if "/mlp/" in leaf and not leaf.startswith("layer_0/"):
+            assert big_rider, (leaf, g["backward"], g["riders"])
+    # forward: 23 up_proj gathers behind the layer below's down_proj product
+    assert table["mlp/up_proj", "forward", True] >= layers - 1
+    assert table["mlp/down_proj", "forward", True] == layers
+    assert table["mlp/down_proj", "backward", True] >= layers - 1
+    assert table["mlp/up_proj", "backward", True] == layers
+    assert not _rematerialised(text)
+
+
 # ------------------------------------------------------------ decode kernels
 def _pool(sds, cfg, int8):
     """One layer's cache operands in the geometry ``init_cache`` gives the
