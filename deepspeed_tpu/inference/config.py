@@ -268,9 +268,11 @@ class AutoscalerConfig(DeepSpeedConfigModel):
     cooldown_down_s = ConfigField(default=30.0, help="minimum seconds after "
                                   "ANY scale action before shrinking "
                                   "(hysteresis against grow/shrink flapping)")
-    host_gap_veto = ConfigField(default=0.5, help="host-gap fraction (device-"
-                                "idle seconds per wall second, from the gaps "
-                                "behind serving/host_gap_ms) at/above which scale-up is "
+    host_gap_veto = ConfigField(default=0.5, help="host fraction (the host's "
+                                "share of the pumps' syncs over the last tick: "
+                                "busy / (busy + wait) of the accounts behind "
+                                "serving/pump_busy_ms and serving/pump_wait_ms) "
+                                "at/above which scale-up is "
                                 "VETOED: the host, not the device, is the "
                                 "bottleneck, and another replica would only "
                                 "add host work")

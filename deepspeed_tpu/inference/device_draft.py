@@ -180,6 +180,7 @@ class DeviceDraft:
                 if len(rest) == 4:
                     first_choice, choice = (np.asarray(x) for x in jax.device_get(rest[2:]))
             block = np.asarray(jax.device_get(block))
+        self._landed(fl.program)
         toks = block[:2 * K].reshape(K, 2, N)
         adv = block[2 * K:3 * K]
         draft = block[3 * K + 1]

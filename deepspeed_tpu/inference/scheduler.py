@@ -85,7 +85,7 @@ ordered before any later program by the device's queue. Where the next
 sync's inputs need the last one's results ON THE HOST the pump lands before
 it launches, by what it can observe and with no setting
 (:meth:`DecodeScheduler._lands_first`): a drafter, cold-expert offload,
-parked or chained rows, a capacity-sampled fence, a migrate hook on a final
+parked or chained rows, a migrate hook on a final
 chunk, a row flagged for cancellation. ``pause`` / ``flush`` / ``drain`` /
 ``swap_weights`` / ``migrate_out`` / ``admit_migration`` land what is in
 flight first. Counters ``serving/syncs_ahead`` (launched with the previous
@@ -251,7 +251,6 @@ gauges ``serving/adapters_resident``, ``serving/adapter_pool_bytes``,
 
 import collections
 import threading
-import time
 
 import jax
 import jax.numpy as jnp
@@ -897,14 +896,15 @@ class DecodeScheduler(DeviceDraft):
         # can pair prefill and decode replicas per request
         self.replica_idx = None
         # serving capacity accounting (telemetry/capacity.py): per-program
-        # roofline registry + sampled fenced timing + the pump's account.
+        # roofline registry + the pump's account, which also times the device.
         # Only built on an enabled sink — the disabled path allocates
         # nothing and every hook below gates on `self.capacity is None`.
         self.capacity = None
         self._work = None  # the observer of required work (required_work.py)
         self._gap = None
-        self._sync_seq = 0
-        self._cap_sample = False
+        # the last program handed to the device, as the capacity gauges price
+        # it: (key, split, spans, lens), or None (sink off, unkeyed program)
+        self._dispatched = None
         self._goodput_spec_seen = 0
         if self.telemetry.enabled:
             from ..accelerator import get_accelerator
@@ -920,8 +920,7 @@ class DecodeScheduler(DeviceDraft):
                               ep_size=self.ep_size),
                 peak_flops=accel.peak_flops(),
                 peak_hbm_bw=accel.peak_hbm_bandwidth(),
-                n_devices=n_dev,
-                sample_every=getattr(self.telemetry, "capacity_sample_every", 32))
+                n_devices=n_dev)
             self._work = RequiredWork(self.telemetry, model, self.cache, self.tp_size,
                                       state_pool=self._state_pool, drafts=self._device_draft)
             # the account needs to know a program was built under a span
@@ -1429,16 +1428,16 @@ class DecodeScheduler(DeviceDraft):
         counts on the host, so that the pump lands before it launches: a
         drafter reads the accepted tokens, cold-expert offload replays on
         the routing counts (and backs off on an overflow), parked or chained
-        rows are paged by their landed lengths, a capacity-sampled dispatch
-        is fenced, a final chunk's row may be handed to the migrate hook
-        the moment its tokens are out, and a row flagged for cancellation
+        rows are paged by their landed lengths, a final chunk's row may be
+        handed to the migrate hook the moment its tokens are out, and a row
+        flagged for cancellation
         gets the tokens already computed for it before it is reaped (as
         behind a serial pump, where a sync lands in the step that launched
         it). All of it state the scheduler can see; everything else
         launches ahead."""
         fl = self._flight
         return (self.drafter is not None or self.experts is not None
-                or bool(self._parked) or bool(self.cache.chain) or self._cap_sample
+                or bool(self._parked) or bool(self.cache.chain)
                 or (self.migrate_hook is not None and fl is not None and fl.final)
                 or any(r.cancelled and r.inflight for r in self.active.values()))
 
@@ -1452,14 +1451,6 @@ class DecodeScheduler(DeviceDraft):
         "spec", "decode", or None when nothing could run)."""
         tel = self.telemetry
         t0 = tel.now()
-        # sampled fenced-timing window (telemetry/capacity.py): every Nth
-        # sync the next dispatch is fenced and timed for the live MFU /
-        # bandwidth / roofline gauges; between samples the async dispatch
-        # pipeline is untouched
-        cap = self.capacity
-        if cap is not None:
-            self._sync_seq += 1
-            self._cap_sample = cap.should_sample(self._sync_seq)
         delivered = 0
         kind = None
         if self._flight is not None:
@@ -1503,6 +1494,7 @@ class DecodeScheduler(DeviceDraft):
                 tel.counter("serving/syncs_ahead" if ahead else "serving/syncs_serial")
             if isinstance(ran, _Flight):
                 ran.t0 = t0
+                ran.program = self._dispatched
                 self._flight = ran
             else:
                 delivered += ran[0]
@@ -1522,7 +1514,7 @@ class DecodeScheduler(DeviceDraft):
         whose request ended while the sync was in flight (an EOS or a
         cancellation the launch could not know of) computed once more for
         nothing: its tokens are dropped and counted."""
-        toks_k, logits_k = self._fetch_block(fl.out, fl.collect, fl.K)
+        toks_k, logits_k = self._fetch_block(fl.out, fl.collect, fl.K, fl.program)
         if fl.chunk is not None:
             preq, pos, take, final = fl.chunk
             tr = preq.trace
@@ -1562,6 +1554,9 @@ class DecodeScheduler(DeviceDraft):
                     ("serving/kv_bytes_live", self.cache.live_bytes(), None)])
         cap = self.capacity
         if cap is not None:
+            # under a gateway every delivered token was posted to its event
+            # loop: the delivery's account takes a landing's at once
+            self._gap.posted += delivered
             # goodput: tokens delivered vs computed-then-discarded.
             # Speculative rejected columns fold in here (as the delta
             # of drafted - accepted this sync); MoE miss replays and
@@ -2226,10 +2221,12 @@ class DecodeScheduler(DeviceDraft):
             self.cache.lengths[slot] += K
             req.inflight += K
 
-    def _fetch_block(self, out, collect, K):
+    def _fetch_block(self, out, collect, K, program=None):
         """Fetch a compiled step program's result behind the pool (which
         the launch already handed on): the (K, num_slots) token block (+
-        logits when collected, the routing choice, the MoE stats)."""
+        logits when collected, the routing choice, the MoE stats).
+        ``program``: the landed flight's (:meth:`_landed`); None where the
+        sync was fetched by the step that launched it."""
         # the device_get waits for THIS sync. With the next one launched
         # already the device has work queued when sched/fetch closes; where
         # the pump is serial the device is idle from then until the next
@@ -2242,8 +2239,32 @@ class DecodeScheduler(DeviceDraft):
             self._choice = (tuple(np.asarray(x) for x in jax.device_get(rest[1:]))
                             if len(rest) == 3 else None)
             toks_k = np.asarray(jax.device_get(toks_k)).reshape(K, self.cache.num_slots)
+        self._landed(program)
         self._steps += K
         return toks_k, logits_k
+
+    def _landed(self, program=None):
+        """Behind every ``sched/fetch``: where the pump's account found the
+        period that just closed device-bound (``HostGapTracker.device_s``:
+        the pump waited for the device, so the period IS the device's time
+        for this sync), it is a sample for the capacity gauges, priced as the
+        landed program (``program``: the flight's, or the last one
+        dispatched where the sync landed in the step that launched it).
+        Nothing is fenced for it and a host-bound period is no sample."""
+        gap = self._gap
+        if gap is None or gap.device_s is None:
+            return
+        program = program if program is not None else self._dispatched
+        if program is None:
+            return
+        key, split, spans, lens = program
+        width, ksteps = program_shape(key)
+        live_ctx = lens[spans > 0] if spans.shape == lens.shape else lens
+        # the extent-walk kernels DMA every extent's pool column per KV
+        # block, so their KV traffic prices at max_extents x contiguous
+        kv_mult = self.cache.max_extents if key[0] in ("fused_ext", "fused_seqp") else 1
+        self.capacity.observe_dispatch(key, gap.device_s, live_ctx, width, ksteps,
+                                       kv_mult=kv_mult, split=split)
 
     def _pop_expert_stats(self, rest):
         """Where a step program's MoE stats ride its result to the landing
@@ -2342,39 +2363,16 @@ class DecodeScheduler(DeviceDraft):
         of required work hears of it first (``required_work.py``; ``spans``,
         ``lens``: the host's copies of the spans at ``call_args[4]`` and the
         lengths at ``call_args[3]``; ``chunk``: ``(slot, final)`` of a chunk
-        sync's prefill row). On a sampled sync, fences the dispatch —
-        ``block_until_ready`` on the input pool (drain outstanding work) and
-        on the result, each under ``sched/fence`` (the pump blocked on the
-        device: ``wait`` in its account) — so the measured wall time is this
-        program's device time alone. The fence touches only arrays the
-        pipeline already owns: zero new XLA programs."""
+        sync's prefill row), and what was dispatched is kept for the landing
+        that will time it (:meth:`_landed`). The launch is never fenced."""
         cap = self.capacity
         if cap is not None:  # the sink is on
             key = cap.key_for(fn)
             split = self._splits_chunk(key)
             self._work.dispatched(key, split, spans, lens, chunk)
-        if cap is None or not self._cap_sample:
-            with self._span("sched/dispatch"), self.engine.mesh:
-                return self._run_program(fn, call_args)
-        # one fenced dispatch per sampled sync, even across MoE replays
-        self._cap_sample = False
-        with self._span("sched/fence"):
-            jax.block_until_ready(call_args[1])
-        t0 = time.perf_counter()
+            self._dispatched = (key, split, spans, lens) if key is not None else None
         with self._span("sched/dispatch"), self.engine.mesh:
-            out = self._run_program(fn, call_args)
-        with self._span("sched/fence"):
-            jax.block_until_ready(out)
-        dur = time.perf_counter() - t0
-        if key is not None:
-            width, ksteps = program_shape(key)
-            live_ctx = lens[spans > 0] if spans.shape == lens.shape else lens
-            # the extent-walk kernels DMA every extent's pool column per KV
-            # block, so their KV traffic prices at max_extents x contiguous
-            kv_mult = self.cache.max_extents if key[0] in ("fused_ext", "fused_seqp") else 1
-            cap.observe_dispatch(key, dur, live_ctx, width, ksteps,
-                                 kv_mult=kv_mult, split=split)
-        return out
+            return self._run_program(fn, call_args)
 
     def _run_program(self, fn, call_args):
         """Call a step program. A call that traced it (the first at these
@@ -2803,6 +2801,7 @@ class DecodeScheduler(DeviceDraft):
             # (W, N, V)
             logits_k = np.asarray(jax.device_get(rest[0]), np.float32) if collect else None
             toks_k = np.asarray(jax.device_get(toks_k)).reshape(W, N)
+        self._landed()
         self._steps += 1
         tel = self.telemetry
         delivered = 0

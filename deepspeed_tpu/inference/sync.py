@@ -18,9 +18,11 @@ class _Flight:
     behind the pool (tokens, then logits, routing choice and MoE stats where
     the program returns them), still on the device; ``rows`` the decode rows
     it advances ``K`` tokens each; ``chunk`` the prefill row's ``(request,
-    pos, take, final)`` on a chunk sync; ``t0`` when its iteration began."""
+    pos, take, final)`` on a chunk sync; ``t0`` when its iteration began;
+    ``program`` what the capacity gauges price it as (``DecodeScheduler.
+    _dispatched`` at its launch; None with the sink off)."""
 
-    __slots__ = ("out", "K", "collect", "rows", "chunk", "t0")
+    __slots__ = ("out", "K", "collect", "rows", "chunk", "t0", "program")
 
     def __init__(self, out, K, collect, rows, chunk=None):
         self.out = out
@@ -29,6 +31,7 @@ class _Flight:
         self.rows = rows
         self.chunk = chunk
         self.t0 = 0.0
+        self.program = None
 
     @property
     def final(self):

@@ -116,11 +116,6 @@ class TelemetryConfig(DeepSpeedConfigModel):
     # slow_window_s / burn_threshold / eval_interval_s; the serving
     # gateway falls back to its default objective slate when none given
     slo = ConfigField(default=dict)
-    # serving capacity accounting (telemetry/capacity.py): fence-and-time
-    # every Nth scheduler sync for the live MFU / HBM-bandwidth / roofline
-    # gauges (1 = every sync, tests only; the async dispatch pipeline is
-    # never fenced between samples)
-    capacity_sample_every = ConfigField(default=32)
     # on-demand XLA profiling (telemetry/profiler.py): capture one device
     # trace of this many seconds at the training engine's next report
     # interval (0 = off; serving uses POST /v1/debug/profile instead)

@@ -89,7 +89,9 @@ class FleetSignals:
         self.decode_sat = float(decode_sat)        # in-flight work / decode slots
         self.mfu = float(mfu)                      # serving/mfu gauge
         self.hbm_bw_util = float(hbm_bw_util)      # serving/hbm_bw_util gauge
-        self.host_gap_frac = float(host_gap_frac)  # device-idle s per wall s
+        # the host's share of the pumps' syncs since the last snapshot:
+        # busy / (busy + wait) of their accounts (Gateway.fleet_signals)
+        self.host_gap_frac = float(host_gap_frac)
         self.goodput_fraction = float(goodput_fraction)
         self.occupancy = float(occupancy)          # busy slots / total slots
         self.replicas = int(replicas)              # non-retired fleet size

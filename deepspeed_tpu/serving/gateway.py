@@ -112,6 +112,9 @@ from .fair_queue import FairQueue, QueueFull
 from .replica import ReplicaSet
 
 _JSON = "application/json"
+# the token events between two whose send stands under a ``gateway/send``
+# profiler annotation, less one (a mask: one send in 64)
+SEND_SPAN_EVERY = 63
 
 
 def _round_up(x, m):
@@ -127,7 +130,7 @@ class _GatewayRequest:
                  "cost", "deadline", "stream", "loop", "events", "handle",
                  "cancel_requested", "cancel_reason", "finished", "enq_ts",
                  "admit_ts", "n_tokens", "trace", "trace_id", "replica",
-                 "adapter_id", "return_logits", "resume")
+                 "adapter_id", "return_logits", "resume", "unread")
 
     def __init__(self, rid, prompt, *, max_new_tokens, eos_token_id, do_sample,
                  temperature, top_k, top_p, seed, tenant, priority, deadline,
@@ -159,6 +162,7 @@ class _GatewayRequest:
         self.trace = trace          # RequestTrace (None when tracing is off)
         self.trace_id = trace_id    # request identity echoed as x-request-id
         self.replica = None         # serving replica this request landed on
+        self.unread = False         # its handler went away: events go unread
         self.adapter_id = adapter_id  # model variant (multi-LoRA serving)
         # unary responses can carry per-step logits (the multihost
         # bit-identity surface: logits must round-trip process boundaries)
@@ -295,7 +299,17 @@ class Gateway:
         self.replicas.on_replica_added = self._spawn_pump
         self._brownout_bar = None   # weight bar arrivals shed under (None=off)
         self._park_pending = set()  # greqs awaiting park-out on their owning pump
-        self._gap_mark = None       # (now, fleet host-gap total) delta basis
+        self._gap_mark = None       # (busy_s, wait_s) of the fleet's pumps at the last snapshot
+        # the event loop's delivery (telemetry/capacity.py: Delivery), only
+        # with the sink on: the loop thread adds what it wrote, the pumps'
+        # trackers count what their schedulers delivered (a landing's tokens
+        # at once: ``_post`` posts what it always posted), and the primary
+        # pump's account reads both at every landing. None with the sink
+        # off: the per-event path then tests this and does nothing else
+        self._delivery = None
+        if self.telemetry.enabled:
+            from ..telemetry.capacity import Delivery
+            self._delivery = Delivery()
         # multi-host serving (serving/router.py): the WorkerAgent attaches a
         # NetPrefixStore here so /v1/store/fetch can serve this shard's KV
         # bytes to remote restores; None on single-process gateways
@@ -455,6 +469,14 @@ class Gateway:
         # turns 50 times a second; the sink records none of them), heard by
         # the scheduler's account of the pump's time (None with the sink off)
         span, gap = self.telemetry.span, sched._gap
+        if gap is not None and self._delivery is not None:
+            # the account also covers the host's two threads: this pump's
+            # processor clock, and through the primary's the event loop's
+            from ..telemetry.capacity import thread_cpu_clock
+            loop = self._loop_thread if primary else None
+            gap.bind_threads(thread_cpu_clock(threading.get_ident()), self._delivery,
+                             thread_cpu_clock(loop.ident) if loop is not None else None,
+                             primary=primary)
         while not self._force_stop:
             with span("gateway/admit", record=False, observer=gap), self._dispatch_lock:
                 self._enforce_cancellations()
@@ -531,6 +553,8 @@ class Gateway:
                 with span("gateway/idle", record=False, observer=gap):
                     self._wake.wait(0.02)
                 self._wake.clear()
+        if gap is not None:
+            gap.unbind_threads()  # this thread's clock dies with it
         # force-stop: anything still in flight is failed, not silently
         # dropped (any one pump suffices — _fail_in_flight spans the fleet)
         if self._force_stop and primary:
@@ -758,7 +782,11 @@ class Gateway:
 
     def _post(self, greq, event):
         """Pump -> HTTP handler handoff; never raises (the response side may
-        already be gone — its queue then just collects unread events)."""
+        already be gone — its queue then just collects unread events, which
+        the delivery's account counts so: a token more a disconnect, and a
+        lost race between two pumps is one event's drift)."""
+        if greq.unread and event[0] == "token":
+            self._delivery.unread += 1
         try:
             greq.loop.call_soon_threadsafe(greq.events.put_nowait, event)
         except RuntimeError:
@@ -794,16 +822,19 @@ class Gateway:
             for ent in cap.programs.values():
                 mfu = max(mfu, float(ent.get("mfu", 0.0)))
                 bw = max(bw, float(ent.get("hbm_bw_util", 0.0)))
-        # host-gap fraction: device-idle seconds accrued per wall second
-        # since the previous snapshot, summed over the fleet's trackers —
-        # the "the host is the bottleneck" veto input
+        # host-gap fraction: the share of the fleet's pumps' syncs since the
+        # previous snapshot that was the host's work and not the wait for
+        # the device (busy / (busy + wait) of the pumps' accounts) — the
+        # "the host is the bottleneck" veto input. The device-idle gap
+        # cannot be it: behind a pump that runs ahead every gap reads 0
         host_gap_frac = 0.0
-        gap_total = sum(r.scheduler._gap.total_gap_s for r in reps
-                        if r.scheduler._gap is not None)
-        mark, self._gap_mark = self._gap_mark, (now, gap_total)
-        if mark is not None and now > mark[0]:
-            host_gap_frac = max(0.0, min(1.0, (gap_total - mark[1])
-                                         / (now - mark[0])))
+        gaps = [r.scheduler._gap for r in reps if r.scheduler._gap is not None]
+        totals = (sum(g.busy_s for g in gaps), sum(g.wait_s for g in gaps))
+        mark, self._gap_mark = self._gap_mark, totals
+        if mark is not None:
+            busy, wait = totals[0] - mark[0], totals[1] - mark[1]
+            if busy + wait > 0.0:
+                host_gap_frac = max(0.0, min(1.0, busy / (busy + wait)))
         return FleetSignals(
             now=now, burn_fast=burn_fast, burn_slow=burn_slow,
             queue_depth=len(self._fair),
@@ -1449,6 +1480,14 @@ class Gateway:
                 "host_gap_total_s": round(sched._gap.total_gap_s, 6),
                 "pump_busy_total_s": round(sched._gap.busy_s, 6),
                 "pump_wait_total_s": round(sched._gap.wait_s, 6),
+                # the event loop's delivery (token events of streaming and
+                # unary responses; the live view is gateway/backlog_events)
+                "delivery": ({
+                    "posted": self._delivery.posted(),
+                    "written": self._delivery.events,
+                    "taken": self._delivery.taken,
+                    "unread": self._delivery.unread,
+                } if self._delivery is not None else None),
                 "profiling": (self.profiler.active
                               if self.profiler is not None else None),
             } if sched.capacity is not None else None),
@@ -1732,9 +1771,22 @@ class Gateway:
                              "token_ids": toks,
                              "finish_reason": finish_reason}]}
 
+    def _handler_gone(self, greq):
+        """A response handler is leaving (sink on): token events still in
+        its queue, and any posted from now on, will never be written. The
+        first are counted taken here, the second ``unread`` where they are
+        posted (``_post``), so the backlog stays what the loop has yet to do."""
+        greq.unread = True
+        left = 0
+        while not greq.events.empty():
+            left += greq.events.get_nowait()[0] == "token"
+        self._delivery.taken += left
+
     async def _respond_stream(self, greq, reader, writer):
         eof_task = asyncio.ensure_future(self._watch_eof(reader))
         tel = self.telemetry
+        # the loop's own totals of what it delivers, None with the sink off
+        sent = self._delivery
         headers_sent = False
         try:
             while True:
@@ -1761,10 +1813,38 @@ class Gateway:
                         tel.histogram("gateway/ttfb_ms",
                                       (time.monotonic() - greq.enq_ts) * 1e3)
                 if kind == "token":
-                    _, tok, reason = ev
+                    tok, reason = ev[1], ev[2]
                     payload = json.dumps(self._chunk(greq, [tok], reason))
-                    writer.write(f"data: {payload}\n\n".encode())
-                    await writer.drain()
+                    data = f"data: {payload}\n\n".encode()
+                    if sent is None:
+                        writer.write(data)
+                        await writer.drain()
+                    else:
+                        n = sent.events + sent.taken + sent.unread + 1
+                        if n & SEND_SPAN_EVERY:
+                            writer.write(data)
+                        else:
+                            # one send in 64 under a profiler annotation (no
+                            # sink event): a capture shows what a send takes
+                            # beside the pump's spans and the device. One an
+                            # event cost a traced cell-9 run 8% of its tokens
+                            with tel.span("gateway/send", record=False):
+                                writer.write(data)
+                        # the bytes are with the transport (the selector
+                        # transport has tried the socket inside write):
+                        # delivered, as far as this process can tell. Plain
+                        # arithmetic on totals this thread alone adds to,
+                        # ``events`` last (Delivery)
+                        landed = sent.landing_of(n)
+                        if landed is not None:
+                            lag = time.perf_counter() - landed
+                            sent.lag_s += lag
+                            if lag > sent.lag_max_s:
+                                sent.lag_max_s = lag
+                        sent.bytes += len(data)
+                        sent.writes += 1
+                        sent.events += 1
+                        await writer.drain()
                     if reason is not None:
                         break
                 elif kind == "done":
@@ -1790,6 +1870,8 @@ class Gateway:
             self._client_gone(greq)
         finally:
             eof_task.cancel()
+            if sent is not None:
+                self._handler_gone(greq)
 
     async def _respond_unary(self, greq, reader, writer):
         eof_task = asyncio.ensure_future(self._watch_eof(reader))
@@ -1809,8 +1891,10 @@ class Gateway:
                                      extra=list(ev[3]) if len(ev) > 3 else ())
                     return
                 if kind == "token":
-                    _, tok, reason = ev
+                    tok, reason = ev[1], ev[2]
                     toks.append(tok)
+                    if self._delivery is not None:  # counted posted, here taken, never written
+                        self._delivery.taken += 1
                     if reason is not None:
                         finish_reason = reason
                         break
@@ -1860,6 +1944,8 @@ class Gateway:
             self._client_gone(greq)
         finally:
             eof_task.cancel()
+            if self._delivery is not None:
+                self._handler_gone(greq)
 
     # ------------------------------------------------------------------ HTTP writing
     _REASONS = {200: "OK", 400: "Bad Request", 404: "Not Found",

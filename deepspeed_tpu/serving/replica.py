@@ -279,7 +279,7 @@ class Replica:
             "tok_s": round(self.tok_s, 2),
             # capacity accounting (telemetry/capacity.py): this replica's
             # own pump-thread host-gap totals and goodput — per-replica
-            # because each pump fences and attributes independently
+            # because each pump attributes independently
             "goodput_fraction": (round(s.capacity.goodput_fraction, 5)
                                  if s.capacity is not None else None),
             "host_gap_total_s": (round(s._gap.total_gap_s, 4)

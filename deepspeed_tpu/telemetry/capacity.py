@@ -1,5 +1,5 @@
-"""Serving capacity accounting: per-program roofline registry, sampled
-fenced dispatch timing, host-gap attribution, and goodput.
+"""Serving capacity accounting: per-program roofline registry, the pump's
+account of its time and of the host's two threads, and goodput.
 
 Training has reported MFU since PR 1 (``runtime/engine``'s interval
 gauges), but serving had no utilization accounting at all — an operator
@@ -19,14 +19,14 @@ no-ops when the telemetry sink is disabled:
 - :class:`CapacityMeter` — the per-compiled-program registry. Every
   program the scheduler builds (fused/spec/copy/tier_slice/
   tier_restore, LoRA variants included) registers here at warm/build
-  time; a *sampled* fenced-timing window (every ``sample_every``-th sync,
-  default 1/32 — the async dispatch pipeline is never fenced on the hot
-  path) turns one dispatch's wall time into ``serving/mfu``,
+  time; a landing whose period the pump's account found DEVICE-BOUND (the
+  pump waited for the device for ``DEVICE_BOUND_SHARE`` of it or more, so
+  the period is the time the device took for that sync; nothing is ever
+  fenced) turns that time into ``serving/mfu``,
   ``serving/hbm_bw_util``, and a per-program-kind roofline classification
   gauge (``serving/roofline/<kind>``: analytic arithmetic intensity over
   the machine balance — >= 1 means compute-bound, < 1 bandwidth-bound).
-  Sampling uses only ``block_until_ready`` on arrays the program already
-  produced, so it adds ZERO new XLA programs after warmup. The meter also
+  A host-bound period says nothing of the device and is no sample. The meter also
   owns goodput: useful vs wasted token-FLOPs (speculative rejected
   columns, MoE miss-replay dispatches, migration/restore traffic
   converted at the machine balance) rolled into the
@@ -48,14 +48,23 @@ no-ops when the telemetry sink is disabled:
   the pace, whatever the gap below reads. And the device-idle gap, as
   before: the pump is one sync deep, so where it runs ahead the device has
   work queued when a fetch returns and the gap is 0 (one 0.0 observation a
-  sync); where it is serial (a drafter, expert offload, paged extents, a
-  capacity-sampled fence: ``DecodeScheduler._lands_first``) the time from
+  sync); where it is serial (a drafter, expert offload, paged extents:
+  ``DecodeScheduler._lands_first``) the time from
   one sync's fetch to the next dispatch is pure host time:
-  ``serving/host_gap_ms``.
+  ``serving/host_gap_ms``. Where the gateway has told it the host's two
+  interpreter threads (``bind_threads``), the same landing also says what
+  each burned of the processor in the period (``serving/pump_cpu_ms``,
+  ``serving/loop_cpu_ms``, ``serving/host_threads_cpu_pct``) and what the
+  event loop delivered in it (:class:`Delivery`: ``gateway/delivery_lag_ms``,
+  ``gateway/delivery_lag_max_ms``, ``gateway/backlog_events``,
+  ``gateway/loop_cpu_us_per_event``, the ``gateway/sse_*`` counters).
 
-Everything here is stdlib + numpy on the host side; the only device
-interaction is the sampled fence.
+Everything here is stdlib + numpy on the host side and touches the device
+nowhere.
 """
+
+import collections
+import time
 
 import numpy as np
 
@@ -68,11 +77,29 @@ BUSY_BUCKETS = ("admit", "trie_probe", "assemble", "dispatch", "deliver",
 
 # where the self time of each span the tracker hears goes, unless a program
 # was built under it: that is ``compile``, a part like ``wait`` and ``idle``
-PUMP_SPANS = {"sched/step": "other", "sched/fetch": "wait", "sched/fence": "wait",
+PUMP_SPANS = {"sched/step": "other", "sched/fetch": "wait",
               "gateway/admit": "gateway", "gateway/idle": "idle",
               **{"sched/" + b: b for b in BUSY_BUCKETS[:6]}}
 PUMP_PARTS = ("wait", "idle", "compile") + BUSY_BUCKETS
 GATEWAY_SPANS = ("gateway/admit", "gateway/idle")
+
+# the share of a period the pump has to have waited for the device (under
+# ``sched/fetch``) for the period to count as the device's own time for that
+# sync, and so as a capacity sample. A fetch that finds its block ready still
+# costs the ``device_get`` and the way back to the interpreter lock, 0.1-0.3 ms
+# (under 2% of the shortest period any cell runs, 16.6 ms); the most host-paced
+# cell there is (cell 2) waits 25% of its period with its device idle 4.7%, a
+# device-paced one (cell 4) 81% (ledger, PR 51). A twentieth lies above the
+# first and a fifth of the way to the second.
+DEVICE_BOUND_SHARE = 0.05
+
+# the landed periods a reading of the two threads' processor time spans. A
+# thread's clock need not resolve one period: the chip machine's ticks in
+# steps of 10 ms (every reading of PR 52's first chip run was a multiple of
+# it) under periods of 16 to 130 ms, so each landing observes the mean a sync
+# over the run of periods that ends in it, 16 at most: a tick is then 0.6 ms
+# of the shortest period's reading
+CPU_PERIODS = 16
 
 _GATED_ACTS = ("swiglu", "geglu")
 
@@ -206,19 +233,17 @@ def _program_kind(key):
 
 
 class CapacityMeter:
-    """Per-compiled-program roofline registry + sampled fenced timing +
-    goodput accounting. One instance per scheduler; only built when the
-    sink is enabled (the disabled path allocates nothing)."""
+    """Per-compiled-program roofline registry + the device-bound periods'
+    timing + goodput accounting. One instance per scheduler; only built when
+    the sink is enabled (the disabled path allocates nothing)."""
 
-    def __init__(self, sink, model, *, peak_flops, peak_hbm_bw, n_devices=1,
-                 sample_every=32):
+    def __init__(self, sink, model, *, peak_flops, peak_hbm_bw, n_devices=1):
         self.sink = sink
         self.model = model
         self.peak_flops = float(peak_flops) * max(1, int(n_devices))
         self.peak_hbm_bw = float(peak_hbm_bw) * max(1, int(n_devices))
         # machine balance: FLOPs/byte at the roofline ridge point
         self.balance = self.peak_flops / max(1.0, self.peak_hbm_bw)
-        self.sample_every = max(1, int(sample_every))
         self.programs = {}      # key -> {"kind", "samples", "mfu", "bw", ...}
         self._by_id = {}        # id(fn) -> key
         self.samples = 0
@@ -241,14 +266,13 @@ class CapacityMeter:
     def key_for(self, fn):
         return self._by_id.get(id(fn))
 
-    def should_sample(self, sync_seq):
-        return sync_seq % self.sample_every == 0
-
     # ---------------------------------------------------------------- sampling
     def observe_dispatch(self, key, dur_s, live_ctx, width, ksteps,
                          kv_mult=1.0, split=False):
-        """Fold one fenced dispatch sample into the live gauges. ``dur_s``
-        is the fence-to-fence wall time of the dispatch alone."""
+        """Fold one sample into the live gauges. ``dur_s`` is the time the
+        device took for the sync: the period that closed at its landing,
+        where the pump's account found it device-bound
+        (``HostGapTracker.device_s``)."""
         if dur_s <= 0.0:
             return
         flops, bytes_ = self.model.dispatch_cost(live_ctx, width, ksteps,
@@ -320,6 +344,69 @@ class CapacityMeter:
         return out
 
 
+def thread_cpu_clock(ident):
+    """A callable that reads, from ANY thread, the processor seconds the
+    LIVE thread ``ident`` (``threading.get_ident()``, ``Thread.ident``) has
+    burned; None where the platform keeps no such clock. Reading it after
+    the thread has exited raises ``OSError``."""
+    try:
+        clock = time.pthread_getcpuclockid(ident)
+        time.clock_gettime(clock)
+    except (AttributeError, OSError):
+        return None
+    return lambda: time.clock_gettime(clock)
+
+
+class Delivery:
+    """What the gateway's event-loop thread delivered, as totals that only
+    that thread adds to and the primary pump's account reads at a landing
+    and takes differences of. Nothing is swapped or reset (but the maximum,
+    where a lost race costs one event's lag): an addition the reader catches
+    half done is whole in the next period, and none is ever lost, so posted =
+    written + taken + unread + backlog holds at every reading.
+
+    ``events``, ``writes``, ``bytes``: SSE token events whose bytes were
+    handed to the transport, the ``write`` calls and the bytes they took.
+    ``lag_s`` / ``lag_max_s``: from the landing an event came from to that
+    hand-over, summed, and the largest since the last reading. ``taken``:
+    token events a response that does not stream took off its queue, or a
+    handler that went away left in it. ``unread`` (the pumps add to it):
+    token events posted to a handler that had gone. ``pumps``: the trackers
+    whose pumps post (``posted``: one a replica).
+
+    *Which landing an event came from* costs the pump nothing a token (on a
+    host-paced server a microsecond a token on either thread is a twentieth
+    of the tokens: PR 52's first build stamped every event and opened a span
+    for each, and a traced cell-9 run lost a sixth of its tokens). The loop
+    hands events on in the order they were posted, so the n-th it handles is
+    the n-th posted: every landing leaves ``(token events posted before it,
+    its time)`` in ``landings`` and the loop reads the landing of its n-th
+    event off the head (:meth:`landing_of`). Exact where one pump posts and
+    the handlers run in order; a landing out where they interleave."""
+
+    __slots__ = ("events", "writes", "bytes", "lag_s", "lag_max_s", "taken", "unread",
+                 "pumps", "landings")
+
+    def __init__(self):
+        self.events = self.writes = self.bytes = self.taken = self.unread = 0
+        self.lag_s = self.lag_max_s = 0.0
+        self.pumps = []
+        # bounded: a loop that writes nothing (unary traffic alone) never
+        # reads the head off
+        self.landings = collections.deque(maxlen=4096)
+
+    def posted(self):
+        return sum(p.posted for p in self.pumps)
+
+    def landing_of(self, n):
+        """The time of the landing that posted the ``n``-th token event
+        (1-based), or None before any landing. Called by the loop alone."""
+        ring = self.landings
+        while len(ring) > 1 and ring[1][0] < n:
+            ring.popleft()
+        return ring[0][1] if ring else None
+
+
 class HostGapTracker:
     """The pump thread's account of its time, from its span boundaries.
 
@@ -357,13 +444,48 @@ class HostGapTracker:
     cannot tell the two pumps apart (both alternate dispatch and fetch),
     hence the callable. Where the host's work a sync passes the device's
     the gap still reads 0 (the host cannot see the device finish): ``wait``
-    near 0 is the sign."""
+    near 0 is the sign.
+
+    *The device's time.* A landed period in which the pump waited for the
+    device for ``device_bound_share`` of it or more, and handed it at most
+    one program, was the device's: ``device_s`` is the period less the gap
+    measured in it (a serial pump's host work before its dispatch), else
+    None. The scheduler reads it behind every fetch and feeds the capacity
+    gauges from it (``DecodeScheduler._landed``); nothing is fenced.
+
+    *The two threads.* ``bind_threads`` gives the account the processor
+    clocks of the pump's thread and, for the primary replica's, of the event
+    loop's, and the loop's :class:`Delivery`. Every close then reads them
+    (two ``clock_gettime`` a period, nothing a token): ``serving/pump_cpu_ms``,
+    ``serving/loop_cpu_ms``, ``serving/host_threads_cpu_pct`` (100 x the two
+    over the wall time), each the mean a sync over the run of landed periods
+    that ends in this landing (``CPU_PERIODS`` at most: a thread's clock may
+    tick more coarsely than a period lasts). The share CAN pass 100: a thread
+    in a system call or in XLA's own code holds no interpreter lock, and the
+    loop's ``send`` and the pump's dispatch and ``device_get`` are such. From
+    the loop's totals: ``gateway/backlog_events`` (posted less written and
+    taken, now), and over the events written since the last close
+    ``gateway/delivery_lag_ms`` (their mean), ``gateway/delivery_lag_max_ms``
+    and ``gateway/loop_cpu_us_per_event`` (the loop's processor time over the
+    events written, both over that run of periods); a period that wrote none
+    observes none of the three. Histograms hold one observation a LANDED sync, like
+    ``serving/pump_busy_ms``; the counters beside them
+    (``serving/pump/cpu_ms``, ``gateway/loop/cpu_ms``, ``gateway/sse_events``
+    / ``_writes`` / ``_bytes``) take every close. ``pump_busy_ms`` less
+    ``pump_cpu_ms`` is the time the pump held a span open without the
+    processor: the lock, or the machine. ``posted`` is the pump's side of
+    the delivery: the token events it posted, which are the tokens its
+    scheduler delivered (``DecodeScheduler._observe`` adds a landing's at
+    once; nothing is counted or stamped a token)."""
 
     __slots__ = ("sink", "_unlanded", "_compiles", "_compiled", "_open_ts", "gaps",
                  "total_gap_s", "_stack", "_acc", "_period_ts", "_was_idle", "_left_ts",
-                 "_left_gateway", "busy_s", "wait_s")
+                 "_left_gateway", "busy_s", "wait_s", "device_bound_share", "device_s",
+                 "_period_gap_s", "_period_dispatches", "posted", "_posts",
+                 "_pump_cpu", "_loop_cpu", "_delivery", "_cpu_marks", "_sent_mark", "_bound")
 
-    def __init__(self, sink, unlanded=None, compiles=None):
+    def __init__(self, sink, unlanded=None, compiles=None,
+                 device_bound_share=DEVICE_BOUND_SHARE):
         self.sink = sink
         self._unlanded = unlanded if unlanded is not None else (lambda: False)
         self._compiles = compiles if compiles is not None else (lambda: 0)
@@ -379,6 +501,47 @@ class HostGapTracker:
         self._left_gateway = False   # ... and whether that span was the gateway's
         self.busy_s = 0.0
         self.wait_s = 0.0
+        self.device_bound_share = float(device_bound_share)
+        self.device_s = None     # the device's time for the sync that just landed, or None
+        self._period_gap_s = 0.0     # device-idle gap measured in the open period
+        self._period_dispatches = 0  # programs handed to the device in it
+        self.posted = 0          # token events this pump posted to the event loop
+        self._posts = None       # the loop's Delivery, which every landing tells its time
+        self._pump_cpu = self._loop_cpu = self._delivery = None
+        self._bound = False      # whether a gateway's pump told it its threads
+        # (ts, pump, loop processor seconds, events written) where each of the
+        # last CPU_PERIODS landed periods began, and where the open one did
+        self._cpu_marks = collections.deque(maxlen=CPU_PERIODS + 1)
+        self._sent_mark = (0, 0, 0, 0.0)  # the loop's totals at the last close
+
+    def bind_threads(self, pump_cpu, delivery=None, loop_cpu=None, primary=False):
+        """The gateway's pump, as it starts: ``pump_cpu`` reads its thread's
+        processor seconds (:func:`thread_cpu_clock`), ``delivery`` is the
+        event loop's totals (this pump's ``posted`` is counted into its
+        backlog from here on). A fleet has several pumps and one loop: the
+        ``primary`` replica's pump also gives ``loop_cpu``, the loop thread's
+        clock, and its account emits the loop's numbers; the others emit
+        their pump's alone. A clock the platform lacks is None and the
+        numbers it feeds are left out, not made up."""
+        self._pump_cpu, self._loop_cpu = pump_cpu, loop_cpu if primary else None
+        self._cpu_marks.clear()
+        self._bound = True
+        # what the scheduler delivered before a gateway pumped it (a caller's
+        # own requests, a benchmark's reference check) was posted to no loop
+        self.posted = 0
+        if delivery is not None:
+            delivery.pumps.append(self)
+            self._posts = delivery
+            if primary:
+                self._delivery = delivery
+                self._sent_mark = (delivery.events, delivery.writes, delivery.bytes,
+                                   delivery.lag_s)
+
+    def unbind_threads(self):
+        """The gateway's pump, as it exits: a thread's clock cannot be read
+        once the thread is gone. What the pump posted stays counted."""
+        self._pump_cpu = self._loop_cpu = self._delivery = self._posts = None
+        self._bound = False
 
     def span_enter(self, name, ts):
         """A span of the pump opened at ``ts``."""
@@ -412,6 +575,10 @@ class HostGapTracker:
             # results on the host: the device idles from here unless the
             # next sync was launched already
             self._open_ts = None if self._unlanded() else t1
+            if self._posts is not None:
+                # what this landing delivers is posted behind everything
+                # posted so far
+                self._posts.landings.append((self._posts.posted(), t1))
             self._close(t1, landed=True)
         elif name == "gateway/idle" and not self._unlanded():
             self._was_idle = True
@@ -421,11 +588,13 @@ class HostGapTracker:
         gap closes. Behind a sync that has not landed the device was busy: a
         gap of 0.0. A dispatch before any sync (warm-up) records nothing."""
         open_ts, self._open_ts = self._open_ts, None
+        self._period_dispatches += 1
         if open_ts is None and not self._unlanded():
             return
         gap = 0.0 if open_ts is None else max(0.0, ts - open_ts)
         self.gaps += 1
         self.total_gap_s += gap
+        self._period_gap_s += gap
         self.sink.histogram("serving/host_gap_ms", gap * 1e3)
 
     def _close(self, ts, landed):
@@ -439,9 +608,11 @@ class HostGapTracker:
             covered = ts - span[1]
             span[1], span[2] = ts, 0.0
         start, self._period_ts = self._period_ts, ts
+        self.device_s = None
         if start is not None:
+            period = ts - start
             wait = acc["wait"]
-            busy = ts - start - wait - acc["idle"] - acc["compile"]
+            busy = period - wait - acc["idle"] - acc["compile"]
             acc["gateway"] = busy - sum(acc[b] for b in BUSY_BUCKETS if b != "gateway")
             self.busy_s += busy
             self.wait_s += wait
@@ -449,8 +620,73 @@ class HostGapTracker:
             if landed:
                 sink.histogram("serving/pump_busy_ms", busy * 1e3)
                 sink.histogram("serving/pump_wait_ms", wait * 1e3)
+                if (period > 0.0 and self._period_dispatches <= 1
+                        and wait >= self.device_bound_share * period):
+                    self.device_s = period - self._period_gap_s
             for part, v in (("busy", busy), *acc.items()):
                 if v:
                     sink.counter(f"serving/pump/{part}_ms", v * 1e3)
+        if self._bound:
+            self._close_threads(ts, start is not None, landed)
+        self._period_gap_s, self._period_dispatches = 0.0, 0
         for part in acc:
             acc[part] = 0.0
+
+    def _close_threads(self, ts, opened, landed):
+        """The two threads' part of a close at ``ts``: their processor time
+        and what the loop delivered. ``opened``: a period was open (else the
+        clocks are only marked). The processor time is observed as the mean
+        a sync over the landed periods that end here, ``CPU_PERIODS`` at
+        most and none across an idle stretch; the counters take every
+        close's own difference, so their totals are exact."""
+        sink = self.sink
+        pump_cpu, loop_cpu, d = self._pump_cpu, self._loop_cpu, self._delivery
+        # ``events`` before the sums read behind it: the loop adds to it
+        # last, so every event counted has its lag and its bytes in them;
+        # and ``posted`` last of all (counted before the post, and only
+        # growing), so the backlog is never under 0
+        events = d.events if d is not None else 0
+        marks = self._cpu_marks
+        now = (ts, pump_cpu() if pump_cpu is not None else 0.0,
+               loop_cpu() if loop_cpu is not None else 0.0, events)
+        last = marks[-1] if marks and opened else None
+        if not (landed and opened):
+            marks.clear()   # an idle stretch, or the first period's opening
+        marks.append(now)
+        if last is None:
+            return
+        if pump_cpu is not None:
+            sink.counter("serving/pump/cpu_ms", (now[1] - last[1]) * 1e3)
+        if loop_cpu is not None:
+            sink.counter("gateway/loop/cpu_ms", (now[2] - last[2]) * 1e3)
+        if landed:
+            base, n = marks[0], len(marks) - 1
+            if pump_cpu is not None:
+                sink.histogram("serving/pump_cpu_ms", (now[1] - base[1]) / n * 1e3)
+            if loop_cpu is not None:
+                sink.histogram("serving/loop_cpu_ms", (now[2] - base[2]) / n * 1e3)
+                if pump_cpu is not None and ts > base[0]:
+                    sink.histogram("serving/host_threads_cpu_pct", 100.0 * (
+                        now[1] - base[1] + now[2] - base[2]) / (ts - base[0]))
+        if d is None:
+            return
+        sent = (events, d.writes, d.bytes, d.lag_s)
+        backlog = -(events + d.taken + d.unread) + d.posted()
+        lag_max, d.lag_max_s = d.lag_max_s, 0.0
+        before, self._sent_mark = self._sent_mark, sent
+        n = events - before[0]
+        if n:
+            sink.counter("gateway/sse_events", n)
+            sink.counter("gateway/sse_writes", sent[1] - before[1])
+            sink.counter("gateway/sse_bytes", sent[2] - before[2])
+        if landed:
+            sink.histogram("gateway/backlog_events", backlog)
+            if n:
+                sink.histogram("gateway/delivery_lag_ms", (sent[3] - before[3]) / n * 1e3)
+                sink.histogram("gateway/delivery_lag_max_ms", lag_max * 1e3)
+                if loop_cpu is not None:
+                    # the loop's processor time over the events written, both
+                    # over the run of periods the thread readings span
+                    base = marks[0]
+                    sink.histogram("gateway/loop_cpu_us_per_event",
+                                   (now[2] - base[2]) * 1e6 / (events - base[3]))

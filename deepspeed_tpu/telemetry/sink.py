@@ -286,11 +286,6 @@ class TelemetrySink:
         # it before building RequestTrace objects / iteration spans)
         self.trace_requests = bool(_cfg_get(config, "request_tracing", True))
         self.slo_config = dict(_cfg_get(config, "slo", None) or {})
-        # roofline/goodput capacity accounting (telemetry/capacity.py):
-        # fence-and-time every Nth scheduler sync (1 = every sync — tests
-        # only; 0/absent = the 1/32 default)
-        self.capacity_sample_every = max(1, int(
-            _cfg_get(config, "capacity_sample_every", 32) or 32))
         self._monitor = monitor
         self._lock = threading.RLock()
         self._io_lock = threading.Lock()  # serializes JSONL appends/trace writes

@@ -4,8 +4,14 @@ on-demand profiling).
 The contracts under test, in the order the module docstring of
 ``telemetry/capacity.py`` states them:
 
-- the sampled fenced-timing window adds ZERO new XLA programs after warmup
-  (jax.monitoring-guarded, ``capacity_sample_every=1`` so EVERY sync fences);
+- a landing's device-bound period is the capacity sample: nothing is fenced,
+  a host-bound period is no sample, and sampling adds ZERO new XLA programs
+  after warmup (jax.monitoring-guarded; a tiny model on the CPU is
+  host-bound, so the real-scheduler tests set the tracker's device-bound
+  share to 0 and EVERY landing samples);
+- the account of the host's two threads: their CPU time from the two
+  injected clocks, and the event loop's delivery (posted = written + taken +
+  backlog at every landing);
 - host-gap bucket counters sum EXACTLY to the measured gap — including the
   deferred-steal case where the nested timer stamps before its enclosing
   section, and the over-attribution scale-back;
@@ -36,8 +42,8 @@ import jax
 import deepspeed_tpu
 from deepspeed_tpu.comm import comm
 from deepspeed_tpu.telemetry.capacity import (
-    BUSY_BUCKETS, PUMP_PARTS, CapacityMeter, CapacityModel, HostGapTracker, program_shape,
-    _program_kind)
+    BUSY_BUCKETS, DEVICE_BOUND_SHARE, PUMP_PARTS, PUMP_SPANS, CapacityMeter, CapacityModel,
+    Delivery, HostGapTracker, program_shape, thread_cpu_clock, _program_kind)
 from deepspeed_tpu.telemetry.profiler import (ProfileBusy, XlaProfiler,
                                               trace_artifacts)
 
@@ -53,7 +59,8 @@ def _count_xla_compiles():
     return _XLA_COMPILES
 
 
-def make_engine(params=None, num_slots=4, telemetry=None, **cb_extra):
+def make_engine(params=None, num_slots=4, telemetry=None, every_landing_samples=False,
+                **cb_extra):
     comm._state["mesh"] = None
     from deepspeed_tpu.telemetry import set_sink
     set_sink(None)  # sink hermeticity: no cross-test counter bleed
@@ -63,7 +70,12 @@ def make_engine(params=None, num_slots=4, telemetry=None, **cb_extra):
            "continuous_batching": cb}
     if telemetry:
         cfg["telemetry"] = telemetry
-    return deepspeed_tpu.init_inference("tiny", config=cfg, params=params)
+    eng = deepspeed_tpu.init_inference("tiny", config=cfg, params=params)
+    if every_landing_samples:
+        # a tiny model on the CPU is host-bound: its pump hardly waits. With
+        # the share at 0 every landed period counts as the device's time
+        eng.scheduler()._gap.device_bound_share = 0.0
+    return eng
 
 
 @pytest.fixture(scope="module")
@@ -254,7 +266,7 @@ def test_host_gap_of_a_pump_one_sync_deep(ahead):
         assert sink.hists["serving/pump_wait_ms"] == [pytest.approx(9.0)]
         assert _buckets_ms(sink) == {"admit": pytest.approx(4.0), "assemble": pytest.approx(2.0),
                                      "dispatch": pytest.approx(2.0), "other": pytest.approx(13.0)}
-        # the pump turns serial (say a capacity-sampled fence): N+1 and N+2
+        # the pump turns serial (say a row flagged for cancellation): N+1 and N+2
         # land with nothing out, and the next dispatch measures a real gap
         unlanded[0] = False
         _play(gap, ("-sched/dispatch", 0.040, 0.041), ("sched/fetch", 0.041, 0.050),
@@ -277,7 +289,7 @@ def test_host_gap_of_a_pump_one_sync_deep(ahead):
 
 
 def test_pump_account_adds_up_over_a_scripted_run():
-    """Ahead, an idle turn, serial with a fence, a program built under a
+    """Ahead, an idle turn, a serial sync, a program built under a
     dispatch: after every boundary the busy buckets add up to ``busy`` and
     the parts to the time in closed periods, exactly; the histograms hold
     one observation a landing."""
@@ -326,10 +338,10 @@ def test_pump_account_adds_up_over_a_scripted_run():
     check(48.0)
     assert _pump_ms(sink)["idle"] == pytest.approx(40.0)
     assert len(sink.hists["serving/pump_busy_ms"]) == 2
-    # a serial, fenced sync: the fence is time blocked on the device
+    # a serial sync, landed by the step that launched it: its fetch is the
+    # time blocked on the device
     _play(gap, ("sched/admit", 2.118, 2.119), ("sched/assemble", 2.119, 2.121),
-          ("sched/fence", 2.121, 2.121), ("sched/dispatch", 2.121, 2.122),
-          ("sched/fence", 2.122, 2.140), ("sched/fetch", 2.140, 2.141))
+          ("sched/dispatch", 2.121, 2.122), ("sched/fetch", 2.122, 2.141))
     check(23.0)
     assert sink.hists["serving/pump_wait_ms"][-1] == pytest.approx(19.0)
     assert sink.hists["serving/pump_busy_ms"][-1] == pytest.approx(4.0)
@@ -337,6 +349,252 @@ def test_pump_account_adds_up_over_a_scripted_run():
     assert gap.busy_s * 1e3 == pytest.approx(_pump_ms(sink)["busy"])
     assert gap.wait_s * 1e3 == pytest.approx(_pump_ms(sink)["wait"])
     assert set(_pump_ms(sink)) <= {"busy", *PUMP_PARTS}
+    assert "sched/fence" not in PUMP_SPANS  # nothing is fenced any more
+
+
+# ------------------------------------------------ the account's two threads
+class _Clock:
+    """An injected thread CPU clock: reads what the test last set."""
+
+    def __init__(self):
+        self.s = 0.0
+
+    def __call__(self):
+        return self.s
+
+
+def _bound_tracker(unlanded=True, primary=True, share=DEVICE_BOUND_SHARE):
+    sink, pump, loop, sent = FakeSink(), _Clock(), _Clock(), Delivery()
+    gap = HostGapTracker(sink, unlanded=lambda: unlanded, device_bound_share=share)
+    gap.bind_threads(pump, sent, loop, primary=primary)
+    return sink, gap, pump, loop, sent
+
+
+def _sync(gap, t, busy=0.006, wait=0.004, burn=()):
+    """One step of a pump that runs ahead, from ``t``: ``busy`` of host work
+    (a dispatch in it) and ``wait`` under the fetch; ``burn``: (clock,
+    seconds) the threads burn in it; returns its landing."""
+    _play(gap, ("+sched/step", t))
+    for clock, seconds in burn:
+        clock.s += seconds
+    _play(gap, ("sched/dispatch", t + 0.001, t + 0.002),
+          ("sched/fetch", t + busy, t + busy + wait))
+    gap.span_exit("sched/step", t, t + busy + wait)
+    return t + busy + wait
+
+
+def test_threads_cpu_comes_from_the_two_injected_clocks_and_identities_hold():
+    sink, gap, pump, loop, _ = _bound_tracker()
+    pump.s, loop.s = 5.0, 7.0           # whatever the threads burned before
+    burn = ((pump, 0.0055), (loop, 0.0030))
+    t = _sync(gap, 0.0, burn=burn)      # period 1: opens at the step, 10 ms, 5.5 + 3.0 ms of CPU
+    t = _sync(gap, t, burn=burn)        # period 2: the same
+    assert sink.hists["serving/pump_cpu_ms"] == [pytest.approx(5.5), pytest.approx(5.5)]
+    assert sink.hists["serving/loop_cpu_ms"] == [pytest.approx(3.0), pytest.approx(3.0)]
+    assert sink.hists["serving/host_threads_cpu_pct"] == [pytest.approx(85.0)] * 2
+    assert sink.counters["serving/pump/cpu_ms"] == (2, pytest.approx(11.0))
+    assert sink.counters["gateway/loop/cpu_ms"] == (2, pytest.approx(6.0))
+    # a reading is the mean a sync over the run of landed periods that ends
+    # in it: a third period that burns nothing reads two thirds of the others
+    t = _sync(gap, t)
+    assert sink.hists["serving/pump_cpu_ms"][2] == pytest.approx(11.0 / 3)
+    assert sink.hists["serving/host_threads_cpu_pct"][2] == pytest.approx(100 * 17.0 / 30)
+    # the period's identities are what they were: buckets to busy, parts to the period
+    ms = _pump_ms(sink)
+    assert sum(_buckets_ms(sink).values()) == pytest.approx(ms["busy"], abs=1e-9)
+    assert ms["busy"] + ms["wait"] == pytest.approx(30.0)
+    assert sink.hists["serving/pump_busy_ms"] == [pytest.approx(6.0)] * 3
+    assert sink.hists["serving/pump_wait_ms"] == [pytest.approx(4.0)] * 3
+    # one observation a landed sync of each, like pump_busy_ms; the pump's
+    # CPU never passes the wall time it had (here: busy + wait)
+    for name in ("serving/pump_cpu_ms", "serving/loop_cpu_ms", "serving/host_threads_cpu_pct"):
+        assert len(sink.hists[name]) == len(sink.hists["serving/pump_busy_ms"]) == 3
+    assert set(_pump_ms(sink)) <= {"busy", "cpu", *PUMP_PARTS}
+
+
+def test_threads_cpu_reads_through_a_clock_that_ticks():
+    """The chip machine's thread clocks tick in steps of 10 ms under periods
+    of 16 ms and up: one period's reading is 0 or 10. Over the run of
+    ``CPU_PERIODS`` periods that ends in a landing the mean a sync is within
+    a tick over the run of the truth, and an idle stretch starts the run
+    anew (a reading never spans time in which nobody pumped)."""
+    from deepspeed_tpu.telemetry.capacity import CPU_PERIODS
+    sink, gap, pump, loop, _ = _bound_tracker()
+    true_pump = true_loop = 0.0
+    t = 0.0
+    for _ in range(3 * CPU_PERIODS):    # 16 ms periods: 9.3 ms of pump CPU, 4.1 of the loop's
+        gap.span_enter("sched/step", t)
+        true_pump, true_loop = true_pump + 0.0093, true_loop + 0.0041
+        pump.s, loop.s = int(true_pump * 100) / 100, int(true_loop * 100) / 100   # 10 ms ticks
+        _play(gap, ("sched/dispatch", t + 0.001, t + 0.002), ("sched/fetch", t + 0.012, t + 0.016))
+        gap.span_exit("sched/step", t, t + 0.016)
+        t += 0.016
+    tick = 10.0 / CPU_PERIODS
+    for got in sink.hists["serving/pump_cpu_ms"][CPU_PERIODS:]:
+        assert abs(got - 9.3) <= tick + 1e-9
+    for got in sink.hists["serving/loop_cpu_ms"][CPU_PERIODS:]:
+        assert abs(got - 4.1) <= tick + 1e-9
+    assert sink.hists["serving/pump_cpu_ms"][0] in (0.0, 10.0)     # one period: a tick or none
+    assert sink.counters["serving/pump/cpu_ms"][1] == pytest.approx(pump.s * 1e3)  # totals exact
+    # an idle stretch: the next landing's run starts at the step that follows it
+    flight = [False]                    # the last sync has landed, nothing is out
+    gap._unlanded = lambda: flight[0]
+    _play(gap, ("gateway/idle", t, t + 5.0))
+    n = len(sink.hists["serving/pump_cpu_ms"])
+    pump.s += 0.5                       # burned while idle (say a collection): in the counter alone
+    _play(gap, ("+sched/step", t + 5.0))
+    flight[0] = True
+    pump.s += 0.01
+    _play(gap, ("sched/dispatch", t + 5.001, t + 5.002), ("sched/fetch", t + 5.006, t + 5.010))
+    assert sink.hists["serving/pump_cpu_ms"][n] == pytest.approx(10.0)
+    assert sink.hists["serving/host_threads_cpu_pct"][n] == pytest.approx(100.0)
+
+
+def test_threads_unbound_or_clockless_leave_their_numbers_out():
+    # no gateway told the tracker its threads: nothing of the four is emitted
+    sink = FakeSink()
+    gap = HostGapTracker(sink, unlanded=lambda: True)
+    _sync(gap, _sync(gap, 0.0))
+    assert not [n for n in (*sink.hists, *sink.counters)
+                if "cpu" in n or n.startswith("gateway/")]
+    # a platform without per-thread clocks (both None): the delivery is still
+    # accounted, the CPU numbers are left out, not faked
+    sink, sent = FakeSink(), Delivery()
+    gap = HostGapTracker(sink, unlanded=lambda: True)
+    gap.bind_threads(None, sent, None, primary=True)
+    t = _sync(gap, 0.0)
+    gap.posted += 2
+    sent.lag_s += 0.004; sent.bytes += 200; sent.writes += 2; sent.events += 2
+    _sync(gap, t)
+    assert sink.hists["gateway/delivery_lag_ms"] == [pytest.approx(2.0)]
+    assert not [n for n in (*sink.hists, *sink.counters) if "cpu" in n]
+    # another replica's pump emits its own CPU and nothing of the loop's
+    sink, gap, pump, loop, sent = _bound_tracker(primary=False)
+    burn = ((pump, 0.004), (loop, 0.003))
+    _sync(gap, _sync(gap, 0.0, burn=burn), burn=burn)
+    assert sink.hists["serving/pump_cpu_ms"] == [pytest.approx(4.0)] * 2
+    assert not [n for n in (*sink.hists, *sink.counters)
+                if n.startswith("gateway/") or "loop" in n or "threads" in n]
+    assert sent.pumps == [gap]          # ... but its posts count into the backlog
+
+
+def test_thread_cpu_clock_reads_another_thread_s_clock():
+    import threading
+    done, go = threading.Event(), threading.Event()
+
+    def burn():
+        go.wait(10)
+        x = 0
+        while not done.is_set():
+            x += 1
+
+    th = threading.Thread(target=burn, daemon=True)
+    th.start()
+    clock = thread_cpu_clock(th.ident)
+    if clock is None:
+        done.set(), go.set()
+        pytest.skip("no per-thread CPU clocks on this platform")
+    before = clock()
+    go.set()
+    time.sleep(0.05)
+    burned = clock() - before       # read from here, of the other thread, while it lives
+    done.set()
+    th.join(10)
+    assert before < 0.02 and burned > 0.005
+
+
+def test_delivery_posted_is_written_plus_taken_plus_backlog_at_every_landing():
+    sink, gap, pump, loop, sent = _bound_tracker()
+    other = HostGapTracker(FakeSink())          # a second replica's pump posts too
+    other.bind_threads(_Clock(), sent)
+    t = _sync(gap, 0.0)
+    script = [  # (posted here, posted there, written, taken, lag of each written, loop CPU)
+        (8, 0, 5, 1, 0.002, 0.0004), (4, 3, 0, 0, 0.0, 0.0001), (0, 0, 9, 0, 0.010, 0.0009)]
+    posted = written = taken = 0
+    for here, there, wrote, took, lag, cpu in script:
+        gap.posted += here
+        other.posted += there
+        for _ in range(wrote):                  # the loop's own arithmetic
+            sent.lag_s += lag
+            sent.lag_max_s = max(sent.lag_max_s, lag)
+            sent.bytes += 100
+            sent.writes += 1
+            sent.events += 1
+        sent.taken += took
+        loop.s += cpu
+        t = _sync(gap, t)
+        posted, written, taken = posted + here + there, written + wrote, taken + took
+        assert sink.hists["gateway/backlog_events"][-1] == posted - written - taken >= 0
+    assert sink.hists["gateway/backlog_events"] == [0, 2, 9, 0]
+    # a period that wrote nothing observes no lag and no cost an event
+    assert sink.hists["gateway/delivery_lag_ms"] == [pytest.approx(2.0), pytest.approx(10.0)]
+    assert sink.hists["gateway/delivery_lag_max_ms"] == [pytest.approx(2.0), pytest.approx(10.0)]
+    # ... the cost an event over the run of periods the thread readings span:
+    # 400 us over 5 events, then 1,400 us over 14
+    assert sink.hists["gateway/loop_cpu_us_per_event"] == [pytest.approx(80.0),
+                                                           pytest.approx(100.0)]
+    assert sink.counters["gateway/sse_events"] == (2, 14)
+    assert sink.counters["gateway/sse_writes"] == (2, 14)
+    assert sink.counters["gateway/sse_bytes"] == (2, 1400)
+    assert len(sink.hists["gateway/backlog_events"]) == len(sink.hists["serving/pump_busy_ms"])
+
+
+def test_delivery_reads_an_event_s_landing_off_the_ring_not_off_the_event():
+    """The loop handles events in the order they were posted, so the n-th
+    it handles came from the landing that had ``< n`` posted before it:
+    every landing leaves (posted before it, its time) and nothing rides an
+    event. Events posted to a handler that has gone count ``unread`` and
+    stay out of the backlog."""
+    sink, gap, _, _, sent = _bound_tracker()
+    gap.posted = 68                 # delivered to direct callers before a gateway pumped: not posted
+    gap.bind_threads(None, None)
+    assert gap.posted == 0
+    sink, gap, _, _, sent = _bound_tracker()
+    t1 = _sync(gap, 0.0)            # landing 1 at 10 ms delivers 5 tokens
+    gap.posted += 5                 # (DecodeScheduler._observe, behind its deliver)
+    t2 = _sync(gap, t1)             # landing 2 at 20 ms delivers 3
+    gap.posted += 3
+    assert list(sent.landings) == [(0, pytest.approx(0.010)), (5, pytest.approx(0.020))]
+    assert [sent.landing_of(n) for n in (1, 5)] == [pytest.approx(0.010)] * 2
+    assert [sent.landing_of(n) for n in (6, 8)] == [pytest.approx(0.020)] * 2
+    assert list(sent.landings) == [(5, pytest.approx(0.020))]   # the head moved on
+    assert Delivery().landing_of(1) is None                      # before any landing
+    # 6 written, 1 taken, 1 posted to a handler that had gone: nothing is owed
+    sent.events, sent.taken, sent.unread = 6, 1, 1
+    _sync(gap, t2)
+    assert sink.hists["gateway/backlog_events"] == [0, 5, 0]
+
+
+@pytest.mark.parametrize("wait,ahead,sampled", [
+    (0.0, True, None),            # host-bound: the pump never waited
+    (0.0004, True, None),         # under the share: a fetch's own cost
+    (0.004, True, 0.010),         # device-bound, ahead: the period
+    (0.004, False, 0.005),        # device-bound, serial: from its dispatch (1 ms) to its landing
+])
+def test_device_bound_period_is_the_capacity_sample(wait, ahead, sampled):
+    sink = FakeSink()
+    gap = HostGapTracker(sink, unlanded=lambda: ahead)
+    busy = 0.010 - wait
+    t = _sync(gap, 0.0, busy, wait)
+    _play(gap, ("+sched/step", t), ("sched/dispatch", t + busy - 0.001, t + busy),
+          ("sched/fetch", t + busy, t + 0.010))
+    assert gap.device_s == (None if sampled is None else pytest.approx(sampled))
+    # what the scheduler does with it (DecodeScheduler._landed)
+    model = CapacityModel(type("C", (), {"hidden_size": 64, "num_layers": 2,
+                                         "num_heads": 4, "vocab_size": 128})(),
+                          kv_bytes_per_token=1024, num_slots=4)
+    meter = CapacityMeter(sink, model, peak_flops=1e12, peak_hbm_bw=1e11)
+    if gap.device_s is not None:
+        meter.observe_dispatch(("fused", True, False, 1, 4), gap.device_s,
+                               np.array([10, 20]), width=1, ksteps=4)
+    assert meter.samples == (sampled is not None)
+    if sampled is not None:
+        flops, _ = model.dispatch_cost(np.array([10, 20]), 1, 4)
+        assert sink.gauges["serving/mfu"] == pytest.approx(flops / sampled / 1e12)
+    # a period in which two programs were dispatched (a replay) is no sample
+    _play(gap, ("sched/dispatch", t + 0.011, t + 0.012), ("sched/dispatch", t + 0.012, t + 0.013),
+          ("sched/fetch", t + 0.013, t + 0.020))
+    assert gap.device_s is None
 
 
 # ---------------------------------------------------------- program-key units
@@ -373,13 +631,12 @@ def test_observe_dispatch_roofline_classification():
                                          "num_heads": 4, "vocab_size": 128})(),
                           kv_bytes_per_token=1024, num_slots=4)
     sink = FakeSink()
-    meter = CapacityMeter(sink, model, peak_flops=1e12, peak_hbm_bw=1e11,
-                          sample_every=4)
+    meter = CapacityMeter(sink, model, peak_flops=1e12, peak_hbm_bw=1e11)
     key = ("fused", True, False, 1, 1)
     meter.register(key, model)  # any hashable stand-in for the fn
     assert meter.key_for(model) == key
-    assert [meter.should_sample(s) for s in range(5)] == [
-        True, False, False, False, True]
+    meter.observe_dispatch(key, 0.0, np.array([10, 20]), width=1, ksteps=1)
+    assert meter.samples == 0  # no time, no sample
     meter.observe_dispatch(key, 1e-3, np.array([10, 20]), width=1, ksteps=1)
     assert meter.samples == 1
     assert 0.0 < sink.gauges["serving/mfu"]
@@ -444,13 +701,18 @@ def _decode(eng, n=3, max_new=8):
 
 
 def test_capacity_metrics_emitted_cpu_smoke(params, tmp_path):
-    eng = make_engine(params, telemetry={"enabled": True,
-                                         "output_path": str(tmp_path),
-                                         "capacity_sample_every": 1})
+    eng = make_engine(params, telemetry={"enabled": True, "output_path": str(tmp_path)},
+                      every_landing_samples=True)
     _decode(eng)
     sched = eng.scheduler()
     assert sched.capacity is not None and sched._gap is not None
-    assert sched.capacity.samples > 0
+    # every landing behind at most one dispatch was a sample, and no sync
+    # launched serial for it: the pump ran ahead wherever rows were live
+    landed = sched.syncs_ahead + sched.syncs_serial
+    assert 0 < sched.capacity.samples <= landed
+    assert eng.telemetry.snapshot()["counters"]["serving/capacity_samples"]["total"] \
+        == sched.capacity.samples
+    assert sched.syncs_ahead > sched.syncs_serial
     table = sched.capacity.program_table()
     assert table and all(e["bound"] in ("compute", "bandwidth")
                          for e in table.values())
@@ -462,7 +724,6 @@ def test_capacity_metrics_emitted_cpu_smoke(params, tmp_path):
     assert hg["count"] == sched._gap.gaps > 0
     # the account: one observation a landed sync, parts of known names only,
     # the busy buckets summing to busy
-    landed = sched.syncs_ahead + sched.syncs_serial
     assert snap["histograms"]["serving/pump_busy_ms"]["count"] == landed > 0
     assert snap["histograms"]["serving/pump_wait_ms"]["count"] == landed
     pump = {name[len("serving/pump/"):-len("_ms")]: c["total"]
@@ -472,6 +733,8 @@ def test_capacity_metrics_emitted_cpu_smoke(params, tmp_path):
     assert pump["busy"] == pytest.approx(sched._gap.busy_s * 1e3, rel=1e-6)
     assert pump["wait"] == pytest.approx(sched._gap.wait_s * 1e3, rel=1e-6)
     assert pump["compile"] > 0  # the step programs were built under sched/dispatch
+    # no gateway told the account its threads: none of their numbers here
+    assert "serving/pump_cpu_ms" not in snap["histograms"]
     # Prometheus rendering carries the gauges + the native histogram family
     from deepspeed_tpu.telemetry.prometheus import render
     text = render(snap)
@@ -492,13 +755,13 @@ def test_disabled_sink_allocates_nothing(params):
                 if n.startswith("serving/pump")]
 
 
-def test_sampled_fencing_adds_zero_new_xla_programs(params, tmp_path):
-    """capacity_sample_every=1 fences EVERY sync — over a warm mix of both
-    prompt-length buckets, fresh requests must add zero compiles."""
+def test_landing_samples_add_zero_new_xla_programs(params, tmp_path):
+    """With EVERY landing a capacity sample, over a warm mix of both
+    prompt-length buckets, fresh requests must add zero compiles: the
+    sample is arithmetic on the period, no program and no fence."""
     compiles = _count_xla_compiles()
-    eng = make_engine(params, telemetry={"enabled": True,
-                                         "output_path": str(tmp_path),
-                                         "capacity_sample_every": 1})
+    eng = make_engine(params, telemetry={"enabled": True, "output_path": str(tmp_path)},
+                      every_landing_samples=True)
     _decode(eng, n=3)  # warm: both prefill buckets + fused decode
     before = len(compiles)
     fresh = [np.roll(PROMPTS[0], 5), np.roll(PROMPTS[1], 3)]
@@ -507,7 +770,7 @@ def test_sampled_fencing_adds_zero_new_xla_programs(params, tmp_path):
     for h in handles:
         assert h.result().tolist()
     assert len(compiles) == before, \
-        f"sampled fencing added {len(compiles) - before} XLA program(s)"
+        f"sampling added {len(compiles) - before} XLA program(s)"
     assert eng.scheduler().capacity.samples > 0
     eng.telemetry.close()
 
@@ -515,25 +778,33 @@ def test_sampled_fencing_adds_zero_new_xla_programs(params, tmp_path):
 def test_instrumented_decode_overhead_bounded(params, tmp_path):
     """The capacity instrumentation's marginal cost per sync, as counts (a
     wall-clock ratio of two tiny CPU decodes is the machine's load, not the
-    program's cost): with fenced sampling every 4th sync, one dispatch in
-    four is fenced and never more — the async hot path is not serialized —
-    and the pump marks at most 8 spans a sync, however many tokens the sync
-    carries."""
-    eng = make_engine(params, telemetry={
-        "enabled": True, "output_path": str(tmp_path), "capacity_sample_every": 4})
+    program's cost): sampling serializes nothing — with every landing a
+    sample the pump still launches ahead of all but the first sync of a
+    burst, and at the stated share a host-bound CPU run samples at most as
+    often — and the pump marks at most 8 spans a sync, none of them a fence,
+    however many tokens the sync carries."""
+    eng = make_engine(params, telemetry={"enabled": True, "output_path": str(tmp_path)},
+                      every_landing_samples=True)
     sched = eng.scheduler()
     _decode(eng, n=2)  # warm
-    syncs0, fenced0 = sched._sync_seq, sched.capacity.samples
+    ahead0, serial0, sampled0 = sched.syncs_ahead, sched.syncs_serial, sched.capacity.samples
     _decode(eng, n=4, max_new=48)
-    syncs, fenced = sched._sync_seq - syncs0, sched.capacity.samples - fenced0
-    assert syncs >= 8
-    assert 1 <= fenced <= syncs // 4 + 1, (fenced, syncs)
+    ahead, serial = sched.syncs_ahead - ahead0, sched.syncs_serial - serial0
+    sampled = sched.capacity.samples - sampled0
+    assert ahead + serial >= 8
+    assert serial <= 2 and ahead >= 6, (ahead, serial)   # a burst's first sync alone
+    assert 1 <= sampled <= ahead + serial, (sampled, ahead, serial)
+    # at the stated share the same run samples no more than that
+    sched._gap.device_bound_share = DEVICE_BOUND_SHARE
+    sampled0, landed0 = sched.capacity.samples, sched.syncs_ahead + sched.syncs_serial
+    _decode(eng, n=2, max_new=16)
+    assert sched.capacity.samples - sampled0 <= sched.syncs_ahead + sched.syncs_serial - landed0
     eng.telemetry.close()
     with open(eng.telemetry.jsonl_path) as f:
         spans = [e["name"] for e in map(json.loads, f)
                  if e.get("type") == "span" and e["name"].startswith("sched/")]
     steps = spans.count("sched/step")
-    assert steps >= 8
+    assert steps >= 8 and "sched/fence" not in spans
     assert (len(spans) - steps) / steps <= 8, (len(spans), steps)
 
 
@@ -580,8 +851,8 @@ def test_profiler_report_boundary_request(tmp_path):
 def test_gateway_profile_endpoint_and_capacity_metrics(params, tmp_path):
     from deepspeed_tpu.serving import Gateway
     eng = make_engine(params, num_slots=2,
-                      telemetry={"enabled": True, "output_path": str(tmp_path),
-                                 "capacity_sample_every": 1})
+                      telemetry={"enabled": True, "output_path": str(tmp_path)},
+                      every_landing_samples=True)
     gw = Gateway(eng, port=0, request_timeout_s=60.0)
     gw.start_background()
     base = f"http://127.0.0.1:{gw.port}"
@@ -606,6 +877,8 @@ def test_gateway_profile_endpoint_and_capacity_metrics(params, tmp_path):
         assert cap["host_gap_total_s"] >= 0.0
         assert cap["host_gaps"] >= 0
         assert cap["pump_busy_total_s"] > 0.0 and cap["pump_wait_total_s"] >= 0.0
+        # a unary response takes its token events off the queue and writes none
+        assert cap["delivery"] == {"posted": 6, "written": 0, "taken": 6, "unread": 0}
         text = get("/v1/metrics", {"Accept": "text/plain"}).decode()
         assert "dstpu_serving_mfu " in text
         assert 'dstpu_serving_host_gap_ms_hist_bucket{le="' in text

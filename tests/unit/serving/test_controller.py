@@ -186,6 +186,39 @@ def test_host_gap_vetoes_scale_up_into_brownout():
     assert ctl.brownout_level == 1
 
 
+@pytest.mark.parametrize("busy_ms,wait_ms,vetoed", [(9.0, 1.0, True), (2.0, 8.0, False)])
+def test_fleet_signals_host_fraction_is_the_account_s_not_the_gaps(
+        params, tmp_path, busy_ms, wait_ms, vetoed):
+    """D17: behind a pump that runs ahead every device-idle gap reads 0.0,
+    so the veto's input is the account's own totals over the tick: a host
+    busy 0.9 of its syncs vetoes the scale-up whatever the gaps say."""
+    eng = make_engine(params, telemetry={"enabled": True, "output_path": str(tmp_path)})
+    gw = Gateway(eng, port=0)               # never started: the signals alone
+    gap = eng.scheduler()._gap
+    gap._unlanded = lambda: True            # a pump that runs ahead
+    assert gw.fleet_signals(now=0.0).host_gap_frac == 0.0   # nothing to compare with yet
+    t = 0.0
+    for _ in range(20):                     # twenty syncs of busy + wait
+        gap.span_enter("sched/step", t)
+        gap.span_enter("sched/dispatch", t + 0.0005)
+        gap.span_exit("sched/dispatch", t + 0.0005, t + 0.001)
+        t += (busy_ms + wait_ms) / 1e3
+        gap.span_enter("sched/fetch", t - wait_ms / 1e3)
+        gap.span_exit("sched/fetch", t - wait_ms / 1e3, t)
+        gap.span_exit("sched/step", t - (busy_ms + wait_ms) / 1e3, t)
+    assert gap.gaps >= 19 and gap.total_gap_s == 0.0        # every gap 0.0
+    sig = gw.fleet_signals(now=1.0)
+    assert sig.host_gap_frac == pytest.approx(busy_ms / (busy_ms + wait_ms))
+    d = make_ctl().tick(hot(10.0, host_gap_frac=sig.host_gap_frac))
+    if vetoed:
+        assert d["action"] == "brownout" and "host_bound" in d["reason"]
+    else:
+        assert d["action"] == "scale_up"
+    # the next tick sees only what was accounted since this one: nothing
+    assert gw.fleet_signals(now=2.0).host_gap_frac == 0.0
+    eng.telemetry.close()
+
+
 def test_at_max_replicas_escalates_brownout_ladder():
     ctl = make_ctl()
     trace, t = [], 0.0

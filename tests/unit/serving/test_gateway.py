@@ -548,3 +548,81 @@ def test_fair_queue_adapter_flows_share_tenant_weight():
     while q.pop() is not None:
         pass
     assert len(q) == 0 and not q._siblings and not q._flows
+
+
+# ------------------------------------------------- the event loop's delivery
+@pytest.mark.parametrize("sink_on", [True, False], ids=["sink-on", "sink-off"])
+def test_delivery_account_of_four_streams(tmp_path, baseline, monkeypatch, sink_on):
+    """Four streaming clients. Sink on: every delivered token is counted
+    posted (a landing's at once: the pump still posts its 3-tuples), every
+    posted event is written, one send in 64 under a ``gateway/send``
+    annotation, the bytes are the clients', no lag is negative, and every
+    landed sync observes each of the account's new histograms once. Sink off:
+    the pump posts the 3-tuples it always did and the loop makes no span."""
+    import asyncio
+    params, _ = baseline
+    cfg = {"telemetry": {"enabled": True, "output_path": str(tmp_path)}} if sink_on else {}
+    eng = make_engine(params=params, num_slots=4, **cfg)
+    spans, posted = [], []
+    real_span, real_put = eng.telemetry.span, asyncio.Queue.put_nowait
+
+    def span(name, *a, **kw):
+        spans.append(name)
+        return real_span(name, *a, **kw)
+
+    def put_nowait(self, ev):
+        if isinstance(ev, tuple) and ev[0] == "token":
+            posted.append(ev)
+        return real_put(self, ev)
+
+    monkeypatch.setattr(eng.telemetry, "span", span)
+    monkeypatch.setattr(asyncio.Queue, "put_nowait", put_nowait)
+    gw = Gateway(eng, port=0)
+    gw.start_background()
+    raws = [None] * 4
+
+    def client(i):
+        conn = http.client.HTTPConnection("127.0.0.1", gw.port, timeout=120)
+        conn.request("POST", "/v1/completions", json.dumps(
+            {"prompt": [5 + i, 6, 7, 8, 9], "max_tokens": 24, "stream": True}), {})
+        raws[i] = conn.getresponse().read()
+        conn.close()
+
+    try:
+        threads = [threading.Thread(target=client, args=(i, )) for i in range(4)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(120)
+    finally:
+        assert gw.close(timeout=60)
+    n = 4 * 24
+    assert all(sse_tokens(raw)[2] for raw in raws) and len(posted) == n
+    assert sum(len(sse_tokens(raw)[0]) for raw in raws) == n
+    assert all(len(ev) == 3 for ev in posted)    # nothing rides a token event, sink on or off
+    if not sink_on:
+        assert "gateway/send" not in spans and gw._delivery is None
+        return
+    d = gw._delivery
+    assert spans.count("gateway/send") == n // 64   # one send in 64 is annotated
+    assert d.posted() == d.events == d.writes == n and d.taken == d.unread == 0
+    assert d.bytes == sum(len(raw) for raw in raws) - 4 * len(b"data: [DONE]\n\n")
+    assert d.lag_s >= 0.0
+    hists = eng.telemetry.snapshot()["histograms"]
+    landed = hists["serving/pump_busy_ms"]["count"]
+    for name in ("serving/pump_cpu_ms", "serving/loop_cpu_ms", "serving/host_threads_cpu_pct",
+                 "gateway/backlog_events"):
+        assert hists[name]["count"] == landed > 0, name
+    for name in ("gateway/delivery_lag_ms", "gateway/delivery_lag_max_ms",
+                 "gateway/loop_cpu_us_per_event"):   # one a landed sync that wrote anything
+        assert 0 < hists[name]["count"] <= landed, name
+        assert hists[name]["min"] >= 0.0
+    assert hists["gateway/backlog_events"]["min"] >= 0
+    assert hists["gateway/backlog_events"]["max"] <= n
+    # the pump's CPU is within the wall time of its account's periods (the
+    # step programs were built in them: ``compile`` is a part of its own)
+    total = eng.telemetry.counter_total
+    wall = sum(total(f"serving/pump/{part}_ms") for part in ("busy", "wait", "idle", "compile"))
+    assert 0.0 < total("serving/pump/cpu_ms") <= 1.02 * wall + 5.0
+    assert 0.0 < total("gateway/loop/cpu_ms") <= 1.02 * wall + 5.0
+    assert 0 < eng.telemetry.counter_total("gateway/sse_events") <= n
