@@ -232,6 +232,7 @@ class DeviceDraft:
                         else:
                             self._deliver(req, tok)
                         delivered += 1
+            self._landing_delivered()
         self._count_discarded(discarded)
         void = row_steps - accepted
         self.spec_steps += K

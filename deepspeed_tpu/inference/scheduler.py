@@ -895,6 +895,10 @@ class DecodeScheduler(DeviceDraft):
         # request traces stamp it so the migration-aware trace_summary view
         # can pair prefill and decode replicas per request
         self.replica_idx = None
+        # OPTIONAL ``on_landing()``: called, with no arguments, once a
+        # landing's tokens have all been through their ``on_token`` hooks
+        # (:meth:`_landing_delivered`); whoever batches tokens sets it
+        self.on_landing = None
         # serving capacity accounting (telemetry/capacity.py): per-program
         # roofline registry + the pump's account, which also times the device.
         # Only built on an enabled sink — the disabled path allocates
@@ -967,7 +971,9 @@ class DecodeScheduler(DeviceDraft):
         the device step, never inside it). Hook exceptions are logged and
         swallowed so one bad consumer can't wedge the shared decode loop.
         Cancelled requests stop receiving callbacks; the hook is never
-        called with a token after it has seen ``done=True``.
+        called with a token after it has seen ``done=True``. A consumer that
+        would rather hear of a landing's tokens at once sets the scheduler's
+        ``on_landing`` beside its hooks (:meth:`_landing_delivered`).
 
         ``adapter_id``: OPTIONAL model variant (multi-LoRA serving) — the
         request's rows decode through that adapter's paged (A, B) pages
@@ -1523,7 +1529,9 @@ class DecodeScheduler(DeviceDraft):
                 tr.phase("prefill_chunk", start=fl.t0,
                          flow_in=[fid] if fid else None,
                          pos=int(pos), take=int(take), final=bool(final))
-        delivered = self._deliver_block(fl.rows, toks_k, logits_k, fl.K)
+        # a final chunk's row delivers behind the decode rows, in this landing
+        chunk_next = fl.final and not fl.chunk[0].done
+        delivered = self._deliver_block(fl.rows, toks_k, logits_k, fl.K, chunk_next)
         if fl.chunk is not None:
             delivered += self._deliver_chunk(fl, toks_k, logits_k)
         self._observe(delivered, fl.K, fl.t0)
@@ -1554,9 +1562,6 @@ class DecodeScheduler(DeviceDraft):
                     ("serving/kv_bytes_live", self.cache.live_bytes(), None)])
         cap = self.capacity
         if cap is not None:
-            # under a gateway every delivered token was posted to its event
-            # loop: the delivery's account takes a landing's at once
-            self._gap.posted += delivered
             # goodput: tokens delivered vs computed-then-discarded.
             # Speculative rejected columns fold in here (as the delta
             # of drafted - accepted this sync); MoE miss replays and
@@ -2283,12 +2288,28 @@ class DecodeScheduler(DeviceDraft):
             req.choice.append(first[:, slot, :width])
             req.choice.extend(substeps[j][:, slot][:, None] for j in range(1, K))
 
-    def _deliver_block(self, live, toks_k, logits_k, K):
+    def _landing_delivered(self):
+        """A landing's tokens have all been through their ``on_token`` hooks:
+        tell ``on_landing``, if anyone listens (the gateway hands a landing's
+        tokens to its event loop at once). Called by every path that
+        delivers, under ``sched/deliver`` where it has one: behind a
+        landing's last token and BEFORE the pump admits, assembles and
+        dispatches the next sync. Like a token hook's, its exception must
+        not wedge the shared loop."""
+        if self.on_landing is not None:
+            try:
+                self.on_landing()
+            except Exception:
+                from ..utils.logging import logger
+                logger.warning("scheduler on_landing hook raised", exc_info=True)
+
+    def _deliver_block(self, live, toks_k, logits_k, K, chunk_next=False):
         """Deliver a fetched K-step token block to the rows it was launched
         for. Each row's KV advanced K positions on device (the program wrote
         rows [len, len+K)); tokens past EOS/budget were computed but are
         discarded, and so is the whole row of a request that ended while the
-        sync was in flight. Returns tokens delivered."""
+        sync was in flight. ``chunk_next``: the landing's final chunk delivers
+        behind these rows, and says so itself. Returns tokens delivered."""
         n_delivered = 0
         discarded = 0
         with self._span("sched/deliver"):
@@ -2305,6 +2326,8 @@ class DecodeScheduler(DeviceDraft):
                         req.logits.append(logits_k[k, slot])
                     self._deliver(req, int(toks_k[k, slot]))
                     n_delivered += 1
+            if not chunk_next:
+                self._landing_delivered()
         self._count_discarded(discarded)
         return n_delivered
 
@@ -2342,6 +2365,7 @@ class DecodeScheduler(DeviceDraft):
                     preq.logits.append(logits_k[k, ps])
                 self._deliver(preq, int(toks_k[k, ps]))
                 delivered += 1
+            self._landing_delivered()
         # disaggregated serving: a prefill-role replica hands the
         # freshly-prefilled request to a decode replica here — after
         # this sync's tokens streamed (they were computed anyway), with
@@ -2831,6 +2855,7 @@ class DecodeScheduler(DeviceDraft):
             accepted += max(0, row_delivered - 1)
             if tel.enabled:
                 tel.histogram("serving/spec_tokens_per_step", row_delivered)
+        self._landing_delivered()
         self.spec_steps += 1
         self.spec_row_steps += len(live)
         self.spec_drafted += total_draft

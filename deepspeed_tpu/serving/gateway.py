@@ -79,10 +79,22 @@ Threading model: the asyncio event loop owns sockets and parsing; one
 **pump thread per replica** owns ALL of that replica's scheduler
 interaction (submit/step/cancel — each scheduler stays single-threaded).
 Admission (fair-queue pop + placement) and terminal accounting serialize on
-the dispatch/finish locks. Tokens cross from a pump to a response's
-``asyncio.Queue`` via ``loop.call_soon_threadsafe`` from the scheduler's
-``on_token`` hook, so SSE events flush as each host sync lands (TTFB =
-queue wait + prefill + first sync, not request completion).
+the dispatch/finish locks. Tokens cross from a pump to the responses a
+LANDING at a time: the scheduler's ``on_token`` hook appends each token to
+the batch of the thread that runs it (:class:`_Landing`: a pump's own,
+nothing shared between pumps), and when the scheduler says the landing is
+delivered (``DecodeScheduler.on_landing``, still under ``sched/deliver``,
+before the pump assembles the next sync) the batch goes to the event loop
+in ONE ``loop.call_soon_threadsafe``, whose callback puts every row's item
+on its response's ``asyncio.Queue``. An SSE event is one such item: a row's
+tokens of one landing, 1 to ``steps_per_sync`` of them in order (one where
+``steps_per_sync`` is 1, at a request's last partial landing, or behind a
+final chunk whose first token came alone), one JSON document and one socket
+write. So a streaming client receives chunks of up to ``steps_per_sync``
+tokens as each host sync lands (TTFB = queue wait + prefill + first sync,
+not request completion), and any other event for a request (``done``,
+``cancelled``, ``failed``, ``handoff``) first hands on what the posting
+thread's batch holds: nothing overtakes a token.
 
 Telemetry (PR-1 sink): histograms ``gateway/queue_wait_ms``,
 ``gateway/ttfb_ms``; gauges ``gateway/queue_depth``,
@@ -127,14 +139,14 @@ class _GatewayRequest:
 
     __slots__ = ("rid", "prompt", "max_new_tokens", "eos_token_id", "do_sample",
                  "temperature", "top_k", "top_p", "seed", "tenant", "priority",
-                 "cost", "deadline", "stream", "loop", "events", "handle",
+                 "cost", "deadline", "stream", "events", "handle",
                  "cancel_requested", "cancel_reason", "finished", "enq_ts",
                  "admit_ts", "n_tokens", "trace", "trace_id", "replica",
                  "adapter_id", "return_logits", "resume", "unread")
 
     def __init__(self, rid, prompt, *, max_new_tokens, eos_token_id, do_sample,
                  temperature, top_k, top_p, seed, tenant, priority, deadline,
-                 stream, loop, trace=None, trace_id=None, adapter_id=None,
+                 stream, trace=None, trace_id=None, adapter_id=None,
                  return_logits=False, resume=None):
         self.rid = rid
         self.prompt = prompt
@@ -150,7 +162,6 @@ class _GatewayRequest:
         self.cost = len(prompt) + max_new_tokens  # DRR work estimate
         self.deadline = deadline
         self.stream = stream
-        self.loop = loop
         self.events = asyncio.Queue()
         self.handle = None
         self.cancel_requested = False
@@ -172,6 +183,20 @@ class _GatewayRequest:
         # ordinary arrivals — resume requests bypass the fair queue and go
         # straight to the fleet's migration admission)
         self.resume = resume
+
+
+class _Landing(threading.local):
+    """The tokens a scheduler delivered on THIS thread that the event loop
+    has not been handed yet. A thread's own (every pump thread, and any
+    other thread that steps a scheduler, finds a fresh one the first time
+    it looks), so no two pumps ever share a batch and none takes a lock.
+    ``rows``: request -> [its tokens of the landing in order, the finish
+    reason with the last]. ``gap``: the account of the pump that runs on this
+    thread (None with the sink off, and on a thread that is no pump)."""
+
+    def __init__(self):
+        self.rows = {}
+        self.gap = None
 
 
 class Gateway:
@@ -300,10 +325,13 @@ class Gateway:
         self._brownout_bar = None   # weight bar arrivals shed under (None=off)
         self._park_pending = set()  # greqs awaiting park-out on their owning pump
         self._gap_mark = None       # (busy_s, wait_s) of the fleet's pumps at the last snapshot
+        # what each pump's scheduler delivered since its last hand-over to
+        # the event loop (one batch a thread)
+        self._landing = _Landing()
         # the event loop's delivery (telemetry/capacity.py: Delivery), only
         # with the sink on: the loop thread adds what it wrote, the pumps'
-        # trackers count what their schedulers delivered (a landing's tokens
-        # at once: ``_post`` posts what it always posted), and the primary
+        # trackers count the events they posted (a landing's at once, where
+        # its batch is handed over: ``_flush_landing``), and the primary
         # pump's account reads both at every landing. None with the sink
         # off: the per-event path then tests this and does nothing else
         self._delivery = None
@@ -477,6 +505,10 @@ class Gateway:
             gap.bind_threads(thread_cpu_clock(threading.get_ident()), self._delivery,
                              thread_cpu_clock(loop.ident) if loop is not None else None,
                              primary=primary)
+            self._landing.gap = gap   # this thread's batches count as its posts
+        # the scheduler says when a landing's tokens are all through their
+        # hooks: its batch crosses to the event loop then, not a step later
+        sched.on_landing = self._flush_landing
         while not self._force_stop:
             with span("gateway/admit", record=False, observer=gap), self._dispatch_lock:
                 self._enforce_cancellations()
@@ -521,6 +553,9 @@ class Gateway:
                     # semantics — fail everything, stay up, retry on the
                     # next admitted request
                     self._fail_in_flight("scheduler step failed")
+            # what a path that told of no landing delivered, or a step that
+            # raised in the middle of one left behind
+            self._flush_landing()
             self._settle_done()
             if primary:
                 # every primary iteration, stepped or not: the program set
@@ -661,19 +696,55 @@ class Gateway:
                     tel.gauge("gateway/active_requests", len(self._active))
 
     def _make_on_token(self, greq):
+        landing = self._landing
+
         def on_token(tok, done):
             greq.n_tokens += 1
-            reason = None
+            rows = landing.rows  # the batch of the thread that runs the hook
+            row = rows.get(greq)
+            if row is None:
+                rows[greq] = row = [[], None]
+            row[0].append(int(tok))
             if done:
-                reason = ("stop" if (greq.eos_token_id is not None
+                row[1] = ("stop" if (greq.eos_token_id is not None
                                      and tok == greq.eos_token_id) else "length")
-                # account BEFORE posting the final token: the HTTP side
+                # account BEFORE the final token is handed on: the HTTP side
                 # responds the moment the event lands, and a client that
                 # reads the response then polls /v1/metrics must see its
                 # own completion counted (the reverse order raced)
                 self._finish(greq, None)
-            self._post(greq, ("token", int(tok), reason))
         return on_token
+
+    def _flush_landing(self):
+        """Hand what the calling thread's batch holds to the event loop in
+        ONE wake-up (a lock and a byte down the loop's self-pipe, whatever
+        the number of rows). Called by the scheduler when a landing is
+        delivered (``on_landing``), by ``_post`` before any other event and
+        by the pump behind every step; never raises. With the sink on the
+        events count as the pump's posts BEFORE they are posted, so the
+        loop's backlog is never read under 0."""
+        landing = self._landing
+        rows = landing.rows
+        if not rows:
+            return
+        landing.rows = {}
+        if landing.gap is not None:
+            landing.gap.posted += len(rows)
+        try:
+            self._loop.call_soon_threadsafe(self._hand_over, rows)
+        except RuntimeError:
+            pass  # event loop closed mid-drain
+
+    def _hand_over(self, rows):
+        """On the event loop: a landing's batch, each row's tokens onto its
+        response's queue as one ``("token", [ids], finish reason)`` event. A
+        handler that has gone (it says so with the sink on alone) will never
+        take one: counted unread here, by the thread that knows."""
+        for greq, (toks, reason) in rows.items():
+            if greq.unread:
+                self._delivery.unread += 1
+            else:
+                greq.events.put_nowait(("token", toks, reason))
 
     def _finish(self, greq, event):
         """Request reached a terminal state on the pump side: account it,
@@ -781,14 +852,14 @@ class Gateway:
             self._post(greq, ("failed", 503, msg))
 
     def _post(self, greq, event):
-        """Pump -> HTTP handler handoff; never raises (the response side may
-        already be gone — its queue then just collects unread events, which
-        the delivery's account counts so: a token more a disconnect, and a
-        lost race between two pumps is one event's drift)."""
-        if greq.unread and event[0] == "token":
-            self._delivery.unread += 1
+        """Pump -> HTTP handler handoff of every event but a token (``done``,
+        ``cancelled``, ``failed``, ``handoff``); never raises (the response
+        side may already be gone — its queue then just collects unread
+        events). The tokens this thread still holds go first: nothing
+        overtakes a token."""
+        self._flush_landing()
         try:
-            greq.loop.call_soon_threadsafe(greq.events.put_nowait, event)
+            self._loop.call_soon_threadsafe(greq.events.put_nowait, event)
         except RuntimeError:
             pass  # event loop closed mid-drain
 
@@ -1480,11 +1551,14 @@ class Gateway:
                 "host_gap_total_s": round(sched._gap.total_gap_s, 6),
                 "pump_busy_total_s": round(sched._gap.busy_s, 6),
                 "pump_wait_total_s": round(sched._gap.wait_s, 6),
-                # the event loop's delivery (token events of streaming and
-                # unary responses; the live view is gateway/backlog_events)
+                # the event loop's delivery, in events (a row's tokens of
+                # one landing, of streaming and unary responses; ``tokens``:
+                # those inside the events written; the live view is
+                # gateway/backlog_events)
                 "delivery": ({
                     "posted": self._delivery.posted(),
                     "written": self._delivery.events,
+                    "tokens": self._delivery.tokens,
                     "taken": self._delivery.taken,
                     "unread": self._delivery.unread,
                 } if self._delivery is not None else None),
@@ -1674,8 +1748,7 @@ class Gateway:
                                  tenant=kwargs["tenant"],
                                  priority=kwargs["priority"])
             trace.mark("queued")
-        greq = _GatewayRequest(self._next_rid(), loop=asyncio.get_running_loop(),
-                               trace=trace, trace_id=trace_id, **kwargs)
+        greq = _GatewayRequest(self._next_rid(), trace=trace, trace_id=trace_id, **kwargs)
         if trace is not None:
             trace.rid = greq.rid
             # per-request track: a client may reuse an x-request-id across
@@ -1774,8 +1847,9 @@ class Gateway:
     def _handler_gone(self, greq):
         """A response handler is leaving (sink on): token events still in
         its queue, and any posted from now on, will never be written. The
-        first are counted taken here, the second ``unread`` where they are
-        posted (``_post``), so the backlog stays what the loop has yet to do."""
+        first are counted taken here, the second ``unread`` where the loop
+        hands them on (``_hand_over``), so the backlog stays what the loop
+        has yet to do."""
         greq.unread = True
         left = 0
         while not greq.events.empty():
@@ -1813,8 +1887,9 @@ class Gateway:
                         tel.histogram("gateway/ttfb_ms",
                                       (time.monotonic() - greq.enq_ts) * 1e3)
                 if kind == "token":
-                    tok, reason = ev[1], ev[2]
-                    payload = json.dumps(self._chunk(greq, [tok], reason))
+                    # the row's tokens of one landing: ONE document, one write
+                    toks, reason = ev[1], ev[2]
+                    payload = json.dumps(self._chunk(greq, toks, reason))
                     data = f"data: {payload}\n\n".encode()
                     if sent is None:
                         writer.write(data)
@@ -1842,6 +1917,7 @@ class Gateway:
                             if lag > sent.lag_max_s:
                                 sent.lag_max_s = lag
                         sent.bytes += len(data)
+                        sent.tokens += len(toks)
                         sent.writes += 1
                         sent.events += 1
                         await writer.drain()
@@ -1891,8 +1967,8 @@ class Gateway:
                                      extra=list(ev[3]) if len(ev) > 3 else ())
                     return
                 if kind == "token":
-                    tok, reason = ev[1], ev[2]
-                    toks.append(tok)
+                    reason = ev[2]
+                    toks.extend(ev[1])
                     if self._delivery is not None:  # counted posted, here taken, never written
                         self._delivery.taken += 1
                     if reason is not None:

@@ -365,14 +365,26 @@ class Delivery:
     half done is whole in the next period, and none is ever lost, so posted =
     written + taken + unread + backlog holds at every reading.
 
+    *The unit is the event*: what the loop writes at once, which is ONE
+    row's tokens of ONE landing (1 to ``steps_per_sync`` of them: the gateway
+    posts a landing's rows to the loop in one wake-up and a handler writes a
+    row's as one SSE document). Everything here but ``tokens`` and ``bytes``
+    counts events, never tokens: a landing posts as many as the rows it
+    delivered to.
+
     ``events``, ``writes``, ``bytes``: SSE token events whose bytes were
-    handed to the transport, the ``write`` calls and the bytes they took.
+    handed to the transport, the ``write`` calls and the bytes they took;
+    ``tokens``: the tokens inside those events (events a token is what the
+    batching buys: 1 at ``steps_per_sync`` 1, about a K-th at K).
     ``lag_s`` / ``lag_max_s``: from the landing an event came from to that
     hand-over, summed, and the largest since the last reading. ``taken``:
     token events a response that does not stream took off its queue, or a
-    handler that went away left in it. ``unread`` (the pumps add to it):
-    token events posted to a handler that had gone. ``pumps``: the trackers
-    whose pumps post (``posted``: one a replica).
+    handler that went away left in it. ``unread``: token events that
+    reached the loop for a handler that had gone (the loop counts them
+    where it hands a landing's batch on, so this total too is the loop's
+    alone). ``pumps``: the trackers whose pumps post (``posted``: one a
+    replica, counted by the gateway where a landing's batch is handed over,
+    before the post).
 
     *Which landing an event came from* costs the pump nothing a token (on a
     host-paced server a microsecond a token on either thread is a twentieth
@@ -384,11 +396,11 @@ class Delivery:
     event off the head (:meth:`landing_of`). Exact where one pump posts and
     the handlers run in order; a landing out where they interleave."""
 
-    __slots__ = ("events", "writes", "bytes", "lag_s", "lag_max_s", "taken", "unread",
-                 "pumps", "landings")
+    __slots__ = ("events", "writes", "bytes", "tokens", "lag_s", "lag_max_s", "taken",
+                 "unread", "pumps", "landings")
 
     def __init__(self):
-        self.events = self.writes = self.bytes = self.taken = self.unread = 0
+        self.events = self.writes = self.bytes = self.tokens = self.taken = self.unread = 0
         self.lag_s = self.lag_max_s = 0.0
         self.pumps = []
         # bounded: a loop that writes nothing (unary traffic alone) never
@@ -471,12 +483,12 @@ class HostGapTracker:
     observes none of the three. Histograms hold one observation a LANDED sync, like
     ``serving/pump_busy_ms``; the counters beside them
     (``serving/pump/cpu_ms``, ``gateway/loop/cpu_ms``, ``gateway/sse_events``
-    / ``_writes`` / ``_bytes``) take every close. ``pump_busy_ms`` less
-    ``pump_cpu_ms`` is the time the pump held a span open without the
+    / ``_writes`` / ``_bytes`` / ``_tokens``) take every close. ``pump_busy_ms``
+    less ``pump_cpu_ms`` is the time the pump held a span open without the
     processor: the lock, or the machine. ``posted`` is the pump's side of
-    the delivery: the token events it posted, which are the tokens its
-    scheduler delivered (``DecodeScheduler._observe`` adds a landing's at
-    once; nothing is counted or stamped a token)."""
+    the delivery: the token events it posted, one a row a landing delivered
+    to (the gateway adds a landing's at once, where it hands the batch to
+    the loop; nothing is counted or stamped a token)."""
 
     __slots__ = ("sink", "_unlanded", "_compiles", "_compiled", "_open_ts", "gaps",
                  "total_gap_s", "_stack", "_acc", "_period_ts", "_was_idle", "_left_ts",
@@ -512,7 +524,7 @@ class HostGapTracker:
         # (ts, pump, loop processor seconds, events written) where each of the
         # last CPU_PERIODS landed periods began, and where the open one did
         self._cpu_marks = collections.deque(maxlen=CPU_PERIODS + 1)
-        self._sent_mark = (0, 0, 0, 0.0)  # the loop's totals at the last close
+        self._sent_mark = (0, 0, 0, 0.0, 0)  # the loop's totals at the last close
 
     def bind_threads(self, pump_cpu, delivery=None, loop_cpu=None, primary=False):
         """The gateway's pump, as it starts: ``pump_cpu`` reads its thread's
@@ -526,16 +538,13 @@ class HostGapTracker:
         self._pump_cpu, self._loop_cpu = pump_cpu, loop_cpu if primary else None
         self._cpu_marks.clear()
         self._bound = True
-        # what the scheduler delivered before a gateway pumped it (a caller's
-        # own requests, a benchmark's reference check) was posted to no loop
-        self.posted = 0
         if delivery is not None:
             delivery.pumps.append(self)
             self._posts = delivery
             if primary:
                 self._delivery = delivery
                 self._sent_mark = (delivery.events, delivery.writes, delivery.bytes,
-                                   delivery.lag_s)
+                                   delivery.lag_s, delivery.tokens)
 
     def unbind_threads(self):
         """The gateway's pump, as it exits: a thread's clock cannot be read
@@ -670,7 +679,7 @@ class HostGapTracker:
                         now[1] - base[1] + now[2] - base[2]) / (ts - base[0]))
         if d is None:
             return
-        sent = (events, d.writes, d.bytes, d.lag_s)
+        sent = (events, d.writes, d.bytes, d.lag_s, d.tokens)
         backlog = -(events + d.taken + d.unread) + d.posted()
         lag_max, d.lag_max_s = d.lag_max_s, 0.0
         before, self._sent_mark = self._sent_mark, sent
@@ -679,6 +688,7 @@ class HostGapTracker:
             sink.counter("gateway/sse_events", n)
             sink.counter("gateway/sse_writes", sent[1] - before[1])
             sink.counter("gateway/sse_bytes", sent[2] - before[2])
+            sink.counter("gateway/sse_tokens", sent[4] - before[4])
         if landed:
             sink.histogram("gateway/backlog_events", backlog)
             if n:

@@ -32,6 +32,10 @@ DATA_METRICS = {
                              "program_counter"),
 }
 TRACE_METRIC = "loop_send_trace_pct"
+# PR 53's one data file on the built-in ``counter_ratio``: events a hundred
+# tokens the loop wrote (100 where an event is a token, ~25 where it is a
+# row's four of a landing)
+ENGAGEMENT = "sse_events_per_100_tokens"
 ALL_METRICS = sorted(DATA_METRICS) + [TRACE_METRIC]
 CELL_9 = "lfm2-8b-a1b.serve.reason-closed"
 
@@ -73,11 +77,12 @@ def test_benchmark_entry_says_what_the_file_says(name):
     assert set(entry) == {"name", "unit", "better", "source", "layer", "moves", "workloads"}
     assert {k: entry[k] for k in entry if k != "name"} == {
         k: spec[k] for k in ("unit", "better", "source", "layer", "moves", "workloads")}
-    # appended behind everything the benchmark had, in this order
-    assert [m["name"] for m in bench["per_layer"]][-8:] == [
+    # appended behind everything the benchmark had, in this order (PR 53's
+    # one behind them)
+    assert [m["name"] for m in bench["per_layer"]][-9:] == [
         "delivery_lag_ms", "delivery_lag_max_ms", "delivery_backlog_events",
         "loop_cpu_us_per_event", "loop_cpu_ms", "pump_cpu_ms", "host_threads_cpu_pct",
-        "loop_send_trace_pct"]
+        "loop_send_trace_pct", ENGAGEMENT]
 
 
 @pytest.mark.parametrize("name", ALL_METRICS)
@@ -172,3 +177,47 @@ def test_loop_send_trace_pct_on_the_xplane_fixture(trace):  # noqa: F811
     wait = cells.custom_reducer({"name": "pump_wait_trace_pct",
                                  "dir": os.path.join(ROOT, "chipbench", "metrics")})
     assert wait({"program_trace": sent}) == wait({"program_trace": trace})
+
+
+# ------------------------------------------------ PR 53: events a hundred tokens
+def test_engagement_metric_is_data_alone_and_says_what_the_benchmark_says():
+    bench, spec = _bench(), _spec(ENGAGEMENT)
+    assert not os.path.exists(os.path.join(ROOT, "chipbench", "metrics", ENGAGEMENT + ".py"))
+    assert spec["reducer"] == "counter_ratio"
+    assert spec["args"] == {"num": "gateway/sse_events", "den": ["gateway/sse_tokens"]}
+    assert (spec["layer"], spec["unit"], spec["better"], spec["source"], spec["moves"]) == (
+        "gateway", "events", "lower", "program_counter", "serve_tokens_per_s")
+    assert spec["workloads"] == _serving(bench) and len(spec["workloads"]) == 7
+    entry = bench["per_layer"][-1]
+    assert entry["name"] == ENGAGEMENT
+    assert set(entry) == {"name", "unit", "better", "source", "layer", "moves", "workloads"}
+    assert {k: entry[k] for k in entry if k != "name"} == {
+        k: spec[k] for k in ("unit", "better", "source", "layer", "moves", "workloads")}
+
+
+def test_training_cells_load_nothing_of_the_delivery():
+    """The two training cells build no gateway: none of PR 52's eight metrics
+    nor PR 53's one is found for them, and every serving cell finds all nine."""
+    bench = _bench()
+    training = [c["name"] for c in bench["workloads"] if c["name"] not in _serving(bench)]
+    assert sorted(training) == ["gpt2-large.train.s1024", "opt-1.3b.train.zero3.s2048"]
+    ours = set(ALL_METRICS) | {ENGAGEMENT}
+    for cell in bench["workloads"]:
+        _, workload, root = cells.load_workload(cell["name"])
+        found = ours & set(cells.per_layer_metrics(cell["name"], workload, root))
+        assert found == (set() if cell["name"] in training else ours), cell["name"]
+
+
+@pytest.mark.parametrize("events,tokens,want", [
+    (96, 384, 25.0), (100, 384, pytest.approx(26.0416667)), (384, 384, 100.0)],
+    ids=["a-row-s-four", "with-partial-landings", "a-token-an-event"])
+def test_engagement_metric_reads_the_two_counters(events, tokens, want):
+    spec = _spec(ENGAGEMENT)
+    reduce = reducers.BUILTIN[spec["reducer"]]
+    obs = {"telemetry": {"histograms": {}, "counters": {
+        "gateway/sse_events": {"total": events}, "gateway/sse_tokens": {"total": tokens}}}}
+    assert reduce(spec["args"], obs) == want
+    # the parent counts events and no tokens: nothing to read, nothing raised
+    parent = {"telemetry": {"histograms": {}, "counters": {"gateway/sse_events": {"total": events}}}}
+    assert reduce(spec["args"], parent) is None
+    assert reduce(spec["args"], {}) is None

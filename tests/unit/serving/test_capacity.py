@@ -504,6 +504,8 @@ def test_thread_cpu_clock_reads_another_thread_s_clock():
 
 
 def test_delivery_posted_is_written_plus_taken_plus_backlog_at_every_landing():
+    """In EVENTS: an event here carries four tokens (a row's of one landing),
+    and only ``tokens`` (with the counter it feeds) and the bytes know."""
     sink, gap, pump, loop, sent = _bound_tracker()
     other = HostGapTracker(FakeSink())          # a second replica's pump posts too
     other.bind_threads(_Clock(), sent)
@@ -518,6 +520,7 @@ def test_delivery_posted_is_written_plus_taken_plus_backlog_at_every_landing():
             sent.lag_s += lag
             sent.lag_max_s = max(sent.lag_max_s, lag)
             sent.bytes += 100
+            sent.tokens += 4
             sent.writes += 1
             sent.events += 1
         sent.taken += took
@@ -536,7 +539,33 @@ def test_delivery_posted_is_written_plus_taken_plus_backlog_at_every_landing():
     assert sink.counters["gateway/sse_events"] == (2, 14)
     assert sink.counters["gateway/sse_writes"] == (2, 14)
     assert sink.counters["gateway/sse_bytes"] == (2, 1400)
+    assert sink.counters["gateway/sse_tokens"] == (2, 56)    # 25 events a hundred tokens
     assert len(sink.hists["gateway/backlog_events"]) == len(sink.hists["serving/pump_busy_ms"])
+
+
+@pytest.mark.parametrize("per_event", [1, 4], ids=["a-token-an-event", "four-tokens-an-event"])
+def test_sse_tokens_is_the_one_total_that_counts_tokens(per_event):
+    """Three landings of 6 rows, every event written in the period behind its
+    landing: posted, written, the backlog and the cost an event read the same
+    whatever an event carries; ``gateway/sse_tokens`` over ``sse_events`` is
+    what the batching buys (100 events a hundred tokens, or 25)."""
+    sink, gap, pump, loop, sent = _bound_tracker()
+    t = _sync(gap, 0.0)
+    for _ in range(3):
+        gap.posted += 6
+        for _ in range(6):
+            sent.bytes += 60 + 4 * per_event
+            sent.tokens += per_event
+            sent.writes += 1
+            sent.events += 1
+        loop.s += 6 * 0.0002
+        t = _sync(gap, t)
+    assert sent.posted() == sent.events == 18 and sent.tokens == 18 * per_event
+    assert sink.hists["gateway/backlog_events"] == [0, 0, 0, 0]
+    assert sink.hists["gateway/loop_cpu_us_per_event"] == [pytest.approx(200.0)] * 3
+    events, tokens = sink.counters["gateway/sse_events"][1], sink.counters["gateway/sse_tokens"][1]
+    assert (events, tokens) == (18, 18 * per_event)
+    assert 100.0 * events / tokens == pytest.approx(100.0 / per_event)
 
 
 def test_delivery_reads_an_event_s_landing_off_the_ring_not_off_the_event():
@@ -545,14 +574,13 @@ def test_delivery_reads_an_event_s_landing_off_the_ring_not_off_the_event():
     every landing leaves (posted before it, its time) and nothing rides an
     event. Events posted to a handler that has gone count ``unread`` and
     stay out of the backlog."""
+    # nothing but the gateway's hand-over of a batch counts a post: a tracker
+    # whose scheduler delivered to direct callers before any pump has posted 0
+    assert HostGapTracker(FakeSink()).posted == 0
     sink, gap, _, _, sent = _bound_tracker()
-    gap.posted = 68                 # delivered to direct callers before a gateway pumped: not posted
-    gap.bind_threads(None, None)
-    assert gap.posted == 0
-    sink, gap, _, _, sent = _bound_tracker()
-    t1 = _sync(gap, 0.0)            # landing 1 at 10 ms delivers 5 tokens
-    gap.posted += 5                 # (DecodeScheduler._observe, behind its deliver)
-    t2 = _sync(gap, t1)             # landing 2 at 20 ms delivers 3
+    t1 = _sync(gap, 0.0)            # landing 1 at 10 ms delivers to 5 rows
+    gap.posted += 5                 # (Gateway._flush_landing: an event a row, before the post)
+    t2 = _sync(gap, t1)             # landing 2 at 20 ms delivers to 3
     gap.posted += 3
     assert list(sent.landings) == [(0, pytest.approx(0.010)), (5, pytest.approx(0.020))]
     assert [sent.landing_of(n) for n in (1, 5)] == [pytest.approx(0.010)] * 2
@@ -877,8 +905,10 @@ def test_gateway_profile_endpoint_and_capacity_metrics(params, tmp_path):
         assert cap["host_gap_total_s"] >= 0.0
         assert cap["host_gaps"] >= 0
         assert cap["pump_busy_total_s"] > 0.0 and cap["pump_wait_total_s"] >= 0.0
-        # a unary response takes its token events off the queue and writes none
-        assert cap["delivery"] == {"posted": 6, "written": 0, "taken": 6, "unread": 0}
+        # a unary response takes its token events off the queue and writes
+        # none: 6 tokens at steps_per_sync 4 are two landings' events
+        assert cap["delivery"] == {"posted": 2, "written": 0, "tokens": 0, "taken": 2,
+                                   "unread": 0}
         text = get("/v1/metrics", {"Accept": "text/plain"}).decode()
         assert "dstpu_serving_mfu " in text
         assert 'dstpu_serving_host_gap_ms_hist_bucket{le="' in text

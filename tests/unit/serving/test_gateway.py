@@ -553,12 +553,15 @@ def test_fair_queue_adapter_flows_share_tenant_weight():
 # ------------------------------------------------- the event loop's delivery
 @pytest.mark.parametrize("sink_on", [True, False], ids=["sink-on", "sink-off"])
 def test_delivery_account_of_four_streams(tmp_path, baseline, monkeypatch, sink_on):
-    """Four streaming clients. Sink on: every delivered token is counted
-    posted (a landing's at once: the pump still posts its 3-tuples), every
-    posted event is written, one send in 64 under a ``gateway/send``
-    annotation, the bytes are the clients', no lag is negative, and every
-    landed sync observes each of the account's new histograms once. Sink off:
-    the pump posts the 3-tuples it always did and the loop makes no span."""
+    """Four streaming clients at ``steps_per_sync`` 4. An event is a row's
+    tokens of one landing: every token is read, in events of at most four
+    tokens and about a quarter as many events as tokens. Sink on: every
+    event is counted posted (a landing's at once, where its batch is handed
+    over) and every posted event is written, one send in 64 under a
+    ``gateway/send`` annotation, the bytes and the tokens written are the
+    clients', no lag is negative, and every landed sync observes each of the
+    account's histograms once. Sink off: the same 3-tuples cross and the loop
+    makes no span, no account exists."""
     import asyncio
     params, _ = baseline
     cfg = {"telemetry": {"enabled": True, "output_path": str(tmp_path)}} if sink_on else {}
@@ -597,15 +600,20 @@ def test_delivery_account_of_four_streams(tmp_path, baseline, monkeypatch, sink_
     finally:
         assert gw.close(timeout=60)
     n = 4 * 24
-    assert all(sse_tokens(raw)[2] for raw in raws) and len(posted) == n
+    assert all(sse_tokens(raw)[2] for raw in raws)
     assert sum(len(sse_tokens(raw)[0]) for raw in raws) == n
-    assert all(len(ev) == 3 for ev in posted)    # nothing rides a token event, sink on or off
+    assert sum(len(ev[1]) for ev in posted) == n     # every token crossed, inside an event
+    assert all(len(ev) == 3 and 1 <= len(ev[1]) <= 4 for ev in posted)  # nothing rides an event
+    events = len(posted)
+    assert n // 4 <= events <= n // 4 + 8    # a landing's four a row; a final chunk may split one
+    assert sum(raw.count(b'"token_ids"') for raw in raws) == events     # one document an event
     if not sink_on:
         assert "gateway/send" not in spans and gw._delivery is None
         return
     d = gw._delivery
-    assert spans.count("gateway/send") == n // 64   # one send in 64 is annotated
-    assert d.posted() == d.events == d.writes == n and d.taken == d.unread == 0
+    assert spans.count("gateway/send") == events // 64   # one send in 64 is annotated
+    assert d.posted() == d.events == d.writes == events and d.taken == d.unread == 0
+    assert d.tokens == n
     assert d.bytes == sum(len(raw) for raw in raws) - 4 * len(b"data: [DONE]\n\n")
     assert d.lag_s >= 0.0
     hists = eng.telemetry.snapshot()["histograms"]
@@ -618,11 +626,271 @@ def test_delivery_account_of_four_streams(tmp_path, baseline, monkeypatch, sink_
         assert 0 < hists[name]["count"] <= landed, name
         assert hists[name]["min"] >= 0.0
     assert hists["gateway/backlog_events"]["min"] >= 0
-    assert hists["gateway/backlog_events"]["max"] <= n
+    assert hists["gateway/backlog_events"]["max"] <= events
     # the pump's CPU is within the wall time of its account's periods (the
     # step programs were built in them: ``compile`` is a part of its own)
     total = eng.telemetry.counter_total
     wall = sum(total(f"serving/pump/{part}_ms") for part in ("busy", "wait", "idle", "compile"))
     assert 0.0 < total("serving/pump/cpu_ms") <= 1.02 * wall + 5.0
     assert 0.0 < total("gateway/loop/cpu_ms") <= 1.02 * wall + 5.0
-    assert 0 < eng.telemetry.counter_total("gateway/sse_events") <= n
+    # the counters take every close: what they hold so far is the loop's
+    assert 0 < total("gateway/sse_events") <= events
+    assert total("gateway/sse_events") <= total("gateway/sse_tokens") <= n
+
+
+# ------------------------------------------- a landing crosses in one wake-up
+def stream(port, body, timeout=120):
+    """One streamed completion; returns the events' (token_ids, finish_reason)
+    in order and whether [DONE] closed the stream."""
+    conn = http.client.HTTPConnection("127.0.0.1", port, timeout=timeout)
+    try:
+        conn.request("POST", "/v1/completions", json.dumps(dict(body, stream=True)), {})
+        resp = conn.getresponse()
+        assert resp.status == 200
+        raw = resp.read().decode()
+    finally:
+        conn.close()
+    chunks = [json.loads(line[6:])["choices"][0] for line in raw.splitlines()
+              if line.startswith("data: {")]
+    return ([(c["token_ids"], c["finish_reason"]) for c in chunks],
+            "".join(c["text"] for c in chunks), raw.rstrip().endswith("data: [DONE]"))
+
+
+SAMPLED = {"prompt": PROMPT, "max_tokens": 12, "temperature": 1.0, "seed": 7}
+
+
+@pytest.mark.parametrize("ksteps", [4, 1])
+def test_stream_is_the_unary_answer_in_events_of_a_landing(baseline, ksteps):
+    """A streamed request gives the unary answer's and the direct submit's
+    tokens, in order, as events of at most ``steps_per_sync`` tokens with the
+    finish reason on the last: about a quarter as many events as tokens at
+    4, one a token at 1; and the concatenated text is the tokens'."""
+    params, _ = baseline
+    eng = make_engine(params=params, continuous_batching={
+        "enabled": True, "num_slots": 2, "steps_per_sync": ksteps})
+    ref = [int(t) for t in eng.scheduler().submit(
+        PROMPT, max_new_tokens=12, do_sample=True, temperature=1.0, seed=7).result()]
+    assert len(set(ref)) > 8     # a sampled row: the order of distinct tokens is checked
+    gw = Gateway(eng, port=0)
+    gw.start_background()
+    try:
+        events, text, done = stream(gw.port, SAMPLED)
+        status, _, body = post(gw.port, SAMPLED)
+    finally:
+        assert gw.close(timeout=60)
+    toks = [t for ids, _ in events for t in ids]
+    assert status == 200 and json.loads(body)["choices"][0]["token_ids"] == ref
+    assert toks == ref and done
+    assert text == "".join(f"{t} " for t in ref)
+    assert [r for _, r in events] == [None] * (len(events) - 1) + ["length"]
+    assert all(1 <= len(ids) <= ksteps for ids, _ in events)
+    assert len(events) == (12 if ksteps == 1 else 3)
+
+
+@pytest.mark.parametrize("eos_at", [2, 5], ids=["first-landing", "second-landing"])
+def test_stream_ends_on_an_eos_in_the_middle_of_a_landing(baseline, eos_at):
+    """The row stops at its EOS though the landing computed tokens past it:
+    the stream's last event ends with that token and says ``stop``."""
+    params, _ = baseline
+    eng = make_engine(params=params)
+    ref = [int(t) for t in eng.scheduler().submit(
+        PROMPT, max_new_tokens=12, do_sample=True, temperature=1.0, seed=7).result()]
+    gw = Gateway(eng, port=0)
+    gw.start_background()
+    try:
+        events, _, done = stream(gw.port, dict(SAMPLED, eos_token_id=ref[eos_at]))
+    finally:
+        assert gw.close(timeout=60)
+    assert done and [t for ids, _ in events for t in ids] == ref[:eos_at + 1]
+    assert events[-1] == (ref[4 * (eos_at // 4):eos_at + 1], "stop")
+
+
+class _CountingLoop:
+    """A stand-in for the gateway's event loop: counts the wake-ups and keeps
+    the callbacks, which ``run`` calls in the order they were posted."""
+
+    def __init__(self, closed=False):
+        self.calls, self.closed = [], closed
+
+    def call_soon_threadsafe(self, fn, *args):
+        if self.closed:
+            raise RuntimeError("Event loop is closed")
+        self.calls.append((fn, args))
+
+    def run(self):
+        calls, self.calls = self.calls, []
+        for fn, args in calls:
+            fn(*args)
+
+
+def _request(gw, rid, **kw):
+    from deepspeed_tpu.serving.gateway import _GatewayRequest
+    fields = dict(max_new_tokens=8, eos_token_id=None, do_sample=False, temperature=1.0,
+                  top_k=0, top_p=1.0, seed=0, tenant="t", priority="standard",
+                  deadline=None, stream=True)
+    fields.update(kw)
+    return _GatewayRequest(rid, list(PROMPT), **fields)
+
+
+def _drain(greq):
+    out = []
+    while not greq.events.empty():
+        out.append(greq.events.get_nowait())
+    return out
+
+
+@pytest.fixture()
+def idle_gateway(baseline):
+    """A gateway that was never started (no loop, no pump): its hooks, its
+    batch and its posts driven by hand against a counting loop."""
+    params, _ = baseline
+    gw = Gateway(make_engine(params=params, num_slots=4), port=0)
+    gw._loop = _CountingLoop()
+    return gw
+
+
+def test_a_landing_of_n_rows_is_one_wake_up(idle_gateway):
+    """The scheduler's landings, stepped by hand with the gateway's hooks on
+    three requests: every step that delivers makes ONE
+    ``call_soon_threadsafe`` whatever the rows (the fused landing of a final
+    chunk and the decode rows too), and its callback puts one event a row on
+    that row's queue, the row's tokens in order."""
+    gw, loop = idle_gateway, idle_gateway._loop
+    sched = gw.scheduler
+    sched.on_landing = gw._flush_landing
+    greqs = [_request(gw, i) for i in range(3)]
+    handles = [sched.submit([5 + i, 6, 7], max_new_tokens=8,
+                            on_token=gw._make_on_token(g)) for i, g in enumerate(greqs)]
+    got = {g: [] for g in greqs}
+    for _ in range(64):
+        if all(h.done for h in handles) and not sched.in_flight:
+            break
+        delivered = sched.step()
+        assert len(loop.calls) == (1 if delivered else 0)
+        loop.run()
+        rows = 0
+        for g in greqs:
+            events = _drain(g)
+            assert len(events) <= 1          # one event a row a landing
+            for kind, toks, reason in events:
+                assert kind == "token" and 1 <= len(toks) <= 4
+                got[g].extend(toks)
+                rows += 1
+                assert (reason == "length") == (len(got[g]) == 8)
+        assert bool(rows) == bool(delivered)
+    assert [got[g] for g in greqs] == [list(h.result()) for h in handles]
+    assert not gw._landing.rows and [g.n_tokens for g in greqs] == [8, 8, 8]
+
+
+def test_two_pumps_never_share_a_batch_and_a_closed_loop_never_raises(idle_gateway):
+    gw, loop = idle_gateway, idle_gateway._loop
+    a, b = _request(gw, 1), _request(gw, 2)
+    seen = {}
+
+    def pump(name, greq, toks):
+        hook = gw._make_on_token(greq)
+        for t in toks:
+            hook(t, False)
+        seen[name] = dict(gw._landing.rows)     # this thread's batch, before its flush
+        if name == "a":
+            gw._flush_landing()
+
+    for name, greq, toks in (("a", a, [1, 2, 3]), ("b", b, [7, 8])):
+        t = threading.Thread(target=pump, args=(name, greq, toks))
+        t.start()
+        t.join()
+    assert seen == {"a": {a: [[1, 2, 3], None]}, "b": {b: [[7, 8], None]}}
+    assert not gw._landing.rows                  # and this thread's holds neither
+    assert len(loop.calls) == 1                  # pump a's flush took pump a's rows alone
+    loop.run()
+    assert _drain(a) == [("token", [1, 2, 3], None)] and _drain(b) == []
+    # the loop closed in the middle of a drain: the pump's side never raises,
+    # and the batch does not pile up behind it
+    gw._loop = _CountingLoop(closed=True)
+    gw._make_on_token(a)(4, True)
+    gw._flush_landing()
+    gw._post(a, ("cancelled", "disconnect"))
+    assert not gw._landing.rows and a.finished
+
+
+@pytest.mark.parametrize("event", [
+    ("cancelled", "disconnect"), ("cancelled", "deadline"), ("failed", 500, "replica step failed"),
+    ("handoff", {"key": "k", "kv_len": 3})], ids=["cancel", "deadline", "failure", "hand-off"])
+def test_no_event_overtakes_a_token(idle_gateway, event):
+    """Whatever ends a request on the pump's side arrives behind every token
+    delivered before it: the post of any other event first hands on what the
+    batch holds, another row's tokens with it."""
+    gw, loop = idle_gateway, idle_gateway._loop
+    ending, other = _request(gw, 1), _request(gw, 2)
+    gw._active.update((ending, other))
+    for t in (11, 12):
+        gw._make_on_token(ending)(t, False)
+    gw._make_on_token(other)(21, False)
+    gw._finish(ending, event)
+    assert len(loop.calls) == 2                  # the batch, then the event
+    loop.run()
+    assert _drain(ending) == [("token", [11, 12], None), event]
+    assert _drain(other) == [("token", [21], None)]
+    assert ending.finished and ending not in gw._active
+
+
+def test_delivery_identity_in_events_with_streams_unary_and_a_disconnect(tmp_path, baseline):
+    """Sink on, two streams, two unary requests and a stream whose client
+    leaves after its first event, all at once: posted = written + taken +
+    unread once nothing is owed, counted in EVENTS (far fewer than the
+    tokens); the tokens written are at least those the staying clients read,
+    and once later landings have closed the account's periods the counter
+    ``gateway/sse_tokens`` holds exactly the loop's total."""
+    params, _ = baseline
+    eng = make_engine(params=params, num_slots=4,
+                      telemetry={"enabled": True, "output_path": str(tmp_path)})
+    gw = Gateway(eng, port=0)
+    gw.start_background()
+    out = {}
+
+    def streamer(i):
+        out[i] = stream(gw.port, {"prompt": [5 + i, 6, 7], "max_tokens": 40})
+
+    def unary(i):
+        out[i] = post(gw.port, {"prompt": [5 + i, 6, 7], "max_tokens": 40})
+
+    def leaver(i):
+        conn = http.client.HTTPConnection("127.0.0.1", gw.port, timeout=60)
+        conn.request("POST", "/v1/completions", json.dumps(
+            {"prompt": [5 + i, 6, 7], "max_tokens": 100, "stream": True}), {})
+        resp = conn.getresponse()
+        resp.readline()     # the first event ...
+        resp.close()        # ... then gone
+        conn.close()
+
+    try:
+        threads = [threading.Thread(target=fn, args=(i, )) for i, fn in enumerate(
+            (streamer, streamer, unary, unary, leaver))]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(120)
+        deadline = time.time() + 30
+        while time.time() < deadline and (gw._active or not gw.stats["disconnects"]):
+            time.sleep(0.02)
+        assert not gw._active and gw.stats["disconnects"] == 1
+        d = gw._delivery
+        read = sum(len(ids) for i in (0, 1) for ids, _ in out[i][0])
+        assert read == 80 and all(out[i][2] for i in (0, 1))
+        assert all(len(json.loads(out[i][2])["choices"][0]["token_ids"]) == 40 for i in (2, 3))
+        deadline = time.time() + 10
+        while time.time() < deadline and d.posted() != d.events + d.taken + d.unread:
+            time.sleep(0.02)         # the leaver's last events: the loop is still counting them
+        assert d.posted() == d.events + d.taken + d.unread
+        assert d.taken >= 20 and d.events >= 20          # the unary pair's, the streams'
+        assert d.posted() <= (gw.stats["tokens"] + 3) // 2   # events, not tokens
+        assert read <= d.tokens <= gw.stats["tokens"] - 80
+        # two more landings close the periods the events were written in
+        post(gw.port, {"prompt": PROMPT, "max_tokens": 8})
+        assert eng.telemetry.counter_total("gateway/sse_tokens") == d.tokens
+        assert eng.telemetry.counter_total("gateway/sse_events") == d.events
+        _, _, raw = get(gw.port, "/v1/metrics")
+        served = json.loads(raw)["capacity"]["delivery"]
+        assert served["tokens"] == d.tokens and served["written"] == d.events
+    finally:
+        assert gw.close(timeout=60)

@@ -77,10 +77,16 @@ def main():
             fail(f"stream status {resp.status}")
         raw = resp.read().decode()
         conn.close()
-        n_chunks = raw.count('"token_ids": [')
-        if n_chunks != 8 or "data: [DONE]" not in raw:
-            fail(f"stream returned {n_chunks} chunks, DONE={'[DONE]' in raw}")
-        print("ok: streamed 8 SSE token chunks + [DONE]", flush=True)
+        # an event carries a row's tokens of one landing (1 to steps_per_sync
+        # of them): count the tokens inside the events, not the events
+        events = [json.loads(line[6:])["choices"][0]["token_ids"]
+                  for line in raw.splitlines()
+                  if line.startswith("data: {")]
+        n_tokens = sum(len(toks) for toks in events)
+        if n_tokens != 8 or "data: [DONE]" not in raw:
+            fail(f"stream returned {n_tokens} tokens in {len(events)} events, "
+                 f"DONE={'[DONE]' in raw}")
+        print(f"ok: streamed 8 tokens in {len(events)} SSE events + [DONE]", flush=True)
 
         # -- shed under a full queue --------------------------------------
         # Deterministic, not a thread race: park a long request in the single
