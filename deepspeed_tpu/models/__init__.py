@@ -13,9 +13,14 @@ from .transformer import TransformerConfig, CausalLM, CausalLMModel, sambay_laye
 _PRESETS = {}
 
 
-def register(name):
+def register(name, overrides_first=False):
+    """``overrides_first``: the preset's function takes ``get_model``'s
+    overrides itself and builds ONE configuration from the published sizes
+    with them applied, for a model whose published configuration the program
+    refuses whole (parts of it are not served) and serves cut."""
 
     def deco(fn):
+        fn.overrides_first = overrides_first
         _PRESETS[name] = fn
         return fn
 
@@ -29,7 +34,10 @@ def available_models():
 def get_model(name, **overrides):
     if name not in _PRESETS:
         raise ValueError(f"Unknown model {name}; available: {available_models()}")
-    cfg = _PRESETS[name]()
+    preset = _PRESETS[name]
+    if preset.overrides_first:
+        return CausalLMModel(preset(**overrides))
+    cfg = preset()
     if overrides:
         import dataclasses
         cfg = dataclasses.replace(cfg, **overrides)
@@ -378,3 +386,82 @@ def tiny_lfm2_moe():
     convolutions' windows, as at the published sizes), 8 experts top-2, 3
     taps."""
     return _lfm2_moe(256, _LFM2_8B_LAYERS[:6], 4, 2, 64, 256, 2, 8, 2, 64, 3, 256, 256)
+
+
+def bailing_hybrid_layers(layers, group_size):
+    """``layer_types`` by ``bailing_hybrid``'s rule: layer ``i`` is latent
+    attention (``full_attention``) where ``(i + 1) % layer_group_size == 0``
+    and Kimi delta attention (``linear_attention``) elsewhere. The rule is of
+    the PUBLISHED depth: a cut states its kinds outright."""
+    return tuple("full_attention" if (i + 1) % group_size == 0 else "linear_attention"
+                 for i in range(layers))
+
+
+def _bailing_hybrid(hidden, layers, heads, lin_dk, kv_rank, nope, rope, v_dim, dense_ffn,
+                    first_dense, experts, groups, groups_kept, top_k, expert_ffn, routed_scale,
+                    vocab, seq, theta, group_size=6, mtp=1, limits=(), shared_limits=(),
+                    **overrides):
+    """A ``bailing_hybrid`` stack (inclusionAI Ling-3.0): pre-norm blocks ``h
+    = x + Mixer(RMSNorm(x))``, ``y = h + FFN(RMSNorm(h))``; of every
+    ``group_size`` layers the last is latent attention with ONE query
+    projection (no low-rank step) and a head-wise sigmoid output gate, the
+    others Kimi delta attention (the gated delta rule with a decay a key
+    channel from a full-rank projection, bounded below by -5, sigmoid output
+    gate, as many key as value heads of ``lin_dk``); the first
+    ``first_dense`` layers a dense SwiGLU, above them gated experts under a
+    sigmoid router with a selection bias, chosen inside ``groups_kept`` of
+    ``groups`` groups, and one shared expert of the experts' width; untied
+    head; a multi-token-prediction module behind the stack and a clamp on
+    the top layers' gated activations, both published and NOT served
+    (``TransformerConfig`` refuses them by name: cut them off with
+    ``overrides``, which are applied before anything is checked). Unrolled:
+    the layers differ. Served only."""
+    kw = dict(
+        vocab_size=vocab, hidden_size=hidden, num_layers=layers, num_heads=heads,
+        head_dim=nope + rope, intermediate_size=dense_ffn, max_seq_len=seq,
+        pos_embedding="rope", rope_theta=theta, rope_interleave=True, norm="rmsnorm",
+        activation="swiglu", tie_embeddings=False, layernorm_epsilon=1e-6, attn_bias=False,
+        mlp_bias=False, kv_lora_rank=kv_rank, q_lora_rank=0, qk_nope_head_dim=nope,
+        qk_rope_head_dim=rope, v_head_dim=v_dim, attn_head_gate=True,
+        layer_types=bailing_hybrid_layers(layers, group_size), linear_num_heads=heads,
+        linear_key_head_dim=lin_dk, linear_value_head_dim=lin_dk, linear_conv_kernel=4,
+        linear_channel_decay=True, linear_decay_lower_bound=-5.0, linear_out_gate="sigmoid",
+        num_experts=experts, moe_top_k=top_k, moe_ffn_size=expert_ffn, moe_shared_experts=1,
+        moe_shared_ffn_size=expert_ffn, moe_routed_scale=routed_scale, moe_scoring="sigmoid",
+        moe_n_group=groups, moe_topk_group=groups_kept, moe_dropless=True,
+        moe_first_dense=first_dense, mtp_layers=mtp, moe_swiglu_limits=tuple(limits),
+        moe_shared_swiglu_limits=tuple(shared_limits), scan_layers=False)
+    return TransformerConfig(**{**kw, **overrides})
+
+
+@register("ling-3.0-flash", overrides_first=True)
+def ling_3_flash(**overrides):
+    """Ling-3.0-flash at its published sizes (huggingface.co/inclusionAI/
+    Ling-3.0-flash config.json, ``model_type: bailing_hybrid``): 42 layers,
+    five Kimi-delta layers (32 heads, keys and values of 128, convolution of
+    4) to each latent-attention layer (32 heads, latent 512 + 64 rotated,
+    nope 128, value 128), layers 0 and 1 a dense SwiGLU of 6,144, above them
+    512 experts of 768 top-8 inside 4 of 8 groups with a shared one, scale
+    2.5, vocabulary 157,184 untied, ~125 B parameters. WHOLE it is refused:
+    layers 34-41 clamp their gated activations
+    (``share_expert_swiglu_limit_list`` from 34, ``expert_swiglu_limit_list``
+    from 35) at a limit published by value and not by form, and the
+    multi-token-prediction module cannot draft over a pool of latent rows
+    and recurrent state. A cut below layer 34 with ``mtp_layers=0``
+    (``chipbench/configs/ling-3.0-flash.json``) is served."""
+    return _bailing_hybrid(2560, 42, 32, 128, 512, 128, 64, 128, 6144, 2, 512, 8, 4, 8, 768,
+                           2.5, 157184, 262144, 6e6, limits=(0, ) * 35 + (4, ) * 7,
+                           shared_limits=(0, ) * 34 + (5, ) * 6 + (7, ) * 2, **overrides)
+
+
+@register("tiny-ling", overrides_first=True)
+def tiny_ling(**overrides):
+    """Test-scale ``bailing_hybrid``: 7 layers (KDA, KDA, latent, KDA, KDA,
+    KDA, latent: both kinds, a period of 3 so that a latent layer lies among
+    the expert layers twice), one leading dense layer, 16 experts in 4
+    groups, 2 groups kept, top-4, 4 heads of 16, nothing refused."""
+    kw = dict(layer_types=("linear_attention", "linear_attention", "full_attention",
+                           "linear_attention", "linear_attention", "linear_attention",
+                           "full_attention"))
+    return _bailing_hybrid(64, 7, 4, 16, 16, 8, 8, 16, 128, 1, 16, 4, 2, 4, 32, 2.5, 256, 256,
+                           1e4, mtp=0, **{**kw, **overrides})

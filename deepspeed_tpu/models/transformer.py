@@ -134,6 +134,18 @@ class TransformerConfig:
     # what a sigmoid router adds to the chosen scores' sum before it divides by
     # it (DeepSeek-V3's code 1e-20; ``lfm2_moe`` publishes 1e-6)
     moe_renorm_eps: float = 1e-20
+    # a sigmoid router's group limit (n_group / topk_group): the experts lie
+    # in moe_n_group groups of equal size, a group's score is the sum of its
+    # two largest s + bias, and a token chooses its k among the experts of
+    # its moe_topk_group best groups. 1 group = no limit
+    moe_n_group: int = 1
+    moe_topk_group: int = 1
+    # per layer, the limit of the routed and of the shared experts' clamped
+    # gated activation (expert_swiglu_limit_list, share_expert_swiglu_limit_list;
+    # 0 = none). Published by VALUE and not by form: a layer with a non-zero
+    # limit is refused, not guessed. () = none anywhere
+    moe_swiglu_limits: Tuple[float, ...] = ()
+    moe_shared_swiglu_limits: Tuple[float, ...] = ()
     # how the router scores: "softmax" (probabilities, top-k, renormalised),
     # or "sigmoid" (DeepSeek-V3's rule: s = sigmoid(logits); the k experts
     # are the top of s + a stored selection bias, their weights s itself,
@@ -145,13 +157,18 @@ class TransformerConfig:
     # latent attention (MLA, kv_lora_rank > 0): low-rank q and kv projections
     # with their RMSNorms, per-head [nope ; rope] query/key parts, ONE rotated
     # key part for all heads, and a cache of kv_lora_rank + qk_rope_head_dim
-    # values a position (see LatentAttention)
+    # values a position (see LatentAttention). q_lora_rank 0 = the query is ONE
+    # projection, no low-rank step (``bailing_hybrid``). Under layer_types the
+    # full_attention layers are the latent ones
     kv_lora_rank: int = 0
     q_lora_rank: int = 0
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
     rope_interleave: bool = False  # rotate dims (2j, 2j+1) together, not (j, j + d/2)
+    # a latent layer's output gated a HEAD before W_o: o_i * sigmoid(a W_gate)_i, W_gate
+    # hidden -> heads (gated_attention_proj_granularity_type: head_wise)
+    attn_head_gate: bool = False
     # YaRN frequencies (rope_factor > 1) and the position-dependent query scale
     rope_factor: float = 1.0
     rope_beta_fast: float = 32.0
@@ -197,6 +214,16 @@ class TransformerConfig:
     linear_value_head_dim: int = 0
     linear_conv_kernel: int = 4  # causal depthwise convolution over q, k, v
     linear_neg_eigval: bool = False  # beta in (0, 2): negative eigenvalues of the transition
+    # the decay of a linear layer's state: one value a HEAD, g = -exp(A_log)
+    # softplus(x W_a + dt_bias) (Gated DeltaNet), or with linear_channel_decay
+    # one a KEY CHANNEL from a full-rank projection (Kimi delta attention,
+    # arXiv:2510.26692: W_a hidden -> heads x dk, dt_bias one a channel, A_log
+    # one a head). linear_decay_lower_bound < 0 bounds the log decay:
+    # g = bound * sigmoid(exp(A_log) (x W_a + dt_bias)), in (bound, 0)
+    # (kda_safe_gate, kda_lower_bound); 0 = the softplus form
+    linear_channel_decay: bool = False
+    linear_decay_lower_bound: float = 0.0
+    linear_out_gate: str = "silu"  # the output gate's activation: "silu" | "sigmoid" (KDA)
     # a short_conv layer: taps of its causal depthwise convolution (conv_L_cache)
     short_conv_kernel: int = 0
     # h = x + norm(mixer(x)), y = h + norm(ffn(h)): mixer and FFN (dense or
@@ -346,16 +373,19 @@ class TransformerConfig:
                     raise ValueError("a one-sublayer block is x + f(norm(x)): no post_norm, "
                                      "no dropout")
             experts_beside = self.num_experts and not one
-            if experts_beside and (sambay or "linear_attention" in self.layer_types
-                                   or not self.moe_dropless):
+            if experts_beside and (sambay or not self.moe_dropless):
                 raise ValueError("experts in a mixer-and-FFN block under layer_types go with "
-                                 "full_attention and short_conv layers and the dropless "
-                                 "dispatch only (elsewhere they live in one-sublayer moe "
-                                 "layers)")
-            if self.kv_lora_rank or self.parallel_residual or self.int8_weights:
-                raise ValueError("layer_types composes with plain attention in a float dtype "
-                                 "only (no latent attention, parallel residual or int8 "
-                                 "weights)")
+                                 "full_attention, linear_attention and short_conv layers and "
+                                 "the dropless dispatch only (elsewhere they live in "
+                                 "one-sublayer moe layers)")
+            if self.parallel_residual or self.int8_weights:
+                raise ValueError("layer_types composes with a float dtype and sequential "
+                                 "residuals only (no parallel residual or int8 weights)")
+            if self.kv_lora_rank and (sambay or one or any(self.layer_windows)
+                                      or self.post_norm or self.qk_norm):
+                raise ValueError("latent attention under layer_types is the full_attention "
+                                 "layer of a pre-norm mixer-and-FFN stack: no SambaY or "
+                                 "one-sublayer kinds, windows, post_norm or qk_norm")
         elif self.rope_windowed_only:
             raise ValueError("rope_windowed_only goes by layer_windows, which need layer_types")
         if self.post_norm and (self.parallel_residual or self.dropout > 0
@@ -370,21 +400,56 @@ class TransformerConfig:
             raise ValueError("moe_first_dense puts a dense MLP in the first layers of a stack "
                              "of mixer-and-expert blocks: it needs num_experts, unrolled "
                              "layers and at least one expert layer above")
+        for name in ("moe_swiglu_limits", "moe_shared_swiglu_limits"):
+            limits = tuple(getattr(self, name))
+            object.__setattr__(self, name, limits)
+            if limits and len(limits) != self.num_layers:
+                raise ValueError(f"{name} gives a limit (0 = none) for each of the "
+                                 f"{self.num_layers} layers, got {len(limits)}")
+            clamped = [i for i, x in enumerate(limits) if x]
+            if clamped:
+                raise ValueError(
+                    f"{name}: layers {clamped[0]}-{clamped[-1]} clamp their gated activation "
+                    f"(expert_swiglu_limit_list / share_expert_swiglu_limit_list) at a limit "
+                    f"the configuration publishes by value and not by form: not served. Cut "
+                    f"the depth below layer {clamped[0]} (num_layers with layer_types and "
+                    f"both lists)")
         if self.mtp_layers not in (0, 1):
             raise ValueError("one multi-token-prediction module at most (mtp_layers 0 or 1)")
         if self.mtp_layers and (self.scan_layers or self.kv_lora_rank or self.int8_weights
                                 or self.carries_across_layers
                                 or set(self.layer_types) & ({"short_conv"}
                                                             | set(ONE_SUBLAYER_TYPES))):
-            raise ValueError("the multi-token-prediction module is a block of attention and an "
+            raise ValueError("the multi-token-prediction module (mtp_layers: "
+                             "num_nextn_predict_layers) is a block of attention and an "
                              "FFN behind an unrolled stack of such blocks (no latent "
                              "attention, int8 weights, SambaY, short_conv or one-sublayer "
                              "kinds)")
-        if self.kv_lora_rank and not (self.q_lora_rank and self.qk_nope_head_dim
-                                      and self.qk_rope_head_dim and self.v_head_dim
-                                      and self.pos_embedding == "rope"):
-            raise ValueError("latent attention (kv_lora_rank > 0) needs q_lora_rank, "
-                             "qk_nope_head_dim, qk_rope_head_dim, v_head_dim and rope positions")
+        if self.kv_lora_rank and not (self.qk_nope_head_dim and self.qk_rope_head_dim
+                                      and self.v_head_dim and self.pos_embedding == "rope"):
+            raise ValueError("latent attention (kv_lora_rank > 0) needs qk_nope_head_dim, "
+                             "qk_rope_head_dim, v_head_dim and rope positions")
+        if self.attn_head_gate and not self.kv_lora_rank:
+            raise ValueError("attn_head_gate is the latent layers' head-wise output gate: it "
+                             "needs kv_lora_rank")
+        if self.linear_out_gate not in ("silu", "sigmoid"):
+            raise ValueError(f"linear_out_gate must be 'silu' or 'sigmoid', got "
+                             f"{self.linear_out_gate!r}")
+        if self.linear_decay_lower_bound > 0:
+            raise ValueError("linear_decay_lower_bound bounds a LOG decay from below: 0 (the "
+                             "softplus form) or negative")
+        if self.moe_n_group < 1 or not 1 <= self.moe_topk_group <= self.moe_n_group:
+            raise ValueError("moe_topk_group of moe_n_group groups: 1 <= kept <= groups")
+        if self.moe_n_group > 1:
+            size = self.num_experts // self.moe_n_group if self.num_experts else 0
+            if (self.moe_scoring != "sigmoid" or not size or self.num_experts % self.moe_n_group
+                    or size < 2 or self.moe_topk_group * size < self.moe_top_k
+                    or self.moe_first_expert % size or self.experts_held % size):
+                raise ValueError("a group-limited router (moe_n_group > 1) is the sigmoid "
+                                 "router's: groups of equal size (2 or more experts), the kept "
+                                 "groups hold at least top-k experts, and a layer holds WHOLE "
+                                 "groups (moe_first_expert and moe_experts_held multiples of "
+                                 "the group's size)")
         if (self.rope_factor > 1 or self.attn_temp_beta) and self.rope_original_max_len <= 0:
             raise ValueError("YaRN frequencies and the position-dependent query scale "
                              "need rope_original_max_len")
@@ -514,7 +579,9 @@ class TransformerConfig:
         h, v, L = self.hidden_size, self.vocab_size, self.num_layers
         if self.kv_lora_rank:
             nh, qk = self.num_heads, self.qk_nope_head_dim + self.qk_rope_head_dim
-            attn = (h * self.q_lora_rank + self.q_lora_rank * nh * qk + h * self.latent_width
+            q_proj = (h * self.q_lora_rank + self.q_lora_rank * nh * qk if self.q_lora_rank
+                      else h * nh * qk)
+            attn = (q_proj + h * self.latent_width
                     + self.kv_lora_rank * nh * (self.qk_nope_head_dim + self.v_head_dim)
                     + nh * self.v_head_dim * h)
         else:
@@ -558,9 +625,15 @@ class TransformerConfig:
             # q, k; v, gate, out; the two per-head gates with A_log and
             # dt_bias; the convolution; the gated norm's scale
             nl, dk, dv = self.linear_num_heads, self.linear_key_head_dim, self.linear_value_head_dim
-            lin = (2 * h * nl * dk + 3 * h * nl * dv + 2 * h * nl + 2 * nl
+            decay = nl * dk if self.linear_channel_decay else nl  # W_a's outputs, and dt_bias
+            lin = (2 * h * nl * dk + 3 * h * nl * dv + h * nl + h * decay + decay + nl
                    + self.linear_conv_channels * self.linear_conv_kernel + dv)
-            return (L * (mlp + 2 * h) + (L - n_lin) * attn + n_lin * lin) + emb + pos + h
+            if self.kv_lora_rank:
+                # the two latents' norms (a query of one projection has none), a head-wise gate
+                attn += (self.kv_lora_rank + self.q_lora_rank
+                         + (h * self.num_heads if self.attn_head_gate else 0))
+            ffn = self.moe_first_dense * per_h * self.ffn_size + (L - self.moe_first_dense) * mlp
+            return (ffn + L * 2 * h + (L - n_lin) * attn + n_lin * lin) + emb + pos + h
         dense = self.moe_first_dense * (per_h * self.ffn_size - mlp)
         # the module: one expert block, W_eh over [embedding ; hidden], three norms
         mtp = self.mtp_layers * (attn + mlp + 2 * h + 2 * h * h + 3 * h)
@@ -1752,6 +1825,14 @@ class LatentAttention(nn.Module):
         [k_nope_i ; v_i] = c_kv W_kvb,i
         s_ts,i = (q_nope_i . k_nope_i + RoPE(q_rope_i) . k_r) scale g(t)
 
+    With ``q_lora_rank`` 0 the query is ONE projection ``q_i = a W_q,i``
+    (``bailing_hybrid``); with ``attn_head_gate`` a head's output is gated
+    before ``W_o``: ``y = [sigmoid(a W_gate)_i o_i]_i W_o``, one gate a head
+    (traced under ``mla_proj``, with the other projections). Under
+    ``layer_types`` the ``full_attention`` layers of a configuration with
+    ``kv_lora_rank`` are latent, and their one leaf lies beside the other
+    layers' state in the cache tree.
+
     Without a cache (full forward) the EXPANDED form computes per-head K
     and V from ``c_kv``. With a cache (static generate, slot-pool decode and
     chunked-prefill spans alike) the ABSORBED form attends the latent rows
@@ -1777,8 +1858,14 @@ class LatentAttention(nn.Module):
                         kernel_init=nn.initializers.normal(0.02))
         norm = partial(RMSNorm, epsilon=cfg.layernorm_epsilon, dtype=cfg.dtype)
         with jax.named_scope("mla_proj"):
-            c_q = norm(name="q_a_norm")(dense(cfg.q_lora_rank, name="q_a_proj")(x))
-            q = HeadProjection(nh, nope + rope, False, cfg.dtype, name="q_b_proj")(c_q)
+            if cfg.q_lora_rank:
+                c_q = norm(name="q_a_norm")(dense(cfg.q_lora_rank, name="q_a_proj")(x))
+                q = HeadProjection(nh, nope + rope, False, cfg.dtype, name="q_b_proj")(c_q)
+            else:  # ONE query projection
+                q = HeadProjection(nh, nope + rope, False, cfg.dtype, name="q_proj")(x)
+            if cfg.attn_head_gate:  # (B, nh, T, 1): one gate a head
+                head_gate = jax.nn.sigmoid(dense(nh, name="g_proj")(x).astype(jnp.float32))
+                head_gate = head_gate.transpose(0, 2, 1)[..., None].astype(cfg.dtype)
             kv_a = dense(rank + rope, name="kv_a_proj")(x)
             c_kv = norm(name="kv_a_norm")(kv_a[..., :rank])  # (B, T, rank)
             # (rank, nh, nope + v): k_nope and v of every head from the latent
@@ -1820,7 +1907,7 @@ class LatentAttention(nn.Module):
                 out = jnp.einsum("bnqk,bnkd->bnqd", probs, kv[..., nope:])
             new_cache = None
         else:
-            (pool, ) = kv_cache  # (B, 1, S, rank + rope)
+            pool = kv_cache[0]  # (B, 1, S, rank + rope); a wider tree's other places hold nothing
             fresh = jnp.concatenate([c_kv[:, None], k_r], axis=-1).astype(pool.dtype)
             if write_index is not None and q_spans is not None:
                 (pool, ) = _commit_span_rows([(pool, fresh)], write_index, q_spans,
@@ -1841,13 +1928,17 @@ class LatentAttention(nn.Module):
                     qf, pool[:, 0].astype(cfg.dtype), pos, live_end, attn_mask, score_scale,
                     rank=rank, block_kv=cfg.decode_block_kv, dtype=cfg.dtype)
                 out = jnp.einsum("bntr,rnd->bntd", o_lat, w_kvb[..., nope:])
-            new_cache = (pool, )
+            new_cache = (pool, ) + (None, ) * (len(kv_cache) - 1)
         with jax.named_scope("mla_proj"):
-            out = OutProjection(H, False, cfg.dtype, name="o_proj")(out.astype(cfg.dtype))
+            out = out.astype(cfg.dtype)
+            if cfg.attn_head_gate:
+                out = out * head_gate
+            out = OutProjection(H, False, cfg.dtype, name="o_proj")(out)
         return out, new_cache
 
 
 GDN_CHUNK = 64  # positions the gated-delta scan solves together
+KDA_CHUNK = 16  # ... with a decay a key channel, bounded below by -5 a step: e^(16 x 5) < 3.4e38
 GDN_L2_EPS = 1e-6  # under the root of q's and k's L2 norm: a zero vector stays zero
 
 
@@ -1876,15 +1967,17 @@ def gated_delta_step(S, q, k, v, g, beta):
     """The gated delta rule for ONE token, float32: ``S`` (B, n, dk, dv),
     ``q``/``k`` (B, n, dk), ``v`` (B, n, dv), ``g`` (log decay) and ``beta``
     (B, n). ``S' = a S + beta k (v - a S^T k)^T`` with ``a = exp(g)``;
-    returns ``(S'^T q, S')``. Elementwise products and sums over ``dk``: a
+    returns ``(S'^T q, S')``. ``g`` (B, n, dk) is a decay a KEY CHANNEL (Kimi
+    delta attention): ``a S`` is then ``Diag(a) S``, row ``d`` of the state
+    scaled by ``a_d``. Elementwise products and sums over ``dk``: a
     slot's state is read and written once, nothing runs on tiny matrices."""
-    S = S * jnp.exp(g)[..., None, None]
+    S = S * (jnp.exp(g)[..., None] if g.ndim == k.ndim else jnp.exp(g)[..., None, None])
     u = beta[..., None] * (v - jnp.sum(S * k[..., None], axis=-2))
     S = S + k[..., None] * u[..., None, :]
     return jnp.sum(S * q[..., None], axis=-2), S
 
 
-def gated_delta_chunked(S, q, k, v, g, beta, chunk=GDN_CHUNK):
+def gated_delta_chunked(S, q, k, v, g, beta, chunk=None):
     """The same recurrence over ``T`` tokens, chunk by chunk, float32:
     ``q``/``k`` (B, n, T, dk), ``v`` (B, n, T, dv), ``g``/``beta`` (B, n,
     T), ``S`` the incoming state. With ``G_t`` the running sum of ``g``
@@ -1898,12 +1991,28 @@ def gated_delta_chunked(S, q, k, v, g, beta, chunk=GDN_CHUNK):
     A token with ``beta`` 0 and ``g`` 0 (padding up to a whole chunk, a
     column past a row's span) leaves the state as it is. Returns ``(O (B, n,
     T, dv), S_T)``. The small products run at ``highest`` precision: their
-    operands are float32 that bfloat16 passes would round."""
+    operands are float32 that bfloat16 passes would round.
+
+    ``g`` (B, n, T, dk) is a decay a KEY CHANNEL: ``e^(G_t - G_s)`` no longer
+    factors out of ``k_t . k_s``, whose terms each carry their channel's.
+    The same equations then hold with the decay inside the products,
+
+        A[t, s] = beta_t (k_t * e^(G_t - G_m)) . (k_s * e^(G_m - G_s)),   diag(e^G) K S_0 -> (K * e^G) S_0,
+
+    (``m`` the chunk's middle position) in chunks of :data:`KDA_CHUNK`
+    positions: an exponent reaches 8 x 5 at the bounded gate's floor of -5 a
+    step (16 x 5 in a chunk's masked corner), inside float32, where 64
+    positions' ``e^320`` would not be. A sum of 40 is known to 4e-6 in
+    float32, so at the floor the factors carry that much relative error;
+    nearer 0 they are exact to rounding."""
     B, n, T, dk = q.shape
+    channel = g.ndim == 4
+    chunk = chunk or (KDA_CHUNK if channel else GDN_CHUNK)
     pad = -T % chunk
     if pad:
         q, k, v = (jnp.pad(x, ((0, 0), (0, 0), (0, pad), (0, 0))) for x in (q, k, v))
-        g, beta = (jnp.pad(x, ((0, 0), (0, 0), (0, pad))) for x in (g, beta))
+        g, beta = (jnp.pad(x, ((0, 0), (0, 0), (0, pad)) + ((0, 0), ) * (x.ndim - 3))
+                   for x in (g, beta))
     nc = (T + pad) // chunk
     # (nc, B, n, chunk, ...): the scan walks the chunks
     split = lambda x: jnp.moveaxis(x.reshape(x.shape[:2] + (nc, chunk) + x.shape[3:]), 2, 0)
@@ -1926,7 +2035,25 @@ def gated_delta_chunked(S, q, k, v, g, beta, chunk=GDN_CHUNK):
              + mm("bnsd,bnsv->bndv", kc * jnp.exp(G[..., -1:] - G)[..., None], U))
         return S, o
 
-    S, o = jax.lax.scan(body, S, tuple(split(x) for x in (q, k, v, g, beta)))
+    def body_channel(S, xs):
+        qc, kc, vc, gc, bc = xs
+        G = jnp.cumsum(gc, axis=-2)  # (B, n, chunk, dk)
+        # e^(G_t - G_s) = e^(G_t - G_m) e^(G_m - G_s) about the chunk's middle
+        # position m: exponents of half the chunk's reach, half the rounding
+        mid = G[..., chunk // 2:chunk // 2 + 1, :]
+        gam, up, down = jnp.exp(G), jnp.exp(G - mid), jnp.exp(mid - G)
+        A = jnp.where(strict, bc[..., None] * mm("bntd,bnsd->bnts", kc * up, kc * down), 0.0)
+        rhs = bc[..., None] * (vc - mm("bntd,bndv->bntv", kc * gam, S))
+        U = jax.scipy.linalg.solve_triangular(eye + A, rhs, lower=True, unit_diagonal=True)
+        o = (mm("bntd,bndv->bntv", qc * gam, S)
+             + mm("bnts,bnsv->bntv",
+                  jnp.where(lower, mm("bntd,bnsd->bnts", qc * up, kc * down), 0.0), U))
+        S = (gam[..., -1, :, None] * S
+             + mm("bnsd,bnsv->bndv", kc * jnp.exp(G[..., -1:, :] - G), U))
+        return S, o
+
+    S, o = jax.lax.scan(body_channel if channel else body, S,
+                        tuple(split(x) for x in (q, k, v, g, beta)))
     o = jnp.moveaxis(o, 0, 2).reshape(B, n, T + pad, -1)
     return o[:, :, :T], S
 
@@ -1954,6 +2081,18 @@ class GatedDeltaNet(nn.Module):
         beta = sigmoid(x W_b) (x 2 with linear_neg_eigval) ; g = -exp(A_log) softplus(x W_a + dt_bias)
         S_t = e^g S_(t-1) + beta k (v - e^g S_(t-1)^T k)^T ; o_t = S_t^T q_t
         y = [RMSNorm_dv(o) * SiLU(x W_g)] W_o
+
+    Kimi delta attention (KDA, arXiv:2510.26692, as ``bailing_hybrid``
+    configures it) is the same mixer with the decay one value a KEY CHANNEL
+    (``linear_channel_decay``: ``W_a`` hidden -> n dk, full rank, ``dt_bias`` one
+    a channel, ``A_log`` one a head), the log decay bounded
+    (``linear_decay_lower_bound`` lb < 0: ``g = lb sigmoid(exp(A_log) (x W_a +
+    dt_bias))``, in (lb, 0)), and a sigmoid output gate (``linear_out_gate``):
+
+        S_t = (I - beta k k^T) Diag(e^g) S_(t-1) + beta k v^T ; y = [RMSNorm_dv(o) * sigmoid(x W_g)] W_o
+
+    One mixer, one set of scopes and counters, one one-token kernel for both
+    decays (the head's scalar is the constant vector).
 
     What a slot holds for such a layer (``init_cache``): the state and the
     convolution's last ``W - 1`` inputs ``(B, 1, W - 1, n (2 dk + dv))``,
@@ -2009,8 +2148,19 @@ class GatedDeltaNet(nn.Module):
             if cfg.linear_neg_eigval:
                 beta = 2.0 * beta
             a_log = self.param("A_log", gdn_a_log_init, (n, ), f32)
-            dt_bias = self.param("dt_bias", gdn_dt_bias_init, (n, ), f32)
-            g = -jnp.exp(a_log) * jax.nn.softplus(dense(n, name="a_proj")(x).astype(f32) + dt_bias)
+            # the log decay: one a head (B, T, n), or one a key channel (B, T, n, dk)
+            wide = n * dk if cfg.linear_channel_decay else n
+            dt_bias = self.param("dt_bias", gdn_dt_bias_init, (wide, ), f32)
+            # (the rate before the projection, as the head's form always traced it:
+            # cell 5's programs are held to their lowered text, test_tpu_compile.py)
+            neg_rate = -jnp.exp(a_log)
+            raw = dense(wide, name="a_proj")(x).astype(f32) + dt_bias
+            if cfg.linear_channel_decay:
+                raw, neg_rate = raw.reshape(B, T, n, dk), neg_rate[:, None]
+            if cfg.linear_decay_lower_bound:
+                g = cfg.linear_decay_lower_bound * jax.nn.sigmoid(-neg_rate * raw)
+            else:
+                g = neg_rate * jax.nn.softplus(raw)
             conv_w = self.param("conv", gdn_conv_init, (cfg.linear_conv_channels, W), f32)
             in_place = False
             if kv_cache is None:
@@ -2023,7 +2173,7 @@ class GatedDeltaNet(nn.Module):
                 fresh = live_row & (write_index == 0)
                 # the rule _commit_span_rows takes its in-place kernel by
                 in_place = (T == 1 and cfg.attention_impl == "flash" and _tp_mesh_size() == 1
-                            and gdn_step.tiles(state_rest, n, dk, dv))
+                            and gdn_step.tiles(state_rest, n, dk, dv, cfg.linear_channel_decay))
                 if T == 1:
                     gdn_step.tally(in_place)
                 if not in_place:
@@ -2031,7 +2181,8 @@ class GatedDeltaNet(nn.Module):
                                       gdn_step.unpack_state(state_rest, packing).astype(f32))
                 window = jnp.where(fresh[:, None, None], 0, window_rest[:, 0]).astype(cfg.dtype)
                 live = (jnp.arange(T)[None, :] < q_spans[:, None])[..., None]
-                beta, g = jnp.where(live, beta, 0.0), jnp.where(live, g, 0.0)
+                beta = jnp.where(live, beta, 0.0)
+                g = jnp.where(live[..., None] if cfg.linear_channel_decay else live, g, 0.0)
             # causal depthwise convolution over [window ; this call's inputs]
             seq = jnp.concatenate([window, mixed.astype(cfg.dtype)], axis=1)
             conv = sum(seq[:, j:j + T].astype(f32) * conv_w[:, j] for j in range(W))
@@ -2041,7 +2192,8 @@ class GatedDeltaNet(nn.Module):
             v = heads(u[..., 2 * n * dk:], dv)
             l2 = lambda y: y * jax.lax.rsqrt(jnp.sum(y * y, axis=-1, keepdims=True) + GDN_L2_EPS)
             q, k = l2(q) * dk ** -0.5, l2(k)
-            g, beta = g.transpose(0, 2, 1), beta.transpose(0, 2, 1)  # (B, n, T)
+            # (B, n, T), a channel decay (B, n, T, dk)
+            g, beta = jnp.swapaxes(g, 1, 2), beta.transpose(0, 2, 1)
         with jax.named_scope("gdn_state"):
             if T == 1:
                 column = (q[:, :, 0], k[:, :, 0], v[:, :, 0], g[:, :, 0], beta[:, :, 0])
@@ -2072,7 +2224,8 @@ class GatedDeltaNet(nn.Module):
                               tail[:, None].astype(window_rest.dtype), window_rest))
         with jax.named_scope("gdn_out"):
             o = RMSNorm(epsilon=cfg.layernorm_epsilon, dtype=cfg.dtype, name="o_norm")(o)
-            o = o * jax.nn.silu(gate.reshape(B, T, n, dv).transpose(0, 2, 1, 3))
+            out_gate = jax.nn.sigmoid if cfg.linear_out_gate == "sigmoid" else jax.nn.silu
+            o = o * out_gate(gate.reshape(B, T, n, dv).transpose(0, 2, 1, 3))
             out = OutProjection(H, False, cfg.dtype, name="o_proj")(o)
         return out, new_cache
 
@@ -3099,7 +3252,10 @@ class CausalLMModel:
 
         A ``linear_attention`` layer (``layer_types``) holds no rows: in the
         same two places of the tree it carries its recurrent state and its
-        convolution window, per slot (:meth:`cache_spec`)."""
+        convolution window, per slot (:meth:`cache_spec`). Where such layers
+        stand beside LATENT ones (``kv_lora_rank`` under ``layer_types``) a
+        slot holds, by layer, either that pair or one latent leaf: two kinds
+        of cache in one tree, the latent layers' second place empty."""
         spec = self.cache_spec(batch_size, max_len, dtype, quantized)
         if self.cfg.scan_layers:
             return tuple(fill((self.cfg.num_layers, ) + shape, t)
@@ -3187,10 +3343,13 @@ class CausalLMModel:
         two_leaves = {"linear_attention", "mamba2", None} & set(mixers) or any(windows)
         if not two_leaves and "short_conv" not in mixers:
             return [tuple(rows)] * cfg.num_layers + module
-        # (packed rows beside state: a short_conv layer's one leaf alone)
-        if quantized or (len(rows) != 2 and two_leaves):
+        # (packed rows beside state: a short_conv layer's one leaf alone; the
+        # latent row is ONE leaf whatever lies beside it: LatentAttention
+        # reads its layer's first place and nothing tells leaves apart by count)
+        if quantized or (len(rows) != 2 and two_leaves and not cfg.latent_width):
             raise NotImplementedError("a pool with state or ring leaves has no int8 tier and, "
-                                      "but for short_conv layers' windows, no packed geometry")
+                                      "but for short_conv layers' windows and latent rows, no "
+                                      "packed geometry")
         gdn_pack = gdn_step.state_packing(cfg.linear_num_heads, cfg.linear_value_head_dim)
         state = lambda shape, W, channels: (
             ("state", (batch_size, ) + shape, dt, jnp.zeros),

@@ -559,7 +559,8 @@ class MoE(nn.Module):
             if cfg.moe_scoring == "sigmoid":
                 bias = self.param("e_score_correction_bias", nn.initializers.zeros, (E, ),
                                   jnp.float32)
-                ids, w = sigmoid_serving_choice(logits, bias, k, cfg.moe_renorm_eps)
+                ids, w = sigmoid_serving_choice(logits, bias, k, cfg.moe_renorm_eps,
+                                                cfg.moe_n_group, cfg.moe_topk_group)
             else:
                 ids, w = top_k_serving_choice(logits, k)
             w = w * cfg.moe_routed_scale

@@ -134,23 +134,45 @@ def top_k_serving_choice(logits, k):
     return ids, jnp.take_along_axis(weights, ids, axis=-1)
 
 
-def sigmoid_serving_choice(logits, bias, k, eps=1e-20):
-    """The serving choice of a sigmoid router with a selection bias
-    (DeepSeek-V3's rule, one group): ``s = sigmoid(logits)`` in fp32 over ALL
-    experts; the k experts are the top k of ``s + bias`` (the same iterative
-    argmax as :func:`_serving_top_k`: ties resolve to the lowest expert id);
-    their weights are ``s`` itself, WITHOUT the bias, renormalised over the k
-    (``w / (sum w + eps)``: DeepSeek-V3's code adds 1e-20, ``lfm2_moe``
-    publishes 1e-6; ``TransformerConfig.moe_renorm_eps``). Returns ``(expert ids (N, k) int32, weights (N,
-    k) fp32)``; each row is a pure function of its logits."""
-    s = jax.nn.sigmoid(logits.astype(jnp.float32))
-    masked = s + bias.astype(jnp.float32)
+def _top_ids(masked, k):
+    """The ``k`` largest of each row of ``masked`` (N, E) by iterative argmax
+    (ties resolve to the lowest id): ``(ids (N, k) int32, masked with the
+    chosen at -inf)``."""
     ids = []
     for _ in range(k):
         idx = jnp.argmax(masked, axis=-1).astype(jnp.int32)
         ids.append(idx)
         masked = jnp.where(jnp.arange(masked.shape[-1])[None, :] == idx[:, None], -jnp.inf,
                            masked)
-    ids = jnp.stack(ids, axis=-1)
+    return jnp.stack(ids, axis=-1), masked
+
+
+def sigmoid_serving_choice(logits, bias, k, eps=1e-20, n_group=1, topk_group=1):
+    """The serving choice of a sigmoid router with a selection bias
+    (DeepSeek-V3's rule): ``s = sigmoid(logits)`` in fp32 over ALL
+    experts; the k experts are the top k of ``s + bias`` (the same iterative
+    argmax as :func:`_serving_top_k`: ties resolve to the lowest expert id);
+    their weights are ``s`` itself, WITHOUT the bias, renormalised over the k
+    (``w / (sum w + eps)``: DeepSeek-V3's code adds 1e-20, ``lfm2_moe``
+    publishes 1e-6; ``TransformerConfig.moe_renorm_eps``). With ``n_group``
+    > 1 the choice is GROUP-LIMITED (``n_group`` / ``topk_group``): group
+    ``j`` is experts ``[j E / n_group, (j + 1) E / n_group)``, its score the
+    sum of its two largest ``s + bias``; a row keeps its ``topk_group`` best
+    groups (ties to the lowest group) and chooses its k among their experts.
+    One group is the rule without a limit, bit for bit. Returns ``(expert
+    ids (N, k) int32, weights (N, k) fp32)``; each row is a pure function of
+    its logits."""
+    s = jax.nn.sigmoid(logits.astype(jnp.float32))
+    masked = s + bias.astype(jnp.float32)
+    if n_group > 1:
+        N, E = masked.shape
+        grouped = masked.reshape(N, n_group, E // n_group)
+        _, rest = _top_ids(grouped.reshape(N * n_group, -1), 2)
+        # a group's two largest: what the iterative argmax took out of it
+        two = jnp.where(jnp.isneginf(rest).reshape(grouped.shape), grouped, 0.0)
+        kept, _ = _top_ids(jnp.sum(two, axis=-1), topk_group)  # (N, topk_group)
+        keep = jnp.any(kept[:, :, None] == jnp.arange(n_group)[None, None, :], axis=1)
+        masked = jnp.where(jnp.repeat(keep, E // n_group, axis=-1), masked, -jnp.inf)
+    ids, _ = _top_ids(masked, k)
     w = jnp.take_along_axis(s, ids, axis=-1)
     return ids, w / (jnp.sum(w, axis=-1, keepdims=True) + eps)

@@ -125,7 +125,7 @@ def unpack_state(S, p):
     return S.reshape(B, U, dk, p, L // p).swapaxes(2, 3).reshape(B, U * p, dk, L // p)
 
 
-def _vmem_estimate(bu, dk, L, itemsize):
+def _vmem_estimate(bu, dk, L, itemsize, kq_rows=8):
     """VMEM bytes of one grid step over ``bu`` units of ``(dk, L)``, counted
     as Mosaic lays blocks out (lanes pad to 128, pipelined operands are
     double-buffered): the state block in and out, the tiles of k and q, the
@@ -133,24 +133,26 @@ def _vmem_estimate(bu, dk, L, itemsize):
     body walks the units)."""
     Lp = _pad(L, LANES)
     io = 2 * 2 * bu * dk * Lp * itemsize
-    parts = 2 * bu * 8 * _pad(dk, LANES) * 4
+    parts = 2 * bu * kq_rows * _pad(dk, LANES) * 4
     rows = 2 * 2 * _pad(bu, 8) * Lp * 4
     return io + parts + rows + 6 * _pad(dk, LANES) * Lp * 4
 
 
-def tiles(leaf, n, dk, dv):
+def tiles(leaf, n, dk, dv, channel_decay=False):
     """Whether ``leaf`` is a state leaf this kernel updates: ``n`` heads of
     ``(dk, dv)`` at :func:`state_packing`'s packing with whole lane tiles,
     ``dk`` a whole number of the dtype's sublane tiles and at most one lane
     tile (a ``k`` is a row of its operand), one unit inside the VMEM
-    budget."""
+    budget. ``channel_decay``: the decay is one value a key channel (two
+    sublane tiles of operand rows a unit)."""
     if leaf.ndim != 4 or leaf.dtype not in (jnp.bfloat16, jnp.float32):
         return False
     p = state_packing(n, dv)
     itemsize = jnp.dtype(leaf.dtype).itemsize
+    rows = KQ_ROWS if channel_decay else 8
     return (leaf.shape[1:] == (n // p, dk, p * dv) and (p * dv) % LANES == 0
             and dk % (8 * (4 // itemsize)) == 0 and dk <= LANES
-            and _pallas.fits_vmem(_vmem_estimate(1, dk, p * dv, itemsize)))
+            and _pallas.fits_vmem(_vmem_estimate(1, dk, p * dv, itemsize, rows)))
 
 
 def _top8(x):
@@ -171,11 +173,15 @@ def _split3(x, dtype=jnp.float32):
 
 
 def _step_kernel(order_ref, count_ref, fresh_ref, sc_ref, s_ref, kq_ref, v_ref,
-                 out_ref, o_ref, *, n, p, dk, dv, bu):
+                 out_ref, o_ref, *, n, p, dk, dv, bu, channel):
     """Step ``t``'s slot ``order[t]``, a block of ``bu`` units of it.
     ``sc_ref`` (SMEM): ``a``, ``beta`` and ``k . q`` of every head, ``(3 B
     n,)``; ``kq_ref``: a unit's rows ``k`` of its ``p`` heads, then ``q`` of
-    them, float32, one sublane tile; ``v_ref`` / ``o_ref``: ``(bu, L)`` rows."""
+    them, float32, one sublane tile; ``v_ref`` / ``o_ref``: ``(bu, L)`` rows.
+    ``channel``: the decay is a vector ``a`` over ``dk`` a head (module
+    docstring); ``kq_ref`` is then two sublane tiles, the rows ``k``, ``q``,
+    ``a * k``, ``a * q`` and ``a`` of the ``p`` heads, and ``sc_ref``'s ``a`` is
+    not read."""
     t = pl.program_id(1)
     B = pl.num_programs(1)
     i = order_ref[t]
@@ -190,6 +196,9 @@ def _step_kernel(order_ref, count_ref, fresh_ref, sc_ref, s_ref, kq_ref, v_ref,
                  & (row < p * len(_TERMS)))
     k_row = jax.lax.broadcasted_iota(jnp.int32, (KQ_ROWS, LANES), 0)
     exact = s_ref.dtype == jnp.bfloat16
+    # row (head, part) of the operands of Diag(a)'s lane broadcast
+    own_head = channel and ((row // 3 == jax.lax.broadcasted_iota(jnp.int32, row.shape, 1) // dv)
+                            & (row < 3 * p))
 
     def by_head(vals):
         """``vals[h]`` (a scalar or a ``(1, L)`` row) over head ``h``'s lanes."""
@@ -208,17 +217,36 @@ def _step_kernel(order_ref, count_ref, fresh_ref, sc_ref, s_ref, kq_ref, v_ref,
             u = beta * v
             o = kdq * u
         else:
-            a = by_head([sc_ref[x] for x in heads])
+            if not channel:  # (read first, as the head's path always traced it)
+                a = by_head([sc_ref[x] for x in heads])
             S = s_ref[0, j]
             # [k ; q] S: a tile of rows a part, over every lane
             lhs = jnp.concatenate(kq3, axis=0).astype(jnp.bfloat16)[:, :dk]
             R = sum(jnp.dot(lhs, part, preferred_element_type=f32)
                     for part in ((S, ) if exact else _split3(S, jnp.bfloat16)))
-            R = R[:8] + R[8:16] + R[16:]
-            Sk = by_head([R[h:h + 1] for h in range(p)])
-            Sq = by_head([R[p + h:p + h + 1] for h in range(p)])
-            u = beta * (v - a * Sk)
-            o = a * Sq + kdq * u
+            if channel:
+                # S^T (a * k) and S^T (a * q): the rows that entered scaled.
+                # Diag(a) over the block: a's three parts, each on its head's
+                # lanes of a row of ones, transposed by the product
+                R = R[:KQ_ROWS] + R[KQ_ROWS:2 * KQ_ROWS] + R[2 * KQ_ROWS:]
+                Sk = by_head([R[2 * p + h:2 * p + h + 1] for h in range(p)])
+                Sq = by_head([R[3 * p + h:3 * p + h + 1] for h in range(p)])
+                aa = jnp.zeros((KQ_ROWS, LANES), f32)
+                for h in range(p):
+                    for part in range(3):
+                        aa = jnp.where(k_row == 3 * h + part,
+                                       kq3[part][4 * p + h:4 * p + h + 1], aa)
+                a = jax.lax.dot_general(
+                    aa.astype(jnp.bfloat16), own_head.astype(jnp.bfloat16),
+                    (((0, ), (0, )), ((), ())), preferred_element_type=f32)[:dk]
+                u = beta * (v - Sk)
+                o = Sq + kdq * u
+            else:
+                R = R[:8] + R[8:16] + R[16:]
+                Sk = by_head([R[h:h + 1] for h in range(p)])
+                Sq = by_head([R[p + h:p + h + 1] for h in range(p)])
+                u = beta * (v - a * Sk)
+                o = a * Sq + kdq * u
         o_ref[0, 0, j:j + 1, :] = o
         # k u^T as one product over the terms: row (head, term) holds the
         # term's part of the head's k on one side, its part of u on the
@@ -262,7 +290,8 @@ def gated_delta_update(state, q, k, v, g, beta, live, fresh):
 
     ``state``: the leaf at rest, ``(B, n / p, dk, p * dv)`` (``tiles`` holds);
     ``q``/``k`` (B, n, dk), ``v`` (B, n, dv), ``g``/``beta`` (B, n), float32,
-    as ``gated_delta_step`` takes them; ``live``, ``fresh``: (B,) bool. Slot
+    as ``gated_delta_step`` takes them (``g`` (B, n, dk): a decay a key
+    channel); ``live``, ``fresh``: (B,) bool. Slot
     ``i`` advances when ``live[i]`` (from zero when ``fresh[i]`` too) and is
     left bit for bit otherwise. Returns ``(o (B, n, dv) float32, new
     leaf)``; the leaf operand is aliased to the new one.
@@ -279,14 +308,16 @@ def _update(state, q, k, v, g, beta, live, fresh, *, interpret):
     U, L = state.shape[1], state.shape[3]
     p = n // U
     itemsize = jnp.dtype(state.dtype).itemsize
+    channel = g.ndim == 3
+    kq_rows = KQ_ROWS if channel else 8
     if state.shape != (B, U, dk, p * dv) or dk > LANES or p * len(_TERMS) > KQ_ROWS:
         raise ValueError(f"gdn step: leaf {state.shape} is not {n} heads of ({dk}, {dv}) "
                          f"packed {p} a lane row")
     bu = next((b for b in range(U, 0, -1) if U % b == 0
-               and _pallas.fits_vmem(_vmem_estimate(b, dk, L, itemsize))), None)
+               and _pallas.fits_vmem(_vmem_estimate(b, dk, L, itemsize, kq_rows))), None)
     if bu is None:
         raise ValueError(f"gdn step: one unit of ({dk}, {L}) needs "
-                         f"{_vmem_estimate(1, dk, L, itemsize)} bytes of VMEM, over the "
+                         f"{_vmem_estimate(1, dk, L, itemsize, kq_rows)} bytes of VMEM, over the "
                          f"{_pallas.VMEM_BLOCK_BUDGET}-byte budget")
     f32 = jnp.float32
     q, k, v = q.astype(f32), k.astype(f32), v.astype(f32)
@@ -298,22 +329,25 @@ def _update(state, q, k, v, g, beta, live, fresh, *, interpret):
     step = jnp.minimum(idx, jnp.maximum(count - 1, 0))
     order = jnp.sum(jnp.where(live[None, :] & (rank[None, :] == step[:, None]), idx[None, :], 0),
                     axis=1)
-    sc = jnp.concatenate([jnp.exp(g.astype(f32)).reshape(-1), beta.astype(f32).reshape(-1),
-                          jnp.sum(k * q, axis=-1).reshape(-1)])
-    # a unit's k rows, then its q rows: one float32 sublane tile, dk padded
-    # to the lane tile (the kernel splits them into bf16 parts)
+    a = jnp.exp(g.astype(f32))
+    sc = jnp.concatenate([(jnp.ones_like(beta, f32) if channel else a).reshape(-1),
+                          beta.astype(f32).reshape(-1), jnp.sum(k * q, axis=-1).reshape(-1)])
+    # a unit's k rows, then its q rows (a decay a channel: then a * k, a * q
+    # and a): whole float32 sublane tiles, dk padded to the lane tile (the
+    # kernel splits them into bf16 parts)
     unit = lambda x: x.reshape(B, U, p, dk)
-    kq = jnp.pad(jnp.concatenate([unit(k), unit(q)], axis=2),
-                 ((0, 0), (0, 0), (0, 8 - 2 * p), (0, LANES - dk)))
+    rows_in = [unit(k), unit(q)] + ([unit(a * k), unit(a * q), unit(a)] if channel else [])
+    kq = jnp.pad(jnp.concatenate(rows_in, axis=2),
+                 ((0, 0), (0, 0), (0, kq_rows - len(rows_in) * p), (0, LANES - dk)))
     rows = v.reshape(B, U // bu, bu, L)
     blocks = U // bu
 
     at_slot = lambda h, t, order_r, *_: (order_r[t], h, 0, 0)
     state_spec = pl.BlockSpec((1, bu, dk, L), at_slot)
-    kq_spec = pl.BlockSpec((1, bu, 8, LANES), at_slot)
+    kq_spec = pl.BlockSpec((1, bu, kq_rows, LANES), at_slot)
     row_spec = pl.BlockSpec((1, 1, bu, L), at_slot)
     new, o = pl.pallas_call(
-        functools.partial(_step_kernel, n=n, p=p, dk=dk, dv=dv, bu=bu),
+        functools.partial(_step_kernel, n=n, p=p, dk=dk, dv=dv, bu=bu, channel=channel),
         name="dstpu_gdn_step",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,

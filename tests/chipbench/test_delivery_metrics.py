@@ -58,7 +58,11 @@ def _serving(bench):
 @pytest.mark.parametrize("name", ALL_METRICS)
 def test_metric_file_lists_exactly_the_cells_of_the_serving_rate(name):
     bench, spec = _bench(), _spec(name)
-    assert spec["workloads"] == _serving(bench) and len(spec["workloads"]) == 7
+    # the seven serving cells the benchmark had when the file was written; a
+    # cell appended since (PR 54's tenth) names the metric in its own file
+    assert spec["workloads"] == _serving(bench)[:7] and len(spec["workloads"]) == 7
+    for later in _serving(bench)[7:]:
+        assert name in cells.load_workload(later)[1]["per_layer"], later
     assert spec["moves"] == "serve_tokens_per_s" and spec["better"] == "lower"
     if name in DATA_METRICS:
         hist, quantile, layer, unit, source = DATA_METRICS[name]
@@ -75,11 +79,15 @@ def test_benchmark_entry_says_what_the_file_says(name):
     bench, spec = _bench(), _spec(name)
     entry, = [m for m in bench["per_layer"] if m["name"] == name]
     assert set(entry) == {"name", "unit", "better", "source", "layer", "moves", "workloads"}
-    assert {k: entry[k] for k in entry if k != "name"} == {
-        k: spec[k] for k in ("unit", "better", "source", "layer", "moves", "workloads")}
-    # appended behind everything the benchmark had, in this order (PR 53's
-    # one behind them)
-    assert [m["name"] for m in bench["per_layer"]][-9:] == [
+    assert {k: entry[k] for k in entry if k not in ("name", "workloads")} == {
+        k: spec[k] for k in ("unit", "better", "source", "layer", "moves")}
+    # the file's cells first, then the serving cells appended since
+    assert entry["workloads"] == _serving(bench) and entry["workloads"][:7] == spec["workloads"]
+    # appended behind everything the benchmark had then, in this order (PR 53's
+    # one behind them; later PRs' metrics behind that)
+    names = [m["name"] for m in bench["per_layer"]]
+    first = names.index("delivery_lag_ms")
+    assert names[first:first + 9] == [
         "delivery_lag_ms", "delivery_lag_max_ms", "delivery_backlog_events",
         "loop_cpu_us_per_event", "loop_cpu_ms", "pump_cpu_ms", "host_threads_cpu_pct",
         "loop_send_trace_pct", ENGAGEMENT]
@@ -187,12 +195,12 @@ def test_engagement_metric_is_data_alone_and_says_what_the_benchmark_says():
     assert spec["args"] == {"num": "gateway/sse_events", "den": ["gateway/sse_tokens"]}
     assert (spec["layer"], spec["unit"], spec["better"], spec["source"], spec["moves"]) == (
         "gateway", "events", "lower", "program_counter", "serve_tokens_per_s")
-    assert spec["workloads"] == _serving(bench) and len(spec["workloads"]) == 7
-    entry = bench["per_layer"][-1]
-    assert entry["name"] == ENGAGEMENT
+    assert spec["workloads"] == _serving(bench)[:7] and len(spec["workloads"]) == 7
+    entry, = [m for m in bench["per_layer"] if m["name"] == ENGAGEMENT]
     assert set(entry) == {"name", "unit", "better", "source", "layer", "moves", "workloads"}
-    assert {k: entry[k] for k in entry if k != "name"} == {
-        k: spec[k] for k in ("unit", "better", "source", "layer", "moves", "workloads")}
+    assert {k: entry[k] for k in entry if k not in ("name", "workloads")} == {
+        k: spec[k] for k in ("unit", "better", "source", "layer", "moves")}
+    assert entry["workloads"] == _serving(bench)  # (the cells appended since name it themselves)
 
 
 def test_training_cells_load_nothing_of_the_delivery():

@@ -390,7 +390,7 @@ def test_the_other_refusals(tiny):
     with pytest.raises(ValueError, match="moe layers need num_experts"):
         dataclasses.replace(cfg, num_layers=1, layer_types=("mamba2", ))
     with pytest.raises(ValueError, match="experts in a mixer-and-FFN block"):
-        dataclasses.replace(get_model("tiny-hybrid").cfg, num_experts=4, moe_dropless=True)
+        dataclasses.replace(get_model("tiny-sambay").cfg, num_experts=4, moe_dropless=True)
     with pytest.raises(ValueError, match="ssm_num_heads"):
         dataclasses.replace(cfg, ssm_groups=3)
     with pytest.raises(ValueError, match="no capacity-buffered path"):
@@ -490,7 +490,9 @@ def test_no_preset_goes_unguarded():
     # PR 50's by tests/unit/inference/test_lfm2_moe_pool.py
     assert set(available_models()) == set(PARENT_TREES) | {
         "nemotron-3-nano-30b-a3b", "tiny-nemotron-h", "k-exaone-236b-a23b", "tiny-exaone-moe",
-        "lfm2-8b-a1b", "tiny-lfm2-moe"}
+        "lfm2-8b-a1b", "tiny-lfm2-moe",
+        # PR 54's by tests/unit/inference/test_ling_hybrid_pool.py
+        "ling-3.0-flash", "tiny-ling"}
 
 
 def test_preset_builds_the_published_sizes():

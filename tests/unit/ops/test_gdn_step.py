@@ -27,7 +27,9 @@ SPANS = {
 }
 
 
-def _operands(shape, dtype, slots=6, seed=0):
+def _operands(shape, dtype, slots=6, seed=0, decay="head"):
+    """``decay`` "channel": a log decay a KEY CHANNEL (Kimi delta attention's
+    bounded gate), spread over (-5, 0) with both ends present."""
     n, dk, dv = shape
     ks = jax.random.split(jax.random.PRNGKey(seed), 6)
     l2 = lambda y: y / jnp.linalg.norm(y, axis=-1, keepdims=True)
@@ -36,6 +38,9 @@ def _operands(shape, dtype, slots=6, seed=0):
     k = l2(jax.random.normal(ks[2], (slots, n, dk)))
     v = jax.random.normal(ks[3], (slots, n, dv))
     g = -2.0 * jax.random.uniform(ks[4], (slots, n))
+    if decay == "channel":
+        g = -5.0 * jax.random.uniform(ks[4], (slots, n, dk)) ** 2
+        g = g.at[:, :, 0].set(-4.999).at[:, :, 1].set(-1e-4)
     beta = 2.0 * jax.random.uniform(ks[5], (slots, n))
     return S, (q, k, v, g, beta)
 
@@ -57,24 +62,29 @@ def _bits(x):
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
-@pytest.mark.parametrize("shape, case", [
-    ("published", "mixed"), ("published", "all_live"),
-    *(("two_64s", case) for case in sorted(SPANS)),
-    ("plain_128", "mixed"), ("plain_128", "all_dead")])
-def test_update_matches_the_definition(shape, case, dtype):
+@pytest.mark.parametrize("shape, case, decay", [
+    ("published", "mixed", "head"), ("published", "all_live", "head"),
+    *(("two_64s", case, "head") for case in sorted(SPANS)),
+    ("plain_128", "mixed", "head"), ("plain_128", "all_dead", "head"),
+    ("two_64s", "mixed", "channel"), ("two_64s", "all_live", "channel"),
+    ("plain_128", "mixed", "channel"), ("plain_128", "last_alone", "channel")])
+def test_update_matches_the_definition(shape, case, decay, dtype):
     """float32 at rest: 1e-5 of the largest value on ``o`` and ``S'``; bf16
     at rest: ``o`` the same, the stored state within one bf16 step of the
     definition's (a float32 reassociation moves a value by 1e-6 of the
     largest before the rounding, and a tie falls either way); a span-0 slot
-    bit for bit."""
+    bit for bit. For a decay a head and a decay a key channel (the same
+    kernel, the channel's decays entering on the rows of ``k`` and ``q`` and
+    as a scale a row of the block)."""
     n, dk, dv = SHAPES[shape]
     slots = 3 if shape == "published" else 6
-    S, column = _operands(SHAPES[shape], dtype, slots)
+    S, column = _operands(SHAPES[shape], dtype, slots, decay=decay)
     live, fresh = _flags(case, slots)
     p = gdn_step.state_packing(n, dv)
     assert p == (1 if shape == "plain_128" else 2)
     leaf = gdn_step.pack_state(S, p)
-    assert gdn_step.tiles(leaf, n, dk, dv) and leaf.shape == (slots, n // p, dk, p * dv)
+    assert gdn_step.tiles(leaf, n, dk, dv, decay == "channel")
+    assert leaf.shape == (slots, n // p, dk, p * dv)
     o, new = jax.jit(gdn_step.gated_delta_update)(leaf, *column, live, fresh)
     assert new.shape == leaf.shape and new.dtype == leaf.dtype and o.dtype == jnp.float32
     new = gdn_step.unpack_state(new, p)

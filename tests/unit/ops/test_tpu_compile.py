@@ -1010,6 +1010,69 @@ def test_lfm2_moe_step_program(for_chip, width, collect):
     assert 7.86e9 + 3.23e9 + mem.temp_size_in_bytes < 15.75 * 2**30, mem
 
 
+@pytest.mark.parametrize("width, collect", [
+    (1, False), (512, False),
+    pytest.param(512, True, marks=pytest.mark.slow)],  # ~40 s more; the chip run compiles it
+    ids=["decode", "chunk", "chunk-collecting"])
+def test_ling_hybrid_step_program(for_chip, width, collect):
+    """Ling-3.0-flash's sync at the published widths as the chip benchmark
+    serves it (the slots of the cell's own file x 4096, ``steps_per_sync`` 4,
+    ``prefill_chunk`` 512, 128 of 512 experts held, a quarter of the
+    vocabulary), the scheduler's own program (``DecodeScheduler._fused_fn``)
+    with a dense layer under a Kimi-delta mixer, an expert layer under one and
+    an expert layer under latent attention: the state leaf (32, 128, 128)
+    beside the latent rows of 576 in one tree; the decode column's state
+    update through the in-place kernel with a decay a key channel; the
+    slots x 8 pairs by the dense product over the 128 held (both widths
+    multiples of 256, 3 rows an expert): no grouped product. It fits with its
+    temporaries beside the cell's 10.46 GB of weights and 2.20 GB of pool."""
+    import json
+    import types
+    from deepspeed_tpu.inference.scheduler import DecodeScheduler
+    sds, _ = for_chip
+    here = os.path.join(os.path.dirname(__file__), "..", "..", "..", "chipbench")
+    with open(os.path.join(here, "workloads", "ling-3.0-flash.serve.reason-closed.json")) as f:
+        serve = json.load(f)["serve"]
+    with open(os.path.join(here, "configs", "ling-3.0-flash.json")) as f:
+        overrides = json.load(f)["overrides"]
+    slots, pool_len, steps = serve["num_slots"], serve["max_len"], serve["steps_per_sync"]
+    assert (pool_len, steps, serve["prefill_chunk"]) == (4096, 4, 512) and 96 <= slots <= 192
+    kinds = ("linear_attention", "linear_attention", "full_attention")
+    model = get_model("ling-3.0-flash", **dict(
+        overrides, dtype=jnp.bfloat16, num_layers=3, layer_types=kinds,
+        moe_swiglu_limits=(0, ) * 3, moe_shared_swiglu_limits=(0, ) * 3,
+        attention_impl="flash"))
+    abstract = lambda tree, dtype=None: jax.tree_util.tree_map(
+        lambda a: sds(a.shape, dtype or a.dtype), tree)
+    params = abstract(jax.eval_shape(model.init_params, jax.random.key(0)), jnp.bfloat16)
+    pool = abstract(jax.eval_shape(lambda: model.init_cache(slots, pool_len)))
+    shapes = sorted({leaf.shape for leaf in jax.tree_util.tree_leaves(pool)})
+    assert shapes == [(slots, 1, 3, 12288), (slots, 1, pool_len, 576), (slots, 32, 128, 128)]
+    mock = types.SimpleNamespace(
+        engine=types.SimpleNamespace(module=model, model_config=model.cfg), _shard_deg=1,
+        _fused_block=False, _moe_stats=True, _moe=True, experts=None, _compiled={},
+        capacity=None, _pool_sharding=None, _state_pool=True,
+        cache=types.SimpleNamespace(num_slots=slots))
+    for name in ("_program", "_jit_step", "_moe_forward_stats", "_held_experts",
+                 "_splits_chunk"):
+        setattr(mock, name, types.MethodType(getattr(DecodeScheduler, name), mock))
+    fn = DecodeScheduler._fused_fn(mock, False, collect, steps, width)
+    i32 = lambda *shape: sds(shape, jnp.int32)
+    args = (params, pool, i32(slots, width), i32(slots), i32(slots), sds((slots, ), jnp.uint32),
+            i32(slots), sds((slots, ), jnp.bool_), sds((slots, ), jnp.float32), i32(slots),
+            sds((slots, ), jnp.float32), i32(slots))
+    compiled = fn.lower(*args).compile()
+    text = compiled.as_text()
+    assert "dstpu_gdn_step" in text  # (a chunk's sync ends in decode substeps)
+    assert "ragged-dot" not in text and "moe_experts" in text
+    for scope in ("gdn_proj", "gdn_state", "gdn_out", "mla_proj", "mla_attn", "moe_router"):
+        assert scope in text, scope
+    assert _pool_relayouts(text, f"[{slots},32,128,128]") == (0, 0)
+    mem = compiled.memory_analysis()
+    print(width, collect, "temporaries", mem.temp_size_in_bytes)
+    assert 10.46e9 + 2.20e9 * slots / 192 + mem.temp_size_in_bytes < 15.75 * 2**30, mem
+
+
 def _accepted_cell_syncs():
     """(cell, model one period deep, slots, chunk, pool length, fused) of the
     serving cells the benchmark had before PR 39, as their tests above size

@@ -129,6 +129,11 @@ def test_scan_vs_unrolled():
 def test_presets_resolve():
     for name in available_models():
         from deepspeed_tpu.models import _PRESETS
+        if name == "ling-3.0-flash":
+            # refused WHOLE by name (its top layers' clamped activations), served cut
+            with pytest.raises(ValueError, match="expert_swiglu_limit_list"):
+                _PRESETS[name]()
+            continue
         cfg = _PRESETS[name]()
         assert cfg.num_params() > 0
     # spot-check published sizes
