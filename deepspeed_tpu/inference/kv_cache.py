@@ -49,6 +49,13 @@ import numpy as np
 
 import jax
 
+# The kinds of leaf (``cache_spec``) that hold one entry a position and so grow
+# with a slot's length: rows (positions at ``ndim - 2``) and columns (positions
+# last). A ring's rows are positions mod its length and a state has none:
+# per-slot bytes, both. Nothing here slices by position: a slot is sliced,
+# updated and copied whole, by its slot axis.
+PER_POSITION_KINDS = ("rows", "columns")
+
 
 class SlotKVCache:
     """Fixed pool of KV cache slots + free-list allocation with three slot
@@ -69,7 +76,9 @@ class SlotKVCache:
 
     ``kinds``: the model's declaration of what each leaf holds
     (``model.cache_kinds()``, a tree of ``pool``'s structure): ``"rows"``
-    (a row axis at ``ndim - 2``, one row a position) or ``"state"`` (per
+    (a row axis at ``ndim - 2``, one row a position) or ``"columns"`` (the
+    position axis last, one column a position: a latent layer's leaf, which
+    grows with a slot's length as rows do) or ``"state"`` (per
     slot, no row axis: a linear-attention or Mamba layer's recurrent state
     and convolution window) or ``"ring"`` (a windowed layer's K and V: a row
     axis at ``ndim - 2`` of a fixed number of rows whatever ``max_len`` is,
@@ -386,16 +395,18 @@ class SlotKVCache:
 
     def bytes_per_token(self):
         """HBM bytes backing ONE cache row (all layers, K+V, and — on the
-        int8 tier — the per-token scale leaves): every ROW leaf keeps its
-        slot and row axes, so per-row bytes fall out of leaf sizes
-        generically for the plain and quantized layouts, split or packed
-        (the packed leaf holds the split pair's bytes). State and ring leaves
+        int8 tier — the per-token scale leaves): every leaf of rows or of
+        columns keeps its slot axis and its position axis, wherever the
+        latter is, so per-position bytes fall out of leaf sizes
+        generically for the plain and quantized layouts, split, packed (the
+        packed leaf holds the split pair's bytes) or latent (a position a
+        column: the same bytes). State and ring leaves
         (:meth:`state_bytes_per_slot`, :meth:`window_bytes_per_slot`) do not
         count: they do not grow with a slot's length. 0 when the
         pool is host-bookkeeping-only (tests)."""
         denom = self.num_slots * self.max_len
         return int(sum((leaf.size // denom) * leaf.dtype.itemsize
-                       for leaf in self._leaves("rows")))
+                       for kind in PER_POSITION_KINDS for leaf in self._leaves(kind)))
 
     def _leaves(self, kind):
         """The pool's leaves the model declared as ``kind``."""
@@ -489,9 +500,9 @@ def slot_slice(pool, slot):
     single-request prefill program. Works on both layouts — stacked leaves
     are (L, N, kv, S, lanes) (slot axis 1), per-layer leaves (N, kv, S,
     lanes) (slot axis 0) — and on every geometry of ``init_cache`` (split
-    K and V leaves, the packed K/V leaf, the latent leaf, the int8 tier's
-    scale leaf, a linear-attention layer's state and window): the slot axis
-    is at ``ndim - 4`` in all of them."""
+    K and V leaves, the packed K/V leaf, the position-last latent leaf, the
+    int8 tier's scale leaf, a linear-attention layer's state and window):
+    the slot axis is at ``ndim - 4`` in all of them."""
     return jax.tree_util.tree_map(
         lambda c: jax.lax.dynamic_slice_in_dim(c, slot, 1, axis=c.ndim - 4), pool)
 

@@ -1072,9 +1072,13 @@ def _commit_span_rows(writes, write_index, q_spans, paged_kernels):
     on one device. Then the K/V leaves (the leading writes of one shape)
     commit through the in-place kernel (``ops/pallas/kv_commit.py``), which
     takes the pool row-major as those kernels do, so the compiler has no
-    layout to convert between; elsewhere (the XLA attention fallback, the
-    latent pool, a tensor-parallel pool, leaves the kernel does not tile,
-    the kilobyte scale leaf) the scatter stays. Both leave the same bytes.
+    layout to convert between; elsewhere (the XLA attention fallback, a
+    tensor-parallel pool, leaves the kernel does not tile, the kilobyte
+    scale leaf) the scatter stays. Both leave the same bytes. The latent
+    leaf is not a leaf of rows and does not come here: its columns commit
+    through :func:`_commit_span_columns`, which shares this function's
+    semantics and none of its code (rows row-major for the paged kernels,
+    positions minor for the latent walk: the two layouts conflict).
     The choice is tallied per trace for the scheduler's
     ``serving/kv_commit_*_programs`` counters."""
     from ..ops.pallas import kv_commit
@@ -1093,6 +1097,35 @@ def _commit_span_rows(writes, write_index, q_spans, paged_kernels):
             write_index, q_spans)) if in_place else []
         written += [jax.vmap(upd)(c, kk, tgt) for c, kk in writes[len(written):]]
     return written
+
+
+def _commit_span_columns(pool, fresh, write_index, q_spans):
+    """:func:`_commit_span_rows` for the latent leaf, which rests
+    position-last (``cache_spec``'s ``"columns"``): ``pool`` ``(B, 1, D, S)``,
+    ``fresh`` ``(B, 1, T, D)`` as the projections make it. The same bytes
+    land: column ``j`` of row ``i`` at position ``write_index_i + j``;
+    columns past the row's span and positions past ``S`` are dropped; a slot
+    with span 0 is not touched. Through the in-place column kernel
+    (``ops/pallas/kv_commit.py: commit_kv_columns``) wherever it tiles the
+    leaf (``S`` whole 128-position blocks; the engine serves latent attention
+    on one device), tallied ``in_place``:
+    an XLA scatter wants the window it writes (one position's ``D`` values)
+    minor whichever way the leaf is shaped, and the compiler then relays the
+    whole leaf around every commit and once more for every forward's block
+    walk (ISSUE 55: six moves of the leaf a four-step sync). Elsewhere the
+    scatter stays, tallied as such."""
+    from ..ops.pallas import kv_commit
+    in_place = kv_commit.commits_columns_in_place(pool)
+    kv_commit.tally(in_place)
+    with jax.named_scope("kv_commit"):
+        if in_place:
+            return kv_commit.commit_kv_columns(pool, fresh, write_index, q_spans)
+        T, S = fresh.shape[2], pool.shape[3]
+        tgt = write_index[:, None] + jnp.arange(T)[None, :]
+        tgt = jnp.where(jnp.arange(T)[None, :] < q_spans[:, None], tgt, S)
+        upd = lambda c, kk, i: c.at[:, :, i].set(
+            jnp.swapaxes(kk, 1, 2).astype(c.dtype), mode="drop")
+        return jax.vmap(upd)(pool, fresh, tgt)
 
 
 def _tp_replicate(x):
@@ -1770,19 +1803,22 @@ def _latent_attention_xla(qf, lat, qpos, live_end, key_mask, score_scale, *, ran
     """Absorbed latent attention against a latent cache, in XLA.
 
     ``qf``: (B, nh, T, rank + rope) absorbed queries ``[q_nope W_kvb^K ;
-    RoPE(q_rope)]``; ``lat``: (B, S, rank + rope) cache rows ``[c_kv ; k_r]``,
-    one for all heads; ``qpos``: (B or 1, T) absolute position of each query
-    (its causal end); ``live_end``: one past the last position any live query
-    attends; ``key_mask``: optional (B, S) attendable rows;
-    ``score_scale``: (B or 1, T) fp32 ``scale * g(t)``. Returns
-    ``sum_s p_s c_kv,s``: (B, nh, T, rank), for the value up-projection.
+    RoPE(q_rope)]``; ``lat``: (B, rank + rope, S) the cache as it rests, a
+    position a column ``[c_kv ; k_r]``, one for all heads; ``qpos``: (B or 1,
+    T) absolute position of each query (its causal end); ``live_end``: one
+    past the last position any live query attends; ``key_mask``: optional
+    (B, S) attendable positions; ``score_scale``: (B or 1, T) fp32 ``scale *
+    g(t)``. Returns ``sum_s p_s c_kv,s``: (B, nh, T, rank), for the value
+    up-projection.
 
     An online softmax over key blocks, walked to the batch's longest live
     row only (a masked block leaves every accumulator bit-unchanged, so a
     row's result does not depend on how far its neighbours reach). The block
-    narrows for wide spans so that one score plane stays near 256 MB."""
+    narrows for wide spans so that one score plane stays near 256 MB. Both
+    products take a block ``(B, D, blk)`` with its positions minor, the form
+    the leaf rests in: nothing is transposed for them."""
     B, nh, T, _ = qf.shape
-    S = lat.shape[1]
+    S = lat.shape[2]
     blk = min(block_kv, S)
     while blk > 64 and B * nh * T * blk > (1 << 26) and S % (blk // 2) == 0:
         blk //= 2
@@ -1792,8 +1828,8 @@ def _latent_attention_xla(qf, lat, qpos, live_end, key_mask, score_scale, *, ran
 
     def body(j, carry):
         m, l, acc = carry
-        kb = jax.lax.dynamic_slice_in_dim(lat, j * blk, blk, axis=1)  # (B, blk, D)
-        s = jnp.einsum("bntd,bsd->bnts", qf, kb, preferred_element_type=jnp.float32)
+        kb = jax.lax.dynamic_slice_in_dim(lat, j * blk, blk, axis=2)  # (B, D, blk)
+        s = jnp.einsum("bntd,bds->bnts", qf, kb, preferred_element_type=jnp.float32)
         s = s * score_scale[:, None, :, None]
         kpos = j * blk + jnp.arange(blk)
         keep = kpos[None, None, :] <= qpos[:, :, None]  # (B or 1, T, blk)
@@ -1803,7 +1839,7 @@ def _latent_attention_xla(qf, lat, qpos, live_end, key_mask, score_scale, *, ran
         m_new = jnp.maximum(m, jnp.max(s, axis=-1))
         p = jnp.exp(s - m_new[..., None])
         alpha = jnp.exp(m - m_new)
-        pv = jnp.einsum("bnts,bsr->bntr", p.astype(dtype), kb[..., :rank],
+        pv = jnp.einsum("bnts,brs->bntr", p.astype(dtype), kb[:, :rank],
                         preferred_element_type=jnp.float32)
         return m_new, l * alpha + jnp.sum(p, axis=-1), acc * alpha[..., None] + pv
 
@@ -1815,10 +1851,14 @@ def _latent_attention_xla(qf, lat, qpos, live_end, key_mask, score_scale, *, ran
 
 class LatentAttention(nn.Module):
     """Multi-head latent attention (MLA; DeepSeek-V2, arXiv:2405.04434, as
-    ``mistral4`` configures it). Per position the cache holds ONE row of
+    ``mistral4`` configures it). Per position the cache holds ONE vector of
     ``kv_lora_rank + qk_rope_head_dim`` values for all heads: the normalised
     kv latent ``c_kv`` and the rotated shared key part ``k_r``; per-head K
-    and V are never stored.
+    and V are never stored. At rest it is a COLUMN of the layer's leaf ``(B,
+    1, rank + rope, S)``: the absorbed form's two products contract and
+    produce over a block's positions, and take them minor; the span commit
+    sets columns in place (:func:`_commit_span_columns`), so a sync carries
+    the leaf in one form from entry to exit.
 
         c_q = RMSNorm(a W_qa) ; q_i = c_q W_qb,i = [q_nope_i ; q_rope_i]
         [c_kv ; k_r] = a W_kva ; c_kv = RMSNorm(c_kv) ; k_r = RoPE(k_r)
@@ -1835,7 +1875,7 @@ class LatentAttention(nn.Module):
 
     Without a cache (full forward) the EXPANDED form computes per-head K
     and V from ``c_kv``. With a cache (static generate, slot-pool decode and
-    chunked-prefill spans alike) the ABSORBED form attends the latent rows
+    chunked-prefill spans alike) the ABSORBED form attends the latent columns
     directly: ``q~_i = q_nope_i W_kvb,i^K^T`` against ``c_kv``, and ``o_i =
     (sum p c_kv) W_kvb,i^V``. The call signature is :class:`Attention`'s;
     adapters, extent chains and sequence-parallel spans are refused."""
@@ -1907,16 +1947,18 @@ class LatentAttention(nn.Module):
                 out = jnp.einsum("bnqk,bnkd->bnqd", probs, kv[..., nope:])
             new_cache = None
         else:
-            pool = kv_cache[0]  # (B, 1, S, rank + rope); a wider tree's other places hold nothing
+            # (B, 1, rank + rope, S), a position a column; a wider tree's
+            # other places hold nothing
+            pool = kv_cache[0]
             fresh = jnp.concatenate([c_kv[:, None], k_r], axis=-1).astype(pool.dtype)
             if write_index is not None and q_spans is not None:
-                (pool, ) = _commit_span_rows([(pool, fresh)], write_index, q_spans,
-                                             paged_kernels=False)
+                pool = _commit_span_columns(pool, fresh, write_index, q_spans)
             elif write_index is not None:
                 pool = jax.vmap(lambda c, r, i: jax.lax.dynamic_update_slice_in_dim(
-                    c, r, i, axis=1))(pool, fresh, write_index)
+                    c, r, i, axis=2))(pool, jnp.swapaxes(fresh, 2, 3), write_index)
             else:
-                pool = jax.lax.dynamic_update_slice_in_dim(pool, fresh, cache_index, axis=2)
+                pool = jax.lax.dynamic_update_slice_in_dim(
+                    pool, jnp.swapaxes(fresh, 2, 3), cache_index, axis=3)
             with jax.named_scope("mla_attn"):
                 q_lat = jnp.einsum("bntd,rnd->bntr", q[..., :nope], w_kvb[..., :nope])
                 qf = jnp.concatenate([q_lat, q_rope], axis=-1)  # (B, nh, T, rank + rope)
@@ -3233,8 +3275,16 @@ class CausalLMModel:
           ``[0, head_size)`` and its value after it: the tree ``(kv
           leaves, )``. The same bytes at rest, in the row-major form the
           kernels read;
-        - **latent** (``kv_lora_rank > 0``): ONE leaf a layer, ``(B, 1, S,
-          latent_width)``: the tree ``(latent leaves, )``.
+        - **latent** (``kv_lora_rank > 0``): ONE leaf a layer, ``(B, 1,
+          latent_width, S)``: the tree ``(latent leaves, )``. POSITION-LAST:
+          a position is a column of ``latent_width`` values, because both of
+          the block walk's products read a block with its positions minor
+          (:func:`_latent_attention_xla`) and the column commit writes it in
+          place (:func:`_commit_span_columns`). The same bytes a token as
+          ``(B, 1, S, latent_width)``; in that shape the span write is an
+          XLA scatter that wants its window (a position's values) minor
+          where the walk wants the positions, and a four-step sync moves
+          the whole leaf six times between the two (ISSUE 55).
 
         Scanned models carry each component stacked ``(L, ...)``; unrolled
         models carry per-layer tuples — separate tensors alias IN-PLACE
@@ -3247,8 +3297,10 @@ class CausalLMModel:
         S, 1), shared by K and V across every head (``(k, v, scale)`` split,
         ``(kv, scale)`` packed). Scales init to 1 (rows past each slot's end
         are never attended), and every leaf keeps its batch/slot axis at
-        ``ndim - 4`` and its row axis at ``ndim - 2``, so the slot pool's
-        slice/update/copy programs treat every geometry uniformly.
+        ``ndim - 4``, so the slot pool's slice/update/copy programs treat
+        every geometry uniformly; the position axis is at ``ndim - 2`` of a
+        leaf of rows and at ``ndim - 1`` of a leaf of columns, as
+        :meth:`cache_spec` declares it.
 
         A ``linear_attention`` layer (``layer_types``) holds no rows: in the
         same two places of the tree it carries its recurrent state and its
@@ -3276,7 +3328,10 @@ class CausalLMModel:
         """What a slot holds, as each layer declares it: for every layer a
         tuple of ``(kind, shape, dtype, fill)`` components, ``kind`` being
         ``"rows"`` (a row axis at ``ndim - 2``, one row a position: K, V,
-        the packed pair, the latent row, the int8 tier's scales) or
+        the packed pair, the int8 tier's scales) or ``"columns"`` (the
+        position axis LAST, one column a position: a latent layer's ``(B, 1,
+        latent_width, S)``, the form its readers take and its commit writes
+        in place; it grows with a slot's length as rows do) or
         ``"state"`` (per-slot, no row axis: a linear-attention layer's
         recurrent state ``(B, n / p, dk, p dv)``, ``p`` heads side by side in
         the lanes so that a row is whole 128-lane tiles (``gdn_step.
@@ -3303,11 +3358,12 @@ class CausalLMModel:
             # latent geometry: ONE leaf a layer, a single "head" of
             # kv_lora_rank + qk_rope_head_dim values a position (normalised
             # c_kv and rotated k_r), never expanded to per-head K and V at
-            # rest. The slot axis stays at ndim - 4, so slot_slice /
-            # slot_update / copy_slot and the radix copy take it as it is.
+            # rest, a position a COLUMN. The slot axis stays at ndim - 4, so
+            # slot_slice / slot_update / copy_slot and the radix copy take
+            # it as it is.
             if quantized:
                 raise NotImplementedError("the latent KV pool has no int8 tier")
-            rows = [("rows", (batch_size, 1, max_len, cfg.latent_width), dt, jnp.zeros)]
+            rows = [("columns", (batch_size, 1, cfg.latent_width, max_len), dt, jnp.zeros)]
         else:
             packed = kv_packs(cfg.head_size)
             shape = (batch_size, cfg.kv_heads, max_len,
@@ -3370,9 +3426,9 @@ class CausalLMModel:
                 for i, (m, w) in enumerate(zip(mixers, windows))] + module
 
     def cache_kinds(self):
-        """``"rows"``, ``"ring"`` or ``"state"`` for every leaf of
-        :meth:`init_cache`'s tree, in a tree of its structure (sizes do not
-        enter)."""
+        """``"rows"``, ``"columns"``, ``"ring"`` or ``"state"`` for every leaf
+        of :meth:`init_cache`'s tree, in a tree of its structure (sizes do
+        not enter)."""
         spec = self.cache_spec(1, 1)
         if self.cfg.scan_layers:
             return tuple(kind for kind, *_ in spec[0])

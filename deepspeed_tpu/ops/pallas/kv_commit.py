@@ -27,6 +27,11 @@ the same bytes again. A slot with span 0 rewrites one block unchanged.
 For ``C > 1`` the fresh rows are shifted to the block's rows through an f32
 staging buffer (an unaligned dynamic slice of sublanes is a 32-bit
 operation on the chip); bf16 and int8 values pass through f32 unchanged.
+
+The latent leaf rests position-last, ``(N, 1, D, S)``, and has a kernel of
+its own at the end of this file (:func:`commit_kv_columns`): the same write,
+a position a column, 128 positions a lane block. The two share the rule that
+picks a slot's blocks and the tally, nothing else.
 """
 
 import functools
@@ -200,3 +205,113 @@ def _commit(leaves, new_rows, write_index, q_spans, *, interpret):
         interpret=interpret,
     )(write_index.astype(jnp.int32), q_spans.astype(jnp.int32),
       *new_rows, *leaves))
+
+
+# ------------------------------------------------------- the latent columns
+LANES = 128  # positions of one lane block: what a column commit moves
+
+
+def _fresh_offset(i, wi, block, cols):
+    """Where, in the flat fresh columns (``LANES`` of padding, then slot
+    ``i``'s column ``c`` at ``i * cols + c``), the column of lane 0 of the
+    slot's lane block ``block`` stands: the block is the write head's or a
+    later one, so the padding covers it (but for a head past the pool, which
+    writes nothing: held at 0)."""
+    return jnp.maximum(LANES + i * cols + block * LANES - wi, 0)
+
+
+def _column_kernel(wi_ref, span_ref, lo_ref, hi_ref, pool_ref, out_ref, *, cols, n_blocks):
+    """``lo_ref``, ``hi_ref``: the two aligned lane blocks of the flat fresh
+    columns that the pool block's 128 columns straddle."""
+    i = pl.program_id(0)
+    wi = wi_ref[i]
+    span = span_ref[i]
+    block = _row_block(wi, span, pl.program_id(1), LANES, n_blocks)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
+    pos = block * LANES + lane
+    live = (pos >= wi) & (pos < wi + span)
+    # lane l takes flat column off + l: both blocks turned left by off mod 128
+    # (a rotation of lanes is a 32-bit operation; bf16 passes through f32
+    # unchanged), the low block's tail then the high block's head
+    head = LANES - _fresh_offset(i, wi, block, cols) % LANES  # lanes the low block fills
+    turned = lambda ref: pltpu.roll(ref[...].astype(jnp.float32), head % LANES, 1)
+    fresh = jnp.where(lane < head, turned(lo_ref), turned(hi_ref))
+    out_ref[0, 0] = jnp.where(live, fresh.astype(out_ref.dtype), pool_ref[0, 0])
+
+
+def commit_kv_columns(leaf, new_rows, write_index, q_spans):
+    """:func:`commit_kv_rows` for a leaf that rests POSITION-LAST, the latent
+    leaf ``(N, 1, D, S)`` (``CausalLMModel.cache_spec``'s ``"columns"``): a
+    position is a column of ``D`` values down the sublanes, and ``S`` is a
+    whole number of 128-lane blocks. ``new_rows``: ``(N, 1, C, D)``, as the
+    projections make them. Row ``i``'s column ``j`` lands at position
+    ``write_index[i] + j`` when ``j < q_spans[i]`` and that position is ``<
+    S``; every other byte stays, a dead slot's (span 0) and a retained
+    prefix's among them: what ``.at[:, :, t].set(..., mode="drop")`` leaves,
+    byte for byte, without the layout that scatter wants the whole leaf in.
+
+    Kernel shape: grid ``(slots, lane blocks a span of C can straddle)``; a
+    step reads the 128-column block under the write head (or a later one,
+    held at the last block with a live target as the row kernel holds its
+    own), sets the live lanes and writes it back through the alias. The
+    fresh columns come transposed and flat, ``(D, 128 + N * C)`` and some
+    padding: a block's 128 columns are 128 consecutive flat ones at an
+    offset that is no multiple of 128, so a step takes the two aligned
+    blocks around it and turns them."""
+    return _commit_columns(leaf, new_rows, write_index, q_spans, interpret=_pallas.interpret())
+
+
+def commits_columns_in_place(leaf):
+    """Whether a position-last leaf is one :func:`commit_kv_columns` writes."""
+    return (leaf.ndim == 4 and leaf.shape[1] == 1 and leaf.shape[3] % LANES == 0
+            and jnp.dtype(leaf.dtype).itemsize in (2, 4))
+
+
+@functools.partial(jax.jit, static_argnames="interpret")
+def _commit_columns(leaf, new_rows, write_index, q_spans, *, interpret):
+    N, _, D, S = leaf.shape
+    C = new_rows.shape[2]
+    if not commits_columns_in_place(leaf) or new_rows.shape != (N, 1, C, D):
+        raise ValueError(f"kv commit: the leaf {leaf.shape} is not (slots, 1, width, whole "
+                         f"{LANES}-position blocks), or the fresh rows {new_rows.shape} are "
+                         f"not its columns")
+    n_blocks = S // LANES
+    steps = (C - 2) // LANES + 2  # lane blocks a span of C can straddle
+    flat = new_rows.astype(leaf.dtype).reshape(N * C, D).T
+    flat = jnp.pad(flat, ((0, 0), (LANES, _pad(N * C, LANES) - N * C + LANES)))
+    last = flat.shape[1] // LANES - 2
+
+    def block_of(i, j, wi_r, span_r):
+        return _row_block(wi_r[i], span_r[i], j, LANES, n_blocks)
+
+    def fresh_index(side):
+        def index(i, j, wi_r, span_r):
+            off = _fresh_offset(i, wi_r[i], block_of(i, j, wi_r, span_r), C)
+            return (0, jnp.minimum(off // LANES, last) + side)
+        return index
+
+    def pool_index(i, j, wi_r, span_r):
+        return (i, 0, 0, block_of(i, j, wi_r, span_r))
+
+    pool_spec = pl.BlockSpec((1, 1, D, LANES), pool_index)
+    # the result is HBM's: a leaf that fits VMEM (cell 4's 84 MB a layer) is
+    # otherwise parked there around the steps' loop, and moved out and in
+    # again every step, whole (3.5% of cell 4's window: PERF.md, PR 55)
+    pool_shape = pltpu.HBM(leaf.shape, leaf.dtype)
+    return pl.pallas_call(
+        functools.partial(_column_kernel, cols=C, n_blocks=n_blocks),
+        name="dstpu_kv_commit_columns",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(N, steps),
+            in_specs=[pl.BlockSpec((D, LANES), fresh_index(0)),
+                      pl.BlockSpec((D, LANES), fresh_index(1)), pool_spec],
+            out_specs=pool_spec,
+        ),
+        out_shape=pool_shape,
+        input_output_aliases={4: 0},  # operand numbers count the two scalar-prefetch operands
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_pallas.VMEM_LIMIT_BYTES),
+        interpret=interpret,
+    )(write_index.astype(jnp.int32), q_spans.astype(jnp.int32), flat, flat, leaf)

@@ -354,18 +354,21 @@ def test_preset_builds_the_published_sizes():
     ("gpt2-large", True, [(2, 20, 64, 128)] * 36, "packed"),
     ("llama2-7b", False, [(32, 2, 32, 64, 128)] * 2, "split"),
     ("llama2-7b", True, [(2, 32, 64, 128)] * 64, "split"),
-    ("mistral-small-4-119b", False, [(36, 2, 1, 64, 320)], "latent"),
-    ("mistral-small-4-119b", True, [(2, 1, 64, 320)] * 36, "latent"),
+    ("mistral-small-4-119b", False, [(36, 2, 1, 320, 64)], "latent"),
+    ("mistral-small-4-119b", True, [(2, 1, 320, 64)] * 36, "latent"),
 ])
 def test_models_without_layer_types_build_the_cache_they_built(name, unrolled, leaves, geometry):
     """``init_cache``'s leaves and ``kv_pool_geometry`` as the parent commit
     (f7774de) built them, every leaf declared as rows; the int8 tier keeps
-    its scale leaf last."""
+    its scale leaf last. But the latent leaf, position-last since PR 55 and
+    declared as columns: a position's 320 values down a column, the same
+    bytes a token."""
     model = get_model(name, scan_layers=not unrolled)
     pool = jax.eval_shape(lambda: model.init_cache(2, 64))
     assert [leaf.shape for leaf in jax.tree_util.tree_leaves(pool)] == leaves
     assert tfm.kv_pool_geometry(model.cfg, pool) == geometry
-    assert set(jax.tree_util.tree_leaves(model.cache_kinds())) == {"rows"}
+    assert set(jax.tree_util.tree_leaves(model.cache_kinds())) == {
+        "columns" if geometry == "latent" else "rows"}
     assert (jax.tree_util.tree_structure(model.cache_kinds())
             == jax.tree_util.tree_structure(pool))
     if geometry != "latent":

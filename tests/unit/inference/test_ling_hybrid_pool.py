@@ -308,16 +308,16 @@ def test_what_each_layer_kind_declares(tiny):
     model, _ = tiny
     spec = model.cache_spec(3, 32)
     assert [[k for k, *_ in layer] for layer in spec] == [
-        ["state", "state"] if t == "linear_attention" else ["rows"]
+        ["state", "state"] if t == "linear_attention" else ["columns"]
         for t in model.cfg.layer_types]
     kinds = model.cache_kinds()
-    assert kinds[1][2] is None and kinds[1][6] is None and kinds[0][2] == "rows"
-    # the cut the benchmark serves: a state (32, 128, 128), a window of 3, a row of 576
+    assert kinds[1][2] is None and kinds[1][6] is None and kinds[0][2] == "columns"
+    # the cut the benchmark serves: a state (32, 128, 128), a window of 3, a column of 576
     served = get_model("ling-3.0-flash", **CUT)
     spec = served.cache_spec(192, 4096, dtype=jnp.bfloat16)
     shapes = [[shape for _, shape, *_ in layer] for layer in spec]
     assert shapes[0] == [(192, 32, 128, 128), (192, 1, 3, 12288)] and shapes[5] == [
-        (192, 1, 4096, 576)]
+        (192, 1, 576, 4096)]
     per_slot = sum(2 * int(np.prod(s[1:])) for layer in shapes for s in layer if len(layer) == 2)
     assert per_slot == 6_733_824 and 2 * 576 == 1_152
     assert served.cfg.num_params() == 5_231_790_016 == sum(
@@ -352,6 +352,42 @@ def test_what_a_pool_of_state_and_latent_rows_refuses(tiny, cb, message):
     applies is named."""
     with pytest.raises((ValueError, NotImplementedError), match=message):
         _engine(tiny, **cb).scheduler()
+
+@pytest.mark.parametrize("chunk", [1, 8], ids=["column", "chunk"])
+def test_state_beside_latent_columns_through_a_sync_of_each_width(tiny, monkeypatch, chunk):
+    """Cell 10's tree (a state leaf and a window beside a latent leaf of
+    columns) through the scheduler's own syncs, the decode column's and a
+    chunk's with its decode substeps: after every landing the pool the
+    in-place column commit leaves is, leaf for leaf and byte for byte, the
+    pool the XLA scatter leaves, and so are the logits; the programs are
+    tallied by the commit they were built with."""
+    from deepspeed_tpu.ops.pallas import kv_commit
+    prompts = _prompts((13, 5) if chunk > 1 else (3, 2))
+
+    def serve(in_place):
+        with monkeypatch.context() as mp:
+            if not in_place:
+                mp.setattr(kv_commit, "commits_columns_in_place", lambda leaf: False)
+            sched = _engine(tiny, slots=3, chunk=chunk, steps=4).scheduler()
+            handles = [sched.submit(p, max_new_tokens=6, collect_logits=True) for p in prompts]
+            pools = []
+            while not all(h.done for h in handles):
+                sched.step()
+                pools.append(jax.tree_util.tree_map(np.asarray, sched.cache.pool))
+            sched.drain()
+        return sched, pools, [h.result_logits() for h in handles]
+
+    kernel, pools, logits = serve(True)
+    scatter, pools_scatter, logits_scatter = serve(False)
+    assert set(kernel.cache.leaf_kinds) == {"state", "columns"}
+    assert kernel.kv_commit_programs["scatter"] == 0 < kernel.kv_commit_programs["inplace"]
+    assert scatter.kv_commit_programs["inplace"] == 0 < scatter.kv_commit_programs["scatter"]
+    assert len(pools) == len(pools_scatter) > 2
+    for a, b in zip(pools, pools_scatter):
+        for x, y in zip(jax.tree_util.tree_leaves(a), jax.tree_util.tree_leaves(b)):
+            np.testing.assert_array_equal(x.view(np.uint8), y.view(np.uint8))
+    for a, b in zip(logits, logits_scatter):
+        assert np.array_equal(a, b)
 
 
 # (name, digest of the parameter tree's (path, shape, dtype), num_params) of

@@ -227,7 +227,9 @@ def test_latent_pool_bytes_copy_and_radix_hit(tiny):
     bf16 = _engine(params=params, kv_cache_dtype="bfloat16").scheduler()
     assert bf16.cache.bytes_per_token() == cfg.num_layers * cfg.latent_width * 2
     leaves = jax.tree_util.tree_leaves(sched.cache.pool)
-    assert len(leaves) == 1 and leaves[0].shape[-3:] == (1, sched.cache.max_len, cfg.latent_width)
+    # position-last: a position is a column (the same bytes a token as rows of 24)
+    assert len(leaves) == 1 and leaves[0].shape[-3:] == (1, cfg.latent_width, sched.cache.max_len)
+    assert set(sched.cache.leaf_kinds) == {"columns"}
     prompt = [int(t) for t in np.random.default_rng(2).integers(0, 256, 40)]
     first = sched.submit(prompt, max_new_tokens=6, collect_logits=True)
     a, la = first.result(), first.result_logits()
@@ -238,6 +240,81 @@ def test_latent_pool_bytes_copy_and_radix_hit(tiny):
     pool = copy_slot(sched.cache.pool, 0, 3)
     leaf = jax.tree_util.tree_leaves(pool)[0]
     assert np.array_equal(leaf[:, 3], leaf[:, 0]) and float(jnp.abs(leaf[:, 0]).max()) > 0
+
+
+def _latent_bits(sched):
+    """The latent leaf (scanned: layers, slots, 1, width, positions) as bytes."""
+    return np.asarray(jax.tree_util.tree_leaves(sched.cache.pool)[0].view(jnp.uint8))
+
+
+def test_latent_columns_commit_in_place_and_a_retained_prefix_stays_byte_stable(tiny):
+    """The scheduler's programs write the latent columns through the in-place
+    kernel (128 positions: one lane block; ``scatter`` 0); a finished request's
+    slot is retained for the radix cache and not a byte of it moves while two
+    other requests prefill and decode beside it (it rides every sync with
+    span 0)."""
+    _, params, _, _ = tiny
+    sched = _engine(params=params).scheduler()
+    rng = np.random.default_rng(5)
+    kept = [int(t) for t in rng.integers(0, 256, 40)]
+    sched.submit(kept, max_new_tokens=4).result()
+    sched.drain()
+    slot = next(i for i, st in enumerate(sched.cache.state) if st == "cached")
+    before = _latent_bits(sched)[:, slot]
+    assert before.any()
+    for n in (21, 9):
+        sched.submit([int(t) for t in rng.integers(0, 256, n)], max_new_tokens=10)
+    sched.drain()
+    assert sched.cache.state[slot] == "cached"
+    np.testing.assert_array_equal(_latent_bits(sched)[:, slot], before)
+    assert sched.kv_commit_programs["inplace"] > 0 and sched.kv_commit_programs["scatter"] == 0
+
+
+def test_a_reused_slot_serves_from_position_zero_whatever_columns_it_held(tiny):
+    """A slot's reset on a latent leaf is its write head back at 0: the
+    columns a longer request left behind the new one's head are never
+    attended, and the new request's logits are a fresh pool's, bit for bit."""
+    _, params, _, _ = tiny
+    rng = np.random.default_rng(6)
+    long_, short = ([int(t) for t in rng.integers(0, 256, n)] for n in (70, 12))
+    used = _engine(params=params, num_slots=1, prefix_cache=False).scheduler()
+    used.submit(long_, max_new_tokens=20).result()
+    h = used.submit(short, max_new_tokens=8, collect_logits=True)
+    fresh = _engine(params=params, num_slots=1, prefix_cache=False).scheduler()
+    g = fresh.submit(short, max_new_tokens=8, collect_logits=True)
+    assert list(h.result()) == list(g.result())
+    assert np.array_equal(h.result_logits(), g.result_logits())
+    held, clean = _latent_bits(used)[:, 0], _latent_bits(fresh)[:, 0]
+    live = 4 * (len(short) + 8 - 1)  # bytes a channel; the last token's column is never written
+    np.testing.assert_array_equal(held[..., :live], clean[..., :live])
+    # (a pump that runs ahead may have written a sync's columns past the end)
+    assert held[..., live + 32:].any() and not clean[..., live + 32:].any()
+
+
+@pytest.mark.parametrize("T, masked", [(1, False), (5, True), (24, False)],
+                         ids=["column", "span-masked", "chunk"])
+def test_block_walk_on_the_position_last_leaf_matches_the_plain_softmax(T, masked):
+    """``_latent_attention_xla`` over ``(B, D, S)`` (positions last, a walk of
+    64-position blocks to the longest live row) against one softmax over
+    every position, float32: same scores, same masks, same values."""
+    from deepspeed_tpu.models.transformer import _latent_attention_xla
+    B, nh, D, rank, S = 3, 4, 24, 16, 256
+    ks = jax.random.split(jax.random.key(T), 2)
+    qf = jax.random.normal(ks[0], (B, nh, T, D), jnp.float32)
+    lat = jax.random.normal(ks[1], (B, D, S), jnp.float32)
+    heads = jnp.asarray([0, 77, S - T])
+    qpos = heads[:, None] + jnp.arange(T)[None]
+    key_mask = (jnp.arange(S)[None] >= jnp.asarray([0, 3, 130])[:, None]) if masked else None
+    scale = jnp.full((B, T), 0.2, jnp.float32)
+    got = _latent_attention_xla(qf, lat, qpos, jnp.max(qpos) + 1, key_mask, scale,
+                                rank=rank, block_kv=64, dtype=jnp.float32)
+    s = jnp.einsum("bntd,bds->bnts", qf, lat) * 0.2
+    keep = jnp.arange(S)[None, None] <= qpos[:, :, None]
+    if masked:
+        keep = keep & key_mask[:, None]
+    probs = jax.nn.softmax(jnp.where(keep[:, None], s, -jnp.inf), axis=-1)
+    want = jnp.einsum("bnts,brs->bntr", probs, lat[:, :rank])
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-6)
 
 
 @pytest.mark.parametrize("option, message", [

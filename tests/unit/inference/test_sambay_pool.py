@@ -354,14 +354,17 @@ def _digest(tree):
      ("70973dd72aba3407", 36)),
     ("llama2-7b", {"scan_layers": False}, ("413338bc326a560b", 291), ("e55ad93897f78f38", 64),
      ("7d581e97c6a287c5", 64)),
+    # PR 55: the latent leaves rest position-last, (2, 1, 320, 64), declared
+    # "columns" (30bae00ac1380acc / 70973dd72aba3407 while they were rows of
+    # 320; tiny-mla-moe's 3b84258aa35bd04e / 7efe6c18a76c403f likewise)
     ("mistral-small-4-119b", {"scan_layers": False}, ("d378c3c33fb6535c", 579),
-     ("30bae00ac1380acc", 36), ("70973dd72aba3407", 36)),
+     ("2b3563502a59eee8", 36), ("5bb7fe90c6ddf5e9", 36)),
     # PR 42: its state leaves rest two heads a lane row, (2, 15, 96, 384)
     # (1c5f151820235ef8 while they were (2, 30, 96, 192))
     ("olmo-hybrid-7b", {}, ("7bf46abc96dcf40d", 475), ("df45013d6f63db31", 64),
      ("72fa486ac50a3d4b", 64)),
     ("tiny-hybrid", {}, ("fe34f5c3f89eebab", 62), ("19da4dfe885cea0f", 8), ("2cb26fe38a1746cd", 8)),
-    ("tiny-mla-moe", {}, ("585ee1651c3e6625", 19), ("3b84258aa35bd04e", 1), ("7efe6c18a76c403f", 1)),
+    ("tiny-mla-moe", {}, ("585ee1651c3e6625", 19), ("52eea5a78f13e3c5", 1), ("d70a613fd25d4c61", 1)),
 ])
 def test_models_without_new_layer_types_build_what_they_built(name, overrides, params, pool, kinds):
     """The parameter tree, the cache tree and the declared kinds (paths,

@@ -1,7 +1,9 @@
 """The in-place K/V commit kernel (``ops/pallas/kv_commit.py``, interpret
 mode) against the ``vmap`` scatter it stands in for: the whole pool, bit
 for bit, in both geometries a per-head pool rests in: split (a K and a V
-leaf) and packed (one leaf, a row's key and value side by side)."""
+leaf) and packed (one leaf, a row's key and value side by side). And the
+column kernel of the latent leaf, which rests position-last, against the
+bytes the scatter left in the leaf as it was shaped until PR 55."""
 
 import functools
 
@@ -116,3 +118,80 @@ def test_commits_in_place_reads_the_leaf():
     assert not kv_commit.commits_in_place(sds((4, 2, 48, 64), jnp.int8))   # 32-row blocks
     assert not kv_commit.commits_in_place(sds((4, 2, 20, 64), jnp.float32))
     assert not kv_commit.commits_in_place(sds((4, 64, 1), jnp.float16))
+
+
+# ------------------------------------------------------- the latent columns
+CN, CD, CS = 5, 48, 384  # slots, values a position, positions: three lane blocks
+
+
+def _column_cases(C):
+    """(write heads, spans) per named case, five slots each."""
+    return {
+        # a decode step: one column a live slot, at any lane of any block
+        "one_column": ([0, 127, 128, 200, CS - 1], [1] * CN),
+        # a chunk's span: the columns straddle lane blocks from any offset
+        "chunk_span": ([0, 1, 127, 129, CS - C], [C, C - 1, C, max(C // 2, 1), C]),
+        # a span that runs past S: the columns past the end are dropped
+        "past_S": ([CS - 1, CS - 2, CS - C + 1, CS, CS + 7], [C] * CN),
+        # dead slots (span 0) beside live ones: not touched, whatever their head
+        "dead_slot": ([0, 5, 130, CS - 1, CS + 3], [C, 0, 0, min(C, 2), 0]),
+        # a retained prefix: slot 1 holds positions [0, 140) and rides with span
+        # 0; slot 2 appends behind its own prefix of 140
+        "retained_prefix": ([140, 140, 140, 0, 260], [0, 0, C, 0, 1]),
+    }
+
+
+def _old_scatter(leaf, fresh, heads, spans):
+    """What the parent left: the span scatter on the leaf shaped ``(N, 1, S,
+    D)`` (``_commit_span_rows`` where no kernel is taken), seen position-last."""
+    (rows, ) = _scatter((jnp.swapaxes(leaf, 2, 3), ), (fresh, ), heads, spans)
+    return jnp.swapaxes(rows, 2, 3)
+
+
+@pytest.mark.parametrize("case", ["one_column", "chunk_span", "past_S", "dead_slot",
+                                  "retained_prefix"])
+@pytest.mark.parametrize("C", [1, 7, 200])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
+def test_column_commit_matches_the_old_scatter(dtype, C, case):
+    rng = np.random.default_rng(C)
+    make = lambda *shape: jnp.asarray(rng.standard_normal(shape), dtype)
+    leaf, fresh = make(CN, 1, CD, CS), make(CN, 1, C, CD)
+    heads, spans = (jnp.asarray(x, jnp.int32) for x in _column_cases(C)[case])
+    got = jax.jit(kv_commit.commit_kv_columns)(leaf, fresh, heads, spans)
+    want = _old_scatter(leaf, fresh, heads, spans)
+    bits = lambda x: np.asarray(x.view(jnp.uint8))
+    np.testing.assert_array_equal(bits(got), bits(want))
+    idle = np.asarray(spans) == 0
+    np.testing.assert_array_equal(bits(got)[idle], bits(leaf)[idle])
+    assert case == "dead_slot" or not np.array_equal(bits(got), bits(leaf))
+
+
+@pytest.mark.parametrize("S", [CS, 96], ids=["kernel", "scatter"])
+def test_span_columns_takes_the_kernel_where_it_tiles_the_leaf(S):
+    """``_commit_span_columns`` (what ``LatentAttention`` calls): the kernel
+    where ``S`` is whole 128-position blocks, the scatter elsewhere; the same
+    bytes either way, and the tally says which."""
+    from deepspeed_tpu.models.transformer import _commit_span_columns
+    rng = np.random.default_rng(S)
+    make = lambda *shape: jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+    leaf, fresh = make(3, 1, CD, S), make(3, 1, 9, CD)
+    heads, spans = jnp.asarray([0, S - 4, 50], jnp.int32), jnp.asarray([9, 9, 0], jnp.int32)
+    before = kv_commit.traced()
+    got = _commit_span_columns(leaf, fresh, heads, spans)
+    in_place, scatter = (a - b for a, b in zip(kv_commit.traced(), before))
+    assert (in_place, scatter) == ((1, 0) if S == CS else (0, 1))
+    np.testing.assert_array_equal(np.asarray(got.view(jnp.uint8)),
+                                  np.asarray(_old_scatter(leaf, fresh, heads, spans).view(jnp.uint8)))
+
+
+def test_commits_columns_in_place_reads_the_leaf():
+    sds = jax.ShapeDtypeStruct
+    assert kv_commit.commits_columns_in_place(sds((192, 1, 576, 4096), jnp.bfloat16))  # cell 10
+    assert kv_commit.commits_columns_in_place(sds((64, 1, 320, 2048), jnp.bfloat16))  # cell 4
+    assert kv_commit.commits_columns_in_place(sds((2, 1, 24, 128), jnp.float32))
+    assert not kv_commit.commits_columns_in_place(sds((2, 1, 24, 96), jnp.float32))
+    assert not kv_commit.commits_columns_in_place(sds((2, 2, 24, 128), jnp.float32))
+    assert not kv_commit.commits_columns_in_place(sds((2, 1, 24, 128), jnp.int8))
+    with pytest.raises(ValueError, match="not its columns"):
+        kv_commit.commit_kv_columns(jnp.zeros((2, 1, 24, 128)), jnp.zeros((2, 1, 128, 3)),
+                                    jnp.zeros(2, jnp.int32), jnp.zeros(2, jnp.int32))

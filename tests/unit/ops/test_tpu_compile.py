@@ -422,6 +422,20 @@ def test_kv_commit(for_chip, name, int8, cols):
     assert "dstpu_kv_commit" in text
 
 
+@pytest.mark.parametrize("slots, cols, width, pool_len", [
+    (64, 1, 320, 2048), (1, 256, 320, 2048), (192, 1, 576, 4096), (1, 512, 576, 4096)],
+    ids=["cell4-decode", "cell4-chunk", "cell10-decode", "cell10-chunk"])
+def test_kv_commit_columns(for_chip, slots, cols, width, pool_len):
+    """The latent leaf's column commit at the two cells' shapes: a decode
+    step's one column a slot, and the chunk's span into its own slot."""
+    from deepspeed_tpu.ops.pallas.kv_commit import commit_kv_columns
+    sds, compile_ = for_chip
+    rows = sds((slots, ), jnp.int32)
+    text = compile_(commit_kv_columns, sds((slots, 1, width, pool_len), jnp.bfloat16),
+                    sds((slots, 1, cols, width), jnp.bfloat16), rows, rows)
+    assert "dstpu_kv_commit_columns" in text
+
+
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
 def test_gdn_step(for_chip, dtype):
     """The one-token gated-delta update alone at cell 5's shape: 64 slots of
@@ -513,6 +527,24 @@ def _pool_relayouts(text, shape):
     moves = re.compile(r"= \w+" + re.escape(shape) + r"\S* (copy|scatter|transpose)\(")
     count = lambda names: sum(bool(moves.search(l)) for n in names for l in comps[n])
     return count(inside), count(set(comps) - inside)
+
+
+def _leaf_moves(text, shape):
+    """:func:`_pool_relayouts` of a pool leaf in both of its spellings, as the
+    tree holds it and with its unit dimensions dropped (the latent leaf ``(N,
+    1, D, S)`` is ``(N, D, S)`` to the block walk: a transposition for the
+    walk carries no unit dimension, and a meter that asks for one spelling
+    reads 0 beside it). The asynchronous copies count too, around the loops
+    (``copy-start`` of the whole leaf: the compiler parks a leaf that fits
+    VMEM there, cell 4's 84 MB, and moves it out and in again every step
+    unless the commit's result is declared HBM's; 3.5% of cell 4's window
+    on the chip, PR 55)."""
+    spell = lambda dims: "[" + ",".join(map(str, dims)) + "]"
+    spellings = {spell(shape), spell([d for d in shape if d != 1])}
+    in_loop, around = map(sum, zip(*(_pool_relayouts(text, s) for s in spellings)))
+    parked = sum(" copy-start(" in line and any(s in line for s in spellings)
+                 for line in text.splitlines())
+    return in_loop, around + parked
 
 
 def _fused_sync(model, steps):
@@ -655,7 +687,13 @@ def test_latent_moe_step_program(for_chip, step):
     benchmark serves it (64 slots, ``prefill_chunk`` 256, a 2,048-position
     latent pool, 32 of the 128 experts held, a quarter of the vocabulary),
     one layer deep: latent attention over the pool, the span commit of latent
-    rows, the routed experts. The chunk sync runs its live rows only (the 64
+    columns, the routed experts. The latent leaf rests position-last, the
+    form the block walk's two products take, and its commit is the in-place
+    column kernel: NOTHING moves the whole leaf, in the steps' loop or around
+    it, in either spelling (until PR 55 the span scatter wanted a position's
+    320 values minor and the walk the positions: one relayout on entry, one a
+    forward for the walk, the one inside the loop spelt without the unit
+    dimension where this test did not look, and one on exit). The chunk sync runs its live rows only (the 64
     decode rows as one column by the sparse dispatch, 2 rows an expert; the
     chunk as a (1, 256) forward over its own slot by the dense product over
     the 32 experts held, 8 rows an expert), and needs less room than the
@@ -668,9 +706,10 @@ def test_latent_moe_step_program(for_chip, step):
         max_seq_len=8192, attention_impl="flash", scan_layers=False))
     compiled, pool, around = _compile_sync(
         sds, model, slots, 1 if step == "decode" else chunk, pool_len)
-    assert jax.tree_util.tree_leaves(pool)[0].shape == (slots, 1, pool_len, 320)
-    assert around <= 2  # 320 lanes are dense in no form: the latent leaf is relaid in and out
+    assert jax.tree_util.tree_leaves(pool)[0].shape == (slots, 1, 320, pool_len)
     text = compiled.as_text()
+    assert "dstpu_kv_commit_columns" in text
+    assert _leaf_moves(text, (slots, 1, 320, pool_len)) == (0, 0)
     assert "ragged-dot" in text and "tpu_custom_call" in text  # the grouped products, on the chip
     # the chunk's forward multiplies its 256 rows by all 32 held experts, and
     # reads the kernels as they rest: no whole leaf is copied for it
@@ -1047,7 +1086,7 @@ def test_ling_hybrid_step_program(for_chip, width, collect):
     params = abstract(jax.eval_shape(model.init_params, jax.random.key(0)), jnp.bfloat16)
     pool = abstract(jax.eval_shape(lambda: model.init_cache(slots, pool_len)))
     shapes = sorted({leaf.shape for leaf in jax.tree_util.tree_leaves(pool)})
-    assert shapes == [(slots, 1, 3, 12288), (slots, 1, pool_len, 576), (slots, 32, 128, 128)]
+    assert shapes == [(slots, 1, 3, 12288), (slots, 1, 576, pool_len), (slots, 32, 128, 128)]
     mock = types.SimpleNamespace(
         engine=types.SimpleNamespace(module=model, model_config=model.cfg), _shard_deg=1,
         _fused_block=False, _moe_stats=True, _moe=True, experts=None, _compiled={},
@@ -1068,9 +1107,16 @@ def test_ling_hybrid_step_program(for_chip, width, collect):
     for scope in ("gdn_proj", "gdn_state", "gdn_out", "mla_proj", "mla_attn", "moe_router"):
         assert scope in text, scope
     assert _pool_relayouts(text, f"[{slots},32,128,128]") == (0, 0)
+    # the latent leaf is carried in the one form it rests in, through the
+    # column commit and the walk of every forward (six whole-leaf moves a
+    # sync and 1.0 GB of temporaries for them, until PR 55)
+    assert "dstpu_kv_commit_columns" in text
+    assert _leaf_moves(text, (slots, 1, 576, pool_len)) == (0, 0)
     mem = compiled.memory_analysis()
     print(width, collect, "temporaries", mem.temp_size_in_bytes)
     assert 10.46e9 + 2.20e9 * slots / 192 + mem.temp_size_in_bytes < 15.75 * 2**30, mem
+    if width == 1:
+        assert mem.temp_size_in_bytes < 0.3e9, mem
 
 
 def _accepted_cell_syncs():
@@ -1106,9 +1152,15 @@ def _accepted_cell_syncs():
 # column's one-token update is ``dstpu_gdn_step`` (``ops/pallas/gdn_step.py``);
 # the chunk program converts its slot's state around the scan. The other
 # three cells hold no gated-delta layer and lower what they lowered.
+# PR 55 moved TWO, both of cell 4 (7b6b3c73f810f160 and b75b19168934fbc2 at its
+# parent e232eee): the latent leaf rests position-last, (64, 1, 320, 2048), its
+# span commit is ``dstpu_kv_commit_columns`` (``ops/pallas/kv_commit.py``) in
+# place of the scatter, and the block walk's two products read blocks of
+# ``(B, D, blk)``. Cells 2, 5 and 6 hold no latent leaf and lower what they
+# lowered.
 PARENT_LOWERED = {
     "gpt2-large.serve.chat-closed": ("15065a760c93d007", "9bccfab4d863721b"),
-    "mistral-small-4-119b.serve.decode-closed": ("7b6b3c73f810f160", "b75b19168934fbc2"),
+    "mistral-small-4-119b.serve.decode-closed": ("64fc6e6f85e47a4b", "18ee3dd3cfc4bec7"),
     "olmo-hybrid-7b.serve.decode-closed": ("f887b9840c76ab25", "f1dfd33c486d64da"),
     "phi-4-mini-flash.serve.reason-closed": ("71e23c35b3433be3", "af018c91144275bb"),
 }
