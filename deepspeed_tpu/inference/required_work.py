@@ -12,7 +12,9 @@ host's copies of the lengths and spans alone:
   ``serving/cross_decoder_rows_unread``: the K/V positions the attention of a
   model with windowed or shared-row layers has to read;
 - ``serving/ssd_state_updates`` / ``serving/ssd_chunk_tokens``: the one-token
-  updates and chunk positions of a model's Mamba-2 layers;
+  updates and chunk positions of a model's Mamba-2 mixers (a ``mamba2``
+  layer's, and a ``parallel_hybrid`` layer's beside its attention: such a
+  layer counts in this family AND in the attended keys below);
 - ``serving/short_conv_updates`` / ``serving/short_conv_chunk_tokens`` /
   ``serving/short_conv_layer_calls``: the same two of a model's gated
   short-convolution layers, and the forwards that run them (each reads the
@@ -35,6 +37,13 @@ import numpy as np
 
 from ..ops.pallas.decode_attention import span_tile, walk_block_kv, walked_keys
 from ..telemetry.capacity import program_shape
+
+
+def layer_mixers(cfg, i):
+    """The mixers layer ``i``'s block runs (``TransformerConfig.layer_mixers``:
+    a ``parallel_hybrid`` block runs attention AND a Mamba-2 mixer, and both
+    are counted); plain attention for a configuration without layer kinds."""
+    return cfg.layer_mixers(i) if hasattr(cfg, "layer_mixers") else ("full_attention", )
 
 
 def attention_walks(model, tp):
@@ -61,7 +70,7 @@ def attention_walks(model, tp):
     groups = collections.Counter()
     for i in range(cfg.num_layers):
         kind = cfg.layer_type(i) if hasattr(cfg, "layer_type") else "full_attention"
-        mixer = cfg.layer_parts(i)[0] if hasattr(cfg, "layer_parts") else kind
+        attends = "full_attention" in layer_mixers(cfg, i)
         window = cfg.layer_window(i) if hasattr(cfg, "layer_window") else 0
         if carries:
             if kind == "diff_attention" and window:
@@ -70,10 +79,10 @@ def attention_walks(model, tp):
                 groups[(window, 0, cfg.kv_heads // 2, 2 * cfg.head_size, False)] += 1
             elif kind in ("diff_attention", "cross_attention"):
                 groups[(0, 0, cfg.kv_heads // 2, 2 * cfg.head_size, False)] += 1
-        elif mixer == "full_attention" and window and getattr(cfg, "layer_windows", ()):
+        elif attends and window and getattr(cfg, "layer_windows", ()):
             if cfg.ring_rows(i) == window and shard == 1:  # else XLA reads the ring
                 groups[(window, 0, cfg.kv_heads, cfg.head_size, False)] += 1
-        elif mixer == "full_attention":
+        elif attends:
             groups[(0, window, cfg.kv_heads // shard, cfg.head_size,
                     kv_packs(cfg.head_size))] += 1
     if getattr(cfg, "mtp_layers", 0):  # the module's own rows, read while it drafts
@@ -106,9 +115,9 @@ class RequiredWork:
                             sum(not w and cfg.layer_type(i) in ("diff_attention", "cross_attention")
                                 for i, w in zip(layers, windows)))
         # Mamba-2 layers: what the counters of state updates multiply by
-        mixer_of = getattr(cfg, "layer_parts", lambda i: ("full_attention", "mlp"))
-        self.ssd_layers = sum(mixer_of(i)[0] == "mamba2" for i in range(cfg.num_layers))
-        self.conv_layers = sum(mixer_of(i)[0] == "short_conv" for i in range(cfg.num_layers))
+        mixers = [layer_mixers(cfg, i) for i in range(cfg.num_layers)]
+        self.ssd_layers = sum("mamba2" in ms for ms in mixers)
+        self.conv_layers = sum("short_conv" in ms for ms in mixers)
         self.attn_walks = attention_walks(model, tp)
         self._walk_blocks = {}
 

@@ -212,6 +212,13 @@ attention layer ``"rows"``, an FFN-only layer nothing, and holds nothing; the
 pool is a state pool with the same operand, refusals and bypass counter. See
 ``benchmarks/SERVING.md`` ("One-sublayer blocks").
 
+**Two mixers in one block** (``falcon_h1``: ``parallel_hybrid`` layers, a
+Mamba-2 mixer AND attention on one normed input): every layer declares
+``"rows", "rows", "state", "state"`` and its slot holds both kinds; the pool is
+a state pool with the same operand, refusals and bypass counter although every
+layer also holds rows. See ``benchmarks/SERVING.md`` ("Two mixers in one
+block").
+
 **Gated short convolutions** (``lfm2_moe``: ``short_conv`` layers beside
 attention): such a layer declares ONE ``"state"`` leaf, the gated inputs of a
 slot's last ``short_conv_kernel - 1`` positions, and an attention layer its

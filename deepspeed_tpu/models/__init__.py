@@ -290,6 +290,60 @@ def tiny_nemotron_h():
     return dataclasses.replace(cfg, ssm_chunk_size=8)
 
 
+def _falcon_h1(hidden, layers, heads, kv_heads, head_dim, ffn, ssm_heads, ssm_head_dim,
+               ssm_state, ssm_groups, chunk, vocab, seq, theta, embedding, lm_head, attn_in,
+               attn_out, key, ssm_in, ssm_out, ssm_zxbcdt, mlp_gate, mlp_down):
+    """A ``falcon_h1`` stack (TII Falcon-H1): every block runs a Mamba-2 mixer
+    AND grouped-query attention side by side on ONE normed input, ``h = x +
+    m_s SSM(m_si a) + m_a Attn(m_ai a)`` with ``a = RMSNorm(x)``, then ``y = h
+    + MLP(RMSNorm(h))``, a SwiGLU whose gate and output are scaled; keys
+    scaled before rotation (whole head, halves rotated), the embedding and
+    the logits scaled, the Mamba-2 in-projection's output scaled by five
+    constants over ``[z ; x ; B ; C ; dt]``; no bias but the convolution's,
+    untied head. Unrolled (``layer_types``). Served only."""
+    return TransformerConfig(
+        vocab_size=vocab, hidden_size=hidden, num_layers=layers, num_heads=heads,
+        num_kv_heads=kv_heads, head_dim=head_dim, intermediate_size=ffn, max_seq_len=seq,
+        pos_embedding="rope", rope_theta=theta, norm="rmsnorm", activation="swiglu",
+        tie_embeddings=False, layernorm_epsilon=1e-5, attn_bias=False, mlp_bias=False,
+        layer_types=("parallel_hybrid", ) * layers, ssm_state_size=ssm_state, ssm_conv_kernel=4,
+        ssm_num_heads=ssm_heads, ssm_head_dim=ssm_head_dim, ssm_groups=ssm_groups,
+        ssm_chunk_size=chunk, embedding_multiplier=embedding, lm_head_multiplier=lm_head,
+        attention_in_multiplier=attn_in, attention_out_multiplier=attn_out, key_multiplier=key,
+        ssm_in_multiplier=ssm_in, ssm_out_multiplier=ssm_out, ssm_multipliers=ssm_zxbcdt,
+        mlp_gate_multiplier=mlp_gate, mlp_down_multiplier=mlp_down, scan_layers=False)
+
+
+@register("falcon-h1-34b-instruct")
+def falcon_h1_34b_instruct():
+    """Falcon-H1-34B-Instruct at its published sizes (huggingface.co/tiiuae/
+    Falcon-H1-34B-Instruct config.json, ``model_type: falcon_h1``): 72
+    two-mixer blocks of hidden 5,120, 20 query and 4 key/value heads of 128
+    (theta 1e11) beside 32 Mamba-2 heads of 128 with a state of 256 in 2
+    groups (chunks of 128), a SwiGLU of 21,504, vocabulary 261,120 untied,
+    the twelve published multipliers, 33.6 B parameters. ``num_layers`` is
+    overridden together with ``layer_types``."""
+    return _falcon_h1(5120, 72, 20, 4, 128, 21504, 32, 128, 256, 2, 128, 261120, 262144, 1e11,
+                      embedding=5.656854249492381, lm_head=0.0078125, attn_in=1.0,
+                      attn_out=0.0375, key=0.011048543456039804, ssm_in=0.25,
+                      ssm_out=0.08838834764831845,
+                      ssm_zxbcdt=(0.3535533905932738, 0.25, 0.1767766952966369, 0.5,
+                                  0.3535533905932738),
+                      mlp_gate=0.1767766952966369, mlp_down=0.011160714285714284)
+
+
+@register("tiny-falcon-h1")
+def tiny_falcon_h1():
+    """Test-scale ``falcon_h1``: 4 two-mixer blocks, 5 query heads to ONE
+    key/value head of 16 (the published odd group of five), 4 Mamba-2 heads
+    of 8 with a state of 16 in 2 groups, chunks of 8, every multiplier off 1
+    and no two alike, so that each one left out or misplaced shows."""
+    return _falcon_h1(64, 4, 5, 1, 16, 128, 4, 8, 16, 2, 8, 256, 256, 1e4,
+                      embedding=3.0, lm_head=0.5, attn_in=1.5, attn_out=0.6, key=0.4,
+                      ssm_in=0.7, ssm_out=1.3, ssm_zxbcdt=(0.8, 1.2, 0.9, 1.1, 1.4),
+                      mlp_gate=1.6, mlp_down=0.75)
+
+
 def _exaone_moe(hidden, layers, heads, kv_heads, head_dim, dense_ffn, window, period, experts,
                 top_k, expert_ffn, routed_scale, vocab, seq, theta=1e6, first_dense=1, mtp=1):
     """An ``exaone_moe`` stack (K-EXAONE): blocks ``h = x + RMSNorm(Attn(x))``,

@@ -1,10 +1,16 @@
-"""Mamba-2 (state-space duality, arXiv:2405.21060), the mixer of a ``mamba2``
-layer as ``nemotron_h`` configures it: ``nh`` heads of ``hd`` channels, a
-scalar decay a head, a state of ``hd x N`` a head, ``B`` and ``C`` shared by
-``G`` groups of heads, ONE causal depthwise convolution over ``x``, ``B`` and
-``C`` together, a group-wise gated RMSNorm:
+"""Mamba-2 (state-space duality, arXiv:2405.21060), the mixer two kinds of
+layer share: a ``mamba2`` layer's only sublayer (``nemotron_h``: 64 heads of
+64, a state of 128 a channel, 8 groups, nothing scaled) and a
+``parallel_hybrid`` layer's second mixer beside attention (``falcon_h1``: 32
+heads of 128, a state of 256, 2 groups, its input and its in-projection's
+output scaled by published constants). Every size is the configuration's:
+``nh`` heads of ``hd`` channels, a scalar decay a head, a state of ``hd x N``
+a head, ``B`` and ``C`` shared by ``G`` groups of heads, ONE causal depthwise
+convolution over ``x``, ``B`` and ``C`` together, a group-wise gated RMSNorm:
 
-    [z ; xBC ; dt] = u W_in                         z: nh hd, xBC: nh hd + 2 G N, dt: nh
+    [z ; xBC ; dt] = (m_in u) W_in * mu             z: nh hd, xBC: nh hd + 2 G N, dt: nh
+                                                    (m_in: ssm_in_multiplier; mu: ssm_multipliers'
+                                                    five constants over z, x, B, C, dt; 1 unless set)
     xBC_t = SiLU(sum_j w[:, j] xBC_(t-W+1+j) + b_c) = [x_t (nh, hd) ; B_t (G, N) ; C_t (G, N)]
     Delta_t = softplus(dt_t + dt_bias) ; a = -exp(A_log)               a scalar a head
     S_t = exp(Delta_t a) S_(t-1) + (Delta_t x_t) (x) B_t               head h reads group h // (nh / G)
@@ -34,7 +40,7 @@ import jax.numpy as jnp
 import flax.linen as nn
 
 from .transformer import (TransformerConfig, _serves_by_spans, gdn_conv_init,
-                          gdn_dt_bias_init, last_live_inputs)
+                          gdn_dt_bias_init, last_live_inputs, scaled)
 
 
 def mamba2_a_log_init(key, shape, dtype=jnp.float32):
@@ -118,9 +124,23 @@ class GroupGatedNorm(nn.Module):
         return grouped.reshape(g.shape) * scale
 
 
+def in_projection_multipliers(cfg):
+    """``mu``: ``cfg.ssm_multipliers``' five constants spread over the
+    in-projection's outputs ``[z (nh hd) ; x (nh hd) ; B (G N) ; C (G N) ; dt
+    (nh)]``, float32; None where the configuration publishes none. A constant
+    of the configuration, not a parameter."""
+    if not cfg.ssm_multipliers:
+        return None
+    gn = cfg.ssm_groups * cfg.ssm_state_size
+    widths = (cfg.mamba2_inner, cfg.mamba2_inner, gn, gn, cfg.ssm_num_heads)
+    return jnp.concatenate([jnp.full((w, ), m, jnp.float32)
+                            for w, m in zip(widths, cfg.ssm_multipliers)])
+
+
 class Mamba2(nn.Module):
-    """The mixer of a ``mamba2`` layer (module docstring). The call is the
-    narrow one of the newer mixers; ``carry`` passes through untouched."""
+    """The mixer of a ``mamba2`` layer and of a ``parallel_hybrid`` layer's
+    state-space branch (module docstring). The call is the narrow one of the
+    newer mixers; ``carry`` passes through untouched."""
     cfg: TransformerConfig
     layer_idx: int = -1
 
@@ -135,7 +155,10 @@ class Mamba2(nn.Module):
         dense = partial(nn.Dense, use_bias=False, dtype=cfg.dtype, param_dtype=f32,
                         kernel_init=nn.initializers.normal(0.02))
         with jax.named_scope("ssd_proj"):
-            zxd = dense(di + cc + nh, name="in_proj")(x)
+            zxd = dense(di + cc + nh, name="in_proj")(scaled(x, cfg.ssm_in_multiplier))
+            mu = in_projection_multipliers(cfg)
+            if mu is not None:
+                zxd = (zxd.astype(f32) * mu).astype(cfg.dtype)
             z, xbc, dt = zxd[..., :di], zxd[..., di:di + cc], zxd[..., di + cc:]
             conv_w = self.param("conv", gdn_conv_init, (cc, W), f32)
             conv_b = self.param("conv_bias", nn.initializers.zeros, (cc, ), f32)

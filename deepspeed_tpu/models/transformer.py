@@ -44,9 +44,14 @@ SAMBAY_TYPES = ("mamba", "diff_attention", "gmu", "cross_attention")
 ONE_SUBLAYER_TYPES = {"mamba2": ("mamba2", None), "attention": ("full_attention", None),
                       "moe": (None, "moe"), "mlp": (None, "mlp")}
 # "short_conv" (``lfm2``): a gated short convolution in attention's place of
-# a mixer-and-FFN block (:class:`ShortConv`)
-LAYER_TYPES = (("full_attention", "linear_attention", "short_conv") + SAMBAY_TYPES
-               + tuple(ONE_SUBLAYER_TYPES))
+# a mixer-and-FFN block (:class:`ShortConv`).
+# "parallel_hybrid" (``falcon_h1``): a block of TWO mixers and an FFN, grouped-
+# query attention and a Mamba-2 mixer side by side on ONE normed input, each
+# scaled by a published constant and summed into the residual. Its slot holds
+# both mixers' leaves, in this order
+PARALLEL_HYBRID_MIXERS = ("full_attention", "mamba2")
+LAYER_TYPES = (("full_attention", "linear_attention", "short_conv", "parallel_hybrid")
+               + SAMBAY_TYPES + tuple(ONE_SUBLAYER_TYPES))
 
 
 def sambay_layers(num_layers, mb_per_layer, sliding_window):
@@ -186,8 +191,9 @@ class TransformerConfig:
     # DiffAttention(cross), which hold state, rows or a ring of rows, nothing,
     # and nothing; ONE_SUBLAYER_TYPES: a block of ONE sublayer, a Mamba-2
     # mixer, attention, an expert FFN or a dense FFN alone, which hold state,
-    # rows, nothing and nothing). () = every layer full attention. Needs
-    # unrolled layers
+    # rows, nothing and nothing; "parallel_hybrid": Attention AND Mamba2 on one
+    # normed input, then a dense FFN, which holds rows and state in ONE
+    # layer). () = every layer full attention. Needs unrolled layers
     layer_types: Tuple[str, ...] = ()
     # keys a layer's query sees, its own included (0 = all), for the kinds whose
     # mixer attends over rows of its own: diff_attention and full_attention
@@ -208,6 +214,26 @@ class TransformerConfig:
     ssm_head_dim: int = 0
     ssm_groups: int = 1
     ssm_chunk_size: int = 128
+    # the constants a ``falcon_h1`` config publishes (maximal-update
+    # parametrisation), each applied to the ACTIVATION where the published
+    # forward applies it, in float32 and rounded once to the serving dtype: on
+    # the embedding's output and the logits; on the attention branch's input,
+    # its keys before rotation and its output; on the Mamba-2 branch's input
+    # and output; on the in-projection's output over [z ; x ; B ; C ; dt]
+    # before the convolution (ssm_multipliers: five, in that order; () = none);
+    # on the MLP's gate before SiLU and its down-projection's output. 1 = not
+    # applied: the program is the one without it. They go with
+    # parallel_hybrid layers
+    embedding_multiplier: float = 1.0
+    lm_head_multiplier: float = 1.0
+    attention_in_multiplier: float = 1.0
+    attention_out_multiplier: float = 1.0
+    key_multiplier: float = 1.0
+    ssm_in_multiplier: float = 1.0
+    ssm_out_multiplier: float = 1.0
+    ssm_multipliers: Tuple[float, ...] = ()
+    mlp_gate_multiplier: float = 1.0
+    mlp_down_multiplier: float = 1.0
     mlp_bias: Optional[bool] = None  # None = follow norm (layernorm -> biased)
     linear_num_heads: int = 0  # key heads = value heads of a linear layer
     linear_key_head_dim: int = 0
@@ -359,19 +385,28 @@ class TransformerConfig:
                 if set(self.layer_types) - set(ONE_SUBLAYER_TYPES):
                     raise ValueError(f"the one-sublayer kinds {tuple(ONE_SUBLAYER_TYPES)} do "
                                      f"not mix with kinds whose block is a mixer and an FFN")
-                if "mamba2" in one and not (
-                        self.ssm_num_heads and self.ssm_head_dim and self.ssm_state_size
-                        and self.ssm_conv_kernel > 1 and self.ssm_chunk_size > 0
-                        and self.ssm_groups > 0 and self.ssm_num_heads % self.ssm_groups == 0):
-                    raise ValueError("mamba2 layers need ssm_num_heads (a multiple of "
-                                     "ssm_groups), ssm_head_dim, ssm_state_size and a "
-                                     "convolution of width > 1")
                 if ("moe" in one) != bool(self.num_experts):
                     raise ValueError("moe layers need num_experts, and experts under "
                                      "layer_types need moe layers to live in")
                 if self.post_norm or self.dropout > 0:
                     raise ValueError("a one-sublayer block is x + f(norm(x)): no post_norm, "
                                      "no dropout")
+            two = "parallel_hybrid" in self.layer_types
+            if two and (set(self.layer_types) != {"parallel_hybrid"} or any(self.layer_windows)
+                        or self.post_norm or self.num_experts or self.kv_lora_rank
+                        or self.dropout > 0 or self.pos_embedding not in ("rope", "none")):
+                raise ValueError("a parallel_hybrid block is x + m_s SSM(norm(x)) + m_a "
+                                 "Attn(norm(x)), then a dense FFN: every layer of the stack is "
+                                 "one, and it composes with no other kind, layer_windows, "
+                                 "post_norm, experts beside it, latent attention, dropout, "
+                                 "learned positions or alibi")
+            if ("mamba2" in one or two) and not (
+                    self.ssm_num_heads and self.ssm_head_dim and self.ssm_state_size
+                    and self.ssm_conv_kernel > 1 and self.ssm_chunk_size > 0
+                    and self.ssm_groups > 0 and self.ssm_num_heads % self.ssm_groups == 0):
+                raise ValueError("mamba2 and parallel_hybrid layers need ssm_num_heads (a "
+                                 "multiple of ssm_groups), ssm_head_dim, ssm_state_size and a "
+                                 "convolution of width > 1")
             experts_beside = self.num_experts and not one
             if experts_beside and (sambay or not self.moe_dropless):
                 raise ValueError("experts in a mixer-and-FFN block under layer_types go with "
@@ -388,6 +423,14 @@ class TransformerConfig:
                                  "one-sublayer kinds, windows, post_norm or qk_norm")
         elif self.rope_windowed_only:
             raise ValueError("rope_windowed_only goes by layer_windows, which need layer_types")
+        object.__setattr__(self, "ssm_multipliers", tuple(self.ssm_multipliers))  # a JSON list
+        if len(self.ssm_multipliers) not in (0, 5):
+            raise ValueError("ssm_multipliers scales the Mamba-2 in-projection's output over "
+                             "[z ; x ; B ; C ; dt]: five constants, or none")
+        if self.has_multipliers and "parallel_hybrid" not in self.layer_types:
+            raise ValueError("the published multipliers (embedding, lm_head, attention in / "
+                             "out, key, ssm in / out, ssm_multipliers, mlp gate / down) are "
+                             "applied by a stack of parallel_hybrid layers, served")
         if self.post_norm and (self.parallel_residual or self.dropout > 0
                                or (self.num_experts and not self.moe_dropless)):
             raise ValueError("post_norm composes with sequential residuals, no dropout and, "
@@ -418,13 +461,13 @@ class TransformerConfig:
             raise ValueError("one multi-token-prediction module at most (mtp_layers 0 or 1)")
         if self.mtp_layers and (self.scan_layers or self.kv_lora_rank or self.int8_weights
                                 or self.carries_across_layers
-                                or set(self.layer_types) & ({"short_conv"}
+                                or set(self.layer_types) & ({"short_conv", "parallel_hybrid"}
                                                             | set(ONE_SUBLAYER_TYPES))):
             raise ValueError("the multi-token-prediction module (mtp_layers: "
                              "num_nextn_predict_layers) is a block of attention and an "
                              "FFN behind an unrolled stack of such blocks (no latent "
-                             "attention, int8 weights, SambaY, short_conv or one-sublayer "
-                             "kinds)")
+                             "attention, int8 weights, SambaY, parallel_hybrid, short_conv "
+                             "or one-sublayer kinds)")
         if self.kv_lora_rank and not (self.qk_nope_head_dim and self.qk_rope_head_dim
                                       and self.v_head_dim and self.pos_embedding == "rope"):
             raise ValueError("latent attention (kv_lora_rank > 0) needs qk_nope_head_dim, "
@@ -514,6 +557,23 @@ class TransformerConfig:
         # (a scanned stack's layers carry no index, and no leading dense layer)
         sparse = self.num_experts and not 0 <= layer_idx < self.moe_first_dense
         return ONE_SUBLAYER_TYPES.get(kind, (kind, "moe" if sparse else "mlp"))
+
+    def layer_mixers(self, layer_idx):
+        """The mixers layer ``layer_idx``'s block runs, in the order its slot
+        holds their leaves: none (an FFN alone), :meth:`layer_parts`' one, or
+        a ``parallel_hybrid`` block's two."""
+        mixer = self.layer_parts(layer_idx)[0]
+        if mixer == "parallel_hybrid":
+            return PARALLEL_HYBRID_MIXERS
+        return () if mixer is None else (mixer, )
+
+    @property
+    def has_multipliers(self):
+        """Whether any published multiplier is other than 1."""
+        return bool(self.ssm_multipliers) or any(m != 1.0 for m in (
+            self.embedding_multiplier, self.lm_head_multiplier, self.attention_in_multiplier,
+            self.attention_out_multiplier, self.key_multiplier, self.ssm_in_multiplier,
+            self.ssm_out_multiplier, self.mlp_gate_multiplier, self.mlp_down_multiplier))
 
     def layer_rotates(self, layer_idx):
         """Whether layer ``layer_idx``'s attention rotates its queries and
@@ -612,14 +672,18 @@ class TransformerConfig:
                    "gmu": 2 * h * di, "cross_attention": q_o}
             return (sum(per[t] for t in self.layer_types) + L * (mlp + 4 * h)
                     + emb + pos + 2 * h)
+        # a Mamba-2 mixer: W_in, the taps and their bias, dt_bias / A_log / D,
+        # the gated norm's scale, W_out
+        di, cc, nh = self.mamba2_inner, self.mamba2_conv_channels, self.ssm_num_heads
+        mamba2 = h * (di + cc + nh) + cc * (self.ssm_conv_kernel + 1) + 3 * nh + di + di * h
         if set(self.layer_types) & set(ONE_SUBLAYER_TYPES):
-            di, cc = self.mamba2_inner, self.mamba2_conv_channels
-            nh = self.ssm_num_heads
-            per = {"mamba2": (h * (di + cc + nh) + cc * (self.ssm_conv_kernel + 1) + 3 * nh
-                              + di + di * h),
+            per = {"mamba2": mamba2,
                    "attention": attn, "mlp": per_h * self.ffn_size,
                    "moe": mlp + (self.num_experts if self.moe_scoring == "sigmoid" else 0)}
             return sum(per[t] + h for t in self.layer_types) + emb + pos + h
+        if "parallel_hybrid" in self.layer_types:
+            # both mixers, the FFN, two norms
+            return L * (attn + mamba2 + mlp + 2 * h) + emb + pos + h
         n_lin = sum(t == "linear_attention" for t in self.layer_types)
         if n_lin:
             # q, k; v, gate, out; the two per-head gates with A_log and
@@ -811,6 +875,15 @@ def _chunked_ce_bwd(T, chunk, transpose, res, g):
 
 
 _chunked_ce.defvjp(_chunked_ce_fwd, _chunked_ce_bwd)
+
+
+def scaled(x, multiplier):
+    """``x`` times a published constant (``TransformerConfig``: the
+    ``*_multiplier`` fields): the product in float32, rounded once to ``x``'s
+    dtype; ``x`` itself where the constant is 1."""
+    if multiplier == 1.0:
+        return x
+    return (x.astype(jnp.float32) * multiplier).astype(x.dtype)
 
 
 class RMSNorm(nn.Module):
@@ -1029,7 +1102,7 @@ def kv_pool_geometry(cfg, kv_cache):
         return "split"  # a pair's keys, and its values, as one head of 2 x head size
     else:
         leaf = kv_cache[0][next(i for i in range(cfg.num_layers)
-                                if cfg.layer_parts(i)[0] == "full_attention")]
+                                if "full_attention" in cfg.layer_mixers(i))]
     return "packed" if leaf.shape[-1] == 2 * cfg.head_size else "split"
 
 
@@ -1502,15 +1575,16 @@ class Attention(nn.Module):
             k = k.reshape(B, T, nkv, hd).transpose(0, 2, 1, 3)
             v = v.reshape(B, T, nkv, hd).transpose(0, 2, 1, 3)
         else:
-            q = HeadProjection(nh, hd, use_bias, cfg.dtype, i8, i8g, name="q_proj")(
-                x, sub("q_proj"))
-            q = behind(q, "k_proj")
-            k = HeadProjection(nkv, hd, use_bias, cfg.dtype, i8, i8g, name="k_proj")(
-                x, sub("k_proj"))
-            k = behind(k, "v_proj")
-            v = HeadProjection(nkv, hd, use_bias, cfg.dtype, i8, i8g, name="v_proj")(
-                x, sub("v_proj"))
-            v = behind(v, "o_proj")
+            with jax.named_scope("attn_proj"):
+                q = HeadProjection(nh, hd, use_bias, cfg.dtype, i8, i8g, name="q_proj")(
+                    x, sub("q_proj"))
+                q = behind(q, "k_proj")
+                k = HeadProjection(nkv, hd, use_bias, cfg.dtype, i8, i8g, name="k_proj")(
+                    x, sub("k_proj"))
+                k = scaled(behind(k, "v_proj"), cfg.key_multiplier)
+                v = HeadProjection(nkv, hd, use_bias, cfg.dtype, i8, i8g, name="v_proj")(
+                    x, sub("v_proj"))
+                v = behind(v, "o_proj")
 
         if lora_ops:
             # per-row adapter deltas land on the projection OUTPUTS (before
@@ -1791,8 +1865,9 @@ class Attention(nn.Module):
             o_in = out.transpose(0, 2, 1, 3).reshape(out.shape[0], out.shape[2],
                                                      nh * hd)
             d_o = _lora_site_delta(o_in, lora_ops, "o")
-        out = OutProjection(H, use_bias, cfg.dtype, cfg.int8_weights,
-                            cfg.int8_group_size, name="o_proj")(out, sub("o_proj"))
+        with jax.named_scope("attn_proj"):
+            out = OutProjection(H, use_bias, cfg.dtype, cfg.int8_weights,
+                                cfg.int8_group_size, name="o_proj")(out, sub("o_proj"))
         if d_o is not None:
             out = out + d_o.reshape(out.shape).astype(out.dtype)
         return out, new_cache
@@ -2710,6 +2785,7 @@ class MLP(nn.Module):
                                 kernel_init=nn.initializers.normal(0.02))
         if cfg.activation in ("swiglu", "geglu"):
             gate = lora_add(dense(cfg.ffn_size, name="gate_proj")(x), "gate", x)
+            gate = scaled(gate, cfg.mlp_gate_multiplier)
             up = lora_add(dense(cfg.ffn_size, name="up_proj")(x), "up", x)
             act = nn.silu(gate) if cfg.activation == "swiglu" else nn.gelu(gate)
             h = act * up
@@ -2729,7 +2805,8 @@ class MLP(nn.Module):
             # bitwise-TP layout: gather the ffn-sharded activation (exact
             # concat) so the replicated down_proj contracts fully locally
             h = _tp_replicate(h)
-        return lora_add(dense(cfg.hidden_size, name="down_proj")(h), "down", h)
+        return scaled(lora_add(dense(cfg.hidden_size, name="down_proj")(h), "down", h),
+                      cfg.mlp_down_multiplier)
 
 
 class Block(nn.Module):
@@ -2784,6 +2861,28 @@ class Block(nn.Module):
                         "a mamba2 layer serves without adapters, extent chains, "
                         "sequence-parallel spans or padding masks")
                 return narrow(h, kv_cache, write_index, q_spans, carry)[:2]
+        elif mixer_kind == "parallel_hybrid":
+            from .mamba2 import Mamba2
+            ssm = Mamba2(cfg, layer_idx=self.layer_idx, name="mamba2")
+            attn = Attention(cfg, layer_idx=self.layer_idx, name="attn")
+
+            def mixer(a, sin, cos, attn_mask, kv_cache, cache_index, position_ids, write_index,
+                      q_spans, lora_ops, ext_ops, seq_shard):
+                """Both mixers on the one normed input ``a``; the slot's
+                leaves are attention's rows, then the Mamba-2 state and window
+                (``PARALLEL_HYBRID_MIXERS``, ``cache_spec``)."""
+                if lora_ops or ext_ops is not None or seq_shard or attn_mask is not None:
+                    raise NotImplementedError(
+                        "a parallel_hybrid layer serves without adapters, extent chains, "
+                        "sequence-parallel spans or padding masks")
+                rows, state = (None, None) if kv_cache is None else (kv_cache[:2], kv_cache[2:])
+                with jax.named_scope("hybrid_mixer"):
+                    s, state = ssm(a, state, write_index, q_spans)[:2]
+                    t, rows = attn(scaled(a, cfg.attention_in_multiplier), sin, cos, None, rows,
+                                   cache_index, position_ids, write_index, q_spans)
+                    h = (scaled(s, cfg.ssm_out_multiplier)
+                         + scaled(t, cfg.attention_out_multiplier))
+                return h, (None if kv_cache is None else tuple(rows) + tuple(state))
         elif mixer_kind == "linear_attention":
             mixer = GatedDeltaNet(cfg, layer_idx=self.layer_idx, name="gdn")
         elif mixer_kind == "short_conv":
@@ -2886,6 +2985,7 @@ class CausalLM(nn.Module):
         emb = nn.Embed(cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype,
                        embedding_init=nn.initializers.normal(0.02), name="embed")
         x = emb(input_ids) if kv_cache is not None else _embed_layout(emb(input_ids))
+        x = scaled(x, cfg.embedding_multiplier)
         if cfg.embed_norm:  # BLOOM's word_embeddings_layernorm
             x = make_norm(cfg, name="embed_norm")(x)
         if cfg.pos_embedding == "learned":
@@ -3044,6 +3144,7 @@ class CausalLM(nn.Module):
             else:
                 logits = nn.Dense(cfg.vocab_size, use_bias=cfg.lm_head_bias, dtype=cfg.dtype,
                                   param_dtype=jnp.float32, name="lm_head")(x)
+            logits = scaled(logits, cfg.lm_head_multiplier)
         hidden = (x, ) if with_hidden else ()
         if kv_cache is not None:
             return (logits, new_cache) + hidden
@@ -3345,7 +3446,10 @@ class CausalLMModel:
         ``cfg.ring_rows`` rows whatever ``max_len`` is, position ``p`` in row
         ``p mod R``: a windowed differential or full-attention layer's K and
         V, per-slot bytes as a state's are). Every component keeps its slot axis at ``ndim -
-        4``. A layer may declare nothing (a gated memory unit reads the
+        4``. A ``parallel_hybrid`` layer declares BOTH its mixers' components,
+        K and V rows and then the Mamba-2 state and window: two kinds in one
+        layer, and whoever reads a layer's leaves takes its mixer's slice
+        (``Block``) and never counts leaves to tell kinds apart. A layer may declare nothing (a gated memory unit reads the
         forward's carry, a cross-attention layer the rows of the full layer
         below it, a block that is an FFN alone has no mixer).
         :meth:`init_cache` builds the tree from it;
@@ -3391,13 +3495,14 @@ class CausalLMModel:
                 return ()  # gmu, cross_attention: they read the forward's carry
 
             return [declares(i, t) for i, t in enumerate(cfg.layer_types)]
-        mixers = [cfg.layer_parts(i)[0] for i in range(cfg.num_layers)]
+        mixers = [cfg.layer_mixers(i) for i in range(cfg.num_layers)]
         windows = [cfg.layer_window(i) for i in range(cfg.num_layers)]
         # a multi-token-prediction module holds rows of its own, declared
         # behind the stack's layers like one more full-attention layer
         module = [tuple(rows)] * cfg.mtp_layers
-        two_leaves = {"linear_attention", "mamba2", None} & set(mixers) or any(windows)
-        if not two_leaves and "short_conv" not in mixers:
+        run = set().union(*mixers)
+        two_leaves = {"linear_attention", "mamba2"} & run or () in mixers or any(windows)
+        if not two_leaves and "short_conv" not in run:
             return [tuple(rows)] * cfg.num_layers + module
         # (packed rows beside state: a short_conv layer's one leaf alone; the
         # latent row is ONE leaf whatever lies beside it: LatentAttention
@@ -3420,10 +3525,11 @@ class CausalLMModel:
             "mamba2": state((cfg.ssm_num_heads, cfg.ssm_head_dim, cfg.ssm_state_size),
                             cfg.ssm_conv_kernel, cfg.mamba2_conv_channels),
             "short_conv": (("state", (batch_size, 1, cfg.short_conv_kernel - 1, cfg.hidden_size),
-                            dt, jnp.zeros), ),
-            None: ()}  # an FFN alone
-        return [ring(i) if w else declares[m]
-                for i, (m, w) in enumerate(zip(mixers, windows))] + module
+                            dt, jnp.zeros), )}
+        # a layer's mixers' leaves in their order: none for an FFN alone, a
+        # parallel_hybrid layer's rows and THEN its state and window
+        return [ring(i) if w else sum((declares[m] for m in ms), ())
+                for i, (ms, w) in enumerate(zip(mixers, windows))] + module
 
     def cache_kinds(self):
         """``"rows"``, ``"columns"``, ``"ring"`` or ``"state"`` for every leaf
@@ -3692,7 +3798,7 @@ class CausalLMModel:
             return False
         if self.cfg.ce_chunk_size is None and self.cfg.vocab_size < 4096:
             return False
-        if self.cfg.lm_head_bias:
+        if self.cfg.lm_head_bias or self.cfg.lm_head_multiplier != 1.0:
             return False  # chunked CE rebuilds logits from the weight only
         return not (dist.has_mesh() and dist.get_mesh().shape[dist.SEQ_AXIS] > 1)
 

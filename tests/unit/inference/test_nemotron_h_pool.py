@@ -492,7 +492,9 @@ def test_no_preset_goes_unguarded():
         "nemotron-3-nano-30b-a3b", "tiny-nemotron-h", "k-exaone-236b-a23b", "tiny-exaone-moe",
         "lfm2-8b-a1b", "tiny-lfm2-moe",
         # PR 54's by tests/unit/inference/test_ling_hybrid_pool.py
-        "ling-3.0-flash", "tiny-ling"}
+        "ling-3.0-flash", "tiny-ling",
+        # PR 56's by tests/unit/inference/test_falcon_h1_pool.py
+        "falcon-h1-34b-instruct", "tiny-falcon-h1"}
 
 
 def test_preset_builds_the_published_sizes():

@@ -981,6 +981,31 @@ def test_exaone_moe_verify_and_draft_program(for_chip, width):
     assert 9.09e9 + 4.57e9 + mem.temp_size_in_bytes < 15.75 * 2**30, mem
 
 
+def _compile_state_pool_sync(sds, model, params, pool, slots, steps, width, collect=False):
+    """The scheduler's own sync (``DecodeScheduler._fused_fn`` on a mock that
+    holds what the builder reads) of a model whose pool holds state, compiled
+    for the described chip: ``steps`` forwards, the first ``width`` columns
+    wide, greedy, or the collecting variant that ``correct`` runs."""
+    import types
+    from deepspeed_tpu.inference.scheduler import DecodeScheduler
+    moe = bool(model.cfg.num_experts)
+    mock = types.SimpleNamespace(
+        engine=types.SimpleNamespace(module=model, model_config=model.cfg), _shard_deg=1,
+        _fused_block=False, _moe_stats=moe, _moe=moe, experts=None, _compiled={},
+        capacity=None, _pool_sharding=None, _state_pool=True,
+        cache=types.SimpleNamespace(num_slots=slots))
+    for name in ("_program", "_jit_step", "_moe_forward_stats", "_held_experts",
+                 "_splits_chunk"):
+        setattr(mock, name, types.MethodType(getattr(DecodeScheduler, name), mock))
+    assert mock._splits_chunk(("fused", False, collect, width, steps)) is (width > 1)
+    fn = DecodeScheduler._fused_fn(mock, False, collect, steps, width)
+    i32 = lambda *shape: sds(shape, jnp.int32)
+    args = (params, pool, i32(slots, width), i32(slots), i32(slots), sds((slots, ), jnp.uint32),
+            i32(slots), sds((slots, ), jnp.bool_), sds((slots, ), jnp.float32), i32(slots),
+            sds((slots, ), jnp.float32), i32(slots))
+    return fn.lower(*args).compile()
+
+
 @pytest.mark.parametrize("width, collect", [(1, False), (512, False), (512, True)],
                          ids=["decode", "chunk", "chunk-collecting"])
 def test_lfm2_moe_step_program(for_chip, width, collect):
@@ -1001,8 +1026,6 @@ def test_lfm2_moe_step_program(for_chip, width, collect):
     7.86 GB of weights and the 3.23 GB of pool that ISSUE 50's 128 slots
     would take (the host's delivery, not memory, holds the cell under 128)."""
     import json
-    import types
-    from deepspeed_tpu.inference.scheduler import DecodeScheduler
     sds, _ = for_chip
     with open(os.path.join(os.path.dirname(__file__), "..", "..", "..", "chipbench", "workloads",
                            "lfm2-8b-a1b.serve.reason-closed.json")) as f:
@@ -1020,21 +1043,7 @@ def test_lfm2_moe_step_program(for_chip, width, collect):
     pool = abstract(jax.eval_shape(lambda: model.init_cache(slots, pool_len)))
     shapes = sorted({leaf.shape for leaf in jax.tree_util.tree_leaves(pool)})
     assert shapes == [(slots, 1, 2, 2048), (slots, 8, pool_len, 128)]
-    mock = types.SimpleNamespace(
-        engine=types.SimpleNamespace(module=model, model_config=model.cfg), _shard_deg=1,
-        _fused_block=False, _moe_stats=True, _moe=True, experts=None, _compiled={},
-        capacity=None, _pool_sharding=None, _state_pool=True,
-        cache=types.SimpleNamespace(num_slots=slots))
-    for name in ("_program", "_jit_step", "_moe_forward_stats", "_held_experts",
-                 "_splits_chunk"):
-        setattr(mock, name, types.MethodType(getattr(DecodeScheduler, name), mock))
-    assert mock._splits_chunk(("fused", False, collect, width, steps)) is (width > 1)
-    fn = DecodeScheduler._fused_fn(mock, False, collect, steps, width)
-    i32 = lambda *shape: sds(shape, jnp.int32)
-    args = (params, pool, i32(slots, width), i32(slots), i32(slots), sds((slots, ), jnp.uint32),
-            i32(slots), sds((slots, ), jnp.bool_), sds((slots, ), jnp.float32), i32(slots),
-            sds((slots, ), jnp.float32), i32(slots))
-    compiled = fn.lower(*args).compile()
+    compiled = _compile_state_pool_sync(sds, model, params, pool, slots, steps, width, collect)
     text = compiled.as_text()
     assert "dstpu_decode_attn" in text and "dstpu_kv_commit" in text
     assert "ragged-dot" not in text and "moe_experts" in text
@@ -1066,8 +1075,6 @@ def test_ling_hybrid_step_program(for_chip, width, collect):
     multiples of 256, 3 rows an expert): no grouped product. It fits with its
     temporaries beside the cell's 10.46 GB of weights and 2.20 GB of pool."""
     import json
-    import types
-    from deepspeed_tpu.inference.scheduler import DecodeScheduler
     sds, _ = for_chip
     here = os.path.join(os.path.dirname(__file__), "..", "..", "..", "chipbench")
     with open(os.path.join(here, "workloads", "ling-3.0-flash.serve.reason-closed.json")) as f:
@@ -1087,20 +1094,7 @@ def test_ling_hybrid_step_program(for_chip, width, collect):
     pool = abstract(jax.eval_shape(lambda: model.init_cache(slots, pool_len)))
     shapes = sorted({leaf.shape for leaf in jax.tree_util.tree_leaves(pool)})
     assert shapes == [(slots, 1, 3, 12288), (slots, 1, 576, pool_len), (slots, 32, 128, 128)]
-    mock = types.SimpleNamespace(
-        engine=types.SimpleNamespace(module=model, model_config=model.cfg), _shard_deg=1,
-        _fused_block=False, _moe_stats=True, _moe=True, experts=None, _compiled={},
-        capacity=None, _pool_sharding=None, _state_pool=True,
-        cache=types.SimpleNamespace(num_slots=slots))
-    for name in ("_program", "_jit_step", "_moe_forward_stats", "_held_experts",
-                 "_splits_chunk"):
-        setattr(mock, name, types.MethodType(getattr(DecodeScheduler, name), mock))
-    fn = DecodeScheduler._fused_fn(mock, False, collect, steps, width)
-    i32 = lambda *shape: sds(shape, jnp.int32)
-    args = (params, pool, i32(slots, width), i32(slots), i32(slots), sds((slots, ), jnp.uint32),
-            i32(slots), sds((slots, ), jnp.bool_), sds((slots, ), jnp.float32), i32(slots),
-            sds((slots, ), jnp.float32), i32(slots))
-    compiled = fn.lower(*args).compile()
+    compiled = _compile_state_pool_sync(sds, model, params, pool, slots, steps, width, collect)
     text = compiled.as_text()
     assert "dstpu_gdn_step" in text  # (a chunk's sync ends in decode substeps)
     assert "ragged-dot" not in text and "moe_experts" in text
@@ -1117,6 +1111,52 @@ def test_ling_hybrid_step_program(for_chip, width, collect):
     assert 10.46e9 + 2.20e9 * slots / 192 + mem.temp_size_in_bytes < 15.75 * 2**30, mem
     if width == 1:
         assert mem.temp_size_in_bytes < 0.3e9, mem
+
+
+@pytest.mark.parametrize("width", [1, 512], ids=["decode", "chunk"])
+def test_falcon_h1_step_program(for_chip, width):
+    """Falcon-H1-34B-Instruct's sync at the published widths as the chip
+    benchmark serves it (the slots of the cell's own file x 4096,
+    ``steps_per_sync`` 4, ``prefill_chunk`` 512, the whole vocabulary of
+    261,120), the scheduler's own program (``DecodeScheduler._fused_fn``), two
+    of its six two-mixer blocks deep: K/V rows AND Mamba-2 state in the SAME
+    layer's slot, the decode column through ``dstpu_decode_attn`` and
+    ``dstpu_kv_commit`` in groups of five query heads and the one-token state
+    update at 32 x 128 x 256 under ``ssd_state``, both branches under
+    ``hybrid_mixer``. It fits with its temporaries (the chunk's 512 x 261,120
+    logits among them) beside the cell's 10.51 GB of weights and 4.04 GB of
+    pool."""
+    import json
+    sds, _ = for_chip
+    here = os.path.join(os.path.dirname(__file__), "..", "..", "..", "chipbench")
+    with open(os.path.join(here, "workloads", "falcon-h1-34b.serve.reason-closed.json")) as f:
+        serve = json.load(f)["serve"]
+    slots, pool_len, steps = serve["num_slots"], serve["max_len"], serve["steps_per_sync"]
+    assert (slots, pool_len, steps, serve["prefill_chunk"]) == (64, 4096, 4, 512)
+    model = get_model("falcon-h1-34b-instruct", dtype=jnp.bfloat16, num_layers=2,
+                      layer_types=("parallel_hybrid", ) * 2, max_seq_len=pool_len,
+                      attention_impl="flash")
+    abstract = lambda tree, dtype=None: jax.tree_util.tree_map(
+        lambda a: sds(a.shape, dtype or a.dtype), tree)
+    params = abstract(jax.eval_shape(model.init_params, jax.random.key(0)), jnp.bfloat16)
+    pool = abstract(jax.eval_shape(lambda: model.init_cache(slots, pool_len)))
+    assert [leaf.shape for leaf in (pool[0][0], pool[1][0], pool[2][0], pool[3][0])] == [
+        (slots, 4, pool_len, 128), (slots, 4, pool_len, 128), (slots, 32, 128, 256),
+        (slots, 1, 3, 5120)]
+    compiled = _compile_state_pool_sync(sds, model, params, pool, slots, steps, width)
+    text = compiled.as_text()
+    for mark in ("dstpu_decode_attn", "dstpu_kv_commit", "hybrid_mixer", "attn_proj", "ssd_proj",
+                 "ssd_state", "ssd_out", "lm_head"):
+        assert mark in text, mark
+    # the state leaf and the rows are carried in the one form they rest in
+    assert _pool_relayouts(text, f"[{slots},32,128,256]") == (0, 0)
+    assert _pool_relayouts(text, f"[{slots},4,{pool_len},128]") == (0, 0)
+    mem = compiled.memory_analysis()
+    print(width, "temporaries", mem.temp_size_in_bytes)
+    assert mem.temp_size_in_bytes < (0.1e9 if width == 1 else 0.4e9), mem
+    # six layers' temporaries are under three times two layers' (0.14 and 0.74
+    # GB compiled six deep, 0.065 and 0.305 here: PERF.md section 4)
+    assert 10.51e9 + 4.04e9 + 3 * mem.temp_size_in_bytes < 15.75 * 2**30, mem
 
 
 def _accepted_cell_syncs():
