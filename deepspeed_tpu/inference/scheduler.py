@@ -794,6 +794,8 @@ class DecodeScheduler(DeviceDraft):
         self.kv_commit_programs = {"inplace": 0, "scatter": 0}
         # ... and, for gated-delta layers, by their one-token state update
         self.gdn_step_programs = {"kernel": 0, "xla": 0}
+        # ... and, for Mamba-2 mixers, by theirs
+        self.ssd_step_programs = {"kernel": 0, "xla": 0}
         # ... and the geometry init_cache gave the pool they carry: "packed"
         # (K beside V in one 128-lane leaf a layer: head size 64), "split"
         # or "latent"
@@ -2411,21 +2413,27 @@ class DecodeScheduler(DeviceDraft):
         ``scatter`` if any layer's span write fell back to the XLA scatter
         (``models/transformer.py: _commit_span_rows``), so a server whose
         steps relay the pool says so; and which one-token update its
-        gated-delta layers took, ``xla`` if any fell back to the definition
-        (``GatedDeltaNet``), so a server whose states go two passes says so."""
+        gated-delta layers and its Mamba-2 mixers took, ``xla`` if any fell
+        back to the definition (``GatedDeltaNet``, ``Mamba2``), so a server
+        whose states go two passes says so."""
         from ..moe.layer import traced_dispatches
-        from ..ops.pallas import gdn_step, kv_commit
-        before, moe_before, gdn_before = kv_commit.traced(), traced_dispatches(), gdn_step.traced()
+        from ..ops.pallas import gdn_step, kv_commit, ssd_step
+        updates = ((gdn_step, self.gdn_step_programs, "gdn_step"),
+                   (ssd_step, self.ssd_step_programs, "ssd_step"))
+        before, moe_before = kv_commit.traced(), traced_dispatches()
+        updates_before = [module.traced() for module, _, _ in updates]
         out = fn(*call_args)
-        after, moe_after, gdn_after = kv_commit.traced(), traced_dispatches(), gdn_step.traced()
+        after, moe_after = kv_commit.traced(), traced_dispatches()
         if after != before:
             path = "scatter" if after[1] > before[1] else "inplace"
             self.kv_commit_programs[path] += 1
             self.telemetry.counter(f"serving/kv_commit_{path}_programs")
-        if gdn_after != gdn_before:
-            path = "xla" if gdn_after[1] > gdn_before[1] else "kernel"
-            self.gdn_step_programs[path] += 1
-            self.telemetry.counter(f"serving/gdn_step_{path}_programs")
+        for (module, programs, name), was in zip(updates, updates_before):
+            now = module.traced()
+            if now != was:
+                path = "xla" if now[1] > was[1] else "kernel"
+                programs[path] += 1
+                self.telemetry.counter(f"serving/{name}_{path}_programs")
         if moe_after != moe_before:
             _, dense, broadcast = (a > b for a, b in zip(moe_after, moe_before))
             # the counters say how a program's pairs were EVALUATED: ``dense``

@@ -26,11 +26,13 @@ over exactly its ``q_spans`` live columns (later columns get Delta 0: no
 decay, no input), a span-0 row's leaves come out bit for bit, a span at
 position 0 starts from zero whatever the slot held.
 
-One column is the one-token update (:func:`ssd_step`); anything wider (a
-prefill chunk, the full forward) the chunked matrix form of the same
-recurrence (:func:`ssd_chunked`): the part inside a chunk of
-``cfg.ssm_chunk_size`` positions as masked products, the state carried from
-chunk to chunk.
+One column is the one-token update: :func:`ssd_step`, the definition, or
+where the state leaf tiles (``ops/pallas/ssd_step.py: tiles``: both published
+shapes; no tiny preset) the kernel that does the same on the leaf in place,
+``dstpu_ssd_step``. Anything wider (a prefill chunk, the full forward) is
+the chunked matrix form of the same recurrence (:func:`ssd_chunked`): the
+part inside a chunk of ``cfg.ssm_chunk_size`` positions as masked products,
+the state carried from chunk to chunk.
 """
 
 from functools import partial
@@ -39,7 +41,8 @@ import jax
 import jax.numpy as jnp
 import flax.linen as nn
 
-from .transformer import (TransformerConfig, _serves_by_spans, gdn_conv_init,
+from ..ops.pallas import ssd_step as ssd_kernel
+from .transformer import (TransformerConfig, _serves_by_spans, _tp_mesh_size, gdn_conv_init,
                           gdn_dt_bias_init, last_live_inputs, scaled)
 
 
@@ -166,6 +169,7 @@ class Mamba2(nn.Module):
             a = -jnp.exp(self.param("A_log", mamba2_a_log_init, (nh, ), f32))
             D = self.param("D", nn.initializers.ones, (nh, ), f32)
             dt = jax.nn.softplus(dt.astype(f32) + dt_bias)  # (B, T, nh)
+            in_place = False
             if kv_cache is None:
                 state = jnp.zeros((B, nh, hd, N), f32)
                 window = jnp.zeros((B, W - 1, cc), cfg.dtype)
@@ -173,8 +177,19 @@ class Mamba2(nn.Module):
                 state_rest, window_rest = kv_cache
                 live_row = q_spans > 0
                 fresh = live_row & (write_index == 0)
-                state = jnp.where(fresh[:, None, None, None], 0.0, state_rest.astype(f32))
+                # GatedDeltaNet's rule for its in-place kernel
+                in_place = (T == 1 and cfg.attention_impl == "flash" and _tp_mesh_size() == 1
+                            and ssd_kernel.tiles(state_rest, nh, hd, N, G))
+                if T == 1:
+                    ssd_kernel.tally(in_place)
+                if not in_place:
+                    state = jnp.where(fresh[:, None, None, None], 0.0, state_rest.astype(f32))
                 window = jnp.where(fresh[:, None, None], 0, window_rest[:, 0]).astype(cfg.dtype)
+                if in_place:
+                    # ONE reading of the window leaf: sixteen layers deep the compiler
+                    # recomputes this select for the convolution AFTER the step's new
+                    # window was written into the donated leaf (PERF.md, PR 57)
+                    window = jax.lax.optimization_barrier(window)
                 dt = jnp.where((jnp.arange(T)[None, :] < q_spans[:, None])[..., None], dt, 0.0)
             # causal depthwise convolution over [window ; this call's inputs]
             seq = jnp.concatenate([window, xbc.astype(cfg.dtype)], axis=1)
@@ -184,7 +199,11 @@ class Mamba2(nn.Module):
             Bm = u[..., di:di + G * N].reshape(B, T, G, N)
             Cm = u[..., di + G * N:].reshape(B, T, G, N)
         with jax.named_scope("ssd_state"):
-            if T == 1:
+            if in_place:
+                y, new_state = ssd_kernel.ssd_update(state_rest, xs[:, 0], dt[:, 0], a, Bm[:, 0],
+                                                     Cm[:, 0], D, live_row, fresh)
+                y = y[:, None]
+            elif T == 1:
                 per_head = lambda v: jnp.repeat(v[:, 0], nh // G, axis=1)  # (B, nh, N)
                 y, state = ssd_step(state, xs[:, 0], dt[:, 0], a, per_head(Bm), per_head(Cm), D)
                 y = y[:, None]
@@ -199,8 +218,10 @@ class Mamba2(nn.Module):
                 else:
                     tail = last_live_inputs(seq, q_spans, W - 1)
                 keep = live_row[:, None, None, None]
+                if not in_place:
+                    new_state = jnp.where(keep, state.astype(state_rest.dtype), state_rest)
                 new_cache = (
-                    jnp.where(keep, state.astype(state_rest.dtype), state_rest),
+                    new_state,
                     jnp.where(keep, tail[:, None].astype(window_rest.dtype), window_rest))
         with jax.named_scope("ssd_out"):
             g = GroupGatedNorm(G, cfg.layernorm_epsilon, name="norm")(

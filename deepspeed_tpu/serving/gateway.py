@@ -1511,6 +1511,9 @@ class Gateway:
                           # state update: "xla" ones pass over the state twice
                           "gdn_step_programs": dict(getattr(
                               sched, "gdn_step_programs", {})),
+                          # ... and by their Mamba-2 mixers'
+                          "ssd_step_programs": dict(getattr(
+                              sched, "ssd_step_programs", {})),
                           # the pool's geometry at rest: "packed" (K beside
                           # V in one leaf a layer), "split" or "latent"
                           "kv_pool_geometry": getattr(

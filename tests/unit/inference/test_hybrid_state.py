@@ -172,6 +172,37 @@ def test_the_kernel_serves_the_definitions_streams(published_heads):
         assert ref.compare(logits, want_logits, tol=TOL)["ok"]
 
 
+def test_mamba2_programs_are_counted_by_their_one_token_update():
+    """``ssd_step_programs``, beside ``gdn_step_programs``: a served
+    ``tiny-nemotron-h`` (cut to two layers) has a state leaf (4 heads of 8 x
+    16) that does not tile, so
+    every program with a decode column counts ``xla`` (``serving/
+    ssd_step_xla_programs``); a program traced over a leaf that tiles (two
+    heads of 64 x 128) counts ``kernel``, once, when it is built."""
+    base = get_model("tiny-nemotron-h", dtype=jnp.float32)
+    # (two of its eight layers: the programs build in a quarter of the time)
+    model = type(base)(dataclasses.replace(
+        base.cfg, num_layers=2, layer_types=("mamba2", "attention"), num_experts=0))
+    eng = _engine((model, jax.jit(model.init_params)(jax.random.key(7))), slots=2, steps=2)
+    sched = eng.scheduler()
+    sched.submit(_prompts((9, ))[0], max_new_tokens=4)
+    sched.drain()
+    served = dict(sched.ssd_step_programs)
+    assert served["xla"] > 0 and served["kernel"] == 0
+    assert sched.gdn_step_programs == {"kernel": 0, "xla": 0}
+    tiling = type(model)(dataclasses.replace(
+        model.cfg, ssm_num_heads=2, ssm_head_dim=64, ssm_state_size=128, ssm_groups=1,
+        attention_impl="flash", max_seq_len=32))
+    params = jax.jit(tiling.init_params)(jax.random.key(0))
+    at = jnp.asarray([3, 0], jnp.int32)
+    step = jax.jit(lambda params, pool: tiling.apply_with_cache(
+        params, jnp.asarray([[5], [7]], jnp.int32), pool, 0, position_ids=at[:, None],
+        write_index=at, q_spans=jnp.ones(2, jnp.int32)))
+    for _ in range(2):  # the second call traces nothing
+        sched._run_program(step, (params, tiling.init_cache(2, 32)))
+    assert sched.ssd_step_programs == {"kernel": 1, "xla": served["xla"]}
+
+
 def test_a_span_0_slot_is_bit_for_bit_unchanged(tiny):
     """A sync that advances other slots leaves an idle slot's state, window
     and rows exactly as they were: slot 1's, once its request has ended,

@@ -46,7 +46,7 @@ def tiny():
     """(model, params, hyper-parameters of the reference, ids) of the
     ``tiny-mla-moe`` preset, all 8 experts held, float32."""
     model = get_model("tiny-mla-moe", dtype=jnp.float32)
-    params = model.init_params(jax.random.key(3))
+    params = jax.jit(model.init_params)(jax.random.key(3))  # (jitted: seconds less a worker)
     hp = ref.kwargs_for(_config(), model.cfg)
     ids = jnp.asarray(np.random.default_rng(0).integers(0, 256, (2, 56)), jnp.int32)
     return model, params, hp, ids

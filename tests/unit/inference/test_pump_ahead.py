@@ -65,7 +65,7 @@ def served(request):
     _fresh()
     model, params, kw = MODELS[request.param]()
     if params is None:
-        params = model.init_params(jax.random.key(5))
+        params = jax.jit(model.init_params)(jax.random.key(5))  # (jitted: seconds less a worker)
     return request.param, model, jax.device_get(params), dict(kw, programs={})
 
 
@@ -187,7 +187,7 @@ def test_prefill_lane_advances_at_launch():
     empty: a final chunk frees the lane when it is launched, not a sync later
     when it lands."""
     model, _, _ = _tiny()
-    sched = _scheduler(model, model.init_params(jax.random.key(5)))
+    sched = _scheduler(model, jax.jit(model.init_params)(jax.random.key(5)))
     rng = np.random.default_rng(3)
     a, b = ([int(t) for t in rng.integers(3, 256, 2 * CHUNK)] for _ in range(2))
     ha, hb = sched.submit(a, max_new_tokens=12), sched.submit(b, max_new_tokens=12)
@@ -251,7 +251,7 @@ def test_pump_is_serial_where_it_can_see_it_must_be(build):
 def _in_flight_sched(n=2, budget=24, **submit_kw):
     """A scheduler with ``n`` rows decoding and a sync in flight."""
     model, _, _ = _tiny()
-    sched = _scheduler(model, model.init_params(jax.random.key(5)))
+    sched = _scheduler(model, jax.jit(model.init_params)(jax.random.key(5)))
     rng = np.random.default_rng(8)
     handles = [sched.submit([int(t) for t in rng.integers(3, 256, 10 + i)], max_new_tokens=budget,
                             **submit_kw) for i in range(n)]

@@ -199,17 +199,19 @@ def test_the_layer_takes_the_kernel_by_shape(heads, kernel):
     same numbers either way; the tally says which."""
     n, dk, dv = heads
     model, plain = _layer_model(n, dk, dv, "flash"), _layer_model(n, dk, dv, "xla")
-    params = model.init_params(jax.random.key(0))
-    pool = jax.tree_util.tree_map(
+    # (jitted: op by op, the initialisation alone compiles for seconds)
+    params = jax.jit(model.init_params)(jax.random.key(0))
+    pool = jax.jit(lambda: jax.tree_util.tree_map(
         lambda x: 0.1 * jax.random.normal(jax.random.key(1), x.shape, x.dtype),
-        model.init_cache(3, 32))
+        model.init_cache(3, 32)))()
     ids = jnp.asarray([[5], [7], [9]], jnp.int32)
     heads_at, spans = jnp.asarray([4, 0, 9], jnp.int32), jnp.asarray([1, 1, 0], jnp.int32)
     outs = []
     for m in (model, plain):
         before = gdn_step.traced()
-        outs.append(m.apply_with_cache(params, ids, pool, 0, position_ids=heads_at[:, None],
-                                       write_index=heads_at, q_spans=spans))
+        outs.append(jax.jit(lambda params, pool, m=m: m.apply_with_cache(
+            params, ids, pool, 0, position_ids=heads_at[:, None], write_index=heads_at,
+            q_spans=spans))(params, pool))
         took = tuple(a - b for a, b in zip(gdn_step.traced(), before))
         assert took == ((2, 0) if kernel and m is model else (0, 2))
     (logits, cache), (want_logits, want_cache) = outs

@@ -452,6 +452,26 @@ def test_gdn_step(for_chip, dtype):
     assert "dstpu_gdn_step" in text
 
 
+@pytest.mark.parametrize("slots, nh, hd, N, G, dtype", [
+    (192, 64, 64, 128, 8, jnp.bfloat16), (64, 32, 128, 256, 2, jnp.bfloat16),
+    (8, 16, 8, 128, 1, jnp.float32)], ids=["cell7", "cell11", "small-f32"])
+def test_ssd_step(for_chip, slots, nh, hd, N, G, dtype):
+    """The one-token Mamba-2 update alone at the two cells' shapes: 192 slots
+    of 64 heads (64, 128) in 8 groups, 64 slots of 32 heads (128, 256) in 2;
+    and a float32 leaf of half a megabyte, sixteen heads a piece (with its
+    result declared ``pltpu.HBM`` the compiler aborts on this one:
+    ``ssd_step.py``'s docstring)."""
+    from deepspeed_tpu.ops.pallas import ssd_step
+    sds, compile_ = for_chip
+    leaf = sds((slots, nh, hd, N), dtype)
+    assert ssd_step.tiles(leaf, nh, hd, N, G)
+    f32 = jnp.float32
+    text = compile_(ssd_step.ssd_update, leaf, sds((slots, nh, hd), f32), sds((slots, nh), f32),
+                    sds((nh, ), f32), sds((slots, G, N), f32), sds((slots, G, N), f32),
+                    sds((nh, ), f32), sds((slots, ), jnp.bool_), sds((slots, ), jnp.bool_))
+    assert "dstpu_ssd_step" in text
+
+
 # ------------------------------------------------------- fused decode blocks
 def _layer_operands(name):
     """Shapes of the operand tuples the engines hand the fused kernels:
@@ -882,7 +902,7 @@ def test_nemotron_h_step_program(for_chip, step):
     ``steps_per_sync`` 4, experts 0-63 of 128, half the vocabulary), with one
     layer of each kind (a Mamba-2 mixer, an expert layer, attention, each a
     block of ONE sublayer): the one-token update of 192 states of 64 x 64 x
-    128 and, in the chunk sync, the chunked matrix form over the chunk's own
+    128 in place (``dstpu_ssd_step``) and, in the chunk sync, the chunked matrix form over the chunk's own
     slot, attention without positions through the paged kernels, and the
     192 x 6 pairs (9 rows an expert; the chunk's 512 rows: 24) by the dense
     product over the 64 experts held: no grouped product is left in either
@@ -907,6 +927,10 @@ def test_nemotron_h_step_program(for_chip, step):
     for shape in set(shapes):
         assert _pool_relayouts(text, "[" + ",".join(map(str, shape)) + "]") == (0, 0), shape
     assert "dstpu_decode_attn" in text and "dstpu_kv_commit" in text
+    # the one-token update on the state leaf in place (a chunk's sync ends in
+    # decode substeps), the leaf neither moved nor parked
+    assert "dstpu_ssd_step" in text
+    assert _leaf_moves(text, (slots, 64, 64, 128)) == (0, 0)
     assert "ragged-dot" not in text and "moe_experts" in text
     for kernel in ("[64,2688,1856]", "[64,1856,2688]"):
         assert _pool_relayouts(text, kernel) == (0, 0), kernel
@@ -914,6 +938,41 @@ def test_nemotron_h_step_program(for_chip, step):
     print(step, "temporaries", mem.temp_size_in_bytes)
     assert mem.temp_size_in_bytes < 0.6e9, mem
     assert 10.57e9 + 3.07e9 + mem.temp_size_in_bytes < 15.75 * 2**30, mem
+
+
+@pytest.mark.slow
+def test_nemotron_h_whole_share_reads_each_window_leaf_once(for_chip):
+    """Cell 7's collecting chunk sync at its whole depth (16 layers, 2.5
+    minutes to compile: the slow lane). Sixteen layers deep the compiler
+    rematerialises cheap fusions, and with the state leaves off XLA's hands
+    (``dstpu_ssd_step``) it recomputed a Mamba-2 layer's ``where(fresh, 0,
+    window)`` for the convolution AFTER the fusion that writes the step's new
+    window into the same donated leaf: the first forward of every sync read a
+    window one step on, in two of the seven layers, and ``correct`` read 0.35
+    against a limit of 0.03 (my chip runs, PR 57; twelve layers deep nothing
+    is rematerialised and nothing was wrong). ``Mamba2`` now hands that
+    select through an ``optimization_barrier`` where it takes the kernel:
+    every window leaf is read before anything writes it."""
+    import json
+    sds, _ = for_chip
+    here = os.path.join(os.path.dirname(__file__), "..", "..", "..", "chipbench", "configs")
+    with open(os.path.join(here, "nemotron-3-nano-30b-a3b.json")) as f:
+        kinds = tuple(json.load(f)["overrides"]["layer_types"])
+    model = _nemotron_share(kinds)
+    abstract = lambda tree, dtype=None: jax.tree_util.tree_map(
+        lambda a: sds(a.shape, dtype or a.dtype), tree)
+    params = abstract(jax.eval_shape(model.init_params, jax.random.key(0)), jnp.bfloat16)
+    pool = abstract(jax.eval_shape(lambda: model.init_cache(192, 4096)))
+    text = _compile_state_pool_sync(sds, model, params, pool, 192, 4, 512, collect=True).as_text()
+    assert text.count("dstpu_ssd_step") >= 14
+    entry = text[text.index("ENTRY "):].splitlines()
+    windows = re.findall(r"(pool_\d+__\d+_\.1): bf16\[192,1,3,6144\]", entry[0])
+    assert len(windows) == 7
+    for leaf in windows:
+        uses = [(i, bool(re.match(r"\s*%[\w.\-]+ = \(?bf16\[192,1,3,6144\]", line)))
+                for i, line in enumerate(entry) if re.search("%" + re.escape(leaf) + r"[,)]", line)]
+        first_write = min((i for i, writes in uses if writes), default=len(entry))
+        assert not [i for i, writes in uses if not writes and i > first_write], (leaf, uses)
 
 
 @pytest.mark.parametrize("width", [2, pytest.param(512, marks=pytest.mark.slow)],
@@ -1122,8 +1181,8 @@ def test_falcon_h1_step_program(for_chip, width):
     of its six two-mixer blocks deep: K/V rows AND Mamba-2 state in the SAME
     layer's slot, the decode column through ``dstpu_decode_attn`` and
     ``dstpu_kv_commit`` in groups of five query heads and the one-token state
-    update at 32 x 128 x 256 under ``ssd_state``, both branches under
-    ``hybrid_mixer``. It fits with its temporaries (the chunk's 512 x 261,120
+    update at 32 x 128 x 256 in place (``dstpu_ssd_step``) under ``ssd_state``,
+    both branches under ``hybrid_mixer``. It fits with its temporaries (the chunk's 512 x 261,120
     logits among them) beside the cell's 10.51 GB of weights and 4.04 GB of
     pool."""
     import json
@@ -1145,11 +1204,12 @@ def test_falcon_h1_step_program(for_chip, width):
         (slots, 1, 3, 5120)]
     compiled = _compile_state_pool_sync(sds, model, params, pool, slots, steps, width)
     text = compiled.as_text()
-    for mark in ("dstpu_decode_attn", "dstpu_kv_commit", "hybrid_mixer", "attn_proj", "ssd_proj",
-                 "ssd_state", "ssd_out", "lm_head"):
+    for mark in ("dstpu_decode_attn", "dstpu_kv_commit", "dstpu_ssd_step", "hybrid_mixer",
+                 "attn_proj", "ssd_proj", "ssd_state", "ssd_out", "lm_head"):
         assert mark in text, mark
     # the state leaf and the rows are carried in the one form they rest in
-    assert _pool_relayouts(text, f"[{slots},32,128,256]") == (0, 0)
+    # (the state leaf is 128 MiB, VMEM's size: not parked there either)
+    assert _leaf_moves(text, (slots, 32, 128, 256)) == (0, 0)
     assert _pool_relayouts(text, f"[{slots},4,{pool_len},128]") == (0, 0)
     mem = compiled.memory_analysis()
     print(width, "temporaries", mem.temp_size_in_bytes)
