@@ -18,6 +18,9 @@ jax.config.update("jax_num_cpu_devices", 8)
 
 import pytest  # noqa: E402
 
+# helpers with assertions of their own, imported by the tests of the serving path
+pytest.register_assert_rewrite("tests.unit.inference._ladder", "tests.unit.inference._serving")
+
 
 @pytest.fixture(autouse=True)
 def _reset_global_mesh():
@@ -35,13 +38,15 @@ def pytest_configure(config):
 # fast / slow lanes (reference CI splits sequential/parallel lanes, SURVEY §4;
 # VERDICT r3 item 10: a red test must not hide behind a 10-minute wall).
 #
-#   core lane:  pytest tests/ -m "not slow"     (~3 min)
+#   core lane:  pytest tests/ -m "not slow"   the driver's tier-1: six workers
+#               (-n 6 --dist load), cut at 1,470 s; the command is `commands`
+#               of /root/TESTS_LAST_RUN.json and tools/ci_check.sh runs it
 #   slow lane:  pytest tests/ -m slow
 #
-# tests/slow_tests.txt is the measured duration table (nodeids >= 15s on the
-# single-core dev box); regenerate with
-#   pytest tests/ -q --durations=0 | awk '$1+0>=15 && $2=="call" {print $3}'
-# New tests default to the core lane until measured.
+# tests/slow_tests.txt names the slow lane's tests (and @pytest.mark.slow a
+# few more). Where the core lane's time goes, a file at a time: ROADMAP.md
+# D11 and the per-file table in CHANGES.md; tools/ci_check.sh prints the sum
+# of the cases' times beside the wall. New tests default to the core lane.
 # ---------------------------------------------------------------------------
 _SLOW_FILE = os.path.join(os.path.dirname(__file__), "slow_tests.txt")
 
@@ -63,3 +68,52 @@ def pytest_collection_modifyitems(config, items):
         base = item.nodeid.split("[")[0]
         if item.nodeid in slow or base in slow:
             item.add_marker(marker)
+
+
+# ---------------------------------------------------------------------------
+# where a case runs. The driver deals single tests (--dist load), and the step
+# programs that schedulers of one shape share (tests/unit/inference/_serving.py)
+# live in one process: the cases of these files go to ONE worker each, the
+# file a unit of work, and every other test is dealt alone as before. No unit
+# may pass about 600 CPU-seconds (tests/unit/ops/test_tpu_compile.py's, one
+# file already) or the run's tail pays back what sharing saved. Without -n, or
+# with -p no:xdist, nothing here is called: placement is an economy, never a
+# condition of a test passing.
+# ---------------------------------------------------------------------------
+_ONE_WORKER = tuple("tests/unit/inference/" + name for name in (
+    "test_ling_hybrid_pool.py", "test_nemotron_h_pool.py", "test_sambay_pool.py",
+    "test_exaone_moe_pool.py", "test_lfm2_moe_pool.py", "test_falcon_h1_pool.py",
+    "test_hybrid_state.py", "test_mla_moe.py", "test_scheduler.py", "test_pump_ahead.py",
+    "test_dispatch_seam.py", "test_packed_kv_pool.py", "test_packed_kv_features.py",
+    "test_packed_kv_serving.py"))
+
+
+@pytest.hookimpl(optionalhook=True)  # (no such hook under -p no:xdist)
+def pytest_xdist_make_scheduler(config, log):
+    if config.getvalue("dist") != "load":
+        return None  # another mode was asked for by name: xdist's own
+    from xdist.scheduler import LoadScopeScheduling
+
+    class FileOrAlone(LoadScopeScheduling):
+        """A file of ``_ONE_WORKER`` is one unit of work, any other test a
+        unit of its own; units of many tests are dealt first (xdist's own
+        ``loadscopereorder``), so the long ones start with the run."""
+
+        def _split_scope(self, nodeid):
+            path = nodeid.split("::", 1)[0]
+            return path if path in _ONE_WORKER else nodeid
+
+        def _reschedule(self, node):
+            """xdist's own gives a node ONE unit when it runs low. A worker runs
+            a test only once it knows the next, so a node with one test of one
+            unit waits for ever: a worker that replaces a crashed one (D15's
+            aborts) did, with the queue's rest behind it, and the run hung.
+            Here a node is dealt units until it holds more than two tests."""
+            if node.shutting_down:
+                return
+            while self.workqueue and self._pending_of(self.assigned_work[node]) <= 2:
+                self._assign_work_unit(node)
+            if not self.workqueue:
+                node.shutdown()
+
+    return FileOrAlone(config, log)

@@ -5,10 +5,11 @@ split runs go through the very same code)."""
 
 import numpy as np
 
-import deepspeed_tpu
-from deepspeed_tpu.comm import comm
 from deepspeed_tpu.models import get_model
 from deepspeed_tpu.models import transformer as tfm
+
+from ._serving import engine as _engine
+from ._serving import fresh_process_state  # noqa: F401 (the tests' own import)
 
 HD = 64
 _RNG = np.random.default_rng(29)
@@ -22,22 +23,15 @@ def split_rule(monkeypatch):
     monkeypatch.setattr(tfm, "kv_packs", lambda head_size: False)
 
 
-def fresh_process_state():
-    comm._state["mesh"] = None
-    from deepspeed_tpu.telemetry import set_sink
-    set_sink(None)
-
-
-def engine(model="tiny", params=None, roles=None, **cb):
-    fresh_process_state()
-    cb = dict({"enabled": True, "num_slots": 2, "prefill_chunk": 16}, **cb)
+def engine(model="tiny", params=None, roles=None, slots=2, chunk=16, **cb):
+    """``tiny-gpt2``: the fused int8 decode blocks (cell 2's path). The callers
+    compare a packed pool with one served under :func:`split_rule`, or tallies
+    of what was built: programs of its own (``_serving.engine``'s ``fresh``)."""
     if roles is not None:
         cb["disaggregation"] = {"enabled": True, "roles": roles, "migrate_min_tokens": 0}
-    config = {"dtype": "float32", "max_out_tokens": 512, "continuous_batching": cb}
-    if model == "tiny-gpt2":  # the fused int8 decode blocks (cell 2's path)
-        config.update(dtype="int8", kernel_inject=True)
-    return deepspeed_tpu.init_inference(get_model(model, head_dim=HD), config=config,
-                                        params=params)
+    fused = model == "tiny-gpt2"
+    return _engine((get_model(model, head_dim=HD), params), slots, chunk, 4, fused, fresh=True,
+                   config={"max_out_tokens": 512, **({"dtype": "int8"} if fused else {})}, **cb)
 
 
 def ask(sched, prompt, sampled=False, n=8):

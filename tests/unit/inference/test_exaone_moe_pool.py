@@ -15,7 +15,6 @@ reference's float32 limit, 1e-5; the served path reads 1e-6 at worst; a wrong
 ring row, window, rotation, span or void row gives 1e-3 and up."""
 
 import dataclasses
-import hashlib
 
 import jax
 import jax.numpy as jnp
@@ -23,55 +22,25 @@ import numpy as np
 import pytest
 
 import deepspeed_tpu
-from chipbench.references import exaone_moe as ref
 from deepspeed_tpu.models import get_model
 from deepspeed_tpu.models import transformer as tfm
 
-TOL = ref.TOL["float32"]
-HP = {"eps": 1e-5, "top_k": 2, "routed_scale": 2.5, "theta": 1e6, "first": 0}
-VOCAB = 256
+from . import _ladder
+from ._serving import VOCAB
+from ._serving import prompts as _prompts
 
-
-def _params(model, seed=7):
-    """The benchmark's draw, norm scales perturbed."""
-    from chipbench.jobs.serve_ref import seeded_params
-    root = jax.random.key(seed)
-
-    def perturb(path, leaf):
-        name = jax.tree_util.keystr(path)
-        key = jax.random.fold_in(root, int(hashlib.sha256(name.encode()).hexdigest()[:7], 16))
-        if name.endswith("['scale']"):
-            return 1.0 + 0.1 * jax.random.normal(key, leaf.shape, leaf.dtype)
-        return leaf
-
-    return jax.tree_util.tree_map_with_path(perturb,
-                                            seeded_params(model, seed, jnp.dtype("float32")))
+NAME = "tiny-exaone-moe"
+ref, HP, TOL = _ladder.reference(NAME)
+DRAFT = {"spec_tokens": 1, "spec_draft": "module"}  # the module drafts on the device
 
 
 @pytest.fixture(scope="module")
 def tiny():
-    model = get_model("tiny-exaone-moe", dtype=jnp.float32)
-    return model, _params(model)
-
-
-def _engine(tiny, slots=4, chunk=16, steps=4, kernels=False, draft=False, **cb):
-    model, params = tiny
-    if draft:
-        cb = dict(cb, spec_tokens=1, spec_draft="module")
-    return deepspeed_tpu.init_inference(model, config={
-        "dtype": "float32", "kernel_inject": kernels, "max_out_tokens": 128,
-        "continuous_batching": dict({"enabled": True, "num_slots": slots,
-                                     "steps_per_sync": steps, "prefill_chunk": chunk}, **cb)},
-        params=params)
-
-
-def _prompts(lengths, seed=0):
-    rng = np.random.RandomState(seed)
-    return [[int(t) for t in rng.randint(0, VOCAB, n)] for n in lengths]
+    return _ladder.built(NAME)
 
 
 def _tree(model, params):
-    return ref.from_tree(params, model.cfg.layer_windows)
+    return _ladder.tree_of(NAME, model, params)
 
 
 def _reference(eng, prompt, tokens):
@@ -82,54 +51,56 @@ def _reference(eng, prompt, tokens):
     return lg[0], dl[0]
 
 
-def test_full_forward_matches_the_reference(tiny):
-    """40 positions pass the 8-key window five times; the module's logits of
-    every position but the last (which has no next token)."""
-    model, params = tiny
-    ids = jax.random.randint(jax.random.key(1), (2, 40), 0, VOCAB)
-    with jax.default_matmul_precision("highest"):
-        got, drafts = model.apply_with_mtp(params, ids)
-    want, want_drafts, _ = ref.forward(_tree(model, params), ids, HP)
-    for g, w in ((got, want), (drafts, want_drafts)):
-        res = ref.compare(g[:, :-1].reshape(-1, VOCAB), w.reshape(-1, VOCAB), tol=TOL)
-        assert res["ok"], res["error"]
-    # the window and the rotation matter: a reference that drops them differs
-    wrong = ref.forward(ref.from_tree(params, (0, ) * 5), ids, HP)[0]
-    assert not ref.compare(got[:, :-1].reshape(-1, VOCAB), wrong.reshape(-1, VOCAB), tol=1e-3)["ok"]
+class TestLadder(_ladder.Ladder):
+    """The module's logits ride beside the stack's: both rungs of logits are
+    this file's own."""
+    twin = NAME
 
+    def test_full_forward_matches_the_reference(self):
+        """40 positions pass the 8-key window five times; the module's logits of
+        every position but the last (which has no next token)."""
+        model, params = _ladder.built(NAME)
+        ids = jax.random.randint(jax.random.key(1), (2, 40), 0, VOCAB)
+        with jax.default_matmul_precision("highest"):
+            got, drafts = model.apply_with_mtp(params, ids)
+        want, want_drafts, _ = ref.forward(_tree(model, params), ids, HP)
+        for g, w in ((got, want), (drafts, want_drafts)):
+            res = ref.compare(g[:, :-1].reshape(-1, VOCAB), w.reshape(-1, VOCAB), tol=TOL)
+            assert res["ok"], res["error"]
+        # the window and the rotation matter: a reference that drops them differs
+        wrong = ref.forward(ref.from_tree(params, (0, ) * 5), ids, HP)[0]
+        assert not ref.compare(got[:, :-1].reshape(-1, VOCAB), wrong.reshape(-1, VOCAB), tol=1e-3)["ok"]
 
-@pytest.mark.parametrize("slots, chunk, steps, kernels, draft", [
-    (4, 16, 4, False, False), (4, 16, 4, True, False),
-    (4, 16, 4, False, True), (4, 12, 3, False, True), (4, 16, 4, True, True)])
-def test_served_path_matches_the_reference(tiny, slots, chunk, steps, kernels, draft):
-    """Prefill in chunks (a partial last one; 70 positions wrap the 8-row
-    rings eight times; chunks of 12 straddle a ring's end), then 16 tokens
-    through the pool, neighbours live in other slots, with the drafter off
-    (one column a step; with the kernels injected a ring's one column goes
-    through the paged kernel) and on (two columns a step, every draft of
-    these random weights rejected, every step a roll-back): the stack's logits
-    and, drafting, the module's beside them."""
-    eng = _engine(tiny, slots, chunk, steps, kernels, draft)
-    sched = eng.scheduler()
-    assert eng.model_config.attention_impl == ("flash" if kernels else "xla")
-    prompts = _prompts((37, 70, 9))
-    handles = [sched.submit(p, max_new_tokens=16, collect_logits=True) for p in prompts]
-    sched.drain()
-    for p, h in zip(prompts, handles):
-        want, want_drafts = _reference(eng, p, h.result())
-        res = ref.compare(h.result_logits(), want, tol=TOL)
-        assert res["ok"] and res["rows"] == 16, res["error"]
-        if draft:
-            res = ref.compare(h.result_draft_logits(), want_drafts, tol=TOL)
+    def test_served_path_matches_the_reference(self, case):
+        """Prefill in chunks (a partial last one; 70 positions wrap the 8-row
+        rings eight times; chunks of 12 straddle a ring's end), then 16 tokens
+        through the pool, neighbours live in other slots, with the drafter off
+        (one column a step; with the kernels injected a ring's one column goes
+        through the paged kernel) and on (two columns a step, every draft of
+        these random weights rejected, every step a roll-back): the stack's logits
+        and, drafting, the module's beside them."""
+        slots, chunk, steps, kernels, draft = case
+        eng = _ladder.engine(NAME, slots, chunk, steps, kernels, fresh=True, **(DRAFT if draft else {}))
+        sched = eng.scheduler()
+        assert eng.model_config.attention_impl == ("flash" if kernels else "xla")
+        prompts = _prompts((37, 70, 9))
+        handles = [sched.submit(p, max_new_tokens=16, collect_logits=True) for p in prompts]
+        sched.drain()
+        for p, h in zip(prompts, handles):
+            want, want_drafts = _reference(eng, p, h.result())
+            res = ref.compare(h.result_logits(), want, tol=TOL)
             assert res["ok"] and res["rows"] == 16, res["error"]
-            # the chosen experts of every committed position, the module's layer last
-            assert h.result_choice().shape[0] == 5 and h.result_choice().shape[1] >= len(p) + 15
-    assert sched.radix is None and sched.state_slots_reset == 3
-    if draft:
-        assert sched.drafter is None and sched.spec_drafted > 0
-        assert sched.spec_rows_void == sched.spec_drafted - sched.spec_accepted
-        # the pump kept running ahead: a device drafter reads nothing on the host
-        assert sched.syncs_ahead > sched.syncs_serial
+            if draft:
+                res = ref.compare(h.result_draft_logits(), want_drafts, tol=TOL)
+                assert res["ok"] and res["rows"] == 16, res["error"]
+                # the chosen experts of every committed position, the module's layer last
+                assert h.result_choice().shape[0] == 5 and h.result_choice().shape[1] >= len(p) + 15
+        assert sched.radix is None and sched.state_slots_reset == 3
+        if draft:
+            assert sched.drafter is None and sched.spec_drafted > 0
+            assert sched.spec_rows_void == sched.spec_drafted - sched.spec_accepted
+            # the pump kept running ahead: a device drafter reads nothing on the host
+            assert sched.syncs_ahead > sched.syncs_serial
 
 
 @pytest.mark.parametrize("sampled", [False, True], ids=["greedy", "sampled"])
@@ -140,7 +111,7 @@ def test_stream_with_the_drafter_is_the_stream_without_it(tiny, sampled):
     kw = dict(do_sample=True, temperature=0.9, top_k=40, top_p=0.95) if sampled else {}
 
     def run(**cb):
-        sched = _engine(tiny, slots=3, chunk=16, **cb).scheduler()
+        sched = _ladder.engine(NAME, slots=3, chunk=16, **cb).scheduler()
         hs = [sched.submit(p, max_new_tokens=13 + 3 * i, seed=11 + i, **kw)
               for i, p in enumerate(prompts)]
         sched.drain()
@@ -148,7 +119,7 @@ def test_stream_with_the_drafter_is_the_stream_without_it(tiny, sampled):
 
     _, plain = run(steps=3)
     for steps in ((4, ) if sampled else (1, 4)):
-        sched, drafted = run(steps=steps, draft=True)
+        sched, drafted = run(steps=steps, **DRAFT)
         assert drafted == plain
         assert sched.cache.active_slots == 0 and not sched.active
 
@@ -166,7 +137,7 @@ def _agreeing(exact):
                               layer_types=("full_attention", ), layer_windows=(0, ),
                               moe_first_dense=0)
     model = type(get_model("tiny"))(cfg)
-    p = _params(model, seed=5)
+    p = _ladder.params_of(NAME, model, seed=5)
     emb = p["embed"]["embedding"]
     p["embed"]["embedding"] = emb * jax.lax.rsqrt(jnp.mean(jnp.square(emb), -1, keepdims=True))
     layer = p["layer_0"]
@@ -190,7 +161,8 @@ def test_a_drafter_that_agrees_commits_two_a_step(exact):
     prompts = _prompts((6, 30, 19), seed=4)
 
     def run(draft):
-        sched = _engine(tiny, slots=4, chunk=16, steps=4, draft=draft).scheduler()
+        sched = _ladder.engine(NAME, slots=4, chunk=16, steps=4, twin=tiny,
+                               **(DRAFT if draft else {})).scheduler()
         hs = [sched.submit(p, max_new_tokens=40, collect_logits=True) for p in prompts]
         sched.drain()
         return sched, [h.result().tolist() for h in hs], [h.result_logits() for h in hs]
@@ -215,7 +187,8 @@ def test_a_drafter_that_never_agrees_leaves_the_pool_as_a_run_without_it(tiny):
     prompt = _prompts((45, ), seed=9)[0]
     pools = {}
     for draft in (False, True):
-        sched = _engine(tiny, slots=2, chunk=16, steps=4, draft=draft).scheduler()
+        sched = _ladder.engine(NAME, slots=2, chunk=16, steps=4,
+                               **(DRAFT if draft else {})).scheduler()
         # 24 tokens: 4 in the final chunk's sync, 20 in five more, none past the budget
         h = sched.submit(prompt, max_new_tokens=24)
         while not h.done:
@@ -339,18 +312,6 @@ def test_what_is_still_refused(tiny):
     with pytest.raises(NotImplementedError, match="span programs"):
         tiny[0].apply_with_cache(tiny[1], jnp.zeros((1, 4), jnp.int32),
                                  tiny[0].init_cache(1, 32), 0)
-
-
-@pytest.mark.parametrize("cb, message", [
-    ({"spec_tokens": 2}, "speculative verify by a host drafter"),
-    ({"spec_tokens": 2, "spec_draft": "module"}, "spec_tokens other than 1"),
-    ({"spec_tokens": 1, "spec_draft": "module", "prefill_chunk": 2}, "prefill_chunk under 3"),
-    ({"spec_tokens": 1, "spec_draft": "oracle"}, "spec_draft must be"),
-    ({"kv_cache_dtype": "int8"}, "int8 KV pool"),
-])
-def test_what_the_scheduler_still_refuses(tiny, cb, message):
-    with pytest.raises(ValueError, match=message):
-        _engine(tiny, **cb).scheduler()
 
 
 def test_a_module_drafter_needs_a_module():

@@ -20,20 +20,19 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import deepspeed_tpu
-from chipbench.references import olmo_hybrid as ref
 from deepspeed_tpu.models import get_model
 from deepspeed_tpu.models import transformer as tfm
 
-TOL = ref.TOL["float32"]
-HP = {"eps": 1e-6, "neg_eigval": True}
+from . import _ladder
+from ._serving import prompts as _prompts
+
+NAME = "tiny-hybrid"
+ref, HP, TOL = _ladder.reference(NAME)
 
 
 @pytest.fixture(scope="module")
 def tiny():
-    from chipbench.jobs.serve_hybrid import hybrid_params
-    model = get_model("tiny-hybrid", dtype=jnp.float32)
-    return model, hybrid_params(model, 7, jnp.dtype("float32"))
+    return _ladder.built(NAME)
 
 
 @pytest.fixture(scope="module")
@@ -43,42 +42,36 @@ def published_heads():
     of them: one packed unit of 384 lanes), so that the decode column's
     state update is the Pallas kernel (``ops/pallas/gdn_step.py``, interpret
     mode here) and the leaf at rest the packed one."""
-    from chipbench.jobs.serve_hybrid import hybrid_params
-    base = get_model("tiny-hybrid", dtype=jnp.float32)
+    base = _ladder.built(NAME)[0]
     model = type(base)(dataclasses.replace(
         base.cfg, linear_num_heads=2, linear_key_head_dim=96, linear_value_head_dim=192))
-    return model, hybrid_params(model, 7, jnp.dtype("float32"))
-
-
-def _engine(tiny, slots=4, chunk=16, steps=4, dtype="float32", **cb):
-    model, params = tiny
-    return deepspeed_tpu.init_inference(model, config={
-        "dtype": dtype, "kernel_inject": True, "max_out_tokens": 256,
-        "continuous_batching": dict({"enabled": True, "num_slots": slots,
-                                     "steps_per_sync": steps, "prefill_chunk": chunk}, **cb)},
-        params=params)
-
-
-def _prompts(lengths, seed=0):
-    rng = np.random.RandomState(seed)
-    return [[int(t) for t in rng.randint(0, 256, n)] for n in lengths]
+    return model, _ladder.params_of(NAME, model)
 
 
 def _reference_logits(eng, prompt, tokens):
     """The reference's logits of the positions that chose ``tokens``."""
-    cfg = eng.model_config
     ids = jnp.asarray([prompt + [int(t) for t in tokens[:-1]]], jnp.int32)
-    return ref.forward(ref.from_tree(eng.params, cfg.layer_types), ids, HP,
+    return ref.forward(_ladder.tree_of(NAME, eng.module, eng.params), ids, HP,
                        first=len(prompt) - 1)[0]
 
 
-def test_full_forward_matches_the_reference(tiny):
-    model, params = tiny
-    ids = jax.random.randint(jax.random.key(1), (2, 150), 0, 256)
-    with jax.default_matmul_precision("highest"):
-        got = model.apply(params, ids)
-    want = ref.forward(ref.from_tree(params, model.cfg.layer_types), ids, HP)
-    assert ref.compare(got.reshape(-1, 256), want.reshape(-1, 256), tol=TOL)["ok"]
+class TestLadder(_ladder.Ladder):
+    """(150 = 2 x 64 + 22 = 128 + 22 = 9 x 16 + 6: a partial last chunk at
+    every chunk size; the idle slot sits through eight chunks of a neighbour's
+    prefill.)"""
+    twin = NAME
+
+    def served_pool(self, case, sched):
+        # tiny-hybrid's heads (8 x 16) do not tile: the definition serves them
+        assert sched.gdn_step_programs["xla"] > 0 and sched.gdn_step_programs["kernel"] == 0
+
+    def more_refusals(self, eng, sched):
+        """The way in of a migration, the engine's own gate, scanned layers."""
+        with pytest.raises(ValueError, match="cannot migrate between replicas"):
+            sched.admit_migration(None)
+        assert any("layer_types" in r for r in eng._fused_decode_eligible().reasons)
+        with pytest.raises(ValueError, match="requires scan_layers=False"):
+            dataclasses.replace(eng.module.cfg, scan_layers=True)
 
 
 @pytest.mark.parametrize("length", [1, 63, 64, 150, 200])
@@ -108,27 +101,6 @@ def test_chunked_scan_matches_the_recurrence(length):
     assert rel(o_scan, jnp.stack(outs, axis=2)) < 5e-6 and rel(S_scan, S) < 5e-6
 
 
-@pytest.mark.parametrize("slots, chunk, steps, split", [
-    (4, 16, 1, False), (4, 16, 4, False), (4, 64, 4, False), (8, 64, 1, True), (8, 64, 4, True),
-    (8, 128, 4, True)])
-def test_served_path_matches_the_reference(tiny, slots, chunk, steps, split):
-    """Prefill in chunks (a partial last one: 150 = 2 x 64 + 22 = 128 + 22 =
-    9 x 16 + 6), then decode through the pool, neighbours live in other
-    slots, in the whole-block program and in the live-rows split."""
-    eng = _engine(tiny, slots, chunk, steps)
-    sched = eng.scheduler()
-    assert sched._splits_chunk(("fused", False, True, chunk, steps)) is split
-    prompts = _prompts((37, 150, 70))
-    handles = [sched.submit(p, max_new_tokens=12, collect_logits=True) for p in prompts]
-    sched.drain()
-    for p, h in zip(prompts, handles):
-        res = ref.compare(h.result_logits(), _reference_logits(eng, p, h.result()), tol=TOL)
-        assert res["ok"], res["error"]
-    assert sched.state_slots_reset == 3 and sched.radix is None
-    # tiny-hybrid's heads (8 x 16) do not tile: the definition serves them
-    assert sched.gdn_step_programs["xla"] > 0 and sched.gdn_step_programs["kernel"] == 0
-
-
 @pytest.mark.parametrize("slots, chunk, steps", [(4, 16, 4), (8, 64, 1)])
 def test_published_heads_are_served_through_the_kernel(published_heads, slots, chunk, steps):
     """The same streams at the published head shape: the decode column's
@@ -136,7 +108,7 @@ def test_published_heads_are_served_through_the_kernel(published_heads, slots, c
     converts its slot's state on load and store, and the logits are the
     reference's; the counters say ``kernel`` here and ``xla`` for
     ``tiny-hybrid``'s heads, which do not tile."""
-    eng = _engine(published_heads, slots, chunk, steps)
+    eng = _ladder.engine(NAME, slots, chunk, steps, twin=published_heads, fresh=True)
     sched = eng.scheduler()
     shapes = [leaf.shape for leaf in jax.tree_util.tree_leaves(sched.cache.pool)]
     assert shapes.count((slots, 1, 96, 384)) == 3
@@ -157,11 +129,7 @@ def test_the_kernel_serves_the_definitions_streams(published_heads):
     prompts = _prompts((23, 41), seed=3)
     got = {}
     for inject in (True, False):
-        model, params = published_heads
-        eng = deepspeed_tpu.init_inference(model, config={
-            "dtype": "float32", "kernel_inject": inject, "max_out_tokens": 256,
-            "continuous_batching": {"enabled": True, "num_slots": 4, "steps_per_sync": 4,
-                                    "prefill_chunk": 16}}, params=params)
+        eng = _ladder.engine(NAME, kernels=inject, twin=published_heads, fresh=True)
         sched = eng.scheduler()
         handles = [sched.submit(p, max_new_tokens=24, collect_logits=True) for p in prompts]
         sched.drain()
@@ -183,7 +151,8 @@ def test_mamba2_programs_are_counted_by_their_one_token_update():
     # (two of its eight layers: the programs build in a quarter of the time)
     model = type(base)(dataclasses.replace(
         base.cfg, num_layers=2, layer_types=("mamba2", "attention"), num_experts=0))
-    eng = _engine((model, jax.jit(model.init_params)(jax.random.key(7))), slots=2, steps=2)
+    eng = _ladder.engine(NAME, slots=2, steps=2, fresh=True,
+                         twin=(model, jax.jit(model.init_params)(jax.random.key(7))))
     sched = eng.scheduler()
     sched.submit(_prompts((9, ))[0], max_new_tokens=4)
     sched.drain()
@@ -203,52 +172,10 @@ def test_mamba2_programs_are_counted_by_their_one_token_update():
     assert sched.ssd_step_programs == {"kernel": 1, "xla": served["xla"]}
 
 
-def test_a_span_0_slot_is_bit_for_bit_unchanged(tiny):
-    """A sync that advances other slots leaves an idle slot's state, window
-    and rows exactly as they were: slot 1's, once its request has ended,
-    through a neighbour's chunked prefill (eight chunks) and both
-    neighbours' decode."""
-    sched = _engine(tiny, slots=4, chunk=16, steps=4).scheduler()
-    a, b, c = _prompts((20, 50, 120))
-    long_one = sched.submit(a, max_new_tokens=60)
-    short = sched.submit(b, max_new_tokens=6)  # still live when the third is admitted
-    late = sched.submit(c, max_new_tokens=8)
-    while not short.done:
-        sched.step()
-    assert sched.cache.state[1] == "free" and late._req.slot == 2 and not late.done
-    slot1 = lambda: [np.asarray(leaf[1]) for leaf in jax.tree_util.tree_leaves(sched.cache.pool)]
-    before = slot1()
-    assert all(np.any(x != 0) for x in before)
-    steps = 0
-    while not (long_one.done and late.done):
-        sched.step()
-        steps += 1
-    assert steps >= 8 and sched.cache.state[1] == "free"
-    for x, y in zip(before, slot1()):
-        np.testing.assert_array_equal(x, y)
-
-
-def test_a_reused_slot_gives_a_fresh_pools_logits(tiny):
-    """A new request in a slot that held another starts from a zero state
-    and window: its logits are a fresh pool's, bit for bit."""
-    prompt = _prompts((40, ), seed=5)[0]
-    fresh = _engine(tiny, slots=2, chunk=16).scheduler()
-    want = fresh.submit(prompt, max_new_tokens=8, collect_logits=True)
-    fresh.drain()
-    used = _engine(tiny, slots=2, chunk=16).scheduler()
-    for p in _prompts((33, 61), seed=6):
-        used.submit(p, max_new_tokens=10)
-    used.drain()
-    got = used.submit(prompt, max_new_tokens=8, collect_logits=True)
-    used.drain()
-    assert used.state_slots_reset == 3
-    np.testing.assert_array_equal(got.result_logits(), want.result_logits())
-
-
 def test_one_prompt_twice_is_served_cold_twice(tiny):
     """The radix cache is off for a pool with state: the second request finds
     no prefix, gives the same logits, and the lookups not made are counted."""
-    sched = _engine(tiny, slots=4, chunk=16).scheduler()
+    sched = _ladder.engine(NAME, slots=4, chunk=16).scheduler()
     prompt = _prompts((50, ), seed=7)[0]
     one = sched.submit(prompt, max_new_tokens=8, collect_logits=True)
     sched.drain()
@@ -256,53 +183,7 @@ def test_one_prompt_twice_is_served_cold_twice(tiny):
     sched.drain()
     np.testing.assert_array_equal(one.result_logits(), two.result_logits())
     assert sched.radix is None and sched.prefix_cache_state_bypass == 2
-    assert _engine(tiny, prefix_cache=False).scheduler().prefix_cache_state_bypass == 0
-
-
-@pytest.mark.parametrize("overrides, message", [
-    ({"spec_tokens": 2}, "speculative verify"),
-    ({"max_extents": 2}, "extent chains"),
-    ({"seq_parallel_min_tokens": 64}, "sequence-parallel prefill"),
-    ({"prefix_store": object()}, "tier demotion"),
-    ({"allow_lossy_kv": True}, "lossy KV windows"),
-    ({"kv_cache_dtype": "int8"}, "an int8 KV pool"),
-    ({"adapter_store": object()}, "adapters"),
-])
-def test_what_a_state_pool_refuses(tiny, overrides, message):
-    eng = _engine(tiny)
-    with pytest.raises(ValueError, match="holds recurrent state.*" + message):
-        eng.scheduler(**overrides)
-
-
-def test_the_other_refusals(tiny):
-    """Migration between replicas, the static-batch cache, int8 weights, a
-    tensor-parallel pool, scanned layers; the fused decode gate declines
-    with ``layer_types`` as its reason."""
-    model, params = tiny
-    eng = _engine(tiny)
-    sched = eng.scheduler()
-    with pytest.raises(ValueError, match="cannot migrate between replicas"):
-        sched.migrate_out(None, "key", None)
-    with pytest.raises(ValueError, match="cannot migrate between replicas"):
-        sched.admit_migration(None)
-    with pytest.raises(ValueError, match="continuous-batching scheduler"):
-        eng.generate([[1, 2, 3]], max_new_tokens=2)
-    assert any("layer_types" in r for r in eng._fused_decode_eligible().reasons)
-    assert any("layer_types" in r for r in sched._fused_block_reasons)
-    with pytest.raises(ValueError, match="served in its float dtype"):
-        deepspeed_tpu.init_inference(model, config={"dtype": "int8"}, params=params)
-    with pytest.raises(ValueError, match="requires scan_layers=False"):
-        dataclasses.replace(model.cfg, scan_layers=True)
-    with pytest.raises(NotImplementedError, match="span programs"):
-        model.apply_with_cache(params, jnp.zeros((2, 4), jnp.int32), model.init_cache(2, 64), 0)
-    from deepspeed_tpu.comm import comm
-    comm._state["mesh"] = None
-    comm.initialize_mesh(tensor=2)
-    tp = deepspeed_tpu.init_inference(model, config={
-        "dtype": "float32", "continuous_batching": {"enabled": True, "num_slots": 2}},
-        params=params)
-    with pytest.raises(ValueError, match="a tensor-parallel pool"):
-        tp.scheduler()
+    assert _ladder.engine(NAME, prefix_cache=False).scheduler().prefix_cache_state_bypass == 0
 
 
 @pytest.mark.parametrize("heads, widens, at_worst", [("tiny", 1.5, 0.02),
@@ -330,11 +211,8 @@ def test_bf16_state_at_rest_over_512_decode_steps(request, heads, widens, at_wor
     prompt = _prompts((24, ), seed=9)[0]
     medians = {}
     for at_rest in ("bfloat16", "auto"):
-        eng = deepspeed_tpu.init_inference(model, config={
-            "dtype": "float32", "kernel_inject": True, "max_out_tokens": 768,
-            "continuous_batching": {"enabled": True, "num_slots": 2, "steps_per_sync": 4,
-                                    "prefill_chunk": 16, "kv_cache_dtype": at_rest}},
-            params=params)
+        eng = _ladder.engine(NAME, slots=2, twin=(model, params), fresh=True,
+                             config={"max_out_tokens": 768}, kv_cache_dtype=at_rest)
         sched = eng.scheduler()
         want_dtype = jnp.dtype(jnp.bfloat16 if at_rest == "bfloat16" else jnp.float32)
         assert {leaf.dtype for leaf in jax.tree_util.tree_leaves(sched.cache.pool)} == {want_dtype}

@@ -15,116 +15,80 @@ served path reads 2e-6 at worst; a wrong carried row, span, weight or choice
 gives 1e-3 and up."""
 
 import dataclasses
-import hashlib
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import deepspeed_tpu
-from chipbench.references import lfm2_moe as ref
 from deepspeed_tpu.models import available_models, get_model, lfm2_layers
 from deepspeed_tpu.models.transformer import ShortConv
 from deepspeed_tpu.moe.sharded_moe import sigmoid_serving_choice
 
-TOL = ref.TOL["float32"]
-HP = {"eps": 1e-5, "top_k": 2, "routed_scale": 1.0, "renorm_eps": 1e-6, "theta": 1e6,
-      "first": 0}
-VOCAB = 256
+from . import _ladder
+from ._serving import prompts as _prompts
 
-
-def _params(model, seed=7):
-    """The benchmark's draw, norm scales perturbed."""
-    from chipbench.jobs.serve_nemotron_h import nemotron_params
-    root = jax.random.key(seed)
-
-    def perturb(path, leaf):
-        name = jax.tree_util.keystr(path)
-        key = jax.random.fold_in(root, int(hashlib.sha256(name.encode()).hexdigest()[:7], 16))
-        if name.endswith("['scale']"):
-            return 1.0 + 0.1 * jax.random.normal(key, leaf.shape, leaf.dtype)
-        return leaf
-
-    return jax.tree_util.tree_map_with_path(perturb,
-                                            nemotron_params(model, seed, jnp.dtype("float32")))
+NAME = "tiny-lfm2-moe"
+ref, HP, TOL = _ladder.reference(NAME)
 
 
 @pytest.fixture(scope="module")
 def tiny():
-    model = get_model("tiny-lfm2-moe", dtype=jnp.float32)
-    return model, _params(model)
+    return _ladder.built(NAME)
 
 
-def _engine(tiny, slots=4, chunk=16, steps=4, kernels=False, **cb):
-    model, params = tiny
-    return deepspeed_tpu.init_inference(model, config={
-        "dtype": "float32", "kernel_inject": kernels, "max_out_tokens": 128,
-        "continuous_batching": dict({"enabled": True, "num_slots": slots,
-                                     "steps_per_sync": steps, "prefill_chunk": chunk}, **cb)},
-        params=params)
-
-
-def _prompts(lengths, seed=0):
-    rng = np.random.RandomState(seed)
-    return [[int(t) for t in rng.randint(0, VOCAB, n)] for n in lengths]
-
-
-def _tree(model, params):
-    return ref.from_tree(params, model.cfg.layer_types)
-
-
-def test_full_forward_matches_the_reference(tiny):
-    model, params = tiny
-    ids = jax.random.randint(jax.random.key(1), (2, 70), 0, VOCAB)
-    with jax.default_matmul_precision("highest"):
-        got = model.apply(params, ids)
-    want, _ = ref.forward(_tree(model, params), ids, HP)
-    res = ref.compare(got.reshape(-1, VOCAB), want.reshape(-1, VOCAB), tol=TOL)
-    assert res["ok"], res["error"]
-    # the published epsilon is in the numbers: DeepSeek-V3's 1e-20 is another model
-    other, _ = ref.forward(_tree(model, params), ids, dict(HP, renorm_eps=1e-2))
-    assert not ref.compare(got.reshape(-1, VOCAB), other.reshape(-1, VOCAB), tol=TOL)["ok"]
-
-
-@pytest.mark.parametrize("slots, chunk, steps, split, kernels", [
-    (4, 16, 1, False, False), (4, 16, 4, False, False), (4, 2, 4, False, False),
-    (4, 1, 4, False, False), (8, 64, 4, True, False), (4, 16, 4, False, True),
-    (8, 64, 4, True, True)])
-def test_served_path_matches_the_reference(tiny, slots, chunk, steps, split, kernels):
-    """Prefill in chunks, then 16 decode steps through the pool, on LOGITS at
-    every position: a prompt inside one chunk (9), one over three chunks with
+class TestLadder(_ladder.Ladder):
+    """(The served prompts: one inside a chunk (9), one over three chunks with
     a partial last (37 = 16 + 16 + 5), last chunks of ONE and of TWO live
     positions (33, 34: shorter than and equal to the two carried rows), and
-    every chunk of 2 or of 1; neighbours live in other slots, padding columns
-    in every chunk program, in the whole-block program and in the live-rows
-    split, in XLA and through the paged kernels (interpreted) over the packed
-    K/V leaf; the reference is given the program's routing and follows none."""
-    eng = _engine(tiny, slots, chunk, steps, kernels)
-    sched = eng.scheduler()
-    assert eng.model_config.attention_impl == ("flash" if kernels else "xla")
-    assert sched._splits_chunk(("fused", False, True, chunk, steps)) is split
-    assert sched.kv_pool_geometry == "packed"
-    prompts = _prompts((37, 33, 34, 9))
-    handles = [sched.submit(p, max_new_tokens=16, collect_logits=True) for p in prompts]
-    sched.drain()
-    tree = _tree(eng.module, eng.params)
-    for p, h in zip(prompts, handles):
-        ids = jnp.asarray([p + [int(t) for t in h.result()[:-1]]], jnp.int32)
-        choice = h.result_choice()[:, None, :ids.shape[1]]
-        assert choice.shape[0] == 4  # the four expert layers; the dense ones choose nothing
-        want, routing = ref.forward(tree, ids, HP, first=len(p) - 1, choice=choice)
-        res = ref.compare(h.result_logits(), want[0], routing["followed"], routing["refused"],
-                          tol=TOL)
-        assert res["ok"] and res["rows"] == 16, res["error"]
-        assert res["routing_margin_rows"] == res["routing_refused_rows"] == 0
+    every chunk of 2 or of 1; the paged kernels over the packed K/V leaf.)"""
+    twin = NAME
+
+    def served_request(self, case, prompt, handle, tree, ids, kw):
         # a program that lost its rows at a call boundary would not pass
-        lost, _ = ref.forward(tree, ids, HP, first=len(p) - 1, choice=choice,
-                              call_starts=ref.serving_calls(len(p), ids.shape[1], chunk))
-        assert not ref.compare(h.result_logits(), lost[0], tol=TOL)["ok"]
-    assert sched.state_slots_reset == 4 and sched.radix is None
-    programs = sched.moe_dispatch_programs
-    assert programs["dense"] == 0 < programs["sparse"]
+        lost, _ = ref.forward(tree, ids, HP, **kw, call_starts=ref.serving_calls(
+            len(prompt), ids.shape[1], case["chunk"]))
+        assert not ref.compare(handle.result_logits(), lost[0], tol=TOL)["ok"]
+
+    def served_pool(self, case, sched):
+        programs = sched.moe_dispatch_programs
+        assert programs["dense"] == 0 < programs["sparse"]
+
+    def used_pool(self, used):
+        """The fillers left carried rows in slot 0 of every convolution layer."""
+        rows = [leaf for leaf, k in zip(jax.tree_util.tree_leaves(used.cache.pool),
+                                        used.cache.leaf_kinds) if k == "state"]
+        assert len(rows) == 5 and all(bool(jnp.any(leaf[0] != 0)) for leaf in rows)
+
+    def more_refusals(self, eng, sched):
+        """An int8 tier, taps under 2, a mix with the SambaY and one-sublayer
+        kinds, experts without the dropless dispatch, a drafting module, the
+        packed geometry beside any other state."""
+        model = eng.module
+        cfg = model.cfg
+        with pytest.raises(NotImplementedError, match="no int8 tier"):
+            model.init_cache(2, 64, quantized=True)
+        with pytest.raises(ValueError, match="short_conv_kernel"):
+            dataclasses.replace(cfg, short_conv_kernel=1)
+        with pytest.raises(ValueError, match="do not mix"):
+            dataclasses.replace(cfg, layer_types=("short_conv", "mamba") + cfg.layer_types[2:])
+        with pytest.raises(ValueError, match="do not mix"):
+            dataclasses.replace(cfg, layer_types=("short_conv", "mlp") + cfg.layer_types[2:])
+        # (experts beside a linear-attention layer are served since PR 54: the mix builds)
+        mixed = dataclasses.replace(cfg, layer_types=("short_conv", "linear_attention")
+                                    + cfg.layer_types[2:], linear_num_heads=4,
+                                    linear_key_head_dim=8, linear_value_head_dim=16)
+        assert mixed.layer_parts(2) == ("full_attention", "moe")
+        with pytest.raises(ValueError, match="linear_attention and short_conv layers"):
+            dataclasses.replace(mixed, moe_dropless=False)
+        with pytest.raises(ValueError, match="short_conv or one-sublayer"):
+            dataclasses.replace(cfg, mtp_layers=1)
+        with pytest.raises(ValueError, match="only a diff_attention or full_attention layer"):
+            dataclasses.replace(cfg, layer_windows=(8, 0, 0, 0, 0, 0))
+        # packed K/V rows rest beside a short convolution's rows and beside no other state
+        hybrid = get_model("tiny-hybrid")
+        with pytest.raises(NotImplementedError, match="no packed geometry"):
+            type(hybrid)(dataclasses.replace(hybrid.cfg, head_dim=64)).init_cache(2, 64)
 
 
 def _mixer(H=32, W=3, seed=0):
@@ -181,49 +145,6 @@ def test_mixer_chunk_form_one_token_form_and_a_plain_loop_agree(W):
         mixer.apply({"params": params}, x, None, None, jnp.ones((1, 12), bool))
 
 
-def test_a_span_0_slot_is_bit_for_bit_unchanged(tiny):
-    """A sync that advances other slots leaves an idle slot's carried rows and
-    K/V rows exactly as they were: slot 1's, once its request has ended."""
-    sched = _engine(tiny, slots=4, chunk=16, steps=4).scheduler()
-    a, b, c = _prompts((20, 50, 100))
-    long_one = sched.submit(a, max_new_tokens=60)
-    short = sched.submit(b, max_new_tokens=6)
-    late = sched.submit(c, max_new_tokens=8)
-    while not short.done:
-        sched.step()
-    assert sched.cache.state[1] == "free" and late._req.slot == 2 and not late.done
-    slot1 = lambda: [np.asarray(leaf[1]) for leaf in jax.tree_util.tree_leaves(sched.cache.pool)]
-    before = slot1()
-    assert len(before) == 6 and all(np.any(x != 0) for x in before)
-    while not (long_one.done and late.done):
-        sched.step()
-    for x, y in zip(before, slot1()):
-        np.testing.assert_array_equal(x, y)
-
-
-def test_a_freed_slot_leaks_no_state_into_its_next_request(tiny):
-    """A new request in a slot that held another starts from zeroed rows: its
-    logits are a fresh pool's, bit for bit, whatever the slot held and
-    whatever the neighbours; one prompt twice is served cold twice and
-    counted."""
-    prompt = _prompts((40, ), seed=5)[0]
-    fresh = _engine(tiny, slots=2, chunk=16).scheduler()
-    want = fresh.submit(prompt, max_new_tokens=8, collect_logits=True)
-    fresh.drain()
-    used = _engine(tiny, slots=2, chunk=16).scheduler()
-    for p in _prompts((33, 61), seed=6):
-        used.submit(p, max_new_tokens=10)
-    used.drain()
-    rows = [leaf for leaf, k in zip(jax.tree_util.tree_leaves(used.cache.pool),
-                                    used.cache.leaf_kinds) if k == "state"]
-    assert len(rows) == 5 and all(bool(jnp.any(leaf[0] != 0)) for leaf in rows)
-    for _ in range(2):
-        got = used.submit(prompt, max_new_tokens=8, collect_logits=True)
-        used.drain()
-        np.testing.assert_array_equal(got.result_logits(), want.result_logits())
-    assert used.state_slots_reset == 4 and used.prefix_cache_state_bypass == 4
-
-
 def test_what_each_layer_kind_declares_and_what_the_pool_counts(tiny):
     """A short_conv layer declares ONE ``state`` leaf ``(slots, 1, L - 1,
     hidden)`` and nothing else, an attention layer its packed rows; the pool
@@ -244,72 +165,6 @@ def test_what_each_layer_kind_declares_and_what_the_pool_counts(tiny):
     split = type(model)(dataclasses.replace(model.cfg, head_dim=16))
     assert split.cache_kinds() == (("state", "state", "rows", "state", "state", "state"),
                                    (None, None, "rows", None, None, None))
-
-
-@pytest.mark.parametrize("overrides, message", [
-    ({"spec_tokens": 2}, "speculative verify"),
-    ({"max_extents": 2}, "extent chains"),
-    ({"seq_parallel_min_tokens": 64}, "sequence-parallel prefill"),
-    ({"prefix_store": object()}, "tier demotion"),
-    ({"allow_lossy_kv": True}, "lossy KV windows"),
-    ({"kv_cache_dtype": "int8"}, "an int8 KV pool"),
-    ({"adapter_store": object()}, "adapters"),
-])
-def test_what_a_pool_with_carried_rows_refuses(tiny, overrides, message):
-    eng = _engine(tiny, kernels=True)
-    with pytest.raises(ValueError, match=r"holds recurrent state \(layer_types\).*" + message):
-        eng.scheduler(**overrides)
-
-
-def test_the_other_refusals(tiny):
-    """The static-batch cache, int8 weights, a tensor-parallel pool, an int8
-    tier, taps under 2, a mix with the SambaY and one-sublayer kinds, experts
-    without the dropless dispatch, a drafting module, the packed geometry
-    beside any other state; the fused decode gate declines by kind."""
-    model, params = tiny
-    cfg = model.cfg
-    eng = _engine(tiny)
-    sched = eng.scheduler()
-    with pytest.raises(ValueError, match="cannot migrate between replicas"):
-        sched.migrate_out(None, "key", None)
-    with pytest.raises(ValueError, match="continuous-batching scheduler"):
-        eng.generate([[1, 2, 3]], max_new_tokens=2)
-    assert any("short_conv" in r for r in sched._fused_block_reasons)
-    with pytest.raises(ValueError, match="served in its float dtype"):
-        deepspeed_tpu.init_inference(model, config={"dtype": "int8"}, params=params)
-    with pytest.raises(NotImplementedError, match="span programs"):
-        model.apply_with_cache(params, jnp.zeros((2, 4), jnp.int32), model.init_cache(2, 64), 0)
-    with pytest.raises(NotImplementedError, match="no int8 tier"):
-        model.init_cache(2, 64, quantized=True)
-    with pytest.raises(ValueError, match="short_conv_kernel"):
-        dataclasses.replace(cfg, short_conv_kernel=1)
-    with pytest.raises(ValueError, match="do not mix"):
-        dataclasses.replace(cfg, layer_types=("short_conv", "mamba") + cfg.layer_types[2:])
-    with pytest.raises(ValueError, match="do not mix"):
-        dataclasses.replace(cfg, layer_types=("short_conv", "mlp") + cfg.layer_types[2:])
-    # (experts beside a linear-attention layer are served since PR 54: the mix builds)
-    mixed = dataclasses.replace(cfg, layer_types=("short_conv", "linear_attention")
-                                + cfg.layer_types[2:], linear_num_heads=4,
-                                linear_key_head_dim=8, linear_value_head_dim=16)
-    assert mixed.layer_parts(2) == ("full_attention", "moe")
-    with pytest.raises(ValueError, match="linear_attention and short_conv layers"):
-        dataclasses.replace(mixed, moe_dropless=False)
-    with pytest.raises(ValueError, match="short_conv or one-sublayer"):
-        dataclasses.replace(cfg, mtp_layers=1)
-    with pytest.raises(ValueError, match="only a diff_attention or full_attention layer"):
-        dataclasses.replace(cfg, layer_windows=(8, 0, 0, 0, 0, 0))
-    # packed K/V rows rest beside a short convolution's rows and beside no other state
-    hybrid = get_model("tiny-hybrid")
-    with pytest.raises(NotImplementedError, match="no packed geometry"):
-        type(hybrid)(dataclasses.replace(hybrid.cfg, head_dim=64)).init_cache(2, 64)
-    from deepspeed_tpu.comm import comm
-    comm._state["mesh"] = None
-    comm.initialize_mesh(tensor=2)
-    tp = deepspeed_tpu.init_inference(model, config={
-        "dtype": "float32", "continuous_batching": {"enabled": True, "num_slots": 2}},
-        params=params)
-    with pytest.raises(ValueError, match="a tensor-parallel pool"):
-        tp.scheduler()
 
 
 def test_router_with_the_published_epsilon_and_the_older_ones_as_they_were():
@@ -390,12 +245,8 @@ def test_counters_of_required_convolution_work(tiny, tmp_path):
     """Hand-counted: one request of 20 prompt tokens, chunk 16, K = 4, alone
     in the pool; 5 short-convolution layers, 4 expert layers of 8 experts
     top-2, all held."""
-    model, params = tiny
-    eng = deepspeed_tpu.init_inference(model, config={
-        "dtype": "float32", "max_out_tokens": 128,
-        "continuous_batching": {"enabled": True, "num_slots": 2, "steps_per_sync": 4,
-                                "prefill_chunk": 16},
-        "telemetry": {"enabled": True, "output_path": str(tmp_path)}}, params=params)
+    eng = _ladder.engine(NAME, slots=2, config={
+        "telemetry": {"enabled": True, "output_path": str(tmp_path)}})
     sched = eng.scheduler()
     sched.submit(_prompts((20, ))[0], max_new_tokens=8)
     sched.drain()

@@ -16,23 +16,22 @@ scales moved off 1, so that a dropped scale shows. ``TOL``: the reference's
 float32 limit, 1e-5; the served path reads 2e-6 at worst."""
 
 import dataclasses
-import hashlib
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import deepspeed_tpu
-from chipbench.references import ling_hybrid as ref
 from deepspeed_tpu.models import get_model
 from deepspeed_tpu.models import transformer as tfm
 from deepspeed_tpu.moe.sharded_moe import sigmoid_serving_choice
 
-TOL = ref.TOL["float32"]
-HP = {"eps": 1e-6, "top_k": 4, "routed_scale": 2.5, "renorm_eps": 1e-20, "n_group": 4,
-      "topk_group": 2, "decay_lower_bound": -5.0, "theta": 1e4, "first": 0}
-VOCAB = 256
+from . import _ladder
+from ._serving import VOCAB
+from ._serving import prompts as _prompts
+
+NAME = "tiny-ling"
+ref, HP, TOL = _ladder.reference(NAME)
 CUT = dict(num_layers=7, layer_types=["linear_attention"] * 5 + ["full_attention",
                                                                  "linear_attention"],
            moe_first_dense=1, moe_experts_held=128, moe_first_expert=0, vocab_size=39296,
@@ -40,97 +39,31 @@ CUT = dict(num_layers=7, layer_types=["linear_attention"] * 5 + ["full_attention
            moe_shared_swiglu_limits=[0] * 7)
 
 
-def _params(model, seed=7):
-    """The benchmark's draw, norm scales perturbed."""
-    from chipbench.jobs.serve_ling_hybrid import ling_params
-    root = jax.random.key(seed)
-
-    def perturb(path, leaf):
-        name = jax.tree_util.keystr(path)
-        key = jax.random.fold_in(root, int(hashlib.sha256(name.encode()).hexdigest()[:7], 16))
-        if name.endswith("['scale']"):
-            return 1.0 + 0.1 * jax.random.normal(key, leaf.shape, leaf.dtype)
-        return leaf
-
-    return jax.tree_util.tree_map_with_path(perturb,
-                                            ling_params(model, seed, jnp.dtype("float32")))
-
-
 @pytest.fixture(scope="module")
 def tiny():
-    model = get_model("tiny-ling", dtype=jnp.float32)
-    return model, _params(model)
-
-
-def _engine(tiny, slots=4, chunk=8, steps=4, kernels=False, **cb):
-    model, params = tiny
-    return deepspeed_tpu.init_inference(model, config={
-        "dtype": "float32", "kernel_inject": kernels, "max_out_tokens": 128,
-        "continuous_batching": dict({"enabled": True, "num_slots": slots,
-                                     "steps_per_sync": steps, "prefill_chunk": chunk}, **cb)},
-        params=params)
-
-
-def _prompts(lengths, seed=0):
-    rng = np.random.RandomState(seed)
-    return [[int(t) for t in rng.randint(0, VOCAB, n)] for n in lengths]
+    return _ladder.built(NAME)
 
 
 def _tree(model, params):
-    return ref.from_tree(params, model.cfg.layer_types)
+    return _ladder.tree_of(NAME, model, params)
 
 
-def test_full_forward_matches_the_reference(tiny):
-    """70 positions: four of the scan's chunks of 16 and a padded fifth."""
-    model, params = tiny
-    ids = jax.random.randint(jax.random.key(1), (2, 70), 0, VOCAB)
-    with jax.default_matmul_precision("highest"):
-        got = model.apply(params, ids)
-    tree = _tree(model, params)
-    want, _ = ref.forward(tree, ids, HP)
-    res = ref.compare(got.reshape(-1, VOCAB), want.reshape(-1, VOCAB), tol=TOL)
-    assert res["ok"], res["error"]
-    # a decay a head, no group limit: other models
-    for other in (dict(head_decay=True), dict(group_limit=False)):
-        lost, _ = ref.forward(tree, ids, HP, **other)
-        assert not ref.compare(got.reshape(-1, VOCAB), lost.reshape(-1, VOCAB), tol=TOL)["ok"]
+class TestLadder(_ladder.Ladder):
+    """(70 positions: four of the scan's chunks of 16 and a padded fifth. The
+    served prompts: one inside a chunk or two (9), one over boundaries with a
+    partial last (37), last chunks of ONE and of TWO live positions (33, 34:
+    fewer than the window's three carried inputs); with two slots for four
+    requests, a slot freed and taken by a new request, which starts from a
+    zero state and window whatever the last one left. The refusals: the latent
+    pool's and the state pool's, both; the first that applies is named.)"""
+    twin = NAME
 
-
-@pytest.mark.parametrize("slots, chunk, steps, kernels", [
-    (4, 8, 4, False), (4, 16, 1, False), (2, 1, 4, False), (8, 8, 4, True)])
-def test_served_path_matches_the_reference(tiny, slots, chunk, steps, kernels):
-    """Prefill in chunks, then 16 decode steps through the pool, on LOGITS at
-    every position: a prompt inside a chunk or two (9), one over boundaries
-    with a partial last (37), last chunks of ONE and of TWO live positions
-    (33, 34: fewer than the window's three carried inputs); neighbours live
-    in other slots, padding columns in every chunk program; state and window
-    carried from chunk to chunk and into the decode column, the latent
-    layers' rows committed beside them; with two slots for four requests, a
-    slot freed and taken by a new request, which starts from a zero state and
-    window whatever the last one left; the reference is given the program's
-    routing and follows none."""
-    eng = _engine(tiny, slots, chunk, steps, kernels)
-    sched = eng.scheduler()
-    assert sched.kv_pool_geometry == "latent"
-    prompts = _prompts((37, 33, 34, 9))
-    handles = [sched.submit(p, max_new_tokens=16, collect_logits=True) for p in prompts]
-    sched.drain()
-    tree = _tree(eng.module, eng.params)
-    for p, h in zip(prompts, handles):
-        ids = jnp.asarray([p + [int(t) for t in h.result()[:-1]]], jnp.int32)
-        choice = h.result_choice()[:, None, :ids.shape[1]]
-        assert choice.shape[0] == 6  # the six expert layers; the dense one chooses nothing
-        want, routing = ref.forward(tree, ids, HP, first=len(p) - 1, choice=choice)
-        res = ref.compare(h.result_logits(), want[0], routing["followed"], routing["refused"],
-                          tol=TOL)
-        assert res["ok"] and res["rows"] == 16, res["error"]
-        assert res["routing_margin_rows"] == res["routing_refused_rows"] == 0
-    assert sched.state_slots_reset == 4 and sched.radix is None
-    # two latent layers' rows of 16 + 8 values; five KDA layers' state and window
-    assert sched.cache.bytes_per_token() == 2 * 24 * 4
-    assert sched.cache.state_bytes_per_slot() == 5 * (4 * 16 * 16 + 3 * 3 * 64) * 4
-    # the tiny heads do not tile: the definition serves the decode column
-    assert sched.gdn_step_programs["kernel"] == 0 < sched.gdn_step_programs["xla"]
+    def served_pool(self, case, sched):
+        # two latent layers' rows of 16 + 8 values; five KDA layers' state and window
+        assert sched.cache.bytes_per_token() == 2 * 24 * 4
+        assert sched.cache.state_bytes_per_slot() == 5 * (4 * 16 * 16 + 3 * 3 * 64) * 4
+        # the tiny heads do not tile: the definition serves the decode column
+        assert sched.gdn_step_programs["kernel"] == 0 < sched.gdn_step_programs["xla"]
 
 
 def test_a_span_0_slot_is_bit_for_bit_unchanged(tiny):
@@ -342,17 +275,6 @@ def test_what_the_preset_refuses(overrides, message):
         get_model("ling-3.0-flash", **overrides)
 
 
-@pytest.mark.parametrize("cb, message", [
-    (dict(spec_tokens=2), "recurrent state cannot roll back"),
-    (dict(kv_cache_dtype="int8"), "an int8 KV pool"),
-    (dict(spec_tokens=1, spec_draft="module"), "a latent pool"),
-])
-def test_what_a_pool_of_state_and_latent_rows_refuses(tiny, cb, message):
-    """The latent pool's refusals and the state pool's, both: the first that
-    applies is named."""
-    with pytest.raises((ValueError, NotImplementedError), match=message):
-        _engine(tiny, **cb).scheduler()
-
 @pytest.mark.parametrize("chunk", [1, 8], ids=["column", "chunk"])
 def test_state_beside_latent_columns_through_a_sync_of_each_width(tiny, monkeypatch, chunk):
     """Cell 10's tree (a state leaf and a window beside a latent leaf of
@@ -368,7 +290,7 @@ def test_state_beside_latent_columns_through_a_sync_of_each_width(tiny, monkeypa
         with monkeypatch.context() as mp:
             if not in_place:
                 mp.setattr(kv_commit, "commits_columns_in_place", lambda leaf: False)
-            sched = _engine(tiny, slots=3, chunk=chunk, steps=4).scheduler()
+            sched = _ladder.engine(NAME, slots=3, chunk=chunk, steps=4, fresh=True).scheduler()
             handles = [sched.submit(p, max_new_tokens=6, collect_logits=True) for p in prompts]
             pools = []
             while not all(h.done for h in handles):
@@ -388,31 +310,3 @@ def test_state_beside_latent_columns_through_a_sync_of_each_width(tiny, monkeypa
             np.testing.assert_array_equal(x.view(np.uint8), y.view(np.uint8))
     for a, b in zip(logits, logits_scatter):
         assert np.array_equal(a, b)
-
-
-# (name, digest of the parameter tree's (path, shape, dtype), num_params) of
-# the models of cells 5 and 4, and the tiny twins' logits (sum, sum of
-# magnitudes) on seeded weights, as the parent of PR 54 built them
-BEFORE = {"olmo-hybrid-7b": ("7bf46abc96dcf40d", 7430870688),
-          "mistral-small-4-119b": ("9f1f4c1b70b5955c", 118972780544)}
-LOGITS_BEFORE = {"tiny-hybrid": (845.377414025158, 28211.244966304577),
-                 "tiny-mla-moe": (-210.4655717877904, 28185.74727749292)}
-
-
-@pytest.mark.parametrize("name", sorted(BEFORE))
-def test_the_older_models_build_the_trees_they_built(name):
-    model = get_model(name)
-    shapes = jax.eval_shape(model.init_params, jax.random.key(0))
-    flat = sorted((jax.tree_util.keystr(p), tuple(x.shape), str(x.dtype))
-                  for p, x in jax.tree_util.tree_flatten_with_path(shapes)[0])
-    assert (hashlib.sha256(repr(flat).encode()).hexdigest()[:16],
-            model.cfg.num_params()) == BEFORE[name]
-
-
-@pytest.mark.parametrize("name", sorted(LOGITS_BEFORE))
-def test_the_older_models_give_the_logits_they_gave(name):
-    model = get_model(name, dtype=jnp.float32)
-    params = model.init_params(jax.random.key(0))
-    ids = jax.random.randint(jax.random.key(1), (2, 70), 0, 256)
-    out = np.asarray(model.apply(params, ids), np.float64)
-    np.testing.assert_allclose((out.sum(), np.abs(out).sum()), LOGITS_BEFORE[name], rtol=1e-9)

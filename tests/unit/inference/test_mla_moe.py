@@ -21,6 +21,8 @@ from deepspeed_tpu.comm import comm
 from deepspeed_tpu.models import get_model
 from deepspeed_tpu.moe import layer as moe_layer
 
+from . import _serving
+
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))))
 F32_TOL = 1e-4  # float32 on the CPU against float32 "highest": 2e-7 measured
 RULE = moe_layer.dense_held_pays  # the rule itself, for the tests of it
@@ -52,13 +54,10 @@ def tiny():
     return model, params, hp, ids
 
 
-def _engine(model="tiny-mla-moe", params=None, **cb):
-    comm._state["mesh"] = None
-    from deepspeed_tpu.telemetry import set_sink
-    set_sink(None)
-    return deepspeed_tpu.init_inference(model, params=params, config={
-        "dtype": "float32", "max_out_tokens": 128,
-        "continuous_batching": dict({"enabled": True, "num_slots": 4, "prefill_chunk": 16}, **cb)})
+def _served(params, slots=4, chunk=16, **cb):
+    """An engine over the preset; every case of this file runs under the
+    patched rule, so its programs are its own (``fresh``)."""
+    return _serving.engine(("tiny-mla-moe", params), slots, chunk, fresh=True, **cb)
 
 
 def test_preset_builds_the_published_sizes():
@@ -122,7 +121,7 @@ def test_scheduler_prefill_chunks_and_decode_match_reference(tiny):
     decoding row) then decode through the latent pool, logits not tokens,
     against the reference's full forward."""
     model, params, hp, _ = tiny
-    eng = _engine(params=params)
+    eng = _served(params)
     sched = eng.scheduler()
     rng = np.random.default_rng(1)
     prompts = [[int(t) for t in rng.integers(0, 256, n)] for n in (9, 40)]
@@ -220,11 +219,11 @@ def test_latent_pool_bytes_copy_and_radix_hit(tiny):
     is served through the radix copy and gives the same logits."""
     from deepspeed_tpu.inference.kv_cache import copy_slot
     model, params, _, _ = tiny
-    eng = _engine(params=params)
+    eng = _served(params)
     sched = eng.scheduler()
     cfg = model.cfg
     assert sched.cache.bytes_per_token() == cfg.num_layers * cfg.latent_width * 4
-    bf16 = _engine(params=params, kv_cache_dtype="bfloat16").scheduler()
+    bf16 = _served(params, kv_cache_dtype="bfloat16").scheduler()
     assert bf16.cache.bytes_per_token() == cfg.num_layers * cfg.latent_width * 2
     leaves = jax.tree_util.tree_leaves(sched.cache.pool)
     # position-last: a position is a column (the same bytes a token as rows of 24)
@@ -254,7 +253,7 @@ def test_latent_columns_commit_in_place_and_a_retained_prefix_stays_byte_stable(
     other requests prefill and decode beside it (it rides every sync with
     span 0)."""
     _, params, _, _ = tiny
-    sched = _engine(params=params).scheduler()
+    sched = _served(params).scheduler()
     rng = np.random.default_rng(5)
     kept = [int(t) for t in rng.integers(0, 256, 40)]
     sched.submit(kept, max_new_tokens=4).result()
@@ -277,10 +276,10 @@ def test_a_reused_slot_serves_from_position_zero_whatever_columns_it_held(tiny):
     _, params, _, _ = tiny
     rng = np.random.default_rng(6)
     long_, short = ([int(t) for t in rng.integers(0, 256, n)] for n in (70, 12))
-    used = _engine(params=params, num_slots=1, prefix_cache=False).scheduler()
+    used = _served(params, 1, prefix_cache=False).scheduler()
     used.submit(long_, max_new_tokens=20).result()
     h = used.submit(short, max_new_tokens=8, collect_logits=True)
-    fresh = _engine(params=params, num_slots=1, prefix_cache=False).scheduler()
+    fresh = _served(params, 1, prefix_cache=False).scheduler()
     g = fresh.submit(short, max_new_tokens=8, collect_logits=True)
     assert list(h.result()) == list(g.result())
     assert np.array_equal(h.result_logits(), g.result_logits())
@@ -324,7 +323,7 @@ def test_block_walk_on_the_position_last_leaf_matches_the_plain_softmax(T, maske
 def test_latent_pool_refuses_what_it_does_not_support(tiny, option, message):
     model, params, _, _ = tiny
     with pytest.raises(ValueError, match="latent KV pool does not support.*" + message):
-        _engine(params=params).scheduler(**option)
+        _served(params).scheduler(**option)
 
 
 def test_latent_model_refuses_int8_weights():
@@ -552,7 +551,7 @@ def test_two_chunk_prompts_beside_decoding_rows_match_reference(tiny):
     that decode, every request's logits against the reference that follows
     its routing choice (one entry a position the programs ran, in order)."""
     model, params, hp, _ = tiny
-    eng = _engine(params=params, num_slots=16, prefill_chunk=64)
+    eng = _served(params, 16, 64)
     sched = eng.scheduler()
     rng = np.random.default_rng(7)
     prompts = [[int(t) for t in rng.integers(0, 256, n)] for n in (12, 100, 70, 100)]

@@ -15,76 +15,61 @@ that a dropped bias or scale shows. ``TOL``: the reference's float32 limit,
 choice gives 1e-3 and up."""
 
 import dataclasses
-import hashlib
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import deepspeed_tpu
 from chipbench.references import nemotron_h as ref
-from deepspeed_tpu.models import available_models, get_model, mamba2, nemotron_h_layers
+from deepspeed_tpu.models import get_model, mamba2, nemotron_h_layers
 from deepspeed_tpu.moe.sharded_moe import sigmoid_serving_choice
 
-TOL = ref.TOL["float32"]
-HP = {"eps": 1e-5, "top_k": 2, "routed_scale": 2.5, "ssm_heads": 4, "ssm_head_dim": 8,
-      "ssm_state": 16, "ssm_groups": 2, "first": 0}
-VOCAB = 256
+from . import _ladder
+from ._serving import VOCAB
+from ._serving import prompts as _prompts
 
-
-def _params(model, seed=7):
-    """The benchmark's draw, biases and norm scales perturbed."""
-    from chipbench.jobs.serve_nemotron_h import nemotron_params
-    root = jax.random.key(seed)
-
-    def perturb(path, leaf):
-        name = jax.tree_util.keystr(path)
-        key = jax.random.fold_in(root, int(hashlib.sha256(name.encode()).hexdigest()[:7], 16))
-        if name.endswith("['conv_bias']"):
-            return 0.1 * jax.random.normal(key, leaf.shape, leaf.dtype)
-        if name.endswith("['scale']") or name.endswith("['D']"):
-            return 1.0 + 0.1 * jax.random.normal(key, leaf.shape, leaf.dtype)
-        return leaf
-
-    return jax.tree_util.tree_map_with_path(perturb,
-                                            nemotron_params(model, seed, jnp.dtype("float32")))
+NAME = "tiny-nemotron-h"
+_, HP, TOL = _ladder.reference(NAME)
 
 
 @pytest.fixture(scope="module")
 def tiny():
-    model = get_model("tiny-nemotron-h", dtype=jnp.float32)
-    return model, _params(model)
-
-
-def _engine(tiny, slots=4, chunk=16, steps=4, kernels=False, **cb):
-    model, params = tiny
-    return deepspeed_tpu.init_inference(model, config={
-        "dtype": "float32", "kernel_inject": kernels, "max_out_tokens": 128,
-        "continuous_batching": dict({"enabled": True, "num_slots": slots,
-                                     "steps_per_sync": steps, "prefill_chunk": chunk}, **cb)},
-        params=params)
-
-
-def _prompts(lengths, seed=0):
-    rng = np.random.RandomState(seed)
-    return [[int(t) for t in rng.randint(0, VOCAB, n)] for n in lengths]
+    return _ladder.built(NAME)
 
 
 def _tree(model, params):
-    return ref.from_tree(params, model.cfg.layer_types)
+    return _ladder.tree_of(NAME, model, params)
 
 
 def _agrees(model, params, ids, hp=HP):
-    with jax.default_matmul_precision("highest"):
-        got = model.apply(params, ids)
-    want, _ = ref.forward(_tree(model, params), ids, hp)
-    res = ref.compare(got.reshape(-1, VOCAB), want.reshape(-1, VOCAB), tol=TOL)
+    res = _ladder.agrees(NAME, model, params, ids, hp)
     assert res["ok"], res["error"]
 
 
-def test_full_forward_matches_the_reference(tiny):
-    _agrees(*tiny, jax.random.randint(jax.random.key(1), (2, 70), 0, VOCAB))
+class TestLadder(_ladder.Ladder):
+    twin = NAME
+
+    def served_pool(self, case, sched):
+        assert sched.moe_dispatch_programs["dense"] == 0 < sched.moe_dispatch_programs["sparse"]
+
+    def more_refusals(self, eng, sched):
+        """Experts outside ``moe`` layers, a mix with two-sublayer kinds, the
+        training router, an int8 tier."""
+        model, _ = _ladder.built(NAME)
+        with pytest.raises(NotImplementedError, match="no int8 tier"):
+            model.init_cache(2, 64, quantized=True)
+        cfg = model.cfg
+        with pytest.raises(ValueError, match="do not mix"):
+            dataclasses.replace(cfg, num_layers=2, layer_types=("mamba2", "full_attention"))
+        with pytest.raises(ValueError, match="moe layers need num_experts"):
+            dataclasses.replace(cfg, num_layers=1, layer_types=("mamba2", ))
+        with pytest.raises(ValueError, match="experts in a mixer-and-FFN block"):
+            dataclasses.replace(get_model("tiny-sambay").cfg, num_experts=4, moe_dropless=True)
+        with pytest.raises(ValueError, match="ssm_num_heads"):
+            dataclasses.replace(cfg, ssm_groups=3)
+        with pytest.raises(ValueError, match="no capacity-buffered path"):
+            dataclasses.replace(get_model("tiny-moe").cfg, moe_scoring="sigmoid")
 
 
 @pytest.mark.parametrize("kinds", [("mamba2", ), ("attention", ), ("moe", ), ("mlp", )])
@@ -94,7 +79,7 @@ def test_each_sublayer_alone_matches_its_reference(tiny, kinds):
     cfg = dataclasses.replace(tiny[0].cfg, num_layers=1, layer_types=kinds,
                               num_experts=8 if kinds == ("moe", ) else 0)
     model = type(tiny[0])(cfg)
-    _agrees(model, _params(model, seed=11),
+    _agrees(model, _ladder.params_of(NAME, model, seed=11),
             jax.random.randint(jax.random.key(2), (2, 50), 0, VOCAB))
 
 
@@ -154,7 +139,7 @@ def test_expert_layer_scales_by_the_routed_factor(tiny):
     model, params = tiny
     cfg = dataclasses.replace(model.cfg, num_layers=1, layer_types=("moe", ))
     one = type(model)(cfg)
-    p = _params(one, seed=5)
+    p = _ladder.params_of(NAME, one, seed=5)
     ids = jax.random.randint(jax.random.key(3), (1, 20), 0, VOCAB)
     _agrees(one, p, ids)
     with pytest.raises(AssertionError):
@@ -170,7 +155,7 @@ def test_shares_of_the_experts_add_up_to_the_uncut_layer(tiny):
     from deepspeed_tpu.moe.layer import MoE
     model, _ = tiny
     whole_cfg = dataclasses.replace(model.cfg, num_layers=1, layer_types=("moe", ))
-    p = _params(type(model)(whole_cfg), seed=9)["layer_0"]["moe"]
+    p = _ladder.params_of(NAME, type(model)(whole_cfg), seed=9)["layer_0"]["moe"]
     x = jax.random.normal(jax.random.key(4), (2, 13, 64))
     lp = {k: jnp.asarray(v, jnp.float32) for k, v in dict(
         gate=p["gate"], bias=p["e_score_correction_bias"], w_up=p["experts"]["up_proj"],
@@ -204,7 +189,7 @@ def test_both_dispatches_of_the_expert_layer_agree_bit_for_bit(tiny, monkeypatch
     model, _ = tiny
     cfg = dataclasses.replace(model.cfg, num_layers=1, layer_types=("moe", ),
                               moe_experts_held=held, moe_first_expert=first)
-    p = _params(type(model)(dataclasses.replace(cfg, moe_experts_held=8, moe_first_expert=0)),
+    p = _ladder.params_of(NAME, type(model)(dataclasses.replace(cfg, moe_experts_held=8, moe_first_expert=0)),
                 seed=9)["layer_0"]["moe"]
     p = dict(p, experts={k: v[first:first + held] for k, v in p["experts"].items()})
     x = jax.random.normal(jax.random.key(4), (3, 11, 64))
@@ -234,7 +219,7 @@ def test_the_rule_picks_each_step_programs_dispatch(tiny, monkeypatch, slots, ch
     # the tiny widths are no multiples of 256 and would go dense at every N: by rows an expert
     monkeypatch.setattr(moe_layer, "dense_held_pays", lambda N, k, E, H, F: 2 * E < N * k)
     pays = lambda n: 2 * cfg.num_experts < n * cfg.moe_top_k
-    sched = _engine(tiny, slots, chunk, 4).scheduler()
+    sched = _ladder.engine(NAME, slots, chunk, 4, fresh=True).scheduler()
     # under the live-rows split the chunk's forward is (1, chunk)
     split = sched._splits_chunk(("fused", False, True, chunk, 4))
     assert (pays(slots), pays(chunk if split else slots * chunk)) == {
@@ -256,81 +241,6 @@ def test_the_rule_picks_each_step_programs_dispatch(tiny, monkeypatch, slots, ch
     assert (programs["dense_held"] < programs["sparse"]) is (rows != "both"), programs
 
 
-@pytest.mark.parametrize("slots, chunk, steps, split, kernels", [
-    (4, 16, 1, False, False), (4, 16, 4, False, False), (4, 12, 4, False, False),
-    (4, 2, 4, False, False), (8, 64, 4, True, False), (4, 16, 4, False, True),
-    (8, 64, 4, True, True)])
-def test_served_path_matches_the_reference(tiny, slots, chunk, steps, split, kernels):
-    """Prefill in chunks (sizes that do not divide the prompt; chunks of 12
-    end inside a Mamba-2 chunk of 8; a chunk of 2 is shorter than the
-    convolution), then 16 decode steps through the pool at every position,
-    neighbours live in other slots, in the whole-block program and in the
-    live-rows split, in XLA and through the paged kernels (interpreted); the
-    reference is given the program's routing and follows none of it."""
-    eng = _engine(tiny, slots, chunk, steps, kernels)
-    sched = eng.scheduler()
-    assert eng.model_config.attention_impl == ("flash" if kernels else "xla")
-    assert sched._splits_chunk(("fused", False, True, chunk, steps)) is split
-    prompts = _prompts((37, 70, 9))
-    handles = [sched.submit(p, max_new_tokens=16, collect_logits=True) for p in prompts]
-    sched.drain()
-    tree = _tree(eng.module, eng.params)
-    for p, h in zip(prompts, handles):
-        ids = jnp.asarray([p + [int(t) for t in h.result()[:-1]]], jnp.int32)
-        choice = h.result_choice()[:, None, :ids.shape[1]]
-        assert choice.shape[0] == 3  # the three expert layers
-        want, routing = ref.forward(tree, ids, HP, first=len(p) - 1, choice=choice)
-        res = ref.compare(h.result_logits(), want[0], routing["followed"], routing["refused"],
-                          tol=TOL)
-        assert res["ok"] and res["rows"] == 16, res["error"]
-        assert res["routing_margin_rows"] == res["routing_refused_rows"] == 0
-    assert sched.state_slots_reset == 3 and sched.radix is None
-    assert sched.moe_dispatch_programs["dense"] == 0 < sched.moe_dispatch_programs["sparse"]
-
-
-def test_a_span_0_slot_is_bit_for_bit_unchanged(tiny):
-    """A sync that advances other slots leaves an idle slot's state, window
-    and rows exactly as they were: slot 1's, once its request has ended,
-    through a neighbour's chunked prefill and both neighbours' decode."""
-    sched = _engine(tiny, slots=4, chunk=16, steps=4).scheduler()
-    a, b, c = _prompts((20, 50, 100))
-    long_one = sched.submit(a, max_new_tokens=60)
-    short = sched.submit(b, max_new_tokens=6)  # still live when the third is admitted
-    late = sched.submit(c, max_new_tokens=8)
-    while not short.done:
-        sched.step()
-    assert sched.cache.state[1] == "free" and late._req.slot == 2 and not late.done
-    slot1 = lambda: [np.asarray(leaf[1]) for leaf in jax.tree_util.tree_leaves(sched.cache.pool)]
-    before = slot1()
-    assert all(np.any(x != 0) for x in before)
-    steps = 0
-    while not (long_one.done and late.done):
-        sched.step()
-        steps += 1
-    assert steps >= 6 and sched.cache.state[1] == "free"
-    for x, y in zip(before, slot1()):
-        np.testing.assert_array_equal(x, y)
-
-
-def test_a_reused_slot_gives_a_fresh_pools_logits(tiny):
-    """A new request in a slot that held another starts from a zero state and
-    window: its logits are a fresh pool's, bit for bit, whatever the
-    neighbours; one prompt twice is served cold twice and counted."""
-    prompt = _prompts((40, ), seed=5)[0]
-    fresh = _engine(tiny, slots=2, chunk=16).scheduler()
-    want = fresh.submit(prompt, max_new_tokens=8, collect_logits=True)
-    fresh.drain()
-    used = _engine(tiny, slots=2, chunk=16).scheduler()
-    for p in _prompts((33, 61), seed=6):
-        used.submit(p, max_new_tokens=10)
-    used.drain()
-    for _ in range(2):
-        got = used.submit(prompt, max_new_tokens=8, collect_logits=True)
-        used.drain()
-        np.testing.assert_array_equal(got.result_logits(), want.result_logits())
-    assert used.state_slots_reset == 4 and used.prefix_cache_state_bypass == 4
-
-
 def test_neighbours_in_other_slots_change_nothing(tiny):
     """One request alone in the pool and the same request among three others
     (another routing mix in every expert layer, other states beside its own,
@@ -338,10 +248,10 @@ def test_neighbours_in_other_slots_change_nothing(tiny):
     reference's float32 limit (the CPU's products round with the block's
     shape, by 3e-7)."""
     prompt = _prompts((45, ), seed=8)[0]
-    alone = _engine(tiny, slots=4, chunk=16).scheduler()
+    alone = _ladder.engine(NAME, slots=4, chunk=16).scheduler()
     want = alone.submit(prompt, max_new_tokens=12, collect_logits=True)
     alone.drain()
-    crowd = _engine(tiny, slots=4, chunk=16).scheduler()
+    crowd = _ladder.engine(NAME, slots=4, chunk=16).scheduler()
     others = [crowd.submit(p, max_new_tokens=30) for p in _prompts((21, 60), seed=9)]
     got = crowd.submit(prompt, max_new_tokens=12, collect_logits=True)
     late = crowd.submit(_prompts((35, ), seed=10)[0], max_new_tokens=20)
@@ -351,69 +261,11 @@ def test_neighbours_in_other_slots_change_nothing(tiny):
     assert res["ok"] and res["rows"] == 12, res["error"]
 
 
-@pytest.mark.parametrize("overrides, message", [
-    ({"spec_tokens": 2}, "speculative verify"),
-    ({"max_extents": 2}, "extent chains"),
-    ({"seq_parallel_min_tokens": 64}, "sequence-parallel prefill"),
-    ({"prefix_store": object()}, "tier demotion"),
-    ({"allow_lossy_kv": True}, "lossy KV windows"),
-    ({"kv_cache_dtype": "int8"}, "an int8 KV pool"),
-    ({"adapter_store": object()}, "adapters"),
-])
-def test_what_a_pool_with_mamba2_state_refuses(tiny, overrides, message):
-    eng = _engine(tiny, kernels=True)
-    with pytest.raises(ValueError, match=r"holds recurrent state \(layer_types\).*" + message):
-        eng.scheduler(**overrides)
-
-
-def test_the_other_refusals(tiny):
-    """The static-batch cache, int8 weights, a tensor-parallel pool, experts
-    outside ``moe`` layers, a mix with two-sublayer kinds, the training
-    router; the fused decode gate declines by kind."""
-    model, params = tiny
-    eng = _engine(tiny)
-    sched = eng.scheduler()
-    with pytest.raises(ValueError, match="cannot migrate between replicas"):
-        sched.migrate_out(None, "key", None)
-    with pytest.raises(ValueError, match="continuous-batching scheduler"):
-        eng.generate([[1, 2, 3]], max_new_tokens=2)
-    assert any("attention, mamba2, mlp, moe" in r for r in sched._fused_block_reasons)
-    with pytest.raises(ValueError, match="served in its float dtype"):
-        deepspeed_tpu.init_inference(model, config={"dtype": "int8"}, params=params)
-    with pytest.raises(NotImplementedError, match="span programs"):
-        model.apply_with_cache(params, jnp.zeros((2, 4), jnp.int32), model.init_cache(2, 64), 0)
-    with pytest.raises(NotImplementedError, match="no int8 tier"):
-        model.init_cache(2, 64, quantized=True)
-    cfg = model.cfg
-    with pytest.raises(ValueError, match="do not mix"):
-        dataclasses.replace(cfg, num_layers=2, layer_types=("mamba2", "full_attention"))
-    with pytest.raises(ValueError, match="moe layers need num_experts"):
-        dataclasses.replace(cfg, num_layers=1, layer_types=("mamba2", ))
-    with pytest.raises(ValueError, match="experts in a mixer-and-FFN block"):
-        dataclasses.replace(get_model("tiny-sambay").cfg, num_experts=4, moe_dropless=True)
-    with pytest.raises(ValueError, match="ssm_num_heads"):
-        dataclasses.replace(cfg, ssm_groups=3)
-    with pytest.raises(ValueError, match="no capacity-buffered path"):
-        dataclasses.replace(get_model("tiny-moe").cfg, moe_scoring="sigmoid")
-    from deepspeed_tpu.comm import comm
-    comm._state["mesh"] = None
-    comm.initialize_mesh(tensor=2)
-    tp = deepspeed_tpu.init_inference(model, config={
-        "dtype": "float32", "continuous_batching": {"enabled": True, "num_slots": 2}},
-        params=params)
-    with pytest.raises(ValueError, match="a tensor-parallel pool"):
-        tp.scheduler()
-
-
 def test_counters_of_required_state_work(tiny, tmp_path):
     """Hand-counted: one request of 20 prompt tokens, chunk 16, K = 4, alone
     in the pool; 3 Mamba-2 layers, 3 expert layers of 8 experts top-2."""
-    model, params = tiny
-    eng = deepspeed_tpu.init_inference(model, config={
-        "dtype": "float32", "max_out_tokens": 128,
-        "continuous_batching": {"enabled": True, "num_slots": 2, "steps_per_sync": 4,
-                                "prefill_chunk": 16},
-        "telemetry": {"enabled": True, "output_path": str(tmp_path)}}, params=params)
+    eng = _ladder.engine(NAME, slots=2, config={
+        "telemetry": {"enabled": True, "output_path": str(tmp_path)}})
     sched = eng.scheduler()
     sched.submit(_prompts((20, ))[0], max_new_tokens=8)
     sched.drain()
@@ -451,50 +303,6 @@ def test_a_one_sublayer_block_has_no_leaves_of_the_absent_half(tiny):
     # the pool: state for a Mamba-2 layer, rows for attention, nothing for an FFN
     kinds = model.cache_kinds()
     assert [k for k in kinds[0]] == ["state", None, "state", "rows", None, None, "state", None]
-
-
-def _digest(tree):
-    items = [(jax.tree_util.keystr(p), tuple(getattr(leaf, "shape", ())),
-              str(getattr(leaf, "dtype", leaf)))
-             for p, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]]
-    return hashlib.sha256(repr(items).encode()).hexdigest()[:16], len(items)
-
-
-# every preset the parent commit 51fe9b3 registers: (digest, leaves) of its
-# parameter tree (paths, shapes, dtypes), taken there with ``_digest``
-PARENT_TREES = {
-    "gpt2-125m": ("6d07fb2736454732", 20), "gpt2-large": ("eecb65f1ac785bed", 20),
-    "gpt2-medium": ("849d0b37ca49721a", 20), "gpt2-xl": ("314611c3dbf7dfc6", 20),
-    "llama2-7b": ("700e0b920ed6e38b", 12), "llama3-70b": ("a69a8630b0d72197", 12),
-    "llama3-8b": ("e2bb5b2b306a8848", 12), "mistral-small-4-119b": ("9f1f4c1b70b5955c", 19),
-    "mixtral-8x7b": ("a0664719c25ea1b2", 13), "olmo-hybrid-7b": ("7bf46abc96dcf40d", 475),
-    "opt-125m": ("6146cd9c77b7a716", 20), "opt-66b": ("ba9a7bbc96259f34", 20),
-    "phi-4-mini-flash-reasoning": ("6ca2df6e33827984", 502), "tiny": ("2e710ce0485acc07", 11),
-    "tiny-gpt2": ("6ae0caca0a306fc5", 20), "tiny-hybrid": ("fe34f5c3f89eebab", 62),
-    "tiny-mla-moe": ("585ee1651c3e6625", 19), "tiny-moe": ("68682b378434514e", 12),
-    "tiny-sambay": ("a16812365d3d6eca", 136),
-}
-
-
-@pytest.mark.parametrize("name", sorted(PARENT_TREES))
-def test_existing_presets_build_the_trees_they_built(name):
-    """``Block`` picks mixer and FFN from the kind, relu2 experts have two
-    leaves, the shared expert a width of its own: none of it moves the
-    parameter tree of a preset the parent had."""
-    model = get_model(name)
-    assert _digest(jax.eval_shape(model.init_params, jax.random.key(0))) == PARENT_TREES[name]
-
-
-def test_no_preset_goes_unguarded():
-    # PR 41's presets are guarded by tests/unit/inference/test_exaone_moe_pool.py,
-    # PR 50's by tests/unit/inference/test_lfm2_moe_pool.py
-    assert set(available_models()) == set(PARENT_TREES) | {
-        "nemotron-3-nano-30b-a3b", "tiny-nemotron-h", "k-exaone-236b-a23b", "tiny-exaone-moe",
-        "lfm2-8b-a1b", "tiny-lfm2-moe",
-        # PR 54's by tests/unit/inference/test_ling_hybrid_pool.py
-        "ling-3.0-flash", "tiny-ling",
-        # PR 56's by tests/unit/inference/test_falcon_h1_pool.py
-        "falcon-h1-34b-instruct", "tiny-falcon-h1"}
 
 
 def test_preset_builds_the_published_sizes():

@@ -53,7 +53,7 @@ def test_scheduler_packed_equals_split(monkeypatch, kv_dtype):
 
 def _migrated(kv_dtype):
     from deepspeed_tpu.serving import ReplicaSet
-    rs = ReplicaSet.build(engine(num_slots=4, kv_cache_dtype=kv_dtype,
+    rs = ReplicaSet.build(engine(slots=4, kv_cache_dtype=kv_dtype,
                                   roles=["prefill", "decode"]), 2)
     handles = [rs.dispatch(p, max_new_tokens=10, collect_logits=True, seed=7)[1]
                for p in (LONG, LONG, OTHER)]
@@ -74,7 +74,7 @@ def test_migration_packed_equals_split(monkeypatch, kv_dtype):
 
 
 def _fused_stream():
-    eng = engine("tiny-gpt2", num_slots=3)
+    eng = engine("tiny-gpt2", slots=3)
     sched = eng.scheduler()
     assert sched._fused_block, sched._fused_block_reasons
     runs = [ask(sched, p, n=6) for p in (LONG[:40], OTHER[:7], LONG[:23], LONG[:40])]

@@ -13,19 +13,17 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from unittest import mock
+
 import deepspeed_tpu
-from deepspeed_tpu.comm import comm
 from deepspeed_tpu.inference import scheduler as sched_mod
 from deepspeed_tpu.models import get_model
 
+from ._serving import engine
+from ._serving import fresh_process_state as _fresh
+
 K = 4
 CHUNK = 16
-
-
-def _fresh():
-    comm._state["mesh"] = None
-    from deepspeed_tpu.telemetry import set_sink
-    set_sink(None)
 
 
 def _tiny():
@@ -66,19 +64,16 @@ def served(request):
     model, params, kw = MODELS[request.param]()
     if params is None:
         params = jax.jit(model.init_params)(jax.random.key(5))  # (jitted: seconds less a worker)
-    return request.param, model, jax.device_get(params), dict(kw, programs={})
+    return request.param, model, jax.device_get(params), kw
 
 
-def _scheduler(model, params, serial=False, slots=3, programs=None):
-    """``programs``: a dict the schedulers of one model share, as the replicas
-    of a fleet do, so that each step program is built once a model and not
-    once a scheduler."""
-    _fresh()
-    eng = deepspeed_tpu.init_inference(model, params=params, config={
-        "dtype": "float32", "max_out_tokens": 128,
-        "continuous_batching": {"enabled": True, "num_slots": slots, "steps_per_sync": K,
-                                "prefill_chunk": CHUNK}})
-    sched = eng.scheduler(compiled_cache=programs)
+def _scheduler(model, params, serial=False, slots=3, fresh=False):
+    """The schedulers of one model share their step programs, as the replicas
+    of a fleet do (``_serving.engine``): each is built once a model and not
+    once a scheduler. The pumps forced to land first share among themselves
+    only."""
+    sched = engine((model, params), slots, CHUNK, K, fresh=fresh,
+                   also="lands first" if serial else None).scheduler()
     if serial:  # the pump the parent ran: every sync lands before the next is launched
         sched._lands_first = lambda: True
     return sched
@@ -122,21 +117,19 @@ def test_ahead_pump_is_token_for_token_the_serial_pump(served):
     to land first: the same tokens, for every request, with a request ended
     by an EOS in mid-sync among them (its slot's next tenant included)."""
     name, model, params, submit_kw = served
-    submit_kw = dict(submit_kw)
-    programs = submit_kw.pop("programs")
     vocab = model.cfg.vocab_size
     reqs = _requests(vocab)
     # a first serial pass finds where an EOS can fire in mid-sync
     plain = [h.result().tolist()
-             for h in _run(_scheduler(model, params, serial=True, programs=programs), reqs)]
+             for h in _run(_scheduler(model, params, serial=True), reqs)]
     picked = [(i, _mid_sync_eos(toks)) for i, toks in enumerate(plain)
               if reqs[i][1].get("do_sample") and _mid_sync_eos(toks) is not None]
     assert picked, "no sampled stream has a fresh token in mid-sync"
     i, j = picked[0]
     reqs[i] = (reqs[i][0], dict(reqs[i][1], eos_token_id=plain[i][j]))
 
-    serial = _scheduler(model, params, serial=True, programs=programs)
-    ahead = _scheduler(model, params, programs=programs)
+    serial = _scheduler(model, params, serial=True)
+    ahead = _scheduler(model, params)
     want = _run(serial, reqs, **submit_kw)
     got = _run(ahead, reqs, **submit_kw)
     assert serial.syncs_ahead == 0 and serial.ahead_rows_discarded == 0
@@ -161,8 +154,8 @@ def test_warm_programs_warms_the_carried_token_merge(served):
     its own at each ids width: after ``warm_programs`` the one-deep pump's
     traffic, greedy and sampled, compiles nothing (a compile inside a
     benchmark window makes the run not ``correct``)."""
-    _, model, params, kw = served
-    sched = _scheduler(model, params, programs=kw["programs"])
+    _, model, params, _ = served
+    sched = _scheduler(model, params, fresh=True)  # what it compiles is what is counted
     sched.warm_programs()
     compiles = []
     jax.monitoring.register_event_duration_secs_listener(
@@ -214,16 +207,25 @@ def _drafter_sched():
                                           ([10, 11, 12], {"max_new_tokens": 9})]
 
 
+# an offload or long-context scheduler warms every step program its ladder can
+# reach as it is built, with every span zero: nothing of the pump's order shows
+# there. Built cold, the traffic below compiles the few it reaches
+_COLD = mock.patch.object(sched_mod.DecodeScheduler, "warm_programs",
+                          lambda self, ladder=True: None)
+
+
 def _offload_sched():
     from .test_moe_decode import OFFLOAD_REQS, make_engine
-    return make_engine(1, offload=2).scheduler(), OFFLOAD_REQS
+    with _COLD:
+        return make_engine(1, offload=2).scheduler(), OFFLOAD_REQS
 
 
 def _paged_sched():
     from .test_long_context import LPROMPT, make_long_engine
     eng = make_long_engine(hierarchical_kv={"enabled": True, "host_capacity_mb": 64})
-    return (eng.scheduler(max_len=64, prefill_chunk=16, max_extents=4),
-            [(LPROMPT, {"max_new_tokens": 24})])
+    with _COLD:
+        return (eng.scheduler(max_len=64, prefill_chunk=16, max_extents=4),
+                [(LPROMPT, {"max_new_tokens": 24})])
 
 
 @pytest.mark.parametrize("build", [_drafter_sched, _offload_sched, _paged_sched],

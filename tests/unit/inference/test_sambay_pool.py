@@ -13,63 +13,29 @@ that a dropped bias or scale shows. ``TOL``: the reference's float32 limit,
 or span gives 1e-3 and up."""
 
 import dataclasses
-import hashlib
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 import pytest
 
-import deepspeed_tpu
-from chipbench.references import phi4_flash as ref
 from deepspeed_tpu.models import get_model
 from deepspeed_tpu.models import transformer as tfm
 
-TOL = ref.TOL["float32"]
-HP = {"eps": 1e-5, "head_dim": 64}
-VOCAB = 256
+from . import _ladder
+from ._serving import VOCAB
+from ._serving import prompts as _prompts
 
-
-def _params(model, seed=7):
-    """The benchmark's draw, biases and norm scales perturbed."""
-    from chipbench.jobs.serve_sambay import sambay_params
-    root = jax.random.key(seed)
-
-    def perturb(path, leaf):
-        name = jax.tree_util.keystr(path)
-        key = jax.random.fold_in(root, int(hashlib.sha256(name.encode()).hexdigest()[:7], 16))
-        if name.endswith("['bias']") or name.endswith("['conv_bias']"):
-            return 0.1 * jax.random.normal(key, leaf.shape, leaf.dtype)
-        if name.endswith("['scale']"):
-            return 1.0 + 0.1 * jax.random.normal(key, leaf.shape, leaf.dtype)
-        return leaf
-
-    return jax.tree_util.tree_map_with_path(perturb,
-                                            sambay_params(model, seed, jnp.dtype("float32")))
+NAME = "tiny-sambay"
+ref, HP, TOL = _ladder.reference(NAME)
 
 
 @pytest.fixture(scope="module")
 def tiny():
-    model = get_model("tiny-sambay", dtype=jnp.float32)
-    return model, _params(model)
-
-
-def _engine(tiny, slots=4, chunk=16, steps=4, kernels=False, **cb):
-    model, params = tiny
-    return deepspeed_tpu.init_inference(model, config={
-        "dtype": "float32", "kernel_inject": kernels, "max_out_tokens": 128,
-        "continuous_batching": dict({"enabled": True, "num_slots": slots,
-                                     "steps_per_sync": steps, "prefill_chunk": chunk}, **cb)},
-        params=params)
-
-
-def _prompts(lengths, seed=0):
-    rng = np.random.RandomState(seed)
-    return [[int(t) for t in rng.randint(0, VOCAB, n)] for n in lengths]
+    return _ladder.built(NAME)
 
 
 def _tree(model, params):
-    return ref.from_tree(params, model.cfg.layer_types, model.cfg.layer_windows)
+    return _ladder.tree_of(NAME, model, params)
 
 
 def _reference_logits(eng, prompt, tokens):
@@ -79,15 +45,18 @@ def _reference_logits(eng, prompt, tokens):
 
 
 def _agrees(model, params, ids):
-    with jax.default_matmul_precision("highest"):
-        got = model.apply(params, ids)
-    want = ref.forward(_tree(model, params), ids, HP)
-    res = ref.compare(got.reshape(-1, VOCAB), want.reshape(-1, VOCAB), tol=TOL)
+    res = _ladder.agrees(NAME, model, params, ids)
     assert res["ok"], res["error"]
 
 
-def test_full_forward_matches_the_reference(tiny):
-    _agrees(*tiny, jax.random.randint(jax.random.key(1), (2, 70), 0, VOCAB))
+class TestLadder(_ladder.Ladder):
+    """(70 positions wrap the 16-row ring four times; chunks of 12 straddle
+    the ring's end: rows 12-15 and 0-7.)"""
+    twin = NAME
+
+    def more_refusals(self, eng, sched):
+        with pytest.raises(NotImplementedError, match="no int8 tier"):
+            eng.module.init_cache(2, 64, quantized=True)
 
 
 @pytest.mark.parametrize("kinds, windows", [
@@ -105,7 +74,7 @@ def test_each_mixer_alone_matches_its_reference(tiny, kinds, windows):
     cfg = dataclasses.replace(tiny[0].cfg, num_layers=len(kinds), layer_types=kinds,
                               layer_windows=windows)
     model = type(tiny[0])(cfg)
-    _agrees(model, _params(model, seed=11),
+    _agrees(model, _ladder.params_of(NAME, model, seed=11),
             jax.random.randint(jax.random.key(2), (2, 50), 0, VOCAB))
 
 
@@ -127,28 +96,6 @@ def test_layer_table_and_its_rules():
         dataclasses.replace(cfg, num_layers=1, layer_types=("mamba", ), layer_windows=(16, ))
 
 
-@pytest.mark.parametrize("slots, chunk, steps, split, kernels", [
-    (4, 16, 1, False, False), (4, 16, 4, False, False), (4, 12, 4, False, False),
-    (8, 64, 4, True, False), (4, 16, 4, False, True), (8, 64, 4, True, True)])
-def test_served_path_matches_the_reference(tiny, slots, chunk, steps, split, kernels):
-    """Prefill in chunks (a partial last one; 70 positions wrap the 16-row
-    ring four times; chunks of 12 straddle the ring's end: rows 12-15 and
-    0-7), then 16 decode steps through the pool at every position,
-    neighbours live in other slots, in the whole-block program and in the
-    live-rows split, in XLA and through the paged kernels (interpreted)."""
-    eng = _engine(tiny, slots, chunk, steps, kernels)
-    sched = eng.scheduler()
-    assert eng.model_config.attention_impl == ("flash" if kernels else "xla")
-    assert sched._splits_chunk(("fused", False, True, chunk, steps)) is split
-    prompts = _prompts((37, 70, 9))
-    handles = [sched.submit(p, max_new_tokens=16, collect_logits=True) for p in prompts]
-    sched.drain()
-    for p, h in zip(prompts, handles):
-        res = ref.compare(h.result_logits(), _reference_logits(eng, p, h.result()), tol=TOL)
-        assert res["ok"] and res["rows"] == 16, res["error"]
-    assert sched.state_slots_reset == 3 and sched.radix is None
-
-
 def test_a_window_that_is_not_the_rings_length(tiny):
     """A window of 12 rests in a ring of 16 rows: the ring then holds keys
     that have left the window, masked by position, in XLA and with the
@@ -157,7 +104,7 @@ def test_a_window_that_is_not_the_rings_length(tiny):
         tiny[0].cfg, layer_windows=(0, 12, 0, 12, 0, 0, 0, 0)))
     assert [model.cfg.ring_rows(i) for i in (1, 3)] == [16, 16]
     for kernels in (False, True):
-        eng = _engine((model, tiny[1]), 4, 16, 4, kernels)
+        eng = _ladder.engine(NAME, 4, 16, 4, kernels, twin=(model, tiny[1]))
         sched = eng.scheduler()
         prompt = _prompts((45, ), seed=3)[0]
         h = sched.submit(prompt, max_new_tokens=16, collect_logits=True)
@@ -188,104 +135,12 @@ def test_a_cross_layer_reads_the_chunks_own_rows(tiny):
     assert len(kinds) == len(jax.tree_util.tree_leaves(pool)) == 12
 
 
-def test_a_span_0_slot_is_bit_for_bit_unchanged(tiny):
-    """A sync that advances other slots leaves an idle slot's state, window,
-    ring and rows exactly as they were: slot 1's, once its request has
-    ended, through a neighbour's chunked prefill and both neighbours'
-    decode."""
-    sched = _engine(tiny, slots=4, chunk=16, steps=4).scheduler()
-    a, b, c = _prompts((20, 50, 100))
-    long_one = sched.submit(a, max_new_tokens=60)
-    short = sched.submit(b, max_new_tokens=6)  # still live when the third is admitted
-    late = sched.submit(c, max_new_tokens=8)
-    while not short.done:
-        sched.step()
-    assert sched.cache.state[1] == "free" and late._req.slot == 2 and not late.done
-    slot1 = lambda: [np.asarray(leaf[1]) for leaf in jax.tree_util.tree_leaves(sched.cache.pool)]
-    before = slot1()
-    assert all(np.any(x != 0) for x in before)
-    steps = 0
-    while not (long_one.done and late.done):
-        sched.step()
-        steps += 1
-    assert steps >= 6 and sched.cache.state[1] == "free"
-    for x, y in zip(before, slot1()):
-        np.testing.assert_array_equal(x, y)
-
-
-def test_a_reused_slot_gives_a_fresh_pools_logits(tiny):
-    """A new request in a slot that held another starts from a zero state
-    and sees none of the ring's old rows: its logits are a fresh pool's, bit
-    for bit; one prompt twice is served cold twice and counted."""
-    prompt = _prompts((40, ), seed=5)[0]
-    fresh = _engine(tiny, slots=2, chunk=16).scheduler()
-    want = fresh.submit(prompt, max_new_tokens=8, collect_logits=True)
-    fresh.drain()
-    used = _engine(tiny, slots=2, chunk=16).scheduler()
-    for p in _prompts((33, 61), seed=6):
-        used.submit(p, max_new_tokens=10)
-    used.drain()
-    for _ in range(2):
-        got = used.submit(prompt, max_new_tokens=8, collect_logits=True)
-        used.drain()
-        np.testing.assert_array_equal(got.result_logits(), want.result_logits())
-    assert used.state_slots_reset == 4 and used.prefix_cache_state_bypass == 4
-
-
-@pytest.mark.parametrize("overrides, message", [
-    ({"spec_tokens": 2}, "speculative verify"),
-    ({"max_extents": 2}, "extent chains"),
-    ({"seq_parallel_min_tokens": 64}, "sequence-parallel prefill"),
-    ({"prefix_store": object()}, "tier demotion"),
-    ({"allow_lossy_kv": True}, "lossy KV windows"),
-    ({"kv_cache_dtype": "int8"}, "an int8 KV pool"),
-    ({"adapter_store": object()}, "adapters"),
-])
-def test_what_a_pool_with_ring_or_shared_rows_refuses(tiny, overrides, message):
-    eng = _engine(tiny, kernels=True)
-    with pytest.raises(ValueError, match="holds recurrent state, ring rows, rows that layers "
-                                         "share.*" + message):
-        eng.scheduler(**overrides)
-
-
-def test_the_other_refusals(tiny):
-    """Migration between replicas, the static-batch cache, int8 weights, a
-    tensor-parallel pool; the fused decode gate declines by kind."""
-    model, params = tiny
-    eng = _engine(tiny)
-    sched = eng.scheduler()
-    with pytest.raises(ValueError, match="cannot migrate between replicas"):
-        sched.migrate_out(None, "key", None)
-    with pytest.raises(ValueError, match="continuous-batching scheduler"):
-        eng.generate([[1, 2, 3]], max_new_tokens=2)
-    assert any("cross_attention, diff_attention, gmu, mamba" in r
-               for r in sched._fused_block_reasons)
-    with pytest.raises(ValueError, match="served in its float dtype"):
-        deepspeed_tpu.init_inference(model, config={"dtype": "int8"}, params=params)
-    with pytest.raises(NotImplementedError, match="span programs"):
-        model.apply_with_cache(params, jnp.zeros((2, 4), jnp.int32), model.init_cache(2, 64), 0)
-    with pytest.raises(NotImplementedError, match="no int8 tier"):
-        model.init_cache(2, 64, quantized=True)
-    from deepspeed_tpu.comm import comm
-    comm._state["mesh"] = None
-    comm.initialize_mesh(tensor=2)
-    tp = deepspeed_tpu.init_inference(model, config={
-        "dtype": "float32", "continuous_batching": {"enabled": True, "num_slots": 2}},
-        params=params)
-    with pytest.raises(ValueError, match="a tensor-parallel pool"):
-        tp.scheduler()
-
-
 def test_counters_and_gauges_of_attended_rows(tiny, tmp_path):
     """Hand-counted: one request of 20 prompt tokens, chunk 16, K = 4, alone
     in the pool; 2 windowed layers (window 16), 2 readers of the shared rows
     (the full layer and the cross layer)."""
-    model, params = tiny
-    eng = deepspeed_tpu.init_inference(model, config={
-        "dtype": "float32", "max_out_tokens": 128,
-        "continuous_batching": {"enabled": True, "num_slots": 2, "steps_per_sync": 4,
-                                "prefill_chunk": 16},
-        "telemetry": {"enabled": True, "output_path": str(tmp_path)}}, params=params)
+    eng = _ladder.engine(NAME, slots=2, config={
+        "telemetry": {"enabled": True, "output_path": str(tmp_path)}})
     sched = eng.scheduler()
     sched.submit(_prompts((20, ))[0], max_new_tokens=8)
     sched.drain()
@@ -339,39 +194,3 @@ def test_preset_builds_the_published_sizes():
     assert len(shapes) == 36
     for comp in served.cache_kinds():
         assert all(k is None for k in comp[18:])
-
-
-def _digest(tree):
-    items = [(jax.tree_util.keystr(p), tuple(getattr(leaf, "shape", ())),
-              str(getattr(leaf, "dtype", leaf)))
-             for p, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]]
-    return hashlib.sha256(repr(items).encode()).hexdigest()[:16], len(items)
-
-
-@pytest.mark.parametrize("name, overrides, params, pool, kinds", [
-    ("gpt2-large", {}, ("eecb65f1ac785bed", 20), ("2ace4ad8ee7b3d07", 1), ("7efe6c18a76c403f", 1)),
-    ("gpt2-large", {"scan_layers": False}, ("9c4584445a884b9c", 580), ("253ab50641bbfc93", 36),
-     ("70973dd72aba3407", 36)),
-    ("llama2-7b", {"scan_layers": False}, ("413338bc326a560b", 291), ("e55ad93897f78f38", 64),
-     ("7d581e97c6a287c5", 64)),
-    # PR 55: the latent leaves rest position-last, (2, 1, 320, 64), declared
-    # "columns" (30bae00ac1380acc / 70973dd72aba3407 while they were rows of
-    # 320; tiny-mla-moe's 3b84258aa35bd04e / 7efe6c18a76c403f likewise)
-    ("mistral-small-4-119b", {"scan_layers": False}, ("d378c3c33fb6535c", 579),
-     ("2b3563502a59eee8", 36), ("5bb7fe90c6ddf5e9", 36)),
-    # PR 42: its state leaves rest two heads a lane row, (2, 15, 96, 384)
-    # (1c5f151820235ef8 while they were (2, 30, 96, 192))
-    ("olmo-hybrid-7b", {}, ("7bf46abc96dcf40d", 475), ("df45013d6f63db31", 64),
-     ("72fa486ac50a3d4b", 64)),
-    ("tiny-hybrid", {}, ("fe34f5c3f89eebab", 62), ("19da4dfe885cea0f", 8), ("2cb26fe38a1746cd", 8)),
-    ("tiny-mla-moe", {}, ("585ee1651c3e6625", 19), ("52eea5a78f13e3c5", 1), ("d70a613fd25d4c61", 1)),
-])
-def test_models_without_new_layer_types_build_what_they_built(name, overrides, params, pool, kinds):
-    """The parameter tree, the cache tree and the declared kinds (paths,
-    shapes, dtypes; digests taken at the parent commit a87638f with this very
-    function): models without the new kinds build what they built, so their
-    step programs are the parent's and its compile-cache entries are hit."""
-    model = get_model(name, **overrides)
-    assert _digest(jax.eval_shape(model.init_params, jax.random.key(0))) == params
-    assert _digest(jax.eval_shape(lambda: model.init_cache(2, 64))) == pool
-    assert _digest(model.cache_kinds()) == kinds

@@ -12,27 +12,30 @@ import pytest
 import jax
 
 import deepspeed_tpu
-from deepspeed_tpu.comm import comm
+
+from ._serving import engine, fresh_process_state
 
 PROMPTS = [[5, 6, 7, 8, 9], [10, 11, 12]]
 
 
 def make_engine(model="tiny", params=None, **cfg):
-    comm._state["mesh"] = None
-    # drop any process-global telemetry sink a previous test's engine
+    # (drops any process-global telemetry sink a previous test's engine
     # installed: an enabled global sink takes precedence over this engine's
-    # own config, so counter assertions would see cross-test events
-    from deepspeed_tpu.telemetry import set_sink
-    set_sink(None)
+    # own config, so counter assertions would see cross-test events)
+    fresh_process_state()
     config = {"dtype": "float32"}
     config.update(cfg)
     return deepspeed_tpu.init_inference(model, config=config, params=params)
 
 
-def make_sched_engine(params=None, num_slots=4, collect_logits=False, **cfg):
-    cfg["continuous_batching"] = {"enabled": True, "num_slots": num_slots,
-                                  "collect_logits": collect_logits}
-    return make_engine(params=params, **cfg)
+def make_sched_engine(params=None, num_slots=4, collect_logits=False, fresh=False, **cfg):
+    """An engine over ``tiny`` whose scheduler shares its shape's step programs
+    (``_serving.engine``); ``fresh``: a case that asserts what its scheduler
+    BUILT (``_compiled``, ``compiled_program_count()``, XLA's compiles) or sets
+    what a trace reads builds its own."""
+    kernels = cfg.pop("replace_with_kernel_inject", False)
+    return engine(("tiny", params), num_slots, 64, 4, kernels, fresh=fresh,
+                  config=dict({"max_out_tokens": 1024}, **cfg), collect_logits=collect_logits)
 
 
 @pytest.fixture(scope="module")
@@ -128,7 +131,7 @@ def test_sampling_reproducible_and_slot_independent(baseline):
     """Seeded sampling is a function of (seed, step), not slot or batch
     composition: the same request re-submitted into a busy pool repeats."""
     params, _ = baseline
-    eng = make_sched_engine(params, num_slots=3)
+    eng = make_sched_engine(params, fresh=True, num_slots=3)
     sched = eng.scheduler()
     kw = dict(max_new_tokens=6, do_sample=True, temperature=0.7, top_k=20, top_p=0.9,
               seed=11)
@@ -242,7 +245,7 @@ def test_fused_compile_count_o1_in_length_mix(baseline):
     prompt-length mix, measured by actual XLA backend compiles
     (jax.monitoring), not just the scheduler's own cache."""
     params, _ = baseline
-    eng = make_sched_engine(params, num_slots=3)
+    eng = make_sched_engine(params, fresh=True, num_slots=3)
     sched = eng.scheduler()  # radix cache on by default
     assert sched.radix is not None
     compiles = _count_xla_compiles()
@@ -373,7 +376,7 @@ def test_prefix_cache_hit_bit_identical_logits(baseline):
     cold_logits = cold.result_logits()
     assert sched_cold.radix is None
 
-    eng = make_sched_engine(params, collect_logits=True)
+    eng = make_sched_engine(params, fresh=True, collect_logits=True)
     sched = eng.scheduler()
     first = sched.submit(prompt, max_new_tokens=6)
     first_logits = first.result_logits()  # cold: registers the 70-token prefix
@@ -394,7 +397,7 @@ def test_prefix_cache_single_slot_repeat_hits(baseline):
     resident (src == dst copy is a no-op), and the hit stands."""
     params, _ = baseline
     prompt = [int(t) for t in np.resize(np.arange(5, 47), 70)]  # > one chunk
-    eng = make_sched_engine(params, num_slots=1)
+    eng = make_sched_engine(params, fresh=True, num_slots=1)
     sched = eng.scheduler()
     first = sched.submit(prompt, max_new_tokens=6).result()
     again = sched.submit(prompt, max_new_tokens=6).result()
@@ -415,7 +418,7 @@ def test_prefix_cache_eviction_spares_matched_donor(baseline):
     params, _ = baseline
     pa = [int(t) for t in np.resize(np.arange(5, 47), 70)]
     pb = [int(t) for t in np.resize(np.arange(90, 140), 70)]
-    eng = make_sched_engine(params, num_slots=2)
+    eng = make_sched_engine(params, fresh=True, num_slots=2)
     sched = eng.scheduler()
     sched.submit(pa, max_new_tokens=3).result()  # donor, and the LRU entry
     sched.submit(pb, max_new_tokens=3).result()
@@ -498,7 +501,7 @@ def test_on_token_changes_nothing(baseline):
     it runs host-side after the fetch, never inside a program. A raising
     hook is logged and swallowed: delivery and the shared loop continue."""
     params, _ = baseline
-    eng = make_sched_engine(params, num_slots=2, collect_logits=True)
+    eng = make_sched_engine(params, fresh=True, num_slots=2, collect_logits=True)
     sched = eng.scheduler()
     plain = sched.submit(PROMPTS[0], max_new_tokens=6)
     plain_logits = plain.result_logits()
@@ -600,7 +603,7 @@ def test_speculative_compile_count_o1(baseline):
     (sampling, collect) actually used, at the single configured width.
     Draft counts, acceptance patterns, and prompt lengths are runtime data."""
     params, _ = baseline
-    eng = make_sched_engine(params, num_slots=3)
+    eng = make_sched_engine(params, fresh=True, num_slots=3)
     sched = eng.scheduler(spec_tokens=4)
     # phase 1 warms the full program set: short/long prompts (both fused
     # sync step-count variants + a radix copy), a repetitive prompt (spec
@@ -768,19 +771,20 @@ def _program_tags(sched):
     return {k[0] for k in sched._compiled if k != "copy"}
 
 
-def _split_engine(model, params=None, whole_block=False, **cfg):
+def _split_engine(model, params=None, whole_block=False, fresh=False):
     """An (8, 64) scheduler of ``model`` (a preset, or ``FUSED_INT8``) that
     collects logits, with the scheduler module's shape rule as it is or
     (``whole_block``) answering no, so the same traffic runs through the
-    whole-block programs."""
+    whole-block programs. ``whole_block`` sets what a program's build reads,
+    and the pair whose ``_compiled`` is compared is ``fresh``: both build
+    their own programs; the others share."""
     from deepspeed_tpu.inference.scheduler import _split_pays
     from deepspeed_tpu.models import get_model
     fused = model == FUSED_INT8
     if fused:
         model = get_model("tiny-gpt2", head_dim=64)
-        cfg.update(dtype="int8", kernel_inject=True)
-    cfg["continuous_batching"] = dict(enabled=True, collect_logits=True, **SPLIT_SHAPE)
-    eng = make_engine(model, params=params, max_out_tokens=128, **cfg)
+    eng = engine((model, params), 8, 64, 4, fused, fresh=fresh or whole_block,
+                 config={"dtype": "int8"} if fused else {}, collect_logits=True)
     sched = eng.scheduler()
     assert sched._fused_block == fused, sched._fused_block_reasons
     if whole_block:
@@ -814,7 +818,7 @@ def split_pair(request):
         from deepspeed_tpu.models import get_model
         params = jax.device_get(get_model("tiny-gpt2", head_dim=64).init_params(
             jax.random.key(3)))
-    eng, sched = _split_engine(model, params)
+    eng, sched = _split_engine(model, params, fresh=True)
     if params is None:
         params = jax.device_get(eng.params)
     vocab = eng.model_config.vocab_size
@@ -894,6 +898,15 @@ def test_split_leaves_other_slots_byte_stable(split_pair):
     sched.cache.check_invariants()
 
 
+def _warm_unrun(sched):
+    """``warm_programs(ladder=False)`` with its dispatches left out: every
+    program it reaches is built (its key, its split decision) and none is
+    traced or run. For the scheduler whose KEYS are compared with those of one
+    that warmed for real."""
+    sched._call_step = lambda fn, args, lora, spans, lens: (sched.cache.pool, )
+    sched.warm_programs(ladder=False)
+
+
 def _fused_int8_sched(shape=SPLIT_SHAPE, **cfg):
     """A scheduler on the fused int8 decode blocks (tag ``fused_block``)."""
     sched = make_engine(dtype="int8", kernel_inject=True,
@@ -942,7 +955,7 @@ def test_programs_that_keep_the_whole_block(baseline, case):
             sched.warm_programs(ladder=False)
             whole = _fused_int8_sched()
             whole._splits_chunk = lambda key: False
-            whole.warm_programs(ladder=False)
+            _warm_unrun(whole)
             assert set(sched._compiled) == set(whole._compiled)
             assert sched.compiled_program_count() == whole.compiled_program_count() == 7
             assert _program_tags(sched) == {"fused_block"}
@@ -953,7 +966,7 @@ def test_programs_that_keep_the_whole_block(baseline, case):
         sched.warm_programs(ladder=False)
         block = make_engine(params=params, continuous_batching=cb).scheduler()
         block._splits_chunk = lambda key: False
-        block.warm_programs(ladder=False)
+        _warm_unrun(block)
         assert set(sched._compiled) == set(block._compiled)
         assert sched.compiled_program_count() == block.compiled_program_count() == 7
         return

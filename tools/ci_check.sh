@@ -5,8 +5,10 @@
 #   tools/ci_check.sh --gateway  # gateway smoke only
 #
 # Tier-1 is the driver's own command (`commands` in /root/TESTS_LAST_RUN.json):
-# every test under tests/ that is not marked slow, six workers, one file a
-# worker at a time, cut at 1,470 s. ALLOW_MULTIPLE_LIBTPU_LOAD lets the workers
+# every test under tests/ that is not marked slow, six workers, single tests
+# dealt as workers free up (--dist load; tests/conftest.py keeps the files
+# whose cases share step programs on one worker each), cut at 1,470 s.
+# ALLOW_MULTIPLE_LIBTPU_LOAD lets the workers
 # load the TPU compiler side by side on a machine without a chip; never set it
 # on a machine that has one. DOTS_PASSED is the count the driver holds a PR to.
 # Exit code is nonzero if either part fails.
@@ -33,8 +35,11 @@ out=$(mktemp -d "${TMPDIR:-/tmp}/ci_check.XXXXXX")
 trap 'rm -rf "$out"' EXIT
 timeout -k 10 1470 env JAX_PLATFORMS=cpu ALLOW_MULTIPLE_LIBTPU_LOAD=1 python -m pytest tests/ \
   -q -m 'not slow' --continue-on-collection-errors -p no:cacheprovider -p xdist -n 6 \
-  --dist loadfile --junitxml="$out/t1.xml" -p no:randomly 2>&1 | tee "$out/t1.log"
+  --dist load --junitxml="$out/t1.xml" -p no:randomly 2>&1 | tee "$out/t1.log"
 t1_rc=${PIPESTATUS[0]}
+# the wall the driver's limit cuts, and the sum of the cases' own times (what a
+# file costs whichever worker runs it): tools/junit_times.py FILE lists it a file
+python tools/junit_times.py --total "$out/t1.xml"
 echo "DOTS_PASSED=$(grep -aE '^[.FEsx]+( *\[ *[0-9]+%\])?$' "$out/t1.log" | tr -cd . | wc -c)"
 echo "WORKERS_DOWN=$(grep -acE '\[gw[0-9]+\] node down' "$out/t1.log")"
 
